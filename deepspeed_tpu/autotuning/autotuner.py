@@ -380,8 +380,11 @@ class KnobSearch:
     probe_runner: object = None  # (kind, overrides, steps) -> (dict, err)
     steps: int = 3
     seq_len: int = 128
-    # device-byte budget for pruning; None = ask the backend (the CPU test
-    # mesh reports none -> pruning off, every candidate is measured)
+    # device-byte budget for pruning and device count; None = what the
+    # default-config probe reports it ran on (the CPU test mesh reports no
+    # budget -> pruning off, every candidate is measured). This parent
+    # never asks a jax backend itself: it would then hold the chip its
+    # probe children need.
     memory_bytes: float | None = None
     n_devices: int | None = None
     cost_ctx: dict = field(default_factory=dict)  # knob cost-hint inputs
@@ -398,19 +401,8 @@ class KnobSearch:
             self.space = DEFAULT_SPACE
         return self.space
 
-    def _n_dev(self) -> int:
-        if self.n_devices is None:
-            import jax
-
-            self.n_devices = len(jax.devices())
-        return self.n_devices
-
     def _knob_default(self, name):
         return self._space().get(name).default
-
-    def _limit(self) -> float | None:
-        return (self.memory_bytes if self.memory_bytes is not None
-                else device_memory_bytes())
 
     # ------------------------------------------------------------ pruning
     def _estimate_bytes(self, overrides: dict) -> float | None:
@@ -435,7 +427,11 @@ class KnobSearch:
             act = info.activation_bytes(mb, self.seq_len)
             if g("activation_checkpointing.enabled"):
                 act /= 2
-            return (info.state_bytes(stage, self._n_dev(),
+            if self.n_devices is None:
+                raise ValueError(
+                    "KnobSearch: no device count — pass n_devices, or use a "
+                    "probe runner whose result carries a 'device' block")
+            return (info.state_bytes(stage, self.n_devices,
                                      sharded_update=sharded) + act)
         est = 0.0
         for name, value in ov.items():
@@ -446,7 +442,7 @@ class KnobSearch:
         return est if est > 0.0 else None
 
     def _prune_reason(self, overrides: dict) -> str | None:
-        limit = self._limit()
+        limit = self.memory_bytes
         if not limit:
             return None
         est = self._estimate_bytes(overrides)
@@ -537,6 +533,11 @@ class KnobSearch:
         if not baseline.ok:
             raise RuntimeError(
                 f"autotuning: the default-config probe failed: {baseline.error}")
+        device = baseline.metrics.get("device") or {}
+        if self.n_devices is None:
+            self.n_devices = device.get("count")
+        if self.memory_bytes is None:
+            self.memory_bytes = device.get("bytes_limit")
         best = baseline
         for knob in sweep:
             for value in knob.domain:
@@ -582,6 +583,7 @@ class KnobSearch:
                 self.profile_dir,
                 subsystem=(K.TRAIN if self.subsystem == K.TRAIN else K.SERVE),
                 fingerprint=profiles.model_fingerprint(self.model_info),
+                topology=device.get("topology"),
                 workload=self.workload,
                 overrides=best.overrides,
                 score=best.score,

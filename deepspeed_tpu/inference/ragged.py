@@ -226,9 +226,8 @@ class RaggedConfig:
     # decode run-ahead: when the scheduler has no prefill or admission work,
     # run up to this many decode steps inside ONE jitted lax.scan (greedy
     # next-token fed back on device) instead of one dispatch per token —
-    # the multi-step-scheduling idiom of continuous-batching engines, and
-    # the difference between dispatch-latency-bound and compute-bound decode
-    # on remote/tunneled accelerators. 0 disables.
+    # the multi-step-scheduling idiom of continuous-batching engines: one
+    # host dispatch per K tokens instead of one per token. 0 disables.
     decode_run_ahead: int = 0
     # tiled prefill: lay prefill chunks at tile-aligned offsets so the tiled
     # Pallas kernel fetches each KV block once per TILE instead of once per
@@ -245,8 +244,7 @@ class RaggedConfig:
     # runs the mixed SplitFuse step (decodes + prefill chunks) and then
     # fused_chunk-1 further decode steps for the decode rows, next tokens
     # fed back on device. Unlike decode_run_ahead (which only engages when
-    # every running sequence decodes), arrivals never break the fusion —
-    # the high-RTT-transport fix the round-4 bench demanded.
+    # every running sequence decodes), arrivals never break the fusion.
     fused_chunk: int = 0
     # how many fused chunks may be in flight undispatched-results-wise:
     # chunk t+1 is dispatched before chunk t's tokens are read back, the
@@ -831,8 +829,8 @@ class RaggedInferenceEngine:
             if self.cfg.spec_ngram < 1:
                 raise ValueError("spec_ngram must be >= 1")
         # scheduling efficiency telemetry (padding fraction; comparable to the
-        # dense engine's pad-to-max waste) + dispatch accounting (on a
-        # high-RTT transport, dispatches per token is the serving cost)
+        # dense engine's pad-to-max waste) + dispatch accounting (host
+        # dispatches per generated token)
         self.tokens_scheduled = 0
         self.tokens_padded = 0
         self.dispatch_count = 0
@@ -2974,10 +2972,8 @@ class RaggedInferenceEngine:
         slicing host-side bounds the grid by the batch's ACTUAL context.
 
         Short tables pass through whole: every distinct width is a fresh
-        program shape, and on a remote-compile transport a handful of extra
-        compiles costs far more than the grid steps it saves (measured: the
-        full-width 18-block table beats a 2/4/8/16-bucket ladder end to
-        end). Power-of-4 buckets keep the long-context compile count tiny."""
+        program shape to compile and warm. Power-of-4 buckets keep the
+        long-context compile count tiny."""
         return self.block_tables[:, :self._table_width(max_pos)]
 
     def _table_width(self, max_pos: int) -> int:
@@ -3172,12 +3168,17 @@ class RaggedInferenceEngine:
         """Precompile the engine's multi-step program zoos via
         ``lower().compile()`` (no execution, no engine state touched): the
         fused-chunk family when ``fused_chunk`` >= 2 and the multi-step
-        scheduler family when ``sched_steps`` >= 2. On a remote-compile
-        transport every NOVEL combo otherwise costs seconds of compilation
-        in the middle of serving — measured as 4-5 s stalls that dominated
-        staggered-arrival latency. Returns the number of programs compiled.
+        scheduler family when ``sched_steps`` >= 2. Every NOVEL combo
+        otherwise costs seconds of compilation in the middle of serving.
+        The compiled programs reach the dispatch path through the
+        persistent compilation cache (``utils/compile_cache.py``), which
+        this turns on. A program the compiler refuses raises here, before
+        any request is taken. Returns the number of programs compiled.
         Greedy combos by default; call again with ``sampled``/filter flags
         for sampling workloads."""
+        from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         n = 0
         if self.cfg.fused_chunk >= 2:
             n += self._warmup_fused(sampled, has_tk, has_tp)
@@ -3220,27 +3221,17 @@ class RaggedInferenceEngine:
         for kk in sorted(ks):
             for b in buckets:
                 for w in self._width_ladder():
-                    try:
-                        fn = self._get_dev_sched(kk, b, w, sampled,
-                                                 sampled and has_tk,
-                                                 sampled and has_tp)
-                        staged_abs = jax.ShapeDtypeStruct((3 * b,),
-                                                          jnp.int32)
-                        if cfg.spec_draft:
-                            fn.lower(abstract, cache_abs, state_abs,
-                                     hist_abs, btf_abs, staged_abs,
-                                     rng_abs).compile()
-                        else:
-                            fn.lower(abstract, cache_abs, state_abs,
-                                     btf_abs, staged_abs,
-                                     rng_abs).compile()
-                        n += 1
-                    except Exception as e:  # pragma: no cover
-                        from deepspeed_tpu.utils.logging import logger
-
-                        logger.warning(
-                            "warmup: sched combo (k=%s t=%s w=%s) failed "
-                            "to precompile: %s", kk, b, w, e)
+                    fn = self._get_dev_sched(kk, b, w, sampled,
+                                             sampled and has_tk,
+                                             sampled and has_tp)
+                    staged_abs = jax.ShapeDtypeStruct((3 * b,), jnp.int32)
+                    if cfg.spec_draft:
+                        fn.lower(abstract, cache_abs, state_abs, hist_abs,
+                                 btf_abs, staged_abs, rng_abs).compile()
+                    else:
+                        fn.lower(abstract, cache_abs, state_abs, btf_abs,
+                                 staged_abs, rng_abs).compile()
+                    n += 1
         return n
 
     def _warmup_fused(self, sampled: bool, has_tk: bool,
@@ -3304,43 +3295,37 @@ class RaggedInferenceEngine:
             i32 = lambda s: jax.ShapeDtypeStruct((s,), jnp.int32)  # noqa: E731
             f32 = lambda s: jax.ShapeDtypeStruct((s,), jnp.float32)  # noqa: E731
             nt_prog = nt if ct else 0
-            try:
-                if cfg.device_state:
-                    # device-resident variant: full-width table on device,
-                    # packed staging buffer, persistent state tuple
-                    state_abs = tuple(
-                        jax.ShapeDtypeStruct((cfg.max_seqs + 1,), dt)
-                        for dt in (jnp.int32, jnp.int32, jnp.int32,
-                                   jnp.int32, jnp.float32, jnp.int32,
-                                   jnp.float32))
-                    btf_abs = jax.ShapeDtypeStruct(
-                        self.block_tables.shape, jnp.int32)
-                    slen = 4 * t_total + 2 * max(nd, 1)
-                    if nt_prog:
-                        slen += 3 * max(nt_prog, 1)
-                    fn = self._get_dev_fused(t_total, kk, nd, nt_prog, w,
-                                             sampled, has_tk, has_tp)
-                    fn.lower(abstract, cache_abs, state_abs, btf_abs,
-                             i32(slen), rng_abs).compile()
-                else:
-                    fn = self._get_fused_chunk(kk, nd, nt_prog, sampled,
-                                               has_tk, has_tp)
-                    fn.lower(
-                        abstract, cache_abs, st_abs,
-                        i32(t_total), i32(t_total), i32(t_total),
-                        i32(max(nd, 1)), i32(max(nd, 1)), i32(max(nd, 1)),
-                        i32(t_total),
-                        i32(max(nt_prog, 1)), i32(max(nt_prog, 1)),
-                        i32(max(nt_prog, 1)),
-                        bt_abs, rng_abs, i32(t_total), i32(t_total),
-                        f32(t_total), i32(t_total), f32(t_total),
-                    ).compile()
-                n += 1
-            except Exception as e:  # pragma: no cover - environment-specific
-                from deepspeed_tpu.utils.logging import logger
-
-                logger.warning("warmup: combo (k=%s nd=%s nt=%s) failed to "
-                               "precompile: %s", kk, nd, nt, e)
+            if cfg.device_state:
+                # device-resident variant: full-width table on device,
+                # packed staging buffer, persistent state tuple
+                state_abs = tuple(
+                    jax.ShapeDtypeStruct((cfg.max_seqs + 1,), dt)
+                    for dt in (jnp.int32, jnp.int32, jnp.int32,
+                               jnp.int32, jnp.float32, jnp.int32,
+                               jnp.float32))
+                btf_abs = jax.ShapeDtypeStruct(
+                    self.block_tables.shape, jnp.int32)
+                slen = 4 * t_total + 2 * max(nd, 1)
+                if nt_prog:
+                    slen += 3 * max(nt_prog, 1)
+                fn = self._get_dev_fused(t_total, kk, nd, nt_prog, w,
+                                         sampled, has_tk, has_tp)
+                fn.lower(abstract, cache_abs, state_abs, btf_abs,
+                         i32(slen), rng_abs).compile()
+            else:
+                fn = self._get_fused_chunk(kk, nd, nt_prog, sampled,
+                                           has_tk, has_tp)
+                fn.lower(
+                    abstract, cache_abs, st_abs,
+                    i32(t_total), i32(t_total), i32(t_total),
+                    i32(max(nd, 1)), i32(max(nd, 1)), i32(max(nd, 1)),
+                    i32(t_total),
+                    i32(max(nt_prog, 1)), i32(max(nt_prog, 1)),
+                    i32(max(nt_prog, 1)),
+                    bt_abs, rng_abs, i32(t_total), i32(t_total),
+                    f32(t_total), i32(t_total), f32(t_total),
+                ).compile()
+            n += 1
         return n
 
     def _dispatch_fused(self) -> bool:

@@ -210,8 +210,6 @@ class ShardCtx:
         """Models call attention through here; with an active ``sequence`` axis
         this routes to Ulysses all-to-all or ring/context-parallel attention."""
         impl = impl or self.attn_impl
-        from deepspeed_tpu.ops.attention import attention as local_attention
-
         if self.fpdt_chunks > 1:
             from deepspeed_tpu.parallel.fpdt import fpdt_attention
 
@@ -229,7 +227,7 @@ class ShardCtx:
             return ulysses_attention(q, k, v, self.mesh, causal=causal,
                                      local_fn=local)
         if self.sp_degree <= 1:
-            return local_attention(q, k, v, causal=causal, impl=impl)
+            return self._local_attention(q, k, v, causal, impl)
         if self.sp_mode == "ring":
             from deepspeed_tpu.parallel.ring_attention import ring_attention
 
@@ -237,6 +235,38 @@ class ShardCtx:
         from deepspeed_tpu.parallel.ulysses import ulysses_attention
 
         return ulysses_attention(q, k, v, self.mesh, causal=causal, impl=impl)
+
+    def _local_attention(self, q, k, v, causal: bool, impl: str):
+        """Attention with nothing to exchange between devices: every batch
+        row and every head is its own problem. The XLA path leaves the
+        partitioning to GSPMD. The Pallas kernel cannot ("Mosaic kernels
+        cannot be automatically partitioned. Please wrap the call in a
+        shard_map"), so on a mesh of more than one device it runs manual
+        over every axis that is not manual already: batch and heads split
+        as their activation rules say, everything else replicated."""
+        from deepspeed_tpu.ops.attention import attention, flash_blocks
+
+        mesh = self.mesh
+        auto = () if mesh is None else tuple(
+            a for a in mesh.axis_names
+            if a not in (getattr(self, "_manual_axes", ()) or ()))
+        if (getattr(self, "_suspend_constraints", False)
+                or all(mesh.shape[a] == 1 for a in auto)
+                or flash_blocks(q, k, None, impl) is None):
+            return attention(q, k, v, causal=causal, impl=impl)
+        from deepspeed_tpu.utils.compat import shard_map_compat
+
+        def split(dim):
+            axes = self.rules.get(dim) or ()
+            axes = axes if isinstance(axes, tuple) else (axes,)
+            return tuple(a for a in axes if a in auto and mesh.shape[a] > 1)
+
+        spec = jax.sharding.PartitionSpec(
+            split("batch") or None, None, split("heads_act") or None, None)
+        return shard_map_compat(
+            lambda q, k, v: attention(q, k, v, causal=causal, impl=impl),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            axis_names=set(auto))(q, k, v)
 
     def embed_lookup(self, table: jnp.ndarray, ids: jnp.ndarray,
                      *act_dims: Optional[str]) -> jnp.ndarray:

@@ -40,6 +40,12 @@ class GPT2Config:
         return GPT2Config()
 
     @staticmethod
+    def gpt2_xl() -> "GPT2Config":
+        """GPT-2 XL, 1.56B (openai-community/gpt2-xl config.json: n_embd
+        1600, n_head 25, n_layer 48, n_positions 1024, vocab 50257)."""
+        return GPT2Config(hidden_size=1600, num_layers=48, num_heads=25)
+
+    @staticmethod
     def tiny(vocab_size: int = 256) -> "GPT2Config":
         return GPT2Config(vocab_size=vocab_size, hidden_size=64, num_layers=2,
                           num_heads=4, max_seq_len=128)
@@ -218,17 +224,15 @@ def ragged_forward(cfg: GPT2Config, params, tokens, slots, positions,
     """Flat ragged step: [T] mixed tokens -> ([T, V] logits, cache).
     Learned positional embeddings ride the per-token ``positions`` the
     ragged layout already carries."""
+    from deepspeed_tpu.models.paged import scan_layers_paged
+
     x = (params["wte"][tokens] + params["wpe"][positions]).astype(
         cache["k"].dtype)
-
-    def body(x, lp_kv):
-        lp, kc, vc = lp_kv
-        x, kc, vc = _ragged_block(cfg, x, lp, kc, vc, positions, slots,
-                                  block_tables, prefill_tiles=prefill_tiles)
-        return x, (kc, vc)
-
-    x, (new_k, new_v) = lax.scan(body, x,
-                                 (params["layers"], cache["k"], cache["v"]))
+    x, new_k, new_v = scan_layers_paged(
+        lambda x, lp, kc, vc: _ragged_block(
+            cfg, x, lp, kc, vc, positions, slots, block_tables,
+            prefill_tiles=prefill_tiles),
+        x, params["layers"], cache["k"], cache["v"])
     x = layernorm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 

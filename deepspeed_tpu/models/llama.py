@@ -315,15 +315,14 @@ def ragged_forward(cfg: LlamaConfig, params, tokens, slots, positions,
     ``_ragged_layer`` (tiled-prefill fast path).
     """
     # plain gather (see decode_forward's note: replication is a training fix)
+    from deepspeed_tpu.models.paged import scan_layers_paged
+
     x = params["embed"][tokens].astype(cache["k"].dtype)
-
-    def body(x, lp_kv):
-        lp, kc, vc = lp_kv
-        x, kc, vc = _ragged_layer(cfg, x, lp, kc, vc, positions, slots,
-                                  block_tables, prefill_tiles=prefill_tiles)
-        return x, (kc, vc)
-
-    x, (new_k, new_v) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    x, new_k, new_v = scan_layers_paged(
+        lambda x, lp, kc, vc: _ragged_layer(
+            cfg, x, lp, kc, vc, positions, slots, block_tables,
+            prefill_tiles=prefill_tiles),
+        x, params["layers"], cache["k"], cache["v"])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     head = lm_head(cfg, params)
     logits = x @ head.astype(x.dtype)

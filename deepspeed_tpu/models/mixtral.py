@@ -299,16 +299,14 @@ def ragged_forward(cfg: MixtralConfig, params, tokens, slots, positions,
     """Flat ragged step: [T] mixed tokens -> ([T, V] logits, cache) — the
     MoE member of the continuous-batching engine (reference
     ``inference/v2/model_implementations/mixtral``)."""
+    from deepspeed_tpu.models.paged import scan_layers_paged
+
     x = params["embed"][tokens].astype(cache["k"].dtype)
-
-    def body(x, lp_kv):
-        lp, kc, vc = lp_kv
-        x, kc, vc = _ragged_layer(cfg, x, lp, kc, vc, positions, slots,
-                                  block_tables, prefill_tiles=prefill_tiles)
-        return x, (kc, vc)
-
-    x, (new_k, new_v) = lax.scan(body, x,
-                                 (params["layers"], cache["k"], cache["v"]))
+    x, new_k, new_v = scan_layers_paged(
+        lambda x, lp, kc, vc: _ragged_layer(
+            cfg, x, lp, kc, vc, positions, slots, block_tables,
+            prefill_tiles=prefill_tiles),
+        x, params["layers"], cache["k"], cache["v"])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 

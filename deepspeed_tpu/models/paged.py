@@ -10,8 +10,45 @@ instead of three times.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+def scan_layers_paged(layer_fn, x, layers, k_pool, v_pool):
+    """Run ``layer_fn(x, lp, kc, vc) -> (x, kc, vc)`` over the stacked layers
+    with the blocked KV pool CARRIED through the scan: each layer's
+    ``[NB, BS, Hkv, D]`` slice is indexed out of the ``[L, ...]`` pool and
+    written back in place, so a step program holds the pool once.
+
+    As scan ``xs``/``ys`` the pool was held twice — the stacked input and the
+    stacked output are distinct buffers to the compiler, donation or not. At
+    GPT-2 XL (48 layers) with a 16K-token pool the TPU compiler put a
+    single-step program at 15.5 GiB of the chip's 15.75 that way, and at
+    9.3 GiB this way (PERF.md). Works on a fp pool and on a
+    ``kvquant.QuantizedKV`` alike: both are pytrees of ``[L, ...]`` arrays.
+    """
+    n_layers = jax.tree_util.tree_leaves(k_pool)[0].shape[0]
+
+    def take(pool, i):
+        return jax.tree_util.tree_map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), pool)
+
+    def put(pool, layer, i):
+        return jax.tree_util.tree_map(
+            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0),
+            pool, layer)
+
+    def body(carry, lp_i):
+        x, k_all, v_all = carry
+        lp, i = lp_i
+        x, kc, vc = layer_fn(x, lp, take(k_all, i), take(v_all, i))
+        return (x, put(k_all, kc, i), put(v_all, vc, i)), None
+
+    (x, k_pool, v_pool), _ = lax.scan(
+        body, (x, k_pool, v_pool),
+        (layers, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, k_pool, v_pool
 
 
 def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables):

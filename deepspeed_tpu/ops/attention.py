@@ -20,10 +20,7 @@ import jax.numpy as jnp
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -65,6 +62,37 @@ def xla_attention(
     return out
 
 
+def flash_blocks(q, k, bias=None, impl: str = "auto"):
+    """The flash kernel's ``(block_q, block_k)`` where ``attention`` will run
+    it, None where it takes the XLA path: decided from the backend and the
+    shapes, BEFORE any call."""
+    import os
+
+    from deepspeed_tpu.ops.pallas.flash_attention import supported
+
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    if impl == "xla":
+        return None
+
+    # 1024x1024 blocks measured fastest on v5e (0.63 vs 0.54 MFU at 256x512
+    # on the 512M bench model; 2048 overflows VMEM — the [bq, bk] fp32
+    # probability block is the VMEM governor); env-tunable for on-hardware
+    # sweeps. Halve down to a divisor of the sequence so odd lengths (1536,
+    # 2560, ...) keep the kernel.
+    def fit(n, want):
+        while want > 8 and n % min(want, n):
+            want //= 2
+        return want
+
+    bq = fit(q.shape[1], int(os.environ.get("DSTPU_FLASH_BLOCK_Q", 1024)))
+    bk = fit(k.shape[1], int(os.environ.get("DSTPU_FLASH_BLOCK_K", 1024)))
+    if impl == "auto" and not (_on_tpu() and bias is None
+                               and supported(q, k, bq, bk)):
+        return None
+    return bq, bk
+
+
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -74,40 +102,19 @@ def attention(
     scale: float | None = None,
     impl: str = "auto",
 ) -> jnp.ndarray:
-    """Dispatching attention entry point used by all models."""
-    if impl == "auto":
-        impl = "pallas" if (_on_tpu() and bias is None) else "xla"
-    if impl == "pallas":
-        try:
-            import os
+    """Dispatching attention entry point used by all models.
 
-            from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-
-            # 1024x1024 blocks measured fastest on v5e (0.63 vs 0.54 MFU at
-            # 256x512 on the 512M bench model; 2048 overflows VMEM — the
-            # [bq, bk] fp32 probability block is the VMEM governor);
-            # env-tunable for on-hardware sweeps. Halve down to a divisor of
-            # the sequence so odd lengths (1536, 2560, ...) keep the kernel
-            # instead of silently demoting to the XLA path.
-            def fit(n, want):
-                while want > 8 and n % min(want, n):
-                    want //= 2
-                return want
-
-            return flash_attention(
-                q, k, v, causal, scale,
-                fit(q.shape[1],
-                    int(os.environ.get("DSTPU_FLASH_BLOCK_Q", 1024))),
-                fit(k.shape[1],
-                    int(os.environ.get("DSTPU_FLASH_BLOCK_K", 1024))))
-        except (ImportError, NotImplementedError):
-            impl = "xla"
-    if impl == "xla":
+    ``impl="auto"`` decides kernel-or-XLA from the backend and the shapes
+    BEFORE the call (``flash_blocks``); whatever the chosen kernel raises
+    propagates — a kernel the chip's compiler refuses must stop the run, not
+    reroute it.
+    """
+    blocks = flash_blocks(q, k, bias, impl)
+    if blocks is None:
         return xla_attention(q, k, v, causal=causal, bias=bias, scale=scale)
-    raise ValueError(f"unknown attention impl {impl!r}")
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-
-_PAGED_FALLBACK_WARNED = False
+    return flash_attention(q, k, v, causal, scale, *blocks)
 
 
 def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
@@ -116,7 +123,9 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     (reference ``inference/v2/kernels/ragged_ops`` blocked flash attention).
 
     impl="pallas": stream blocks through VMEM via the block table (no padded
-    gather); impl="xla": gather the padded context (fallback / CPU tests).
+    gather); impl="xla": gather the padded context (short contexts, quantized
+    pools, the CPU). The choice is made here from the backend and the
+    shapes; an error from the chosen kernel propagates.
 
     A quantized pool (``inference/kvquant.QuantizedKV``) always takes the
     XLA path: the gather+dequant fuse into one program there (the fp
@@ -147,24 +156,12 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
                 cross = int(os.environ.get("DSTPU_PAGED_XLA_CTX", 2048))
                 impl = "xla" if ctx <= cross else "pallas"
     if impl == "pallas":
-        try:
-            from deepspeed_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention,
-            )
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention,
+        )
 
-            return paged_decode_attention(q, k_pool, v_pool, slots, positions,
-                                          block_tables, scale=scale)
-        except (ImportError, NotImplementedError) as e:
-            global _PAGED_FALLBACK_WARNED
-            if not _PAGED_FALLBACK_WARNED:
-                _PAGED_FALLBACK_WARNED = True
-                from deepspeed_tpu.utils.logging import logger
-
-                logger.warning(
-                    "paged attention: Pallas kernel unavailable (%s); "
-                    "falling back to the padded-gather XLA path — decode "
-                    "memory/latency will degrade at long contexts", e)
-            impl = "xla"
+        return paged_decode_attention(q, k_pool, v_pool, slots, positions,
+                                      block_tables, scale=scale)
     if impl != "xla":
         raise ValueError(f"unknown paged attention impl {impl!r}")
     t_tokens, hq, d = q.shape
@@ -198,23 +195,20 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     rows past ``tile_valid`` padding). The Pallas kernel fetches each KV block
     ONCE per tile instead of once per token
     (``ops/pallas/paged_attention.ragged_prefill_attention``); the XLA
-    fallback expands the tile metadata to per-token (slot, position) arrays
-    and reuses the padded-gather path.
+    path (quantized pools, the CPU) expands the tile metadata to per-token
+    (slot, position) arrays and reuses the padded gather.
     """
     if getattr(k_pool, "is_quantized_kv", False):
         impl = "xla"  # fused gather+dequant (see paged_attention)
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
     if impl == "pallas":
-        try:
-            from deepspeed_tpu.ops.pallas.paged_attention import (
-                ragged_prefill_attention as _pallas_prefill,
-            )
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            ragged_prefill_attention as _pallas_prefill,
+        )
 
-            return _pallas_prefill(q, k_pool, v_pool, tile_slot, tile_pos0,
-                                   tile_valid, block_tables, tile, scale=scale)
-        except (ImportError, NotImplementedError):
-            impl = "xla"
+        return _pallas_prefill(q, k_pool, v_pool, tile_slot, tile_pos0,
+                               tile_valid, block_tables, tile, scale=scale)
     if impl != "xla":
         raise ValueError(f"unknown prefill attention impl {impl!r}")
     t = q.shape[0]

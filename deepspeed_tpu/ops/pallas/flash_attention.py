@@ -25,14 +25,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on pure-CPU builds of pallas
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -86,7 +79,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
         lse_ref[0, 0] = m_sc[:, 0:1] + jnp.log(safe_l)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     n_rep = hq // hkv
@@ -121,22 +114,23 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             _scratch((block_q, 128)),
             _scratch((block_q, 128)),
         ],
-        interpret=_interpret_mode(),
+        interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
 
 def _scratch(shape):
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _interpret_mode() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode(interpret: bool | None) -> bool:
+    """Interpret mode is for a caller that chose the CPU (tests,
+    rehearsals); on the chip the kernel compiles or the error surfaces."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def _supported(q, k, block_q, block_k) -> bool:
+def supported(q, k, block_q, block_k) -> bool:
+    """Shapes the kernel takes; dispatchers ask BEFORE the call."""
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     if hq % hkv:
@@ -148,22 +142,22 @@ def _supported(q, k, block_q, block_k) -> bool:
     return True
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True, scale: float | None = None,
-                    block_q: int = 256, block_k: int = 512):
-    """Drop-in for ``ops.attention.xla_attention`` on TPU shapes."""
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if not _supported(q, k, block_q, block_k):
-        raise NotImplementedError("flash_attention: unsupported shape")
-    out, _ = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
-    return out
+                    block_q: int = 256, block_k: int = 512,
+                    interpret: bool | None = None):
+    """Drop-in for ``ops.attention.xla_attention`` on TPU shapes.
+    ``interpret=None`` interprets off the chip and compiles on it; a compile
+    test for a described device passes ``False``."""
+    return _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret)[0]
 
 
-def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
+def _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if not _supported(q, k, block_q, block_k):
+    if not supported(q, k, block_q, block_k):
         raise NotImplementedError("flash_attention: unsupported shape")
-    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+    out, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+                          interpret_mode(interpret))
     return out, (q, k, v, out, lse)
 
 
@@ -254,7 +248,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k):
+def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k,
+                      interpret):
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     n_rep = hq // hkv
@@ -292,7 +287,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k):
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j, i: (b_, h, j, 0)),
         ),
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
-        interpret=_interpret_mode(),
+        interpret=interpret,
     )(qt, kt, vt, dot, lse4, delta)
 
     dq = pl.pallas_call(
@@ -303,7 +298,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k):
         in_specs=[q_spec_i, kv_spec_i, kv_spec_i, q_spec_i, row_spec_i, row_spec_i],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
         scratch_shapes=[_scratch((block_q, d))],
-        interpret=_interpret_mode(),
+        interpret=interpret,
     )(qt, kt, vt, dot, lse4, delta)
 
     dq = dq.transpose(0, 2, 1, 3)
@@ -348,12 +343,13 @@ def _fa_bwd_xla(causal, scale, block_q, block_k, res, do):
     return dq, dk_full.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _fa_bwd(causal, scale, block_q, block_k, res, do):
+def _fa_bwd(causal, scale, block_q, block_k, interpret, res, do):
     if os.environ.get("DSTPU_FLASH_XLA_BWD"):
         return _fa_bwd_xla(causal, scale, block_q, block_k, res, do)
     q, k, v, out, lse = res
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    return _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k)
+    return _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q,
+                             block_k, interpret_mode(interpret))
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

@@ -25,11 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
 
 _NEG_INF = -1e30
 
@@ -83,15 +81,14 @@ def _kernel(slots_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
-                           scale: float | None = None):
+                           scale: float | None = None,
+                           interpret: bool | None = None):
     """[T, Hq, D] ragged tokens -> [T, Hq, D] attention outputs.
 
     ``k_pool``/``v_pool``: [NB, BS, Hkv, D]; ``block_tables``:
     [max_seqs+1, MB] mapping (slot, block-ordinal) -> pool block id. Exact
     vs the dense-gather path (same position masking).
     """
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
     t_tokens, hq, d = q.shape
     nb, bs, hkv, _ = k_pool.shape
     mb = block_tables.shape[1]
@@ -126,7 +123,7 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
         grid_spec=grid_spec,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(interpret),
     )(slots.astype(jnp.int32), positions.astype(jnp.int32),
       block_tables.astype(jnp.int32), q, k_pool, v_pool)
 
@@ -191,9 +188,31 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
             ct, hkv * rep, d).astype(o_ref.dtype)
 
 
+# Scoped VMEM a Mosaic kernel may use by default (v5e), and what one query
+# row of one head costs the prefill kernel in it: three float32 scratches
+# (acc, m, l; D and the m/l column pad to 128 lanes), the double-buffered
+# bf16 q and o blocks, and about three float32 temporaries (q, scores, p).
+# Checked against the compiler: 128 rows x 32 heads x 128 is refused at
+# 16.2-16.4 MiB, 128 x 25 x 64 is accepted (tests/unit/test_compile_tpu.py).
+_VMEM_SCOPED_BYTES = 16 * 2**20
+_PREFILL_BYTES_PER_LANE = 3 * 4 + 2 * 2 * 2 + 3 * 4
+
+
+def prefill_kernel_tile(tile: int, hq: int, d: int) -> int:
+    """Largest power-of-two split of the scheduler's ``tile`` whose working
+    set fits scoped VMEM with an eighth to spare (the K/V blocks and the
+    compiler's own stack share the limit)."""
+    row = hq * max(d, 128) * _PREFILL_BYTES_PER_LANE
+    ct = tile
+    while ct > 8 and ct % 2 == 0 and ct * row > _VMEM_SCOPED_BYTES * 7 // 8:
+        ct //= 2
+    return ct
+
+
 def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
-                             scale: float | None = None):
+                             scale: float | None = None,
+                             interpret: bool | None = None):
     """Tiled prefill attention: [NT*CT, Hq, D] tile-aligned prefill tokens ->
     outputs, one KV-block DMA shared by the whole CT-token tile (the
     SplitFuse blocked flash attention, reference
@@ -203,14 +222,22 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     Scheduler contract (``inference/ragged.py``): each tile's tokens belong
     to ONE sequence at consecutive positions ``pos0..pos0+valid-1``; rows
     past ``valid`` are padding. ``tile_valid == 0`` marks an all-pad tile.
+
+    Where a whole scheduler tile does not fit scoped VMEM (32 heads x 128),
+    each tile runs as consecutive sub-tiles: a sub-tile is itself a tile of
+    the same sequence, so the kernel and its masking are unchanged and only
+    the KV block is fetched once per sub-tile instead of once per tile.
     """
-    if pltpu is None:
-        raise NotImplementedError("pallas TPU backend unavailable")
     t_tokens, hq, d = q.shape
     nb, bs, hkv, _ = k_pool.shape
     mb = block_tables.shape[1]
     rep = hq // hkv
-    ct = tile
+    ct = prefill_kernel_tile(tile, hq, d)
+    if ct != tile:
+        sub0 = jnp.arange(tile // ct, dtype=jnp.int32) * ct
+        tile_slot = jnp.repeat(tile_slot, tile // ct)
+        tile_pos0 = (tile_pos0[:, None] + sub0).reshape(-1)
+        tile_valid = jnp.clip(tile_valid[:, None] - sub0, 0, ct).reshape(-1)
     n_tiles = t_tokens // ct
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
@@ -241,7 +268,7 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
         grid_spec=grid_spec,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(interpret),
     )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
       tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, k_pool, v_pool)

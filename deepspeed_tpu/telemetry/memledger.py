@@ -71,14 +71,24 @@ OWNERS = (
 )
 
 _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "OUT OF MEMORY", "OUT_OF_MEMORY")
+# The TPU compiler reports a program it refuses under the same status: a
+# kernel over its scoped VMEM ("Ran out of memory in memory space vmem"),
+# a program that cannot fit HBM ("XLA:TPU compile permanent error"). Those
+# are decided at compile time from shapes — the same program is refused
+# again on any retry, and no amount of freed device memory changes that.
+_COMPILE_REFUSAL_MARKERS = ("MEMORY SPACE VMEM", "COMPILE PERMANENT ERROR")
 
 
 def is_resource_exhausted(exc: BaseException) -> bool:
-    """True when ``exc`` is an out-of-device-memory failure (XLA/PJRT
+    """True when ``exc`` is a RUN-TIME out-of-device-memory failure (XLA/PJRT
     surfaces these as RESOURCE_EXHAUSTED status text). Shared by the
     dispatch watchdog and the engine seams so every layer agrees on what
-    counts as an OOM."""
+    counts as an OOM. A compile-time refusal carries the same status word
+    but is not one: it must stop the run, never walk a degradation ladder
+    onto a program the compiler happens to accept."""
     msg = f"{type(exc).__name__}: {exc}".upper()
+    if any(m in msg for m in _COMPILE_REFUSAL_MARKERS):
+        return False
     return any(m in msg for m in _OOM_MARKERS)
 
 

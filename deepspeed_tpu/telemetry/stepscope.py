@@ -85,19 +85,21 @@ _PEAK_TABLE = {
 
 
 def device_peak_flops() -> float:
-    """Peak FLOPs/s of the local device, or a nominal 1e12 denominator for
-    CPU smoke runs (same convention as bench.py)."""
-    try:
-        import jax
+    """Peak FLOPs/s of the local TPU; a ``device_kind`` the table does not
+    list is an error, never an assumed peak. Off the chip (the CPU test
+    mesh) the denominator is a nominal 1e12 — what an MFU means there is
+    the benchmark's to settle, not this table's."""
+    import jax
 
-        if jax.default_backend() == "tpu":
-            kind = str(getattr(jax.devices()[0], "device_kind", "")).lower()
-            for key, peak in _PEAK_TABLE.items():
-                if key in kind:
-                    return peak
-    except Exception:
-        pass
-    return 1e12
+    if jax.default_backend() != "tpu":
+        return 1e12
+    kind = jax.devices()[0].device_kind.lower()
+    for key, peak in _PEAK_TABLE.items():
+        if key in kind:
+            return peak
+    raise ValueError(
+        f"stepscope: device kind {kind!r} is not in _PEAK_TABLE; add its "
+        "published peak there rather than assuming one")
 
 
 class StepScope:

@@ -1,74 +1,28 @@
-"""Version-compat shims for jax API drift.
+"""The two jax spellings every manual-region call site goes through.
 
-The codebase targets the ``jax.shard_map(..., axis_names=, check_vma=)``
-spelling (jax >= 0.6); older builds only ship
-``jax.experimental.shard_map.shard_map`` with the equivalent
-``auto=``/``check_rep=`` contract. ``inference/kvquant.py`` carried a local
-try/except for this; every shard_map call site now routes through the shared
-resolver here so the whole training/inference surface runs on whichever jax
-the container pins.
+One installation (jax 0.9): ``jax.shard_map`` and ``lax.axis_size`` exist, so
+these are plain pass-throughs kept for their call sites' sake — they fix the
+defaults this codebase wants (manual over every mesh axis unless
+``axis_names`` says otherwise; no varying-manual-axes check).
 """
 
 from __future__ import annotations
 
 import jax
+from jax import lax
 
-__all__ = ["axis_size_compat", "shard_map_compat", "supports_partial_manual"]
-
-
-def supports_partial_manual(mesh, manual_axes) -> bool:
-    """Whether this jax can run shard_map manual over ``manual_axes`` while
-    other mesh axes of size > 1 stay GSPMD-auto.
-
-    On pre-0.6 jax the experimental fallback compiles partial-manual
-    ``ppermute`` into an XLA SPMD-partitioner CHECK failure (an uncatchable
-    C++ abort: ``target.IsManualSubgroup() == sharding().IsManualSubgroup()``)
-    — so the compat wrapper refuses that regime up front instead of letting
-    the process die at compile time. Size-1 auto axes are fine.
-    """
-    if getattr(jax, "shard_map", None) is not None:
-        return True
-    manual = set(manual_axes or mesh.axis_names)
-    return all(mesh.shape[a] <= 1 for a in mesh.axis_names if a not in manual)
+__all__ = ["axis_size_compat", "shard_map_compat"]
 
 
 def axis_size_compat(axis_name):
-    """``lax.axis_size`` across jax versions.
-
-    Pre-0.5 jax has no ``lax.axis_size``; ``lax.psum(1, axis)`` inside a
-    manual region constant-folds to the same concrete int.
-    """
-    from jax import lax
-
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return lax.psum(1, axis_name)
+    """Size of a manual mesh axis, as a concrete int inside the region."""
+    return lax.axis_size(axis_name)
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None,
                      check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    ``axis_names`` is the set of MANUAL axes (the new-API meaning); omitted
-    means manual over every mesh axis. On pre-0.6 jax this maps to the
-    experimental module's complement spelling: ``auto`` = the non-manual
-    axes, ``check_rep`` = ``check_vma``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=axis_names or set(mesh.axis_names),
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _esm
-
-    manual = set(axis_names or mesh.axis_names)
-    if not supports_partial_manual(mesh, manual):
-        raise NotImplementedError(
-            "partial-manual shard_map (manual over "
-            f"{sorted(manual)} with live auto axes) fatally aborts XLA's "
-            "SPMD partitioner on this jax version; upgrade jax or make the "
-            "manual region cover every mesh axis of size > 1")
-    auto = frozenset(a for a in mesh.axis_names if a not in manual)
-    return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma, auto=auto)
+    """``jax.shard_map``; ``axis_names`` is the set of MANUAL axes, omitted
+    means manual over every mesh axis."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=axis_names or set(mesh.axis_names),
+                         check_vma=check_vma)

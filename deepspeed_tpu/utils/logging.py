@@ -46,12 +46,14 @@ def set_log_level(level: str | int) -> None:
 
 
 def _process_index() -> int:
-    try:
-        import jax
+    """This process's rank, without initializing a jax backend: a log line
+    must never be what takes the chip from a child that needs it. A job that
+    has not called ``jax.distributed.initialize`` is one process, rank 0."""
+    import jax
 
-        return jax.process_index()
-    except Exception:  # pre-init / no backend
+    if not jax.distributed.is_initialized():
         return 0
+    return jax.process_index()
 
 
 def log_dist(message: str, ranks: list[int] | None = None, level: int = logging.INFO) -> None:

@@ -29,17 +29,9 @@ import os
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_cpu_enable_async_dispatch", False)
-except Exception:
-    pass
-# no persistent compile cache: cache-deserialized CPU collective programs
-# deadlock on this VM (see tests/conftest.py)
-_cache = os.environ.get("DSTPU_TEST_JIT_CACHE")
-if _cache:
-    jax.config.update("jax_compilation_cache_dir", _cache)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+jax.config.update("jax_cpu_enable_async_dispatch", False)
+# no persistent compile cache is set here: cache-deserialized CPU collective
+# programs deadlock on this VM (see tests/conftest.py)
 import numpy as np
 """
 
@@ -64,7 +56,7 @@ def tree_cpu_ticks(pid: int) -> int:
 
 
 def run_with_stall_watchdog(cmd, env=None, stall_seconds: int = 120,
-                            timeout: int = 900, poll: int = 5, **popen_kw):
+                            timeout: int = 900, poll: int = 1, **popen_kw):
     """Run ``cmd``; kill it if its process tree makes no CPU progress for
     ``stall_seconds`` (the wedge signature). Returns
     ``(returncode_or_None, stalled: bool)`` — ``stalled=True`` means it was

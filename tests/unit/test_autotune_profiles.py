@@ -291,18 +291,45 @@ class TestKnobSearch:
         assert out["gate_failures"] == 1
         assert out["gate_violations_accepted"] == 0
 
-    def test_winner_persists_and_reloads(self, tmp_path, monkeypatch):
-        monkeypatch.setattr(profiles, "current_topology", lambda: TOPO)
+    @pytest.mark.parametrize("from_probe", [False, True],
+                             ids=["given", "from_probe"])
+    def test_winner_persists_and_reloads(self, tmp_path, monkeypatch,
+                                         from_probe):
+        """``from_probe``: the search parent is told nothing about the
+        device and must not ask a jax backend (it would hold the chip its
+        probe children need) — count, byte limit and topology come from
+        the default-config probe's ``device`` block."""
         scores = {frozenset({(self.MB, 4)}): 5.0}
-        out = KnobSearch(TRAIN, model_info=INFO, n_devices=1,
-                         knob_names=(self.MB,),
-                         probe_runner=_fake_runner(scores),
-                         profile_dir=str(tmp_path)).tune()
+        runner = _fake_runner(scores)
+        if from_probe:
+            import jax
+
+            def no_backend(*a, **k):
+                raise AssertionError("search parent touched a jax backend")
+
+            monkeypatch.setattr(profiles, "current_topology", no_backend)
+            monkeypatch.setattr(jax, "devices", no_backend)
+            mb8 = (INFO.state_bytes(0, 4) + INFO.activation_bytes(8, 128))
+
+            def runner(kind, overrides, steps, inner=runner):
+                out, err = inner(kind, overrides, steps)
+                out["device"] = {"topology": TOPO, "count": 4,
+                                 "bytes_limit": mb8 * 1.01 / 0.9}
+                return out, err
+        else:
+            monkeypatch.setattr(profiles, "current_topology", lambda: TOPO)
+        search = KnobSearch(TRAIN, model_info=INFO,
+                            n_devices=None if from_probe else 1,
+                            knob_names=(self.MB,), probe_runner=runner,
+                            profile_dir=str(tmp_path))
+        out = search.tune()
         assert out["profile_path"] and os.path.exists(out["profile_path"])
         prof = profiles.load_profile(str(tmp_path), subsystem=TRAIN,
                                      fingerprint=FP, topology=TOPO)
         assert prof["overrides"] == {self.MB: 4}
         assert prof["score"] == 5.0
+        if from_probe:
+            assert search.n_devices == 4 and out["pruned"] >= 1
 
     def test_counters_bump_when_telemetry_on(self):
         from deepspeed_tpu import telemetry

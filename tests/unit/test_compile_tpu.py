@@ -1,0 +1,124 @@
+"""The Pallas kernels of the train and serve paths, compiled for a described
+(not attached) TPU v5e at the head geometries of the three model families and
+at the block/tile sizes the engines really use. Interpret mode cannot see what
+these see: a slice off the tiling, more scoped VMEM than a kernel may take.
+Nothing runs, so this says nothing about results or speed."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.paged_attention import (
+    paged_decode_attention,
+    prefill_kernel_tile,
+    ragged_prefill_attention,
+)
+
+# (q heads, kv heads, head size): Llama-3-8B / Mixtral-8x7B, GPT-2 XL,
+# GPT-2 medium
+GEOMETRIES = [(32, 8, 128), (25, 25, 64), (16, 16, 64)]
+SEQ = 1024     # ops.attention fits flash blocks to min(1024, seq)
+TILE = 128     # RaggedConfig.prefill_tile of bench.py and chip_smoke.py
+BLOCK = 32     # their KV block_size
+MAX_BLOCKS = 8
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, True, None, SEQ, SEQ, False)
+
+
+def _flash_bwd(q, k, v):
+    return jax.grad(
+        lambda *a: _flash_fwd(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+
+def _decode(q, kp, vp, slots, pos, bt):
+    return paged_decode_attention(q, kp, vp, slots, pos, bt, interpret=False)
+
+
+def _prefill(q, kp, vp, ts, tp, tv, bt):
+    return ragged_prefill_attention(q, kp, vp, ts, tp, tv, bt, TILE,
+                                    interpret=False)
+
+
+def _args(kernel, hq, hkv, d, devices):
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    if kernel in (_flash_fwd, _flash_bwd):
+        return s((1, SEQ, hq, d)), s((1, SEQ, hkv, d)), s((1, SEQ, hkv, d))
+    pool = s((64, BLOCK, hkv, d))
+    bt = s((9, MAX_BLOCKS), jnp.int32)
+    if kernel is _decode:
+        rows = s((8,), jnp.int32)
+        return s((8, hq, d)), pool, pool, rows, rows, bt
+    tiles = s((2,), jnp.int32)
+    return s((2 * TILE, hq, d)), pool, pool, tiles, tiles, tiles, bt
+
+
+@pytest.mark.parametrize("hq,hkv,d", GEOMETRIES)
+@pytest.mark.parametrize("kernel,n_calls", [
+    (_flash_fwd, 1), (_flash_bwd, 3), (_decode, 1), (_prefill, 1)],
+    ids=["flash_fwd", "flash_bwd", "paged_decode", "tiled_prefill"])
+def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
+    text = jax.jit(kernel).lower(
+        *_args(kernel, hq, hkv, d, v5e)).compile().as_text()
+    assert text.count("tpu_custom_call") >= n_calls
+
+
+def test_flash_compiles_on_a_described_mesh(v5e, monkeypatch):
+    """GSPMD refuses a Mosaic kernel on more than one device; through
+    ``ShardCtx.attention`` it runs manual over the mesh (ZeRO-3 on four
+    chips, ``chip_smoke.py --chips 4``), forward and backward."""
+    import numpy as np
+
+    from deepspeed_tpu.models.api import ShardCtx
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mesh = jax.sharding.Mesh(np.array(v5e).reshape(4), ("fsdp",))
+    ctx = ShardCtx(mesh=mesh)
+    hq, hkv, d = GEOMETRIES[1]
+    spec = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("fsdp"))
+    q = jax.ShapeDtypeStruct((4, SEQ, hq, d), jnp.bfloat16, sharding=spec)
+    kv = jax.ShapeDtypeStruct((4, SEQ, hkv, d), jnp.bfloat16, sharding=spec)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: ctx.attention(q, k, v).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_prefill_tile_split_is_what_the_compiler_needs():
+    """32 heads x 128 at the scheduler's 128-row tile is over the scoped
+    VMEM limit (the compiler says 16.2-16.4 MiB of 16), so it runs as two
+    64-row sub-tiles; the GPT-2 geometries keep the whole tile."""
+    assert prefill_kernel_tile(TILE, 32, 128) == 64
+    assert prefill_kernel_tile(TILE, 25, 64) == TILE
+    assert prefill_kernel_tile(TILE, 16, 64) == TILE
+    assert prefill_kernel_tile(8, 32, 128) == 8

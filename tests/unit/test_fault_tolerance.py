@@ -162,6 +162,44 @@ class TestDispatchWatchdog:
         assert eng.degraded_reason
         assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1
 
+    @pytest.mark.parametrize("message,degrades", [
+        # what the v5e compiler says of a kernel over its scoped VMEM
+        ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+         "allocating on stack for %pallas_call = bf16[128,32,128] "
+         "custom-call(...), custom_call_target=\"tpu_custom_call\". Scoped "
+         "allocation with size 16.18M and limit 16.00M exceeded scoped vmem "
+         "limit by 188.0K.", False),
+        # what the runtime says when HBM is full
+        ("RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+         "allocate 1.50G. That was not possible. There are 812.4M free.",
+         True),
+    ], ids=["compile_refusal", "runtime_oom"])
+    def test_resource_exhausted_ladder(self, message, degrades, monkeypatch):
+        """A RESOURCE_EXHAUSTED raised while compiling stops the engine as
+        it is; one raised at run time still walks the degradation ladder."""
+        import jax
+
+        eng = _engine(WCFG)
+        calls = []
+
+        def step_impl():
+            calls.append(1)
+            if len(calls) == 1:
+                raise jax.errors.JaxRuntimeError(message)
+            return {}
+
+        monkeypatch.setattr(eng, "_step_impl", step_impl)
+        _put_all(eng)
+        if degrades:
+            eng.step()
+            assert len(calls) == 2  # retried one rung down
+            assert eng.degraded_mode == 1 and not eng.cfg.device_state
+        else:
+            with pytest.raises(jax.errors.JaxRuntimeError, match="vmem"):
+                eng.step()
+            assert eng.degraded_mode == 0 and eng.cfg.device_state
+            assert eng.step_failures == 0 and eng.step_retries == 0
+
     def test_alloc_and_readback_faults_recover(self, ref_tokens):
         eng = _engine(WCFG)
         get_fault_injector().configure([

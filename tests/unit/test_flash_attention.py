@@ -68,3 +68,32 @@ def test_bf16_inputs():
     out = flash_attention(q, k, v, True, None, 64, 64)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+def test_kernel_runs_manual_on_a_mesh():
+    """GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"): on a mesh of several devices ``ShardCtx.attention`` runs the
+    kernel manual over the mesh, batch and heads split by the activation
+    rules — forward and backward equal to the XLA path GSPMD partitions."""
+    from deepspeed_tpu.comm.comm import init_distributed
+    from deepspeed_tpu.config.config import MeshConfig
+    from deepspeed_tpu.models.api import ShardCtx
+
+    mesh = init_distributed(MeshConfig(data=2, fsdp=2, tensor=2)).mesh
+    ctx = ShardCtx(mesh=mesh)
+    q, k, v = _qkv(b=4, sq=64, skv=64, hq=4, hkv=2)
+
+    def loss(impl, q, k, v):
+        return jnp.sum(ctx.attention(q, k, v, causal=True, impl=impl) ** 2)
+
+    for impl in ("pallas", "xla"):
+        val, grads = jax.jit(jax.value_and_grad(
+            lambda *a, i=impl: loss(i, *a), argnums=(0, 1, 2)))(q, k, v)
+        if impl == "pallas":
+            got = (val, *grads)
+            text = jax.jit(lambda *a: loss("pallas", *a)).lower(q, k, v).as_text()
+            assert "shard_map" in text or "manual" in text
+        else:
+            for a, b in zip(got, (val, *grads)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=5e-5, atol=5e-5)

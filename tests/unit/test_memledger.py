@@ -335,6 +335,12 @@ class TestOomForensics:
         assert is_resource_exhausted(RuntimeError("RESOURCE_EXHAUSTED: oom"))
         assert is_resource_exhausted(ValueError("Out of memory allocating"))
         assert not is_resource_exhausted(RuntimeError("UNAVAILABLE: retry"))
+        # the compiler refusing a program is not the device running out
+        assert not is_resource_exhausted(RuntimeError(
+            "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem"))
+        assert not is_resource_exhausted(RuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+            "of memory in memory space hbm."))
 
     def test_record_oom_without_ledger_never_raises(self):
         # telemetry off entirely: the seam hook must be inert

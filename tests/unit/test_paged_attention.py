@@ -4,6 +4,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deepspeed_tpu.ops.attention import paged_attention
 
@@ -40,24 +41,32 @@ def test_pallas_mixed_prefill_decode_positions():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_tiled_prefill_kernel_matches_xla():
+@pytest.mark.parametrize("CT,MB,split", [(8, 4, False), (32, 10, True)],
+                         ids=["whole_tile", "split_tile"])
+def test_tiled_prefill_kernel_matches_xla(CT, MB, split, monkeypatch):
     """The tiled prefill kernel (interpret mode on CPU) is exact vs the XLA
-    fallback, including tile padding, block-edge positions and a pad tile."""
+    path, including tile padding, block-edge positions and a pad tile —
+    also when a tile over the scoped-VMEM budget runs as sub-tiles."""
     from deepspeed_tpu.ops.attention import ragged_prefill_attention
+    from deepspeed_tpu.ops.pallas import paged_attention as kernels
 
+    Hq, Hkv, D, BS = 4, 2, 16, 8
+    if split:  # a budget this geometry exceeds: 32-row tiles run as 4 x 8
+        monkeypatch.setattr(kernels, "_VMEM_SCOPED_BYTES", 2**17)
+    assert kernels.prefill_kernel_tile(CT, Hq, D) == 8
     rng = np.random.default_rng(4)
-    CT, Hq, Hkv, D, NB, BS, MB = 8, 4, 2, 16, 16, 8, 4
-    # 4 tiles: seq0 chunk of 14 tokens (tiles 0-1, pos 5..18), seq1 chunk of
-    # 6 tokens (tile 2, pos 0..5), tile 3 all-pad
+    NB = 2 * MB + 1
+    # 4 tiles: seq0 chunk of 2*CT-2 tokens from position 5 (tiles 0-1), seq1
+    # chunk of 6 tokens (tile 2, pos 0..5), tile 3 all-pad
     q = jnp.asarray(rng.normal(size=(4 * CT, Hq, D)).astype(np.float32))
     kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, D)).astype(np.float32))
     vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, D)).astype(np.float32))
     bt = np.zeros((3, MB), np.int32)
-    bt[0] = [3, 5, 7, 11]
-    bt[1] = [2, 9, 1, 0]
+    bt[0] = rng.permutation(np.arange(1, MB + 1))
+    bt[1] = rng.permutation(np.arange(MB + 1, 2 * MB + 1))
     ts = jnp.asarray(np.array([0, 0, 1, 2], np.int32))
-    tp = jnp.asarray(np.array([5, 13, 0, 0], np.int32))
-    tv = jnp.asarray(np.array([8, 6, 6, 0], np.int32))
+    tp = jnp.asarray(np.array([5, 5 + CT, 0, 0], np.int32))
+    tv = jnp.asarray(np.array([CT, CT - 2, 6, 0], np.int32))
     out_x = ragged_prefill_attention(q, kp, vp, ts, tp, tv, jnp.asarray(bt),
                                      CT, impl="xla")
     out_p = ragged_prefill_attention(q, kp, vp, ts, tp, tv, jnp.asarray(bt),

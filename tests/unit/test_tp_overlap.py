@@ -26,13 +26,7 @@ from deepspeed_tpu.utils.compat import shard_map_compat
 
 def _tp_mesh(tensor=4, data=2):
     reset_topology()
-    mesh = init_distributed(MeshConfig(data=data, tensor=tensor)).mesh
-    from deepspeed_tpu.utils.compat import supports_partial_manual
-
-    if not supports_partial_manual(mesh, {"tensor"}):
-        pytest.skip("partial-manual shard_map unsupported on this jax "
-                    "(would abort XLA's SPMD partitioner)")
-    return mesh
+    return init_distributed(MeshConfig(data=data, tensor=tensor)).mesh
 
 
 def test_ring_all_reduce_matches_psum():

@@ -92,7 +92,7 @@ class TestSanitizeXlaFlags:
         assert runner.calls == []
 
     def test_unknown_inherited_flag_is_removed(self, fake):
-        """The MULTICHIP_r02 crash: an inherited flag the CPU child's
+        """The multichip dry-run crash: an inherited flag the CPU child's
         registry rejects must be filtered out, valid neighbors kept."""
         fake(rejected={"--xla_cpu_collective_call_warn_stuck_seconds=120"})
         got = xf.sanitize_xla_flags(
