@@ -50,6 +50,7 @@ from deepspeed_tpu.telemetry import get_telemetry
 from deepspeed_tpu.telemetry.memledger import is_resource_exhausted, record_oom
 from deepspeed_tpu.telemetry.tracing import format_traceparent
 from deepspeed_tpu.utils.logging import log_dist
+from deepspeed_tpu.utils.tracing import span
 
 
 class BlockedAllocator:
@@ -739,21 +740,23 @@ class RaggedInferenceEngine:
             jnp.zeros(s1, jnp.float32), jnp.zeros(s1, jnp.int32),
             jnp.ones(s1, jnp.float32),
         )
-        self._slot_row_jit = jax.jit(
-            lambda st, row, iv, fv: (
-                st[0].at[row].set(iv[0]), st[1].at[row].set(iv[1]),
-                st[2].at[row].set(iv[2]), st[3].at[row].set(iv[3]),
-                st[4].at[row].set(fv[0]), st[5].at[row].set(iv[4]),
-                st[6].at[row].set(fv[1])),
-            donate_argnums=(0,))
+        def ragged_slot_rows(st, row, iv, fv):
+            return (st[0].at[row].set(iv[0]), st[1].at[row].set(iv[1]),
+                    st[2].at[row].set(iv[2]), st[3].at[row].set(iv[3]),
+                    st[4].at[row].set(fv[0]), st[5].at[row].set(iv[4]),
+                    st[6].at[row].set(fv[1]))
+
+        self._slot_row_jit = jax.jit(ragged_slot_rows, donate_argnums=(0,))
         # device-resident block table: host self.block_tables stays ground
         # truth; rows dirtied by allocation/splice/release are delta-uploaded
         # (pow2-bucketed row count) before the next dispatch instead of
         # re-shipping a fresh _table_view slice every step
         self._bt_dev = jnp.asarray(self.block_tables)
         self._bt_dirty: set[int] = set()
-        self._bt_row_jit = jax.jit(
-            lambda bt, idx, vals: bt.at[idx].set(vals), donate_argnums=(0,))
+        def ragged_bt_rows(bt, idx, vals):
+            return bt.at[idx].set(vals)
+
+        self._bt_row_jit = jax.jit(ragged_bt_rows, donate_argnums=(0,))
         # packed staging buffer cache: one flat int32 upload per dispatch,
         # and ZERO uploads when the bytes match the previous dispatch at the
         # same size (the steady-decode case)
@@ -2232,6 +2235,9 @@ class RaggedInferenceEngine:
                      topp_st)
             return picked, state, cache
 
+        # the program's name in a trace (``jit_ragged_step_d8_t3``): the same
+        # string the engine/dispatch span carries
+        step_fn.__name__ = self._step_program_name(t, nd, nt)
         fn = jax.jit(step_fn, donate_argnums=(1, 2))
         self._dev_step_jits[key] = fn
         return fn
@@ -2636,13 +2642,72 @@ class RaggedInferenceEngine:
         them as one packed buffer (decode rows carry no token/position —
         those live on device), dispatch the device-resident step program,
         and queue the picked-token readback as a pending record. Returns
-        False when nothing is schedulable."""
+        False when nothing is schedulable.
+
+        One host span per phase (``engine/schedule``, ``engine/stage``,
+        ``engine/dispatch``; ``engine/readback`` is the reconcile's). The
+        dispatch span carries the work of the dispatch, which the
+        benchmark's readers match to the program's execution on the device:
+        ``kv_tokens`` is the context every scheduled sequence attends over
+        (once a sequence), ``attn_pairs`` the query x key pairs, and
+        ``dec_kv_tokens`` the decode rows' part of ``kv_tokens`` (a decode
+        row is one query, so also its part of the pairs)."""
+        t0 = time.perf_counter()
+        with span("engine/schedule"):
+            plan = self._pack_step_device()
+        if plan is None:
+            return False
+        (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec, kv_pre,
+         pairs_pre) = plan
+        sampled = any(s.temperature > 0.0 for _, s in emit)
+        has_tk = sampled and any(s.top_k > 0 for _, s in emit)
+        has_tp = sampled and any(s.top_p < 1.0 for _, s in emit)
+        with span("engine/stage"):
+            self._sync_bt()
+            staged = self._stage(np.concatenate(parts))
+        fn = self._get_dev_step(t_total, nd, nt, self._table_width(max_pos),
+                                sampled, has_tk, has_tp)
+        if self._faults.enabled:
+            self._faults.fire(POINT_DISPATCH)
+        with span("engine/dispatch",
+                  program=self._step_program_name(t_total, nd, nt),
+                  tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
+                  attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec):
+            picked, self._dev_state, self.cache = fn(
+                self.params, self.cache, self._dev_state, self._bt_dev,
+                staged, self._sample_root)
+        participants: dict[int, _SeqState] = {}
+        for _, seq in emit:
+            participants[seq.slot] = seq
+        for seq in participants.values():
+            seq.refs += 1
+            self._hist_stale[seq.slot] = True
+        self._pending.append({"kind": "step", "picked": picked,
+                              "emit": emit,
+                              "participants": list(participants.values())})
+        self._note_dispatch(t0)
+        if tpairs is not None:
+            self._trace_spans(t0, time.perf_counter(), tpairs,
+                              mode="dev_step")
+        return True
+
+    def _step_program_name(self, t: int, nd: int, nt: int) -> str:
+        """The name a device-resident step program goes by in a trace: its
+        decode-row bucket and tile count (untiled: its token bucket)."""
+        if self._use_tiles:
+            return f"ragged_step_d{nd}_t{nt}"
+        return f"ragged_step_n{t}"
+
+    def _pack_step_device(self):
+        """The scheduling half of ``_dispatch_step_device``: pick the decode
+        rows and prefill chunks of this step, allocate their blocks and pack
+        the staging planes. Returns None when nothing is schedulable."""
         cfg = self.cfg
         ct = cfg.prefill_tile if self._use_tiles else 0
         budget = cfg.max_tokens_per_step
-        t0 = time.perf_counter()
         trace_on = self._tracer.enabled
         tpairs = [] if trace_on else None
+        kv_dec = kv_pre = pairs_pre = 0
         size = budget + ct
         tokens = np.zeros(size, np.int32)
         slots = np.full(size, cfg.max_seqs, np.int32)
@@ -2675,6 +2740,7 @@ class RaggedInferenceEngine:
                 tpairs.append((seq, "engine/decode", 1))
             max_pos = max(max_pos, seq.pos)
             seq.pos += 1
+            kv_dec += seq.pos
             n_dec += 1
 
         ts = tpz = tv = None
@@ -2698,7 +2764,9 @@ class RaggedInferenceEngine:
                     tpz[tile0 + ti] = seq.pos + ti * ct
                     tv[tile0 + ti] = min(ct, take - ti * ct)
                 max_pos = max(max_pos, seq.pos + take - 1)
+                pairs_pre += take * seq.pos + take * (take + 1) // 2
                 seq.pos += take
+                kv_pre += seq.pos
                 sched += take
                 if trace_on:
                     tpairs.append((seq, "engine/prefill", take))
@@ -2725,7 +2793,9 @@ class RaggedInferenceEngine:
                 positions[sl] = np.arange(seq.pos, seq.pos + take,
                                           dtype=np.int32)
                 max_pos = max(max_pos, seq.pos + take - 1)
+                pairs_pre += take * seq.pos + take * (take + 1) // 2
                 seq.pos += take
+                kv_pre += seq.pos
                 n += take
                 if trace_on:
                     tpairs.append((seq, "engine/prefill", take))
@@ -2736,39 +2806,15 @@ class RaggedInferenceEngine:
             t_total = 0 if n == 0 else next(b for b in self._buckets
                                             if b >= n)
         if n == 0:
-            return False
+            return None
         self.tokens_scheduled += n
         self.tokens_padded += t_total - n
-        sampled = any(s.temperature > 0.0 for _, s in emit)
-        has_tk = sampled and any(s.top_k > 0 for _, s in emit)
-        has_tp = sampled and any(s.top_p < 1.0 for _, s in emit)
         parts = [tokens[:t_total], slots[:t_total], positions[:t_total],
                  flags[:t_total]]
         if ct:
             parts += [ts, tpz, tv]
-        self._sync_bt()
-        staged = self._stage(np.concatenate(parts))
-        fn = self._get_dev_step(t_total, nd, nt, self._table_width(max_pos),
-                                sampled, has_tk, has_tp)
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        picked, self._dev_state, self.cache = fn(
-            self.params, self.cache, self._dev_state, self._bt_dev, staged,
-            self._sample_root)
-        participants: dict[int, _SeqState] = {}
-        for _, seq in emit:
-            participants[seq.slot] = seq
-        for seq in participants.values():
-            seq.refs += 1
-            self._hist_stale[seq.slot] = True
-        self._pending.append({"kind": "step", "picked": picked,
-                              "emit": emit,
-                              "participants": list(participants.values())})
-        self._note_dispatch(t0)
-        if trace_on:
-            self._trace_spans(t0, time.perf_counter(), tpairs,
-                              mode="dev_step")
-        return True
+        return (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec,
+                kv_pre, pairs_pre)
 
     def _reconcile_pending(self) -> dict:
         """Read back the OLDEST pending dispatch's tokens and fold them
@@ -2781,7 +2827,8 @@ class RaggedInferenceEngine:
         t0 = time.perf_counter()
         out: dict = {}
         if rec["kind"] == "step":
-            picked = np.asarray(rec["picked"])
+            with span("engine/readback"):
+                picked = np.asarray(rec["picked"])
             t1 = time.perf_counter()
             self.readback_ns += int((t1 - t0) * 1e9)
             if self._tracer.enabled:
@@ -2850,7 +2897,8 @@ class RaggedInferenceEngine:
         oldest pending dispatch once the window holds two — so the blocking
         ``np.asarray`` readback of step t overlaps the device executing
         step t+1."""
-        self._admit_queued()
+        with span("engine/schedule"):
+            self._admit_queued()
         dispatched = False
         self._sched_wait = False
         if self.cfg.sched_steps >= 2:
@@ -3981,7 +4029,8 @@ class RaggedInferenceEngine:
                         cm.prefix_transfer(pub, seq.tenant, nblk)
             self._running[seq.slot] = seq
             if self.cfg.device_state:
-                self._write_slot_row(seq)
+                with span("engine/stage"):
+                    self._write_slot_row(seq)
             if use_cache:
                 tel = self.telemetry
                 if hit:

@@ -115,6 +115,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             _scratch((block_q, 128)),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -288,6 +289,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k,
         ),
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse4, delta)
 
     dq = pl.pallas_call(
@@ -299,6 +301,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
         scratch_shapes=[_scratch((block_q, d))],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse4, delta)
 
     dq = dq.transpose(0, 2, 1, 3)

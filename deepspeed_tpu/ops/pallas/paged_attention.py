@@ -124,6 +124,7 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret_mode(interpret),
+        name="paged_decode",
     )(slots.astype(jnp.int32), positions.astype(jnp.int32),
       block_tables.astype(jnp.int32), q, k_pool, v_pool)
 
@@ -269,6 +270,7 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret_mode(interpret),
+        name="tiled_prefill",
     )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
       tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, k_pool, v_pool)

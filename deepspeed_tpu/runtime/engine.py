@@ -50,6 +50,7 @@ from deepspeed_tpu.runtime.lr_schedules import LRScheduler, build_schedule
 from deepspeed_tpu.runtime.precision import LossScaleState
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import ThroughputTimer
+from deepspeed_tpu.utils.tracing import StepTracer, span
 from deepspeed_tpu.utils.compat import shard_map_compat
 
 REMAT_POLICIES = {
@@ -654,8 +655,6 @@ class Engine:
 
         # jax.profiler capture window + debug-nans trap (reference nvtx
         # instrumentation / sanity-check config, SURVEY §5.1-5.2)
-        from deepspeed_tpu.utils.tracing import StepTracer
-
         self.step_tracer = StepTracer(
             config.tracing,
             sync_fn=lambda: jax.block_until_ready(self._last_metrics))
@@ -2233,7 +2232,8 @@ class Engine:
                 fn = self._build_train_batch_fn()
                 self._ltd_jits[k] = fn
             self._train_batch_jit = fn
-        dev_batch = self._put_gas_batch(batch)
+        with span("train/stage_batch"):
+            dev_batch = self._put_gas_batch(batch)
         self.tput_timer.start()
         _c0 = time.perf_counter() if scope is not None else 0.0
         # 1-bit-family two-phase wire: dense program during the optimizer's
@@ -2242,44 +2242,47 @@ class Engine:
         in_dense_phase = (self._qgrad
                           and self.global_steps < self._qgrad_warmup_steps)
         try:
-            if in_dense_phase:
-                if self._warm_batch_jit is None:
-                    self._warm_batch_jit = self._build_train_batch_fn(
-                        use_qgrad=False)
-                self.params, self.opt_state, self.scale_state, metrics = \
-                    self._warm_batch_jit(
+            # the enqueue of the step program: it returns before the device
+            # is done (the caller's fetch of the loss settles the step)
+            with span("train/dispatch"):
+                if in_dense_phase:
+                    if self._warm_batch_jit is None:
+                        self._warm_batch_jit = self._build_train_batch_fn(
+                            use_qgrad=False)
+                    self.params, self.opt_state, self.scale_state, metrics = \
+                        self._warm_batch_jit(
+                            self.params, self.opt_state, self.scale_state,
+                            jnp.int32(self.global_steps), self._train_rng,
+                            dev_batch,
+                        )
+                elif self._qgrad:
+                    (self.params, self.opt_state, self.scale_state, metrics,
+                     self._qgrad_error) = self._train_batch_jit(
                         self.params, self.opt_state, self.scale_state,
-                        jnp.int32(self.global_steps), self._train_rng,
-                        dev_batch,
+                        jnp.int32(self.global_steps), self._train_rng, dev_batch,
+                        self._qgrad_error,
                     )
-            elif self._qgrad:
-                (self.params, self.opt_state, self.scale_state, metrics,
-                 self._qgrad_error) = self._train_batch_jit(
-                    self.params, self.opt_state, self.scale_state,
-                    jnp.int32(self.global_steps), self._train_rng, dev_batch,
-                    self._qgrad_error,
-                )
-            elif self._sentinel is not None:
-                (self.params, self.opt_state, self.scale_state, metrics,
-                 self._sent_state) = self._train_batch_jit(
-                    self.params,
-                    self.opt_state,
-                    self.scale_state,
-                    jnp.int32(self.global_steps),
-                    self._train_rng,
-                    dev_batch,
-                    self._sent_state,
-                )
-            else:
-                self.params, self.opt_state, self.scale_state, metrics = \
-                    self._train_batch_jit(
+                elif self._sentinel is not None:
+                    (self.params, self.opt_state, self.scale_state, metrics,
+                     self._sent_state) = self._train_batch_jit(
                         self.params,
                         self.opt_state,
                         self.scale_state,
                         jnp.int32(self.global_steps),
                         self._train_rng,
                         dev_batch,
+                        self._sent_state,
                     )
+                else:
+                    self.params, self.opt_state, self.scale_state, metrics = \
+                        self._train_batch_jit(
+                            self.params,
+                            self.opt_state,
+                            self.scale_state,
+                            jnp.int32(self.global_steps),
+                            self._train_rng,
+                            dev_batch,
+                        )
         except Exception as e:
             # OOM forensics: a RESOURCE_EXHAUSTED dispatch writes the
             # per-owner crash report BEFORE unwinding (the ledger breakdown

@@ -35,6 +35,7 @@ from deepspeed_tpu.serving.protocol import (
 )
 from deepspeed_tpu.telemetry import get_telemetry
 from deepspeed_tpu.utils.logging import log_dist
+from deepspeed_tpu.utils.tracing import instant, span
 
 
 class StreamError(RuntimeError):
@@ -151,11 +152,15 @@ class ReplicaStats:
 class _Open:
     """Loop-thread bookkeeping for one in-engine request."""
 
-    __slots__ = ("stream", "delivered")
+    __slots__ = ("stream", "delivered", "t_submit", "noted")
 
-    def __init__(self, stream: TokenStream):
+    def __init__(self, stream: TokenStream, t_submit: float = 0.0):
         self.stream = stream
         self.delivered = 0
+        self.t_submit = t_submit
+        # profiler instants written for this request so far: 0 none,
+        # 1 request/admit, 2 request/first_token too (nothing left to note)
+        self.noted = 0 if t_submit else 2
 
 
 class EngineLoop:
@@ -476,7 +481,7 @@ class EngineLoop:
                             tenant=getattr(req, "tenant", "default"),
                             sla_class=getattr(
                                 req, "sla_class", "interactive"))
-                    self._open[rid] = _Open(stream)
+                    self._open[rid] = _Open(stream, req.t_submit)
                 except ValueError as e:
                     stream._fail(str(e))
             with self._lock:
@@ -502,6 +507,8 @@ class EngineLoop:
                 op.stream._fail(f"request {rid} lost by engine")
                 del self._open[rid]
                 continue
+            if op.noted < 2:
+                self._note_progress(op, seq)
             gen = seq.generated
             while op.delivered < len(gen):
                 op.stream._push(gen[op.delivered])
@@ -509,6 +516,20 @@ class EngineLoop:
             if rid in eng._results:
                 op.stream._finish(self._finish_reason(seq))
                 del self._open[rid]
+
+    @staticmethod
+    def _note_progress(op: _Open, seq) -> None:
+        """Profiler instants of a request's two waits, from the stamps the
+        front end (``t_submit``) and the engine (``t_admit``,
+        ``t_first_token``; telemetry on) already take: seconds from submit
+        to admission into a slot, and to the first token read back."""
+        if op.noted == 0 and seq.t_admit:
+            instant("request/admit", wait_s=seq.t_admit - op.t_submit)
+            op.noted = 1
+        if op.noted == 1 and seq.t_first_token:
+            instant("request/first_token",
+                    wait_s=seq.t_first_token - op.t_submit)
+            op.noted = 2
 
     def _publish_stats(self) -> None:
         eng = self._engine
@@ -589,8 +610,9 @@ class EngineLoop:
     def _run_loop(self) -> None:
         eng = self._engine
         while True:
-            self._drain_inbox()
-            self._drain_calls()
+            with span("loop/inbox"):
+                self._drain_inbox()
+                self._drain_calls()
             if eng.has_work:
                 if self._faults.enabled:
                     # outside the try: an injected loop fault kills the
@@ -605,11 +627,13 @@ class EngineLoop:
                 else:
                     self._consec_crashes = 0
                     self.steps += 1
+                with span("loop/deliver"):
+                    self._deliver()
+                    self._publish_stats()
+                continue
+            with span("loop/deliver"):
                 self._deliver()
                 self._publish_stats()
-                continue
-            self._deliver()
-            self._publish_stats()
             with self._lock:
                 idle = (not self._inbox and not self._cancel_ids
                         and not self._pending_calls)

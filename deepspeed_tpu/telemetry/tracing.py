@@ -42,7 +42,6 @@ import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
 
 _TRACEPARENT_RE = re.compile(
     r"^([0-9a-f]{2})-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$")
@@ -205,20 +204,6 @@ class Tracer:
         ctx = self.begin(parent)
         self.finish(ctx, name, t0, t1, **attrs)
         return ctx
-
-    @contextmanager
-    def span(self, parent: TraceContext | None, name: str, **attrs):
-        """Measure a block as a child span; yields the child context (None
-        when not tracing, so nested call sites stay guard-free)."""
-        ctx = self.begin(parent)
-        if ctx is None:
-            yield None
-            return
-        t0 = time.perf_counter()
-        try:
-            yield ctx
-        finally:
-            self.finish(ctx, name, t0, time.perf_counter(), **attrs)
 
     def counter_sample(self, track: str, values: dict,
                        t: float | None = None) -> None:

@@ -1,51 +1,31 @@
-"""Profiler tracing hooks (the reference's nvtx instrumentation, TPU-native).
+"""Profiler tracing hooks (the reference's nvtx ranges, TPU-native).
 
-Role parity with ``deepspeed/utils/nvtx.py:25 instrument_w_nvtx`` (decorator
-pushing an nvtx range around every hot function) and the accelerator
-``range_push/pop`` API — expressed with ``jax.profiler``: host-side spans use
-``TraceAnnotation``, traced-code regions use ``jax.named_scope`` (which names
-the HLO ops so device traces attribute time to framework phases), and whole
+The role of ``deepspeed/utils/nvtx.py`` and the accelerator's
+``range_push/pop``, expressed with ``jax.profiler``: host-side spans are
+``TraceAnnotation``s (:func:`span`, :func:`instant`), every training step is
+a ``StepTraceAnnotation`` whoever opened the profiler session, and whole
 training windows are captured with ``start_trace``/``stop_trace`` driven by
 the engine's ``tracing`` config (viewable in TensorBoard/XProf/Perfetto).
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 
-# traced-code scope: names HLO ops (device-side attribution)
-named_scope = jax.named_scope
+
+def span(name: str, **args):
+    """A host span on the profiler's clock: ``with span("engine/stage"):``.
+    Recorded only while a profiler session is open (a capture of
+    :class:`StepTracer`, ``/debug/profile``, the benchmark's traced slice);
+    with none the annotation is built and dropped. ``args`` become the
+    event's arguments in the trace."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
-def instrument(name: str | None = None):
-    """Decorator: host-side profiler span around the call
-    (``instrument_w_nvtx`` analog)."""
-
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            with jax.profiler.TraceAnnotation(label):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
-
-
-def range_push(name: str):
-    """Imperative span begin (reference ``accelerator.range_push``). Returns
-    the annotation object; pass it to :func:`range_pop`."""
-    ann = jax.profiler.TraceAnnotation(name)
-    ann.__enter__()
-    return ann
-
-
-def range_pop(ann) -> None:
-    ann.__exit__(None, None, None)
+def instant(name: str, **args) -> None:
+    """A span of no length: an event whose arguments carry the reading."""
+    with jax.profiler.TraceAnnotation(name, **args):
+        pass
 
 
 class StepTracer:
@@ -71,14 +51,13 @@ class StepTracer:
             atexit.register(self.close)
 
     def before_step(self, step: int) -> None:
-        if not self.cfg.enabled or self._done:
-            return
-        # a step that raised mid-window never reached after_step: exit the
-        # stale annotation before opening a new one
+        # a step that raised never reached after_step: exit the stale
+        # annotation before opening a new one
         self._exit_step_ann()
         # >= so a resumed run (global step already past start_step) still
         # captures its first window
-        if not self._active and step >= self.cfg.start_step:
+        if (self.cfg.enabled and not self._done and not self._active
+                and step >= self.cfg.start_step):
             try:
                 jax.profiler.start_trace(self.cfg.trace_dir)
             except Exception as e:
@@ -87,13 +66,15 @@ class StepTracer:
                 logger.warning(f"StepTracer: start_trace failed ({e}); "
                                "capture disabled for this run")
                 self._finish()
-                return
-            self._active = True
-            self._started_at = step
-        if self._active:
-            self._step_ann = jax.profiler.StepTraceAnnotation(
-                "train_step", step_num=step)
-            self._step_ann.__enter__()
+            else:
+                self._active = True
+                self._started_at = step
+        # on every step, not only inside a capture of our own: a session
+        # someone else opened (the benchmark, /debug/profile) sees the steps
+        # too, and with no session the annotation records nothing
+        self._step_ann = jax.profiler.StepTraceAnnotation(
+            "train_step", step_num=step)
+        self._step_ann.__enter__()
 
     def after_step(self, step: int) -> None:
         self._exit_step_ann()

@@ -93,6 +93,39 @@ def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
     assert text.count("tpu_custom_call") >= n_calls
 
 
+@pytest.mark.parametrize("kernel,name", [
+    (_flash_fwd, "flash_fwd"), (_flash_bwd, "flash_bwd_dkv"),
+    (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
+    (_prefill, "tiled_prefill")],
+    ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
+         "tiled_prefill"])
+def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
+    """``pl.pallas_call(name=...)``: the compiled custom call is
+    ``%<name>.N`` (``%transpose_jvp_<name>__.N`` under a bare ``jax.grad``),
+    which is an operation's event name in a device trace, and
+    ``benchmark/kernels/<name>.json``'s pattern finds it and no other."""
+    import json
+    import os
+    import re
+
+    kernels = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                           "benchmark", "kernels")
+    patterns = {}
+    for f in os.listdir(kernels):
+        with open(os.path.join(kernels, f)) as fh:
+            patterns[f[:-5]] = re.compile(json.load(fh)["trace_pattern"])
+    text = jax.jit(kernel).lower(
+        *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    mine = [ln for ln in calls if patterns[name].search(ln)]
+    assert mine and all(name in ln.split(" = ")[0] for ln in mine)
+    for ln in calls:  # every kernel call is some named kernel's, and one's only
+        hits = [k for k, rx in patterns.items()
+                if k != "pallas_custom_call" and rx.search(ln)]
+        assert len(hits) == 1 and patterns["pallas_custom_call"].search(ln)
+
+
 def test_flash_compiles_on_a_described_mesh(v5e, monkeypatch):
     """GSPMD refuses a Mosaic kernel on more than one device; through
     ``ShardCtx.attention`` it runs manual over the mesh (ZeRO-3 on four

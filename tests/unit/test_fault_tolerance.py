@@ -404,9 +404,15 @@ class TestDrainAndDisconnect:
                        for i in range(2)]
             for t in threads:
                 t.start()
-            while not eng.has_work and any(t.is_alive() for t in threads):
+            # wait until BOTH requests are genuinely inflight (a request the
+            # front end has not routed yet is refused by the drain, rightly)
+            t_end = time.monotonic() + 60
+            while (len(eng._running) + len(eng._queued) < 2
+                   and time.monotonic() < t_end):
                 time.sleep(0.005)
-            os.kill(os.getpid(), signal.SIGTERM)
+            # the preemption notice, through the handler SIGTERM is bound to:
+            # a real signal would go to the xdist worker this test runs in
+            signal.getsignal(signal.SIGTERM)(signal.SIGTERM, None)
             assert handler.should_stop
             for t in threads:
                 t.join(timeout=120)

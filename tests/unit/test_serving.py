@@ -419,9 +419,15 @@ class TestEndToEnd:
                        for i in range(2)]
             for t in threads:
                 t.start()
-            while not eng.has_work and any(t.is_alive() for t in threads):
-                time.sleep(0.005)  # wait until work is genuinely inflight
-            os.kill(os.getpid(), signal.SIGTERM)
+            # wait until BOTH requests are genuinely inflight (a request the
+            # front end has not routed yet is refused by the drain, rightly)
+            t_end = time.monotonic() + 60
+            while (len(eng._running) + len(eng._queued) < 2
+                   and time.monotonic() < t_end):
+                time.sleep(0.005)
+            # the preemption notice, through the handler SIGTERM is bound to:
+            # a real signal would go to the xdist worker this test runs in
+            signal.getsignal(signal.SIGTERM)(signal.SIGTERM, None)
             assert handler.should_stop
             assert router.state() == "draining"
             # new work is refused while draining (healthz -> 503)

@@ -9,7 +9,6 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm.topology import reset_topology
 from deepspeed_tpu.models import llama
-from deepspeed_tpu.utils.tracing import instrument, named_scope, range_pop, range_push
 
 
 def _engine(tmp_path, extra):
@@ -76,21 +75,6 @@ def test_capture_survives_raising_step(tmp_path):
     engine.step_tracer.close()
     found = [f for root, _, files in os.walk(trace_dir) for f in files]
     assert found, "no trace files written after mid-window failure"
-
-
-def test_instrument_and_ranges_run():
-    calls = []
-
-    @instrument("unit-span")
-    def work(x):
-        calls.append(x)
-        return x + 1
-
-    assert work(1) == 2 and calls == [1]
-    ann = range_push("manual-span")
-    range_pop(ann)
-    with named_scope("scoped"):
-        pass
 
 
 def test_sanity_checks_catch_bad_batches(tmp_path):
