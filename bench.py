@@ -3083,8 +3083,8 @@ def smoke_main():
         t, mb, bs, hq, hkv, d = 16, 8, 32, 16, 8, 64
         nb = t * mb + 1
         q = jnp.asarray(rng.normal(size=(t, hq, d)), jnp.bfloat16)
-        kp = jnp.asarray(rng.normal(size=(nb, bs, hkv, d)), jnp.bfloat16)
-        vp = jnp.asarray(rng.normal(size=(nb, bs, hkv, d)), jnp.bfloat16)
+        kp = jnp.asarray(rng.normal(size=(nb, bs, hkv * d)), jnp.bfloat16)
+        vp = jnp.asarray(rng.normal(size=(nb, bs, hkv * d)), jnp.bfloat16)
         slots = jnp.arange(t, dtype=jnp.int32)
         positions = jnp.asarray(rng.integers(1, mb * bs, (t,)), jnp.int32)
         # read-only parity check: aliased blocks across rows are fine

@@ -235,9 +235,9 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
         bt = np.zeros((3, mb), np.int32)
         bt[:2] = rng.permutation(np.arange(1, 2 * mb + 1)).reshape(2, mb)
         bt = jnp.asarray(bt)
-        kp = jax.random.normal(next(keys), (2 * mb + 1, block, hkv, d),
+        kp = jax.random.normal(next(keys), (2 * mb + 1, block, hkv * d),
                                jnp.bfloat16)
-        vp = jax.random.normal(next(keys), (2 * mb + 1, block, hkv, d),
+        vp = jax.random.normal(next(keys), (2 * mb + 1, block, hkv * d),
                                jnp.bfloat16)
         slots = jnp.asarray([0, 1, 0, 1, 0, 1, 0, 1], jnp.int32)
         pos = jnp.asarray(rng.integers(0, seq_len, 8), jnp.int32)

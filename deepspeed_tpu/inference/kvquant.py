@@ -124,13 +124,19 @@ class QuantizedKV:
     pytree node, modeled on ``ops/quantizer.QuantizedWeight``.
 
     Children ``(q, s)`` flow through jit / lax.scan / tree_map / donation;
-    static aux ``(codec, dtype)`` ride along every transform, so a scan
-    slice of the full ``[L, nb, bs, Hkv, D]`` pool is itself a QuantizedKV
-    over ``[nb, bs, Hkv, D]``. The properties keep existing model/engine
-    code shape-compatible without edits:
+    static aux ``(codec, dtype)`` ride along every transform. Both arrays
+    have the paged contract's form (``models/paged.py``): blocks on the
+    leading axes, a lane-dense row behind them. The payload ``q`` is
+    ``[L, nb, bs, Hkv*D]`` (``[blocks, bs, Hkv*D]`` as a layer sees it); the
+    scales ``s``, one per (row, head), are one row a block, ``[L, nb,
+    bs*Hkv]`` (a ``[.., bs, Hkv]`` array pads its 8 or 25 heads to 128 lanes
+    on the chip, and its scatter and its gather each want a layout of
+    their own: a copy of all scales a layer). Head count and size are read
+    off the two shapes. The properties keep model/engine code
+    shape-compatible without edits:
 
     - ``.shape`` is the payload shape (``kc.shape[1]`` is still the block
-      size, ``k_pool.shape[2]`` still the kv-head count per layer slice);
+      size);
     - ``.dtype`` is the COMPUTE dtype (``cache["k"].dtype`` still picks
       the activation dtype for the forward);
     - ``.nbytes`` is payload + scales, so ``kv_bytes_per_token()``, the
@@ -178,21 +184,28 @@ class QuantizedKV:
     # -- pool ops (the two touch points of the paged contract) -------------
     def scatter_rows(self, blk, off, rows):
         """Quantize-at-write: scatter new KV rows ``[T, Hkv, D]`` into
-        ``(block, offset)`` cells of a per-layer pool ``[nb, bs, Hkv, D]``
-        (``models/paged.write_kv_paged``)."""
+        ``(block, offset)`` cells of the pool ``[blocks, bs, Hkv*D]``
+        (``models/paged.write_kv_paged``). A row's ``Hkv`` scales go as one
+        window into its block's scale row at lane ``offset * Hkv``."""
         codec = get_codec(self.codec)
         q_rows, s_rows = quantize_kv_rows(rows, codec)
-        return QuantizedKV(
-            self.q.at[blk, off].set(q_rows),
-            self.s.at[blk, off].set(s_rows),
-            self.codec, self._dtype_name)
+        t, hkv = s_rows.shape
+        scales = jax.lax.scatter(
+            self.s, jnp.stack([blk, off * hkv], axis=-1), s_rows,
+            jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1,), inserted_window_dims=(0,),
+                scatter_dims_to_operand_dims=(0, 1)))
+        return QuantizedKV(self.q.at[blk, off].set(q_rows.reshape(t, -1)),
+                           scales, self.codec, self._dtype_name)
 
     def gather_dequant(self, tables):
-        """Dequant fused into the gather: ``tables [T, MB]`` over a
-        per-layer pool returns fp32 context ``[T, MB, bs, Hkv, D]`` —
-        a per-dispatch transient inside the attention program, fused by
-        XLA with the surrounding einsum (``ops/attention``)."""
-        return dequantize_kv_rows(self.q[tables], self.s[tables])
+        """Dequant fused into the gather: ``tables [T, MB]`` over the pool
+        returns fp32 context ``[T, MB, bs, Hkv, D]`` — a per-dispatch
+        transient inside the attention program, fused by XLA with the
+        surrounding einsum (``ops/attention``)."""
+        q = self.q[tables]                           # [T, MB, bs, Hkv*D]
+        s = self.s[tables].reshape(q.shape[:3] + (-1,))
+        return dequantize_kv_rows(q.reshape(s.shape + (-1,)), s)
 
     # -- pickling (handoff / disk spill payloads) --------------------------
     def __getstate__(self):
@@ -213,25 +226,7 @@ jax.tree_util.register_pytree_node(
 )
 
 
-# --------------------------------------------------------- pool construction
-def build_quantized_paged_cache(init_fn, num_blocks: int, block_size: int,
-                                dtype, codec: KVQCodec):
-    """Build the quantized pool DIRECTLY at storage precision: the model's
-    ``init_paged_cache_fn`` is only ``eval_shape``-d, so no transient fp
-    pool is ever allocated (the whole point is not to pay the fp footprint
-    even once at startup)."""
-    # close over the args: block counts and dtype are static, not tracers
-    struct = jax.eval_shape(lambda: init_fn(num_blocks, block_size, dtype))
-
-    def to_q(leaf):
-        return QuantizedKV(
-            jnp.zeros(leaf.shape, codec.storage_dtype),
-            jnp.zeros(leaf.shape[:-1], codec.scale_dtype),
-            codec.name, np.dtype(leaf.dtype).name)
-
-    return jax.tree_util.tree_map(to_q, struct)
-
-
+# ------------------------------------------------------------- pool sizes
 def paged_block_bytes(init_fn, num_blocks: int, block_size: int, dtype) -> int:
     """Bytes one UNQUANTIZED block (all layers, k+v) would cost at
     ``dtype`` — the baseline for the bytes-saved counter and the

@@ -1149,14 +1149,11 @@ class RaggedInferenceEngine:
     def _build_cache(self):
         """Build the paged KV pool: the family's plain fp pool when quant is
         off (bit-identical to the pre-quant engine), else the low-bit
-        ``QuantizedKV`` pool built from ``eval_shape`` (no transient fp
-        allocation at the full pool size)."""
-        if self._kvq is None:
-            return self.spec.init_paged_cache_fn(
-                self.cfg.num_blocks, self.cfg.block_size, self.dtype)
-        return kvquant.build_quantized_paged_cache(
-            self.spec.init_paged_cache_fn, self.cfg.num_blocks,
-            self.cfg.block_size, self.dtype, self._kvq)
+        ``QuantizedKV`` pool built directly at storage precision (no
+        transient fp allocation at the full pool size)."""
+        return self.spec.init_paged_cache_fn(
+            self.cfg.num_blocks, self.cfg.block_size, self.dtype,
+            codec=self._kvq)
 
     def kv_quant_stats(self) -> dict | None:
         """Low-bit KV summary for bench/telemetry readers; None = quant off.

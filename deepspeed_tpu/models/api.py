@@ -333,7 +333,8 @@ class ModelSpec:
     init_cache_fn: Callable | None = None
     decode_fn: Callable | None = None
     # ragged/continuous-batching hooks (reference inference/v2):
-    # init_paged_cache_fn(num_blocks, block_size, dtype) -> cache;
+    # init_paged_cache_fn(num_blocks, block_size, dtype, codec=None) -> cache
+    #   in the paged contract's storage form (models/paged.py);
     # ragged_forward_fn(params, tokens, slots, positions, block_tables, cache)
     #   -> (logits [T, V], cache)
     init_paged_cache_fn: Callable | None = None

@@ -190,10 +190,16 @@ def decode_forward(cfg: GPT2Config, params, tokens, cache, start_pos,
 
 
 def init_paged_cache(cfg: GPT2Config, num_blocks: int, block_size: int,
-                     dtype=jnp.bfloat16) -> dict:
-    """Blocked KV pool [L, num_blocks, block_size, H, Dh] (ragged engine)."""
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_heads, cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+                     dtype=jnp.bfloat16, codec=None) -> dict:
+    """Blocked KV pool of the ragged engine in the paged contract's storage
+    form, ``[L, num_blocks, block_size, Hkv*Dh]`` (``models/paged.py``): a
+    step program addresses it through block tables and never holds an array
+    the size of a layer's slice. ``codec``: a ``kvquant.KVQCodec`` builds the
+    low-bit pool at storage precision."""
+    from deepspeed_tpu.models.paged import init_paged_pool
+
+    return init_paged_pool(cfg.num_layers, num_blocks, block_size,
+                           cfg.num_heads, cfg.hd, dtype, codec)
 
 
 def _ragged_block(cfg: GPT2Config, x, lp, kc, vc, positions, slots,
@@ -229,10 +235,10 @@ def ragged_forward(cfg: GPT2Config, params, tokens, slots, positions,
     x = (params["wte"][tokens] + params["wpe"][positions]).astype(
         cache["k"].dtype)
     x, new_k, new_v = scan_layers_paged(
-        lambda x, lp, kc, vc: _ragged_block(
-            cfg, x, lp, kc, vc, positions, slots, block_tables,
+        lambda x, lp, kc, vc, layer_tables: _ragged_block(
+            cfg, x, lp, kc, vc, positions, slots, layer_tables,
             prefill_tiles=prefill_tiles),
-        x, params["layers"], cache["k"], cache["v"])
+        x, params["layers"], cache["k"], cache["v"], block_tables)
     x = layernorm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 

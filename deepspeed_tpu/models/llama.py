@@ -255,13 +255,16 @@ def decode_forward(cfg: LlamaConfig, params, tokens, cache, start_pos,
 
 
 def init_paged_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
-                     dtype=jnp.bfloat16) -> dict:
-    """Blocked KV pool, stacked [L, num_blocks, block_size, Hkv, Dh] — the
-    paged cache of the ragged engine (reference
-    ``inference/v2/ragged/kv_cache.py`` blocked KV; block 0 is the scratch
-    block padding tokens write into)."""
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads, cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+                     dtype=jnp.bfloat16, codec=None) -> dict:
+    """Blocked KV pool of the ragged engine in the paged contract's storage
+    form, ``[L, num_blocks, block_size, Hkv*Dh]`` (``models/paged.py``): a
+    step program addresses it through block tables and never holds an array
+    the size of a layer's slice. ``codec``: a ``kvquant.KVQCodec`` builds the
+    low-bit pool at storage precision."""
+    from deepspeed_tpu.models.paged import init_paged_pool
+
+    return init_paged_pool(cfg.num_layers, num_blocks, block_size,
+                           cfg.num_kv_heads, cfg.hd, dtype, codec)
 
 
 def _ragged_layer(cfg: LlamaConfig, x, lp, kc, vc, positions, slots,
@@ -319,10 +322,10 @@ def ragged_forward(cfg: LlamaConfig, params, tokens, slots, positions,
 
     x = params["embed"][tokens].astype(cache["k"].dtype)
     x, new_k, new_v = scan_layers_paged(
-        lambda x, lp, kc, vc: _ragged_layer(
-            cfg, x, lp, kc, vc, positions, slots, block_tables,
+        lambda x, lp, kc, vc, layer_tables: _ragged_layer(
+            cfg, x, lp, kc, vc, positions, slots, layer_tables,
             prefill_tiles=prefill_tiles),
-        x, params["layers"], cache["k"], cache["v"])
+        x, params["layers"], cache["k"], cache["v"], block_tables)
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     head = lm_head(cfg, params)
     logits = x @ head.astype(x.dtype)

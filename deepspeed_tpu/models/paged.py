@@ -3,9 +3,28 @@
 One home for the logic every family (llama, gpt2, mixtral) used to carry
 verbatim: the paged-pool KV scatter, the decode/tiled-prefill attention
 split over the block pool (reference ``inference/v2/ragged_ops`` layout),
-and the dense-cache append+attend used by the v1-style engines. A fix to
-the paged contract (e.g. the ``_table_view`` width slicing) lands HERE once
-instead of three times.
+and the dense-cache append+attend used by the v1-style engines.
+
+**The paged contract** (docs/SERVING.md "The paged KV pool"), stated once:
+
+- *Storage.* ``cache["k"]`` / ``cache["v"]`` are ``[L, NB, BS, Hkv*D]``:
+  a block's ``BS`` token rows are contiguous and a row is one lane-dense
+  vector of all KV heads (GPT-2 XL 1600 lanes, Mixtral / Llama-3-8B 1024).
+  A ``kvquant.QuantizedKV`` pool keeps its payload in that form and its
+  scales, one per (row, head), as one lane-dense row a block,
+  ``[L, NB, BS*Hkv]``.
+- *Block axis.* Inside a step program a layer sees the pool with ``L`` and
+  ``NB`` merged (``[L*NB, BS, Hkv*D]``, a bitcast) and addresses it through
+  ITS block table, ``block_tables + layer * NB``. It never owns a slice.
+- *Who may index what.* A step program touches the block axis only through
+  a block table: ``write_kv_paged`` scatters the step's ``T`` rows in
+  place, the XLA gather and the two Pallas kernels read the blocks the
+  table names. Host-side code between steps (``_gather_blocks``,
+  ``_scatter_blocks``, tiers, prefix cache, ``KVHandoff``) indexes blocks
+  as ``a[:, ids]`` on ``[L, NB, ...]``.
+- A step program must never hold an array the size of a layer's slice of
+  the pool (``tests/unit/test_compile_tpu.py`` asserts it on the compiled
+  program): its pool traffic is the rows written plus the context read.
 """
 
 from __future__ import annotations
@@ -15,45 +34,61 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def scan_layers_paged(layer_fn, x, layers, k_pool, v_pool):
-    """Run ``layer_fn(x, lp, kc, vc) -> (x, kc, vc)`` over the stacked layers
-    with the blocked KV pool CARRIED through the scan: each layer's
-    ``[NB, BS, Hkv, D]`` slice is indexed out of the ``[L, ...]`` pool and
-    written back in place, so a step program holds the pool once.
+def init_paged_pool(num_layers: int, num_blocks: int, block_size: int,
+                    kv_heads: int, head_dim: int, dtype, codec=None) -> dict:
+    """The blocked KV pool in the contract's storage form: ``{"k", "v"}`` of
+    ``[L, NB, BS, Hkv*D]`` (block 0 is the scratch block padding tokens write
+    into). With a ``kvquant.KVQCodec`` the pool is built at storage
+    precision, payload plus one scale per (row, head) (``[L, NB, BS*Hkv]``)."""
+    shape = (num_layers, num_blocks, block_size, kv_heads * head_dim)
+    if codec is None:
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    from deepspeed_tpu.inference.kvquant import QuantizedKV
 
-    As scan ``xs``/``ys`` the pool was held twice — the stacked input and the
-    stacked output are distinct buffers to the compiler, donation or not. At
-    GPT-2 XL (48 layers) with a 16K-token pool the TPU compiler put a
-    single-step program at 15.5 GiB of the chip's 15.75 that way, and at
-    9.3 GiB this way (PERF.md). Works on a fp pool and on a
-    ``kvquant.QuantizedKV`` alike: both are pytrees of ``[L, ...]`` arrays.
+    def pool():
+        return QuantizedKV(
+            jnp.zeros(shape, codec.storage_dtype),
+            jnp.zeros(shape[:2] + (block_size * kv_heads,), codec.scale_dtype),
+            codec.name, jnp.dtype(dtype).name)
+
+    return {"k": pool(), "v": pool()}
+
+
+def scan_layers_paged(layer_fn, x, layers, k_pool, v_pool, block_tables):
+    """Run ``layer_fn(x, lp, kc, vc, layer_tables) -> (x, kc, vc)`` over the
+    stacked layers. The pool is carried through the scan WHOLE, with ``L``
+    and ``NB`` merged into one block axis; a layer addresses it through
+    ``layer_tables = block_tables + layer * NB`` and never gets a slice, so
+    per step and layer the pool traffic is the rows ``write_kv_paged``
+    scatters plus the blocks attention reads: nothing proportional to
+    ``NB``. Works on a fp pool and on a ``kvquant.QuantizedKV`` alike: both
+    are pytrees of ``[L, NB, ...]`` arrays.
     """
-    n_layers = jax.tree_util.tree_leaves(k_pool)[0].shape[0]
+    n_layers, nb = jax.tree_util.tree_leaves(k_pool)[0].shape[:2]
 
-    def take(pool, i):
+    def merged(pool):
         return jax.tree_util.tree_map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), pool)
+            lambda a: a.reshape((n_layers * nb,) + a.shape[2:]), pool)
 
-    def put(pool, layer, i):
+    def split(pool):
         return jax.tree_util.tree_map(
-            lambda a, u: lax.dynamic_update_index_in_dim(a, u, i, 0),
-            pool, layer)
+            lambda a: a.reshape((n_layers, nb) + a.shape[1:]), pool)
 
     def body(carry, lp_i):
-        x, k_all, v_all = carry
+        x, kc, vc = carry
         lp, i = lp_i
-        x, kc, vc = layer_fn(x, lp, take(k_all, i), take(v_all, i))
-        return (x, put(k_all, kc, i), put(v_all, vc, i)), None
+        return layer_fn(x, lp, kc, vc, block_tables + i * nb), None
 
-    (x, k_pool, v_pool), _ = lax.scan(
-        body, (x, k_pool, v_pool),
+    (x, kc, vc), _ = lax.scan(
+        body, (x, merged(k_pool), merged(v_pool)),
         (layers, jnp.arange(n_layers, dtype=jnp.int32)))
-    return x, k_pool, v_pool
+    return x, split(kc), split(vc)
 
 
 def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables):
     """Scatter each ragged token's new KV into (block, offset) of its
-    sequence's pool blocks. ``kk``/``vv``: [T, Hkv, D].
+    sequence's pool blocks, in place. ``kk``/``vv``: [T, Hkv, D]; ``kc`` /
+    ``vc``: [blocks, BS, Hkv*D].
 
     This is the ONE write site of the paged contract, so it is also the
     ONE quantize site: a low-bit pool (``inference/kvquant.QuantizedKV``)
@@ -65,8 +100,9 @@ def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables):
     off = positions % bs
     if getattr(kc, "is_quantized_kv", False):
         return kc.scatter_rows(blk, off, kk), vc.scatter_rows(blk, off, vv)
-    kc = kc.at[blk, off].set(kk.astype(kc.dtype))
-    vc = vc.at[blk, off].set(vv.astype(vc.dtype))
+    t = kk.shape[0]
+    kc = kc.at[blk, off].set(kk.reshape(t, -1).astype(kc.dtype))
+    vc = vc.at[blk, off].set(vv.reshape(t, -1).astype(vc.dtype))
     return kc, vc
 
 

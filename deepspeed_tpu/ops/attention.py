@@ -120,7 +120,8 @@ def attention(
 def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
                     scale: float | None = None, impl: str = "auto"):
     """Ragged paged-KV attention: [T, Hq, D] tokens over the blocked pool
-    (reference ``inference/v2/kernels/ragged_ops`` blocked flash attention).
+    ``[blocks, BS, Hkv*D]`` (reference ``inference/v2/kernels/ragged_ops``
+    blocked flash attention).
 
     impl="pallas": stream blocks through VMEM via the block table (no padded
     gather); impl="xla": gather the padded context (short contexts, quantized
@@ -165,18 +166,17 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     if impl != "xla":
         raise ValueError(f"unknown paged attention impl {impl!r}")
     t_tokens, hq, d = q.shape
-    hkv = k_pool.shape[2]
+    hkv = k_pool.shape[-1] // d
     tables = block_tables[slots]                       # [T, MB]
-    if getattr(k_pool, "is_quantized_kv", False):
-        ctx_k = repeat_kv(k_pool.gather_dequant(tables)
-                          .reshape(t_tokens, -1, hkv, d), hq // hkv)
-        ctx_v = repeat_kv(v_pool.gather_dequant(tables)
-                          .reshape(t_tokens, -1, hkv, d), hq // hkv)
-    else:
-        ctx_k = repeat_kv(k_pool[tables].reshape(t_tokens, -1, hkv, d),
-                          hq // hkv)
-        ctx_v = repeat_kv(v_pool[tables].reshape(t_tokens, -1, hkv, d),
-                          hq // hkv)
+
+    def context(pool):
+        # heads are split on the GATHERED context (T x context), never on
+        # the pool: the pool's rows stay lane-dense [.., BS, Hkv*D]
+        ctx = (pool.gather_dequant(tables)
+               if getattr(pool, "is_quantized_kv", False) else pool[tables])
+        return repeat_kv(ctx.reshape(t_tokens, -1, hkv, d), hq // hkv)
+
+    ctx_k, ctx_v = context(k_pool), context(v_pool)
     scale = scale if scale is not None else 1.0 / jnp.sqrt(jnp.float32(d))
     k_pos = jnp.arange(ctx_k.shape[1])
     bias = jnp.where(k_pos[None, :] <= positions[:, None], 0.0, -1e30)

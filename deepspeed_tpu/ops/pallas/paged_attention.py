@@ -14,6 +14,11 @@ vectors are scalar-prefetch operands, so the KV BlockSpec index map resolves
 the DMA fetches exactly that block (the TPU paged-attention idiom). Blocks
 past the token's position are predicated off with ``pl.when``.
 
+The pool has the paged contract's storage form (``models/paged.py``):
+``[blocks, BS, Hkv*D]``, a token row one lane-dense vector of all KV heads.
+Both kernels fetch ``(1, BS, Hkv*D)`` blocks and split the heads inside VMEM
+(``_split_heads``), so the pool is never re-laid-out around them.
+
 Inference-only (no VJP): the ragged engine never differentiates through
 decode.
 """
@@ -32,8 +37,16 @@ from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
 _NEG_INF = -1e30
 
 
+def _split_heads(ref, hkv: int, d: int):
+    """One pool block ``ref[0]`` ``[BS, Hkv*D]`` as float32 ``[Hkv, BS, D]``:
+    a static lane slice per head (a reshape of the lane dimension is refused
+    by Mosaic at D = 64; the slices compile at 64 and 128)."""
+    return jnp.stack([ref[0, :, g * d:(g + 1) * d].astype(jnp.float32)
+                      for g in range(hkv)])
+
+
 def _kernel(slots_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-            acc, m_sc, l_sc, *, bs: int, rep: int, scale: float):
+            acc, m_sc, l_sc, *, bs: int, hkv: int, rep: int, scale: float):
     t = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -48,15 +61,13 @@ def _kernel(slots_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j * bs <= pos)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale          # [Hq, D]
-        k = k_ref[0].astype(jnp.float32)                  # [BS, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
         hq, d = q.shape
-        hkv = k.shape[1]
+        k = _split_heads(k_ref, hkv, d)                   # [Hkv, BS, D]
+        v = _split_heads(v_ref, hkv, d)
         qg = q.reshape(hkv, rep, d)
         # scores[g, r, k] over this block's keys
         s = jax.lax.dot_general(
-            qg, k.transpose(1, 2, 0),                     # [Hkv, D, BS]
-            (((2,), (1,)), ((0,), (0,))),                 # contract D, batch g
+            qg, k, (((2,), (2,)), ((0,), (0,))),          # contract D, batch g
         )                                                 # [Hkv, rep, BS]
         kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
         s = jnp.where(kpos <= pos, s, _NEG_INF)
@@ -70,8 +81,7 @@ def _kernel(slots_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         m_sc[:, :1] = m_new
         pg = p.reshape(hkv, rep, bs)
         pv = jax.lax.dot_general(
-            pg, v.transpose(1, 0, 2),                     # [Hkv, BS, D]
-            (((2,), (1,)), ((0,), (0,))),                 # [Hkv, rep, D]
+            pg, v, (((2,), (1,)), ((0,), (0,))),          # [Hkv, rep, D]
         ).reshape(hq, d)
         acc[:] = acc[:] * corr + pv
 
@@ -85,12 +95,13 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
                            interpret: bool | None = None):
     """[T, Hq, D] ragged tokens -> [T, Hq, D] attention outputs.
 
-    ``k_pool``/``v_pool``: [NB, BS, Hkv, D]; ``block_tables``:
+    ``k_pool``/``v_pool``: [blocks, BS, Hkv*D]; ``block_tables``:
     [max_seqs+1, MB] mapping (slot, block-ordinal) -> pool block id. Exact
     vs the dense-gather path (same position masking).
     """
     t_tokens, hq, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    _, bs, hd = k_pool.shape
+    hkv = hd // d
     mb = block_tables.shape[1]
     rep = hq // hkv
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -101,15 +112,15 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
     # bandwidth scales with the actual context, not the table width, and
     # nothing is ever read through freed/stale block_tables entries.
     def _kv_map(t, j, sl, po, bt):
-        return (bt[sl[t], jnp.minimum(j, po[t] // bs)], 0, 0, 0)
+        return (bt[sl[t], jnp.minimum(j, po[t] // bs)], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(t_tokens, mb),
         in_specs=[
             pl.BlockSpec((1, hq, d), lambda t, j, sl, po, bt: (t, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d), _kv_map),
-            pl.BlockSpec((1, bs, hkv, d), _kv_map),
+            pl.BlockSpec((1, bs, hd), _kv_map),
+            pl.BlockSpec((1, bs, hd), _kv_map),
         ],
         out_specs=pl.BlockSpec((1, hq, d), lambda t, j, sl, po, bt: (t, 0, 0)),
         scratch_shapes=[
@@ -118,7 +129,7 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
             pltpu.VMEM((hq, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, bs=bs, rep=rep, scale=scale)
+    kernel = functools.partial(_kernel, bs=bs, hkv=hkv, rep=rep, scale=scale)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
@@ -131,7 +142,8 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
 
 # --------------------------------------------------------------- tiled prefill
 def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc, m_sc, l_sc, *, bs: int, ct: int, rep: int, scale: float):
+                    acc, m_sc, l_sc, *, bs: int, ct: int, hkv: int, rep: int,
+                    scale: float):
     c = pl.program_id(0)   # query tile
     j = pl.program_id(1)   # kv block ordinal
     nj = pl.num_programs(1)
@@ -148,16 +160,14 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(jnp.logical_and(valid > 0, j * bs <= max_pos))
     def _compute():
         q = q_ref[...].astype(jnp.float32) * scale        # [CT, Hq, D]
-        k = k_ref[0].astype(jnp.float32)                  # [BS, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
-        hq, d = q.shape[1], q.shape[2]
-        hkv = k.shape[1]
+        d = q.shape[2]
+        k = _split_heads(k_ref, hkv, d)                   # [Hkv, BS, D]
+        v = _split_heads(v_ref, hkv, d)
         # GQA layout: [Hkv, CT*rep, D]; row r -> query token i = r // rep
         qg = q.reshape(ct, hkv, rep, d).transpose(1, 0, 2, 3).reshape(
             hkv, ct * rep, d)
         s = jax.lax.dot_general(
-            qg, k.transpose(1, 2, 0),                     # [Hkv, D, BS]
-            (((2,), (1,)), ((0,), (0,))),
+            qg, k, (((2,), (2,)), ((0,), (0,))),
         )                                                 # [Hkv, CT*rep, BS]
         qi = jax.lax.broadcasted_iota(jnp.int32, (1, ct * rep, 1), 1) // rep
         qpos = pos0 + qi
@@ -175,14 +185,12 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         l_sc[:, :, :1] = l_sc[:, :, :1] * corr + jnp.sum(p, -1, keepdims=True)
         m_sc[:, :, :1] = m_new
         pv = jax.lax.dot_general(
-            p, v.transpose(1, 0, 2),                      # [Hkv, BS, D]
-            (((2,), (1,)), ((0,), (0,))),
+            p, v, (((2,), (1,)), ((0,), (0,))),
         )                                                 # [Hkv, CT*rep, D]
         acc[:] = acc[:] * corr + pv
 
     @pl.when(j == nj - 1)
     def _finish():
-        hkv = acc.shape[0]
         d = acc.shape[2]
         out = acc[:] / jnp.maximum(l_sc[:, :, :1], 1e-30)
         o_ref[...] = out.reshape(hkv, ct, rep, d).transpose(1, 0, 2, 3).reshape(
@@ -230,7 +238,8 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     the KV block is fetched once per sub-tile instead of once per tile.
     """
     t_tokens, hq, d = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    _, bs, hd = k_pool.shape
+    hkv = hd // d
     mb = block_tables.shape[1]
     rep = hq // hkv
     ct = prefill_kernel_tile(tile, hq, d)
@@ -245,15 +254,15 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     # clamp past the tile's last needed block: unchanged id -> no new DMA
     def _kv_map(c, j, ts, tp, tv, bt):
         last = jnp.maximum(tp[c] + tv[c] - 1, 0) // bs
-        return (bt[ts[c], jnp.minimum(j, last)], 0, 0, 0)
+        return (bt[ts[c], jnp.minimum(j, last)], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_tiles, mb),
         in_specs=[
             pl.BlockSpec((ct, hq, d), lambda c, j, ts, tp, tv, bt: (c, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d), _kv_map),
-            pl.BlockSpec((1, bs, hkv, d), _kv_map),
+            pl.BlockSpec((1, bs, hd), _kv_map),
+            pl.BlockSpec((1, bs, hd), _kv_map),
         ],
         out_specs=pl.BlockSpec((ct, hq, d),
                                lambda c, j, ts, tp, tv, bt: (c, 0, 0)),
@@ -263,8 +272,8 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
             pltpu.VMEM((hkv, ct * rep, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_prefill_kernel, bs=bs, ct=ct, rep=rep,
-                               scale=scale)
+    kernel = functools.partial(_prefill_kernel, bs=bs, ct=ct, hkv=hkv,
+                               rep=rep, scale=scale)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),

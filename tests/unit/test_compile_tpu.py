@@ -74,7 +74,7 @@ def _args(kernel, hq, hkv, d, devices):
 
     if kernel in (_flash_fwd, _flash_bwd):
         return s((1, SEQ, hq, d)), s((1, SEQ, hkv, d)), s((1, SEQ, hkv, d))
-    pool = s((64, BLOCK, hkv, d))
+    pool = s((64, BLOCK, hkv * d))
     bt = s((9, MAX_BLOCKS), jnp.int32)
     if kernel is _decode:
         rows = s((8,), jnp.int32)
@@ -155,3 +155,126 @@ def test_prefill_tile_split_is_what_the_compiler_needs():
     assert prefill_kernel_tile(TILE, 25, 64) == TILE
     assert prefill_kernel_tile(TILE, 16, 64) == TILE
     assert prefill_kernel_tile(8, 32, 128) == 8
+
+
+# ------------------------------------------------------- the paged contract
+# A family's ragged step at its real head geometry and the serving cells'
+# pool (blocks, block size); depth, FFN and vocabulary are small, so an array
+# as large as a layer's slice of the pool can only be the pool's.
+def _step_family(name):
+    from deepspeed_tpu.models import gpt2, llama, mixtral
+
+    if name == "gpt2-xl":      # 25 x 64, benchmark/configs/gpt2-xl.json
+        return gpt2, gpt2.GPT2Config(vocab_size=512, hidden_size=1600,
+                                     num_layers=4, num_heads=25), 513, 32, 8
+    if name == "mixtral":      # 32q / 8kv x 128, mixtral-8x7b-d3.json
+        return mixtral, mixtral.MixtralConfig(
+            vocab_size=512, intermediate_size=256, num_layers=3,
+            num_experts=4), 1537, 128, 32
+    return llama, llama.LlamaConfig(           # Llama-3-8B's heads
+        vocab_size=512, intermediate_size=256, num_layers=4,
+        num_kv_heads=8), 1025, 128, 32
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "bf16": 2, "f16": 2,
+              "s32": 4, "u32": 4, "f32": 4}
+# results that are another buffer's bytes under a new name
+_HLO_ALIASES = ("parameter", "get-tuple-element", "bitcast", "tuple", "while")
+
+
+def _materialized(text):
+    """``(bytes, opcode, line)`` of every array-valued instruction of the
+    optimized HLO that gets a buffer of its own: those outside fused
+    computations. A fusion's opcode is ``scatter`` where that is what its
+    computation holds, a Pallas kernel's is ``kernel``."""
+    import re
+
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    bodies, comp = {}, None
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace() and ln.rstrip().endswith("{"):
+            comp = ln.removeprefix("ENTRY ").split(" ")[0]
+            bodies[comp] = []
+        elif comp is not None:
+            bodies[comp].append(ln)
+    out = []
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for ln in lines:
+            m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                         r"([\w\-]+)\(", ln)
+            if not m or m.group(1) not in _HLO_BYTES:
+                continue
+            size = _HLO_BYTES[m.group(1)]
+            for n in filter(None, m.group(2).split(",")):
+                size *= int(n)
+            op = m.group(3)
+            called = re.search(r"calls=(%[\w.\-]+)", ln)
+            if op == "fusion" and called and any(
+                    " scatter(" in b for b in bodies[called.group(1)]):
+                op = "scatter"
+            elif op == "custom-call" and "tpu_custom_call" in ln:
+                op = "kernel"
+            if op not in _HLO_ALIASES:
+                out.append((size, op, ln.strip()))
+    return out
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("family", ["gpt2-xl", "mixtral", "llama3-8b"])
+def test_step_program_holds_no_layer_slice_of_the_pool(v5e, monkeypatch,
+                                                       family, quantized):
+    """The paged contract (``models/paged.py``), on the compiled program: a
+    ragged step with a donated pool writes its rows by in-place scatters
+    and reads through block tables. Nothing else in it — no slice, copy or
+    re-layout — is as large as one layer's slice of the pool, and its
+    temporaries together stay under one slice. The fp step runs the tiled
+    prefill kernel (and past 2,048 tokens of table the decode kernel); a
+    ``QuantizedKV`` pool takes the XLA gather, whose float32 context is
+    rows x table wide: here four decode rows over a table of two blocks."""
+    from deepspeed_tpu.inference import kvquant
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mod, cfg, blocks, block, table = _step_family(family)
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+    rows, tiles, table = (4, 0, 2) if quantized else (8, 1, table)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        mod.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: mod.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16,
+        codec=kvquant.get_codec("int8") if quantized else None))
+    payload = jax.tree_util.tree_leaves(cache)[0]       # k (or its payload)
+    assert payload.shape == (cfg.num_layers, blocks, block,
+                             payload.shape[-1])
+    layer_slice = blocks * block * payload.shape[-1] * payload.dtype.itemsize
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return mod.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE) if tiles else None)
+
+    t = rows + tiles * TILE
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(33, table), i32(1), i32(1), i32(1)).compile()
+    big = [(size, op, ln) for size, op, ln in _materialized(compiled.as_text())
+           if size >= layer_slice]
+    scatters = [ln for _, op, ln in big if op == "scatter"]
+    # k and v (and their scales' arrays are smaller than a payload slice)
+    assert len(scatters) == 2, scatters
+    assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
+    if not quantized:
+        assert compiled.as_text().count("tpu_custom_call") >= (
+            2 if table * block > 2048 else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice

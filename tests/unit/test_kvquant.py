@@ -25,7 +25,6 @@ from deepspeed_tpu.inference import kvquant
 from deepspeed_tpu.inference.kvquant import (
     DRIFT_BUDGET,
     QuantizedKV,
-    build_quantized_paged_cache,
     drift_verdict,
     get_codec,
     paged_block_bytes,
@@ -40,6 +39,7 @@ from deepspeed_tpu.inference.ragged import (
     RaggedInferenceEngine,
 )
 from deepspeed_tpu.models import llama
+from deepspeed_tpu.models.paged import init_paged_pool
 
 CFG = llama.LlamaConfig(
     vocab_size=97, hidden_size=32, intermediate_size=64,
@@ -142,26 +142,26 @@ class TestParseQuant:
 
 
 # ------------------------------------------------------------------ the pytree
-def _init_fn(nb, bs, dtype, heads=2, dim=64, layers=2):
-    return {"k": jnp.zeros((layers, nb, bs, heads, dim), dtype),
-            "v": jnp.zeros((layers, nb, bs, heads, dim), dtype)}
+def _init_fn(nb, bs, dtype, codec=None, heads=2, dim=64, layers=2):
+    """A family's ``init_paged_cache_fn``: the contract's storage form,
+    ``[L, nb, bs, H*D]`` (``models/paged.py``)."""
+    return init_paged_pool(layers, nb, bs, heads, dim, dtype, codec)
 
 
 class TestQuantizedKV:
     def test_pool_built_at_storage_precision(self):
-        pool = build_quantized_paged_cache(_init_fn, 8, BS, jnp.float16,
-                                           get_codec("int8"))
+        pool = _init_fn(8, BS, jnp.float16, get_codec("int8"))
         k = pool["k"]
         assert k.q.dtype == jnp.int8 and k.s.dtype == jnp.float16
-        assert k.shape == (2, 8, BS, 2, 64)      # payload shape
+        assert k.shape == (2, 8, BS, 2 * 64)     # payload shape
         assert k.dtype == np.dtype("float16")    # COMPUTE dtype
-        assert k.s.shape == k.q.shape[:-1]
+        # one scale per (row, head), one lane-dense row a block
+        assert k.s.shape == (2, 8, BS * 2)
 
     def test_resident_multiplier_vs_fp16_clears_floor(self):
         # at head_dim 64: int8 payload + f16 per-row-per-head scale is
         # 1 + 2/64 bytes/elem vs 2 -> ~1.94x, over the 1.8x acceptance floor
-        pool = build_quantized_paged_cache(_init_fn, 8, BS, jnp.float16,
-                                           get_codec("int8"))
+        pool = _init_fn(8, BS, jnp.float16, get_codec("int8"))
         q_bytes = sum(leaf.nbytes for leaf in pool.values())
         fp16_bytes = sum(
             a.nbytes for a in jax.tree_util.tree_leaves(
@@ -169,41 +169,44 @@ class TestQuantizedKV:
         assert fp16_bytes / q_bytes >= 1.8
 
     def test_paged_block_bytes(self):
-        # [L=2, nb, bs=4, H=2, D=64] fp16 k+v: 2*4*2*64*2 bytes * 2 leaves
+        # [L=2, nb, bs=4, H*D=2*64] fp16 k+v: 2*4*2*64*2 bytes * 2 leaves
         assert paged_block_bytes(_init_fn, 8, BS, jnp.float16) == \
             2 * (2 * BS * 2 * 64 * 2)
 
     def test_tree_map_and_scan_slicing_preserve_wrapper(self):
-        pool = build_quantized_paged_cache(_init_fn, 4, BS, jnp.float32,
-                                           get_codec("fp8"))
+        pool = _init_fn(4, BS, jnp.float32, get_codec("fp8"))
         sliced = jax.tree_util.tree_map(lambda a: a[:, :2], pool)
         assert isinstance(sliced["k"], QuantizedKV)
         assert sliced["k"].codec == "fp8"
         assert sliced["k"].shape[1] == 2 and sliced["k"].s.shape[1] == 2
 
     def test_pickle_roundtrip(self):
-        pool = build_quantized_paged_cache(_init_fn, 4, BS, jnp.float32,
-                                           get_codec("int8"))
+        pool = _init_fn(4, BS, jnp.float32, get_codec("int8"))
         back = pickle.loads(pickle.dumps(pool["k"]))
         assert back.codec == "int8" and back.is_quantized_kv
         assert np.asarray(back.q).shape == pool["k"].q.shape
         assert back.nbytes == pool["k"].nbytes
 
     def test_scatter_then_gather_roundtrip(self):
-        full = build_quantized_paged_cache(_init_fn, 4, BS, jnp.float32,
-                                           get_codec("int8"))["k"]
-        # per-layer slice the way lax.scan sees it: through the pytree
-        pool = jax.tree_util.tree_map(lambda a: a[0], full)
+        full = _init_fn(4, BS, jnp.float32, get_codec("int8"))["k"]
+        # the pool the way a layer sees it: L and nb merged, through the
+        # pytree; layer 1's blocks start at 4
+        pool = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), full)
         rng = np.random.default_rng(2)
         rows = jnp.asarray(rng.normal(size=(3, 2, 64)).astype(np.float32))
-        blk = jnp.asarray([1, 1, 2]); off = jnp.asarray([0, 1, 3])
+        blk = jnp.asarray([5, 5, 6]); off = jnp.asarray([0, 1, 3])
         pool = pool.scatter_rows(blk, off, rows)
-        got = pool.gather_dequant(jnp.asarray([[1, 2]]))  # [1, 2, bs, H, D]
+        got = pool.gather_dequant(jnp.asarray([[5, 6]]))  # [1, 2, bs, H, D]
+        assert got.shape == (1, 2, BS, 2, 64)
         amax = float(jnp.max(jnp.abs(rows)))
         np.testing.assert_allclose(np.asarray(got[0, 0, 0]),
                                    np.asarray(rows[0]), atol=0.02 * amax)
         np.testing.assert_allclose(np.asarray(got[0, 1, 3]),
                                    np.asarray(rows[2]), atol=0.02 * amax)
+        # rows nobody wrote stay zero: scales landed in their own cells
+        assert not np.asarray(got[0, 0, 2:]).any()
+        assert not np.asarray(got[0, 1, :3]).any()
 
 
 # --------------------------------------------------------- drift-gated parity

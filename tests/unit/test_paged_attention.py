@@ -12,8 +12,8 @@ from deepspeed_tpu.ops.attention import paged_attention
 def _setup(seed=0, T=6, Hq=4, Hkv=2, D=16, NB=16, BS=8, MB=4):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(T, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, D)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32))
     bt = np.zeros((3, MB), np.int32)
     bt[0] = [3, 5, 7, 11]
     bt[1] = [2, 9, 1, 0]
@@ -59,8 +59,8 @@ def test_tiled_prefill_kernel_matches_xla(CT, MB, split, monkeypatch):
     # 4 tiles: seq0 chunk of 2*CT-2 tokens from position 5 (tiles 0-1), seq1
     # chunk of 6 tokens (tile 2, pos 0..5), tile 3 all-pad
     q = jnp.asarray(rng.normal(size=(4 * CT, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv, D)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32))
     bt = np.zeros((3, MB), np.int32)
     bt[0] = rng.permutation(np.arange(1, MB + 1))
     bt[1] = rng.permutation(np.arange(MB + 1, 2 * MB + 1))

@@ -11,16 +11,18 @@ import pytest
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
-from deepspeed_tpu.models import gpt2, mixtral
+from deepspeed_tpu.models import gpt2, llama, mixtral
 
 MIX = mixtral.MixtralConfig.tiny(89)
 GPT = gpt2.GPT2Config.tiny(89)
+LLA = llama.LlamaConfig.tiny(89)
+FAMILIES = {"gpt2": (gpt2, GPT), "mixtral": (mixtral, MIX),
+            "llama": (llama, LLA)}
 
 
 def _build(name):
-    if name == "mixtral":
-        return lambda ctx: mixtral.build(MIX, ctx=ctx)
-    return lambda ctx: gpt2.build(GPT, ctx=ctx)
+    mod, cfg = FAMILIES[name]
+    return lambda ctx: mod.build(cfg, ctx=ctx)
 
 
 def _prompts(n=4, seed=3):
@@ -38,13 +40,13 @@ def _dense_reference(name, prompts, max_new):
     return out
 
 
-def _ragged(name, fused=0, tile=0):
+def _ragged(name, fused=0, tile=0, quant="off"):
     return RaggedInferenceEngine(
         model=_build(name), dtype=jnp.float32, seed=0,
         ragged_config=RaggedConfig(
             max_tokens_per_step=16, max_seqs=3, block_size=4,
             num_blocks=49, max_blocks_per_seq=16,
-            fused_chunk=fused, prefill_tile=tile))
+            fused_chunk=fused, prefill_tile=tile, quant=quant))
 
 
 @pytest.mark.parametrize("name", ["mixtral", "gpt2"])
@@ -95,3 +97,199 @@ def test_mixtral_decode_routing_is_per_token():
     for uid, p in prompts.items():
         together.put(uid, p, max_new_tokens=6)
     assert together.generate_all() == solo
+
+
+# ------------------------------------------------------- the paged contract
+# A layer addresses the pool (``[L, NB, BS, Hkv*D]``, L and NB merged inside
+# the step) through its own block table and owns no slice of it
+# (``models/paged.py``). The reference below knows nothing of pools, tables
+# or tiles: the family's dense ``decode_forward``, one sequence at a time.
+from deepspeed_tpu.inference import kvquant  # noqa: E402
+
+NB, BS, TILE, PAD_ROW = 12, 4, 4, 3
+TABLES = np.zeros((PAD_ROW + 1, 3), np.int32)   # the last row: all scratch
+TABLES[0] = [3, 7, 1]
+TABLES[1] = [5, 2, 9]
+TABLES[2] = [11, 4, 6]
+
+
+def _params(mod, cfg):
+    import jax
+
+    return mod.init_params(cfg, jax.random.PRNGKey(5))
+
+
+def _dense(mod, cfg, params, seq):
+    """Logits and per-layer K/V ``[L, len, Hkv, D]`` of one sequence from
+    the dense path."""
+    cache = mod.init_cache(cfg, 1, 16, jnp.float32)
+    logits, cache = mod.decode_forward(
+        cfg, params, jnp.asarray(seq, jnp.int32)[None], cache, 0)
+    n = len(seq)
+    return (np.asarray(logits[0]), np.asarray(cache["k"][:, 0, :n]),
+            np.asarray(cache["v"][:, 0, :n]))
+
+
+def _layout(chunks, n_dec, tiled):
+    """The step's flat token rows for ``chunks`` = [(slot, pos0, tokens)]:
+    the first ``n_dec`` chunks are single decode rows; with ``tiled`` every
+    later chunk starts a new TILE-aligned run of tiles, rows past its end
+    are padding (pad slot, position 0)."""
+    toks, slots, pos, rows = [], [], [], []
+    ts, tp, tv = [], [], []
+    for i, (slot, p0, tokens) in enumerate(chunks):
+        rows.append([len(toks) + j for j in range(len(tokens))])
+        toks += tokens
+        slots += [slot] * len(tokens)
+        pos += list(range(p0, p0 + len(tokens)))
+        if tiled and i >= n_dec:
+            for off in range(0, len(tokens), TILE):
+                ts.append(slot)
+                tp.append(p0 + off)
+                tv.append(min(TILE, len(tokens) - off))
+            pad = -len(tokens) % TILE
+            toks += [0] * pad
+            slots += [PAD_ROW] * pad
+            pos += [0] * pad
+    tiles = None
+    if tiled:
+        tiles = (n_dec, jnp.asarray(ts, jnp.int32), jnp.asarray(tp, jnp.int32),
+                 jnp.asarray(tv, jnp.int32), TILE)
+    toks, slots, pos = (jnp.asarray(a, jnp.int32) for a in (toks, slots, pos))
+    return toks, slots, pos, tiles, rows
+
+
+def _pool_rows(pool, layer, slot, n, heads):
+    """Positions ``0..n-1`` of ``slot`` read back from layer ``layer`` of the
+    pool through the block table, as float ``[n, Hkv, D]``."""
+    p = np.arange(n)
+    blk, off = TABLES[slot, p // BS], p % BS
+    if getattr(pool, "is_quantized_kv", False):
+        q = np.asarray(pool.q)[layer, blk, off].astype(np.float32)
+        s = np.asarray(pool.s)[layer, blk].reshape(n, BS, heads)[p, off]
+        return q.reshape(n, heads, -1) * s.astype(np.float32)[..., None]
+    return np.asarray(pool)[layer, blk, off].reshape(n, heads, -1)
+
+
+@pytest.mark.parametrize("tiled", [False, True], ids=["flat", "tiles"])
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_paged_addressing_matches_the_dense_reference(name, quant, tiled):
+    """A prefill step of two sequences, then a mixed step (their next
+    tokens beside a third sequence's prompt): logits and every pool row the
+    tables name equal the dense path's, on an fp pool exactly (float32) and
+    on an int8 pool within its rounding; no other block is touched."""
+    import jax
+
+    mod, cfg = FAMILIES[name]
+    params = _params(mod, cfg)
+    rng = np.random.default_rng(17)
+    seqs = [list(rng.integers(1, 89, n)) for n in (8, 6, 3)]
+    codec = kvquant.get_codec(quant) if quant else None
+    cache = mod.init_paged_cache(cfg, NB, BS, jnp.float32, codec=codec)
+    heads = getattr(cfg, "num_kv_heads", cfg.num_heads)
+    steps = [
+        ([(0, 0, seqs[0][:7]), (1, 0, seqs[1][:5])], 0),
+        ([(0, 7, seqs[0][7:]), (1, 5, seqs[1][5:]), (2, 0, seqs[2])], 2),
+    ]
+    got = {}
+    for chunks, n_dec in steps:
+        toks, slots, pos, tiles, rows = _layout(chunks, n_dec, tiled)
+        logits, cache = mod.ragged_forward(
+            cfg, params, toks, slots, pos, jnp.asarray(TABLES), cache,
+            prefill_tiles=tiles)
+        for (slot, p0, tokens), r in zip(chunks, rows):
+            for j, row in enumerate(r):
+                got[slot, p0 + j] = np.asarray(logits[row])
+    tol = dict(rtol=2e-4, atol=2e-4) if codec is None else dict(
+        rtol=0.1, atol=0.1)
+    for slot, seq in enumerate(seqs):
+        want, k, v = _dense(mod, cfg, params, seq)
+        for p in range(len(seq)):
+            np.testing.assert_allclose(got[slot, p], want[p], **tol,
+                                       err_msg=f"logits {slot}:{p}")
+        for layer in range(cfg.num_layers):
+            for pool, dense in ((cache["k"], k), (cache["v"], v)):
+                rows = _pool_rows(pool, layer, slot, len(seq), heads)
+                amax = np.abs(dense[layer]).max()
+                np.testing.assert_allclose(
+                    rows, dense[layer],
+                    atol=1e-4 if codec is None else 0.02 * amax,
+                    rtol=1e-4 if codec is None else 0.05,
+                    err_msg=f"layer {layer} slot {slot}")
+    # blocks no table names (8, 10) hold what they were built with
+    for leaf in jax.tree_util.tree_leaves(cache):
+        assert not np.asarray(leaf)[:, [8, 10]].any()
+
+
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+def test_pallas_kernels_read_through_a_layers_own_table(name, monkeypatch):
+    """The same mixed step with both Pallas kernels (interpret mode) where
+    the chip would run them: they index the merged block axis through the
+    layer's table like the XLA gather."""
+    from deepspeed_tpu.ops import attention
+
+    mod, cfg = FAMILIES[name]
+    params = _params(mod, cfg)
+    rng = np.random.default_rng(3)
+    chunks = [(0, 0, list(rng.integers(1, 89, 7))),
+              (1, 0, list(rng.integers(1, 89, 5)))]
+    mixed = [(0, 7, [11]), (1, 5, [13]), (2, 0, list(rng.integers(1, 89, 6)))]
+
+    def run():
+        cache = mod.init_paged_cache(cfg, NB, BS, jnp.float32)
+        out = []
+        for step, n_dec in ((chunks, 0), (mixed, 2)):
+            toks, slots, pos, tiles, rows = _layout(step, n_dec, True)
+            logits, cache = mod.ragged_forward(
+                cfg, params, toks, slots, pos, jnp.asarray(TABLES), cache,
+                prefill_tiles=tiles)
+            out.append(np.asarray(logits)[np.concatenate(rows)])
+        return np.concatenate(out), cache
+
+    want, want_cache = run()
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setenv("DSTPU_PAGED_IMPL", "pallas")
+    got, got_cache = run()
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    # but for block 0, every layer's scratch block: padding rows write there
+    # what the two paths leave unspecified
+    for a, b in zip(got_cache.values(), want_cache.values()):
+        np.testing.assert_allclose(np.asarray(a)[:, 1:], np.asarray(b)[:, 1:],
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("quant", ["off", "int8"])
+@pytest.mark.parametrize("name", sorted(FAMILIES))
+class TestBlockPayloadsOnTheStorageForm:
+    """Host-side code indexes blocks as ``a[:, ids]`` on ``[L, NB, ...]``;
+    payloads have the pool's form on both ends."""
+
+    def test_gather_scatter_round_trip(self, name, quant):
+        import jax
+
+        eng = _ragged(name, quant=quant)
+        eng.put("a", list(range(1, 12)), max_new_tokens=2)
+        eng.generate_all()
+        src, dst = [1, 2, 3], [9, 10, 11]
+        payload = eng._gather_blocks(src)
+        pool = jax.tree_util.tree_leaves(eng.cache)
+        for got, leaf in zip(jax.tree_util.tree_leaves(payload), pool):
+            assert got.shape == (leaf.shape[0], 3) + leaf.shape[2:]
+            assert got.any()          # the prompt's KV, not zeros
+        eng._scatter_blocks(dst, payload)
+        for leaf in jax.tree_util.tree_leaves(eng.cache):
+            a = np.asarray(leaf)
+            np.testing.assert_array_equal(a[:, dst], a[:, src])
+
+    def test_handoff_export_import(self, name, quant):
+        prompt = list(range(3, 14))
+        want = _ragged(name, quant=quant)
+        want.put("h", prompt, max_new_tokens=6)
+        a, b = _ragged(name, quant=quant), _ragged(name, quant=quant)
+        a.put("h", prompt, max_new_tokens=6, handoff=True)
+        a.generate_all()
+        record = a.export_handoff("h")
+        assert record.n_blocks == 3 and record.codec == quant
+        assert b.import_handoff(record)
+        assert b.generate_all()["h"] == want.generate_all()["h"]
