@@ -1,0 +1,425 @@
+"""DeepSeek-V3-family causal LM as Moonlight-16B-A3B configures it
+(``model_type: deepseek_v3``): multi-head latent attention (MLA), leading
+dense SwiGLU layers, then layers of sigmoid-routed experts beside shared ones.
+
+Layer equations (pre-norm RMSNorm, a residual after each half):
+
+- **MLA.** ``q = x W_q -> [T, H, nope + rope]``, RoPE on the ``rope`` lanes.
+  ``a = x W_kva -> [T, lat + rope]``; ``c = RMSNorm(a[:, :lat])``; ``k_rope =
+  RoPE(a[:, lat:])``, one head shared by all ``H``. ``kv = c W_kvb -> [T, H,
+  nope + v]``, ``k = [kv[..., :nope], k_rope]``, ``v = kv[..., nope:]``;
+  causal softmax of ``q . k * (nope + rope)^-0.5``. ``forward`` (training,
+  evaluation) computes exactly that on ``xla_attention``.
+- **Serving caches the row ``[c, k_rope]``** (``lat + rope`` lanes a token and
+  layer, 1,152 B in bf16 at Moonlight's 512 + 64, against 8,192 B for 16 K
+  and V heads of 128) and decodes **absorbed**: ``q_lat[h] = q_nope[h]
+  W_kvb_k[h]^T``, score ``= (q_lat . c + q_rope . k_rope) * scale``, ``o_lat =
+  P c``, ``o[h] = o_lat[h] W_kvb_v[h]``: attention over the cached rows
+  themselves (``models/paged.latent_pool_attention``), prefill chunks too.
+- **Router.** ``s = sigmoid(x_f32 W_r)``; the ``top_k`` largest of ``s +
+  e_score_correction_bias`` are picked; the weights are ``s`` there (without
+  the bias), divided by their sum, times ``routed_scaling_factor``. ``y =
+  sum_i w_i SwiGLU_i(x) + SwiGLU_shared(x)``; the routed sum goes through
+  ``models/experts.routed_experts``, the function Mixtral serves through.
+
+One departure from the published code: it rotates interleaved lane pairs
+after a permutation of the projections' columns; ``ops/attention.apply_rope``
+rotates half-split lanes, which with seeded weights is the same model up to
+that permutation. ``q_lora_rank`` (a low-rank query projection), ``n_group >
+1`` (group-limited routing) and a quantized latent pool are not implemented
+and raise.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deepspeed_tpu.models.api import ModelSpec, ShardCtx, causal_lm_loss
+from deepspeed_tpu.models.experts import routed_experts, swiglu
+from deepspeed_tpu.models.llama import rmsnorm
+from deepspeed_tpu.ops.attention import apply_rope, xla_attention
+
+
+@dataclass(frozen=True)
+class DeepseekConfig:
+    vocab_size: int = 163840
+    hidden_size: int = 2048
+    intermediate_size: int = 11264        # the dense layers' FFN
+    moe_intermediate_size: int = 1408     # one expert's FFN
+    num_layers: int = 27
+    num_heads: int = 16
+    kv_lora_rank: int = 512
+    q_lora_rank: int | None = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    num_experts: int = 64
+    num_shared_experts: int = 2
+    top_k: int = 6
+    first_k_dense: int = 1
+    scoring_func: str = "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.446
+    n_group: int = 1
+    rope_theta: float = 50000.0
+    rms_norm_eps: float = 1e-5
+    max_seq_len: int = 8192
+
+    def __post_init__(self):
+        if self.q_lora_rank is not None:
+            raise NotImplementedError(
+                "deepseek: q_lora_rank (a low-rank query projection) is not "
+                "implemented; Moonlight-16B-A3B has none")
+        if self.n_group != 1:
+            raise NotImplementedError(
+                "deepseek: group-limited routing (n_group > 1) is not "
+                "implemented; Moonlight-16B-A3B routes over one group")
+        if not 0 <= self.first_k_dense < self.num_layers:
+            raise ValueError("deepseek: first_k_dense must leave at least "
+                             "one expert layer")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_lanes(self) -> int:
+        """Lanes of a cached row: the latent, the shared roped key, and
+        zeros up to whole 128-lane tiles (640 for 512 + 64; why:
+        ``ops/pallas/mla_attention.py``)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @staticmethod
+    def tiny(vocab_size: int = 256) -> "DeepseekConfig":
+        return DeepseekConfig(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=48, num_layers=3, num_heads=2,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16,
+            v_head_dim=16, num_experts=8, num_shared_experts=1, top_k=3,
+            first_k_dense=1, max_seq_len=128)
+
+
+def init_params(cfg: DeepseekConfig, rng) -> dict:
+    d, h = cfg.hidden_size, cfg.num_heads
+    e, fm = cfg.num_experts, cfg.moe_intermediate_size
+    fs = cfg.num_shared_experts * fm
+    k = iter(jax.random.split(rng, 24))
+    std = 0.02
+    out_std = std / jnp.sqrt(2.0 * cfg.num_layers)
+
+    def norm(key, *shape, s=std):
+        return jax.random.normal(key, shape, jnp.float32) * s
+
+    def attention(n):
+        return {
+            "attn_norm": jnp.ones((n, d), jnp.float32),
+            "wq": norm(next(k), n, d, h * cfg.qk_head_dim),
+            "wkv_a": norm(next(k), n, d,
+                          cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            "kv_norm": jnp.ones((n, cfg.kv_lora_rank), jnp.float32),
+            "wkv_b": norm(next(k), n, cfg.kv_lora_rank,
+                          h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "wo": norm(next(k), n, h * cfg.v_head_dim, d, s=out_std),
+            "mlp_norm": jnp.ones((n, d), jnp.float32),
+        }
+
+    nd, nm = cfg.first_k_dense, cfg.num_moe_layers
+    return {
+        "embed": norm(next(k), cfg.vocab_size, d),
+        "dense": {
+            **attention(nd),
+            "w_gate": norm(next(k), nd, d, cfg.intermediate_size),
+            "w_up": norm(next(k), nd, d, cfg.intermediate_size),
+            "w_down": norm(next(k), nd, cfg.intermediate_size, d, s=out_std),
+        },
+        "layers": {
+            **attention(nm),
+            "router": norm(next(k), nm, d, e),
+            # drawn small and non-zero, so that selection (with the bias)
+            # and weighting (without it) differ
+            "router_bias": norm(next(k), nm, e, s=0.01),
+            "w_gate": norm(next(k), nm, e, d, fm),
+            "w_up": norm(next(k), nm, e, d, fm),
+            "w_down": norm(next(k), nm, e, fm, d, s=out_std),
+            "ws_gate": norm(next(k), nm, d, fs),
+            "ws_up": norm(next(k), nm, d, fs),
+            "ws_down": norm(next(k), nm, fs, d, s=out_std),
+        },
+        "final_norm": jnp.ones((d,), jnp.float32),
+        "lm_head": norm(next(k), d, cfg.vocab_size),
+    }
+
+
+_ATTENTION_AXES = {
+    "attn_norm": ("layers", "embed"),
+    "wq": ("layers", "embed", "heads"),
+    "wkv_a": ("layers", "embed", None),
+    "kv_norm": ("layers", None),
+    "wkv_b": ("layers", None, "heads"),
+    "wo": ("layers", "heads", "embed"),
+    "mlp_norm": ("layers", "embed"),
+}
+PARAM_LOGICAL_AXES = {
+    "embed": ("vocab", "embed"),
+    "dense": {
+        **_ATTENTION_AXES,
+        "w_gate": ("layers", "embed", "ffn"),
+        "w_up": ("layers", "embed", "ffn"),
+        "w_down": ("layers", "ffn", "embed"),
+    },
+    "layers": {
+        **_ATTENTION_AXES,
+        "router": ("layers", "embed", None),
+        "router_bias": ("layers", None),
+        "w_gate": ("layers", "experts", "embed", "ffn"),
+        "w_up": ("layers", "experts", "embed", "ffn"),
+        "w_down": ("layers", "experts", "ffn", "embed"),
+        "ws_gate": ("layers", "embed", "ffn"),
+        "ws_up": ("layers", "embed", "ffn"),
+        "ws_down": ("layers", "ffn", "embed"),
+    },
+    "final_norm": ("embed",),
+    "lm_head": ("embed", "vocab"),
+}
+
+
+def _ffn(cfg: DeepseekConfig, h, lp):
+    """The FFN half of a layer on flat tokens ``h`` [T, D]: a dense layer's
+    SwiGLU, or the routed experts plus the shared ones."""
+    if "router" not in lp:
+        return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+    routed = routed_experts(
+        h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], cfg.top_k,
+        scoring=cfg.scoring_func, bias=lp["router_bias"],
+        renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+        eps=1e-20)
+    return routed + swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+
+
+def _mla_inputs(cfg: DeepseekConfig, h, lp, positions):
+    """``h`` [T, D] (normed) -> ``q_nope`` [T, H, nope], roped ``q_rope``
+    [T, H, rope], the normed latent ``c`` [T, lat], roped ``k_rope``
+    [T, rope]."""
+    t = h.shape[0]
+    lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = (h @ lp["wq"]).reshape(t, cfg.num_heads, cfg.qk_head_dim)
+    a = h @ lp["wkv_a"]
+    c = rmsnorm(a[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
+    q_rope, k_rope = apply_rope(q[None, ..., nope:], a[None, :, None, lat:],
+                                positions[None], cfg.rope_theta)
+    return q[..., :nope], q_rope[0], c, k_rope[0, :, 0]
+
+
+def _wkv_b(cfg: DeepseekConfig, lp):
+    """``kv_b_proj`` as ``[lat, H, nope + v]``: the key half and the value
+    half of every head."""
+    w = lp["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads,
+                            cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions):
+    """One layer of the plain forward pass, ``x`` [B, S, D]: MLA as
+    published (per-head keys and values from the latent), not absorbed."""
+    lp = ctx.layer_weights(lp, x.dtype)
+    b, s, d = x.shape
+    heads = cfg.num_heads
+
+    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps).reshape(b * s, d)
+    q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions.reshape(-1))
+    wk, wv = _wkv_b(cfg, lp)
+    k_nope = jnp.einsum("tl,lhn->thn", c, wk)
+    v = jnp.einsum("tl,lhv->thv", c, wv)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, None], (b * s, heads,
+                                                     cfg.qk_rope_head_dim))],
+        axis=-1)
+    o = xla_attention(q.reshape(b, s, heads, -1), k.reshape(b, s, heads, -1),
+                      v.reshape(b, s, heads, -1), causal=True,
+                      scale=cfg.softmax_scale)
+    x = x + o.reshape(b, s, heads * cfg.v_head_dim) @ lp["wo"]
+
+    h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    x = x + _ffn(cfg, h.reshape(b * s, d), lp).reshape(b, s, d)
+    return ctx.constrain(x, "batch", "seq", "embed_act")
+
+
+def _dense_layers(cfg: DeepseekConfig, params) -> list:
+    """The leading dense layers' weights, one tree a layer."""
+    return [jax.tree_util.tree_map(lambda a: a[i], params["dense"])
+            for i in range(cfg.first_k_dense)]
+
+
+def _lm_head(params, x):
+    from deepspeed_tpu.ops.quantizer import maybe_dequantize
+
+    return x @ maybe_dequantize(params["lm_head"], x.dtype).astype(x.dtype)
+
+
+def forward(cfg: DeepseekConfig, params, input_ids, ctx: ShardCtx | None = None,
+            remat: bool = False, remat_policy=None):
+    """``[B, S]`` token ids -> ``[B, S, V]`` logits, dropless (every expert
+    computes every token; the router's weights combine)."""
+    ctx = ctx or ShardCtx()
+    b, s = input_ids.shape
+    x = ctx.embed_lookup(params["embed"], input_ids, "batch", "seq", "embed_act")
+    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    layer = partial(_layer, cfg, ctx)
+    if remat:
+        layer = jax.checkpoint(layer, policy=remat_policy)
+    for lp in _dense_layers(cfg, params):
+        x = layer(x, lp, positions)
+    x, _ = lax.scan(lambda x, lp: (layer(x, lp, positions), None), x,
+                    params["layers"])
+    x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
+    return ctx.constrain(_lm_head(params, x), "batch", "seq", "vocab_act")
+
+
+# ------------------------------------------------------------------ inference
+def init_paged_cache(cfg: DeepseekConfig, num_blocks: int, block_size: int,
+                     dtype=jnp.bfloat16, codec=None) -> dict:
+    """The latent pool of the ragged engine, ``{"kv": [L, num_blocks,
+    block_size, row_lanes]}``: ONE row a token and layer, ``[c, k_rope,
+    zeros]``, in the paged contract's storage form (``models/paged.py``);
+    ``L`` counts the dense layers too."""
+    if codec is not None:
+        raise NotImplementedError(
+            "deepseek: a quantized latent pool is not implemented (a row's "
+            "latent and its roped key want scales of their own)")
+    return {"kv": jnp.zeros((cfg.num_layers, num_blocks, block_size,
+                             cfg.row_lanes), dtype)}
+
+
+def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
+                  block_tables, prefill_tiles=None):
+    """One layer over a flat ragged token batch [T, D]: the step's rows
+    ``[c, k_rope, zeros]`` are scattered into the latent pool, then absorbed
+    attention reads the cached rows through the layer's block table."""
+    from deepspeed_tpu.models.paged import (
+        latent_pool_attention,
+        write_rows_paged,
+    )
+    from deepspeed_tpu.ops.quantizer import dequantize_layer
+
+    lp = dequantize_layer(lp, x.dtype)
+    t_tokens = x.shape[0]
+
+    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions)
+    pad = cfg.row_lanes - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+    kv = write_rows_paged(
+        pool["kv"],
+        jnp.concatenate([c, k_rope, jnp.zeros((t_tokens, pad), c.dtype)], -1),
+        slots, positions, block_tables)
+    wk, wv = _wkv_b(cfg, lp)
+    q = jnp.concatenate(
+        [jnp.einsum("thn,lhn->thl", q_nope, wk), q_rope,
+         jnp.zeros((t_tokens, cfg.num_heads, pad), q_rope.dtype)], axis=-1)
+    o_lat = latent_pool_attention(
+        q, kv, slots, positions, block_tables, cfg.kv_lora_rank,
+        cfg.softmax_scale, prefill_tiles).astype(x.dtype)
+    o = jnp.einsum("thl,lhv->thv", o_lat, wv)
+    x = x + o.reshape(t_tokens, -1) @ lp["wo"]
+
+    h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    return x + _ffn(cfg, h, lp), {"kv": kv}
+
+
+def ragged_forward(cfg: DeepseekConfig, params, tokens, slots, positions,
+                   block_tables, cache, prefill_tiles=None):
+    """Flat ragged step: [T] mixed tokens -> ([T, V] logits, cache). The
+    stack is not homogeneous: the dense layers run before the scan at layers
+    ``0 .. first_k_dense - 1`` of the pool, the expert layers scan after
+    them (``models/paged.scan_layers_paged``)."""
+    from deepspeed_tpu.models.paged import scan_layers_paged
+
+    def layer(x, lp, pool, layer_tables):
+        return _ragged_layer(cfg, x, lp, pool, positions, slots, layer_tables,
+                             prefill_tiles=prefill_tiles)
+
+    x = params["embed"][tokens].astype(cache["kv"].dtype)
+    x, cache = scan_layers_paged(
+        layer, x, params["layers"], cache, block_tables,
+        lead=[(layer, lp) for lp in _dense_layers(cfg, params)])
+    x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
+    return _lm_head(params, x), cache
+
+
+def _attention_params(cfg: DeepseekConfig) -> int:
+    d, h = cfg.hidden_size, cfg.num_heads
+    return (d * h * cfg.qk_head_dim
+            + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) + cfg.kv_lora_rank
+            + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + h * cfg.v_head_dim * d + 2 * d)
+
+
+def _dense_layer_params(cfg: DeepseekConfig) -> int:
+    return _attention_params(cfg) + 3 * cfg.hidden_size * cfg.intermediate_size
+
+
+def _moe_layer_params(cfg: DeepseekConfig, experts: int) -> int:
+    d, fm = cfg.hidden_size, cfg.moe_intermediate_size
+    return (_attention_params(cfg) + d * cfg.num_experts + cfg.num_experts
+            + 3 * d * fm * (experts + cfg.num_shared_experts))
+
+
+def num_params(cfg: DeepseekConfig) -> int:
+    d = cfg.hidden_size
+    return (2 * cfg.vocab_size * d + d
+            + cfg.first_k_dense * _dense_layer_params(cfg)
+            + cfg.num_moe_layers * _moe_layer_params(cfg, cfg.num_experts))
+
+
+def flops_per_token(cfg: DeepseekConfig, seq_len: int) -> float:
+    """Active-parameter training FLOPs: ``top_k`` of the routed experts, the
+    shared ones, attention over ``seq_len`` (scores at ``nope + rope``,
+    values at ``v`` lanes a head)."""
+    active = (cfg.vocab_size * cfg.hidden_size
+              + cfg.first_k_dense * _dense_layer_params(cfg)
+              + cfg.num_moe_layers * _moe_layer_params(cfg, cfg.top_k))
+    attn = (6.0 * cfg.num_layers * cfg.num_heads
+            * (cfg.qk_head_dim + cfg.v_head_dim) * seq_len / 2.0)
+    return 6.0 * active + attn
+
+
+def build(cfg: DeepseekConfig, ctx: ShardCtx | None = None,
+          remat: bool | None = None, remat_policy=None) -> ModelSpec:
+    ctx = ctx or ShardCtx()
+    remat = ctx.remat if remat is None else remat
+    remat_policy = remat_policy if remat_policy is not None else ctx.remat_policy
+    fwd = partial(forward, cfg, ctx=ctx, remat=remat, remat_policy=remat_policy)
+
+    def loss_fn(params, batch, rng=None):
+        del rng  # dropless routing draws nothing
+        return causal_lm_loss(fwd(params, batch["input_ids"]),
+                              batch["input_ids"], batch.get("labels"))
+
+    return ModelSpec(
+        name="deepseek",
+        config=cfg,
+        init_fn=partial(init_params, cfg),
+        loss_fn=loss_fn,
+        forward_fn=fwd,
+        param_logical_axes=PARAM_LOGICAL_AXES,
+        logical_dim_units={"heads": cfg.num_heads,
+                           "experts": cfg.num_experts},
+        num_params=num_params(cfg),
+        flops_per_token=partial(flops_per_token, cfg),
+        init_paged_cache_fn=partial(init_paged_cache, cfg),
+        ragged_forward_fn=partial(ragged_forward, cfg),
+        supports_prefill_tiles=True,
+    )

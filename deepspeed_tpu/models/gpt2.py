@@ -234,16 +234,20 @@ def ragged_forward(cfg: GPT2Config, params, tokens, slots, positions,
 
     x = (params["wte"][tokens] + params["wpe"][positions]).astype(
         cache["k"].dtype)
-    x, new_k, new_v = scan_layers_paged(
-        lambda x, lp, kc, vc, layer_tables: _ragged_block(
-            cfg, x, lp, kc, vc, positions, slots, layer_tables,
-            prefill_tiles=prefill_tiles),
-        x, params["layers"], cache["k"], cache["v"], block_tables)
+
+    def layer(x, lp, pool, layer_tables):
+        x, kc, vc = _ragged_block(
+            cfg, x, lp, pool["k"], pool["v"], positions, slots, layer_tables,
+            prefill_tiles=prefill_tiles)
+        return x, {"k": kc, "v": vc}
+
+    x, cache = scan_layers_paged(layer, x, params["layers"], cache,
+                                 block_tables)
     x = layernorm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 
     logits = x @ maybe_dequantize(params["wte"], x.dtype).astype(x.dtype).T
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 def num_params(cfg: GPT2Config) -> int:

@@ -321,15 +321,19 @@ def ragged_forward(cfg: LlamaConfig, params, tokens, slots, positions,
     from deepspeed_tpu.models.paged import scan_layers_paged
 
     x = params["embed"][tokens].astype(cache["k"].dtype)
-    x, new_k, new_v = scan_layers_paged(
-        lambda x, lp, kc, vc, layer_tables: _ragged_layer(
-            cfg, x, lp, kc, vc, positions, slots, layer_tables,
-            prefill_tiles=prefill_tiles),
-        x, params["layers"], cache["k"], cache["v"], block_tables)
+
+    def layer(x, lp, pool, layer_tables):
+        x, kc, vc = _ragged_layer(
+            cfg, x, lp, pool["k"], pool["v"], positions, slots, layer_tables,
+            prefill_tiles=prefill_tiles)
+        return x, {"k": kc, "v": vc}
+
+    x, cache = scan_layers_paged(layer, x, params["layers"], cache,
+                                 block_tables)
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     head = lm_head(cfg, params)
     logits = x @ head.astype(x.dtype)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 # ------------------------------------------------------------------ pipeline

@@ -18,6 +18,7 @@ from jax import lax
 
 from deepspeed_tpu.config.config import MoEConfig
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx, causal_lm_loss
+from deepspeed_tpu.models.experts import routed_experts
 from deepspeed_tpu.models.llama import rmsnorm
 from deepspeed_tpu.ops.attention import apply_rope
 from deepspeed_tpu.parallel.moe import moe_ffn
@@ -165,38 +166,6 @@ def forward(cfg: MixtralConfig, params, input_ids, ctx: ShardCtx | None = None,
 
 
 # ------------------------------------------------------------------ inference
-def _moe_infer(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
-               top_k: int) -> jnp.ndarray:
-    """Dropless per-token top-k MoE for the inference paths (``h`` [T, D]
-    flat tokens).
-
-    Role parity with the reference's ragged MoE serving stack
-    (``inference/v2/model_implementations/mixtral/model.py`` +
-    ``inference/v2/kernels/ragged_ops`` top-k gating, MoE gather/scatter):
-    the CUDA version compacts tokens per expert with gather/scatter kernels;
-    the TPU-native shape is a batched [E] einsum — every expert processes
-    every token on the MXU and the router's renormalized top-k weights
-    combine the results. Exact (no capacity, no drops), at E/top_k x the
-    ideal expert FLOPs — the right trade at serving token counts, where the
-    expert GEMMs are small and a compaction pass would serialize; a
-    sort-based exact dispatch is the optimization point if prefill chunks
-    ever dominate.
-    """
-    t, d = h.shape
-    probs = jax.nn.softmax(
-        h.astype(jnp.float32) @ router_w.astype(jnp.float32), axis=-1)
-    topv, topi = lax.top_k(probs, top_k)
-    topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-9)
-    e = probs.shape[-1]
-    w = jnp.zeros((t, e), jnp.float32).at[
-        jnp.arange(t)[:, None], topi].set(topv)
-    dtype = h.dtype
-    g = jnp.einsum("td,edf->tef", h, w_gate.astype(dtype))
-    u = jnp.einsum("td,edf->tef", h, w_up.astype(dtype))
-    y = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u, w_down.astype(dtype))
-    return jnp.einsum("ted,te->td", y, w.astype(dtype))
-
-
 def init_cache(cfg: MixtralConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> dict:
     """Dense fixed-shape KV cache [L, B, max_len, Hkv, Dh] (v1 engine)."""
@@ -224,8 +193,8 @@ def _cached_layer(cfg: MixtralConfig, x, lp, k_cache, v_cache, start_pos,
     x = x + o.reshape(b, t, hq * hd) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    y = _moe_infer(h.reshape(b * t, d), lp["router"], lp["w_gate"],
-                   lp["w_up"], lp["w_down"], cfg.top_k)
+    y = routed_experts(h.reshape(b * t, d), lp["router"], lp["w_gate"],
+                       lp["w_up"], lp["w_down"], cfg.top_k)
     return x + y.reshape(b, t, d), k_cache, v_cache
 
 
@@ -294,8 +263,8 @@ def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
     x = x + o.reshape(t_tokens, hq * hd) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    x = x + _moe_infer(h, lp["router"], lp["w_gate"], lp["w_up"],
-                       lp["w_down"], cfg.top_k)
+    x = x + routed_experts(h, lp["router"], lp["w_gate"], lp["w_up"],
+                           lp["w_down"], cfg.top_k)
     return x, kc, vc
 
 
@@ -307,16 +276,20 @@ def ragged_forward(cfg: MixtralConfig, params, tokens, slots, positions,
     from deepspeed_tpu.models.paged import scan_layers_paged
 
     x = params["embed"][tokens].astype(cache["k"].dtype)
-    x, new_k, new_v = scan_layers_paged(
-        lambda x, lp, kc, vc, layer_tables: _ragged_layer(
-            cfg, x, lp, kc, vc, positions, slots, layer_tables,
-            prefill_tiles=prefill_tiles),
-        x, params["layers"], cache["k"], cache["v"], block_tables)
+
+    def layer(x, lp, pool, layer_tables):
+        x, kc, vc = _ragged_layer(
+            cfg, x, lp, pool["k"], pool["v"], positions, slots, layer_tables,
+            prefill_tiles=prefill_tiles)
+        return x, {"k": kc, "v": vc}
+
+    x, cache = scan_layers_paged(layer, x, params["layers"], cache,
+                                 block_tables)
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 
     logits = x @ maybe_dequantize(params["lm_head"], x.dtype).astype(x.dtype)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 def num_params(cfg: MixtralConfig) -> int:

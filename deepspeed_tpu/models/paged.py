@@ -54,81 +54,119 @@ def init_paged_pool(num_layers: int, num_blocks: int, block_size: int,
     return {"k": pool(), "v": pool()}
 
 
-def scan_layers_paged(layer_fn, x, layers, k_pool, v_pool, block_tables):
-    """Run ``layer_fn(x, lp, kc, vc, layer_tables) -> (x, kc, vc)`` over the
-    stacked layers. The pool is carried through the scan WHOLE, with ``L``
-    and ``NB`` merged into one block axis; a layer addresses it through
-    ``layer_tables = block_tables + layer * NB`` and never gets a slice, so
-    per step and layer the pool traffic is the rows ``write_kv_paged``
-    scatters plus the blocks attention reads: nothing proportional to
-    ``NB``. Works on a fp pool and on a ``kvquant.QuantizedKV`` alike: both
-    are pytrees of ``[L, NB, ...]`` arrays.
+def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
+    """Run ``layer_fn(x, lp, pool, layer_tables) -> (x, pool)`` over the
+    stacked ``layers``. ``pool`` is the family's paged cache, any pytree of
+    ``[L, NB, ...]`` arrays (``{"k", "v"}`` of fp arrays or of
+    ``kvquant.QuantizedKV``; the one-leaf latent pool of ``deepseek``). It
+    is carried through the scan WHOLE, with ``L`` and ``NB`` merged into one
+    block axis; a layer addresses it through ``layer_tables = block_tables +
+    layer * NB`` and never gets a slice, so per step and layer the pool
+    traffic is the rows the layer scatters plus the blocks its attention
+    reads: nothing proportional to ``NB``.
+
+    A stack that is not homogeneous puts its leading layers of another kind
+    in ``lead``, ``[(fn, lp), ...]`` with ``fn`` as ``layer_fn``: they run
+    before the scan at layers ``0 .. len(lead) - 1`` of the pool and the
+    scanned layers follow them, so ``L`` counts every layer.
     """
-    n_layers, nb = jax.tree_util.tree_leaves(k_pool)[0].shape[:2]
+    leaves = jax.tree_util.tree_leaves(pool)
+    n_layers, nb = leaves[0].shape[:2]
+    n_lead = len(lead)
 
-    def merged(pool):
-        return jax.tree_util.tree_map(
-            lambda a: a.reshape((n_layers * nb,) + a.shape[2:]), pool)
-
-    def split(pool):
-        return jax.tree_util.tree_map(
-            lambda a: a.reshape((n_layers, nb) + a.shape[1:]), pool)
+    pool = jax.tree_util.tree_map(
+        lambda a: a.reshape((n_layers * nb,) + a.shape[2:]), pool)
+    for i, (fn, lp) in enumerate(lead):
+        x, pool = fn(x, lp, pool, block_tables + i * nb)
 
     def body(carry, lp_i):
-        x, kc, vc = carry
+        x, pool = carry
         lp, i = lp_i
-        return layer_fn(x, lp, kc, vc, block_tables + i * nb), None
+        return layer_fn(x, lp, pool, block_tables + i * nb), None
 
-    (x, kc, vc), _ = lax.scan(
-        body, (x, merged(k_pool), merged(v_pool)),
-        (layers, jnp.arange(n_layers, dtype=jnp.int32)))
-    return x, split(kc), split(vc)
+    (x, pool), _ = lax.scan(
+        body, (x, pool),
+        (layers, jnp.arange(n_lead, n_layers, dtype=jnp.int32)))
+    return x, jax.tree_util.tree_map(
+        lambda a: a.reshape((n_layers, nb) + a.shape[1:]), pool)
 
 
-def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables):
-    """Scatter each ragged token's new KV into (block, offset) of its
-    sequence's pool blocks, in place. ``kk``/``vv``: [T, Hkv, D]; ``kc`` /
-    ``vc``: [blocks, BS, Hkv*D].
+def write_rows_paged(pool, rows, slots, positions, block_tables):
+    """Scatter each ragged token's new cache row into (block, offset) of its
+    sequence's pool blocks, in place. ``rows``: [T, ...] (flattened to the
+    pool's lanes); ``pool``: [blocks, BS, lanes].
 
     This is the ONE write site of the paged contract, so it is also the
     ONE quantize site: a low-bit pool (``inference/kvquant.QuantizedKV``)
     quantizes each token row at write time — per-row scales keep the
     incremental scatter exact (rewriting a row never re-rounds another).
     """
-    bs = kc.shape[1]
+    bs = pool.shape[1]
     blk = block_tables[slots, positions // bs]  # [T]
     off = positions % bs
-    if getattr(kc, "is_quantized_kv", False):
-        return kc.scatter_rows(blk, off, kk), vc.scatter_rows(blk, off, vv)
-    t = kk.shape[0]
-    kc = kc.at[blk, off].set(kk.reshape(t, -1).astype(kc.dtype))
-    vc = vc.at[blk, off].set(vv.reshape(t, -1).astype(vc.dtype))
-    return kc, vc
+    if getattr(pool, "is_quantized_kv", False):
+        return pool.scatter_rows(blk, off, rows)
+    return pool.at[blk, off].set(
+        rows.reshape(rows.shape[0], -1).astype(pool.dtype))
+
+
+def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables):
+    """``write_rows_paged`` for a K pool and a V pool. ``kk``/``vv``:
+    [T, Hkv, D]; ``kc``/``vc``: [blocks, BS, Hkv*D]."""
+    return (write_rows_paged(kc, kk, slots, positions, block_tables),
+            write_rows_paged(vc, vv, slots, positions, block_tables))
+
+
+def _decode_then_tiles(q, slots, positions, prefill_tiles, decode, prefill):
+    """A flat ragged token batch is its decode rows, then tile-aligned
+    prefill chunks (``prefill_tiles`` = ``(n_dec, tile_slot, tile_pos0,
+    tile_valid, tile)``; None: every row is a decode row): ``decode(q,
+    slots, positions)`` over the former, ``prefill(q, tile_slot, tile_pos0,
+    tile_valid, tile)`` over the latter."""
+    if prefill_tiles is None:
+        return decode(q, slots, positions)
+    n_dec, ts, tp, tv, ct = prefill_tiles
+    parts = []
+    if n_dec:
+        parts.append(decode(q[:n_dec], slots[:n_dec], positions[:n_dec]))
+    if q.shape[0] > n_dec:
+        parts.append(prefill(q[n_dec:], ts, tp, tv, ct))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
 def ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
                           prefill_tiles=None):
     """Attention over the blocked pool for a flat ragged token batch:
     per-token paged kernel for the decode region, the tiled SplitFuse
-    kernel for tile-aligned prefill chunks (``prefill_tiles`` =
-    ``(n_dec, tile_slot, tile_pos0, tile_valid, tile)``)."""
+    kernel for tile-aligned prefill chunks."""
     from deepspeed_tpu.ops.attention import (
         paged_attention,
         ragged_prefill_attention,
     )
 
-    t_tokens = q.shape[0]
-    if prefill_tiles is None:
-        return paged_attention(q, kc, vc, slots, positions, block_tables)
-    n_dec, ts, tp, tv, ct = prefill_tiles
-    parts = []
-    if n_dec:
-        parts.append(paged_attention(q[:n_dec], kc, vc, slots[:n_dec],
-                                     positions[:n_dec], block_tables))
-    if t_tokens > n_dec:
-        parts.append(ragged_prefill_attention(
-            q[n_dec:], kc, vc, ts, tp, tv, block_tables, ct))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    return _decode_then_tiles(
+        q, slots, positions, prefill_tiles,
+        lambda q, sl, po: paged_attention(q, kc, vc, sl, po, block_tables),
+        lambda q, ts, tp, tv, ct: ragged_prefill_attention(
+            q, kc, vc, ts, tp, tv, block_tables, ct))
+
+
+def latent_pool_attention(q, pool, slots, positions, block_tables, lat: int,
+                          scale: float, prefill_tiles=None):
+    """Absorbed MLA attention over a latent pool (``[blocks, BS, W]``, a
+    row ``[c, k_rope, zeros]``, values = its first ``lat`` lanes) for a flat
+    ragged token batch: ``q`` [T, H, W] -> [T, H, lat]."""
+    from deepspeed_tpu.ops.attention import (
+        latent_paged_attention,
+        latent_prefill_attention,
+    )
+
+    return _decode_then_tiles(
+        q, slots, positions, prefill_tiles,
+        lambda q, sl, po: latent_paged_attention(
+            q, pool, sl, po, block_tables, lat, scale),
+        lambda q, ts, tp, tv, ct: latent_prefill_attention(
+            q, pool, ts, tp, tv, block_tables, ct, lat, scale))
 
 
 def speculative_lane_layout(cur_tok, draft, pos, live, cap, slots,

@@ -211,15 +211,69 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                                tile_valid, block_tables, tile, scale=scale)
     if impl != "xla":
         raise ValueError(f"unknown prefill attention impl {impl!r}")
-    t = q.shape[0]
-    c = jnp.arange(t) // tile
-    i = jnp.arange(t) % tile
-    pad_row = block_tables.shape[0] - 1  # all-scratch padding row
-    valid = i < tile_valid[c]
-    slots = jnp.where(valid, tile_slot[c], pad_row).astype(jnp.int32)
-    positions = jnp.where(valid, tile_pos0[c] + i, 0).astype(jnp.int32)
+    slots, positions = _tile_rows(q.shape[0], tile_slot, tile_pos0, tile_valid,
+                                  tile, block_tables.shape[0] - 1)
     return paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
                            scale=scale, impl="xla")
+
+
+def _tile_rows(n_rows: int, tile_slot, tile_pos0, tile_valid, tile: int,
+               pad_row: int):
+    """Per-row ``(slots, positions)`` of ``n_rows`` tile-aligned prefill
+    rows; rows past a tile's ``tile_valid`` go to the all-scratch padding
+    row at position 0."""
+    c = jnp.arange(n_rows) // tile
+    i = jnp.arange(n_rows) % tile
+    valid = i < tile_valid[c]
+    return (jnp.where(valid, tile_slot[c], pad_row).astype(jnp.int32),
+            jnp.where(valid, tile_pos0[c] + i, 0).astype(jnp.int32))
+
+
+def latent_paged_attention(q, pool, slots, positions, block_tables, lat: int,
+                           scale: float, impl: str = "auto"):
+    """Absorbed MLA attention of ragged rows over a latent pool: ``q``
+    [T, H, W] against rows ``[c, k_rope, zeros]`` of ``pool`` [blocks, BS,
+    W], values the first ``lat`` lanes of the same rows -> [T, H, lat]. On the chip the Pallas kernel walks each row's own blocks
+    (``ops/pallas/mla_attention.mla_decode_attention``); the XLA gather of
+    the padded context is the form the CPU runs."""
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "xla"
+    if impl == "pallas":
+        from deepspeed_tpu.ops.pallas.mla_attention import mla_decode_attention
+
+        return mla_decode_attention(q, pool, slots, positions, block_tables,
+                                    lat, scale)
+    if impl != "xla":
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    t_tokens = q.shape[0]
+    ctx = pool[block_tables[slots]].reshape(
+        t_tokens, -1, pool.shape[-1]).astype(jnp.float32)    # [T, C, W]
+    k_pos = jnp.arange(ctx.shape[1])
+    bias = jnp.where(k_pos[None, :] <= positions[:, None], 0.0, -1e30)
+    scores = jnp.einsum("thl,tcl->thc", q.astype(jnp.float32) * scale,
+                        ctx) + bias[:, None, :]
+    p = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("thc,tcl->thl", p, ctx[..., :lat]).astype(q.dtype)
+
+
+def latent_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
+                             block_tables, tile: int, lat: int, scale: float,
+                             impl: str = "auto"):
+    """``latent_paged_attention`` for tile-aligned prefill rows (the
+    scheduler contract of ``ragged_prefill_attention``): on the chip the
+    tiled kernel, a block fetched once a tile; the XLA form expands the tile
+    metadata to per-row (slot, position)."""
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "xla"
+    if impl == "pallas":
+        from deepspeed_tpu.ops.pallas.mla_attention import mla_prefill_attention
+
+        return mla_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
+                                     block_tables, tile, lat, scale)
+    slots, positions = _tile_rows(q.shape[0], tile_slot, tile_pos0, tile_valid,
+                                  tile, block_tables.shape[0] - 1)
+    return latent_paged_attention(q, pool, slots, positions, block_tables,
+                                  lat, scale, impl=impl)
 
 
 @functools.partial(jax.jit, static_argnums=(3,))

@@ -1,0 +1,275 @@
+"""Pallas attention over a latent (MLA) paged pool: absorbed decode and
+absorbed tiled prefill.
+
+Multi-head latent attention (DeepSeek-V2/V3) caches ONE row a token and
+layer: the normed latent ``c`` (``kv_lora_rank`` lanes) followed by the roped
+key all heads share (``qk_rope_head_dim`` lanes). With the key half of
+``kv_b_proj`` multiplied into the query (``q_lat``) and the value half applied
+to the result, attention runs on the cached rows directly: to these kernels it
+is ``H`` query heads of ``lat + rope`` lanes over ONE KV head whose values are
+lanes ``0 .. lat-1`` of the same row. No per-head K or V of the context is
+ever made, and a block is fetched once for both uses.
+
+The pool has the paged contract's storage form (``models/paged.py``):
+``[blocks, BS, W]`` with ``W`` = ``lat + rope`` rounded up to whole 128-lane
+tiles, the lanes past ``lat + rope`` zero (``q`` is padded alike, so they add
+nothing to a score). At Moonlight's 512 + 64 that is 640: the device lays a
+576-lane row out as 640 whether it is one array or ``c`` and ``k_rope`` apart
+(64 lanes pad to 128), and a DMA written by hand cannot take 576 lanes of 640
+(Mosaic: "slice shape along dimension 2 must be aligned to tiling (128)"), so
+the padding is the pool's own: one array, one DMA a block, one scatter a token.
+
+``mla_decode`` walks each decode row's OWN blocks: the grid is the rows, and
+inside a row the blocks ``0 .. pos // BS`` are copied HBM -> VMEM by hand,
+double-buffered, the first block of the next row already in flight while the
+last of this one is computed. A grid of rows x table width (the shape of
+``paged_attention.paged_decode_attention``) would spend a grid step on every
+table entry: at 128 rows x 32 entries that is 4,096 steps a layer for ~1,100
+blocks of real context.
+
+Inference-only (no VJP).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
+from deepspeed_tpu.ops.pallas.paged_attention import (
+    _VMEM_SCOPED_BYTES,
+    split_tiles,
+)
+
+_NEG_INF = -1e30
+
+
+def _scores(q, blk):
+    """``q`` [R, W] x ``blk`` [BS, W] -> float32 [R, BS]."""
+    return jax.lax.dot_general(q, blk, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _decode_kernel(slots_ref, pos_ref, bt_ref, q_ref, pool_ref, o_ref,
+                   buf, sem, parity, *, bs: int, lat: int, scale: float):
+    t = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    pos = pos_ref[t]
+    n_blk = pos // bs + 1
+
+    def fetch(row, j, slot):
+        return pltpu.make_async_copy(
+            pool_ref.at[bt_ref[slots_ref[row], j]], buf.at[slot],
+            sem.at[slot])
+
+    @pl.when(t == 0)
+    def _first():
+        parity[0] = 0
+        fetch(0, 0, 0).start()
+
+    base = parity[0]
+    q = q_ref[0]                                          # [H, W]
+    h = q.shape[0]
+
+    def block(j, carry):
+        m_prev, l_prev, acc = carry
+        slot = (base + j) % 2
+
+        @pl.when(j + 1 < n_blk)
+        def _next_block():
+            fetch(t, j + 1, 1 - slot).start()
+
+        @pl.when(jnp.logical_and(j + 1 == n_blk, t + 1 < n_rows))
+        def _next_row():
+            fetch(t + 1, 0, 1 - slot).start()
+
+        fetch(t, j, slot).wait()
+        blk = buf[slot]                                   # [BS, W]
+        s = _scores(q.astype(blk.dtype), blk) * scale
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        s = jnp.where(kpos <= pos, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)                            # [H, BS]
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(blk.dtype), blk[:, :lat],
+                     preferred_element_type=jnp.float32)  # [H, lat]
+        return m_new, l_new, acc * corr + pv
+
+    # position 0 is never masked, so the running maximum is real from the
+    # first block on and no row of p is all zeros
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blk, block,
+        (jnp.full((h, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, lat), jnp.float32)))
+    parity[0] = (base + n_blk) % 2
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def mla_decode_attention(q, pool, slots, positions, block_tables, lat: int,
+                         scale: float, interpret: bool | None = None):
+    """Absorbed MLA decode: ``q`` [T, H, W] (``q_lat``, the roped ``q_rope``,
+    zeros) over ``pool`` [blocks, BS, W] through ``block_tables[slots]`` ->
+    [T, H, lat] (``P c``; the caller applies the
+    value half of ``kv_b_proj``). Each row reads blocks ``0 .. pos // BS``
+    of its sequence, once."""
+    t_tokens, h, width = q.shape
+    _, bs, _ = pool.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(t_tokens,),
+        in_specs=[
+            pl.BlockSpec((1, h, width), lambda t, sl, po, bt: (t, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, h, lat), lambda t, sl, po, bt: (t, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, bs, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    kernel = functools.partial(_decode_kernel, bs=bs, lat=lat, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((t_tokens, h, lat), q.dtype),
+        grid_spec=grid_spec,
+        # the next row's first block is in flight across grid steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(interpret),
+        name="mla_decode",
+    )(slots.astype(jnp.int32), positions.astype(jnp.int32),
+      block_tables.astype(jnp.int32), q, pool)
+
+
+# --------------------------------------------------------------- tiled prefill
+def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, kv_ref, o_ref,
+                    acc, m_sc, l_sc, *, bs: int, heads: int, lat: int,
+                    scale: float):
+    c = pl.program_id(0)   # query tile
+    j = pl.program_id(1)   # kv block ordinal
+    nj = pl.num_programs(1)
+    pos0 = tp_ref[c]
+    valid = tv_ref[c]
+    max_pos = pos0 + valid - 1
+
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    @pl.when(jnp.logical_and(valid > 0, j * bs <= max_pos))
+    def _compute():
+        blk = kv_ref[0]                                   # [BS, W]
+        q = q_ref[...]                                    # [CT*H, W]
+        rows = q.shape[0]
+        s = _scores(q.astype(blk.dtype), blk) * scale
+        qi = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        mask = jnp.logical_and(kpos <= pos0 + qi, qi < valid)
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_sc[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        # fully-masked rows (pad queries) give exp(-inf - -inf): zero them
+        p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[:, :1] = l_sc[:, :1] * corr + jnp.sum(p, -1, keepdims=True)
+        m_sc[:, :1] = m_new
+        acc[:] = acc[:] * corr + jnp.dot(
+            p.astype(blk.dtype), blk[:, :lat],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nj - 1)
+    def _finish():
+        o_ref[...] = (acc[:] / jnp.maximum(l_sc[:, :1], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+# What one query row of one head costs the prefill kernel in scoped VMEM
+# (16 MiB on v5e, ``paged_attention._VMEM_SCOPED_BYTES``), in bytes: the
+# float32 accumulator (lat lanes) and m, l (128 lanes each), the
+# double-buffered q (W lanes) and o blocks in bf16, and about three float32
+# [rows, BS] temporaries (scores, p, the mask's select).
+def _prefill_row_bytes(lat: int, width: int, bs: int) -> int:
+    return (lat + 2 * 128) * 4 + 2 * 2 * (width + lat) + 3 * 4 * bs
+
+
+def mla_prefill_kernel_tile(tile: int, heads: int, lat: int, width: int,
+                            bs: int) -> int:
+    """Largest power-of-two split of the scheduler's ``tile`` whose working
+    set fits scoped VMEM with an eighth to spare (as
+    ``paged_attention.prefill_kernel_tile``)."""
+    row = heads * _prefill_row_bytes(lat, width, bs)
+    ct = tile
+    while ct > 8 and ct % 2 == 0 and ct * row > _VMEM_SCOPED_BYTES * 7 // 8:
+        ct //= 2
+    return ct
+
+
+def mla_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
+                          block_tables, tile: int, lat: int, scale: float,
+                          interpret: bool | None = None):
+    """Absorbed MLA prefill over the latent pool: ``q`` [NT*CT, H, W]
+    tile-aligned prefill tokens -> [NT*CT, H, lat]. Same scheduler contract
+    and sub-tiling as ``paged_attention.ragged_prefill_attention``; a tile's
+    ``CT * H`` query rows share each fetched block, so the matmuls are
+    ``[CT*H, W] x [W, BS]`` and ``[CT*H, BS] x [BS, lat]``.
+
+    Absorbed, a query-key pair costs ``2 H (2 lat + rope)`` FLOPs (34.8 k at
+    Moonlight's widths) against ``2 H (nope + rope + v)`` (10.2 k) on
+    decompressed keys and values; the decompressed form also needs
+    ``kv_b_proj`` over the whole context once a chunk (4.2 MFLOP a context
+    token) and a per-head K and V of it in memory. At the chunks the
+    scheduler makes (<= 4 tiles a step) the two cost about the same and
+    both are a few per cent of the step's expert einsum; the absorbed form
+    keeps one read path over the cached rows and materialises nothing.
+    """
+    t_tokens, h, width = q.shape
+    _, bs, _ = pool.shape
+    mb = block_tables.shape[1]
+    ct = mla_prefill_kernel_tile(tile, h, lat, width, bs)
+    tile_slot, tile_pos0, tile_valid = split_tiles(
+        tile_slot, tile_pos0, tile_valid, tile, ct)
+    n_tiles = t_tokens // ct
+
+    # clamp past the tile's last needed block: unchanged id -> no new DMA
+    def _kv_map(c, j, ts, tp, tv, bt):
+        last = jnp.maximum(tp[c] + tv[c] - 1, 0) // bs
+        return (bt[ts[c], jnp.minimum(j, last)], 0, 0)
+
+    def _q_map(c, j, ts, tp, tv, bt):
+        return (c, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_tiles, mb),
+        in_specs=[
+            pl.BlockSpec((ct * h, width), _q_map),
+            pl.BlockSpec((1, bs, width), _kv_map),
+        ],
+        out_specs=pl.BlockSpec((ct * h, lat), _q_map),
+        scratch_shapes=[
+            pltpu.VMEM((ct * h, lat), jnp.float32),
+            pltpu.VMEM((ct * h, 128), jnp.float32),
+            pltpu.VMEM((ct * h, 128), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_prefill_kernel, bs=bs, heads=h, lat=lat,
+                               scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((t_tokens * h, lat), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret_mode(interpret),
+        name="mla_prefill",
+    )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
+      tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
+      q.reshape(t_tokens * h, width), pool)
+    return out.reshape(t_tokens, h, lat)

@@ -218,6 +218,17 @@ def prefill_kernel_tile(tile: int, hq: int, d: int) -> int:
     return ct
 
 
+def split_tiles(tile_slot, tile_pos0, tile_valid, tile: int, ct: int):
+    """The scheduler's tiles of ``tile`` rows as consecutive sub-tiles of
+    ``ct``: a sub-tile is itself a tile of the same sequence."""
+    if ct == tile:
+        return tile_slot, tile_pos0, tile_valid
+    sub0 = jnp.arange(tile // ct, dtype=jnp.int32) * ct
+    return (jnp.repeat(tile_slot, tile // ct),
+            (tile_pos0[:, None] + sub0).reshape(-1),
+            jnp.clip(tile_valid[:, None] - sub0, 0, ct).reshape(-1))
+
+
 def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
                              scale: float | None = None,
@@ -243,11 +254,8 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     mb = block_tables.shape[1]
     rep = hq // hkv
     ct = prefill_kernel_tile(tile, hq, d)
-    if ct != tile:
-        sub0 = jnp.arange(tile // ct, dtype=jnp.int32) * ct
-        tile_slot = jnp.repeat(tile_slot, tile // ct)
-        tile_pos0 = (tile_pos0[:, None] + sub0).reshape(-1)
-        tile_valid = jnp.clip(tile_valid[:, None] - sub0, 0, ct).reshape(-1)
+    tile_slot, tile_pos0, tile_valid = split_tiles(
+        tile_slot, tile_pos0, tile_valid, tile, ct)
     n_tiles = t_tokens // ct
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 

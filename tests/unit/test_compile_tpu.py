@@ -14,6 +14,11 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.mla_attention import (
+    mla_decode_attention,
+    mla_prefill_attention,
+    mla_prefill_kernel_tile,
+)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_decode_attention,
     prefill_kernel_tile,
@@ -66,12 +71,35 @@ def _prefill(q, kp, vp, ts, tp, tv, bt):
                                     interpret=False)
 
 
+# Moonlight-16B-A3B's latent attention: 16 heads over ONE cached row of 512
+# latent + 64 rope lanes, padded to 640; 128-token blocks, a table of 32
+MLA_HEADS, MLA_LAT, MLA_WIDTH, MLA_BLOCK, MLA_TABLE = 16, 512, 640, 128, 32
+
+
+def _mla_decode(q, pool, slots, pos, bt):
+    return mla_decode_attention(q, pool, slots, pos, bt, MLA_LAT, 192 ** -0.5,
+                                interpret=False)
+
+
+def _mla_prefill(q, pool, ts, tp, tv, bt):
+    return mla_prefill_attention(q, pool, ts, tp, tv, bt, TILE, MLA_LAT,
+                                 192 ** -0.5, interpret=False)
+
+
 def _args(kernel, hq, hkv, d, devices):
     dev = jax.sharding.SingleDeviceSharding(devices[0])
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
+    if kernel in (_mla_decode, _mla_prefill):
+        pool = s((64, MLA_BLOCK, MLA_WIDTH))
+        bt = s((129, MLA_TABLE), jnp.int32)
+        if kernel is _mla_decode:
+            rows = s((128,), jnp.int32)
+            return s((128, MLA_HEADS, MLA_WIDTH)), pool, rows, rows, bt
+        tiles = s((3,), jnp.int32)
+        return s((3 * TILE, MLA_HEADS, MLA_WIDTH)), pool, tiles, tiles, tiles, bt
     if kernel in (_flash_fwd, _flash_bwd):
         return s((1, SEQ, hq, d)), s((1, SEQ, hkv, d)), s((1, SEQ, hkv, d))
     pool = s((64, BLOCK, hkv * d))
@@ -93,12 +121,24 @@ def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
     assert text.count("tpu_custom_call") >= n_calls
 
 
+@pytest.mark.parametrize("kernel", [_mla_decode, _mla_prefill],
+                         ids=["mla_decode", "mla_prefill"])
+def test_mla_kernel_compiles_for_v5e(v5e, kernel):
+    """At Moonlight's widths and the reason-pool cell's shapes (128 decode
+    rows, 3 prefill tiles): the hand-written DMA of a 640-lane block, the
+    dynamic trip count, the 64-row sub-tile's scoped VMEM."""
+    compiled = jax.jit(kernel).lower(*_args(kernel, 0, 0, 0, v5e)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize("kernel,name", [
     (_flash_fwd, "flash_fwd"), (_flash_bwd, "flash_bwd_dkv"),
     (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
-    (_prefill, "tiled_prefill")],
+    (_prefill, "tiled_prefill"), (_mla_decode, "mla_decode"),
+    (_mla_prefill, "mla_prefill")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
-         "tiled_prefill"])
+         "tiled_prefill", "mla_decode", "mla_prefill"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     """``pl.pallas_call(name=...)``: the compiled custom call is
     ``%<name>.N`` (``%transpose_jvp_<name>__.N`` under a bare ``jax.grad``),
@@ -155,6 +195,9 @@ def test_prefill_tile_split_is_what_the_compiler_needs():
     assert prefill_kernel_tile(TILE, 25, 64) == TILE
     assert prefill_kernel_tile(TILE, 16, 64) == TILE
     assert prefill_kernel_tile(8, 32, 128) == 8
+    # 16 heads x (512-lane accumulator, 640-lane q): 128 rows are 19 MiB
+    assert mla_prefill_kernel_tile(TILE, MLA_HEADS, MLA_LAT, MLA_WIDTH,
+                                   MLA_BLOCK) == 64
 
 
 # ------------------------------------------------------- the paged contract
@@ -162,8 +205,12 @@ def test_prefill_tile_split_is_what_the_compiler_needs():
 # pool (blocks, block size); depth, FFN and vocabulary are small, so an array
 # as large as a layer's slice of the pool can only be the pool's.
 def _step_family(name):
-    from deepspeed_tpu.models import gpt2, llama, mixtral
+    from deepspeed_tpu.models import deepseek, gpt2, llama, mixtral
 
+    if name == "moonlight":    # moonlight-16b-a3b-d8.json's attention and pool
+        return deepseek, deepseek.DeepseekConfig(
+            vocab_size=512, intermediate_size=256, moe_intermediate_size=128,
+            num_layers=3, num_experts=4, top_k=2), 2049, 128, 32
     if name == "gpt2-xl":      # 25 x 64, benchmark/configs/gpt2-xl.json
         return gpt2, gpt2.GPT2Config(vocab_size=512, hidden_size=1600,
                                      num_layers=4, num_heads=25), 513, 32, 8
@@ -277,4 +324,59 @@ def test_step_program_holds_no_layer_slice_of_the_pool(v5e, monkeypatch,
     if not quantized:
         assert compiled.as_text().count("tpu_custom_call") >= (
             2 if table * block > 2048 else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+
+
+def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
+    """The paged contract on a pool that is ONE leaf (``deepseek``, MLA): a
+    mixed step (128 decode rows beside a prefill tile) with a donated pool
+    scatters each layer's rows in place (the dense layer outside the scan,
+    the expert layers inside it), both attention kernels take the pool
+    itself, once each (a block is fetched once for scores and for values:
+    no K pool beside a V pool, no copy), and nothing else in the program is
+    as large as one layer's slice of the pool."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mod, cfg, blocks, block, table = _step_family("moonlight")
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+    rows, tiles = 128, 1
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        mod.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: mod.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16))
+    (leaf,) = jax.tree_util.tree_leaves(cache)
+    assert leaf.shape == (cfg.num_layers, blocks, block, MLA_WIDTH)
+    layer_slice = blocks * block * MLA_WIDTH * 2
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return mod.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    t = rows + tiles * TILE
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(129, table), i32(1), i32(1), i32(1)).compile()
+    text = compiled.as_text()
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= layer_slice]
+    scatters = [ln for _, op, ln in big if op == "scatter"]
+    assert len(scatters) == 2, scatters   # the dense layer's, the scan body's
+    assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
+    pool_shape = f"bf16[{cfg.num_layers * blocks},{block},{MLA_WIDTH}]"
+    kernels = [ln for ln in text.splitlines()
+               if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(kernels) == 4              # decode and prefill, twice each
+    for ln in kernels:
+        layouts = ln.split("operand_layout_constraints=")[1]
+        assert layouts.count(pool_shape) == 1, ln
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice

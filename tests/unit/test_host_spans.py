@@ -294,7 +294,8 @@ def _ctx(cellspec, cell: str, tl: dict, kernel_s=None) -> dict:
 
 FIXTURE_OF = {"mixtral-8x7b-d3.longdoc-pool": "v5e_mixtral_longdoc_spans",
               "mixtral-8x7b-d3.chat-open": "v5e_mixtral_chat_spans",
-              "gpt2-xl.train-zero3-x4": "v5e_x4_gpt2xl_train_spans"}
+              "gpt2-xl.train-zero3-x4": "v5e_x4_gpt2xl_train_spans",
+              "moonlight-16b-a3b-d8.reason-pool": "v5e_moonlight_reason_spans"}
 
 
 def _new_readers():
@@ -304,8 +305,9 @@ def _new_readers():
     for m in bench["per_layer"]:
         path = os.path.join(BENCH, "layer_metrics", m["name"] + ".py")
         with open(path) as f:
-            if "host_spans" not in f.read():  # a reader from before the spans
-                continue
+            text = f.read()
+        if "host_spans" not in text and "latent_spans" not in text:
+            continue  # a reader from before the spans
         cell = next(c for c in m["workloads"] if c in FIXTURE_OF)
         cases.append((m["name"], cell))
     return cases

@@ -11,17 +11,21 @@ import pytest
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
-from deepspeed_tpu.models import gpt2, llama, mixtral
+from deepspeed_tpu.models import deepseek, gpt2, llama, mixtral
 
 MIX = mixtral.MixtralConfig.tiny(89)
 GPT = gpt2.GPT2Config.tiny(89)
 LLA = llama.LlamaConfig.tiny(89)
 FAMILIES = {"gpt2": (gpt2, GPT), "mixtral": (mixtral, MIX),
             "llama": (llama, LLA)}
+# MLA over a ONE-leaf latent pool, a dense layer before the expert layers; no
+# dense-cache engine and no quantized pool, so it joins the cases that need
+# neither (the paged-addressing cases have their own in test_deepseek.py)
+DSK = deepseek.DeepseekConfig.tiny(89)
 
 
 def _build(name):
-    mod, cfg = FAMILIES[name]
+    mod, cfg = (deepseek, DSK) if name == "deepseek" else FAMILIES[name]
     return lambda ctx: mod.build(cfg, ctx=ctx)
 
 
@@ -32,6 +36,21 @@ def _prompts(n=4, seed=3):
 
 
 def _dense_reference(name, prompts, max_new):
+    if name == "deepseek":  # no dense cache: the whole sequence every token
+        import jax
+
+        spec = deepseek.build(DSK)
+        params = spec.init_fn(jax.random.PRNGKey(0))
+        fwd = jax.jit(spec.forward_fn)
+        out = {}
+        for uid, p in prompts.items():
+            seq = list(p)
+            for _ in range(max_new):
+                ids = np.zeros(24, np.int32)   # causal: the padding is inert
+                ids[:len(seq)] = seq
+                seq.append(int(np.argmax(fwd(params, ids[None])[0, len(seq) - 1])))
+            out[uid] = seq[len(p):]
+        return out
     eng = InferenceEngine(_build(name), dtype=jnp.float32, seed=0)
     out = {}
     for uid, p in prompts.items():
@@ -49,7 +68,7 @@ def _ragged(name, fused=0, tile=0, quant="off"):
             fused_chunk=fused, prefill_tile=tile, quant=quant))
 
 
-@pytest.mark.parametrize("name", ["mixtral", "gpt2"])
+@pytest.mark.parametrize("name", ["mixtral", "gpt2", "deepseek"])
 class TestRaggedFamilies:
     def test_greedy_parity_vs_dense(self, name):
         """Continuous batching at mixed lengths must reproduce the dense
@@ -259,8 +278,9 @@ def test_pallas_kernels_read_through_a_layers_own_table(name, monkeypatch):
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("quant", ["off", "int8"])
-@pytest.mark.parametrize("name", sorted(FAMILIES))
+@pytest.mark.parametrize("name,quant", [
+    (n, q) for n in sorted(FAMILIES) for q in ("off", "int8")]
+    + [("deepseek", "off")])
 class TestBlockPayloadsOnTheStorageForm:
     """Host-side code indexes blocks as ``a[:, ids]`` on ``[L, NB, ...]``;
     payloads have the pool's form on both ends."""
