@@ -2648,7 +2648,8 @@ class RaggedInferenceEngine:
         ``kv_tokens`` is the context every scheduled sequence attends over
         (once a sequence), ``attn_pairs`` the query x key pairs, and
         ``dec_kv_tokens`` the decode rows' part of ``kv_tokens`` (a decode
-        row is one query, so also its part of the pairs)."""
+        row is one query, so also its part of the pairs); ``moe`` is the
+        form of the step's expert FFNs (``_moe_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step_device()
@@ -2669,7 +2670,8 @@ class RaggedInferenceEngine:
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
-                  attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec):
+                  attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
+                  **self._moe_attr(t_total)):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._bt_dev,
                 staged, self._sample_root)
@@ -2687,6 +2689,13 @@ class RaggedInferenceEngine:
             self._trace_spans(t0, time.perf_counter(), tpairs,
                               mode="dev_step")
         return True
+
+    def _moe_attr(self, t: int) -> dict:
+        """``{"moe": "grouped" | "dense"}``: the form the model's routed
+        experts take in a step program of ``t`` rows, from the predicate the
+        model itself calls; nothing for a family without routed experts."""
+        form = self.spec.moe_form
+        return {} if form is None else {"moe": form(t)}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:
         """The name a device-resident step program goes by in a trace: its

@@ -342,6 +342,10 @@ class ModelSpec:
     # ragged_forward_fn accepts prefill_tiles=(n_dec, tile_slot, tile_pos0,
     # tile_valid, tile) for the tiled-prefill fast path (SplitFuse kernel)
     supports_prefill_tiles: bool = False
+    # moe_form(rows) -> "grouped" | "dense": the form ragged_forward_fn's
+    # routed experts take at a step of ``rows`` tokens (models/experts.py's
+    # rule on the model's geometry); None for a family with no routed experts
+    moe_form: Callable[[int], str] | None = None
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

@@ -40,7 +40,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx, causal_lm_loss
-from deepspeed_tpu.models.experts import routed_experts, swiglu
+from deepspeed_tpu.models.experts import (
+    expert_form,
+    expert_stacks,
+    routed_experts,
+    routed_experts_einsum,
+    swiglu,
+)
 from deepspeed_tpu.models.llama import rmsnorm
 from deepspeed_tpu.ops.attention import apply_rope, xla_attention
 
@@ -196,14 +202,15 @@ PARAM_LOGICAL_AXES = {
 }
 
 
-def _ffn(cfg: DeepseekConfig, h, lp):
+def _ffn(cfg: DeepseekConfig, h, lp, experts, **stacked):
     """The FFN half of a layer on flat tokens ``h`` [T, D]: a dense layer's
-    SwiGLU, or the routed experts plus the shared ones."""
+    SwiGLU, or the routed experts (``experts``: the serving rule with a
+    scan's ``stacked``, or its einsum form by name) plus the shared ones."""
     if "router" not in lp:
         return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-    routed = routed_experts(
+    routed = experts(
         h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], cfg.top_k,
-        scoring=cfg.scoring_func, bias=lp["router_bias"],
+        **stacked, scoring=cfg.scoring_func, bias=lp["router_bias"],
         renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
         eps=1e-20)
     return routed + swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
@@ -254,7 +261,9 @@ def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions):
     x = x + o.reshape(b, s, heads * cfg.v_head_dim) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    x = x + _ffn(cfg, h.reshape(b * s, d), lp).reshape(b, s, d)
+    # differentiated, and maybe under ``ctx``'s mesh: the einsum form
+    x = x + _ffn(cfg, h.reshape(b * s, d), lp,
+                 routed_experts_einsum).reshape(b, s, d)
     return ctx.constrain(x, "batch", "seq", "embed_act")
 
 
@@ -305,7 +314,7 @@ def init_paged_cache(cfg: DeepseekConfig, num_blocks: int, block_size: int,
 
 
 def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
-                  block_tables, prefill_tiles=None):
+                  block_tables, prefill_tiles=None, stacks=None):
     """One layer over a flat ragged token batch [T, D]: the step's rows
     ``[c, k_rope, zeros]`` are scattered into the latent pool, then absorbed
     attention reads the cached rows through the layer's block table."""
@@ -336,7 +345,8 @@ def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
     x = x + o.reshape(t_tokens, -1) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    return x + _ffn(cfg, h, lp), {"kv": kv}
+    stacked = (*stacks, lp["first_expert"]) if "first_expert" in lp else None
+    return x + _ffn(cfg, h, lp, routed_experts, stacked=stacked), {"kv": kv}
 
 
 def ragged_forward(cfg: DeepseekConfig, params, tokens, slots, positions,
@@ -347,13 +357,15 @@ def ragged_forward(cfg: DeepseekConfig, params, tokens, slots, positions,
     them (``models/paged.scan_layers_paged``)."""
     from deepspeed_tpu.models.paged import scan_layers_paged
 
+    layers, stacks = expert_stacks(params["layers"])
+
     def layer(x, lp, pool, layer_tables):
         return _ragged_layer(cfg, x, lp, pool, positions, slots, layer_tables,
-                             prefill_tiles=prefill_tiles)
+                             prefill_tiles=prefill_tiles, stacks=stacks)
 
     x = params["embed"][tokens].astype(cache["kv"].dtype)
     x, cache = scan_layers_paged(
-        layer, x, params["layers"], cache, block_tables,
+        layer, x, layers, cache, block_tables,
         lead=[(layer, lp) for lp in _dense_layers(cfg, params)])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     return _lm_head(params, x), cache
@@ -422,4 +434,6 @@ def build(cfg: DeepseekConfig, ctx: ShardCtx | None = None,
         init_paged_cache_fn=partial(init_paged_cache, cfg),
         ragged_forward_fn=partial(ragged_forward, cfg),
         supports_prefill_tiles=True,
+        moe_form=partial(expert_form, num_experts=cfg.num_experts,
+                         top_k=cfg.top_k),
     )

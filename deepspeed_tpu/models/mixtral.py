@@ -18,7 +18,12 @@ from jax import lax
 
 from deepspeed_tpu.config.config import MoEConfig
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx, causal_lm_loss
-from deepspeed_tpu.models.experts import routed_experts
+from deepspeed_tpu.models.experts import (
+    expert_form,
+    expert_stacks,
+    routed_experts,
+    routed_experts_einsum,
+)
 from deepspeed_tpu.models.llama import rmsnorm
 from deepspeed_tpu.ops.attention import apply_rope
 from deepspeed_tpu.parallel.moe import moe_ffn
@@ -193,8 +198,10 @@ def _cached_layer(cfg: MixtralConfig, x, lp, k_cache, v_cache, start_pos,
     x = x + o.reshape(b, t, hq * hd) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    y = routed_experts(h.reshape(b * t, d), lp["router"], lp["w_gate"],
-                       lp["w_up"], lp["w_down"], cfg.top_k)
+    # may run under ``InferenceEngine``'s tensor-parallel mesh
+    y = routed_experts_einsum(h.reshape(b * t, d), lp["router"],
+                              lp["w_gate"], lp["w_up"], lp["w_down"],
+                              cfg.top_k)
     return x + y.reshape(b, t, d), k_cache, v_cache
 
 
@@ -234,7 +241,7 @@ def init_paged_cache(cfg: MixtralConfig, num_blocks: int, block_size: int,
 
 
 def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
-                  block_tables, prefill_tiles=None):
+                  block_tables, prefill_tiles=None, stacks=None):
     """One decoder layer over a flat ragged token batch [T, D]: paged
     attention identical to the Llama ragged layer, MoE FFN routed per token
     (decode tokens route through the SAME per-token top-k machinery as
@@ -263,8 +270,9 @@ def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
     x = x + o.reshape(t_tokens, hq * hd) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    x = x + routed_experts(h, lp["router"], lp["w_gate"], lp["w_up"],
-                           lp["w_down"], cfg.top_k)
+    x = x + routed_experts(
+        h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], cfg.top_k,
+        stacked=stacks and (*stacks, lp["first_expert"]))
     return x, kc, vc
 
 
@@ -276,15 +284,15 @@ def ragged_forward(cfg: MixtralConfig, params, tokens, slots, positions,
     from deepspeed_tpu.models.paged import scan_layers_paged
 
     x = params["embed"][tokens].astype(cache["k"].dtype)
+    layers, stacks = expert_stacks(params["layers"])
 
     def layer(x, lp, pool, layer_tables):
         x, kc, vc = _ragged_layer(
             cfg, x, lp, pool["k"], pool["v"], positions, slots, layer_tables,
-            prefill_tiles=prefill_tiles)
+            prefill_tiles=prefill_tiles, stacks=stacks)
         return x, {"k": kc, "v": vc}
 
-    x, cache = scan_layers_paged(layer, x, params["layers"], cache,
-                                 block_tables)
+    x, cache = scan_layers_paged(layer, x, layers, cache, block_tables)
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 
@@ -339,4 +347,6 @@ def build(cfg: MixtralConfig, ctx: ShardCtx | None = None, attn_impl: str = "aut
         init_paged_cache_fn=partial(init_paged_cache, cfg),
         ragged_forward_fn=partial(ragged_forward, cfg),
         supports_prefill_tiles=True,
+        moe_form=partial(expert_form, num_experts=cfg.num_experts,
+                         top_k=cfg.top_k),
     )

@@ -19,6 +19,7 @@ from deepspeed_tpu.ops.pallas.mla_attention import (
     mla_prefill_attention,
     mla_prefill_kernel_tile,
 )
+from deepspeed_tpu.ops.pallas.moe_gmm import ROW_ALIGN, grouped_swiglu
 from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_decode_attention,
     prefill_kernel_tile,
@@ -86,6 +87,33 @@ def _mla_prefill(q, pool, ts, tp, tv, bt):
                                  192 ** -0.5, interpret=False)
 
 
+# the grouped expert FFN at the two MoE cells' widths, in the shapes of a
+# full call (``models/experts.py`` gives every step those): (experts, picks a
+# token, hidden, expert FFN, rows, rows a pass)
+MOE_GEOMETRIES = {"mixtral": (8, 2, 4096, 14336, 512, 128),
+                  "moonlight": (64, 6, 2048, 1408, 512, 64)}
+
+
+def _moe_gmm(tm, max_rows):
+    def kernel(x, w_gate, w_up, w_down, row0, counts):
+        return grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm,
+                              max_rows, interpret=False)
+
+    return kernel
+
+
+def _moe_args(geometry, devices):
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    e, k, d, f, t, tm = MOE_GEOMETRIES[geometry]
+    rows = -(-(t * k + e * (ROW_ALIGN - 1)) // ROW_ALIGN) * ROW_ALIGN + tm
+    return (s((rows, d)), s((e, d, f)), s((e, d, f)), s((e, f, d)),
+            s((e,), jnp.int32), s((e,), jnp.int32))
+
+
 def _args(kernel, hq, hkv, d, devices):
     dev = jax.sharding.SingleDeviceSharding(devices[0])
 
@@ -132,13 +160,26 @@ def test_mla_kernel_compiles_for_v5e(v5e, kernel):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+@pytest.mark.parametrize("geometry", sorted(MOE_GEOMETRIES))
+def test_moe_gmm_compiles_for_v5e(v5e, geometry):
+    """At the published widths: the hand-written
+    DMA of ``tm`` rows at a dynamic ``ROW_ALIGN``-ed row, the dynamic trip
+    counts, and the VMEM the weight tiles and an expert's rows take under
+    the raised limit."""
+    *_, t, tm = MOE_GEOMETRIES[geometry]
+    compiled = jax.jit(_moe_gmm(tm, t)).lower(
+        *_moe_args(geometry, v5e)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize("kernel,name", [
     (_flash_fwd, "flash_fwd"), (_flash_bwd, "flash_bwd_dkv"),
     (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
     (_prefill, "tiled_prefill"), (_mla_decode, "mla_decode"),
-    (_mla_prefill, "mla_prefill")],
+    (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
-         "tiled_prefill", "mla_decode", "mla_prefill"])
+         "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     """``pl.pallas_call(name=...)``: the compiled custom call is
     ``%<name>.N`` (``%transpose_jvp_<name>__.N`` under a bare ``jax.grad``),
@@ -154,8 +195,13 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     for f in os.listdir(kernels):
         with open(os.path.join(kernels, f)) as fh:
             patterns[f[:-5]] = re.compile(json.load(fh)["trace_pattern"])
-    text = jax.jit(kernel).lower(
-        *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
+    if kernel == "moe_gmm":
+        *_, t, tm = MOE_GEOMETRIES["moonlight"]
+        text = jax.jit(_moe_gmm(tm, t)).lower(
+            *_moe_args("moonlight", v5e)).compile().as_text()
+    else:
+        text = jax.jit(kernel).lower(
+            *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
     calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
     mine = [ln for ln in calls if patterns[name].search(ln)]
@@ -327,6 +373,100 @@ def test_step_program_holds_no_layer_slice_of_the_pool(v5e, monkeypatch,
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
 
 
+@pytest.mark.parametrize("family,rows,tiles", [("mixtral", 8, 3),
+                                               ("moonlight", 128, 1)])
+def test_grouped_step_copies_no_expert_weights(v5e, monkeypatch, family, rows,
+                                               tiles):
+    """A Mosaic kernel's operand is an array in HBM: handed a layer scan's
+    slice of the stacked expert weights, the step copied a layer's whole
+    expert weights (2.8 GB at Mixtral's widths) every layer of every step,
+    and ran at half the einsum's speed on the chip (PR 27). The scan closes
+    over the weights whole (``experts.expert_stacks``) and ``moe_gmm``
+    addresses the layer in them: its weight operands are every layer's
+    experts, and nothing shaped like one layer's gets a buffer."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mod, cfg, blocks, block, table = _step_family(family)
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        mod.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: mod.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16))
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return mod.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    t = rows + tiles * TILE
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(129, table), i32(tiles), i32(tiles), i32(tiles)
+    ).compile().as_text()
+    e, d = cfg.num_experts, cfg.hidden_size
+    f = getattr(cfg, "moe_intermediate_size", cfg.intermediate_size)
+    layer_weights = (f"bf16[{e},{d},{f}]", f"bf16[{e},{f},{d}]")
+    assert [ln for _, _, ln in _materialized(text)
+            if ln.split(" = ")[1].startswith(layer_weights)] == []
+    (call,) = [ln for ln in text.splitlines()
+               if " custom-call(" in ln and "moe_gmm" in ln.split(" = ")[0]]
+    layouts = call.split("operand_layout_constraints=")[1]
+    n = e * (cfg.num_layers - getattr(cfg, "first_k_dense", 0))
+    assert layouts.count(f"bf16[{n},{d},{f}]") == 2      # every layer's
+    assert layouts.count(f"bf16[{n},{f},{d}]") == 1
+
+
+def test_decode_step_relays_out_no_expert_stack(v5e, monkeypatch):
+    """The einsum form reads the scan's own slice of the expert weights,
+    which XLA fuses into the einsums. Given every layer's weights whole (what
+    the grouped kernel takes), it re-laid the ``w_down`` stack out for the
+    einsum outside the layer loop: 2.6 GB copied every decode step at
+    Moonlight's widths, -10% in the cell (PR 27). Those widths, 3 expert
+    layers, the 128-row decode program: its temporaries stay far under one
+    expert matrix of a layer."""
+    from deepspeed_tpu.models import deepseek
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = deepseek.DeepseekConfig(vocab_size=512, num_layers=4)
+    assert (cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
+            cfg.top_k) == (2048, 1408, 64, 6)
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        deepseek.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: deepseek.init_paged_cache(
+        cfg, 65, 128, jnp.bfloat16))
+
+    def step(params, cache, tokens, slots, positions, tables):
+        return deepseek.ragged_forward(cfg, params, tokens, slots, positions,
+                                       tables, cache)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(128), i32(128), i32(128),
+        i32(129, 32)).compile()
+    one_matrix = (cfg.num_experts * cfg.hidden_size
+                  * cfg.moe_intermediate_size * 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < one_matrix // 8
+
+
 def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
     """The paged contract on a pool that is ONE leaf (``deepseek``, MLA): a
     mixed step (128 decode rows beside a prefill tile) with a donated pool
@@ -375,8 +515,12 @@ def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
     pool_shape = f"bf16[{cfg.num_layers * blocks},{block},{MLA_WIDTH}]"
     kernels = [ln for ln in text.splitlines()
                if " custom-call(" in ln and "tpu_custom_call" in ln]
-    assert len(kernels) == 4              # decode and prefill, twice each
-    for ln in kernels:
+    # at 256 rows the scan body's routed experts take the grouped form
+    grouped = [ln for ln in kernels if "moe_gmm" in ln.split(" = ")[0]]
+    attention = [ln for ln in kernels if ln not in grouped]
+    assert len(grouped) == 1
+    assert len(attention) == 4            # decode and prefill, twice each
+    for ln in attention:
         layouts = ln.split("operand_layout_constraints=")[1]
         assert layouts.count(pool_shape) == 1, ln
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice

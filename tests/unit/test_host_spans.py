@@ -298,6 +298,14 @@ FIXTURE_OF = {"mixtral-8x7b-d3.longdoc-pool": "v5e_mixtral_longdoc_spans",
               "moonlight-16b-a3b-d8.reason-pool": "v5e_moonlight_reason_spans"}
 
 
+# readers of what PR 27 added to the program (the dispatch span's ``moe``,
+# the ``moe_gmm`` kernel) read a slice recorded with it
+FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans",
+                     "kernel.moe_gmm_share": "v5e_moonlight_reason_moe_spans"}
+CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
+                   "moonlight-16b-a3b-d8.reason-pool"}
+
+
 def _new_readers():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -308,23 +316,25 @@ def _new_readers():
             text = f.read()
         if "host_spans" not in text and "latent_spans" not in text:
             continue  # a reader from before the spans
-        cell = next(c for c in m["workloads"] if c in FIXTURE_OF)
-        cases.append((m["name"], cell))
+        fixture = FIXTURE_OF_METRIC.get(m["name"])
+        cell = (CELL_OF_FIXTURE[fixture] if fixture
+                else next(c for c in m["workloads"] if c in FIXTURE_OF))
+        cases.append((m["name"], cell, fixture or FIXTURE_OF[cell]))
     return cases
 
 
-@pytest.mark.parametrize("metric,cell", _new_readers())
-def test_a_reader_on_a_slice_recorded_on_the_chip(bench, metric, cell):
+@pytest.mark.parametrize("metric,cell,fixture", _new_readers())
+def test_a_reader_on_a_slice_recorded_on_the_chip(bench, metric, cell, fixture):
     cellspec, host_spans = bench
-    with open(os.path.join(FIXTURES, FIXTURE_OF[cell] + ".expect.json")) as f:
+    with open(os.path.join(FIXTURES, fixture + ".expect.json")) as f:
         expect = json.load(f)
     read = cellspec.layer_readers(cellspec.resolve(cell))[metric][1]
-    value = read(_ctx(cellspec, cell, _timeline(FIXTURE_OF[cell])))
+    value = read(_ctx(cellspec, cell, _timeline(fixture)))
     assert value == pytest.approx(expect[metric], rel=1e-6)
     if metric.endswith("_roofline") or "roofline" in metric or metric.endswith("_share"):
         assert 0.0 <= value <= 100.0
     # a program that writes no span (the parent commit): no value, no error
-    bare = dict(_timeline(FIXTURE_OF[cell]), host=[])
+    bare = dict(_timeline(fixture), host=[])
     bare["kernels"] = {k: [] for k in bare["kernels"]}
     assert read(_ctx(cellspec, cell, bare)) is None
 
