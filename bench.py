@@ -273,8 +273,7 @@ def serve_trial_main():
         # budget/max_seqs sized so the whole load admits in one wave and
         # prefill takes few dispatches (what a host dispatch costs on a
         # local chip is not measured on today's code)
-        max_seqs, budget, block, tile, ahead = 32, 1024, 32, 128, 48
-        fused, depth = 16, 3
+        max_seqs, budget, block, tile = 32, 1024, 32, 128
     else:
         model_cfg = llama.LlamaConfig(
             vocab_size=512, hidden_size=256, intermediate_size=688,
@@ -282,8 +281,7 @@ def serve_trial_main():
         )
         n_req, max_new, max_prompt = 6, 8, 64
         prompt_lens = [16, 32, 64]
-        max_seqs, budget, block, tile, ahead = 4, 64, 16, 16, 8
-        fused, depth = 4, 2
+        max_seqs, budget, block, tile = 4, 64, 16, 16
 
     # request-lifecycle spans (queue wait, TTFT, per-token decode latency,
     # preemptions) for every ragged request in this trial
@@ -305,27 +303,17 @@ def serve_trial_main():
     rcfg = RaggedConfig(
         max_tokens_per_step=budget, max_seqs=max_seqs, block_size=block,
         num_blocks=max_seqs * mbs + 1, max_blocks_per_seq=mbs,
-        # fused multi-step decode: without it, one dispatch per generated
-        # token
-        decode_run_ahead=int(e.get("BENCH_RUN_AHEAD", ahead)),
         # tiled prefill: one KV-block fetch per tile instead of per token
         # (the per-token decode kernel is O(context) DMA per token,
         # ~tile x redundant on prefill chunks)
         prefill_tile=int(e.get("BENCH_PREFILL_TILE", tile)),
-        # fused mixed chunks + async dispatch window: prompt chunks ride
-        # step 0 of the same K-step program the decodes run ahead in, and
-        # chunk t+1 dispatches before chunk t's readback — arrivals no
-        # longer collapse the engine to one dispatch per token (the round-4
-        # staggered-latency fix)
-        fused_chunk=int(e.get("BENCH_FUSED_CHUNK", fused)),
-        pipeline_depth=int(e.get("BENCH_PIPELINE_DEPTH", depth)),
     )
     ragged = RaggedInferenceEngine(
         model=lambda ctx: llama.build(model_cfg, ctx=ctx),
         ragged_config=rcfg, seed=0,
     )
-    # precompile the fused program zoo (fills the persistent cache; without
-    # it, shape combos first hit mid-serve cost 4-5 s stalls each)
+    # turns the persistent compile cache on (and precompiles the K-step
+    # scheduler family when sched_steps is set: none here)
     t0 = time.perf_counter()
     nwarm = ragged.warmup()
     print(f"# ragged warmup: {nwarm} programs in "
@@ -460,220 +448,6 @@ def serve_trial_main():
         "serve_max_new": max_new,
         "telemetry_jsonl": tel_path,
     }))
-
-
-def decode_steady_main():
-    """Child process: steady-state decode dispatch-overhead benchmark.
-
-    The PR-4 target: once every live sequence is decoding, the engine's
-    per-dispatch host work should be admission-free — device-resident
-    scheduler rows, delta-synced block table, one packed staging buffer,
-    double-buffered readback. This trial runs the SAME pure-decode workload
-    through (a) the device-resident path, (b) the legacy host-staged path
-    (``device_state=False``), and (c) the dense padded engine, and reports
-    tokens/s plus a host-staging vs readback vs H2D breakdown per dispatch.
-    It then re-checks token parity (device vs host-staged) across all four
-    dispatch modes with greedy and seeded sampling — a perf path that
-    changes tokens is a non-result. One JSON line out.
-    """
-    import numpy as np
-    import jax
-
-    from deepspeed_tpu.inference.engine import InferenceEngine
-    from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
-    from deepspeed_tpu.models import llama
-
-    e = os.environ
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        model_cfg = llama.LlamaConfig(
-            vocab_size=32768, hidden_size=2048, intermediate_size=5632,
-            num_layers=8, num_heads=16, num_kv_heads=8, max_seq_len=1024)
-        n_req, prompt_len, max_new = 16, 64, 96
-        max_seqs, budget, block, ahead = 16, 256, 32, 32
-        fused, depth, tile = 16, 3, 64
-        sched_k, econ_k, econ_new = 16, 128, 190
-    else:
-        model_cfg = llama.LlamaConfig(
-            vocab_size=512, hidden_size=256, intermediate_size=688,
-            num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=256)
-        n_req, prompt_len = 4, 16
-        max_new = int(e.get("BENCH_STEADY_MAX_NEW", 24))
-        max_seqs, budget, block, ahead = 4, 64, 16, 8
-        fused, depth, tile = 4, 2, 16
-        sched_k, econ_k, econ_new = 8, 128, 190
-
-    rng = np.random.default_rng(0)
-    # equal-length prompts: the dense baseline then pads nothing, so the
-    # ragged-vs-dense ratio isolates dispatch overhead, not padding waste
-    prompts = [rng.integers(0, model_cfg.vocab_size, (prompt_len,),
-                            dtype=np.int32) for _ in range(n_req)]
-    mbs = -(-(prompt_len + max_new) // block)
-    build_model = lambda ctx: llama.build(model_cfg, ctx=ctx)  # noqa: E731
-
-    def build(device_state, **over):
-        kw = dict(max_tokens_per_step=budget, max_seqs=max_seqs,
-                  block_size=block, num_blocks=max_seqs * mbs + 1,
-                  max_blocks_per_seq=mbs, decode_run_ahead=ahead,
-                  prefill_tile=tile, fused_chunk=fused, pipeline_depth=depth,
-                  device_state=device_state)
-        kw.update(over)
-        return RaggedInferenceEngine(
-            model=build_model, ragged_config=RaggedConfig(**kw), seed=0)
-
-    def run(engine, tag):
-        for i, p in enumerate(prompts):
-            engine.put((tag, i), p, max_new_tokens=max_new)
-        return engine.generate_all()
-
-    def measure(device_state, **over):
-        engine = build(device_state, **over)
-        run(engine, "warm")  # compiles every bucket this workload hits
-        # reset the dispatch-overhead meters: the warmup pass pays tracing +
-        # compilation on the host, which is not steady-state staging cost
-        engine.host_stage_ns = engine.readback_ns = 0
-        engine.h2d_bytes = engine._h2d_seen = 0
-        d0 = engine.dispatch_count
-        t0 = time.perf_counter()
-        out = run(engine, "run")
-        dt = time.perf_counter() - t0
-        disp = max(engine.dispatch_count - d0, 1)
-        toks = sum(len(v) for v in out.values())
-        return {
-            "tokens_per_s": round(toks / dt, 1),
-            "host_stage_ms_per_step": round(
-                engine.host_stage_ns / disp / 1e6, 4),
-            "readback_ms_per_step": round(
-                engine.readback_ns / disp / 1e6, 4),
-            "h2d_bytes_per_step": round(engine.h2d_bytes / disp, 1),
-            "dispatches": disp,
-            "wall_s": round(dt, 3),
-        }, out
-
-    dev, dev_out = measure(True)
-    host, host_out = measure(False)
-    # the PR-10 headline: K decode steps per dispatch via the device-side
-    # multi-step scheduler (speculation stays OFF here — random weights
-    # give the n-gram draft source nothing to match, so acceptance would
-    # only add verify lanes; its win is measured separately below)
-    sch, sch_out = measure(True, sched_steps=sched_k)
-
-    dense = InferenceEngine(model=build_model, seed=0)
-    batch = np.stack(prompts)
-    dense.generate(batch, max_new_tokens=max_new)  # compile
-    t0 = time.perf_counter()
-    dense.generate(batch, max_new_tokens=max_new)
-    dense_tok_s = n_req * max_new / (time.perf_counter() - t0)
-
-    # token parity, all 4 dispatch modes x greedy+seeded, device vs host
-    modes = {
-        "plain": dict(decode_run_ahead=0, prefill_tile=0, fused_chunk=0),
-        "tiled": dict(decode_run_ahead=0, fused_chunk=0),
-        "run_ahead": dict(prefill_tile=0, fused_chunk=0),
-        "fused": {},
-    }
-
-    def parity_run(engine):
-        for i, p in enumerate(prompts[:3]):
-            kw = {} if i == 0 else dict(temperature=0.9, top_k=20,
-                                        top_p=0.9, seed=7 + i)
-            engine.put(i, p, max_new_tokens=6, **kw)
-        return engine.generate_all()
-
-    # three verdicts per mode, all against the plain host-staged streams:
-    # device-resident state, the multi-step scheduler, and scheduler +
-    # self-speculation (exact-match verify => must be token-identical)
-    parity, sched_parity, spec_parity = {}, {}, {}
-    for name, over in modes.items():
-        base = parity_run(build(False, **over))
-        parity[name] = parity_run(build(True, **over)) == base
-        sched_parity[name] = parity_run(
-            build(True, sched_steps=sched_k, **over)) == base
-        spec_parity[name] = parity_run(
-            build(True, sched_steps=sched_k, spec_draft=4, **over)) == base
-
-    # speculation acceptance on a draftable workload: a repetitive prompt
-    # gives the n-gram source real matches (random weights + random prompts
-    # would measure nothing)
-    spec_eng = build(True, sched_steps=sched_k, spec_draft=4)
-    pat = list(rng.integers(0, model_cfg.vocab_size, (5,))) * 4
-    spec_eng.put("rep", np.asarray(pat, np.int32), max_new_tokens=max_new)
-    spec_eng.generate_all()
-    spec_rate = spec_eng.spec_accepted / max(spec_eng.spec_proposed, 1)
-
-    # dispatch economy under staggered arrivals: requests trickle in, and
-    # once the LAST arrival reaches steady decode the scheduler should run
-    # the whole remaining tail at K steps per dispatch — dispatches per
-    # token over that steady segment is what the per-dispatch host cost
-    # multiplies
-    mbs_econ = -(-(prompt_len + econ_new) // block)
-    econ = RaggedInferenceEngine(
-        model=build_model, ragged_config=RaggedConfig(
-            max_tokens_per_step=budget, max_seqs=max_seqs,
-            block_size=block, num_blocks=max_seqs * mbs_econ + 1,
-            max_blocks_per_seq=mbs_econ, sched_steps=econ_k), seed=0)
-    fed = 0
-    d0 = t0 = None
-    for step_i in range(100000):
-        # one arrival per engine turn: each new request prefillls while the
-        # earlier ones decode, so no row ever runs a deep solo chunk before
-        # the batch fills
-        if fed < n_req:
-            econ.put(fed, prompts[fed], max_new_tokens=econ_new)
-            fed += 1
-        if not econ.has_work:
-            break
-        econ.step()
-        if (d0 is None and fed == n_req and not econ._queued
-                and all(s.in_decode for s in econ._running.values())):
-            d0, t0 = econ.dispatch_count, econ.tokens_emitted
-    econ.drain()
-    econ_disp = econ.dispatch_count - d0
-    econ_toks = max(econ.tokens_emitted - t0, 1)
-    stag_dpt = round(econ_disp / econ_toks, 4)
-
-    print(json.dumps({
-        "steady_ragged_tokens_per_s": sch["tokens_per_s"],
-        "steady_ragged_no_sched_tokens_per_s": dev["tokens_per_s"],
-        "steady_host_staged_tokens_per_s": host["tokens_per_s"],
-        "steady_dense_tokens_per_s": round(dense_tok_s, 1),
-        # the headline: multi-step scheduled decode vs the dense padded
-        # engine (was 0.276 with one host dispatch per token-step)
-        "steady_ragged_vs_dense": round(
-            sch["tokens_per_s"] / dense_tok_s, 3),
-        "steady_ragged_vs_dense_no_sched": round(
-            dev["tokens_per_s"] / dense_tok_s, 3),
-        "ragged_vs_dense": round(sch["tokens_per_s"] / dense_tok_s, 3),
-        # how much per-dispatch host staging the device-resident path
-        # removed vs the pre-PR host-staged path
-        "steady_staging_reduction": round(
-            host["host_stage_ms_per_step"]
-            / max(dev["host_stage_ms_per_step"], 1e-9), 2),
-        "steady_device_state": dev,
-        "steady_host_staged": host,
-        "steady_sched": sch,
-        "steady_sched_steps": sched_k,
-        "steady_dispatches_per_token": round(
-            sch["dispatches"] / max(n_req * max_new, 1), 4),
-        # dispatch economy over the steady tail of a staggered-arrival run
-        # (scheduler depth econ_k, generation econ_new)
-        "staggered_dispatches_per_token": stag_dpt,
-        "staggered_econ_dispatches": econ_disp,
-        "staggered_econ_tokens": econ_toks,
-        "steady_outputs_match": dev_out == host_out and sch_out == host_out,
-        "steady_parity": parity,
-        "steady_sched_parity": sched_parity,
-        "steady_spec_parity": spec_parity,
-        "steady_spec_proposed": spec_eng.spec_proposed,
-        "steady_spec_accepted": spec_eng.spec_accepted,
-        "steady_spec_acceptance_rate": round(spec_rate, 3),
-        "steady_reqs": n_req,
-        "steady_max_new": max_new,
-    }))
-
-
-def run_decode_steady_subprocess(timeout: float = 900.0):
-    return _run_flagged_subprocess("BENCH_DECODE_STEADY", timeout)
 
 
 def train_anatomy_main():
@@ -1138,16 +912,16 @@ def serving_bench_main():
             num_layers=8, num_heads=16, num_kv_heads=8, max_seq_len=1024)
         n_req, max_new, rate = 48, 48, 8.0
         prompt_lens = [64, 128, 256, 512]
-        max_seqs, budget, block, tile, ahead = 32, 1024, 32, 128, 48
-        fused, depth, max_prompt = 16, 3, 512
+        max_seqs, budget, block, tile = 32, 1024, 32, 128
+        max_prompt = 512
     else:
         model_cfg = llama.LlamaConfig(
             vocab_size=512, hidden_size=256, intermediate_size=688,
             num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=256)
         n_req, max_new, rate = 10, 8, 4.0
         prompt_lens = [16, 32, 64]
-        max_seqs, budget, block, tile, ahead = 4, 64, 16, 16, 8
-        fused, depth, max_prompt = 4, 2, 64
+        max_seqs, budget, block, tile = 4, 64, 16, 16
+        max_prompt = 64
     n_req = int(e.get("BENCH_SERVING_REQUESTS", n_req))
     rate = float(e.get("BENCH_SERVING_RATE", rate))  # arrivals per second
     # shared-prefix workload (--shared-prefix-tokens): every prompt opens
@@ -1191,8 +965,7 @@ def serving_bench_main():
     rcfg = RaggedConfig(
         max_tokens_per_step=budget, max_seqs=max_seqs, block_size=block,
         num_blocks=num_blocks, max_blocks_per_seq=mbs,
-        decode_run_ahead=ahead, prefill_tile=tile,
-        fused_chunk=fused, pipeline_depth=depth,
+        prefill_tile=tile,
         enable_prefix_cache=shared_prefix > 0 or kv_tier,
         kv_tier=kv_tier,
         kv_tier_host_blocks=4 * mbs,
@@ -2193,8 +1966,7 @@ def _chaos_bench_impl():
     def make_engine():
         rcfg = RaggedConfig(
             max_tokens_per_step=16, max_seqs=3, block_size=4, num_blocks=49,
-            max_blocks_per_seq=16, decode_run_ahead=4, prefill_tile=8,
-            fused_chunk=4, pipeline_depth=2, device_state=True,
+            max_blocks_per_seq=16, prefill_tile=8, device_state=True,
             dispatch_retries=2, retry_backoff_s=0.01, degrade_after=2)
         return RaggedInferenceEngine(
             model=lambda ctx: llama.build(model_cfg, ctx=ctx),
@@ -3203,7 +2975,7 @@ def smoke_main():
         perf["sparse_local_ms"] = round(timeit(sp, qs), 2)
         perf["dense_same_shape_ms"] = round(timeit(dn, qs), 2)
 
-    @run("ragged_fused_serve")
+    @run("ragged_tiled_serve")
     def _serve():
         from deepspeed_tpu.inference.engine import InferenceEngine
         from deepspeed_tpu.inference.ragged import (
@@ -3230,12 +3002,11 @@ def smoke_main():
             dtype=jnp.float32,
             ragged_config=RaggedConfig(
                 max_tokens_per_step=64, max_seqs=4, block_size=16,
-                num_blocks=33, max_blocks_per_seq=8, fused_chunk=4,
-                pipeline_depth=2, prefill_tile=16))
+                num_blocks=33, max_blocks_per_seq=8, prefill_tile=16))
         for u, p in prompts.items():
             eng.put(u, p, max_new_tokens=8)
         got = eng.generate_all()
-        assert got == want, "fused serve != dense greedy"
+        assert got == want, "tiled serve != dense greedy"
 
     ok = all(c["ok"] for c in checks.values())
     print(json.dumps({"smoke_ok": ok, "checks": checks, "perf": perf,
@@ -3397,8 +3168,7 @@ def _probe_serve(overrides, steps):
             engine.put(i, p, max_new_tokens=6, **kw)
         return engine.generate_all()
 
-    dispatch_knobs = ("sched_steps", "spec_draft", "decode_run_ahead",
-                      "prefill_tile", "fused_chunk", "pipeline_depth")
+    dispatch_knobs = ("sched_steps", "spec_draft", "prefill_tile")
     plain = {k: v for k, v in overrides.items() if k not in dispatch_knobs}
     parity_ok = (parity_run(build(device_state=False, **plain))
                  == parity_run(build(**overrides)))
@@ -3528,7 +3298,7 @@ def autotune_bench_main():
         probe_runner=runner, profile_dir=profile_dir).tune()
     serve = KnobSearch(
         SERVE, model_info=info, steps=steps,
-        knob_names=("sched_steps", "fused_chunk"),
+        knob_names=("sched_steps", "prefill_tile"),
         probe_runner=runner, profile_dir=profile_dir).tune()
 
     # --- round trip 1: a fresh initialize() loads the train profile ------
@@ -3650,15 +3420,6 @@ def enable_compile_cache():
 def main():
     if "--mode" in sys.argv:
         mode = sys.argv[sys.argv.index("--mode") + 1:][:1]
-        if mode == ["decode-steady"]:
-            result, err = run_decode_steady_subprocess()
-            if result is None:
-                print(f"decode-steady bench failed:\n{_err_text(err)}",
-                      file=sys.stderr)
-                _fail_json(err)
-                return 1
-            print(json.dumps(result))
-            return 0
         if mode == ["chaos"]:
             result, err = run_chaos_subprocess()
             if result is None:
@@ -3733,7 +3494,7 @@ def main():
             return 0 if result.get("autotune_ok") else 1
         if mode != ["serving"]:
             print(f"bench: unknown --mode {mode or '(missing)'}; "
-                  "supported: serving, decode-steady, chaos, train-anatomy, "
+                  "supported: serving, chaos, train-anatomy, "
                   "train-chaos, pipeline, fleet, probe, autotune",
                   file=sys.stderr)
             return 2
@@ -3851,9 +3612,6 @@ def main():
     if os.environ.get("BENCH_SERVE"):
         enable_compile_cache()
         return serve_trial_main()
-    if os.environ.get("BENCH_DECODE_STEADY"):
-        enable_compile_cache()
-        return decode_steady_main()
     if os.environ.get("BENCH_TRAIN_ANATOMY"):
         # no shared jit cache: recompile accounting is part of what this
         # trial measures, so cold compiles must be real

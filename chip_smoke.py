@@ -612,7 +612,7 @@ def serve_phase(cfg, rcfg, requests: list, *, sample: int,
         config={k: getattr(rcfg, k) for k in (
             "max_tokens_per_step", "max_seqs", "block_size", "num_blocks",
             "max_blocks_per_seq", "device_state", "prefill_tile",
-            "sched_steps", "fused_chunk")},
+            "sched_steps")},
         warmup={"programs": warmed, "seconds": round(warmup_s, 2)},
         load_seconds=round(load_s, 2),
         requests={"n": len(requests),
@@ -794,9 +794,9 @@ def main(argv=None) -> int:
                 seq_len=SEQ_LEN, steps=TRAIN_STEPS, expect_kernels=True)
             # what a deployment can set on this chip today: scheduler state
             # on the device (the default) and prompts through the tiled
-            # prefill kernel — and neither fused chunks nor sched_steps. The
-            # rehearsal shows why: a K-step program (sched_steps, fused_chunk
-            # and decode_run_ahead alike) holds the KV pool about three times
+            # prefill kernel — and not sched_steps. The rehearsal shows why:
+            # a K-step program (sched_steps, and the two older K-step modes
+            # PR 28 removed alike) holds the KV pool about three times
             # over, and with 48 layers and this pool the TPU compiler
             # refuses it (21.9 GB of 15.75; it fits below ~8K tokens of
             # pool). So decode is one dispatch per token here (PERF.md §7).

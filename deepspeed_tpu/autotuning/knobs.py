@@ -1,9 +1,8 @@
 """KnobSpace: the registry of tunable performance knobs (docs/AUTOTUNING.md).
 
-The framework grew ~20 interacting perf knobs across two engines (dispatch
-mode x sched_steps x spec_draft x prefill_tile x fused_chunk x kv budgets x
-quant codec x grad_overlap bucket/sharding x pipeline shape x headroom
-guard). The search driver (autotuner.KnobSearch) needs three facts per knob
+The framework grew ~20 interacting perf knobs across two engines
+(sched_steps x spec_draft x prefill_tile x kv budgets x quant codec x
+grad_overlap bucket/sharding x pipeline shape x headroom guard). The search driver (autotuner.KnobSearch) needs three facts per knob
 that the config dataclasses don't carry:
 
 - its **domain** — the candidate values worth measuring;
@@ -158,14 +157,8 @@ def _build_default_space() -> KnobSpace:
     # ---- serve (RaggedConfig field names) ----
     s.register(Knob("sched_steps", SERVE, (0, 8, 16), 0,
                     doc="device-side multi-step decode scheduler depth"))
-    s.register(Knob("fused_chunk", SERVE, (0, 4, 16), 0,
-                    doc="fused mixed-chunk dispatch depth"))
-    s.register(Knob("decode_run_ahead", SERVE, (0, 8, 32), 0,
-                    doc="all-decode run-ahead scan depth"))
     s.register(Knob("prefill_tile", SERVE, (0, 16, 64), 0,
                     doc="tiled prefill kernel tile"))
-    s.register(Knob("pipeline_depth", SERVE, (2, 3), 2,
-                    doc="fused-chunk pipelining depth"))
     s.register(Knob("spec_draft", SERVE, (0, 4), 0,
                     doc="self-speculative draft depth"))
     s.register(Knob("enable_prefix_cache", SERVE, (False, True), False,

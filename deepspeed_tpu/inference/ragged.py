@@ -52,6 +52,11 @@ from deepspeed_tpu.telemetry.tracing import format_traceparent
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.tracing import span
 
+# With arrivals queued but UNADMITTABLE (a free slot exists yet the KV pool
+# cannot cover the reservation) a multi-step scheduler dispatch still runs up
+# to this many decode steps: decode progress is exactly what frees blocks.
+SCHED_ADMISSION_CAP = 8
+
 
 class BlockedAllocator:
     """Ref-counted free-list allocator over the KV block pool
@@ -224,34 +229,11 @@ class RaggedConfig:
     block_size: int = 16
     num_blocks: int = 257  # 256 usable + scratch
     max_blocks_per_seq: int = 32
-    # decode run-ahead: when the scheduler has no prefill or admission work,
-    # run up to this many decode steps inside ONE jitted lax.scan (greedy
-    # next-token fed back on device) instead of one dispatch per token —
-    # the multi-step-scheduling idiom of continuous-batching engines: one
-    # host dispatch per K tokens instead of one per token. 0 disables.
-    decode_run_ahead: int = 0
     # tiled prefill: lay prefill chunks at tile-aligned offsets so the tiled
     # Pallas kernel fetches each KV block once per TILE instead of once per
     # token (ops/pallas ragged_prefill_attention — the SplitFuse blocked
     # flash attention). 0 disables (per-token kernel for everything).
     prefill_tile: int = 0
-    # with arrivals queued but UNADMITTABLE (a free slot exists yet the KV
-    # pool can't cover the reservation), run-ahead still fuses up to this
-    # many decode steps per dispatch — decode progress is exactly what frees
-    # blocks; admittable requests are admitted before run-ahead is even
-    # considered. Only active when decode_run_ahead is set.
-    run_ahead_admission_cap: int = 8
-    # fused mixed chunks (>= 2 enables): EVERY dispatch is one program that
-    # runs the mixed SplitFuse step (decodes + prefill chunks) and then
-    # fused_chunk-1 further decode steps for the decode rows, next tokens
-    # fed back on device. Unlike decode_run_ahead (which only engages when
-    # every running sequence decodes), arrivals never break the fusion.
-    fused_chunk: int = 0
-    # how many fused chunks may be in flight undispatched-results-wise:
-    # chunk t+1 is dispatched before chunk t's tokens are read back, the
-    # next-token feed riding a device-resident per-slot buffer (bounded
-    # speculation; EOS reconciled on readback)
-    pipeline_depth: int = 2
     # device-resident scheduler state (the steady-state decode fix): slot
     # rows (last token / position / seed / prompt length / sampling params)
     # live in persistent device arrays updated in place by donated jitted
@@ -259,8 +241,10 @@ class RaggedConfig:
     # dirty-row delta upload — so a steady decode step stages NO per-row
     # host arrays (the packed staging buffer byte-compares equal and is
     # reused) and token readback for dispatch t overlaps dispatch t+1.
-    # False restores the legacy host-staged dispatch path (token-identical;
-    # kept as the parity baseline and an escape hatch).
+    # False takes the host-staged step instead (in ``_step_impl``: the same
+    # packer, token and position fed from host state, tokens read back at
+    # once) — token-identical, the tests' reference and rung 1 of the
+    # watchdog's ladder.
     device_state: bool = True
     # device-side multi-step decode scheduler (>= 2 enables): when every
     # running sequence is decoding, ONE jitted program runs up to
@@ -269,7 +253,7 @@ class RaggedConfig:
     # slot and early-exits when all rows retire), returning per-slot
     # steps_taken so the host only reconciles — no per-token dispatch and
     # no post-EOS wasted compute. Requires device_state (silently inert
-    # under the host-staged kill switch, which stays token-identical).
+    # under the host-staged step, which stays token-identical).
     sched_steps: int = 0
     # self-speculative decoding depth (> 0 enables; requires
     # sched_steps >= 2): each scheduler iteration proposes up to
@@ -298,8 +282,8 @@ class RaggedConfig:
     # engine-seeded RNG so a replayed run backs off identically
     retry_jitter: float = 0.25
     # consecutive device-path failures that trigger automatic degradation:
-    # device-resident state -> host-staged kill-switch path -> plain-step
-    # fallback (token-identical rungs). 0 disables degradation.
+    # device-resident state -> host-staged step -> the same with prefill
+    # tiles off (token-identical rungs). 0 disables degradation.
     degrade_after: int = 3
     # block-level prefix caching (SGLang/vLLM-style): retired sequences
     # publish their full prompt blocks into a hash-chained index; admission
@@ -402,10 +386,10 @@ class _SeqState:
     # per-request sampling seed: token g of this request draws from
     # fold_in(fold_in(SAMPLE_ROOT, seed), g) — independent of batch
     # composition and dispatch history, so a sampled generation is
-    # reproducible on any engine (cache hit == cold, fused == plain)
+    # reproducible on any engine (cache hit == cold, device == host-staged)
     seed: int = 0
-    # fused-pipeline bookkeeping: chunks dispatched but not yet reconciled
-    # that reference this sequence (release deferred until it drains)
+    # dispatches not yet read back that reference this sequence (release is
+    # deferred until they drain)
     refs: int = 0
     # request-lifecycle telemetry (perf_counter stamps; 0.0 = not recorded):
     # enqueue -> admit is queue wait, enqueue -> first token is TTFT
@@ -676,7 +660,7 @@ class RaggedInferenceEngine:
                 bytes_per_token=self.kv_bytes_per_token(),
                 codec=self._kvq_name,
             )
-            self.allocator.demote_hook = self._demote_block
+            self.allocator.demote_hook = self._demote_hook()
         # row max_seqs is the all-zeros padding row -> scratch block 0
         self.block_tables = np.zeros(
             (self.cfg.max_seqs + 1, self.cfg.max_blocks_per_seq), np.int32
@@ -700,7 +684,6 @@ class RaggedInferenceEngine:
             b *= 2
         self._buckets.append(self.cfg.max_tokens_per_step)
         self._step_jit = self._build_step()
-        self._chunk_jit = None  # decode run-ahead program (lazy)
         self._use_tiles = self.cfg.prefill_tile > 0
         if self._use_tiles and not self.spec.supports_prefill_tiles:
             raise ValueError(
@@ -717,15 +700,6 @@ class RaggedInferenceEngine:
             self._dec_buckets.append(b)
             b *= 2
         self._dec_buckets.append(self.cfg.max_seqs)
-        # fused mixed-chunk pipeline (see RaggedConfig.fused_chunk)
-        self._fused_jits: dict = {}
-        self._inflight_chunks: list = []
-        # per-slot device buffer of the latest emitted token (+1 scratch row):
-        # the next chunk's decode feed reads it ON DEVICE, so chunk t+1 can
-        # dispatch before chunk t's tokens ever reach the host
-        self._slot_toks = jnp.zeros(self.cfg.max_seqs + 1, jnp.int32)
-        # host mirror of which slots have a valid device-side next token
-        self._slot_feed = np.zeros(self.cfg.max_seqs + 1, bool)
         # ---- device-resident scheduler state (cfg.device_state) ----
         # per-slot persistent rows (+1 scratch row at index max_seqs):
         # (last_token, next_position, seed, prompt_len, temp, top_k, top_p).
@@ -761,13 +735,11 @@ class RaggedInferenceEngine:
         # and ZERO uploads when the bytes match the previous dispatch at the
         # same size (the steady-decode case)
         self._staging_cache: dict[int, tuple[bytes, Any]] = {}
-        # double-buffered readback for the non-fused modes: dispatched steps
-        # whose tokens have not been read back yet (depth 1: readback of
-        # step t overlaps the device executing step t+1)
+        # double-buffered readback: dispatched steps whose tokens have not
+        # been read back yet (depth 1: readback of step t overlaps the
+        # device executing step t+1)
         self._pending: list[dict] = []
         self._dev_step_jits: dict = {}
-        self._dev_chunk_jits: dict = {}
-        self._dev_fused_jits: dict = {}
         # device-side multi-step scheduler (cfg.sched_steps) + self-
         # speculative decoding (cfg.spec_draft) program cache
         self._dev_sched_jits: dict = {}
@@ -820,10 +792,6 @@ class RaggedInferenceEngine:
         self._kv_scatter_jits: dict[int, Any] = {}
         self.kv_blocks_exported = 0
         self.kv_blocks_imported = 0
-        if self.cfg.fused_chunk == 1 or self.cfg.fused_chunk < 0:
-            raise ValueError("fused_chunk must be 0 (off) or >= 2")
-        if self.cfg.fused_chunk and self.cfg.pipeline_depth < 1:
-            raise ValueError("pipeline_depth must be >= 1")
         if self.cfg.sched_steps == 1 or self.cfg.sched_steps < 0:
             raise ValueError("sched_steps must be 0 (off) or >= 2")
         if self.cfg.spec_draft:
@@ -866,12 +834,11 @@ class RaggedInferenceEngine:
         self._warmed = False
         # specialization keys already dispatched for the paths whose jit
         # cache is internal to jax (no explicit program dict to probe)
-        self._chunk_keys: set = set()
         self._step_keys: set = set()
         # ---- dispatch watchdog (docs/FAULT_TOLERANCE.md) ----
         # degraded_mode: 0 = full configured path, 1 = host-staged fallback
-        # (device_state flipped off), 2 = plain-step fallback (fused/run-
-        # ahead/tiles disabled). Every rung is token-identical; the ladder
+        # (device_state flipped off), 2 = the same with prefill tiles off
+        # (the plain SplitFuse step). Every rung is token-identical; the ladder
         # trades dispatch efficiency for a smaller failure surface.
         self._faults = get_fault_injector()
         self._retry_rng = random.Random(self._engine_seed ^ 0x5EED)
@@ -912,9 +879,8 @@ class RaggedInferenceEngine:
         """Enqueue a request (reference ``engine_v2.py put()``). Admission into
         the running batch happens inside ``step()`` as slots/budget free up.
         ``temperature``/``top_k``/``top_p`` select per-request sampling
-        (0-temperature = greedy), applied inside the compiled step — sampled
-        decode works under run-ahead and the fused pipeline with no host
-        round trip (``inference/sampling.py``). ``seed`` pins the request's
+        (0-temperature = greedy), applied inside the compiled step with no
+        host round trip (``inference/sampling.py``). ``seed`` pins the request's
         sampling stream: token g draws from a key derived only from
         (seed, g), so the same seeded request yields identical tokens on any
         engine regardless of batch composition, dispatch mode, or prefix-
@@ -993,8 +959,7 @@ class RaggedInferenceEngine:
 
     @property
     def has_work(self) -> bool:
-        return bool(self._queued or self._running or self._inflight_chunks
-                    or self._pending)
+        return bool(self._queued or self._running or self._pending)
 
     @property
     def finished_uids(self):
@@ -1022,8 +987,8 @@ class RaggedInferenceEngine:
         full drain); a serving frontend needs one or a hung client leaks KV
         pages forever. A queued request is dropped and a running one releases
         its KV blocks on the next ``step()`` (``_release`` via the normal
-        retirement machinery — under the fused pipeline the release defers
-        until in-flight chunks referencing the sequence reconcile). The
+        retirement machinery — the release defers until the pending
+        dispatches referencing the sequence reconcile). The
         request span is emitted with ``status=cancelled``. Returns False if
         the uid is unknown or already retired."""
         for seq in self._queued:
@@ -1041,8 +1006,8 @@ class RaggedInferenceEngine:
         at the top of every step, so an abort can never outlive one step
         boundary. Queued sequences hold no blocks and retire directly;
         running ones go through ``_release`` (KV blocks + slot freed) unless
-        the fused pipeline still references them (``refs`` > 0), in which
-        case ``_reconcile_oldest`` releases them as the chunks drain."""
+        a pending dispatch still references them (``refs`` > 0), in which
+        case ``_reconcile_pending`` releases them as the window drains."""
         now = None
         for seq in (*self._queued, *self._running.values()):
             if seq.status == "finished" and seq.deadline:
@@ -1198,7 +1163,7 @@ class RaggedInferenceEngine:
                                     self.cache),
             "device_sched_state": led.register(
                 "device_sched_state", "ragged/slot_rows+block_table",
-                (self._dev_state, self._bt_dev, self._slot_toks)),
+                (self._dev_state, self._bt_dev)),
         }
         if self._hist_dev is not None:
             h["spec_lanes"] = led.register(
@@ -1266,7 +1231,7 @@ class RaggedInferenceEngine:
             return
         led.update(h["kv_pool"], self.cache)
         led.update(h["device_sched_state"],
-                   (self._dev_state, self._bt_dev, self._slot_toks))
+                   (self._dev_state, self._bt_dev))
         if "spec_lanes" in h:
             led.update(h["spec_lanes"], self._hist_dev)
 
@@ -1552,7 +1517,6 @@ class RaggedInferenceEngine:
         self.block_tables[slot, :] = 0
         self.block_tables[slot, :n_ctx] = blocks
         self._bt_dirty.add(slot)
-        self._slot_feed[slot] = False
         self._running[slot] = seq
         if cfg.device_state:
             # the record's device-row snapshot IS the slot row (PR-4 format);
@@ -1667,6 +1631,16 @@ class RaggedInferenceEngine:
         return m * bs
 
     # --------------------------------- hierarchical KV tiering (kvtier.py)
+    def _demote_hook(self):
+        """The allocator's way back into the engine on eviction. It holds
+        the engine weakly: engine -> allocator -> bound method -> engine is
+        a cycle, and a dropped tiered engine would keep its weights and pool
+        on the device until the cycle collector next ran (the memory
+        ledger's census reads them as unattributed bytes). The engine owns
+        the allocator, so the hook never outlives what it points at."""
+        demote = weakref.WeakMethod(self._demote_block)
+        return lambda block, key: demote()(block, key)
+
     def _demote_block(self, block: int, key) -> bool:
         """Allocator demote hook: gather one evicted block's payload
         device->host and park it in the tier store. Runs on the engine
@@ -1885,7 +1859,7 @@ class RaggedInferenceEngine:
         queue_wait = (seq.t_admit - seq.t_enqueue
                       if seq.t_admit and seq.t_enqueue else None)
         # mean inter-token latency after the first token; chunked dispatch
-        # (run-ahead / fused pipeline) amortizes inside the mean
+        # (``sched_steps``) amortizes inside the mean
         decode_latency = ((seq.t_last_token - seq.t_first_token) / (n_gen - 1)
                           if n_gen > 1 and seq.t_first_token else None)
         dur = (seq.t_last_token - seq.t_enqueue
@@ -2030,41 +2004,6 @@ class RaggedInferenceEngine:
 
         return jax.jit(step_fn, donate_argnums=(1,))
 
-    def _build_decode_chunk(self) -> Callable:
-        """K fused decode steps over the paged cache: one dispatch, next
-        token (greedy or per-request sampled) fed back on device, KV
-        scattered per step. ``K`` and the sampled? flag are static (jit
-        specializes per (K, batch, sampled) triple)."""
-        fwd = self.spec.ragged_forward_fn
-        from functools import partial
-
-        @partial(jax.jit, static_argnums=(0, 1, 2, 3), donate_argnums=(5,))
-        def chunk_fn(k, sampled, has_tk, has_tp, params, cache, tokens, slots,
-                     positions, block_tables, root, seeds, gen0, temp, topk,
-                     topp):
-            def pick(lg, r):
-                if not sampled:
-                    return jnp.argmax(
-                        lg.astype(jnp.float32), axis=-1).astype(jnp.int32)
-                from deepspeed_tpu.inference.sampling import sample_tokens
-
-                return sample_tokens(lg, r, temp,
-                                     top_k=topk if has_tk else None,
-                                     top_p=topp if has_tp else None)[0]
-
-            def one(carry, i):
-                cache, toks, pos = carry
-                logits, cache = fwd(params, toks, slots, pos, block_tables, cache)
-                from deepspeed_tpu.inference.sampling import per_request_keys
-                nxt = pick(logits, per_request_keys(root, seeds, gen0 + i))
-                return (cache, nxt, pos + 1), nxt
-
-            (cache, _, _), out = jax.lax.scan(
-                one, (cache, tokens, positions), jnp.arange(k))
-            return out, cache  # out: [K, T] generated tokens
-
-        return chunk_fn
-
     # ------------------------------------------- device-resident dispatch
     def _write_slot_row(self, seq: _SeqState) -> None:
         """Admission hook: write one slot's persistent device row in place
@@ -2123,8 +2062,8 @@ class RaggedInferenceEngine:
         return dev
 
     def _h2d(self, arr: np.ndarray):
-        """Legacy-path upload helper: jnp.asarray + H2D byte accounting, so
-        the host-staged and device-resident paths report comparable
+        """The host-staged step's upload: jnp.asarray + H2D byte accounting,
+        so the host-staged and device-resident paths report comparable
         ``h2d_bytes`` to the bench and telemetry."""
         if self._faults.enabled:
             self._faults.fire(POINT_H2D)
@@ -2238,126 +2177,6 @@ class RaggedInferenceEngine:
         fn = jax.jit(step_fn, donate_argnums=(1, 2))
         self._dev_step_jits[key] = fn
         return fn
-
-    def _get_dev_chunk(self, k: int, t: int, w: int, sampled: bool,
-                       has_tk: bool, has_tp: bool):
-        """Device-resident decode run-ahead: K fused decode steps whose
-        feed token, start position, and per-request sampling parameters are
-        all gathered from the persistent slot rows — the staging buffer is
-        just the slot ids, which byte-compare equal across a steady decode
-        run (zero upload)."""
-        key = (k, t, w, sampled, has_tk, has_tp)
-        fn = self._dev_chunk_jits.get(key)
-        self._note_program("dev_chunk", fn is None)
-        if fn is not None:
-            return fn
-        fwd = self.spec.ragged_forward_fn
-        max_seqs = self.cfg.max_seqs
-
-        def chunk_fn(params, cache, state, bt_full, staged, root):
-            from deepspeed_tpu.inference.sampling import (per_request_keys,
-                                                          sample_tokens)
-            tok_st, pos_st, seed_st, plen_st, temp_st, topk_st, topp_st = state
-            slots = staged[:t]
-            real = slots != max_seqs
-            bt = bt_full[:, :w] if w < bt_full.shape[1] else bt_full
-            toks0 = tok_st[slots]
-            pos0 = jnp.where(real, pos_st[slots], 0)
-            seeds = seed_st[slots]
-            gen0 = pos0 - plen_st[slots] + 1
-            temp = temp_st[slots]
-            topk = topk_st[slots]
-            topp = topp_st[slots]
-
-            def pick(lg, r):
-                if not sampled:
-                    return jnp.argmax(lg.astype(jnp.float32),
-                                      axis=-1).astype(jnp.int32)
-                return sample_tokens(lg, r, temp,
-                                     top_k=topk if has_tk else None,
-                                     top_p=topp if has_tp else None)[0]
-
-            def one(carry, i):
-                cache, toks, pos = carry
-                logits, cache = fwd(params, toks, slots, pos, bt, cache)
-                nxt = pick(logits, per_request_keys(root, seeds, gen0 + i))
-                return (cache, nxt, pos + 1), nxt
-
-            (cache, last, _), out = jax.lax.scan(
-                one, (cache, toks0, pos0), jnp.arange(k))
-            sl = jnp.where(real, slots, max_seqs)
-            tok_st = tok_st.at[sl].set(jnp.where(real, last, tok_st[sl]))
-            pos_st = pos_st.at[sl].add(jnp.where(real, k, 0))
-            state = (tok_st, pos_st, seed_st, plen_st, temp_st, topk_st,
-                     topp_st)
-            return out, state, cache
-
-        fn = jax.jit(chunk_fn, donate_argnums=(1, 2))
-        self._dev_chunk_jits[key] = fn
-        return fn
-
-    def _dispatch_chunk_device(self) -> bool:
-        """Device-state analog of ``_try_decode_run_ahead``: same
-        eligibility and capacity rules, but the dispatch stages only slot
-        ids and the tokens land in a pending record instead of blocking on
-        readback."""
-        cfg = self.cfg
-        k_max = cfg.decode_run_ahead
-        seqs = [s for s in self._running.values() if not s.finished]
-        if not seqs or any(not s.in_decode for s in seqs):
-            return False
-        if self._queued and self._free_slots:
-            k_max = min(k_max, cfg.run_ahead_admission_cap)
-            if k_max < 2:
-                return False
-        # remaining tokens still SCHEDULABLE (pos-based: generated lags the
-        # schedule by the pending window, pos is the ground truth here)
-        rem = min(len(s.prompt) + s.max_new_tokens - s.pos for s in seqs)
-        k = min(k_max, rem)
-        while k >= 2 and not all(self._ensure_capacity(s, s.pos + k)
-                                 for s in seqs):
-            k -= 1
-        if k < 2:
-            return False
-        k = 1 << (k.bit_length() - 1)
-        t0 = time.perf_counter()
-        t = len(seqs)
-        bucket = next(b for b in self._buckets if b >= t)
-        slots = np.full(bucket, cfg.max_seqs, np.int32)
-        sampled = has_tk = has_tp = False
-        for j, s in enumerate(seqs):
-            slots[j] = s.slot
-            sampled = sampled or s.temperature > 0.0
-            has_tk = has_tk or s.top_k > 0
-            has_tp = has_tp or s.top_p < 1.0
-        max_pos = max(s.pos + k - 1 for s in seqs)
-        self._sync_bt()
-        staged = self._stage(slots)
-        fn = self._get_dev_chunk(k, bucket, self._table_width(max_pos),
-                                 sampled, sampled and has_tk,
-                                 sampled and has_tp)
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        out, self._dev_state, self.cache = fn(
-            self.params, self.cache, self._dev_state, self._bt_dev, staged,
-            self._sample_root)
-        emits = []
-        for s in seqs:
-            s.pos += k
-            s.refs += 1
-            self._slot_feed[s.slot] = True
-            self._hist_stale[s.slot] = True
-            emits.append((s, k))
-        self.tokens_scheduled += k * t
-        self.tokens_padded += k * (bucket - t)
-        self._pending.append({"kind": "chunk", "out": out, "emits": emits,
-                              "participants": seqs})
-        self._note_dispatch(t0)
-        if self._tracer.enabled:
-            self._trace_spans(t0, time.perf_counter(),
-                              [(s, "engine/decode", k) for s in seqs],
-                              mode="dev_run_ahead")
-        return True
 
     # ------------------------------------- device-side multi-step scheduler
     def _get_dev_sched(self, k: int, t: int, w: int, sampled: bool,
@@ -2529,22 +2348,18 @@ class RaggedInferenceEngine:
 
     def _dispatch_sched_device(self) -> bool:
         """Dispatch one multi-step scheduler program when every running
-        sequence is decoding. Mirrors ``_dispatch_chunk_device``'s
-        eligibility/admission rules but budgets PER ROW (rows near their
-        length limit no longer cap the whole chunk — the program retires
-        them in place), advances host positions optimistically by each
-        row's own budget, and queues a pending record carrying the
-        per-row ``steps_taken`` readback."""
+        sequence is decoding. Budgets PER ROW (a row near its length limit
+        does not cap the whole chunk — the program retires it in place),
+        advances host positions optimistically by each row's own budget,
+        and queues a pending record carrying the per-row ``steps_taken``
+        readback."""
         cfg = self.cfg
         k_max = cfg.sched_steps
         seqs = [s for s in self._running.values() if not s.finished]
         if not seqs or any(not s.in_decode for s in seqs):
             return False
         if self._queued and self._free_slots:
-            # bounded chunk under admission pressure, like run-ahead
-            k_max = min(k_max, cfg.run_ahead_admission_cap)
-            if k_max < 1:
-                return False
+            k_max = min(k_max, SCHED_ADMISSION_CAP)
         plan = []
         max_bud = 0
         for s in seqs:
@@ -2618,7 +2433,6 @@ class RaggedInferenceEngine:
             # reconcile and releases once its refs drain
             s.pos += k_s
             s.refs += 1
-            self._slot_feed[s.slot] = True
             emits.append((s, k_s))
             sched_tok += k_s
         self.tokens_scheduled += sched_tok
@@ -2634,10 +2448,9 @@ class RaggedInferenceEngine:
         return True
 
     def _dispatch_step_device(self) -> bool:
-        """Device-state analog of the plain/tiled SplitFuse step: schedule
-        decodes + prefill chunks exactly as the legacy path does, but stage
-        them as one packed buffer (decode rows carry no token/position —
-        those live on device), dispatch the device-resident step program,
+        """The device-resident SplitFuse step (plain or tiled): stage the
+        packer's plan as one packed buffer (decode rows carry no
+        token/position — those live on device), dispatch the step program,
         and queue the picked-token readback as a pending record. Returns
         False when nothing is schedulable.
 
@@ -2652,7 +2465,7 @@ class RaggedInferenceEngine:
         form of the step's expert FFNs (``_moe_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
-            plan = self._pack_step_device()
+            plan = self._pack_step(host_feed=False)
         if plan is None:
             return False
         (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec, kv_pre,
@@ -2704,10 +2517,14 @@ class RaggedInferenceEngine:
             return f"ragged_step_d{nd}_t{nt}"
         return f"ragged_step_n{t}"
 
-    def _pack_step_device(self):
-        """The scheduling half of ``_dispatch_step_device``: pick the decode
-        rows and prefill chunks of this step, allocate their blocks and pack
-        the staging planes. Returns None when nothing is schedulable."""
+    def _pack_step(self, host_feed: bool):
+        """The one place that decides which rows a step carries, for the
+        device step and the host-staged step alike: decode rows first, then
+        prefill tiles or chunks (a partial chunk under pool pressure), the
+        rows that emit, the bucket; allocates their blocks and packs the
+        planes. A decode row's token and position come from the device's
+        slot rows (flag 3) or, with ``host_feed``, from host state here.
+        Returns None when nothing is schedulable."""
         cfg = self.cfg
         ct = cfg.prefill_tile if self._use_tiles else 0
         budget = cfg.max_tokens_per_step
@@ -2728,8 +2545,8 @@ class RaggedInferenceEngine:
                 continue
             # the feed at limit-1 yields the final budgeted token; sched
             # mode uses the exact bound (its own budgets already do), the
-            # legacy modes keep the historical +1 slop (extra token is
-            # discarded at reconcile)
+            # others keep the historical +1 slop (extra token is discarded
+            # at reconcile)
             lim = len(seq.prompt) + seq.max_new_tokens
             if cfg.sched_steps >= 2:
                 lim -= 1
@@ -2740,7 +2557,12 @@ class RaggedInferenceEngine:
                 self.preemptions += 1
                 continue
             slots[n_dec] = seq.slot
-            flags[n_dec] = 3  # feed token+position from device state | emit
+            if host_feed:
+                tokens[n_dec] = seq.token_at(seq.pos)
+                positions[n_dec] = seq.pos
+                flags[n_dec] = 2  # emit
+            else:
+                flags[n_dec] = 3  # feed token+position from device state | emit
             emit.append((n_dec, seq))
             if trace_on:
                 tpairs.append((seq, "engine/decode", 1))
@@ -2779,7 +2601,6 @@ class RaggedInferenceEngine:
                 if seq.pos == len(seq.prompt):
                     flags[start + take - 1] |= 2
                     emit.append((start + take - 1, seq))
-                    self._slot_feed[seq.slot] = True
             n = n_dec + sched
             t_total = nd + nt * ct
         else:
@@ -2808,7 +2629,6 @@ class RaggedInferenceEngine:
                 if seq.pos == len(seq.prompt):
                     flags[n - 1] |= 2
                     emit.append((n - 1, seq))
-                    self._slot_feed[seq.slot] = True
             t_total = 0 if n == 0 else next(b for b in self._buckets
                                             if b >= n)
         if n == 0:
@@ -2826,7 +2646,7 @@ class RaggedInferenceEngine:
         """Read back the OLDEST pending dispatch's tokens and fold them
         into host state (EOS/max_new enforcement via ``_append_tokens``;
         release deferred until a sequence's last pending reference
-        drains — the non-fused modes' double-buffer reconcile)."""
+        drains)."""
         if self._faults.enabled:
             self._faults.fire(POINT_READBACK)
         rec = self._pending.pop(0)
@@ -2842,7 +2662,7 @@ class RaggedInferenceEngine:
                                            for _, s in rec["emit"]])
             for row, seq in rec["emit"]:
                 self._append_tokens(seq, [int(picked[row])], out)
-        elif rec["kind"] == "sched":
+        else:  # "sched"
             toks = np.asarray(rec["out"])    # [K, bucket]
             steps = np.asarray(rec["steps"])  # [bucket] device steps_taken
             t1 = time.perf_counter()
@@ -2881,28 +2701,19 @@ class RaggedInferenceEngine:
                         "spec_tokens_accepted_total",
                         "draft tokens accepted by exact-match "
                         "verification").inc(a)
-        else:
-            toks = np.asarray(rec["out"])  # [K, bucket]
-            t1 = time.perf_counter()
-            self.readback_ns += int((t1 - t0) * 1e9)
-            if self._tracer.enabled:
-                self._trace_spans(t0, t1, [(s, "engine/readback", k)
-                                           for s, k in rec["emits"]])
-            for j, (seq, k) in enumerate(rec["emits"]):
-                self._append_tokens(seq, toks[:k, j], out)
         for seq in rec["participants"]:
             seq.refs -= 1
             if seq.finished and seq.refs == 0 and seq.slot >= 0:
-                self._slot_feed[seq.slot] = False
                 self._release(seq)
         return out
 
     def _step_device(self) -> dict:
-        """One device-resident turn for the plain/tiled/run-ahead modes:
-        dispatch one step if anything is schedulable, then reconcile the
-        oldest pending dispatch once the window holds two — so the blocking
-        ``np.asarray`` readback of step t overlaps the device executing
-        step t+1."""
+        """One device-resident turn: admit, dispatch one step if anything is
+        schedulable (the multi-step scheduler program when ``sched_steps``
+        is set and every row decodes, the SplitFuse step otherwise), then
+        reconcile the oldest pending dispatch once the window holds two — so
+        the blocking ``np.asarray`` readback of step t overlaps the device
+        executing step t+1."""
         with span("engine/schedule"):
             self._admit_queued()
         dispatched = False
@@ -2910,112 +2721,13 @@ class RaggedInferenceEngine:
         if self.cfg.sched_steps >= 2:
             dispatched = self._dispatch_sched_device()
         if not dispatched and not self._sched_wait:
-            if self.cfg.decode_run_ahead >= 2:
-                dispatched = self._dispatch_chunk_device()
-            if not dispatched:
-                dispatched = self._dispatch_step_device()
+            dispatched = self._dispatch_step_device()
         if self._pending and (not dispatched or len(self._pending) >= 2):
             return self._reconcile_pending()
         if not dispatched and not self._pending and (
                 self._queued or self._running):
             self._deadlock_guard(0)
         return {}
-
-    def _try_decode_run_ahead(self) -> dict | None:
-        """Fused multi-step decode when the scheduler is quiescent: every
-        running sequence is decoding and no admission can happen (queue empty
-        or no free slot). Returns the emit dict, or None to fall back to the
-        single SplitFuse step."""
-        k_max = self.cfg.decode_run_ahead
-        seqs = list(self._running.values())
-        if k_max < 2 or not seqs or any(not s.in_decode for s in seqs):
-            return None
-        if self._queued and self._free_slots:
-            # a queued request has a slot but the pool can't cover its
-            # reservation (step() already admitted everything admittable):
-            # fuse a BOUNDED chunk — decode progress is what frees blocks
-            k_max = min(k_max, self.cfg.run_ahead_admission_cap)
-            if k_max < 2:
-                return None
-        k = min(k_max, min(s.max_new_tokens - len(s.generated) for s in seqs))
-        while k >= 2 and not all(self._ensure_capacity(s, s.pos + k)
-                                 for s in seqs):
-            k -= 1  # pool pressure: partial growth is kept, retry smaller
-        if k < 2:
-            return None
-        # round k DOWN to a power of two: jit specializes per (k, batch), and
-        # arbitrary residuals (47, 45, 31, ...) would each compile a fresh
-        # K-step scan — the bucketing discipline every other dimension uses
-        k = 1 << (k.bit_length() - 1)
-        t0 = time.perf_counter()
-        t = len(seqs)
-        bucket = next(b for b in self._buckets if b >= t)
-        tokens = np.zeros(bucket, np.int32)
-        slots = np.full(bucket, self.cfg.max_seqs, np.int32)
-        positions = np.zeros(bucket, np.int32)
-        seeds = np.zeros(bucket, np.int32)
-        gen0 = np.zeros(bucket, np.int32)
-        temp = np.zeros(bucket, np.float32)
-        topk = np.zeros(bucket, np.int32)
-        topp = np.ones(bucket, np.float32)
-        sampled = False
-        for j, s in enumerate(seqs):
-            tokens[j] = s.token_at(s.pos)
-            slots[j] = s.slot
-            positions[j] = s.pos
-            # feeding token_at(pos) produces generated[pos+1 - len(prompt)]
-            seeds[j] = s.seed
-            gen0[j] = s.pos - len(s.prompt) + 1
-            temp[j], topk[j], topp[j] = s.temperature, s.top_k, s.top_p
-            sampled = sampled or s.temperature > 0.0
-        if self._chunk_jit is None:
-            self._chunk_jit = self._build_decode_chunk()
-        max_pos = max(s.pos + k - 1 for s in seqs)
-        has_tk = bool(topk.any())
-        has_tp = bool((topp < 1.0).any())
-        # jit specializes per (statics, shapes); track the key ourselves so
-        # cold dispatches are observable (no explicit program dict here)
-        ckey = (k, sampled, has_tk, has_tp, bucket,
-                self._table_width(max_pos))
-        self._note_program("chunk", ckey not in self._chunk_keys)
-        self._chunk_keys.add(ckey)
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        out, self.cache = self._chunk_jit(
-            k, sampled, has_tk, has_tp,
-            self.params, self.cache,
-            self._h2d(tokens), self._h2d(slots), self._h2d(positions),
-            self._h2d(self._table_view(max_pos)), self._sample_root,
-            self._h2d(seeds), self._h2d(gen0),
-            self._h2d(temp), self._h2d(topk), self._h2d(topp),
-        )
-        self._note_dispatch(t0)
-        t1 = time.perf_counter()
-        out = np.asarray(out)  # [K, bucket]
-        t2 = time.perf_counter()
-        self.readback_ns += int((t2 - t1) * 1e9)
-        if self._tracer.enabled:
-            self._trace_spans(t0, t1, [(s, "engine/decode", k) for s in seqs],
-                              mode="run_ahead")
-            self._trace_spans(t1, t2,
-                              [(s, "engine/readback", k) for s in seqs])
-        self.tokens_scheduled += k * t
-        self.tokens_padded += k * (bucket - t)
-        emit: dict = {}
-        now = time.perf_counter() if self.telemetry.enabled else 0.0
-        for j, s in enumerate(seqs):
-            for i in range(k):
-                tok = int(out[i, j])
-                s.generated.append(tok)
-                s.pos += 1
-                emit[s.uid] = tok
-                if now:
-                    self._stamp_emission(s, now)
-                if s.finished:
-                    break  # tokens past EOS stay in the pool; freed on release
-            if s.finished:
-                self._release(s)
-        return emit
 
     def _table_view(self, max_pos: int):
         """Slice the block table to the bucketed block count covering
@@ -3045,10 +2757,9 @@ class RaggedInferenceEngine:
         return min(b, mb)
 
     def _plan_prefill_tiles(self, nd: int, budget: int):
-        """Pick tile-aligned prompt chunks for this step (shared by the
-        legacy tiled step and the fused pipeline — the tile-capacity walk,
-        the capacity backoff under pool pressure, and the power-of-2 tile
-        rounding with its non-power-of-2 cap fixup live HERE only).
+        """Pick tile-aligned prompt chunks for this step (the tile-capacity
+        walk, the capacity backoff under pool pressure, and the power-of-2
+        tile rounding with its non-power-of-2 cap fixup).
 
         Returns ``(chunks, nt)``: ``chunks`` is ``[(seq, tile0, take)]``
         with ``tile0`` the chunk's first tile index relative to the tile
@@ -3080,130 +2791,6 @@ class RaggedInferenceEngine:
             nt = tiles_used
         return chunks, nt
 
-    # ------------------------------------------------- fused mixed pipeline
-    def _get_fused_chunk(self, k: int, nd: int, nt: int, sampled: bool,
-                         has_tk: bool = False, has_tp: bool = False):
-        """One program = one mixed SplitFuse step + (k-1) decode steps for
-        the decode region, next tokens fed back on device (the FastGen
-        multi-step idiom, reference ``engine_v2.py:30`` + the SplitFuse
-        policy of ``blogs/deepspeed-fastgen/README.md:28`` — generalized so
-        arrivals never break the fusion: the prompt chunk rides step 0 of
-        the same dispatched program the decodes run ahead in).
-
-        Rows [0, nd) are the decode region (padding rows -> scratch);
-        rows [nd, T) the prefill region (tile-aligned when ``nt`` > 0).
-        ``slot_toks`` [max_seqs+1] carries each slot's latest emitted token
-        ACROSS programs, so chunk t+1's decode feed never needs chunk t's
-        host readback (``feed_sel`` picks device feed vs fresh host token).
-        Statics: (k, nd, nt, sampled, has_tk, has_tp); jit specializes per
-        bucket set.
-        """
-        key = (k, nd, nt, sampled, has_tk, has_tp)
-        fn = self._fused_jits.get(key)
-        self._note_program("fused", fn is None)
-        if fn is not None:
-            return fn
-        fwd = self.spec.ragged_forward_fn
-        ct = self.cfg.prefill_tile
-        max_seqs = self.cfg.max_seqs
-
-        def pick(logits, keys, temp, tk, tp_):
-            if not sampled:
-                return jnp.argmax(
-                    logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-            from deepspeed_tpu.inference.sampling import sample_tokens
-
-            toks, _ = sample_tokens(logits, keys, temp,
-                                    top_k=tk if has_tk else None,
-                                    top_p=tp_ if has_tp else None)
-            return toks
-
-        def chunk_fn(params, cache, slot_toks, tokens, slots, positions,
-                     feed_sel, dec_remaining, eos_ids, pf_last_mask, ts, tp,
-                     tv, block_tables, root, seeds, gidx, temp, topk, topp):
-            from deepspeed_tpu.inference.sampling import per_request_keys
-            if nd:
-                fed = jnp.where(feed_sel > 0, slot_toks[slots[:nd]],
-                                tokens[:nd])
-                tokens = tokens.at[:nd].set(fed)
-                # mid-chunk retirement, entry case: a pipelined chunk can be
-                # dispatched before the host reconciles a row's EOS pick —
-                # its device feed token IS the EOS. Mask the row to the
-                # scratch slot for the whole chunk (no real-state writes, no
-                # surfaced tokens) instead of running it dead for k steps.
-                done0 = (fed == eos_ids[:nd]) & (eos_ids[:nd] >= 0)
-                slots = slots.at[:nd].set(
-                    jnp.where(done0, max_seqs, slots[:nd]))
-                positions = positions.at[:nd].set(
-                    jnp.where(done0, 0, positions[:nd]))
-            if nt:
-                logits, cache = fwd(params, tokens, slots, positions,
-                                    block_tables, cache,
-                                    prefill_tiles=(nd, ts, tp, tv, ct))
-            else:
-                logits, cache = fwd(params, tokens, slots, positions,
-                                    block_tables, cache)
-            tok0 = pick(logits, per_request_keys(root, seeds, gidx),
-                        temp, topk, topp)
-            st = slot_toks
-            t_total = tokens.shape[0]
-            if t_total > nd:
-                # prompt-completing rows publish their first generated token
-                mask = pf_last_mask[nd:] > 0
-                sl_pf = jnp.where(mask, slots[nd:], max_seqs)
-                st = st.at[sl_pf].set(
-                    jnp.where(mask, tok0[nd:], st[sl_pf]))
-            if nd:
-                # mid-chunk retirement, in-scan case: a row that picks its
-                # EOS stops running (scratch-routed like frozen rows) and
-                # its remaining steps surface -1 sentinels, never tokens
-                eosd = eos_ids[:nd]
-                dec0 = jnp.where(done0, -1, tok0[:nd])
-                last_feed = tok0[:nd]
-            if nd and k > 1:
-                def one(carry, i):
-                    cache, toks, pos, done = carry
-                    active = (i < dec_remaining) & ~done
-                    # frozen rows (k_s exhausted) must not touch real state:
-                    # slot -> max_seqs routes their KV writes to the all-zero
-                    # scratch row of the block table (block 0, never
-                    # allocated), and the position is clamped to 0 so it can
-                    # never index past any real sequence's table extent —
-                    # without the clamp a frozen row's still-advancing
-                    # ``pos`` overruns its retired table row and only
-                    # gather clamping hides it
-                    s = jnp.where(active, slots[:nd], max_seqs)
-                    p = jnp.where(active, pos, 0)
-                    lg, cache = fwd(params, toks, s, p, block_tables, cache)
-                    r = per_request_keys(root, seeds[:nd], gidx[:nd] + i)
-                    nxt = pick(lg, r, temp[:nd], topk[:nd], topp[:nd])
-                    # frozen/retired rows keep their last token (feed
-                    # stability); only live picks are surfaced
-                    nxt = jnp.where(active, nxt, toks)
-                    done = done | (active & (nxt == eosd) & (eosd >= 0))
-                    return (cache, nxt, pos + 1, done), \
-                        jnp.where(active, nxt, -1)
-
-                hit0 = done0 | ((tok0[:nd] == eosd) & (eosd >= 0))
-                (cache, last_feed, _, _), rest = jax.lax.scan(
-                    one, (cache, tok0[:nd], positions[:nd] + 1, hit0),
-                    jnp.arange(1, k))
-                dec_toks = jnp.concatenate([dec0[None], rest], axis=0)
-            else:
-                dec_toks = (dec0[None] if nd
-                            else jnp.zeros((1, 0), jnp.int32))
-            if nd:
-                # next chunk's device feed: the final carry token — equal to
-                # the k_s-th emitted token for full rows, the frozen token
-                # for short rows, the EOS for mid-scan-retired rows (done0
-                # rows scatter to scratch via their masked slot)
-                st = st.at[slots[:nd]].set(last_feed)
-            return dec_toks, tok0, st, cache
-
-        fn = jax.jit(chunk_fn, donate_argnums=(1, 2))
-        self._fused_jits[key] = fn
-        return fn
-
     def _width_ladder(self) -> list[int]:
         """Block-table widths ``_table_width`` can actually dispatch (jit
         caches are shape-keyed; warming the wrong width warms nothing)."""
@@ -3219,23 +2806,21 @@ class RaggedInferenceEngine:
 
     def warmup(self, sampled: bool = False, has_tk: bool = False,
                has_tp: bool = False) -> int:
-        """Precompile the engine's multi-step program zoos via
-        ``lower().compile()`` (no execution, no engine state touched): the
-        fused-chunk family when ``fused_chunk`` >= 2 and the multi-step
-        scheduler family when ``sched_steps`` >= 2. Every NOVEL combo
-        otherwise costs seconds of compilation in the middle of serving.
-        The compiled programs reach the dispatch path through the
-        persistent compilation cache (``utils/compile_cache.py``), which
-        this turns on. A program the compiler refuses raises here, before
-        any request is taken. Returns the number of programs compiled.
+        """Turn the persistent compilation cache on
+        (``utils/compile_cache.py``) and, when ``sched_steps`` >= 2,
+        precompile the multi-step scheduler family via ``lower().compile()``
+        (no execution, no engine state touched): every NOVEL combo otherwise
+        costs seconds of compilation in the middle of serving, and a program
+        the compiler refuses raises here, before any request is taken. The
+        SplitFuse step programs are NOT compiled here (a server runs each
+        once at set-up; they reach later processes through the cache).
+        Returns the number of programs compiled, 0 without ``sched_steps``.
         Greedy combos by default; call again with ``sampled``/filter flags
         for sampling workloads."""
         from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
         enable_compile_cache()
         n = 0
-        if self.cfg.fused_chunk >= 2:
-            n += self._warmup_fused(sampled, has_tk, has_tp)
         if self.cfg.sched_steps >= 2 and self.cfg.device_state:
             n += self._warmup_sched(sampled, has_tk, has_tp)
         # warmup's own program-cache fills are not serve-time misses: reset
@@ -3288,511 +2873,6 @@ class RaggedInferenceEngine:
                     n += 1
         return n
 
-    def _warmup_fused(self, sampled: bool, has_tk: bool,
-                      has_tp: bool) -> int:
-        cfg = self.cfg
-        ct = cfg.prefill_tile if self._use_tiles else 0
-        k = cfg.fused_chunk
-        nd_full = next(b for b in self._dec_buckets
-                       if b >= min(cfg.max_seqs, cfg.max_tokens_per_step))
-        combos: set = set()
-        # the dispatcher caps its scan depth at min(k, pow2-roundup of the
-        # deepest remaining budget), so tail batches (everyone nearly done)
-        # hit smaller-k programs too
-        ks = {k}
-        p = 1
-        while p < k:
-            ks.add(p)
-            p *= 2
-        if ct:
-            cap0 = max(1, (cfg.max_tokens_per_step - 0) // ct)
-            capd = max(1, (cfg.max_tokens_per_step - nd_full) // ct)
-
-            def nts(cap):
-                vals = {cap}
-                b = 1
-                while b <= cap:
-                    vals.add(b)
-                    b *= 2
-                return vals
-
-            for nt in nts(cap0):
-                combos.add((1, 0, nt))
-            for kk in ks:
-                for nt in nts(capd) | {0}:
-                    combos.add((kk, nd_full, nt))
-        else:
-            for b in [0] + self._buckets:
-                combos.add((1, 0, b) if b else None)
-                for kk in ks:
-                    combos.add((kk, nd_full, b))
-            combos.discard(None)
-        abstract = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
-        cache_abs = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.cache)
-        st_abs = jax.ShapeDtypeStruct((cfg.max_seqs + 1,), jnp.int32)
-        widths = self._width_ladder()
-        rng_abs = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        n = 0
-        combos = {(kk, nd, nt, w) for kk, nd, nt in combos for w in widths}
-        for kk, nd, nt, w in sorted(combos):
-            bt_abs = jax.ShapeDtypeStruct(
-                (self.block_tables.shape[0], w), jnp.int32)
-            if ct:
-                t_total = nd + nt * ct
-            else:
-                t_total = nd if nt == 0 else nt  # flat: nt carries the bucket
-            if t_total <= 0 or t_total < nd \
-                    or t_total > cfg.max_tokens_per_step + nd:
-                continue
-            i32 = lambda s: jax.ShapeDtypeStruct((s,), jnp.int32)  # noqa: E731
-            f32 = lambda s: jax.ShapeDtypeStruct((s,), jnp.float32)  # noqa: E731
-            nt_prog = nt if ct else 0
-            if cfg.device_state:
-                # device-resident variant: full-width table on device,
-                # packed staging buffer, persistent state tuple
-                state_abs = tuple(
-                    jax.ShapeDtypeStruct((cfg.max_seqs + 1,), dt)
-                    for dt in (jnp.int32, jnp.int32, jnp.int32,
-                               jnp.int32, jnp.float32, jnp.int32,
-                               jnp.float32))
-                btf_abs = jax.ShapeDtypeStruct(
-                    self.block_tables.shape, jnp.int32)
-                slen = 4 * t_total + 2 * max(nd, 1)
-                if nt_prog:
-                    slen += 3 * max(nt_prog, 1)
-                fn = self._get_dev_fused(t_total, kk, nd, nt_prog, w,
-                                         sampled, has_tk, has_tp)
-                fn.lower(abstract, cache_abs, state_abs, btf_abs,
-                         i32(slen), rng_abs).compile()
-            else:
-                fn = self._get_fused_chunk(kk, nd, nt_prog, sampled,
-                                           has_tk, has_tp)
-                fn.lower(
-                    abstract, cache_abs, st_abs,
-                    i32(t_total), i32(t_total), i32(t_total),
-                    i32(max(nd, 1)), i32(max(nd, 1)), i32(max(nd, 1)),
-                    i32(t_total),
-                    i32(max(nt_prog, 1)), i32(max(nt_prog, 1)),
-                    i32(max(nt_prog, 1)),
-                    bt_abs, rng_abs, i32(t_total), i32(t_total),
-                    f32(t_total), i32(t_total), f32(t_total),
-                ).compile()
-            n += 1
-        return n
-
-    def _dispatch_fused(self) -> bool:
-        """Schedule + dispatch ONE fused chunk from host state (no readback).
-        Returns False when nothing is schedulable."""
-        self._admit_queued()
-        t0 = time.perf_counter()
-        cfg = self.cfg
-        k_max = cfg.fused_chunk
-        ct = cfg.prefill_tile if self._use_tiles else 0
-        budget = cfg.max_tokens_per_step
-
-        decs: list[tuple[_SeqState, int]] = []
-        for seq in list(self._running.values()):
-            if seq.finished or not seq.in_decode:
-                continue
-            rem = seq.max_new_tokens - (seq.pos - len(seq.prompt))
-            if rem <= 0:
-                continue
-            k_s = min(k_max, rem)
-            if not self._ensure_capacity(seq, seq.pos + k_s):
-                continue  # admitted seqs cannot hit this (reservation)
-            decs.append((seq, k_s))
-            if len(decs) >= min(budget, cfg.max_seqs):
-                break
-        # the decode region is all-or-nothing (0 or one fixed bucket):
-        # per-count buckets looked cheaper per step but every (k, nd, nt,
-        # width) combo is a separate compiled program, and on a remote-
-        # compile transport the staggered-arrival shape zoo cost seconds of
-        # mid-serve compilation per novel combo — far more than the padded
-        # rows cost (they ride the scratch slot). Capped by the token
-        # budget so max_seqs > budget configs still honor SplitFuse.
-        nd_cap = min(cfg.max_seqs, budget)
-        nd = (0 if not decs
-              else next(b for b in self._dec_buckets if b >= nd_cap))
-
-        # prefill chunks after the decode region
-        chunks: list[tuple[_SeqState, int, int]] = []  # (seq, start, take)
-        if ct:
-            tile_chunks, nt = self._plan_prefill_tiles(nd, budget)
-            chunks = [(seq, nd + tile0 * ct, take)
-                      for seq, tile0, take in tile_chunks]
-            t_total = nd + nt * ct
-        else:
-            nt = 0
-            fill = nd
-            for seq in list(self._running.values()):
-                if seq.finished or seq.in_decode or fill >= budget:
-                    continue
-                take = min(budget - fill, len(seq.prompt) - seq.pos)
-                while take and not self._ensure_capacity(seq, seq.pos + take):
-                    take -= 1
-                if take <= 0:
-                    continue
-                chunks.append((seq, fill, take))
-                fill += take
-            t_total = (nd if fill == nd
-                       else next(b for b in self._buckets if b >= fill))
-        if not decs and not chunks:
-            return False
-
-        # cap the scan depth at what the decode region can actually use —
-        # rows with k_s < k freeze early, so steps past max(k_s) are pure
-        # scratch-row work. Round UP to a power of two: k is a static jit
-        # arg and arbitrary residuals would each compile a fresh program.
-        if decs:
-            k = min(k_max, 1 << (max(ks for _, ks in decs) - 1).bit_length())
-        else:
-            k = 1
-        if cfg.device_state:
-            return self._dispatch_fused_device(decs, chunks, nd, nt, k,
-                                               t_total, t0)
-        tokens = np.zeros(max(t_total, 1), np.int32)
-        slots = np.full(max(t_total, 1), cfg.max_seqs, np.int32)
-        positions = np.zeros(max(t_total, 1), np.int32)
-        feed_sel = np.zeros(max(nd, 1), np.int32)
-        dec_remaining = np.zeros(max(nd, 1), np.int32)
-        eos_row = np.full(max(nd, 1), -1, np.int32)
-        pf_last = np.zeros(max(t_total, 1), np.int32)
-        seeds = np.zeros(max(t_total, 1), np.int32)
-        gidx = np.zeros(max(t_total, 1), np.int32)
-        temp = np.zeros(max(t_total, 1), np.float32)
-        topk = np.zeros(max(t_total, 1), np.int32)
-        topp = np.ones(max(t_total, 1), np.float32)
-        sampled = False
-
-        for j, (seq, k_s) in enumerate(decs):
-            slots[j] = seq.slot
-            positions[j] = seq.pos
-            dec_remaining[j] = k_s
-            if seq.eos_token_id is not None:
-                eos_row[j] = seq.eos_token_id
-            # step 0 feeds token_at(pos) -> emits generated index
-            # pos - len(prompt) + 1; scan step i emits that + i
-            seeds[j] = seq.seed
-            gidx[j] = seq.pos - len(seq.prompt) + 1
-            temp[j], topk[j], topp[j] = seq.temperature, seq.top_k, seq.top_p
-            sampled = sampled or seq.temperature > 0.0
-            if self._slot_feed[seq.slot]:
-                feed_sel[j] = 1
-            else:
-                gen_idx = seq.pos - len(seq.prompt)
-                if gen_idx > len(seq.generated) - 1 and gen_idx != -1:
-                    raise RuntimeError(
-                        "fused scheduler: host token unavailable and no "
-                        f"device feed for uid={seq.uid!r} (pos={seq.pos})")
-                tokens[j] = seq.token_at(seq.pos)
-
-        pf_done: list[tuple[int, _SeqState]] = []
-        ts = np.full(max(nt, 1), cfg.max_seqs, np.int32)
-        tpos = np.zeros(max(nt, 1), np.int32)
-        tval = np.zeros(max(nt, 1), np.int32)
-        for seq, start, take in chunks:
-            sl = slice(start, start + take)
-            tokens[sl] = seq.prompt[seq.pos:seq.pos + take]
-            slots[sl] = seq.slot
-            positions[sl] = np.arange(seq.pos, seq.pos + take, dtype=np.int32)
-            # only the prompt-completing row's pick is kept (generated
-            # index 0, which gidx already holds); other rows' are discarded
-            seeds[sl] = seq.seed
-            temp[sl], topk[sl], topp[sl] = (seq.temperature, seq.top_k,
-                                            seq.top_p)
-            sampled = sampled or seq.temperature > 0.0
-            if ct:
-                tile0 = (start - nd) // ct
-                for t in range(-(-take // ct)):
-                    ts[tile0 + t] = seq.slot
-                    tpos[tile0 + t] = seq.pos + t * ct
-                    tval[tile0 + t] = min(ct, take - t * ct)
-            if seq.pos + take == len(seq.prompt):
-                pf_last[start + take - 1] = 1
-                pf_done.append((start + take - 1, seq))
-            seq.pos += take
-
-        # telemetry: step-0 real tokens + scan-step active decode tokens
-        n0 = len(decs) + sum(c[2] for c in chunks)
-        active_scan = sum(k_s - 1 for _, k_s in decs)
-        self.tokens_scheduled += n0 + active_scan
-        self.tokens_padded += (t_total - n0) + (k - 1) * nd - active_scan
-
-        max_pos = max(
-            [seq.pos + k_s - 1 for seq, k_s in decs]
-            + [seq.pos - 1 for seq, _, _ in chunks], default=0)
-        fn = self._get_fused_chunk(k, nd, nt, sampled,
-                                   bool(topk.any()),
-                                   bool((topp < 1.0).any()))
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        dec_toks, tok0, self._slot_toks, self.cache = fn(
-            self.params, self.cache, self._slot_toks,
-            self._h2d(tokens), self._h2d(slots), self._h2d(positions),
-            self._h2d(feed_sel), self._h2d(dec_remaining),
-            self._h2d(eos_row), self._h2d(pf_last), self._h2d(ts),
-            self._h2d(tpos), self._h2d(tval),
-            self._h2d(self._table_view(max_pos)),
-            self._sample_root, self._h2d(seeds), self._h2d(gidx),
-            self._h2d(temp), self._h2d(topk), self._h2d(topp),
-        )
-        self._note_dispatch(t0)
-        if self._tracer.enabled:
-            t1 = time.perf_counter()
-            self._trace_spans(
-                t0, t1,
-                [(s, "engine/decode", ks) for s, ks in decs]
-                + [(s, "engine/prefill", take) for s, _, take in chunks],
-                mode="fused")
-
-        participants: dict[int, _SeqState] = {}
-        for seq, k_s in decs:
-            seq.pos += k_s
-            self._slot_feed[seq.slot] = True
-            participants[seq.slot] = seq
-        for row, seq in pf_done:
-            self._slot_feed[seq.slot] = True
-            participants[seq.slot] = seq
-        for seq, _, _ in chunks:
-            participants[seq.slot] = seq
-        for seq in participants.values():
-            seq.refs += 1
-            self._hist_stale[seq.slot] = True
-        self._inflight_chunks.append({
-            "dec_toks": dec_toks, "tok0": tok0,
-            "decs": decs, "pf_done": pf_done,
-            "participants": list(participants.values()),
-        })
-        return True
-
-    def _get_dev_fused(self, t: int, k: int, nd: int, nt: int, w: int,
-                       sampled: bool, has_tk: bool, has_tp: bool):
-        """Device-resident fused mixed chunk: same program structure as
-        ``_get_fused_chunk`` (step 0 mixed SplitFuse + k-1 decode scan
-        steps, ``pf_last`` rows publishing their first generated token),
-        but feed tokens, positions, seeds, and sampling parameters are all
-        gathered from the persistent slot rows instead of host arrays, and
-        the slot rows (token + position) update in place. The staging
-        buffer shrinks to [tokens | slots | positions | flags | dec_rem
-        (| tile metadata)] — constant bytes across steady decode chunks."""
-        key = (t, k, nd, nt, w, sampled, has_tk, has_tp)
-        fn = self._dev_fused_jits.get(key)
-        self._note_program("dev_fused", fn is None)
-        if fn is not None:
-            return fn
-        fwd = self.spec.ragged_forward_fn
-        ct = self.cfg.prefill_tile
-        max_seqs = self.cfg.max_seqs
-        ndl = max(nd, 1)
-        ntl = max(nt, 1)
-
-        def pick(logits, keys, temp, tk, tp_):
-            if not sampled:
-                return jnp.argmax(
-                    logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-            from deepspeed_tpu.inference.sampling import sample_tokens
-
-            return sample_tokens(logits, keys, temp,
-                                 top_k=tk if has_tk else None,
-                                 top_p=tp_ if has_tp else None)[0]
-
-        def chunk_fn(params, cache, state, bt_full, staged, root):
-            from deepspeed_tpu.inference.sampling import (keys_for_positions,
-                                                          per_request_keys)
-            tok_st, pos_st, seed_st, plen_st, temp_st, topk_st, topp_st = state
-            tokens = staged[0:t]
-            slots = staged[t:2 * t]
-            positions = staged[2 * t:3 * t]
-            flags = staged[3 * t:4 * t]
-            dec_rem = staged[4 * t:4 * t + ndl]
-            eos_ids = staged[4 * t + ndl:4 * t + 2 * ndl]
-            feed = (flags & 1) > 0
-            live0 = slots != max_seqs
-            tokens = jnp.where(feed, tok_st[slots], tokens)
-            positions = jnp.where(feed & live0, pos_st[slots], positions)
-            if nd:
-                # mid-chunk retirement, entry case (see _get_fused_chunk):
-                # a row whose device feed is already its EOS masks to the
-                # scratch slot for the whole chunk
-                done0 = (tokens[:nd] == eos_ids[:nd]) & (eos_ids[:nd] >= 0)
-                slots = slots.at[:nd].set(
-                    jnp.where(done0, max_seqs, slots[:nd]))
-                positions = positions.at[:nd].set(
-                    jnp.where(done0, 0, positions[:nd]))
-            real = slots != max_seqs
-            seeds = seed_st[slots]
-            temp = temp_st[slots]
-            topk = topk_st[slots]
-            topp = topp_st[slots]
-            gidx = positions - plen_st[slots] + 1
-            bt = bt_full[:, :w] if w < bt_full.shape[1] else bt_full
-            if nt:
-                ts = staged[4 * t + 2 * ndl:4 * t + 2 * ndl + ntl]
-                tp_ = staged[4 * t + 2 * ndl + ntl:4 * t + 2 * ndl + 2 * ntl]
-                tv = staged[4 * t + 2 * ndl + 2 * ntl:
-                            4 * t + 2 * ndl + 3 * ntl]
-                logits, cache = fwd(params, tokens, slots, positions, bt,
-                                    cache, prefill_tiles=(nd, ts, tp_, tv, ct))
-            else:
-                logits, cache = fwd(params, tokens, slots, positions, bt,
-                                    cache)
-            tok0 = pick(logits,
-                        keys_for_positions(root, seeds, positions,
-                                           plen_st[slots]),
-                        temp, topk, topp)
-            if t > nd:
-                # prompt-completing rows publish their first generated token
-                mask = (flags[nd:] & 2) > 0
-                sl_pf = jnp.where(mask, slots[nd:], max_seqs)
-                tok_st = tok_st.at[sl_pf].set(
-                    jnp.where(mask, tok0[nd:], tok_st[sl_pf]))
-                mpf = real[nd:]
-                sl_p = jnp.where(mpf, slots[nd:], max_seqs)
-                pos_st = pos_st.at[sl_p].max(
-                    jnp.where(mpf, positions[nd:] + 1, 0))
-            if nd:
-                # mid-chunk retirement, in-scan case (see _get_fused_chunk)
-                eosd = eos_ids[:nd]
-                dec0 = jnp.where(done0, -1, tok0[:nd])
-                last_feed = tok0[:nd]
-            if nd and k > 1:
-                def one(carry, i):
-                    cache, toks, pos, done = carry
-                    active = (i < dec_rem) & ~done
-                    # frozen/retired rows -> scratch (see _get_fused_chunk)
-                    s = jnp.where(active, slots[:nd], max_seqs)
-                    p = jnp.where(active, pos, 0)
-                    lg, cache = fwd(params, toks, s, p, bt, cache)
-                    r = per_request_keys(root, seeds[:nd], gidx[:nd] + i)
-                    nxt = pick(lg, r, temp[:nd], topk[:nd], topp[:nd])
-                    nxt = jnp.where(active, nxt, toks)
-                    done = done | (active & (nxt == eosd) & (eosd >= 0))
-                    return (cache, nxt, pos + 1, done), \
-                        jnp.where(active, nxt, -1)
-
-                hit0 = done0 | ((tok0[:nd] == eosd) & (eosd >= 0))
-                (cache, last_feed, _, _), rest = jax.lax.scan(
-                    one, (cache, tok0[:nd], positions[:nd] + 1, hit0),
-                    jnp.arange(1, k))
-                dec_toks = jnp.concatenate([dec0[None], rest], axis=0)
-            else:
-                dec_toks = (dec0[None] if nd
-                            else jnp.zeros((1, 0), jnp.int32))
-            if nd:
-                rd = real[:nd]  # done0 rows already masked -> scratch
-                sl_d = jnp.where(rd, slots[:nd], max_seqs)
-                tok_st = tok_st.at[sl_d].set(
-                    jnp.where(rd, last_feed, tok_st[sl_d]))
-                pos_st = pos_st.at[sl_d].add(
-                    jnp.where(rd, jnp.minimum(dec_rem, k), 0))
-            state = (tok_st, pos_st, seed_st, plen_st, temp_st, topk_st,
-                     topp_st)
-            return dec_toks, tok0, state, cache
-
-        fn = jax.jit(chunk_fn, donate_argnums=(1, 2))
-        self._dev_fused_jits[key] = fn
-        return fn
-
-    def _dispatch_fused_device(self, decs, chunks, nd: int, nt: int, k: int,
-                               t_total: int, t0: float) -> bool:
-        """Stage + dispatch one fused chunk against the device-resident
-        slot rows: decode rows carry only slot + feed flag (token,
-        position, and sampling params are gathered on device), prefill
-        rows the usual token runs — all in ONE packed staging buffer that
-        byte-compares equal across steady decode chunks (zero upload)."""
-        cfg = self.cfg
-        ct = cfg.prefill_tile if self._use_tiles else 0
-        tokens = np.zeros(max(t_total, 1), np.int32)
-        slots = np.full(max(t_total, 1), cfg.max_seqs, np.int32)
-        positions = np.zeros(max(t_total, 1), np.int32)
-        flags = np.zeros(max(t_total, 1), np.int32)
-        dec_remaining = np.zeros(max(nd, 1), np.int32)
-        eos_row = np.full(max(nd, 1), -1, np.int32)
-        sampled = has_tk = has_tp = False
-        max_pos = 0
-        for j, (seq, k_s) in enumerate(decs):
-            slots[j] = seq.slot
-            flags[j] = 1  # feed token + position from device state
-            dec_remaining[j] = k_s
-            if seq.eos_token_id is not None:
-                eos_row[j] = seq.eos_token_id
-            sampled = sampled or seq.temperature > 0.0
-            has_tk = has_tk or seq.top_k > 0
-            has_tp = has_tp or seq.top_p < 1.0
-            max_pos = max(max_pos, seq.pos + k_s - 1)
-        pf_done: list[tuple[int, _SeqState]] = []
-        ts = np.full(max(nt, 1), cfg.max_seqs, np.int32)
-        tpos = np.zeros(max(nt, 1), np.int32)
-        tval = np.zeros(max(nt, 1), np.int32)
-        for seq, start, take in chunks:
-            sl = slice(start, start + take)
-            tokens[sl] = seq.prompt[seq.pos:seq.pos + take]
-            slots[sl] = seq.slot
-            positions[sl] = np.arange(seq.pos, seq.pos + take, dtype=np.int32)
-            sampled = sampled or seq.temperature > 0.0
-            has_tk = has_tk or seq.top_k > 0
-            has_tp = has_tp or seq.top_p < 1.0
-            if ct:
-                tile0 = (start - nd) // ct
-                for ti in range(-(-take // ct)):
-                    ts[tile0 + ti] = seq.slot
-                    tpos[tile0 + ti] = seq.pos + ti * ct
-                    tval[tile0 + ti] = min(ct, take - ti * ct)
-            if seq.pos + take == len(seq.prompt):
-                flags[start + take - 1] |= 2
-                pf_done.append((start + take - 1, seq))
-            max_pos = max(max_pos, seq.pos + take - 1)
-            seq.pos += take
-
-        n0 = len(decs) + sum(c[2] for c in chunks)
-        active_scan = sum(k_s - 1 for _, k_s in decs)
-        self.tokens_scheduled += n0 + active_scan
-        self.tokens_padded += (t_total - n0) + (k - 1) * nd - active_scan
-
-        parts = [tokens, slots, positions, flags, dec_remaining, eos_row]
-        if nt:
-            parts += [ts, tpos, tval]
-        self._sync_bt()
-        staged = self._stage(np.concatenate(parts))
-        fn = self._get_dev_fused(max(t_total, 1), k, nd, nt,
-                                 self._table_width(max_pos), sampled,
-                                 sampled and has_tk, sampled and has_tp)
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        dec_toks, tok0, self._dev_state, self.cache = fn(
-            self.params, self.cache, self._dev_state, self._bt_dev, staged,
-            self._sample_root)
-
-        participants: dict[int, _SeqState] = {}
-        for seq, k_s in decs:
-            seq.pos += k_s
-            self._slot_feed[seq.slot] = True
-            participants[seq.slot] = seq
-        for _row, seq in pf_done:
-            self._slot_feed[seq.slot] = True
-            participants[seq.slot] = seq
-        for seq, _, _ in chunks:
-            participants[seq.slot] = seq
-        for seq in participants.values():
-            seq.refs += 1
-            self._hist_stale[seq.slot] = True
-        self._inflight_chunks.append({
-            "dec_toks": dec_toks, "tok0": tok0,
-            "decs": decs, "pf_done": pf_done,
-            "participants": list(participants.values()),
-        })
-        self._note_dispatch(t0)
-        if self._tracer.enabled:
-            t1 = time.perf_counter()
-            self._trace_spans(
-                t0, t1,
-                [(s, "engine/decode", ks) for s, ks in decs]
-                + [(s, "engine/prefill", take) for s, _, take in chunks],
-                mode="dev_fused")
-        return True
-
     def _append_tokens(self, seq: _SeqState, toks, out: dict) -> None:
         now = time.perf_counter() if self.telemetry.enabled else 0.0
         if seq.cost is not None and not seq.finished:
@@ -3809,113 +2889,13 @@ class RaggedInferenceEngine:
             if now:
                 self._stamp_emission(seq, now)
 
-    def _reconcile_oldest(self) -> dict:
-        """Read back the OLDEST in-flight chunk's tokens and fold them into
-        host state (EOS/max_new enforcement, deferred release)."""
-        if self._faults.enabled:
-            self._faults.fire(POINT_READBACK)
-        rec = self._inflight_chunks.pop(0)
-        t0 = time.perf_counter()
-        dec_toks = np.asarray(rec["dec_toks"])
-        tok0 = np.asarray(rec["tok0"])
-        t1 = time.perf_counter()
-        self.readback_ns += int((t1 - t0) * 1e9)
-        if self._tracer.enabled:
-            self._trace_spans(
-                t0, t1,
-                [(s, "engine/readback", ks) for s, ks in rec["decs"]]
-                + [(s, "engine/readback", 1) for _, s in rec["pf_done"]])
-        out: dict = {}
-        for row, seq in rec["pf_done"]:
-            self._append_tokens(seq, [int(tok0[row])], out)
-        for j, (seq, k_s) in enumerate(rec["decs"]):
-            self._append_tokens(seq, dec_toks[:k_s, j], out)
-        for seq in rec["participants"]:
-            seq.refs -= 1
-            if seq.finished and seq.refs == 0 and seq.slot >= 0:
-                self._slot_feed[seq.slot] = False
-                self._release(seq)
-        return out
-
-    def _step_fused(self) -> dict:
-        """One fused-pipeline turn: keep the dispatch window full, reconcile
-        the oldest chunk when the window is full (or nothing new can be
-        dispatched). Bounded speculation: at most ``pipeline_depth`` chunks
-        of tokens are unreconciled at any time."""
-        dispatched = False
-        while len(self._inflight_chunks) < self.cfg.pipeline_depth:
-            if not self._dispatch_fused():
-                break
-            dispatched = True
-        if self._inflight_chunks and (
-                not dispatched
-                or len(self._inflight_chunks) >= self.cfg.pipeline_depth):
-            return self._reconcile_oldest()
-        if not dispatched and not self._inflight_chunks:
-            self._deadlock_guard(0)
-        return {}
-
-    def _sched_eligible(self) -> bool:
-        """Whether a multi-step scheduler turn could engage right now:
-        everything running is decoding and admission pressure does not
-        forbid a chunk (same preconditions ``_dispatch_sched_device``
-        checks before planning)."""
-        seqs = [s for s in self._running.values() if not s.finished]
-        if not seqs or any(not s.in_decode for s in seqs):
-            return False
-        if self._queued and self._free_slots and \
-                min(self.cfg.sched_steps,
-                    self.cfg.run_ahead_admission_cap) < 1:
-            return False
-        return True
-
-    def _step_fused_sched(self) -> dict:
-        """Fused pipeline with the multi-step scheduler layered on top:
-        mixed prefill+decode waves run through the fused-chunk program;
-        once the batch is all-decode the turn switches to the scheduler
-        dispatch (device-side retirement, optional speculation). The two
-        in-flight queues never interleave — each family's window drains
-        fully before the other dispatches — so reconcile order stays FIFO
-        per sequence."""
-        self._admit_queued()
-        if self._sched_eligible():
-            if self._inflight_chunks:
-                return self._reconcile_oldest()
-            return self._step_device()
-        if self._pending:
-            return self._reconcile_pending()
-        return self._step_fused()
-
     def drain(self) -> dict:
-        """Reconcile every in-flight chunk (a flush point for callers that
+        """Reconcile every pending dispatch (a flush point for callers that
         need host-complete state)."""
         out: dict = {}
-        while self._inflight_chunks:
-            out.update(self._reconcile_oldest())
         while self._pending:
             out.update(self._reconcile_pending())
         return out
-
-    def _schedule_decodes(self, budget: int, tokens, slots, positions,
-                          emit) -> int:
-        """Pass 1: ongoing decodes first (latency priority, FastGen policy).
-        Writes into the arrays from index 0, returns the count."""
-        n = 0
-        for seq in list(self._running.values()):
-            if not seq.in_decode or n >= budget:
-                continue
-            if not self._ensure_capacity(seq, seq.pos + 1):
-                # pool pressure: this seq stalls (is preempted) for one step
-                seq.preemptions += 1
-                self.preemptions += 1
-                continue
-            tokens[n] = seq.token_at(seq.pos)
-            slots[n] = seq.slot
-            positions[n] = seq.pos
-            emit.append((n, seq))
-            seq.pos += 1
-            n += 1
-        return n
 
     def _admit_queued(self) -> None:
         """Pass 2: admit queued requests while slots remain (their prompt
@@ -4183,10 +3163,10 @@ class RaggedInferenceEngine:
     # ------------------------------------------------- dispatch watchdog
     def _recover_device_path(self) -> None:
         """Re-anchor the engine on host ground truth after a failed step:
-        discard ALL unread speculation (pending readbacks + in-flight fused
-        chunks — partially draining them could interleave token order) and
-        rewind every running sequence's schedule position to what its
-        host-visible ``generated`` list proves was delivered. Re-running
+        discard ALL unread speculation (the pending readbacks — partially
+        draining them could interleave token order) and rewind every running
+        sequence's schedule position to what its host-visible ``generated``
+        list proves was delivered. Re-running
         the discarded positions rewrites identical KV and — because token
         ``g`` of a request samples from a key derived only from (seed, g) —
         re-picks identical tokens, so recovery is invisible in the output
@@ -4195,9 +3175,7 @@ class RaggedInferenceEngine:
         dispatch before the host bindings are swapped, so cache/state
         references here are the pre-dispatch values."""
         self._pending.clear()
-        self._inflight_chunks.clear()
         self._staging_cache.clear()
-        self._slot_feed[:] = False
         for seq in self._running.values():
             seq.refs = 0
             g = len(seq.generated)
@@ -4230,9 +3208,9 @@ class RaggedInferenceEngine:
 
     def _maybe_degrade(self, exc: Exception) -> bool:
         """Walk one rung down the degradation ladder once failures repeat:
-        full device-resident path -> host-staged kill-switch path
-        (``device_state`` off) -> plain single-program SplitFuse step
-        (fused/run-ahead/tiles off). Returns True when a rung was taken;
+        full device-resident path -> host-staged step (``device_state``
+        off) -> the same with prefill tiles off (the plain SplitFuse step,
+        no tiled kernel). Returns True when a rung was taken;
         every rung is token-identical (pinned by the mode-parity tests), so
         degradation costs dispatch efficiency, never output."""
         cfg = self.cfg
@@ -4243,14 +3221,11 @@ class RaggedInferenceEngine:
             cfg.device_state = False
             self.degraded_mode = 1
             rung = "host-staged fallback (device_state off)"
-        elif (cfg.fused_chunk or cfg.decode_run_ahead or cfg.prefill_tile
-              or self._use_tiles):
-            cfg.fused_chunk = 0
-            cfg.decode_run_ahead = 0
+        elif self._use_tiles:
             cfg.prefill_tile = 0
             self._use_tiles = False
             self.degraded_mode = 2
-            rung = "plain-step fallback (fused/run-ahead/tiles off)"
+            rung = "plain-step fallback (prefill tiles off)"
         else:
             return False  # already at the bottom rung
         self.degraded_reason = reason
@@ -4371,7 +3346,6 @@ class RaggedInferenceEngine:
         self._queued = []
         self._running = {}
         self._pending.clear()
-        self._inflight_chunks.clear()
         self._staging_cache.clear()
         self._kvq_blocks_allocated += self.allocator.allocated_total
         self.allocator = BlockedAllocator(self.cfg.num_blocks)
@@ -4379,7 +3353,7 @@ class RaggedInferenceEngine:
             # the tier store SURVIVES reset: its records are keyed by exact
             # token chains, valid for any allocator generation of the same
             # params — demoted prefixes stay restorable after containment
-            self.allocator.demote_hook = self._demote_block
+            self.allocator.demote_hook = self._demote_hook()
         if self._prefix_listener is not None:
             # fresh allocator has no published keys: tell the cluster index
             # to forget this replica, then keep listening
@@ -4390,9 +3364,7 @@ class RaggedInferenceEngine:
         self._bt_dev = jnp.asarray(self.block_tables)
         self._free_slots = list(range(self.cfg.max_seqs - 1, -1, -1))
         self._reserved = 0
-        self._slot_feed[:] = False
         s1 = self.cfg.max_seqs + 1
-        self._slot_toks = jnp.zeros(s1, jnp.int32)
         self._dev_state = (
             jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
             jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
@@ -4416,9 +3388,9 @@ class RaggedInferenceEngine:
 
     def step(self) -> dict:
         """One SplitFuse step. Returns {uid: token} for sequences that emitted
-        a token this step (under decode run-ahead / the fused pipeline: the
-        LAST token of each sequence's chunk; the full stream is in the
-        per-sequence state). Runs under the dispatch watchdog: transient
+        a token this step (under ``sched_steps``: the LAST token of each
+        sequence's chunk; the full stream is in the per-sequence state).
+        Runs under the dispatch watchdog: transient
         device-path failures are retried (and eventually degraded) in
         place, so callers only ever see fatal errors."""
         if not self.has_work:
@@ -4482,10 +3454,8 @@ class RaggedInferenceEngine:
                   1.0 - self.program_cold_dispatches
                   / self.program_dispatches)
         tel.note_program_cache_size(
-            len(self._tiled_jits) + len(self._fused_jits)
-            + len(self._dev_step_jits) + len(self._dev_chunk_jits)
-            + len(self._dev_fused_jits) + len(self._dev_sched_jits)
-            + len(self._chunk_keys) + len(self._step_keys))
+            len(self._tiled_jits) + len(self._dev_step_jits)
+            + len(self._dev_sched_jits) + len(self._step_keys))
         if self.cfg.enable_prefix_cache:
             alloc = self.allocator
             bb = self._block_bytes()
@@ -4562,79 +3532,57 @@ class RaggedInferenceEngine:
         tel.sample_memory(step=self.dispatch_count)
 
     def _step_impl(self) -> dict:
+        """The two ways the engine takes a step:
+
+        sweep aborts -> ``_step_device()`` if ``cfg.device_state`` (the
+                        normal path: admit -> [``sched_steps`` >= 2 and all
+                        rows decode: ``_dispatch_sched_device``] ->
+                        ``_dispatch_step_device`` -> reconcile)
+                     -> the host-staged step below otherwise (rung 1 of the
+                        watchdog's ladder; the tests' reference)
+
+        The host-staged step takes the plan ``_step_device`` would dispatch,
+        with the decode rows' token and position fed from host state, ships
+        the arrays one by one, and picks the tokens from the logits and
+        reads them back at once (no pending window, no device slot rows).
+        It is written here and not in a routine of its own because this
+        frame lies under every trace of a step program: CPython keeps
+        frames in 16 KiB chunks and frees a chunk as its first frame
+        returns, so how many bytes the frames below jax's recursion take
+        decides which of its hot calls allocate a chunk each time. With the
+        host step elsewhere (232 bytes less here) a serving cell's set-up
+        read 5 s longer on the chip and another's 2 s shorter (PERF.md,
+        PR 28)."""
         self._sweep_aborts()
         if not self.has_work:
             return {}  # the sweep retired everything schedulable
-        if self.cfg.fused_chunk >= 2:
-            if self.cfg.sched_steps >= 2 and self.cfg.device_state:
-                return self._step_fused_sched()
-            return self._step_fused()
         if self.cfg.device_state:
             return self._step_device()
-        # admission FIRST: a newly admitted sequence is in prefill, which
-        # disables run-ahead for this step — so queued requests are admitted
-        # within one step whenever a slot + pool reservation exist, and the
-        # admission-capped run-ahead below only governs the pool-blocked case
-        # (without this order, capped chunks re-fire back-to-back and starve
-        # admission for up to a whole generation)
         self._admit_queued()
-        ahead = self._try_decode_run_ahead()
-        if ahead is not None:
-            return ahead
-        if self._use_tiles:
-            return self._step_tiled()
         t0 = time.perf_counter()
-        budget = self.cfg.max_tokens_per_step
-        tokens = np.zeros(budget, np.int32)
-        slots = np.full(budget, self.cfg.max_seqs, np.int32)  # padding row
-        positions = np.zeros(budget, np.int32)
-        emit: list[tuple[int, _SeqState]] = []
-        n = self._schedule_decodes(budget, tokens, slots, positions, emit)
-        trace_on = self._tracer.enabled
-        # emit holds exactly the decode rows at this point
-        tpairs = ([(s, "engine/decode", 1) for _, s in emit]
-                  if trace_on else None)
-
-        # 3) prefill chunks for running prompts within the remaining budget
-        for seq in list(self._running.values()):
-            if seq.in_decode or n >= budget:
-                continue
-            take = min(budget - n, len(seq.prompt) - seq.pos)
-            while take and not self._ensure_capacity(seq, seq.pos + take):
-                take -= 1  # partial chunk under pool pressure
-            if take <= 0:
-                continue
-            sl = slice(n, n + take)
-            tokens[sl] = seq.prompt[seq.pos:seq.pos + take]
-            slots[sl] = seq.slot
-            positions[sl] = np.arange(seq.pos, seq.pos + take, dtype=np.int32)
-            seq.pos += take
-            n += take
-            if trace_on:
-                tpairs.append((seq, "engine/prefill", take))
-            if seq.pos == len(seq.prompt):
-                emit.append((n - 1, seq))  # last prompt token -> first new token
-
+        plan = self._pack_step(host_feed=True)
+        if plan is None:
+            self._deadlock_guard(0)  # raises unless idling on headroom
+            return {}
+        parts, emit, tpairs, t_total, n, nd, nt, max_pos, *_ = plan
         self._deadlock_guard(n)
-        bucket = next(b for b in self._buckets if b >= n)
-        self.tokens_scheduled += n
-        self.tokens_padded += bucket - n
-
-        max_pos = int(positions[:n].max(initial=0))
-        skey = ("step", bucket, self._table_width(max_pos))
-        self._note_program("step", skey not in self._step_keys)
-        self._step_keys.add(skey)
+        tokens, slots, positions = (self._h2d(p) for p in parts[:3])
+        if self._use_tiles:
+            mode, step_fn = "tiled", self._get_tiled_step(nd, nt)
+            tiles = [self._h2d(p) for p in parts[4:]]
+        else:
+            skey = ("step", t_total, self._table_width(max_pos))
+            self._note_program("step", skey not in self._step_keys)
+            self._step_keys.add(skey)
+            mode, step_fn, tiles = "step", self._step_jit, []
+        table = self._h2d(self._table_view(max_pos))
         if self._faults.enabled:
             self._faults.fire(POINT_DISPATCH)
-        logits, self.cache = self._step_jit(
-            self.params, self.cache,
-            self._h2d(tokens[:bucket]), self._h2d(slots[:bucket]),
-            self._h2d(positions[:bucket]),
-            self._h2d(self._table_view(max_pos)),
-        )
+        logits, self.cache = step_fn(
+            self.params, self.cache, tokens, slots, positions, *tiles, table)
         self._note_dispatch(t0)
-        if trace_on:
-            self._trace_spans(t0, time.perf_counter(), tpairs, mode="step")
+        if tpairs is not None:
+            self._trace_spans(t0, time.perf_counter(), tpairs, mode=mode)
         return self._emit_tokens(logits, emit)
 
     def _get_tiled_step(self, nd: int, nt: int):
@@ -4654,77 +3602,6 @@ class RaggedInferenceEngine:
             fn = jax.jit(step_fn, donate_argnums=(1,))
             self._tiled_jits[key] = fn
         return fn
-
-    def _step_tiled(self) -> dict:
-        """One SplitFuse step with tile-aligned prefill layout: tokens
-        [0, ND) are decodes (bucketed), the rest are prefill chunks laid at
-        tile boundaries so the tiled kernel fetches each KV block once per
-        tile (see RaggedConfig.prefill_tile)."""
-        ct = self.cfg.prefill_tile
-        budget = self.cfg.max_tokens_per_step
-        t0 = time.perf_counter()
-        tokens = np.zeros(budget + ct, np.int32)
-        slots = np.full(budget + ct, self.cfg.max_seqs, np.int32)
-        positions = np.zeros(budget + ct, np.int32)
-        emit: list[tuple[int, _SeqState]] = []
-        n_dec = self._schedule_decodes(min(budget, self.cfg.max_seqs),
-                                       tokens, slots, positions, emit)
-        trace_on = self._tracer.enabled
-        # emit holds exactly the decode rows at this point
-        tpairs = ([(s, "engine/decode", 1) for _, s in emit]
-                  if trace_on else None)
-        self._admit_queued()
-        nd = 0 if n_dec == 0 else next(b for b in self._dec_buckets
-                                       if b >= n_dec)
-
-        # prefill chunks at tile-aligned offsets after the decode region
-        # (planner shared with the fused pipeline)
-        chunks, nt = self._plan_prefill_tiles(nd, budget)
-        sched = 0
-        for seq, tile0, take in chunks:
-            start = nd + tile0 * ct
-            tokens[start:start + take] = seq.prompt[seq.pos:seq.pos + take]
-            slots[start:start + take] = seq.slot
-            positions[start:start + take] = np.arange(
-                seq.pos, seq.pos + take, dtype=np.int32)
-            seq.pos += take
-            sched += take
-            if trace_on:
-                tpairs.append((seq, "engine/prefill", take))
-            if seq.pos == len(seq.prompt):
-                emit.append((start + take - 1, seq))
-        self._deadlock_guard(n_dec + sched)
-        total = nd + nt * ct
-        # per-tile metadata (pad tiles: scratch row, valid=0)
-        ts = np.full(max(nt, 1), self.cfg.max_seqs, np.int32)
-        tp = np.zeros(max(nt, 1), np.int32)
-        tv = np.zeros(max(nt, 1), np.int32)
-        for seq, tile0, take in chunks:
-            pos0 = positions[nd + tile0 * ct]
-            for t in range(-(-take // ct)):
-                ts[tile0 + t] = seq.slot
-                tp[tile0 + t] = pos0 + t * ct
-                tv[tile0 + t] = min(ct, take - t * ct)
-
-        self.tokens_scheduled += n_dec + sched
-        self.tokens_padded += total - n_dec - sched
-
-        step_fn = self._get_tiled_step(nd, nt)
-        max_pos = int(positions[:total].max(initial=0)) if total else 0
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        logits, self.cache = step_fn(
-            self.params, self.cache,
-            self._h2d(tokens[:total]), self._h2d(slots[:total]),
-            self._h2d(positions[:total]),
-            self._h2d(ts[:max(nt, 1)]), self._h2d(tp[:max(nt, 1)]),
-            self._h2d(tv[:max(nt, 1)]),
-            self._h2d(self._table_view(max_pos)),
-        )
-        self._note_dispatch(t0)
-        if trace_on:
-            self._trace_spans(t0, time.perf_counter(), tpairs, mode="tiled")
-        return self._emit_tokens(logits, emit)
 
     # ------------------------------------------------------------------ convenience
     def generate_all(self, max_steps: int = 10_000) -> dict:

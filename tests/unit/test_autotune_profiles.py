@@ -155,25 +155,36 @@ class TestPrecedence:
         assert cfg.activation_checkpointing.enabled is True
         assert "zero_optimization.stage" in rec["skipped"]
 
-    def test_serving_profile_fills_defaults_only(self):
+    @pytest.mark.parametrize(
+        "stale", [{}, {"a_knob_since_removed": 8}], ids=["current", "stale"])
+    def test_serving_profile_fills_defaults_only(self, stale):
+        """Config wins, defaults are filled, and a saved profile that names
+        a knob ``RaggedConfig`` no longer has is skipped, not an error."""
         from deepspeed_tpu.inference.ragged import RaggedConfig
 
         rcfg = RaggedConfig(sched_steps=4)  # operator-written
         rec = profiles.apply_serving_profile(
-            rcfg, {"overrides": {"sched_steps": 16, "fused_chunk": 8}})
+            rcfg, {"overrides": {"sched_steps": 16, "prefill_tile": 16,
+                                 **stale}})
         assert rcfg.sched_steps == 4  # config wins
-        assert rcfg.fused_chunk == 8  # still-default field filled
-        assert rec["skipped"] == {"sched_steps": 16}
-        assert rec["applied"] == {"fused_chunk": 8}
+        assert rcfg.prefill_tile == 16  # still-default field filled
+        assert rec["skipped"] == {"sched_steps": 16, **stale}
+        assert rec["applied"] == {"prefill_tile": 16}
+        assert not any(hasattr(rcfg, name) for name in stale)
 
 
 class TestKnobSpace:
     def test_registry_shape(self):
         train = DEFAULT_SPACE.knobs(TRAIN)
         serve = DEFAULT_SPACE.knobs(SERVE)
-        assert len(train) >= 5 and len(serve) >= 8
+        assert len(train) >= 5 and len(serve) >= 7
         for k in train + serve:
             assert k.default in k.domain
+        # a serve knob is a RaggedConfig field: a knob for a field that is
+        # gone would tune nothing
+        from deepspeed_tpu.inference.ragged import RaggedConfig
+
+        assert all(hasattr(RaggedConfig, k.name) for k in serve)
 
     def test_trim_and_order(self):
         names = ("activation_checkpointing.enabled",

@@ -19,6 +19,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from step_modes import MODES  # tests/unit is rootdir-inserted by pytest
 
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import llama
@@ -56,15 +57,6 @@ def _engine(cache=False, params=None, **over):
         ragged_config=RaggedConfig(**kw), dtype=jnp.float32, seed=0,
         params=params)
 
-
-# the four dispatch modes: plain SplitFuse, tiled prefill, decode run-ahead,
-# fused mixed pipeline
-MODES = {
-    "plain": {},
-    "tiled": {"prefill_tile": 8},
-    "run_ahead": {"decode_run_ahead": 4},
-    "fused": {"fused_chunk": 4, "pipeline_depth": 2},
-}
 
 SHARED = [11, 7, 3, 5, 2, 13, 17, 19]          # two full blocks of 4
 PROMPT_A = SHARED + [23, 29, 31]

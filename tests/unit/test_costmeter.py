@@ -41,8 +41,7 @@ CFG = llama.LlamaConfig(
 )
 PCFG = dict(
     max_tokens_per_step=16, max_seqs=3, block_size=4, num_blocks=49,
-    max_blocks_per_seq=16, decode_run_ahead=0, prefill_tile=0,
-    fused_chunk=0, device_state=False)
+    max_blocks_per_seq=16, prefill_tile=0, device_state=False)
 
 
 def _engine(**over):

@@ -45,19 +45,17 @@ CFG = llama.LlamaConfig(
     vocab_size=97, hidden_size=32, intermediate_size=64,
     num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=128,
 )
-# full device-resident + fused pipeline (the chaos-bench shape): exercises
-# the watchdog across the richest dispatch path
+# the device-resident tiled step (the path that serves): exercises the
+# watchdog where retries, recovery and the ladder matter
 WCFG = dict(
     max_tokens_per_step=16, max_seqs=3, block_size=4, num_blocks=49,
-    max_blocks_per_seq=16, decode_run_ahead=4, prefill_tile=8,
-    fused_chunk=4, pipeline_depth=2, device_state=True,
+    max_blocks_per_seq=16, prefill_tile=8, device_state=True,
     dispatch_retries=2, retry_backoff_s=0.01, degrade_after=2)
 # plain host-staged single-program path: cheapest to compile, used by the
 # loop/router tests that don't care which dispatch family runs
 PCFG = dict(
     max_tokens_per_step=16, max_seqs=3, block_size=4, num_blocks=49,
-    max_blocks_per_seq=16, decode_run_ahead=0, prefill_tile=0,
-    fused_chunk=0, device_state=False,
+    max_blocks_per_seq=16, prefill_tile=0, device_state=False,
     dispatch_retries=2, retry_backoff_s=0.01, degrade_after=2)
 
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from step_modes import MODES  # tests/unit is rootdir-inserted by pytest
 
 from deepspeed_tpu.comm.comm import init_distributed
 from deepspeed_tpu.comm.topology import reset_topology
@@ -47,13 +48,6 @@ CFG = llama.LlamaConfig(
 )
 
 BS = 4
-
-MODES = {
-    "plain": {},
-    "tiled": {"prefill_tile": 8},
-    "run_ahead": {"decode_run_ahead": 4},
-    "fused": {"fused_chunk": 4, "pipeline_depth": 2},
-}
 
 SHARED = [11, 7, 3, 5, 2, 13, 17, 19]          # two full blocks of 4
 PROMPT_A = SHARED + [23, 29, 31]

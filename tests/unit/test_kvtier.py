@@ -12,13 +12,16 @@ edges, the async prefetch hit/abandoned protocol, and the
 notify-before-free ordering the cluster index depends on.
 """
 
+import gc
 import os
 import pickle
 import threading
+import weakref
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from step_modes import MODES  # tests/unit is rootdir-inserted by pytest
 
 from deepspeed_tpu.inference.kvtier import (
     DiskTier,
@@ -56,13 +59,6 @@ def _engine(cache=True, **over):
         model=lambda ctx: llama.build(CFG, ctx=ctx),
         ragged_config=RaggedConfig(**kw), dtype=jnp.float32, seed=0)
 
-
-MODES = {
-    "plain": {},
-    "tiled": {"prefill_tile": 8},
-    "run_ahead": {"decode_run_ahead": 4},
-    "fused": {"fused_chunk": 4, "pipeline_depth": 2},
-}
 
 SHARED = [11, 7, 3, 5, 2, 13, 17, 19]          # two full blocks of 4
 PROMPT_A = SHARED + [23, 29, 31]               # warms the cache
@@ -448,6 +444,29 @@ class TestTieredParity:
         cold = _engine(cache=False, num_blocks=49)
         cold.put("g", PROMPT_B, max_new_tokens=8)
         assert got["g"] == cold.generate_all()["g"]
+
+    def test_dropped_tiered_engine_is_freed_without_the_cycle_collector(
+            self, tmp_path):
+        """The allocator's demote hook holds the engine weakly: a dropped
+        tiered engine gives its weights and pool back at once, not whenever
+        a full collection next runs (until then the memory ledger's census
+        reads them as unattributed bytes, in whatever test runs next)."""
+        gc.collect()
+        gc.disable()
+        try:
+            t = _engine(kv_tier=True, kv_tier_host_blocks=16,
+                        kv_tier_dir=str(tmp_path / "kv"))
+            t.put("warm", PROMPT_A, max_new_tokens=6)
+            t.generate_all()
+            _churn(t)
+            assert t.kv_tier_stats()["demotions"] > 0
+            t.reset_state()  # installs the hook on the fresh allocator too
+            t._kvtier.close()
+            gone = weakref.ref(t)
+            del t
+            assert gone() is None
+        finally:
+            gc.enable()
 
 
 class TestTierConfigGates:

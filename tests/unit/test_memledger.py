@@ -38,12 +38,12 @@ CFG = llama.LlamaConfig(
     vocab_size=97, hidden_size=32, intermediate_size=64,
     num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=128,
 )
-# plain host-staged path: cheapest to compile; the fused/device-state OOM
+# plain host-staged path: cheapest to compile; the device-state OOM
 # ladder is exercised by the CI memory-ledger smoke
 PCFG = dict(
     max_tokens_per_step=16, max_seqs=3, block_size=4, num_blocks=49,
-    max_blocks_per_seq=16, decode_run_ahead=0, prefill_tile=0,
-    fused_chunk=0, device_state=False, dispatch_retries=2,
+    max_blocks_per_seq=16, prefill_tile=0,
+    device_state=False, dispatch_retries=2,
     retry_backoff_s=0.01, degrade_after=2)
 
 

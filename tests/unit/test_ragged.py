@@ -4,6 +4,7 @@ ragged manager, blocked allocator, engine numerics vs the dense path)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from step_modes import MODES  # tests/unit is rootdir-inserted by pytest
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.ragged import (
@@ -87,66 +88,6 @@ class TestRaggedEngine:
         got = eng.generate_all()
         for uid in prompts:
             assert got[uid] == [int(t) for t in ref[uid]], uid
-
-    def test_decode_run_ahead_token_parity(self):
-        """The fused multi-step decode (decode_run_ahead) must emit exactly
-        the per-step engine's greedy tokens — it only changes dispatch
-        granularity, never the math."""
-        prompts = _prompts(7)
-        max_new = 9
-        base = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
-        for uid, p in prompts.items():
-            base.put(uid, p, max_new_tokens=max_new)
-        expect = base.generate_all()
-
-        import dataclasses
-
-        fused = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx),
-            dataclasses.replace(RCFG, decode_run_ahead=4),
-            dtype=jnp.float32, seed=0,
-        )
-        for uid, p in prompts.items():
-            fused.put(uid, p, max_new_tokens=max_new)
-        got = fused.generate_all()
-        assert got == expect
-        # the run-ahead path actually engaged: far fewer host steps than
-        # tokens generated would imply is impossible to check directly, but
-        # the chunk program must have compiled (device-resident variant by
-        # default; legacy _chunk_jit when device_state is off)
-        assert fused._dev_chunk_jits or fused._chunk_jit is not None
-
-    def test_run_ahead_respects_eos_and_limits(self):
-        """EOS inside a fused chunk truncates the stream exactly as the
-        per-step path does, and max_new_tokens is never exceeded."""
-        import dataclasses
-
-        prompts = _prompts(11)
-        base = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
-        for uid, p in prompts.items():
-            base.put(uid, p, max_new_tokens=7)
-        expect = base.generate_all()
-        # pick an eos that actually appears mid-stream for at least one seq
-        eos = next((t for toks in expect.values() for t in toks[:-1]), None)
-
-        fused = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx),
-            dataclasses.replace(RCFG, decode_run_ahead=5),
-            dtype=jnp.float32, seed=0, eos_token_id=eos,
-        )
-        for uid, p in prompts.items():
-            fused.put(uid, p, max_new_tokens=7)
-        got = fused.generate_all()
-        for uid, toks in got.items():
-            assert len(toks) <= 7
-            if eos in toks:
-                assert toks.index(eos) == len(toks) - 1  # truncated at EOS
 
     def test_tiled_prefill_token_parity(self):
         """The tile-aligned prefill layout + tiled attention path must emit
@@ -300,14 +241,9 @@ class TestRaggedEngine:
         )
 
 
-# the four dispatch modes the device-resident state must stay
-# token-identical in (mirrors test_prefix_cache.MODES)
-DISPATCH_MODES = {
-    "plain": {},
-    "tiled": {"prefill_tile": 8},
-    "run_ahead": {"decode_run_ahead": 4},
-    "fused": {"fused_chunk": 4, "pipeline_depth": 2},
-}
+# what the device-resident state must stay token-identical in, against the
+# host-staged step under the same options
+DISPATCH_MODES = {m: MODES[m] for m in ("plain", "tiled", "sched")}
 
 
 def _engine_ds(device_state, **over):
@@ -321,7 +257,7 @@ def _engine_ds(device_state, **over):
 class TestDeviceResidentState:
     """cfg.device_state keeps slot rows / block table / feed tokens on
     device and double-buffers readback; it must be token-identical to the
-    legacy host-staged path in every mode, greedy and seeded-sampled."""
+    host-staged path in every mode, greedy and seeded-sampled."""
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
     def test_token_parity_vs_host_staged(self, mode):
@@ -428,3 +364,118 @@ class TestDeviceResidentState:
                 fresh.put(f"{wave}-{uid}", p, max_new_tokens=5,
                           temperature=0.8, seed=100 + wave)
         assert fresh.generate_all() == got
+
+
+# ---------------------------------------------------------------- step paths
+# The engine takes a step in two ways (device-resident, host-staged), and ONE
+# routine decides which rows either carries. The cases drive two engines into
+# the same host state (both host-staged, so no pending window lags one of
+# them) and pack one plan for each feed.
+def _drive_to(eng, scenario):
+    p = _prompts(41)
+    if scenario == "prefill":
+        eng.put("b", p["b"], max_new_tokens=8)
+        eng.put("a", p["a"], max_new_tokens=8)
+    elif scenario == "decode":
+        eng.put("a", p["a"], max_new_tokens=8)
+        eng.put("b", p["b"], max_new_tokens=8)
+        while not (eng._running
+                   and all(s.in_decode for s in eng._running.values())):
+            eng.step()
+    elif scenario == "mixed":
+        eng.put("a", p["a"], max_new_tokens=8)
+        eng.step()
+        eng.step()
+        eng.put("c", p["c"], max_new_tokens=8)
+    else:  # "pressure": another holder leaves the pool two free blocks
+        eng.put("c", p["c"], max_new_tokens=8)
+    eng._admit_queued()
+    if scenario == "pressure":
+        eng.allocator.allocate(eng.allocator.free_blocks - 2)
+
+
+class TestStepPaths:
+    @pytest.mark.parametrize("scenario",
+                             ["decode", "prefill", "mixed", "pressure"])
+    @pytest.mark.parametrize("tile", [0, 8], ids=["untiled", "tiled"])
+    def test_one_plan_for_device_feed_and_host_feed(self, tile, scenario):
+        plans = []
+        for host_feed in (False, True):
+            eng = _engine_ds(False, prefill_tile=tile)
+            _drive_to(eng, scenario)
+            plans.append(eng._pack_step(host_feed=host_feed))
+        dev, host = plans
+        # bucket, tokens, decode bucket, tiles, highest position, KV work
+        assert dev[3:] == host[3:]
+        (d_tok, d_slot, d_pos, d_flag), d_tiles = dev[0][:4], dev[0][4:]
+        (h_tok, h_slot, h_pos, h_flag), h_tiles = host[0][:4], host[0][4:]
+        assert (d_slot == h_slot).all()
+        assert [(r, s.uid) for r, s in dev[1]] == \
+            [(r, s.uid) for r, s in host[1]]
+        assert ((d_flag & 2) == (h_flag & 2)).all()  # the rows that emit
+        feed = (d_flag & 1) > 0  # device feed: token, position from slot rows
+        assert not (h_flag & 1).any()
+        assert (d_tok[~feed] == h_tok[~feed]).all()
+        assert (d_pos[~feed] == h_pos[~feed]).all()
+        for row, seq in host[1]:
+            if feed[row]:  # host feed: the same row, from host state
+                assert h_slot[row] == seq.slot
+                assert h_pos[row] == seq.pos - 1
+                assert h_tok[row] == seq.token_at(seq.pos - 1)
+        assert len(d_tiles) == len(h_tiles) == (3 if tile else 0)
+        for d, h in zip(d_tiles, h_tiles):
+            assert (d == h).all()
+        # the scenario is the one its name says
+        n, nd, nt = dev[4], dev[5], dev[6]
+        assert feed.any() == (scenario in ("decode", "mixed"))
+        assert (n > feed.sum()) == (scenario != "decode")
+        assert (nd > 0, nt > 0) == ((bool(tile) and feed.any()),
+                                    (bool(tile) and scenario != "decode"))
+        if scenario == "pressure":
+            assert n == 2 * RCFG.block_size  # a partial chunk, not 16 tokens
+
+    def test_warmup_without_k_step_option(self, monkeypatch):
+        """What the benchmark's set-up relies on: with no ``sched_steps``
+        ``warmup()`` compiles nothing, turns the compile cache on and zeroes
+        the cold-dispatch baseline."""
+        turned_on = []
+        monkeypatch.setattr(
+            "deepspeed_tpu.utils.compile_cache.enable_compile_cache",
+            lambda: turned_on.append(True))
+        eng = _engine_ds(True, prefill_tile=8)
+        eng.put("a", _prompts()["a"], max_new_tokens=3)
+        eng.generate_all()
+        assert eng.program_cold_dispatches > 0
+        assert eng.warmup() == 0
+        assert turned_on == [True]
+        assert eng.program_dispatches == eng.program_cold_dispatches == 0
+        assert not eng._dev_sched_jits
+        eng.put("b", _prompts()["a"], max_new_tokens=3)
+        eng.generate_all()
+        assert eng.program_dispatches > 0
+        assert eng.program_cold_dispatches == 0  # the programs were there
+
+    def test_ladder_second_rung_turns_tiles_off(self):
+        """Failures that go on after rung 1 (host-staged) take rung 2: the
+        host-staged step with prefill tiles off, token-identical."""
+        from deepspeed_tpu.serving.faults import (POINT_DISPATCH,
+                                                  get_fault_injector)
+
+        over = dict(prefill_tile=8, dispatch_retries=2, retry_backoff_s=0.0,
+                    degrade_after=2)
+        outs = {}
+        for faulty in (False, True):
+            eng = _engine_ds(True, **over)
+            if faulty:
+                get_fault_injector().configure(
+                    [{"point": POINT_DISPATCH, "after": 2, "times": 4}])
+            for uid, p in _prompts(43).items():
+                eng.put(uid, p, max_new_tokens=6, temperature=0.8,
+                        seed=7 + len(p))
+            outs[faulty] = eng.generate_all()
+        assert outs[True] == outs[False]
+        assert eng.degraded_mode == 2 and eng.step_failures == 4
+        assert not eng.cfg.device_state
+        assert eng.cfg.prefill_tile == 0 and not eng._use_tiles
+        assert eng._step_keys and not eng._pending  # the plain step served
+        assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1

@@ -59,13 +59,13 @@ def _dense_reference(name, prompts, max_new):
     return out
 
 
-def _ragged(name, fused=0, tile=0, quant="off"):
+def _ragged(name, tile=0, quant="off", device_state=True):
     return RaggedInferenceEngine(
         model=_build(name), dtype=jnp.float32, seed=0,
         ragged_config=RaggedConfig(
             max_tokens_per_step=16, max_seqs=3, block_size=4,
             num_blocks=49, max_blocks_per_seq=16,
-            fused_chunk=fused, prefill_tile=tile, quant=quant))
+            prefill_tile=tile, quant=quant, device_state=device_state))
 
 
 @pytest.mark.parametrize("name", ["mixtral", "gpt2", "deepseek"])
@@ -80,18 +80,6 @@ class TestRaggedFamilies:
             eng.put(uid, p, max_new_tokens=8)
         assert eng.generate_all() == want
 
-    def test_fused_pipeline_parity(self, name):
-        """The fused mixed-chunk pipeline serves the family too (device-fed
-        multi-step decode over the paged cache, MoE routing inside the
-        scan for mixtral)."""
-        prompts = _prompts(5, seed=11)
-        legacy = _ragged(name)
-        fused = _ragged(name, fused=4)
-        for uid, p in prompts.items():
-            legacy.put(uid, p, max_new_tokens=7)
-            fused.put(uid, p, max_new_tokens=7)
-        assert fused.generate_all() == legacy.generate_all()
-
     def test_tiled_prefill_parity(self, name):
         prompts = _prompts(4, seed=7)
         flat = _ragged(name)
@@ -100,6 +88,21 @@ class TestRaggedFamilies:
             flat.put(uid, p, max_new_tokens=5)
             tiled.put(uid, p, max_new_tokens=5)
         assert flat.generate_all() == tiled.generate_all()
+
+
+@pytest.mark.parametrize("name", ["gpt2", "llama", "mixtral", "deepseek"])
+def test_host_staged_fallback_parity(name):
+    """What a degraded engine serves on (the host-staged step, tiled) gives
+    the family the device step's tokens: five prompts through three slots,
+    so prefill tiles ride beside decode rows."""
+    prompts = _prompts(5, seed=11)
+    device = _ragged(name, tile=4)
+    host = _ragged(name, tile=4, device_state=False)
+    for uid, p in prompts.items():
+        device.put(uid, p, max_new_tokens=7)
+        host.put(uid, p, max_new_tokens=7)
+    assert host.generate_all() == device.generate_all()
+    assert host._tiled_jits and not host._dev_step_jits
 
 
 def test_mixtral_decode_routing_is_per_token():

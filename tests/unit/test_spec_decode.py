@@ -1,9 +1,9 @@
 """Device-side multi-step decode scheduling + self-speculative decoding
 (``sched_steps`` / ``spec_draft``): token-identity parity against the plain
 host-staged path across every dispatch mode (greedy AND seeded), prefix-cache
-hits, mid-flight cancel during a multi-step chunk, the mid-chunk EOS
-retirement masking in the fused programs, warmup coverage of the new
-scheduler program family, and the speculation telemetry counters."""
+hits, mid-flight cancel during a multi-step chunk, mid-chunk EOS retirement,
+warmup coverage of the scheduler program family, and the speculation
+telemetry counters."""
 
 import dataclasses
 
@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import step_modes  # tests/unit is rootdir-inserted by pytest
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
@@ -26,14 +27,9 @@ RCFG = RaggedConfig(
     num_blocks=49, max_blocks_per_seq=16,
 )
 
-# the four dispatch modes the scheduler loop must stay token-identical in
-# (mirrors test_prefix_cache.MODES / test_ragged.DISPATCH_MODES)
-MODES = {
-    "plain": {},
-    "tiled": {"prefill_tile": 8},
-    "run_ahead": {"decode_run_ahead": 4},
-    "fused": {"fused_chunk": 4, "pipeline_depth": 2},
-}
+# the step options the scheduler loop rides on and must stay
+# token-identical under
+MODES = {m: step_modes.MODES[m] for m in ("plain", "tiled")}
 
 
 def _engine(**over):
@@ -248,74 +244,39 @@ class TestCancelMidMultiStep:
         assert eng.allocator.free_blocks == RCFG.num_blocks - 1
 
 
-class TestFusedEosMasking:
-    """Mid-chunk retirement in the FUSED path: a row that hits EOS inside a
-    fused chunk stops contributing compute — later steps of its column carry
-    the -1 sentinel, never real (wasted) tokens."""
-
-    @pytest.mark.parametrize("device_state", [False, True])
-    def test_post_eos_steps_are_masked(self, device_state):
-        probe = _engine(device_state=False)
-        p = _prompts(7)["b"]
-        probe.put("x", p, max_new_tokens=10)
-        ref = probe.generate_all()["x"]
-        eos = int(ref[1])  # EOS at generated index 1: inside chunk 1
-
-        # depth 2 keeps a chunk in flight across step() returns so the
-        # probe below can actually inspect its readback buffer
-        eng = _engine(fused_chunk=4, pipeline_depth=2,
-                      device_state=device_state)
-        eng.put("x", p, max_new_tokens=10, eos_token_id=eos)
-        seen_masked = False
-        for _ in range(50):
-            if not eng.has_work:
-                break
-            eng.step()
-            for rec in eng._inflight_chunks:
-                dec = np.asarray(rec["dec_toks"])
-                for j, (seq, k_s) in enumerate(rec["decs"]):
-                    col = list(dec[:k_s, j])
-                    if eos in col:
-                        cut = col.index(eos)
-                        assert all(t == -1 for t in col[cut + 1:]), (
-                            "post-EOS steps surfaced real tokens", col)
-                        if cut + 1 < k_s:
-                            seen_masked = True
-        assert seen_masked, "EOS never struck mid-chunk; probe setup broken"
-        out = {u: list(s.generated) for u, s in eng._results.items()}
-        assert out["x"] == ref[:2]  # truncated at EOS, nothing extra
-
-
 class TestWarmupCoverage:
     def test_warmup_lowers_sched_programs(self):
-        """warmup() must precompile the multi-step scheduler family too:
-        with fused prefill + sched decode warmed, live traffic compiles
-        NOTHING (program_cold_dispatches stays 0) and coverage reads 1.0."""
+        """warmup() must precompile the multi-step scheduler family: live
+        traffic then creates NO scheduler program (every cold dispatch is a
+        SplitFuse step program, which warmup() leaves to a server's set-up)
+        and the coverage gauge says so."""
         telemetry.configure(enabled=True)
-        eng = _engine(fused_chunk=4, pipeline_depth=2, sched_steps=4)
+        eng = _engine(sched_steps=4)
         assert eng.cfg.device_state
         n = eng.warmup()
         assert n > 0
-        assert eng._dev_sched_jits   # scheduler programs actually lowered
+        assert len(eng._dev_sched_jits) == n   # actually lowered
         legacy = _engine(device_state=False)
         for uid, p in _prompts(31).items():
             eng.put(uid, p, max_new_tokens=6)
             legacy.put(uid, p, max_new_tokens=6)
         assert eng.generate_all() == legacy.generate_all()
+        assert len(eng._dev_sched_jits) == n, (
+            "serve-time scheduler compile after warmup")
         assert eng.program_dispatches > 0
-        assert eng.program_cold_dispatches == 0, (
-            "serve-time compile after warmup")
+        assert eng.program_cold_dispatches == len(eng._dev_step_jits)
         tel = telemetry.get_telemetry()
         eng._sample_step_telemetry()
-        assert tel.registry.gauge("ragged_warmup_coverage").value() == 1.0
+        assert tel.registry.gauge("ragged_warmup_coverage").value() == \
+            1.0 - eng.program_cold_dispatches / eng.program_dispatches
 
     def test_warmup_covers_spec_variant(self):
-        eng = _engine(fused_chunk=4, pipeline_depth=2, sched_steps=4,
-                      spec_draft=2)
-        assert eng.warmup() > 0
+        eng = _engine(sched_steps=4, spec_draft=2)
+        n = eng.warmup()
+        assert n > 0
         legacy = _engine(device_state=False)
         for uid, p in _prompts(37).items():
             eng.put(uid, p, max_new_tokens=6)
             legacy.put(uid, p, max_new_tokens=6)
         assert eng.generate_all() == legacy.generate_all()
-        assert eng.program_cold_dispatches == 0
+        assert len(eng._dev_sched_jits) == n
