@@ -575,8 +575,8 @@ def serve_phase(cfg, rcfg, requests: list, *, sample: int,
              f"{rcfg.num_blocks - 1}")
 
     # which attention ran where: the tiled prefill programs must hold the
-    # Pallas kernel; decode at this context is the XLA gather by the
-    # dispatcher's measured rule (ops/attention.paged_attention)
+    # Pallas kernel; decode is the paged-decode kernel at every table width
+    # (ops/attention.paged_attention)
     prefill_programs = {k: fn for k, fn in engine._dev_step_jits.items()
                         if k[2] > 0}
     _require(prefill_programs, "serve: no tiled prefill program was built")
@@ -622,8 +622,8 @@ def serve_phase(cfg, rcfg, requests: list, *, sample: int,
                                       for r in results]},
         engine=stats,
         attention={"prefill": f"pallas tiled ({kernels} tpu_custom_call)",
-                   "decode": "xla gather (context <= 2048, "
-                             "ops/attention.paged_attention)"},
+                   "decode": "pallas paged_decode "
+                             "(ops/attention.paged_attention)"},
         numerics=numerics, memory=mem)
 
 

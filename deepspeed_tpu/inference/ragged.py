@@ -2732,21 +2732,23 @@ class RaggedInferenceEngine:
     def _table_view(self, max_pos: int):
         """Slice the block table to the bucketed block count covering
         ``max_pos`` (the highest position any token in this dispatch will
-        touch). The Pallas kernels grid their KV loop over the TABLE WIDTH,
-        so a full-width table makes every token pay ``max_blocks_per_seq``
-        grid steps regardless of its context (the round-4 bandwidth finding);
-        slicing host-side bounds the grid by the batch's ACTUAL context.
+        touch). The tiled prefill kernel grids its KV loop over the TABLE
+        WIDTH (a grid step for every entry, the ones past a tile's context
+        predicated off), and the XLA gather of a quantized pool or the CPU
+        gathers the whole width; the decode kernel walks each row's own
+        context whatever the width.
 
-        Short tables pass through whole: every distinct width is a fresh
-        program shape to compile and warm. Power-of-4 buckets keep the
-        long-context compile count tiny."""
+        Tables of 64 blocks and fewer pass through whole: every distinct
+        width is a fresh program shape to compile and warm. Power-of-4
+        buckets keep the long-context compile count tiny."""
         return self.block_tables[:, :self._table_width(max_pos)]
 
     def _table_width(self, max_pos: int) -> int:
         """Bucketed block-table width covering ``max_pos`` (the shared
         bucketing behind ``_table_view``; the device-resident path keeps the
         full table on device and bakes this width into the program as a
-        static so the kernel grid is bounded without any per-step upload)."""
+        static, so the prefill kernel's grid is bounded without any per-step
+        upload)."""
         mb = self.cfg.max_blocks_per_seq
         if mb <= 64:
             return mb

@@ -123,10 +123,14 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     ``[blocks, BS, Hkv*D]`` (reference ``inference/v2/kernels/ragged_ops``
     blocked flash attention).
 
-    impl="pallas": stream blocks through VMEM via the block table (no padded
-    gather); impl="xla": gather the padded context (short contexts, quantized
-    pools, the CPU). The choice is made here from the backend and the
-    shapes; an error from the chosen kernel propagates.
+    ``impl="auto"``: on the chip an unquantized pool takes the Pallas kernel
+    at every table width, since the kernel walks each row's own context and
+    its cost does not follow the table (``ops/pallas/paged_attention.py``;
+    one layer, GPT-2 XL's heads, 4 rows x 350 tokens of a 1,024-token table:
+    gather 176 us, kernel 20 us; 32 rows 3.35 ms and 0.13; the table in
+    PERF.md section 6, PR 29). Off the chip it is the XLA gather of the
+    padded context. The choice is made here, from the backend and the pool's
+    kind; an error from the chosen kernel propagates.
 
     A quantized pool (``inference/kvquant.QuantizedKV``) always takes the
     XLA path: the gather+dequant fuse into one program there (the fp
@@ -137,25 +141,7 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     if getattr(k_pool, "is_quantized_kv", False):
         impl = "xla"
     if impl == "auto":
-        import os
-
-        impl = os.environ.get("DSTPU_PAGED_IMPL", "")
-        if not impl:
-            if not _on_tpu():
-                impl = "xla"
-            else:
-                # measured on v5e (T=32, bs=32, bf16): the padded-gather XLA
-                # path wins below ~2K tokens of real context (4.8 ms vs
-                # 6.8 ms at 18 blocks) — decode there is tiny-matmul-bound
-                # and the sequential per-(token, block) kernel grid loses to
-                # one fused gather+attention op; past ~2K the gather's
-                # O(T * ctx) materialization loses to the kernel's streamed
-                # blocks (19.8 ms vs 29.5 ms at 8K). The engine slices the
-                # block table to the batch's real context (_table_view), so
-                # this width tracks actual context, not engine capacity.
-                ctx = block_tables.shape[1] * k_pool.shape[1]
-                cross = int(os.environ.get("DSTPU_PAGED_XLA_CTX", 2048))
-                impl = "xla" if ctx <= cross else "pallas"
+        impl = "pallas" if _on_tpu() else "xla"
     if impl == "pallas":
         from deepspeed_tpu.ops.pallas.paged_attention import (
             paged_decode_attention,

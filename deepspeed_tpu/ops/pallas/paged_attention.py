@@ -9,15 +9,31 @@ materializes ``[T, max_blocks*block, H, D]``; this kernel streams one block
 at a time through VMEM with online-softmax accumulation).
 
 Mechanism: ``PrefetchScalarGridSpec`` — the block table and slot/position
-vectors are scalar-prefetch operands, so the KV BlockSpec index map resolves
-``pool_block = block_tables[slots[t], j]`` *before* the kernel body runs and
-the DMA fetches exactly that block (the TPU paged-attention idiom). Blocks
-past the token's position are predicated off with ``pl.when``.
+vectors are scalar-prefetch operands, so a KV BlockSpec's index map resolves
+``pool_block = block_tables[slot, j]`` *before* the kernel body runs and the
+DMA fetches exactly that block (the TPU paged-attention idiom). A DMA written
+by hand cannot (Mosaic refuses to slice GPT-2 XL's 1600-lane rows out of the
+pool: "slice shape along dimension 2 must be aligned to tiling (128)"), so
+both kernels leave the fetching to the pipeline.
 
 The pool has the paged contract's storage form (``models/paged.py``):
-``[blocks, BS, Hkv*D]``, a token row one lane-dense vector of all KV heads.
-Both kernels fetch ``(1, BS, Hkv*D)`` blocks and split the heads inside VMEM
-(``_split_heads``), so the pool is never re-laid-out around them.
+``[blocks, BS, Hkv*D]``, a token row one lane-dense vector of all KV heads,
+and is never re-laid-out around the kernels.
+
+``paged_decode`` walks each decode row's OWN context. Its grid is not rows x
+table width but the rows' steps laid end to end: a row at position ``pos``
+takes ``pos // CH + 1`` steps of ``CH`` tokens (``decode_step_blocks`` whole
+pool blocks, each an operand of its own), the grid's length is their sum, a
+traced value, and two prefetched vectors say which row and which chunk a
+step works on. So no grid step is spent on a table entry past a row's
+context and none is read: past the row's last block an operand's index stays
+what it was a step before. Inside a step the heads are not split either: the
+query rides in as ``[Hq, Hkv*D]``, each head's ``D`` values in its KV head's
+lanes and zeros elsewhere, so one bf16 matmul against the chunk gives all
+heads' scores, one more ``P x chunk``, and the output is that product's
+diagonal segments. The prefill kernel's grid is tiles x table width, past a
+tile's last block clamped to it (no new DMA) and predicated off; it splits
+the heads out of a block in VMEM (``_split_heads``).
 
 Inference-only (no VJP): the ragged engine never differentiates through
 decode.
@@ -45,49 +61,75 @@ def _split_heads(ref, hkv: int, d: int):
                       for g in range(hkv)])
 
 
-def _kernel(slots_ref, pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-            acc, m_sc, l_sc, *, bs: int, hkv: int, rep: int, scale: float):
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    pos = pos_ref[t]
+# Bytes of K (and as many of V) one grid step of the decode kernel takes, as
+# whole pool blocks: a step costs ~0.2 us beside its DMA and its matmuls,
+# which both go by the byte, so the blocks a step takes follow from their
+# size: four of GPT-2 XL's 32-token blocks (128 tokens), two of Mixtral's
+# 128-token blocks (256). Twice as much costs the chat shapes ~10% (a row's
+# last step is mostly masked), half as much the long-document ones 17%
+# (sweep on the chip, PERF.md section 6, PR 29). Eight blocks at most: each
+# is an operand of its own, with two buffers and a DMA in flight.
+DECODE_STEP_BYTES = 512 * 1024
+_DECODE_STEP_BLOCKS_MAX = 8
 
-    @pl.when(j == 0)
+
+def decode_step_blocks(bs: int, lanes: int, itemsize: int) -> int:
+    """Pool blocks one grid step of the decode kernel takes: the power of
+    two that ``DECODE_STEP_BYTES`` holds, 1 to 8."""
+    want = max(1, DECODE_STEP_BYTES // (bs * lanes * itemsize))
+    return min(1 << (want.bit_length() - 1), _DECODE_STEP_BLOCKS_MAX)
+
+
+def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
+                   *refs, bs: int, nb: int, hkv: int, rep: int, d: int,
+                   scale: float):
+    k_refs, v_refs = refs[:nb], refs[nb:2 * nb]
+    o_ref, acc, m_sc, l_sc = refs[2 * nb:]
+    s_id = pl.program_id(0)
+    t = row_ref[s_id]
+    c = chunk_ref[s_id]
+    pos = pos_ref[t]
+    ch = nb * bs
+
+    @pl.when(c == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    @pl.when(j * bs <= pos)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale          # [Hq, D]
-        hq, d = q.shape
-        k = _split_heads(k_ref, hkv, d)                   # [Hkv, BS, D]
-        v = _split_heads(v_ref, hkv, d)
-        qg = q.reshape(hkv, rep, d)
-        # scores[g, r, k] over this block's keys
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (0,))),          # contract D, batch g
-        )                                                 # [Hkv, rep, BS]
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
-        s = jnp.where(kpos <= pos, s, _NEG_INF)
-        s = s.reshape(hq, bs)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)        # [Hq, 1]
-        m_prev = m_sc[:, :1]
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new)                            # [Hq, BS]
-        corr = jnp.exp(m_prev - m_new)                    # [Hq, 1]
-        l_sc[:, :1] = l_sc[:, :1] * corr + jnp.sum(p, -1, keepdims=True)
-        m_sc[:, :1] = m_new
-        pg = p.reshape(hkv, rep, bs)
-        pv = jax.lax.dot_general(
-            pg, v, (((2,), (1,)), ((0,), (0,))),          # [Hkv, rep, D]
-        ).reshape(hq, d)
-        acc[:] = acc[:] * corr + pv
+    def blocks(block_refs):                               # [CH, Hkv*D]
+        parts = [r[0] for r in block_refs]
+        return parts[0] if nb == 1 else jnp.concatenate(parts, axis=0)
 
-    @pl.when(j == nj - 1)
+    q = q_ref[0]                                          # [Hq, Hkv*D]
+    k = blocks(k_refs)
+    s = jax.lax.dot_general(q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    kpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (1, ch), 1)
+    s = jnp.where(kpos <= pos, s, _NEG_INF)               # [Hq, CH]
+    # position 0 is never masked, so the running maximum is real from a
+    # row's first chunk on and no row of p is all zeros
+    m_prev = m_sc[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[:, :1] = l_sc[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_sc[:, :1] = m_new
+    v = blocks(v_refs)
+    acc[:] = acc[:] * corr + jnp.dot(p.astype(v.dtype), v,
+                                     preferred_element_type=jnp.float32)
+
+    @pl.when(c == pos // ch)
     def _finish():
-        o_ref[0] = (acc[:] / jnp.maximum(l_sc[:, :1], 1e-30)).astype(o_ref.dtype)
+        hq, hd = acc.shape
+        # row r*Hkv + g holds head (g, r) in lanes g*D .. (g+1)*D - 1
+        mine = (jax.lax.broadcasted_iota(jnp.int32, (hq, hd), 1) // d
+                == jax.lax.broadcasted_iota(jnp.int32, (hq, hd), 0) % hkv)
+        o = jnp.where(mine, acc[:] / l_sc[:, :1], 0.0)
+        for r in range(rep):
+            o_ref[0, r:r + 1, :] = jnp.sum(
+                o[r * hkv:(r + 1) * hkv], axis=0, keepdims=True
+            ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
@@ -97,47 +139,98 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
 
     ``k_pool``/``v_pool``: [blocks, BS, Hkv*D]; ``block_tables``:
     [max_seqs+1, MB] mapping (slot, block-ordinal) -> pool block id. Exact
-    vs the dense-gather path (same position masking).
+    vs the dense-gather path (same position masking). Each row reads blocks
+    ``0 .. pos // BS`` of its sequence, once, whatever ``MB`` is.
     """
+    scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
+    return _paged_decode(
+        q, k_pool, v_pool, slots.astype(jnp.int32),
+        positions.astype(jnp.int32), block_tables.astype(jnp.int32),
+        scale=float(scale), interpret=interpret_mode(interpret))
+
+
+# ONE jitted function: the step programs of one row count (d4_t0 .. d4_t3)
+# share its trace
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
+                  scale: float, interpret: bool):
     t_tokens, hq, d = q.shape
     _, bs, hd = k_pool.shape
     hkv = hd // d
-    mb = block_tables.shape[1]
     rep = hq // hkv
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    nb = decode_step_blocks(bs, hd, k_pool.dtype.itemsize)
 
-    # Past the token's last valid block (j > pos // bs) the index map clamps
-    # to that last block: the pipeline sees an unchanged block id, skips the
-    # DMA, and the body's `pl.when` predicate skips the compute — so decode
-    # bandwidth scales with the actual context, not the table width, and
-    # nothing is ever read through freed/stale block_tables entries.
-    def _kv_map(t, j, sl, po, bt):
-        return (bt[sl[t], jnp.minimum(j, po[t] // bs)], 0, 0)
+    # The grid is the rows' chunks laid end to end, ``pos // CH + 1`` of them
+    # a row: step s works on chunk ``step_chunk[s]`` of row ``step_row[s]``.
+    # The pipeline reads the index maps one step ahead, so the vectors have
+    # one entry more than there can be steps, and entries past the last stay
+    # on the last row's last chunk, whose blocks are then in place already.
+    # (Made again in every layer of a step: 0.4 us of 20, sweep of PR 29.)
+    n_chunks = positions // (nb * bs) + 1
+    ends = jnp.cumsum(n_chunks)
+    steps = jnp.arange(t_tokens * -(-block_tables.shape[1] // nb) + 1,
+                       dtype=jnp.int32)
+    step_row = jnp.minimum(
+        jnp.sum(steps[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        t_tokens - 1)
+    step_chunk = jnp.minimum(steps - (ends - n_chunks)[step_row],
+                             n_chunks[step_row] - 1)
 
+    # The heads stay where the pool has them, side by side in a row's lanes:
+    # query head (g, r) becomes row r*Hkv + g of a [Hq, Hkv*D] matrix that is
+    # zero outside lanes g*D .. (g+1)*D - 1, so ONE matmul against a chunk
+    # [CH, Hkv*D] gives every head's scores, and the diagonal segments of
+    # P x chunk every head's output. ``spread`` copies a head's D values into
+    # every KV head's lanes (a matmul whose every sum has one term, so exact:
+    # XLA re-lays a [.., Hkv, D] -> [.., Hkv*D] reshape out at D = 64) and
+    # ``mine`` keeps a row's own.
+    lane = jnp.arange(hd)[None, :]
+    spread = (lane % d == jnp.arange(d)[:, None]).astype(q.dtype)
+    mine = lane // d == jnp.arange(hq)[:, None] % hkv
+    q_rows = q.reshape(t_tokens, hkv, rep, d).transpose(0, 2, 1, 3).reshape(
+        t_tokens, hq, d)
+    q_wide = jnp.where(mine, jnp.einsum(
+        "thd,de->the", q_rows, spread,
+        precision=jax.lax.Precision.HIGHEST), 0).astype(q.dtype)
+
+    def _row_map(s, row, chunk, sl, po, bt):
+        return (row[s], 0, 0)
+
+    def _kv_map(i):
+        # block i of the step's chunk. Past the row's last block the entry
+        # is never read: the index stays what this operand had a step ago
+        # (no new DMA; the keys there are masked), or the row's last block
+        def index(s, row, chunk, sl, po, bt):
+            t, j = row[s], chunk[s] * nb + i
+            last = po[t] // bs
+            j = jnp.where(j <= last, j, jnp.where(j >= nb, j - nb, last))
+            return (bt[sl[t], j], 0, 0)
+        return index
+
+    kv_specs = [pl.BlockSpec((1, bs, hd), _kv_map(i)) for i in range(nb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(t_tokens, mb),
-        in_specs=[
-            pl.BlockSpec((1, hq, d), lambda t, j, sl, po, bt: (t, 0, 0)),
-            pl.BlockSpec((1, bs, hd), _kv_map),
-            pl.BlockSpec((1, bs, hd), _kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, hq, d), lambda t, j, sl, po, bt: (t, 0, 0)),
+        num_scalar_prefetch=5,
+        grid=(ends[-1],),
+        in_specs=[pl.BlockSpec((1, hq, hd), _row_map)] + kv_specs + kv_specs,
+        out_specs=pl.BlockSpec((1, rep, hd), _row_map),
         scratch_shapes=[
-            pltpu.VMEM((hq, d), jnp.float32),
+            pltpu.VMEM((hq, hd), jnp.float32),
             pltpu.VMEM((hq, 128), jnp.float32),
             pltpu.VMEM((hq, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, bs=bs, hkv=hkv, rep=rep, scale=scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_decode_kernel, bs=bs, nb=nb, hkv=hkv, rep=rep,
+                               d=d, scale=scale)
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_tokens, rep, hd), q.dtype),
         grid_spec=grid_spec,
-        interpret=interpret_mode(interpret),
+        interpret=interpret,
         name="paged_decode",
-    )(slots.astype(jnp.int32), positions.astype(jnp.int32),
-      block_tables.astype(jnp.int32), q, k_pool, v_pool)
+    )(step_row, step_chunk, slots, positions, block_tables, q_wide,
+      *([k_pool] * nb), *([v_pool] * nb))
+    return out.reshape(t_tokens, rep, hkv, d).transpose(0, 2, 1, 3).reshape(
+        t_tokens, hq, d)
 
 
 # --------------------------------------------------------------- tiled prefill

@@ -149,6 +149,33 @@ def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
     assert text.count("tpu_custom_call") >= n_calls
 
 
+# the decode rows of the three K/V-pool serving cells: (q heads, kv heads,
+# head size, block size, table width, rows, pool blocks)
+DECODE_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, 32, 4, 513),
+                "mixtral.chat": (32, 8, 128, 128, 8, 16, 1025),
+                "mixtral.longdoc": (32, 8, 128, 128, 64, 16, 1537)}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+def test_decode_kernel_compiles_at_the_cells_shapes(v5e, cell):
+    """``paged_decode`` at a cell's block size and table width: a grid whose
+    length is traced, one operand a block of a step (GPT-2 XL's 1600-lane
+    blocks, which no hand-written DMA may slice), and nothing beside the
+    kernel larger than the [rows, heads, lanes] query it is handed."""
+    hq, hkv, d, block, table, rows, blocks = DECODE_CELLS[cell]
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    pool, i32 = s((blocks, block, hkv * d)), s((rows,), jnp.int32)
+    compiled = jax.jit(_decode).lower(
+        s((rows, hq, d)), pool, pool, i32, i32,
+        s((rows + 1, table), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**21
+
+
 @pytest.mark.parametrize("kernel", [_mla_decode, _mla_prefill],
                          ids=["mla_decode", "mla_prefill"])
 def test_mla_kernel_compiles_for_v5e(v5e, kernel):
@@ -269,6 +296,28 @@ def _step_family(name):
         num_kv_heads=8), 1025, 128, 32
 
 
+def _abstract_step(v5e, mod, cfg, blocks, block, codec=None):
+    """A family's bf16 parameters and paged cache as shapes on the first
+    described chip, and ``on_chip(tree)`` / ``i32(*shape)`` for the rest of
+    a step's arguments."""
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        mod.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: mod.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16, codec=codec))
+    return on_chip, i32, params, cache
+
+
 _HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "bf16": 2, "f16": 2,
               "s32": 4, "u32": 4, "f32": 4}
 # results that are another buffer's bytes under a new name
@@ -323,30 +372,17 @@ def test_step_program_holds_no_layer_slice_of_the_pool(v5e, monkeypatch,
     and reads through block tables. Nothing else in it — no slice, copy or
     re-layout — is as large as one layer's slice of the pool, and its
     temporaries together stay under one slice. The fp step runs the tiled
-    prefill kernel (and past 2,048 tokens of table the decode kernel); a
+    prefill kernel and, at every table width, the decode kernel; a
     ``QuantizedKV`` pool takes the XLA gather, whose float32 context is
     rows x table wide: here four decode rows over a table of two blocks."""
     from deepspeed_tpu.inference import kvquant
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
     mod, cfg, blocks, block, table = _step_family(family)
-    dev = jax.sharding.SingleDeviceSharding(v5e[0])
     rows, tiles, table = (4, 0, 2) if quantized else (8, 1, table)
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
-            tree)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
-
-    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.bfloat16),
-        mod.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.eval_shape(lambda: mod.init_paged_cache(
-        cfg, blocks, block, jnp.bfloat16,
-        codec=kvquant.get_codec("int8") if quantized else None))
+    on_chip, i32, params, cache = _abstract_step(
+        v5e, mod, cfg, blocks, block,
+        codec=kvquant.get_codec("int8") if quantized else None)
     payload = jax.tree_util.tree_leaves(cache)[0]       # k (or its payload)
     assert payload.shape == (cfg.num_layers, blocks, block,
                              payload.shape[-1])
@@ -368,9 +404,36 @@ def test_step_program_holds_no_layer_slice_of_the_pool(v5e, monkeypatch,
     assert len(scatters) == 2, scatters
     assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
     if not quantized:
-        assert compiled.as_text().count("tpu_custom_call") >= (
-            2 if table * block > 2048 else 1)
+        assert compiled.as_text().count("tpu_custom_call") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+
+
+def test_chat_decode_step_gathers_no_table_wide_context(v5e, monkeypatch):
+    """GPT-2 XL's chat decode step (4 rows, a table of 32 blocks of 32
+    tokens): before PR 29 every layer gathered each row's whole table as
+    ``f32[4,1024,25,64]``, 58% of the step on the chip. The step holds the
+    decode kernel and no array of rows x 1,024 positions x the heads, in any
+    layout or precision."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mod, cfg, blocks, block, _ = _step_family("gpt2-xl")
+    rows, table = 4, 32
+    on_chip, i32, params, cache = _abstract_step(v5e, mod, cfg, blocks, block)
+
+    def step(params, cache, tokens, slots, positions, tables):
+        return mod.ragged_forward(cfg, params, tokens, slots, positions,
+                                  tables, cache)
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(rows), i32(rows), i32(rows),
+        i32(33, table)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if " custom-call(" in ln
+             and "tpu_custom_call" in ln]
+    assert calls and all("paged_decode" in ln.split(" = ")[0] for ln in calls)
+    context = rows * table * block * cfg.num_heads * (
+        cfg.hidden_size // cfg.num_heads)
+    # (the pool itself passes through the kernel and the two row scatters)
+    assert [ln for size, op, ln in _materialized(text)
+            if op not in ("kernel", "scatter") and size >= context] == []
 
 
 @pytest.mark.parametrize("family,rows,tiles", [("mixtral", 8, 3),
@@ -386,21 +449,7 @@ def test_grouped_step_copies_no_expert_weights(v5e, monkeypatch, family, rows,
     experts, and nothing shaped like one layer's gets a buffer."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
     mod, cfg, blocks, block, table = _step_family(family)
-    dev = jax.sharding.SingleDeviceSharding(v5e[0])
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
-            tree)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
-
-    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.bfloat16),
-        mod.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.eval_shape(lambda: mod.init_paged_cache(
-        cfg, blocks, block, jnp.bfloat16))
+    on_chip, i32, params, cache = _abstract_step(v5e, mod, cfg, blocks, block)
 
     def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
         return mod.ragged_forward(
@@ -439,21 +488,7 @@ def test_decode_step_relays_out_no_expert_stack(v5e, monkeypatch):
     cfg = deepseek.DeepseekConfig(vocab_size=512, num_layers=4)
     assert (cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
             cfg.top_k) == (2048, 1408, 64, 6)
-    dev = jax.sharding.SingleDeviceSharding(v5e[0])
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
-            tree)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
-
-    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.bfloat16),
-        deepseek.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.eval_shape(lambda: deepseek.init_paged_cache(
-        cfg, 65, 128, jnp.bfloat16))
+    on_chip, i32, params, cache = _abstract_step(v5e, deepseek, cfg, 65, 128)
 
     def step(params, cache, tokens, slots, positions, tables):
         return deepseek.ragged_forward(cfg, params, tokens, slots, positions,
@@ -477,22 +512,8 @@ def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
     as large as one layer's slice of the pool."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
     mod, cfg, blocks, block, table = _step_family("moonlight")
-    dev = jax.sharding.SingleDeviceSharding(v5e[0])
     rows, tiles = 128, 1
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
-            tree)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
-
-    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.bfloat16),
-        mod.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = jax.eval_shape(lambda: mod.init_paged_cache(
-        cfg, blocks, block, jnp.bfloat16))
+    on_chip, i32, params, cache = _abstract_step(v5e, mod, cfg, blocks, block)
     (leaf,) = jax.tree_util.tree_leaves(cache)
     assert leaf.shape == (cfg.num_layers, blocks, block, MLA_WIDTH)
     layer_slice = blocks * block * MLA_WIDTH * 2

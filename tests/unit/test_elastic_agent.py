@@ -20,6 +20,12 @@ import os, sys, time
 open({str(tmp_path)!r} + f"/seen_{{os.environ['RANK']}}_{{os.environ['WORLD_SIZE']}}", "w").close()
 if os.environ['RANK'] == {die_rank!r} and not os.path.exists({str(marker)!r}):
     open({str(marker)!r}, "w").close()
+    # die only once rank 0 of this wave is up: the agent tears the wave down
+    # within a poll of the death, and a loaded machine starts python slowly
+    for _ in range(200):
+        if os.path.exists({str(tmp_path)!r} + f"/seen_0_{{os.environ['WORLD_SIZE']}}"):
+            break
+        time.sleep(0.05)
     sys.exit(17)
 time.sleep(0.2)
 """

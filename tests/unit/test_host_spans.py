@@ -301,9 +301,13 @@ FIXTURE_OF = {"mixtral-8x7b-d3.longdoc-pool": "v5e_mixtral_longdoc_spans",
 # readers of what PR 27 added to the program (the dispatch span's ``moe``,
 # the ``moe_gmm`` kernel) read a slice recorded with it
 FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans",
-                     "kernel.moe_gmm_share": "v5e_moonlight_reason_moe_spans"}
+                     "kernel.moe_gmm_share": "v5e_moonlight_reason_moe_spans",
+                     # PR 29: the decode kernel in a chat cell's decode steps
+                     "kernel.chat_paged_decode_share": "v5e_gpt2xl_chat_decode_spans",
+                     "kernel.chat_paged_decode_roofline": "v5e_gpt2xl_chat_decode_spans"}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
-                   "moonlight-16b-a3b-d8.reason-pool"}
+                   "moonlight-16b-a3b-d8.reason-pool",
+                   "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open"}
 
 
 def _new_readers():

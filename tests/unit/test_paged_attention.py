@@ -41,6 +41,85 @@ def test_pallas_mixed_prefill_decode_positions():
                                rtol=2e-5, atol=2e-5)
 
 
+# (q heads, kv heads, head size, block size, table width): the two chat
+# cells' decode shapes (benchmark/configs/gpt2-xl.json, mixtral-8x7b-d3.json)
+CHAT_GEOMETRIES = {"gpt2-xl": (25, 25, 64, 32, 32),
+                   "mixtral": (32, 8, 128, 128, 8)}
+
+
+def _chat_case(geometry, rows, dtype, seed=0):
+    """``rows`` decode rows of unequal context over a pool of 64 blocks + a
+    poisoned one: positions 0, a block's last token, the next block's first,
+    a partly filled last block, a step's last token and the next step's
+    first, the whole table, then random ones; the last of several rows is
+    the padding row (all-scratch table row, position 0). Returns the
+    kernel's arguments, whose table entries past each row's context are
+    out-of-range ids or the NaN block, and the gather's, where they are 0."""
+    from deepspeed_tpu.ops.pallas.paged_attention import decode_step_blocks
+
+    hq, hkv, d, bs, mb = CHAT_GEOMETRIES[geometry]
+    rng = np.random.default_rng(seed)
+    nb, poisoned = 66, 65
+    q = rng.normal(size=(rows, hq, d)).astype(np.float32)
+    kp = rng.normal(size=(nb, bs, hkv * d)).astype(np.float32)
+    vp = rng.normal(size=(nb, bs, hkv * d)).astype(np.float32)
+    kp[poisoned] = vp[poisoned] = np.nan
+    ch = bs * decode_step_blocks(bs, hkv * d, jnp.dtype(dtype).itemsize)
+    edges = [0, bs - 1, bs, bs + 5, ch - 1, min(ch, mb * bs - 1), mb * bs - 1]
+    pos = np.array([edges[r] if r < len(edges) else rng.integers(0, mb * bs)
+                    for r in range(rows)], np.int32)
+    slots = np.arange(rows, dtype=np.int32)
+    clean = np.zeros((rows + 1, mb), np.int32)
+    dirty = np.zeros((rows + 1, mb), np.int32)
+    for r in range(rows):
+        need = pos[r] // bs + 1
+        clean[r, :need] = rng.integers(1, poisoned, need)
+        dirty[r] = rng.choice([2**30, -1, poisoned, nb], mb)
+        dirty[r, :need] = clean[r, :need]
+    if rows > 1:                       # the padding row
+        slots[-1], pos[-1] = rows, 0
+    def args(table):
+        return (jnp.asarray(q, dtype), jnp.asarray(kp, dtype),
+                jnp.asarray(vp, dtype), jnp.asarray(slots), jnp.asarray(pos),
+                jnp.asarray(table))
+    return args(dirty), args(clean)
+
+
+@pytest.mark.parametrize("rows", [1, 4, 8, 32])
+@pytest.mark.parametrize("geometry", sorted(CHAT_GEOMETRIES))
+def test_decode_kernel_walks_each_rows_own_context(geometry, rows):
+    """The decode kernel (interpret mode) against the gather at the chat
+    cells' shapes: the walk ends at each row's position and never reads
+    through a table entry past it (those hold out-of-range ids and a block
+    of NaNs, which one masked read would carry into the output)."""
+    dirty, clean = _chat_case(geometry, rows, jnp.float32)
+    want = paged_attention(*clean, impl="xla")
+    got = paged_attention(*dirty, impl="pallas")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("geometry", sorted(CHAT_GEOMETRIES))
+def test_decode_kernel_in_the_pools_own_precision(geometry):
+    """bfloat16, as the cells serve: more blocks a step than in float32
+    (``decode_step_blocks`` goes by bytes), products of bf16 operands
+    accumulated in float32; ``chip_smoke.py``'s measure and limit."""
+    dirty, clean = _chat_case(geometry, 8, jnp.bfloat16, seed=1)
+    want = np.asarray(paged_attention(*clean, impl="xla"), np.float32)
+    got = np.asarray(paged_attention(*dirty, impl="pallas"), np.float32)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() / np.abs(want).max() <= 3e-2
+
+
+def test_decode_step_blocks_follow_the_blocks_bytes():
+    from deepspeed_tpu.ops.pallas.paged_attention import decode_step_blocks
+
+    assert decode_step_blocks(32, 1600, 2) == 4     # GPT-2 XL: 128 tokens
+    assert decode_step_blocks(128, 1024, 2) == 2    # Mixtral, Llama-3-8B: 256
+    assert decode_step_blocks(128, 1024, 4) == 1
+    assert decode_step_blocks(8, 32, 4) == 8        # never more than eight
+
+
 @pytest.mark.parametrize("CT,MB,split", [(8, 4, False), (32, 10, True)],
                          ids=["whole_tile", "split_tile"])
 def test_tiled_prefill_kernel_matches_xla(CT, MB, split, monkeypatch):

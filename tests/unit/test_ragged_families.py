@@ -271,7 +271,6 @@ def test_pallas_kernels_read_through_a_layers_own_table(name, monkeypatch):
 
     want, want_cache = run()
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    monkeypatch.setenv("DSTPU_PAGED_IMPL", "pallas")
     got, got_cache = run()
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     # but for block 0, every layer's scratch block: padding rows write there
