@@ -188,10 +188,16 @@ def device_phase(chips: int) -> dict:
 
 # ----------------------------------------------------------------- kernels
 def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
-                  block: int = KV_BLOCK) -> dict:
+                  block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
+                  experts=(16, 1024, 2688, 64, 6)) -> dict:
     """Each Pallas kernel of the train and serve paths against the XLA path
     on the same seeded bf16 input, at each ``(q heads, kv heads, head size)``.
-    Off the chip the kernels interpret; on it this is their first execution."""
+    Off the chip the kernels interpret; on it this is their first execution.
+    Beside the attention kernels, once each: the Mamba-2 decode kernel at
+    ``ssm`` = (state rows, state size, heads x head size, groups) against
+    XLA's gather -> update -> scatter, and the grouped expert kernel's
+    two-matrix ``relu**2`` form at ``experts`` = (held, latent, ffn, routed,
+    top k), a held share, against the all-experts einsum."""
     import jax
     import jax.numpy as jnp
 
@@ -263,6 +269,38 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
         close("tiled_prefill",
               jnp.where(valid[:, None, None], outs["pallas"], 0),
               jnp.where(valid[:, None, None], outs["xla"], 0))
+
+    from deepspeed_tpu.models import experts as moe
+    from deepspeed_tpu.ops.pallas.ssm import ssm_decode, ssm_decode_xla
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 1), 10))
+    rows_n, n, hp, groups = ssm
+    rows = jnp.asarray(np.random.default_rng(SEED).permutation(rows_n)[:8],
+                       jnp.int32)
+    args = (jax.random.normal(next(keys), (rows_n, n, hp), jnp.float32), rows,
+            jax.random.uniform(next(keys), (8, hp), jnp.float32),
+            jax.random.normal(next(keys), (8, hp), jnp.float32),
+            jax.random.normal(next(keys), (8, n, groups), jnp.float32),
+            jax.random.normal(next(keys), (8, n, groups), jnp.float32))
+    for name, got, want in zip(("ssm_decode_state", "ssm_decode_y"),
+                               jax.jit(ssm_decode)(*args),
+                               jax.jit(ssm_decode_xla)(*args)):
+        close(name, got, want, tol=1e-5)
+
+    held, lat, ffn, routed, top_k = experts
+    h = jax.random.normal(next(keys), (256, lat), jnp.bfloat16)
+    router = jax.random.normal(next(keys), (lat, routed), jnp.float32)
+    w_up = (jax.random.normal(next(keys), (held, lat, ffn), jnp.float32)
+            * lat ** -0.5).astype(jnp.bfloat16)
+    w_down = (jax.random.normal(next(keys), (held, ffn, lat), jnp.float32)
+              * ffn ** -0.5).astype(jnp.bfloat16)
+    share = (held, routed)   # the second share of routed // held
+    topv, topi = moe._route(h, router, top_k, "sigmoid", None, True, 5.0, 1e-20)
+    close("moe_gmm_relu2",
+          jax.jit(lambda *a: moe._grouped_experts(*a, None, w_up, w_down, 0,
+                                                  held, share))(h, topv, topi),
+          jax.jit(lambda *a: moe._einsum_experts(*a, None, w_up, w_down,
+                                                 share))(h, topv, topi))
 
     return _emit("kernels", seconds=round(time.perf_counter() - t0, 2),
                  geometries=[list(g) for g in geometries],
@@ -787,7 +825,10 @@ def main(argv=None) -> int:
                 steps=6, expect_reduce_scatter=True)
             sharded_full_phase(xl, seq_len=SEQ_LEN, steps=4)
         else:
-            kernels_phase([(xl.num_heads, xl.num_heads, xl.hd), (32, 8, 128)])
+            # GPT-2 XL; Llama-3-8B / Mixtral; Nemotron-3's 16 query heads a
+            # KV head on 256-lane rows
+            kernels_phase([(xl.num_heads, xl.num_heads, xl.hd), (32, 8, 128),
+                           (32, 2, 128)])
             train_phase(
                 dataclasses.replace(xl, num_layers=TRAIN_LAYERS),
                 full_layers=xl.num_layers, micro_batch=TRAIN_MICRO_BATCH,

@@ -38,6 +38,7 @@ import numpy as np
 
 from deepspeed_tpu.inference import kvquant
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx
+from deepspeed_tpu.models.paged import block_leaves, slot_leaves
 from deepspeed_tpu.serving.faults import (
     POINT_ALLOC,
     POINT_DISPATCH,
@@ -56,6 +57,28 @@ from deepspeed_tpu.utils.tracing import span
 # cannot cover the reservation) a multi-step scheduler dispatch still runs up
 # to this many decode steps: decode progress is exactly what frees blocks.
 SCHED_ADMISSION_CAP = 8
+
+
+_CACHE_MISSES = [0]  # this process's persistent-compilation-cache misses
+
+
+def _persistent_cache_miss_counter() -> Callable[[], int]:
+    """Count jax's ``/jax/compilation_cache/cache_misses`` events from now
+    on (one listener a process, however many engines ask)."""
+    if len(_CACHE_MISSES) == 1:
+        def on_event(event: str, **_) -> None:
+            if event == "/jax/compilation_cache/cache_misses":
+                _CACHE_MISSES[0] += 1
+
+        jax.monitoring.register_event_listener(on_event)
+        _CACHE_MISSES.append(on_event)
+    return lambda: _CACHE_MISSES[0]
+
+
+# why a model with slot state cannot be resumed from blocks alone
+_NO_SNAPSHOT = ("a sequence's recurrent state is not in its blocks, and there "
+                "is no snapshot of it at a block boundary to restore beside "
+                "them")
 
 
 class BlockedAllocator:
@@ -429,14 +452,35 @@ class _SeqState:
     sla_class: str = "interactive"
     cost: Any = None
 
+    # a model with slot state, after the watchdog's recovery: positions
+    # [0, replay) are run again as prefill, from an empty state (the prompt
+    # and the generated tokens before the resume point; none of them emits)
+    replay: int = 0
+
     def token_at(self, p: int) -> int:
         if p < len(self.prompt):
             return self.prompt[p]
         return self.generated[p - len(self.prompt)]
 
+    def tokens_at(self, start: int, end: int) -> list[int]:
+        if end <= len(self.prompt):
+            return self.prompt[start:end]
+        return (self.prompt + self.generated)[start:end]
+
+    @property
+    def prefill_end(self) -> int:
+        """The first position that runs as a decode row."""
+        return max(len(self.prompt), self.replay)
+
+    @property
+    def emits_at_prompt_end(self) -> bool:
+        """The row of the prompt's last token picks the first generated
+        token, unless a replay is passing it: that token is known."""
+        return not self.generated
+
     @property
     def in_decode(self) -> bool:
-        return self.pos >= len(self.prompt)
+        return self.pos >= self.prefill_end
 
     @property
     def finished(self) -> bool:
@@ -622,6 +666,13 @@ class RaggedInferenceEngine:
             )(self.params)
         self.quantize_bits = woq_bits
         self.cache = self._build_cache()
+        # a model whose layers carry a recurrent state a slot keeps it in
+        # slot leaves beside the pool's block leaves (models/paged.py); the
+        # engine learns it from the cache the model's own hook built
+        self._slot_state = slot_leaves(self.cache) is not None
+        self._slot_bytes = self.state_bytes_per_slot()
+        if self._slot_state:
+            self._refuse_for_slot_state()
         # bytes one block would cost unquantized at the engine dtype / at
         # fp16: the baselines for kvquant_bytes_saved_total and the
         # resident-block multiplier the bench gates on. The blocks base
@@ -693,9 +744,10 @@ class RaggedInferenceEngine:
         if self._use_tiles and self.cfg.prefill_tile > self.cfg.max_tokens_per_step:
             raise ValueError("prefill_tile exceeds max_tokens_per_step")
         self._tiled_jits: dict = {}
-        # decode-region buckets for the tiled path (decodes <= max_seqs)
+        # decode-region buckets for the tiled path (decodes <= max_seqs):
+        # doubling from the model's smallest (``ModelSpec.decode_bucket_min``)
         self._dec_buckets = []
-        b = 4
+        b = self.spec.decode_bucket_min
         while b < self.cfg.max_seqs:
             self._dec_buckets.append(b)
             b *= 2
@@ -832,6 +884,10 @@ class RaggedInferenceEngine:
         self.program_dispatches = 0
         self.program_cold_dispatches = 0
         self._warmed = False
+        # ``warmup`` sets it where a persistent compilation cache is on: a
+        # function that says how many lookups have missed it so far (the
+        # first step program's tells whether the cache is cold)
+        self._cache_misses: Callable[[], int] | None = None
         # specialization keys already dispatched for the paths whose jit
         # cache is internal to jax (no explicit program dict to probe)
         self._step_keys: set = set()
@@ -895,6 +951,8 @@ class RaggedInferenceEngine:
         prompt = [int(t) for t in np.asarray(prompt_tokens).reshape(-1)]
         if not prompt:
             raise ValueError("empty prompt")
+        if handoff:
+            self._refuse_block_transfer("KVHandoff")
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         # a prefill-stage (handoff) request runs prompt + ONE token here;
@@ -1093,16 +1151,60 @@ class RaggedInferenceEngine:
         self._prefix_listener = listener
         self.allocator.listener = listener
 
+    def _refuse_for_slot_state(self) -> None:
+        """What assumes that a sequence's context is a chain of blocks, and
+        nothing else, cannot serve a model with slot state: each refusal
+        names the piece that is missing."""
+        cfg = self.cfg
+        refused = (
+            (cfg.enable_prefix_cache, "enable_prefix_cache", _NO_SNAPSHOT),
+            (cfg.kv_tier, "kv_tier", _NO_SNAPSHOT),
+            (cfg.sched_steps >= 2, "sched_steps",
+             "a multi-step program has no slot-state rows of its own yet"),
+            (cfg.spec_draft, "spec_draft",
+             "a rejected draft position has already moved the recurrent "
+             "state, and there is no rollback"),
+            (self._kvq is not None, f"quant={cfg.quant!r}",
+             "a quantized pool beside slot state is not implemented"),
+            (not cfg.prefill_tile, "prefill_tile=0",
+             "a prompt's rows in one step must be a tile: the recurrence "
+             "runs them as one chunk, in order"),
+        )
+        for on, name, why in refused:
+            if on:
+                self._refuse(name, why)
+
+    def _refuse(self, what: str, why: str) -> None:
+        raise ValueError(f"model {self.spec.name} keeps recurrent state in "
+                         f"slot leaves; {what} is refused: {why}")
+
+    def _refuse_block_transfer(self, what: str) -> None:
+        """``KVHandoff`` moves a sequence as its blocks."""
+        if self._slot_state:
+            self._refuse(what, _NO_SNAPSHOT)
+
+    def _blocks(self):
+        """The cache's block leaves (``[L, NB, ...]`` each): what every
+        per-block operation and byte count below sees."""
+        return block_leaves(self.cache)
+
     def kv_bytes_per_token(self) -> int:
         """Bytes of paged-cache state one token position occupies across all
-        cache leaves — the bytes side of the transfer-vs-prefill cost model."""
+        block leaves — the bytes side of the transfer-vs-prefill cost model.
+        A model's slot leaves are not a token's: ``state_bytes_per_slot``."""
         bs = self.cfg.block_size
         total = 0
-        for a in jax.tree_util.tree_leaves(self.cache):
+        for a in jax.tree_util.tree_leaves(self._blocks()):
             per_block = int(a.shape[0]) * int(np.prod(a.shape[2:])) \
                 * a.dtype.itemsize
             total += per_block // bs
         return total
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one slot holds across all slot leaves,
+        whatever its sequence's length; 0 for a model with none."""
+        return sum(int(a.shape[0]) * int(np.prod(a.shape[2:])) * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(slot_leaves(self.cache)))
 
     def _block_bytes(self) -> int:
         """Bytes one KV block occupies across all cache leaves (cached)."""
@@ -1118,7 +1220,7 @@ class RaggedInferenceEngine:
         transient fp allocation at the full pool size)."""
         return self.spec.init_paged_cache_fn(
             self.cfg.num_blocks, self.cfg.block_size, self.dtype,
-            codec=self._kvq)
+            codec=self._kvq, num_slots=self.cfg.max_seqs + 1)
 
     def kv_quant_stats(self) -> dict | None:
         """Low-bit KV summary for bench/telemetry readers; None = quant off.
@@ -1160,7 +1262,7 @@ class RaggedInferenceEngine:
             "params": led.register("params", "ragged/model_params",
                                    self.params),
             "kv_pool": led.register("kv_pool", "ragged/paged_kv_cache",
-                                    self.cache),
+                                    self._blocks()),
             "device_sched_state": led.register(
                 "device_sched_state", "ragged/slot_rows+block_table",
                 (self._dev_state, self._bt_dev)),
@@ -1168,6 +1270,10 @@ class RaggedInferenceEngine:
         if self._hist_dev is not None:
             h["spec_lanes"] = led.register(
                 "spec_lanes", "ragged/spec_token_history", self._hist_dev)
+        if self._slot_state:
+            h["slot_state"] = led.register(
+                "slot_state", "ragged/recurrent_slot_state",
+                slot_leaves(self.cache))
         self._memledger_handles = h
         ref = weakref.ref(self)
 
@@ -1229,7 +1335,9 @@ class RaggedInferenceEngine:
         h = self._memledger_handles
         if led is None or h is None:
             return
-        led.update(h["kv_pool"], self.cache)
+        led.update(h["kv_pool"], self._blocks())
+        if "slot_state" in h:
+            led.update(h["slot_state"], slot_leaves(self.cache))
         led.update(h["device_sched_state"],
                    (self._dev_state, self._bt_dev))
         if "spec_lanes" in h:
@@ -1339,7 +1447,7 @@ class RaggedInferenceEngine:
             r *= 2
         idx = np.zeros(r, np.int32)
         idx[:n] = blocks
-        out = g(self.cache, jnp.asarray(idx))
+        out = g(self._blocks(), jnp.asarray(idx))
         return jax.tree_util.tree_map(lambda a: np.asarray(a[:, :n]), out)
 
     def _scatter_blocks(self, blocks: list[int], payload) -> None:
@@ -1360,7 +1468,9 @@ class RaggedInferenceEngine:
                 payload)
         self.h2d_bytes += idx.nbytes + sum(
             int(a.nbytes) for a in jax.tree_util.tree_leaves(payload))
-        self.cache = s(self.cache, jnp.asarray(idx), payload)
+        blocks = s(self._blocks(), jnp.asarray(idx), payload)
+        self.cache = ({**self.cache, **blocks} if self._slot_state
+                      else blocks)
 
     def export_handoff(self, uid) -> KVHandoff | None:
         """Turn a finished prefill-stage request (``put(handoff=True)``) into
@@ -1437,6 +1547,7 @@ class RaggedInferenceEngine:
         when no slot or insufficient unreserved blocks are available right
         now (the cluster falls back to a cold submit); raises ValueError for
         requests this engine could never serve."""
+        self._refuse_block_transfer("KVHandoff")
         cfg = self.cfg
         bs = cfg.block_size
         if getattr(h, "codec", "off") != self._kvq_name:
@@ -2013,7 +2124,8 @@ class RaggedInferenceEngine:
         token). When the watchdog rebuilds a mid-decode sequence's row,
         ``pos`` is already past the prompt and the host-known token at that
         position seeds the device feed instead."""
-        tok = seq.token_at(seq.pos) if seq.pos >= len(seq.prompt) else 0
+        feed = max(seq.pos, seq.replay)  # where the next decode row feeds
+        tok = seq.token_at(feed) if feed >= len(seq.prompt) else 0
         iv = np.asarray([tok, seq.pos, seq.seed, len(seq.prompt), seq.top_k],
                         np.int32)
         fv = np.asarray([seq.temperature, seq.top_p], np.float32)
@@ -2123,8 +2235,13 @@ class RaggedInferenceEngine:
         key = (t, nd, nt, w, sampled, has_tk, has_tp)
         fn = self._dev_step_jits.get(key)
         self._note_program("dev_step", fn is None)
-        if fn is not None:
-            return fn
+        if fn is None:
+            fn = self._dev_step_jits[key] = self._build_dev_step(*key)
+        return fn
+
+    def _build_dev_step(self, t: int, nd: int, nt: int, w: int, sampled: bool,
+                        has_tk: bool, has_tp: bool):
+        """The jitted program of ``_get_dev_step``'s key, newly made."""
         fwd = self.spec.ragged_forward_fn
         ct = self.cfg.prefill_tile if self._use_tiles else 0
         max_seqs = self.cfg.max_seqs
@@ -2174,9 +2291,72 @@ class RaggedInferenceEngine:
         # the program's name in a trace (``jit_ragged_step_d8_t3``): the same
         # string the engine/dispatch span carries
         step_fn.__name__ = self._step_program_name(t, nd, nt)
-        fn = jax.jit(step_fn, donate_argnums=(1, 2))
-        self._dev_step_jits[key] = fn
-        return fn
+        return jax.jit(step_fn, donate_argnums=(1, 2))
+
+    def _step_zoo(self) -> list[tuple]:
+        """``(t, nd, nt, w)`` of every greedy device step program the
+        scheduler can pick with prefill tiles on: a function of the engine's
+        sizes alone (each decode bucket beside each tile count
+        ``_plan_prefill_tiles`` can return, each table width)."""
+        cfg = self.cfg
+        ct, budget = cfg.prefill_tile, cfg.max_tokens_per_step
+        zoo = []
+        for nd in [0] + self._dec_buckets:
+            cap = (budget - nd) // ct
+            tiles, p = {cap}, 1
+            while p < cap:
+                tiles.add(p)
+                p *= 2
+            for nt in sorted(tiles - {0}) + ([0] if nd else []):
+                zoo += [(nd + nt * ct, nd, nt, w) for w in self._width_ladder()]
+        return zoo
+
+    def _precompile_zoo_in_background(self) -> None:
+        """The first step program of this engine found nothing in the
+        persistent compilation cache, so none of them will: compile the
+        others now, several at a time on threads of their own
+        (``lower().compile()``: nothing runs, no engine state is touched, the
+        executables go to the cache and are dropped), while the caller goes
+        on dispatching. A program the foreground reaches later is traced
+        there as ever and its compile is a cache hit; one it reaches before
+        the background has is compiled twice, which costs only spare cores.
+        Compiling is what a cold set-up spends its time on (27 programs of
+        4-7 s each at the benchmark's 128-slot engines). The chip's compiler
+        takes one program at a time whatever the threads, so what this buys
+        is the overlap of the foreground's tracing, lowering and running with
+        the compiling: 178 -> 146 s on the chip's host, twice (PERF.md
+        section 6, PR 31), not the several-fold a CPU-only compile shows."""
+        import os
+        from concurrent.futures import ThreadPoolExecutor
+
+        def abstract(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+        fixed = (abstract(self.params), abstract(self.cache),
+                 abstract(self._dev_state), abstract(self._bt_dev))
+        root = abstract(self._sample_root)
+
+        def compile_one(key):
+            t, _, nt, _ = key
+            try:
+                staged = jax.ShapeDtypeStruct((4 * t + 3 * max(nt, 1),),
+                                              jnp.int32)
+                self._build_dev_step(*key, False, False, False).lower(
+                    *fixed, staged, root).compile()
+            except Exception as e:  # the foreground compiles it itself
+                log_dist(f"ragged engine: background compile of step "
+                         f"program {key} failed: {e!r}", ranks=[0])
+
+        workers = max(1, min(8, (os.cpu_count() or 2) - 2))
+        pool = ThreadPoolExecutor(workers, thread_name_prefix="ragged-compile")
+        have = {key[:4] for key in self._dev_step_jits}
+        for key in self._step_zoo():
+            if key not in have:
+                pool.submit(compile_one, key)
+        pool.shutdown(wait=False)
+        log_dist(f"ragged engine: cold compilation cache; compiling the step "
+                 f"programs {workers} at a time in the background", ranks=[0])
 
     # ------------------------------------- device-side multi-step scheduler
     def _get_dev_sched(self, k: int, t: int, w: int, sampled: bool,
@@ -2462,14 +2642,15 @@ class RaggedInferenceEngine:
         (once a sequence), ``attn_pairs`` the query x key pairs, and
         ``dec_kv_tokens`` the decode rows' part of ``kv_tokens`` (a decode
         row is one query, so also its part of the pairs); ``moe`` is the
-        form of the step's expert FFNs (``_moe_attr``)."""
+        form of the step's expert FFNs (``_moe_attr``); a model with slot
+        state adds what of it the step moves (``_state_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
         if plan is None:
             return False
         (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec, kv_pre,
-         pairs_pre) = plan
+         pairs_pre, n_dec, n_pre) = plan
         sampled = any(s.temperature > 0.0 for _, s in emit)
         has_tk = sampled and any(s.top_k > 0 for _, s in emit)
         has_tp = sampled and any(s.top_p < 1.0 for _, s in emit)
@@ -2478,16 +2659,27 @@ class RaggedInferenceEngine:
             staged = self._stage(np.concatenate(parts))
         fn = self._get_dev_step(t_total, nd, nt, self._table_width(max_pos),
                                 sampled, has_tk, has_tp)
+        # the FIRST step program an engine builds tells whether the persistent
+        # cache is cold (``warmup`` counts its misses). Only the first: a warm
+        # cache that misses a program or two later (its size limit thins it)
+        # must not pay for a background compile of programs it mostly has
+        probe = (self._cache_misses is not None and self._use_tiles
+                 and len(self._dev_step_jits) == 1
+                 and self.program_dispatches == self.program_cold_dispatches == 1)
+        misses = self._cache_misses() if probe else 0
         if self._faults.enabled:
             self._faults.fire(POINT_DISPATCH)
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
-                  **self._moe_attr(t_total)):
+                  **self._moe_attr(t_total),
+                  **self._state_attr(n_dec, n_pre, n)):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._bt_dev,
                 staged, self._sample_root)
+        if probe and self._cache_misses() > misses:
+            self._precompile_zoo_in_background()
         participants: dict[int, _SeqState] = {}
         for _, seq in emit:
             participants[seq.slot] = seq
@@ -2509,6 +2701,20 @@ class RaggedInferenceEngine:
         model itself calls; nothing for a family without routed experts."""
         form = self.spec.moe_form
         return {} if form is None else {"moe": form(t)}
+
+    def _state_attr(self, n_dec: int, n_pre: int, n: int) -> dict:
+        """For a model with slot state, what of it a step moves:
+        ``state_bytes``, the slot-state bytes the step must read and write
+        (its decode rows and its distinct prefilling slots, a slot's state
+        once each way), ``dec_state_bytes`` the decode rows' part, and
+        ``ssm_prefill_tokens``, the prompt tokens its tiles run through the
+        recurrence. Nothing for a model with none."""
+        if not self._slot_state:
+            return {}
+        per_slot = 2 * self._slot_bytes
+        return {"state_bytes": (n_dec + n_pre) * per_slot,
+                "dec_state_bytes": n_dec * per_slot,
+                "ssm_prefill_tokens": n - n_dec}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:
         """The name a device-resident step program goes by in a trace: its
@@ -2583,7 +2789,7 @@ class RaggedInferenceEngine:
             for seq, tile0, take in chunks:
                 start = nd + tile0 * ct
                 sl = slice(start, start + take)
-                tokens[sl] = seq.prompt[seq.pos:seq.pos + take]
+                tokens[sl] = seq.tokens_at(seq.pos, seq.pos + take)
                 slots[sl] = seq.slot
                 positions[sl] = np.arange(seq.pos, seq.pos + take,
                                           dtype=np.int32)
@@ -2598,7 +2804,7 @@ class RaggedInferenceEngine:
                 sched += take
                 if trace_on:
                     tpairs.append((seq, "engine/prefill", take))
-                if seq.pos == len(seq.prompt):
+                if seq.pos == len(seq.prompt) and seq.emits_at_prompt_end:
                     flags[start + take - 1] |= 2
                     emit.append((start + take - 1, seq))
             n = n_dec + sched
@@ -2609,13 +2815,13 @@ class RaggedInferenceEngine:
             for seq in list(self._running.values()):
                 if seq.finished or seq.in_decode or n >= budget:
                     continue
-                take = min(budget - n, len(seq.prompt) - seq.pos)
+                take = min(budget - n, seq.prefill_end - seq.pos)
                 while take and not self._ensure_capacity(seq, seq.pos + take):
                     take -= 1  # partial chunk under pool pressure
                 if take <= 0:
                     continue
                 sl = slice(n, n + take)
-                tokens[sl] = seq.prompt[seq.pos:seq.pos + take]
+                tokens[sl] = seq.tokens_at(seq.pos, seq.pos + take)
                 slots[sl] = seq.slot
                 positions[sl] = np.arange(seq.pos, seq.pos + take,
                                           dtype=np.int32)
@@ -2626,7 +2832,7 @@ class RaggedInferenceEngine:
                 n += take
                 if trace_on:
                     tpairs.append((seq, "engine/prefill", take))
-                if seq.pos == len(seq.prompt):
+                if seq.pos == len(seq.prompt) and seq.emits_at_prompt_end:
                     flags[n - 1] |= 2
                     emit.append((n - 1, seq))
             t_total = 0 if n == 0 else next(b for b in self._buckets
@@ -2640,7 +2846,7 @@ class RaggedInferenceEngine:
         if ct:
             parts += [ts, tpz, tv]
         return (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec,
-                kv_pre, pairs_pre)
+                kv_pre, pairs_pre, n_dec, len(chunks) if ct else 0)
 
     def _reconcile_pending(self) -> dict:
         """Read back the OLDEST pending dispatch's tokens and fold them
@@ -2776,7 +2982,7 @@ class RaggedInferenceEngine:
             if seq.finished or seq.in_decode or tiles_used >= ntiles_cap:
                 continue
             avail = (ntiles_cap - tiles_used) * ct
-            take = min(avail, len(seq.prompt) - seq.pos)
+            take = min(avail, seq.prefill_end - seq.pos)
             while take and not self._ensure_capacity(seq, seq.pos + take):
                 take -= 1  # partial chunk under pool pressure
             if take <= 0:
@@ -2815,13 +3021,18 @@ class RaggedInferenceEngine:
         costs seconds of compilation in the middle of serving, and a program
         the compiler refuses raises here, before any request is taken. The
         SplitFuse step programs are NOT compiled here (a server runs each
-        once at set-up; they reach later processes through the cache).
+        once at set-up; they reach later processes through the cache). What
+        is set here is the cache's miss counter: if the FIRST step program a
+        server then runs misses the cache, none of them is in it, and the
+        engine compiles the rest in the background while the server goes on
+        (``_precompile_zoo_in_background``); a warm cache sees no change.
         Returns the number of programs compiled, 0 without ``sched_steps``.
         Greedy combos by default; call again with ``sampled``/filter flags
         for sampling workloads."""
         from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-        enable_compile_cache()
+        if enable_compile_cache() and self._cache_misses is None:
+            self._cache_misses = _persistent_cache_miss_counter()
         n = 0
         if self.cfg.sched_steps >= 2 and self.cfg.device_state:
             n += self._warmup_sched(sampled, has_tk, has_tp)
@@ -3193,6 +3404,13 @@ class RaggedInferenceEngine:
             else:
                 # mid-prefill: re-prefill the uncached tail (idempotent)
                 seq.pos = seq.cached_prefix
+            if self._slot_state:
+                # re-running a position rewrites identical K and V but would
+                # move a recurrent state a second time, and there is no
+                # rollback: the sequence starts again from an empty state
+                # and runs everything before its resume point as prefill
+                seq.replay = seq.pos if g else 0
+                seq.pos = 0
         # device mirrors are stale by construction now: rebuild the block
         # table wholesale and re-seed the slot rows from host truth
         self._bt_dirty.clear()
@@ -3223,7 +3441,7 @@ class RaggedInferenceEngine:
             cfg.device_state = False
             self.degraded_mode = 1
             rung = "host-staged fallback (device_state off)"
-        elif self._use_tiles:
+        elif self._use_tiles and not self._slot_state:
             cfg.prefill_tile = 0
             self._use_tiles = False
             self.degraded_mode = 2
@@ -3425,6 +3643,10 @@ class RaggedInferenceEngine:
             len(self._queued))
         g("inference_running_seqs", "admitted sequences").set(
             len(self._running))
+        if self._slot_state:
+            g("inference_state_slots",
+              "slots holding a sequence's recurrent state").set(
+                  len(self._running))
         g("inference_tokens_scheduled", "useful token-slots scheduled").set(
             self.tokens_scheduled)
         g("inference_tokens_padded", "padding token-slots scheduled").set(

@@ -333,8 +333,11 @@ class ModelSpec:
     init_cache_fn: Callable | None = None
     decode_fn: Callable | None = None
     # ragged/continuous-batching hooks (reference inference/v2):
-    # init_paged_cache_fn(num_blocks, block_size, dtype, codec=None) -> cache
-    #   in the paged contract's storage form (models/paged.py);
+    # init_paged_cache_fn(num_blocks, block_size, dtype, codec=None,
+    #   num_slots=None) -> cache in the paged contract's storage form
+    #   (models/paged.py): block leaves, and for a model with a recurrent
+    #   state a slot, slot leaves of num_slots rows (max_seqs + 1) under
+    #   "slots" -- that is how the engine learns the model has them;
     # ragged_forward_fn(params, tokens, slots, positions, block_tables, cache)
     #   -> (logits [T, V], cache)
     init_paged_cache_fn: Callable | None = None
@@ -346,6 +349,12 @@ class ModelSpec:
     # routed experts take at a step of ``rows`` tokens (models/experts.py's
     # rule on the model's geometry); None for a family with no routed experts
     moe_form: Callable[[int], str] | None = None
+    # the smallest decode-row bucket of the tiled step programs (the engine's
+    # ladder doubles from it to max_seqs; every bucket is a step program
+    # beside each tile count, to compile cold and to load cached). A model
+    # whose padding row costs its step next to nothing asks for a coarser
+    # ladder than the default
+    decode_bucket_min: int = 4
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

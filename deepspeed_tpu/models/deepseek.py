@@ -300,11 +300,13 @@ def forward(cfg: DeepseekConfig, params, input_ids, ctx: ShardCtx | None = None,
 
 # ------------------------------------------------------------------ inference
 def init_paged_cache(cfg: DeepseekConfig, num_blocks: int, block_size: int,
-                     dtype=jnp.bfloat16, codec=None) -> dict:
+                     dtype=jnp.bfloat16, codec=None,
+                     num_slots=None) -> dict:
     """The latent pool of the ragged engine, ``{"kv": [L, num_blocks,
     block_size, row_lanes]}``: ONE row a token and layer, ``[c, k_rope,
     zeros]``, in the paged contract's storage form (``models/paged.py``);
     ``L`` counts the dense layers too."""
+    del num_slots  # this family keeps no state a slot (models/paged.py)
     if codec is not None:
         raise NotImplementedError(
             "deepseek: a quantized latent pool is not implemented (a row's "

@@ -1,10 +1,13 @@
 """Expert FFNs of the inference paths, shared by the MoE families.
 
-``routed_experts`` is the dropless per-token top-k MoE both ``mixtral`` and
-``deepseek`` serve through; the families differ only in how a token's combine
-weights come out of the router's logits (its keyword arguments). It has two
-exact forms of the same computation, and ``expert_form`` picks between them
-from the step's shapes. ``routed_experts_einsum`` is the one form that
+``routed_experts`` is the dropless per-token top-k MoE ``mixtral``,
+``deepseek`` and ``nemotron_h`` serve through; the families differ in how a
+token's combine weights come out of the router's logits (its keyword
+arguments), in the expert itself (three matrices, gated SwiGLU; or two,
+ungated ``relu**2``: ``w_gate`` None) and in which of the routed experts the
+layer holds (``held``: one rank's share of an expert-parallel deployment). It
+has two exact forms of the same computation, and ``expert_form`` picks between
+them from the step's shapes. ``routed_experts_einsum`` is the one form that
 differentiates and partitions over a mesh, for the callers that need either.
 ``swiglu`` is the plain gated FFN beside them: a dense layer, shared experts.
 """
@@ -54,6 +57,33 @@ def expert_form(rows: int, num_experts: int, top_k: int) -> str:
     return "grouped" if grouped else "dense"
 
 
+# ``_top_k`` ranks by comparison from this many (experts x picks) on:
+# ``lax.top_k`` of 22 among 512 costs a step program 2.5 s of compile where
+# its row count is no multiple of 128 (132, 136, ...: most of an engine's
+# programs), 6 among 64 and 2 among 8 nothing to speak of (PERF.md 6, PR 31)
+_RANKED_TOP_K_MIN = 4096
+
+
+def _top_k(scores, k: int):
+    """``lax.top_k(scores, k)`` over the last axis of ``scores`` [T, E]: the
+    same values and indices in the same order, ties to the lower index. A
+    large one ranks every score by comparison with every other (``E x E``
+    compares a row, fused into the count) and reads the first ``k`` ranks
+    off: no sort for the compiler to lay out."""
+    e = scores.shape[-1]
+    if e * k < _RANKED_TOP_K_MIN:
+        return lax.top_k(scores, k)
+    i = jnp.arange(e)
+    ahead = ((scores[:, None, :] > scores[:, :, None])
+             | ((scores[:, None, :] == scores[:, :, None])
+                & (i[None, None, :] < i[None, :, None])))
+    rank = jnp.sum(ahead, axis=-1, dtype=jnp.int32)               # [T, E]
+    hit = rank[:, None, :] == jnp.arange(k)[None, :, None]        # [T, k, E]
+    return (jnp.sum(jnp.where(hit, scores[:, None, :], 0.0), axis=-1),
+            jnp.sum(jnp.where(hit, i[None, None, :], 0), axis=-1,
+                    dtype=jnp.int32))
+
+
 def _route(h, router_w, top_k: int, scoring: str, bias, renormalize: bool,
            scale: float, eps: float):
     """The router, in float32 -> (combine weights ``[T, top_k]``, picked
@@ -66,9 +96,9 @@ def _route(h, router_w, top_k: int, scoring: str, bias, renormalize: bool,
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
     if bias is None:
-        topv, topi = lax.top_k(scores, top_k)
+        topv, topi = _top_k(scores, top_k)
     else:
-        _, topi = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        _, topi = _top_k(scores + bias.astype(jnp.float32), top_k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
     if renormalize:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
@@ -77,28 +107,73 @@ def _route(h, router_w, top_k: int, scoring: str, bias, renormalize: bool,
     return topv, topi
 
 
-def _einsum_experts(h, topv, topi, w_gate, w_up, w_down):
-    """Every expert over every token on the MXU, the router's weights (zero
-    for the experts a token did not pick) combining the results."""
+def _held_picks(topi, held, e: int):
+    """The picks as indices into the ``e`` experts the layer holds, ``e`` (one
+    past the last) for a pick of an expert it does not hold."""
+    if held is None:
+        return topi
+    local = topi - held[0]
+    return jnp.where((local >= 0) & (local < e), local, e)
+
+
+def _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held=None):
+    """Every held expert over every token on the MXU, the router's weights
+    (zero for the experts a token did not pick) combining the results; a pick
+    of an expert the layer does not hold has no column to land in."""
     t, _ = h.shape
-    e = w_gate.shape[0]
+    e = w_up.shape[0]
     w = jnp.zeros((t, e), jnp.float32).at[
-        jnp.arange(t)[:, None], topi].set(topv)
+        jnp.arange(t)[:, None], _held_picks(topi, held, e)].set(
+            topv, **({} if held is None else {"mode": "drop"}))
     dtype = h.dtype
-    g = jnp.einsum("td,edf->tef", h, w_gate.astype(dtype))
     u = jnp.einsum("td,edf->tef", h, w_up.astype(dtype))
-    y = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u, w_down.astype(dtype))
+    if w_gate is None:
+        a = jnp.square(jax.nn.relu(u))
+    else:
+        g = jnp.einsum("td,edf->tef", h, w_gate.astype(dtype))
+        a = jax.nn.silu(g) * u
+    y = jnp.einsum("tef,efd->ted", a, w_down.astype(dtype))
     return jnp.einsum("ted,te->td", y, w.astype(dtype))
 
 
+# ``_picks_before`` counts in blocks from this many (picks x experts) on: the
+# plain cumulative sum compiles in 0.4 s at Moonlight's 3,072 x 64 and in 6.3 s
+# at 11,264 x 128 (512 rows x top-22 over 128 held experts), in every step
+# program that takes the grouped form (a TPU v5e's compiler, PERF.md 6, PR 31)
+_COUNT_BLOCKED_MIN = 2 ** 20
+_COUNT_BLOCK = 128
+
+
+def _picks_before(flat, e: int):
+    """``[P, e]`` int32: how many of picks ``0 .. p`` are of expert ``j``
+    (``flat`` [P], a pick's expert; ``e`` or more: of none). A cumulative sum
+    down the picks; a large one is taken in blocks of ``_COUNT_BLOCK`` picks,
+    inside a block as a product with a triangle of ones (exact: ones and
+    zeros in bfloat16, whole numbers under 2**24 summed in float32), the
+    blocks' totals summed before them, which the compiler takes in a fraction of the time."""
+    hits = flat[:, None] == jnp.arange(e)[None, :]
+    p = flat.shape[0]
+    if p * e < _COUNT_BLOCKED_MIN or p % _COUNT_BLOCK:
+        return jnp.cumsum(hits, axis=0, dtype=jnp.int32)
+    blocks = hits.reshape(-1, _COUNT_BLOCK, e).astype(jnp.bfloat16)
+    inside = jnp.einsum("ts,bse->bte",
+                        jnp.tril(jnp.ones((_COUNT_BLOCK,) * 2, jnp.bfloat16)),
+                        blocks, preferred_element_type=jnp.float32)
+    earlier = jnp.cumsum(inside[:, -1], axis=0) - inside[:, -1]
+    return (inside + earlier[:, None]).reshape(p, e).astype(jnp.int32)
+
+
 def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
-                     num_experts):
+                     num_experts, held=None):
     """Each pick through its own expert only: the ``T x top_k`` picks are
     sorted by expert (a counting sort: a pick's place is its expert's first
     row plus the picks of that expert before it), every expert's rows go
     through that expert's weights (``ops/pallas/moe_gmm.py``), and the
     results come back to token order for the router's weights. The layer's
-    ``num_experts`` experts start at ``first_expert`` of the weights."""
+    ``num_experts`` experts start at ``first_expert`` of the weights. With
+    ``held`` (first routed expert held, experts routed over) a pick of an
+    expert the layer does not hold sorts past the last group: it gets no
+    row, is never multiplied, and comes back as zero."""
     t, d = h.shape
     e, k = num_experts, topi.shape[1]
     if t > _GROUPED_MAX_ROWS:
@@ -106,7 +181,7 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
         parts = [jnp.pad(a, ((0, pad), (0, 0))).reshape(
             -1, _GROUPED_MAX_ROWS, a.shape[1]) for a in (h, topv, topi)]
         out = lax.map(lambda p: _grouped_experts(
-            *p, w_gate, w_up, w_down, first_expert, e), tuple(parts))
+            *p, w_gate, w_up, w_down, first_expert, e, held), tuple(parts))
         return out.reshape(-1, d)[:t]
     # The kernel's shapes are those of a full call whatever ``t`` is, so
     # that every step program of an engine shares ONE traced kernel
@@ -114,30 +189,36 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     # A pass of the kernel is 128 rows where an expert's share of a full call
     # is more than 64 (128 at Mixtral's 2 of 8), else 64 (48 at Moonlight's
     # 6 of 64): one pass an expert either way.
-    tm = 128 if _GROUPED_MAX_ROWS * k > 64 * e else 64
-    flat = topi.reshape(-1)
-    before = jnp.cumsum(flat[:, None] == jnp.arange(e)[None, :], axis=0,
-                        dtype=jnp.int32)
+    routed = e if held is None else held[1]
+    tm = 128 if _GROUPED_MAX_ROWS * k > 64 * routed else 64
+    flat = _held_picks(topi, held, e).reshape(-1)
+    before = _picks_before(flat, e)
     counts = before[-1]
     aligned = -(-counts // ROW_ALIGN) * ROW_ALIGN
     row0 = jnp.cumsum(aligned) - aligned
     place = row0[flat] + jnp.take_along_axis(before, flat[:, None], 1)[:, 0] - 1
     rows = (-(-(_GROUPED_MAX_ROWS * k + e * (ROW_ALIGN - 1)) // ROW_ALIGN)
             * ROW_ALIGN + tm)
+    drop = {}
+    if held is not None:
+        place = jnp.where(flat < e, place, rows)
+        drop = {"mode": "drop"}
     token = jnp.zeros((rows,), jnp.int32).at[place].set(
-        jnp.arange(t * k, dtype=jnp.int32) // k)
+        jnp.arange(t * k, dtype=jnp.int32) // k, **drop)
     dtype = h.dtype
-    y = grouped_swiglu(h[token], w_gate.astype(dtype), w_up.astype(dtype),
-                       w_down.astype(dtype), row0, counts, tm,
-                       max_rows=_GROUPED_MAX_ROWS, first_expert=first_expert)
-    return jnp.einsum("tkd,tk->td", y[place].reshape(t, k, d),
-                      topv).astype(dtype)
+    y = grouped_swiglu(h[token], None if w_gate is None else w_gate.astype(dtype),
+                       w_up.astype(dtype), w_down.astype(dtype), row0, counts,
+                       tm, max_rows=_GROUPED_MAX_ROWS,
+                       first_expert=first_expert)
+    y = y[place] if held is None else y.at[place].get(mode="fill",
+                                                      fill_value=0)
+    return jnp.einsum("tkd,tk->td", y.reshape(t, k, d), topv).astype(dtype)
 
 
 def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                    top_k: int, *, stacked=None, scoring: str = "softmax",
                    bias=None, renormalize: bool = True, scale: float = 1.0,
-                   eps: float = 1e-9) -> jnp.ndarray:
+                   eps: float = 1e-9, held=None, router_h=None) -> jnp.ndarray:
     """Dropless per-token top-k MoE for the serving paths (``h`` [T, D]
     flat tokens): exact (no capacity, no drops, every pick computed), bf16
     operands with float32 accumulation in either form.
@@ -169,41 +250,60 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     bias, which never enters a weight); the picked scores are divided by
     their sum (``renormalize``) and multiplied by ``scale``. The defaults
     are Mixtral's.
+
+    The expert: ``w_down(silu(x w_gate) * x w_up)``, or with ``w_gate`` None
+    the ungated ``w_down(relu(x w_up)**2)`` of two matrices (``stacked``
+    then has None in ``w_gate``'s place).
+
+    One rank's share: ``held = (first, routed)`` says that the weights are
+    experts ``first .. first + E - 1`` of the ``routed`` the router scores.
+    The router still scores and picks over all of them and the weights are
+    normalised over all the picks, as published; the layer computes the part
+    its own experts give, and what the absent ones would add is left out
+    (the other ranks' parts, which the deployment adds up). ``held`` None:
+    the layer holds every routed expert.
+
+    ``router_h``: what the router scores where that is not what the experts
+    compute on (experts in a latent space, routed on the full hidden state).
     """
-    topv, topi = _route(h, router_w, top_k, scoring, bias, renormalize,
-                        scale, eps)
-    e = w_gate.shape[0]
-    if expert_form(h.shape[0], e, top_k) == "dense":
-        return _einsum_experts(h, topv, topi, w_gate, w_up, w_down)
+    topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
+                        scoring, bias, renormalize, scale, eps)
+    e = w_up.shape[0]
+    routed = e if held is None else held[1]
+    if expert_form(h.shape[0], routed, top_k) == "dense":
+        return _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
     return _grouped_experts(h, topv, topi,
-                            *(stacked or (w_gate, w_up, w_down, 0)), e)
+                            *(stacked or (w_gate, w_up, w_down, 0)), e, held)
 
 
 def expert_stacks(layers: dict):
     """For a serving scan over the stacked ``layers``: ``(layers, stacks)``,
     ``stacks`` the expert weights whole, ``[L, E, ...]`` as ``[L x E, ...]``
-    (the same bytes), for the scan's body to close over, and ``layers`` with
-    ``first_expert`` [L] beside the weights, so that a layer can hand
-    ``routed_experts`` its ``stacked``. Weights that are not plain arrays
+    (the same bytes; None for ``w_gate`` where the experts have none), for
+    the scan's body to close over, and ``layers`` with ``first_expert`` [L]
+    beside the weights, so that a layer can hand ``routed_experts`` its
+    ``stacked``. Weights that are not plain arrays
     (weight-only quantization dequantizes a layer's slice) have no stacks:
     ``(layers, None)``."""
-    weights = [layers[k] for k in ("w_gate", "w_up", "w_down")]
-    if not all(isinstance(w, jax.Array) for w in weights):
+    weights = [layers.get(k) for k in ("w_gate", "w_up", "w_down")]
+    if not all(isinstance(w, jax.Array) for w in weights if w is not None):
         return layers, None
-    n, e = weights[0].shape[:2]
+    n, e = weights[1].shape[:2]
     first = jnp.arange(n, dtype=jnp.int32) * e
     return ({**layers, "first_expert": first},
-            tuple(w.reshape((n * e,) + w.shape[2:]) for w in weights))
+            tuple(w if w is None else w.reshape((n * e,) + w.shape[2:])
+                  for w in weights))
 
 
 def routed_experts_einsum(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                           top_k: int, *, scoring: str = "softmax", bias=None,
                           renormalize: bool = True, scale: float = 1.0,
-                          eps: float = 1e-9) -> jnp.ndarray:
+                          eps: float = 1e-9, held=None,
+                          router_h=None) -> jnp.ndarray:
     """``routed_experts`` in its einsum form at every row count: plain XLA,
     so it differentiates (``deepseek``'s training-shaped ``forward`` /
     ``loss_fn``) and partitions over a mesh (``mixtral``'s dense-cache
     inference layer under ``InferenceEngine``'s tensor-parallel mesh)."""
-    topv, topi = _route(h, router_w, top_k, scoring, bias, renormalize,
-                        scale, eps)
-    return _einsum_experts(h, topv, topi, w_gate, w_up, w_down)
+    topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
+                        scoring, bias, renormalize, scale, eps)
+    return _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)

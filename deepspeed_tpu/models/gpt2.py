@@ -190,12 +190,14 @@ def decode_forward(cfg: GPT2Config, params, tokens, cache, start_pos,
 
 
 def init_paged_cache(cfg: GPT2Config, num_blocks: int, block_size: int,
-                     dtype=jnp.bfloat16, codec=None) -> dict:
+                     dtype=jnp.bfloat16, codec=None,
+                     num_slots=None) -> dict:
     """Blocked KV pool of the ragged engine in the paged contract's storage
     form, ``[L, num_blocks, block_size, Hkv*Dh]`` (``models/paged.py``): a
     step program addresses it through block tables and never holds an array
     the size of a layer's slice. ``codec``: a ``kvquant.KVQCodec`` builds the
     low-bit pool at storage precision."""
+    del num_slots  # this family keeps no state a slot (models/paged.py)
     from deepspeed_tpu.models.paged import init_paged_pool
 
     return init_paged_pool(cfg.num_layers, num_blocks, block_size,

@@ -25,6 +25,29 @@ and the dense-cache append+attend used by the v1-style engines.
 - A step program must never hold an array the size of a layer's slice of
   the pool (``tests/unit/test_compile_tpu.py`` asserts it on the compiled
   program): its pool traffic is the rows written plus the context read.
+- *Slot leaves.* A model whose layers carry a recurrent state (a
+  state-space layer: constant size, whatever the context) keeps it beside
+  the block leaves, under the cache's ``"slots"`` key: leaves ``[L_s, S,
+  ...]``, one row a layer and engine slot, ``S = max_seqs + 1``; the last
+  row is the scratch slot padding rows use, as block 0 is for the pool.
+  ``block_leaves`` / ``slot_leaves`` tell the two apart. The engine owns
+  both in ``engine.cache`` and the family's ``init_paged_cache`` makes both
+  (it receives ``num_slots``). Everything above about blocks (bytes a
+  token, block operations between steps, tiers, hand-off) sees block
+  leaves only; nothing on the host indexes a slot leaf.
+- A step program sees a slot leaf with ``L_s`` and ``S`` merged and
+  addresses it by ``slots + layer * S``. It reads the rows of the step's
+  slots and writes them back in place (donation): it never holds an array
+  the size of a layer's slice of the state either. A slot is zeroed INSIDE
+  the step: a row or tile at position 0 starts from zeros whatever the
+  slot held (a reused slot, a preempted request recomputed), so there is
+  no reset program. Padding rows read and write the scratch slot and leave
+  it zero.
+- What a prefix of blocks cannot restore, refuses: the engine raises at
+  construction for ``enable_prefix_cache``, ``kv_tier``, ``KVHandoff``
+  (all need a state snapshot at a block boundary), ``sched_steps`` /
+  ``spec_draft`` (a rejected position needs a rollback of the state) and a
+  quantized pool, when the cache has slot leaves.
 """
 
 from __future__ import annotations
@@ -54,6 +77,44 @@ def init_paged_pool(num_layers: int, num_blocks: int, block_size: int,
     return {"k": pool(), "v": pool()}
 
 
+SLOTS = "slots"  # the cache key of the slot leaves (module doc)
+
+
+def slot_leaves(cache):
+    """The cache's slot leaves, ``[L_s, S, ...]`` each (None: it has none)."""
+    return cache.get(SLOTS) if isinstance(cache, dict) else None
+
+
+def block_leaves(cache):
+    """The cache without its slot leaves: every leaf ``[L, NB, ...]``."""
+    if slot_leaves(cache) is None:
+        return cache
+    return {k: v for k, v in cache.items() if k != SLOTS}
+
+
+def stack_plan(pattern: str) -> tuple[str, str, int]:
+    """``(lead, period, repeats)`` with ``pattern == lead + period *
+    repeats``, ``repeats`` >= 2, and ``len(lead) + len(period)`` (the layers
+    a step program compiles) the least: what ``scan_layers_paged`` can run
+    as leading layers and a scan over a period. A pattern that is no such
+    thing raises."""
+    n = len(pattern)
+    best = None
+    for lead in range(n):
+        for period in range(1, (n - lead) // 2 + 1):
+            rest = pattern[lead:]
+            if len(rest) % period == 0 \
+                    and rest == rest[:period] * (len(rest) // period) \
+                    and (best is None or lead + period < sum(best[:2])):
+                best = (lead, period, len(rest) // period)
+    if best is None:
+        raise NotImplementedError(
+            f"layer pattern {pattern!r} is not leading layers followed by a "
+            "repeated period: the layer scan cannot express it")
+    lead, period, repeats = best
+    return pattern[:lead], pattern[lead:lead + period], repeats
+
+
 def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
     """Run ``layer_fn(x, lp, pool, layer_tables) -> (x, pool)`` over the
     stacked ``layers``. ``pool`` is the family's paged cache, any pytree of
@@ -69,7 +130,13 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
     in ``lead``, ``[(fn, lp), ...]`` with ``fn`` as ``layer_fn``: they run
     before the scan at layers ``0 .. len(lead) - 1`` of the pool and the
     scanned layers follow them, so ``L`` counts every layer.
+
+    A stack of several kinds of layer, some with no blocks and some with a
+    state a slot, gives ``layer_fn`` as a period of layers instead of one
+    function: ``_scan_periods`` below has that form's arguments.
     """
+    if not callable(layer_fn):
+        return _scan_periods(layer_fn, x, layers, pool, block_tables, lead)
     leaves = jax.tree_util.tree_leaves(pool)
     n_layers, nb = leaves[0].shape[:2]
     n_lead = len(lead)
@@ -89,6 +156,65 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
         (layers, jnp.arange(n_lead, n_layers, dtype=jnp.int32)))
     return x, jax.tree_util.tree_map(
         lambda a: a.reshape((n_layers, nb) + a.shape[1:]), pool)
+
+
+def _scan_periods(period, x, layers, pool, block_tables, lead):
+    """``scan_layers_paged`` for a stack of several kinds of layer: the body
+    of the scan is a PERIOD of layers, ``period = [(kind, fn), ...]`` with
+    ``layers`` a tuple of stacked trees, one a position of the period
+    (``[repeats, ...]`` each), and ``lead = [(kind, fn, lp), ...]`` before
+    it. ``kind`` says which leaves of the pool count the layer, and so what
+    it is handed as its address: ``"block"``, ``fn(x, lp, pool,
+    layer_tables)`` as above, its layer among the block leaves' ``L``;
+    ``"slot"``, ``fn(x, lp, pool, slot0)`` with ``slot0 = layer * S`` the
+    row of its slot 0 in the merged slot leaves; None, ``fn(x, lp, pool,
+    None)`` for a layer with no state. Every ``fn`` returns ``(x, pool)``;
+    the pool is carried whole, block leaves and slot leaves each merged."""
+    blocks, slots = block_leaves(pool), slot_leaves(pool)
+    shapes = {"block": jax.tree_util.tree_leaves(blocks)[0].shape[:2]}
+    if slots is not None:
+        shapes["slot"] = jax.tree_util.tree_leaves(slots)[0].shape[:2]
+
+    def merged(tree):
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), tree)
+
+    def address(kind, i):
+        if kind == "block":
+            return block_tables + i * shapes["block"][1]
+        return None if kind is None else i * shapes["slot"][1]
+
+    pool = merged(pool)
+    seen = {"block": 0, "slot": 0, None: 0}
+    for kind, fn, lp in lead:
+        x, pool = fn(x, lp, pool, address(kind, seen[kind]))
+        seen[kind] += 1
+    # position j of the period is layer index[j][r] of its kind in repeat r
+    repeats = jax.tree_util.tree_leaves(layers[0])[0].shape[0]
+    per = {k: sum(1 for kind, _ in period if kind == k) for k in seen}
+    index, at = [], dict(seen)
+    for kind, _ in period:
+        index.append(at[kind] + per[kind] * jnp.arange(repeats, dtype=jnp.int32))
+        at[kind] += 1
+    for kind, (n, _) in shapes.items():
+        if seen[kind] + per[kind] * repeats != n:
+            raise ValueError(
+                f"the cache's {kind} leaves hold {n} layers, the stack has "
+                f"{seen[kind] + per[kind] * repeats} of that kind")
+
+    def body(carry, xs):
+        x, pool = carry
+        for (kind, fn), lp, i in zip(period, *xs):
+            x, pool = fn(x, lp, pool, address(kind, i))
+        return (x, pool), None
+
+    (x, pool), _ = lax.scan(body, (x, pool), (tuple(layers), tuple(index)))
+    out = jax.tree_util.tree_map(
+        lambda a: a.reshape(shapes["block"] + a.shape[1:]), block_leaves(pool))
+    if slots is not None:
+        out = {**out, SLOTS: jax.tree_util.tree_map(
+            lambda a: a.reshape(shapes["slot"] + a.shape[1:]), pool[SLOTS])}
+    return x, out
 
 
 def write_rows_paged(pool, rows, slots, positions, block_tables):

@@ -1,5 +1,8 @@
 """Pallas grouped expert FFN: each expert's weights over that expert's own
-rows only, read from HBM once a layer.
+rows only, read from HBM once a layer. The expert is gated (three matrices,
+``silu(x w_gate) * (x w_up)`` into ``w_down``: Mixtral, DeepSeek-V3) or
+ungated (two, ``relu(x w_up)**2`` into ``w_down``: Nemotron-H's latent
+experts; one weight operand fewer, the same walk).
 
 The rows (the ``T x top_k`` picks of a serving step) arrive grouped by expert:
 expert ``e`` owns rows ``row0[e] .. row0[e] + n_e`` of ``x``, ``row0`` a
@@ -48,12 +51,13 @@ _WEIGHT_TILE_BYTES = 36 * 2**20
 _VMEM_LIMIT_BYTES = 100 * 2**20
 
 
-def ffn_tile(d: int, f: int, itemsize: int) -> int:
+def ffn_tile(d: int, f: int, itemsize: int, matrices: int = 3) -> int:
     """The ffn columns a grid step takes: all of them where they fit
     ``_WEIGHT_TILE_BYTES``, else the widest divisor of ``f`` that does and is
-    whole 128-lane tiles."""
+    whole 128-lane tiles. ``matrices``: an expert's weight matrices (3 gated,
+    2 ungated)."""
     def fits(tf):
-        return 3 * 2 * d * tf * itemsize <= _WEIGHT_TILE_BYTES
+        return matrices * 2 * d * tf * itemsize <= _WEIGHT_TILE_BYTES
 
     if fits(f):
         return f
@@ -63,9 +67,11 @@ def ffn_tile(d: int, f: int, itemsize: int) -> int:
     return tiles[-1]
 
 
-def _kernel(first_ref, row0_ref, passes_ref, x_hbm, wg_ref, wu_ref, wd_ref,
-            o_hbm, xbuf, acc, in_sem, out_sem, *, tm: int):
+def _kernel(first_ref, row0_ref, passes_ref, x_hbm, *refs, tm: int,
+            gated: bool):
     del first_ref  # the weights' index maps read it
+    wg_ref = refs[0] if gated else None
+    wu_ref, wd_ref, o_hbm, xbuf, acc, in_sem, out_sem = refs[gated:]
     e, j = pl.program_id(0), pl.program_id(1)
     n_e, n_j = pl.num_programs(0), pl.num_programs(1)
     slot = e % 2
@@ -104,9 +110,14 @@ def _kernel(first_ref, row0_ref, passes_ref, x_hbm, wg_ref, wu_ref, wd_ref,
 
     def one_pass(c, first):
         x = xbuf[slot, c]
-        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-        y = jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), wd_ref[0],
+        if gated:
+            g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+            a = jax.nn.silu(g) * u
+        else:
+            a = jnp.square(jax.nn.relu(
+                jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)))
+        y = jnp.dot(a.astype(x.dtype), wd_ref[0],
                     preferred_element_type=jnp.float32)
         acc[slot, c] = y if first else acc[slot, c] + y
 
@@ -143,7 +154,9 @@ def grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm: int,
     up to ``ROW_ALIGN`` plus ``tm``) through ``w_gate`` / ``w_up`` [N, D, F]
     and ``w_down`` [N, F, D] -> float32 [R, D]: row ``r`` of expert ``e`` is
     ``(silu(x[r] w_gate[e]) * (x[r] w_up[e])) w_down[e]``; the rows between
-    the experts hold nothing meant.
+    the experts hold nothing meant. ``w_gate`` None is the ungated expert of
+    two matrices, ``relu(x[r] w_up[e])**2 w_down[e]``: the same walk with one
+    weight operand fewer.
 
     The ``E`` experts are ``first_expert .. first_expert + E - 1`` of the
     ``N`` the weights hold: a layer scan hands the kernel every layer's
@@ -164,17 +177,18 @@ def grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm: int,
 @functools.partial(jax.jit, static_argnames=("tm", "max_rows", "interpret"))
 def _grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, first_expert, *,
                     tm: int, max_rows: int, interpret: bool):
-    _, d, f = w_gate.shape
+    gated = w_gate is not None
+    _, d, f = w_up.shape
     n_e = row0.shape[0]
-    tf = ffn_tile(d, f, x.dtype.itemsize)
+    tf = ffn_tile(d, f, x.dtype.itemsize, 3 if gated else 2)
     passes = pl.cdiv(max_rows, tm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_e, f // tf),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, d, tf), lambda e, j, e0, r0, n: (e0[0] + e, 0, j)),
-            pl.BlockSpec((1, d, tf), lambda e, j, e0, r0, n: (e0[0] + e, 0, j)),
+            *[pl.BlockSpec((1, d, tf),
+                           lambda e, j, e0, r0, n: (e0[0] + e, 0, j))] * (1 + gated),
             pl.BlockSpec((1, tf, d), lambda e, j, e0, r0, n: (e0[0] + e, j, 0)),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -186,7 +200,7 @@ def _grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, first_expert, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm),
+        functools.partial(_kernel, tm=tm, gated=gated),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
@@ -195,4 +209,5 @@ def _grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, first_expert, *,
         interpret=interpret,
         name="moe_gmm",
     )(first_expert, row0.astype(jnp.int32),
-      ((counts + tm - 1) // tm).astype(jnp.int32), x, w_gate, w_up, w_down)
+      ((counts + tm - 1) // tm).astype(jnp.int32), x,
+      *((w_gate,) if gated else ()), w_up, w_down)

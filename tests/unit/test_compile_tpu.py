@@ -27,8 +27,8 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
 )
 
 # (q heads, kv heads, head size): Llama-3-8B / Mixtral-8x7B, GPT-2 XL,
-# GPT-2 medium
-GEOMETRIES = [(32, 8, 128), (25, 25, 64), (16, 16, 64)]
+# GPT-2 medium, Nemotron-3-Super (16 query heads a KV head, 256-lane rows)
+GEOMETRIES = [(32, 8, 128), (25, 25, 64), (16, 16, 64), (32, 2, 128)]
 SEQ = 1024     # ops.attention fits flash blocks to min(1024, seq)
 TILE = 128     # RaggedConfig.prefill_tile of bench.py and chip_smoke.py
 BLOCK = 32     # their KV block_size
@@ -91,13 +91,16 @@ def _mla_prefill(q, pool, ts, tp, tv, bt):
 # full call (``models/experts.py`` gives every step those): (experts, picks a
 # token, hidden, expert FFN, rows, rows a pass)
 MOE_GEOMETRIES = {"mixtral": (8, 2, 4096, 14336, 512, 128),
-                  "moonlight": (64, 6, 2048, 1408, 512, 64)}
+                  "moonlight": (64, 6, 2048, 1408, 512, 64),
+                  # 128 held of 512, ungated relu**2 in the 1,024-wide latent
+                  "nemotron": (128, 22, 1024, 2688, 512, 64)}
+UNGATED = {"nemotron"}
 
 
-def _moe_gmm(tm, max_rows):
+def _moe_gmm(tm, max_rows, gated=True):
     def kernel(x, w_gate, w_up, w_down, row0, counts):
-        return grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm,
-                              max_rows, interpret=False)
+        return grouped_swiglu(x, w_gate if gated else None, w_up, w_down, row0,
+                              counts, tm, max_rows, interpret=False)
 
     return kernel
 
@@ -194,19 +197,52 @@ def test_moe_gmm_compiles_for_v5e(v5e, geometry):
     counts, and the VMEM the weight tiles and an expert's rows take under
     the raised limit."""
     *_, t, tm = MOE_GEOMETRIES[geometry]
-    compiled = jax.jit(_moe_gmm(tm, t)).lower(
+    compiled = jax.jit(_moe_gmm(tm, t, geometry not in UNGATED)).lower(
         *_moe_args(geometry, v5e)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def _ssm_decode(state, rows, da, dtx, bt, ct):
+    from deepspeed_tpu.ops.pallas.ssm import ssm_decode
+
+    return ssm_decode(state, rows, da, dtx, bt, ct, interpret=False)
+
+
+def _ssm_args(devices, rows=128):
+    """The Mamba state of the Nemotron-3 cell: 5 layers x 129 slots of [128,
+    8192] float32, 8 groups, a decode bucket of ``rows``."""
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    return (s((5 * 129, 128, 8192)), s((rows,), jnp.int32), s((rows, 8192)),
+            s((rows, 8192)), s((rows, 128, 8)), s((rows, 128, 8)))
+
+
+def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e):
+    """A row's whole state (4 MB) a grid step, in and out double-buffered
+    under the raised VMEM limit, a [128, 1] column broadcast over the lanes;
+    the donated state is the output (aliased), and nothing else in the
+    program is as large as ONE row's state."""
+    compiled = jax.jit(_ssm_decode, donate_argnums=(0,)).lower(
+        *_ssm_args(v5e)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 129 * 128 * 8192 * 4
+    assert mem.temp_size_in_bytes < 128 * 8192 * 4
 
 
 @pytest.mark.parametrize("kernel,name", [
     (_flash_fwd, "flash_fwd"), (_flash_bwd, "flash_bwd_dkv"),
     (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
     (_prefill, "tiled_prefill"), (_mla_decode, "mla_decode"),
-    (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm")],
+    (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm"),
+    ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
-         "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm"])
+         "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm",
+         "moe_gmm_ungated", "ssm_decode"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     """``pl.pallas_call(name=...)``: the compiled custom call is
     ``%<name>.N`` (``%transpose_jvp_<name>__.N`` under a bare ``jax.grad``),
@@ -226,6 +262,13 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
         *_, t, tm = MOE_GEOMETRIES["moonlight"]
         text = jax.jit(_moe_gmm(tm, t)).lower(
             *_moe_args("moonlight", v5e)).compile().as_text()
+    elif kernel == "moe_gmm_ungated":
+        *_, t, tm = MOE_GEOMETRIES["nemotron"]
+        text = jax.jit(_moe_gmm(tm, t, gated=False)).lower(
+            *_moe_args("nemotron", v5e)).compile().as_text()
+    elif kernel == "ssm_decode":
+        text = jax.jit(_ssm_decode).lower(
+            *_ssm_args(v5e, rows=8)).compile().as_text()
     else:
         text = jax.jit(kernel).lower(
             *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
@@ -328,7 +371,8 @@ def _materialized(text):
     """``(bytes, opcode, line)`` of every array-valued instruction of the
     optimized HLO that gets a buffer of its own: those outside fused
     computations. A fusion's opcode is ``scatter`` where that is what its
-    computation holds, a Pallas kernel's is ``kernel``."""
+    computation holds, ``update`` where it is a dynamic-update-slice, a
+    Pallas kernel's is ``kernel``."""
     import re
 
     fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
@@ -356,6 +400,10 @@ def _materialized(text):
             if op == "fusion" and called and any(
                     " scatter(" in b for b in bodies[called.group(1)]):
                 op = "scatter"
+            elif op == "fusion" and called and any(
+                    "ROOT" in b and " dynamic-update-slice(" in b
+                    for b in bodies[called.group(1)]):
+                op = "update"   # one row of its operand, in place
             elif op == "custom-call" and "tpu_custom_call" in ln:
                 op = "kernel"
             if op not in _HLO_ALIASES:
@@ -545,3 +593,75 @@ def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
         layouts = ln.split("operand_layout_constraints=")[1]
         assert layouts.count(pool_shape) == 1, ln
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+
+
+# ------------------------------------------------- the contract's slot leaves
+@pytest.mark.parametrize("rows,tiles", [(8, 1), (8, 0), (0, 2)],
+                         ids=["mixed", "decode", "prefill"])
+def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
+                                                             rows, tiles):
+    """The paged contract's clause for slot leaves (``models/paged.py``), on
+    the compiled program, at Nemotron-3-Super's Mamba and attention widths
+    and the cell's pool and slots (FFN, experts and vocabulary small, so an
+    array as large as a layer's slice of the state can only be the state's):
+    a step reads the rows of its slots and writes them back in place. The
+    decode rows go through ``ssm_decode`` (state aliased in and out), a
+    tile's row is ONE dynamic slice and ONE dynamic-update-slice, the
+    attention layer's K and V are scattered as in every other family. No
+    gather, copy or re-layout of the float32 state ``[2 x 129, 128, 8192]``
+    or of one layer's ``[129, 128, 8192]``: with a whole-row gather XLA
+    re-laid the entire leaf out in lane quarters, and with one einsum over
+    the group axis it transposed it, every step (PERF.md section 6, PR 31)."""
+    from deepspeed_tpu.models import nemotron_h
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = nemotron_h.NemotronHConfig(
+        vocab_size=512, num_layers=5, hybrid_override_pattern="*EMEM",
+        moe_intermediate_size=128, moe_shared_expert_intermediate_size=256,
+        num_experts=16, experts_held=4, top_k=6)
+    blocks, block, table, slots = 4097, 128, 32, 129
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        nemotron_h.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: nemotron_h.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16, num_slots=slots))
+    ssm = cache["slots"]["ssm"]
+    assert ssm.shape == (2, slots, 128, 8192) and ssm.dtype == jnp.float32
+    assert cache["k"].shape == (1, blocks, block, 256)
+    state_slice = slots * 128 * 8192 * 4
+    pool_slice = blocks * block * 256 * 2
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return nemotron_h.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    t, nt = rows + tiles * TILE, max(tiles, 1)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(slots, table), i32(nt), i32(nt), i32(nt)).compile()
+    text = compiled.as_text()
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= pool_slice]
+    # in place: the K and V scatters, the kernel's aliased state, a tile's row
+    assert [ln for _, op, ln in big
+            if op not in ("scatter", "kernel", "update")] == []
+    assert len([ln for _, op, ln in big if op == "scatter"]) == 2
+    assert len([ln for _, op, ln in big if op == "update"]) == tiles
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sum("ssm_decode" in n for n in names) == (1 if rows else 0)
+    # the whole state's and a layer's shape appear as nothing but the
+    # parameter, its merged view, the loop's carry and the in-place updates
+    assert compiled.memory_analysis().temp_size_in_bytes < min(state_slice,
+                                                               pool_slice)

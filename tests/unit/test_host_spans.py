@@ -304,10 +304,20 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                      "kernel.moe_gmm_share": "v5e_moonlight_reason_moe_spans",
                      # PR 29: the decode kernel in a chat cell's decode steps
                      "kernel.chat_paged_decode_share": "v5e_gpt2xl_chat_decode_spans",
-                     "kernel.chat_paged_decode_roofline": "v5e_gpt2xl_chat_decode_spans"}
+                     "kernel.chat_paged_decode_roofline": "v5e_gpt2xl_chat_decode_spans",
+                     # PR 31: the readers of slot state read a slice of the
+                     # hybrid cell (``state_bytes`` in its dispatch spans,
+                     # the ``ssm_decode`` kernel, ONE attention layer)
+                     **{m: "v5e_nemotron_reason_spans" for m in (
+                         "model.ssm_step_roofline_kv", "kernel.ssm_decode_share",
+                         "kernel.ssm_decode_roofline",
+                         "kernel.hybrid_paged_decode_roofline",
+                         "kernel.hybrid_tiled_prefill_roofline")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
-                   "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open"}
+                   "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
+                   "v5e_nemotron_reason_spans":
+                   "nemotron-3-super-120b-d11-ep4.reason-pool"}
 
 
 def _new_readers():
@@ -318,7 +328,7 @@ def _new_readers():
         path = os.path.join(BENCH, "layer_metrics", m["name"] + ".py")
         with open(path) as f:
             text = f.read()
-        if "host_spans" not in text and "latent_spans" not in text:
+        if not any(m in text for m in ("host_spans", "latent_spans", "ssm_spans")):
             continue  # a reader from before the spans
         fixture = FIXTURE_OF_METRIC.get(m["name"])
         cell = (CELL_OF_FIXTURE[fixture] if fixture

@@ -479,3 +479,66 @@ class TestStepPaths:
         assert eng.cfg.prefill_tile == 0 and not eng._use_tiles
         assert eng._step_keys and not eng._pending  # the plain step served
         assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1
+
+
+# -------------------------------------------- a cold compilation cache (PR 31)
+class TestColdCachePrecompile:
+    """When the first step program a process builds misses the persistent
+    compilation cache, the engine compiles the others in the background;
+    a warm cache, or no cache at all, sees no change."""
+
+    def test_step_zoo_is_the_programs_the_sizes_allow(self):
+        eng = _engine_ds(True, prefill_tile=4, max_tokens_per_step=16,
+                         max_seqs=4)
+        w = RCFG.max_blocks_per_seq
+        assert eng._step_zoo() == [
+            (4, 0, 1, w), (8, 0, 2, w), (16, 0, 4, w),           # no decoders
+            (8, 4, 1, w), (12, 4, 2, w), (16, 4, 3, w), (4, 4, 0, w)]
+
+    @pytest.mark.parametrize("first_miss", [1, 2, None],
+                             ids=["cold", "thinned", "warm"])
+    def test_the_first_programs_miss_starts_the_others(self, first_miss):
+        """The counter ``warmup`` installs is read before and after the FIRST
+        step program's first call. It rises there (a cold cache: the others
+        compile in the background), at a later program (a warm cache its size
+        limit has thinned: no background compile of what is mostly there) or
+        never."""
+        import threading
+        import time
+
+        sizes = dict(prefill_tile=4, max_tokens_per_step=16, max_seqs=4)
+        eng, ref = _engine_ds(True, **sizes), _engine_ds(True, **sizes)
+        reads = [0]
+
+        def misses():   # two reads a probe: the second of the n-th rises
+            reads[0] += 1
+            return int(first_miss is not None and reads[0] >= 2 * first_miss)
+
+        eng._cache_misses = misses
+        built, build = [], eng._build_dev_step
+
+        def recording(*key):
+            if threading.current_thread().name.startswith("ragged-compile"):
+                built.append(key[:4])
+            return build(*key)
+
+        eng._build_dev_step = recording
+        for e in (eng, ref):
+            for uid, p in _prompts(23).items():
+                e.put(uid, p, max_new_tokens=6)
+        first = None
+        while eng.has_work:
+            eng.step()
+            first = first or next(iter(eng._dev_step_jits))[:4]
+        ref.generate_all()
+        assert {u: s.generated for u, s in eng._results.items()} == \
+            {u: s.generated for u, s in ref._results.items()}
+        for _ in range(600):
+            if not any(t.name.startswith("ragged-compile")
+                       for t in threading.enumerate()):
+                break
+            time.sleep(0.1)
+        assert sorted(built) == (
+            sorted(k for k in eng._step_zoo() if k != first)
+            if first_miss == 1 else [])
+        assert reads[0] == 2     # one probe an engine
