@@ -66,29 +66,46 @@ class ShardCtx:
     # attention-visible sequence (under Ulysses: the full gathered sequence)
     fpdt_chunks: int = 0
     fpdt_offload: bool = True
-    # ZeRO++ qwZ hook (parallel/qwz.py): installed by the engine when
-    # zero_optimization.quantized_weights is on; applied to each scanned
-    # layer's weight slice so the stage-3 gather rides int8
-    qwz: Any = None
+    # ZeRO stage-3 weight gather (parallel/qwz.WeightGather): installed by the
+    # engine at stage 3 with fsdp > 1, dense or int8
+    # (zero_optimization.quantized_weights); states each layer's gather at
+    # the head of the scanned layer body, and the tied table's / head's once
+    weight_gather: Any = None
     # ZeRO-Infinity param-offload hook (runtime/param_offload.py): installed
     # when zero_optimization.offload_param.device != none; streams each
     # scanned layer's host-resident weight slice into HBM + compute-casts it
     param_stream: Any = None
 
+    def _hooks_live(self) -> bool:
+        # inside a pipeline's manual region the hints are suspended (GPipe:
+        # fully manual, no mesh context; 1F1B: manual over `pipeline` only,
+        # where the engine suspends them the same way): the weights there are
+        # left to the partitioner
+        return not getattr(self, "_suspend_constraints", False)
+
     def layer_weights(self, lp: dict, dtype) -> dict:
         """Per-layer weight preparation, called first thing in layer bodies:
         just-in-time WOQ dequantization (inference), then the ZeRO-Infinity
-        host->HBM stream-in (which also compute-casts), then the qwZ quantized
-        gather (stage-3 training) when installed and constraints are live."""
+        host->HBM stream-in (which also compute-casts), then the stage-3
+        gather of the slice (training), when installed and constraints are
+        live."""
         from deepspeed_tpu.ops.quantizer import dequantize_layer
 
         lp = dequantize_layer(lp, dtype)
-        if (self.param_stream is not None
-                and not getattr(self, "_suspend_constraints", False)):
+        if self.param_stream is not None and self._hooks_live():
             lp = self.param_stream(lp, dtype)
-        if self.qwz is not None and not getattr(self, "_suspend_constraints", False):
-            lp = self.qwz(lp, dtype)
+        if self.weight_gather is not None and self._hooks_live():
+            lp = self.weight_gather.layer(lp)
         return lp
+
+    def whole_weight(self, w, *path):
+        """A stage-3 leaf outside the layer scan that feeds a matmul or a
+        lookup (the tied table, an untied head), named by its path in the
+        params tree: gathered over fsdp where the loss function first uses
+        it, so every use in the step shares one gather."""
+        if self.weight_gather is None or not self._hooks_live():
+            return w
+        return self.weight_gather.leaf(w, *path)
 
     @property
     def sp_degree(self) -> int:

@@ -273,10 +273,10 @@ def _dense_layers(cfg: DeepseekConfig, params) -> list:
             for i in range(cfg.first_k_dense)]
 
 
-def _lm_head(params, x):
+def _lm_head(head, x):
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 
-    return x @ maybe_dequantize(params["lm_head"], x.dtype).astype(x.dtype)
+    return x @ maybe_dequantize(head, x.dtype).astype(x.dtype)
 
 
 def forward(cfg: DeepseekConfig, params, input_ids, ctx: ShardCtx | None = None,
@@ -295,7 +295,8 @@ def forward(cfg: DeepseekConfig, params, input_ids, ctx: ShardCtx | None = None,
     x, _ = lax.scan(lambda x, lp: (layer(x, lp, positions), None), x,
                     params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
-    return ctx.constrain(_lm_head(params, x), "batch", "seq", "vocab_act")
+    head = ctx.whole_weight(params["lm_head"], "lm_head")  # stage 3: gathered
+    return ctx.constrain(_lm_head(head, x), "batch", "seq", "vocab_act")
 
 
 # ------------------------------------------------------------------ inference
@@ -370,7 +371,7 @@ def ragged_forward(cfg: DeepseekConfig, params, tokens, slots, positions,
         layer, x, layers, cache, block_tables,
         lead=[(layer, lp) for lp in _dense_layers(cfg, params)])
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
-    return _lm_head(params, x), cache
+    return _lm_head(params["lm_head"], x), cache
 
 
 def _attention_params(cfg: DeepseekConfig) -> int:

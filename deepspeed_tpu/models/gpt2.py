@@ -103,7 +103,14 @@ def layernorm(x, g, b, eps):
 
 
 def _block(cfg: GPT2Config, ctx: ShardCtx, attn_impl: str, x, lp):
-    lp = ctx.layer_weights(lp, x.dtype)  # WOQ dequant + qwZ gather hooks
+    # WOQ dequant, then under ZeRO stage 3 the layer's weights are gathered
+    # HERE, whole, before any of them multiplies. Left to the partitioner,
+    # h @ wq with wq sharded on its contraction dimension became a ring of
+    # four K = 400 partial products, and h @ w_in four 1600-column pieces
+    # each written into the [4, 1024, 6400] result by a bare
+    # dynamic-update-slice: 8.7% of the four-chip step (ledger PR 31,
+    # gpt2-xl.train-zero3-x4, breakdown.device_ops)
+    lp = ctx.layer_weights(lp, x.dtype)
     b, s, d = x.shape
     h = layernorm(x, lp["ln1_g"], lp["ln1_b"], cfg.layer_norm_eps)
     q = (h @ lp["wq"] + lp["bq"]).reshape(b, s, cfg.num_heads, cfg.hd)
@@ -124,7 +131,10 @@ def forward(cfg: GPT2Config, params, input_ids, ctx: ShardCtx | None = None,
             pld_theta=None, pld_rng=None, ltd_keep: int = 0, ltd_rng=None):
     ctx = ctx or ShardCtx()
     b, s = input_ids.shape
-    x = params["wte"][input_ids] + params["wpe"][:s][None, :, :]
+    # stage 3: the tied table is gathered once, for the lookup and the head
+    wte = ctx.whole_weight(params["wte"], "wte")
+    wpe = ctx.whole_weight(params["wpe"], "wpe")
+    x = wte[input_ids] + wpe[:s][None, :, :]
     x = ctx.constrain(x, "batch", "seq", "embed_act")
 
     layer = partial(_block, cfg, ctx, attn_impl)
@@ -134,7 +144,7 @@ def forward(cfg: GPT2Config, params, input_ids, ctx: ShardCtx | None = None,
                         pld_theta=pld_theta, pld_rng=pld_rng,
                         ltd_keep=ltd_keep, ltd_rng=ltd_rng)
     x = layernorm(x, params["lnf_g"], params["lnf_b"], cfg.layer_norm_eps)
-    logits = x @ params["wte"].T.astype(x.dtype)  # tied head
+    logits = x @ wte.T.astype(x.dtype)  # tied head
     return ctx.constrain(logits, "batch", "seq", "vocab_act")
 
 

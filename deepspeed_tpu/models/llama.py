@@ -171,12 +171,17 @@ def hidden_states(cfg: LlamaConfig, params: dict, input_ids: jnp.ndarray,
     return rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
-def lm_head(cfg: LlamaConfig, params: dict) -> jnp.ndarray:
+def lm_head(cfg: LlamaConfig, params: dict,
+            ctx: ShardCtx | None = None) -> jnp.ndarray:
+    """The head's matrix [D, V]; with a training ``ctx`` under ZeRO stage 3,
+    gathered over fsdp before it multiplies."""
+    ctx = ctx or ShardCtx()
     if cfg.tie_embeddings:
-        return params["embed"].T
+        return ctx.whole_weight(params["embed"], "embed").T
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 
-    return maybe_dequantize(params["lm_head"], jnp.float32)
+    return maybe_dequantize(
+        ctx.whole_weight(params["lm_head"], "lm_head"), jnp.float32)
 
 
 def forward(cfg: LlamaConfig, params: dict, input_ids: jnp.ndarray,
@@ -188,7 +193,7 @@ def forward(cfg: LlamaConfig, params: dict, input_ids: jnp.ndarray,
     x = hidden_states(cfg, params, input_ids, ctx=ctx, attn_impl=attn_impl,
                       remat_policy=remat_policy, remat=remat,
                       pld_theta=pld_theta, pld_rng=pld_rng)
-    logits = x @ lm_head(cfg, params).astype(x.dtype)
+    logits = x @ lm_head(cfg, params, ctx).astype(x.dtype)
     return ctx.constrain(logits, "batch", "seq", "vocab_act")
 
 
@@ -419,10 +424,10 @@ def build(cfg: LlamaConfig, ctx: ShardCtx | None = None, attn_impl: str = "auto"
                               ltd_keep=ltd_keep, ltd_rng=ltd_rng)
             if ctx.loss_tile_size:
                 return tiled_causal_lm_loss(
-                    x, lm_head(cfg, params), batch["input_ids"],
+                    x, lm_head(cfg, params, ctx), batch["input_ids"],
                     batch.get("labels"), tile_size=ctx.loss_tile_size,
                 )
-            logits = x @ lm_head(cfg, params).astype(x.dtype)
+            logits = x @ lm_head(cfg, params, ctx).astype(x.dtype)
             return causal_lm_loss(logits, batch["input_ids"],
                                   batch.get("labels"))
         logits = fwd(params, batch["input_ids"], pld_theta=pld, pld_rng=rng)

@@ -163,7 +163,8 @@ def forward(cfg: MixtralConfig, params, input_ids, ctx: ShardCtx | None = None,
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     from deepspeed_tpu.ops.quantizer import maybe_dequantize
 
-    logits = x @ maybe_dequantize(params["lm_head"], x.dtype).astype(x.dtype)
+    head = ctx.whole_weight(params["lm_head"], "lm_head")  # stage 3: gathered
+    logits = x @ maybe_dequantize(head, x.dtype).astype(x.dtype)
     logits = ctx.constrain(logits, "batch", "seq", "vocab_act")
     if return_aux:
         return logits, aux_sum / cfg.num_layers

@@ -422,6 +422,9 @@ def forward(cfg: NemotronHConfig, params, input_ids, ctx: ShardCtx | None = None
     x = ctx.embed_lookup(params["embed"], input_ids, "batch", "seq", "embed_act")
     seen = {kind: 0 for kind in KINDS}
     for kind in cfg.hybrid_override_pattern:
+        # a per-kind dict, not a slice of a stacked "layers" subtree: the
+        # stage-3 gather hook (parallel/qwz.WeightGather) passes it through
+        # and this family's training weights are left to the partitioner
         lp = ctx.layer_weights(_layer_params(params, kind, seen[kind]), x.dtype)
         seen[kind] += 1
         h = rmsnorm(x, lp["norm"], cfg.rms_norm_eps)

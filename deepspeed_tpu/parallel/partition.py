@@ -11,16 +11,26 @@ This is the TPU-native replacement for three reference subsystems at once:
   ``tensor`` axis, with unit-granularity checks (a kv-head dim is only sharded
   if the *head count*, not just the dim size, divides the axis).
 - The ZeRO-3 prefetch coordinator (``partitioned_param_coordinator.py:73``):
-  per-layer gather/release/prefetch falls out of scanning over a
-  layer-stacked param pytree whose within-layer dims are fsdp-sharded — XLA's
-  latency-hiding scheduler prefetches the next layer's allgather during the
-  current layer's compute.
+  per-layer gather/release falls out of scanning over a layer-stacked param
+  pytree whose within-layer dims are fsdp-sharded. The gather itself is NOT
+  left to the partitioner: the planner shards the largest divisible dim of a
+  leaf, which for ``[1600,1600]`` projections and ``w_out`` is the
+  contraction dim, and for such a matmul the partitioner prefers a ring of
+  K/fsdp-wide partial products to a gather (four K = 400 products a
+  projection, four 1600-column pieces of ``h @ w_in`` each written into the
+  ``[4,1024,6400]`` result by a bare ``dynamic-update-slice``: the second
+  largest operation of the four-chip step, ledger PR 31,
+  ``gpt2-xl.train-zero3-x4``). At stage 3 the engine therefore STATES the
+  gather at the head of each scan step (``parallel/qwz.WeightGather`` through
+  ``ShardCtx.layer_weights``; the leaves outside the scan through
+  ``ShardCtx.whole_weight``): one all-gather a weight, whole matmuls, the
+  gradient reduce-scattered back onto ``grad_specs``.
 
 Stage semantics (reference ``runtime/zero/config.py:401``):
   0: params/grads/opt-state replicated (pure DP; grads psum)
   1: opt-state sharded
   2: + grads sharded (psum -> reduce-scatter at the accumulation boundary)
-  3: + params sharded (allgather-on-use per scan step)
+  3: + params sharded (all-gathered at the head of each scan step)
 """
 
 from __future__ import annotations

@@ -139,9 +139,12 @@ class Engine:
 
         zero = config.zero_optimization
         self.zero_stage = zero.stage
+        self._zero3_gathered: list = []
+        abstract_params = jax.eval_shape(self.model_spec.init_fn,
+                                         jax.random.PRNGKey(0))
         self.plan: ShardingPlan = plan_sharding(
             self.model_spec.param_logical_axes,
-            jax.eval_shape(self.model_spec.init_fn, jax.random.PRNGKey(0)),
+            abstract_params,
             topo,
             zero_stage=zero.stage,
             use_tp=topo.size("tensor") > 1,
@@ -151,35 +154,52 @@ class Engine:
             hierarchical=zero.hierarchical_partitioning,
         )
 
-        # ZeRO++ qwZ: route the scanned layer weights through the int8
-        # quantized gather (parallel/qwz.py; reference
-        # partition_parameters.py:1446 quantized all_gather_coalesced).
-        # Installed on the shard_ctx AFTER model build — the model closures
-        # hold the (mutable) ctx, so the hook reaches every layer body.
-        if zero.quantized_weights:
-            if topo.size("pipeline") > 1:
-                raise ValueError(
-                    "quantized_weights does not compose with pipeline "
-                    "parallelism (the stage body runs manual-SPMD where the "
-                    "qwZ gather constraint has no meaning); drop one")
-            if topo.size("fsdp") <= 1:
-                log_dist(
-                    "quantized_weights: fsdp axis is 1 — stage-3 has no "
-                    "weight gather to quantize; running dense", ranks=[0])
-            else:
-                from deepspeed_tpu.parallel import qwz as qwz_mod
+        # ZeRO stage 3 over fsdp > 1 STATES its weight gathers
+        # (parallel/qwz.WeightGather; reference partition_parameters.py:1446
+        # all_gather_coalesced): at the head of every scanned layer body, and
+        # once a step for the table / head outside the scan, each leaf the
+        # plan shards over fsdp is constrained to its spec with fsdp dropped.
+        # Left unsaid, the partitioner turns gather-then-matmul into rings of
+        # K/fsdp-wide partial matmuls (module docstring of
+        # parallel/partition.py). zero_optimization.quantized_weights only
+        # picks what rides the wire (qwZ: int8 + scales). Installed on the
+        # shard_ctx AFTER model build — the model closures hold the (mutable)
+        # ctx, so the hook reaches every layer body. Stages 0-2 and fsdp = 1
+        # have no sharded live weight and install nothing.
+        if zero.quantized_weights and topo.size("pipeline") > 1:
+            raise ValueError(
+                "quantized_weights does not compose with pipeline "
+                "parallelism (the stage body runs manual-SPMD where the "
+                "qwZ gather constraint has no meaning); drop one")
+        if zero.stage >= 3 and topo.size("fsdp") > 1:
+            from deepspeed_tpu.parallel.qwz import WeightGather
 
-                specs = self.plan.param_specs
-                if not (isinstance(specs, dict) and "layers" in specs):
-                    raise ValueError(
-                        "quantized_weights requires a model with a stacked "
-                        "'layers' param subtree (the scanned stage-3 path)")
-                self.shard_ctx.qwz = qwz_mod.build_layer_hook(
-                    topo.mesh, specs["layers"], block=zero.qwz_block)
+            specs = self.plan.param_specs
+            stacked = isinstance(specs, dict) and "layers" in specs
+            if zero.quantized_weights and not stacked:
+                raise ValueError(
+                    "quantized_weights requires a model with a stacked "
+                    "'layers' param subtree (the scanned stage-3 path)")
+            if isinstance(specs, dict):
+                self.shard_ctx.weight_gather = WeightGather(
+                    topo.mesh, specs,
+                    codec="int8" if zero.quantized_weights else None,
+                    block=zero.qwz_block)
+                # (path, elements, codec, scanned) of the gathered leaves: the
+                # comms plan books them as they travel (_fsdp_plan)
+                self._zero3_gathered = self.shard_ctx.weight_gather.gathered(
+                    abstract_params)
                 log_dist(
-                    "stage-3 weight all-gather: int8 blockwise (qwZ, block="
-                    f"{zero.qwz_block}) over fsdp={topo.size('fsdp')}",
-                    ranks=[0])
+                    "stage-3 weight all-gather, stated at the head of each "
+                    "layer: "
+                    + (f"int8 blockwise (qwZ, block={zero.qwz_block})"
+                       if zero.quantized_weights
+                       else str(jnp.dtype(config.compute_dtype)))
+                    + f" over fsdp={topo.size('fsdp')}", ranks=[0])
+        elif zero.quantized_weights:
+            log_dist(
+                "quantized_weights: fsdp axis is 1 — stage-3 has no "
+                "weight gather to quantize; running dense", ranks=[0])
 
         # ZeRO-Infinity parameter offload (reference
         # runtime/zero/parameter_offload.py:117 DeepSpeedZeRoOffload +
@@ -1227,15 +1247,24 @@ class Engine:
             return
         n_elems = sum(
             int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(self.params))
-        grad_bytes = 4 * n_elems
         codec, bpe = self._reduction_codec()
         if fs > 1:
-            # ZeRO over fsdp: reduce-scatter grads, all-gather updated params
-            # (always a dense fp32 wire — qgZ quantizes the data axis only)
-            COMMS_LOGGER.append_traced("reduce_scatter", grad_bytes, "fsdp",
-                                       fs, caller="train_batch_fn")
-            COMMS_LOGGER.append_traced("all_gather", grad_bytes, "fsdp",
-                                       fs, caller="train_batch_fn")
+            rows, gather = self._fsdp_plan()
+            for op, nbytes, caller in rows:
+                COMMS_LOGGER.append_traced(op, int(nbytes), "fsdp", fs,
+                                           caller=caller)
+            if gather is not None and self.telemetry.enabled:
+                self.telemetry.gauge(
+                    "zero3_gathered_leaves",
+                    "param leaves the plan shards over fsdp and the step "
+                    "gathers (a scanned layer's stacked leaf counts once)"
+                ).set(float(gather["leaves"]), codec=gather["codec"])
+                self.telemetry.gauge(
+                    "zero3_gather_bytes_per_step",
+                    "payload bytes of the stage-3 weight gathers a step: "
+                    "wire dtype x passes (forward, rematerialized forward) "
+                    "x microbatches"
+                ).set(float(gather["bytes"]), codec=gather["codec"])
         if dp <= 1:
             return
         if self._grad_overlap:
@@ -1278,6 +1307,42 @@ class Engine:
             COMMS_LOGGER.append_traced("all_reduce", int(bpe * n_elems),
                                        "data", dp, caller=caller)
 
+    def _fsdp_plan(self):
+        """``([(op, payload bytes, caller)], stated-gather summary or None)``:
+        a step's collectives over the fsdp axis.
+
+        ZeRO <= 2 over fsdp: fp32 grads reduce-scattered, the updated fp32
+        params gathered (qgZ quantizes the data axis only). Stage 3 with the
+        stated gather (``ShardCtx.weight_gather``): a gathered leaf travels
+        in the compute dtype (or int8 + one fp32 scale a block, qwZ) once
+        for the forward and once more where the backward rematerializes it,
+        every microbatch; its gradient comes back in the compute dtype, a
+        scanned layer's through an all-reduce and a slice, a leaf's outside
+        the scan through a reduce-scatter (``qwz.gather_weight``); nothing is
+        gathered after the update, the params stay shards."""
+        n_elems = sum(
+            int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(self.params))
+        if not self._zero3_gathered:
+            return [("reduce_scatter", 4.0 * n_elems, "train_batch_fn"),
+                    ("all_gather", 4.0 * n_elems, "train_batch_fn")], None
+        zero = self.config.zero_optimization
+        itemsize = jnp.dtype(self.config.compute_dtype).itemsize
+        wire = {None: float(itemsize), "int8": 1.0 + 4.0 / zero.qwz_block}
+        passes = 2 if self.shard_ctx.remat else 1
+        gas = self.gas
+        codec = ("int8" if zero.quantized_weights
+                 else str(jnp.dtype(self.config.compute_dtype)))
+        gather_bytes = gas * passes * sum(
+            n * wire[c] for _, n, c, _ in self._zero3_gathered)
+        grads = {scanned: gas * itemsize * sum(
+            n for _, n, _, s in self._zero3_gathered if s == scanned)
+            for scanned in (True, False)}
+        return ([("all_gather", gather_bytes, f"zero3_gather[{codec}]x{passes}"),
+                 ("all_reduce", grads[True], "zero3_gather/layer_grads"),
+                 ("reduce_scatter", grads[False], "zero3_gather/leaf_grads")],
+                {"leaves": len(self._zero3_gathered), "codec": codec,
+                 "bytes": gather_bytes})
+
     def _grad_wire_bytes(self) -> float:
         """Estimated per-step gradient-sync wire bytes (same plan as
         ``_record_comms_plan``, with ring-collective wire factors): feeds the
@@ -1287,12 +1352,14 @@ class Engine:
             return 0.0
         n_elems = sum(
             int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(self.params))
-        grad_bytes = 4.0 * n_elems
         _, bpe = self._reduction_codec()
         wire = 0.0
         if fs > 1:
-            # ring reduce-scatter + all-gather each move (n-1)/n of the data
-            wire += 2.0 * grad_bytes * (fs - 1) / fs
+            # ring reduce-scatter and all-gather each move (n-1)/n of the
+            # data, an all-reduce is one of each
+            wire += sum((2.0 if op == "all_reduce" else 1.0) * nbytes
+                        for op, nbytes, _ in self._fsdp_plan()[0]
+                        ) * (fs - 1) / fs
         if dp > 1:
             if self._grad_overlap:
                 plan = self._overlap_plan

@@ -170,16 +170,18 @@ class TestQwzEngine:
         batch = _batches(1)[0]
         ref = _engine(qwz=False)
         ref_losses = [float(ref.train_batch(batch)) for _ in range(8)]
+        assert ref.shard_ctx.weight_gather.codec is None  # the same hook
         qw = _engine(qwz=True)
-        assert qw.shard_ctx.qwz is not None
+        assert qw.shard_ctx.weight_gather.codec == "int8"
         qw_losses = [float(qw.train_batch(batch)) for _ in range(8)]
         assert all(np.isfinite(qw_losses))
         assert qw_losses[-1] < qw_losses[0]
         # int8 blockwise weight error perturbs the trajectory only slightly
         np.testing.assert_allclose(qw_losses, ref_losses, rtol=0.05)
 
-    def test_composes_with_tensor_axis(self):
-        engine = _engine(qwz=True, mesh={"data": 1, "fsdp": 4, "tensor": 2})
+    @pytest.mark.parametrize("qwz", [False, True], ids=["dense", "int8"])
+    def test_composes_with_tensor_axis(self, qwz):
+        engine = _engine(qwz=qwz, mesh={"data": 1, "fsdp": 4, "tensor": 2})
         losses = [float(engine.train_batch(b)) for b in _batches(3)]
         assert all(np.isfinite(losses))
 

@@ -10,6 +10,7 @@ observability work.)"""
 
 import http.client
 import json
+import time
 import tracemalloc
 
 import jax.numpy as jnp
@@ -400,8 +401,15 @@ class TestServingTracePropagation:
         conn.close()
         assert echoed[0] == trace_id  # same trace, server-side span id
         assert body["trace_id"] == trace_id
-        spans = telemetry.get_telemetry().tracer.snapshot(trace_id)
-        names = {s["name"] for s in spans}
+        # the handler closes `http/request` after the body's last byte is
+        # out: a client that has read it can be ahead of the span by a moment
+        deadline = time.monotonic() + 10
+        while True:
+            spans = telemetry.get_telemetry().tracer.snapshot(trace_id)
+            names = {s["name"] for s in spans}
+            if "http/request" in names or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert {"http/request", "router/submit", "loop/inbox_wait",
                 "engine/request", "request/admission", "engine/prefill",
                 "engine/decode", "engine/readback"} <= names
