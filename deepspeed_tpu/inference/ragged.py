@@ -75,6 +75,13 @@ def _persistent_cache_miss_counter() -> Callable[[], int]:
     return lambda: _CACHE_MISSES[0]
 
 
+def _kept_pairs(pos0: int, take: int, topk: int) -> int:
+    """``sum(min(p + 1, topk) for p in range(pos0, pos0 + take))``: the
+    query x kept-row pairs of a chunk of ``take`` queries from ``pos0``."""
+    full = max(0, min(take, topk - pos0))     # queries that keep every row
+    return (full * pos0 + full * (full + 1) // 2) + (take - full) * topk
+
+
 # why a model with slot state cannot be resumed from blocks alone
 _NO_SNAPSHOT = ("a sequence's recurrent state is not in its blocks, and there "
                 "is no snapshot of it at a block boundary to restore beside "
@@ -673,6 +680,12 @@ class RaggedInferenceEngine:
         self._slot_bytes = self.state_bytes_per_slot()
         if self._slot_state:
             self._refuse_for_slot_state()
+        # a family whose attention reads a selection of the context only
+        # (``ModelSpec.index_topk`` rows a query): counted beside the context
+        # on ``engine/dispatch``, and refused where it cannot be carried
+        self._topk = self.spec.index_topk
+        if self._topk:
+            self._refuse_for_selection()
         # bytes one block would cost unquantized at the engine dtype / at
         # fp16: the baselines for kvquant_bytes_saved_total and the
         # resident-block multiplier the bench gates on. The blocks base
@@ -1174,9 +1187,28 @@ class RaggedInferenceEngine:
             if on:
                 self._refuse(name, why)
 
-    def _refuse(self, what: str, why: str) -> None:
-        raise ValueError(f"model {self.spec.name} keeps recurrent state in "
-                         f"slot leaves; {what} is refused: {why}")
+    def _refuse_for_selection(self) -> None:
+        """A model that selects the rows its attention reads has step
+        programs for the tiled step and the plain step only."""
+        cfg = self.cfg
+        trait = f"selects the {self._topk} cached rows a query attends over"
+        if cfg.sched_steps >= 2:
+            self._refuse(
+                "sched_steps",
+                "a multi-step program would score, select and gather inside "
+                "its scan of steps, and no such program has been built or "
+                "sized for the selection's [rows, table] scores", trait)
+        if cfg.spec_draft:
+            self._refuse(
+                "spec_draft",
+                "the verify lanes of one row are queries at successive "
+                "positions of ONE slot, which the selection's decode path "
+                "(one query a slot) has not been written for", trait)
+
+    def _refuse(self, what: str, why: str,
+                trait: str = "keeps recurrent state in slot leaves") -> None:
+        raise ValueError(f"model {self.spec.name} {trait}; {what} is "
+                         f"refused: {why}")
 
     def _refuse_block_transfer(self, what: str) -> None:
         """``KVHandoff`` moves a sequence as its blocks."""
@@ -2643,14 +2675,21 @@ class RaggedInferenceEngine:
         ``dec_kv_tokens`` the decode rows' part of ``kv_tokens`` (a decode
         row is one query, so also its part of the pairs); ``moe`` is the
         form of the step's expert FFNs (``_moe_attr``); a model with slot
-        state adds what of it the step moves (``_state_attr``)."""
+        state adds what of it the step moves (``_state_attr``); a model
+        that selects the rows a query attends over (``ModelSpec.index_topk``
+        a query; the three above are then what its indexer scores) adds
+        ``sel_pairs``, the query x kept-row pairs (``min(position + 1,
+        index_topk)`` a query), ``sel_kv_tokens``, the fewest cached rows
+        any implementation must read (``min(context, index_topk)`` a decode
+        row and a prefill tile), and ``dec_sel_kv_tokens``, the decode rows'
+        part of that."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
         if plan is None:
             return False
         (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec, kv_pre,
-         pairs_pre, n_dec, n_pre) = plan
+         pairs_pre, n_dec, n_pre, sel) = plan
         sampled = any(s.temperature > 0.0 for _, s in emit)
         has_tk = sampled and any(s.top_k > 0 for _, s in emit)
         has_tp = sampled and any(s.top_p < 1.0 for _, s in emit)
@@ -2674,10 +2713,19 @@ class RaggedInferenceEngine:
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total),
-                  **self._state_attr(n_dec, n_pre, n)):
+                  **self._state_attr(n_dec, n_pre, n), **sel):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._bt_dev,
                 staged, self._sample_root)
+        if sel and self.telemetry.enabled:
+            tel = self.telemetry
+            tel.counter("inference_sparse_selected_tokens_total",
+                        "cached rows the queries' selections kept (a "
+                        "query x kept-row pair each)").inc(sel["sel_pairs"])
+            tel.counter("inference_sparse_context_tokens_total",
+                        "cached rows the same queries could have read (a "
+                        "query x context-row pair each)").inc(
+                            kv_dec + pairs_pre)
         if probe and self._cache_misses() > misses:
             self._precompile_zoo_in_background()
         participants: dict[int, _SeqState] = {}
@@ -2737,6 +2785,10 @@ class RaggedInferenceEngine:
         trace_on = self._tracer.enabled
         tpairs = [] if trace_on else None
         kv_dec = kv_pre = pairs_pre = 0
+        topk = self._topk
+        sel = dict.fromkeys(
+            ("sel_pairs", "sel_kv_tokens", "dec_sel_kv_tokens"), 0) \
+            if topk else {}
         size = budget + ct
         tokens = np.zeros(size, np.int32)
         slots = np.full(size, cfg.max_seqs, np.int32)
@@ -2776,6 +2828,8 @@ class RaggedInferenceEngine:
             seq.pos += 1
             kv_dec += seq.pos
             n_dec += 1
+            for key in sel:     # a decode row: one query, its kept rows
+                sel[key] += min(seq.pos, topk)
 
         ts = tpz = tv = None
         if ct:
@@ -2799,6 +2853,11 @@ class RaggedInferenceEngine:
                     tv[tile0 + ti] = min(ct, take - ti * ct)
                 max_pos = max(max_pos, seq.pos + take - 1)
                 pairs_pre += take * seq.pos + take * (take + 1) // 2
+                if topk:
+                    sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
+                    sel["sel_kv_tokens"] += sum(
+                        min(seq.pos + min(i + ct, take), topk)
+                        for i in range(0, take, ct))
                 seq.pos += take
                 kv_pre += seq.pos
                 sched += take
@@ -2827,6 +2886,9 @@ class RaggedInferenceEngine:
                                           dtype=np.int32)
                 max_pos = max(max_pos, seq.pos + take - 1)
                 pairs_pre += take * seq.pos + take * (take + 1) // 2
+                if topk:
+                    sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
+                    sel["sel_kv_tokens"] += min(seq.pos + take, topk)
                 seq.pos += take
                 kv_pre += seq.pos
                 n += take
@@ -2846,7 +2908,7 @@ class RaggedInferenceEngine:
         if ct:
             parts += [ts, tpz, tv]
         return (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec,
-                kv_pre, pairs_pre, n_dec, len(chunks) if ct else 0)
+                kv_pre, pairs_pre, n_dec, len(chunks) if ct else 0, sel)
 
     def _reconcile_pending(self) -> dict:
         """Read back the OLDEST pending dispatch's tokens and fold them

@@ -372,6 +372,12 @@ class ModelSpec:
     # whose padding row costs its step next to nothing asks for a coarser
     # ladder than the default
     decode_bucket_min: int = 4
+    # a family whose attention reads some of a sequence's cached rows only
+    # (a learned selection a query) says how many a query keeps; None: every
+    # family that attends over the whole context. The engine counts the
+    # selected work beside the context (``sel_pairs``, ``sel_kv_tokens`` on
+    # ``engine/dispatch``) and refuses what cannot carry the selection
+    index_topk: int | None = None
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

@@ -1,15 +1,21 @@
-"""DeepSeek-V3-family causal LM as Moonlight-16B-A3B configures it
-(``model_type: deepseek_v3``): multi-head latent attention (MLA), leading
-dense SwiGLU layers, then layers of sigmoid-routed experts beside shared ones.
+"""DeepSeek-V3-family causal LM (``model_type: deepseek_v3``; Moonlight-16B-A3B
+configures the subset its defaults give): multi-head latent attention (MLA),
+leading dense SwiGLU layers, then layers of sigmoid-routed experts beside
+shared ones. ``models/deepseek_v32.py`` builds DeepSeek-V3.2's sparse attention
+on this module.
 
 Layer equations (pre-norm RMSNorm, a residual after each half):
 
-- **MLA.** ``q = x W_q -> [T, H, nope + rope]``, RoPE on the ``rope`` lanes.
+- **MLA.** ``q = x W_q -> [T, H, nope + rope]`` (with ``q_lora_rank``: ``q =
+  RMSNorm(x W_qa) W_qb``, a low-rank query), RoPE on the ``rope`` lanes.
   ``a = x W_kva -> [T, lat + rope]``; ``c = RMSNorm(a[:, :lat])``; ``k_rope =
   RoPE(a[:, lat:])``, one head shared by all ``H``. ``kv = c W_kvb -> [T, H,
   nope + v]``, ``k = [kv[..., :nope], k_rope]``, ``v = kv[..., nope:]``;
   causal softmax of ``q . k * (nope + rope)^-0.5``. ``forward`` (training,
-  evaluation) computes exactly that on ``xla_attention``.
+  evaluation) computes exactly that on ``xla_attention``. ``rope_scaling``
+  (YaRN) stretches the rotation's frequencies
+  (``ops/attention.rope_frequencies``) and multiplies the softmax scale by
+  ``mscale**2``, ``mscale = 0.1 mscale_all_dim ln(factor) + 1``.
 - **Serving caches the row ``[c, k_rope]``** (``lat + rope`` lanes a token and
   layer, 1,152 B in bf16 at Moonlight's 512 + 64, against 8,192 B for 16 K
   and V heads of 128) and decodes **absorbed**: ``q_lat[h] = q_nope[h]
@@ -17,21 +23,27 @@ Layer equations (pre-norm RMSNorm, a residual after each half):
   P c``, ``o[h] = o_lat[h] W_kvb_v[h]``: attention over the cached rows
   themselves (``models/paged.latent_pool_attention``), prefill chunks too.
 - **Router.** ``s = sigmoid(x_f32 W_r)``; the ``top_k`` largest of ``s +
-  e_score_correction_bias`` are picked; the weights are ``s`` there (without
-  the bias), divided by their sum, times ``routed_scaling_factor``. ``y =
-  sum_i w_i SwiGLU_i(x) + SwiGLU_shared(x)``; the routed sum goes through
-  ``models/experts.routed_experts``, the function Mixtral serves through.
+  e_score_correction_bias`` are picked (with ``n_group > 1``: among the
+  experts of a token's ``topk_group`` best groups, ``experts._in_best_groups``);
+  the weights are ``s`` there (without the bias), divided by their sum, times
+  ``routed_scaling_factor``. ``y = sum_i w_i SwiGLU_i(x) + SwiGLU_shared(x)``;
+  the routed sum goes through ``models/experts.routed_experts``, the function
+  Mixtral serves through.
+- **One rank's share.** ``experts_held`` of the ``num_experts`` routed experts
+  live here, those of rank ``expert_rank`` (the names ``nemotron_h`` uses): the
+  router scores and picks over all of them, the layer computes the part its
+  own experts give (``routed_experts``' ``held``), and nothing stands in for
+  the exchange with the other ranks.
 
 One departure from the published code: it rotates interleaved lane pairs
 after a permutation of the projections' columns; ``ops/attention.apply_rope``
 rotates half-split lanes, which with seeded weights is the same model up to
-that permutation. ``q_lora_rank`` (a low-rank query projection), ``n_group >
-1`` (group-limited routing) and a quantized latent pool are not implemented
-and raise.
+that permutation. A quantized latent pool is not implemented and raises.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 
@@ -72,22 +84,62 @@ class DeepseekConfig:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 2.446
     n_group: int = 1
+    topk_group: int = 1
+    experts_held: int | None = None      # of num_experts, those that live here
+    expert_rank: int = 0                 # ... experts rank * held onwards
     rope_theta: float = 50000.0
+    # the published ``rope_scaling`` group (YaRN), whole; None: plain RoPE
+    rope_scaling: dict | None = None
     rms_norm_eps: float = 1e-5
     max_seq_len: int = 8192
 
     def __post_init__(self):
-        if self.q_lora_rank is not None:
-            raise NotImplementedError(
-                "deepseek: q_lora_rank (a low-rank query projection) is not "
-                "implemented; Moonlight-16B-A3B has none")
-        if self.n_group != 1:
-            raise NotImplementedError(
-                "deepseek: group-limited routing (n_group > 1) is not "
-                "implemented; Moonlight-16B-A3B routes over one group")
         if not 0 <= self.first_k_dense < self.num_layers:
             raise ValueError("deepseek: first_k_dense must leave at least "
                              "one expert layer")
+        if self.num_experts % self.n_group or not \
+                1 <= self.topk_group <= self.n_group:
+            raise ValueError("deepseek: n_group must divide num_experts and "
+                             "topk_group lie in 1 .. n_group")
+        if self.num_experts % self.held or not \
+                0 <= self.expert_rank < self.num_experts // self.held:
+            raise ValueError("deepseek: experts_held must divide num_experts "
+                             "and expert_rank name one of the shares")
+        if self.rope_scaling is not None:
+            if self.rope_scaling.get("type") != "yarn":
+                raise NotImplementedError(
+                    "deepseek: rope_scaling of type "
+                    f"{self.rope_scaling.get('type')!r} is not implemented "
+                    "(yarn is)")
+            # a dict would make the config unhashable
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
+
+    @property
+    def held_share(self):
+        """``routed_experts``' ``held``; None where every expert lives here."""
+        if self.held == self.num_experts:
+            return None
+        return (self.expert_rank * self.held, self.num_experts)
+
+    @property
+    def route_groups(self):
+        """``routed_experts``' ``groups``; None for one group."""
+        return None if self.n_group == 1 else (self.n_group, self.topk_group)
+
+    @property
+    def yarn(self):
+        """``ops/attention.rope_frequencies``' stretch; None: plain RoPE."""
+        if self.rope_scaling is None:
+            return None
+        r = dict(self.rope_scaling)
+        return (float(r["factor"]), float(r.get("beta_fast", 32)),
+                float(r.get("beta_slow", 1)),
+                int(r["original_max_position_embeddings"]))
 
     @property
     def qk_head_dim(self) -> int:
@@ -102,7 +154,12 @@ class DeepseekConfig:
 
     @property
     def softmax_scale(self) -> float:
-        return self.qk_head_dim ** -0.5
+        scale = self.qk_head_dim ** -0.5
+        if self.rope_scaling is None:
+            return scale
+        r = dict(self.rope_scaling)
+        mscale = 0.1 * r.get("mscale_all_dim", 0) * math.log(r["factor"]) + 1.0
+        return scale * mscale * mscale
 
     @property
     def num_moe_layers(self) -> int:
@@ -120,7 +177,7 @@ class DeepseekConfig:
 
 def init_params(cfg: DeepseekConfig, rng) -> dict:
     d, h = cfg.hidden_size, cfg.num_heads
-    e, fm = cfg.num_experts, cfg.moe_intermediate_size
+    e, held, fm = cfg.num_experts, cfg.held, cfg.moe_intermediate_size
     fs = cfg.num_shared_experts * fm
     k = iter(jax.random.split(rng, 24))
     std = 0.02
@@ -129,10 +186,17 @@ def init_params(cfg: DeepseekConfig, rng) -> dict:
     def norm(key, *shape, s=std):
         return jax.random.normal(key, shape, jnp.float32) * s
 
+    def query(n):
+        if cfg.q_lora_rank is None:
+            return {"wq": norm(next(k), n, d, h * cfg.qk_head_dim)}
+        return {"wq_a": norm(next(k), n, d, cfg.q_lora_rank),
+                "q_norm": jnp.ones((n, cfg.q_lora_rank), jnp.float32),
+                "wq_b": norm(next(k), n, cfg.q_lora_rank, h * cfg.qk_head_dim)}
+
     def attention(n):
         return {
             "attn_norm": jnp.ones((n, d), jnp.float32),
-            "wq": norm(next(k), n, d, h * cfg.qk_head_dim),
+            **query(n),
             "wkv_a": norm(next(k), n, d,
                           cfg.kv_lora_rank + cfg.qk_rope_head_dim),
             "kv_norm": jnp.ones((n, cfg.kv_lora_rank), jnp.float32),
@@ -157,9 +221,9 @@ def init_params(cfg: DeepseekConfig, rng) -> dict:
             # drawn small and non-zero, so that selection (with the bias)
             # and weighting (without it) differ
             "router_bias": norm(next(k), nm, e, s=0.01),
-            "w_gate": norm(next(k), nm, e, d, fm),
-            "w_up": norm(next(k), nm, e, d, fm),
-            "w_down": norm(next(k), nm, e, fm, d, s=out_std),
+            "w_gate": norm(next(k), nm, held, d, fm),
+            "w_up": norm(next(k), nm, held, d, fm),
+            "w_down": norm(next(k), nm, held, fm, d, s=out_std),
             "ws_gate": norm(next(k), nm, d, fs),
             "ws_up": norm(next(k), nm, d, fs),
             "ws_down": norm(next(k), nm, fs, d, s=out_std),
@@ -169,37 +233,43 @@ def init_params(cfg: DeepseekConfig, rng) -> dict:
     }
 
 
-_ATTENTION_AXES = {
-    "attn_norm": ("layers", "embed"),
-    "wq": ("layers", "embed", "heads"),
-    "wkv_a": ("layers", "embed", None),
-    "kv_norm": ("layers", None),
-    "wkv_b": ("layers", None, "heads"),
-    "wo": ("layers", "heads", "embed"),
-    "mlp_norm": ("layers", "embed"),
-}
-PARAM_LOGICAL_AXES = {
-    "embed": ("vocab", "embed"),
-    "dense": {
-        **_ATTENTION_AXES,
-        "w_gate": ("layers", "embed", "ffn"),
-        "w_up": ("layers", "embed", "ffn"),
-        "w_down": ("layers", "ffn", "embed"),
-    },
-    "layers": {
-        **_ATTENTION_AXES,
-        "router": ("layers", "embed", None),
-        "router_bias": ("layers", None),
-        "w_gate": ("layers", "experts", "embed", "ffn"),
-        "w_up": ("layers", "experts", "embed", "ffn"),
-        "w_down": ("layers", "experts", "ffn", "embed"),
-        "ws_gate": ("layers", "embed", "ffn"),
-        "ws_up": ("layers", "embed", "ffn"),
-        "ws_down": ("layers", "ffn", "embed"),
-    },
-    "final_norm": ("embed",),
-    "lm_head": ("embed", "vocab"),
-}
+def param_logical_axes(cfg: DeepseekConfig) -> dict:
+    """The logical axes of ``init_params``' tree, leaf for leaf."""
+    query = ({"wq": ("layers", "embed", "heads")} if cfg.q_lora_rank is None
+             else {"wq_a": ("layers", "embed", None),
+                   "q_norm": ("layers", None),
+                   "wq_b": ("layers", None, "heads")})
+    attention = {
+        "attn_norm": ("layers", "embed"),
+        **query,
+        "wkv_a": ("layers", "embed", None),
+        "kv_norm": ("layers", None),
+        "wkv_b": ("layers", None, "heads"),
+        "wo": ("layers", "heads", "embed"),
+        "mlp_norm": ("layers", "embed"),
+    }
+    return {
+        "embed": ("vocab", "embed"),
+        "dense": {
+            **attention,
+            "w_gate": ("layers", "embed", "ffn"),
+            "w_up": ("layers", "embed", "ffn"),
+            "w_down": ("layers", "ffn", "embed"),
+        },
+        "layers": {
+            **attention,
+            "router": ("layers", "embed", None),
+            "router_bias": ("layers", None),
+            "w_gate": ("layers", "experts", "embed", "ffn"),
+            "w_up": ("layers", "experts", "embed", "ffn"),
+            "w_down": ("layers", "experts", "ffn", "embed"),
+            "ws_gate": ("layers", "embed", "ffn"),
+            "ws_up": ("layers", "embed", "ffn"),
+            "ws_down": ("layers", "ffn", "embed"),
+        },
+        "final_norm": ("embed",),
+        "lm_head": ("embed", "vocab"),
+    }
 
 
 def _ffn(cfg: DeepseekConfig, h, lp, experts, **stacked):
@@ -212,22 +282,39 @@ def _ffn(cfg: DeepseekConfig, h, lp, experts, **stacked):
         h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], cfg.top_k,
         **stacked, scoring=cfg.scoring_func, bias=lp["router_bias"],
         renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-        eps=1e-20)
+        eps=1e-20, held=cfg.held_share, groups=cfg.route_groups)
     return routed + swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
 
 
-def _mla_inputs(cfg: DeepseekConfig, h, lp, positions):
+def _rope(cfg: DeepseekConfig, q, k, positions):
+    """``apply_rope`` on flat tokens, ``q`` [T, H, R] and ``k`` [T, R] (one
+    head), at the config's frequencies."""
+    q, k = apply_rope(q[None], k[None, :, None], positions[None],
+                      cfg.rope_theta, cfg.yarn)
+    return q[0], k[0, :, 0]
+
+
+def _query_latent(cfg: DeepseekConfig, h, lp):
+    """The normed low-rank query ``cq`` [T, q_lora_rank] (``q_lora_rank``
+    set)."""
+    return rmsnorm(h @ lp["wq_a"], lp["q_norm"], cfg.rms_norm_eps)
+
+
+def _mla_inputs(cfg: DeepseekConfig, h, lp, positions, cq=None):
     """``h`` [T, D] (normed) -> ``q_nope`` [T, H, nope], roped ``q_rope``
     [T, H, rope], the normed latent ``c`` [T, lat], roped ``k_rope``
-    [T, rope]."""
+    [T, rope]. ``cq``: ``_query_latent``'s, where the caller has it."""
     t = h.shape[0]
     lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    q = (h @ lp["wq"]).reshape(t, cfg.num_heads, cfg.qk_head_dim)
+    if cfg.q_lora_rank is None:
+        q = h @ lp["wq"]
+    else:
+        q = (_query_latent(cfg, h, lp) if cq is None else cq) @ lp["wq_b"]
+    q = q.reshape(t, cfg.num_heads, cfg.qk_head_dim)
     a = h @ lp["wkv_a"]
     c = rmsnorm(a[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
-    q_rope, k_rope = apply_rope(q[None, ..., nope:], a[None, :, None, lat:],
-                                positions[None], cfg.rope_theta)
-    return q[..., :nope], q_rope[0], c, k_rope[0, :, 0]
+    q_rope, k_rope = _rope(cfg, q[..., nope:], a[:, lat:], positions)
+    return q[..., :nope], q_rope, c, k_rope
 
 
 def _wkv_b(cfg: DeepseekConfig, lp):
@@ -238,15 +325,19 @@ def _wkv_b(cfg: DeepseekConfig, lp):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions):
+def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions, select=None):
     """One layer of the plain forward pass, ``x`` [B, S, D]: MLA as
-    published (per-head keys and values from the latent), not absorbed."""
+    published (per-head keys and values from the latent), not absorbed.
+    ``select(h, cq, lp, positions) -> [B, 1, S, S]`` additive bias: a family
+    whose attention reads some of the context only (``deepseek_v32``)."""
     lp = ctx.layer_weights(lp, x.dtype)
     b, s, d = x.shape
     heads = cfg.num_heads
 
     h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps).reshape(b * s, d)
-    q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions.reshape(-1))
+    cq = None if cfg.q_lora_rank is None else _query_latent(cfg, h, lp)
+    q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions.reshape(-1),
+                                            cq)
     wk, wv = _wkv_b(cfg, lp)
     k_nope = jnp.einsum("tl,lhn->thn", c, wk)
     v = jnp.einsum("tl,lhv->thv", c, wv)
@@ -255,8 +346,9 @@ def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions):
         [k_nope, jnp.broadcast_to(k_rope[:, None], (b * s, heads,
                                                      cfg.qk_rope_head_dim))],
         axis=-1)
+    bias = None if select is None else select(h, cq, lp, positions)
     o = xla_attention(q.reshape(b, s, heads, -1), k.reshape(b, s, heads, -1),
-                      v.reshape(b, s, heads, -1), causal=True,
+                      v.reshape(b, s, heads, -1), causal=True, bias=bias,
                       scale=cfg.softmax_scale)
     x = x + o.reshape(b, s, heads * cfg.v_head_dim) @ lp["wo"]
 
@@ -280,14 +372,15 @@ def _lm_head(head, x):
 
 
 def forward(cfg: DeepseekConfig, params, input_ids, ctx: ShardCtx | None = None,
-            remat: bool = False, remat_policy=None):
+            remat: bool = False, remat_policy=None, select=None):
     """``[B, S]`` token ids -> ``[B, S, V]`` logits, dropless (every expert
-    computes every token; the router's weights combine)."""
+    computes every token; the router's weights combine). ``select``:
+    ``_layer``'s."""
     ctx = ctx or ShardCtx()
     b, s = input_ids.shape
     x = ctx.embed_lookup(params["embed"], input_ids, "batch", "seq", "embed_act")
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    layer = partial(_layer, cfg, ctx)
+    layer = partial(_layer, cfg, ctx, select=select)
     if remat:
         layer = jax.checkpoint(layer, policy=remat_policy)
     for lp in _dense_layers(cfg, params):
@@ -317,10 +410,14 @@ def init_paged_cache(cfg: DeepseekConfig, num_blocks: int, block_size: int,
 
 
 def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
-                  block_tables, prefill_tiles=None, stacks=None):
+                  block_tables, prefill_tiles=None, stacks=None, sparse=None):
     """One layer over a flat ragged token batch [T, D]: the step's rows
     ``[c, k_rope, zeros]`` are scattered into the latent pool, then absorbed
-    attention reads the cached rows through the layer's block table."""
+    attention reads the cached rows through the layer's block table.
+    ``sparse(h, cq, lp, q, pool) -> (o_lat, pool)`` takes the attention's
+    place for a family that reads some of the cached rows only and keeps
+    further block leaves (``deepseek_v32``); ``pool`` has this step's latent
+    rows in it."""
     from deepspeed_tpu.models.paged import (
         latent_pool_attention,
         write_rows_paged,
@@ -331,7 +428,8 @@ def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
     t_tokens = x.shape[0]
 
     h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions)
+    cq = None if cfg.q_lora_rank is None else _query_latent(cfg, h, lp)
+    q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions, cq)
     pad = cfg.row_lanes - cfg.kv_lora_rank - cfg.qk_rope_head_dim
     kv = write_rows_paged(
         pool["kv"],
@@ -341,30 +439,37 @@ def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
     q = jnp.concatenate(
         [jnp.einsum("thn,lhn->thl", q_nope, wk), q_rope,
          jnp.zeros((t_tokens, cfg.num_heads, pad), q_rope.dtype)], axis=-1)
-    o_lat = latent_pool_attention(
-        q, kv, slots, positions, block_tables, cfg.kv_lora_rank,
-        cfg.softmax_scale, prefill_tiles).astype(x.dtype)
-    o = jnp.einsum("thl,lhv->thv", o_lat, wv)
+    if sparse is None:
+        pool = {"kv": kv}
+        o_lat = latent_pool_attention(
+            q, kv, slots, positions, block_tables, cfg.kv_lora_rank,
+            cfg.softmax_scale, prefill_tiles)
+    else:
+        o_lat, pool = sparse(h, cq, lp, q, {**pool, "kv": kv})
+    o = jnp.einsum("thl,lhv->thv", o_lat.astype(x.dtype), wv)
     x = x + o.reshape(t_tokens, -1) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     stacked = (*stacks, lp["first_expert"]) if "first_expert" in lp else None
-    return x + _ffn(cfg, h, lp, routed_experts, stacked=stacked), {"kv": kv}
+    return x + _ffn(cfg, h, lp, routed_experts, stacked=stacked), pool
 
 
 def ragged_forward(cfg: DeepseekConfig, params, tokens, slots, positions,
-                   block_tables, cache, prefill_tiles=None):
+                   block_tables, cache, prefill_tiles=None, sparse=None):
     """Flat ragged step: [T] mixed tokens -> ([T, V] logits, cache). The
     stack is not homogeneous: the dense layers run before the scan at layers
     ``0 .. first_k_dense - 1`` of the pool, the expert layers scan after
-    them (``models/paged.scan_layers_paged``)."""
+    them (``models/paged.scan_layers_paged``). ``sparse(layer_tables)`` makes
+    ``_ragged_layer``'s ``sparse`` for a layer's block table."""
     from deepspeed_tpu.models.paged import scan_layers_paged
 
     layers, stacks = expert_stacks(params["layers"])
 
     def layer(x, lp, pool, layer_tables):
-        return _ragged_layer(cfg, x, lp, pool, positions, slots, layer_tables,
-                             prefill_tiles=prefill_tiles, stacks=stacks)
+        return _ragged_layer(
+            cfg, x, lp, pool, positions, slots, layer_tables,
+            prefill_tiles=prefill_tiles, stacks=stacks,
+            sparse=None if sparse is None else sparse(layer_tables))
 
     x = params["embed"][tokens].astype(cache["kv"].dtype)
     x, cache = scan_layers_paged(
@@ -376,7 +481,10 @@ def ragged_forward(cfg: DeepseekConfig, params, tokens, slots, positions,
 
 def _attention_params(cfg: DeepseekConfig) -> int:
     d, h = cfg.hidden_size, cfg.num_heads
-    return (d * h * cfg.qk_head_dim
+    r = cfg.q_lora_rank
+    query = (d * h * cfg.qk_head_dim if r is None
+             else d * r + r + r * h * cfg.qk_head_dim)
+    return (query
             + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) + cfg.kv_lora_rank
             + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
             + h * cfg.v_head_dim * d + 2 * d)
@@ -393,19 +501,21 @@ def _moe_layer_params(cfg: DeepseekConfig, experts: int) -> int:
 
 
 def num_params(cfg: DeepseekConfig) -> int:
+    """Parameters that live here: the held experts, not all the routed."""
     d = cfg.hidden_size
     return (2 * cfg.vocab_size * d + d
             + cfg.first_k_dense * _dense_layer_params(cfg)
-            + cfg.num_moe_layers * _moe_layer_params(cfg, cfg.num_experts))
+            + cfg.num_moe_layers * _moe_layer_params(cfg, cfg.held))
 
 
 def flops_per_token(cfg: DeepseekConfig, seq_len: int) -> float:
-    """Active-parameter training FLOPs: ``top_k`` of the routed experts, the
-    shared ones, attention over ``seq_len`` (scores at ``nope + rope``,
-    values at ``v`` lanes a head)."""
+    """Active-parameter training FLOPs: ``top_k`` of the routed experts (of
+    them, the held share), the shared ones, attention over ``seq_len``
+    (scores at ``nope + rope``, values at ``v`` lanes a head)."""
     active = (cfg.vocab_size * cfg.hidden_size
               + cfg.first_k_dense * _dense_layer_params(cfg)
-              + cfg.num_moe_layers * _moe_layer_params(cfg, cfg.top_k))
+              + cfg.num_moe_layers * _moe_layer_params(
+                  cfg, cfg.top_k * cfg.held / cfg.num_experts))
     attn = (6.0 * cfg.num_layers * cfg.num_heads
             * (cfg.qk_head_dim + cfg.v_head_dim) * seq_len / 2.0)
     return 6.0 * active + attn
@@ -429,9 +539,8 @@ def build(cfg: DeepseekConfig, ctx: ShardCtx | None = None,
         init_fn=partial(init_params, cfg),
         loss_fn=loss_fn,
         forward_fn=fwd,
-        param_logical_axes=PARAM_LOGICAL_AXES,
-        logical_dim_units={"heads": cfg.num_heads,
-                           "experts": cfg.num_experts},
+        param_logical_axes=param_logical_axes(cfg),
+        logical_dim_units={"heads": cfg.num_heads, "experts": cfg.held},
         num_params=num_params(cfg),
         flops_per_token=partial(flops_per_token, cfg),
         init_paged_cache_fn=partial(init_paged_cache, cfg),

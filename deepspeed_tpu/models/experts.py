@@ -84,8 +84,25 @@ def _top_k(scores, k: int):
                     dtype=jnp.int32))
 
 
+def _in_best_groups(picking, groups):
+    """``picking`` [T, E] with the experts outside a token's best groups at
+    ``-inf``. ``groups = (n_group, topk_group)``: the experts are ``n_group``
+    runs of neighbours, a group's score is the sum of its two largest
+    entries, and the ``topk_group`` best groups stay (DeepSeek-V3's
+    group-limited routing: a token's picks lie on few nodes)."""
+    n_group, topk_group = groups
+    t, e = picking.shape
+    group_score = jnp.sum(
+        lax.top_k(picking.reshape(t, n_group, e // n_group), 2)[0], axis=-1)
+    _, best = lax.top_k(group_score, topk_group)
+    stays = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None], best].set(True)
+    return jnp.where(jnp.repeat(stays, e // n_group, axis=1), picking,
+                     -jnp.inf)
+
+
 def _route(h, router_w, top_k: int, scoring: str, bias, renormalize: bool,
-           scale: float, eps: float):
+           scale: float, eps: float, groups=None):
     """The router, in float32 -> (combine weights ``[T, top_k]``, picked
     experts ``[T, top_k]``)."""
     logits = h.astype(jnp.float32) @ router_w.astype(jnp.float32)
@@ -95,10 +112,13 @@ def _route(h, router_w, top_k: int, scoring: str, bias, renormalize: bool,
         scores = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
-    if bias is None:
+    if bias is None and groups is None:
         topv, topi = _top_k(scores, top_k)
     else:
-        _, topi = _top_k(scores + bias.astype(jnp.float32), top_k)
+        picking = scores if bias is None else scores + bias.astype(jnp.float32)
+        if groups is not None:
+            picking = _in_best_groups(picking, groups)
+        _, topi = _top_k(picking, top_k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
     if renormalize:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + eps)
@@ -218,7 +238,8 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
 def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                    top_k: int, *, stacked=None, scoring: str = "softmax",
                    bias=None, renormalize: bool = True, scale: float = 1.0,
-                   eps: float = 1e-9, held=None, router_h=None) -> jnp.ndarray:
+                   eps: float = 1e-9, held=None, router_h=None,
+                   groups=None) -> jnp.ndarray:
     """Dropless per-token top-k MoE for the serving paths (``h`` [T, D]
     flat tokens): exact (no capacity, no drops, every pick computed), bf16
     operands with float32 accumulation in either form.
@@ -249,7 +270,8 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     by ``scores + bias`` (``bias`` [E]: the auxiliary-loss-free selection
     bias, which never enters a weight); the picked scores are divided by
     their sum (``renormalize``) and multiplied by ``scale``. The defaults
-    are Mixtral's.
+    are Mixtral's. ``groups = (n_group, topk_group)`` limits the picks to a
+    token's best groups of neighbouring experts (``_in_best_groups``).
 
     The expert: ``w_down(silu(x w_gate) * x w_up)``, or with ``w_gate`` None
     the ungated ``w_down(relu(x w_up)**2)`` of two matrices (``stacked``
@@ -267,7 +289,7 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     compute on (experts in a latent space, routed on the full hidden state).
     """
     topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
-                        scoring, bias, renormalize, scale, eps)
+                        scoring, bias, renormalize, scale, eps, groups)
     e = w_up.shape[0]
     routed = e if held is None else held[1]
     if expert_form(h.shape[0], routed, top_k) == "dense":
@@ -298,12 +320,12 @@ def expert_stacks(layers: dict):
 def routed_experts_einsum(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                           top_k: int, *, scoring: str = "softmax", bias=None,
                           renormalize: bool = True, scale: float = 1.0,
-                          eps: float = 1e-9, held=None,
-                          router_h=None) -> jnp.ndarray:
+                          eps: float = 1e-9, held=None, router_h=None,
+                          groups=None) -> jnp.ndarray:
     """``routed_experts`` in its einsum form at every row count: plain XLA,
     so it differentiates (``deepseek``'s training-shaped ``forward`` /
     ``loss_fn``) and partitions over a mesh (``mixtral``'s dense-cache
     inference layer under ``InferenceEngine``'s tensor-parallel mesh)."""
     topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
-                        scoring, bias, renormalize, scale, eps)
+                        scoring, bias, renormalize, scale, eps, groups)
     return _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)

@@ -262,16 +262,41 @@ def latent_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
                                   lat, scale, impl=impl)
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def apply_rope(q, k, positions, theta: float = 10000.0):
+def rope_frequencies(half: int, theta: float, yarn=None):
+    """The ``half`` rotation frequencies of a ``2 * half``-lane head:
+    ``theta ** (-i / half)``. ``yarn = (factor, beta_fast, beta_slow,
+    original_max)`` stretches them as YaRN does: a lane that turns fewer than
+    ``beta_slow`` times over the ``original_max`` positions the model was
+    trained on is slowed by ``factor`` (interpolated), one that turns more
+    than ``beta_fast`` times is left alone, and the lanes between the two
+    ramp linearly from one to the other."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return freqs
+    factor, beta_fast, beta_slow, original_max = yarn
+
+    def lane_of(turns):  # the (fractional) lane that turns ``turns`` times
+        return (half * math.log(original_max / (turns * 2 * math.pi))
+                / math.log(theta))
+
+    low = max(math.floor(lane_of(beta_fast)), 0)
+    high = min(math.ceil(lane_of(beta_slow)), half - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def apply_rope(q, k, positions, theta: float = 10000.0, yarn=None):
     """Rotary position embedding (reference: ``apply_rotary_pos_emb`` kernels,
     ``csrc/transformer/inference/csrc/apply_rotary_pos_emb.cu``).
 
-    q/k: [B, S, H, D]; positions: [B, S] absolute positions.
+    q/k: [B, S, H, D]; positions: [B, S] absolute positions; ``yarn``:
+    ``rope_frequencies``' stretch (a tuple: static).
     """
     d = q.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = rope_frequencies(half, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :]  # [B, S, 1, half]
     sin = jnp.sin(angles)[:, :, None, :]

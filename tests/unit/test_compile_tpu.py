@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
+from deepspeed_tpu.ops.pallas import dsa_attention as dsa
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.mla_attention import (
     mla_decode_attention,
@@ -85,6 +86,52 @@ def _mla_decode(q, pool, slots, pos, bt):
 def _mla_prefill(q, pool, ts, tp, tv, bt):
     return mla_prefill_attention(q, pool, ts, tp, tv, bt, TILE, MLA_LAT,
                                  192 ** -0.5, interpret=False)
+
+
+# DeepSeek-V3.2-Exp's sparse attention at the longctx-pool cell's shapes: 16
+# decode rows beside 3 tiles, 128 heads over 640-lane rows, a 64 x 128
+# indexer keeping 2,048 rows, 128-token blocks behind a table of 64
+DSA_ROWS, DSA_TILES, DSA_TABLE, DSA_KEEP = 16, 3, 64, 2048
+DSA_HEADS, DSA_INDEX_HEADS, DSA_INDEX_DIM = 128, 64, 128
+
+
+def _dsa_index(q, w, pool, slots, pos, bt, ts, tp, tv):
+    return dsa.dsa_index_scores(q, w, pool, slots, pos, bt,
+                                (DSA_ROWS, ts, tp, tv, TILE), interpret=False)
+
+
+def _dsa_decode(q, rows, n):
+    return dsa.dsa_decode_attention(q, rows, n, MLA_LAT, 192 ** -0.5,
+                                    interpret=False)
+
+
+def _dsa_prefill(q, pool, bias, ts, tp, tv, bt):
+    return dsa.dsa_prefill_attention(q, pool, bias, ts, tp, tv, bt, TILE,
+                                     MLA_LAT, 192 ** -0.5, interpret=False)
+
+
+def _dsa_args(kernel, devices):
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    t = DSA_ROWS + DSA_TILES * TILE
+    tiles = s((DSA_TILES,), jnp.int32)
+    bt = s((DSA_ROWS + 1, DSA_TABLE), jnp.int32)
+    if kernel is _dsa_index:
+        rows = s((t,), jnp.int32)
+        return (s((t, DSA_INDEX_HEADS, DSA_INDEX_DIM)),
+                s((t, DSA_INDEX_HEADS), jnp.float32),
+                s((64, MLA_BLOCK, DSA_INDEX_DIM)), rows, rows, bt,
+                tiles, tiles, tiles)
+    if kernel is _dsa_decode:
+        return (s((DSA_ROWS, DSA_HEADS, MLA_WIDTH)),
+                s((DSA_ROWS, DSA_KEEP, MLA_WIDTH)), s((DSA_ROWS,), jnp.int32))
+    return (s((DSA_TILES * TILE, DSA_HEADS, MLA_WIDTH)),
+            s((64, MLA_BLOCK, MLA_WIDTH)),
+            s((DSA_TILES * TILE, DSA_TABLE * MLA_BLOCK), jnp.float32),
+            tiles, tiles, tiles, bt)
 
 
 # the grouped expert FFN at the two MoE cells' widths, in the shapes of a
@@ -190,6 +237,21 @@ def test_mla_kernel_compiles_for_v5e(v5e, kernel):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+@pytest.mark.parametrize("kernel,calls", [(_dsa_index, 2), (_dsa_decode, 1),
+                                          (_dsa_prefill, 1)],
+                         ids=["dsa_index", "dsa_attn_decode",
+                              "dsa_attn_prefill"])
+def test_dsa_kernel_compiles_for_v5e(v5e, kernel, calls):
+    """At DeepSeek-V3.2-Exp's widths and the longctx-pool cell's shapes: the
+    indexer's two bodies (a decode row's 64 heads in one product, a tile's
+    head-major products), a decode row's 2,048 gathered rows of 640 lanes in
+    one block (2.6 MB, double-buffered), the 8-query sub-tile of 128 heads
+    with its bias rows."""
+    compiled = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize("geometry", sorted(MOE_GEOMETRIES))
 def test_moe_gmm_compiles_for_v5e(v5e, geometry):
     """At the published widths: the hand-written
@@ -239,10 +301,13 @@ def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e):
     (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
     (_prefill, "tiled_prefill"), (_mla_decode, "mla_decode"),
     (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm"),
-    ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode")],
+    ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode"),
+    (_dsa_index, "dsa_index"), (_dsa_decode, "dsa_attn_decode"),
+    (_dsa_prefill, "dsa_attn_prefill")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
          "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm",
-         "moe_gmm_ungated", "ssm_decode"])
+         "moe_gmm_ungated", "ssm_decode", "dsa_index", "dsa_attn_decode",
+         "dsa_attn_prefill"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     """``pl.pallas_call(name=...)``: the compiled custom call is
     ``%<name>.N`` (``%transpose_jvp_<name>__.N`` under a bare ``jax.grad``),
@@ -269,6 +334,8 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     elif kernel == "ssm_decode":
         text = jax.jit(_ssm_decode).lower(
             *_ssm_args(v5e, rows=8)).compile().as_text()
+    elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill):
+        text = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile().as_text()
     else:
         text = jax.jit(kernel).lower(
             *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
@@ -812,3 +879,63 @@ def test_stage3_left_to_the_partitioner_is_what_the_ledger_showed(
     pieces = [s for _, s in _results(_loop_lines(text),
                                      "collective-permute-start")]
     assert (400, 1600) in pieces and (1600, 1600) in pieces
+
+
+def test_sparse_step_reads_the_kept_rows_only(v5e, monkeypatch):
+    """The paged contract with TWO block leaves and a selection
+    (``deepseek_v32`` at DeepSeek-V3.2-Exp's attention widths, a small FFN and
+    vocabulary): a mixed step (16 decode rows beside a tile) with a donated
+    pool scatters each layer's latent rows and index keys in place; the
+    decode rows' only read of the latent pool is a gather of 2,048 rows a row
+    (``bf16[16,2048,640]``: no array of rows x the table's 8,192 positions x
+    640 lanes exists); the index scores are ``[T, 8192]`` float32 summed over
+    the heads (no such array a head); every kernel goes by its name."""
+    import re
+
+    from deepspeed_tpu.models import deepseek_v32 as v32
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = v32.DeepseekV32Config(
+        vocab_size=512, hidden_size=2048, intermediate_size=256,
+        moe_intermediate_size=128, num_layers=3, num_heads=DSA_HEADS,
+        q_lora_rank=1536, num_experts=8, experts_held=4, top_k=2, n_group=4,
+        topk_group=2, rope_scaling={
+            "type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096})
+    blocks, rows, tiles = 1025, DSA_ROWS, 1
+    on_chip, i32, params, cache = _abstract_step(v5e, v32, cfg, blocks, MLA_BLOCK)
+    assert cache["kv"].shape == (3, blocks, MLA_BLOCK, MLA_WIDTH)
+    assert cache["idx"].shape == (3, blocks, MLA_BLOCK, DSA_INDEX_DIM)
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return v32.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    t = rows + tiles * TILE
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(rows + 1, DSA_TABLE), i32(1), i32(1), i32(1)).compile().as_text()
+    arrays = _materialized(text)
+    idx_slice = blocks * MLA_BLOCK * DSA_INDEX_DIM * 2
+    # both leaves, the dense layer's and the scan body's
+    assert len([ln for size, op, ln in arrays
+                if op == "scatter" and size >= idx_slice]) == 4
+    assert [ln for size, op, ln in arrays if size >= 5 * idx_slice  # "kv"'s
+            and op not in ("scatter", "kernel")] == []
+    shapes = {re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])", ln).group(1)
+              for _, _, ln in arrays}
+    width = DSA_TABLE * MLA_BLOCK
+    assert {f"bf16[{rows},{DSA_KEEP},{MLA_WIDTH}]",                # the gather
+            f"bf16[{rows * DSA_KEEP},{MLA_WIDTH}]"} & shapes
+    wide = [s for s in shapes
+            if s.endswith(f",{width},{MLA_WIDTH}]")                 # whole tables
+            or re.search(rf"\[\d+,({DSA_HEADS}|{DSA_INDEX_HEADS}),{width}\]",
+                         s)]                                        # [T, H, S]
+    assert wide == []
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    for kernel, calls in (("dsa_index", 4), ("dsa_attn_decode", 2),
+                          ("dsa_attn_prefill", 2)):
+        assert sum(kernel in n for n in names) == calls, (kernel, names)

@@ -312,12 +312,25 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "model.ssm_step_roofline_kv", "kernel.ssm_decode_share",
                          "kernel.ssm_decode_roofline",
                          "kernel.hybrid_paged_decode_roofline",
-                         "kernel.hybrid_tiled_prefill_roofline")}}
+                         "kernel.hybrid_tiled_prefill_roofline")},
+                     # PR 33: the readers of a selection read a slice of the
+                     # sparse cell (``sel_pairs`` / ``sel_kv_tokens`` in its
+                     # dispatch spans, the three ``dsa_*`` kernels)
+                     **{m: "v5e_deepseek_v32_longctx_spans" for m in (
+                         "sched.dsa_selected_share", "kernel.dsa_index_share",
+                         "kernel.dsa_index_roofline",
+                         "kernel.dsa_attn_prefill_share",
+                         "kernel.dsa_attn_prefill_roofline",
+                         "kernel.dsa_attn_decode_share",
+                         "kernel.dsa_attn_decode_roofline",
+                         "model.dsa_step_roofline_kv")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
                    "v5e_nemotron_reason_spans":
-                   "nemotron-3-super-120b-d11-ep4.reason-pool"}
+                   "nemotron-3-super-120b-d11-ep4.reason-pool",
+                   "v5e_deepseek_v32_longctx_spans":
+                   "deepseek-v32-exp-d5-ep16.longctx-pool"}
 
 
 def _new_readers():
@@ -328,7 +341,8 @@ def _new_readers():
         path = os.path.join(BENCH, "layer_metrics", m["name"] + ".py")
         with open(path) as f:
             text = f.read()
-        if not any(m in text for m in ("host_spans", "latent_spans", "ssm_spans")):
+        if not any(m in text for m in ("host_spans", "latent_spans", "ssm_spans",
+                                       "dsa_spans")):
             continue  # a reader from before the spans
         fixture = FIXTURE_OF_METRIC.get(m["name"])
         cell = (CELL_OF_FIXTURE[fixture] if fixture
