@@ -19,13 +19,21 @@ nothing to a score). At Moonlight's 512 + 64 that is 640: the device lays a
 (Mosaic: "slice shape along dimension 2 must be aligned to tiling (128)"), so
 the padding is the pool's own: one array, one DMA a block, one scatter a token.
 
-``mla_decode`` walks each decode row's OWN blocks: the grid is the rows, and
-inside a row the blocks ``0 .. pos // BS`` are copied HBM -> VMEM by hand,
-double-buffered, the first block of the next row already in flight while the
-last of this one is computed. A grid of rows x table width (the shape of
-``paged_attention.paged_decode_attention``) would spend a grid step on every
-table entry: at 128 rows x 32 entries that is 4,096 steps a layer for ~1,100
-blocks of real context.
+``mla_decode`` walks each decode row's OWN blocks, ``decode_step_blocks`` of
+them a step (four at Moonlight's 164 KB a block: the one rule of both decode
+kernels, ``paged_attention.py``). The rows' steps are laid end to end as
+``paged_decode`` lays them (``decode_steps``); the grid is the rows, and a
+step's blocks ``0 .. pos // BS`` are copied HBM -> VMEM by hand, all of a
+step's copies started together and those of the next ``_DECODE_AHEAD`` steps
+in flight while this one is computed, whichever rows they belong to. One
+online-softmax update a step: ``[H, W] x [W, k BS]``, one max / exp / sum,
+``[H, k BS] x [k BS, lat]``, one rescale. Block operands fetched by the
+pipeline, as ``paged_decode`` has them, measured 22-30% slower here (PERF.md
+section 6, PR 34): the pipeline keeps ONE step in flight (it has two buffers
+an operand and takes no third), and one 0.66 MB step does not cover a copy's
+latency; by hand it is two. A grid of rows x table width would spend a grid
+step on every table entry: at 128 rows x 32 entries 4,096 steps a layer for
+~1,100 blocks of real context.
 
 Inference-only (no VJP).
 """
@@ -42,6 +50,8 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
 from deepspeed_tpu.ops.pallas.paged_attention import (
     _VMEM_SCOPED_BYTES,
+    decode_step_blocks,
+    decode_steps,
     split_tiles,
 )
 
@@ -54,59 +64,92 @@ def _scores(q, blk):
                                preferred_element_type=jnp.float32)
 
 
-def _decode_kernel(slots_ref, pos_ref, bt_ref, q_ref, pool_ref, o_ref,
-                   buf, sem, parity, *, bs: int, lat: int, scale: float):
+# Steps whose copies are in flight beside the one being computed (so one
+# buffer more): with one ahead a copy's latency shows between steps, 367 us
+# a layer at the reason-pool cell's shape against 307 with two and 298 with
+# three (PERF.md section 6, PR 34). Three buffers of four 164 KB blocks are
+# 2 MB of the 16 MiB of scoped VMEM.
+_DECODE_AHEAD = 2
+
+
+def _decode_kernel(ends_ref, row_ref, chunk_ref, slots_ref, pos_ref, bt_ref,
+                   q_ref, pool_ref, o_ref, buf, sem, *, bs: int, nb: int,
+                   lat: int, scale: float):
     t = pl.program_id(0)
     n_rows = pl.num_programs(0)
+    n_buf = buf.shape[0]                                  # _DECODE_AHEAD + 1
+    ch = nb * bs
     pos = pos_ref[t]
-    n_blk = pos // bs + 1
 
-    def fetch(row, j, slot):
-        return pltpu.make_async_copy(
-            pool_ref.at[bt_ref[slots_ref[row], j]], buf.at[slot],
-            sem.at[slot])
+    # Nothing here is negative, so lax.div / lax.rem and not // and %: the
+    # flooring forms are a dozen scalar operations each, and the kernel is
+    # lowered again for every step program (half of its lowering time).
+    def copies(s, act: str):
+        """Start, or wait for, step ``s``'s copies into buffer ``s % n_buf``
+        (returned): the blocks of its row's context alone, so every block
+        moves once."""
+        row, c = row_ref[s], chunk_ref[s]
+        slot = jax.lax.rem(s, n_buf)
+
+        def one(i, _):
+            getattr(pltpu.make_async_copy(
+                pool_ref.at[bt_ref[slots_ref[row], c * nb + i]],
+                buf.at[slot, pl.ds(pl.multiple_of(i * bs, bs), bs)],
+                sem.at[slot, i]), act)()
+            return _
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(jax.lax.div(pos_ref[row], bs) + 1 - c * nb, nb),
+            one, 0)
+        return slot
+
+    def start(s, _=None):                                 # also a loop body
+        @pl.when(s < ends_ref[n_rows - 1])
+        def _in_range():
+            copies(s, "start")
+
+    def wait(s):
+        return buf[copies(s, "wait")]                     # [CH, W]
 
     @pl.when(t == 0)
     def _first():
-        parity[0] = 0
-        fetch(0, 0, 0).start()
+        jax.lax.fori_loop(0, _DECODE_AHEAD, start, None)
 
-    base = parity[0]
     q = q_ref[0]                                          # [H, W]
     h = q.shape[0]
+    last_chunk = jax.lax.div(pos, ch)
+    s_row = ends_ref[t] - last_chunk - 1                  # the row's step 0
 
-    def block(j, carry):
+    def chunk(c, carry, tail: bool = False):
         m_prev, l_prev, acc = carry
-        slot = (base + j) % 2
-
-        @pl.when(j + 1 < n_blk)
-        def _next_block():
-            fetch(t, j + 1, 1 - slot).start()
-
-        @pl.when(jnp.logical_and(j + 1 == n_blk, t + 1 < n_rows))
-        def _next_row():
-            fetch(t + 1, 0, 1 - slot).start()
-
-        fetch(t, j, slot).wait()
-        blk = buf[slot]                                   # [BS, W]
+        start(s_row + c + _DECODE_AHEAD)
+        blk = wait(s_row + c)
         s = _scores(q.astype(blk.dtype), blk) * scale
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(kpos <= pos, s, _NEG_INF)
+        v = blk[:, :lat]
+        if tail:
+            # Only a row's last chunk has keys past pos. There the buffer
+            # holds what the pool had, an earlier step's blocks or nothing
+            # yet: a masked score's p is 0, and 0 times a value that is not
+            # finite is not 0, so those values go too.
+            kpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (1, ch), 1)
+            s = jnp.where(kpos <= pos, s, _NEG_INF)
+            vpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
+            v = jnp.where(vpos <= pos, v, jnp.zeros((), v.dtype))
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                            # [H, BS]
+        p = jnp.exp(s - m_new)                            # [H, CH]
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jnp.dot(p.astype(blk.dtype), blk[:, :lat],
+        pv = jnp.dot(p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)  # [H, lat]
         return m_new, l_new, acc * corr + pv
 
     # position 0 is never masked, so the running maximum is real from the
-    # first block on and no row of p is all zeros
-    _, l, acc = jax.lax.fori_loop(
-        0, n_blk, block,
+    # first chunk on and no row of p is all zeros
+    carry = jax.lax.fori_loop(
+        0, last_chunk, chunk,
         (jnp.full((h, 1), _NEG_INF, jnp.float32),
          jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, lat), jnp.float32)))
-    parity[0] = (base + n_blk) % 2
+    _, l, acc = chunk(last_chunk, carry, tail=True)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
@@ -117,34 +160,50 @@ def mla_decode_attention(q, pool, slots, positions, block_tables, lat: int,
     [T, H, lat] (``P c``; the caller applies the
     value half of ``kv_b_proj``). Each row reads blocks ``0 .. pos // BS``
     of its sequence, once."""
+    return _mla_decode(
+        q, pool, slots.astype(jnp.int32), positions.astype(jnp.int32),
+        block_tables.astype(jnp.int32), lat=lat, scale=float(scale),
+        interpret=interpret_mode(interpret))
+
+
+# ONE jitted function: the step programs of one row count share its trace
+@functools.partial(jax.jit, static_argnames=("lat", "scale", "interpret"))
+def _mla_decode(q, pool, slots, positions, block_tables, *, lat: int,
+                scale: float, interpret: bool):
     t_tokens, h, width = q.shape
     _, bs, _ = pool.shape
+    nb = decode_step_blocks(bs, width, pool.dtype.itemsize, arrays=1)
+    ends, step_row, step_chunk = decode_steps(
+        positions, nb * bs, t_tokens * -(-block_tables.shape[1] // nb))
+
+    def _row_map(t, *prefetched):
+        return (t, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=6,
         grid=(t_tokens,),
         in_specs=[
-            pl.BlockSpec((1, h, width), lambda t, sl, po, bt: (t, 0, 0)),
+            pl.BlockSpec((1, h, width), _row_map),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, h, lat), lambda t, sl, po, bt: (t, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, lat), _row_map),
         scratch_shapes=[
-            pltpu.VMEM((2, bs, width), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((_DECODE_AHEAD + 1, nb * bs, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((_DECODE_AHEAD + 1, nb)),
         ],
     )
-    kernel = functools.partial(_decode_kernel, bs=bs, lat=lat, scale=scale)
+    kernel = functools.partial(_decode_kernel, bs=bs, nb=nb, lat=lat,
+                               scale=scale)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, h, lat), q.dtype),
         grid_spec=grid_spec,
-        # the next row's first block is in flight across grid steps
+        # the next rows' steps are in flight across grid steps
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret_mode(interpret),
+        interpret=interpret,
         name="mla_decode",
-    )(slots.astype(jnp.int32), positions.astype(jnp.int32),
-      block_tables.astype(jnp.int32), q, pool)
+    )(ends, step_row, step_chunk, slots, positions, block_tables, q, pool)
 
 
 # --------------------------------------------------------------- tiled prefill

@@ -61,23 +61,46 @@ def _split_heads(ref, hkv: int, d: int):
                       for g in range(hkv)])
 
 
-# Bytes of K (and as many of V) one grid step of the decode kernel takes, as
-# whole pool blocks: a step costs ~0.2 us beside its DMA and its matmuls,
-# which both go by the byte, so the blocks a step takes follow from their
-# size: four of GPT-2 XL's 32-token blocks (128 tokens), two of Mixtral's
+# Bytes of K (and as many of V) one step of a decode kernel takes, as whole
+# pool blocks: a step costs ~0.2 us beside its DMA and its matmuls, which
+# both go by the byte, so the blocks a step takes follow from their size:
+# four of GPT-2 XL's 32-token blocks (128 tokens), two of Mixtral's
 # 128-token blocks (256). Twice as much costs the chat shapes ~10% (a row's
 # last step is mostly masked), half as much the long-document ones 17%
-# (sweep on the chip, PERF.md section 6, PR 29). Eight blocks at most: each
-# is an operand of its own, with two buffers and a DMA in flight.
+# (sweep on the chip, PERF.md section 6, PR 29). A latent pool's ONE array is
+# a token's key and its value, so a step takes both shares from it: four of
+# Moonlight's 164 KB blocks (two cost 18% more, eight the same within 1%
+# with twice the masked tail: PERF.md section 6, PR 34). Eight blocks at
+# most: each is a copy of its own, with a buffer and a DMA in flight.
 DECODE_STEP_BYTES = 512 * 1024
 _DECODE_STEP_BLOCKS_MAX = 8
 
 
-def decode_step_blocks(bs: int, lanes: int, itemsize: int) -> int:
-    """Pool blocks one grid step of the decode kernel takes: the power of
-    two that ``DECODE_STEP_BYTES`` holds, 1 to 8."""
-    want = max(1, DECODE_STEP_BYTES // (bs * lanes * itemsize))
+def decode_step_blocks(bs: int, lanes: int, itemsize: int,
+                       arrays: int = 2) -> int:
+    """Pool blocks one step of a decode kernel takes from each of the
+    ``arrays`` a token's key and value are read from: the power of two that
+    its share of ``2 * DECODE_STEP_BYTES`` holds, 1 to 8."""
+    want = max(1, 2 * DECODE_STEP_BYTES // (arrays * bs * lanes * itemsize))
     return min(1 << (want.bit_length() - 1), _DECODE_STEP_BLOCKS_MAX)
+
+
+def decode_steps(positions, ch: int, n_steps: int):
+    """The decode rows' chunks of ``ch`` tokens laid end to end, ``pos // ch
+    + 1`` of them a row: ``(ends, step_row, step_chunk)``, step ``s`` works
+    on chunk ``step_chunk[s]`` of row ``step_row[s]``, row ``t``'s steps are
+    ``ends[t - 1] .. ends[t] - 1``, and the ``n_steps`` entries past
+    ``ends[-1]`` stay on the last row's last chunk. (Made again in every
+    layer of a step: 0.4 us of 20, sweep of PR 29.)"""
+    n_chunks = positions // ch + 1
+    ends = jnp.cumsum(n_chunks)
+    steps = jnp.arange(n_steps, dtype=jnp.int32)
+    step_row = jnp.minimum(
+        jnp.sum(steps[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        positions.shape[0] - 1)
+    step_chunk = jnp.minimum(steps - (ends - n_chunks)[step_row],
+                             n_chunks[step_row] - 1)
+    return ends, step_row, step_chunk
 
 
 def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
@@ -160,21 +183,12 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
     rep = hq // hkv
     nb = decode_step_blocks(bs, hd, k_pool.dtype.itemsize)
 
-    # The grid is the rows' chunks laid end to end, ``pos // CH + 1`` of them
-    # a row: step s works on chunk ``step_chunk[s]`` of row ``step_row[s]``.
-    # The pipeline reads the index maps one step ahead, so the vectors have
-    # one entry more than there can be steps, and entries past the last stay
-    # on the last row's last chunk, whose blocks are then in place already.
-    # (Made again in every layer of a step: 0.4 us of 20, sweep of PR 29.)
-    n_chunks = positions // (nb * bs) + 1
-    ends = jnp.cumsum(n_chunks)
-    steps = jnp.arange(t_tokens * -(-block_tables.shape[1] // nb) + 1,
-                       dtype=jnp.int32)
-    step_row = jnp.minimum(
-        jnp.sum(steps[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
-        t_tokens - 1)
-    step_chunk = jnp.minimum(steps - (ends - n_chunks)[step_row],
-                             n_chunks[step_row] - 1)
+    # The grid is the rows' chunks laid end to end (``decode_steps``). The
+    # pipeline reads the index maps one step ahead, so the vectors have one
+    # entry more than there can be steps; the entries past the last stay on
+    # the last row's last chunk, whose blocks are then in place already.
+    ends, step_row, step_chunk = decode_steps(
+        positions, nb * bs, t_tokens * -(-block_tables.shape[1] // nb) + 1)
 
     # The heads stay where the pool has them, side by side in a row's lanes:
     # query head (g, r) becomes row r*Hkv + g of a [Hq, Hkv*D] matrix that is

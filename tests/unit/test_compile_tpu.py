@@ -22,6 +22,7 @@ from deepspeed_tpu.ops.pallas.mla_attention import (
 )
 from deepspeed_tpu.ops.pallas.moe_gmm import ROW_ALIGN, grouped_swiglu
 from deepspeed_tpu.ops.pallas.paged_attention import (
+    decode_step_blocks,
     paged_decode_attention,
     prefill_kernel_tile,
     ragged_prefill_attention,
@@ -164,14 +165,14 @@ def _moe_args(geometry, devices):
             s((e,), jnp.int32), s((e,), jnp.int32))
 
 
-def _args(kernel, hq, hkv, d, devices):
+def _args(kernel, hq, hkv, d, devices, mla_block=MLA_BLOCK):
     dev = jax.sharding.SingleDeviceSharding(devices[0])
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
     if kernel in (_mla_decode, _mla_prefill):
-        pool = s((64, MLA_BLOCK, MLA_WIDTH))
+        pool = s((64, mla_block, MLA_WIDTH))
         bt = s((129, MLA_TABLE), jnp.int32)
         if kernel is _mla_decode:
             rows = s((128,), jnp.int32)
@@ -226,13 +227,19 @@ def test_decode_kernel_compiles_at_the_cells_shapes(v5e, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**21
 
 
-@pytest.mark.parametrize("kernel", [_mla_decode, _mla_prefill],
-                         ids=["mla_decode", "mla_prefill"])
-def test_mla_kernel_compiles_for_v5e(v5e, kernel):
+@pytest.mark.parametrize("kernel,block", [
+    (_mla_decode, MLA_BLOCK), (_mla_prefill, MLA_BLOCK), (_mla_decode, 16)],
+    ids=["mla_decode", "mla_prefill", "mla_decode_8_blocks_a_step"])
+def test_mla_kernel_compiles_for_v5e(v5e, kernel, block):
     """At Moonlight's widths and the reason-pool cell's shapes (128 decode
-    rows, 3 prefill tiles): the hand-written DMA of a 640-lane block, the
-    dynamic trip count, the 64-row sub-tile's scoped VMEM."""
-    compiled = jax.jit(kernel).lower(*_args(kernel, 0, 0, 0, v5e)).compile()
+    rows, 3 prefill tiles): the hand-written DMAs of a step's 640-lane
+    blocks (four of 128 tokens; eight, the most the rule gives, of 16), the
+    dynamic trip count, the 64-row sub-tile's scoped VMEM; and no gather of
+    the padded context beside the kernel."""
+    assert decode_step_blocks(block, MLA_WIDTH, 2, arrays=1) == (
+        4 if block == MLA_BLOCK else 8)
+    compiled = jax.jit(kernel).lower(
+        *_args(kernel, 0, 0, 0, v5e, mla_block=block)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 

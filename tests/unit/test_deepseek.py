@@ -213,6 +213,66 @@ def test_mla_kernels_equal_the_xla_gather(dtype, tol):
                                rtol=tol, atol=tol)
 
 
+# blocks a step -> block size: a float32 row of 256 lanes is 1 KiB, and a
+# step takes the power of two of blocks that 1 MiB holds
+_WALK_BLOCK = {1: 1024, 2: 512, 4: 256}
+_WALK_EDGES = ("0", "BS-1", "BS", "kBS-1", "kBS", "last")
+
+
+@pytest.mark.parametrize("case", [*_WALK_EDGES, "one_block_then_full", "nan"])
+@pytest.mark.parametrize("k", sorted(_WALK_BLOCK))
+def test_mla_decode_walks_its_steps(k, case):
+    """``mla_decode`` (interpret mode) against the XLA gather at 1, 2 and 4
+    blocks a step, a row at every edge of the walk among rows whose slots
+    repeat: the first token, a block's last and the next block's first, a
+    step's last and the next step's first, the table's last; a row of one
+    block before a row of the table's width (its first step is in flight
+    while the short row is computed). ``nan``: every pool block no table
+    names and every token past a row's position hold NaN, and nothing of
+    them reaches the output."""
+    from deepspeed_tpu.ops.pallas import mla_attention
+    from deepspeed_tpu.ops.pallas.paged_attention import decode_step_blocks
+
+    heads, lat, rope, width = 2, 128, 64, 256
+    bs = _WALK_BLOCK[k]
+    assert decode_step_blocks(bs, width, 4, arrays=1) == k
+    mb = 2 * k + 1
+    last = mb * bs - 1
+    rng = np.random.default_rng(34 + k)
+    lanes = np.arange(width) < lat + rope
+    n_blocks = 3 * mb + 3
+    pool = (rng.standard_normal((n_blocks, bs, width)) * lanes).astype(
+        np.float32)
+    tables = np.zeros((4, mb), np.int32)
+    tables[:3] = rng.permutation(np.arange(1, n_blocks))[:3 * mb].reshape(3, mb)
+    if case == "one_block_then_full":
+        slots, pos = [2, 0, 1, 0], [last, 5, last, bs + 1]
+    elif case == "nan":
+        slots = [0, 1, 0, 3, 2, 1]
+        pos = [k * bs - 1, last, 0, 0, bs, k * bs]
+    else:
+        edge = {"0": 0, "BS-1": bs - 1, "BS": bs, "kBS-1": k * bs - 1,
+                "kBS": k * bs, "last": last}[case]
+        slots, pos = [0, 1, 0, 3, 1], [bs // 2, edge, last, 0, edge]
+    q = (rng.standard_normal((len(slots), heads, width)) * lanes).astype(
+        np.float32)
+    args = (jnp.asarray(slots, jnp.int32), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(tables))
+    want = attention.latent_paged_attention(
+        jnp.asarray(q), jnp.asarray(pool), *args, lat, 0.07, impl="xla")
+    if case == "nan":
+        read = np.zeros((n_blocks, bs), bool)
+        for slot, p in zip(slots, pos):
+            tok = np.arange(p + 1)
+            read[tables[slot, tok // bs], tok % bs] = True
+        pool = np.where(read[:, :, None], pool, np.nan)
+    got = mla_attention.mla_decode_attention(
+        jnp.asarray(q), jnp.asarray(pool), *args, lat, 0.07, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_ragged_step_with_the_kernels_equals_the_gather(params, monkeypatch):
     """A prefill step of two sequences and a mixed step through
     ``ragged_forward`` with both kernels where the chip runs them
