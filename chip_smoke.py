@@ -135,7 +135,8 @@ def _memory(device=None) -> dict:
 
 class _CompileMeter:
     """Deltas of what telemetry/compile_watch.py counts: seconds spent in
-    trace/lower/compile, backend compiles, persistent-cache hits and misses.
+    trace/lower/backend-compile (retrievals from the persistent cache
+    included), program builds, persistent-cache hits and misses.
     Turns telemetry on, as a deployment does: the counters and the
     ``/metrics`` page only exist with it."""
 

@@ -51,28 +51,12 @@ from deepspeed_tpu.telemetry import get_telemetry
 from deepspeed_tpu.telemetry.memledger import is_resource_exhausted, record_oom
 from deepspeed_tpu.telemetry.tracing import format_traceparent
 from deepspeed_tpu.utils.logging import log_dist
-from deepspeed_tpu.utils.tracing import span
+from deepspeed_tpu.utils.tracing import phase, span
 
 # With arrivals queued but UNADMITTABLE (a free slot exists yet the KV pool
 # cannot cover the reservation) a multi-step scheduler dispatch still runs up
 # to this many decode steps: decode progress is exactly what frees blocks.
 SCHED_ADMISSION_CAP = 8
-
-
-_CACHE_MISSES = [0]  # this process's persistent-compilation-cache misses
-
-
-def _persistent_cache_miss_counter() -> Callable[[], int]:
-    """Count jax's ``/jax/compilation_cache/cache_misses`` events from now
-    on (one listener a process, however many engines ask)."""
-    if len(_CACHE_MISSES) == 1:
-        def on_event(event: str, **_) -> None:
-            if event == "/jax/compilation_cache/cache_misses":
-                _CACHE_MISSES[0] += 1
-
-        jax.monitoring.register_event_listener(on_event)
-        _CACHE_MISSES.append(on_event)
-    return lambda: _CACHE_MISSES[0]
 
 
 def _kept_pairs(pos0: int, take: int, topk: int) -> int:
@@ -634,6 +618,13 @@ class RaggedInferenceEngine:
     def __init__(self, model, ragged_config: RaggedConfig | None = None,
                  dtype=jnp.bfloat16, params: Any = None, seed: int = 0,
                  eos_token_id: int | None = None, quantize_bits: int = 0):
+        # the pool, the slot leaves, the device state: once a process
+        with phase("engine/init"):
+            self._init(model, ragged_config, dtype, params, seed,
+                       eos_token_id, quantize_bits)
+
+    def _init(self, model, ragged_config, dtype, params, seed, eos_token_id,
+              quantize_bits):
         self.cfg = ragged_config or RaggedConfig()
         self.ctx = ShardCtx()
         self.spec: ModelSpec = model(self.ctx) if callable(model) else model
@@ -3084,20 +3075,24 @@ class RaggedInferenceEngine:
         the compiler refuses raises here, before any request is taken. The
         SplitFuse step programs are NOT compiled here (a server runs each
         once at set-up; they reach later processes through the cache). What
-        is set here is the cache's miss counter: if the FIRST step program a
-        server then runs misses the cache, none of them is in it, and the
+        is set here is the cold-cache probe's counter, the compile watch's
+        (``telemetry/compile_watch.py``: builds whose executable jax wrote to
+        the cache): if the FIRST step program a server then runs misses the
+        cache and is written to it, none of them was in it, and the
         engine compiles the rest in the background while the server goes on
         (``_precompile_zoo_in_background``); a warm cache sees no change.
         Returns the number of programs compiled, 0 without ``sched_steps``.
         Greedy combos by default; call again with ``sampled``/filter flags
         for sampling workloads."""
+        from deepspeed_tpu.telemetry.compile_watch import WATCH
         from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-        if enable_compile_cache() and self._cache_misses is None:
-            self._cache_misses = _persistent_cache_miss_counter()
         n = 0
-        if self.cfg.sched_steps >= 2 and self.cfg.device_state:
-            n += self._warmup_sched(sampled, has_tk, has_tp)
+        with phase("engine/warmup", sched_steps=self.cfg.sched_steps):
+            if enable_compile_cache() and self._cache_misses is None:
+                self._cache_misses = WATCH.cache_writes
+            if self.cfg.sched_steps >= 2 and self.cfg.device_state:
+                n += self._warmup_sched(sampled, has_tk, has_tp)
         # warmup's own program-cache fills are not serve-time misses: reset
         # the dispatch baseline so warmup_coverage reflects live traffic only
         self._warmed = True
@@ -3739,9 +3734,6 @@ class RaggedInferenceEngine:
               "program (1.0 = no serve-time compiles since warmup)").set(
                   1.0 - self.program_cold_dispatches
                   / self.program_dispatches)
-        tel.note_program_cache_size(
-            len(self._tiled_jits) + len(self._dev_step_jits)
-            + len(self._dev_sched_jits) + len(self._step_keys))
         if self.cfg.enable_prefix_cache:
             alloc = self.allocator
             bb = self._block_bytes()

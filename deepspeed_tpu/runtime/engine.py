@@ -50,7 +50,7 @@ from deepspeed_tpu.runtime.lr_schedules import LRScheduler, build_schedule
 from deepspeed_tpu.runtime.precision import LossScaleState
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import ThroughputTimer
-from deepspeed_tpu.utils.tracing import StepTracer, span
+from deepspeed_tpu.utils.tracing import StepTracer, phase, span
 from deepspeed_tpu.utils.compat import shard_map_compat
 
 REMAT_POLICIES = {
@@ -3501,14 +3501,16 @@ def initialize(
         topo = dist.init_distributed(cfg.mesh, devices=mesh_devices)
     cfg.resolve_batch_sizes(topo.dp_world_size)
     dist.configure(cfg.comms_logger)
-    if cfg.pipeline.stages > 1:
-        # the staged MPMD runtime: per-stage programs + schedule executor
-        # (stages in (0, 1) keep the single fused program — bit-identical)
-        from deepspeed_tpu.runtime.pipe.engine import PipeEngine
+    # state placement and sharding: once a process
+    with phase("train/init", zero_stage=cfg.zero_optimization.stage):
+        if cfg.pipeline.stages > 1:
+            # the staged MPMD runtime: per-stage programs + schedule executor
+            # (stages in (0, 1) keep the single fused program — bit-identical)
+            from deepspeed_tpu.runtime.pipe.engine import PipeEngine
 
-        engine = PipeEngine(model, cfg, topo, training_data=training_data,
+            engine = PipeEngine(model, cfg, topo, training_data=training_data,
+                                seed=seed, initial_params=initial_params)
+        else:
+            engine = Engine(model, cfg, topo, training_data=training_data,
                             seed=seed, initial_params=initial_params)
-    else:
-        engine = Engine(model, cfg, topo, training_data=training_data,
-                        seed=seed, initial_params=initial_params)
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler

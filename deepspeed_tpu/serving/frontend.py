@@ -481,12 +481,15 @@ def build_server(engines, host: str = "127.0.0.1", port: int = 0,
     the loops and listener cold (tests use this for determinism).
     """
     from deepspeed_tpu.serving.engine_loop import EngineLoop
+    from deepspeed_tpu.utils.tracing import phase
 
-    loops = [EngineLoop(e, name=f"replica-{i}") for i, e in enumerate(engines)]
-    router = ReplicaRouter(loops, router_cfg)
-    frontend = ServingFrontend(router, host=host, port=port)
-    if start:
-        for lp in loops:
-            lp.start()
-        frontend.start()
+    with phase("server/build", replicas=len(engines)):
+        loops = [EngineLoop(e, name=f"replica-{i}")
+                 for i, e in enumerate(engines)]
+        router = ReplicaRouter(loops, router_cfg)
+        frontend = ServingFrontend(router, host=host, port=port)
+        if start:
+            for lp in loops:
+                lp.start()
+            frontend.start()
     return frontend, router, loops

@@ -18,6 +18,7 @@ import threading
 import time
 from contextlib import contextmanager
 
+from deepspeed_tpu.telemetry.compile_watch import WATCH
 from deepspeed_tpu.telemetry.registry import (
     Counter,
     Gauge,
@@ -173,9 +174,7 @@ class Telemetry:
                     fairness_weight=float(cm.get("fairness_weight", 1.0)),
                 )
             if opts.get("compile_metrics", True):
-                from deepspeed_tpu.telemetry.compile_watch import CompileWatch
-
-                self._compile_watch = CompileWatch(self.registry).install()
+                self._compile_watch = WATCH.attach(self.registry)
             fleet = opts.get("fleet") or {}
             if fleet is True:
                 fleet = {"enabled": True}
@@ -369,20 +368,18 @@ class Telemetry:
     # ------------------------------------------------------------- compile
     @property
     def compile_watch(self):
-        """The installed :class:`CompileWatch`, or None."""
+        """The process's :class:`CompileWatch` while it feeds this bus's
+        registry (``compile_metrics``), else None."""
         return self._compile_watch
-
-    def note_program_cache_size(self, n_programs: int) -> None:
-        """Feed the compile watch's cache-size-delta fallback (no-op when
-        jax.monitoring listeners are active)."""
-        cw = self._compile_watch
-        if cw is not None:
-            cw.note_cache_size(n_programs)
 
     # ------------------------------------------------------------- snapshot
     def snapshot(self) -> dict:
-        """The full registry as plain data (JSON-serializable)."""
-        return {"ts": time.time(), "metrics": self.registry.snapshot()}
+        """The full registry as plain data (JSON-serializable), and under
+        ``startup`` the compile watch's start-up phases and program builds
+        (None while its listeners are not installed; it listens whether or
+        not this bus is enabled)."""
+        return {"ts": time.time(), "metrics": self.registry.snapshot(),
+                "startup": WATCH.snapshot()}
 
     def dump(self, path: str) -> dict:
         """Persist ``snapshot()`` as a JSON file; returns the snapshot."""
@@ -415,11 +412,13 @@ class Telemetry:
         self.enabled = False
 
     def reset(self) -> None:
-        """Back to the pristine disabled state (test isolation)."""
+        """Back to the pristine disabled state (test isolation): the compile
+        watch's logs empty too, its listeners stay."""
         with self._lock:
             self._teardown_locked()
         self.enabled = False
         self.registry.reset()
+        WATCH.reset()
 
     def _teardown_locked(self) -> None:
         for sink in self._sinks:
@@ -447,10 +446,8 @@ class Telemetry:
         self._slo = None
         self._costmeter = None
         if self._compile_watch is not None:
-            try:
-                self._compile_watch.uninstall()
-            except Exception:
-                pass
+            # the listeners and the logs are the process's and stay
+            self._compile_watch.detach()
             self._compile_watch = None
 
 

@@ -10,6 +10,9 @@ the engine's ``tracing`` config (viewable in TensorBoard/XProf/Perfetto).
 
 from __future__ import annotations
 
+import contextlib
+import time
+
 import jax
 
 
@@ -20,6 +23,27 @@ def span(name: str, **args):
     with none the annotation is built and dropped. ``args`` become the
     event's arguments in the trace."""
     return jax.profiler.TraceAnnotation(name, **args)
+
+
+@contextlib.contextmanager
+def phase(name: str, **args):
+    """A :func:`span` that is ALSO put down in the compile watch's start-up
+    log (``telemetry.snapshot()["startup"]["phases"]``: name, thread, begin
+    and end on ``time.perf_counter()``, ``args``) whether or not a profiler
+    session is open. For work a process does ONCE (an engine's construction,
+    its warm-up, a server's assembly): it takes a lock and keeps a record, so
+    no step path calls it. The program builds that fall inside it are in the
+    same log (``telemetry/compile_watch.py``) and are told from the rest of
+    the phase by their times."""
+    from deepspeed_tpu.telemetry.compile_watch import WATCH
+
+    WATCH.install()
+    t0 = time.perf_counter()
+    try:
+        with span(name, **args):
+            yield
+    finally:
+        WATCH.note_phase(name, t0, time.perf_counter(), args)
 
 
 def instant(name: str, **args) -> None:

@@ -495,11 +495,32 @@ class TestColdCachePrecompile:
             (4, 0, 1, w), (8, 0, 2, w), (16, 0, 4, w),           # no decoders
             (8, 4, 1, w), (12, 4, 2, w), (16, 4, 3, w), (4, 4, 0, w)]
 
+    def test_warmup_hands_the_probe_the_compile_watchs_counter(
+            self, monkeypatch, tmp_path):
+        """With a cache directory the probe reads the process's ONE compile
+        watch (builds whose executable jax wrote to the cache); without one
+        there is no probe. The engine registers no listener of its own."""
+        from jax._src import monitoring
+
+        from deepspeed_tpu.telemetry.compile_watch import WATCH
+
+        eng = _engine_ds(True, prefill_tile=4, max_tokens_per_step=16,
+                         max_seqs=4)
+        eng.warmup()
+        assert eng._cache_misses is None
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        eng.warmup()
+        assert eng._cache_misses == WATCH.cache_writes
+        assert eng._cache_misses() == WATCH.snapshot()["cache_writes"]
+        assert [cb for cb in monitoring._event_listeners
+                if getattr(cb, "__self__", None) is not WATCH] == []
+
     @pytest.mark.parametrize("first_miss", [1, 2, None],
                              ids=["cold", "thinned", "warm"])
     def test_the_first_programs_miss_starts_the_others(self, first_miss):
-        """The counter ``warmup`` installs is read before and after the FIRST
-        step program's first call. It rises there (a cold cache: the others
+        """The counter ``warmup`` installs (the compile watch's
+        ``cache_writes``) is read before and after the FIRST step program's
+        first call. It rises there (a cold cache: the others
         compile in the background), at a later program (a warm cache its size
         limit has thinned: no background compile of what is mostly there) or
         never."""

@@ -276,15 +276,12 @@ class TestEngineTracing:
         assert 0.0 < cov < 1.0
 
     def test_backend_compile_counter_on_cpu(self):
-        """jax.monitoring's backend-compile event fires on every real XLA
-        compile, so building + serving a fresh engine must increment
+        """jax.monitoring's backend-compile event fires on every program
+        build, so building + serving a fresh engine must increment
         ``jit_cache_misses_total{source="monitoring"}``."""
-        telemetry.configure(enabled=True)  # installs CompileWatch
+        telemetry.configure(enabled=True)  # attaches the compile watch
         tel = telemetry.get_telemetry()
-        cw = tel.compile_watch
-        assert cw is not None
-        if cw.fallback:  # pragma: no cover - jax without monitoring hooks
-            pytest.skip("jax.monitoring unavailable; fallback covered below")
+        assert tel.compile_watch is not None
         before = tel.registry.counter(
             "jit_cache_misses_total").value(source="monitoring")
         eng = _engine()
@@ -295,19 +292,6 @@ class TestEngineTracing:
         assert after > before
         # the series renders at scrape time even when it is still zero
         assert "jit_cache_misses_total" in tel.registry.render_prometheus()
-
-    def test_cache_size_delta_fallback(self):
-        from deepspeed_tpu.telemetry.compile_watch import CompileWatch
-
-        reg = telemetry.get_telemetry().registry
-        cw = CompileWatch(reg)
-        cw.fallback = True  # simulate a jax without monitoring hooks
-        cw.note_cache_size(3)
-        cw.note_cache_size(5)   # +2 programs -> 2 misses
-        cw.note_cache_size(5)   # no delta
-        cw.note_cache_size(4)   # shrink is not a miss
-        assert reg.counter("jit_cache_misses_total").value(
-            source="cache_size_delta") == 2
 
 
 # ------------------------------------------------------------------- SLO
