@@ -2672,8 +2672,9 @@ class RaggedInferenceEngine:
         ``sel_pairs``, the query x kept-row pairs (``min(position + 1,
         index_topk)`` a query), ``sel_kv_tokens``, the fewest cached rows
         any implementation must read (``min(context, index_topk)`` a decode
-        row and a prefill tile), and ``dec_sel_kv_tokens``, the decode rows'
-        part of that."""
+        row and a prefill tile), ``dec_sel_kv_tokens``, the decode rows'
+        part of that, and ``sel_decode``, the form the program's decode rows
+        read the pool in (``_sel_decode_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
@@ -2687,8 +2688,9 @@ class RaggedInferenceEngine:
         with span("engine/stage"):
             self._sync_bt()
             staged = self._stage(np.concatenate(parts))
-        fn = self._get_dev_step(t_total, nd, nt, self._table_width(max_pos),
-                                sampled, has_tk, has_tp)
+        width = self._table_width(max_pos)
+        fn = self._get_dev_step(t_total, nd, nt, width, sampled, has_tk,
+                                has_tp)
         # the FIRST step program an engine builds tells whether the persistent
         # cache is cold (``warmup`` counts its misses). Only the first: a warm
         # cache that misses a program or two later (its size limit thins it)
@@ -2704,7 +2706,8 @@ class RaggedInferenceEngine:
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total),
-                  **self._state_attr(n_dec, n_pre, n), **sel):
+                  **self._state_attr(n_dec, n_pre, n), **sel,
+                  **self._sel_decode_attr(width)):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._bt_dev,
                 staged, self._sample_root)
@@ -2740,6 +2743,14 @@ class RaggedInferenceEngine:
         model itself calls; nothing for a family without routed experts."""
         form = self.spec.moe_form
         return {} if form is None else {"moe": form(t)}
+
+    def _sel_decode_attr(self, width: int) -> dict:
+        """``{"sel_decode": "walk" | "gather"}`` for a family that selects:
+        how the decode rows of a step program whose block table is ``width``
+        blocks wide read the pool, from the rule the model itself calls."""
+        form = self.spec.sparse_decode_form
+        return {} if form is None else {
+            "sel_decode": form(width * self.cfg.block_size)}
 
     def _state_attr(self, n_dec: int, n_pre: int, n: int) -> dict:
         """For a model with slot state, what of it a step moves:

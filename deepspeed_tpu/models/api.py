@@ -378,6 +378,11 @@ class ModelSpec:
     # selected work beside the context (``sel_pairs``, ``sel_kv_tokens`` on
     # ``engine/dispatch``) and refuses what cannot carry the selection
     index_topk: int | None = None
+    # sparse_decode_form(table_tokens) -> "walk" | "gather": how that family's
+    # decode rows read the pool in a step program whose block table is
+    # ``table_tokens`` wide, from the rule its ragged_forward_fn calls
+    # (``sel_decode`` on ``engine/dispatch``); None without ``index_topk``
+    sparse_decode_form: Callable[[int], str] | None = None
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

@@ -22,10 +22,15 @@ table (``models/paged.py``): the latent row ``"kv"`` as ``deepseek`` has it and
 the index key ``"idx"`` (``DI`` lanes). A step scatters both, scores its
 queries against every cached index key of their own sequences
 (``ops/pallas/dsa_attention.dsa_index_scores``), selects, and attends over the
-kept rows alone: a decode row over a gather of its ``min(context, index_topk)``
-rows (``dsa_decode_attention``), a prefill tile through ``dsa_prefill_attention``
-with the selection as a bias. Off the chip the same steps run as plain XLA
-(the ``*_xla`` forms below, which the kernels' tests compare with).
+kept rows alone: a prefill tile through ``dsa_prefill_attention`` with the
+selection as a bias; a decode row in one of two forms, by the width of the step
+program's block table (``decode_form``): up to ``WALK_MAX_TABLE_TOKENS`` it
+walks its own blocks with the selection as a mask on the scores
+(``mla_attention.mla_decode_attention(keep=...)``, the walk ``deepseek``'s
+decode rows take, reading the whole context at the pool's bandwidth); past that
+it attends over a gather of its ``min(context, index_topk)`` kept rows
+(``_gather_kept`` + ``dsa_decode_attention``). Off the chip the same steps run
+as plain XLA (the ``*_xla`` forms below, which the kernels' tests compare with).
 
 Departures from the published model (each also under ``assumed`` in the
 benchmark's configuration): the published indexer rotates ``qI`` and ``kI`` by a
@@ -51,10 +56,30 @@ from deepspeed_tpu.models.deepseek import DeepseekConfig, _rope
 from deepspeed_tpu.models.experts import expert_form
 
 # a step program beside each tile count for 0 and for max_seqs decode rows:
-# a padding decode row costs this model a gather of one row
+# a padding decode row costs this model a walk of one block
 DECODE_BUCKET_MIN = 16
 _INT_MIN = -2 ** 31
 _NEG_INF = -1e30
+# The widest block table, in tokens, under which a decode row WALKS its whole
+# context under the selection's mask; past it the kept rows are gathered. The
+# walk reads every cached row at the pool's bandwidth (41 us a 1K tokens of 16
+# rows' context); the gather reads index_topk rows a row at XLA's ~40 GB/s
+# behind an index that is a compare-and-count over [rows, index_topk, table],
+# so it grows with the table too. One layer on the chip, 16 rows of 128 heads,
+# walk / gather us at tables of 4K, 8K, 16K, 32K tokens three quarters full:
+# 200 / 835, 268 / 929, 524 / 1,130, 984 / 1,614; full: 346 / 929 at 8K, 660
+# / 1,131 at 16K, 1,312 / 1,619 at 32K (PERF.md section 6, PR 37). The walk
+# won at every width timed, so this is the widest one timed; the two lines
+# would meet near 64K.
+WALK_MAX_TABLE_TOKENS = 32768
+
+
+def decode_form(table_tokens: int) -> str:
+    """``"walk"`` | ``"gather"``: how the decode rows of a step program whose
+    block table is ``table_tokens`` wide read the latent pool (module doc).
+    The table's width is static in a step program, so the rule costs a step
+    nothing; the engine writes its answer on ``engine/dispatch``."""
+    return "walk" if table_tokens <= WALK_MAX_TABLE_TOKENS else "gather"
 
 
 @dataclass(frozen=True)
@@ -263,6 +288,7 @@ def sparse_pool_attention(cfg: DeepseekV32Config, q, q_idx, w_idx, pool_kv,
     step's rows in them -> [T, H, lat]."""
     from deepspeed_tpu.ops.attention import _on_tpu
     from deepspeed_tpu.ops.pallas import dsa_attention as dsa
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_decode_attention
 
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
@@ -276,7 +302,16 @@ def sparse_pool_attention(cfg: DeepseekV32Config, q, q_idx, w_idx, pool_kv,
     mask = select_mask(scores, positions, k)
     n_dec = q.shape[0] if prefill_tiles is None else prefill_tiles[0]
     parts = []
-    if n_dec:
+    if n_dec and decode_form(mask.shape[1]) == "walk":
+        if impl == "pallas":
+            parts.append(mla_decode_attention(
+                q[:n_dec], pool_kv, slots[:n_dec], positions[:n_dec],
+                block_tables, lat, scale, keep=mask[:n_dec]))
+        else:
+            parts.append(prefill_attention_xla(
+                q[:n_dec], pool_kv, jnp.where(mask[:n_dec], 0.0, _NEG_INF),
+                slots[:n_dec], block_tables, lat, scale))
+    elif n_dec:
         rows, n_kept = _gather_kept(mask[:n_dec], min(k, mask.shape[1]),
                                     pool_kv, slots[:n_dec], block_tables)
         attend = (dsa.dsa_decode_attention if impl == "pallas"
@@ -403,4 +438,5 @@ def build(cfg: DeepseekV32Config, ctx: ShardCtx | None = None,
                          top_k=cfg.top_k),
         decode_bucket_min=DECODE_BUCKET_MIN,
         index_topk=cfg.index_topk,
+        sparse_decode_form=decode_form,
     )

@@ -35,7 +35,10 @@ would read ``CT`` times the rows.
 (``K = index_topk``; an XLA gather of ``min(context, K)`` rows a decode row,
 the only rows of the latent pool a decode row reads): all ``H`` heads share
 the gathered rows, so one row's work is ``[H, W] x [W, K]`` and ``[H, K] x
-[K, lat]`` on 2.6 MB, at the chip's ridge.
+[K, lat]`` on 2.6 MB, at the chip's ridge. It is the decode rows' form past
+``deepseek_v32.WALK_MAX_TABLE_TOKENS`` of table only; under it they walk their
+own blocks under the selection's mask (``mla_attention.mla_decode_attention``
+with ``keep``, which then goes by this kernel's name, ``dsa_attn_decode``).
 
 Inference-only (no VJP).
 """

@@ -35,6 +35,13 @@ latency; by hand it is two. A grid of rows x table width would spend a grid
 step on every table entry: at 128 rows x 32 entries 4,096 steps a layer for
 ~1,100 blocks of real context.
 
+A family that selects the rows a query attends over (``deepseek_v32``) hands
+the same walk its decode rows' selection, ``keep`` [T, S]: one row of it is
+resident a grid step, a step's lanes one sublane of it, and a dropped
+position's score goes where a position past ``pos`` goes. Nothing else
+differs, the name apart (``dsa_attn_decode``); without ``keep`` the call
+traces to the program it was before there was one.
+
 Inference-only (no VJP).
 """
 
@@ -73,8 +80,11 @@ _DECODE_AHEAD = 2
 
 
 def _decode_kernel(ends_ref, row_ref, chunk_ref, slots_ref, pos_ref, bt_ref,
-                   q_ref, pool_ref, o_ref, buf, sem, *, bs: int, nb: int,
-                   lat: int, scale: float):
+                   q_ref, pool_ref, *refs, bs: int, nb: int, lat: int,
+                   scale: float):
+    # with a selection its row rides in before the output: [1, S / CH, CH]
+    keep_ref = refs[0] if len(refs) == 4 else None
+    o_ref, buf, sem = refs[-3:]
     t = pl.program_id(0)
     n_rows = pl.num_programs(0)
     n_buf = buf.shape[0]                                  # _DECODE_AHEAD + 1
@@ -126,13 +136,21 @@ def _decode_kernel(ends_ref, row_ref, chunk_ref, slots_ref, pos_ref, bt_ref,
         blk = wait(s_row + c)
         s = _scores(q.astype(blk.dtype), blk) * scale
         v = blk[:, :lat]
+        if keep_ref is not None:
+            # the selection is causal already, so it is the tail's mask too.
+            # A chunk may keep nothing, the first included: until a row's
+            # first kept key its maximum stays _NEG_INF, every p is 1 and l
+            # and acc sum finite garbage, which that key's corr = 0 wipes;
+            # a selection keeps a key at or before pos, so one comes.
+            s = jnp.where(keep_ref[0, pl.ds(c, 1), :] > 0, s, _NEG_INF)
         if tail:
             # Only a row's last chunk has keys past pos. There the buffer
             # holds what the pool had, an earlier step's blocks or nothing
             # yet: a masked score's p is 0, and 0 times a value that is not
             # finite is not 0, so those values go too.
-            kpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (1, ch), 1)
-            s = jnp.where(kpos <= pos, s, _NEG_INF)
+            if keep_ref is None:
+                kpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (1, ch), 1)
+                s = jnp.where(kpos <= pos, s, _NEG_INF)
             vpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
             v = jnp.where(vpos <= pos, v, jnp.zeros((), v.dtype))
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -143,8 +161,8 @@ def _decode_kernel(ends_ref, row_ref, chunk_ref, slots_ref, pos_ref, bt_ref,
                      preferred_element_type=jnp.float32)  # [H, lat]
         return m_new, l_new, acc * corr + pv
 
-    # position 0 is never masked, so the running maximum is real from the
-    # first chunk on and no row of p is all zeros
+    # without a selection position 0 is never masked, so the running maximum
+    # is real from the first chunk on and no row of p is all zeros
     carry = jax.lax.fori_loop(
         0, last_chunk, chunk,
         (jnp.full((h, 1), _NEG_INF, jnp.float32),
@@ -154,30 +172,49 @@ def _decode_kernel(ends_ref, row_ref, chunk_ref, slots_ref, pos_ref, bt_ref,
 
 
 def mla_decode_attention(q, pool, slots, positions, block_tables, lat: int,
-                         scale: float, interpret: bool | None = None):
+                         scale: float, keep=None,
+                         interpret: bool | None = None):
     """Absorbed MLA decode: ``q`` [T, H, W] (``q_lat``, the roped ``q_rope``,
     zeros) over ``pool`` [blocks, BS, W] through ``block_tables[slots]`` ->
     [T, H, lat] (``P c``; the caller applies the
     value half of ``kv_b_proj``). Each row reads blocks ``0 .. pos // BS``
-    of its sequence, once."""
+    of its sequence, once.
+
+    ``keep`` [T, S] bool (``S`` the table's width in tokens), for a family
+    that selects the cached rows a query attends over: the softmax runs over
+    row ``t``'s positions ``keep[t]`` alone, which lie at or before its own
+    and are one at least. The walk is the same (a dropped position's score
+    goes where a position past ``pos`` goes); the call is then
+    ``dsa_attn_decode``, that family's sparse decode attention."""
     return _mla_decode(
         q, pool, slots.astype(jnp.int32), positions.astype(jnp.int32),
-        block_tables.astype(jnp.int32), lat=lat, scale=float(scale),
+        block_tables.astype(jnp.int32), keep, lat=lat, scale=float(scale),
         interpret=interpret_mode(interpret))
 
 
 # ONE jitted function: the step programs of one row count share its trace
 @functools.partial(jax.jit, static_argnames=("lat", "scale", "interpret"))
-def _mla_decode(q, pool, slots, positions, block_tables, *, lat: int,
-                scale: float, interpret: bool):
+def _mla_decode(q, pool, slots, positions, block_tables, keep=None, *,
+                lat: int, scale: float, interpret: bool):
     t_tokens, h, width = q.shape
     _, bs, _ = pool.shape
     nb = decode_step_blocks(bs, width, pool.dtype.itemsize, arrays=1)
-    ends, step_row, step_chunk = decode_steps(
-        positions, nb * bs, t_tokens * -(-block_tables.shape[1] // nb))
+    ch = nb * bs
+    n_chunks = -(-block_tables.shape[1] // nb)
+    ends, step_row, step_chunk = decode_steps(positions, ch,
+                                              t_tokens * n_chunks)
 
     def _row_map(t, *prefetched):
         return (t, 0, 0)
+
+    selection, selection_specs = (), []
+    if keep is not None:
+        # a row of the selection resident a grid step, a chunk's lanes one
+        # sublane of it: 32 KB at a table of 8,192 tokens
+        keep = jnp.pad(keep.astype(jnp.float32),
+                       ((0, 0), (0, n_chunks * ch - keep.shape[1])))
+        selection = (keep.reshape(t_tokens, n_chunks, ch),)
+        selection_specs = [pl.BlockSpec((1, n_chunks, ch), _row_map)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -185,10 +222,11 @@ def _mla_decode(q, pool, slots, positions, block_tables, *, lat: int,
         in_specs=[
             pl.BlockSpec((1, h, width), _row_map),
             pl.BlockSpec(memory_space=pl.ANY),
+            *selection_specs,
         ],
         out_specs=pl.BlockSpec((1, h, lat), _row_map),
         scratch_shapes=[
-            pltpu.VMEM((_DECODE_AHEAD + 1, nb * bs, width), pool.dtype),
+            pltpu.VMEM((_DECODE_AHEAD + 1, ch, width), pool.dtype),
             pltpu.SemaphoreType.DMA((_DECODE_AHEAD + 1, nb)),
         ],
     )
@@ -202,8 +240,9 @@ def _mla_decode(q, pool, slots, positions, block_tables, *, lat: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="mla_decode",
-    )(ends, step_row, step_chunk, slots, positions, block_tables, q, pool)
+        name="mla_decode" if keep is None else "dsa_attn_decode",
+    )(ends, step_row, step_chunk, slots, positions, block_tables, q, pool,
+      *selection)
 
 
 # --------------------------------------------------------------- tiled prefill

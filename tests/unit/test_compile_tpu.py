@@ -106,6 +106,11 @@ def _dsa_decode(q, rows, n):
                                     interpret=False)
 
 
+def _dsa_walk(q, pool, slots, pos, bt, keep):
+    return mla_decode_attention(q, pool, slots, pos, bt, MLA_LAT, 192 ** -0.5,
+                                keep=keep, interpret=False)
+
+
 def _dsa_prefill(q, pool, bias, ts, tp, tv, bt):
     return dsa.dsa_prefill_attention(q, pool, bias, ts, tp, tv, bt, TILE,
                                      MLA_LAT, 192 ** -0.5, interpret=False)
@@ -129,6 +134,11 @@ def _dsa_args(kernel, devices):
     if kernel is _dsa_decode:
         return (s((DSA_ROWS, DSA_HEADS, MLA_WIDTH)),
                 s((DSA_ROWS, DSA_KEEP, MLA_WIDTH)), s((DSA_ROWS,), jnp.int32))
+    if kernel is _dsa_walk:
+        rows = s((DSA_ROWS,), jnp.int32)
+        return (s((DSA_ROWS, DSA_HEADS, MLA_WIDTH)),
+                s((64, MLA_BLOCK, MLA_WIDTH)), rows, rows, bt,
+                s((DSA_ROWS, DSA_TABLE * MLA_BLOCK), jnp.bool_))
     return (s((DSA_TILES * TILE, DSA_HEADS, MLA_WIDTH)),
             s((64, MLA_BLOCK, MLA_WIDTH)),
             s((DSA_TILES * TILE, DSA_TABLE * MLA_BLOCK), jnp.float32),
@@ -245,18 +255,23 @@ def test_mla_kernel_compiles_for_v5e(v5e, kernel, block):
 
 
 @pytest.mark.parametrize("kernel,calls", [(_dsa_index, 2), (_dsa_decode, 1),
-                                          (_dsa_prefill, 1)],
+                                          (_dsa_prefill, 1), (_dsa_walk, 1)],
                          ids=["dsa_index", "dsa_attn_decode",
-                              "dsa_attn_prefill"])
+                              "dsa_attn_prefill", "dsa_attn_decode_walk"])
 def test_dsa_kernel_compiles_for_v5e(v5e, kernel, calls):
     """At DeepSeek-V3.2-Exp's widths and the longctx-pool cell's shapes: the
     indexer's two bodies (a decode row's 64 heads in one product, a tile's
     head-major products), a decode row's 2,048 gathered rows of 640 lanes in
     one block (2.6 MB, double-buffered), the 8-query sub-tile of 128 heads
-    with its bias rows."""
+    with its bias rows; the masked walk of a decode row's own blocks
+    (``mla_decode`` with a selection: 128 heads' [128, 512] scores a step,
+    a row of the selection resident beside three buffers of four blocks)."""
     compiled = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile()
     assert compiled.as_text().count("tpu_custom_call") == calls
-    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    # beside the walk its [16, 8192] selection as float32, and re-laid out
+    # a chunk a sublane: 0.5 MB each
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2**21 if kernel is _dsa_walk else 2**20)
 
 
 @pytest.mark.parametrize("geometry", sorted(MOE_GEOMETRIES))
@@ -310,11 +325,11 @@ def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e):
     (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm"),
     ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode"),
     (_dsa_index, "dsa_index"), (_dsa_decode, "dsa_attn_decode"),
-    (_dsa_prefill, "dsa_attn_prefill")],
+    (_dsa_prefill, "dsa_attn_prefill"), (_dsa_walk, "dsa_attn_decode")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
          "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm",
          "moe_gmm_ungated", "ssm_decode", "dsa_index", "dsa_attn_decode",
-         "dsa_attn_prefill"])
+         "dsa_attn_prefill", "dsa_attn_decode_walk"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     """``pl.pallas_call(name=...)``: the compiled custom call is
     ``%<name>.N`` (``%transpose_jvp_<name>__.N`` under a bare ``jax.grad``),
@@ -341,7 +356,7 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     elif kernel == "ssm_decode":
         text = jax.jit(_ssm_decode).lower(
             *_ssm_args(v5e, rows=8)).compile().as_text()
-    elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill):
+    elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill, _dsa_walk):
         text = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile().as_text()
     else:
         text = jax.jit(kernel).lower(
@@ -350,6 +365,11 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
              if " custom-call(" in ln and "tpu_custom_call" in ln]
     mine = [ln for ln in calls if patterns[name].search(ln)]
     assert mine and all(name in ln.split(" = ")[0] for ln in mine)
+    if kernel in (_mla_decode, _dsa_walk):
+        # ONE walk under two names: the steps' three vectors, slots,
+        # positions, the table, q and the pool; a selection is a ninth operand
+        operands = re.search(r"custom-call\(([^)]*)\)", mine[0]).group(1)
+        assert operands.count("%") == (9 if kernel is _dsa_walk else 8)
     for ln in calls:  # every kernel call is some named kernel's, and one's only
         hits = [k for k, rx in patterns.items()
                 if k != "pallas_custom_call" and rx.search(ln)]
@@ -888,15 +908,21 @@ def test_stage3_left_to_the_partitioner_is_what_the_ledger_showed(
     assert (400, 1600) in pieces and (1600, 1600) in pieces
 
 
-def test_sparse_step_reads_the_kept_rows_only(v5e, monkeypatch):
+@pytest.mark.parametrize("table", [DSA_TABLE, 8 * DSA_TABLE],
+                         ids=["table_8k_walks", "table_64k_gathers"])
+def test_sparse_step_reads_the_kept_rows_only(v5e, monkeypatch, table):
     """The paged contract with TWO block leaves and a selection
     (``deepseek_v32`` at DeepSeek-V3.2-Exp's attention widths, a small FFN and
     vocabulary): a mixed step (16 decode rows beside a tile) with a donated
-    pool scatters each layer's latent rows and index keys in place; the
-    decode rows' only read of the latent pool is a gather of 2,048 rows a row
-    (``bf16[16,2048,640]``: no array of rows x the table's 8,192 positions x
-    640 lanes exists); the index scores are ``[T, 8192]`` float32 summed over
-    the heads (no such array a head); every kernel goes by its name."""
+    pool scatters each layer's latent rows and index keys in place. At the
+    longctx-pool cell's table of 8,192 tokens the decode rows WALK their own
+    blocks under the selection (``mla_decode`` with a ninth operand, named
+    ``dsa_attn_decode``) and nothing is gathered; past
+    ``WALK_MAX_TABLE_TOKENS`` their only read of the latent pool is a gather
+    of 2,048 rows a row (``bf16[16,2048,640]``). Either way no array of rows
+    x the table's positions x 640 lanes exists; the index scores are ``[T,
+    S]`` float32 summed over the heads (no such array a head); every kernel
+    goes by its name."""
     import re
 
     from deepspeed_tpu.models import deepseek_v32 as v32
@@ -921,9 +947,11 @@ def test_sparse_step_reads_the_kept_rows_only(v5e, monkeypatch):
             prefill_tiles=(rows, ts, tp, tv, TILE))
 
     t = rows + tiles * TILE
+    assert v32.decode_form(table * MLA_BLOCK) == (
+        "walk" if table == DSA_TABLE else "gather")
     text = jax.jit(step, donate_argnums=(1,)).lower(
         on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
-        i32(rows + 1, DSA_TABLE), i32(1), i32(1), i32(1)).compile().as_text()
+        i32(rows + 1, table), i32(1), i32(1), i32(1)).compile().as_text()
     arrays = _materialized(text)
     idx_slice = blocks * MLA_BLOCK * DSA_INDEX_DIM * 2
     # both leaves, the dense layer's and the scan body's
@@ -933,9 +961,10 @@ def test_sparse_step_reads_the_kept_rows_only(v5e, monkeypatch):
             and op not in ("scatter", "kernel")] == []
     shapes = {re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])", ln).group(1)
               for _, _, ln in arrays}
-    width = DSA_TABLE * MLA_BLOCK
-    assert {f"bf16[{rows},{DSA_KEEP},{MLA_WIDTH}]",                # the gather
-            f"bf16[{rows * DSA_KEEP},{MLA_WIDTH}]"} & shapes
+    width = table * MLA_BLOCK
+    gathered = {f"bf16[{rows},{DSA_KEEP},{MLA_WIDTH}]",            # the gather
+                f"bf16[{rows * DSA_KEEP},{MLA_WIDTH}]"} & shapes
+    assert bool(gathered) == (table != DSA_TABLE)
     wide = [s for s in shapes
             if s.endswith(f",{width},{MLA_WIDTH}]")                 # whole tables
             or re.search(rf"\[\d+,({DSA_HEADS}|{DSA_INDEX_HEADS}),{width}\]",

@@ -7,6 +7,7 @@ sequence lengths so that the selection bites: each piece against
 imported from the program), against its XLA form, or against a hand-worked
 case."""
 
+import functools
 import importlib.util
 import math
 import os
@@ -219,6 +220,100 @@ def test_dsa_kernels_equal_their_xla_forms(dtype, tol):
     assert out["pallas"].shape == (len(c["real"]), 2, c["lat"])
     np.testing.assert_allclose(out["pallas"][c["real"]], out["xla"][c["real"]],
                                rtol=0, atol=tol)
+
+
+WALK_ROWS = ["everything_kept", "scattered_over_three_steps",
+             "first_chunk_keeps_nothing", "middle_chunk_keeps_nothing",
+             "padding_row_at_position_0"]
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_case(dtype_name):
+    """Five decode rows over pools of 8-token blocks behind a table of 24 (a
+    step of the walk takes 8 blocks, so 3 steps of 64 tokens), 16 kept at
+    most: the masked walk, ``decode_attention_xla`` over ``_gather_kept``'s
+    rows, and the softmax over the kept positions in float64."""
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_decode_attention
+
+    dtype = jnp.dtype(dtype_name)
+    rng = np.random.default_rng(5)
+    bs, mb, nb, n_slots, h, lat, w, k, scale = 8, 24, 200, 4, 2, 32, 128, 16, 0.3
+    bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:(n_slots + 1) * mb]
+                     .reshape(n_slots + 1, mb), jnp.int32).at[n_slots].set(0)
+    pool = jnp.asarray(rng.standard_normal((nb, bs, w)), dtype
+                       ).at[..., lat + 16:].set(0)
+    q = jnp.asarray(rng.standard_normal((5, h, w)), dtype
+                    ).at[..., lat + 16:].set(0)
+    positions = np.array([5, 190, 150, 140, 0], np.int32)
+    slots = np.array([0, 1, 2, 3, n_slots], np.int32)
+    mask = np.zeros((5, mb * bs), bool)
+    mask[0, :6] = True
+    mask[1, rng.choice(191, k, replace=False)] = True
+    mask[2, 64 + rng.choice(151 - 64, k, replace=False)] = True
+    mask[3, rng.choice(64, 8, replace=False)] = True
+    mask[3, 128:131] = True
+    mask[4, 0] = True
+    got = np.asarray(mla_decode_attention(
+        q, pool, jnp.asarray(slots), jnp.asarray(positions), bt, lat, scale,
+        keep=jnp.asarray(mask), interpret=True), np.float32)
+    rows, n = v32._gather_kept(jnp.asarray(mask), k, pool, jnp.asarray(slots), bt)
+    gathered = np.asarray(v32.decode_attention_xla(q, rows, n, lat, scale),
+                          np.float32)
+    ctx = np.asarray(pool, np.float64)[np.asarray(bt)[slots]].reshape(5, -1, w)
+    s = np.einsum("thw,tcw->thc", np.asarray(q, np.float64) * scale, ctx)
+    s = np.where(mask[:, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    exact = np.einsum("thc,tcl->thl", p / p.sum(-1, keepdims=True),
+                      ctx[..., :lat])
+    return got, gathered, exact
+
+
+@pytest.mark.parametrize("row", range(len(WALK_ROWS)), ids=WALK_ROWS)
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+def test_the_masked_walk_is_the_softmax_over_the_kept_rows(dtype, tol, row):
+    """``mla_decode_attention(keep=...)``: a chunk that keeps nothing, the
+    first included, leaves nothing behind; a row keeps what it would gather."""
+    got, gathered, exact = _walk_case(dtype)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[row], gathered[row], rtol=0, atol=tol)
+    np.testing.assert_allclose(got[row], exact[row], rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("table,form", [(3, "walk"), (6, "gather")],
+                         ids=["table_24_walks", "table_48_gathers"])
+def test_both_decode_forms_attend_alike(monkeypatch, table, form, impl):
+    """``sparse_pool_attention`` on either side of the table-width constant
+    (set between the two tables here): the decode rows take the form
+    ``decode_form`` names, and either way the output is the softmax over the
+    selection."""
+    monkeypatch.setattr(v32, "WALK_MAX_TABLE_TOKENS", 32)
+    c = _ragged_case(np.random.default_rng(1), jnp.float32)
+    bt, n_dec = c["bt"][:, :table], c["tiles"][0]
+    positions = jnp.minimum(c["positions"], table * 8 - 1)
+    gathers = []
+    real = v32._gather_kept
+    monkeypatch.setattr(v32, "_gather_kept",
+                        lambda *a: gathers.append(1) or real(*a))
+
+    class Geometry:
+        kv_lora_rank, softmax_scale, index_topk = c["lat"], 0.3, 6
+
+    assert v32.decode_form(table * 8) == form
+    got = np.asarray(v32.sparse_pool_attention(
+        Geometry, c["q"][:n_dec], c["q_idx"][:n_dec], c["w_idx"][:n_dec],
+        c["pool_kv"], c["pool_idx"], c["slots"][:n_dec], positions[:n_dec],
+        bt, impl=impl))
+    assert bool(gathers) == (form == "gather")
+    scores = v32.index_scores_xla(c["q_idx"][:n_dec], c["w_idx"][:n_dec],
+                                  c["pool_idx"], c["slots"][:n_dec],
+                                  positions[:n_dec], bt)
+    mask = v32.select_mask(scores, positions[:n_dec], 6)
+    assert int(mask[0].sum()) == 6          # the selection bites
+    want = np.asarray(v32.prefill_attention_xla(
+        c["q"][:n_dec], c["pool_kv"], jnp.where(mask, 0.0, -1e30),
+        c["slots"][:n_dec], bt, c["lat"], 0.3))
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
 
 
 def test_selection_is_the_exact_top_k_lowest_position_first():
@@ -460,6 +555,14 @@ def test_the_engine_reports_what_a_step_selected(params, monkeypatch):
     assert decode["tokens"] == 1 and decode["dec_kv_tokens"] >= 23
     assert (decode["sel_pairs"], decode["sel_kv_tokens"],
             decode["dec_sel_kv_tokens"]) == (8, 8, 8)
+    # the form the program's decode rows read the pool in: the rule the model
+    # calls, on the table's 8 blocks x 4 tokens
+    assert {a["sel_decode"] for a in seen} == {"walk"}
+    monkeypatch.setattr(v32, "WALK_MAX_TABLE_TOKENS", 16)
+    seen.clear()
+    eng.put("t", list(range(1, 9)), max_new_tokens=2)
+    eng.generate_all()
+    assert seen and {a["sel_decode"] for a in seen} == {"gather"}
     # a family that attends over everything writes none of the three
     seen.clear()
     plain = RaggedInferenceEngine(
@@ -469,6 +572,7 @@ def test_the_engine_reports_what_a_step_selected(params, monkeypatch):
             max_blocks_per_seq=8, prefill_tile=4))
     plain.put("s", list(range(1, 9)), max_new_tokens=2)
     plain.generate_all()
+    assert plain.spec.sparse_decode_form is None
     assert seen and not any(k.startswith("sel_") or "dec_sel" in k
                             for a in seen for k in a)
 
