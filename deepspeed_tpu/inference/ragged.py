@@ -30,6 +30,7 @@ import random
 import time
 import weakref
 from dataclasses import dataclass, field, fields
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -677,6 +678,13 @@ class RaggedInferenceEngine:
         self._topk = self.spec.index_topk
         if self._topk:
             self._refuse_for_selection()
+        # counts only a step program knows (``ModelSpec.step_counters``): it
+        # hands their sums back behind the picked tokens, the reconcile folds
+        # them into ``step_counts`` (totals) and ``_counts_unspanned`` (what
+        # no ``engine/dispatch`` span has carried yet)
+        self._counters = tuple(self.spec.step_counters)
+        self.step_counts = dict.fromkeys(self._counters, 0)
+        self._counts_unspanned = dict.fromkeys(self._counters, 0)
         # bytes one block would cost unquantized at the engine dtype / at
         # fp16: the baselines for kvquant_bytes_saved_total and the
         # resident-block multiplier the bench gates on. The blocks base
@@ -2266,6 +2274,8 @@ class RaggedInferenceEngine:
                         has_tk: bool, has_tp: bool):
         """The jitted program of ``_get_dev_step``'s key, newly made."""
         fwd = self.spec.ragged_forward_fn
+        if self._counters:
+            fwd = partial(fwd, row_counts=True)
         ct = self.cfg.prefill_tile if self._use_tiles else 0
         max_seqs = self.cfg.max_seqs
         ntl = max(nt, 1)
@@ -2287,11 +2297,12 @@ class RaggedInferenceEngine:
                 ts = staged[4 * t:4 * t + ntl]
                 tp_ = staged[4 * t + ntl:4 * t + 2 * ntl]
                 tv = staged[4 * t + 2 * ntl:4 * t + 3 * ntl]
-                logits, cache = fwd(params, tokens, slots, positions, bt,
-                                    cache, prefill_tiles=(nd, ts, tp_, tv, ct))
+                logits, cache, *counts = fwd(
+                    params, tokens, slots, positions, bt, cache,
+                    prefill_tiles=(nd, ts, tp_, tv, ct))
             else:
-                logits, cache = fwd(params, tokens, slots, positions, bt,
-                                    cache)
+                logits, cache, *counts = fwd(params, tokens, slots, positions,
+                                             bt, cache)
             if sampled:
                 keys = keys_for_positions(root, seed_st[slots], positions,
                                           plen_st[slots])
@@ -2309,6 +2320,12 @@ class RaggedInferenceEngine:
             pos_st = pos_st.at[sl_p].max(jnp.where(real, positions + 1, 0))
             state = (tok_st, pos_st, seed_st, plen_st, temp_st, topk_st,
                      topp_st)
+            if counts:
+                # the model's counts over the step's real rows ride behind
+                # the picked tokens: one array, the readback there already is
+                picked = jnp.concatenate([picked, jnp.sum(
+                    jnp.where(real[None, :], counts[0], 0), axis=1,
+                    dtype=jnp.int32)])
             return picked, state, cache
 
         # the program's name in a trace (``jit_ragged_step_d8_t3``): the same
@@ -2674,7 +2691,10 @@ class RaggedInferenceEngine:
         any implementation must read (``min(context, index_topk)`` a decode
         row and a prefill tile), ``dec_sel_kv_tokens``, the decode rows'
         part of that, and ``sel_decode``, the form the program's decode rows
-        read the pool in (``_sel_decode_attr``)."""
+        read the pool in (``_sel_decode_attr``); a model whose step program
+        counts what only it knows (``ModelSpec.step_counters``: a router's
+        ``moe_picks``, ``moe_zero_picks``, ``moe_held_picks``) adds those
+        counts of the steps before this one (``_counts_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
@@ -2707,7 +2727,7 @@ class RaggedInferenceEngine:
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total),
                   **self._state_attr(n_dec, n_pre, n), **sel,
-                  **self._sel_decode_attr(width)):
+                  **self._sel_decode_attr(width), **self._counts_attr()):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._bt_dev,
                 staged, self._sample_root)
@@ -2743,6 +2763,18 @@ class RaggedInferenceEngine:
         model itself calls; nothing for a family without routed experts."""
         form = self.spec.moe_form
         return {} if form is None else {"moe": form(t)}
+
+    def _counts_attr(self) -> dict:
+        """The model's step counters (``ModelSpec.step_counters``) for an
+        ``engine/dispatch`` span: the sums of the steps reconciled since the
+        last span took them. A step's own counts are on the device when its
+        span is written, and waiting for them would stall the pipeline; so a
+        span carries EARLIER steps' counts (the one or two before it in a
+        steady loop), every step's exactly once. Nothing for a model with
+        none."""
+        out, self._counts_unspanned = (
+            self._counts_unspanned, dict.fromkeys(self._counters, 0))
+        return out
 
     def _sel_decode_attr(self, width: int) -> dict:
         """``{"sel_decode": "walk" | "gather"}`` for a family that selects:
@@ -2932,6 +2964,8 @@ class RaggedInferenceEngine:
                                            for _, s in rec["emit"]])
             for row, seq in rec["emit"]:
                 self._append_tokens(seq, [int(picked[row])], out)
+            if self._counters:
+                self._note_step_counts(picked[-len(self._counters):])
         else:  # "sched"
             toks = np.asarray(rec["out"])    # [K, bucket]
             steps = np.asarray(rec["steps"])  # [bucket] device steps_taken
@@ -2976,6 +3010,19 @@ class RaggedInferenceEngine:
             if seq.finished and seq.refs == 0 and seq.slot >= 0:
                 self._release(seq)
         return out
+
+    def _note_step_counts(self, counts) -> None:
+        """Fold one step's counter sums (the tail of its readback) into the
+        totals, the next dispatch span's arguments and ``/metrics``."""
+        for name, value in zip(self._counters, counts):
+            value = int(value)
+            self.step_counts[name] += value
+            self._counts_unspanned[name] += value
+            if self.telemetry.enabled:
+                self.telemetry.counter(
+                    f"inference_{name}_total",
+                    "summed over the device-resident steps' real rows, by "
+                    "the step programs themselves").inc(value)
 
     def _step_device(self) -> dict:
         """One device-resident turn: admit, dispatch one step if anything is

@@ -383,6 +383,15 @@ class ModelSpec:
     # ``table_tokens`` wide, from the rule its ragged_forward_fn calls
     # (``sel_decode`` on ``engine/dispatch``); None without ``index_topk``
     sparse_decode_form: Callable[[int], str] | None = None
+    # counts only the step program knows (what a router picked): the names,
+    # in order, of the per-token int32 counts ``ragged_forward_fn(...,
+    # row_counts=True)`` returns as a third result, ``[len(step_counters),
+    # T]``. The engine's device-resident step sums them over the step's real
+    # rows, hands the sums back with the picked tokens (one readback) and
+    # writes them on ``engine/dispatch`` and ``/metrics``
+    # (``inference_<name>_total``); () for a family with none, whose step
+    # programs have no such output
+    step_counters: tuple = ()
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

@@ -73,6 +73,10 @@ class DeepseekConfig:
     num_heads: int = 16
     kv_lora_rank: int = 512
     q_lora_rank: int | None = None
+    # multiply the normed low-rank query / the normed latent by
+    # sqrt(hidden_size / rank) (``models/longcat_flash.py`` sets both)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -105,6 +109,9 @@ class DeepseekConfig:
                 0 <= self.expert_rank < self.num_experts // self.held:
             raise ValueError("deepseek: experts_held must divide num_experts "
                              "and expert_rank name one of the shares")
+        if self.mla_scale_q_lora and self.q_lora_rank is None:
+            raise ValueError("deepseek: mla_scale_q_lora scales the low-rank "
+                             "query (q_lora_rank)")
         if self.rope_scaling is not None:
             if self.rope_scaling.get("type") != "yarn":
                 raise NotImplementedError(
@@ -296,14 +303,19 @@ def _rope(cfg: DeepseekConfig, q, k, positions):
 
 def _query_latent(cfg: DeepseekConfig, h, lp):
     """The normed low-rank query ``cq`` [T, q_lora_rank] (``q_lora_rank``
-    set)."""
-    return rmsnorm(h @ lp["wq_a"], lp["q_norm"], cfg.rms_norm_eps)
+    set), times ``sqrt(hidden / rank)`` under ``mla_scale_q_lora``."""
+    cq = rmsnorm(h @ lp["wq_a"], lp["q_norm"], cfg.rms_norm_eps)
+    if cfg.mla_scale_q_lora:
+        cq = cq * (cfg.hidden_size / cfg.q_lora_rank) ** 0.5
+    return cq
 
 
 def _mla_inputs(cfg: DeepseekConfig, h, lp, positions, cq=None):
     """``h`` [T, D] (normed) -> ``q_nope`` [T, H, nope], roped ``q_rope``
-    [T, H, rope], the normed latent ``c`` [T, lat], roped ``k_rope``
-    [T, rope]. ``cq``: ``_query_latent``'s, where the caller has it."""
+    [T, H, rope], the normed latent ``c`` [T, lat] (times ``sqrt(hidden /
+    lat)`` under ``mla_scale_kv_lora``: what is cached and what ``W_kvb``
+    multiplies), roped ``k_rope`` [T, rope]. ``cq``: ``_query_latent``'s,
+    where the caller has it."""
     t = h.shape[0]
     lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     if cfg.q_lora_rank is None:
@@ -313,6 +325,8 @@ def _mla_inputs(cfg: DeepseekConfig, h, lp, positions, cq=None):
     q = q.reshape(t, cfg.num_heads, cfg.qk_head_dim)
     a = h @ lp["wkv_a"]
     c = rmsnorm(a[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
+    if cfg.mla_scale_kv_lora:
+        c = c * (cfg.hidden_size / lat) ** 0.5
     q_rope, k_rope = _rope(cfg, q[..., nope:], a[:, lat:], positions)
     return q[..., :nope], q_rope, c, k_rope
 
@@ -325,16 +339,13 @@ def _wkv_b(cfg: DeepseekConfig, lp):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions, select=None):
-    """One layer of the plain forward pass, ``x`` [B, S, D]: MLA as
-    published (per-head keys and values from the latent), not absorbed.
-    ``select(h, cq, lp, positions) -> [B, 1, S, S]`` additive bias: a family
-    whose attention reads some of the context only (``deepseek_v32``)."""
-    lp = ctx.layer_weights(lp, x.dtype)
-    b, s, d = x.shape
+def _plain_attention(cfg: DeepseekConfig, h, lp, positions, select=None):
+    """MLA as published on the normed ``h`` [B, S, D] (per-head keys and
+    values from the latent, not absorbed), through ``W_o`` -> [B, S, D].
+    ``select``: ``_layer``'s."""
+    b, s, d = h.shape
     heads = cfg.num_heads
-
-    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps).reshape(b * s, d)
+    h = h.reshape(b * s, d)
     cq = None if cfg.q_lora_rank is None else _query_latent(cfg, h, lp)
     q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions.reshape(-1),
                                             cq)
@@ -350,8 +361,18 @@ def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions, select=None):
     o = xla_attention(q.reshape(b, s, heads, -1), k.reshape(b, s, heads, -1),
                       v.reshape(b, s, heads, -1), causal=True, bias=bias,
                       scale=cfg.softmax_scale)
-    x = x + o.reshape(b, s, heads * cfg.v_head_dim) @ lp["wo"]
+    return o.reshape(b, s, heads * cfg.v_head_dim) @ lp["wo"]
 
+
+def _layer(cfg: DeepseekConfig, ctx: ShardCtx, x, lp, positions, select=None):
+    """One layer of the plain forward pass, ``x`` [B, S, D].
+    ``select(h, cq, lp, positions) -> [B, 1, S, S]`` additive bias: a family
+    whose attention reads some of the context only (``deepseek_v32``)."""
+    lp = ctx.layer_weights(lp, x.dtype)
+    b, s, d = x.shape
+    x = x + _plain_attention(
+        cfg, rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps), lp, positions,
+        select)
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     # differentiated, and maybe under ``ctx``'s mesh: the einsum form
     x = x + _ffn(cfg, h.reshape(b * s, d), lp,
@@ -409,25 +430,20 @@ def init_paged_cache(cfg: DeepseekConfig, num_blocks: int, block_size: int,
                              cfg.row_lanes), dtype)}
 
 
-def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
-                  block_tables, prefill_tiles=None, stacks=None, sparse=None):
-    """One layer over a flat ragged token batch [T, D]: the step's rows
-    ``[c, k_rope, zeros]`` are scattered into the latent pool, then absorbed
-    attention reads the cached rows through the layer's block table.
-    ``sparse(h, cq, lp, q, pool) -> (o_lat, pool)`` takes the attention's
-    place for a family that reads some of the cached rows only and keeps
-    further block leaves (``deepseek_v32``); ``pool`` has this step's latent
-    rows in it."""
+def _pool_attention(cfg: DeepseekConfig, h, lp, pool, positions, slots,
+                    block_tables, prefill_tiles=None, sparse=None):
+    """MLA over the latent pool on the normed ``h`` [T, D] of a flat ragged
+    token batch, through ``W_o`` -> ([T, D], pool): the step's rows ``[c,
+    k_rope, zeros]`` are scattered into the pool's ``"kv"`` leaf through
+    ``block_tables`` (the table of ONE block layer), then absorbed attention
+    reads the cached rows through the same table. ``sparse``:
+    ``_ragged_layer``'s."""
     from deepspeed_tpu.models.paged import (
         latent_pool_attention,
         write_rows_paged,
     )
-    from deepspeed_tpu.ops.quantizer import dequantize_layer
 
-    lp = dequantize_layer(lp, x.dtype)
-    t_tokens = x.shape[0]
-
-    h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
+    t_tokens = h.shape[0]
     cq = None if cfg.q_lora_rank is None else _query_latent(cfg, h, lp)
     q_nope, q_rope, c, k_rope = _mla_inputs(cfg, h, lp, positions, cq)
     pad = cfg.row_lanes - cfg.kv_lora_rank - cfg.qk_rope_head_dim
@@ -446,8 +462,24 @@ def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
             cfg.softmax_scale, prefill_tiles)
     else:
         o_lat, pool = sparse(h, cq, lp, q, {**pool, "kv": kv})
-    o = jnp.einsum("thl,lhv->thv", o_lat.astype(x.dtype), wv)
-    x = x + o.reshape(t_tokens, -1) @ lp["wo"]
+    o = jnp.einsum("thl,lhv->thv", o_lat.astype(h.dtype), wv)
+    return o.reshape(t_tokens, -1) @ lp["wo"], pool
+
+
+def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
+                  block_tables, prefill_tiles=None, stacks=None, sparse=None):
+    """One layer over a flat ragged token batch [T, D]: ``_pool_attention``,
+    then the FFN half. ``sparse(h, cq, lp, q, pool) -> (o_lat, pool)`` takes
+    the attention's place for a family that reads some of the cached rows
+    only and keeps further block leaves (``deepseek_v32``); ``pool`` has
+    this step's latent rows in it."""
+    from deepspeed_tpu.ops.quantizer import dequantize_layer
+
+    lp = dequantize_layer(lp, x.dtype)
+    o, pool = _pool_attention(
+        cfg, rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps), lp, pool,
+        positions, slots, block_tables, prefill_tiles, sparse)
+    x = x + o
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
     stacked = (*stacks, lp["first_expert"]) if "first_expert" in lp else None

@@ -5,7 +5,12 @@
 token's combine weights come out of the router's logits (its keyword
 arguments), in the expert itself (three matrices, gated SwiGLU; or two,
 ungated ``relu**2``: ``w_gate`` None) and in which of the routed experts the
-layer holds (``held``: one rank's share of an expert-parallel deployment). It
+layer holds (``held``: one rank's share of an expert-parallel deployment). A
+pick is of one of three kinds: of an expert the layer holds (computed here), of
+a routed expert it does not hold (another rank's part: nothing here), or of a
+zero-compute expert (``zero_experts``: the router's outputs past the routed
+ones are identity experts, a pick of one adds ``w * h`` and touches no weight;
+computed on the token's own chip, so counted ONCE across the ranks). It
 has two exact forms of the same computation, and ``expert_form`` picks between
 them from the step's shapes. ``routed_experts_einsum`` is the one form that
 differentiates and partitions over a mesh, for the callers that need either.
@@ -129,7 +134,8 @@ def _route(h, router_w, top_k: int, scoring: str, bias, renormalize: bool,
 
 def _held_picks(topi, held, e: int):
     """The picks as indices into the ``e`` experts the layer holds, ``e`` (one
-    past the last) for a pick of an expert it does not hold."""
+    past the last) for a pick of an expert it does not hold (another rank's,
+    or a zero-compute one: those lie past the ``held[1]`` routed)."""
     if held is None:
         return topi
     local = topi - held[0]
@@ -184,7 +190,7 @@ def _picks_before(flat, e: int):
 
 
 def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
-                     num_experts, held=None):
+                     num_experts, held=None, zero_experts: int = 0):
     """Each pick through its own expert only: the ``T x top_k`` picks are
     sorted by expert (a counting sort: a pick's place is its expert's first
     row plus the picks of that expert before it), every expert's rows go
@@ -193,7 +199,8 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     ``num_experts`` experts start at ``first_expert`` of the weights. With
     ``held`` (first routed expert held, experts routed over) a pick of an
     expert the layer does not hold sorts past the last group: it gets no
-    row, is never multiplied, and comes back as zero."""
+    row, is never multiplied, and comes back as zero; so does a pick of one
+    of the ``zero_experts`` outputs past the routed ones."""
     t, d = h.shape
     e, k = num_experts, topi.shape[1]
     if t > _GROUPED_MAX_ROWS:
@@ -201,16 +208,20 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
         parts = [jnp.pad(a, ((0, pad), (0, 0))).reshape(
             -1, _GROUPED_MAX_ROWS, a.shape[1]) for a in (h, topv, topi)]
         out = lax.map(lambda p: _grouped_experts(
-            *p, w_gate, w_up, w_down, first_expert, e, held), tuple(parts))
+            *p, w_gate, w_up, w_down, first_expert, e, held, zero_experts),
+            tuple(parts))
         return out.reshape(-1, d)[:t]
     # The kernel's shapes are those of a full call whatever ``t`` is, so
     # that every step program of an engine shares ONE traced kernel
     # (``grouped_swiglu``); the rows past the step's own are never read.
     # A pass of the kernel is 128 rows where an expert's share of a full call
     # is more than 64 (128 at Mixtral's 2 of 8), else 64 (48 at Moonlight's
-    # 6 of 64): one pass an expert either way.
-    routed = e if held is None else held[1]
-    tm = 128 if _GROUPED_MAX_ROWS * k > 64 * routed else 64
+    # 6 of 64; 8 at 12 of 512 + 256 zero-compute outputs): one pass an expert
+    # either way. ``rows`` below stays sized for every pick landing on a held
+    # expert, which the router may do, whatever share of them it is expected
+    # to send elsewhere.
+    outputs = (e if held is None else held[1]) + zero_experts
+    tm = 128 if _GROUPED_MAX_ROWS * k > 64 * outputs else 64
     flat = _held_picks(topi, held, e).reshape(-1)
     before = _picks_before(flat, e)
     counts = before[-1]
@@ -239,7 +250,8 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                    top_k: int, *, stacked=None, scoring: str = "softmax",
                    bias=None, renormalize: bool = True, scale: float = 1.0,
                    eps: float = 1e-9, held=None, router_h=None,
-                   groups=None) -> jnp.ndarray:
+                   groups=None, zero_experts: int = 0,
+                   count_picks: bool = False) -> jnp.ndarray:
     """Dropless per-token top-k MoE for the serving paths (``h`` [T, D]
     flat tokens): exact (no capacity, no drops, every pick computed), bf16
     operands with float32 accumulation in either form.
@@ -287,15 +299,55 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
 
     ``router_h``: what the router scores where that is not what the experts
     compute on (experts in a latent space, routed on the full hidden state).
+
+    Zero-compute experts: with ``zero_experts = Z`` the router scores ``routed
+    + Z`` outputs, and a pick of one of the last ``Z`` is an identity expert:
+    it adds its weight times ``h`` and gets no column in the einsum and no
+    row in the sort. Every rank computes that part for its own tokens, so a
+    deployment adds it up once, not once a rank. ``count_picks`` also returns
+    ``[T, 2]`` int32, a token's zero-compute picks and its picks of held
+    experts (what a step program hands back for ``engine/dispatch``).
     """
     topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
                         scoring, bias, renormalize, scale, eps, groups)
     e = w_up.shape[0]
+    held = _with_zero_experts(router_w, held, e, zero_experts)
     routed = e if held is None else held[1]
     if expert_form(h.shape[0], routed, top_k) == "dense":
-        return _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
-    return _grouped_experts(h, topv, topi,
-                            *(stacked or (w_gate, w_up, w_down, 0)), e, held)
+        y = _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
+    else:
+        y = _grouped_experts(h, topv, topi,
+                             *(stacked or (w_gate, w_up, w_down, 0)), e, held,
+                             zero_experts)
+    if zero_experts:
+        y = y + _identity_part(h, topv, topi, routed)
+    if not count_picks:
+        return y
+    return y, jnp.stack(
+        [jnp.sum(topi >= routed, axis=-1, dtype=jnp.int32),
+         jnp.sum(_held_picks(topi, held, e) < e, axis=-1, dtype=jnp.int32)],
+        axis=-1)
+
+
+def _with_zero_experts(router_w, held, e: int, zero_experts: int):
+    """``held`` for a router whose last ``zero_experts`` outputs are
+    zero-compute experts: a layer that holds every routed expert still has
+    picks with no column (``(0, e)``); the router's width is checked."""
+    if not zero_experts:
+        return held
+    held = (0, e) if held is None else held
+    if router_w.shape[-1] != held[1] + zero_experts:
+        raise ValueError(
+            f"the router scores {router_w.shape[-1]} outputs, not {held[1]} "
+            f"routed + {zero_experts} zero-compute experts")
+    return held
+
+
+def _identity_part(h, topv, topi, routed: int):
+    """What a token's picks of zero-compute experts (``topi >= routed``) add:
+    the sum of their weights times ``h``."""
+    w = jnp.sum(jnp.where(topi >= routed, topv, 0.0), axis=-1)
+    return (w[:, None] * h.astype(jnp.float32)).astype(h.dtype)
 
 
 def expert_stacks(layers: dict):
@@ -321,11 +373,15 @@ def routed_experts_einsum(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                           top_k: int, *, scoring: str = "softmax", bias=None,
                           renormalize: bool = True, scale: float = 1.0,
                           eps: float = 1e-9, held=None, router_h=None,
-                          groups=None) -> jnp.ndarray:
+                          groups=None, zero_experts: int = 0) -> jnp.ndarray:
     """``routed_experts`` in its einsum form at every row count: plain XLA,
     so it differentiates (``deepseek``'s training-shaped ``forward`` /
     ``loss_fn``) and partitions over a mesh (``mixtral``'s dense-cache
     inference layer under ``InferenceEngine``'s tensor-parallel mesh)."""
     topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
                         scoring, bias, renormalize, scale, eps, groups)
-    return _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
+    held = _with_zero_experts(router_w, held, w_up.shape[0], zero_experts)
+    y = _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
+    if zero_experts:
+        y = y + _identity_part(h, topv, topi, held[1])
+    return y

@@ -16,6 +16,14 @@ and the dense-cache append+attend used by the v1-style engines.
 - *Block axis.* Inside a step program a layer sees the pool with ``L`` and
   ``NB`` merged (``[L*NB, BS, Hkv*D]``, a bitcast) and addresses it through
   ITS block table, ``block_tables + layer * NB``. It never owns a slice.
+- *Block layers.* ``L`` counts BLOCK layers, the rows a token has in a leaf,
+  and the family's ``init_paged_cache`` is who counts them: a model layer
+  with one attention owns one, a model layer with two attention sublayers
+  (``longcat_flash``) owns two neighbours, ``2 i`` and ``2 i + 1``, and is
+  handed both tables (``scan_layers_paged(block_layers=2)``). Nothing else
+  knows: bytes a cached token, allocation, release, the prefix cache's
+  splice, preemption and containment all read the leaves (``a.shape[0]``
+  rows a token), never a config's ``num_layers``.
 - *Who may index what.* A step program touches the block axis only through
   a block table: ``write_kv_paged`` scatters the step's ``T`` rows in
   place, the XLA gather and the two Pallas kernels read the blocks the
@@ -115,7 +123,8 @@ def stack_plan(pattern: str) -> tuple[str, str, int]:
     return pattern[:lead], pattern[lead:lead + period], repeats
 
 
-def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
+def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=(),
+                      block_layers: int = 1):
     """Run ``layer_fn(x, lp, pool, layer_tables) -> (x, pool)`` over the
     stacked ``layers``. ``pool`` is the family's paged cache, any pytree of
     ``[L, NB, ...]`` arrays (``{"k", "v"}`` of fp arrays or of
@@ -131,6 +140,12 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
     before the scan at layers ``0 .. len(lead) - 1`` of the pool and the
     scanned layers follow them, so ``L`` counts every layer.
 
+    A layer with more than one attention owns ``block_layers`` neighbouring
+    block layers, one a sublayer: layer ``i`` is handed the tuple of their
+    tables, ``block_tables + (i * block_layers + a) * NB``, and what it
+    carries from one sublayer to the next (a residual, a branch computed
+    after the first and added after the last) stays inside ``layer_fn``.
+
     A stack of several kinds of layer, some with no blocks and some with a
     state a slot, gives ``layer_fn`` as a period of layers instead of one
     function: ``_scan_periods`` below has that form's arguments.
@@ -140,20 +155,30 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=()):
     leaves = jax.tree_util.tree_leaves(pool)
     n_layers, nb = leaves[0].shape[:2]
     n_lead = len(lead)
+    if n_layers % block_layers:
+        raise ValueError(f"the cache's leaves hold {n_layers} block layers, "
+                         f"no multiple of a layer's {block_layers}")
+
+    def tables(i):
+        if block_layers == 1:
+            return block_tables + i * nb
+        return tuple(block_tables + (i * block_layers + a) * nb
+                     for a in range(block_layers))
 
     pool = jax.tree_util.tree_map(
         lambda a: a.reshape((n_layers * nb,) + a.shape[2:]), pool)
     for i, (fn, lp) in enumerate(lead):
-        x, pool = fn(x, lp, pool, block_tables + i * nb)
+        x, pool = fn(x, lp, pool, tables(i))
 
     def body(carry, lp_i):
         x, pool = carry
         lp, i = lp_i
-        return layer_fn(x, lp, pool, block_tables + i * nb), None
+        return layer_fn(x, lp, pool, tables(i)), None
 
     (x, pool), _ = lax.scan(
         body, (x, pool),
-        (layers, jnp.arange(n_lead, n_layers, dtype=jnp.int32)))
+        (layers, jnp.arange(n_lead, n_layers // block_layers,
+                            dtype=jnp.int32)))
     return x, jax.tree_util.tree_map(
         lambda a: a.reshape((n_layers, nb) + a.shape[1:]), pool)
 

@@ -975,3 +975,83 @@ def test_sparse_step_reads_the_kept_rows_only(v5e, monkeypatch, table):
     for kernel, calls in (("dsa_index", 4), ("dsa_attn_decode", 2),
                           ("dsa_attn_prefill", 2)):
         assert sum(kernel in n for n in names) == calls, (kernel, names)
+
+
+# ------------------------------------------- two block layers a model layer
+# longcat-flash-omni-d4-ep32.json as it is served: every published width, 4
+# double layers, 16 held experts, 1/8 vocabulary, the reason-pool cell's pool
+LONGCAT_HEADS, LONGCAT_BLOCKS, LONGCAT_TABLE = 64, 2049, 32
+
+
+@pytest.mark.parametrize("rows,tiles", [(128, 3), (128, 0), (0, 4)],
+                         ids=["mixed", "decode", "prefill"])
+def test_double_layer_step_scatters_two_rows_a_layer(v5e, monkeypatch, rows,
+                                                     tiles):
+    """The paged contract where a model layer owns TWO block layers
+    (``longcat_flash`` at the benchmark cell's own sizes): the scan's body
+    scatters the step's rows twice, once a sublayer (4 layers: 8 row
+    scatters a step), ``mla_decode`` and ``mla_prefill`` at 64 heads compile
+    and go by the names ``benchmark/kernels/*.json`` find, once a sublayer
+    each, the 256-row-and-up steps' expert branch is ONE ``moe_gmm`` call, the
+    counts the step hands back are ``[3, T]``, nothing else in the program is
+    as large as one block layer's slice of the pool, and its temporaries fit
+    beside 10.35 GB of weights and the 2.69 GB pool."""
+    import json
+    import os
+    import re
+
+    from deepspeed_tpu.models import longcat_flash as lc
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = lc.LongcatFlashConfig(vocab_size=16384, num_layers=4,
+                                experts_held=16)
+    assert lc.num_params(cfg) == 5_172_749_312
+    on_chip, i32, params, cache = _abstract_step(v5e, lc, cfg, LONGCAT_BLOCKS,
+                                                 MLA_BLOCK)
+    assert cache["kv"].shape == (8, LONGCAT_BLOCKS, MLA_BLOCK, MLA_WIDTH)
+    layer_slice = LONGCAT_BLOCKS * MLA_BLOCK * MLA_WIDTH * 2
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return lc.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE) if tiles else None,
+            row_counts=True)
+
+    t, n = rows + tiles * TILE, max(tiles, 1)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(129, LONGCAT_TABLE), i32(n), i32(n), i32(n)).compile()
+    text = compiled.as_text()
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= layer_slice]
+    # the compiler may write a sublayer's scatter out twice (``.remat``: the
+    # same rows into the same donated buffer again, in place)
+    scatters = {ln.split(" = ")[0].removesuffix(".remat")
+                for _, op, ln in big if op == "scatter"}
+    assert len(scatters) == 2, scatters
+    # ... in the body of the scan over the 4 layers: 8 row scatters a step
+    cond = [body for body in _computations(text).values()
+            if any('op_name="jit(step)/while/cond/lt"' in ln for ln in body)]
+    assert len(cond) == 1 and any(" constant(4)" in ln for ln in cond[0])
+    assert all("/while/body/" in ln for _, op, ln in big if op == "scatter")
+    assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
+    kernels = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                           "benchmark", "kernels")
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    found = {}
+    for name in ("mla_decode", "mla_prefill", "moe_gmm"):
+        with open(os.path.join(kernels, name + ".json")) as f:
+            rx = re.compile(json.load(f)["trace_pattern"])
+        found[name] = sum(bool(rx.search(ln)) for ln in calls)
+    assert found == {"mla_decode": 2 if rows else 0,
+                     "mla_prefill": 2 if tiles else 0,
+                     "moe_gmm": 1 if t >= 256 else 0}
+    assert len(calls) == sum(found.values())
+    pool_shape = f"bf16[{8 * LONGCAT_BLOCKS},{MLA_BLOCK},{MLA_WIDTH}]"
+    for ln in calls:
+        if "moe_gmm" not in ln.split(" = ")[0]:
+            assert ln.split("operand_layout_constraints=")[1].count(
+                pool_shape) == 1, ln
+    assert f"s32[3,{t}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30

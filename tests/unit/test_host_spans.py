@@ -323,14 +323,23 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "kernel.dsa_attn_prefill_roofline",
                          "kernel.dsa_attn_decode_share",
                          "kernel.dsa_attn_decode_roofline",
-                         "model.dsa_step_roofline_kv")}}
+                         "model.dsa_step_roofline_kv")},
+                     # PR 38: the readers of the counts a step program hands
+                     # back read a slice of the cell whose router has
+                     # zero-compute experts (``moe_picks`` / ``moe_zero_picks``
+                     # / ``moe_held_picks`` in its dispatch spans)
+                     **{m: "v5e_longcat_flash_reason_spans" for m in (
+                         "sched.moe_zero_pick_share",
+                         "sched.moe_held_rows_per_expert")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
                    "v5e_nemotron_reason_spans":
                    "nemotron-3-super-120b-d11-ep4.reason-pool",
                    "v5e_deepseek_v32_longctx_spans":
-                   "deepseek-v32-exp-d5-ep16.longctx-pool"}
+                   "deepseek-v32-exp-d5-ep16.longctx-pool",
+                   "v5e_longcat_flash_reason_spans":
+                   "longcat-flash-omni-d4-ep32.reason-pool"}
 
 
 def _new_readers():
