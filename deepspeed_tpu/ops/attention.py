@@ -62,35 +62,87 @@ def xla_attention(
     return out
 
 
-def flash_blocks(q, k, bias=None, impl: str = "auto"):
-    """The flash kernel's ``(block_q, block_k)`` where ``attention`` will run
-    it, None where it takes the XLA path: decided from the backend and the
-    shapes, BEFORE any call."""
-    import os
+def _fit(n: int, want: int) -> int:
+    """``want`` halved down to a divisor of ``n`` (``want`` itself where it
+    covers ``n``), so odd lengths (1536, 2560, ...) keep the kernel."""
+    while want > 8 and n % min(want, n):
+        want //= 2
+    return want
 
+
+# The flash kernels' geometry, measured on v5e (my chip runs, PR 39; PERF.md
+# section 6 has the table). A program holds up to FLASH_BLOCK query rows and
+# one K/V block of as many in VMEM: at 1,024 a training sequence is one grid
+# step a head. Inside it the kernels walk FLASH_SUB-row sub-blocks and stop at
+# the diagonal, so 10 of 16 pairs of a 1,024-token square are multiplied. One
+# layer's forward (twice: remat) + dK dV + dQ at [4, 25, 1024, 64]: 1,836 us
+# on the whole square, 1,177 / 1,193 / 1,420 at sub-blocks of 128 / 256 / 512;
+# at [1, 32, 2048, 128] 1,981 against 1,310 / 1,348 / 1,492. 128 and 256 are
+# within 3%, at both head widths: 256 is the forward's best (226 us a call
+# against 235) and unrolls half as many strips.
+FLASH_BLOCK = 1024
+FLASH_SUB = 256
+
+
+def _sub_block(block_q: int, block_k: int) -> int:
+    """The walk's sub-block inside blocks of these sizes: FLASH_SUB rows, or
+    half of that, where it divides both (a block no longer than it is one
+    sub-block); a block neither divides keeps the whole square. The kernels
+    slice scores by sub-block along lanes, so a width is whole 128-lane
+    tiles."""
+    for sub in (FLASH_SUB, FLASH_SUB // 2):
+        if not (block_q % min(sub, block_q) or block_k % min(sub, block_k)):
+            return sub
+    return max(block_q, block_k)
+
+
+def flash_blocks(q, k, bias=None, impl: str = "auto"):
+    """The flash kernel's ``(block_q, block_k, sub)`` where ``attention``
+    will run it, None where it takes the XLA path: decided from the backend
+    and the shapes, BEFORE any call. ``sub`` is the width of the sub-blocks
+    the kernels walk inside a block (``flash_pair_share`` says what that
+    leaves of the square); a sequence shorter than two of them is one."""
     from deepspeed_tpu.ops.pallas.flash_attention import supported
 
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
     if impl == "xla":
         return None
-
-    # 1024x1024 blocks measured fastest on v5e (0.63 vs 0.54 MFU at 256x512
-    # on the 512M bench model; 2048 overflows VMEM — the [bq, bk] fp32
-    # probability block is the VMEM governor); env-tunable for on-hardware
-    # sweeps. Halve down to a divisor of the sequence so odd lengths (1536,
-    # 2560, ...) keep the kernel.
-    def fit(n, want):
-        while want > 8 and n % min(want, n):
-            want //= 2
-        return want
-
-    bq = fit(q.shape[1], int(os.environ.get("DSTPU_FLASH_BLOCK_Q", 1024)))
-    bk = fit(k.shape[1], int(os.environ.get("DSTPU_FLASH_BLOCK_K", 1024)))
-    if impl == "auto" and not (_on_tpu() and bias is None
-                               and supported(q, k, bq, bk)):
+    sq, skv = q.shape[1], k.shape[1]
+    bq, bk = _fit(sq, FLASH_BLOCK), _fit(skv, FLASH_BLOCK)
+    sub = _sub_block(min(bq, sq), min(bk, skv))
+    # dK / dV read lse and delta across a row, one block of q rows wide: the
+    # chip's compiler takes whole 128-lane tiles or the whole sequence
+    tiled = bq >= sq or bq % 128 == 0
+    if impl == "auto" and not (_on_tpu() and bias is None and tiled
+                               and supported(q, k, bq, bk, sub)):
         return None
-    return bq, bk
+    return bq, bk, sub
+
+
+# the walk of the flash call traced last: what a step program compiled since
+# runs in every layer (a model's attentions share one shape)
+_traced_walk: dict = {}
+
+
+def flash_walk_args() -> dict:
+    """Arguments for a step's dispatch span: the sub-block width the flash
+    kernels of the program traced last walk (``flash_sub``) and the share of
+    the square they multiply (``flash_pair_share``); empty where no flash
+    call was traced (the XLA path)."""
+    return dict(_traced_walk)
+
+
+def flash_pair_share(sq: int, skv: int, sub: int, causal: bool = True) -> float:
+    """The share of the ``sq x skv`` square's sub-block pairs a flash call
+    multiplies: ``causal_pairs / all_pairs`` by the kernels' own walk, 1.0
+    without a mask. Static: the walk engages by shape."""
+    from deepspeed_tpu.ops.pallas.flash_attention import kv_walk
+
+    tq, tk = min(sub, sq), min(sub, skv)
+    nq, nk = sq // tq, skv // tk
+    return sum(kv_walk(n * tq, tq, tk, nk, causal)[1]
+               for n in range(nq)) / (nq * nk)
 
 
 def attention(
@@ -114,6 +166,8 @@ def attention(
         return xla_attention(q, k, v, causal=causal, bias=bias, scale=scale)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
+    _traced_walk.update(flash_sub=blocks[2], flash_pair_share=round(
+        flash_pair_share(q.shape[1], k.shape[1], blocks[2], causal), 4))
     return flash_attention(q, k, v, causal, scale, *blocks)
 
 

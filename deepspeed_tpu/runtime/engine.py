@@ -38,6 +38,7 @@ from deepspeed_tpu.comm import comm as dist
 from deepspeed_tpu.comm.topology import MeshTopology, get_topology, topology_initialized
 from deepspeed_tpu.config.config import Config, load_config
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx
+from deepspeed_tpu.ops.attention import flash_walk_args
 from deepspeed_tpu.ops.optimizers import base_lr, build_optimizer
 from deepspeed_tpu.parallel.partition import (
     ShardingPlan,
@@ -2311,7 +2312,7 @@ class Engine:
         try:
             # the enqueue of the step program: it returns before the device
             # is done (the caller's fetch of the loss settles the step)
-            with span("train/dispatch"):
+            with span("train/dispatch", **flash_walk_args()):
                 if in_dense_phase:
                     if self._warm_batch_jit is None:
                         self._warm_batch_jit = self._build_train_batch_fn(

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
+from deepspeed_tpu.ops.attention import flash_blocks, flash_pair_share
 from deepspeed_tpu.ops.pallas import dsa_attention as dsa
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.mla_attention import (
@@ -31,7 +32,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
 # (q heads, kv heads, head size): Llama-3-8B / Mixtral-8x7B, GPT-2 XL,
 # GPT-2 medium, Nemotron-3-Super (16 query heads a KV head, 256-lane rows)
 GEOMETRIES = [(32, 8, 128), (25, 25, 64), (16, 16, 64), (32, 2, 128)]
-SEQ = 1024     # ops.attention fits flash blocks to min(1024, seq)
+SEQ = 1024     # one flash block (ops.attention.flash_blocks), walked in sub-blocks
 TILE = 128     # RaggedConfig.prefill_tile of bench.py and chip_smoke.py
 BLOCK = 32     # their KV block_size
 MAX_BLOCKS = 8
@@ -57,7 +58,8 @@ def v5e():
 
 
 def _flash_fwd(q, k, v):
-    return flash_attention(q, k, v, True, None, SEQ, SEQ, False)
+    return flash_attention(q, k, v, True, None,
+                           *flash_blocks(q, k, None, "pallas"), False)
 
 
 def _flash_bwd(q, k, v):
@@ -205,9 +207,15 @@ def _args(kernel, hq, hkv, d, devices, mla_block=MLA_BLOCK):
     (_flash_fwd, 1), (_flash_bwd, 3), (_decode, 1), (_prefill, 1)],
     ids=["flash_fwd", "flash_bwd", "paged_decode", "tiled_prefill"])
 def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
-    text = jax.jit(kernel).lower(
-        *_args(kernel, hq, hkv, d, v5e)).compile().as_text()
+    args = _args(kernel, hq, hkv, d, v5e)
+    text = jax.jit(kernel).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= n_calls
+    if kernel in (_flash_fwd, _flash_bwd):
+        # what was lowered walks sub-blocks: its work is bounded by the
+        # causal sub-block pairs, and the whole square only without a mask
+        sub = flash_blocks(*args[:2], None, "pallas")[2]
+        assert flash_pair_share(SEQ, SEQ, sub) <= 0.75
+        assert flash_pair_share(SEQ, SEQ, sub, causal=False) == 1.0
 
 
 # the decode rows of the three K/V-pool serving cells: (q heads, kv heads,
