@@ -1,0 +1,9 @@
+"""Roofline share of the KDA decode kernel: every decode row's state is read
+once and written once (``dec_state_bytes`` of the dispatch spans: rows x the
+reference's ``state_bytes_per_slot`` x 2); bytes-bound.
+``ssm_spans.decode_kernel_roofline`` on the kernel's own name."""
+import ssm_spans
+
+
+def read(ctx):
+    return ssm_spans.decode_kernel_roofline(ctx, kernel="kda_decode")

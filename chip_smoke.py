@@ -190,15 +190,20 @@ def device_phase(chips: int) -> dict:
 # ----------------------------------------------------------------- kernels
 def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                   block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
-                  experts=(16, 1024, 2688, 64, 6)) -> dict:
+                  experts=(16, 1024, 2688, 64, 6),
+                  kda=(258, 128, 32, 128)) -> dict:
     """Each Pallas kernel of the train and serve paths against the XLA path
     on the same seeded bf16 input, at each ``(q heads, kv heads, head size)``.
     Off the chip the kernels interpret; on it this is their first execution.
     Beside the attention kernels, once each: the Mamba-2 decode kernel at
     ``ssm`` = (state rows, state size, heads x head size, groups) against
-    XLA's gather -> update -> scatter, and the grouped expert kernel's
+    XLA's gather -> update -> scatter, the grouped expert kernel's
     two-matrix ``relu**2`` form at ``experts`` = (held, latent, ffn, routed,
-    top k), a held share, against the all-experts einsum."""
+    top k), a held share, against the all-experts einsum, and the KDA decode
+    kernel at ``kda`` = (state rows, key channels = values a head, heads,
+    decode rows; the Kimi-Linear cell's 128 rows x 32 heads x 128 x 128)
+    against its XLA form, both timed (``kda_decode_ms``: the state donated,
+    the median of five calls)."""
     import jax
     import jax.numpy as jnp
 
@@ -303,10 +308,41 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
           jax.jit(lambda *a: moe._einsum_experts(*a, None, w_up, w_down,
                                                  share))(h, topv, topi))
 
+    from deepspeed_tpu.ops.pallas.kda import kda_decode, kda_decode_xla
+
+    rows_n, kd, heads, t = kda
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 2), 6))
+    state = jax.random.normal(next(keys), (rows_n, kd, heads * kd), jnp.float32)
+    k_in = jax.random.normal(next(keys), (t, kd, heads), jnp.float32)
+    step = (jnp.asarray(np.random.default_rng(SEED).permutation(rows_n)[:t],
+                        jnp.int32),
+            jax.random.uniform(next(keys), (t, kd, heads), jnp.float32, 0.2, 1.0),
+            k_in / jnp.linalg.norm(k_in, axis=1, keepdims=True),
+            jax.random.normal(next(keys), (t, kd, heads), jnp.float32),
+            jax.random.normal(next(keys), (t, heads * kd), jnp.float32),
+            jnp.repeat(jax.random.uniform(next(keys), (t, heads), jnp.float32),
+                       kd, axis=1))
+    forms = {"pallas": jax.jit(lambda *a: kda_decode(*a, impl="pallas"),
+                               donate_argnums=0),
+             "xla": jax.jit(kda_decode_xla, donate_argnums=0)}
+    outs = {name: fn(state + 0.0, *step) for name, fn in forms.items()}
+    for name, got, want in zip(("kda_decode_state", "kda_decode_y"),
+                               outs["pallas"], outs["xla"]):
+        close(name, got, want, tol=1e-5)
+    kda_ms = {}
+    for name, fn in forms.items():
+        s_run, times = outs[name][0], []
+        for _ in range(5):
+            t1 = time.perf_counter()
+            s_run, y = fn(s_run, *step)
+            jax.block_until_ready((s_run, y))
+            times.append((time.perf_counter() - t1) * 1e3)
+        kda_ms[name] = round(sorted(times)[2], 3)
+
     return _emit("kernels", seconds=round(time.perf_counter() - t0, 2),
                  geometries=[list(g) for g in geometries],
                  shapes={"seq_len": seq_len, "tile": tile, "block": block},
-                 max_rel_err=worst, memory=_memory())
+                 max_rel_err=worst, kda_decode_ms=kda_ms, memory=_memory())
 
 
 # ------------------------------------------------------------------- train

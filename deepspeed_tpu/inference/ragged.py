@@ -2365,7 +2365,8 @@ class RaggedInferenceEngine:
         takes one program at a time whatever the threads, so what this buys
         is the overlap of the foreground's tracing, lowering and running with
         the compiling: 178 -> 146 s on the chip's host, twice (PERF.md
-        section 6, PR 31), not the several-fold a CPU-only compile shows."""
+        section 6, PR 31), not the several-fold a CPU-only compile shows.
+        Only a zoo of more programs than the pool has threads is worth it."""
         import os
         from concurrent.futures import ThreadPoolExecutor
 
@@ -2389,11 +2390,17 @@ class RaggedInferenceEngine:
                          f"program {key} failed: {e!r}", ranks=[0])
 
         workers = max(1, min(8, (os.cpu_count() or 2) - 2))
-        pool = ThreadPoolExecutor(workers, thread_name_prefix="ragged-compile")
         have = {key[:4] for key in self._dev_step_jits}
-        for key in self._step_zoo():
-            if key not in have:
-                pool.submit(compile_one, key)
+        todo = [key for key in self._step_zoo() if key not in have]
+        if len(todo) <= workers:
+            # every one of them would start at once and none be done before
+            # the foreground, slowed by all of them, has compiled it itself:
+            # 7 step programs of 6-14 s each took 148 s that way where the
+            # foreground alone takes ~75 (PERF.md section 6, PR 40)
+            return
+        pool = ThreadPoolExecutor(workers, thread_name_prefix="ragged-compile")
+        for key in todo:
+            pool.submit(compile_one, key)
         pool.shutdown(wait=False)
         log_dist(f"ragged engine: cold compilation cache; compiling the step "
                  f"programs {workers} at a time in the background", ranks=[0])
@@ -2721,16 +2728,25 @@ class RaggedInferenceEngine:
         misses = self._cache_misses() if probe else 0
         if self._faults.enabled:
             self._faults.fire(POINT_DISPATCH)
+        state = self._state_attr(n_dec, n_pre, n)
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
-                  **self._moe_attr(t_total),
-                  **self._state_attr(n_dec, n_pre, n), **sel,
+                  **self._moe_attr(t_total), **state, **sel,
                   **self._sel_decode_attr(width), **self._counts_attr()):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._bt_dev,
                 staged, self._sample_root)
+        if state and self.telemetry.enabled:
+            moved = self.telemetry.counter(
+                "inference_slot_state_bytes_total",
+                "slot-state bytes the steps had to read and write (a slot's "
+                "state once each way), by the rows that moved them")
+            kind = {k: v for k, v in state.items() if k == "state_kind"}
+            moved.inc(state["dec_state_bytes"], part="decode", **kind)
+            moved.inc(state["state_bytes"] - state["dec_state_bytes"],
+                      part="prefill", **kind)
         if sel and self.telemetry.enabled:
             tel = self.telemetry
             tel.counter("inference_sparse_selected_tokens_total",
@@ -2790,13 +2806,18 @@ class RaggedInferenceEngine:
         (its decode rows and its distinct prefilling slots, a slot's state
         once each way), ``dec_state_bytes`` the decode rows' part, and
         ``ssm_prefill_tokens``, the prompt tokens its tiles run through the
-        recurrence. Nothing for a model with none."""
+        recurrence, and ``state_kind``, the recurrence's name where the model
+        gives one (``ModelSpec.state_kind``: ``"mamba2"``, ``"kda"``). The
+        dispatch feeds the same bytes to ``inference_slot_state_bytes_total``
+        (``part`` ``decode`` / ``prefill``). Nothing for a model with none."""
         if not self._slot_state:
             return {}
         per_slot = 2 * self._slot_bytes
+        kind = self.spec.state_kind
         return {"state_bytes": (n_dec + n_pre) * per_slot,
                 "dec_state_bytes": n_dec * per_slot,
-                "ssm_prefill_tokens": n - n_dec}
+                "ssm_prefill_tokens": n - n_dec,
+                **({} if kind is None else {"state_kind": kind})}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:
         """The name a device-resident step program goes by in a trace: its

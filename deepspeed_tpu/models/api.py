@@ -392,6 +392,12 @@ class ModelSpec:
     # (``inference_<name>_total``); () for a family with none, whose step
     # programs have no such output
     step_counters: tuple = ()
+    # a family whose layers carry a recurrent state a slot (slot leaves in its
+    # paged cache, ``models/paged.py``) names the recurrence: ``"mamba2"`` (a
+    # scalar decay a head, the state fed by an outer product), ``"kda"`` (a
+    # decay a channel, the delta rule). ``state_kind`` on ``engine/dispatch``
+    # and the label of ``inference_slot_state_bytes_total``; None: no state
+    state_kind: str | None = None
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

@@ -15,7 +15,10 @@ Layer equations (pre-norm RMSNorm, a residual after each half):
   evaluation) computes exactly that on ``xla_attention``. ``rope_scaling``
   (YaRN) stretches the rotation's frequencies
   (``ops/attention.rope_frequencies``) and multiplies the softmax scale by
-  ``mscale**2``, ``mscale = 0.1 mscale_all_dim ln(factor) + 1``.
+  ``mscale**2``, ``mscale = 0.1 mscale_all_dim ln(factor) + 1``. With
+  ``mla_use_nope`` nothing is rotated: the ``rope`` lanes of ``q`` and of
+  the cached row stay as projected (``models/kimi_linear.py``, whose other
+  layers carry the position).
 - **Serving caches the row ``[c, k_rope]``** (``lat + rope`` lanes a token and
   layer, 1,152 B in bf16 at Moonlight's 512 + 64, against 8,192 B for 16 K
   and V heads of 128) and decodes **absorbed**: ``q_lat[h] = q_nope[h]
@@ -77,6 +80,10 @@ class DeepseekConfig:
     # sqrt(hidden_size / rank) (``models/longcat_flash.py`` sets both)
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # no rotation: the ``rope`` lanes of the query and of the cached row stay
+    # as projected (``models/kimi_linear.py`` sets it: its other layers carry
+    # the position)
+    mla_use_nope: bool = False
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -314,8 +321,9 @@ def _mla_inputs(cfg: DeepseekConfig, h, lp, positions, cq=None):
     """``h`` [T, D] (normed) -> ``q_nope`` [T, H, nope], roped ``q_rope``
     [T, H, rope], the normed latent ``c`` [T, lat] (times ``sqrt(hidden /
     lat)`` under ``mla_scale_kv_lora``: what is cached and what ``W_kvb``
-    multiplies), roped ``k_rope`` [T, rope]. ``cq``: ``_query_latent``'s,
-    where the caller has it."""
+    multiplies), roped ``k_rope`` [T, rope] (both ``rope`` parts as
+    projected, unrotated, under ``mla_use_nope``). ``cq``:
+    ``_query_latent``'s, where the caller has it."""
     t = h.shape[0]
     lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     if cfg.q_lora_rank is None:
@@ -327,7 +335,9 @@ def _mla_inputs(cfg: DeepseekConfig, h, lp, positions, cq=None):
     c = rmsnorm(a[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
     if cfg.mla_scale_kv_lora:
         c = c * (cfg.hidden_size / lat) ** 0.5
-    q_rope, k_rope = _rope(cfg, q[..., nope:], a[:, lat:], positions)
+    q_rope, k_rope = q[..., nope:], a[:, lat:]
+    if not cfg.mla_use_nope:
+        q_rope, k_rope = _rope(cfg, q_rope, k_rope, positions)
     return q[..., :nope], q_rope, c, k_rope
 
 

@@ -158,6 +158,7 @@ class LongcatFlashConfig:
 
     # ---- what ``models/deepseek``'s MLA helpers read off a config
     yarn = None                           # plain RoPE: no ``rope_scaling``
+    mla_use_nope = False                  # ... and every sublayer rotates
 
     @property
     def qk_head_dim(self) -> int:

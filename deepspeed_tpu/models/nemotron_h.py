@@ -702,4 +702,5 @@ def build(cfg: NemotronHConfig, ctx: ShardCtx | None = None) -> ModelSpec:
         moe_form=partial(expert_form, num_experts=cfg.num_experts,
                          top_k=cfg.top_k),
         decode_bucket_min=DECODE_BUCKET_MIN,
+        state_kind="mamba2",
     )

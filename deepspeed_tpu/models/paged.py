@@ -1,9 +1,12 @@
 """Shared KV-cache plumbing for the model families' inference paths.
 
-One home for the logic every family (llama, gpt2, mixtral) used to carry
-verbatim: the paged-pool KV scatter, the decode/tiled-prefill attention
-split over the block pool (reference ``inference/v2/ragged_ops`` layout),
-and the dense-cache append+attend used by the v1-style engines.
+One home for what every family's serving path shares (gpt2, llama, mixtral
+on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
+nemotron_h and kimi_linear with a recurrent state a slot beside either): the
+paged-pool scatter, the decode/tiled-prefill attention split over the block
+pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
+one or several kinds of layer, and the dense-cache append+attend used by the
+v1-style engines.
 
 **The paged contract** (docs/SERVING.md "The paged KV pool"), stated once:
 
@@ -33,10 +36,15 @@ and the dense-cache append+attend used by the v1-style engines.
 - A step program must never hold an array the size of a layer's slice of
   the pool (``tests/unit/test_compile_tpu.py`` asserts it on the compiled
   program): its pool traffic is the rows written plus the context read.
-- *Slot leaves.* A model whose layers carry a recurrent state (a
-  state-space layer: constant size, whatever the context) keeps it beside
-  the block leaves, under the cache's ``"slots"`` key: leaves ``[L_s, S,
-  ...]``, one row a layer and engine slot, ``S = max_seqs + 1``; the last
+- *Slot leaves.* A model whose layers carry a recurrent state (constant
+  size, whatever the context: a state-space layer, ``nemotron_h``'s
+  Mamba-2, whose state a head decays by one scalar and is fed an outer
+  product; a linear-attention layer, ``kimi_linear``'s Kimi Delta Attention,
+  whose state a key channel decays by its own factor and is fed by the
+  delta rule) keeps it beside the block leaves, K and V heads or a latent
+  row alike (``kimi_linear``: ``{"kv": [L_mla, NB, BS, 640], "slots":
+  {...}}``), under the cache's ``"slots"`` key: leaves ``[L_s, S, ...]``,
+  one row a layer and engine slot, ``S = max_seqs + 1``; the last
   row is the scratch slot padding rows use, as block 0 is for the pool.
   ``block_leaves`` / ``slot_leaves`` tell the two apart. The engine owns
   both in ``engine.cache`` and the family's ``init_paged_cache`` makes both
@@ -124,7 +132,7 @@ def stack_plan(pattern: str) -> tuple[str, str, int]:
 
 
 def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=(),
-                      block_layers: int = 1):
+                      block_layers: int = 1, tail=()):
     """Run ``layer_fn(x, lp, pool, layer_tables) -> (x, pool)`` over the
     stacked ``layers``. ``pool`` is the family's paged cache, any pytree of
     ``[L, NB, ...]`` arrays (``{"k", "v"}`` of fp arrays or of
@@ -148,10 +156,14 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=(),
 
     A stack of several kinds of layer, some with no blocks and some with a
     state a slot, gives ``layer_fn`` as a period of layers instead of one
-    function: ``_scan_periods`` below has that form's arguments.
+    function: ``_scan_periods`` below has that form's arguments (and its
+    ``tail``, the layers after the last whole period).
     """
     if not callable(layer_fn):
-        return _scan_periods(layer_fn, x, layers, pool, block_tables, lead)
+        return _scan_periods(layer_fn, x, layers, pool, block_tables, lead,
+                             tail)
+    if tail:
+        raise ValueError("a tail follows a scan over periods of layers only")
     leaves = jax.tree_util.tree_leaves(pool)
     n_layers, nb = leaves[0].shape[:2]
     n_lead = len(lead)
@@ -183,12 +195,13 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=(),
         lambda a: a.reshape((n_layers, nb) + a.shape[1:]), pool)
 
 
-def _scan_periods(period, x, layers, pool, block_tables, lead):
+def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
     """``scan_layers_paged`` for a stack of several kinds of layer: the body
     of the scan is a PERIOD of layers, ``period = [(kind, fn), ...]`` with
     ``layers`` a tuple of stacked trees, one a position of the period
-    (``[repeats, ...]`` each), and ``lead = [(kind, fn, lp), ...]`` before
-    it. ``kind`` says which leaves of the pool count the layer, and so what
+    (``[repeats, ...]`` each), ``lead = [(kind, fn, lp), ...]`` before it and
+    ``tail``, of the same form, after it (a published order that ends off
+    the period: ``kimi_linear``'s 27 layers). ``kind`` says which leaves of the pool count the layer, and so what
     it is handed as its address: ``"block"``, ``fn(x, lp, pool,
     layer_tables)`` as above, its layer among the block leaves' ``L``;
     ``"slot"``, ``fn(x, lp, pool, slot0)`` with ``slot0 = layer * S`` the
@@ -221,11 +234,13 @@ def _scan_periods(period, x, layers, pool, block_tables, lead):
     for kind, _ in period:
         index.append(at[kind] + per[kind] * jnp.arange(repeats, dtype=jnp.int32))
         at[kind] += 1
+    after = {k: seen[k] + per[k] * repeats for k in seen}
     for kind, (n, _) in shapes.items():
-        if seen[kind] + per[kind] * repeats != n:
+        have = after[kind] + sum(1 for k, _, _ in tail if k == kind)
+        if have != n:
             raise ValueError(
                 f"the cache's {kind} leaves hold {n} layers, the stack has "
-                f"{seen[kind] + per[kind] * repeats} of that kind")
+                f"{have} of that kind")
 
     def body(carry, xs):
         x, pool = carry
@@ -234,6 +249,9 @@ def _scan_periods(period, x, layers, pool, block_tables, lead):
         return (x, pool), None
 
     (x, pool), _ = lax.scan(body, (x, pool), (tuple(layers), tuple(index)))
+    for kind, fn, lp in tail:
+        x, pool = fn(x, lp, pool, address(kind, after[kind]))
+        after[kind] += 1
     out = jax.tree_util.tree_map(
         lambda a: a.reshape(shapes["block"] + a.shape[1:]), block_leaves(pool))
     if slots is not None:

@@ -326,17 +326,73 @@ def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e):
     assert mem.temp_size_in_bytes < 128 * 8192 * 4
 
 
+def _kda_decode(state, rows, a, k, q, v, beta):
+    from deepspeed_tpu.ops.pallas.kda import kda_decode
+
+    return kda_decode(state, rows, a, k, q, v, beta, impl="pallas",
+                      interpret=False)
+
+
+def _kda_args(devices, rows=128):
+    """The KDA state of the Kimi-Linear cell: 10 layers x 129 slots of [128,
+    4096] float32 (32 heads of 128 x 128), a decode bucket of ``rows``."""
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    return (s((10 * 129, 128, 4096)), s((rows,), jnp.int32),
+            s((rows, 128, 32)), s((rows, 128, 32)), s((rows, 128, 32)),
+            s((rows, 4096)), s((rows, 4096)))
+
+
+def test_kda_decode_compiles_for_v5e_and_updates_in_place(v5e):
+    """A row's whole state (2 MB) a grid step, in and out double-buffered, a
+    head's [128, 1] columns broadcast over its lanes; the donated state is the
+    output (aliased), and nothing else in the program is as large as ONE
+    row's state. The tile rows' read and write compile too, the write in
+    place."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    compiled = jax.jit(_kda_decode, donate_argnums=(0,)).lower(
+        *_kda_args(v5e)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 10 * 129 * 128 * 4096 * 4
+    assert mem.temp_size_in_bytes < 128 * 4096 * 4
+    state, rows = _kda_args(v5e, rows=3)[:2]
+    tiles = jax.ShapeDtypeStruct((3, 128, 4096), jnp.float32,
+                                 sharding=state.sharding)
+
+    def move(state, rows, new):
+        got = kda.state_rows_read(state, rows, impl="pallas", interpret=False)
+        return kda.state_rows_write(state, rows, new + got, impl="pallas",
+                                    interpret=False)
+
+    compiled = jax.jit(move, donate_argnums=(0,)).lower(
+        state, rows, tiles).compile()
+    names = [ln.split(" = ")[0] for ln in compiled.as_text().splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sum("kda_state_read" in n for n in names) == 1
+    assert sum("kda_state_write" in n for n in names) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 10 * 129 * 128 * 4096 * 4
+    assert mem.temp_size_in_bytes < 8 * 128 * 4096 * 4
+
+
 @pytest.mark.parametrize("kernel,name", [
     (_flash_fwd, "flash_fwd"), (_flash_bwd, "flash_bwd_dkv"),
     (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
     (_prefill, "tiled_prefill"), (_mla_decode, "mla_decode"),
     (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm"),
     ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode"),
+    ("kda_decode", "kda_decode"),
     (_dsa_index, "dsa_index"), (_dsa_decode, "dsa_attn_decode"),
     (_dsa_prefill, "dsa_attn_prefill"), (_dsa_walk, "dsa_attn_decode")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
          "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm",
-         "moe_gmm_ungated", "ssm_decode", "dsa_index", "dsa_attn_decode",
+         "moe_gmm_ungated", "ssm_decode", "kda_decode", "dsa_index",
+         "dsa_attn_decode",
          "dsa_attn_prefill", "dsa_attn_decode_walk"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     """``pl.pallas_call(name=...)``: the compiled custom call is
@@ -364,6 +420,9 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     elif kernel == "ssm_decode":
         text = jax.jit(_ssm_decode).lower(
             *_ssm_args(v5e, rows=8)).compile().as_text()
+    elif kernel == "kda_decode":
+        text = jax.jit(_kda_decode).lower(
+            *_kda_args(v5e, rows=8)).compile().as_text()
     elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill, _dsa_walk):
         text = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile().as_text()
     else:
@@ -771,6 +830,76 @@ def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
     assert sum("ssm_decode" in n for n in names) == (1 if rows else 0)
     # the whole state's and a layer's shape appear as nothing but the
     # parameter, its merged view, the loop's carry and the in-place updates
+    assert compiled.memory_analysis().temp_size_in_bytes < min(state_slice,
+                                                               pool_slice)
+
+
+@pytest.mark.parametrize("rows,tiles", [(8, 1), (8, 0), (0, 2)],
+                         ids=["mixed", "decode", "prefill"])
+def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
+                                                            rows, tiles):
+    """The same clause for the first cache that has slot leaves BESIDE a
+    latent pool, at Kimi-Linear's KDA and MLA widths and the cell's pool and
+    slots (FFN, experts and vocabulary small): lead ``D``, period ``KM``. The
+    decode rows go through ``kda_decode`` (state aliased in and out), the
+    tiles' rows through ``kda_state_read`` / ``kda_state_write`` (in place),
+    the MLA layer's latent rows are scattered as in ``deepseek``. No gather,
+    copy or re-layout of the float32 state ``[3 x 129, 128, 4096]``: in a
+    step program with tiles and NO decode row nothing held the array's layout
+    until the tiles' rows moved through kernels, and XLA laid all of it out
+    with the key channels on the lanes, 2.7 GB in and out at the cell's ten
+    layers (the compiled program, PR 40)."""
+    from deepspeed_tpu.models import kimi_linear
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=512, num_layers=5, intermediate_size=256,
+        moe_intermediate_size=128, num_experts=16, experts_held=4, top_k=6,
+        linear_attn_config={"kda_layers": [1, 2, 4], "full_attn_layers": [3, 5],
+                            "head_dim": 128, "num_heads": 32,
+                            "short_conv_kernel_size": 4})
+    assert kimi_linear._plan(cfg.layer_pattern) == ("D", "KM", 2, "")
+    blocks, block, table, slots = 4097, 128, 32, 129
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        kimi_linear.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: kimi_linear.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16, num_slots=slots))
+    kda = cache["slots"]["kda"]
+    assert kda.shape == (3, slots, 128, 4096) and kda.dtype == jnp.float32
+    assert cache["kv"].shape == (2, blocks, block, 640)
+    state_slice = slots * 128 * 4096 * 4
+    pool_slice = blocks * block * 640 * 2
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return kimi_linear.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    t, nt = rows + tiles * TILE, max(tiles, 1)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(slots, table), i32(nt), i32(nt), i32(nt)).compile()
+    text = compiled.as_text()
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= min(state_slice, pool_slice)]
+    # in place: the latent rows' scatter, the kernels' aliased state
+    assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert sum("kda_decode" in n for n in names) == (2 if rows else 0)
+    assert sum("kda_state_write" in n for n in names) == (2 if tiles else 0)
+    assert sum("mla_decode" in n for n in names) == (1 if rows else 0)
     assert compiled.memory_analysis().temp_size_in_bytes < min(state_slice,
                                                                pool_slice)
 

@@ -330,7 +330,14 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                      # / ``moe_held_picks`` in its dispatch spans)
                      **{m: "v5e_longcat_flash_reason_spans" for m in (
                          "sched.moe_zero_pick_share",
-                         "sched.moe_held_rows_per_expert")}}
+                         "sched.moe_held_rows_per_expert")},
+                     # PR 40: the second recurrence's kernel and the state's
+                     # share of a step's bytes read a slice of the cell whose
+                     # spans say ``state_kind`` "kda"
+                     **{m: "v5e_kimi_linear_reason_spans" for m in (
+                         "kernel.kda_decode_share",
+                         "kernel.kda_decode_roofline",
+                         "sched.state_bytes_share")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -339,7 +346,9 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "v5e_deepseek_v32_longctx_spans":
                    "deepseek-v32-exp-d5-ep16.longctx-pool",
                    "v5e_longcat_flash_reason_spans":
-                   "longcat-flash-omni-d4-ep32.reason-pool"}
+                   "longcat-flash-omni-d4-ep32.reason-pool",
+                   "v5e_kimi_linear_reason_spans":
+                   "kimi-linear-48b-a3b-d13-ep8.reason-pool"}
 
 
 def _new_readers():

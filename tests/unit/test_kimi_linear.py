@@ -1,0 +1,475 @@
+"""``models/kimi_linear.py`` at a small size on the CPU, seeded weights: what is
+served (prefill in tiles, then decode, through the latent pool AND the slot
+state) against the plain reference ``benchmark/reference/kimi_linear.py``; the
+chunk form of the delta rule against the token-by-token recurrence at the
+strongest decay the configuration can draw; the decode kernel against XLA's
+form; one rank's share of the experts; MLA with and without rotation; what a
+dispatch span says of the state.
+
+Logits are compared, not tokens. Tolerance 2e-4 (float32 everywhere here): the
+program runs a prompt as chunks (a triangular solve and matmuls inside a chunk,
+the state carried between them) and the reference as a scan over tokens, so the
+same sums are taken in another order; observed differences are under 2e-6 on
+logits of magnitude 0.6.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
+from deepspeed_tpu.models import deepseek, experts, kimi_linear
+from deepspeed_tpu.models.paged import SLOTS
+from deepspeed_tpu.ops.pallas.kda import kda_decode, kda_decode_xla
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ATOL = 2e-4
+
+
+def _reference():
+    spec = importlib.util.spec_from_file_location(
+        "reference_kimi_linear",
+        os.path.join(REPO, "benchmark", "reference", "kimi_linear.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+CFG = kimi_linear.KimiLinearConfig.tiny()   # "DKMKM", 4 of 8 experts held
+
+
+@pytest.fixture(scope="module")
+def params():
+    return kimi_linear.init_params(CFG, jax.random.PRNGKey(1))
+
+
+def _engine(params, cfg=CFG, device_state=False, **sizes):
+    rc = RaggedConfig(**{**dict(
+        max_tokens_per_step=32, max_seqs=4, block_size=8, num_blocks=33,
+        max_blocks_per_seq=8, prefill_tile=8, device_state=device_state),
+        **sizes})
+    return RaggedInferenceEngine(lambda ctx: kimi_linear.build(cfg, ctx=ctx),
+                                 rc, dtype=jnp.float32, params=params)
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return {uid: rng.integers(0, CFG.vocab_size, n).tolist()
+            for uid, n in enumerate(lengths)}
+
+
+# case -> (engine sizes, prompt lengths, new tokens, step after which the
+# watchdog's recovery runs, or None)
+SERVED = {
+    # 13 tokens, tile 8: two tiles of ONE slot in one step, the second partial
+    "prompt_in_one_step": ({}, [13], 4, None),
+    # 16 a step: 16 + 16 + 5, a partial last tile, the state carried over steps
+    "prompt_chunked_over_steps": ({"max_tokens_per_step": 16}, [37], 4, None),
+    # six requests over four slots: decode rows beside tiles, slots reused
+    "mixed_steps": ({}, [5, 19, 37, 9, 26, 3], 6, None),
+    # one slot: the second request starts from zeros where the first ended
+    "slot_reused": ({"max_seqs": 1}, [11, 7], 5, None),
+    # positions rewound mid-flight (what a preempted or contained request
+    # gets): the state restarts from zeros with a re-prefill from position 0
+    "recovered_and_recomputed": ({}, [5, 19, 37, 9], 8, 4),
+    # 8 blocks of 8 for three requests that grow to 27 + 34 + 29 tokens: the
+    # third waits for the blocks of the first and takes over its slot's rows
+    "tight_pool": ({"num_blocks": 9}, [19, 26, 21], 8, None),
+    # three decoders in a bucket of four: a padding row on the scratch slot
+    "bucket_padding_rows": ({}, [6, 9, 4], 5, None),
+}
+
+
+def _serve(eng, prompts, new_tokens, recover_after=None):
+    """Run the requests to their end; ``{(uid, g): logits row}`` of every
+    emission of the host-staged path (generated token ``g`` of ``uid``; a
+    recomputed request's later emission replaces its earlier one)."""
+    rows = {}
+    emit_tokens = eng._emit_tokens
+
+    def recording(logits, emit):
+        lg = np.asarray(logits)
+        for row, seq in emit:
+            rows[(seq.uid, len(seq.generated))] = lg[row]
+        return emit_tokens(logits, emit)
+
+    eng._emit_tokens = recording
+    for uid, prompt in prompts.items():
+        eng.put(uid, prompt, max_new_tokens=new_tokens)
+    steps = 0
+    while eng.has_work:
+        eng.step()
+        steps += 1
+        if steps == recover_after:
+            eng._recover_device_path()
+        assert steps < 500
+    return rows
+
+
+@pytest.mark.parametrize("case", SERVED)
+def test_served_logits_match_the_reference(params, case):
+    sizes, lengths, new_tokens, recover_after = SERVED[case]
+    eng = _engine(params, **sizes)
+    prompts = _prompts(lengths)
+    rows = _serve(eng, prompts, new_tokens, recover_after)
+    for uid, prompt in prompts.items():
+        generated = eng.get_request(uid).generated
+        assert len(generated) == new_tokens
+        want = np.asarray(REF.forward(CFG, params,
+                                      jnp.asarray(prompt + generated)))
+        for g in range(new_tokens):
+            np.testing.assert_allclose(
+                rows[(uid, g)], want[len(prompt) + g - 1], atol=ATOL,
+                err_msg=f"{case}: request {uid}, generated token {g}")
+    # the scratch slot is what padding rows and tiles read and write: zero
+    # before, zero after
+    slots = eng.cache[SLOTS]
+    assert not np.asarray(slots["kda"][:, -1]).any()
+    assert not np.asarray(slots["conv"][:, -1]).any()
+    assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1
+
+
+@pytest.mark.parametrize("case", ["mixed_steps", "recovered_and_recomputed",
+                                  "slot_reused"])
+def test_device_resident_path_serves_the_reference_tokens(params, case):
+    """The device-resident step (slot rows, picks on the device) against the
+    reference's greedy tokens, teacher-forced on what was served."""
+    sizes, lengths, new_tokens, recover_after = SERVED[case]
+    eng = _engine(params, device_state=True, **sizes)
+    prompts = _prompts(lengths)
+    _serve(eng, prompts, new_tokens, recover_after)
+    for uid, prompt in prompts.items():
+        generated = eng.get_request(uid).generated[:new_tokens]
+        want = np.asarray(REF.forward(CFG, params,
+                                      jnp.asarray(prompt + generated)))
+        greedy = want.argmax(-1)[len(prompt) - 1:len(prompt) + new_tokens - 1]
+        assert generated == greedy.tolist(), (case, uid)
+
+
+@pytest.mark.parametrize("pattern", ["DKMKM", "DKKMKKKMKM", "AKMKM"])
+def test_plain_forward_is_the_reference(pattern):
+    """The family's ``forward`` (the chunk form, chunks of 8 in sub-chunks of
+    4) against the reference's token-by-token recurrence: the published order's
+    three kinds of layer, a tail after the last whole period (``D`` + 2 x
+    ``KKMK`` + ``M``), and a leading layer that is MLA + dense."""
+    cfg = kimi_linear.KimiLinearConfig.tiny(pattern=pattern)
+    p = kimi_linear.init_params(cfg, jax.random.PRNGKey(3))
+    lead, period, repeats, tail = kimi_linear._plan(cfg.layer_pattern)
+    assert lead + period * repeats + tail == pattern
+    assert (len(p["lead"]), len(p["period"]), len(p["tail"])) == (
+        len(lead), len(period), len(tail))
+    ids = jnp.asarray(_prompts([41], seed=3)[0])
+    np.testing.assert_allclose(
+        np.asarray(kimi_linear.forward(cfg, p, ids[None])[0]),
+        np.asarray(REF.forward(cfg, p, ids)), atol=ATOL)
+    assert kimi_linear.num_params(cfg) == REF.num_params(cfg) == sum(
+        a.size for a in jax.tree_util.tree_leaves(p))
+    axes = kimi_linear.param_logical_axes(cfg)
+    is_axes = lambda a: isinstance(a, tuple)  # noqa: E731
+    assert jax.tree_util.tree_structure(p) == jax.tree_util.tree_structure(
+        axes, is_leaf=is_axes)
+    for leaf, ax in zip(jax.tree_util.tree_leaves(p),
+                        jax.tree_util.tree_leaves(axes, is_leaf=is_axes)):
+        assert leaf.ndim == len(ax)
+
+
+def test_a_tail_after_the_scan_is_served(params):
+    """``D`` + 2 x ``KKMK`` + ``M``: the last MLA layer runs after the scan, at
+    block layer 2 of 3 (``paged._scan_periods``' ``tail``)."""
+    cfg = kimi_linear.KimiLinearConfig.tiny(pattern="DKKMKKKMKM")
+    assert kimi_linear._plan(cfg.layer_pattern) == ("D", "KKMK", 2, "M")
+    p = kimi_linear.init_params(cfg, jax.random.PRNGKey(4))
+    eng = _engine(p, cfg=cfg, max_tokens_per_step=16, max_seqs=2,
+                  num_blocks=17, max_blocks_per_seq=4)
+    assert eng.cache["kv"].shape[0] == 3 and eng.cache[SLOTS]["kda"].shape[0] == 7
+    prompt = _prompts([21], seed=9)[0]
+    eng.put(0, prompt, max_new_tokens=3)
+    got = list(eng.generate_all()[0])
+    want = np.asarray(REF.forward(cfg, p, jnp.asarray(prompt + got)))
+    assert got == want.argmax(-1)[len(prompt) - 1:len(prompt) + 2].tolist()
+
+
+def test_the_published_order_has_a_plan():
+    full = kimi_linear.KimiLinearConfig()
+    assert full.layer_pattern == "D" + "KKMK" * 5 + "KKMKKM"
+    lead, period, repeats, tail = kimi_linear._plan(full.layer_pattern)
+    assert len(lead + period + tail) == 7 and repeats == 6
+    # 49.12 B whole (published: 48B); the benchmark's cut is in its own test
+    assert REF.num_params(full) == kimi_linear.num_params(full) == 49_122_681_728
+    with pytest.raises(NotImplementedError, match="repeated period"):
+        kimi_linear.KimiLinearConfig.tiny(pattern="DM")
+
+
+# ------------------------------------------------------- the chunk form
+def _token_by_token(q, k, v, g, beta, s0):
+    """The recurrence as written, one tile after another of ONE sequence:
+    ``q`` .. [I, R, H, *], ``s0`` [K, H x V] -> ``(y [I, R, H x V], s)``."""
+    n_i, r, h, kd = q.shape
+    vd = v.shape[-1]
+
+    def token(state, xs):                                         # [H, K, V]
+        q_t, k_t, v_t, g_t, b_t = xs
+        state = jnp.exp(g_t)[:, :, None] * state
+        u = b_t[:, None] * (v_t - jnp.einsum("hkv,hk->hv", state, k_t))
+        state = state + k_t[:, :, None] * u[:, None, :]
+        return state, jnp.einsum("hkv,hk->hv", state, q_t)
+
+    flat = [t.reshape((n_i * r,) + t.shape[2:]) for t in (q, k, v, g, beta)]
+    state, y = jax.lax.scan(
+        token, s0.reshape(kd, h, vd).transpose(1, 0, 2), tuple(flat))
+    return (y.reshape(n_i, r, h * vd),
+            state.transpose(1, 0, 2).reshape(kd, h * vd))
+
+
+@pytest.mark.parametrize("tiles", [1, 4])
+def test_chunk_form_is_the_recurrence_at_the_strongest_decay(tiles):
+    """A 128-row tile (and four with the carry) in sub-chunks of 16 at the
+    strongest decay the configuration can draw, ``A = 16`` and ``dt = 0.1``
+    on every channel of half the heads (``g = -1.6`` a token, ``G = -205``
+    over the tile: ``exp(-G)`` alone is float32's ``inf``), the seeded range
+    on the others, from a state that is not zero: finite, and the
+    token-by-token recurrence to float32 rounding (2e-5 on readings of
+    magnitude ~1: a 16 x 16 inverse, 8 substitution steps and the pairwise
+    sums in another order)."""
+    rng = np.random.default_rng(tiles)
+    r, h, kd = 128, 4, 32
+    shape = (tiles, r, h, kd)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(rng.standard_normal(shape)) * kd ** -0.5
+    k = unit(rng.standard_normal(shape))
+    v = rng.standard_normal(shape)
+    dt = np.exp(rng.uniform(np.log(0.001), np.log(0.1), shape))
+    g = -rng.uniform(1.0, 16.0, (1, 1, h, 1)) * dt
+    g[:, :, :h // 2] = -16.0 * 0.1
+    beta = rng.uniform(0.0, 1.0, shape[:3])
+    s0 = rng.standard_normal((kd, h * kd))
+    q, k, v, g, beta, s0 = (jnp.asarray(a, jnp.float32)
+                            for a in (q, k, v, g, beta, s0))
+    assert float(jnp.cumsum(g, axis=1).min()) < -200.0
+    cont = jnp.arange(tiles) > 0
+    got_y, got_s = kimi_linear.kda_tiles(
+        q, k, v, g, beta, jnp.broadcast_to(s0, (tiles,) + s0.shape), cont, 16)
+    want_y, want_s = _token_by_token(q, k, v, g, beta, s0)
+    assert np.isfinite(np.asarray(got_y)).all()
+    assert np.isfinite(np.asarray(got_s)).all()
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got_s[-1]), np.asarray(want_s),
+                               atol=2e-5)
+
+
+def test_rows_past_a_tiles_valid_ones_neither_decay_nor_feed():
+    rng = np.random.default_rng(5)
+    r, h, kd, valid = 16, 2, 8, 11
+    q, k, v = (jnp.asarray(rng.standard_normal((1, r, h, kd)), jnp.float32)
+               for _ in range(3))
+    g = -jnp.asarray(rng.uniform(0.01, 1.0, (1, r, h, kd)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0, 1, (1, r, h)), jnp.float32)
+    s0 = jnp.asarray(rng.standard_normal((1, kd, h * kd)), jnp.float32)
+    live = (jnp.arange(r) < valid)[None, :, None]
+    _, s_pad = kimi_linear.kda_tiles(
+        q, k, v, jnp.where(live[..., None], g, 0.0), jnp.where(live, beta, 0.0),
+        s0, jnp.zeros((1,), bool), 4)
+    _, s_cut = _token_by_token(q[:, :valid], k[:, :valid], v[:, :valid],
+                               g[:, :valid], beta[:, :valid], s0[0])
+    np.testing.assert_allclose(np.asarray(s_pad[0]), np.asarray(s_cut),
+                               atol=1e-5)
+
+
+# ------------------------------------------------------------ the kernel
+def test_kda_decode_kernel_is_the_xla_form():
+    """``kda_decode`` in interpret mode against gather -> update -> scatter,
+    and both against the recurrence a row; two padding rows (``a = 1``,
+    ``beta = 0``) on the scratch row leave it as it was, and no row outside
+    the step's moves."""
+    rng = np.random.default_rng(0)
+    rows_n, kd, h, vd, t = 10, 16, 2, 128, 5
+    state = jnp.asarray(rng.standard_normal((rows_n, kd, h * vd)), jnp.float32)
+    rows = jnp.asarray([3, 7, 9, 1, 9], jnp.int32)              # 9: scratch
+    a = jnp.asarray(rng.uniform(0.2, 1.0, (t, kd, h)), jnp.float32)
+    k = rng.standard_normal((t, kd, h))
+    k = jnp.asarray(k / np.linalg.norm(k, axis=1, keepdims=True), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((t, kd, h)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((t, h * vd)), jnp.float32)
+    beta = jnp.repeat(jnp.asarray(rng.uniform(0, 1, (t, h)), jnp.float32), vd, 1)
+    pad = jnp.asarray([False, False, True, False, True])
+    a = jnp.where(pad[:, None, None], 1.0, a)
+    beta = jnp.where(pad[:, None], 0.0, beta)
+    got_s, got_y = kda_decode(state, rows, a, k, q, v, beta, impl="pallas",
+                              interpret=True)
+    want_s, want_y = kda_decode_xla(state, rows, a, k, q, v, beta)
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y), atol=1e-4)
+    untouched = [0, 2, 4, 5, 6, 8, 9]
+    np.testing.assert_array_equal(np.asarray(got_s)[untouched],
+                                  np.asarray(state)[untouched])
+    # row 0 of the step, head 1, as the recurrence writes it
+    s = np.asarray(state[3]).reshape(kd, h, vd)[:, 1]
+    d = np.asarray(a[0, :, 1])[:, None] * s
+    u = float(beta[0, vd]) * (np.asarray(v[0, vd:]) - d.T @ np.asarray(k[0, :, 1]))
+    new = d + np.outer(np.asarray(k[0, :, 1]), u)
+    np.testing.assert_allclose(
+        np.asarray(got_s[3]).reshape(kd, h, vd)[:, 1], new, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_y[0, vd:]),
+                               new.T @ np.asarray(q[0, :, 1]), atol=1e-4)
+
+
+def test_padding_rows_leave_other_slots_alone(params):
+    """A step of one real decode row and three padding rows: the slots that
+    are not in the step keep their state bit for bit."""
+    cache = kimi_linear.init_paged_cache(CFG, 9, 8, jnp.float32, num_slots=5)
+    key = jax.random.PRNGKey(5)
+    cache[SLOTS]["kda"] = jax.random.normal(key, cache[SLOTS]["kda"].shape
+                                            ).at[:, -1].set(0.0)
+    before = np.asarray(cache[SLOTS]["kda"])
+    tables = np.zeros((5, 2), np.int32)
+    tables[2] = [3, 4]
+    i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+    _, cache = kimi_linear.ragged_forward(
+        CFG, params, i32([7, 0, 0, 0]), i32([2, 4, 4, 4]), i32([5, 0, 0, 0]),
+        jnp.asarray(tables), cache,
+        prefill_tiles=(4, i32([4]), i32([0]), i32([0]), 8))
+    after = np.asarray(cache[SLOTS]["kda"])
+    np.testing.assert_array_equal(after[:, [0, 1, 3, 4]], before[:, [0, 1, 3, 4]])
+    assert (after[:, 2] != before[:, 2]).any()
+
+
+# --------------------------------------------------- one rank's share
+def test_eight_ranks_parts_add_up_to_the_uncut_layer():
+    """The share test of the model-configs guide, section 4: an expert layer
+    with all 16 experts against the eight ranks' layers of 2 experts each,
+    the same router over all 16. The ranks' routed parts add up and the
+    shared expert counts once: the uncut layer of the reference."""
+    whole = kimi_linear.KimiLinearConfig.tiny(num_experts=16, experts_held=None)
+    full = jax.tree_util.tree_map(
+        lambda a: a[0],
+        kimi_linear.init_params(whole, jax.random.PRNGKey(2))["period"][0]["ffn"])
+    h = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (23, whole.hidden_size)), jnp.float32)
+    want = np.asarray(REF._moe(whole, h, full, jnp.float32))
+    shared = experts.swiglu(h, full["ws_gate"], full["ws_up"], full["ws_down"])
+    routed = 0.0
+    for rank in range(8):
+        cfg = kimi_linear.KimiLinearConfig.tiny(
+            num_experts=16, experts_held=2, expert_rank=rank)
+        lp = {**full, **{w: full[w][2 * rank:2 * rank + 2]
+                         for w in ("w_gate", "w_up", "w_down")}}
+        layer = deepseek._ffn(cfg, h, lp, experts.routed_experts)
+        # a rank's own layer is what the reference computes for that rank
+        np.testing.assert_allclose(
+            np.asarray(layer), np.asarray(REF._moe(cfg, h, lp, jnp.float32)),
+            atol=ATOL)
+        routed = routed + (layer - shared)
+    np.testing.assert_allclose(np.asarray(routed + shared), want, atol=ATOL)
+
+
+# ------------------------------------------------------ MLA, no positions
+def test_mla_without_rotation_is_the_reference_and_differs_from_rotated():
+    """``mla_use_nope``: the shared MLA helpers leave the 64 "rope" lanes of
+    the query and of the key as projected; with it off they rotate them, as
+    ``deepseek``'s own configs do (its tests run unchanged). Both against the
+    reference's layer, and against each other."""
+    lp = jax.tree_util.tree_map(
+        lambda a: a[0],
+        kimi_linear.init_params(CFG, jax.random.PRNGKey(6))["period"][1]["mix"])
+    # seeded at std 0.02 the scores are too flat for a rotation to show
+    lp = {**lp, "wq": lp["wq"] * 8, "wkv_a": lp["wkv_a"] * 8}
+    h = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (1, 19, CFG.hidden_size)), jnp.float32)
+    pos = jnp.arange(19)[None]
+    out = {}
+    for nope in (True, False):
+        cfg = kimi_linear.KimiLinearConfig.tiny(mla_use_nope=nope)
+        out[nope] = np.asarray(deepseek._plain_attention(cfg, h, lp, pos)[0])
+        np.testing.assert_allclose(
+            out[nope], np.asarray(REF._mla(cfg, h[0], lp, jnp.float32)),
+            atol=1e-5)
+    assert np.abs(out[True] - out[False]).max() > 1e-3  # 4e-3 on outputs of ~6e-3
+    assert not deepseek.DeepseekConfig.tiny().mla_use_nope
+
+
+# ------------------------------------------------------------ the engine
+def test_engine_accounts_blocks_and_slots_apart(params):
+    """The latent leaf counts the 2 MLA layers, the slot leaves the 3 KDA
+    layers (``S`` and the three convolutions' rows): the reference's
+    geometry, which the benchmark's readers multiply the spans by."""
+    eng = _engine(params)
+    assert eng.cache["kv"].shape[0] == 2 and eng.cache[SLOTS]["kda"].shape[0] == 3
+    assert eng.kv_bytes_per_token() == CFG.row_lanes * 4 * 2
+    assert REF.kv_bytes_per_token(CFG, 4) == (32 + 16) * 4 * 2
+    assert eng.state_bytes_per_slot() == REF.state_bytes_per_slot(CFG, 4) \
+        == 3 * (4 * 2 * 16 * 16 + 3 * 3 * 32 * 4)
+
+
+@pytest.mark.parametrize("family", ["kimi_linear", "nemotron_h"])
+def test_dispatch_span_says_what_state_the_step_moved(params, family,
+                                                      monkeypatch):
+    """``engine/dispatch`` of a model with slot state: ``state_bytes`` (decode
+    rows + distinct prefilling slots, a slot's bytes once each way),
+    ``dec_state_bytes``, ``ssm_prefill_tokens`` and ``state_kind`` (``"kda"``
+    here, ``"mamba2"`` for ``nemotron_h``); the same bytes on
+    ``inference_slot_state_bytes_total``, decode and prefill parts labelled."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.inference import ragged
+    from deepspeed_tpu.models import nemotron_h
+
+    seen = []
+    real = ragged.span
+    monkeypatch.setattr(ragged, "span", lambda name, **a: (
+        seen.append(a) if name == "engine/dispatch" else None, real(name, **a))[1])
+    telemetry.configure(enabled=True)
+    try:
+        if family == "kimi_linear":
+            eng, kind = _engine(params, device_state=True), "kda"
+        else:
+            cfg = nemotron_h.NemotronHConfig.tiny()
+            eng = RaggedInferenceEngine(
+                lambda ctx: nemotron_h.build(cfg, ctx=ctx), RaggedConfig(
+                    max_tokens_per_step=32, max_seqs=4, block_size=8,
+                    num_blocks=33, max_blocks_per_seq=8, prefill_tile=8),
+                dtype=jnp.float32)
+            kind = "mamba2"
+        for uid, prompt in _prompts([11, 5]).items():
+            eng.put(uid, prompt, max_new_tokens=4)
+        eng.generate_all()
+        series = telemetry.snapshot()["metrics"][
+            "inference_slot_state_bytes_total"]["series"]
+    finally:
+        telemetry.configure(enabled=False)
+    per_slot = 2 * eng.state_bytes_per_slot()
+    assert seen and all(a["state_kind"] == kind for a in seen)
+    # the first step prefills both prompts (two slots), the others decode
+    assert seen[0]["state_bytes"] == 2 * per_slot
+    assert seen[0]["dec_state_bytes"] == 0
+    assert seen[0]["ssm_prefill_tokens"] == 16
+    assert all(a["state_bytes"] == a["dec_state_bytes"] == 2 * per_slot
+               and a["ssm_prefill_tokens"] == 0 for a in seen[1:-1])
+    by_part = {s["labels"]["part"]: s["value"] for s in series
+               if s["labels"].get("state_kind") == kind}
+    assert by_part["decode"] == sum(a["dec_state_bytes"] for a in seen)
+    assert by_part["prefill"] == sum(
+        a["state_bytes"] - a["dec_state_bytes"] for a in seen)
+
+
+def test_decode_ladder_and_refusals(params):
+    eng = _engine(params, max_tokens_per_step=512, max_seqs=128,
+                  num_blocks=257, max_blocks_per_seq=2, prefill_tile=128)
+    assert eng._dec_buckets == [128] and len(eng._step_zoo()) == 7
+    assert eng.spec.state_kind == "kda"
+    for sizes, match in ((dict(enable_prefix_cache=True), "snapshot"),
+                         (dict(sched_steps=2), "sched_steps"),
+                         (dict(quant="int8"), "quantized pool"),
+                         (dict(prefill_tile=0), "tile")):
+        with pytest.raises((ValueError, NotImplementedError), match=match):
+            _engine(params, **sizes)
+    odd = _engine(params, prefill_tile=6, block_size=6, max_tokens_per_step=12)
+    odd.put(0, [1, 2, 3, 4, 5, 6, 7], max_new_tokens=1)
+    with pytest.raises(ValueError, match="sub-chunk"):
+        odd.generate_all()

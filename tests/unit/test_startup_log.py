@@ -131,10 +131,15 @@ def test_asked_hit_and_written_follow_the_persistent_cache(watch, tmp_path):
 
     x = jnp.ones(3)     # built here, before anybody can be asked
     keep = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache",
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes")}
     try:
+        # ``test_compile_tpu.py``'s module fixture turns the cache off while
+        # its tests run, and under ``--dist load`` one of them may be this
+        # worker's neighbour
+        jax.config.update("jax_enable_compilation_cache", True)
         jax.config.update("jax_compilation_cache_dir", str(tmp_path))
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 3600.0)
