@@ -67,14 +67,16 @@ in the latent pool, ``cache["kv"]`` ``[L_mla, NB, BS, row_lanes]``; the KDA
 layers' state lies beside it in slot leaves (``models/paged.py``):
 ``cache["slots"]["kda"]`` ``[L_kda, S, K, H x V]`` float32 (the key channels on
 the sublanes, a head's values side by side on the lanes:
-``ops/pallas/kda.py``) and ``["conv"]`` ``[L_kda, S, 3, 3 P]`` (the last three
-rows of the three convolutions' inputs ``[q | k | v]``, oldest first). A
-decode row is one delta-rule update of its slot's state (``kda_decode``), a
-prefill tile one chunk, the state carried from tile to tile of a slot in
-order (the forward substitution and that carry are ``lax.scan``s: as Python
-loops a tiled step program compiles 3-6 s later and its mixed step runs 8%
-sooner, PERF.md section 6, PR 40). A row or tile at position 0 starts from
-zeros whatever the slot held.
+``ops/pallas/kda.py``) and ``["conv"]``, the last three rows of the three
+convolutions' inputs ``[q | k | v]``, oldest first, as a window leaf
+(``models/paged.py``: ``[L_kda, S, 3 x 16, 3 P / 16]`` in bfloat16, a row's
+channels folded over a sublane tile's rows; ``[L_kda, S, 3, 3 P]`` for a width
+the tile does not divide). A decode row is one delta-rule update of its slot's
+state (``kda_decode``), a prefill tile one chunk, the state carried from tile
+to tile of a slot in order (the forward substitution and that carry are
+``lax.scan``s: as Python loops a tiled step program compiles 3-6 s later and
+its mixed step runs 8% sooner, PERF.md section 6, PR 40). A row or tile at
+position 0 starts from zeros whatever the slot held.
 
 **One rank's share.** ``experts_held`` of the ``num_experts`` routed experts
 live here (``expert_rank``'s); the router scores and picks over all of them.
@@ -463,14 +465,18 @@ def _kda_inputs(cfg: KimiLinearConfig, h, lp):
     return h @ lp["w_qkv"].astype(dtype), g, beta, gate
 
 
-def _conv(cfg: KimiLinearConfig, win, lp, rows: int):
+def _conv(cfg: KimiLinearConfig, win, w, rows: int):
     """The three causal depthwise convolutions as one over ``[q | k | v]``,
     and silu: ``win`` [..., rows + kernel - 1, 3 P] (the ``kernel - 1`` rows
-    before the first, then the rows) -> [..., rows, 3 P] float32."""
-    w = lp["conv_w"].astype(jnp.float32)
+    before the first, then the rows) and ``w`` [kernel, 3 P] -> [..., rows,
+    3 P] float32. The channels may be folded over two axes in both, as a
+    window leaf keeps them (``paged.window_fold``): the result's are too."""
+    w = w.astype(jnp.float32)
+    axis = win.ndim - w.ndim
     acc = 0.0
     for j in range(cfg.conv_kernel):
-        acc = acc + win[..., j:j + rows, :].astype(jnp.float32) * w[j]
+        acc = acc + lax.slice_in_dim(win, j, j + rows, axis=axis).astype(
+            jnp.float32) * w[j]
     return jax.nn.silu(acc)
 
 
@@ -617,7 +623,7 @@ def _kda_sequence(cfg: KimiLinearConfig, lp, h):
     s, r, kc = h.shape[0], cfg.chunk_size, cfg.conv_kernel
     qkv, g, beta, gate = _kda_inputs(cfg, h, lp)
     win = jnp.concatenate([jnp.zeros((kc - 1, qkv.shape[1]), qkv.dtype), qkv])
-    q, k, v = _qkv_split(cfg, _conv(cfg, win, lp, s))
+    q, k, v = _qkv_split(cfg, _conv(cfg, win, lp["conv_w"], s))
     pad = -s % r
 
     def chunks(t):
@@ -680,10 +686,11 @@ def init_paged_cache(cfg: KimiLinearConfig, num_blocks: int, block_size: int,
     latent pool as ONE block leaf, ``"kv"`` ``[L_mla, num_blocks, block_size,
     row_lanes]`` (a row ``[c, k_pe, zeros]``), and the KDA layers' state as
     slot leaves under ``"slots"``: ``kda`` ``[L_kda, num_slots, K, H x V]``
-    float32 and ``conv`` ``[L_kda, num_slots, kernel - 1, 3 P]``, the
-    convolutions' carried rows, oldest first. The last slot is the scratch
-    slot."""
-    from deepspeed_tpu.models.paged import SLOTS
+    float32 and ``conv``, the convolutions' ``kernel - 1`` carried rows of
+    ``3 P`` channels, oldest first, as a window leaf
+    (``paged.init_window_leaf``: ``[L_kda, num_slots, (kernel - 1) x r, 3 P /
+    r]``). The last slot is the scratch slot."""
+    from deepspeed_tpu.models.paged import SLOTS, init_window_leaf
 
     if codec is not None:
         raise NotImplementedError(
@@ -699,8 +706,8 @@ def init_paged_cache(cfg: KimiLinearConfig, num_blocks: int, block_size: int,
         SLOTS: {
             "kda": jnp.zeros((n_kda, num_slots, cfg.kda_head_dim,
                               cfg.kda_width), jnp.float32),
-            "conv": jnp.zeros((n_kda, num_slots, cfg.conv_kernel - 1,
-                               3 * cfg.kda_width), dtype),
+            "conv": init_window_leaf(n_kda, num_slots, cfg.conv_kernel - 1,
+                                     3 * cfg.kda_width, dtype),
         },
     }
 
@@ -711,6 +718,11 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
     batch: ``state`` the slot leaves, layers and slots merged; this layer's
     slot ``s`` is row ``slot0 + s``; ``scratch`` the scratch slot. Returns
     ``(out [T, D], state)``."""
+    from deepspeed_tpu.models.paged import (
+        decode_windows,
+        tile_windows,
+        window_fold,
+    )
     from deepspeed_tpu.ops.pallas.kda import (
         kda_decode,
         state_rows_read,
@@ -718,7 +730,7 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
     )
 
     kda, conv = state["kda"], state["conv"]
-    k1, vd = cfg.conv_kernel - 1, cfg.kda_head_dim
+    vd = cfg.kda_head_dim
 
     qkv, g, beta, gate = _kda_inputs(cfg, h, lp)
     t = h.shape[0]
@@ -728,12 +740,12 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
         real = slots[:n_dec] != scratch
         fresh = real & (positions[:n_dec] == 0)
         rows = slots[:n_dec] + slot0
-        tail = conv[rows]
-        win = jnp.concatenate([jnp.where(fresh[:, None, None], 0, tail),
-                               qkv[:n_dec, None]], axis=1)
-        conv = conv.at[rows].set(
-            jnp.where(real[:, None, None], win[:, 1:], tail))
-        qd, kd, vv = _qkv_split(cfg, _conv(cfg, win, lp, 1)[:, 0])
+        # the window's arithmetic runs on the channels as the leaf folds
+        # them: the rows come and go as whole tiles, the 128 new rows and
+        # the weights are what is folded, the 128 results what is unfolded
+        win, conv = decode_windows(conv, rows, qkv[:n_dec], fresh, real)
+        qd, kd, vv = _qkv_split(cfg, _conv(
+            cfg, win, window_fold(conv, lp["conv_w"]), 1).reshape(n_dec, -1))
         # a padding row neither decays nor feeds; position 0 starts from zeros
         a = jnp.where(fresh[:, None, None], 0.0, jnp.exp(
             jnp.where(real[:, None, None], g[:n_dec], 0.0)))
@@ -751,22 +763,18 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
         n_i = ts.shape[0]
         real = ts != scratch
         rows = ts + slot0
-        fresh = (tp == 0)[:, None, None]
+        fresh = tp == 0
         # tile i goes on where tile i - 1 of the same slot ended
         cont = jnp.concatenate([jnp.zeros((1,), bool),
                                 (ts[1:] == ts[:-1]) & real[1:]])
         write = real & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
-        qkv_t = qkv[n_dec:].reshape(n_i, r, -1)
-        carried = jnp.concatenate(
-            [jnp.zeros((1, k1, qkv_t.shape[2]), qkv_t.dtype),
-             qkv_t[:-1, r - k1:]])
-        tail = jnp.where(cont[:, None, None], carried,
-                         jnp.where(fresh, 0, conv[rows]))
-        win = jnp.concatenate([tail, qkv_t], axis=1)
-        # the kernel - 1 rows before the tile's first invalid one
-        new_tail = jax.vmap(
-            lambda w, v: lax.dynamic_slice_in_dim(w, v, k1, axis=0))(win, tv)
-        qt, kt, vt = _qkv_split(cfg, _conv(cfg, win, lp, r))
+        # a tile that is not its slot's last of the step, and a padding
+        # tile, write the scratch slot, and write it zeros
+        rows_w = jnp.where(write, rows, slot0 + scratch)
+        win, conv = tile_windows(conv, rows, rows_w,
+                                 qkv[n_dec:].reshape(n_i, r, -1), cont, fresh,
+                                 write, tv)
+        qt, kt, vt = _qkv_split(cfg, _conv(cfg, win, lp["conv_w"], r))
         valid = (jnp.arange(r)[None, :] < tv[:, None])[..., None]
         gt = jnp.where(valid[..., None],
                        g[n_dec:].reshape((n_i, r) + g.shape[1:]), 0.0)
@@ -775,13 +783,10 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
         # kernels that do nothing else (``ops/pallas/kda.py`` says why)
         y, s_new = kda_tiles(
             qt, kt, vt, gt, bt,
-            jnp.where(fresh, 0.0, state_rows_read(kda, rows)), cont, sub)
-        # a tile that is not its slot's last of the step, and a padding
-        # tile, write the scratch slot, and write it zeros
-        rows_w = jnp.where(write, rows, slot0 + scratch)
+            jnp.where(fresh[:, None, None], 0.0, state_rows_read(kda, rows)),
+            cont, sub)
         kda = state_rows_write(kda, rows_w,
                                jnp.where(write[:, None, None], s_new, 0.0))
-        conv = conv.at[rows_w].set(jnp.where(write[:, None, None], new_tail, 0))
         ys.append(y.reshape(n_i * r, -1))
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
     return _kda_out(cfg, y, gate, lp), {"kda": kda, "conv": conv}

@@ -36,14 +36,16 @@ other ranks or their exchange.
 NB, BS, Hkv x D]``); the Mamba layers' state lies beside it in slot leaves
 (``models/paged.py``): ``ssm`` ``[L_mamba, S, N, H x P]`` float32 (the state
 size first, a head's ``P`` values side by side on the lanes:
-``ops/pallas/ssm.py`` says why) and ``conv`` ``[L_mamba, S, K - 1, conv
-width]``. A ragged step is decode rows, then prefill tiles. A decode row is
-one update of its slot's state (``ssm_decode``). A tile is one chunk of the
-chunked (SSD) form: matmuls inside the chunk, the state carried from tile to
-tile of a slot in order, the first from the slot's state; ``dt = 0`` on a
-tile's rows past its valid ones, so that they neither decay nor feed the
-state. A row or tile at position 0 starts from zeros whatever the slot held.
-The stack runs as its leading layers and a scan over the period of the
+``ops/pallas/ssm.py`` says why) and ``conv``, the convolution's last ``K -
+1`` input rows as a window leaf (``models/paged.py``: ``[L_mamba, S, (K - 1) x
+16, conv width / 16]`` in bfloat16; ``[L_mamba, S, K - 1, conv width]`` for a
+width the tile does not divide). A ragged step is decode rows, then prefill
+tiles. A decode row is one update of its slot's state (``ssm_decode``). A tile
+is one chunk of the chunked (SSD) form: matmuls inside the chunk, the state
+carried from tile to tile of a slot in order, the first from the slot's state;
+``dt = 0`` on a tile's rows past its valid ones, so that they neither decay nor
+feed the state. A row or tile at position 0 starts from zeros whatever the slot
+held. The stack runs as its leading layers and a scan over the period of the
 pattern (``paged.stack_plan``): a step program compiles one period's body.
 
 Multi-token prediction (``num_nextn_predict_layers``) is a drafting module
@@ -299,14 +301,18 @@ def _mamba_split(cfg: NemotronHConfig, h, lp):
     return zxbcdt[..., :di], zxbcdt[..., di:di + cfg.conv_width], dt
 
 
-def _conv(cfg: NemotronHConfig, win, lp, rows: int):
+def _conv(cfg: NemotronHConfig, win, w, b, rows: int):
     """Causal depthwise convolution and silu: ``win`` [..., rows + K - 1,
-    C] (the ``K - 1`` rows before the first, then the rows) -> [..., rows,
-    C] in ``win``'s dtype, float32 inside."""
-    w = lp["conv_w"].astype(jnp.float32)
-    acc = lp["conv_b"].astype(jnp.float32)
+    C] (the ``K - 1`` rows before the first, then the rows), ``w`` [K, C] and
+    ``b`` [C] -> [..., rows, C] in ``win``'s dtype, float32 inside. The
+    channels may be folded over two axes in all three, as a window leaf keeps
+    them (``paged.window_fold``): the result's are too."""
+    w = w.astype(jnp.float32)
+    axis = win.ndim - w.ndim
+    acc = b.astype(jnp.float32)
     for k in range(cfg.conv_kernel):
-        acc = acc + win[..., k:k + rows, :].astype(jnp.float32) * w[k]
+        acc = acc + lax.slice_in_dim(win, k, k + rows, axis=axis).astype(
+            jnp.float32) * w[k]
     return jax.nn.silu(acc).astype(win.dtype)
 
 
@@ -390,7 +396,7 @@ def _mamba_sequence(cfg: NemotronHConfig, lp, h):
     s, r, k = h.shape[0], cfg.chunk_size, cfg.conv_kernel
     z, xbc, dt = _mamba_split(cfg, h, lp)
     win = jnp.concatenate([jnp.zeros((k - 1, xbc.shape[1]), xbc.dtype), xbc])
-    x, b, c = _xbc_split(cfg, _conv(cfg, win, lp, s))
+    x, b, c = _xbc_split(cfg, _conv(cfg, win, lp["conv_w"], lp["conv_b"], s))
     pad = -s % r
     a = -jnp.exp(lp["a_log"].astype(jnp.float32))
 
@@ -454,9 +460,14 @@ def init_paged_cache(cfg: NemotronHConfig, num_blocks: int, block_size: int,
     layers' pool as block leaves, ``{"k", "v"}`` of ``[L_attn, num_blocks,
     block_size, Hkv x D]``, and the Mamba layers' state as slot leaves under
     ``"slots"``: ``ssm`` ``[L_mamba, num_slots, N, H x P]`` float32 and
-    ``conv`` ``[L_mamba, num_slots, K - 1, conv width]``. The last slot is
-    the scratch slot."""
-    from deepspeed_tpu.models.paged import SLOTS, init_paged_pool
+    ``conv``, the convolution's ``K - 1`` carried rows as a window leaf
+    (``paged.init_window_leaf``: ``[L_mamba, num_slots, (K - 1) x r, conv
+    width / r]``). The last slot is the scratch slot."""
+    from deepspeed_tpu.models.paged import (
+        SLOTS,
+        init_paged_pool,
+        init_window_leaf,
+    )
 
     if codec is not None:
         raise NotImplementedError(
@@ -471,8 +482,8 @@ def init_paged_cache(cfg: NemotronHConfig, num_blocks: int, block_size: int,
     cache[SLOTS] = {
         "ssm": jnp.zeros((lm, num_slots, cfg.ssm_state_size, cfg.d_inner),
                          jnp.float32),
-        "conv": jnp.zeros((lm, num_slots, cfg.conv_kernel - 1, cfg.conv_width),
-                          dtype),
+        "conv": init_window_leaf(lm, num_slots, cfg.conv_kernel - 1,
+                                 cfg.conv_width, dtype),
     }
     return cache
 
@@ -504,11 +515,16 @@ def _mamba_ragged(cfg: NemotronHConfig, x, lp, state, slot0, scratch, slots,
     """The Mamba layer over a flat ragged token batch ``x`` [T, D]:
     ``state`` the slot leaves, layers and slots merged; this layer's slot
     ``s`` is row ``slot0 + s``; ``scratch`` the scratch slot."""
+    from deepspeed_tpu.models.paged import (
+        decode_windows,
+        tile_windows,
+        window_fold,
+    )
     from deepspeed_tpu.ops.pallas.ssm import ssm_decode
 
     f32 = jnp.float32
     ssm, conv = state["ssm"], state["conv"]
-    k1, p = cfg.conv_kernel - 1, cfg.mamba_head_dim
+    p = cfg.mamba_head_dim
     h = rmsnorm(x, lp["norm"], cfg.rms_norm_eps)
     z, xbc, dt = _mamba_split(cfg, h, lp)
     a = -jnp.exp(lp["a_log"].astype(f32))
@@ -517,16 +533,18 @@ def _mamba_ragged(cfg: NemotronHConfig, x, lp, state, slot0, scratch, slots,
     ys, xs = [], []
     if n_dec:
         real = slots[:n_dec] != scratch
-        fresh = (real & (positions[:n_dec] == 0))[:, None]
+        fresh = real & (positions[:n_dec] == 0)
         rows = slots[:n_dec] + slot0
-        tail = conv[rows]
-        win = jnp.concatenate([jnp.where(fresh[..., None], 0, tail),
-                               xbc[:n_dec, None]], axis=1)
-        conv = conv.at[rows].set(
-            jnp.where(real[:, None, None], win[:, 1:], tail))
-        xd, bd, cd = _xbc_split(cfg, _conv(cfg, win, lp, 1)[:, 0])
+        # the window's arithmetic runs on the channels as the leaf folds
+        # them: the rows come and go as whole tiles, the step's new rows and
+        # the weights are what is folded, its results what is unfolded
+        win, conv = decode_windows(conv, rows, xbc[:n_dec], fresh, real)
+        xd, bd, cd = _xbc_split(cfg, _conv(
+            cfg, win, window_fold(conv, lp["conv_w"]),
+            window_fold(conv, lp["conv_b"]), 1).reshape(n_dec, -1))
         dtd = jnp.where(real[:, None], dt[:n_dec], 0.0)
-        da = jnp.where(fresh, 0.0, jnp.exp(dtd * a))   # position 0: from zeros
+        # position 0: from zeros
+        da = jnp.where(fresh[:, None], 0.0, jnp.exp(dtd * a))
         ssm, y = ssm_decode(
             ssm, rows, jnp.repeat(da, p, axis=1),
             (dtd[..., None] * xd.astype(f32)).reshape(n_dec, -1),
@@ -538,22 +556,19 @@ def _mamba_ragged(cfg: NemotronHConfig, x, lp, state, slot0, scratch, slots,
         n_i = ts.shape[0]
         real = ts != scratch
         rows = ts + slot0
-        fresh = (tp == 0)[:, None, None]
+        fresh = tp == 0
         # tile i goes on where tile i - 1 of the same slot ended
         cont = jnp.concatenate([jnp.zeros((1,), bool),
                                 (ts[1:] == ts[:-1]) & real[1:]])
         write = real & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
-        xbc_t = xbc[n_dec:].reshape(n_i, r, -1)
-        carried = jnp.concatenate(
-            [jnp.zeros((1, k1, xbc_t.shape[2]), xbc_t.dtype),
-             xbc_t[:-1, r - k1:]])
-        tail = jnp.where(cont[:, None, None], carried,
-                         jnp.where(fresh, 0, conv[rows]))
-        win = jnp.concatenate([tail, xbc_t], axis=1)
-        # the K - 1 rows before the tile's first invalid one
-        new_tail = jax.vmap(
-            lambda w, v: lax.dynamic_slice_in_dim(w, v, k1, axis=0))(win, tv)
-        xt, bt, ct = _xbc_split(cfg, _conv(cfg, win, lp, r))
+        # a tile that is not its slot's last of the step, and a padding
+        # tile, write the scratch slot, and write it zeros
+        rows_w = jnp.where(write, rows, slot0 + scratch)
+        win, conv = tile_windows(conv, rows, rows_w,
+                                 xbc[n_dec:].reshape(n_i, r, -1), cont, fresh,
+                                 write, tv)
+        xt, bt, ct = _xbc_split(cfg, _conv(cfg, win, lp["conv_w"],
+                                           lp["conv_b"], r))
         valid = jnp.arange(r)[None, :] < tv[:, None]
         dtt = jnp.where(valid[..., None], dt[n_dec:].reshape(n_i, r, -1), 0.0)
         # a tile's state is read and written as ONE row of the leaf, a
@@ -563,14 +578,10 @@ def _mamba_ragged(cfg: NemotronHConfig, x, lp, state, slot0, scratch, slots,
         s_old = jnp.stack([lax.dynamic_index_in_dim(ssm, rows[i], 0, False)
                            for i in range(n_i)])
         y, s_new = ssd_tiles(cfg, xt, dtt, a, bt, ct,
-                             jnp.where(fresh, 0.0, s_old), cont)
-        # a tile that is not its slot's last of the step, and a padding
-        # tile, write the scratch slot, and write it zeros
-        rows_w = jnp.where(write, rows, slot0 + scratch)
+                             jnp.where(fresh[:, None, None], 0.0, s_old), cont)
         s_new = jnp.where(write[:, None, None], s_new, 0.0)
         for i in range(n_i):
             ssm = lax.dynamic_update_index_in_dim(ssm, s_new[i], rows_w[i], 0)
-        conv = conv.at[rows_w].set(jnp.where(write[:, None, None], new_tail, 0))
         ys.append(y.reshape(n_i * r, -1))
         xs.append(xt.reshape((n_i * r,) + xt.shape[2:]))
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)

@@ -59,6 +59,23 @@ v1-style engines.
   slot held (a reused slot, a preempted request recomputed), so there is
   no reset program. Padding rows read and write the scratch slot and leave
   it zero.
+- *Window leaves.* A causal convolution's carried rows (a slot's last
+  ``K - 1`` inputs of ``W`` channels, oldest first: both families') are a
+  slot leaf whose slot is WHOLE TILES, as the float32 state's is: ``[L_s,
+  S, (K - 1) x r, W / r]``, each row's channels folded over the ``r`` rows
+  of the dtype's sublane tile (16 bfloat16: ``kimi_linear`` ``[10, 129, 48,
+  768]``, ``nemotron_h`` ``[5, 129, 48, 640]``; row ``j`` is rows ``[r j,
+  r j + r)``). With the ``K - 1`` rows themselves on the sublanes (``[..,
+  3, 12288]``) the compiler keeps the argument in an axis order of its own,
+  copies the whole leaf to a padded layout and back every step and, short
+  of memory, compresses and uncompresses it between the layers (9.4% of
+  the Kimi-Linear cell's device time, PERF.md section 6, PR 41); as one
+  flat row a slot the layers x slots merge is a copy. A width that ``r x
+  128`` does not divide keeps ``[L_s, S, K - 1, W]``. ``init_window_leaf``
+  builds the leaf, ``read_windows`` / ``write_windows`` / ``window_fold``
+  read the form off the array, ``decode_windows`` / ``tile_windows`` are a
+  step's decode rows and prefill tiles through them: one gather and one
+  scatter of the step's rows a layer and nothing else of the leaf's size.
 - What a prefix of blocks cannot restore, refuses: the engine raises at
   construction for ``enable_prefix_cache``, ``kv_tier``, ``KVHandoff``
   (all need a state snapshot at a block boundary), ``sched_steps`` /
@@ -106,6 +123,80 @@ def block_leaves(cache):
     if slot_leaves(cache) is None:
         return cache
     return {k: v for k, v in cache.items() if k != SLOTS}
+
+
+def init_window_leaf(layers: int, slots: int, taps: int, width: int, dtype):
+    """The slot leaf of a causal convolution's carried rows (module doc,
+    *Window leaves*): a slot's last ``taps`` rows of ``width`` channels,
+    oldest first, ``[L_s, S, taps x r, width / r]`` with ``r`` the rows of
+    the dtype's sublane tile (8 float32, 16 bfloat16), or ``r = 1``
+    (``[L_s, S, taps, width]``) for a width that ``r x 128`` does not
+    divide."""
+    r = 32 // jnp.dtype(dtype).itemsize
+    if width % (r * 128):
+        r = 1
+    return jnp.zeros((layers, slots, taps * r, width // r), dtype)
+
+
+def window_fold(leaf, x):
+    """``x`` [..., W] (rows, or weights a channel) with its channels as the
+    window leaf keeps them: ``[..., r, W / r]``."""
+    lanes = leaf.shape[-1]
+    return x.reshape(x.shape[:-1] + (x.shape[-1] // lanes, lanes))
+
+
+def read_windows(leaf, rows, width: int):
+    """The windows of the merged leaf's ``rows``: ``[n, taps, r, W / r]``
+    (``.reshape(n, taps, W)`` is the rows themselves)."""
+    lanes = leaf.shape[-1]
+    return leaf[rows].reshape(rows.shape[0], -1, width // lanes, lanes)
+
+
+def write_windows(leaf, rows, win):
+    """Scatter ``win`` (``[n, taps, r, W / r]`` or ``[n, taps, W]``) into the
+    merged leaf's ``rows``, in place."""
+    return leaf.at[rows].set(win.reshape((rows.shape[0],) + leaf.shape[1:]))
+
+
+def decode_windows(leaf, rows, new, fresh, real):
+    """A step's decode rows ``new`` [n, W] behind their slots' windows:
+    ``(win [n, taps + 1, r, W / r], leaf)``, the leaf with each window moved
+    on by its row. A ``fresh`` row (position 0) starts from zeros whatever
+    the slot held; one that is not ``real`` (padding) leaves its slot, the
+    scratch slot, as it was."""
+    tail = read_windows(leaf, rows, new.shape[-1])
+    # the rows are folded as a copy of their own (3 MB at 128 rows of 12,288):
+    # handed the fold behind the projection that made them, the compiler
+    # transposes the layer's whole projection matrix (56 MB) every step so
+    # as to emit the rows folded (``tests/unit/test_compile_tpu.py``)
+    new = lax.optimization_barrier(new)
+    win = jnp.concatenate([jnp.where(fresh[:, None, None, None], 0, tail),
+                           window_fold(leaf, new)[:, None]], axis=1)
+    return win, write_windows(
+        leaf, rows, jnp.where(real[:, None, None, None], win[:, 1:], tail))
+
+
+def tile_windows(leaf, rows, rows_w, tiles, cont, fresh, write, valid):
+    """A step's prefill ``tiles`` [n_i, R, W] behind their windows: ``(win
+    [n_i, taps + R, W], leaf)``. Tile ``i`` goes on where tile ``i - 1``
+    ended if it ``cont``inues that tile's slot, from zeros if it is ``fresh``
+    (position 0), else from the window of ``rows[i]``. Its first
+    ``valid[i]`` rows are valid: where ``write[i]``, the ``taps`` rows before
+    the first that is not are the new window of ``rows_w[i]``; elsewhere
+    ``rows_w[i]`` is the scratch slot's row (a tile that is not its slot's
+    last of the step, a padding tile) and is written zeros."""
+    n_i, r, w = tiles.shape
+    taps = leaf.shape[-2] * leaf.shape[-1] // w
+    carried = jnp.concatenate([jnp.zeros((1, taps, w), tiles.dtype),
+                               tiles[:-1, r - taps:]])
+    held = read_windows(leaf, rows, w).reshape(n_i, taps, w)
+    tail = jnp.where(cont[:, None, None], carried,
+                     jnp.where(fresh[:, None, None], 0, held))
+    win = jnp.concatenate([tail, tiles], axis=1)
+    new_tail = jax.vmap(
+        lambda x, v: lax.dynamic_slice_in_dim(x, v, taps, axis=0))(win, valid)
+    return win, write_windows(
+        leaf, rows_w, jnp.where(write[:, None, None], new_tail, 0))
 
 
 def stack_plan(pattern: str) -> tuple[str, str, int]:

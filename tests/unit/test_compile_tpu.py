@@ -4,7 +4,10 @@ at the block/tile sizes the engines really use. Interpret mode cannot see what
 these see: a slice off the tiling, more scoped VMEM than a kernel may take.
 Nothing runs, so this says nothing about results or speed."""
 
+import dataclasses
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -763,10 +766,54 @@ def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
 
 
 # ------------------------------------------------- the contract's slot leaves
+def _tiled_bytes(shape, layout, itemsize):
+    """Bytes an array of ``shape`` occupies as the compiler lays it out:
+    ``layout`` is the HLO's ``{minor_to_major:T(rows,lanes)...}``. The two
+    minor axes are padded to whole tiles; a ``(2,1)`` second tile packs two
+    rows of a 2-byte type into one sublane."""
+    import math
+    import re
+
+    order = [int(i) for i in layout.strip("{}").split(":")[0].split(",")]
+    rows, lanes = map(int, re.search(r"T\((\d+),(\d+)\)", layout).groups())
+    packed = re.search(r"\)\((\d+),1\)", layout)
+    rows *= int(packed.group(1)) if packed else 1
+    dims = [shape[i] for i in order]
+    dims[0] = math.ceil(dims[0] / lanes) * lanes
+    dims[1] = math.ceil(dims[1] / rows) * rows
+    return math.prod(dims) * itemsize
+
+
+def _window_leaf_stays_put(text, leaf, scatters):
+    """The window leaf's clause of the contract (``models/paged.py``, *Window
+    leaves*), on the optimized HLO ``text`` of a step program whose cache
+    holds ``leaf``: the argument is whole tiles (no axis padded by more than
+    a tenth, no axis order of the compiler's own: the layers x slots merge is
+    a bitcast), the only arrays of its size with a buffer of their own are
+    the rows' ``scatters`` (in place), and the compiler neither compresses
+    nor uncompresses anything between uses."""
+    shape = ",".join(map(str, leaf.shape))
+    logical = math.prod(leaf.shape) * leaf.dtype.itemsize
+    entry = _computations(text)[re.search(r"ENTRY (%[\w.\-]+)", text).group(1)]
+    layouts = [m.group(1) for ln in entry for m in [re.search(
+        rf"= bf16\[{shape}\](\{{[^ ]*\}}) parameter\(", ln)] if m]
+    assert len(layouts) == 1, layouts
+    assert layouts[0].startswith("{3,2,1,0:"), layouts[0]
+    assert _tiled_bytes(leaf.shape, layouts[0], 2) <= 1.1 * logical, layouts[0]
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= logical]
+    assert [ln for _, op, ln in big
+            if op not in ("scatter", "kernel", "update")] == []
+    assert len([ln for size, _, ln in big if size == logical]) == scatters
+    assert "remat_compressed" not in text and "remat_uncompressed" not in text
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["state", "window"])
 @pytest.mark.parametrize("rows,tiles", [(8, 1), (8, 0), (0, 2)],
                          ids=["mixed", "decode", "prefill"])
 def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
-                                                             rows, tiles):
+                                                             rows, tiles,
+                                                             window):
     """The paged contract's clause for slot leaves (``models/paged.py``), on
     the compiled program, at Nemotron-3-Super's Mamba and attention widths
     and the cell's pool and slots (FFN, experts and vocabulary small, so an
@@ -778,7 +825,14 @@ def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
     gather, copy or re-layout of the float32 state ``[2 x 129, 128, 8192]``
     or of one layer's ``[129, 128, 8192]``: with a whole-row gather XLA
     re-laid the entire leaf out in lane quarters, and with one einsum over
-    the group axis it transposed it, every step (PERF.md section 6, PR 31)."""
+    the group axis it transposed it, every step (PERF.md section 6, PR 31).
+
+    ``window``: the same for the convolution's window leaf, at the cell's
+    five Mamba layers and a hidden size small enough that the leaf (129
+    slots x 3 rows of 10,240 channels a layer) is the largest bf16 array
+    beside the pool: ``_window_leaf_stays_put``. With the three rows on the
+    sublanes the compiler kept the argument in an axis order of its own and
+    copied the whole leaf to a padded layout and back, every step (PR 41)."""
     from deepspeed_tpu.models import nemotron_h
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
@@ -786,6 +840,10 @@ def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
         vocab_size=512, num_layers=5, hybrid_override_pattern="*EMEM",
         moe_intermediate_size=128, moe_shared_expert_intermediate_size=256,
         num_experts=16, experts_held=4, top_k=6)
+    if window:
+        cfg = dataclasses.replace(cfg, hidden_size=512, num_layers=11,
+                                  hybrid_override_pattern="*" + "EM" * 5)
+    mamba = cfg.layers_of("M")
     blocks, block, table, slots = 4097, 128, 32, 129
     dev = jax.sharding.SingleDeviceSharding(v5e[0])
 
@@ -803,7 +861,7 @@ def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
     cache = jax.eval_shape(lambda: nemotron_h.init_paged_cache(
         cfg, blocks, block, jnp.bfloat16, num_slots=slots))
     ssm = cache["slots"]["ssm"]
-    assert ssm.shape == (2, slots, 128, 8192) and ssm.dtype == jnp.float32
+    assert ssm.shape == (mamba, slots, 128, 8192) and ssm.dtype == jnp.float32
     assert cache["k"].shape == (1, blocks, block, 256)
     state_slice = slots * 128 * 8192 * 4
     pool_slice = blocks * block * 256 * 2
@@ -832,12 +890,18 @@ def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
     # parameter, its merged view, the loop's carry and the in-place updates
     assert compiled.memory_analysis().temp_size_in_bytes < min(state_slice,
                                                                pool_slice)
+    if window:
+        # one layer body: a scatter of the decode rows, one of the tiles
+        _window_leaf_stays_put(text, cache["slots"]["conv"],
+                               bool(rows) + bool(tiles))
 
 
+@pytest.mark.parametrize("window", [False, True], ids=["state", "window"])
 @pytest.mark.parametrize("rows,tiles", [(8, 1), (8, 0), (0, 2)],
                          ids=["mixed", "decode", "prefill"])
 def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
-                                                            rows, tiles):
+                                                            rows, tiles,
+                                                            window):
     """The same clause for the first cache that has slot leaves BESIDE a
     latent pool, at Kimi-Linear's KDA and MLA widths and the cell's pool and
     slots (FFN, experts and vocabulary small): lead ``D``, period ``KM``. The
@@ -848,17 +912,32 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
     step program with tiles and NO decode row nothing held the array's layout
     until the tiles' rows moved through kernels, and XLA laid all of it out
     with the key channels on the lanes, 2.7 GB in and out at the cell's ten
-    layers (the compiled program, PR 40)."""
+    layers (the compiled program, PR 40).
+
+    ``window``: the same for the convolutions' window leaf, at the cell's
+    thirteen layers (ten of them KDA) and a hidden size small enough that
+    the leaf (129 slots x 3 rows of 12,288 channels a layer) is the largest
+    bf16 array beside the pool: ``_window_leaf_stays_put``. With the three
+    rows on the sublanes the compiler copied the whole leaf to a padded
+    layout and back and, in the cell's program, compressed and uncompressed
+    it between the layers: 9.4% of the cell's device time (ledger, PR 40)."""
     from deepspeed_tpu.models import kimi_linear
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    widths = {"head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4}
     cfg = kimi_linear.KimiLinearConfig(
         vocab_size=512, num_layers=5, intermediate_size=256,
         moe_intermediate_size=128, num_experts=16, experts_held=4, top_k=6,
         linear_attn_config={"kda_layers": [1, 2, 4], "full_attn_layers": [3, 5],
-                            "head_dim": 128, "num_heads": 32,
-                            "short_conv_kernel_size": 4})
+                            **widths})
     assert kimi_linear._plan(cfg.layer_pattern) == ("D", "KM", 2, "")
+    if window:
+        cfg = dataclasses.replace(
+            cfg, hidden_size=512, num_layers=13, linear_attn_config={
+                "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13],
+                "full_attn_layers": [4, 8, 12], **widths})
+    bodies = "".join(kimi_linear._plan(cfg.layer_pattern)[i] for i in (0, 1, 3))
+    n_kda, n_mla = bodies.count("D") + bodies.count("K"), bodies.count("M")
     blocks, block, table, slots = 4097, 128, 32, 129
     dev = jax.sharding.SingleDeviceSharding(v5e[0])
 
@@ -876,8 +955,9 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
     cache = jax.eval_shape(lambda: kimi_linear.init_paged_cache(
         cfg, blocks, block, jnp.bfloat16, num_slots=slots))
     kda = cache["slots"]["kda"]
-    assert kda.shape == (3, slots, 128, 4096) and kda.dtype == jnp.float32
-    assert cache["kv"].shape == (2, blocks, block, 640)
+    assert kda.shape == (cfg.layers_of("DK"), slots, 128, 4096)
+    assert kda.dtype == jnp.float32
+    assert cache["kv"].shape == (cfg.layers_of("MA"), blocks, block, 640)
     state_slice = slots * 128 * 4096 * 4
     pool_slice = blocks * block * 640 * 2
 
@@ -897,11 +977,19 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
     assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
     names = [ln.split(" = ")[0] for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
-    assert sum("kda_decode" in n for n in names) == (2 if rows else 0)
-    assert sum("kda_state_write" in n for n in names) == (2 if tiles else 0)
-    assert sum("mla_decode" in n for n in names) == (1 if rows else 0)
+    assert sum("kda_decode" in n for n in names) == (n_kda if rows else 0)
+    assert sum("kda_state_write" in n for n in names) == (n_kda if tiles else 0)
+    assert sum("mla_decode" in n for n in names) == (n_mla if rows else 0)
     assert compiled.memory_analysis().temp_size_in_bytes < min(state_slice,
                                                                pool_slice)
+    if window:
+        # a KDA layer body: a scatter of the decode rows, one of the tiles
+        _window_leaf_stays_put(text, cache["slots"]["conv"],
+                               n_kda * (bool(rows) + bool(tiles)))
+        # the step's rows are folded as a copy of their own: handed the fold
+        # behind the projection, the compiler transposed a layer's whole
+        # ``w_qkv`` to emit the decode rows folded (``decode_windows``)
+        assert not re.search(r"= bf16\[1,512,12288\]\S* copy\(", text)
 
 
 # ------------------------------------------------ ZeRO stage 3 on four chips

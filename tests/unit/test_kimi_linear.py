@@ -22,7 +22,8 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
-from deepspeed_tpu.models import deepseek, experts, kimi_linear
+from deepspeed_tpu.models import deepseek, experts, kimi_linear, nemotron_h
+from deepspeed_tpu.models import paged
 from deepspeed_tpu.models.paged import SLOTS
 from deepspeed_tpu.ops.pallas.kda import kda_decode, kda_decode_xla
 
@@ -473,3 +474,132 @@ def test_decode_ladder_and_refusals(params):
     odd.put(0, [1, 2, 3, 4, 5, 6, 7], max_new_tokens=1)
     with pytest.raises(ValueError, match="sub-chunk"):
         odd.generate_all()
+
+
+# ------------------------------------------------ the convolutions' window leaf
+# 16 KDA heads of 64: the three convolutions' 3,072 channels are whole float32
+# tiles (8 x 128 divides them), so the window leaf is folded; the tiny
+# configuration's 96 are not, and the leaf keeps its rows
+FOLDED = kimi_linear.KimiLinearConfig.tiny(linear_attn_config={
+    "kda_layers": [1, 2, 4], "full_attn_layers": [3, 5], "head_dim": 64,
+    "num_heads": 16, "short_conv_kernel_size": 4})     # "DKMKM"
+WINDOW_FAMILIES = {
+    # family -> (module, its tiny configuration, one with a width that folds)
+    "kimi_linear": (kimi_linear, CFG, FOLDED, lambda c: 3 * c.kda_width),
+    # 8 heads of 96 + 2 x 2 groups x 64 states = 1,024 channels
+    "nemotron_h": (nemotron_h, nemotron_h.NemotronHConfig.tiny(),
+                   nemotron_h.NemotronHConfig.tiny(mamba_head_dim=96,
+                                                   ssm_state_size=64),
+                   lambda c: c.conv_width),
+}
+
+
+@pytest.mark.parametrize("form", ["folded", "rows"])
+@pytest.mark.parametrize("family", WINDOW_FAMILIES)
+def test_window_leaf_round_trips(family, form):
+    """The window leaf as each family's ``init_paged_cache`` builds it, in
+    the folded form (a slot whole tiles) and in the form a width the tile
+    does not divide keeps, through ``models/paged``'s accessors: a slot reads
+    its last three rows, oldest first; a row at position 0 reads zeros
+    whatever the slot held; padding rows leave the scratch slot zero; a tile
+    goes on from the tile before it, from zeros or from its slot, and leaves
+    the three rows before its first invalid one; no other layer's row
+    moves."""
+    mod, tiny, folds, width = WINDOW_FAMILIES[family]
+    cfg = folds if form == "folded" else tiny
+    w, k1, s = width(cfg), cfg.conv_kernel - 1, 5
+    leaf = mod.init_paged_cache(cfg, 9, 8, jnp.float32, num_slots=s)[SLOTS]["conv"]
+    n_l = leaf.shape[0]
+    r = 8 if form == "folded" else 1
+    assert w % (8 * 128) == (0 if form == "folded" else w)
+    assert leaf.shape == (n_l, s, k1 * r, w // r) and not leaf.any()
+    # the same bytes a slot whatever the form (``state_bytes_per_slot``)
+    assert int(np.prod(leaf.shape[2:])) == k1 * w
+    # bfloat16 tiles are 16 rows: the cells' leaves
+    assert paged.init_window_leaf(10, 129, 3, 12288, jnp.bfloat16).shape \
+        == (10, 129, 48, 768)
+    assert paged.init_window_leaf(5, 129, 3, 10240, jnp.bfloat16).shape \
+        == (5, 129, 48, 640)
+    assert paged.init_window_leaf(2, 5, 3, 96, jnp.bfloat16).shape == (2, 5, 3, 96)
+
+    rng = np.random.default_rng(0)
+    merged = leaf.reshape((n_l * s,) + leaf.shape[2:])
+    layer, scratch = n_l - 1, s - 1
+    slots = np.array([0, 2, scratch, scratch])         # two padding rows
+    rows = jnp.asarray(slots + layer * s)
+    real = jnp.asarray(slots != scratch)
+    held = {0: np.zeros((k1, w), np.float32), 2: np.zeros((k1, w), np.float32)}
+    for step in range(5):
+        new = rng.standard_normal((4, w)).astype(np.float32)
+        # slot 2 is taken over by a new request at step 3: position 0 again
+        fresh = np.array([step == 0, step in (0, 3), False, False])
+        win, merged = paged.decode_windows(merged, rows, jnp.asarray(new),
+                                           jnp.asarray(fresh) & real, real)
+        assert win.shape == (4, k1 + 1, r, w // r)
+        win = np.asarray(win).reshape(4, k1 + 1, w)
+        now = np.asarray(paged.read_windows(merged, rows, w)).reshape(4, k1, w)
+        for i, slot in enumerate(slots[:2]):
+            before = np.zeros((k1, w)) if fresh[i] else held[slot]
+            np.testing.assert_array_equal(win[i], np.vstack([before, new[i:i + 1]]))
+            held[slot] = win[i, 1:]
+            np.testing.assert_array_equal(now[i], held[slot])
+        assert not now[2:].any()                       # the scratch slot
+    assert held[0].any() and held[2].any()
+
+    # a 13-token prompt of slot 1 as two tiles of 8 (the second has 5 valid
+    # rows) beside a padding tile: only the slot's last tile writes the slot
+    tiles = rng.standard_normal((3, 8, w)).astype(np.float32)
+    t_rows = jnp.asarray(np.array([1, 1, scratch]) + layer * s)
+    write = jnp.asarray([False, True, False])
+    win, merged = paged.tile_windows(
+        merged, t_rows, jnp.where(write, t_rows, scratch + layer * s),
+        jnp.asarray(tiles), jnp.asarray([False, True, False]),
+        jnp.asarray([True, False, True]), write, jnp.asarray([8, 5, 0]))
+    win = np.asarray(win)
+    assert win.shape == (3, k1 + 8, w) and not win[0, :k1].any()
+    np.testing.assert_array_equal(win[1, :k1], tiles[0, 8 - k1:])
+    np.testing.assert_array_equal(win[:, k1:], tiles)
+    # its next tile, in a later step, goes on from what the slot holds
+    win, _ = paged.tile_windows(
+        merged, t_rows[:1], t_rows[:1], jnp.asarray(tiles[2:]),
+        jnp.asarray([False]), jnp.asarray([False]), jnp.asarray([True]),
+        jnp.asarray([8]))
+    np.testing.assert_array_equal(np.asarray(win)[0, :k1], tiles[1, 5 - k1:5])
+    out = np.asarray(merged).reshape(n_l, s, k1, w)
+    np.testing.assert_array_equal(out[layer, 0], held[0])
+    np.testing.assert_array_equal(out[layer, 1], tiles[1, 5 - k1:5])
+    np.testing.assert_array_equal(out[layer, 2], held[2])
+    assert not out[layer, 3:].any() and not out[:layer].any()
+
+
+def test_both_window_forms_serve_the_same_logits():
+    """A width that folds, served with the leaf as ``init_paged_cache``
+    builds it and with the leaf in rows (the accessors read the form off the
+    array): the same logits to the last bit, the same windows left behind,
+    and the reference's logits."""
+    params = kimi_linear.init_params(FOLDED, jax.random.PRNGKey(2))
+    rng = np.random.default_rng(3)
+    prompts = {uid: rng.integers(0, FOLDED.vocab_size, n).tolist()
+               for uid, n in enumerate([5, 19, 13, 3])}
+    engines = [_engine(params, cfg=FOLDED) for _ in range(2)]
+    conv = engines[1].cache[SLOTS]["conv"]
+    k1, w = FOLDED.conv_kernel - 1, 3 * FOLDED.kda_width
+    assert conv.shape[2:] == (k1 * 8, w // 8)
+    engines[1].cache = {**engines[1].cache, SLOTS: {
+        **engines[1].cache[SLOTS],
+        "conv": jnp.zeros(conv.shape[:2] + (k1, w), conv.dtype)}}
+    folded, in_rows = (_serve(eng, prompts, 4) for eng in engines)
+    assert folded.keys() == in_rows.keys() and len(folded) == 4 * 4
+    for key, row in folded.items():
+        np.testing.assert_array_equal(row, in_rows[key])
+    a, b = (np.asarray(eng.cache[SLOTS]["conv"]) for eng in engines)
+    assert a.shape[2:] == (k1 * 8, w // 8) and b.shape[2:] == (k1, w)
+    np.testing.assert_array_equal(a.reshape(b.shape), b)
+    assert b[:, :-1].any() and not b[:, -1].any()
+    for uid, prompt in prompts.items():
+        generated = engines[0].get_request(uid).generated
+        want = np.asarray(REF.forward(FOLDED, params,
+                                      jnp.asarray(prompt + generated)))
+        for g in range(4):
+            np.testing.assert_allclose(folded[(uid, g)],
+                                       want[len(prompt) + g - 1], atol=ATOL)

@@ -45,12 +45,12 @@ def params():
     return nemotron_h.init_params(CFG, jax.random.PRNGKey(1))
 
 
-def _engine(params, device_state=False, **sizes):
+def _engine(params, device_state=False, cfg=CFG, **sizes):
     rc = RaggedConfig(**{**dict(
         max_tokens_per_step=32, max_seqs=4, block_size=8, num_blocks=33,
         max_blocks_per_seq=8, prefill_tile=8, device_state=device_state),
         **sizes})
-    return RaggedInferenceEngine(lambda ctx: nemotron_h.build(CFG, ctx=ctx), rc,
+    return RaggedInferenceEngine(lambda ctx: nemotron_h.build(cfg, ctx=ctx), rc,
                                  dtype=jnp.float32, params=params)
 
 
@@ -359,3 +359,38 @@ def test_handoff_is_refused(params):
     with pytest.raises(ValueError, match="KVHandoff"):
         eng.import_handoff(record)
     assert eng.export_prefix([1, 2, 3]) is None and eng.import_prefix(None) == 0
+
+
+def test_both_window_forms_serve_the_same_logits():
+    """A convolution width that is whole float32 tiles (8 heads of 96 + 2 x
+    2 groups x 64 states = 1,024 channels), served with the window leaf as
+    ``init_paged_cache`` builds it (folded: a slot whole tiles) and with the
+    leaf in rows (``models/paged``'s accessors read the form off the array):
+    the same logits to the last bit, the same windows left behind, and the
+    reference's logits. The accessors' own test is
+    ``test_kimi_linear.py::test_window_leaf_round_trips``."""
+    cfg = nemotron_h.NemotronHConfig.tiny(mamba_head_dim=96, ssm_state_size=64)
+    params = nemotron_h.init_params(cfg, jax.random.PRNGKey(2))
+    prompts = _prompts([5, 19, 13, 3], seed=3)
+    engines = [_engine(params, cfg=cfg) for _ in range(2)]
+    conv = engines[1].cache[SLOTS]["conv"]
+    k1, w = cfg.conv_kernel - 1, cfg.conv_width
+    assert w == 1024 and conv.shape[2:] == (k1 * 8, w // 8)
+    engines[1].cache = {**engines[1].cache, SLOTS: {
+        **engines[1].cache[SLOTS],
+        "conv": jnp.zeros(conv.shape[:2] + (k1, w), conv.dtype)}}
+    folded, in_rows = (_serve(eng, prompts, 4) for eng in engines)
+    assert folded.keys() == in_rows.keys() and len(folded) == 4 * 4
+    for key, row in folded.items():
+        np.testing.assert_array_equal(row, in_rows[key])
+    a, b = (np.asarray(eng.cache[SLOTS]["conv"]) for eng in engines)
+    assert a.shape[2:] == (k1 * 8, w // 8) and b.shape[2:] == (k1, w)
+    np.testing.assert_array_equal(a.reshape(b.shape), b)
+    assert b[:, :-1].any() and not b[:, -1].any()
+    for uid, prompt in prompts.items():
+        generated = engines[0].get_request(uid).generated
+        want = np.asarray(REF.forward(cfg, params,
+                                      jnp.asarray(prompt + generated)))
+        for g in range(4):
+            np.testing.assert_allclose(folded[(uid, g)],
+                                       want[len(prompt) + g - 1], atol=ATOL)
