@@ -191,7 +191,7 @@ def device_phase(chips: int) -> dict:
 def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                   block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
                   experts=(16, 1024, 2688, 64, 6),
-                  kda=(258, 128, 32, 128)) -> dict:
+                  kda=(258, 128, 32, 128), chunk=(3, 128, 16)) -> dict:
     """Each Pallas kernel of the train and serve paths against the XLA path
     on the same seeded bf16 input, at each ``(q heads, kv heads, head size)``.
     Off the chip the kernels interpret; on it this is their first execution.
@@ -203,7 +203,11 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     kernel at ``kda`` = (state rows, key channels = values a head, heads,
     decode rows; the Kimi-Linear cell's 128 rows x 32 heads x 128 x 128)
     against its XLA form, both timed (``kda_decode_ms``: the state donated,
-    the median of five calls)."""
+    the median of five calls), and the KDA chunk kernel over the same state
+    at ``chunk`` = (tiles, rows a tile, sub-chunk; a mixed step's 3 tiles x
+    128 rows) against ``kda_tiles`` between slices (``kda_chunk_ms``), two
+    tiles of one slot and a fresh one, the strongest decay on a quarter of
+    the heads; float32 both, so ``kda_chunk_*`` are held to 1e-5."""
     import jax
     import jax.numpy as jnp
 
@@ -219,7 +223,7 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
         _require(np.isfinite(err) and err <= tol,
                  f"kernel {name}: max |pallas - xla| / max |xla| = {err} "
                  f"> {tol}")
-        worst[name] = round(max(worst.get(name, 0.0), err), 5)
+        worst[name] = round(max(worst.get(name, 0.0), err), 8)
 
     for hq, hkv, d in geometries:
         keys = iter(jax.random.split(jax.random.PRNGKey(SEED), 8))
@@ -308,6 +312,20 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
           jax.jit(lambda *a: moe._einsum_experts(*a, None, w_up, w_down,
                                                  share))(h, topv, topi))
 
+    def median_ms(forms, outs, args):
+        """Each form again from the state it left, which it donates: the
+        median of five calls, ms."""
+        ms = {}
+        for name, fn in forms.items():
+            s_run, times = outs[name][0], []
+            for _ in range(5):
+                t1 = time.perf_counter()
+                s_run, y = fn(s_run, *args)
+                jax.block_until_ready((s_run, y))
+                times.append((time.perf_counter() - t1) * 1e3)
+            ms[name] = round(sorted(times)[2], 3)
+        return ms
+
     from deepspeed_tpu.ops.pallas.kda import kda_decode, kda_decode_xla
 
     rows_n, kd, heads, t = kda
@@ -329,20 +347,46 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     for name, got, want in zip(("kda_decode_state", "kda_decode_y"),
                                outs["pallas"], outs["xla"]):
         close(name, got, want, tol=1e-5)
-    kda_ms = {}
-    for name, fn in forms.items():
-        s_run, times = outs[name][0], []
-        for _ in range(5):
-            t1 = time.perf_counter()
-            s_run, y = fn(s_run, *step)
-            jax.block_until_ready((s_run, y))
-            times.append((time.perf_counter() - t1) * 1e3)
-        kda_ms[name] = round(sorted(times)[2], 3)
+    kda_ms = median_ms(forms, outs, step)
+
+    from deepspeed_tpu.ops.pallas.kda import kda_chunk
+
+    n_i, r, sub = chunk
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 3), 6))
+
+    def unit(key):
+        x = jax.random.normal(key, (n_i, r, heads, kd), jnp.float32)
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    # the seeded gates' range (``kimi_linear.init_params``), and -1.6 a
+    # token, the strongest a channel can draw, on a quarter of the heads
+    g = -jnp.exp(jax.random.uniform(next(keys), (n_i, r, heads, kd),
+                                    jnp.float32, np.log(1e-3), np.log(1.6)))
+    g = g.at[:, :, :max(heads // 4, 1)].set(-1.6)
+    slot, other, spare = (int(x) for x in step[0][:3])
+    tiles = (jnp.asarray([slot] * (n_i - 1) + [other], jnp.int32),
+             jnp.asarray([spare] * (n_i - 2) + [slot, other], jnp.int32),
+             jnp.arange(n_i) == n_i - 1,                       # fresh
+             (jnp.arange(n_i) > 0) & (jnp.arange(n_i) < n_i - 1),
+             jnp.arange(n_i) >= n_i - 2,                       # write
+             *((x.reshape(n_i, r, -1) for x in (
+                 unit(next(keys)) * kd ** -0.5, unit(next(keys)), g,
+                 jax.random.normal(next(keys), (n_i, r, heads, kd),
+                                   jnp.float32)))),
+             jax.random.uniform(next(keys), (n_i, r, heads), jnp.float32))
+    forms = {name: jax.jit(lambda s, *a, name=name: kda_chunk(
+        s, *a, sub, impl=name), donate_argnums=0) for name in ("pallas", "xla")}
+    outs = {name: fn(state + 0.0, *tiles) for name, fn in forms.items()}
+    for name, got, want in zip(("kda_chunk_state", "kda_chunk_y"),
+                               outs["pallas"], outs["xla"]):
+        close(name, got, want, tol=1e-5)
+    chunk_ms = median_ms(forms, outs, tiles)
 
     return _emit("kernels", seconds=round(time.perf_counter() - t0, 2),
                  geometries=[list(g) for g in geometries],
                  shapes={"seq_len": seq_len, "tile": tile, "block": block},
-                 max_rel_err=worst, kda_decode_ms=kda_ms, memory=_memory())
+                 max_rel_err=worst, kda_decode_ms=kda_ms,
+                 kda_chunk_ms=chunk_ms, memory=_memory())
 
 
 # ------------------------------------------------------------------- train

@@ -2728,7 +2728,7 @@ class RaggedInferenceEngine:
         misses = self._cache_misses() if probe else 0
         if self._faults.enabled:
             self._faults.fire(POINT_DISPATCH)
-        state = self._state_attr(n_dec, n_pre, n)
+        state = self._state_attr(n_dec, n_pre, n, nt)
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
@@ -2800,13 +2800,16 @@ class RaggedInferenceEngine:
         return {} if form is None else {
             "sel_decode": form(width * self.cfg.block_size)}
 
-    def _state_attr(self, n_dec: int, n_pre: int, n: int) -> dict:
+    def _state_attr(self, n_dec: int, n_pre: int, n: int, nt: int) -> dict:
         """For a model with slot state, what of it a step moves:
         ``state_bytes``, the slot-state bytes the step must read and write
         (its decode rows and its distinct prefilling slots, a slot's state
-        once each way), ``dec_state_bytes`` the decode rows' part, and
+        once each way), ``dec_state_bytes`` the decode rows' part,
         ``ssm_prefill_tokens``, the prompt tokens its tiles run through the
-        recurrence, and ``state_kind``, the recurrence's name where the model
+        recurrence, ``chunk_tiles``, the tiles the step program runs the
+        recurrence's chunk form over, a layer (padding tiles too: a tile is
+        the same work whatever it holds; ``kda_chunk``'s grid is heads x
+        these), and ``state_kind``, the recurrence's name where the model
         gives one (``ModelSpec.state_kind``: ``"mamba2"``, ``"kda"``). The
         dispatch feeds the same bytes to ``inference_slot_state_bytes_total``
         (``part`` ``decode`` / ``prefill``). Nothing for a model with none."""
@@ -2817,6 +2820,7 @@ class RaggedInferenceEngine:
         return {"state_bytes": (n_dec + n_pre) * per_slot,
                 "dec_state_bytes": n_dec * per_slot,
                 "ssm_prefill_tokens": n - n_dec,
+                "chunk_tiles": nt,
                 **({} if kind is None else {"state_kind": kind})}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:

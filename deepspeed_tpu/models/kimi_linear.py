@@ -73,10 +73,12 @@ convolutions' inputs ``[q | k | v]``, oldest first, as a window leaf
 channels folded over a sublane tile's rows; ``[L_kda, S, 3, 3 P]`` for a width
 the tile does not divide). A decode row is one delta-rule update of its slot's
 state (``kda_decode``), a prefill tile one chunk, the state carried from tile
-to tile of a slot in order (the forward substitution and that carry are
-``lax.scan``s: as Python loops a tiled step program compiles 3-6 s later and
-its mixed step runs 8% sooner, PERF.md section 6, PR 40). A row or tile at
-position 0 starts from zeros whatever the slot held.
+to tile of a slot in order: on the chip ``kda_chunk``, the chunk form as one
+kernel that reads the tiles' rows and the slots' states where they lie; off
+it ``kda_tiles`` between a slice and an update a tile (``kda_chunk_xla``; its
+forward substitution and carry are ``lax.scan``s, which the plain forward
+pass compiles too). A row or tile at position 0 starts from zeros whatever
+the slot held.
 
 **One rank's share.** ``experts_held`` of the ``num_experts`` routed experts
 live here (``expert_rank``'s); the router scores and picks over all of them.
@@ -723,11 +725,7 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
         tile_windows,
         window_fold,
     )
-    from deepspeed_tpu.ops.pallas.kda import (
-        kda_decode,
-        state_rows_read,
-        state_rows_write,
-    )
+    from deepspeed_tpu.ops.pallas.kda import kda_chunk, kda_decode
 
     kda, conv = state["kda"], state["conv"]
     vd = cfg.kda_head_dim
@@ -779,14 +777,11 @@ def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
         gt = jnp.where(valid[..., None],
                        g[n_dec:].reshape((n_i, r) + g.shape[1:]), 0.0)
         bt = jnp.where(valid, beta[n_dec:].reshape(n_i, r, -1), 0.0)
-        # a tile's state is read and written as ONE row of the leaf, by
-        # kernels that do nothing else (``ops/pallas/kda.py`` says why)
-        y, s_new = kda_tiles(
-            qt, kt, vt, gt, bt,
-            jnp.where(fresh[:, None, None], 0.0, state_rows_read(kda, rows)),
-            cont, sub)
-        kda = state_rows_write(kda, rows_w,
-                               jnp.where(write[:, None, None], s_new, 0.0))
+        # a head's channels are a lane block of the rows as they lie: the
+        # chunk form reads them, and the slots' states in the leaf, in place
+        kda, y = kda_chunk(
+            kda, rows, rows_w, fresh, cont, write,
+            *(x.reshape(n_i, r, -1) for x in (qt, kt, gt, vt)), bt, sub)
         ys.append(y.reshape(n_i * r, -1))
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
     return _kda_out(cfg, y, gate, lp), {"kda": kda, "conv": conv}

@@ -1,6 +1,7 @@
-"""Pallas decode step of a Kimi Delta Attention (KDA) layer: one delta-rule
-update a row, the row's state read from HBM once and written back once, in
-place.
+"""Pallas kernels of a Kimi Delta Attention (KDA) layer: the decode step, one
+delta-rule update a row (``kda_decode``), and the chunk form over a step's
+prefill tiles (``kda_chunk``); each reads a row's or tile's state from HBM
+once and writes it back once, in place.
 
 A sequence's state is ``S`` ``[K, H x V]`` float32: a head's ``K`` key
 channels on the sublanes, its ``V`` values side by side on the lanes, the
@@ -36,15 +37,37 @@ XLA's form of the same step (``kda_decode_xla``: gather, update, scatter) is
 what runs off the chip and what ``chip_smoke.py`` times the kernel against
 (PERF.md section 6, PR 40).
 
-``state_rows_read`` / ``state_rows_write`` move a prefill tile's state out of
-that array and back, one row a grid step, the write in place. They compute
-nothing; they are kernels because a kernel's operand has ONE layout. A step
-program with prefill tiles and no decode row holds no ``kda_decode``, and left
-to itself XLA then lays the WHOLE array out to suit the chunk form's matmuls
-(key channels on the lanes): 2.7 GB copied in and copied back, every step, at
-the benchmark's 10 layers x 129 slots (the compiled program, PR 40; PR 31 met
-the same re-layout under a gather). Off the chip they are a dynamic slice
-and a dynamic-update-slice a row.
+``kda_chunk`` runs the recurrence's CHUNK form (``models/kimi_linear.py``'s
+module doc has the algebra) over a step's prefill tiles, a head and a tile a
+grid step, the tile axis the inner, sequential one. ``q`` / ``k`` / ``g`` /
+``v`` come as the model has them, ``[tiles, R, H x K]``: a head is a block of
+128 lanes, and so it is of the state leaf, so nothing is transposed on the
+way in or out. By scalar prefetch a tile names the leaf row its slot's state
+lies in, the row the state it ends with goes to, and whether it starts from
+zeros (position 0), goes on where the tile before it ended (the state stays
+in VMEM scratch between the tiles of a slot) and writes (a tile that is not
+its slot's last of the step, and a padding tile, write the scratch slot
+zeros). The output aliases the leaf: tile ``i + 1``'s row is fetched while
+tile ``i`` still computes, which is safe because a continued tile ignores
+what it fetched and only the scratch slot's row is written twice. Inside, a
+head and tile at a time (everything it needs is under 1 MB): the cumulative
+log-decays as sums of picked rows on the MXU; ``beta (V - (K e^G) S0)`` and
+``(Q e^G) S0`` against the state; then a loop over the sub-chunks of 16 rows,
+each its pairwise block ``[16, 16, K]`` (later row first, every exponent <=
+0) summed over the lanes, its rows' products with the tile's earlier rows
+decayed through the sub-chunk's first row, and the unit lower triangular
+solve by forward substitution (over the sub-chunks, and a column a step
+inside one); last the state's update. float32, every product of two float32
+operands at ``Precision.HIGHEST``. It is a kernel because XLA's form of the
+same algebra (``kda_chunk_xla``: ``kda_tiles`` between a dynamic slice and a
+dynamic-update-slice a tile) is ~60 operations a layer around 0.4 ms of
+products: transposes to a head-major layout and back, a scan whose carry is
+materialised each step (9-11 ms of a 45 ms mixed step at Kimi-Linear's ten
+layers, PERF.md section 6, PR 42). And because a kernel's operand has ONE
+layout: a step program with tiles and no decode row holds no ``kda_decode``,
+and left to itself XLA then lays the WHOLE leaf out to suit the chunk form's
+matmuls (key channels on the lanes): 2.7 GB copied in and copied back, every
+step, at the benchmark's 10 layers x 129 slots (the compiled program, PR 40).
 
 Inference-only (no VJP).
 """
@@ -62,6 +85,7 @@ from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
 
 # a row's state in and out, double-buffered, is 8 MB at 128 x 4,096 float32
 _VMEM_LIMIT_BYTES = 48 * 2**20
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(rows_ref, s_ref, a_ref, k_ref, q_ref, v_ref, b_ref, o_ref, y_ref,
@@ -168,12 +192,6 @@ def kda_decode_xla(state, rows, a, k, q, v, beta):
             y.reshape(t, -1))
 
 
-def _copy_kernel(rows_ref, *refs):
-    del rows_ref  # the index maps read it
-    src_ref, dst_ref = refs[-2:]
-    dst_ref[...] = src_ref[...]
-
-
 def _on_chip(impl: str) -> bool:
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"kda: impl {impl!r} (auto, pallas, xla)")
@@ -181,57 +199,224 @@ def _on_chip(impl: str) -> bool:
                                 and jax.default_backend() == "tpu")
 
 
-def state_rows_read(state, rows, impl: str = "auto",
-                    interpret: bool | None = None):
-    """``state`` [R, K, HV], ``rows`` [I] int32 -> ``state[rows]`` [I, K, HV],
-    a row a grid step (module doc)."""
-    if not _on_chip(impl):
-        return jnp.stack([jax.lax.dynamic_index_in_dim(state, rows[i], 0, False)
-                          for i in range(rows.shape[0])])
-    _, kdim, hv = state.shape
-    n = rows.shape[0]
-    return pl.pallas_call(
-        _copy_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, kdim, hv), state.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(n,),
-            in_specs=[pl.BlockSpec((1, kdim, hv),
-                                   lambda i, rows: (rows[i], 0, 0))],
-            out_specs=pl.BlockSpec((1, kdim, hv), lambda i, rows: (i, 0, 0))),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=interpret_mode(interpret),
-        name="kda_state_read",
-    )(rows.astype(jnp.int32), state)
+def _sum_rows(picks, x):
+    """``picks`` [M, R] bool, ``x`` [R, N] float32 -> ``picks @ x`` as float32
+    sums, in three single bfloat16 passes: 0 / 1 is exact in bfloat16 and
+    ``x`` is the sum of three bfloat16 parts."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    picks = picks.astype(f32).astype(bf16)
+    out = None
+    for _ in range(3):
+        part = x.astype(bf16)
+        x = x - part.astype(f32)
+        term = jnp.dot(picks, part, preferred_element_type=f32)
+        out = term if out is None else out + term
+    return out
 
 
-def state_rows_write(state, rows, new, impl: str = "auto",
-                     interpret: bool | None = None):
-    """``state`` [R, K, HV] with ``state[rows[i]] = new[i]`` in order (a row
-    named twice keeps the later), in place: the output aliases ``state`` and
-    no other row is touched."""
+def _chunk_kernel(rows_ref, rows_w_ref, fresh_ref, cont_ref, write_ref,
+                  s_ref, q_ref, k_ref, g_ref, v_ref, b_ref, o_ref, y_ref,
+                  carry_ref, loc_ref, base_ref, rhs_ref, u_ref, *, sub: int):
+    del rows_ref, rows_w_ref  # the state's index maps read them
+    f32 = jnp.float32
+    h, i = pl.program_id(0), pl.program_id(1)
+    r, kd = q_ref.shape[1:]
+    vd = v_ref.shape[2]
+    dot = functools.partial(jax.lax.dot_general, precision=_HIGHEST,
+                            preferred_element_type=f32)
+
+    def mm(a, b):  # [M, C] x [C, N]
+        return dot(a, b, (((1,), (0,)), ((), ())))
+
+    def head_column(b):  # [.., H] -> head h's [.., 1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
+        return jnp.sum(jnp.where(lane == h, b, 0.0), axis=1, keepdims=True)
+
+    prev = jnp.where(cont_ref[i] > 0, carry_ref[...],
+                     jnp.where(fresh_ref[i] > 0, 0.0, s_ref[0]))
+    # cumulative log-decays: a row's sum inside its sub-chunk (``loc``, <= 0)
+    # and of the sub-chunks before it (``base``), as sums of picked rows
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, r), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, r), 1)
+    first = sub * jax.lax.broadcasted_iota(        # of the row's sub-chunk
+        jnp.int32, (r // sub, sub, r), 0).reshape(r, r)
+    sums = _sum_rows(jnp.concatenate([(col >= first) & (col <= row),
+                                      col < first]), g_ref[0])
+    loc, base = sums[:r], sums[r:]
+    loc_ref[...] = loc
+    base_ref[...] = base
+    decay = jnp.exp(base + loc)
+    # against the state the tile starts from: beta (V - (K e^G) S0), (Q e^G) S0
+    read = mm(jnp.concatenate([k_ref[0] * decay, q_ref[0] * decay]),
+              prev)                                                # [2R, V]
+    rhs_ref[...] = head_column(b_ref[0]) * (v_ref[0] - read[:r])
+    y_ref[0] = read[r:]
+    u_ref[...] = jnp.zeros_like(u_ref)
+    later = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub, kd), 1)
+             - jax.lax.broadcasted_iota(jnp.int32, (sub, sub, kd), 0))
+    row1 = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+
+    def ahead(n):
+        """What sub-chunk ``n`` needs that no earlier sub-chunk's ``U`` feeds:
+        its pairwise block's sums and its rows' products with the tile's
+        earlier rows."""
+        at = pl.multiple_of(n * sub, sub)
+        rows = pl.ds(at, sub)
+        loc_n, k_n, q_n = loc_ref[rows, :], k_ref[0, rows, :], q_ref[0, rows, :]
+        beta_n = head_column(b_ref[0, rows, :])                    # [C, 1]
+        # inside the sub-chunk: pairwise differences, later row ``c`` first,
+        # as [d, c, K]; the sum over K leaves pair (c, d) on row c of block
+        # d: what multiplies row d of U in ``solve``
+        e = jnp.exp(jnp.where(later >= 0, loc_n[None] - loc_n[:, None],
+                              -jnp.inf))
+        kp = k_n[:, None] * e
+        pairs = jnp.sum(jnp.concatenate(
+            [jnp.where(later > 0, (beta_n * k_n)[None] * kp, 0.0),
+             q_n[None] * kp]), axis=-1, keepdims=True)             # [2C, C, 1]
+        # between sub-chunks: rows decayed from the sub-chunk's first row,
+        # columns (the tile's earlier rows) decayed up to it
+        cols = k_ref[0] * jnp.exp(jnp.where(
+            row1 < at, base_ref[pl.ds(at, 1), :]
+            - (base_ref[...] + loc_ref[...]), -jnp.inf))           # [R, K]
+        d_in = jnp.exp(loc_n)
+        off = dot(jnp.concatenate([k_n * d_in * beta_n, q_n * d_in]), cols,
+                  (((1,), (1,)), ((), ())))                        # [2C, R]
+        return off, pairs
+
+    def solve(n, off, pairs):
+        """(I + A) U = ... for sub-chunk ``n``'s rows, and their readings."""
+        rows = pl.ds(pl.multiple_of(n * sub, sub), sub)
+        both = mm(off, u_ref[...])                                 # [2C, V]
+        # forward substitution, a column a step; row d of U_n is final when
+        # column d is taken (a few operations a step on two registers' worth
+        # of rows, so the steps are written out)
+        u_n = rhs_ref[rows, :] - both[:sub]
+        y_n = y_ref[0, rows, :] + both[sub:]
+        for d in range(sub):
+            u_d = u_n[d:d + 1]
+            y_n = y_n + pairs[sub + d] * u_d
+            if d + 1 < sub:
+                u_n = u_n - pairs[d] * u_d
+        u_ref[rows, :] = u_n
+        y_ref[0, rows, :] = y_n
+
+    # a loop, not ``R / sub`` copies of its body (the kernel is compiled in
+    # every step program that has tiles, once a KDA layer body), and in it
+    # sub-chunk ``n + 1``'s ``ahead`` beside sub-chunk ``n``'s ``solve``: the
+    # solve is a chain of short dependent steps that leaves the units idle
+    # (0.49 -> 0.43 ms a layer's call at 3 tiles, PERF.md section 6, PR 42)
+    def step(n, carry):
+        nxt = ahead(n + 1)
+        solve(n, *carry)
+        return nxt
+
+    solve(r // sub - 1, *jax.lax.fori_loop(0, r // sub - 1, step, ahead(0)))
+    # the state the tile ends with: every channel decayed by the tile's whole
+    # sum (the channels are the state's sublanes: a transpose), plus the
+    # rows' feeds decayed from their row to the tile's end
+    gc = base_ref[...] + loc_ref[...]
+    k_end = k_ref[0] * jnp.exp(gc[r - 1:r] - gc)
+    total = jnp.broadcast_to(jnp.exp(gc[r - 1:r]), (vd, kd)).T    # [K, V]
+    new = prev * total + dot(k_end, u_ref[...], (((0,), (0,)), ((), ())))
+    carry_ref[...] = new
+    o_ref[0] = jnp.where(write_ref[i] > 0, new, 0.0)
+
+
+def kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta,
+              sub: int, impl: str = "auto", interpret: bool | None = None):
+    """The chunk form of the recurrence over a step's ``I`` prefill tiles of
+    ``R`` rows, the state read from and written to ``state`` [rows, K, HV]
+    float32 in place (module doc): ``q`` / ``k`` / ``g`` [I, R, H x K]
+    (``q`` scaled; ``g`` the log-decay, 0 on rows that must neither decay nor
+    feed), ``v`` [I, R, H x V], ``beta`` [I, R, H] (0 on those rows), and a
+    tile: ``rows`` [I] where its slot's state lies, ``rows_w`` [I] where the
+    state it ends with goes, ``fresh`` (it starts from zeros whatever the row
+    holds), ``cont`` (it goes on where tile ``i - 1`` ended; never tile 0),
+    ``write`` (else zeros are written: a tile that is not its slot's last of
+    the step, and a padding tile, name the scratch slot in ``rows_w``).
+    ``sub`` the sub-chunk (divides ``R``) -> ``(state, y [I, R, H x V])``.
+    float32, ``Precision.HIGHEST``. ``impl`` as ``kda_decode``'s."""
     if not _on_chip(impl):
-        for i in range(rows.shape[0]):
-            state = jax.lax.dynamic_update_index_in_dim(state, new[i],
-                                                        rows[i], 0)
-        return state
-    _, kdim, hv = state.shape
-    n = rows.shape[0]
+        return kda_chunk_xla(state, rows, rows_w, fresh, cont, write, q, k, g,
+                             v, beta, sub)
+    i32 = jnp.int32
+    return _kda_chunk(state, rows.astype(i32), rows_w.astype(i32),
+                      fresh.astype(i32), cont.astype(i32), write.astype(i32),
+                      q, k, g, v, beta, sub=sub,
+                      interpret=interpret_mode(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("sub", "interpret"))
+def _kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta, *,
+               sub: int, interpret: bool):
+    """ONE jitted function, as ``_kda_decode``."""
+    _, kd, hv = state.shape
+    n_i, r, heads = beta.shape
+    vd = hv // heads
+    f32 = jnp.float32
+
+    def tile(h, i, *_):
+        return (i, 0, h)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        # the tiles are the inner, sequential axis: a head's state goes from
+        # tile to tile of a slot in ``carry_ref``
+        grid=(heads, n_i),
+        in_specs=[
+            pl.BlockSpec((1, kd, vd),
+                         lambda h, i, rows, *_: (rows[i], 0, h)),
+            pl.BlockSpec((1, r, kd), tile),
+            pl.BlockSpec((1, r, kd), tile),
+            pl.BlockSpec((1, r, kd), tile),
+            pl.BlockSpec((1, r, vd), tile),
+            pl.BlockSpec((1, r, heads), lambda h, i, *_: (i, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, kd, vd),
+                         lambda h, i, rows, rows_w, *_: (rows_w[i], 0, h)),
+            pl.BlockSpec((1, r, vd), tile),
+        ],
+        scratch_shapes=[pltpu.VMEM((kd, vd), f32),        # carry
+                        pltpu.VMEM((r, kd), f32),         # loc
+                        pltpu.VMEM((r, kd), f32),         # base
+                        pltpu.VMEM((r, vd), f32),         # rhs
+                        pltpu.VMEM((r, vd), f32)],        # u
+    )
     return pl.pallas_call(
-        _copy_kernel,
-        out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(n,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec((1, kdim, hv), lambda i, rows: (i, 0, 0))],
-            out_specs=pl.BlockSpec((1, kdim, hv),
-                                   lambda i, rows: (rows[i], 0, 0))),
-        # operand 0 is the prefetched ``rows``: the state is operand 1
-        input_output_aliases={1: 0},
+        functools.partial(_chunk_kernel, sub=sub),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct((n_i, r, hv), f32)],
+        grid_spec=grid_spec,
+        # operands 0-4 are prefetched: the state is operand 5
+        input_output_aliases={5: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=interpret_mode(interpret),
-        name="kda_state_write",
-    )(rows.astype(jnp.int32), state, new.astype(state.dtype))
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kda_chunk",
+    )(rows, rows_w, fresh, cont, write, state, q.astype(f32), k.astype(f32),
+      g.astype(f32), v.astype(f32), beta.astype(f32))
+
+
+def kda_chunk_xla(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta,
+                  sub: int):
+    """The same tiles as XLA writes them: a dynamic slice a tile's state,
+    ``models/kimi_linear.kda_tiles`` (the chunk form as ~60 operations over
+    all heads and tiles at once), a dynamic-update-slice a tile. What runs
+    off the chip, and the kernel's yardstick."""
+    from deepspeed_tpu.models.kimi_linear import kda_tiles
+
+    n_i, r, heads = beta.shape
+
+    def by_head(x):  # [I, R, H x K] -> [I, R, H, K]
+        return x.astype(jnp.float32).reshape(n_i, r, heads, -1)
+
+    s0 = jnp.stack([jax.lax.dynamic_index_in_dim(state, rows[i], 0, False)
+                    for i in range(n_i)])
+    y, s_new = kda_tiles(by_head(q), by_head(k), by_head(v), by_head(g), beta,
+                         jnp.where(fresh[:, None, None], 0.0, s0), cont, sub)
+    s_new = jnp.where(write[:, None, None], s_new, 0.0)
+    for i in range(n_i):
+        state = jax.lax.dynamic_update_index_in_dim(state, s_new[i],
+                                                    rows_w[i], 0)
+    return state, y

@@ -353,34 +353,50 @@ def test_kda_decode_compiles_for_v5e_and_updates_in_place(v5e):
     """A row's whole state (2 MB) a grid step, in and out double-buffered, a
     head's [128, 1] columns broadcast over its lanes; the donated state is the
     output (aliased), and nothing else in the program is as large as ONE
-    row's state. The tile rows' read and write compile too, the write in
-    place."""
-    from deepspeed_tpu.ops.pallas import kda
-
+    row's state."""
     compiled = jax.jit(_kda_decode, donate_argnums=(0,)).lower(
         *_kda_args(v5e)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 10 * 129 * 128 * 4096 * 4
     assert mem.temp_size_in_bytes < 128 * 4096 * 4
-    state, rows = _kda_args(v5e, rows=3)[:2]
-    tiles = jax.ShapeDtypeStruct((3, 128, 4096), jnp.float32,
-                                 sharding=state.sharding)
 
-    def move(state, rows, new):
-        got = kda.state_rows_read(state, rows, impl="pallas", interpret=False)
-        return kda.state_rows_write(state, rows, new + got, impl="pallas",
-                                    interpret=False)
 
-    compiled = jax.jit(move, donate_argnums=(0,)).lower(
-        state, rows, tiles).compile()
-    names = [ln.split(" = ")[0] for ln in compiled.as_text().splitlines()
-             if " custom-call(" in ln and "tpu_custom_call" in ln]
-    assert sum("kda_state_read" in n for n in names) == 1
-    assert sum("kda_state_write" in n for n in names) == 1
+def _kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta):
+    from deepspeed_tpu.ops.pallas.kda import kda_chunk
+
+    return kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v,
+                     beta, 16, impl="pallas", interpret=False)
+
+
+def _kda_chunk_args(devices, tiles=3):
+    """The same state, and a mixed step's ``tiles`` prefill tiles of 128
+    rows: 32 heads of 128 channels side by side on the lanes."""
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    return (s((10 * 129, 128, 4096)), *(s((tiles,), jnp.int32),) * 2,
+            *(s((tiles,), jnp.bool_),) * 3, *(s((tiles, 128, 4096)),) * 4,
+            s((tiles, 128, 32)))
+
+
+def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e):
+    """A head and a tile a grid step: float32 products at
+    ``Precision.HIGHEST``, a transposed product, lane sums of ``[16, 16,
+    128]`` blocks and a sub-chunk loop in Mosaic; the donated state is the
+    output (aliased), nothing else in the program is as large as ONE row's
+    state, and the tiles' operands reach the kernel as they are handed in
+    (no transpose to a head-major layout, no copy)."""
+    compiled = jax.jit(_kda_chunk, donate_argnums=(0,)).lower(
+        *_kda_chunk_args(v5e)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= f32\[3,128,4096\]\S* (copy|transpose)\(", text)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 10 * 129 * 128 * 4096 * 4
-    assert mem.temp_size_in_bytes < 8 * 128 * 4096 * 4
+    assert mem.temp_size_in_bytes < 128 * 4096 * 4
 
 
 @pytest.mark.parametrize("kernel,name", [
@@ -389,12 +405,13 @@ def test_kda_decode_compiles_for_v5e_and_updates_in_place(v5e):
     (_prefill, "tiled_prefill"), (_mla_decode, "mla_decode"),
     (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm"),
     ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode"),
-    ("kda_decode", "kda_decode"),
+    ("kda_decode", "kda_decode"), (_kda_chunk, "kda_chunk"),
     (_dsa_index, "dsa_index"), (_dsa_decode, "dsa_attn_decode"),
     (_dsa_prefill, "dsa_attn_prefill"), (_dsa_walk, "dsa_attn_decode")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
          "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm",
-         "moe_gmm_ungated", "ssm_decode", "kda_decode", "dsa_index",
+         "moe_gmm_ungated", "ssm_decode", "kda_decode", "kda_chunk",
+         "dsa_index",
          "dsa_attn_decode",
          "dsa_attn_prefill", "dsa_attn_decode_walk"])
 def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
@@ -426,6 +443,9 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
     elif kernel == "kda_decode":
         text = jax.jit(_kda_decode).lower(
             *_kda_args(v5e, rows=8)).compile().as_text()
+    elif kernel is _kda_chunk:
+        text = jax.jit(kernel).lower(
+            *_kda_chunk_args(v5e, tiles=2)).compile().as_text()
     elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill, _dsa_walk):
         text = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile().as_text()
     else:
@@ -433,6 +453,13 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
             *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
     calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
+    if name == "kda_chunk":
+        # no file of its own: the benchmark reads it off ``device_ops`` by
+        # name, and no other kernel's pattern may take it for its own
+        assert len(calls) == 1 and calls[0].startswith("%kda_chunk.")
+        assert [k for k, rx in patterns.items() if rx.search(calls[0])] == [
+            "pallas_custom_call"]
+        return
     mine = [ln for ln in calls if patterns[name].search(ln)]
     assert mine and all(name in ln.split(" = ")[0] for ln in mine)
     if kernel in (_mla_decode, _dsa_walk):
@@ -905,14 +932,15 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
     """The same clause for the first cache that has slot leaves BESIDE a
     latent pool, at Kimi-Linear's KDA and MLA widths and the cell's pool and
     slots (FFN, experts and vocabulary small): lead ``D``, period ``KM``. The
-    decode rows go through ``kda_decode`` (state aliased in and out), the
-    tiles' rows through ``kda_state_read`` / ``kda_state_write`` (in place),
-    the MLA layer's latent rows are scattered as in ``deepseek``. No gather,
-    copy or re-layout of the float32 state ``[3 x 129, 128, 4096]``: in a
-    step program with tiles and NO decode row nothing held the array's layout
-    until the tiles' rows moved through kernels, and XLA laid all of it out
-    with the key channels on the lanes, 2.7 GB in and out at the cell's ten
-    layers (the compiled program, PR 40).
+    decode rows go through ``kda_decode``, the tiles through ``kda_chunk``
+    (each with the state aliased in and out), the MLA layer's latent rows
+    are scattered as in ``deepseek``. No gather, copy or re-layout of the
+    float32 state ``[3 x 129, 128, 4096]``: in a step program with tiles and
+    NO decode row nothing held the array's layout until the tiles' states
+    moved through a kernel, and XLA laid all of it out with the key channels
+    on the lanes, 2.7 GB in and out at the cell's ten layers (the compiled
+    program, PR 40). The chunk form is the kernel and nothing beside it: no
+    float32 product of a tile's ``[.., 32, 128, 128]`` blocks is left to XLA.
 
     ``window``: the same for the convolutions' window leaf, at the cell's
     thirteen layers (ten of them KDA) and a hidden size small enough that
@@ -978,7 +1006,8 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
     names = [ln.split(" = ")[0] for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
     assert sum("kda_decode" in n for n in names) == (n_kda if rows else 0)
-    assert sum("kda_state_write" in n for n in names) == (n_kda if tiles else 0)
+    assert sum("kda_chunk" in n for n in names) == (n_kda if tiles else 0)
+    assert not re.search(r"= f32\[\d+,32,128,128\]\S* (convolution|dot)\(", text)
     assert sum("mla_decode" in n for n in names) == (n_mla if rows else 0)
     assert compiled.memory_analysis().temp_size_in_bytes < min(state_slice,
                                                                pool_slice)

@@ -2,9 +2,10 @@
 served (prefill in tiles, then decode, through the latent pool AND the slot
 state) against the plain reference ``benchmark/reference/kimi_linear.py``; the
 chunk form of the delta rule against the token-by-token recurrence at the
-strongest decay the configuration can draw; the decode kernel against XLA's
-form; one rank's share of the experts; MLA with and without rotation; what a
-dispatch span says of the state.
+strongest decay the configuration can draw, as XLA's ~60 operations and as
+the kernel; the decode and the chunk kernel against XLA's forms; one rank's
+share of the experts; MLA with and without rotation; what a dispatch span says
+of the state.
 
 Logits are compared, not tokens. Tolerance 2e-4 (float32 everywhere here): the
 program runs a prompt as chunks (a triangular solve and matmuls inside a chunk,
@@ -25,7 +26,12 @@ from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import deepseek, experts, kimi_linear, nemotron_h
 from deepspeed_tpu.models import paged
 from deepspeed_tpu.models.paged import SLOTS
-from deepspeed_tpu.ops.pallas.kda import kda_decode, kda_decode_xla
+from deepspeed_tpu.ops.pallas.kda import (
+    kda_chunk,
+    kda_chunk_xla,
+    kda_decode,
+    kda_decode_xla,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 ATOL = 2e-4
@@ -227,16 +233,51 @@ def _token_by_token(q, k, v, g, beta, s0):
             state.transpose(1, 0, 2).reshape(kd, h * vd))
 
 
+def _rel(got, want):
+    """The largest difference as a share of the largest wanted magnitude."""
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _one_sequence(form, q, k, v, g, beta, s0, sub):
+    """The tiles ``q`` .. [I, R, H, *] of ONE sequence from the state ``s0``
+    [K, H x V] -> ``(y [I, R, H x V], the last tile's state)``, as XLA's
+    form (``kda_tiles``) or as the kernel reads and writes a slot leaf: the
+    sequence's state in row 2 of 4 (the others garbage), row 3 the scratch
+    slot, which every tile but the last writes."""
+    n_i, r = q.shape[:2]
+    cont = jnp.arange(n_i) > 0
+    if form == "xla":
+        y, s = kimi_linear.kda_tiles(
+            q, k, v, g, beta, jnp.broadcast_to(s0, (n_i,) + s0.shape), cont, sub)
+        return y, s[-1]
+    leaf = jnp.full((4,) + s0.shape, jnp.nan, jnp.float32).at[2].set(s0)
+    last = jnp.arange(n_i) == n_i - 1
+    leaf, y = kda_chunk(
+        leaf, jnp.full((n_i,), 2), jnp.where(last, 2, 3),
+        jnp.zeros((n_i,), bool), cont, last,
+        *(t.reshape(n_i, r, -1) for t in (q, k, g, v)), beta, sub,
+        impl="pallas", interpret=True)
+    if n_i > 1:
+        assert not np.asarray(leaf[3]).any()
+    assert np.isnan(np.asarray(leaf[:2])).all()
+    return y, leaf[2]
+
+
 @pytest.mark.parametrize("tiles", [1, 4])
-def test_chunk_form_is_the_recurrence_at_the_strongest_decay(tiles):
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_chunk_form_is_the_recurrence_at_the_strongest_decay(form, tiles):
     """A 128-row tile (and four with the carry) in sub-chunks of 16 at the
     strongest decay the configuration can draw, ``A = 16`` and ``dt = 0.1``
     on every channel of half the heads (``g = -1.6`` a token, ``G = -205``
     over the tile: ``exp(-G)`` alone is float32's ``inf``), the seeded range
     on the others, from a state that is not zero: finite, and the
     token-by-token recurrence to float32 rounding (2e-5 on readings of
-    magnitude ~1: a 16 x 16 inverse, 8 substitution steps and the pairwise
-    sums in another order)."""
+    magnitude ~1: a 16 x 16 block's substitution, 8 steps over the
+    sub-chunks and the pairwise sums in another order). ``kernel``:
+    ``kda_chunk`` in interpret mode, which is also pinned to XLA's form at
+    1e-5 of the largest reading and state: float32 and six-pass products
+    give that, bfloat16 operands or a single pass 1e-3."""
     rng = np.random.default_rng(tiles)
     r, h, kd = 128, 4, 32
     shape = (tiles, r, h, kd)
@@ -255,33 +296,94 @@ def test_chunk_form_is_the_recurrence_at_the_strongest_decay(tiles):
     q, k, v, g, beta, s0 = (jnp.asarray(a, jnp.float32)
                             for a in (q, k, v, g, beta, s0))
     assert float(jnp.cumsum(g, axis=1).min()) < -200.0
-    cont = jnp.arange(tiles) > 0
-    got_y, got_s = kimi_linear.kda_tiles(
-        q, k, v, g, beta, jnp.broadcast_to(s0, (tiles,) + s0.shape), cont, 16)
+    got_y, got_s = _one_sequence(form, q, k, v, g, beta, s0, 16)
     want_y, want_s = _token_by_token(q, k, v, g, beta, s0)
     assert np.isfinite(np.asarray(got_y)).all()
     assert np.isfinite(np.asarray(got_s)).all()
     np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(got_s[-1]), np.asarray(want_s),
-                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s), atol=2e-5)
+    if form == "kernel":
+        xla_y, xla_s = _one_sequence("xla", q, k, v, g, beta, s0, 16)
+        assert _rel(got_y, xla_y) < 1e-5 and _rel(got_s, xla_s) < 1e-5
 
 
-def test_rows_past_a_tiles_valid_ones_neither_decay_nor_feed():
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_rows_past_a_tiles_valid_ones_neither_decay_nor_feed(form):
     rng = np.random.default_rng(5)
     r, h, kd, valid = 16, 2, 8, 11
     q, k, v = (jnp.asarray(rng.standard_normal((1, r, h, kd)), jnp.float32)
                for _ in range(3))
     g = -jnp.asarray(rng.uniform(0.01, 1.0, (1, r, h, kd)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0, 1, (1, r, h)), jnp.float32)
-    s0 = jnp.asarray(rng.standard_normal((1, kd, h * kd)), jnp.float32)
+    s0 = jnp.asarray(rng.standard_normal((kd, h * kd)), jnp.float32)
     live = (jnp.arange(r) < valid)[None, :, None]
-    _, s_pad = kimi_linear.kda_tiles(
-        q, k, v, jnp.where(live[..., None], g, 0.0), jnp.where(live, beta, 0.0),
-        s0, jnp.zeros((1,), bool), 4)
+    _, s_pad = _one_sequence(
+        form, q, k, v, jnp.where(live[..., None], g, 0.0),
+        jnp.where(live, beta, 0.0), s0, 4)
     _, s_cut = _token_by_token(q[:, :valid], k[:, :valid], v[:, :valid],
-                               g[:, :valid], beta[:, :valid], s0[0])
-    np.testing.assert_allclose(np.asarray(s_pad[0]), np.asarray(s_cut),
-                               atol=1e-5)
+                               g[:, :valid], beta[:, :valid], s0)
+    np.testing.assert_allclose(np.asarray(s_pad), np.asarray(s_cut), atol=1e-5)
+
+
+# a step's tiles as the engine hands them over: (read row, write row, fresh,
+# cont, write) a tile; row 5 of the 6-row leaf is the scratch slot
+CHUNK_STEPS = {
+    # a slot's prompt over two and over three tiles: the state is carried in
+    # the kernel, the tiles before the last park zeros in the scratch slot
+    "continued_over_two_tiles": [(1, 5, 0, 0, 0), (1, 1, 0, 1, 1)],
+    "continued_over_three_tiles": [(3, 5, 1, 0, 0), (3, 5, 0, 1, 0),
+                                   (3, 3, 0, 1, 1)],
+    # position 0 of a slot whose row holds what the last request left (NaN)
+    "fresh_slot_over_garbage": [(4, 4, 1, 0, 1)],
+    # a padding tile names the scratch slot both ways and writes it zeros
+    "padding_tile": [(0, 0, 0, 0, 1), (5, 5, 1, 0, 0), (5, 5, 1, 0, 0)],
+    # two slots, the first continued: the second must not start from the
+    # first's carry, and both rows are written
+    "two_slots": [(2, 5, 0, 0, 0), (2, 2, 0, 1, 1), (0, 0, 0, 0, 1)],
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_STEPS)
+def test_kda_chunk_kernel_is_the_xla_form(case):
+    """``kda_chunk`` in interpret mode against a dynamic slice a tile,
+    ``kda_tiles`` and a dynamic-update-slice a tile, on a leaf whose scratch
+    row holds garbage before the step: readings and written rows to 1e-5 of
+    their largest magnitude, and no row but the written ones changes by a
+    bit. The output aliases the leaf, so a tile's row is fetched while the
+    tile before it still computes: a continued tile ignores what it fetched,
+    and the only row written twice is the scratch slot's (zeros each
+    time)."""
+    tiles = CHUNK_STEPS[case]
+    rng = np.random.default_rng(len(case))
+    n_i, r, h, kd, sub = len(tiles), 16, 2, 8, 4
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    q, k, v = (draw(n_i, r, h * kd) for _ in range(3))
+    g = -jnp.abs(draw(n_i, r, h * kd)) * 0.3
+    beta = jax.nn.sigmoid(draw(n_i, r, h))
+    if case == "padding_tile":
+        g, beta = g.at[1:].set(0.0), beta.at[1:].set(0.0)
+    leaf = draw(6, kd, h * kd)
+    if case == "fresh_slot_over_garbage":
+        leaf = leaf.at[4].set(jnp.nan)
+    rows, rows_w, fresh, cont, write = (
+        jnp.asarray(col, jnp.int32) for col in zip(*tiles))
+    args = (leaf, rows, rows_w, fresh > 0, cont > 0, write > 0, q, k, g, v,
+            beta, sub)
+    got_s, got_y = kda_chunk(*args, impl="pallas", interpret=True)
+    want_s, want_y = kda_chunk_xla(*args)
+    assert np.isfinite(np.asarray(got_y)).all()
+    assert _rel(got_y, want_y) < 1e-5 and _rel(got_s, want_s) < 1e-5
+    written = sorted(set(np.asarray(rows_w).tolist()))
+    untouched = [i for i in range(6) if i not in written]
+    np.testing.assert_array_equal(np.asarray(got_s)[untouched],
+                                  np.asarray(leaf)[untouched])
+    if 5 in written:
+        assert not np.asarray(got_s[5]).any()
+    assert all((np.asarray(got_s[i]) != np.asarray(leaf[i])).any()
+               for i in written)
 
 
 # ------------------------------------------------------------ the kernel
@@ -414,7 +516,8 @@ def test_dispatch_span_says_what_state_the_step_moved(params, family,
                                                       monkeypatch):
     """``engine/dispatch`` of a model with slot state: ``state_bytes`` (decode
     rows + distinct prefilling slots, a slot's bytes once each way),
-    ``dec_state_bytes``, ``ssm_prefill_tokens`` and ``state_kind`` (``"kda"``
+    ``dec_state_bytes``, ``ssm_prefill_tokens``, ``chunk_tiles`` (the tiles
+    the step program runs the chunk form over) and ``state_kind`` (``"kda"``
     here, ``"mamba2"`` for ``nemotron_h``); the same bytes on
     ``inference_slot_state_bytes_total``, decode and prefill parts labelled."""
     from deepspeed_tpu import telemetry
@@ -450,8 +553,11 @@ def test_dispatch_span_says_what_state_the_step_moved(params, family,
     assert seen[0]["state_bytes"] == 2 * per_slot
     assert seen[0]["dec_state_bytes"] == 0
     assert seen[0]["ssm_prefill_tokens"] == 16
+    # the program's four: 11 tokens are two tiles of 8, 5 are one, one pads
+    assert seen[0]["chunk_tiles"] == 4
     assert all(a["state_bytes"] == a["dec_state_bytes"] == 2 * per_slot
-               and a["ssm_prefill_tokens"] == 0 for a in seen[1:-1])
+               and a["ssm_prefill_tokens"] == a["chunk_tiles"] == 0
+               for a in seen[1:-1])
     by_part = {s["labels"]["part"]: s["value"] for s in series
                if s["labels"].get("state_kind") == kind}
     assert by_part["decode"] == sum(a["dec_state_bytes"] for a in seen)
