@@ -312,8 +312,7 @@ def serve_trial_main():
         model=lambda ctx: llama.build(model_cfg, ctx=ctx),
         ragged_config=rcfg, seed=0,
     )
-    # turns the persistent compile cache on (and precompiles the K-step
-    # scheduler family when sched_steps is set: none here)
+    # turns the persistent compile cache on (compiles nothing itself)
     t0 = time.perf_counter()
     nwarm = ragged.warmup()
     print(f"# ragged warmup: {nwarm} programs in "
@@ -1135,29 +1134,20 @@ def serving_bench_main():
         qst = engine.kv_quant_stats() or {}
 
         # drift probe: the SAME prompts through a quant-off and a quant-on
-        # engine (spec decode on, so the verdict covers both budget axes:
-        # greedy token-match rate and spec accept-rate drift)
+        # engine, judged by the greedy token-match rate
         def _probe(qspec):
             pcfg = RaggedConfig(
                 max_tokens_per_step=budget, max_seqs=2, block_size=block,
-                num_blocks=2 * mbs + 1, max_blocks_per_seq=mbs,
-                sched_steps=8, spec_draft=4, quant=qspec)
+                num_blocks=2 * mbs + 1, max_blocks_per_seq=mbs, quant=qspec)
             pe = RaggedInferenceEngine(
                 model=lambda ctx: llama.build(model_cfg, ctx=ctx),
                 ragged_config=pcfg, seed=0)
             for i in range(3):
                 pe.put(i, [int(t) for t in prompts[i][:32]],
                        max_new_tokens=12)
-            toks = pe.generate_all()
-            acc = (pe.spec_accepted / pe.spec_proposed
-                   if pe.spec_proposed else None)
-            return toks, acc
+            return pe.generate_all()
 
-        base_toks, base_acc = _probe("off")
-        q_toks, q_acc = _probe(kv_quant)
-        match = _kvq.token_match_rate(base_toks, q_toks)
-        drift = (abs(q_acc - base_acc)
-                 if base_acc is not None and q_acc is not None else None)
+        match = _kvq.token_match_rate(_probe("off"), _probe(kv_quant))
         kv_quant_stats = {
             "enabled": True,
             "codec": qst.get("codec", kv_quant),
@@ -1167,7 +1157,7 @@ def serving_bench_main():
             "fp16_block_bytes": qst.get("fp16_block_bytes"),
             "blocks_allocated_total": qst.get("blocks_allocated_total"),
             "bytes_saved_total": qst.get("bytes_saved_total"),
-            "drift": _kvq.drift_verdict(match, drift),
+            "drift": _kvq.drift_verdict(match),
         }
     # memory-ledger picture BEFORE close() tears the ledger down: per-owner
     # bytes + the final census gap (the leak detector's reading for the run)
@@ -3168,8 +3158,7 @@ def _probe_serve(overrides, steps):
             engine.put(i, p, max_new_tokens=6, **kw)
         return engine.generate_all()
 
-    dispatch_knobs = ("sched_steps", "spec_draft", "prefill_tile")
-    plain = {k: v for k, v in overrides.items() if k not in dispatch_knobs}
+    plain = {k: v for k, v in overrides.items() if k != "prefill_tile"}
     parity_ok = (parity_run(build(device_state=False, **plain))
                  == parity_run(build(**overrides)))
 
@@ -3298,7 +3287,7 @@ def autotune_bench_main():
         probe_runner=runner, profile_dir=profile_dir).tune()
     serve = KnobSearch(
         SERVE, model_info=info, steps=steps,
-        knob_names=("sched_steps", "prefill_tile"),
+        knob_names=("prefill_tile",),
         probe_runner=runner, profile_dir=profile_dir).tune()
 
     # --- round trip 1: a fresh initialize() loads the train profile ------

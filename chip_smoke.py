@@ -709,8 +709,7 @@ def serve_phase(cfg, rcfg, requests: list, *, sample: int,
              "tokens_scheduled": engine.tokens_scheduled,
              "tokens_padded": engine.tokens_padded,
              "preemptions": engine.preemptions,
-             "programs": {"dev_step": len(engine._dev_step_jits),
-                          "dev_sched": len(engine._dev_sched_jits)},
+             "programs": {"dev_step": len(engine._dev_step_jits)},
              "cold_dispatches": engine.program_cold_dispatches}
     mem = _memory()
     engine.cache = None  # the pool's bytes go to the float32 reference
@@ -730,8 +729,7 @@ def serve_phase(cfg, rcfg, requests: list, *, sample: int,
         reduced={},
         config={k: getattr(rcfg, k) for k in (
             "max_tokens_per_step", "max_seqs", "block_size", "num_blocks",
-            "max_blocks_per_seq", "device_state", "prefill_tile",
-            "sched_steps")},
+            "max_blocks_per_seq", "device_state", "prefill_tile")},
         warmup={"programs": warmed, "seconds": round(warmup_s, 2)},
         load_seconds=round(load_s, 2),
         requests={"n": len(requests),
@@ -914,14 +912,13 @@ def main(argv=None) -> int:
                 dataclasses.replace(xl, num_layers=TRAIN_LAYERS),
                 full_layers=xl.num_layers, micro_batch=TRAIN_MICRO_BATCH,
                 seq_len=SEQ_LEN, steps=TRAIN_STEPS, expect_kernels=True)
-            # what a deployment can set on this chip today: scheduler state
-            # on the device (the default) and prompts through the tiled
-            # prefill kernel — and not sched_steps. The rehearsal shows why:
-            # a K-step program (sched_steps, and the two older K-step modes
-            # PR 28 removed alike) holds the KV pool about three times
-            # over, and with 48 layers and this pool the TPU compiler
-            # refuses it (21.9 GB of 15.75; it fits below ~8K tokens of
-            # pool). So decode is one dispatch per token here (PERF.md §7).
+            # what a deployment sets on this chip: scheduler state on the
+            # device (the default) and prompts through the tiled prefill
+            # kernel. A K-step program (sched_steps, removed by PR 43 as the
+            # two older K-step modes were by PR 28) held the KV pool about
+            # three times over, and with 48 layers and this pool the TPU
+            # compiler refused it (21.9 GB of 15.75): decode is one dispatch
+            # a step.
             serve_phase(
                 xl,
                 RaggedConfig(

@@ -1,9 +1,9 @@
 """KnobSpace: the registry of tunable performance knobs (docs/AUTOTUNING.md).
 
 The framework grew ~20 interacting perf knobs across two engines
-(sched_steps x spec_draft x prefill_tile x kv budgets x quant codec x
-grad_overlap bucket/sharding x pipeline shape x headroom guard). The search driver (autotuner.KnobSearch) needs three facts per knob
-that the config dataclasses don't carry:
+(prefill_tile x kv budgets x quant codec x grad_overlap bucket/sharding x
+pipeline shape x headroom guard). The search driver (autotuner.KnobSearch)
+needs three facts per knob that the config dataclasses don't carry:
 
 - its **domain** — the candidate values worth measuring;
 - the **subsystem it patches** — a dotted train-config path or a
@@ -38,7 +38,7 @@ class Knob:
 
     ``name`` doubles as the patch address: a dotted ``Config`` path for
     train knobs (``zero_optimization.grad_overlap.bucket_bytes``), a
-    ``RaggedConfig`` field name for serve knobs (``sched_steps``).
+    ``RaggedConfig`` field name for serve knobs (``prefill_tile``).
     """
 
     name: str
@@ -155,12 +155,8 @@ def _build_default_space() -> KnobSpace:
                     (True, False), True,
                     doc="ZeRO-1 sharded optimizer update on the overlap path"))
     # ---- serve (RaggedConfig field names) ----
-    s.register(Knob("sched_steps", SERVE, (0, 8, 16), 0,
-                    doc="device-side multi-step decode scheduler depth"))
     s.register(Knob("prefill_tile", SERVE, (0, 16, 64), 0,
                     doc="tiled prefill kernel tile"))
-    s.register(Knob("spec_draft", SERVE, (0, 4), 0,
-                    doc="self-speculative draft depth"))
     s.register(Knob("enable_prefix_cache", SERVE, (False, True), False,
                     doc="block-level prefix cache"))
     s.register(Knob("quant", SERVE, ("off", "int8", "fp8"), "off",

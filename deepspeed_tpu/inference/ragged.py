@@ -54,12 +54,6 @@ from deepspeed_tpu.telemetry.tracing import format_traceparent
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.tracing import phase, span
 
-# With arrivals queued but UNADMITTABLE (a free slot exists yet the KV pool
-# cannot cover the reservation) a multi-step scheduler dispatch still runs up
-# to this many decode steps: decode progress is exactly what frees blocks.
-SCHED_ADMISSION_CAP = 8
-
-
 def _kept_pairs(pos0: int, take: int, topk: int) -> int:
     """``sum(min(p + 1, topk) for p in range(pos0, pos0 + take))``: the
     query x kept-row pairs of a chunk of ``take`` queries from ``pos0``."""
@@ -261,28 +255,6 @@ class RaggedConfig:
     # once) — token-identical, the tests' reference and rung 1 of the
     # watchdog's ladder.
     device_state: bool = True
-    # device-side multi-step decode scheduler (>= 2 enables): when every
-    # running sequence is decoding, ONE jitted program runs up to
-    # sched_steps decode steps and retires slots on EOS/length INSIDE the
-    # program (a lax.while_loop that masks retired rows to the scratch
-    # slot and early-exits when all rows retire), returning per-slot
-    # steps_taken so the host only reconciles — no per-token dispatch and
-    # no post-EOS wasted compute. Requires device_state (silently inert
-    # under the host-staged step, which stays token-identical).
-    sched_steps: int = 0
-    # self-speculative decoding depth (> 0 enables; requires
-    # sched_steps >= 2): each scheduler iteration proposes up to
-    # spec_draft tokens per slot from a device-resident n-gram /
-    # prompt-lookup draft (suffix match over the slot's own token
-    # history — no second model), verifies all of them in ONE batched
-    # forward, and surfaces the accepted prefix plus the target's bonus
-    # pick. Verification is exact-match against the target's own
-    # deterministic picks, so output is BIT-identical to plain decoding
-    # for greedy AND seeded sampling (per_request_keys makes each draw a
-    # function of (seed, gen_idx) only).
-    spec_draft: int = 0
-    # suffix-match length for the prompt-lookup draft source
-    spec_ngram: int = 3
     # ---- dispatch watchdog (docs/FAULT_TOLERANCE.md) ----
     # wall-clock budget for one step(); a step exceeding it counts toward
     # the degradation ladder like a transient failure (the device path is
@@ -674,10 +646,8 @@ class RaggedInferenceEngine:
             self._refuse_for_slot_state()
         # a family whose attention reads a selection of the context only
         # (``ModelSpec.index_topk`` rows a query): counted beside the context
-        # on ``engine/dispatch``, and refused where it cannot be carried
+        # on ``engine/dispatch``
         self._topk = self.spec.index_topk
-        if self._topk:
-            self._refuse_for_selection()
         # counts only a step program knows (``ModelSpec.step_counters``): it
         # hands their sums back behind the picked tokens, the reconcile folds
         # them into ``step_counts`` (totals) and ``_counts_unspanned`` (what
@@ -804,27 +774,6 @@ class RaggedInferenceEngine:
         # device executing step t+1)
         self._pending: list[dict] = []
         self._dev_step_jits: dict = {}
-        # device-side multi-step scheduler (cfg.sched_steps) + self-
-        # speculative decoding (cfg.spec_draft) program cache
-        self._dev_sched_jits: dict = {}
-        # self-speculative draft state: per-slot token-history rows (prompt +
-        # generated, by context position) the n-gram draft suffix-matches on
-        # device. The scheduler program appends what it emits; any OTHER
-        # path that moves a slot (admission, handoff import, recovery,
-        # non-sched dispatches) flips the host-side stale flag so the row is
-        # re-uploaded from prompt+generated before the slot's next sched
-        # dispatch.
-        self._hist_dev = (jnp.zeros((s1, self.cfg.max_seq_len), jnp.int32)
-                          if self.cfg.spec_draft else None)
-        self._hist_stale = np.ones(s1, bool)
-        self._hist_row_jit = jax.jit(
-            lambda h, row, vals: h.at[row].set(vals), donate_argnums=(0,))
-        # set when a sched dispatch declined because stale history rows
-        # cannot sync yet (outstanding refs): the turn loop must reconcile
-        # instead of falling through to per-step dispatch
-        self._sched_wait = False
-        self.spec_proposed = 0
-        self.spec_accepted = 0
         # dispatch-overhead accounting (plain ints so the bench reads them
         # with telemetry off; telemetry mirrors them when enabled)
         self.host_stage_ns = 0
@@ -856,13 +805,6 @@ class RaggedInferenceEngine:
         self._kv_scatter_jits: dict[int, Any] = {}
         self.kv_blocks_exported = 0
         self.kv_blocks_imported = 0
-        if self.cfg.sched_steps == 1 or self.cfg.sched_steps < 0:
-            raise ValueError("sched_steps must be 0 (off) or >= 2")
-        if self.cfg.spec_draft:
-            if self.cfg.sched_steps < 2:
-                raise ValueError("spec_draft requires sched_steps >= 2")
-            if self.cfg.spec_ngram < 1:
-                raise ValueError("spec_ngram must be >= 1")
         # scheduling efficiency telemetry (padding fraction; comparable to the
         # dense engine's pad-to-max waste) + dispatch accounting (host
         # dispatches per generated token)
@@ -1171,11 +1113,6 @@ class RaggedInferenceEngine:
         refused = (
             (cfg.enable_prefix_cache, "enable_prefix_cache", _NO_SNAPSHOT),
             (cfg.kv_tier, "kv_tier", _NO_SNAPSHOT),
-            (cfg.sched_steps >= 2, "sched_steps",
-             "a multi-step program has no slot-state rows of its own yet"),
-            (cfg.spec_draft, "spec_draft",
-             "a rejected draft position has already moved the recurrent "
-             "state, and there is no rollback"),
             (self._kvq is not None, f"quant={cfg.quant!r}",
              "a quantized pool beside slot state is not implemented"),
             (not cfg.prefill_tile, "prefill_tile=0",
@@ -1186,28 +1123,9 @@ class RaggedInferenceEngine:
             if on:
                 self._refuse(name, why)
 
-    def _refuse_for_selection(self) -> None:
-        """A model that selects the rows its attention reads has step
-        programs for the tiled step and the plain step only."""
-        cfg = self.cfg
-        trait = f"selects the {self._topk} cached rows a query attends over"
-        if cfg.sched_steps >= 2:
-            self._refuse(
-                "sched_steps",
-                "a multi-step program would score, select and gather inside "
-                "its scan of steps, and no such program has been built or "
-                "sized for the selection's [rows, table] scores", trait)
-        if cfg.spec_draft:
-            self._refuse(
-                "spec_draft",
-                "the verify lanes of one row are queries at successive "
-                "positions of ONE slot, which the selection's decode path "
-                "(one query a slot) has not been written for", trait)
-
-    def _refuse(self, what: str, why: str,
-                trait: str = "keeps recurrent state in slot leaves") -> None:
-        raise ValueError(f"model {self.spec.name} {trait}; {what} is "
-                         f"refused: {why}")
+    def _refuse(self, what: str, why: str) -> None:
+        raise ValueError(f"model {self.spec.name} keeps recurrent state in "
+                         f"slot leaves; {what} is refused: {why}")
 
     def _refuse_block_transfer(self, what: str) -> None:
         """``KVHandoff`` moves a sequence as its blocks."""
@@ -1298,9 +1216,6 @@ class RaggedInferenceEngine:
                 "device_sched_state", "ragged/slot_rows+block_table",
                 (self._dev_state, self._bt_dev)),
         }
-        if self._hist_dev is not None:
-            h["spec_lanes"] = led.register(
-                "spec_lanes", "ragged/spec_token_history", self._hist_dev)
         if self._slot_state:
             h["slot_state"] = led.register(
                 "slot_state", "ragged/recurrent_slot_state",
@@ -1371,8 +1286,6 @@ class RaggedInferenceEngine:
             led.update(h["slot_state"], slot_leaves(self.cache))
         led.update(h["device_sched_state"],
                    (self._dev_state, self._bt_dev))
-        if "spec_lanes" in h:
-            led.update(h["spec_lanes"], self._hist_dev)
 
     def _note_oom(self, seam: str, exc: BaseException) -> None:
         """OOM forensics: snapshot the per-owner breakdown + census into a
@@ -1516,7 +1429,7 @@ class RaggedInferenceEngine:
         # canonical resume point: feeding token_at(pos) at position pos
         # produces generated index pos - len(prompt) + 1, so the decode
         # side must resume one position behind the newest emitted token.
-        # (Speculative dispatch may have scheduled KV further; re-writing
+        # (The step in flight may have scheduled KV further; re-writing
         # that cell on resume is masked until the position is reached.)
         pos = len(seq.prompt) + len(seq.generated) - 1
         n_ctx = -(-pos // bs)
@@ -1668,10 +1581,6 @@ class RaggedInferenceEngine:
             self.h2d_bytes += iv.nbytes + fv.nbytes + 4
             self._dev_state = self._slot_row_jit(
                 self._dev_state, np.int32(slot), iv, fv)
-        # draft history is NOT part of the handoff row format: the decode
-        # side rebuilds it from prompt + generated before the slot's first
-        # speculative dispatch
-        self._hist_stale[slot] = True
         return True
 
     def export_prefix(self, prompt_tokens, trace=None) -> PrefixPayload | None:
@@ -2000,8 +1909,7 @@ class RaggedInferenceEngine:
                 if seq.t_first_token and seq.t_enqueue else None)
         queue_wait = (seq.t_admit - seq.t_enqueue
                       if seq.t_admit and seq.t_enqueue else None)
-        # mean inter-token latency after the first token; chunked dispatch
-        # (``sched_steps``) amortizes inside the mean
+        # mean inter-token latency after the first token
         decode_latency = ((seq.t_last_token - seq.t_first_token) / (n_gen - 1)
                           if n_gen > 1 and seq.t_first_token else None)
         dur = (seq.t_last_token - seq.t_enqueue
@@ -2163,7 +2071,6 @@ class RaggedInferenceEngine:
         self.h2d_bytes += iv.nbytes + fv.nbytes + 4
         self._dev_state = self._slot_row_jit(
             self._dev_state, np.int32(seq.slot), iv, fv)
-        self._hist_stale[seq.slot] = True
 
     def _sync_bt(self) -> None:
         """Delta-upload block-table rows dirtied since the last dispatch
@@ -2405,275 +2312,6 @@ class RaggedInferenceEngine:
         log_dist(f"ragged engine: cold compilation cache; compiling the step "
                  f"programs {workers} at a time in the background", ranks=[0])
 
-    # ------------------------------------- device-side multi-step scheduler
-    def _get_dev_sched(self, k: int, t: int, w: int, sampled: bool,
-                       has_tk: bool, has_tp: bool):
-        """Multi-step decode scheduler with DEVICE-SIDE retirement (+
-        optional self-speculation): a ``lax.while_loop`` over up to ``k``
-        decode iterations that retires rows on EOS/length inside the
-        program — retired rows mask to the scratch slot, the loop exits
-        early once every row is done — and returns per-row ``steps_taken``
-        so the host only reconciles.
-
-        The staging buffer is ``[slots | eos | limit]`` (``limit`` = last
-        feed position, ``prompt_len + max_new - 1``, constant per request),
-        so steady decode byte-compares equal and uploads NOTHING; feed
-        token and position come from the persistent slot rows, and per-row
-        step budgets are derived on device as ``limit - pos``. Rows the
-        host believes live but the device already retired (pipelined
-        dispatch after an EOS pick) re-derive ``done`` from their
-        persistent token row, emit zero steps, and cost no compute.
-
-        With ``cfg.spec_draft`` > 0 each iteration proposes up to D tokens
-        per row from the device-resident history (prompt lookup), verifies
-        them in the SAME forward via ``speculative_lane_layout``, and
-        surfaces the exact-match acceptance prefix + the target's bonus
-        pick — emitting up to D+1 tokens per iteration while staying
-        bit-identical to plain decoding (greedy and seeded)."""
-        d = self.cfg.spec_draft
-        key = (k, t, w, sampled, has_tk, has_tp)
-        fn = self._dev_sched_jits.get(key)
-        self._note_program("dev_sched", fn is None)
-        if fn is not None:
-            return fn
-        fwd = self.spec.ragged_forward_fn
-        max_seqs = self.cfg.max_seqs
-        ngram = self.cfg.spec_ngram
-        lanes = 1 + d
-
-        def sched_body(params, cache, state, hist, bt_full, staged, root):
-            from deepspeed_tpu.inference.sampling import (
-                accept_drafts, keys_for_positions, propose_ngram_drafts,
-                sample_tokens)
-            from deepspeed_tpu.models.paged import speculative_lane_layout
-            tok_st, pos_st, seed_st, plen_st, temp_st, topk_st, topp_st = state
-            slots = staged[:t]
-            eos = staged[t:2 * t]
-            limit = staged[2 * t:3 * t]
-            real = slots != max_seqs
-            bt = bt_full[:, :w] if w < bt_full.shape[1] else bt_full
-            toks0 = tok_st[slots]
-            pos0 = jnp.where(real, pos_st[slots], 0)
-            seeds = seed_st[slots]
-            plen = plen_st[slots]
-            temp = temp_st[slots]
-            topk = topk_st[slots]
-            topp = topp_st[slots]
-            # per-row step budget; the host guaranteed KV capacity for
-            # exactly min(k, limit - pos) feeds, so cap marks the first
-            # position WITHOUT an allocated block
-            bud = jnp.where(real, jnp.clip(limit - pos0, 0, k), 0)
-            cap = pos0 + bud
-            # device-side retirement of rows the host optimistically
-            # re-dispatched: the persistent token row already holds EOS
-            done0 = ~real | (bud <= 0) | ((eos >= 0) & (toks0 == eos))
-
-            def rep(x):  # row value -> per-verify-lane (row-major lanes)
-                return jnp.repeat(x, lanes)
-
-            def pick_lanes(lg, fpos_raw):
-                if not sampled:
-                    return jnp.argmax(lg.astype(jnp.float32),
-                                      axis=-1).astype(jnp.int32)
-                keys = keys_for_positions(root, rep(seeds), fpos_raw,
-                                          rep(plen))
-                return sample_tokens(lg, keys, rep(temp),
-                                     top_k=rep(topk) if has_tk else None,
-                                     top_p=rep(topp) if has_tp else None)[0]
-
-            lane_i = jnp.arange(lanes)[None, :]
-            col_i = jnp.broadcast_to(jnp.arange(t)[:, None], (t, lanes))
-
-            def body(c):
-                if d:
-                    cache, toks, pos, emitted, done, out, prop, acc, hist = c
-                else:
-                    cache, toks, pos, emitted, done, out, prop, acc = c
-                    hist = None
-                live = ~done
-                if d:
-                    draft, _ = propose_ngram_drafts(hist[slots], pos, ngram,
-                                                    d)
-                else:
-                    draft = None
-                ftok, fslot, fpos, fraw = speculative_lane_layout(
-                    toks, draft, pos, live, cap, slots, max_seqs)
-                lg, cache = fwd(params, ftok, fslot, fpos, bt, cache)
-                picked = pick_lanes(lg, fraw).reshape(t, lanes)
-                n_emit, n_acc = accept_drafts(
-                    draft if d else jnp.zeros((t, 0), jnp.int32), picked,
-                    jnp.where(live, bud - emitted, 0), eos)
-                sel = lane_i < n_emit[:, None]
-                # surfaced tokens land at out rows emitted..emitted+n-1;
-                # unselected lanes scatter into dump row k
-                tgt = jnp.where(sel, emitted[:, None] + lane_i, k)
-                out = out.at[tgt, col_i].set(picked)
-                if d:
-                    # emitted token i is the token at context position
-                    # pos+1+i: append to the history the draft reads
-                    hpos = jnp.where(sel, pos[:, None] + 1 + lane_i, 0)
-                    hslot = jnp.where(sel, slots[:, None], max_seqs)
-                    hist = hist.at[hslot, hpos].set(picked)
-                last = jnp.take_along_axis(
-                    picked, jnp.clip(n_emit - 1, 0, lanes - 1)[:, None],
-                    axis=1)[:, 0]
-                toks = jnp.where(n_emit > 0, last, toks)
-                pos = pos + n_emit
-                emitted = emitted + n_emit
-                hit_eos = (eos >= 0) & (last == eos) & (n_emit > 0)
-                done = done | hit_eos | (emitted >= bud)
-                if d:
-                    prop = prop + jnp.sum(
-                        jnp.where(live, d, 0)).astype(jnp.int32)
-                    acc = acc + jnp.sum(n_acc).astype(jnp.int32)
-                r = (cache, toks, pos, emitted, done, out, prop, acc)
-                return r + ((hist,) if d else ())
-
-            zero_i = jnp.zeros((t,), jnp.int32)
-            carry = (cache, toks0, pos0, zero_i, done0,
-                     jnp.full((k + 1, t), -1, jnp.int32),
-                     jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-            if d:
-                carry = carry + (hist,)
-            carry = jax.lax.while_loop(
-                lambda c: jnp.any(~c[4]), body, carry)
-            cache, toks, pos, emitted, _, out, prop, acc = carry[:8]
-            if d:
-                hist = carry[8]
-            sl = jnp.where(real, slots, max_seqs)
-            tok_st = tok_st.at[sl].set(jnp.where(real, toks, tok_st[sl]))
-            pos_st = pos_st.at[sl].set(jnp.where(real, pos, pos_st[sl]))
-            state = (tok_st, pos_st, seed_st, plen_st, temp_st, topk_st,
-                     topp_st)
-            return out[:k], emitted, prop, acc, state, hist, cache
-
-        if d:
-            fn = jax.jit(sched_body, donate_argnums=(1, 2, 3))
-        else:
-            def nohist(params, cache, state, bt_full, staged, root):
-                out, steps, _, _, state, _, cache = sched_body(
-                    params, cache, state, None, bt_full, staged, root)
-                return out, steps, state, cache
-
-            fn = jax.jit(nohist, donate_argnums=(1, 2))
-        self._dev_sched_jits[key] = fn
-        return fn
-
-    def _upload_hist(self, seq: _SeqState) -> None:
-        """Re-seed one slot's device history row from the host's complete
-        view (prompt + generated). Only legal when the slot has no
-        outstanding dispatches (refs drained) — otherwise host ``generated``
-        lags the device position row and the rebuilt history would hold a
-        hole right where the draft matcher reads."""
-        row = np.zeros(self.cfg.max_seq_len, np.int32)
-        toks = list(seq.prompt) + list(seq.generated)
-        row[:len(toks)] = toks
-        self.h2d_bytes += row.nbytes + 4
-        self._hist_dev = self._hist_row_jit(
-            self._hist_dev, np.int32(seq.slot), row)
-        self._hist_stale[seq.slot] = False
-
-    def _dispatch_sched_device(self) -> bool:
-        """Dispatch one multi-step scheduler program when every running
-        sequence is decoding. Budgets PER ROW (a row near its length limit
-        does not cap the whole chunk — the program retires it in place),
-        advances host positions optimistically by each row's own budget,
-        and queues a pending record carrying the per-row ``steps_taken``
-        readback."""
-        cfg = self.cfg
-        k_max = cfg.sched_steps
-        seqs = [s for s in self._running.values() if not s.finished]
-        if not seqs or any(not s.in_decode for s in seqs):
-            return False
-        if self._queued and self._free_slots:
-            k_max = min(k_max, SCHED_ADMISSION_CAP)
-        plan = []
-        max_bud = 0
-        for s in seqs:
-            bud = len(s.prompt) + s.max_new_tokens - 1 - s.pos
-            if bud <= 0:
-                continue  # fully scheduled; retires as pending reconciles
-            plan.append(s)
-            max_bud = max(max_bud, min(bud, k_max))
-        if not plan:
-            return False
-        # pow2 round DOWN: the device derives each row's step count as
-        # min(k, limit - pos), so k must never exceed the capacity the
-        # host actually reserved below
-        k = 1 << (max_bud.bit_length() - 1)
-        kept = []
-        for s in plan:
-            k_s = min(k, len(s.prompt) + s.max_new_tokens - 1 - s.pos)
-            if not self._ensure_capacity(s, s.pos + k_s):
-                s.preemptions += 1
-                self.preemptions += 1
-                continue
-            kept.append((s, k_s))
-        if not kept:
-            return False
-        if cfg.spec_draft:
-            stale = [s for s, _ in kept if self._hist_stale[s.slot]]
-            if any(s.refs for s in stale):
-                self._sched_wait = True
-                return False
-            for s in stale:
-                self._upload_hist(s)
-        t0 = time.perf_counter()
-        t = len(kept)
-        bucket = next(b for b in self._buckets if b >= t)
-        slots = np.full(bucket, cfg.max_seqs, np.int32)
-        eos = np.full(bucket, -1, np.int32)
-        limit = np.zeros(bucket, np.int32)
-        sampled = has_tk = has_tp = False
-        max_pos = 0
-        for j, (s, k_s) in enumerate(kept):
-            slots[j] = s.slot
-            if s.eos_token_id is not None:
-                eos[j] = s.eos_token_id
-            limit[j] = len(s.prompt) + s.max_new_tokens - 1
-            sampled = sampled or s.temperature > 0.0
-            has_tk = has_tk or s.top_k > 0
-            has_tp = has_tp or s.top_p < 1.0
-            max_pos = max(max_pos, s.pos + k_s - 1)
-        self._sync_bt()
-        staged = self._stage(np.concatenate([slots, eos, limit]))
-        fn = self._get_dev_sched(k, bucket, self._table_width(max_pos),
-                                 sampled, sampled and has_tk,
-                                 sampled and has_tp)
-        if self._faults.enabled:
-            self._faults.fire(POINT_DISPATCH)
-        if cfg.spec_draft:
-            out, steps, prop, acc, self._dev_state, self._hist_dev, \
-                self.cache = fn(
-                    self.params, self.cache, self._dev_state, self._hist_dev,
-                    self._bt_dev, staged, self._sample_root)
-        else:
-            out, steps, self._dev_state, self.cache = fn(
-                self.params, self.cache, self._dev_state, self._bt_dev,
-                staged, self._sample_root)
-            prop = acc = None
-        emits = []
-        sched_tok = 0
-        for s, k_s in kept:
-            # optimistic: the device may retire the row earlier on EOS;
-            # the overshoot is never rewound — the sequence finishes at
-            # reconcile and releases once its refs drain
-            s.pos += k_s
-            s.refs += 1
-            emits.append((s, k_s))
-            sched_tok += k_s
-        self.tokens_scheduled += sched_tok
-        self.tokens_padded += k * bucket - sched_tok
-        self._pending.append({"kind": "sched", "out": out, "steps": steps,
-                              "prop": prop, "acc": acc, "emits": emits,
-                              "participants": [s for s, _ in kept]})
-        self._note_dispatch(t0)
-        if self._tracer.enabled:
-            self._trace_spans(t0, time.perf_counter(),
-                              [(s, "engine/decode", ks) for s, ks in kept],
-                              mode="dev_sched")
-        return True
-
     def _dispatch_step_device(self) -> bool:
         """The device-resident SplitFuse step (plain or tiled): stage the
         packer's plan as one packed buffer (decode rows carry no
@@ -2763,9 +2401,7 @@ class RaggedInferenceEngine:
             participants[seq.slot] = seq
         for seq in participants.values():
             seq.refs += 1
-            self._hist_stale[seq.slot] = True
-        self._pending.append({"kind": "step", "picked": picked,
-                              "emit": emit,
+        self._pending.append({"picked": picked, "emit": emit,
                               "participants": list(participants.values())})
         self._note_dispatch(t0)
         if tpairs is not None:
@@ -2860,13 +2496,10 @@ class RaggedInferenceEngine:
         for seq in list(self._running.values()):
             if seq.finished or not seq.in_decode or n_dec >= dec_cap:
                 continue
-            # the feed at limit-1 yields the final budgeted token; sched
-            # mode uses the exact bound (its own budgets already do), the
-            # others keep the historical +1 slop (extra token is discarded
-            # at reconcile)
+            # the feed at limit-1 yields the final budgeted token; one
+            # position past it is scheduled too (its token is discarded at
+            # reconcile)
             lim = len(seq.prompt) + seq.max_new_tokens
-            if cfg.sched_steps >= 2:
-                lim -= 1
             if seq.pos >= lim:
                 continue  # fully scheduled; retires as pending reconciles
             if not self._ensure_capacity(seq, seq.pos + 1):
@@ -2971,65 +2604,25 @@ class RaggedInferenceEngine:
 
     def _reconcile_pending(self) -> dict:
         """Read back the OLDEST pending dispatch's tokens and fold them
-        into host state (EOS/max_new enforcement via ``_append_tokens``;
-        release deferred until a sequence's last pending reference
-        drains)."""
+        into host state (a sequence past its EOS token or ``max_new_tokens``
+        takes no more: ``_append_token``; release is deferred until a
+        sequence's last pending reference drains)."""
         if self._faults.enabled:
             self._faults.fire(POINT_READBACK)
         rec = self._pending.pop(0)
         t0 = time.perf_counter()
         out: dict = {}
-        if rec["kind"] == "step":
-            with span("engine/readback"):
-                picked = np.asarray(rec["picked"])
-            t1 = time.perf_counter()
-            self.readback_ns += int((t1 - t0) * 1e9)
-            if self._tracer.enabled:
-                self._trace_spans(t0, t1, [(s, "engine/readback", 1)
-                                           for _, s in rec["emit"]])
-            for row, seq in rec["emit"]:
-                self._append_tokens(seq, [int(picked[row])], out)
-            if self._counters:
-                self._note_step_counts(picked[-len(self._counters):])
-        else:  # "sched"
-            toks = np.asarray(rec["out"])    # [K, bucket]
-            steps = np.asarray(rec["steps"])  # [bucket] device steps_taken
-            t1 = time.perf_counter()
-            self.readback_ns += int((t1 - t0) * 1e9)
-            if self._tracer.enabled:
-                self._trace_spans(t0, t1, [(s, "engine/readback", ks)
-                                           for s, ks in rec["emits"]])
-            for j, (seq, _ks) in enumerate(rec["emits"]):
-                n = int(steps[j])
-                if n:
-                    self._append_tokens(seq, toks[:n, j], out)
-            if rec["prop"] is not None:
-                p = int(np.asarray(rec["prop"]))
-                a = int(np.asarray(rec["acc"]))
-                self.spec_proposed += p
-                self.spec_accepted += a
-                if p:
-                    # the device returns one aggregate (proposed, accepted)
-                    # per sched dispatch; apportion to tenants proportionally
-                    # to each sequence's committed steps this dispatch
-                    total_n = float(sum(int(steps[j])
-                                        for j in range(len(rec["emits"]))))
-                    if total_n > 0.0:
-                        for j, (seq, _ks) in enumerate(rec["emits"]):
-                            if seq.cost is None:
-                                continue
-                            frac = int(steps[j]) / total_n
-                            seq.cost.spec_proposed += p * frac
-                            seq.cost.spec_accepted += a * frac
-                if self.telemetry.enabled and p:
-                    self.telemetry.counter(
-                        "spec_tokens_proposed_total",
-                        "draft tokens proposed by self-speculative "
-                        "decode").inc(p)
-                    self.telemetry.counter(
-                        "spec_tokens_accepted_total",
-                        "draft tokens accepted by exact-match "
-                        "verification").inc(a)
+        with span("engine/readback"):
+            picked = np.asarray(rec["picked"])
+        t1 = time.perf_counter()
+        self.readback_ns += int((t1 - t0) * 1e9)
+        if self._tracer.enabled:
+            self._trace_spans(t0, t1, [(s, "engine/readback", 1)
+                                       for _, s in rec["emit"]])
+        for row, seq in rec["emit"]:
+            self._append_token(seq, int(picked[row]), out)
+        if self._counters:
+            self._note_step_counts(picked[-len(self._counters):])
         for seq in rec["participants"]:
             seq.refs -= 1
             if seq.finished and seq.refs == 0 and seq.slot >= 0:
@@ -3051,19 +2644,12 @@ class RaggedInferenceEngine:
 
     def _step_device(self) -> dict:
         """One device-resident turn: admit, dispatch one step if anything is
-        schedulable (the multi-step scheduler program when ``sched_steps``
-        is set and every row decodes, the SplitFuse step otherwise), then
-        reconcile the oldest pending dispatch once the window holds two — so
-        the blocking ``np.asarray`` readback of step t overlaps the device
-        executing step t+1."""
+        schedulable, then reconcile the oldest pending dispatch once the
+        window holds two — so the blocking ``np.asarray`` readback of step t
+        overlaps the device executing step t+1."""
         with span("engine/schedule"):
             self._admit_queued()
-        dispatched = False
-        self._sched_wait = False
-        if self.cfg.sched_steps >= 2:
-            dispatched = self._dispatch_sched_device()
-        if not dispatched and not self._sched_wait:
-            dispatched = self._dispatch_step_device()
+        dispatched = self._dispatch_step_device()
         if self._pending and (not dispatched or len(self._pending) >= 2):
             return self._reconcile_pending()
         if not dispatched and not self._pending and (
@@ -3151,96 +2737,42 @@ class RaggedInferenceEngine:
     def warmup(self, sampled: bool = False, has_tk: bool = False,
                has_tp: bool = False) -> int:
         """Turn the persistent compilation cache on
-        (``utils/compile_cache.py``) and, when ``sched_steps`` >= 2,
-        precompile the multi-step scheduler family via ``lower().compile()``
-        (no execution, no engine state touched): every NOVEL combo otherwise
-        costs seconds of compilation in the middle of serving, and a program
-        the compiler refuses raises here, before any request is taken. The
-        SplitFuse step programs are NOT compiled here (a server runs each
-        once at set-up; they reach later processes through the cache). What
-        is set here is the cold-cache probe's counter, the compile watch's
+        (``utils/compile_cache.py``). The step programs are NOT compiled here
+        (a server runs each once at set-up; they reach later processes
+        through the cache), so the arguments choose nothing and the count of
+        programs compiled, which is returned, is 0. What is set here is the
+        cold-cache probe's counter, the compile watch's
         (``telemetry/compile_watch.py``: builds whose executable jax wrote to
         the cache): if the FIRST step program a server then runs misses the
         cache and is written to it, none of them was in it, and the
         engine compiles the rest in the background while the server goes on
-        (``_precompile_zoo_in_background``); a warm cache sees no change.
-        Returns the number of programs compiled, 0 without ``sched_steps``.
-        Greedy combos by default; call again with ``sampled``/filter flags
-        for sampling workloads."""
+        (``_precompile_zoo_in_background``); a warm cache sees no change."""
         from deepspeed_tpu.telemetry.compile_watch import WATCH
         from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-        n = 0
-        with phase("engine/warmup", sched_steps=self.cfg.sched_steps):
+        with phase("engine/warmup"):
             if enable_compile_cache() and self._cache_misses is None:
                 self._cache_misses = WATCH.cache_writes
-            if self.cfg.sched_steps >= 2 and self.cfg.device_state:
-                n += self._warmup_sched(sampled, has_tk, has_tp)
-        # warmup's own program-cache fills are not serve-time misses: reset
+        # program-cache fills before this are not serve-time misses: reset
         # the dispatch baseline so warmup_coverage reflects live traffic only
         self._warmed = True
         self.program_dispatches = 0
         self.program_cold_dispatches = 0
-        return n
+        return 0
 
-    def _warmup_sched(self, sampled: bool, has_tk: bool,
-                      has_tp: bool) -> int:
-        """Lower the multi-step scheduler programs the dispatcher can reach:
-        k is the pow2 round-DOWN of the deepest per-row budget (every pow2
-        <= sched_steps), t the bucket for 1..max_seqs rows, width from the
-        table ladder."""
-        cfg = self.cfg
-        ks = set()
-        p = 1
-        while p <= cfg.sched_steps:
-            ks.add(p)
-            p *= 2
-        bmax = next(b for b in self._buckets if b >= cfg.max_seqs)
-        buckets = [b for b in self._buckets if b <= bmax]
-        abstract = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
-        cache_abs = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.cache)
-        state_abs = tuple(
-            jax.ShapeDtypeStruct((cfg.max_seqs + 1,), dt)
-            for dt in (jnp.int32, jnp.int32, jnp.int32, jnp.int32,
-                       jnp.float32, jnp.int32, jnp.float32))
-        btf_abs = jax.ShapeDtypeStruct(self.block_tables.shape, jnp.int32)
-        hist_abs = jax.ShapeDtypeStruct(
-            (cfg.max_seqs + 1, cfg.max_seq_len), jnp.int32)
-        rng_abs = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        n = 0
-        for kk in sorted(ks):
-            for b in buckets:
-                for w in self._width_ladder():
-                    fn = self._get_dev_sched(kk, b, w, sampled,
-                                             sampled and has_tk,
-                                             sampled and has_tp)
-                    staged_abs = jax.ShapeDtypeStruct((3 * b,), jnp.int32)
-                    if cfg.spec_draft:
-                        fn.lower(abstract, cache_abs, state_abs, hist_abs,
-                                 btf_abs, staged_abs, rng_abs).compile()
-                    else:
-                        fn.lower(abstract, cache_abs, state_abs, btf_abs,
-                                 staged_abs, rng_abs).compile()
-                    n += 1
-        return n
-
-    def _append_tokens(self, seq: _SeqState, toks, out: dict) -> None:
-        now = time.perf_counter() if self.telemetry.enabled else 0.0
-        if seq.cost is not None and not seq.finished:
-            # single choke point every dispatch mode funnels emitted tokens
-            # through: one dispatch participation, len(toks) decode tokens
+    def _append_token(self, seq: _SeqState, tok: int, out: dict) -> None:
+        """Fold one read-back token of the device step into its sequence
+        (the host-staged step's emission is ``_emit_tokens``)."""
+        if seq.finished:
+            return  # the row of a step dispatched past the sequence's end
+        if seq.cost is not None:
             seq.cost.decode_dispatches += 1
-            seq.cost.decode_tokens += len(toks)
-        for t in toks:
-            if seq.finished:
-                break  # post-EOS speculation: discard
-            seq.generated.append(int(t))
-            out[seq.uid] = int(t)
-            self.tokens_emitted += 1
-            if now:
-                self._stamp_emission(seq, now)
+            seq.cost.decode_tokens += 1
+        seq.generated.append(tok)
+        out[seq.uid] = tok
+        self.tokens_emitted += 1
+        if self.telemetry.enabled:
+            self._stamp_emission(seq, time.perf_counter())
 
     def drain(self) -> dict:
         """Reconcile every pending dispatch (a flush point for callers that
@@ -3516,7 +3048,7 @@ class RaggedInferenceEngine:
     # ------------------------------------------------- dispatch watchdog
     def _recover_device_path(self) -> None:
         """Re-anchor the engine on host ground truth after a failed step:
-        discard ALL unread speculation (the pending readbacks — partially
+        discard ALL unread steps (the pending readbacks — partially
         draining them could interleave token order) and rewind every running
         sequence's schedule position to what its host-visible ``generated``
         list proves was delivered. Re-running
@@ -3555,8 +3087,6 @@ class RaggedInferenceEngine:
         # table wholesale and re-seed the slot rows from host truth
         self._bt_dirty.clear()
         self._bt_dev = jnp.asarray(self.block_tables)
-        self._hist_stale[:] = True
-        self._sched_wait = False
         if self.cfg.device_state:
             for seq in self._running.values():
                 self._write_slot_row(seq)
@@ -3731,10 +3261,6 @@ class RaggedInferenceEngine:
             jnp.zeros(s1, jnp.float32), jnp.zeros(s1, jnp.int32),
             jnp.ones(s1, jnp.float32),
         )
-        self._hist_dev = (jnp.zeros((s1, self.cfg.max_seq_len), jnp.int32)
-                          if self.cfg.spec_draft else None)
-        self._hist_stale[:] = True
-        self._sched_wait = False
         self._block_tenant.clear()  # fresh allocator: stale block ids
         self._cost_last_tick = 0.0
         self.cache = self._build_cache()
@@ -3748,9 +3274,7 @@ class RaggedInferenceEngine:
 
     def step(self) -> dict:
         """One SplitFuse step. Returns {uid: token} for sequences that emitted
-        a token this step (under ``sched_steps``: the LAST token of each
-        sequence's chunk; the full stream is in the per-sequence state).
-        Runs under the dispatch watchdog: transient
+        a token this step. Runs under the dispatch watchdog: transient
         device-path failures are retried (and eventually degraded) in
         place, so callers only ever see fatal errors."""
         if not self.has_work:
@@ -3795,13 +3319,8 @@ class RaggedInferenceEngine:
             self.dispatch_count)
         if self.tokens_emitted:
             g("ragged_dispatches_per_token",
-              "device dispatches divided by tokens emitted (multi-step "
-              "scheduling + speculation drive this toward 0)").set(
+              "device dispatches divided by tokens emitted").set(
                   self.dispatch_count / self.tokens_emitted)
-        if self.spec_proposed:
-            g("spec_acceptance_rate",
-              "accepted / proposed draft tokens (cumulative)").set(
-                  self.spec_accepted / self.spec_proposed)
         g("degraded_mode",
           "0 full | 1 host-staged fallback | 2 plain-step fallback").set(
               self.degraded_mode)
@@ -3896,9 +3415,8 @@ class RaggedInferenceEngine:
         """The two ways the engine takes a step:
 
         sweep aborts -> ``_step_device()`` if ``cfg.device_state`` (the
-                        normal path: admit -> [``sched_steps`` >= 2 and all
-                        rows decode: ``_dispatch_sched_device``] ->
-                        ``_dispatch_step_device`` -> reconcile)
+                        normal path: admit -> ``_dispatch_step_device``
+                        -> reconcile)
                      -> the host-staged step below otherwise (rung 1 of the
                         watchdog's ladder; the tests' reference)
 

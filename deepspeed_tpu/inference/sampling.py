@@ -146,76 +146,3 @@ def update_seen(seen_mask, tokens):
     """Mark freshly emitted tokens in the occurrence mask ([T, V] x [T])."""
     return seen_mask.at[jnp.arange(tokens.shape[0]), tokens].set(True)
 
-
-# --------------------------------------------------- speculative decoding
-def propose_ngram_drafts(hist, pos, ngram, depth):
-    """Prompt-lookup draft source (self-speculation without a draft model):
-    for each row, match the ``ngram``-token suffix ending at ``pos`` against
-    every earlier window of that row's token history and propose the
-    ``depth`` tokens that followed the MOST RECENT match.
-
-    ``hist`` [T, S] int32 — per-row token history (``hist[r, p]`` is the
-    token at context position ``p``; positions past the row's frontier hold
-    stale/zero values, which is safe because proposals are always verified).
-    ``pos`` [T] int32 — index of the newest valid token per row. ``ngram``
-    and ``depth`` are Python ints (static under jit).
-
-    Returns ``(draft [T, depth] int32, matched [T] bool)``; unmatched rows
-    draft zeros. Pure vectorized jnp — O(ngram * S) compares per row, no
-    host round trip, so it runs INSIDE the device scheduler loop reading
-    history the loop itself appends to."""
-    t, s = hist.shape
-    idxs = jnp.arange(s)[None, :]                       # candidate ends j
-    match = jnp.ones((t, s), bool)
-    for i in range(ngram):                              # static, small
-        sfx_i = jnp.take_along_axis(
-            hist, jnp.clip(pos - i, 0, s - 1)[:, None], axis=1)  # [T,1]
-        cand = jnp.take_along_axis(
-            hist, jnp.clip(idxs - i, 0, s - 1), axis=1)          # [T,S]
-        match &= cand == sfx_i
-    valid = (idxs >= ngram - 1) & (idxs < pos[:, None]) \
-        & (pos[:, None] >= ngram - 1)
-    jstar = jnp.max(jnp.where(match & valid, idxs, -1), axis=1)  # [T]
-    matched = jstar >= 0
-    gather = jnp.clip(jstar[:, None] + 1 + jnp.arange(depth)[None, :],
-                      0, s - 1)
-    draft = jnp.take_along_axis(hist, gather, axis=1)
-    return jnp.where(matched[:, None], draft, 0), matched
-
-
-def accept_drafts(draft, picked, budget, eos):
-    """Vectorized acceptance-prefix selection for exact-match speculative
-    verification.
-
-    ``picked`` [T, 1+D] are the TARGET model's deterministic picks at the
-    verify lanes (lane i is the pick for generated index g+i); ``draft``
-    [T, D] the proposed continuation (draft lane i was fed at verify lane
-    i+1). A draft token is accepted while it equals the target's own pick
-    for that index — so the surfaced stream is bit-identical to plain
-    autoregressive decoding (greedy AND seeded: our sampler is a
-    deterministic function of (seed, gen_idx), which makes exact-match the
-    degenerate rejection sampler whose residual is the target pick itself).
-    The first mismatching lane contributes the target's pick as the bonus/
-    resample token.
-
-    ``budget`` [T] caps surfaced tokens (remaining emission budget, >= 1
-    for live rows); ``eos`` [T] (-1 = none) truncates at the first EOS
-    *inclusive*. Returns ``(n_emit [T], n_accepted [T])``: surface
-    ``picked[r, :n_emit[r]]``; ``n_accepted`` counts surfaced tokens that
-    came from the draft (the speculation win; the +1 bonus is excluded)."""
-    t, lanes = picked.shape
-    d = lanes - 1
-    if d:
-        lead = jnp.cumprod((draft == picked[:, :d]).astype(jnp.int32),
-                           axis=1)
-        a = jnp.sum(lead, axis=1)                       # leading matches
-    else:
-        a = jnp.zeros((t,), jnp.int32)
-    n_emit = jnp.minimum(a + 1, jnp.maximum(budget, 0))
-    lane_i = jnp.arange(lanes)[None, :]
-    is_eos = (picked == eos[:, None]) & (eos >= 0)[:, None]
-    eos_at = jnp.min(jnp.where(is_eos & (lane_i < n_emit[:, None]),
-                               lane_i, lanes), axis=1)
-    n_emit = jnp.where(eos_at < n_emit, eos_at + 1, n_emit)
-    return n_emit.astype(jnp.int32), \
-        jnp.minimum(n_emit, a).astype(jnp.int32)

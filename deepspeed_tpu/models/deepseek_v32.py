@@ -37,8 +37,7 @@ benchmark's configuration): the published indexer rotates ``qI`` and ``kI`` by a
 Hadamard matrix and keeps ``kI`` in FP8 with scales; the rotation is orthogonal
 and changes no score, so it is left out, and the index keys are cached in the
 engine's dtype. The multi-token-prediction module is outside the forward pass.
-A quantized pool, ``sched_steps`` and ``spec_draft`` are refused by the engine
-(``ModelSpec.index_topk``).
+A quantized pool is refused (``init_paged_cache``).
 """
 
 from __future__ import annotations

@@ -78,9 +78,8 @@ v1-style engines.
   scatter of the step's rows a layer and nothing else of the leaf's size.
 - What a prefix of blocks cannot restore, refuses: the engine raises at
   construction for ``enable_prefix_cache``, ``kv_tier``, ``KVHandoff``
-  (all need a state snapshot at a block boundary), ``sched_steps`` /
-  ``spec_draft`` (a rejected position needs a rollback of the state) and a
-  quantized pool, when the cache has slot leaves.
+  (all need a state snapshot at a block boundary) and a quantized pool,
+  when the cache has slot leaves.
 """
 
 from __future__ import annotations
@@ -427,44 +426,6 @@ def latent_pool_attention(q, pool, slots, positions, block_tables, lat: int,
             q, pool, sl, po, block_tables, lat, scale),
         lambda q, ts, tp, tv, ct: latent_prefill_attention(
             q, pool, ts, tp, tv, block_tables, ct, lat, scale))
-
-
-def speculative_lane_layout(cur_tok, draft, pos, live, cap, slots,
-                            scratch_slot):
-    """Flatten a [T]-row decode batch plus per-row draft proposals into the
-    flat verify batch one ragged forward consumes.
-
-    Row ``r`` occupies lanes ``r*(1+D) .. r*(1+D)+D``: lane 0 feeds the
-    row's current token at ``pos[r]`` (the plain decode step), lane ``1+i``
-    feeds ``draft[r, i]`` at ``pos[r] + 1 + i`` — so one forward scores the
-    committed step AND every draft position, and because ``write_kv_paged``
-    scatters each lane's KV before attention runs, later lanes attend over
-    earlier lanes' keys within the same dispatch. Rejected-draft KV needs no
-    rollback: positions are fed strictly monotonically, so a rejected cell
-    is always re-scattered by a later dispatch before anything attends to it.
-
-    Lanes of dead rows (``live`` False) and lanes at/past the row's covered
-    capacity ``cap[r]`` (first position WITHOUT an allocated block) are
-    routed to ``scratch_slot`` at position 0 — their writes land in the
-    scratch block and their picks are never surfaced (the emission budget
-    clamps first). Returns flat ``(tokens, slots, positions, raw_positions)``
-    each [T*(1+D)]; ``raw_positions`` keeps the unrouted positions for
-    per-lane sampling-key derivation."""
-    t = cur_tok.shape[0]
-    d = 0 if draft is None else draft.shape[1]
-    lanes = 1 + d
-    lane_pos_raw = pos[:, None] + jnp.arange(lanes)[None, :]     # [T, L]
-    if d:
-        lane_tok = jnp.concatenate([cur_tok[:, None], draft], axis=1)
-    else:
-        lane_tok = cur_tok[:, None]
-    ok = live[:, None] & (lane_pos_raw < cap[:, None])
-    lane_slot = jnp.where(ok, slots[:, None], scratch_slot)
-    lane_pos = jnp.where(ok, lane_pos_raw, 0)
-    return (lane_tok.reshape(-1).astype(jnp.int32),
-            lane_slot.reshape(-1).astype(jnp.int32),
-            lane_pos.reshape(-1).astype(jnp.int32),
-            lane_pos_raw.reshape(-1).astype(jnp.int32))
 
 
 def append_kv_and_attend(q, kk, vv, k_cache, v_cache, start_pos, max_len):

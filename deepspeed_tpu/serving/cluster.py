@@ -712,18 +712,10 @@ class ServingCluster:
         """Cluster-level observability block (embedded in /healthz and the
         disagg bench JSON)."""
         roles: dict[str, int] = {}
-        spec_p = spec_a = 0
         for r in self.router._snapshot()[0]:
             roles[r.role] = roles.get(r.role, 0) + 1
-            spec_p += int(getattr(r._engine, "spec_proposed", 0))
-            spec_a += int(getattr(r._engine, "spec_accepted", 0))
         return {
             "roles": roles,
-            # self-speculative decode economy pooled across replicas (draft
-            # history itself is NOT part of the handoff record: the decode
-            # side rebuilds it from prompt+generated on adoption)
-            "speculation": {"proposed": spec_p, "accepted": spec_a,
-                            "acceptance_rate": spec_a / max(spec_p, 1)},
             "prefix_index": self.index.stats(),
             "disagg_requests": self.disagg_requests,
             "handoffs": {"ok": self.handoffs_ok,

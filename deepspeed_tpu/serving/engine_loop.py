@@ -135,12 +135,9 @@ class ReplicaStats:
     # (handoff exports), "decode" replicas adopt handoffs; "unified" does
     # everything. plan_placement() filters on this.
     role: str = "unified"
-    # multi-step scheduled decode: dispatches per emitted token (1.0 for a
-    # per-token engine; the device-side scheduler drives it toward 1/K) and
-    # the self-speculation draft economy, for cluster-level observability
+    # device dispatches per emitted token (under 1.0 where a step carries
+    # several sequences' decode rows)
     dispatches_per_token: float = 1.0
-    spec_proposed: int = 0
-    spec_accepted: int = 0
     # measured free-byte headroom expressed in KV blocks (-1 = backend does
     # not report memory limits; routers fall back to the static block math)
     headroom_blocks: int = -1
@@ -430,8 +427,6 @@ class EngineLoop:
             dispatches_per_token=(
                 getattr(self._engine, "dispatch_count", 0)
                 / max(getattr(self._engine, "tokens_emitted", 0), 1)),
-            spec_proposed=int(getattr(self._engine, "spec_proposed", 0)),
-            spec_accepted=int(getattr(self._engine, "spec_accepted", 0)),
             headroom_blocks=int(getattr(
                 self._engine, "admission_headroom_blocks", lambda: -1)()))
 

@@ -7,8 +7,7 @@ Every served request accumulates a :class:`RequestCost` record at the seams
 the ragged engine already owns:
 
 - prefill tokens x analytic FLOPs/token (``flops_profiler.get_model_profile``)
-- decode tokens and host dispatches, speculative lanes charged as proposed
-  vs accepted separately
+- decode tokens and host dispatches
 - KV **block-seconds**: the occupancy integral of the request's blocks from
   admission to release, including a retained-prefix carveout credited to
   the *publishing* tenant while its blocks sit in the cache, and a
@@ -58,8 +57,6 @@ class RequestCost:
     prefill_flops: float = 0.0
     decode_tokens: int = 0
     decode_dispatches: int = 0
-    spec_proposed: float = 0.0
-    spec_accepted: float = 0.0
     kv_block_seconds: float = 0.0
     prefix_credit_blocks: int = 0   # cached blocks this request published
     prefix_debit_blocks: int = 0    # cached blocks spliced from other tenants
@@ -94,8 +91,6 @@ class _TenantRow:
     prefill_flops: float = 0.0
     decode_tokens: int = 0
     decode_dispatches: int = 0
-    spec_proposed: float = 0.0
-    spec_accepted: float = 0.0
     kv_block_seconds: float = 0.0
     retained_block_seconds: float = 0.0
     prefix_credit_blocks: int = 0
@@ -115,8 +110,6 @@ class _TenantRow:
             "prefill_flops": self.prefill_flops,
             "decode_tokens": self.decode_tokens,
             "decode_dispatches": self.decode_dispatches,
-            "spec_proposed": round(self.spec_proposed, 3),
-            "spec_accepted": round(self.spec_accepted, 3),
             "kv_block_seconds": round(self.kv_block_seconds, 6),
             "retained_block_seconds": round(self.retained_block_seconds, 6),
             "prefix_credit_blocks": self.prefix_credit_blocks,
@@ -166,8 +159,6 @@ class TenantLedger:
             row.prefill_flops += cost.prefill_flops
             row.decode_tokens += cost.decode_tokens
             row.decode_dispatches += cost.decode_dispatches
-            row.spec_proposed += cost.spec_proposed
-            row.spec_accepted += cost.spec_accepted
             row.kv_block_seconds += cost.kv_block_seconds
             row.prefix_credit_blocks += cost.prefix_credit_blocks
             row.prefix_debit_blocks += cost.prefix_debit_blocks
@@ -276,12 +267,6 @@ class CostMeter:
         self._c_dispatches = reg.counter(
             "request_cost_decode_dispatches_total",
             "host dispatches a request participated in, by tenant")
-        self._c_spec_prop = reg.counter(
-            "request_cost_spec_proposed_total",
-            "speculative draft tokens charged as proposed, by tenant")
-        self._c_spec_acc = reg.counter(
-            "request_cost_spec_accepted_total",
-            "speculative draft tokens charged as accepted, by tenant")
         self._c_block_s = reg.counter(
             "request_cost_kv_block_seconds_total",
             "KV block-seconds consumed (occupancy integral), by tenant")
@@ -385,10 +370,6 @@ class CostMeter:
             self._c_decode_tok.inc(cost.decode_tokens, **labels)
         if cost.decode_dispatches:
             self._c_dispatches.inc(cost.decode_dispatches, **labels)
-        if cost.spec_proposed:
-            self._c_spec_prop.inc(cost.spec_proposed, **labels)
-        if cost.spec_accepted:
-            self._c_spec_acc.inc(cost.spec_accepted, **labels)
         self._c_block_s.inc(cost.kv_block_seconds, **labels)
         if cost.tier_promote_bytes:
             self._c_promote_b.inc(cost.tier_promote_bytes, **labels)

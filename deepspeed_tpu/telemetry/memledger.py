@@ -6,8 +6,7 @@ stepscope); this module is the matching byte-truth layer. Every long-lived
 device allocation registers under an **owner tag** from a fixed taxonomy —
 ``params``, ``optimizer_shards``, ``grads``, ``kv_pool``,
 ``prefix_cache_retained``, ``device_sched_state``, ``staging_buffers``,
-``kv_handoff``, ``spec_lanes`` — with pytree-computed nbytes. Two attribution
-shapes exist:
+``kv_handoff`` — with pytree-computed nbytes. Two attribution shapes exist:
 
 - **handles** (``register`` / ``update`` / ``release``): a fixed allocation
   whose size changes only at explicit lifecycle events (params, the paged KV
@@ -65,7 +64,6 @@ OWNERS = (
     "device_sched_state",      # device-resident scheduler rows/block table
     "staging_buffers",         # H2D staging + checkpoint host snapshots
     "kv_handoff",              # parked KV blocks awaiting disagg export
-    "spec_lanes",              # speculative-decode history/draft state
     "host_kv_tier",            # demoted KV blocks in the host-RAM arena
     "disk_kv_tier",            # demoted KV blocks spilled to disk
 )

@@ -156,20 +156,22 @@ class TestPrecedence:
         assert "zero_optimization.stage" in rec["skipped"]
 
     @pytest.mark.parametrize(
-        "stale", [{}, {"a_knob_since_removed": 8}], ids=["current", "stale"])
+        "stale", [{}, {"a_knob_since_removed": 8},
+                  {"sched_steps": 16, "spec_draft": 4}],
+        ids=["current", "stale", "k_step_knobs"])
     def test_serving_profile_fills_defaults_only(self, stale):
         """Config wins, defaults are filled, and a saved profile that names
         a knob ``RaggedConfig`` no longer has is skipped, not an error."""
         from deepspeed_tpu.inference.ragged import RaggedConfig
 
-        rcfg = RaggedConfig(sched_steps=4)  # operator-written
+        rcfg = RaggedConfig(prefill_tile=4)  # operator-written
         rec = profiles.apply_serving_profile(
-            rcfg, {"overrides": {"sched_steps": 16, "prefill_tile": 16,
-                                 **stale}})
-        assert rcfg.sched_steps == 4  # config wins
-        assert rcfg.prefill_tile == 16  # still-default field filled
-        assert rec["skipped"] == {"sched_steps": 16, **stale}
-        assert rec["applied"] == {"prefill_tile": 16}
+            rcfg, {"overrides": {"prefill_tile": 16,
+                                 "enable_prefix_cache": True, **stale}})
+        assert rcfg.prefill_tile == 4  # config wins
+        assert rcfg.enable_prefix_cache is True  # still-default field filled
+        assert rec["skipped"] == {"prefill_tile": 16, **stale}
+        assert rec["applied"] == {"enable_prefix_cache": True}
         assert not any(hasattr(rcfg, name) for name in stale)
 
 
@@ -177,7 +179,7 @@ class TestKnobSpace:
     def test_registry_shape(self):
         train = DEFAULT_SPACE.knobs(TRAIN)
         serve = DEFAULT_SPACE.knobs(SERVE)
-        assert len(train) >= 5 and len(serve) >= 7
+        assert len(train) >= 5 and len(serve) == 5
         for k in train + serve:
             assert k.default in k.domain
         # a serve knob is a RaggedConfig field: a knob for a field that is
@@ -292,13 +294,13 @@ class TestKnobSearch:
 
     def test_gate_violation_disqualifies(self):
         """A faster config that trips parity or census can never win."""
-        key = frozenset({("sched_steps", 16)})
+        key = frozenset({("prefill_tile", 16)})
         scores = {frozenset(): 1.0, key: 100.0}
-        out = KnobSearch(SERVE, knob_names=("sched_steps",),
+        out = KnobSearch(SERVE, knob_names=("prefill_tile",),
                          probe_runner=_fake_runner(
                              scores, gates={key: {"parity_ok": False}})
                          ).tune()
-        assert "sched_steps" not in out["best_overrides"]
+        assert "prefill_tile" not in out["best_overrides"]
         assert out["gate_failures"] == 1
         assert out["gate_violations_accepted"] == 0
 

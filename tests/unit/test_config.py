@@ -1,5 +1,7 @@
 """Config-tree tests (reference test analog: config round-trips, batch triangle)."""
 
+import os
+
 import pytest
 
 from deepspeed_tpu.config.base import AUTO, ConfigError
@@ -113,3 +115,36 @@ def test_load_config_from_json(tmp_path):
     import jax.numpy as jnp
 
     assert cfg.compute_dtype == jnp.float16
+
+
+# ------------------------------------------- the documents name what exists
+# ``RaggedConfig`` fields that are gone (PR 28, PR 43): a document that still
+# names one describes an engine nobody can build
+REMOVED_RAGGED_FIELDS = ("decode_run_ahead", "run_ahead_admission_cap",
+                         "fused_chunk", "pipeline_depth", "sched_steps",
+                         "spec_draft", "spec_ngram")
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def _read(path):
+    with open(os.path.join(REPO, path), encoding="utf-8") as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("path", [
+    "README.md", "docs/SERVING.md", "docs/OBSERVABILITY.md",
+    "docs/AUTOTUNING.md", "docs/FAULT_TOLERANCE.md"])
+def test_no_document_names_a_removed_serving_option(path):
+    text = _read(path)
+    assert [name for name in REMOVED_RAGGED_FIELDS if name in text] == []
+
+
+def test_serving_guide_names_every_serving_option():
+    from dataclasses import fields
+
+    from deepspeed_tpu.inference.ragged import RaggedConfig
+
+    names = [f.name for f in fields(RaggedConfig)]
+    assert len(names) == 26 and not set(names) & set(REMOVED_RAGGED_FIELDS)
+    text = _read("docs/SERVING.md")
+    assert [name for name in names if f"`{name}`" not in text] == []

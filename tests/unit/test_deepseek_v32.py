@@ -578,12 +578,10 @@ def test_the_engine_reports_what_a_step_selected(params, monkeypatch):
 
 
 @pytest.mark.parametrize("over,match", [
-    (dict(sched_steps=2), "sched_steps is refused"),
-    (dict(sched_steps=2, spec_draft=2), "sched_steps is refused"),
     (dict(quant="int8"), "quantized latent pool"),
-], ids=["sched_steps", "spec_draft", "quantized_pool"])
+], ids=["quantized_pool"])
 def test_what_cannot_carry_the_selection_raises(params, over, match):
-    with pytest.raises((ValueError, NotImplementedError), match=match):
+    with pytest.raises(NotImplementedError, match=match):
         _engine(params, device_state=True, **over)
 
 

@@ -86,7 +86,8 @@ def served(tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
         assert loop.close(timeout=60)
-    return {"events": _host_events(trace_dir), "dispatches": eng.dispatch_count}
+    return {"events": _host_events(trace_dir), "dispatches": eng.dispatch_count,
+            "tokens_scheduled": eng.tokens_scheduled}
 
 
 @pytest.mark.parametrize("name", ["engine/schedule", "engine/stage",
@@ -96,6 +97,15 @@ def test_a_span_per_phase_of_every_dispatch(served, name):
     # every dispatch of the session has its phases (the loop's halves run
     # every turn, dispatching or not)
     assert len(served["events"].get(name, [])) >= served["dispatches"] > 0
+
+
+def test_every_dispatch_writes_one_dispatch_span(served):
+    # what lets the benchmark's readers match spans to the counters' deltas:
+    # no step is dispatched without a span, and the spans' rows are the
+    # counter's
+    spans = served["events"]["engine/dispatch"]
+    assert len(spans) == served["dispatches"]
+    assert sum(a["tokens"] for a in spans) == served["tokens_scheduled"]
 
 
 def test_a_dispatch_span_carries_its_work(served):

@@ -565,13 +565,45 @@ def test_dispatch_span_says_what_state_the_step_moved(params, family,
         a["state_bytes"] - a["dec_state_bytes"] for a in seen)
 
 
+@pytest.mark.parametrize("family", ["kimi_linear", "nemotron_h"])
+def test_the_watchdogs_ladder_ends_at_the_host_staged_step(params, family):
+    """Failures that go on take an engine with slot state to rung 1 (the
+    host-staged step) and no further: rung 2 turns prefill tiles off, and the
+    recurrence runs a prompt's rows as tiles. Each failed step starts its
+    sequences again from an empty state, and the tokens are the clean run's."""
+    from deepspeed_tpu.serving.faults import POINT_DISPATCH, get_fault_injector
+
+    mod, cfg, own = ((kimi_linear, CFG, params) if family == "kimi_linear"
+                     else (nemotron_h, nemotron_h.NemotronHConfig.tiny(), None))
+    eng = RaggedInferenceEngine(
+        lambda ctx: mod.build(cfg, ctx=ctx), RaggedConfig(
+            max_tokens_per_step=32, max_seqs=4, block_size=8,
+            num_blocks=33, max_blocks_per_seq=8, prefill_tile=8,
+            dispatch_retries=2, retry_backoff_s=0.0, degrade_after=2),
+        dtype=jnp.float32, params=own, seed=0)
+    outs = {}
+    for faulty in (False, True):   # one engine: the clean run first
+        if faulty:
+            get_fault_injector().configure(
+                [{"point": POINT_DISPATCH, "after": 2, "times": 4}])
+        for uid, prompt in _prompts([11, 5, 19]).items():
+            eng.put((faulty, uid), prompt, max_new_tokens=5)
+        outs[faulty] = {uid: toks for (run, uid), toks
+                        in eng.generate_all().items() if run is faulty}
+    assert outs[True] == outs[False]
+    assert eng.step_failures == 4
+    assert eng.degraded_mode == 1 and not eng.cfg.device_state
+    assert eng.cfg.prefill_tile == 8 and eng._use_tiles
+    assert eng._tiled_jits and not eng._pending  # the host-staged step served
+    assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1
+
+
 def test_decode_ladder_and_refusals(params):
     eng = _engine(params, max_tokens_per_step=512, max_seqs=128,
                   num_blocks=257, max_blocks_per_seq=2, prefill_tile=128)
     assert eng._dec_buckets == [128] and len(eng._step_zoo()) == 7
     assert eng.spec.state_kind == "kda"
     for sizes, match in ((dict(enable_prefix_cache=True), "snapshot"),
-                         (dict(sched_steps=2), "sched_steps"),
                          (dict(quant="int8"), "quantized pool"),
                          (dict(prefill_tile=0), "tile")):
         with pytest.raises((ValueError, NotImplementedError), match=match):

@@ -244,17 +244,6 @@ class TestEngineParity:
         assert token_match_rate(ref["seeded"], a) >= \
             DRIFT_BUDGET["greedy_match_min"]
 
-    def test_spec_decode_accept_rate_drift(self):
-        rates = {}
-        for name in ("off", "int8"):
-            eng = _engine(name, sched_steps=8, spec_draft=4)
-            eng.put("s", PROMPT_A, max_new_tokens=8)
-            eng.generate_all()
-            assert eng.spec_proposed > 0
-            rates[name] = eng.spec_accepted / eng.spec_proposed
-        drift = abs(rates["int8"] - rates["off"])
-        assert drift <= DRIFT_BUDGET["spec_accept_drift_max"], rates
-
     def test_prefix_cache_hit_parity(self):
         # a quant engine serving PROMPT_B from PROMPT_A's cached blocks must
         # match a cold quant engine exactly: the retained set holds the SAME
@@ -414,8 +403,8 @@ class TestDriftVerdict:
         assert token_match_rate({}, {}) == 1.0
 
     def test_verdict_applies_budget(self):
-        ok = drift_verdict(0.99, 0.01)
+        ok = drift_verdict(0.99)
         assert ok["ok"] and ok["budget"] == DRIFT_BUDGET
-        assert not drift_verdict(0.90, 0.0)["ok"]
-        assert not drift_verdict(1.0, 0.05)["ok"]
-        assert drift_verdict(1.0, None)["ok"]
+        assert ok["greedy_token_match_rate"] == 0.99
+        assert not drift_verdict(0.90)["ok"]
+        assert drift_verdict(0.90, {"greedy_match_min": 0.85})["ok"]

@@ -327,8 +327,6 @@ def test_decode_ladder_starts_where_the_model_says(params, family, max_seqs,
 REFUSED = {
     "enable_prefix_cache": (dict(enable_prefix_cache=True), "snapshot"),
     "kv_tier": (dict(enable_prefix_cache=True, kv_tier=True), "snapshot"),
-    "sched_steps": (dict(sched_steps=2), "sched_steps"),
-    "spec_draft": (dict(sched_steps=2, spec_draft=2), "s"),
     "quantized_pool": (dict(quant="int8"), "quantized pool"),
     "untiled_prefill": (dict(prefill_tile=0), "tile"),
 }
@@ -339,14 +337,6 @@ def test_engine_refuses_what_slot_state_cannot_restore(params, what):
     sizes, match = REFUSED[what]
     with pytest.raises((ValueError, NotImplementedError), match=match):
         _engine(params, **sizes)
-
-
-def test_spec_draft_names_the_rollback(params):
-    cfg = RaggedConfig(prefill_tile=8, max_tokens_per_step=32)
-    cfg.spec_draft = 2   # alone: the refusal is its own, not sched_steps'
-    with pytest.raises(ValueError, match="rollback"):
-        RaggedInferenceEngine(lambda ctx: nemotron_h.build(CFG, ctx=ctx), cfg,
-                              dtype=jnp.float32, params=params)
 
 
 def test_handoff_is_refused(params):

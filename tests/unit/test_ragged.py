@@ -167,17 +167,43 @@ class TestRaggedEngine:
             assert eng.allocator.free_blocks == total_free
             assert len(eng._free_slots) == RCFG.max_seqs
 
-    def test_eos_stops_sequence(self):
-        eng = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
-        # run once to learn what the first generated token is, then use it as eos
-        eng.put("probe", _prompts()["a"], max_new_tokens=4)
-        first = eng.generate_all()["probe"][0]
-        eng.put("x", _prompts()["a"], max_new_tokens=4, eos_token_id=first)
-        out = eng.generate_all()["x"]
-        assert out == [first]  # stopped at eos, not max_new
+    @pytest.mark.parametrize("mode", list(MODES))
+    def test_eos_stops_sequence(self, mode):
+        """An EOS token ends its sequence there, on every step path. The
+        device step reads a token back with the next step already
+        dispatched: the row the sequence has in that step surfaces no token,
+        and its slot and blocks come back once the step is reconciled."""
+        kw = {"device_state": True, **MODES[mode]}
+        prompts = _prompts()
+        # run once to learn the tokens, then make two of them EOS tokens: x's
+        # first, and one y has not produced before, mid-decode
+        eng = _engine_ds(**kw)
+        eng.put("px", prompts["a"], max_new_tokens=6)
+        eng.put("py", prompts["b"], max_new_tokens=6)
+        eng.put("pz", prompts["c"], max_new_tokens=6)
+        ref = {uid[1:]: toks for uid, toks in eng.generate_all().items()}
+        cut = next(i for i in range(1, 6) if ref["y"][i] not in ref["y"][:i])
+        emitted = eng.tokens_emitted
+        eng.put("x", prompts["a"], max_new_tokens=6,
+                eos_token_id=ref["x"][0])
+        eng.put("y", prompts["b"], max_new_tokens=6,
+                eos_token_id=ref["y"][cut])
+        eng.put("z", prompts["c"], max_new_tokens=6)
+        y = eng.get_request("y")
+        while not y.finished:
+            eng.step()
+        if kw["device_state"]:
+            assert eng._pending and y.refs == 1 and y.slot >= 0
+        else:
+            assert not eng._pending and y.slot < 0
+        out = {uid: toks for uid, toks in eng.generate_all().items()
+               if uid in "xyz"}
+        assert out["x"] == ref["x"][:1]  # stopped at eos, not max_new
+        assert out["y"] == ref["y"][:cut + 1]
+        assert out["z"] == ref["z"]
+        assert eng.tokens_emitted - emitted == sum(map(len, out.values()))
+        assert len(eng._free_slots) == RCFG.max_seqs
+        assert eng.allocator.free_blocks == RCFG.num_blocks - 1
 
     def test_never_admittable_request_rejected_at_put(self):
         """A request whose worst case exceeds the whole pool is rejected
@@ -243,7 +269,7 @@ class TestRaggedEngine:
 
 # what the device-resident state must stay token-identical in, against the
 # host-staged step under the same options
-DISPATCH_MODES = {m: MODES[m] for m in ("plain", "tiled", "sched")}
+DISPATCH_MODES = {m: MODES[m] for m in ("plain", "tiled")}
 
 
 def _engine_ds(device_state, **over):
@@ -278,16 +304,48 @@ class TestDeviceResidentState:
         greedy.put("s1", _prompts(19)["b"], max_new_tokens=8)
         assert greedy.generate_all()["s1"] != outs[True]["s1"]
 
-    def test_steady_decode_stages_zero_bytes(self):
+    @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
+    def test_counters_count_what_the_host_staged_steps_do(self, mode):
+        """``dispatch_count``, ``tokens_scheduled`` and ``tokens_padded``
+        (the benchmark's ``sched.*`` metrics read them as deltas) mean the
+        same on both step paths. On one workload the device step's are the
+        host-staged step's plus what its pending window adds and nothing
+        else: a request's last token is read back with the next step already
+        dispatched, so each request has one decode row past its end
+        (ROADMAP D1d), and the last of those rows rides a step of its own."""
+        kw = DISPATCH_MODES[mode]
+        engs = {}
+        for dev in (False, True):
+            eng = engs[dev] = _engine_ds(dev, **kw)
+            p = _prompts(17)
+            eng.put("a", p["a"], max_new_tokens=9)
+            eng.put("b", p["b"], max_new_tokens=5, temperature=0.9, top_k=20,
+                    seed=123)
+            eng.put("c", p["c"], max_new_tokens=7, temperature=0.7, top_p=0.9,
+                    seed=7)
+        host, dev = engs[False], engs[True]
+        assert dev.generate_all() == host.generate_all()
+        assert host.tokens_scheduled == 5 + 11 + 23 + (9 + 5 + 7 - 3)
+        assert dev.tokens_scheduled == host.tokens_scheduled + 3
+        assert dev.dispatch_count == host.dispatch_count + 1
+        # that step: one row in the smallest bucket there is
+        last = (dev._dec_buckets if dev._use_tiles else dev._buckets)[0]
+        assert dev.tokens_scheduled + dev.tokens_padded == \
+            host.tokens_scheduled + host.tokens_padded + last
+
+    @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
+    def test_steady_decode_stages_zero_bytes(self, mode):
         """The whole point: once every sequence is decoding, the packed
         staging buffer byte-compares equal step to step and the block table
         has no dirty rows — further steps upload NOTHING."""
         # block_size 16: the whole request (11 prompt + 5 new = 16 tokens)
         # fits one block, so no mid-decode table growth dirties a row
         eng = _engine_ds(True, block_size=16, num_blocks=13,
-                         max_blocks_per_seq=8)
+                         max_blocks_per_seq=8, **DISPATCH_MODES[mode])
         eng.put("a", _prompts(23)["b"], max_new_tokens=5)
-        eng.step()  # prefill dispatch
+        while eng._queued or not all(s.in_decode
+                                     for s in eng._running.values()):
+            eng.step()  # prefill dispatch
         eng.step()  # first decode dispatch (staging buffer cached here)
         assert all(s.in_decode for s in eng._running.values())
         h2d0 = eng.h2d_bytes
@@ -296,10 +354,11 @@ class TestDeviceResidentState:
         assert eng.h2d_bytes == h2d0, (
             "steady-state decode still staging host bytes")
 
-    def test_readback_is_double_buffered(self):
+    @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
+    def test_readback_is_double_buffered(self, mode):
         """A dispatched step's tokens are reconciled one step later (window
         of one pending dispatch), and drain() flushes the window."""
-        eng = _engine_ds(True)
+        eng = _engine_ds(True, **DISPATCH_MODES[mode])
         eng.put("a", _prompts()["a"], max_new_tokens=6)
         eng.step()  # prefill dispatched, nothing reconciled yet
         assert len(eng._pending) == 1
@@ -336,8 +395,9 @@ class TestDeviceResidentState:
         usable = RCFG.num_blocks - 1
         assert eng.allocator.free_blocks == usable
 
-    def test_deadline_timeout_mid_flight(self):
-        eng = _engine_ds(True)
+    @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
+    def test_deadline_timeout_mid_flight(self, mode):
+        eng = _engine_ds(True, **DISPATCH_MODES[mode])
         eng.put("t", _prompts()["c"], max_new_tokens=40, deadline_s=0.05)
         eng.step()
         import time as _time
@@ -348,12 +408,13 @@ class TestDeviceResidentState:
         assert seq.status == "timeout"
         assert len(eng._free_slots) == RCFG.max_seqs
 
-    def test_slot_reuse_rewrites_device_rows(self):
+    @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
+    def test_slot_reuse_rewrites_device_rows(self, mode):
         """A retired slot reused by a new request must behave as a fresh
         row (seed/params rewritten at admission): an oversubscribed sampled
-        workload matches the legacy host-staged path request for request."""
-        eng = _engine_ds(True)
-        fresh = _engine_ds(False)
+        workload matches the host-staged path request for request."""
+        eng = _engine_ds(True, **DISPATCH_MODES[mode])
+        fresh = _engine_ds(False, **DISPATCH_MODES[mode])
         for wave in (0, 1):
             for uid, p in _prompts(wave).items():
                 eng.put(f"{wave}-{uid}", p, max_new_tokens=5,
@@ -434,10 +495,10 @@ class TestStepPaths:
         if scenario == "pressure":
             assert n == 2 * RCFG.block_size  # a partial chunk, not 16 tokens
 
-    def test_warmup_without_k_step_option(self, monkeypatch):
-        """What the benchmark's set-up relies on: with no ``sched_steps``
-        ``warmup()`` compiles nothing, turns the compile cache on and zeroes
-        the cold-dispatch baseline."""
+    def test_warmup_compiles_nothing(self, monkeypatch):
+        """What the benchmark's set-up relies on: ``warmup()`` compiles
+        nothing, turns the compile cache on and zeroes the cold-dispatch
+        baseline."""
         turned_on = []
         monkeypatch.setattr(
             "deepspeed_tpu.utils.compile_cache.enable_compile_cache",
@@ -449,7 +510,6 @@ class TestStepPaths:
         assert eng.warmup() == 0
         assert turned_on == [True]
         assert eng.program_dispatches == eng.program_cold_dispatches == 0
-        assert not eng._dev_sched_jits
         eng.put("b", _prompts()["a"], max_new_tokens=3)
         eng.generate_all()
         assert eng.program_dispatches > 0

@@ -11,7 +11,8 @@ import pytest
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
-from deepspeed_tpu.models import deepseek, gpt2, llama, mixtral
+from deepspeed_tpu.models import (deepseek, deepseek_v32, gpt2, llama,
+                                  longcat_flash, mixtral)
 
 MIX = mixtral.MixtralConfig.tiny(89)
 GPT = gpt2.GPT2Config.tiny(89)
@@ -22,10 +23,18 @@ FAMILIES = {"gpt2": (gpt2, GPT), "mixtral": (mixtral, MIX),
 # dense-cache engine and no quantized pool, so it joins the cases that need
 # neither (the paged-addressing cases have their own in test_deepseek.py)
 DSK = deepseek.DeepseekConfig.tiny(89)
+# the latent families with more to rewrite when a step is run again: two block
+# leaves behind one table (the selection's index keys), two block layers a
+# model layer
+LATENT = {"deepseek": (deepseek, DSK),
+          "deepseek_v32": (deepseek_v32,
+                           deepseek_v32.DeepseekV32Config.tiny(89)),
+          "longcat_flash": (longcat_flash,
+                            longcat_flash.LongcatFlashConfig.tiny(89))}
 
 
 def _build(name):
-    mod, cfg = (deepseek, DSK) if name == "deepseek" else FAMILIES[name]
+    mod, cfg = LATENT.get(name) or FAMILIES[name]
     return lambda ctx: mod.build(cfg, ctx=ctx)
 
 
@@ -59,13 +68,14 @@ def _dense_reference(name, prompts, max_new):
     return out
 
 
-def _ragged(name, tile=0, quant="off", device_state=True):
+def _ragged(name, tile=0, quant="off", device_state=True, **over):
     return RaggedInferenceEngine(
         model=_build(name), dtype=jnp.float32, seed=0,
         ragged_config=RaggedConfig(
             max_tokens_per_step=16, max_seqs=3, block_size=4,
             num_blocks=49, max_blocks_per_seq=16,
-            prefill_tile=tile, quant=quant, device_state=device_state))
+            prefill_tile=tile, quant=quant, device_state=device_state,
+            **over))
 
 
 @pytest.mark.parametrize("name", ["mixtral", "gpt2", "deepseek"])
@@ -103,6 +113,37 @@ def test_host_staged_fallback_parity(name):
         host.put(uid, p, max_new_tokens=7)
     assert host.generate_all() == device.generate_all()
     assert host._tiled_jits and not host._dev_step_jits
+
+
+@pytest.mark.parametrize("name", ["gpt2", "mixtral", "deepseek",
+                                  "deepseek_v32", "longcat_flash"])
+def test_a_failed_step_is_invisible_in_the_tokens(name):
+    """One dispatch and, later, one readback fail mid-run: the watchdog
+    rewinds to what was delivered and runs those positions again, which
+    rewrites the same rows of every block leaf and picks the same tokens
+    (greedy and sampled). ``nemotron_h`` and ``kimi_linear``, whose slot
+    state cannot be run twice, have ``[recovered_and_recomputed]``."""
+    from deepspeed_tpu.serving.faults import (POINT_DISPATCH, POINT_READBACK,
+                                              get_fault_injector)
+
+    prompts = _prompts(5, seed=11)
+    eng = _ragged(name, tile=4, retry_backoff_s=0.0)
+    outs = {}
+    for faulty in (False, True):   # one engine: the programs compile once
+        if faulty:
+            get_fault_injector().configure(
+                [{"point": POINT_DISPATCH, "after": 3},
+                 {"point": POINT_READBACK, "after": 7}])
+        for uid, p in prompts.items():
+            kw = dict(temperature=0.8, top_k=20, seed=31 + uid) if uid % 2 \
+                else {}
+            eng.put((faulty, uid), p, max_new_tokens=7, **kw)
+        outs[faulty] = {uid: toks for (run, uid), toks
+                        in eng.generate_all().items() if run is faulty}
+    assert outs[True] == outs[False]
+    assert eng.step_failures == 2 and eng.degraded_mode == 0
+    assert eng.cfg.device_state and not eng._pending
+    assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1
 
 
 def test_mixtral_decode_routing_is_per_token():
