@@ -44,6 +44,7 @@ import sys
 import tempfile
 import threading
 import time
+from functools import partial
 
 import numpy as np
 
@@ -191,7 +192,8 @@ def device_phase(chips: int) -> dict:
 def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                   block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
                   experts=(16, 1024, 2688, 64, 6),
-                  kda=(258, 128, 32, 128), chunk=(3, 128, 16)) -> dict:
+                  kda=(258, 128, 32, 128), chunk=(3, 128, 16),
+                  swa=(16, 28, 4, 128, 4096, 8192, 128)) -> dict:
     """Each Pallas kernel of the train and serve paths against the XLA path
     on the same seeded bf16 input, at each ``(q heads, kv heads, head size)``.
     Off the chip the kernels interpret; on it this is their first execution.
@@ -207,7 +209,15 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     at ``chunk`` = (tiles, rows a tile, sub-chunk; a mixed step's 3 tiles x
     128 rows) against ``kda_tiles`` between slices (``kda_chunk_ms``), two
     tiles of one slot and a fresh one, the strongest decay on a quarter of
-    the heads; float32 both, so ``kda_chunk_*`` are held to 1e-5."""
+    the heads; float32 both, so ``kda_chunk_*`` are held to 1e-5. Last, a
+    sliding-window layer's decode walk at ``swa`` = (rows, q heads, kv heads,
+    head size, window, context, block; the SmallThinker cell's 16 rows x 28
+    heads x 4,096 of an 8,192 context) and its tile kernel over four tiles of
+    ``tile`` rows around the window's edge (the last tile under it, the first
+    past it, the table's last, a sequence's first), each against XLA's gather
+    of the whole table under the same mask, all timed (``swa_decode_ms``,
+    ``swa_prefill_ms``); the queries are scaled so that a few keys hold a
+    row's weight and an edge off by a block would show."""
     import jax
     import jax.numpy as jnp
 
@@ -382,11 +392,72 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
         close(name, got, want, tol=1e-5)
     chunk_ms = median_ms(forms, outs, tiles)
 
+    rows_n, hq, hkv, d, window, context, blk = swa
+    mb = context // blk
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 4), 4))
+    pool_shape = (rows_n * mb + 1, blk, hkv * d)
+
+    def peaked(key, rows):
+        """Queries whose scores over unit keys spread 3 wide: a few keys hold
+        a row's weight, so one block too many or too few at the window's edge
+        moves whole heads and not a mean's 128th part."""
+        return (3.0 * jax.random.normal(key, (rows, hq, d), jnp.float32)
+                ).astype(jnp.bfloat16)
+
+    pools = (jax.random.normal(next(keys), pool_shape, jnp.bfloat16),
+             jax.random.normal(next(keys), pool_shape, jnp.bfloat16))
+    table = jnp.asarray(1 + np.random.default_rng(SEED).permutation(
+        rows_n * mb).reshape(rows_n, mb), jnp.int32)
+    # decode rows: contexts from inside the window to the table's last row;
+    # tiles: the last under the window, the first past it, the table's last,
+    # and a sequence's first, half valid
+    tile_pos0 = jnp.asarray([window - tile, window, context - tile, 0], jnp.int32)
+    tile_rows = (tile_pos0[:, None] + jnp.arange(tile)).reshape(-1)
+    valid = (jnp.arange(4 * tile) < 3 * tile + tile // 2)[:, None, None]
+
+    def decode(impl, q, slots, pos):
+        return ops.paged_attention(q, *pools, slots, pos, table, impl=impl,
+                                   window=window)
+
+    def prefill(impl, q, slots, pos):
+        if impl == "xla":   # XLA's form gathers a whole table a ROW: 16 at a time
+            out = jax.lax.map(lambda a: decode("xla", *a), tuple(
+                x.reshape(-1, 16, *x.shape[1:]) for x in (
+                    q, jnp.repeat(slots, tile), tile_rows)))
+            return jnp.where(valid, out.reshape(q.shape), 0)
+        return jnp.where(valid, ops.ragged_prefill_attention(
+            q, *pools, slots, pos, jnp.asarray([tile] * 3 + [tile // 2], jnp.int32),
+            table, tile, impl=impl, window=window), 0)
+
+    calls = {
+        "swa_decode": (decode, (
+            peaked(next(keys), rows_n), jnp.arange(rows_n, dtype=jnp.int32),
+            jnp.asarray(np.linspace(window // 2, context - 1, rows_n),
+                        jnp.int32))),
+        "swa_prefill": (prefill, (
+            peaked(next(keys), 4 * tile), jnp.arange(4, dtype=jnp.int32),
+            tile_pos0))}
+    swa_ms = {}
+    for kernel, (op, args) in calls.items():
+        forms = {name: jax.jit(partial(op, name)) for name in ("pallas", "xla")}
+        outs = {name: fn(*args) for name, fn in forms.items()}
+        close(kernel, outs["pallas"], outs["xla"])
+        swa_ms[kernel] = {}
+        for name, fn in forms.items():
+            times = []
+            for _ in range(5):
+                t1 = time.perf_counter()
+                jax.block_until_ready(fn(*args))
+                times.append((time.perf_counter() - t1) * 1e3)
+            swa_ms[kernel][name] = round(sorted(times)[2], 3)
+
     return _emit("kernels", seconds=round(time.perf_counter() - t0, 2),
                  geometries=[list(g) for g in geometries],
                  shapes={"seq_len": seq_len, "tile": tile, "block": block},
                  max_rel_err=worst, kda_decode_ms=kda_ms,
-                 kda_chunk_ms=chunk_ms, memory=_memory())
+                 kda_chunk_ms=chunk_ms, swa_decode_ms=swa_ms["swa_decode"],
+                 swa_prefill_ms=swa_ms["swa_prefill"],
+                 memory=_memory())
 
 
 # ------------------------------------------------------------------- train

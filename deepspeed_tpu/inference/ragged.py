@@ -39,7 +39,12 @@ import numpy as np
 
 from deepspeed_tpu.inference import kvquant
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx
-from deepspeed_tpu.models.paged import block_leaves, slot_leaves
+from deepspeed_tpu.models.paged import (
+    block_leaves,
+    slot_leaves,
+    sliding_blocks_per_seq,
+    sliding_leaves,
+)
 from deepspeed_tpu.serving.faults import (
     POINT_ALLOC,
     POINT_DISPATCH,
@@ -65,6 +70,17 @@ def _kept_pairs(pos0: int, take: int, topk: int) -> int:
 _NO_SNAPSHOT = ("a sequence's recurrent state is not in its blocks, and there "
                 "is no snapshot of it at a block boundary to restore beside "
                 "them")
+# why a model with sliding leaves cannot be resumed from a prefix of blocks
+_NO_PREFIX = ("a sequence's sliding blocks are returned as its window slides "
+              "past them, so a prefix of its blocks no longer holds the "
+              "window layers' rows")
+# a model that keeps part of a sequence outside its chain of blocks: (what it
+# keeps, its leaves beside a pool, why a chain of blocks does not restore it)
+_BESIDE_BLOCKS = {
+    "slot": ("recurrent state in slot leaves", "slot state", _NO_SNAPSHOT),
+    "sliding": ("its window layers' K and V in sliding leaves",
+                "sliding leaves", _NO_PREFIX),
+}
 
 
 class BlockedAllocator:
@@ -420,6 +436,14 @@ class _SeqState:
     # [0, replay) are run again as prefill, from an empty state (the prompt
     # and the generated tokens before the resume point; none of them emits)
     replay: int = 0
+    # a model with sliding leaves (``ModelSpec.sliding_window``): the
+    # sequence's blocks of the sliding pool by block ordinal (the slide takes
+    # the oldest back), the most it may hold between steps, what of that is
+    # reserved and not yet held, and what it held of either pool at its end
+    win_blocks: dict[int, int] = field(default_factory=dict)
+    win_cap: int = 0
+    win_reserved: int = 0
+    blocks_at_end: tuple[int, int] = (0, 0)
 
     def token_at(self, p: int) -> int:
         if p < len(self.prompt):
@@ -643,7 +667,18 @@ class RaggedInferenceEngine:
         self._slot_state = slot_leaves(self.cache) is not None
         self._slot_bytes = self.state_bytes_per_slot()
         if self._slot_state:
-            self._refuse_for_slot_state()
+            self._refuse_beside_blocks("slot")
+        # a model some of whose layers attend over a window keeps their K and
+        # V in sliding leaves, a second pool (models/paged.py): a second
+        # allocator and a second block table of the same width beside the
+        # first, and a sequence's blocks of it go back as its window slides
+        sliding = sliding_leaves(self.cache)
+        self._window = self.spec.sliding_window if sliding is not None else 0
+        if sliding is not None and not self._window:
+            raise ValueError(f"model {self.spec.name} built sliding leaves "
+                             "but says no ModelSpec.sliding_window")
+        if self._window:
+            self._refuse_beside_blocks("sliding")
         # a family whose attention reads a selection of the context only
         # (``ModelSpec.index_topk`` rows a query): counted beside the context
         # on ``engine/dispatch``
@@ -670,6 +705,15 @@ class RaggedInferenceEngine:
                 self.spec.init_paged_cache_fn, self.cfg.num_blocks,
                 self.cfg.block_size, jnp.float16)
         self.allocator = BlockedAllocator(self.cfg.num_blocks)
+        self.window_allocator = BlockedAllocator(
+            jax.tree_util.tree_leaves(sliding)[0].shape[1]) \
+            if self._window else None
+        # sliding blocks promised to admitted sequences and not yet held, the
+        # blocks the slide has taken back, and the window's rows of the step
+        # packed last (all of them, and its decode rows')
+        self._win_reserved = 0
+        self.window_blocks_slid = 0
+        self._win_step = (0, 0, 0)
         # ---- hierarchical KV tiering (inference/kvtier.py) ----
         # tier store + allocator demote hook; None with kv_tier off, and
         # the allocator's eviction path is then bit-identical to before
@@ -698,6 +742,9 @@ class RaggedInferenceEngine:
         self.block_tables = np.zeros(
             (self.cfg.max_seqs + 1, self.cfg.max_blocks_per_seq), np.int32
         )
+        # the sliding pool's table: the same width, the same addressing
+        self.window_tables = np.zeros_like(self.block_tables) \
+            if self._window else None
         self._free_slots = list(range(self.cfg.max_seqs - 1, -1, -1))
         # blocks promised to admitted sequences but not yet allocated;
         # admission reserves worst case (prompt + max_new) so an admitted
@@ -761,6 +808,10 @@ class RaggedInferenceEngine:
         # re-shipping a fresh _table_view slice every step
         self._bt_dev = jnp.asarray(self.block_tables)
         self._bt_dirty: set[int] = set()
+        # the sliding table's mirror: the same shape, so the same row program
+        self._bt_win_dev = jnp.asarray(self.window_tables) \
+            if self._window else None
+        self._bt_win_dirty: set[int] = set()
         def ragged_bt_rows(bt, idx, vals):
             return bt.at[idx].set(vals)
 
@@ -1043,6 +1094,16 @@ class RaggedInferenceEngine:
         total = len(seq.prompt) + seq.max_new_tokens
         return -(-total // self.cfg.block_size)
 
+    def _sliding_blocks_cap(self, seq: _SeqState) -> int:
+        """The sliding blocks ``seq`` holds between steps at most: what the
+        family's pool gives a slot (``models/paged.sliding_blocks_per_seq``),
+        or its whole length where that is shorter; 0 for a model with no
+        window."""
+        if not self._window:
+            return 0
+        return min(self._worst_case_blocks(seq),
+                   sliding_blocks_per_seq(self._window, self.cfg.block_size))
+
     # ---------------------------------------------------------- prefix cache
     def _match_prefix(self, prompt: list[int]) -> list[int]:
         """Longest cached full-block prefix of ``prompt``: walk the hash
@@ -1105,32 +1166,37 @@ class RaggedInferenceEngine:
         self._prefix_listener = listener
         self.allocator.listener = listener
 
-    def _refuse_for_slot_state(self) -> None:
+    def _refuse_beside_blocks(self, kind: str) -> None:
         """What assumes that a sequence's context is a chain of blocks, and
-        nothing else, cannot serve a model with slot state: each refusal
-        names the piece that is missing."""
+        nothing else, cannot serve a model that keeps part of it elsewhere
+        (``kind``: slot leaves, or sliding leaves, whose blocks go back as the
+        window slides): each refusal names the piece that is missing."""
         cfg = self.cfg
-        refused = (
-            (cfg.enable_prefix_cache, "enable_prefix_cache", _NO_SNAPSHOT),
-            (cfg.kv_tier, "kv_tier", _NO_SNAPSHOT),
+        _, leaves, why = _BESIDE_BLOCKS[kind]
+        refused = [
+            (cfg.enable_prefix_cache, "enable_prefix_cache", why),
+            (cfg.kv_tier, "kv_tier", why),
             (self._kvq is not None, f"quant={cfg.quant!r}",
-             "a quantized pool beside slot state is not implemented"),
-            (not cfg.prefill_tile, "prefill_tile=0",
-             "a prompt's rows in one step must be a tile: the recurrence "
-             "runs them as one chunk, in order"),
-        )
+             f"a quantized pool beside {leaves} is not implemented"),
+        ]
+        if kind == "slot":
+            refused.append(
+                (not cfg.prefill_tile, "prefill_tile=0",
+                 "a prompt's rows in one step must be a tile: the recurrence "
+                 "runs them as one chunk, in order"))
         for on, name, why in refused:
             if on:
-                self._refuse(name, why)
+                self._refuse(kind, name, why)
 
-    def _refuse(self, what: str, why: str) -> None:
-        raise ValueError(f"model {self.spec.name} keeps recurrent state in "
-                         f"slot leaves; {what} is refused: {why}")
+    def _refuse(self, kind: str, what: str, why: str) -> None:
+        raise ValueError(f"model {self.spec.name} keeps "
+                         f"{_BESIDE_BLOCKS[kind][0]}; {what} is refused: {why}")
 
     def _refuse_block_transfer(self, what: str) -> None:
         """``KVHandoff`` moves a sequence as its blocks."""
-        if self._slot_state:
-            self._refuse(what, _NO_SNAPSHOT)
+        for kind, on in (("slot", self._slot_state), ("sliding", self._window)):
+            if on:
+                self._refuse(kind, what, _BESIDE_BLOCKS[kind][2])
 
     def _blocks(self):
         """The cache's block leaves (``[L, NB, ...]`` each): what every
@@ -1140,7 +1206,8 @@ class RaggedInferenceEngine:
     def kv_bytes_per_token(self) -> int:
         """Bytes of paged-cache state one token position occupies across all
         block leaves — the bytes side of the transfer-vs-prefill cost model.
-        A model's slot leaves are not a token's: ``state_bytes_per_slot``."""
+        A model's slot leaves are not a token's: ``state_bytes_per_slot``.
+        Sliding leaves count: a token inside the window has a row in them."""
         bs = self.cfg.block_size
         total = 0
         for a in jax.tree_util.tree_leaves(self._blocks()):
@@ -1148,6 +1215,12 @@ class RaggedInferenceEngine:
                 * a.dtype.itemsize
             total += per_block // bs
         return total
+
+    @staticmethod
+    def _leaves_block_bytes(leaves) -> int:
+        """Bytes one block occupies across ``leaves`` (``[L, NB, ...]``)."""
+        return sum(int(a.shape[0]) * int(np.prod(a.shape[2:])) * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(leaves))
 
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state one slot holds across all slot leaves,
@@ -1344,6 +1417,12 @@ class RaggedInferenceEngine:
         bb = max(1, self._block_bytes())
         free = limit - int(stats.get("bytes_in_use") or 0)
         pool_funded = self.allocator.free_blocks * bb
+        if self._window:
+            # ``bb`` is a block of each pool; what each pool's own free list
+            # funds is its own blocks' bytes
+            bb_win = self._leaves_block_bytes(sliding_leaves(self.cache))
+            pool_funded = (self.allocator.free_blocks * (bb - bb_win)
+                           + self.window_allocator.free_blocks * bb_win)
         usable = free + pool_funded - int(cfg.headroom_guard_fraction * limit)
         return max(0, usable // bb)
 
@@ -1833,11 +1912,17 @@ class RaggedInferenceEngine:
         pool can't satisfy it right now. Admitted sequences draw from their
         admission-time reservation, so this cannot fail for them."""
         need = -(-upto // self.cfg.block_size) - len(seq.blocks)
-        if need <= 0:
+        win_need = self._sliding_need(seq, upto) if self._window else ()
+        if need <= 0 and not win_need:
             return True
         if need > self.allocator.free_blocks:
             return False
-        if len(seq.blocks) + need > self.cfg.max_blocks_per_seq:
+        if need > 0 and len(seq.blocks) + need > self.cfg.max_blocks_per_seq:
+            return False
+        # past what the sequence has reserved, the sliding pool gives only
+        # what no other sequence's reservation counts on
+        if win_need and len(win_need) > self.window_allocator.free_blocks - (
+                self._win_reserved - seq.win_reserved):
             return False
         if self._faults.enabled:
             try:
@@ -1846,6 +1931,15 @@ class RaggedInferenceEngine:
                 if is_resource_exhausted(e):
                     self._note_oom("alloc", e)
                 raise
+        if win_need:
+            for b, blk in zip(win_need, self.window_allocator.allocate(
+                    len(win_need))):
+                seq.win_blocks[b] = blk
+                self.window_tables[seq.slot, b] = blk
+            self._bt_win_dirty.add(seq.slot)
+            self._sliding_rereserve(seq)
+        if need <= 0:
+            return True
         new = self.allocator.allocate(need)
         start = len(seq.blocks)
         seq.blocks.extend(new)
@@ -1856,6 +1950,59 @@ class RaggedInferenceEngine:
         self._bt_dirty.add(seq.slot)
         return True
 
+    def _sliding_need(self, seq: _SeqState, upto: int) -> list[int]:
+        """The block ordinals of the sliding pool that queries at positions
+        ``seq.pos .. upto - 1`` read or write and ``seq`` does not hold:
+        from the block of ``seq.pos``'s oldest key to that of ``upto - 1``."""
+        bs = self.cfg.block_size
+        first = max(0, seq.pos - self._window + 1) // bs
+        return [b for b in range(first, -(-upto // bs))
+                if b not in seq.win_blocks]
+
+    def _sliding_rereserve(self, seq: _SeqState) -> None:
+        """``seq``'s reservation in the sliding pool is what it may still
+        come to hold between steps (``win_cap``) less what it holds."""
+        want = max(0, seq.win_cap - len(seq.win_blocks))
+        self._win_reserved += want - seq.win_reserved
+        seq.win_reserved = want
+
+    def _slide_windows(self) -> None:
+        """**The slide**, after a step is dispatched: every running
+        sequence's sliding blocks that no query from its next position on
+        can read (block ``b`` once ``pos - W + 1 > BS b + BS - 1``) go back
+        to the free list and their table entries to the scratch block. The
+        step just dispatched read them through the table as it was
+        uploaded; the blocks are handed out again by the NEXT step's
+        packing at the earliest, so by a later program."""
+        bs = self.cfg.block_size
+        for seq in self._running.values():
+            first = max(0, seq.pos - self._window + 1) // bs
+            # held by ascending ordinal: the oldest tells whether any goes
+            if not seq.win_blocks or next(iter(seq.win_blocks)) >= first:
+                continue
+            gone = [b for b in seq.win_blocks if b < first]
+            self.window_allocator.free([seq.win_blocks.pop(b) for b in gone])
+            self.window_tables[seq.slot, gone] = 0
+            self._bt_win_dirty.add(seq.slot)
+            self.window_blocks_slid += len(gone)
+            self._sliding_rereserve(seq)
+            if self.telemetry.enabled:
+                self.telemetry.counter(
+                    "inference_window_blocks_slid_total",
+                    "sliding-pool blocks returned because their sequence's "
+                    "window slid past them").inc(len(gone))
+
+    def _drop_sliding_blocks(self, seq: _SeqState, cap: int = 0) -> None:
+        """Return every sliding block ``seq`` holds and clear its row of the
+        sliding table; its reservation becomes ``cap`` (0: it is leaving)."""
+        if seq.win_blocks:
+            self.window_allocator.free(list(seq.win_blocks.values()))
+            seq.win_blocks = {}
+        self.window_tables[seq.slot, :] = 0
+        self._bt_win_dirty.add(seq.slot)
+        seq.win_cap = cap
+        self._sliding_rereserve(seq)
+
     @staticmethod
     def _stamp_emission(seq: _SeqState, now: float) -> None:
         if not seq.t_first_token:
@@ -1865,6 +2012,9 @@ class RaggedInferenceEngine:
     def _release(self, seq: _SeqState) -> None:
         self._reserved -= seq.reserved_remaining  # return unused reservation
         seq.reserved_remaining = 0
+        if self._window:
+            seq.blocks_at_end = (len(seq.blocks), len(seq.win_blocks))
+            self._drop_sliding_blocks(seq)
         if seq.cost is not None:
             # close the occupancy integral over this sequence's final slice
             # before its blocks return to the pool
@@ -1925,7 +2075,9 @@ class RaggedInferenceEngine:
             queue_wait_s=queue_wait, ttft_s=ttft,
             decode_latency_s=decode_latency,
             prompt_tokens=len(seq.prompt), new_tokens=n_gen,
-            preemptions=seq.preemptions, **cost_attrs)
+            preemptions=seq.preemptions, **cost_attrs,
+            **({"full_blocks": seq.blocks_at_end[0],
+                "window_blocks": seq.blocks_at_end[1]} if self._window else {}))
         if seq.status == "cancelled":
             tel.counter("inference_requests_cancelled_total",
                         "requests aborted via cancel()").inc()
@@ -2078,20 +2230,34 @@ class RaggedInferenceEngine:
         table. Row count is pow2-bucketed so the scatter compiles
         O(log max_seqs) times; padding index rows re-write the always-zero
         scratch row."""
-        if not self._bt_dirty:
-            return
-        rows = sorted(self._bt_dirty)
-        self._bt_dirty.clear()
+        if self._bt_dirty:
+            self._bt_dev = self._upload_rows(self._bt_dev, self.block_tables,
+                                             self._bt_dirty)
+        if self._bt_win_dirty:
+            self._bt_win_dev = self._upload_rows(
+                self._bt_win_dev, self.window_tables, self._bt_win_dirty)
+
+    def _upload_rows(self, dev, host: np.ndarray, dirty: set):
+        """``dev`` with the ``dirty`` rows of ``host`` written (and
+        ``dirty`` cleared): one table's part of ``_sync_bt``."""
+        rows = sorted(dirty)
+        dirty.clear()
         r = 1
         while r < len(rows):
             r *= 2
         idx = np.full(r, self.cfg.max_seqs, np.int32)
         idx[:len(rows)] = rows
         vals = np.zeros((r, self.cfg.max_blocks_per_seq), np.int32)
-        vals[:len(rows)] = self.block_tables[rows]
+        vals[:len(rows)] = host[rows]
         self.h2d_bytes += idx.nbytes + vals.nbytes
-        self._bt_dev = self._bt_row_jit(self._bt_dev, jnp.asarray(idx),
-                                        jnp.asarray(vals))
+        return self._bt_row_jit(dev, jnp.asarray(idx), jnp.asarray(vals))
+
+    def _tables_dev(self):
+        """What a device step program is handed as its block tables: the
+        table's mirror, or ``(full, sliding)`` for a model with a window."""
+        if self._window:
+            return self._bt_dev, self._bt_win_dev
+        return self._bt_dev
 
     def _stage(self, arr: np.ndarray):
         """Upload ONE packed int32 staging buffer for a dispatch, skipping
@@ -2115,6 +2281,8 @@ class RaggedInferenceEngine:
         """The host-staged step's upload: jnp.asarray + H2D byte accounting,
         so the host-staged and device-resident paths report comparable
         ``h2d_bytes`` to the bench and telemetry."""
+        if isinstance(arr, tuple):  # (full, sliding) table views
+            return tuple(self._h2d(a) for a in arr)
         if self._faults.enabled:
             self._faults.fire(POINT_H2D)
         self.h2d_bytes += arr.nbytes
@@ -2124,10 +2292,13 @@ class RaggedInferenceEngine:
         """Per-dispatch overhead epilogue: host staging wall time (packing +
         upload + dispatch enqueue, NOT device execution) into the plain
         counter and, when enabled, the ``ragged_dispatch_host_ms``
-        histogram."""
+        histogram. For a model with a window, the slide: its tables are
+        uploaded and its step is on its way (``_slide_windows``)."""
         dt = time.perf_counter() - t0
         self.host_stage_ns += int(dt * 1e9)
         self.dispatch_count += 1
+        if self._window:
+            self._slide_windows()
         if self.telemetry.enabled:
             self.telemetry.histogram(
                 "ragged_dispatch_host_ms",
@@ -2199,7 +2370,10 @@ class RaggedInferenceEngine:
             real = slots != max_seqs
             tokens = jnp.where(feed, tok_st[slots], tokens)
             positions = jnp.where(feed & real, pos_st[slots], positions)
-            bt = bt_full[:, :w] if w < bt_full.shape[1] else bt_full
+            if isinstance(bt_full, tuple):  # (full, sliding): one width
+                bt = tuple(b[:, :w] for b in bt_full)
+            else:
+                bt = bt_full[:, :w] if w < bt_full.shape[1] else bt_full
             if ct:
                 ts = staged[4 * t:4 * t + ntl]
                 tp_ = staged[4 * t + ntl:4 * t + 2 * ntl]
@@ -2282,7 +2456,7 @@ class RaggedInferenceEngine:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
 
         fixed = (abstract(self.params), abstract(self.cache),
-                 abstract(self._dev_state), abstract(self._bt_dev))
+                 abstract(self._dev_state), abstract(self._tables_dev()))
         root = abstract(self._sample_root)
 
         def compile_one(key):
@@ -2339,7 +2513,9 @@ class RaggedInferenceEngine:
         read the pool in (``_sel_decode_attr``); a model whose step program
         counts what only it knows (``ModelSpec.step_counters``: a router's
         ``moe_picks``, ``moe_zero_picks``, ``moe_held_picks``) adds those
-        counts of the steps before this one (``_counts_attr``)."""
+        counts of the steps before this one (``_counts_attr``); a model with
+        a window (``ModelSpec.sliding_window``) adds what its window layers
+        read and what the two pools hold (``_window_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
@@ -2372,9 +2548,10 @@ class RaggedInferenceEngine:
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total), **state, **sel,
-                  **self._sel_decode_attr(width), **self._counts_attr()):
+                  **self._sel_decode_attr(width), **self._counts_attr(),
+                  **self._window_attr()):
             picked, self._dev_state, self.cache = fn(
-                self.params, self.cache, self._dev_state, self._bt_dev,
+                self.params, self.cache, self._dev_state, self._tables_dev(),
                 staged, self._sample_root)
         if state and self.telemetry.enabled:
             moved = self.telemetry.counter(
@@ -2428,6 +2605,25 @@ class RaggedInferenceEngine:
             self._counts_unspanned, dict.fromkeys(self._counters, 0))
         return out
 
+    def _window_attr(self) -> dict:
+        """For a model with a window, what its window layers read in the
+        step packed last: ``win_kv_tokens``, the rows a window leaves of
+        every scheduled sequence's context (``min(context, window)``, once a
+        sequence: beside ``kv_tokens``, which is what the full layers read),
+        ``dec_win_kv_tokens`` the decode rows' part, ``win_attn_pairs`` the
+        query x key pairs inside the window (``min(position + 1, window)`` a
+        query, beside ``attn_pairs``), and the blocks either
+        pool holds as the step is dispatched (``full_blocks_busy``,
+        ``win_blocks_busy``: the sliding pool's saving is their ratio).
+        Nothing for a model with none."""
+        if not self._window:
+            return {}
+        win_all, win_dec, win_pairs = self._win_step
+        return {"win_kv_tokens": win_all, "dec_win_kv_tokens": win_dec,
+                "win_attn_pairs": win_pairs,
+                "full_blocks_busy": self.allocator.busy_blocks,
+                "win_blocks_busy": self.window_allocator.busy_blocks}
+
     def _sel_decode_attr(self, width: int) -> dict:
         """``{"sel_decode": "walk" | "gather"}`` for a family that selects:
         how the decode rows of a step program whose block table is ``width``
@@ -2480,6 +2676,8 @@ class RaggedInferenceEngine:
         trace_on = self._tracer.enabled
         tpairs = [] if trace_on else None
         kv_dec = kv_pre = pairs_pre = 0
+        win_dec = win_pre = win_pairs = 0   # the same, cut to the window
+        window = self._window
         topk = self._topk
         sel = dict.fromkeys(
             ("sel_pairs", "sel_kv_tokens", "dec_sel_kv_tokens"), 0) \
@@ -2519,6 +2717,7 @@ class RaggedInferenceEngine:
             max_pos = max(max_pos, seq.pos)
             seq.pos += 1
             kv_dec += seq.pos
+            win_dec += min(seq.pos, window)
             n_dec += 1
             for key in sel:     # a decode row: one query, its kept rows
                 sel[key] += min(seq.pos, topk)
@@ -2545,6 +2744,7 @@ class RaggedInferenceEngine:
                     tv[tile0 + ti] = min(ct, take - ti * ct)
                 max_pos = max(max_pos, seq.pos + take - 1)
                 pairs_pre += take * seq.pos + take * (take + 1) // 2
+                win_pairs += _kept_pairs(seq.pos, take, window)
                 if topk:
                     sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
                     sel["sel_kv_tokens"] += sum(
@@ -2552,6 +2752,7 @@ class RaggedInferenceEngine:
                         for i in range(0, take, ct))
                 seq.pos += take
                 kv_pre += seq.pos
+                win_pre += min(seq.pos, window)
                 sched += take
                 if trace_on:
                     tpairs.append((seq, "engine/prefill", take))
@@ -2578,11 +2779,13 @@ class RaggedInferenceEngine:
                                           dtype=np.int32)
                 max_pos = max(max_pos, seq.pos + take - 1)
                 pairs_pre += take * seq.pos + take * (take + 1) // 2
+                win_pairs += _kept_pairs(seq.pos, take, window)
                 if topk:
                     sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
                     sel["sel_kv_tokens"] += min(seq.pos + take, topk)
                 seq.pos += take
                 kv_pre += seq.pos
+                win_pre += min(seq.pos, window)
                 n += take
                 if trace_on:
                     tpairs.append((seq, "engine/prefill", take))
@@ -2595,6 +2798,7 @@ class RaggedInferenceEngine:
             return None
         self.tokens_scheduled += n
         self.tokens_padded += t_total - n
+        self._win_step = (win_dec + win_pre, win_dec, win_dec + win_pairs)
         parts = [tokens[:t_total], slots[:t_total], positions[:t_total],
                  flags[:t_total]]
         if ct:
@@ -2669,7 +2873,11 @@ class RaggedInferenceEngine:
         Tables of 64 blocks and fewer pass through whole: every distinct
         width is a fresh program shape to compile and warm. Power-of-4
         buckets keep the long-context compile count tiny."""
-        return self.block_tables[:, :self._table_width(max_pos)]
+        width = self._table_width(max_pos)
+        if self._window:  # (full, sliding): ``_h2d`` ships both
+            return (self.block_tables[:, :width],
+                    self.window_tables[:, :width])
+        return self.block_tables[:, :width]
 
     def _table_width(self, max_pos: int) -> int:
         """Bucketed block-table width covering ``max_pos`` (the shared
@@ -2844,6 +3052,10 @@ class RaggedInferenceEngine:
                 # must see them already claimed
                 self.allocator.acquire(hit)
                 worst -= len(hit)
+            win_cap = self._sliding_blocks_cap(seq)
+            if win_cap and win_cap > self.window_allocator.free_blocks \
+                    - self._win_reserved:
+                break  # the sliding pool is short: retry as windows slide
             if worst > self.allocator.free_blocks - self._reserved:
                 if hit:
                     # deref back; published blocks re-enter the LRU (at the
@@ -2867,6 +3079,9 @@ class RaggedInferenceEngine:
             seq.slot = self._free_slots.pop()
             seq.reserved_remaining = worst
             self._reserved += worst
+            if win_cap:
+                seq.win_cap = win_cap
+                self._sliding_rereserve(seq)
             if headroom >= 0:
                 # this admission will draw from the pool; clamp at 0 so the
                 # cap stays armed for the rest of the pass
@@ -3076,17 +3291,25 @@ class RaggedInferenceEngine:
             else:
                 # mid-prefill: re-prefill the uncached tail (idempotent)
                 seq.pos = seq.cached_prefix
-            if self._slot_state:
+            if self._slot_state or self._window:
                 # re-running a position rewrites identical K and V but would
                 # move a recurrent state a second time, and there is no
                 # rollback: the sequence starts again from an empty state
-                # and runs everything before its resume point as prefill
+                # and runs everything before its resume point as prefill.
+                # A model with a window likewise: the rows a rewound query
+                # reads may lie in sliding blocks the slide has taken back,
+                # so its sliding blocks go and are written again from 0
                 seq.replay = seq.pos if g else 0
                 seq.pos = 0
+            if self._window:
+                self._drop_sliding_blocks(seq, seq.win_cap)
         # device mirrors are stale by construction now: rebuild the block
         # table wholesale and re-seed the slot rows from host truth
         self._bt_dirty.clear()
         self._bt_dev = jnp.asarray(self.block_tables)
+        if self._window:
+            self._bt_win_dirty.clear()
+            self._bt_win_dev = jnp.asarray(self.window_tables)
         if self.cfg.device_state:
             for seq in self._running.values():
                 self._write_slot_row(seq)
@@ -3218,6 +3441,7 @@ class RaggedInferenceEngine:
             seq.status = "error"
             seq.blocks = []
             seq.reserved_remaining = 0
+            seq.win_blocks, seq.win_cap, seq.win_reserved = {}, 0, 0
             seq.refs = 0
             seq.slot = -1
             self._results[seq.uid] = seq
@@ -3252,6 +3476,13 @@ class RaggedInferenceEngine:
         self.block_tables[:] = 0
         self._bt_dirty.clear()
         self._bt_dev = jnp.asarray(self.block_tables)
+        if self._window:
+            self.window_allocator = BlockedAllocator(
+                self.window_allocator.num_blocks)
+            self.window_tables[:] = 0
+            self._bt_win_dirty.clear()
+            self._bt_win_dev = jnp.asarray(self.window_tables)
+            self._win_reserved = 0
         self._free_slots = list(range(self.cfg.max_seqs - 1, -1, -1))
         self._reserved = 0
         s1 = self.cfg.max_seqs + 1
@@ -3303,6 +3534,14 @@ class RaggedInferenceEngine:
         g("kv_page_occupancy",
           "fraction of usable KV blocks in use").set(
               (usable - free) / max(usable, 1))
+        if self._window:
+            for pool, alloc in (("full", self.allocator),
+                                ("window", self.window_allocator)):
+                for state, n in (("free", alloc.free_blocks),
+                                 ("busy", alloc.busy_blocks)):
+                    g("kv_pool_blocks", "blocks of a model's two pools (full "
+                      "layers, window layers) by state").set(
+                          n, pool=pool, state=state)
         g("inference_queue_depth", "requests waiting for admission").set(
             len(self._queued))
         g("inference_running_seqs", "admitted sequences").set(

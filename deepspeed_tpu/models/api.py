@@ -398,6 +398,15 @@ class ModelSpec:
     # decay a channel, the delta rule). ``state_kind`` on ``engine/dispatch``
     # and the label of ``inference_slot_state_bytes_total``; None: no state
     state_kind: str | None = None
+    # a family some of whose attention layers read the last ``sliding_window``
+    # keys only keeps those layers' K and V in sliding leaves (``"swa"`` in
+    # its paged cache, ``models/paged.py``) behind a block table and a free
+    # list of their own, and says the window here: the engine returns a
+    # sequence's sliding blocks as its window slides past them, hands the
+    # step programs ``(full table, sliding table)``, counts the window's
+    # rows on ``engine/dispatch`` (``win_kv_tokens``) and refuses what a
+    # prefix of blocks cannot restore; None: every layer reads every row
+    sliding_window: int | None = None
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

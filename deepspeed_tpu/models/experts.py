@@ -3,8 +3,9 @@
 ``routed_experts`` is the dropless per-token top-k MoE ``mixtral``,
 ``deepseek`` and ``nemotron_h`` serve through; the families differ in how a
 token's combine weights come out of the router's logits (its keyword
-arguments), in the expert itself (three matrices, gated SwiGLU; or two,
-ungated ``relu**2``: ``w_gate`` None) and in which of the routed experts the
+arguments), in the expert itself (three matrices, gated by ``silu``, SwiGLU,
+or with ``gate_act="relu"`` by ``relu``: ``smallthinker``; or two, ungated
+``relu**2``: ``w_gate`` None) and in which of the routed experts the
 layer holds (``held``: one rank's share of an expert-parallel deployment). A
 pick is of one of three kinds: of an expert the layer holds (computed here), of
 a routed expert it does not hold (another rank's part: nothing here), or of a
@@ -142,7 +143,8 @@ def _held_picks(topi, held, e: int):
     return jnp.where((local >= 0) & (local < e), local, e)
 
 
-def _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held=None):
+def _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held=None,
+                    gate_act: str = "silu"):
     """Every held expert over every token on the MXU, the router's weights
     (zero for the experts a token did not pick) combining the results; a pick
     of an expert the layer does not hold has no column to land in."""
@@ -157,7 +159,7 @@ def _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held=None):
         a = jnp.square(jax.nn.relu(u))
     else:
         g = jnp.einsum("td,edf->tef", h, w_gate.astype(dtype))
-        a = jax.nn.silu(g) * u
+        a = (jax.nn.relu(g) if gate_act == "relu" else jax.nn.silu(g)) * u
     y = jnp.einsum("tef,efd->ted", a, w_down.astype(dtype))
     return jnp.einsum("ted,te->td", y, w.astype(dtype))
 
@@ -190,7 +192,8 @@ def _picks_before(flat, e: int):
 
 
 def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
-                     num_experts, held=None, zero_experts: int = 0):
+                     num_experts, held=None, zero_experts: int = 0,
+                     gate_act: str = "silu"):
     """Each pick through its own expert only: the ``T x top_k`` picks are
     sorted by expert (a counting sort: a pick's place is its expert's first
     row plus the picks of that expert before it), every expert's rows go
@@ -208,8 +211,8 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
         parts = [jnp.pad(a, ((0, pad), (0, 0))).reshape(
             -1, _GROUPED_MAX_ROWS, a.shape[1]) for a in (h, topv, topi)]
         out = lax.map(lambda p: _grouped_experts(
-            *p, w_gate, w_up, w_down, first_expert, e, held, zero_experts),
-            tuple(parts))
+            *p, w_gate, w_up, w_down, first_expert, e, held, zero_experts,
+            gate_act), tuple(parts))
         return out.reshape(-1, d)[:t]
     # The kernel's shapes are those of a full call whatever ``t`` is, so
     # that every step program of an engine shares ONE traced kernel
@@ -240,7 +243,7 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     y = grouped_swiglu(h[token], None if w_gate is None else w_gate.astype(dtype),
                        w_up.astype(dtype), w_down.astype(dtype), row0, counts,
                        tm, max_rows=_GROUPED_MAX_ROWS,
-                       first_expert=first_expert)
+                       first_expert=first_expert, gate_act=gate_act)
     y = y[place] if held is None else y.at[place].get(mode="fill",
                                                       fill_value=0)
     return jnp.einsum("tkd,tk->td", y.reshape(t, k, d), topv).astype(dtype)
@@ -251,7 +254,8 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                    bias=None, renormalize: bool = True, scale: float = 1.0,
                    eps: float = 1e-9, held=None, router_h=None,
                    groups=None, zero_experts: int = 0,
-                   count_picks: bool = False) -> jnp.ndarray:
+                   count_picks: bool = False,
+                   gate_act: str = "silu") -> jnp.ndarray:
     """Dropless per-token top-k MoE for the serving paths (``h`` [T, D]
     flat tokens): exact (no capacity, no drops, every pick computed), bf16
     operands with float32 accumulation in either form.
@@ -285,7 +289,8 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     are Mixtral's. ``groups = (n_group, topk_group)`` limits the picks to a
     token's best groups of neighbouring experts (``_in_best_groups``).
 
-    The expert: ``w_down(silu(x w_gate) * x w_up)``, or with ``w_gate`` None
+    The expert: ``w_down(silu(x w_gate) * x w_up)``; with ``gate_act="relu"``
+    (static) ``w_down(relu(x w_gate) * x w_up)``; or with ``w_gate`` None
     the ungated ``w_down(relu(x w_up)**2)`` of two matrices (``stacked``
     then has None in ``w_gate``'s place).
 
@@ -298,7 +303,8 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     the layer holds every routed expert.
 
     ``router_h``: what the router scores where that is not what the experts
-    compute on (experts in a latent space, routed on the full hidden state).
+    compute on (experts in a latent space, routed on the full hidden state;
+    a router that reads the layer's input, before its norm and attention).
 
     Zero-compute experts: with ``zero_experts = Z`` the router scores ``routed
     + Z`` outputs, and a pick of one of the last ``Z`` is an identity expert:
@@ -314,11 +320,12 @@ def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     held = _with_zero_experts(router_w, held, e, zero_experts)
     routed = e if held is None else held[1]
     if expert_form(h.shape[0], routed, top_k) == "dense":
-        y = _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
+        y = _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held,
+                            gate_act)
     else:
         y = _grouped_experts(h, topv, topi,
                              *(stacked or (w_gate, w_up, w_down, 0)), e, held,
-                             zero_experts)
+                             zero_experts, gate_act)
     if zero_experts:
         y = y + _identity_part(h, topv, topi, routed)
     if not count_picks:
@@ -373,7 +380,8 @@ def routed_experts_einsum(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
                           top_k: int, *, scoring: str = "softmax", bias=None,
                           renormalize: bool = True, scale: float = 1.0,
                           eps: float = 1e-9, held=None, router_h=None,
-                          groups=None, zero_experts: int = 0) -> jnp.ndarray:
+                          groups=None, zero_experts: int = 0,
+                          gate_act: str = "silu") -> jnp.ndarray:
     """``routed_experts`` in its einsum form at every row count: plain XLA,
     so it differentiates (``deepseek``'s training-shaped ``forward`` /
     ``loss_fn``) and partitions over a mesh (``mixtral``'s dense-cache
@@ -381,7 +389,8 @@ def routed_experts_einsum(h: jnp.ndarray, router_w, w_gate, w_up, w_down,
     topv, topi = _route(h if router_h is None else router_h, router_w, top_k,
                         scoring, bias, renormalize, scale, eps, groups)
     held = _with_zero_experts(router_w, held, w_up.shape[0], zero_experts)
-    y = _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held)
+    y = _einsum_experts(h, topv, topi, w_gate, w_up, w_down, held,
+                        gate_act=gate_act)
     if zero_experts:
         y = y + _identity_part(h, topv, topi, held[1])
     return y

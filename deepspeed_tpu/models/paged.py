@@ -2,7 +2,8 @@
 
 One home for what every family's serving path shares (gpt2, llama, mixtral
 on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
-nemotron_h and kimi_linear with a recurrent state a slot beside either): the
+nemotron_h and kimi_linear with a recurrent state a slot beside either;
+smallthinker with sliding-window layers in a second pool): the
 paged-pool scatter, the decode/tiled-prefill attention split over the block
 pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
 one or several kinds of layer, and the dense-cache append+attend used by the
@@ -80,6 +81,31 @@ v1-style engines.
   construction for ``enable_prefix_cache``, ``kv_tier``, ``KVHandoff``
   (all need a state snapshot at a block boundary) and a quantized pool,
   when the cache has slot leaves.
+- *Sliding leaves.* A model some of whose attention layers never read a row
+  older than ``W`` positions (``smallthinker``: 39 of 52 layers, ``W`` =
+  4,096) keeps those layers' K and V in a SECOND group of block leaves,
+  under the cache's ``"swa"`` key (``{"k", "v"}`` of ``[L_w, NB_w, BS,
+  Hkv*D]``: the same storage form, its own ``L`` and its own ``NB``), with
+  a block table and a free list of their own: TWO tables of one width and
+  one addressing (``positions // BS``), so a kernel's index maps are the
+  full layers'. ``sliding_leaves`` / ``full_leaves`` tell the groups apart;
+  ``block_leaves`` is both (bytes a cached token count every row a token
+  has). A step program is handed ``(full table, sliding table)`` and a
+  layer of kind ``"swa"`` addresses the sliding leaves through the second,
+  ``sliding table + layer * NB_w`` (``_scan_periods``). **The slide**: the
+  engine returns a sequence's sliding block ``b`` to its free list once
+  ``pos - W + 1 > BS b + BS - 1`` for the next query position ``pos``, and
+  points the table's entry at the scratch block, so a sequence holds at
+  most ``sliding_blocks_per_seq(W, BS)`` = ``W / BS + 1`` sliding blocks
+  between steps whatever its length (``NB_w`` = ``max_seqs`` times that,
+  and the scratch block: the family's ``init_paged_cache`` sizes the pool by
+  the function the engine reserves by, from ``num_slots``) and the kernels of such a
+  layer never follow an entry before the window's first block
+  (``ops/pallas/paged_attention.py``, ``window``). A model says it has a
+  window through ``ModelSpec.sliding_window``. What a prefix of blocks
+  cannot restore refuses here too: ``enable_prefix_cache``, ``kv_tier``,
+  ``KVHandoff`` (a prefix's sliding blocks are gone by the time it could
+  be shared) and a quantized pool raise at construction.
 """
 
 from __future__ import annotations
@@ -110,6 +136,7 @@ def init_paged_pool(num_layers: int, num_blocks: int, block_size: int,
 
 
 SLOTS = "slots"  # the cache key of the slot leaves (module doc)
+SWA = "swa"      # the cache key of the sliding leaves (module doc)
 
 
 def slot_leaves(cache):
@@ -122,6 +149,25 @@ def block_leaves(cache):
     if slot_leaves(cache) is None:
         return cache
     return {k: v for k, v in cache.items() if k != SLOTS}
+
+
+def sliding_leaves(cache):
+    """The cache's sliding leaves, ``[L_w, NB_w, ...]`` each (None: it has
+    none): the block leaves of the layers that attend over a window."""
+    return cache.get(SWA) if isinstance(cache, dict) else None
+
+
+def sliding_blocks_per_seq(window: int, block_size: int) -> int:
+    """The sliding blocks a sequence holds between steps at most: the blocks
+    a window can span wherever it starts in one. The one rule by which a
+    family sizes its sliding pool (``max_seqs`` times this, and the scratch
+    block) and the engine reserves a sequence's share of it."""
+    return -(-window // block_size) + 1
+
+
+def full_leaves(cache):
+    """The block leaves of the layers that attend over every row."""
+    return {k: v for k, v in block_leaves(cache).items() if k != SWA}
 
 
 def init_window_leaf(layers: int, slots: int, taps: int, width: int, dtype):
@@ -297,11 +343,19 @@ def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
     ``"slot"``, ``fn(x, lp, pool, slot0)`` with ``slot0 = layer * S`` the
     row of its slot 0 in the merged slot leaves; None, ``fn(x, lp, pool,
     None)`` for a layer with no state. Every ``fn`` returns ``(x, pool)``;
-    the pool is carried whole, block leaves and slot leaves each merged."""
-    blocks, slots = block_leaves(pool), slot_leaves(pool)
+    the pool is carried whole, block leaves and slot leaves each merged.
+
+    A cache with sliding leaves (module doc) has a third kind, ``"swa"``: its
+    layer among the sliding leaves' ``L_w``, addressed through the SECOND of
+    ``block_tables = (full table, sliding table)``."""
+    blocks, slots = full_leaves(pool), slot_leaves(pool)
+    sliding = sliding_leaves(pool)
     shapes = {"block": jax.tree_util.tree_leaves(blocks)[0].shape[:2]}
     if slots is not None:
         shapes["slot"] = jax.tree_util.tree_leaves(slots)[0].shape[:2]
+    if sliding is not None:
+        shapes["swa"] = jax.tree_util.tree_leaves(sliding)[0].shape[:2]
+        block_tables, sliding_tables = block_tables
 
     def merged(tree):
         return jax.tree_util.tree_map(
@@ -310,10 +364,14 @@ def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
     def address(kind, i):
         if kind == "block":
             return block_tables + i * shapes["block"][1]
+        if kind == "swa":
+            return sliding_tables + i * shapes["swa"][1]
         return None if kind is None else i * shapes["slot"][1]
 
     pool = merged(pool)
     seen = {"block": 0, "slot": 0, None: 0}
+    if sliding is not None:
+        seen["swa"] = 0
     for kind, fn, lp in lead:
         x, pool = fn(x, lp, pool, address(kind, seen[kind]))
         seen[kind] += 1
@@ -343,7 +401,10 @@ def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
         x, pool = fn(x, lp, pool, address(kind, after[kind]))
         after[kind] += 1
     out = jax.tree_util.tree_map(
-        lambda a: a.reshape(shapes["block"] + a.shape[1:]), block_leaves(pool))
+        lambda a: a.reshape(shapes["block"] + a.shape[1:]), full_leaves(pool))
+    if sliding is not None:
+        out = {**out, SWA: jax.tree_util.tree_map(
+            lambda a: a.reshape(shapes["swa"] + a.shape[1:]), pool[SWA])}
     if slots is not None:
         out = {**out, SLOTS: jax.tree_util.tree_map(
             lambda a: a.reshape(shapes["slot"] + a.shape[1:]), pool[SLOTS])}
@@ -394,10 +455,11 @@ def _decode_then_tiles(q, slots, positions, prefill_tiles, decode, prefill):
 
 
 def ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
-                          prefill_tiles=None):
+                          prefill_tiles=None, window: int | None = None):
     """Attention over the blocked pool for a flat ragged token batch:
     per-token paged kernel for the decode region, the tiled SplitFuse
-    kernel for tile-aligned prefill chunks."""
+    kernel for tile-aligned prefill chunks; with a ``window`` (static) both
+    over keys ``pos - window < j <= pos``."""
     from deepspeed_tpu.ops.attention import (
         paged_attention,
         ragged_prefill_attention,
@@ -405,9 +467,10 @@ def ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
 
     return _decode_then_tiles(
         q, slots, positions, prefill_tiles,
-        lambda q, sl, po: paged_attention(q, kc, vc, sl, po, block_tables),
+        lambda q, sl, po: paged_attention(q, kc, vc, sl, po, block_tables,
+                                          window=window),
         lambda q, ts, tp, tv, ct: ragged_prefill_attention(
-            q, kc, vc, ts, tp, tv, block_tables, ct))
+            q, kc, vc, ts, tp, tv, block_tables, ct, window=window))
 
 
 def latent_pool_attention(q, pool, slots, positions, block_tables, lat: int,

@@ -172,7 +172,8 @@ def attention(
 
 
 def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
-                    scale: float | None = None, impl: str = "auto"):
+                    scale: float | None = None, impl: str = "auto",
+                    window: int | None = None):
     """Ragged paged-KV attention: [T, Hq, D] tokens over the blocked pool
     ``[blocks, BS, Hkv*D]`` (reference ``inference/v2/kernels/ragged_ops``
     blocked flash attention).
@@ -185,6 +186,9 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     PERF.md section 6, PR 29). Off the chip it is the XLA gather of the
     padded context. The choice is made here, from the backend and the pool's
     kind; an error from the chosen kernel propagates.
+
+    ``window`` (static): a sliding-window layer's rows attend over keys
+    ``pos - window < j <= pos`` only, in either form.
 
     A quantized pool (``inference/kvquant.QuantizedKV``) always takes the
     XLA path: the gather+dequant fuse into one program there (the fp
@@ -202,7 +206,7 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
         )
 
         return paged_decode_attention(q, k_pool, v_pool, slots, positions,
-                                      block_tables, scale=scale)
+                                      block_tables, scale=scale, window=window)
     if impl != "xla":
         raise ValueError(f"unknown paged attention impl {impl!r}")
     t_tokens, hq, d = q.shape
@@ -219,7 +223,10 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     ctx_k, ctx_v = context(k_pool), context(v_pool)
     scale = scale if scale is not None else 1.0 / jnp.sqrt(jnp.float32(d))
     k_pos = jnp.arange(ctx_k.shape[1])
-    bias = jnp.where(k_pos[None, :] <= positions[:, None], 0.0, -1e30)
+    seen = k_pos[None, :] <= positions[:, None]
+    if window is not None:
+        seen = seen & (k_pos[None, :] > positions[:, None] - window)
+    bias = jnp.where(seen, 0.0, -1e30)
     scores = (jnp.einsum("thd,tchd->thc", (q * scale).astype(jnp.float32),
                          ctx_k.astype(jnp.float32)) + bias[:, None, :])
     p = jax.nn.softmax(scores, axis=-1)
@@ -229,7 +236,8 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
 
 def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
-                             scale: float | None = None, impl: str = "auto"):
+                             scale: float | None = None, impl: str = "auto",
+                             window: int | None = None):
     """Tiled prefill attention over the blocked pool: ``q`` holds tile-aligned
     prefill tokens (one sequence per CT-token tile, consecutive positions,
     rows past ``tile_valid`` padding). The Pallas kernel fetches each KV block
@@ -248,13 +256,14 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
         )
 
         return _pallas_prefill(q, k_pool, v_pool, tile_slot, tile_pos0,
-                               tile_valid, block_tables, tile, scale=scale)
+                               tile_valid, block_tables, tile, scale=scale,
+                               window=window)
     if impl != "xla":
         raise ValueError(f"unknown prefill attention impl {impl!r}")
     slots, positions = _tile_rows(q.shape[0], tile_slot, tile_pos0, tile_valid,
                                   tile, block_tables.shape[0] - 1)
     return paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
-                           scale=scale, impl="xla")
+                           scale=scale, impl="xla", window=window)
 
 
 def _tile_rows(n_rows: int, tile_slot, tile_pos0, tile_valid, tile: int,

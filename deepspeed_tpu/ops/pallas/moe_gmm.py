@@ -1,6 +1,7 @@
 """Pallas grouped expert FFN: each expert's weights over that expert's own
 rows only, read from HBM once a layer. The expert is gated (three matrices,
-``silu(x w_gate) * (x w_up)`` into ``w_down``: Mixtral, DeepSeek-V3) or
+``silu(x w_gate) * (x w_up)`` into ``w_down``: Mixtral, DeepSeek-V3; or with
+``gate_act="relu"`` ``relu(x w_gate) * (x w_up)``: SmallThinker) or
 ungated (two, ``relu(x w_up)**2`` into ``w_down``: Nemotron-H's latent
 experts; one weight operand fewer, the same walk).
 
@@ -68,7 +69,7 @@ def ffn_tile(d: int, f: int, itemsize: int, matrices: int = 3) -> int:
 
 
 def _kernel(first_ref, row0_ref, passes_ref, x_hbm, *refs, tm: int,
-            gated: bool):
+            gated: bool, gate_act: str = "silu"):
     del first_ref  # the weights' index maps read it
     wg_ref = refs[0] if gated else None
     wu_ref, wd_ref, o_hbm, xbuf, acc, in_sem, out_sem = refs[gated:]
@@ -113,7 +114,7 @@ def _kernel(first_ref, row0_ref, passes_ref, x_hbm, *refs, tm: int,
         if gated:
             g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
             u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-            a = jax.nn.silu(g) * u
+            a = (jax.nn.relu(g) if gate_act == "relu" else jax.nn.silu(g)) * u
         else:
             a = jnp.square(jax.nn.relu(
                 jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)))
@@ -147,7 +148,7 @@ def _kernel(first_ref, row0_ref, passes_ref, x_hbm, *refs, tm: int,
 
 def grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm: int,
                    max_rows: int, first_expert=0,
-                   interpret: bool | None = None):
+                   interpret: bool | None = None, gate_act: str = "silu"):
     """``x`` [R, D], rows grouped by expert (``row0`` [E] the experts' first
     rows, multiples of ``ROW_ALIGN``; ``counts`` [E] their rows; no expert
     has more than ``max_rows``; ``R`` at least the last expert's end rounded
@@ -156,7 +157,8 @@ def grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm: int,
     ``(silu(x[r] w_gate[e]) * (x[r] w_up[e])) w_down[e]``; the rows between
     the experts hold nothing meant. ``w_gate`` None is the ungated expert of
     two matrices, ``relu(x[r] w_up[e])**2 w_down[e]``: the same walk with one
-    weight operand fewer.
+    weight operand fewer. ``gate_act="relu"`` (static) gates by ``relu`` in
+    ``silu``'s place.
 
     The ``E`` experts are ``first_expert .. first_expert + E - 1`` of the
     ``N`` the weights hold: a layer scan hands the kernel every layer's
@@ -171,12 +173,17 @@ def grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, tm: int,
     return _grouped_swiglu(
         x, w_gate, w_up, w_down, row0, counts,
         jnp.asarray(first_expert, jnp.int32).reshape(1), tm=tm,
-        max_rows=max_rows, interpret=interpret_mode(interpret))
+        max_rows=max_rows, interpret=interpret_mode(interpret),
+        gate_act=gate_act)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "max_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tm", "max_rows", "interpret",
+                                             "gate_act"))
 def _grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, first_expert, *,
-                    tm: int, max_rows: int, interpret: bool):
+                    tm: int, max_rows: int, interpret: bool,
+                    gate_act: str = "silu"):
+    if gate_act not in ("silu", "relu"):
+        raise ValueError(f"unknown expert gate activation {gate_act!r}")
     gated = w_gate is not None
     _, d, f = w_up.shape
     n_e = row0.shape[0]
@@ -200,7 +207,7 @@ def _grouped_swiglu(x, w_gate, w_up, w_down, row0, counts, first_expert, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, gated=gated),
+        functools.partial(_kernel, tm=tm, gated=gated, gate_act=gate_act),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(

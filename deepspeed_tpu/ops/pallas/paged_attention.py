@@ -35,6 +35,15 @@ diagonal segments. The prefill kernel's grid is tiles x table width, past a
 tile's last block clamped to it (no new DMA) and predicated off; it splits
 the heads out of a block in VMEM (``_split_heads``).
 
+A sliding-window layer (``window``: a query at ``i`` attends over keys ``i -
+window < j <= i``) walks less: a decode row's steps are the chunks from
+``max(0, pos - window + 1) // CH`` to ``pos // CH``, at most ``window / CH +
+1`` whatever its context, a tile's grid the ``window``-and-a-tile's worth of
+blocks from its first needed one, and both mask ``j <= i - window`` inside
+the first. Both kernels then go by another name (``swa_decode``,
+``swa_prefill``), so a trace tells a model's window layers from its full
+ones. ``window=None`` traces what it always did.
+
 Inference-only (no VJP): the ragged engine never differentiates through
 decode.
 """
@@ -85,14 +94,19 @@ def decode_step_blocks(bs: int, lanes: int, itemsize: int,
     return min(1 << (want.bit_length() - 1), _DECODE_STEP_BLOCKS_MAX)
 
 
-def decode_steps(positions, ch: int, n_steps: int):
+def decode_steps(positions, ch: int, n_steps: int, window: int | None = None):
     """The decode rows' chunks of ``ch`` tokens laid end to end, ``pos // ch
     + 1`` of them a row: ``(ends, step_row, step_chunk)``, step ``s`` works
     on chunk ``step_chunk[s]`` of row ``step_row[s]``, row ``t``'s steps are
     ``ends[t - 1] .. ends[t] - 1``, and the ``n_steps`` entries past
     ``ends[-1]`` stay on the last row's last chunk. (Made again in every
-    layer of a step: 0.4 us of 20, sweep of PR 29.)"""
+    layer of a step: 0.4 us of 20, sweep of PR 29.) With a ``window`` a
+    row's chunks start at the one that holds ``pos - window + 1``: at most
+    ``window // ch + 1`` steps a row."""
     n_chunks = positions // ch + 1
+    if window is not None:
+        first = jnp.maximum(positions - window + 1, 0) // ch
+        n_chunks = n_chunks - first
     ends = jnp.cumsum(n_chunks)
     steps = jnp.arange(n_steps, dtype=jnp.int32)
     step_row = jnp.minimum(
@@ -100,12 +114,14 @@ def decode_steps(positions, ch: int, n_steps: int):
         positions.shape[0] - 1)
     step_chunk = jnp.minimum(steps - (ends - n_chunks)[step_row],
                              n_chunks[step_row] - 1)
+    if window is not None:
+        step_chunk = step_chunk + first[step_row]
     return ends, step_row, step_chunk
 
 
 def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
                    *refs, bs: int, nb: int, hkv: int, rep: int, d: int,
-                   scale: float):
+                   scale: float, window: int | None = None):
     k_refs, v_refs = refs[:nb], refs[nb:2 * nb]
     o_ref, acc, m_sc, l_sc = refs[2 * nb:]
     s_id = pl.program_id(0)
@@ -113,8 +129,10 @@ def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
     c = chunk_ref[s_id]
     pos = pos_ref[t]
     ch = nb * bs
+    # a row's first chunk: the one that holds the oldest key in its window
+    c0 = 0 if window is None else jnp.maximum(pos - window + 1, 0) // ch
 
-    @pl.when(c == 0)
+    @pl.when(c == c0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
@@ -129,9 +147,12 @@ def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
     s = jax.lax.dot_general(q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     kpos = c * ch + jax.lax.broadcasted_iota(jnp.int32, (1, ch), 1)
-    s = jnp.where(kpos <= pos, s, _NEG_INF)               # [Hq, CH]
-    # position 0 is never masked, so the running maximum is real from a
-    # row's first chunk on and no row of p is all zeros
+    seen = kpos <= pos
+    if window is not None:
+        seen = jnp.logical_and(seen, kpos > pos - window)
+    s = jnp.where(seen, s, _NEG_INF)                      # [Hq, CH]
+    # position 0 (the window's oldest key) is never masked, so the running
+    # maximum is real from a row's first chunk on and no row of p is all zeros
     m_prev = m_sc[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -157,26 +178,30 @@ def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
 
 def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
                            scale: float | None = None,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           window: int | None = None):
     """[T, Hq, D] ragged tokens -> [T, Hq, D] attention outputs.
 
     ``k_pool``/``v_pool``: [blocks, BS, Hkv*D]; ``block_tables``:
     [max_seqs+1, MB] mapping (slot, block-ordinal) -> pool block id. Exact
     vs the dense-gather path (same position masking). Each row reads blocks
-    ``0 .. pos // BS`` of its sequence, once, whatever ``MB`` is.
+    ``0 .. pos // BS`` of its sequence, once, whatever ``MB`` is; with a
+    ``window`` (static) the blocks that hold positions ``pos - window + 1 ..
+    pos``, and no table entry before them need name a block of the row's.
     """
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     return _paged_decode(
         q, k_pool, v_pool, slots.astype(jnp.int32),
         positions.astype(jnp.int32), block_tables.astype(jnp.int32),
-        scale=float(scale), interpret=interpret_mode(interpret))
+        scale=float(scale), interpret=interpret_mode(interpret),
+        window=window)
 
 
 # ONE jitted function: the step programs of one row count (d4_t0 .. d4_t3)
 # share its trace
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
-                  scale: float, interpret: bool):
+                  scale: float, interpret: bool, window: int | None = None):
     t_tokens, hq, d = q.shape
     _, bs, hd = k_pool.shape
     hkv = hd // d
@@ -188,7 +213,8 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
     # entry more than there can be steps; the entries past the last stay on
     # the last row's last chunk, whose blocks are then in place already.
     ends, step_row, step_chunk = decode_steps(
-        positions, nb * bs, t_tokens * -(-block_tables.shape[1] // nb) + 1)
+        positions, nb * bs, t_tokens * -(-block_tables.shape[1] // nb) + 1,
+        window)
 
     # The heads stay where the pool has them, side by side in a row's lanes:
     # query head (g, r) becomes row r*Hkv + g of a [Hq, Hkv*D] matrix that is
@@ -234,13 +260,13 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
         ],
     )
     kernel = functools.partial(_decode_kernel, bs=bs, nb=nb, hkv=hkv, rep=rep,
-                               d=d, scale=scale)
+                               d=d, scale=scale, window=window)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, rep, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        name="paged_decode",
+        name="paged_decode" if window is None else "swa_decode",
     )(step_row, step_chunk, slots, positions, block_tables, q_wide,
       *([k_pool] * nb), *([v_pool] * nb))
     return out.reshape(t_tokens, rep, hkv, d).transpose(0, 2, 1, 3).reshape(
@@ -250,13 +276,16 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
 # --------------------------------------------------------------- tiled prefill
 def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                     acc, m_sc, l_sc, *, bs: int, ct: int, hkv: int, rep: int,
-                    scale: float):
+                    scale: float, window: int | None = None):
     c = pl.program_id(0)   # query tile
     j = pl.program_id(1)   # kv block ordinal
     nj = pl.num_programs(1)
     pos0 = tp_ref[c]
     valid = tv_ref[c]
     max_pos = pos0 + valid - 1
+    if window is not None:
+        # the grid's block 0 is the tile's first needed block
+        jb = j + jnp.maximum(pos0 - window + 1, 0) // bs
 
     @pl.when(j == 0)
     def _init():
@@ -264,7 +293,10 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    @pl.when(jnp.logical_and(valid > 0, j * bs <= max_pos))
+    if window is None:
+        jb = j
+
+    @pl.when(jnp.logical_and(valid > 0, jb * bs <= max_pos))
     def _compute():
         q = q_ref[...].astype(jnp.float32) * scale        # [CT, Hq, D]
         d = q.shape[2]
@@ -278,8 +310,10 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         )                                                 # [Hkv, CT*rep, BS]
         qi = jax.lax.broadcasted_iota(jnp.int32, (1, ct * rep, 1), 1) // rep
         qpos = pos0 + qi
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
+        kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
         mask = jnp.logical_and(kpos <= qpos, qi < valid)
+        if window is not None:
+            mask = jnp.logical_and(mask, kpos > qpos - window)
         s = jnp.where(mask, s, _NEG_INF)
         m_blk = jnp.max(s, axis=-1, keepdims=True)        # [Hkv, CT*rep, 1]
         m_prev = m_sc[:, :, :1]
@@ -339,7 +373,8 @@ def split_tiles(tile_slot, tile_pos0, tile_valid, tile: int, ct: int):
 def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
                              scale: float | None = None,
-                             interpret: bool | None = None):
+                             interpret: bool | None = None,
+                             window: int | None = None):
     """Tiled prefill attention: [NT*CT, Hq, D] tile-aligned prefill tokens ->
     outputs, one KV-block DMA shared by the whole CT-token tile (the
     SplitFuse blocked flash attention, reference
@@ -354,6 +389,10 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     each tile runs as consecutive sub-tiles: a sub-tile is itself a tile of
     the same sequence, so the kernel and its masking are unchanged and only
     the KV block is fetched once per sub-tile instead of once per tile.
+
+    With a ``window`` (static) the grid's second axis is not the table's
+    width but the blocks a window and a tile can span, from the tile's first
+    needed block; no entry before it need name a block of the sequence's.
     """
     t_tokens, hq, d = q.shape
     _, bs, hd = k_pool.shape
@@ -369,8 +408,13 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     # clamp past the tile's last needed block: unchanged id -> no new DMA
     def _kv_map(c, j, ts, tp, tv, bt):
         last = jnp.maximum(tp[c] + tv[c] - 1, 0) // bs
+        if window is not None:
+            j = j + jnp.maximum(tp[c] - window + 1, 0) // bs
         return (bt[ts[c], jnp.minimum(j, last)], 0, 0)
 
+    if window is not None:
+        # keys pos0 - window + 1 .. pos0 + ct - 1, wherever pos0 lies in a block
+        mb = min(mb, (window + ct - 2) // bs + 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_tiles, mb),
@@ -388,13 +432,13 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
         ],
     )
     kernel = functools.partial(_prefill_kernel, bs=bs, ct=ct, hkv=hkv,
-                               rep=rep, scale=scale)
+                               rep=rep, scale=scale, window=window)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret_mode(interpret),
-        name="tiled_prefill",
+        name="tiled_prefill" if window is None else "swa_prefill",
     )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
       tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, k_pool, v_pool)

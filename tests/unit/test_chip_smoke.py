@@ -119,14 +119,17 @@ def test_serve_check_fails_on_a_wrong_token():
 def test_kernels_phase_at_tiny_size():
     out = _run(chip_smoke.kernels_phase, [(4, 2, 16)], seq_len=32, tile=8,
                block=8, ssm=(10, 16, 256, 2), experts=(4, 32, 48, 16, 6),
-               kda=(10, 16, 2, 4), chunk=(3, 16, 4))
+               kda=(10, 16, 2, 4), chunk=(3, 16, 4),
+               swa=(4, 4, 2, 16, 12, 40, 4))
     assert set(out["max_rel_err"]) == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dk", "flash_bwd_dv",
         "paged_decode", "tiled_prefill", "ssm_decode_state", "ssm_decode_y",
         "moe_gmm_relu2", "kda_decode_state", "kda_decode_y",
-        "kda_chunk_state", "kda_chunk_y"}
+        "kda_chunk_state", "kda_chunk_y", "swa_decode", "swa_prefill"}
     assert set(out["kda_decode_ms"]) == {"pallas", "xla"}
     assert set(out["kda_chunk_ms"]) == {"pallas", "xla"}
+    assert set(out["swa_decode_ms"]) == {"pallas", "xla"}
+    assert set(out["swa_prefill_ms"]) == {"pallas", "xla"}
 
 
 @pytest.mark.slow

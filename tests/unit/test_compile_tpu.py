@@ -1309,3 +1309,110 @@ def test_double_layer_step_scatters_two_rows_a_layer(v5e, monkeypatch, rows,
                 pool_shape) == 1, ln
     assert f"s32[3,{t}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+# ------------------------------------------ sliding-window layers, two pools
+# SmallThinker-21BA3B's attention at its cell's shapes: 28 query heads over 4
+# KV heads of 128, a window of 4,096 over 128-token blocks, a table of 64, 16
+# decode rows beside 128-row tiles; pools of 641 and 16 x 33 + 1 blocks
+SWA_HEADS, SWA_KV, SWA_D, SWA_WINDOW, SWA_TABLE = 28, 4, 128, 4096, 64
+SWA_POOLS = (641, 529)
+
+
+@pytest.mark.parametrize("kernel", ["swa_decode", "swa_prefill"])
+def test_window_kernels_compile_at_the_cells_shapes(v5e, kernel):
+    """The two paged kernels with ``window``: 28 heads (no multiple of 8) in
+    the ``[Hq, Hkv*D]`` query form, a whole 128-row tile in scoped VMEM, a
+    prefill grid of 33 blocks, not the table's 64; the instruction goes by
+    the window layers' name, which ``benchmark/kernels/<name>.json`` finds
+    and the full layers' pattern does not."""
+    import json
+    import os
+
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    pool = s((SWA_POOLS[1], 128, SWA_KV * SWA_D))
+    bt = s((17, SWA_TABLE), jnp.int32)
+    if kernel == "swa_decode":
+        rows = s((16,), jnp.int32)
+        fn = lambda *a: paged_decode_attention(  # noqa: E731
+            *a, interpret=False, window=SWA_WINDOW)
+        args = (s((16, SWA_HEADS, SWA_D)), pool, pool, rows, rows, bt)
+    else:
+        assert prefill_kernel_tile(TILE, SWA_HEADS, SWA_D) == TILE
+        tiles = s((4,), jnp.int32)
+        fn = lambda *a: ragged_prefill_attention(  # noqa: E731
+            *a, TILE, interpret=False, window=SWA_WINDOW)
+        args = (s((4 * TILE, SWA_HEADS, SWA_D)), pool, pool, tiles, tiles,
+                tiles, bt)
+    compiled = jax.jit(fn).lower(*args).compile()
+    calls = [ln.strip() for ln in compiled.as_text().splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    kernels = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                           "benchmark", "kernels")
+    found = []
+    for f in os.listdir(kernels):
+        with open(os.path.join(kernels, f)) as fh:
+            if re.search(json.load(fh)["trace_pattern"], calls[0]):
+                found.append(f[:-5])
+    assert sorted(found) == sorted([kernel, "pallas_custom_call"])
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**23
+
+
+def test_window_step_holds_no_layer_slice_of_either_pool(v5e, monkeypatch):
+    """The paged contract with sliding leaves: a step of 16 decode rows beside
+    a tile writes each pool's rows by in-place scatters (K and V, twice) and
+    reads both through their tables; nothing else is as large as a layer's
+    slice of EITHER pool, no layer's weights are copied out of their stack
+    (the four-body period: ``models/smallthinker.py``), and all four
+    attention kernels are in it under their names."""
+    from deepspeed_tpu.models import smallthinker
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = smallthinker.SmallThinkerConfig(
+        vocab_size=512, hidden_size=256, moe_intermediate_size=128,
+        num_layers=8, num_heads=SWA_HEADS, num_kv_heads=SWA_KV,
+        head_dim=SWA_D, num_experts=8, top_k=2, sliding_window=SWA_WINDOW,
+        max_seq_len=8192)
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        smallthinker.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: smallthinker.init_paged_cache(
+        cfg, SWA_POOLS[0], 128, jnp.bfloat16, num_slots=17))
+    assert cache["k"].shape == (2, SWA_POOLS[0], 128, 512)
+    assert cache["swa"]["k"].shape == (6, SWA_POOLS[1], 128, 512)
+    layer_slice = min(SWA_POOLS) * 128 * 512 * 2
+    rows, t = 16, 16 + TILE
+
+    def step(params, cache, tokens, slots, positions, bt, bt_win, ts, tp, tv):
+        return smallthinker.ragged_forward(
+            cfg, params, tokens, slots, positions, (bt, bt_win), cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(17, SWA_TABLE), i32(17, SWA_TABLE), i32(1), i32(1), i32(1)).compile()
+    text = compiled.as_text()
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= layer_slice]
+    # K and V of either pool; twice where the compiler unrolls the two repeats
+    assert len([ln for _, op, ln in big if op == "scatter"]) in (4, 8)
+    assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
+    names = set(re.findall(r"%(\w+?)\.\d+ = [^\n]*tpu_custom_call", text))
+    assert {"paged_decode", "swa_decode", "tiled_prefill",
+            "swa_prefill"} <= names
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice

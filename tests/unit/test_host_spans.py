@@ -347,7 +347,17 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                      **{m: "v5e_kimi_linear_reason_spans" for m in (
                          "kernel.kda_decode_share",
                          "kernel.kda_decode_roofline",
-                         "sched.state_bytes_share")}}
+                         "sched.state_bytes_share")},
+                     # PR 44: the window layers' kernels, the step against both
+                     # pools and what the slide leaves read a slice of the
+                     # cell whose spans carry ``win_kv_tokens`` and the rest
+                     **{m: "v5e_smallthinker_mixedlen_spans" for m in (
+                         "kernel.swa_decode_share",
+                         "kernel.swa_decode_roofline",
+                         "kernel.swa_prefill_share",
+                         "kernel.swa_prefill_roofline",
+                         "model.swa_step_roofline_kv",
+                         "sched.window_held_share")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -358,7 +368,9 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "v5e_longcat_flash_reason_spans":
                    "longcat-flash-omni-d4-ep32.reason-pool",
                    "v5e_kimi_linear_reason_spans":
-                   "kimi-linear-48b-a3b-d13-ep8.reason-pool"}
+                   "kimi-linear-48b-a3b-d13-ep8.reason-pool",
+                   "v5e_smallthinker_mixedlen_spans":
+                   "smallthinker-21b-a3b-ep8.mixedlen-pool"}
 
 
 def _new_readers():
@@ -370,7 +382,7 @@ def _new_readers():
         with open(path) as f:
             text = f.read()
         if not any(m in text for m in ("host_spans", "latent_spans", "ssm_spans",
-                                       "dsa_spans")):
+                                       "dsa_spans", "swa_spans")):
             continue  # a reader from before the spans
         fixture = FIXTURE_OF_METRIC.get(m["name"])
         cell = (CELL_OF_FIXTURE[fixture] if fixture
