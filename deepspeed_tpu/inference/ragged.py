@@ -41,10 +41,12 @@ from deepspeed_tpu.inference import kvquant
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx
 from deepspeed_tpu.models.paged import (
     block_leaves,
+    full_leaves,
     slot_leaves,
     sliding_blocks_per_seq,
     sliding_leaves,
 )
+from deepspeed_tpu.ops.attention import prefill_step_keys
 from deepspeed_tpu.serving.faults import (
     POINT_ALLOC,
     POINT_DISPATCH,
@@ -666,6 +668,9 @@ class RaggedInferenceEngine:
         # engine learns it from the cache the model's own hook built
         self._slot_state = slot_leaves(self.cache) is not None
         self._slot_bytes = self.state_bytes_per_slot()
+        k_pool = full_leaves(self.cache).get("k")
+        self._tile_step_keys = (None if k_pool is None
+                                else prefill_step_keys(k_pool))
         if self._slot_state:
             self._refuse_beside_blocks("slot")
         # a model some of whose layers attend over a window keeps their K and
@@ -2515,7 +2520,9 @@ class RaggedInferenceEngine:
         ``moe_picks``, ``moe_zero_picks``, ``moe_held_picks``) adds those
         counts of the steps before this one (``_counts_attr``); a model with
         a window (``ModelSpec.sliding_window``) adds what its window layers
-        read and what the two pools hold (``_window_attr``)."""
+        read and what the two pools hold (``_window_attr``); a program with
+        tiles over a K/V pool says the keys a grid step of its tile kernel
+        takes (``_tiles_attr``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
@@ -2549,7 +2556,7 @@ class RaggedInferenceEngine:
                   attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total), **state, **sel,
                   **self._sel_decode_attr(width), **self._counts_attr(),
-                  **self._window_attr()):
+                  **self._window_attr(), **self._tiles_attr(nt)):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._tables_dev(),
                 staged, self._sample_root)
@@ -2623,6 +2630,15 @@ class RaggedInferenceEngine:
                 "win_attn_pairs": win_pairs,
                 "full_blocks_busy": self.allocator.busy_blocks,
                 "win_blocks_busy": self.window_allocator.busy_blocks}
+
+    def _tiles_attr(self, nt: int) -> dict:
+        """``{"prefill_step_keys": n}`` for a step program with ``nt`` > 0
+        tiles over a K/V pool whose tiles the Pallas kernel runs: the keys
+        one grid step of it takes (``ops.attention.prefill_step_keys``, the
+        rule the dispatcher itself goes by; a static of the program).
+        Nothing for a program without tiles, a latent pool, the XLA path."""
+        keys = self._tile_step_keys if nt else None
+        return {} if keys is None else {"prefill_step_keys": keys}
 
     def _sel_decode_attr(self, width: int) -> dict:
         """``{"sel_decode": "walk" | "gather"}`` for a family that selects:

@@ -234,6 +234,20 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
                       ).astype(q.dtype)
 
 
+def prefill_step_keys(k_pool) -> int | None:
+    """The keys one grid step of the tile kernel takes over ``k_pool``
+    ``[.., BS, Hkv*D]`` where ``ragged_prefill_attention`` will run it
+    (``prefill_step_blocks`` whole blocks), None where it takes the XLA path
+    (a quantized pool, the CPU): decided from the backend and the pool,
+    BEFORE any call; a static of a step program with tiles."""
+    if getattr(k_pool, "is_quantized_kv", False) or not _on_tpu():
+        return None
+    from deepspeed_tpu.ops.pallas.paged_attention import prefill_step_blocks
+
+    bs, lanes = k_pool.shape[-2:]
+    return bs * prefill_step_blocks(bs, lanes, k_pool.dtype.itemsize)
+
+
 def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
                              scale: float | None = None, impl: str = "auto",
@@ -249,7 +263,7 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     if getattr(k_pool, "is_quantized_kv", False):
         impl = "xla"  # fused gather+dequant (see paged_attention)
     if impl == "auto":
-        impl = "pallas" if _on_tpu() else "xla"
+        impl = "xla" if prefill_step_keys(k_pool) is None else "pallas"
     if impl == "pallas":
         from deepspeed_tpu.ops.pallas.paged_attention import (
             ragged_prefill_attention as _pallas_prefill,

@@ -145,16 +145,16 @@ def _mask_columns(s, q0: int, full: int):
 
 
 def _lanes(x, n: int):
-    """``x`` [rows, 128], a row's one value in every lane, as [rows, n]. The
-    softmax statistics live in that form: as [rows, 1] every use of them
-    costs a lane broadcast on the XLU, which the forward walk then waits for
-    (my chip runs, PR 39: 439 us a call against 264, strip after strip)."""
-    lanes = x.shape[1]
+    """``x`` [.., rows, 128], a row's one value in every lane, as [.., rows,
+    n]. The softmax statistics live in that form: as [rows, 1] every use of
+    them costs a lane broadcast on the XLU, which the forward walk then waits
+    for (my chip runs, PR 39: 439 us a call against 264, strip after strip)."""
+    lanes = x.shape[-1]
     if n <= lanes:
-        return x[:, :n]
+        return x[..., :n]
     if n % lanes:
-        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
-    return jnp.tile(x, (1, n // lanes))
+        return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+    return jnp.tile(x, (1,) * (x.ndim - 1) + (n // lanes,))
 
 
 def _dot(a, b, contract):

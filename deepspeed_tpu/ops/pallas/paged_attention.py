@@ -31,18 +31,48 @@ what it was a step before. Inside a step the heads are not split either: the
 query rides in as ``[Hq, Hkv*D]``, each head's ``D`` values in its KV head's
 lanes and zeros elsewhere, so one bf16 matmul against the chunk gives all
 heads' scores, one more ``P x chunk``, and the output is that product's
-diagonal segments. The prefill kernel's grid is tiles x table width, past a
-tile's last block clamped to it (no new DMA) and predicated off; it splits
-the heads out of a block in VMEM (``_split_heads``).
+diagonal segments.
+
+The tile kernel (``tiled_prefill``) has a grid of tiles x steps, a step
+``prefill_step_blocks`` whole pool blocks (512 keys as four 128-token
+blocks, 256 as eight of GPT-2 XL's), each an operand of its own as in the
+decode kernel: operand ``i`` of step ``j`` is block ``first + j * nb + i`` of
+the tile's sequence, ``first`` the tile's first needed block, clamped to its
+last needed one (an unchanged id is no new DMA; a repeat's keys lie past
+every query's position and are masked by it). q comes laid out by KV head,
+``[tiles, Hkv, rep * CT, D]``: an XLA transpose beside the projection that
+made it, once a layer, and the output goes back the same way, so a KV
+head's group of query heads is ONE matrix of ``rep * CT`` rows and nothing
+is re-laid out inside a step (the step this replaced repacked q's sublanes
+in every one of its 33 steps a tile: 60% of its bundles were stores). A step
+walks those rows in turns, a strip of EVERY KV head's group a turn (one
+batched product of ``Hkv`` matrices of ``strip`` rows against the step's
+keys, their heads' K and V static lane slices of the blocks in VMEM); the
+turns are one ``fori_loop`` body, traced once and unrolled by the compiler.
+The products take the pool's dtype and accumulate in float32 (``p`` in the
+pool's dtype for ``P x V``, the decode kernel's arithmetic; float32 operands
+cost the MXU no second pass and were 1.5% faster, bf16 ones halve q's and
+the blocks' VMEM), the scale is applied to the float32 scores inside the
+exponent, and a row's running maximum and sum lie in every lane of a
+``[rows, 128]`` scratch (``flash_attention._lanes``), so one cross-lane
+maximum, one sum and one rescale of the accumulator are paid a row and
+STEP. The mask (iotas, compares) is made once a step and shared by every
+turn and head, which pay one select a score. Measured and not kept (PERF.md
+section 6, PR 45): a second body without the mask for the steps no edge
+crosses (2.7% of the kernel's time for twice the program), scores a strip
+ahead of the exponentials before them (nothing), the turns as a real loop
+(21-24% slower). ``window=None`` traces the same step without the window's
+compare.
 
 A sliding-window layer (``window``: a query at ``i`` attends over keys ``i -
 window < j <= i``) walks less: a decode row's steps are the chunks from
 ``max(0, pos - window + 1) // CH`` to ``pos // CH``, at most ``window / CH +
 1`` whatever its context, a tile's grid the ``window``-and-a-tile's worth of
-blocks from its first needed one, and both mask ``j <= i - window`` inside
-the first. Both kernels then go by another name (``swa_decode``,
+blocks from its first needed one (NOT from a multiple of the blocks a step:
+the sliding pool's table names no block before it), and both mask ``j <= i -
+window`` inside the first. Both kernels then go by another name (``swa_decode``,
 ``swa_prefill``), so a trace tells a model's window layers from its full
-ones. ``window=None`` traces what it always did.
+ones. The decode kernel under ``window=None`` traces what it always did.
 
 Inference-only (no VJP): the ragged engine never differentiates through
 decode.
@@ -57,17 +87,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
+from deepspeed_tpu.ops.pallas.flash_attention import _lanes, interpret_mode
 
 _NEG_INF = -1e30
-
-
-def _split_heads(ref, hkv: int, d: int):
-    """One pool block ``ref[0]`` ``[BS, Hkv*D]`` as float32 ``[Hkv, BS, D]``:
-    a static lane slice per head (a reshape of the lane dimension is refused
-    by Mosaic at D = 64; the slices compile at 64 and 128)."""
-    return jnp.stack([ref[0, :, g * d:(g + 1) * d].astype(jnp.float32)
-                      for g in range(hkv)])
 
 
 # Bytes of K (and as many of V) one step of a decode kernel takes, as whole
@@ -274,18 +296,58 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
 
 
 # --------------------------------------------------------------- tiled prefill
-def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc, m_sc, l_sc, *, bs: int, ct: int, hkv: int, rep: int,
+# Keys one grid step of the tile kernel takes, as whole pool blocks, each an
+# operand of its own: a row's maximum, its sum and the rescale of its
+# accumulator are paid once a step, so a step wants many keys: four of
+# SmallThinker's, Mixtral's and Nemotron-3's 128-token blocks, eight of
+# GPT-2 XL's 32-token blocks (never more: each is a copy of its own, with a
+# buffer and a DMA in flight), and no more bytes of K (and as many of V) than
+# the kernel's VMEM has room for twice beside a tile's q, output and
+# float32 scratches.
+PREFILL_STEP_KEYS = 512
+PREFILL_STEP_BYTES = 2**20
+# Query rows of ALL KV heads one turn of a step's loop takes (a strip of
+# every head's group, one batched product): whole runs of a tile's rows, as
+# many as stay under this.
+_PREFILL_TURN_ROWS = 1024
+
+
+def _strip_rows(hkv: int, rep: int, ct: int) -> int:
+    """Rows of one KV head's group (``rep`` x ``ct``: query head by query
+    head, a tile's ``ct`` rows each) that a turn of the step's loop takes:
+    ``ct`` times the largest divisor of ``rep`` that keeps all heads' strips
+    together under ``_PREFILL_TURN_ROWS`` rows, at least one run."""
+    fit = [n for n in range(1, rep + 1)
+           if rep % n == 0 and hkv * n * ct <= _PREFILL_TURN_ROWS]
+    return ct * max(fit, default=1)
+
+
+def prefill_step_blocks(bs: int, lanes: int, itemsize: int) -> int:
+    """Pool blocks one grid step of the tile kernel takes from K (and as
+    many from V): the power of two, 1 to 8, that holds ``PREFILL_STEP_KEYS``
+    tokens or ``PREFILL_STEP_BYTES``, whichever is fewer blocks."""
+    want = max(1, min(PREFILL_STEP_KEYS // bs,
+                      PREFILL_STEP_BYTES // (bs * lanes * itemsize)))
+    return min(1 << (want.bit_length() - 1), _DECODE_STEP_BLOCKS_MAX)
+
+
+def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, *refs, bs: int,
+                    nb: int, ct: int, hkv: int, rep: int, d: int, strip: int,
                     scale: float, window: int | None = None):
+    k_refs, v_refs = refs[:nb], refs[nb:2 * nb]
+    o_ref, acc, m_sc, l_sc = refs[2 * nb:]
     c = pl.program_id(0)   # query tile
-    j = pl.program_id(1)   # kv block ordinal
-    nj = pl.num_programs(1)
+    j = pl.program_id(1)   # step of nb kv blocks
     pos0 = tp_ref[c]
     valid = tv_ref[c]
     max_pos = pos0 + valid - 1
-    if window is not None:
-        # the grid's block 0 is the tile's first needed block
-        jb = j + jnp.maximum(pos0 - window + 1, 0) // bs
+    ch = nb * bs
+    # the grid's block 0 is the tile's first needed block
+    first = 0 if window is None else jnp.maximum(pos0 - window + 1, 0) // bs
+    k_lo = (first + j * nb) * bs
+    rows = rep * ct
+    # p = exp(scale * (s - m)) on the raw float32 products: one multiply
+    log2e_scale = scale * 1.4426950408889634
 
     @pl.when(j == 0)
     def _init():
@@ -293,68 +355,90 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    if window is None:
-        jb = j
+    def heads(block_refs):
+        # the step's blocks, then static lane slices of them (Mosaic refuses
+        # a reshape of the lanes at D = 64)
+        parts = [r[0] for r in block_refs]
+        x = parts[0] if nb == 1 else jnp.concatenate(parts, axis=0)
+        return jnp.stack([x[:, g * d:(g + 1) * d] for g in range(hkv)])
 
-    @pl.when(jnp.logical_and(valid > 0, jb * bs <= max_pos))
-    def _compute():
-        q = q_ref[...].astype(jnp.float32) * scale        # [CT, Hq, D]
-        d = q.shape[2]
-        k = _split_heads(k_ref, hkv, d)                   # [Hkv, BS, D]
-        v = _split_heads(v_ref, hkv, d)
-        # GQA layout: [Hkv, CT*rep, D]; row r -> query token i = r // rep
-        qg = q.reshape(ct, hkv, rep, d).transpose(1, 0, 2, 3).reshape(
-            hkv, ct * rep, d)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (0,))),
-        )                                                 # [Hkv, CT*rep, BS]
-        qi = jax.lax.broadcasted_iota(jnp.int32, (1, ct * rep, 1), 1) // rep
-        qpos = pos0 + qi
-        kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2)
-        mask = jnp.logical_and(kpos <= qpos, qi < valid)
+    @pl.when(jnp.logical_and(valid > 0, k_lo <= max_pos))
+    def _step():
+        k, v = heads(k_refs), heads(v_refs)                # [Hkv, CH, D]
+        # Row r of a head's group is query token r % ct and a strip is whole
+        # runs of ct rows, so ONE mask a step serves every strip and head; a
+        # clamped repeat's keys lie past every real query's position.
+        tok = jax.lax.broadcasted_iota(jnp.int32, (strip, ch), 0) % ct
+        ahead = (pos0 - k_lo + tok
+                 - jax.lax.broadcasted_iota(jnp.int32, (strip, ch), 1))
+        seen = ahead >= 0                                   # kpos <= qpos
         if window is not None:
-            mask = jnp.logical_and(mask, kpos > qpos - window)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)        # [Hkv, CT*rep, 1]
-        m_prev = m_sc[:, :, :1]
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new)
-        # fully-masked rows (pad queries / no visible keys in this block)
-        # produce exp(-inf - -inf); zero them rather than poison l
-        p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[:, :, :1] = l_sc[:, :, :1] * corr + jnp.sum(p, -1, keepdims=True)
-        m_sc[:, :, :1] = m_new
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-        )                                                 # [Hkv, CT*rep, D]
-        acc[:] = acc[:] * corr + pv
+            seen = jnp.logical_and(seen, ahead < window)
 
-    @pl.when(j == nj - 1)
+        def strip_of_every_head(i, _):
+            at = pl.ds(pl.multiple_of(i * strip, strip), strip)
+            q = q_ref[0, :, at, :]                         # [Hkv, strip, D]
+            s = jax.lax.dot_general(
+                q.astype(k.dtype), k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)        # [Hkv, strip, CH]
+            s = jnp.where(seen[None], s, _NEG_INF)
+            # A row that has met none of its keys yet (the window's edge
+            # lies in a later block; a padding row) keeps m = -1e30 and
+            # gathers p = 1 over masked keys; its first real key's corr =
+            # exp(-1e30 - m) = 0 wipes that, and every real row meets its
+            # own position.
+            m_prev = m_sc[:, at, :]                         # [Hkv, strip, 128]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp2((s - _lanes(m_new, ch)) * log2e_scale)
+            corr = jnp.exp2((m_prev - m_new) * log2e_scale)
+            l_sc[:, at, :] = l_sc[:, at, :] * corr + jnp.sum(
+                p, -1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)        # [Hkv, strip, D]
+            acc[:, at, :] = acc[:, at, :] * _lanes(corr, d) + pv
+            m_sc[:, at, :] = m_new
+
+        # traced once, laid out end to end: as a loop the turns run one
+        # after another, 21-24% slower (PERF.md section 6, PR 45)
+        jax.lax.fori_loop(0, rows // strip, strip_of_every_head, None,
+                          unroll=True)
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        d = acc.shape[2]
-        out = acc[:] / jnp.maximum(l_sc[:, :, :1], 1e-30)
-        o_ref[...] = out.reshape(hkv, ct, rep, d).transpose(1, 0, 2, 3).reshape(
-            ct, hkv * rep, d).astype(o_ref.dtype)
+        l = jnp.maximum(_lanes(l_sc[:], d), 1e-30)
+        o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
 
 
-# Scoped VMEM a Mosaic kernel may use by default (v5e), and what one query
-# row of one head costs the prefill kernel in it: three float32 scratches
-# (acc, m, l; D and the m/l column pad to 128 lanes), the double-buffered
-# bf16 q and o blocks, and about three float32 temporaries (q, scores, p).
-# Checked against the compiler: 128 rows x 32 heads x 128 is refused at
-# 16.2-16.4 MiB, 128 x 25 x 64 is accepted (tests/unit/test_compile_tpu.py).
+# Scoped VMEM a Mosaic kernel may use by default (v5e), and what the tile
+# kernel asks for instead (of 128 MiB; the default refuses a whole 128-row
+# tile of 32 heads x 128 beside 512 keys, and 28 heads in a program of one
+# tile). What the kernel holds in it: for one query row of one head three
+# float32 scratches (acc, m, l; D pads to 128 lanes) and the double-buffered
+# q and o blocks in the operands' dtype; a step's K and V blocks,
+# double-buffered; and up to four float32 values of a turn's scores (all
+# heads' strips x the step's keys; the compiler's own totals at the cells'
+# shapes: 13.7-18.6 MiB; tests/unit/test_compile_tpu.py).
 _VMEM_SCOPED_BYTES = 16 * 2**20
-_PREFILL_BYTES_PER_LANE = 3 * 4 + 2 * 2 * 2 + 3 * 4
+_PREFILL_VMEM_BYTES = 48 * 2**20
+_STRIP_TEMPORARIES = 4
 
 
-def prefill_kernel_tile(tile: int, hq: int, d: int) -> int:
+def prefill_kernel_tile(tile: int, hq: int, hkv: int, d: int, itemsize: int,
+                        step_keys: int) -> int:
     """Largest power-of-two split of the scheduler's ``tile`` whose working
-    set fits scoped VMEM with an eighth to spare (the K/V blocks and the
-    compiler's own stack share the limit)."""
-    row = hq * max(d, 128) * _PREFILL_BYTES_PER_LANE
+    set fits the kernel's VMEM with an eighth to spare, at ``step_keys``
+    keys a grid step."""
+    row = hq * max(d, 128) * (3 * 4 + 2 * 2 * itemsize)
+
+    def fits(ct):
+        turn = hkv * _strip_rows(hkv, hq // hkv, ct)
+        return ct * row + step_keys * (
+            4 * hkv * d * itemsize + _STRIP_TEMPORARIES * 4 * turn
+        ) <= _PREFILL_VMEM_BYTES * 7 // 8
+
     ct = tile
-    while ct > 8 and ct % 2 == 0 and ct * row > _VMEM_SCOPED_BYTES * 7 // 8:
+    while ct > 8 and ct % 2 == 0 and not fits(ct):
         ct //= 2
     return ct
 
@@ -383,62 +467,97 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
 
     Scheduler contract (``inference/ragged.py``): each tile's tokens belong
     to ONE sequence at consecutive positions ``pos0..pos0+valid-1``; rows
-    past ``valid`` are padding. ``tile_valid == 0`` marks an all-pad tile.
+    past ``valid`` are padding and their outputs unspecified (finite).
+    ``tile_valid == 0`` marks an all-pad tile.
 
-    Where a whole scheduler tile does not fit scoped VMEM (32 heads x 128),
-    each tile runs as consecutive sub-tiles: a sub-tile is itself a tile of
-    the same sequence, so the kernel and its masking are unchanged and only
-    the KV block is fetched once per sub-tile instead of once per tile.
+    Where a whole scheduler tile does not fit the kernel's VMEM (128 heads x
+    128; every cell's geometry fits), each tile runs as consecutive
+    sub-tiles: a sub-tile is itself a tile of the same sequence, so the
+    kernel and its masking are unchanged and only the KV blocks are fetched
+    once per sub-tile instead of once per tile.
 
     With a ``window`` (static) the grid's second axis is not the table's
     width but the blocks a window and a tile can span, from the tile's first
     needed block; no entry before it need name a block of the sequence's.
     """
+    _, hq, d = q.shape
+    _, bs, hd = k_pool.shape
+    nb = prefill_step_blocks(bs, hd, k_pool.dtype.itemsize)
+    ct = prefill_kernel_tile(tile, hq, hd // d, d, q.dtype.itemsize, nb * bs)
+    return _tiled_prefill(
+        q, k_pool, v_pool, tile_slot.astype(jnp.int32),
+        tile_pos0.astype(jnp.int32), tile_valid.astype(jnp.int32),
+        block_tables.astype(jnp.int32), tile=tile, ct=ct, nb=nb,
+        scale=float(scale if scale is not None else 1.0 / (d ** 0.5)),
+        interpret=interpret_mode(interpret), window=window)
+
+
+# ONE jitted function: a period's window layers, and the step programs of one
+# tile count (d4_t3 .. d16_t3), share its trace
+@functools.partial(jax.jit, static_argnames=(
+    "tile", "ct", "nb", "scale", "interpret", "window"))
+def _tiled_prefill(q, k_pool, v_pool, tile_slot, tile_pos0, tile_valid,
+                   block_tables, *, tile: int, ct: int, nb: int, scale: float,
+                   interpret: bool, window: int | None):
     t_tokens, hq, d = q.shape
     _, bs, hd = k_pool.shape
     hkv = hd // d
     mb = block_tables.shape[1]
     rep = hq // hkv
-    ct = prefill_kernel_tile(tile, hq, d)
     tile_slot, tile_pos0, tile_valid = split_tiles(
         tile_slot, tile_pos0, tile_valid, tile, ct)
     n_tiles = t_tokens // ct
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    # clamp past the tile's last needed block: unchanged id -> no new DMA
-    def _kv_map(c, j, ts, tp, tv, bt):
-        last = jnp.maximum(tp[c] + tv[c] - 1, 0) // bs
-        if window is not None:
-            j = j + jnp.maximum(tp[c] - window + 1, 0) // bs
-        return (bt[ts[c], jnp.minimum(j, last)], 0, 0)
+    # block i of step j, counted from the tile's first needed block (NOT from
+    # a multiple of nb: the sliding pool's table names no block before it);
+    # past the tile's last needed block clamped to it: unchanged id -> no new
+    # DMA, and its keys are masked by their position
+    def _kv_map(i):
+        def index(c, j, ts, tp, tv, bt):
+            last = jnp.maximum(tp[c] + tv[c] - 1, 0) // bs
+            blk = j * nb + i
+            if window is not None:
+                blk = blk + jnp.maximum(tp[c] - window + 1, 0) // bs
+            return (bt[ts[c], jnp.minimum(blk, last)], 0, 0)
+        return index
 
     if window is not None:
         # keys pos0 - window + 1 .. pos0 + ct - 1, wherever pos0 lies in a block
         mb = min(mb, (window + ct - 2) // bs + 2)
+
+    def _tile_map(c, j, ts, tp, tv, bt):
+        return (c, 0, 0, 0)
+
+    # a KV head's group of query heads as ONE matrix of rep * CT rows (row r
+    # is head r // CT of the group, token r % CT): laid out here, beside the
+    # projection that made q, once a layer, not in every grid step
+    q_groups = q.reshape(n_tiles, ct, hkv, rep, d).transpose(
+        0, 2, 3, 1, 4).reshape(n_tiles, hkv, rep * ct, d)
+    kv_specs = [pl.BlockSpec((1, bs, hd), _kv_map(i)) for i in range(nb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(n_tiles, mb),
-        in_specs=[
-            pl.BlockSpec((ct, hq, d), lambda c, j, ts, tp, tv, bt: (c, 0, 0)),
-            pl.BlockSpec((1, bs, hd), _kv_map),
-            pl.BlockSpec((1, bs, hd), _kv_map),
-        ],
-        out_specs=pl.BlockSpec((ct, hq, d),
-                               lambda c, j, ts, tp, tv, bt: (c, 0, 0)),
+        grid=(n_tiles, -(-mb // nb)),
+        in_specs=[pl.BlockSpec((1, hkv, rep * ct, d), _tile_map)]
+        + kv_specs + kv_specs,
+        out_specs=pl.BlockSpec((1, hkv, rep * ct, d), _tile_map),
         scratch_shapes=[
-            pltpu.VMEM((hkv, ct * rep, d), jnp.float32),
-            pltpu.VMEM((hkv, ct * rep, 128), jnp.float32),
-            pltpu.VMEM((hkv, ct * rep, 128), jnp.float32),
+            pltpu.VMEM((hkv, rep * ct, d), jnp.float32),
+            pltpu.VMEM((hkv, rep * ct, 128), jnp.float32),
+            pltpu.VMEM((hkv, rep * ct, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_prefill_kernel, bs=bs, ct=ct, hkv=hkv,
-                               rep=rep, scale=scale, window=window)
-    return pl.pallas_call(
+    kernel = functools.partial(
+        _prefill_kernel, bs=bs, nb=nb, ct=ct, hkv=hkv, rep=rep, d=d,
+        strip=_strip_rows(hkv, rep, ct), scale=scale, window=window)
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((t_tokens, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, hkv, rep * ct, d), q.dtype),
         grid_spec=grid_spec,
-        interpret=interpret_mode(interpret),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret,
         name="tiled_prefill" if window is None else "swa_prefill",
-    )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
-      tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pool, v_pool)
+    )(tile_slot, tile_pos0, tile_valid, block_tables, q_groups,
+      *([k_pool] * nb), *([v_pool] * nb))
+    return out.reshape(n_tiles, hkv, rep, ct, d).transpose(
+        0, 3, 1, 2, 4).reshape(t_tokens, hq, d)

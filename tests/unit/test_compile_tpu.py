@@ -29,6 +29,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     decode_step_blocks,
     paged_decode_attention,
     prefill_kernel_tile,
+    prefill_step_blocks,
     ragged_prefill_attention,
 )
 
@@ -494,14 +495,57 @@ def test_flash_compiles_on_a_described_mesh(v5e, monkeypatch):
     assert text.count("tpu_custom_call") >= 3
 
 
+# the tiles of the five K/V-pool serving cells: (q heads, kv heads, head
+# size, block size, window, table width, pool blocks, blocks a grid step)
+PREFILL_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, None, 32, 513, 8),
+                 "mixtral.chat": (32, 8, 128, 128, None, 8, 1025, 4),
+                 "mixtral.longdoc": (32, 8, 128, 128, None, 64, 1537, 4),
+                 "nemotron.reason": (32, 2, 128, 128, None, 32, 4097, 4),
+                 "smallthinker.full": (28, 4, 128, 128, None, 64, 641, 4),
+                 "smallthinker.window": (28, 4, 128, 128, 4096, 64, 529, 4)}
+
+
+@pytest.mark.parametrize("cell,tiles", [(c, 3) for c in sorted(PREFILL_CELLS)]
+                         + [("smallthinker.window", 1)])
+def test_tile_kernel_compiles_at_the_cells_shapes(v5e, cell, tiles):
+    """The tile kernel at a cell's block size, table width and heads, at the
+    blocks a grid step ``prefill_step_blocks`` gives it (each an operand of
+    its own: GPT-2 XL's 1600-lane blocks), a whole 128-row tile in the VMEM
+    it asks for; beside the kernel only q laid out by KV head and the output
+    laid back (a program of ONE tile was the first thing Mosaic's default
+    16 MiB refused: 17.3)."""
+    hq, hkv, d, block, window, table, blocks, nb = PREFILL_CELLS[cell]
+    assert prefill_step_blocks(block, hkv * d, 2) == nb
+    assert prefill_kernel_tile(TILE, hq, hkv, d, 2, nb * block) == TILE
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    pool, i32 = s((blocks, block, hkv * d)), s((tiles,), jnp.int32)
+    compiled = jax.jit(lambda *a: ragged_prefill_attention(
+        *a, TILE, interpret=False, window=window)).lower(
+        s((tiles * TILE, hq, d)), pool, pool, i32, i32, i32,
+        s((17, table), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        2 * tiles * TILE * hq * max(d, 128) * 2)
+
+
 def test_prefill_tile_split_is_what_the_compiler_needs():
-    """32 heads x 128 at the scheduler's 128-row tile is over the scoped
-    VMEM limit (the compiler says 16.2-16.4 MiB of 16), so it runs as two
-    64-row sub-tiles; the GPT-2 geometries keep the whole tile."""
-    assert prefill_kernel_tile(TILE, 32, 128) == 64
-    assert prefill_kernel_tile(TILE, 25, 64) == TILE
-    assert prefill_kernel_tile(TILE, 16, 64) == TILE
-    assert prefill_kernel_tile(8, 32, 128) == 8
+    """Every cell's geometry keeps the scheduler's whole 128-row tile in the
+    48 MiB the kernel asks for (Mosaic's default 16 MiB refused 32 heads x
+    128 at 17.3-20.5). The rule splits where the compiler refuses: handed
+    the size one step above each of the last three (``_tiled_prefill(ct=)``,
+    AOT for a v5e, PR 45) it says 56.6, 54.8 and 57.6 MiB of 48, and takes
+    what the rule gives."""
+    assert prefill_kernel_tile(TILE, 32, 8, 128, 2, 512) == TILE
+    assert prefill_kernel_tile(TILE, 25, 25, 64, 2, 256) == TILE
+    assert prefill_kernel_tile(TILE, 16, 16, 64, 2, 256) == TILE
+    assert prefill_kernel_tile(8, 32, 8, 128, 2, 512) == 8
+    assert prefill_kernel_tile(256, 64, 8, 128, 2, 512) == 128
+    assert prefill_kernel_tile(512, 32, 8, 128, 2, 512) == 256
+    assert prefill_kernel_tile(TILE, 128, 8, 128, 2, 512) == 64
     # 16 heads x (512-lane accumulator, 640-lane q): 128 rows are 19 MiB
     assert mla_prefill_kernel_tile(TILE, MLA_HEADS, MLA_LAT, MLA_WIDTH,
                                    MLA_BLOCK) == 64
@@ -1342,7 +1386,6 @@ def test_window_kernels_compile_at_the_cells_shapes(v5e, kernel):
             *a, interpret=False, window=SWA_WINDOW)
         args = (s((16, SWA_HEADS, SWA_D)), pool, pool, rows, rows, bt)
     else:
-        assert prefill_kernel_tile(TILE, SWA_HEADS, SWA_D) == TILE
         tiles = s((4,), jnp.int32)
         fn = lambda *a: ragged_prefill_attention(  # noqa: E731
             *a, TILE, interpret=False, window=SWA_WINDOW)

@@ -120,57 +120,155 @@ def test_decode_step_blocks_follow_the_blocks_bytes():
     assert decode_step_blocks(8, 32, 4) == 8        # never more than eight
 
 
-@pytest.mark.parametrize("CT,MB,split", [(8, 4, False), (32, 10, True)],
-                         ids=["whole_tile", "split_tile"])
-def test_tiled_prefill_kernel_matches_xla(CT, MB, split, monkeypatch):
-    """The tiled prefill kernel (interpret mode on CPU) is exact vs the XLA
-    path, including tile padding, block-edge positions and a pad tile —
-    also when a tile over the scoped-VMEM budget runs as sub-tiles."""
+# (tile rows, table width, q heads, kv heads, dtype, tiles as (pos0, valid)):
+# D = 16 and 8-token blocks, so a grid step takes 8 blocks = 64 keys
+# (``prefill_step_blocks``) and a table of 12 two steps
+TILE_CASES = {
+    # seq0 chunk of 2*CT-2 tokens from position 5, seq1 chunk of 6, a pad tile
+    "whole_tile": (8, 4, 4, 2, "float32", [(5, 8), (13, 6), (0, 6), (0, 0)]),
+    # a budget this geometry exceeds: 32-row tiles run as 4 x 8
+    "split_tile": (32, 10, 4, 2, "float32",
+                   [(5, 32), (37, 30), (0, 6), (0, 0)]),
+    # one step whose operands 1..7 (2..7) repeat the tile's last block, and a
+    # second step that begins with the last block and repeats it seven times
+    "clamped_repeats": (8, 12, 4, 2, "float32",
+                        [(0, 8), (3, 8), (64, 8), (60, 3)]),
+    # the diagonal inside a step's first block, inside its last block, across
+    # two steps, and a ragged tile under the second step's first block
+    "diagonal_in_first_or_last_block": (8, 12, 4, 2, "float32",
+                                        [(64, 8), (56, 8), (58, 8), (66, 5)]),
+    "rep_1": (8, 12, 2, 2, "float32", [(5, 8), (60, 6), (70, 8), (0, 0)]),
+    "rep_7": (8, 12, 14, 2, "float32", [(5, 8), (60, 6), (70, 8), (0, 0)]),
+    # as the cells serve: products of bf16 operands accumulated in float32
+    "bf16_pool": (16, 12, 4, 2, "bfloat16",
+                  [(5, 16), (50, 14), (72, 16), (0, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_tiled_prefill_kernel_matches_xla(case, monkeypatch):
+    """The tiled prefill kernel (interpret mode on CPU) against the XLA
+    path: tile padding, block-edge positions, a pad tile, a tile over the
+    scoped-VMEM budget run as sub-tiles, steps of several blocks whose last
+    operands are clamped repeats, the diagonal in a step's first and last
+    block, one and seven query heads a KV head, the pool's own precision.
+    Every tile is a sequence of its own whose table entries past its last
+    block name a block of NaNs, which one read would carry into the output
+    (a masked key's ``p = 0`` times its value)."""
     from deepspeed_tpu.ops.attention import ragged_prefill_attention
     from deepspeed_tpu.ops.pallas import paged_attention as kernels
 
-    Hq, Hkv, D, BS = 4, 2, 16, 8
-    if split:  # a budget this geometry exceeds: 32-row tiles run as 4 x 8
-        monkeypatch.setattr(kernels, "_VMEM_SCOPED_BYTES", 2**17)
-    assert kernels.prefill_kernel_tile(CT, Hq, D) == 8
+    CT, MB, Hq, Hkv, dtype, tiles = TILE_CASES[case]
+    D, BS = 16, 8
+    if case == "split_tile":
+        monkeypatch.setattr(kernels, "_PREFILL_VMEM_BYTES", 2**17)
+    assert kernels.prefill_step_blocks(BS, Hkv * D, 4) == 8
+    assert kernels.prefill_kernel_tile(CT, Hq, Hkv, D, 4, 8 * BS) == (
+        8 if case == "split_tile" else CT)
     rng = np.random.default_rng(4)
-    NB = 2 * MB + 1
-    # 4 tiles: seq0 chunk of 2*CT-2 tokens from position 5 (tiles 0-1), seq1
-    # chunk of 6 tokens (tile 2, pos 0..5), tile 3 all-pad
-    q = jnp.asarray(rng.normal(size=(4 * CT, Hq, D)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32))
-    bt = np.zeros((3, MB), np.int32)
-    bt[0] = rng.permutation(np.arange(1, MB + 1))
-    bt[1] = rng.permutation(np.arange(MB + 1, 2 * MB + 1))
-    ts = jnp.asarray(np.array([0, 0, 1, 2], np.int32))
-    tp = jnp.asarray(np.array([5, 5 + CT, 0, 0], np.int32))
-    tv = jnp.asarray(np.array([CT, CT - 2, 6, 0], np.int32))
-    out_x = ragged_prefill_attention(q, kp, vp, ts, tp, tv, jnp.asarray(bt),
-                                     CT, impl="xla")
-    out_p = ragged_prefill_attention(q, kp, vp, ts, tp, tv, jnp.asarray(bt),
-                                     CT, impl="pallas")
-    # compare valid rows only (pad rows are unspecified garbage/zeros)
-    for c in range(4):
-        v = int(tv[c])
-        a = np.asarray(out_x)[c * CT:c * CT + v]
-        b = np.asarray(out_p)[c * CT:c * CT + v]
-        np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5, err_msg=f"tile {c}")
+    NB = len(tiles) * MB + 2
+    poisoned = NB - 1
+    q = rng.normal(size=(len(tiles) * CT, Hq, D)).astype(np.float32)
+    kp = rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32)
+    vp = rng.normal(size=(NB, BS, Hkv * D)).astype(np.float32)
+    kp[poisoned] = vp[poisoned] = np.nan
+    clean = np.zeros((len(tiles) + 1, MB), np.int32)
+    dirty = np.full((len(tiles) + 1, MB), poisoned, np.int32)
+    dirty[-1] = 0                       # the padding row: all scratch
+    for c, (pos0, valid) in enumerate(tiles):
+        need = (pos0 + valid - 1) // BS + 1 if valid else 0
+        clean[c, :need] = 1 + c * MB + rng.permutation(MB)[:need]
+        dirty[c, :need] = clean[c, :need]
+    ts = np.array([c if v else len(tiles) for c, (_, v) in enumerate(tiles)],
+                  np.int32)
+    tp = jnp.asarray(np.array([p for p, _ in tiles], np.int32))
+    tv = jnp.asarray(np.array([v for _, v in tiles], np.int32))
+
+    def run(table, impl):
+        return np.asarray(ragged_prefill_attention(
+            jnp.asarray(q, dtype), jnp.asarray(kp, dtype),
+            jnp.asarray(vp, dtype), jnp.asarray(ts), tp, tv,
+            jnp.asarray(table), CT, impl=impl), np.float32)
+
+    out_x, out_p = run(clean, "xla"), run(dirty, "pallas")
+    # compare valid rows only (pad rows are unspecified, but finite)
+    assert np.isfinite(out_p).all()
+    for c, (_, v) in enumerate(tiles):
+        if not v:
+            continue
+        a = out_x[c * CT:c * CT + v]
+        b = out_p[c * CT:c * CT + v]
+        if dtype == "bfloat16":    # chip_smoke.py's measure and limit
+            assert np.abs(b - a).max() / np.abs(a).max() <= 3e-2, f"tile {c}"
+        else:
+            np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5,
+                                       err_msg=f"tile {c}")
 
 
-def test_ragged_engine_uses_dispatcher():
-    """End-to-end ragged generation still exact after the dispatcher swap."""
+def test_prefill_step_blocks_follow_the_blocks_shape():
+    from deepspeed_tpu.ops.pallas.paged_attention import prefill_step_blocks
+
+    assert prefill_step_blocks(128, 512, 2) == 4     # SmallThinker: 512 keys
+    assert prefill_step_blocks(128, 1024, 2) == 4    # Mixtral: 512 keys, 1 MiB
+    assert prefill_step_blocks(128, 256, 2) == 4     # Nemotron-3
+    assert prefill_step_blocks(32, 1600, 2) == 8     # GPT-2 XL: 256 keys
+    assert prefill_step_blocks(128, 1024, 4) == 2    # by the bytes
+    assert prefill_step_blocks(8, 32, 4) == 8        # never more than eight
+
+
+def _tiny_engine(**cfg):
     from deepspeed_tpu.comm.topology import reset_topology
     from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
     from deepspeed_tpu.models import llama
 
     reset_topology()
-    cfg = llama.LlamaConfig.tiny(256)
-    eng = RaggedInferenceEngine(
-        lambda ctx: llama.build(cfg, ctx=ctx),
+    model = llama.LlamaConfig.tiny(256)
+    return RaggedInferenceEngine(
+        lambda ctx: llama.build(model, ctx=ctx),
         RaggedConfig(max_seqs=4, num_blocks=64, block_size=16,
-                     max_tokens_per_step=32),
+                     max_tokens_per_step=32, **cfg),
         dtype=jnp.float32, seed=3)
+
+
+def test_ragged_engine_uses_dispatcher():
+    """End-to-end ragged generation still exact after the dispatcher swap."""
+    eng = _tiny_engine()
     eng.put("a", list(range(9)), max_new_tokens=5)
     out = eng.generate_all()
     assert len(out["a"]) == 5
+
+
+def test_dispatch_span_says_the_keys_a_grid_step_takes(monkeypatch):
+    """``engine/dispatch`` of a program with tiles carries
+    ``prefill_step_keys``, a static of its tile kernel (eight 16-token
+    blocks here) by the rule the dispatcher goes by; a program without tiles
+    does not, nor any on the XLA path, and the tile kernel serves the XLA
+    path's tokens."""
+    import contextlib
+
+    from deepspeed_tpu.inference import ragged
+    from deepspeed_tpu.ops import attention
+
+    prompt = list(range(3, 43))     # two steps with tiles
+    spans = []
+
+    @contextlib.contextmanager
+    def recording(name, **args):
+        if name == "engine/dispatch":
+            spans.append(args)
+        yield
+
+    monkeypatch.setattr(ragged, "span", recording)
+    want = _tiny_engine(prefill_tile=8)
+    want.put("a", prompt, max_new_tokens=4)
+    want = want.generate_all()
+    assert spans and all("prefill_step_keys" not in a for a in spans)
+    del spans[:]
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)   # interpreted
+    eng = _tiny_engine(prefill_tile=8)
+    eng.put("a", prompt, max_new_tokens=4)
+    assert eng.generate_all() == want
+    tiled = [a for a in spans if not a["program"].endswith("_t0")]
+    assert len(tiled) == 2 and len(spans) > 2
+    assert all(a.get("prefill_step_keys") == (128 if a in tiled else None)
+               for a in spans)

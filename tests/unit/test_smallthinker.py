@@ -7,7 +7,9 @@ parts of an expert layer against the uncut layer; what the second allocator
 promises; the four refusals; and, for every family that was there before, the
 device step program's jaxpr against the one PR 43's tree traces
 (``fixtures/step_jaxprs_pr43.json``; ``python tests/unit/test_smallthinker.py
-<out.json>`` writes it from whatever tree ``PYTHONPATH`` names).
+<out.json> [family ...]`` writes it from whatever tree ``PYTHONPATH`` names;
+``fixtures/step_jaxprs_pr45.json`` holds the Pallas form of the four families
+that run the tile kernel PR 45 rebuilt).
 
 Logits are compared, not tokens. Tolerance 2e-4 (float32 everywhere here): the
 program runs a prompt as tiles against cached rows and the reference as one
@@ -28,8 +30,10 @@ import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
-                       "step_jaxprs_pr43.json")
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
+FIXTURE = os.path.join(FIXTURES, "step_jaxprs_pr43.json")
+# gpt2, llama, mixtral, nemotron_h: their Pallas form holds the tile kernel
+FIXTURE_TILE_KERNEL = os.path.join(FIXTURES, "step_jaxprs_pr45.json")
 ATOL = 2e-4
 
 
@@ -105,9 +109,13 @@ def step_jaxpr_digests(on_tpu: bool) -> dict:
 
 
 if __name__ == "__main__":
+    forms = {"xla": step_jaxpr_digests(False),
+             "pallas": step_jaxpr_digests(True)}
+    if sys.argv[2:]:
+        forms = {form: {k: v for k, v in digests.items() if k in sys.argv[2:]}
+                 for form, digests in forms.items()}
     with open(sys.argv[1], "w") as f:
-        json.dump({"xla": step_jaxpr_digests(False),
-                   "pallas": step_jaxpr_digests(True)}, f, indent=1)
+        json.dump(forms, f, indent=1)
     sys.exit(0)
 
 
@@ -128,9 +136,12 @@ from deepspeed_tpu.ops.pallas.paged_attention import (  # noqa: E402
 @pytest.mark.parametrize("form", ["xla", "pallas"])
 def test_the_other_families_step_programs_are_the_parents(form):
     """``window=None`` traces what it traced, the period scan and the expert
-    forms too: every family's step program prints PR 43's jaxpr."""
+    forms too: every family's step program prints PR 43's jaxpr, but for
+    the tile kernel's body in the four families that run it (PR 45's)."""
     with open(FIXTURE) as f:
         want = json.load(f)[form]
+    with open(FIXTURE_TILE_KERNEL) as f:
+        want.update(json.load(f)[form])
     got = step_jaxpr_digests(form == "pallas")
     assert got == want, sorted(k for k in want if got.get(k) != want[k])
 
@@ -417,35 +428,67 @@ def test_window_decode_rows_match_a_dense_masked_softmax(impl):
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("pos0", [0, 3, 8, 9, 21],
-                         ids=lambda p: f"tile_at_{p}")
-def test_window_prefill_tiles_match_a_dense_masked_softmax(pos0):
-    """Two 8-row tiles of one sequence from ``pos0`` (block-aligned and not;
-    the second partly padding) and a padding tile: the grid starts at each
-    tile's first needed block, so every entry before it may name the
-    poisoned block."""
+def _window_tiles(pos0, window, step_keys, monkeypatch):
+    """Two 8-row tiles of one sequence from ``pos0`` (the second partly
+    padding) and a padding tile through the tile kernel, ``step_keys`` keys
+    a grid step; every table entry before the first tile's first needed
+    block names a block of NaNs, which one read of a slid-out block would
+    carry into the output. Returns the real rows' outputs and the dense
+    softmax over ``W`` keys."""
+    from deepspeed_tpu.ops.pallas import paged_attention as kernels
+
     ct = 8
+    if step_keys:
+        monkeypatch.setattr(kernels, "PREFILL_STEP_KEYS", step_keys)
     rng = np.random.default_rng(5)
     k, v, kp, vp, order = _pool(rng)
+    kp[MB + 1] = vp[MB + 1] = np.nan
     tp = np.asarray([pos0, pos0 + ct, 0], np.int32)
     tv = np.asarray([ct, 5, 0], np.int32)
     q = rng.normal(size=(3 * ct, 4, 16)).astype(np.float32)
     bt = np.zeros((3, MB), np.int32)
     bt[0] = order
-    bt[0, :max(0, pos0 - W + 1) // KBS] = MB + 1
+    bt[0, :max(0, pos0 - window + 1) // KBS] = MB + 1
     out = np.asarray(ragged_prefill_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray([0, 0, 2], jnp.int32), jnp.asarray(tp), jnp.asarray(tv),
-        jnp.asarray(bt), ct, interpret=True, window=W))
+        jnp.asarray(bt), ct, interpret=True, window=window))
+    assert np.isfinite(out).all()
     rows = np.concatenate([np.arange(ct), ct + np.arange(5)])
     pos = np.concatenate([pos0 + np.arange(ct), pos0 + ct + np.arange(5)])
-    np.testing.assert_allclose(out[rows], _dense(q[rows], k, v, pos, W),
-                               rtol=2e-5, atol=2e-5)
+    return out[rows], _dense(q[rows], k, v, pos, W)
 
 
-def test_a_window_edge_off_by_one_block_is_seen():
+# (pos0, keys a grid step): 0 keeps the rule's 8 blocks of 4 tokens, one step
+# a tile; 8 is two blocks a step, three steps a tile, and at 15 and 25 the
+# tile's first needed block (1, 3) is no multiple of them
+WINDOW_TILES = [(0, 0), (3, 0), (8, 0), (9, 0), (21, 0), (15, 8), (25, 8),
+                (21, 8)]
+
+
+@pytest.mark.parametrize(
+    "pos0,step_keys", WINDOW_TILES,
+    ids=[f"tile_at_{p}" + (f"_{k}_keys_a_step" if k else "")
+         for p, k in WINDOW_TILES])
+def test_window_prefill_tiles_match_a_dense_masked_softmax(pos0, step_keys,
+                                                           monkeypatch):
+    """Tiles from ``pos0`` (block-aligned and not): the grid starts at each
+    tile's first needed block, NOT at a multiple of the blocks a step, so
+    every entry before it may name the poisoned block."""
+    got, want = _window_tiles(pos0, W, step_keys, monkeypatch)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_a_window_edge_off_by_one_block_is_seen(kernel, monkeypatch):
     """The control: a window one block short is another model, by far more
-    than the tolerance the served logits are held to."""
+    than the tolerance the served logits are held to; through the tile
+    kernel at a first needed block that is no multiple of the blocks a
+    step."""
+    if kernel == "prefill":
+        got, want = _window_tiles(25, W - KBS, 8, monkeypatch)
+        assert np.abs(got - want).max() > 0.05
+        return
     rng = np.random.default_rng(3)
     k, v, kp, vp, order = _pool(rng)
     pos = np.asarray([W + 3, 27], np.int32)
