@@ -191,6 +191,15 @@ def _picks_before(flat, e: int):
     return (inside + earlier[:, None]).reshape(p, e).astype(jnp.int32)
 
 
+# the sorted rows a trip of the held form's two loops takes (``_rows_of`` and
+# ``_combine``). From a sweep on a TPU v5e, one layer's expert FFN alone at the
+# five held cells' shapes (PERF.md section 6, PR 46), us a layer at 128 / 256
+# rows: 202.9 / 206.5 (8 of 64 experts, 2,560 wide), 1,914 / 1,987 (16 of 512,
+# 6,144), 801 / 790, 2,186 / 2,186, 2,287 / 2,242 (128 of 512, 1,024): within
+# 4% of each other, a trip of either loop costing under 2 us beside its work
+_SORTED_BLOCK = 128
+
+
 def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
                      num_experts, held=None, zero_experts: int = 0,
                      gate_act: str = "silu"):
@@ -199,17 +208,31 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     row plus the picks of that expert before it), every expert's rows go
     through that expert's weights (``ops/pallas/moe_gmm.py``), and the
     results come back to token order for the router's weights. The layer's
-    ``num_experts`` experts start at ``first_expert`` of the weights. With
-    ``held`` (first routed expert held, experts routed over) a pick of an
-    expert the layer does not hold sorts past the last group: it gets no
-    row, is never multiplied, and comes back as zero; so does a pick of one
-    of the ``zero_experts`` outputs past the routed ones."""
+    ``num_experts`` experts start at ``first_expert`` of the weights.
+
+    The kernel's row buffer is sized by the SHAPES, for every pick landing on
+    an expert of this layer (the router may do that). What is moved around the
+    kernel is sized by what the layer holds. ``held`` None, every routed
+    expert: every pick has a row, so the rows are gathered and un-sorted
+    whole, ``[T x top_k, D]`` either way. With ``held`` (first routed expert
+    held, experts routed over) a pick of an expert the layer does not hold
+    sorts past the last group and gets no row, as does a pick of one of the
+    ``zero_experts`` outputs past the routed ones; the rows that exist end at
+    ``row_end``, a value of the step, and only those are gathered
+    (``_rows_of``) and combined (``_combine``), a block at a time from the
+    sorted side: all of them at any routing, the whole buffer when every
+    pick is held."""
     t, d = h.shape
     e, k = num_experts, topi.shape[1]
     if t > _GROUPED_MAX_ROWS:
         pad = -t % _GROUPED_MAX_ROWS
-        parts = [jnp.pad(a, ((0, pad), (0, 0))).reshape(
-            -1, _GROUPED_MAX_ROWS, a.shape[1]) for a in (h, topv, topi)]
+        # with ``held`` the rows that pad the last call pick an expert the
+        # layer does not hold (-1), so they get no row: as picks of expert 0
+        # they would all land on the rank that holds it
+        picks_of_padding = 0 if held is None else -1
+        parts = [jnp.pad(a, ((0, pad), (0, 0)), constant_values=c).reshape(
+            -1, _GROUPED_MAX_ROWS, a.shape[1])
+            for a, c in ((h, 0), (topv, 0), (topi, picks_of_padding))]
         out = lax.map(lambda p: _grouped_experts(
             *p, w_gate, w_up, w_down, first_expert, e, held, zero_experts,
             gate_act), tuple(parts))
@@ -220,9 +243,11 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     # A pass of the kernel is 128 rows where an expert's share of a full call
     # is more than 64 (128 at Mixtral's 2 of 8), else 64 (48 at Moonlight's
     # 6 of 64; 8 at 12 of 512 + 256 zero-compute outputs): one pass an expert
-    # either way. ``rows`` below stays sized for every pick landing on a held
-    # expert, which the router may do, whatever share of them it is expected
-    # to send elsewhere.
+    # either way. ``rows``, the buffer's SHAPE, is the worst case: every pick
+    # of a full call on an expert of this layer, each expert's rows rounded
+    # up to ``ROW_ALIGN``, and a pass past the last. A shape costs nothing:
+    # the kernel copies the rows its experts got and no other, and with
+    # ``held`` so does everything around it (below).
     outputs = (e if held is None else held[1]) + zero_experts
     tm = 128 if _GROUPED_MAX_ROWS * k > 64 * outputs else 64
     flat = _held_picks(topi, held, e).reshape(-1)
@@ -233,20 +258,77 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     place = row0[flat] + jnp.take_along_axis(before, flat[:, None], 1)[:, 0] - 1
     rows = (-(-(_GROUPED_MAX_ROWS * k + e * (ROW_ALIGN - 1)) // ROW_ALIGN)
             * ROW_ALIGN + tm)
-    drop = {}
-    if held is not None:
-        place = jnp.where(flat < e, place, rows)
-        drop = {"mode": "drop"}
-    token = jnp.zeros((rows,), jnp.int32).at[place].set(
-        jnp.arange(t * k, dtype=jnp.int32) // k, **drop)
     dtype = h.dtype
-    y = grouped_swiglu(h[token], None if w_gate is None else w_gate.astype(dtype),
-                       w_up.astype(dtype), w_down.astype(dtype), row0, counts,
-                       tm, max_rows=_GROUPED_MAX_ROWS,
-                       first_expert=first_expert, gate_act=gate_act)
-    y = y[place] if held is None else y.at[place].get(mode="fill",
-                                                      fill_value=0)
-    return jnp.einsum("tkd,tk->td", y.reshape(t, k, d), topv).astype(dtype)
+
+    def experts_of(x):
+        return grouped_swiglu(
+            x, None if w_gate is None else w_gate.astype(dtype),
+            w_up.astype(dtype), w_down.astype(dtype), row0, counts, tm,
+            max_rows=_GROUPED_MAX_ROWS, first_expert=first_expert,
+            gate_act=gate_act)
+
+    if held is None:
+        token = jnp.zeros((rows,), jnp.int32).at[place].set(
+            jnp.arange(t * k, dtype=jnp.int32) // k)
+        y = experts_of(h[token])[place]
+        return jnp.einsum("tkd,tk->td", y.reshape(t, k, d), topv).astype(dtype)
+    # a sorted row's token (``t``: no pick landed there) and router weight
+    place = jnp.where(flat < e, place, rows)
+    token = jnp.full((rows,), t, jnp.int32).at[place].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+    weight = jnp.zeros((rows,), jnp.float32).at[place].set(
+        topv.reshape(-1), mode="drop")
+    blocks = lax.div(row0[-1] + aligned[-1] + (_SORTED_BLOCK - 1),
+                     _SORTED_BLOCK)
+    y = experts_of(_rows_of(h, token, blocks, rows))
+    return _combine(y, token, weight, blocks, t).astype(dtype)
+
+
+def _rows_of(h, token, blocks, rows: int):
+    """``[rows, D]``: row ``r`` is ``h[token[r]]`` in the first ``blocks``
+    blocks of ``_SORTED_BLOCK`` sorted rows (``blocks`` a value of the step:
+    the loop lowers to a ``while``), written in place into a buffer that is
+    never initialised: what lies past them is nothing meant, as the rows
+    between the experts are, and the kernel copies none of it."""
+    t, d = h.shape
+
+    def gather(b, x):
+        r0 = b * _SORTED_BLOCK
+        # a last block past the buffer's end starts early, in both alike
+        tok = lax.dynamic_slice(token, (r0,), (_SORTED_BLOCK,))
+        return lax.dynamic_update_slice(x, h[jnp.minimum(tok, t - 1)],
+                                        (r0, 0))
+
+    return lax.fori_loop(0, blocks, gather, lax.empty((rows, d), h.dtype))
+
+
+def _combine(y, token, weight, blocks, t: int):
+    """float32 ``[t, D]``: every sorted row ``r`` of the first ``blocks``
+    blocks on which a pick landed adds ``weight[r] * y[r]`` to row
+    ``token[r]``: the un-sort and the router's combine in one step, a block
+    as one product with the block's one-hot ``[t, block]`` (``HIGHEST``:
+    float32 on either side, as the sum it replaces). A row on which no pick
+    landed may hold anything, NaN too, and is selected out, not multiplied
+    by zero."""
+    rows, d = y.shape
+
+    def combine(b, out):
+        r0 = b * _SORTED_BLOCK
+        tok = lax.dynamic_slice(token, (r0,), (_SORTED_BLOCK,))
+        w = lax.dynamic_slice(weight, (r0,), (_SORTED_BLOCK,))
+        # a last block past the buffer's end starts early (``dynamic_slice``
+        # clamps): its rows before ``r0`` were the block before's
+        r = jnp.minimum(r0, rows - _SORTED_BLOCK) + jnp.arange(_SORTED_BLOCK)
+        meant = (tok < t) & (r >= r0)
+        part = jnp.where(
+            meant[:, None],
+            w[:, None] * lax.dynamic_slice(y, (r0, 0), (_SORTED_BLOCK, d)),
+            0.0)
+        onehot = tok[None, :] == jnp.arange(t)[:, None]
+        return out + jnp.dot(onehot.astype(jnp.float32), part,
+                             precision=lax.Precision.HIGHEST)
+
+    return lax.fori_loop(0, blocks, combine, jnp.zeros((t, d), jnp.float32))
 
 
 def routed_experts(h: jnp.ndarray, router_w, w_gate, w_up, w_down,

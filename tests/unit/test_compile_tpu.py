@@ -763,6 +763,46 @@ def test_grouped_step_copies_no_expert_weights(v5e, monkeypatch, family, rows,
     assert layouts.count(f"bf16[{n},{f},{d}]") == 1
 
 
+def test_held_grouped_layer_moves_the_rows_that_exist(v5e, monkeypatch):
+    """One rank's share of an expert-parallel layer at the window cell's
+    shapes (a 400-row step, 8 of 64 experts held, 6 picks a token, 2,560
+    wide): the kernel's 3,264-row buffer is a shape, and what the program
+    moves around the kernel follows the rows its experts got (PR 46). Before,
+    a gather filled all 3,264 rows and the results came back as one float32
+    row a PICK, ``f32[2400,2560]``, 66 MB a layer for ~300 real rows. Now the
+    buffer is allocated and never set (no broadcast, no gather of its shape:
+    blocks of 128 sorted rows are written into it in place under a ``while``)
+    and the combine is a second ``while`` over the same blocks."""
+    from deepspeed_tpu.models import experts
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    t, d, f, e, routed, k, layers = 400, 2560, 768, 8, 64, 6, 2
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def layer(h, topv, topi, w_gate, w_up, w_down, first):
+        return experts._grouped_experts(h, topv, topi, w_gate, w_up, w_down,
+                                        first, e, (0, routed), 0, "relu")
+
+    text = jax.jit(layer).lower(
+        s((t, d)), s((t, k), jnp.float32), s((t, k), jnp.int32),
+        s((layers * e, d, f)), s((layers * e, d, f)), s((layers * e, f, d)),
+        s((), jnp.int32)).compile().as_text()
+    made = _materialized(text)
+    rows = 3264  # 512 x 6 picks + 8 x 15 up to ROW_ALIGN, + a pass of 64
+    assert [ln for _, _, ln in made if f"f32[{t * k},{d}]" in
+            ln.split(" = ")[1].split(" ")[0]] == []
+    buffer = {op for _, op, ln in made
+              if ln.split(" = ")[1].startswith(f"bf16[{rows},{d}]")}
+    assert buffer == {"custom-call", "update"}, buffer
+    assert text.count(" while(") == 2
+    block = experts._SORTED_BLOCK
+    assert any(op == "fusion" and ln.split(" = ")[1].startswith(
+        f"bf16[{block},{d}]") for _, op, ln in made)
+
+
 def test_decode_step_relays_out_no_expert_stack(v5e, monkeypatch):
     """The einsum form reads the scan's own slice of the expert weights,
     which XLA fuses into the einsums. Given every layer's weights whole (what

@@ -90,6 +90,148 @@ def test_grouped_form_equals_the_einsum_form(router, t, edge):
         np.testing.assert_array_equal(np.asarray(stacked, np.float32), ruled)
 
 
+# ------------------------------------------ one rank's share (``held``)
+# the layer holds experts 4 .. 4 + e - 1 of the 16 the router scores. How the
+# picks fall: as the seed has it; every pick on a held expert (the row buffer
+# full: the worst case stays exact); none; one held expert picked by every row
+ROUTINGS = ["near_uniform", "every_pick_held", "no_pick_held",
+            "one_expert_for_all"]
+# rows of the step (over 512: two calls under ``lax.map``), held experts,
+# picks a token, gated, gate, zero-compute outputs. ``two_calls`` is also the
+# geometry whose last block of sorted rows reaches past the buffer's end when
+# every pick is held (a pass of 64 rows: 1,072 + 64 rows, 1,056 of them real)
+HELD = {
+    "silu": dict(t=400, e=4, k=3, gated=True, act="silu", zero=0),
+    "relu": dict(t=400, e=4, k=3, gated=True, act="relu", zero=0),
+    "ungated": dict(t=400, e=4, k=3, gated=False, act="silu", zero=0),
+    "zero_experts": dict(t=400, e=4, k=3, gated=True, act="silu", zero=8),
+    "two_calls": dict(t=1024, e=3, k=2, gated=True, act="silu", zero=0),
+}
+ROUTED, FIRST = 16, 4
+
+
+def _held_case(routing: str, geometry: str):
+    g = HELD[geometry]
+    t, e, k = g["t"], g["e"], g["k"]
+    ks = jax.random.split(jax.random.PRNGKey(t + e), 5)
+    h = jnp.abs(jax.random.normal(ks[0], (t, D), jnp.float32))
+    router_w = jax.random.normal(ks[1], (D, ROUTED + g["zero"]),
+                                 jnp.float32) * 0.1
+    mine = slice(FIRST, FIRST + e)
+    if routing == "every_pick_held":
+        # unevenly, so that the experts' ends are not multiples of ROW_ALIGN
+        router_w = router_w.at[:, mine].add(10.0)
+    elif routing == "no_pick_held":
+        router_w = router_w.at[:, mine].set(-10.0)
+    elif routing == "one_expert_for_all":
+        router_w = router_w.at[:, FIRST + 1].set(10.0)
+    w = [jax.random.normal(ks[2 + i], shape, jnp.float32) * shape[1] ** -0.5
+         for i, shape in enumerate([(e, D, F), (e, D, F), (e, F, D)])]
+    if not g["gated"]:
+        w[0] = None
+    kw = dict(held=(FIRST, ROUTED), zero_experts=g["zero"],
+              gate_act=g["act"])
+    return h, router_w, w, k, kw
+
+
+@pytest.mark.parametrize("geometry", sorted(HELD))
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_held_grouped_form_equals_the_einsum_form(routing, geometry,
+                                                  monkeypatch):
+    """With ``held`` the grouped form gathers and combines the rows that
+    exist, a block of sorted rows at a time: every pick of a held expert is
+    computed at any routing, and what the never-initialised part of the row
+    buffer holds (NaN here) reaches nothing."""
+    monkeypatch.setattr(jax.lax, "empty",
+                        lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    h, router_w, w, k, kw = _held_case(routing, geometry)
+    g = HELD[geometry]
+    _, topi = experts._route(h, router_w, k, "softmax", None, True, 1.0, 1e-9)
+    held_picks = int(jnp.sum((topi >= FIRST) & (topi < FIRST + g["e"])))
+    assert {"every_pick_held": held_picks == topi.size,
+            "no_pick_held": held_picks == 0,
+            "one_expert_for_all": bool(jnp.all(jnp.any(topi == FIRST + 1, 1))),
+            "near_uniform": 0 < held_picks < topi.size // 2}[routing]
+    assert experts.expert_form(g["t"], ROUTED, k) == "grouped"
+    want = np.asarray(experts.routed_experts_einsum(h, router_w, *w, k, **kw))
+    got = np.asarray(jax.jit(
+        lambda h, r, *w: experts.routed_experts(h, r, *w, k, **kw))(
+            h, router_w, *w))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want,
+                               atol=2e-5 * max(np.abs(want).max(), 1e-3))
+
+
+def test_rows_that_pad_a_second_call_get_no_row(monkeypatch):
+    """A 528-row step (the window cell's four tiles beside 16 decode rows) is
+    two calls of 512 rows, the second 16 rows and 496 of padding. The rank
+    that holds expert 0 must not take the padding's picks for its own: they
+    were 496 x top_k rows of one expert, more than a call's 512 rows, which
+    is all the kernel's VMEM holds of an expert."""
+    seen = []
+
+    def counting(x, w_gate, w_up, w_down, row0, counts, tm, **kw):
+        jax.debug.callback(lambda c: seen.append(np.asarray(c)), counts)
+        return jnp.zeros(x.shape, jnp.float32)
+
+    monkeypatch.setattr(experts, "grouped_swiglu", counting)
+    t, e, k = 528, 4, 3
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    h = jax.random.normal(ks[0], (t, D), jnp.float32)
+    topi = jnp.argsort(jax.random.normal(ks[1], (t, ROUTED)), axis=1)[:, :k]
+    topv = jnp.full((t, k), 1.0 / k)
+    w = [jnp.zeros(s_) for s_ in [(e, D, F), (e, D, F), (e, F, D)]]
+    jax.block_until_ready(experts._grouped_experts(
+        h, topv, topi.astype(jnp.int32), *w, 0, e, (0, ROUTED)))
+    jax.effects_barrier()
+    want = [np.bincount(np.asarray(topi[a:b]).ravel(), minlength=ROUTED)[:e]
+            for a, b in ((0, 512), (512, t))]
+    assert len(seen) == 2
+    for got in seen:
+        assert any((got == w_).all() for w_ in want), (got, want)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of what it calls, a kernel's body left
+    out (``moe_gmm`` loops over an expert's passes)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("held", [None, (FIRST, ROUTED)],
+                         ids=["every_expert", "held"])
+def test_what_a_grouped_layer_moves_around_the_kernel(held):
+    """A 400-row step. The layer holds every routed expert: the picks' rows
+    are gathered whole and come back whole, ``[T x top_k, D]``, and nothing
+    loops. It holds a share: no array of ``T x top_k`` float32 rows exists and
+    no gather makes the row buffer; two loops move blocks of sorted rows."""
+    t, k, e = 400, 3, ROUTED if held is None else 4
+    sh = jax.ShapeDtypeStruct
+    args = (sh((t, D), jnp.float32), sh((D, ROUTED), jnp.float32),
+            *[sh(s, jnp.float32) for s in [(e, D, F), (e, D, F), (e, F, D)]])
+    jaxpr = jax.make_jaxpr(lambda h, r, *w: experts.routed_experts(
+        h, r, *w, k, held=held))(*args).jaxpr
+    eqns = list(_eqns(jaxpr))
+    kernel, = [q for q in eqns if q.primitive.name == "pallas_call"]
+    rows = kernel.outvars[0].aval.shape[0]
+    assert rows > t * k
+    made = [(q.primitive.name, v.aval.shape, v.aval.dtype)
+            for q in eqns for v in q.outvars if hasattr(v.aval, "shape")]
+    unsorted = [m for m in made if m[1] == (t * k, D) and m[2] == jnp.float32]
+    buffers = [m for m in made if m[0] == "gather" and m[1] == (rows, D)]
+    loops = [q for q in eqns if q.primitive.name == "while"]
+    if held is None:
+        assert [m[0] for m in unsorted] == ["gather"]
+        assert len(buffers) == 1 and not loops
+    else:
+        assert not unsorted and not buffers and len(loops) == 2
+        assert ("gather", (experts._SORTED_BLOCK, D), jnp.float32) in made
+
+
 def test_the_kernel_takes_rows_past_an_experts_end_in_its_stride():
     """A pass always moves ``tm`` rows: what an expert's last pass writes
     into the next experts' rows is overwritten by them, in order."""
