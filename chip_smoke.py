@@ -193,7 +193,8 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                   block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
                   experts=(16, 1024, 2688, 64, 6),
                   kda=(258, 128, 32, 128), chunk=(3, 128, 16),
-                  swa=(16, 28, 4, 128, 4096, 8192, 128)) -> dict:
+                  swa=(16, 28, 4, 128, 4096, 8192, 128),
+                  blocks=(96, 4, 32, 4, 128, 1300, 128)) -> dict:
     """Each Pallas kernel of the train and serve paths against the XLA path
     on the same seeded bf16 input, at each ``(q heads, kv heads, head size)``.
     Off the chip the kernels interpret; on it this is their first execution.
@@ -217,7 +218,14 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     past it, the table's last, a sequence's first), each against XLA's gather
     of the whole table under the same mask, all timed (``swa_decode_ms``,
     ``swa_prefill_ms``); the queries are scaled so that a few keys hold a
-    row's weight and an edge off by a block would show."""
+    row's weight and an edge off by a block would show. And a block model's
+    decode walk at ``blocks`` = (sequences, block length, q heads, kv heads, head
+    size, context, pool block; the SDAR cell's 96 blocks x 4 rows x 32 heads
+    over ~1.3K contexts): ``blk_decode`` (a block ONE row of 128 query heads,
+    its context fetched once) against XLA's gather under the block-causal
+    mask, and beside it the same rows as four single-query ``paged_decode``
+    walks (every row its block's last position: the same keys, fetched four
+    times), all three timed (``blk_decode_ms``)."""
     import jax
     import jax.numpy as jnp
 
@@ -451,7 +459,45 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                 times.append((time.perf_counter() - t1) * 1e3)
             swa_ms[kernel][name] = round(sorted(times)[2], 3)
 
+    seqs, b, hq, hkv, d, context, blk_size = blocks
+    mb = -(-(context + b) // blk_size)
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 5), 4))
+    pool_shape = (seqs * mb + 1, blk_size, hkv * d)
+    pools = (jax.random.normal(next(keys), pool_shape, jnp.bfloat16),
+             jax.random.normal(next(keys), pool_shape, jnp.bfloat16))
+    table = jnp.asarray(1 + np.random.default_rng(SEED).permutation(
+        seqs * mb).reshape(seqs, mb), jnp.int32)
+    # blocks at p0 around ``context``, multiples of the block length
+    p0 = jnp.asarray(np.linspace(context // 2, context, seqs), jnp.int32) // b * b
+    rows = (jnp.repeat(jnp.arange(seqs, dtype=jnp.int32), b),
+            (p0[:, None] + jnp.arange(b)).reshape(-1))
+    q = (3.0 * jax.random.normal(next(keys), (seqs * b, hq, d), jnp.float32)
+         ).astype(jnp.bfloat16)
+    forms = {
+        "pallas": jax.jit(lambda q, sl, po: ops.paged_attention(
+            q, *pools, sl, po, table, impl="pallas", block=b)),
+        # the same keys a row, as one query each: its block's last position
+        "four_walks": jax.jit(lambda q, sl, po: ops.paged_attention(
+            q, *pools, sl, po | (b - 1), table, impl="pallas")),
+        "xla": jax.jit(lambda q, sl, po: jax.lax.map(
+            lambda a: ops.paged_attention(*a[:1], *pools, *a[1:], table,
+                                          impl="xla", block=b),
+            tuple(x.reshape(-1, 4 * b, *x.shape[1:]) for x in (q, sl, po))
+        ).reshape(q.shape))}
+    outs = {name: fn(q, *rows) for name, fn in forms.items()}
+    close("blk_decode", outs["pallas"], outs["xla"])
+    close("blk_decode_as_four_walks", outs["four_walks"], outs["xla"])
+    blk_ms = {}
+    for name, fn in forms.items():
+        times = []
+        for _ in range(5):
+            t1 = time.perf_counter()
+            jax.block_until_ready(fn(q, *rows))
+            times.append((time.perf_counter() - t1) * 1e3)
+        blk_ms[name] = round(sorted(times)[2], 3)
+
     return _emit("kernels", seconds=round(time.perf_counter() - t0, 2),
+                 blk_decode_ms=blk_ms,
                  geometries=[list(g) for g in geometries],
                  shapes={"seq_len": seq_len, "tile": tile, "block": block},
                  max_rel_err=worst, kda_decode_ms=kda_ms,

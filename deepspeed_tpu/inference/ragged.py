@@ -85,6 +85,30 @@ _BESIDE_BLOCKS = {
 }
 
 
+# why a model that generates by blocks cannot take what follows (each names
+# the piece that is missing)
+_BY_BLOCKS = {
+    "enable_prefix_cache": "a published prefix would have to end on a "
+    "committed block and be spliced under the block-causal mask; no test "
+    "shows that right yet",
+    "kv_tier": "the tiers hold demoted prefix blocks, and the prefix cache "
+    "is refused",
+    "KVHandoff": "a hand-off would have to carry the block a slot is "
+    "denoising (its tokens and what of it is masked) beside the pool's "
+    "blocks, and the record has no field for it",
+    "quant": "a denoise pass rewrites its block's rows every pass, and a "
+    "quantized pool under that is not tested",
+    "temperature > 0": "the block step picks by argmax on the device; "
+    "sampling a block's positions from per-position keys is not implemented",
+    "device_state=False": "the host-staged step does not run blocks: the "
+    "block a slot denoises lives in the device's slot rows",
+    "prefill_tile": "a prompt's whole blocks are cached by tiles under the "
+    "block-causal mask, and a tile has to be whole blocks",
+    "block_size": "a pool block has to be whole diffusion blocks, so that no "
+    "block of rows straddles two of them",
+}
+
+
 class BlockedAllocator:
     """Ref-counted free-list allocator over the KV block pool
     (reference ``inference/v2/ragged/blocked_allocator.py``, grown the
@@ -446,6 +470,16 @@ class _SeqState:
     win_cap: int = 0
     win_reserved: int = 0
     blocks_at_end: tuple[int, int] = (0, 0)
+    # a model that generates by blocks (``ModelSpec.block_gen``): the block
+    # length (0: a token a step); of the block at ``pos``, the positions
+    # still masked when its next pass starts and the positions that were
+    # known before it started (the prompt's remainder: not generated, so
+    # not handed on); the denoise and commit passes scheduled so far. The
+    # host's mirror of what the device does to the slot's block, pass by pass
+    blk: int = 0
+    blk_masked: int = 0
+    blk_known: int = 0
+    blk_passes: list[int] = field(default_factory=lambda: [0, 0])
 
     def token_at(self, p: int) -> int:
         if p < len(self.prompt):
@@ -459,14 +493,19 @@ class _SeqState:
 
     @property
     def prefill_end(self) -> int:
-        """The first position that runs as a decode row."""
-        return max(len(self.prompt), self.replay)
+        """The first position that runs as a decode row (by blocks: the
+        prompt's whole blocks are prefilled, its remainder opens the first
+        block)."""
+        whole = len(self.prompt) - (len(self.prompt) % self.blk
+                                    if self.blk else 0)
+        return max(whole, self.replay)
 
     @property
     def emits_at_prompt_end(self) -> bool:
         """The row of the prompt's last token picks the first generated
-        token, unless a replay is passing it: that token is known."""
-        return not self.generated
+        token, unless a replay is passing it: that token is known. (By
+        blocks the logits are unshifted: no prompt row picks anything.)"""
+        return not self.generated and not self.blk
 
     @property
     def in_decode(self) -> bool:
@@ -684,6 +723,13 @@ class RaggedInferenceEngine:
                              "but says no ModelSpec.sliding_window")
         if self._window:
             self._refuse_beside_blocks("sliding")
+        # a model that generates by blocks (``ModelSpec.block_gen``): a
+        # decoding sequence's step is a block of rows, the block lives in the
+        # device's slot rows, and what that cannot carry refuses here
+        self._blk = self.spec.block_gen
+        self._rows_per_decode = 1 if self._blk is None else self._blk.length
+        if self._blk is not None:
+            self._refuse_for_blocks()
         # a family whose attention reads a selection of the context only
         # (``ModelSpec.index_topk`` rows a query): counted beside the context
         # on ``engine/dispatch``
@@ -793,18 +839,17 @@ class RaggedInferenceEngine:
         # by the dispatch programs themselves (picked token / advanced
         # position), so a steady decode dispatch reads everything per-row
         # from device memory instead of re-packed host arrays.
-        s1 = self.cfg.max_seqs + 1
-        self._dev_state = (
-            jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
-            jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
-            jnp.zeros(s1, jnp.float32), jnp.zeros(s1, jnp.int32),
-            jnp.ones(s1, jnp.float32),
-        )
-        def ragged_slot_rows(st, row, iv, fv):
+        # A model that generates by blocks adds the block a slot is
+        # denoising: its tokens ``[S + 1, B]`` and which of them are still
+        # masked, beside ``next_position`` = the block's first position.
+        self._dev_state = self._fresh_dev_state()
+
+        def ragged_slot_rows(st, row, iv, fv, *blk):
             return (st[0].at[row].set(iv[0]), st[1].at[row].set(iv[1]),
                     st[2].at[row].set(iv[2]), st[3].at[row].set(iv[3]),
                     st[4].at[row].set(fv[0]), st[5].at[row].set(iv[4]),
-                    st[6].at[row].set(fv[1]))
+                    st[6].at[row].set(fv[1]),
+                    *(a.at[row].set(v) for a, v in zip(st[7:], blk)))
 
         self._slot_row_jit = jax.jit(ragged_slot_rows, donate_argnums=(0,))
         # device-resident block table: host self.block_tables stays ground
@@ -963,6 +1008,8 @@ class RaggedInferenceEngine:
             raise ValueError("empty prompt")
         if handoff:
             self._refuse_block_transfer("KVHandoff")
+        if self._blk is not None and temperature > 0.0:
+            self._refuse_by_blocks("temperature > 0")
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         # a prefill-stage (handoff) request runs prompt + ONE token here;
@@ -1016,6 +1063,7 @@ class RaggedInferenceEngine:
             handoff=bool(handoff), handoff_budget=int(max_new_tokens),
             expected_cached=max(0, int(expected_cached_tokens)),
             tenant=str(tenant), sla_class=str(sla_class),
+            blk=self._blk.length if self._blk is not None else 0,
         )
         cm = self.telemetry.costmeter
         if cm is not None:
@@ -1202,6 +1250,53 @@ class RaggedInferenceEngine:
         for kind, on in (("slot", self._slot_state), ("sliding", self._window)):
             if on:
                 self._refuse(kind, what, _BESIDE_BLOCKS[kind][2])
+        if self._blk is not None:
+            self._refuse_by_blocks(what)
+
+    def _refuse_by_blocks(self, what: str, shown: str | None = None) -> None:
+        raise ValueError(
+            f"model {self.spec.name} generates by blocks of "
+            f"{self._blk.length}; {shown or what} is refused: "
+            f"{_BY_BLOCKS[what]}")
+
+    def _refuse_for_blocks(self) -> None:
+        """What a model that generates by blocks cannot be served with,
+        refused at construction, each by name."""
+        cfg, b = self.cfg, self._blk.length
+        for on, what, shown in (
+                (cfg.kv_tier, "kv_tier", None),
+                (cfg.enable_prefix_cache, "enable_prefix_cache", None),
+                (self._kvq is not None, "quant", f"quant={cfg.quant!r}"),
+                (not cfg.device_state, "device_state=False", None),
+                (not cfg.prefill_tile or cfg.prefill_tile % b, "prefill_tile",
+                 f"prefill_tile={cfg.prefill_tile}"),
+                (cfg.block_size % b, "block_size",
+                 f"block_size={cfg.block_size}")):
+            if on:
+                self._refuse_by_blocks(what, shown)
+        if self._slot_state or self._window:
+            raise NotImplementedError(
+                f"model {self.spec.name}: generation by blocks beside slot "
+                "state or sliding leaves is not implemented")
+        if b * self.spec.decode_bucket_min > cfg.max_tokens_per_step:
+            raise ValueError(
+                f"max_tokens_per_step={cfg.max_tokens_per_step} holds no "
+                f"decode bucket of {self.spec.decode_bucket_min} blocks of "
+                f"{b} rows")
+
+    def _fresh_dev_state(self):
+        """The device's slot rows, all empty (``_init``'s comment)."""
+        s1 = self.cfg.max_seqs + 1
+        state = (
+            jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
+            jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
+            jnp.zeros(s1, jnp.float32), jnp.zeros(s1, jnp.int32),
+            jnp.ones(s1, jnp.float32),
+        )
+        if self._blk is not None:
+            state += (jnp.zeros((s1, self._blk.length), jnp.int32),
+                      jnp.ones((s1, self._blk.length), bool))
+        return state
 
     def _blocks(self):
         """The cache's block leaves (``[L, NB, ...]`` each): what every
@@ -2081,6 +2176,8 @@ class RaggedInferenceEngine:
             decode_latency_s=decode_latency,
             prompt_tokens=len(seq.prompt), new_tokens=n_gen,
             preemptions=seq.preemptions, **cost_attrs,
+            **({"blk_denoise_passes": seq.blk_passes[0],
+                "blk_commit_passes": seq.blk_passes[1]} if seq.blk else {}),
             **({"full_blocks": seq.blocks_at_end[0],
                 "window_blocks": seq.blocks_at_end[1]} if self._window else {}))
         if seq.status == "cancelled":
@@ -2221,13 +2318,30 @@ class RaggedInferenceEngine:
         ``pos`` is already past the prompt and the host-known token at that
         position seeds the device feed instead."""
         feed = max(seq.pos, seq.replay)  # where the next decode row feeds
-        tok = seq.token_at(feed) if feed >= len(seq.prompt) else 0
+        # (by blocks nothing is fed from ``tok``: ``_first_block``)
+        tok = seq.token_at(feed) \
+            if feed >= len(seq.prompt) and not seq.blk else 0
         iv = np.asarray([tok, seq.pos, seq.seed, len(seq.prompt), seq.top_k],
                         np.int32)
         fv = np.asarray([seq.temperature, seq.top_p], np.float32)
-        self.h2d_bytes += iv.nbytes + fv.nbytes + 4
+        blk = self._first_block(seq) if self._blk is not None else ()
+        self.h2d_bytes += iv.nbytes + fv.nbytes + 4 + sum(
+            a.nbytes for a in blk)
         self._dev_state = self._slot_row_jit(
-            self._dev_state, np.int32(seq.slot), iv, fv)
+            self._dev_state, np.int32(seq.slot), iv, fv, *blk)
+
+    def _first_block(self, seq: _SeqState):
+        """The block a sequence's decoding starts with, at ``prefill_end``:
+        the tokens the host knows from there (the prompt's remainder; after a
+        recovery, nothing more: a block is handed on whole) and the mask
+        over the rest, as the slot's rows ``(tokens [B], masked [B])``; sets
+        the host's mirror of it."""
+        b, p0 = self._blk.length, seq.prefill_end
+        known = seq.tokens_at(
+            p0, min(len(seq.prompt) + len(seq.generated), p0 + b))
+        seq.blk_known, seq.blk_masked = len(known), b - len(known)
+        return (np.asarray(known + [0] * seq.blk_masked, np.int32),
+                np.arange(b) >= len(known))
 
     def _sync_bt(self) -> None:
         """Delta-upload block-table rows dirtied since the last dispatch
@@ -2356,6 +2470,8 @@ class RaggedInferenceEngine:
     def _build_dev_step(self, t: int, nd: int, nt: int, w: int, sampled: bool,
                         has_tk: bool, has_tp: bool):
         """The jitted program of ``_get_dev_step``'s key, newly made."""
+        if self._blk is not None:
+            return self._build_blk_step(t, nd, nt, w)
         fwd = self.spec.ragged_forward_fn
         if self._counters:
             fwd = partial(fwd, row_counts=True)
@@ -2419,6 +2535,89 @@ class RaggedInferenceEngine:
         step_fn.__name__ = self._step_program_name(t, nd, nt)
         return jax.jit(step_fn, donate_argnums=(1, 2))
 
+    def _build_blk_step(self, t: int, nd: int, nt: int, w: int):
+        """The device step program of a model that generates by blocks
+        (``ModelSpec.block_gen``): ``nd`` counts SEQUENCES, each a block of
+        ``B`` rows at the head of the step (``B x nd`` decode rows, then
+        ``nt`` tiles). A block's tokens come from the slot's rows (a position
+        still masked is fed as the mask token), its positions are ``p0 ..
+        p0 + B - 1`` with ``p0`` the slot's ``next_position``. After the
+        forward the program itself moves every block one pass on: where
+        anything is masked it takes ``argmax`` of the UNSHIFTED logits at the
+        masked positions and unmasks ``B / T`` of them (the leftmost, or
+        those whose pick is most probable: ``remask``); a block that came in
+        with nothing masked was the commit pass, whose rows the forward has
+        just written to the pool: its ``p0`` moves on by ``B`` and the slot
+        holds a new block, all masked. The readback carries every block's
+        tokens as they stand after the pass, a row each (the host takes them
+        when a block's last denoise pass reconciles; it knows which pass
+        that is from the schedule and reads nothing else). Prefill rows move
+        their slot's position on and pick nothing."""
+        fwd = self.spec.ragged_forward_fn
+        blk = self._blk
+        b, unmask, mask_id = blk.length, blk.unmask, blk.mask_token_id
+        by_confidence = blk.remask == "low_confidence_static"
+        ct, max_seqs = self.cfg.prefill_tile, self.cfg.max_seqs
+        ntl, rows = max(nt, 1), b * nd
+
+        def step_fn(params, cache, state, bt_full, staged, root):
+            del root  # greedy: sampling a block is refused at ``put``
+            *rest, blk_tok, blk_mask = state
+            pos_st = rest[1]
+            tokens = staged[0:t]
+            slots = staged[t:2 * t]
+            positions = staged[2 * t:3 * t]
+            pre = slots != max_seqs        # real rows ...
+            if nd:
+                pre = pre.at[:rows].set(False)   # ... of the tiles
+                dsl = slots[:rows:b]                                # [nd]
+                held, masked = blk_tok[dsl], blk_mask[dsl]          # [nd, B]
+                tokens = tokens.at[:rows].set(
+                    jnp.where(masked, mask_id, held).reshape(-1))
+                positions = positions.at[:rows].set(
+                    (pos_st[dsl][:, None] + jnp.arange(b)).reshape(-1))
+            bt = bt_full[:, :w] if w < bt_full.shape[1] else bt_full
+            ts = staged[4 * t:4 * t + ntl]
+            tp_ = staged[4 * t + ntl:4 * t + 2 * ntl]
+            tv = staged[4 * t + 2 * ntl:4 * t + 3 * ntl]
+            logits, cache = fwd(params, tokens, slots, positions, bt, cache,
+                                prefill_tiles=(rows, ts, tp_, tv, ct))
+            picked = jnp.zeros(t, jnp.int32)
+            if nd:
+                lg = logits[:rows].astype(jnp.float32)
+                x0 = jnp.argmax(lg, axis=-1).astype(jnp.int32).reshape(nd, b)
+                if by_confidence:
+                    # -log softmax(logits)[x0]: the smaller, the surer
+                    doubt = jax.nn.logsumexp(
+                        lg - jnp.max(lg, axis=-1, keepdims=True),
+                        axis=-1).reshape(nd, b)
+                    j, k = jnp.arange(b)[:, None], jnp.arange(b)[None, :]
+                    ahead = masked[:, None, :] & (
+                        (doubt[:, None, :] < doubt[:, :, None])
+                        | ((doubt[:, None, :] == doubt[:, :, None]) & (k < j)))
+                    rank = jnp.sum(ahead, axis=-1, dtype=jnp.int32)
+                else:
+                    rank = jnp.cumsum(masked, axis=1, dtype=jnp.int32) - 1
+                take = masked & (rank < unmask)
+                held = jnp.where(take, x0, held)
+                live = dsl != max_seqs
+                commit = live & ~jnp.any(masked, axis=1)
+                left = (masked & ~take) | commit[:, None]
+                # a padding block is the scratch slot's: it stays as it is
+                blk_tok = blk_tok.at[dsl].set(
+                    jnp.where(live[:, None], held, blk_tok[dsl]))
+                blk_mask = blk_mask.at[dsl].set(
+                    jnp.where(live[:, None], left, blk_mask[dsl]))
+                pos_st = pos_st.at[dsl].add(jnp.where(commit, b, 0))
+                picked = picked.at[:rows].set(held.reshape(-1))
+            sl_p = jnp.where(pre, slots, max_seqs)
+            pos_st = pos_st.at[sl_p].max(jnp.where(pre, positions + 1, 0))
+            state = (rest[0], pos_st, *rest[2:], blk_tok, blk_mask)
+            return picked, state, cache
+
+        step_fn.__name__ = self._step_program_name(t, nd, nt)
+        return jax.jit(step_fn, donate_argnums=(1, 2))
+
     def _step_zoo(self) -> list[tuple]:
         """``(t, nd, nt, w)`` of every greedy device step program the
         scheduler can pick with prefill tiles on: a function of the engine's
@@ -2426,15 +2625,17 @@ class RaggedInferenceEngine:
         ``_plan_prefill_tiles`` can return, each table width)."""
         cfg = self.cfg
         ct, budget = cfg.prefill_tile, cfg.max_tokens_per_step
+        per = self._rows_per_decode   # rows a decoding sequence takes
         zoo = []
         for nd in [0] + self._dec_buckets:
-            cap = (budget - nd) // ct
+            cap = (budget - nd * per) // ct
             tiles, p = {cap}, 1
             while p < cap:
                 tiles.add(p)
                 p *= 2
             for nt in sorted(tiles - {0}) + ([0] if nd else []):
-                zoo += [(nd + nt * ct, nd, nt, w) for w in self._width_ladder()]
+                zoo += [(nd * per + nt * ct, nd, nt, w)
+                        for w in self._width_ladder()]
         return zoo
 
     def _precompile_zoo_in_background(self) -> None:
@@ -2529,7 +2730,9 @@ class RaggedInferenceEngine:
         if plan is None:
             return False
         (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec, kv_pre,
-         pairs_pre, n_dec, n_pre, sel) = plan
+         pairs_pre, n_dec, n_pre, sel, blk) = plan
+        # a decode row is one query over its context; a block of rows is B
+        pairs_dec = kv_dec * self._rows_per_decode
         sampled = any(s.temperature > 0.0 for _, s in emit)
         has_tk = sampled and any(s.top_k > 0 for _, s in emit)
         has_tp = sampled and any(s.top_p < 1.0 for _, s in emit)
@@ -2553,10 +2756,11 @@ class RaggedInferenceEngine:
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
                   tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
-                  attn_pairs=kv_dec + pairs_pre, dec_kv_tokens=kv_dec,
+                  attn_pairs=pairs_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total), **state, **sel,
                   **self._sel_decode_attr(width), **self._counts_attr(),
-                  **self._window_attr(), **self._tiles_attr(nt)):
+                  **self._window_attr(), **self._tiles_attr(nt),
+                  **self._block_attr(blk)):
             picked, self._dev_state, self.cache = fn(
                 self.params, self.cache, self._dev_state, self._tables_dev(),
                 staged, self._sample_root)
@@ -2583,6 +2787,22 @@ class RaggedInferenceEngine:
         participants: dict[int, _SeqState] = {}
         for _, seq in emit:
             participants[seq.slot] = seq
+        if blk is not None:
+            # a pass that hands nothing on still writes its sequence's blocks
+            for seq in blk["seqs"]:
+                participants[seq.slot] = seq
+            if self.telemetry.enabled:
+                passes = self.telemetry.counter(
+                    "inference_block_passes_total",
+                    "passes of a block of rows through the model, by what "
+                    "the pass was (denoise: unmasked part of the block; "
+                    "commit: wrote the finished block's K and V)")
+                passes.inc(len(blk["seqs"]) - blk["commit"], phase="denoise")
+                passes.inc(blk["commit"], phase="commit")
+                self.telemetry.counter(
+                    "inference_block_tokens_unmasked_total",
+                    "positions the denoise passes unmasked").inc(
+                        blk["unmasked"])
         for seq in participants.values():
             seq.refs += 1
         self._pending.append({"picked": picked, "emit": emit,
@@ -2592,6 +2812,23 @@ class RaggedInferenceEngine:
             self._trace_spans(t0, time.perf_counter(), tpairs,
                               mode="dev_step")
         return True
+
+    def _block_attr(self, blk: dict | None) -> dict:
+        """For a model that generates by blocks, what the step packed last
+        did by blocks: ``blk_seqs``, the sequences that ran a block through
+        the model; ``blk_commit_seqs``, those of them in their commit pass;
+        ``blk_unmasked``, the positions the step unmasks (the host knows it
+        from the schedule); ``blk_len`` and ``blk_steps``, the block length
+        and the denoise passes a block. The span's other arguments keep their
+        meaning: ``tokens`` rows computed, ``kv_tokens`` / ``dec_kv_tokens``
+        the context read once a sequence and pass (``p0 + B``),
+        ``attn_pairs`` query x key pairs (``B`` times that a block). Nothing
+        for a model with none."""
+        if blk is None:
+            return {}
+        return {"blk_seqs": len(blk["seqs"]), "blk_commit_seqs": blk["commit"],
+                "blk_unmasked": blk["unmasked"],
+                "blk_len": self._blk.length, "blk_steps": self._blk.steps}
 
     def _moe_attr(self, t: int) -> dict:
         """``{"moe": "grouped" | "dense"}``: the form the model's routed
@@ -2705,7 +2942,10 @@ class RaggedInferenceEngine:
         flags = np.zeros(size, np.int32)
         emit: list[tuple[int, _SeqState]] = []
         max_pos = 0
-        dec_cap = min(budget, cfg.max_seqs) if ct else budget
+        per = self._rows_per_decode
+        blk = {"seqs": [], "commit": 0, "unmasked": 0} \
+            if self._blk is not None else None
+        dec_cap = min(budget // per, cfg.max_seqs) if ct else budget
         n_dec = 0
         for seq in list(self._running.values()):
             if seq.finished or not seq.in_decode or n_dec >= dec_cap:
@@ -2716,6 +2956,24 @@ class RaggedInferenceEngine:
             lim = len(seq.prompt) + seq.max_new_tokens
             if seq.pos >= lim:
                 continue  # fully scheduled; retires as pending reconciles
+            if blk is not None:
+                # a block of ``per`` rows at seq.pos .. seq.pos + per - 1; the
+                # last block a request needs is denoised and never committed
+                if not seq.blk_masked and seq.pos + per >= lim:
+                    continue
+                if not self._ensure_capacity(seq, seq.pos + per):
+                    seq.preemptions += 1
+                    self.preemptions += 1
+                    continue
+                slots[n_dec * per:(n_dec + 1) * per] = seq.slot
+                flags[n_dec * per:(n_dec + 1) * per] = 1   # fed by the device
+                if trace_on:
+                    tpairs.append((seq, "engine/decode", per))
+                max_pos = max(max_pos, seq.pos + per - 1)
+                kv_dec += seq.pos + per
+                self._schedule_block_pass(seq, n_dec * per, emit, blk)
+                n_dec += 1
+                continue
             if not self._ensure_capacity(seq, seq.pos + 1):
                 seq.preemptions += 1
                 self.preemptions += 1
@@ -2742,13 +3000,13 @@ class RaggedInferenceEngine:
         if ct:
             nd = 0 if n_dec == 0 else next(b for b in self._dec_buckets
                                            if b >= n_dec)
-            chunks, nt = self._plan_prefill_tiles(nd, budget)
+            chunks, nt = self._plan_prefill_tiles(nd * per, budget)
             ts = np.full(max(nt, 1), cfg.max_seqs, np.int32)
             tpz = np.zeros(max(nt, 1), np.int32)
             tv = np.zeros(max(nt, 1), np.int32)
             sched = 0
             for seq, tile0, take in chunks:
-                start = nd + tile0 * ct
+                start = nd * per + tile0 * ct
                 sl = slice(start, start + take)
                 tokens[sl] = seq.tokens_at(seq.pos, seq.pos + take)
                 slots[sl] = seq.slot
@@ -2759,7 +3017,8 @@ class RaggedInferenceEngine:
                     tpz[tile0 + ti] = seq.pos + ti * ct
                     tv[tile0 + ti] = min(ct, take - ti * ct)
                 max_pos = max(max_pos, seq.pos + take - 1)
-                pairs_pre += take * seq.pos + take * (take + 1) // 2
+                # a query sees its position's keys; by blocks, its block's
+                pairs_pre += take * seq.pos + take * (take + per) // 2
                 win_pairs += _kept_pairs(seq.pos, take, window)
                 if topk:
                     sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
@@ -2775,8 +3034,8 @@ class RaggedInferenceEngine:
                 if seq.pos == len(seq.prompt) and seq.emits_at_prompt_end:
                     flags[start + take - 1] |= 2
                     emit.append((start + take - 1, seq))
-            n = n_dec + sched
-            t_total = nd + nt * ct
+            n = n_dec * per + sched
+            t_total = nd * per + nt * ct
         else:
             nd = nt = 0
             n = n_dec
@@ -2820,7 +3079,34 @@ class RaggedInferenceEngine:
         if ct:
             parts += [ts, tpz, tv]
         return (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec,
-                kv_pre, pairs_pre, n_dec, len(chunks) if ct else 0, sel)
+                kv_pre, pairs_pre, n_dec, len(chunks) if ct else 0, sel, blk)
+
+    def _schedule_block_pass(self, seq: _SeqState, row0: int, emit: list,
+                             blk: dict) -> None:
+        """The host's mirror of what the step program does to ``seq``'s
+        block in the pass being packed (``_build_blk_step``): with a static
+        schedule the host knows, without reading anything back, how many
+        positions the pass unmasks, whether it is the block's last denoise
+        pass (its readback then carries the finished block: the rows of the
+        positions that were not known before are the step's emit rows) and
+        whether it is the commit (``pos`` moves on, the slot holds a new
+        block, all masked)."""
+        per = self._blk.length
+        blk["seqs"].append(seq)
+        if seq.cost is not None:
+            seq.cost.decode_dispatches += 1
+        if seq.blk_masked:
+            n = min(self._blk.unmask, seq.blk_masked)
+            seq.blk_masked -= n
+            seq.blk_passes[0] += 1
+            blk["unmasked"] += n
+            if not seq.blk_masked:
+                emit.extend((row0 + i, seq) for i in range(seq.blk_known, per))
+        else:
+            seq.blk_passes[1] += 1
+            blk["commit"] += 1
+            seq.pos += per
+            seq.blk_masked, seq.blk_known = per, 0
 
     def _reconcile_pending(self) -> dict:
         """Read back the OLDEST pending dispatch's tokens and fold them
@@ -2990,7 +3276,8 @@ class RaggedInferenceEngine:
         if seq.finished:
             return  # the row of a step dispatched past the sequence's end
         if seq.cost is not None:
-            seq.cost.decode_dispatches += 1
+            # by blocks a pass is charged where it is scheduled
+            seq.cost.decode_dispatches += not seq.blk
             seq.cost.decode_tokens += 1
         seq.generated.append(tok)
         out[seq.uid] = tok
@@ -3307,6 +3594,14 @@ class RaggedInferenceEngine:
             else:
                 # mid-prefill: re-prefill the uncached tail (idempotent)
                 seq.pos = seq.cached_prefix
+            if self._blk is not None:
+                # a block in flight is dropped and denoised again (the same
+                # result at temperature 0): everything the host holds, whole
+                # blocks of it, is run again as prefill under the block's
+                # mask, and the block after it starts over
+                known = len(seq.prompt) + g
+                seq.replay = known - known % seq.blk if g else 0
+                seq.pos = 0
             if self._slot_state or self._window:
                 # re-running a position rewrites identical K and V but would
                 # move a recurrent state a second time, and there is no
@@ -3346,6 +3641,12 @@ class RaggedInferenceEngine:
         if not cfg.degrade_after or self._consec_failures < cfg.degrade_after:
             return False
         reason = f"{type(exc).__name__}: {exc}"
+        if self._blk is not None:
+            # the host-staged step does not run blocks (``_BY_BLOCKS``)
+            log_dist(f"ragged watchdog: model {self.spec.name} generates by "
+                     "blocks, which only the device step runs; not degrading "
+                     f"({reason})", ranks=[0])
+            return False
         if cfg.device_state:
             cfg.device_state = False
             self.degraded_mode = 1
@@ -3501,13 +3802,7 @@ class RaggedInferenceEngine:
             self._win_reserved = 0
         self._free_slots = list(range(self.cfg.max_seqs - 1, -1, -1))
         self._reserved = 0
-        s1 = self.cfg.max_seqs + 1
-        self._dev_state = (
-            jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
-            jnp.zeros(s1, jnp.int32), jnp.zeros(s1, jnp.int32),
-            jnp.zeros(s1, jnp.float32), jnp.zeros(s1, jnp.int32),
-            jnp.ones(s1, jnp.float32),
-        )
+        self._dev_state = self._fresh_dev_state()
         self._block_tenant.clear()  # fresh allocator: stale block ids
         self._cost_last_tick = 0.0
         self.cache = self._build_cache()

@@ -325,6 +325,45 @@ class ShardCtx:
         )
 
 
+REMASK_RULES = ("sequential", "low_confidence_static")
+
+
+@dataclass(frozen=True)
+class BlockGen:
+    """``ModelSpec.block_gen``: ``length`` positions a block, ``steps``
+    denoise passes a block (each unmasks ``length / steps`` of the positions
+    still masked), ``remask`` the rule that chooses them (``"sequential"``:
+    the leftmost; ``"low_confidence_static"``: those whose pick has the
+    highest probability), ``mask_token_id`` what a masked position is
+    embedded as. A rule whose number of passes depends on the data
+    (``low_confidence_dynamic``: a threshold) is not built: the engine
+    schedules a sequence's passes ahead of any readback."""
+
+    length: int
+    steps: int
+    remask: str
+    mask_token_id: int
+
+    def __post_init__(self):
+        if self.remask not in REMASK_RULES:
+            raise NotImplementedError(
+                f"block generation: remask rule {self.remask!r} is not "
+                f"implemented (have {REMASK_RULES}): a threshold rule unmasks "
+                "a data-dependent number of positions a pass, and the engine "
+                "schedules a block's passes before it reads any of them back")
+        if self.length < 1 or self.length & (self.length - 1):
+            raise ValueError(f"block length {self.length} is no power of two "
+                             "(the mask is pos | (length - 1))")
+        if self.steps < 1 or self.length % self.steps:
+            raise ValueError(f"{self.steps} denoise passes do not divide a "
+                             f"block of {self.length}")
+
+    @property
+    def unmask(self) -> int:
+        """Positions a denoise pass unmasks."""
+        return self.length // self.steps
+
+
 @dataclass
 class ModelSpec:
     """Everything the engine needs to train/evaluate a model."""
@@ -407,6 +446,18 @@ class ModelSpec:
     # rows on ``engine/dispatch`` (``win_kv_tokens``) and refuses what a
     # prefix of blocks cannot restore; None: every layer reads every row
     sliding_window: int | None = None
+    # a family that generates by diffusion over blocks (``sdar``) says how:
+    # a decoding sequence's step is then a BLOCK of ``length`` rows that see
+    # each other (``models/paged.py``, *Blocks of rows*), passed through the
+    # model ``steps`` times (each pass unmasks ``length / steps`` positions on
+    # the device, by ``remask``'s rule) and once more to commit its K and V;
+    # the logits are unshifted (row ``i`` scores the token AT ``i``). The
+    # engine keeps the block a slot on the device, schedules a sequence's
+    # passes without a readback, hands a block's tokens on when its last
+    # denoise pass reconciles, and refuses what it cannot carry
+    # (docs/SERVING.md "Generation by blocks"); None: one token a sequence
+    # and step, through a causal mask
+    block_gen: "BlockGen | None" = None
     # 1F1B pipeline decomposition (parallel/pipeline_1f1b.py): the tuple
     # (stage0_fn, block_fn, last_fn, split_fn, merge_fn) itself
     pipeline_parts: Any = None

@@ -244,12 +244,19 @@ def init_paged_cache(cfg: MixtralConfig, num_blocks: int, block_size: int,
 
 
 def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
-                  block_tables, prefill_tiles=None, stacks=None):
+                  block_tables, prefill_tiles=None, stacks=None,
+                  qk_norm: bool = False, block: int | None = None):
     """One decoder layer over a flat ragged token batch [T, D]: paged
     attention identical to the Llama ragged layer, MoE FFN routed per token
     (decode tokens route through the SAME per-token top-k machinery as
     prefill-chunk tokens — MoE over a paged cache is a routing problem only
-    in the FFN, which is position-free)."""
+    in the FFN, which is position-free).
+
+    ``cfg`` is any config with this layer's fields (``sdar`` shares the
+    layer). ``qk_norm`` (static): an RMSNorm over each head's ``q`` and ``k``
+    (gains ``lp["q_norm"]`` / ``lp["k_norm"]`` ``[head_dim]``) before the
+    rotation. ``block`` (static): the block-causal mask of a model that
+    generates by blocks (``models/paged.py``, *Blocks of rows*)."""
     from deepspeed_tpu.models.paged import (
         ragged_pool_attention,
         write_kv_paged,
@@ -264,12 +271,16 @@ def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
     q = (h @ lp["wq"]).reshape(t_tokens, hq, hd)
     kk = (h @ lp["wk"]).reshape(t_tokens, hkv, hd)
     vv = (h @ lp["wv"]).reshape(t_tokens, hkv, hd)
+    if qk_norm:
+        q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
+        kk = rmsnorm(kk, lp["k_norm"], cfg.rms_norm_eps)
     q, kk = apply_rope(q[None], kk[None], positions[None], cfg.rope_theta)
     q, kk = q[0], kk[0]
 
     kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables)
+    more = {} if block is None else {"block": block}
     o = ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
-                              prefill_tiles).astype(x.dtype)
+                              prefill_tiles, **more).astype(x.dtype)
     x = x + o.reshape(t_tokens, hq * hd) @ lp["wo"]
 
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)

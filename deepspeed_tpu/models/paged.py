@@ -106,6 +106,20 @@ v1-style engines.
   cannot restore refuses here too: ``enable_prefix_cache``, ``kv_tier``,
   ``KVHandoff`` (a prefix's sliding blocks are gone by the time it could
   be shared) and a quantized pool raise at construction.
+- *Blocks of rows.* A model that generates by diffusion over blocks
+  (``sdar``: ``ModelSpec.block_gen``, ``B`` positions denoised together)
+  changes no leaf and no addressing, only WHO SEES WHOM: a row at position
+  ``i`` attends over keys ``j <= (i | (B - 1))``, every row of its own block
+  and of the blocks before it (``ragged_pool_attention(block=B)``; the mask is
+  stated here and in ``ops/pallas/paged_attention.py``). A step's decode
+  region is then whole blocks, ``B`` consecutive rows a sequence at ``p0 ..
+  p0 + B - 1`` with ``p0`` a multiple of ``B``: the step scatters the block's
+  K and V as they are in this pass (a position still masked holds the mask
+  token's) before its attention reads them, so a denoise pass uses the pool's
+  rows ``p0 .. p0 + B - 1`` as its workspace and the COMMIT pass, the block
+  finished, leaves there what later blocks read. A pool block and a prefill
+  tile are multiples of ``B``, so a prompt's whole blocks are cached by tiles
+  under the same mask and no block of rows straddles a pool block.
 """
 
 from __future__ import annotations
@@ -455,22 +469,27 @@ def _decode_then_tiles(q, slots, positions, prefill_tiles, decode, prefill):
 
 
 def ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
-                          prefill_tiles=None, window: int | None = None):
+                          prefill_tiles=None, window: int | None = None,
+                          block: int | None = None):
     """Attention over the blocked pool for a flat ragged token batch:
     per-token paged kernel for the decode region, the tiled SplitFuse
     kernel for tile-aligned prefill chunks; with a ``window`` (static) both
-    over keys ``pos - window < j <= pos``."""
+    over keys ``pos - window < j <= pos``; with a ``block`` (static, ``B``:
+    a model that generates by blocks, module doc *Blocks of rows*) both over
+    keys ``j <= (pos | (B - 1))``, the decode region whole blocks of ``B``
+    rows a sequence."""
     from deepspeed_tpu.ops.attention import (
         paged_attention,
         ragged_prefill_attention,
     )
 
+    more = {} if block is None else {"block": block}
     return _decode_then_tiles(
         q, slots, positions, prefill_tiles,
         lambda q, sl, po: paged_attention(q, kc, vc, sl, po, block_tables,
-                                          window=window),
+                                          window=window, **more),
         lambda q, ts, tp, tv, ct: ragged_prefill_attention(
-            q, kc, vc, ts, tp, tv, block_tables, ct, window=window))
+            q, kc, vc, ts, tp, tv, block_tables, ct, window=window, **more))
 
 
 def latent_pool_attention(q, pool, slots, positions, block_tables, lat: int,

@@ -173,7 +173,7 @@ def attention(
 
 def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
                     scale: float | None = None, impl: str = "auto",
-                    window: int | None = None):
+                    window: int | None = None, block: int | None = None):
     """Ragged paged-KV attention: [T, Hq, D] tokens over the blocked pool
     ``[blocks, BS, Hkv*D]`` (reference ``inference/v2/kernels/ragged_ops``
     blocked flash attention).
@@ -190,6 +190,12 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     ``window`` (static): a sliding-window layer's rows attend over keys
     ``pos - window < j <= pos`` only, in either form.
 
+    ``block`` (static, ``B``): a model that generates by blocks; the rows are
+    whole decoding blocks, ``B`` consecutive rows a sequence at positions
+    ``p0 .. p0 + B - 1`` (``p0`` a multiple of ``B``), and each attends over
+    keys ``j <= (pos | (B - 1))``, its block whole: the kernel takes a block
+    as one row (``blk_decode``), the XLA form moves the mask.
+
     A quantized pool (``inference/kvquant.QuantizedKV``) always takes the
     XLA path: the gather+dequant fuse into one program there (the fp
     context is a per-dispatch transient). A Pallas kernel that streams
@@ -205,10 +211,18 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
             paged_decode_attention,
         )
 
-        return paged_decode_attention(q, k_pool, v_pool, slots, positions,
-                                      block_tables, scale=scale, window=window)
+        if block is None:
+            return paged_decode_attention(q, k_pool, v_pool, slots, positions,
+                                          block_tables, scale=scale,
+                                          window=window)
+        return paged_decode_attention(
+            q.reshape((-1, block) + q.shape[1:]), k_pool, v_pool,
+            slots[::block], positions[::block], block_tables, scale=scale,
+            window=window, block=block).reshape(q.shape)
     if impl != "xla":
         raise ValueError(f"unknown paged attention impl {impl!r}")
+    if block is not None:
+        positions = positions | (block - 1)   # the mask's edge, below
     t_tokens, hq, d = q.shape
     hkv = k_pool.shape[-1] // d
     tables = block_tables[slots]                       # [T, MB]
@@ -251,7 +265,8 @@ def prefill_step_keys(k_pool) -> int | None:
 def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
                              scale: float | None = None, impl: str = "auto",
-                             window: int | None = None):
+                             window: int | None = None,
+                             block: int | None = None):
     """Tiled prefill attention over the blocked pool: ``q`` holds tile-aligned
     prefill tokens (one sequence per CT-token tile, consecutive positions,
     rows past ``tile_valid`` padding). The Pallas kernel fetches each KV block
@@ -269,15 +284,17 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
             ragged_prefill_attention as _pallas_prefill,
         )
 
+        more = {} if block is None else {"block": block}
         return _pallas_prefill(q, k_pool, v_pool, tile_slot, tile_pos0,
                                tile_valid, block_tables, tile, scale=scale,
-                               window=window)
+                               window=window, **more)
     if impl != "xla":
         raise ValueError(f"unknown prefill attention impl {impl!r}")
     slots, positions = _tile_rows(q.shape[0], tile_slot, tile_pos0, tile_valid,
                                   tile, block_tables.shape[0] - 1)
     return paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
-                           scale=scale, impl="xla", window=window)
+                           scale=scale, impl="xla", window=window,
+                           block=block)
 
 
 def _tile_rows(n_rows: int, tile_slot, tile_pos0, tile_valid, tile: int,

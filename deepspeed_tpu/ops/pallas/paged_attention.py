@@ -74,6 +74,18 @@ window`` inside the first. Both kernels then go by another name (``swa_decode``,
 ``swa_prefill``), so a trace tells a model's window layers from its full
 ones. The decode kernel under ``window=None`` traces what it always did.
 
+A model that generates by blocks (``block``: ``B`` positions denoised
+together, ``ModelSpec.block_gen``) attends block-causally: a query at ``i``
+sees keys ``j <= (i | (B - 1))``, its own block whole. A tile and a pool block
+are multiples of ``B``, so the tile kernel's grid is the causal one and only
+its mask's query position moves to its block's last. A decoding block's ``B``
+queries all read the same context, ``p0 + B`` keys with no mask among them,
+so the decode kernel takes the block as ONE row of ``B x Hq`` query heads at
+position ``p0 + B - 1``: a sequence's chunks are fetched once a pass, not
+once a query, in as many grid steps as one row of that context takes. Both
+then go by another name again (``blk_decode``, ``blk_prefill``); ``block=None``
+traces what it always did.
+
 Inference-only (no VJP): the ragged engine never differentiates through
 decode.
 """
@@ -198,10 +210,21 @@ def _decode_kernel(row_ref, chunk_ref, slots_ref, pos_ref, bt_ref, q_ref,
             ).astype(o_ref.dtype)
 
 
+def _kernel_name(base: str, window: int | None, block: int | None) -> str:
+    """The name a kernel goes by in a trace: ``paged_decode`` /
+    ``tiled_prefill``, ``swa_*`` under a window, ``blk_*`` under a block."""
+    if window is not None and block is not None:
+        raise NotImplementedError("a window and a block in one layer")
+    if block is not None:
+        return "blk_" + base.split("_")[-1]
+    return base if window is None else "swa_" + base.split("_")[-1]
+
+
 def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
                            scale: float | None = None,
                            interpret: bool | None = None,
-                           window: int | None = None):
+                           window: int | None = None,
+                           block: int | None = None):
     """[T, Hq, D] ragged tokens -> [T, Hq, D] attention outputs.
 
     ``k_pool``/``v_pool``: [blocks, BS, Hkv*D]; ``block_tables``:
@@ -210,20 +233,43 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
     ``0 .. pos // BS`` of its sequence, once, whatever ``MB`` is; with a
     ``window`` (static) the blocks that hold positions ``pos - window + 1 ..
     pos``, and no table entry before them need name a block of the row's.
+
+    With a ``block`` (static, ``B``) a row is a decoding BLOCK: ``q`` is
+    ``[rows, B, Hq, D]``, ``positions`` the blocks' first positions ``p0``
+    (multiples of ``B``), and every query of a row reads keys ``0 .. p0 + B -
+    1``, the block's own among them, unmasked: the row rides through the
+    kernel as ``B x Hq`` query heads at position ``p0 + B - 1``, so its
+    context is fetched once. -> ``[rows, B, Hq, D]``.
     """
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
-    return _paged_decode(
-        q, k_pool, v_pool, slots.astype(jnp.int32),
-        positions.astype(jnp.int32), block_tables.astype(jnp.int32),
+    if block is None:
+        return _paged_decode(
+            q, k_pool, v_pool, slots.astype(jnp.int32),
+            positions.astype(jnp.int32), block_tables.astype(jnp.int32),
+            scale=float(scale), interpret=interpret_mode(interpret),
+            window=window)
+    rows, b, hq, d = q.shape
+    hkv = k_pool.shape[-1] // d
+    # head (g, r) of query b becomes head (g, b * rep + r) of the row: a KV
+    # head's group is the B queries' groups side by side
+    as_heads = q.reshape(rows, b, hkv, hq // hkv, d).transpose(
+        0, 2, 1, 3, 4).reshape(rows, b * hq, d)
+    out = _paged_decode(
+        as_heads, k_pool, v_pool, slots.astype(jnp.int32),
+        positions.astype(jnp.int32) + (b - 1), block_tables.astype(jnp.int32),
         scale=float(scale), interpret=interpret_mode(interpret),
-        window=window)
+        window=window, name=_kernel_name("paged_decode", window, block))
+    return out.reshape(rows, hkv, b, hq // hkv, d).transpose(
+        0, 2, 1, 3, 4).reshape(rows, b, hq, d)
 
 
 # ONE jitted function: the step programs of one row count (d4_t0 .. d4_t3)
 # share its trace
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window",
+                                             "name"))
 def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
-                  scale: float, interpret: bool, window: int | None = None):
+                  scale: float, interpret: bool, window: int | None = None,
+                  name: str | None = None):
     t_tokens, hq, d = q.shape
     _, bs, hd = k_pool.shape
     hkv = hd // d
@@ -288,7 +334,7 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
         out_shape=jax.ShapeDtypeStruct((t_tokens, rep, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        name="paged_decode" if window is None else "swa_decode",
+        name=name or _kernel_name("paged_decode", window, None),
     )(step_row, step_chunk, slots, positions, block_tables, q_wide,
       *([k_pool] * nb), *([v_pool] * nb))
     return out.reshape(t_tokens, rep, hkv, d).transpose(0, 2, 1, 3).reshape(
@@ -333,7 +379,8 @@ def prefill_step_blocks(bs: int, lanes: int, itemsize: int) -> int:
 
 def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, *refs, bs: int,
                     nb: int, ct: int, hkv: int, rep: int, d: int, strip: int,
-                    scale: float, window: int | None = None):
+                    scale: float, window: int | None = None,
+                    block: int | None = None):
     k_refs, v_refs = refs[:nb], refs[nb:2 * nb]
     o_ref, acc, m_sc, l_sc = refs[2 * nb:]
     c = pl.program_id(0)   # query tile
@@ -369,6 +416,9 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, *refs, bs: int,
         # runs of ct rows, so ONE mask a step serves every strip and head; a
         # clamped repeat's keys lie past every real query's position.
         tok = jax.lax.broadcasted_iota(jnp.int32, (strip, ch), 0) % ct
+        if block is not None:
+            # a query sees its whole block (pos0 is a multiple of it)
+            tok = tok | (block - 1)
         ahead = (pos0 - k_lo + tok
                  - jax.lax.broadcasted_iota(jnp.int32, (strip, ch), 1))
         seen = ahead >= 0                                   # kpos <= qpos
@@ -458,7 +508,8 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
                              tile_valid, block_tables, tile: int,
                              scale: float | None = None,
                              interpret: bool | None = None,
-                             window: int | None = None):
+                             window: int | None = None,
+                             block: int | None = None):
     """Tiled prefill attention: [NT*CT, Hq, D] tile-aligned prefill tokens ->
     outputs, one KV-block DMA shared by the whole CT-token tile (the
     SplitFuse blocked flash attention, reference
@@ -479,6 +530,10 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
     With a ``window`` (static) the grid's second axis is not the table's
     width but the blocks a window and a tile can span, from the tile's first
     needed block; no entry before it need name a block of the sequence's.
+
+    With a ``block`` (static, ``B``; a divisor of the tile and of a pool
+    block, every ``tile_pos0`` and ``tile_valid`` a multiple of it) the mask
+    is block-causal: a query at ``i`` sees keys ``j <= (i | (B - 1))``.
     """
     _, hq, d = q.shape
     _, bs, hd = k_pool.shape
@@ -489,16 +544,17 @@ def ragged_prefill_attention(q, k_pool, v_pool, tile_slot, tile_pos0,
         tile_pos0.astype(jnp.int32), tile_valid.astype(jnp.int32),
         block_tables.astype(jnp.int32), tile=tile, ct=ct, nb=nb,
         scale=float(scale if scale is not None else 1.0 / (d ** 0.5)),
-        interpret=interpret_mode(interpret), window=window)
+        interpret=interpret_mode(interpret), window=window, block=block)
 
 
 # ONE jitted function: a period's window layers, and the step programs of one
 # tile count (d4_t3 .. d16_t3), share its trace
 @functools.partial(jax.jit, static_argnames=(
-    "tile", "ct", "nb", "scale", "interpret", "window"))
+    "tile", "ct", "nb", "scale", "interpret", "window", "block"))
 def _tiled_prefill(q, k_pool, v_pool, tile_slot, tile_pos0, tile_valid,
                    block_tables, *, tile: int, ct: int, nb: int, scale: float,
-                   interpret: bool, window: int | None):
+                   interpret: bool, window: int | None,
+                   block: int | None = None):
     t_tokens, hq, d = q.shape
     _, bs, hd = k_pool.shape
     hkv = hd // d
@@ -548,7 +604,8 @@ def _tiled_prefill(q, k_pool, v_pool, tile_slot, tile_pos0, tile_valid,
     )
     kernel = functools.partial(
         _prefill_kernel, bs=bs, nb=nb, ct=ct, hkv=hkv, rep=rep, d=d,
-        strip=_strip_rows(hkv, rep, ct), scale=scale, window=window)
+        strip=_strip_rows(hkv, rep, ct), scale=scale, window=window,
+        **({} if block is None else {"block": block}))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n_tiles, hkv, rep * ct, d), q.dtype),
@@ -556,7 +613,7 @@ def _tiled_prefill(q, k_pool, v_pool, tile_slot, tile_pos0, tile_valid,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_PREFILL_VMEM_BYTES),
         interpret=interpret,
-        name="tiled_prefill" if window is None else "swa_prefill",
+        name=_kernel_name("tiled_prefill", window, block),
     )(tile_slot, tile_pos0, tile_valid, block_tables, q_groups,
       *([k_pool] * nb), *([v_pool] * nb))
     return out.reshape(n_tiles, hkv, rep, ct, d).transpose(

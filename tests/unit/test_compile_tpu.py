@@ -157,7 +157,9 @@ def _dsa_args(kernel, devices):
 MOE_GEOMETRIES = {"mixtral": (8, 2, 4096, 14336, 512, 128),
                   "moonlight": (64, 6, 2048, 1408, 512, 64),
                   # 128 held of 512, ungated relu**2 in the 1,024-wide latent
-                  "nemotron": (128, 22, 1024, 2688, 512, 64)}
+                  "nemotron": (128, 22, 1024, 2688, 512, 64),
+                  # every one of 128 small experts whole on the chip (PR 47)
+                  "sdar": (128, 8, 2048, 768, 512, 64)}
 UNGATED = {"nemotron"}
 
 
@@ -1498,4 +1500,93 @@ def test_window_step_holds_no_layer_slice_of_either_pool(v5e, monkeypatch):
     names = set(re.findall(r"%(\w+?)\.\d+ = [^\n]*tpu_custom_call", text))
     assert {"paged_decode", "swa_decode", "tiled_prefill",
             "swa_prefill"} <= names
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+
+
+# ------------------------------------- a model that generates by blocks
+# (PR 47) the SDAR cell's attention: 32 query heads over 4 KV heads of 128,
+# blocks of 4 rows, 96 decoding sequences beside a 128-row tile, a table of 16
+# 128-token blocks, a pool of 1,537
+BLK_HEADS, BLK_KV, BLK_D, BLK_LEN, BLK_TABLE, BLK_POOL = 32, 4, 128, 4, 16, 1537
+
+
+@pytest.mark.parametrize("kernel", ["blk_decode", "blk_prefill"])
+def test_block_kernels_compile_at_the_cells_shapes(v5e, kernel):
+    """The two paged kernels with ``block``: a decoding block as ONE row of 4
+    x 32 = 128 query heads in the ``[Hq, Hkv*D]`` form (a [128, 512] query, a
+    float32 accumulator of as much, 32 output rows a KV group), a whole
+    128-row tile under the block-causal mask; the instruction goes by the
+    blocks' name, which ``benchmark/kernels/<name>.json`` finds and the
+    causal kernels' patterns do not."""
+    import json
+    import os
+
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    pool = s((BLK_POOL, 128, BLK_KV * BLK_D))
+    bt = s((97, BLK_TABLE), jnp.int32)
+    if kernel == "blk_decode":
+        rows = s((96,), jnp.int32)
+        fn = lambda *a: paged_decode_attention(  # noqa: E731
+            *a, interpret=False, block=BLK_LEN)
+        args = (s((96, BLK_LEN, BLK_HEADS, BLK_D)), pool, pool, rows, rows, bt)
+    else:
+        tiles = s((1,), jnp.int32)
+        fn = lambda *a: ragged_prefill_attention(  # noqa: E731
+            *a, TILE, interpret=False, block=BLK_LEN)
+        args = (s((TILE, BLK_HEADS, BLK_D)), pool, pool, tiles, tiles, tiles,
+                bt)
+    compiled = jax.jit(fn).lower(*args).compile()
+    calls = [ln.strip() for ln in compiled.as_text().splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    kernels = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                           "benchmark", "kernels")
+    found = []
+    for f in os.listdir(kernels):
+        with open(os.path.join(kernels, f)) as fh:
+            if re.search(json.load(fh)["trace_pattern"], calls[0]):
+                found.append(f[:-5])
+    assert sorted(found) == sorted([kernel, "pallas_custom_call"])
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**24
+
+
+def test_block_step_holds_no_layer_slice_of_the_pool(v5e, monkeypatch):
+    """The paged contract under blocks of rows: a step of 8 decoding blocks
+    (32 rows) beside a tile writes the pool's rows by two in-place scatters
+    and reads it through the table; nothing else is as large as a layer's
+    slice, the q/k norm and the 128-head query form included, and both
+    block kernels are in it under their names (no causal one is)."""
+    from deepspeed_tpu.models import sdar
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = sdar.SdarConfig(
+        vocab_size=512, hidden_size=256, moe_intermediate_size=128,
+        num_layers=3, num_heads=BLK_HEADS, num_kv_heads=BLK_KV,
+        head_dim=BLK_D, num_experts=8, top_k=2, max_seq_len=2048,
+        mask_token_id=511)
+    on_chip, i32, params, cache = _abstract_step(v5e, sdar, cfg, BLK_POOL, 128)
+    assert cache["k"].shape == (3, BLK_POOL, 128, 512)
+    layer_slice = BLK_POOL * 128 * 512 * 2
+    rows, t = 8 * BLK_LEN, 8 * BLK_LEN + TILE
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return sdar.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache), i32(t), i32(t), i32(t),
+        i32(97, BLK_TABLE), i32(1), i32(1), i32(1)).compile()
+    text = compiled.as_text()
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= layer_slice]
+    assert len([ln for _, op, ln in big if op == "scatter"]) == 2
+    assert [ln for _, op, ln in big if op not in ("scatter", "kernel")] == []
+    names = set(re.findall(r"%(\w+?)\.\d+ = [^\n]*tpu_custom_call", text))
+    assert {"blk_decode", "blk_prefill"} <= names
+    assert not {"paged_decode", "tiled_prefill"} & names
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice

@@ -357,7 +357,19 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "kernel.swa_prefill_share",
                          "kernel.swa_prefill_roofline",
                          "model.swa_step_roofline_kv",
-                         "sched.window_held_share")}}
+                         "sched.window_held_share")},
+                     # PR 47: the block kernels, the step against K and V once
+                     # a sequence and pass, and the schedule's passes read a
+                     # slice of the cell whose spans carry ``blk_seqs`` and
+                     # the rest
+                     **{m: "v5e_sdar_blockgen_spans" for m in (
+                         "kernel.blk_decode_share",
+                         "kernel.blk_decode_roofline",
+                         "kernel.blk_prefill_share",
+                         "kernel.blk_prefill_roofline",
+                         "model.blk_step_roofline_kv",
+                         "sched.blk_passes_per_token",
+                         "sched.blk_commit_share")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -370,7 +382,8 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "v5e_kimi_linear_reason_spans":
                    "kimi-linear-48b-a3b-d13-ep8.reason-pool",
                    "v5e_smallthinker_mixedlen_spans":
-                   "smallthinker-21b-a3b-ep8.mixedlen-pool"}
+                   "smallthinker-21b-a3b-ep8.mixedlen-pool",
+                   "v5e_sdar_blockgen_spans": "sdar-30b-a3b-d7.blockgen-pool"}
 
 
 def _new_readers():
@@ -382,7 +395,7 @@ def _new_readers():
         with open(path) as f:
             text = f.read()
         if not any(m in text for m in ("host_spans", "latent_spans", "ssm_spans",
-                                       "dsa_spans", "swa_spans")):
+                                       "dsa_spans", "swa_spans", "blk_spans")):
             continue  # a reader from before the spans
         fixture = FIXTURE_OF_METRIC.get(m["name"])
         cell = (CELL_OF_FIXTURE[fixture] if fixture
