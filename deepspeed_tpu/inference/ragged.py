@@ -45,6 +45,7 @@ from deepspeed_tpu.models.paged import (
     slot_leaves,
     sliding_blocks_per_seq,
     sliding_leaves,
+    tiles_go_as_slices,
 )
 from deepspeed_tpu.ops.attention import prefill_step_keys
 from deepspeed_tpu.serving.faults import (
@@ -765,6 +766,9 @@ class RaggedInferenceEngine:
         self._win_reserved = 0
         self.window_blocks_slid = 0
         self._win_step = (0, 0, 0)
+        # the rows of the step packed last that the pool's write site takes
+        # as slices (``_note_pool_rows``)
+        self._pool_slice_rows = 0
         # ---- hierarchical KV tiering (inference/kvtier.py) ----
         # tier store + allocator demote hook; None with kv_tier off, and
         # the allocator's eviction path is then bit-identical to before
@@ -2723,7 +2727,8 @@ class RaggedInferenceEngine:
         a window (``ModelSpec.sliding_window``) adds what its window layers
         read and what the two pools hold (``_window_attr``); a program with
         tiles over a K/V pool says the keys a grid step of its tile kernel
-        takes (``_tiles_attr``)."""
+        takes (``_tiles_attr``). ``pool_slice_rows`` is the part of ``tokens``
+        the pool's write site takes as slices (``_note_pool_rows``)."""
         t0 = time.perf_counter()
         with span("engine/schedule"):
             plan = self._pack_step(host_feed=False)
@@ -2755,7 +2760,9 @@ class RaggedInferenceEngine:
         state = self._state_attr(n_dec, n_pre, n, nt)
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
-                  tokens=n, pad=t_total - n, kv_tokens=kv_dec + kv_pre,
+                  tokens=n, pad=t_total - n,
+                  pool_slice_rows=self._pool_slice_rows,
+                  kv_tokens=kv_dec + kv_pre,
                   attn_pairs=pairs_dec + pairs_pre, dec_kv_tokens=kv_dec,
                   **self._moe_attr(t_total), **state, **sel,
                   **self._sel_decode_attr(width), **self._counts_attr(),
@@ -2928,7 +2935,7 @@ class RaggedInferenceEngine:
         budget = cfg.max_tokens_per_step
         trace_on = self._tracer.enabled
         tpairs = [] if trace_on else None
-        kv_dec = kv_pre = pairs_pre = 0
+        kv_dec = kv_pre = pairs_pre = sched = 0
         win_dec = win_pre = win_pairs = 0   # the same, cut to the window
         window = self._window
         topk = self._topk
@@ -3004,7 +3011,6 @@ class RaggedInferenceEngine:
             ts = np.full(max(nt, 1), cfg.max_seqs, np.int32)
             tpz = np.zeros(max(nt, 1), np.int32)
             tv = np.zeros(max(nt, 1), np.int32)
-            sched = 0
             for seq, tile0, take in chunks:
                 start = nd * per + tile0 * ct
                 sl = slice(start, start + take)
@@ -3073,6 +3079,7 @@ class RaggedInferenceEngine:
             return None
         self.tokens_scheduled += n
         self.tokens_padded += t_total - n
+        self._note_pool_rows(n, sched)
         self._win_step = (win_dec + win_pre, win_dec, win_dec + win_pairs)
         parts = [tokens[:t_total], slots[:t_total], positions[:t_total],
                  flags[:t_total]]
@@ -3080,6 +3087,25 @@ class RaggedInferenceEngine:
             parts += [ts, tpz, tv]
         return (parts, emit, tpairs, t_total, n, nd, nt, max_pos, kv_dec,
                 kv_pre, pairs_pre, n_dec, len(chunks) if ct else 0, sel, blk)
+
+    def _note_pool_rows(self, n: int, tile_rows: int) -> None:
+        """Of the ``n`` real rows of the step being packed, ``tile_rows`` lie
+        in prefill tiles: the pool's write site takes those as slices where a
+        tile is whole runs of a block's rows (``models/paged.py``,
+        ``tiles_go_as_slices``: the rule it goes by itself; a quantized pool
+        keeps single rows) and every other row as a single row. Kept for the
+        dispatch span (``pool_slice_rows``) and counted."""
+        sliced = tile_rows if self._kvq is None and tiles_go_as_slices(
+            self.cfg.prefill_tile, self.cfg.block_size) else 0
+        self._pool_slice_rows = sliced
+        if self.telemetry.enabled:
+            written = self.telemetry.counter(
+                "inference_pool_rows_written_total",
+                "rows the steps wrote into the paged pool, by the form the "
+                "write site took them in (slice: a prefill tile's rows, whole "
+                "blocks at a time; row: a scatter of single rows)")
+            written.inc(sliced, form="slice")
+            written.inc(n - sliced, form="row")
 
     def _schedule_block_pass(self, seq: _SeqState, row0: int, emit: list,
                              blk: dict) -> None:

@@ -460,7 +460,7 @@ def _pool_attention(cfg: DeepseekConfig, h, lp, pool, positions, slots,
     kv = write_rows_paged(
         pool["kv"],
         jnp.concatenate([c, k_rope, jnp.zeros((t_tokens, pad), c.dtype)], -1),
-        slots, positions, block_tables)
+        slots, positions, block_tables, prefill_tiles)
     wk, wv = _wkv_b(cfg, lp)
     q = jnp.concatenate(
         [jnp.einsum("thn,lhn->thl", q_nope, wk), q_rope,

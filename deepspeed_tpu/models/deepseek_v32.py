@@ -376,7 +376,7 @@ def ragged_forward(cfg: DeepseekV32Config, params, tokens, slots, positions,
         def attend(h, cq, lp, q, pool):
             q_idx, k_idx, w_idx = _indexer_inputs(cfg, h, cq, lp, positions)
             idx = write_rows_paged(pool["idx"], k_idx, slots, positions,
-                                   layer_tables)
+                                   layer_tables, prefill_tiles)
             o_lat = sparse_pool_attention(
                 cfg, q, q_idx, w_idx, pool["kv"], idx, slots, positions,
                 layer_tables, prefill_tiles)

@@ -228,7 +228,8 @@ def _ragged_block(cfg: GPT2Config, x, lp, kc, vc, positions, slots,
     q = (h @ lp["wq"] + lp["bq"]).reshape(t_tokens, cfg.num_heads, cfg.hd)
     kk = (h @ lp["wk"] + lp["bk"]).reshape(t_tokens, cfg.num_heads, cfg.hd)
     vv = (h @ lp["wv"] + lp["bv"]).reshape(t_tokens, cfg.num_heads, cfg.hd)
-    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables)
+    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables,
+                            prefill_tiles)
     o = ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
                               prefill_tiles).astype(x.dtype)
     x = x + o.reshape(t_tokens, d) @ lp["wo"] + lp["bo"]

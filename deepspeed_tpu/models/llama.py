@@ -303,7 +303,8 @@ def _ragged_layer(cfg: LlamaConfig, x, lp, kc, vc, positions, slots,
     q, kk = apply_rope(q[None], kk[None], positions[None], cfg.rope_theta)
     q, kk = q[0], kk[0]
 
-    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables)
+    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables,
+                            prefill_tiles)
     o = ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
                               prefill_tiles).astype(x.dtype)
     x = x + o.reshape(t_tokens, hq * hd) @ lp["wo"]

@@ -277,7 +277,8 @@ def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
     q, kk = apply_rope(q[None], kk[None], positions[None], cfg.rope_theta)
     q, kk = q[0], kk[0]
 
-    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables)
+    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables,
+                            prefill_tiles)
     more = {} if block is None else {"block": block}
     o = ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
                               prefill_tiles, **more).astype(x.dtype)

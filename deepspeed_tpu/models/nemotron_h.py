@@ -498,7 +498,7 @@ def _attn_ragged(cfg: NemotronHConfig, x, lp, pool, layer_tables, slots,
     kk = (h @ lp["wk"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
     vv = (h @ lp["wv"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
     kc, vc = write_kv_paged(pool["k"], pool["v"], kk, vv, slots, positions,
-                            layer_tables)
+                            layer_tables, prefill_tiles)
     o = ragged_pool_attention(q, kc, vc, slots, positions, layer_tables,
                               prefill_tiles).astype(x.dtype)
     return x + o.reshape(t, -1) @ lp["wo"], {**pool, "k": kc, "v": vc}

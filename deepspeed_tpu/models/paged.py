@@ -4,7 +4,7 @@ One home for what every family's serving path shares (gpt2, llama, mixtral
 on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
 nemotron_h and kimi_linear with a recurrent state a slot beside either;
 smallthinker with sliding-window layers in a second pool): the
-paged-pool scatter, the decode/tiled-prefill attention split over the block
+paged-pool write, the decode/tiled-prefill attention split over the block
 pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
 one or several kinds of layer, and the dense-cache append+attend used by the
 v1-style engines.
@@ -29,9 +29,11 @@ v1-style engines.
   splice, preemption and containment all read the leaves (``a.shape[0]``
   rows a token), never a config's ``num_layers``.
 - *Who may index what.* A step program touches the block axis only through
-  a block table: ``write_kv_paged`` scatters the step's ``T`` rows in
-  place, the XLA gather and the two Pallas kernels read the blocks the
-  table names. Host-side code between steps (``_gather_blocks``,
+  a block table: ``write_kv_paged`` writes the step's ``T`` rows in place
+  (a prefill tile's rows as slices of the one or two blocks a run of ``BS``
+  rows lies in, decode rows as one scatter of single rows:
+  ``write_rows_paged``), the XLA gather and the two Pallas kernels read the
+  blocks the table names. Host-side code between steps (``_gather_blocks``,
   ``_scatter_blocks``, tiers, prefix cache, ``KVHandoff``) indexes blocks
   as ``a[:, ids]`` on ``[L, NB, ...]``.
 - A step program must never hold an array the size of a layer's slice of
@@ -124,8 +126,11 @@ v1-style engines.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
@@ -425,30 +430,121 @@ def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
     return x, out
 
 
-def write_rows_paged(pool, rows, slots, positions, block_tables):
-    """Scatter each ragged token's new cache row into (block, offset) of its
+def tiles_go_as_slices(tile: int, block_size: int) -> bool:
+    """Whether ``write_rows_paged`` writes a step's prefill tiles of ``tile``
+    rows into a pool of ``block_size``-row blocks as slices (the rule it goes
+    by itself; the engine counts a step's rows by it): a tile must be whole
+    runs of ``block_size`` rows."""
+    return tile > 0 and tile % block_size == 0
+
+
+def write_rows_paged(pool, rows, slots, positions, block_tables,
+                     prefill_tiles=None):
+    """Write each ragged token's new cache row into (block, offset) of its
     sequence's pool blocks, in place. ``rows``: [T, ...] (flattened to the
     pool's lanes); ``pool``: [blocks, BS, lanes].
+
+    A run of the step's rows that lies consecutively inside pool blocks goes
+    in as slices; only rows that are alone go row by row (a row scatter
+    walks its indices one after another, each a read-modify-write of the
+    row's whole sublane tile: ~140 ns a row on a v5e where a 1 KB row is
+    1.3 ns of HBM time, PERF.md section 6, PR 48). The layer hands over what
+    it holds next to its attention, ``prefill_tiles`` = ``(n_dec, tile_slot,
+    tile_pos0, tile_valid, tile)``:
+
+    - ``rows[n_dec:]`` are tiles of ``tile`` consecutive positions of one
+      sequence each. A tile is ``tile / BS`` runs of ``BS`` rows, and a run
+      lies in at most two blocks of its sequence: it is rolled by its start's
+      offset in the block and merged into both under row masks, ``where(row
+      is the run's and < tile_valid, new, old)``, so rows at or past
+      ``tile_valid`` leave the pool's real blocks as the row form leaves
+      them, which drops them into the scratch block. At offset 0 the second
+      block's mask is empty. One algorithm on an offset it reads
+      (``tile_pos0 % BS``): no branch, no second program. A ``tile`` that
+      ``BS`` does not divide keeps the row form (``tiles_go_as_slices``).
+    - ``rows[:n_dec]``, and every row with no ``prefill_tiles``: one scatter
+      of single rows, ``pool.at[blk, off].set(rows)`` (a step with no row
+      past ``n_dec`` traces that scatter and nothing else). So are the decode
+      rows of a model that generates by blocks (module doc *Blocks of
+      rows*): a scatter whose update is a run's ``[B, lanes]`` the compiler
+      turns into a loop of one update an index, 4 us each (380 us where the
+      single rows take 53, PR 48).
+
+    Whatever the form, the pool after the write holds the same bits in every
+    block but the scratch block (``tests/unit/test_paged_pool_write.py``),
+    and the step program holds no other operation of the pool's shape.
 
     This is the ONE write site of the paged contract, so it is also the
     ONE quantize site: a low-bit pool (``inference/kvquant.QuantizedKV``)
     quantizes each token row at write time — per-row scales keep the
-    incremental scatter exact (rewriting a row never re-rounds another).
+    incremental scatter exact (rewriting a row never re-rounds another). A
+    quantized pool keeps the row form for every row (``scatter_rows``; no
+    benchmark cell runs one).
     """
     bs = pool.shape[1]
     blk = block_tables[slots, positions // bs]  # [T]
     off = positions % bs
     if getattr(pool, "is_quantized_kv", False):
         return pool.scatter_rows(blk, off, rows)
-    return pool.at[blk, off].set(
-        rows.reshape(rows.shape[0], -1).astype(pool.dtype))
+    rows = rows.reshape(rows.shape[0], -1).astype(pool.dtype)
+    n_dec = rows.shape[0]
+    if (prefill_tiles is not None and prefill_tiles[0] < n_dec
+            and tiles_go_as_slices(prefill_tiles[-1], bs)):
+        n_dec, tile_slot, tile_pos0, tile_valid, tile = prefill_tiles
+        pool = _write_tile_runs(pool, rows[n_dec:], tile_slot, tile_pos0,
+                                tile_valid, tile, block_tables)
+    if not n_dec:
+        return pool
+    return pool.at[blk[:n_dec], off[:n_dec]].set(rows[:n_dec])
 
 
-def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables):
+@partial(jax.jit, static_argnames="tile")
+def _write_tile_runs(pool, rows, tile_slot, tile_pos0, tile_valid, tile: int,
+                     block_tables):
+    """``write_rows_paged``'s tiles: ``rows`` [nt * tile, lanes] into
+    ``pool`` [blocks, BS, lanes], a run of ``BS`` rows at a time, each one
+    read-merge-write of the two blocks it may lie in (the updates are a chain
+    on the pool, so two tiles of one sequence see each other's rows and the
+    pool is updated in place). A function of its own under ``jit``: a layer's
+    K and V, and every layer body of a step program, trace it once and lower
+    to calls of one function (inline, the window cell's 13 step programs
+    took 14 s longer to lower, PERF.md section 6, PR 48)."""
+    bs = pool.shape[1]
+    runs = np.arange(rows.shape[0] // bs)
+    t, j = runs * bs // tile, runs * bs % tile
+    pos0 = tile_pos0[t] + j                                          # [runs]
+    valid = jnp.minimum(tile_valid[t] - j, bs)
+    col, off = pos0 // bs, pos0 % bs
+    # a run's first block and the next (none of its rows there at offset 0)
+    blocks = block_tables[tile_slot[t][:, None], jnp.minimum(
+        col[:, None] + np.arange(2), block_tables.shape[1] - 1)]     # [runs, 2]
+    # the run's row that lands on block row i: i - off in the first block,
+    # i - off + BS in the next
+    src = (np.arange(bs) + np.arange(2)[:, None] * bs) - off[:, None, None]
+    mine = (src >= 0) & (src < valid[:, None, None])             # [runs, 2, BS]
+    for n in runs:
+        # row i of the image holds the run's row i - off (mod BS)
+        image = jnp.roll(rows[n * bs:(n + 1) * bs], off[n], axis=0)
+        for c in range(2):
+            # read before the write is fused: a slice fused into the update
+            # reads the donated argument beside it, and the compiler then
+            # copies the whole leaf (a layer outside the scan, PR 48)
+            held = lax.optimization_barrier(lax.dynamic_index_in_dim(
+                pool, blocks[n, c], 0, keepdims=False))
+            pool = lax.dynamic_update_index_in_dim(
+                pool, jnp.where(mine[n, c][:, None], image, held),
+                blocks[n, c], 0)
+    return pool
+
+
+def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables,
+                   prefill_tiles=None):
     """``write_rows_paged`` for a K pool and a V pool. ``kk``/``vv``:
     [T, Hkv, D]; ``kc``/``vc``: [blocks, BS, Hkv*D]."""
-    return (write_rows_paged(kc, kk, slots, positions, block_tables),
-            write_rows_paged(vc, vv, slots, positions, block_tables))
+    return (write_rows_paged(kc, kk, slots, positions, block_tables,
+                             prefill_tiles),
+            write_rows_paged(vc, vv, slots, positions, block_tables,
+                             prefill_tiles))
 
 
 def _decode_then_tiles(q, slots, positions, prefill_tiles, decode, prefill):

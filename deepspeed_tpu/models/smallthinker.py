@@ -336,7 +336,8 @@ def _ragged_layer(cfg: SmallThinkerConfig, kind: str, x, lp, kc, vc, positions,
     t_tokens = x.shape[0]
     h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
     q, kk, vv = _qkv(cfg, kind, h, lp, positions)
-    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, tables)
+    kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, tables,
+                            prefill_tiles)
     o = ragged_pool_attention(q, kc, vc, slots, positions, tables,
                               prefill_tiles, window=_window(cfg, kind)
                               ).astype(x.dtype)

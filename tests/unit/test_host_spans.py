@@ -110,8 +110,9 @@ def test_every_dispatch_writes_one_dispatch_span(served):
 
 def test_a_dispatch_span_carries_its_work(served):
     for args in served["events"]["engine/dispatch"]:
-        assert set(args) == {"program", "tokens", "pad", "kv_tokens",
-                             "attn_pairs", "dec_kv_tokens"}
+        assert set(args) == {"program", "tokens", "pad", "pool_slice_rows",
+                             "kv_tokens", "attn_pairs", "dec_kv_tokens"}
+        assert 0 <= args["pool_slice_rows"] <= args["tokens"]
         assert re.fullmatch(r"ragged_step_d\d+_t\d+", args["program"])
         assert args["tokens"] > 0 and args["pad"] >= 0
         assert args["attn_pairs"] >= args["kv_tokens"] >= args["dec_kv_tokens"]
@@ -369,7 +370,12 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "kernel.blk_prefill_roofline",
                          "model.blk_step_roofline_kv",
                          "sched.blk_passes_per_token",
-                         "sched.blk_commit_share")}}
+                         "sched.blk_commit_share")},
+                     # PR 48: the rows the pool's write site took as slices
+                     # read a slice of the window cell whose spans carry
+                     # ``pool_slice_rows``
+                     "model.pool_slice_share":
+                     "v5e_smallthinker_mixedlen_slices_spans"}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -383,7 +389,9 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "kimi-linear-48b-a3b-d13-ep8.reason-pool",
                    "v5e_smallthinker_mixedlen_spans":
                    "smallthinker-21b-a3b-ep8.mixedlen-pool",
-                   "v5e_sdar_blockgen_spans": "sdar-30b-a3b-d7.blockgen-pool"}
+                   "v5e_sdar_blockgen_spans": "sdar-30b-a3b-d7.blockgen-pool",
+                   "v5e_smallthinker_mixedlen_slices_spans":
+                   "smallthinker-21b-a3b-ep8.mixedlen-pool"}
 
 
 def _new_readers():

@@ -13,7 +13,8 @@ streams, so the same sums are taken in another order; observed differences
 are under 3e-5 on logits of magnitude ~3.
 
 ``python tests/unit/test_sdar.py <out.json>`` writes the fixture
-``fixtures/step_jaxprs_pr46.json`` from whatever tree ``PYTHONPATH`` names.
+``fixtures/step_jaxprs_pr48_smallthinker.json`` from whatever tree
+``PYTHONPATH`` names.
 """
 
 import dataclasses
@@ -31,7 +32,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
-                       "step_jaxprs_pr46.json")
+                       "step_jaxprs_pr48_smallthinker.json")
 ATOL = 5e-4
 
 
@@ -99,8 +100,8 @@ from deepspeed_tpu.ops.pallas.paged_attention import (  # noqa: E402
 
 def test_smallthinkers_step_program_is_the_parents():
     """``block=None`` / ``block_gen=None`` trace what the parent traces (the
-    other eight families: ``test_smallthinker.py``, against PR 43's and PR
-    45's fixtures, which this PR leaves as they are)."""
+    other eight families: ``test_smallthinker.py``); since PR 48 the parent
+    is that PR's tree, whose programs with tiles write them as slices."""
     with open(FIXTURE) as f:
         assert smallthinker_step_digests() == json.load(f)
 

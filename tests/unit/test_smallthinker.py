@@ -5,11 +5,11 @@ pool, whose blocks go back as the window slides) against the plain reference
 ``window`` against a dense masked softmax at the window's edges; the ranks'
 parts of an expert layer against the uncut layer; what the second allocator
 promises; the four refusals; and, for every family that was there before, the
-device step program's jaxpr against the one PR 43's tree traces
-(``fixtures/step_jaxprs_pr43.json``; ``python tests/unit/test_smallthinker.py
-<out.json> [family ...]`` writes it from whatever tree ``PYTHONPATH`` names;
-``fixtures/step_jaxprs_pr45.json`` holds the Pallas form of the four families
-that run the tile kernel PR 45 rebuilt).
+device step program's jaxpr against the one PR 48's tree traces, both forms
+(``fixtures/step_jaxprs_pr48.json``; ``python tests/unit/test_smallthinker.py
+<out.json> [family ...]`` writes it from whatever tree ``PYTHONPATH`` names.
+PR 43's and PR 45's fixtures held until the pool's write site took a step's
+tiles as slices, which every family's program with tiles shows).
 
 Logits are compared, not tokens. Tolerance 2e-4 (float32 everywhere here): the
 program runs a prompt as tiles against cached rows and the reference as one
@@ -31,9 +31,7 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
-FIXTURE = os.path.join(FIXTURES, "step_jaxprs_pr43.json")
-# gpt2, llama, mixtral, nemotron_h: their Pallas form holds the tile kernel
-FIXTURE_TILE_KERNEL = os.path.join(FIXTURES, "step_jaxprs_pr45.json")
+FIXTURE = os.path.join(FIXTURES, "step_jaxprs_pr48.json")
 ATOL = 2e-4
 
 
@@ -136,12 +134,11 @@ from deepspeed_tpu.ops.pallas.paged_attention import (  # noqa: E402
 @pytest.mark.parametrize("form", ["xla", "pallas"])
 def test_the_other_families_step_programs_are_the_parents(form):
     """``window=None`` traces what it traced, the period scan and the expert
-    forms too: every family's step program prints PR 43's jaxpr, but for
-    the tile kernel's body in the four families that run it (PR 45's)."""
+    forms too: every family's step program prints the jaxpr PR 48's tree
+    printed (PR 43's, but for the tile kernel's body PR 45 rebuilt and the
+    tiles' rows written as slices)."""
     with open(FIXTURE) as f:
         want = json.load(f)[form]
-    with open(FIXTURE_TILE_KERNEL) as f:
-        want.update(json.load(f)[form])
     got = step_jaxpr_digests(form == "pallas")
     assert got == want, sorted(k for k in want if got.get(k) != want[k])
 
