@@ -7,6 +7,7 @@ single process hosts N XLA CPU devices via
 through the CPU backend. Must be set before jax initializes its backend.
 """
 
+import faulthandler
 import os
 import sys
 
@@ -49,6 +50,13 @@ jax.config.update("jax_platforms", "cpu")
 # collective rendezvous (observed as 0%-CPU hangs deep into long sessions).
 # CPU-only knob; TPU async stepping is unaffected.
 jax.config.update("jax_cpu_enable_async_dispatch", False)
+# These tests are compile time: their shapes are tiny, and a step a test runs
+# a few times costs seconds to compile. Unoptimized CPU code compiles a third
+# faster (files PR 49 did not touch fell by 27-40% in the whole run, ROADMAP
+# D9) and every test passes with its tolerance as it was; the TPU programs
+# test_compile_tpu.py reads are byte for byte the same (the option reaches
+# XLA:CPU's pipeline only).
+jax.config.update("jax_disable_most_optimizations", True)
 
 # NO persistent compilation cache for the CPU test mesh, and none is set
 # here. This VM's CPUID advertises features the kernel doesn't enable (XLA's
@@ -125,6 +133,32 @@ def pytest_cmdline_main(config):
         if rc and ("-x" in flags or "--exitfirst" in flags):
             break
     return rc
+
+
+# One test may take this long and no longer. A wedged emulated collective
+# parks every thread in C++ (0% CPU), where no signal handler runs;
+# faulthandler's watchdog thread still does: it writes every thread's stack
+# and ends the process, xdist reports the test as failed on a crashed worker
+# and starts another. Over three times the slowest test of the slowest whole
+# run under `-n 6` (69 s; ROADMAP D9).
+TEST_LIMIT_S = 240
+_STDERR_FD = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: over a minute of CPU; the tier-1 command leaves it out")
+    # the process's own stderr: while a test runs, pytest's capture has put a
+    # temporary file on descriptor 2 (it is suspended during configure)
+    config.stash[_STDERR_FD] = os.dup(sys.__stderr__.fileno())
+
+
+@pytest.fixture(autouse=True)
+def _limit_each_test(request):
+    faulthandler.dump_traceback_later(
+        TEST_LIMIT_S, exit=True, file=request.config.stash[_STDERR_FD])
+    yield
+    faulthandler.cancel_dump_traceback_later()
 
 
 @pytest.fixture(autouse=True)

@@ -30,6 +30,7 @@ os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_p
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_cpu_enable_async_dispatch", False)
+jax.config.update("jax_disable_most_optimizations", True)  # as tests/conftest.py
 # no persistent compile cache is set here: cache-deserialized CPU collective
 # programs deadlock on this VM (see tests/conftest.py)
 import numpy as np

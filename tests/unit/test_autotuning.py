@@ -11,11 +11,16 @@ from isolation import run_isolated  # tests/unit is rootdir-inserted by pytest
 
 VOCAB = 256
 
+# one layer: a trial is an engine, an engine here is its compiles, and what
+# the tuner is asked (which trials it runs, skips and picks) does not depend
+# on the depth
 _SETUP = """
+import dataclasses
 from deepspeed_tpu.autotuning.autotuner import Autotuner
 from deepspeed_tpu.models import llama
 VOCAB = 256
-builder = lambda ctx: llama.build(llama.LlamaConfig.tiny(VOCAB), ctx=ctx)
+MODEL = dataclasses.replace(llama.LlamaConfig.tiny(VOCAB), num_layers=1)
+builder = lambda ctx: llama.build(MODEL, ctx=ctx)
 """
 
 

@@ -5,6 +5,7 @@ these see: a slice off the tiling, more scoped VMEM than a kernel may take.
 Nothing runs, so this says nothing about results or speed."""
 
 import dataclasses
+import functools
 import math
 import os
 import re
@@ -163,6 +164,7 @@ MOE_GEOMETRIES = {"mixtral": (8, 2, 4096, 14336, 512, 128),
 UNGATED = {"nemotron"}
 
 
+@functools.lru_cache(maxsize=None)     # one function a geometry: ``_compiled``
 def _moe_gmm(tm, max_rows, gated=True):
     def kernel(x, w_gate, w_up, w_down, row0, counts):
         return grouped_swiglu(x, w_gate if gated else None, w_up, w_down, row0,
@@ -181,6 +183,14 @@ def _moe_args(geometry, devices):
     rows = -(-(t * k + e * (ROW_ALIGN - 1)) // ROW_ALIGN) * ROW_ALIGN + tm
     return (s((rows, d)), s((e, d, f)), s((e, d, f)), s((e, f, d)),
             s((e,), jnp.int32), s((e,), jnp.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled(kernel, *args):
+    """``kernel`` compiled for the described chip at ``args``' shapes, once:
+    the cases that read other facts of one lowering (its calls, its memory,
+    its instructions' names) share it."""
+    return jax.jit(kernel).lower(*args).compile()
 
 
 def _args(kernel, hq, hkv, d, devices, mla_block=MLA_BLOCK):
@@ -214,7 +224,7 @@ def _args(kernel, hq, hkv, d, devices, mla_block=MLA_BLOCK):
     ids=["flash_fwd", "flash_bwd", "paged_decode", "tiled_prefill"])
 def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
     args = _args(kernel, hq, hkv, d, v5e)
-    text = jax.jit(kernel).lower(*args).compile().as_text()
+    text = _compiled(kernel, *args).as_text()
     assert text.count("tpu_custom_call") >= n_calls
     if kernel in (_flash_fwd, _flash_bwd):
         # what was lowered walks sub-blocks: its work is bounded by the
@@ -262,8 +272,7 @@ def test_mla_kernel_compiles_for_v5e(v5e, kernel, block):
     the padded context beside the kernel."""
     assert decode_step_blocks(block, MLA_WIDTH, 2, arrays=1) == (
         4 if block == MLA_BLOCK else 8)
-    compiled = jax.jit(kernel).lower(
-        *_args(kernel, 0, 0, 0, v5e, mla_block=block)).compile()
+    compiled = _compiled(kernel, *_args(kernel, 0, 0, 0, v5e, mla_block=block))
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
@@ -280,7 +289,7 @@ def test_dsa_kernel_compiles_for_v5e(v5e, kernel, calls):
     with its bias rows; the masked walk of a decode row's own blocks
     (``mla_decode`` with a selection: 128 heads' [128, 512] scores a step,
     a row of the selection resident beside three buffers of four blocks)."""
-    compiled = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile()
+    compiled = _compiled(kernel, *_dsa_args(kernel, v5e))
     assert compiled.as_text().count("tpu_custom_call") == calls
     # beside the walk its [16, 8192] selection as float32, and re-laid out
     # a chunk a sublane: 0.5 MB each
@@ -295,8 +304,8 @@ def test_moe_gmm_compiles_for_v5e(v5e, geometry):
     counts, and the VMEM the weight tiles and an expert's rows take under
     the raised limit."""
     *_, t, tm = MOE_GEOMETRIES[geometry]
-    compiled = jax.jit(_moe_gmm(tm, t, geometry not in UNGATED)).lower(
-        *_moe_args(geometry, v5e)).compile()
+    compiled = _compiled(_moe_gmm(tm, t, geometry not in UNGATED),
+                         *_moe_args(geometry, v5e))
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
@@ -434,26 +443,22 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
             patterns[f[:-5]] = re.compile(json.load(fh)["trace_pattern"])
     if kernel == "moe_gmm":
         *_, t, tm = MOE_GEOMETRIES["moonlight"]
-        text = jax.jit(_moe_gmm(tm, t)).lower(
-            *_moe_args("moonlight", v5e)).compile().as_text()
+        text = _compiled(_moe_gmm(tm, t, True),
+                         *_moe_args("moonlight", v5e)).as_text()
     elif kernel == "moe_gmm_ungated":
         *_, t, tm = MOE_GEOMETRIES["nemotron"]
-        text = jax.jit(_moe_gmm(tm, t, gated=False)).lower(
-            *_moe_args("nemotron", v5e)).compile().as_text()
+        text = _compiled(_moe_gmm(tm, t, False),
+                         *_moe_args("nemotron", v5e)).as_text()
     elif kernel == "ssm_decode":
-        text = jax.jit(_ssm_decode).lower(
-            *_ssm_args(v5e, rows=8)).compile().as_text()
+        text = _compiled(_ssm_decode, *_ssm_args(v5e, rows=8)).as_text()
     elif kernel == "kda_decode":
-        text = jax.jit(_kda_decode).lower(
-            *_kda_args(v5e, rows=8)).compile().as_text()
+        text = _compiled(_kda_decode, *_kda_args(v5e, rows=8)).as_text()
     elif kernel is _kda_chunk:
-        text = jax.jit(kernel).lower(
-            *_kda_chunk_args(v5e, tiles=2)).compile().as_text()
+        text = _compiled(kernel, *_kda_chunk_args(v5e, tiles=2)).as_text()
     elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill, _dsa_walk):
-        text = jax.jit(kernel).lower(*_dsa_args(kernel, v5e)).compile().as_text()
+        text = _compiled(kernel, *_dsa_args(kernel, v5e)).as_text()
     else:
-        text = jax.jit(kernel).lower(
-            *_args(kernel, *GEOMETRIES[0], v5e)).compile().as_text()
+        text = _compiled(kernel, *_args(kernel, *GEOMETRIES[0], v5e)).as_text()
     calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
     if name == "kda_chunk":

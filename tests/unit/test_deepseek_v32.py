@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shared import one_engine_each  # tests/unit is rootdir-inserted
 
 from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                             RaggedInferenceEngine, _kept_pairs)
@@ -82,13 +83,21 @@ def _engine(params, **over):
         seed=0, ragged_config=RaggedConfig(**{**sizes, **over}))
 
 
-def _serve_logits(params, spoil=None):
+@pytest.fixture(scope="module")
+def engine_of(params):
+    """``engine_of(**over)``: the module's ONE engine of those options, as new
+    each time it is asked for (``shared.py``): the cases that send
+    other requests, or spoil the pool, share its programs."""
+    return one_engine_each(functools.partial(_engine, params))
+
+
+def _serve_logits(eng, spoil=None):
     """Prefill a 22-token prompt in chunks of <= 8 tokens (tiles of 4) and
     decode 6 tokens; returns the served sequence and the logits row behind
     every emitted token. ``spoil(cache)`` rewrites the pool after every step."""
-    eng = _engine(params)
     rows = []
     emit = eng._emit_tokens
+    dispatched = eng.dispatch_count
 
     def record(logits, pairs):
         rows.extend(np.asarray(logits[i]) for i, _ in pairs)
@@ -97,10 +106,14 @@ def _serve_logits(params, spoil=None):
         return emit(logits, pairs)
 
     eng._emit_tokens = record
-    prompt = list(np.random.default_rng(5).integers(1, VOCAB, PROMPT_LEN))
-    eng.put("s", prompt, max_new_tokens=NEW_TOKENS)
-    out = eng.generate_all()["s"]
-    assert eng.dispatch_count >= 3 + NEW_TOKENS - 1   # 3 prefill chunks
+    try:
+        prompt = list(np.random.default_rng(5).integers(1, VOCAB, PROMPT_LEN))
+        eng.put("s", prompt, max_new_tokens=NEW_TOKENS)
+        out = eng.generate_all()["s"]
+    finally:
+        del eng._emit_tokens        # the engine is shared: the method again
+    # 3 prefill chunks
+    assert eng.dispatch_count - dispatched >= 3 + NEW_TOKENS - 1
     return prompt + out, np.stack(rows)
 
 
@@ -112,8 +125,8 @@ def _reference_rows(reference, params, seq):
     return want[PROMPT_LEN - 1:len(seq) - 1]         # row i predicts i + 1
 
 
-def test_engine_logits_match_the_reference(reference, params):
-    seq, got = _serve_logits(params)
+def test_engine_logits_match_the_reference(reference, params, engine_of):
+    seq, got = _serve_logits(engine_of())
     want = _reference_rows(reference, params, seq)
     assert got.shape == want.shape == (NEW_TOKENS, VOCAB)
     np.testing.assert_allclose(got, want, rtol=0, atol=LOGIT_ATOL)
@@ -136,8 +149,9 @@ def _bf16_latent(cache):
 @pytest.mark.parametrize("spoil", [_bf16_index, _no_index, _bf16_latent],
                          ids=["bf16_index_cache", "no_index_cache",
                               "bf16_latent_cache"])
-def test_the_tolerance_catches_a_spoiled_cache(reference, params, spoil):
-    seq, got = _serve_logits(params, spoil)
+def test_the_tolerance_catches_a_spoiled_cache(reference, params, engine_of,
+                                               spoil):
+    seq, got = _serve_logits(engine_of(), spoil)
     want = _reference_rows(reference, params, seq)
     assert np.abs(got - want).max() > 10 * LOGIT_ATOL
 
@@ -152,7 +166,7 @@ def test_the_tolerance_catches_dense_attention(reference, params, monkeypatch):
     gather = v32._gather_kept
     monkeypatch.setattr(v32, "_gather_kept", lambda mask, k, *rest: gather(
         mask, mask.shape[1], *rest))
-    seq, got = _serve_logits(params)
+    seq, got = _serve_logits(_engine(params))   # its own: another program
     want = _reference_rows(reference, params, seq)
     assert np.abs(got - want).max() > 10 * LOGIT_ATOL
 
@@ -466,7 +480,7 @@ def test_yarn_frequencies_and_mscale_by_hand():
 
 
 # --------------------------------------- both leaves through the cache manager
-def test_a_prefix_hit_restores_both_pool_leaves(params):
+def test_a_prefix_hit_restores_both_pool_leaves(params, engine_of):
     """Two prompts that share 16 tokens (4 whole blocks): the second splices
     the first's blocks in, latent rows and index keys alike, and serves the
     tokens an engine without the prefix cache serves."""
@@ -476,7 +490,7 @@ def test_a_prefix_hit_restores_both_pool_leaves(params):
                "b": shared + list(rng.integers(1, VOCAB, 7))}
     want = {}
     for uid, prompt in prompts.items():
-        eng = _engine(params)
+        eng = engine_of()
         eng.put(uid, prompt, max_new_tokens=4)
         want[uid] = eng.generate_all()[uid]
     eng = _engine(params, enable_prefix_cache=True)
@@ -488,7 +502,8 @@ def test_a_prefix_hit_restores_both_pool_leaves(params):
     assert set(eng.cache) == {"kv", "idx"}
 
 
-def test_pool_pressure_and_containment_recompute_both_pool_leaves(params):
+def test_pool_pressure_and_containment_recompute_both_pool_leaves(params,
+                                                                  engine_of):
     """A pool too small for two sequences at once: the second waits for the
     first's blocks and is computed over them (both leaves hold the first's
     stale rows) to the tokens an engine with room serves. Then crash
@@ -496,10 +511,11 @@ def test_pool_pressure_and_containment_recompute_both_pool_leaves(params):
     requests again, recomputed from their prompts to the same tokens."""
     rng = np.random.default_rng(8)
     prompts = {u: list(rng.integers(1, VOCAB, 14)) for u in ("a", "b")}
-    roomy = _engine(params)
+    roomy = engine_of()
     for uid, prompt in prompts.items():
         roomy.put(uid, prompt, max_new_tokens=10)
     want = roomy.generate_all()
+    want = {uid: want[uid] for uid in prompts}
     tight = _engine(params, num_blocks=10)       # 9 usable blocks of 4 tokens
     for uid, prompt in prompts.items():
         tight.put(uid, prompt, max_new_tokens=10)

@@ -2,6 +2,9 @@
 parity, fp16 loss scaling, GAS equivalence, fwd/bwd/step parity path
 (reference test style: ``tests/unit/runtime`` train-and-compare suites)."""
 
+import functools
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,7 +46,19 @@ def _fixed_batches(n, batch, seq=16, seed=0):
     ]
 
 
-def _run(stage, n_steps=6, gas=1, mesh=None, kind="llama", bf16=False, fp16=None, seed=0):
+def _run(stage, mesh=None, **options):
+    """``(engine, losses)`` of one configuration, once a process: an engine is
+    two compiles of its step, and the three stages' parity cases share their
+    stage-0 baseline (the sharding case, the stage-3 engine)."""
+    return _trained(stage, json.dumps(mesh), json.dumps(options, sort_keys=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _trained(stage, mesh, options):
+    return _train(stage, mesh=json.loads(mesh), **json.loads(options))
+
+
+def _train(stage, n_steps=6, gas=1, mesh=None, kind="llama", bf16=False, fp16=None, seed=0):
     cfg = _config(stage=stage, gas=gas, mesh=mesh or {"data": 8}, bf16=bf16)
     if fp16:
         cfg["fp16"] = fp16
@@ -71,7 +86,7 @@ def test_zero_stage_loss_parity(stage):
 
 
 def test_zero3_params_actually_sharded():
-    engine, _ = _run(stage=3, n_steps=1, mesh={"data": 1, "fsdp": 8})
+    engine, _ = _run(stage=3, n_steps=5, mesh={"data": 1, "fsdp": 8})
     wq = engine.params["layers"]["wq"]
     assert wq.addressable_shards[0].data.size == wq.size // 8
     mu = engine.opt_state[0].mu["layers"]["wq"]

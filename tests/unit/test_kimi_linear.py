@@ -14,6 +14,7 @@ same sums are taken in another order; observed differences are under 2e-6 on
 logits of magnitude 0.6.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shared import one_engine_each, over_one_length  # tests/unit on the path
 
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import deepseek, experts, kimi_linear, nemotron_h
@@ -64,10 +66,21 @@ def _engine(params, cfg=CFG, device_state=False, **sizes):
                                  rc, dtype=jnp.float32, params=params)
 
 
+@pytest.fixture(scope="module")
+def engine_of(params):
+    """``engine_of(**sizes)``: the module's ONE engine of those sizes, as new
+    each time it is asked for (``shared.py``)."""
+    return one_engine_each(functools.partial(_engine, params))
+
+
 def _prompts(lengths, seed=0):
     rng = np.random.default_rng(seed)
     return {uid: rng.integers(0, CFG.vocab_size, n).tolist()
             for uid, n in enumerate(lengths)}
+
+
+# the longest request served here is 37 + 8 tokens
+_reference_rows = over_one_length(REF.forward, 48)
 
 
 # case -> (engine sizes, prompt lengths, new tokens, step after which the
@@ -106,29 +119,31 @@ def _serve(eng, prompts, new_tokens, recover_after=None):
         return emit_tokens(logits, emit)
 
     eng._emit_tokens = recording
-    for uid, prompt in prompts.items():
-        eng.put(uid, prompt, max_new_tokens=new_tokens)
-    steps = 0
-    while eng.has_work:
-        eng.step()
-        steps += 1
-        if steps == recover_after:
-            eng._recover_device_path()
-        assert steps < 500
+    try:
+        for uid, prompt in prompts.items():
+            eng.put(uid, prompt, max_new_tokens=new_tokens)
+        steps = 0
+        while eng.has_work:
+            eng.step()
+            steps += 1
+            if steps == recover_after:
+                eng._recover_device_path()
+            assert steps < 500
+    finally:
+        del eng._emit_tokens        # the engine is shared: the method again
     return rows
 
 
 @pytest.mark.parametrize("case", SERVED)
-def test_served_logits_match_the_reference(params, case):
+def test_served_logits_match_the_reference(params, engine_of, case):
     sizes, lengths, new_tokens, recover_after = SERVED[case]
-    eng = _engine(params, **sizes)
+    eng = engine_of(**sizes)
     prompts = _prompts(lengths)
     rows = _serve(eng, prompts, new_tokens, recover_after)
     for uid, prompt in prompts.items():
         generated = eng.get_request(uid).generated
         assert len(generated) == new_tokens
-        want = np.asarray(REF.forward(CFG, params,
-                                      jnp.asarray(prompt + generated)))
+        want = _reference_rows(CFG, params, prompt + generated)
         for g in range(new_tokens):
             np.testing.assert_allclose(
                 rows[(uid, g)], want[len(prompt) + g - 1], atol=ATOL,
@@ -143,17 +158,17 @@ def test_served_logits_match_the_reference(params, case):
 
 @pytest.mark.parametrize("case", ["mixed_steps", "recovered_and_recomputed",
                                   "slot_reused"])
-def test_device_resident_path_serves_the_reference_tokens(params, case):
+def test_device_resident_path_serves_the_reference_tokens(params, engine_of,
+                                                          case):
     """The device-resident step (slot rows, picks on the device) against the
     reference's greedy tokens, teacher-forced on what was served."""
     sizes, lengths, new_tokens, recover_after = SERVED[case]
-    eng = _engine(params, device_state=True, **sizes)
+    eng = engine_of(device_state=True, **sizes)
     prompts = _prompts(lengths)
     _serve(eng, prompts, new_tokens, recover_after)
     for uid, prompt in prompts.items():
         generated = eng.get_request(uid).generated[:new_tokens]
-        want = np.asarray(REF.forward(CFG, params,
-                                      jnp.asarray(prompt + generated)))
+        want = _reference_rows(CFG, params, prompt + generated)
         greedy = want.argmax(-1)[len(prompt) - 1:len(prompt) + new_tokens - 1]
         assert generated == greedy.tolist(), (case, uid)
 
@@ -499,11 +514,11 @@ def test_mla_without_rotation_is_the_reference_and_differs_from_rotated():
 
 
 # ------------------------------------------------------------ the engine
-def test_engine_accounts_blocks_and_slots_apart(params):
+def test_engine_accounts_blocks_and_slots_apart(engine_of):
     """The latent leaf counts the 2 MLA layers, the slot leaves the 3 KDA
     layers (``S`` and the three convolutions' rows): the reference's
     geometry, which the benchmark's readers multiply the spans by."""
-    eng = _engine(params)
+    eng = engine_of()
     assert eng.cache["kv"].shape[0] == 2 and eng.cache[SLOTS]["kda"].shape[0] == 3
     assert eng.kv_bytes_per_token() == CFG.row_lanes * 4 * 2
     assert REF.kv_bytes_per_token(CFG, 4) == (32 + 16) * 4 * 2
@@ -512,7 +527,7 @@ def test_engine_accounts_blocks_and_slots_apart(params):
 
 
 @pytest.mark.parametrize("family", ["kimi_linear", "nemotron_h"])
-def test_dispatch_span_says_what_state_the_step_moved(params, family,
+def test_dispatch_span_says_what_state_the_step_moved(engine_of, family,
                                                       monkeypatch):
     """``engine/dispatch`` of a model with slot state: ``state_bytes`` (decode
     rows + distinct prefilling slots, a slot's bytes once each way),
@@ -531,7 +546,7 @@ def test_dispatch_span_says_what_state_the_step_moved(params, family,
     telemetry.configure(enabled=True)
     try:
         if family == "kimi_linear":
-            eng, kind = _engine(params, device_state=True), "kda"
+            eng, kind = engine_of(device_state=True), "kda"
         else:
             cfg = nemotron_h.NemotronHConfig.tiny()
             eng = RaggedInferenceEngine(
@@ -736,8 +751,7 @@ def test_both_window_forms_serve_the_same_logits():
     assert b[:, :-1].any() and not b[:, -1].any()
     for uid, prompt in prompts.items():
         generated = engines[0].get_request(uid).generated
-        want = np.asarray(REF.forward(FOLDED, params,
-                                      jnp.asarray(prompt + generated)))
+        want = _reference_rows(FOLDED, params, prompt + generated)
         for g in range(4):
             np.testing.assert_allclose(folded[(uid, g)],
                                        want[len(prompt) + g - 1], atol=ATOL)

@@ -8,6 +8,7 @@ back, each against ``benchmark/reference/longcat_flash.py`` (straight
 ``jax.numpy``, nothing imported from the program) or a hand-worked case."""
 
 import dataclasses
+import functools
 import importlib.util
 import os
 
@@ -15,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shared import one_engine_each  # tests/unit is rootdir-inserted
 
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import experts, paged
@@ -92,23 +94,34 @@ def _engine(params, cfg=CFG, device_state=False, **over):
         seed=0, ragged_config=RaggedConfig(**{**sizes, **over}))
 
 
-def _serve_logits(params, cfg=CFG):
+@pytest.fixture(scope="module")
+def shared_engine(params):
+    """``shared_engine()``: the module's ONE engine of ``_engine``'s own
+    sizes, as new each time it is asked for (``shared.py``)."""
+    return one_engine_each(functools.partial(_engine, params))
+
+
+def _serve_logits(eng, cfg=CFG):
     """Prefill a 22-token prompt in chunks of <= 8 tokens (tiles of 4) and
     decode 6 tokens; the served sequence and the logits row behind every
     emitted token."""
-    eng = _engine(params, cfg)
     rows = []
     emit = eng._emit_tokens
+    dispatched = eng.dispatch_count
 
     def record(logits, pairs):
         rows.extend(np.asarray(logits[i]) for i, _ in pairs)
         return emit(logits, pairs)
 
     eng._emit_tokens = record
-    prompt = list(np.random.default_rng(5).integers(1, VOCAB, PROMPT_LEN))
-    eng.put("s", prompt, max_new_tokens=NEW_TOKENS)
-    out = eng.generate_all()["s"]
-    assert eng.dispatch_count >= 3 + NEW_TOKENS - 1   # 3 prefill chunks
+    try:
+        prompt = list(np.random.default_rng(5).integers(1, VOCAB, PROMPT_LEN))
+        eng.put("s", prompt, max_new_tokens=NEW_TOKENS)
+        out = eng.generate_all()["s"]
+    finally:
+        del eng._emit_tokens        # the engine is shared: the method again
+    # 3 prefill chunks
+    assert eng.dispatch_count - dispatched >= 3 + NEW_TOKENS - 1
     assert eng.cache["kv"].shape[0] == 2 * cfg.num_layers
     return prompt + out, np.stack(rows)
 
@@ -119,8 +132,8 @@ def _reference_rows(reference, params, seq):
     return want[PROMPT_LEN - 1:len(seq) - 1]         # row i predicts i + 1
 
 
-def test_engine_logits_match_the_reference(reference, params):
-    seq, got = _serve_logits(params)
+def test_engine_logits_match_the_reference(reference, params, shared_engine):
+    seq, got = _serve_logits(shared_engine())
     want = _reference_rows(reference, params, seq)
     assert got.shape == want.shape == (NEW_TOKENS, VOCAB)
     np.testing.assert_allclose(got, want, rtol=0, atol=LOGIT_ATOL)
@@ -179,7 +192,7 @@ def test_the_tolerance_catches_a_model_one_line_away(reference, params,
         cfg = dataclasses.replace(CFG, **{
             "mla_scale_q_lora" if fault == "no_q_lora_scale"
             else "mla_scale_kv_lora": False})
-    seq, got = _serve_logits(params, cfg)
+    seq, got = _serve_logits(_engine(params, cfg), cfg)   # another program
     want = _reference_rows(reference, params, seq)
     assert np.abs(got - want).max() > 10 * LOGIT_ATOL
 
@@ -376,7 +389,7 @@ def test_four_ranks_parts_and_the_identity_part_once_add_up(reference):
 
 
 # ------------------------------- both sublayers' rows through the cache manager
-def test_a_prefix_hit_restores_both_sublayers_rows(params):
+def test_a_prefix_hit_restores_both_sublayers_rows(params, shared_engine):
     """Two prompts that share 16 tokens (4 whole blocks): the second splices
     the first's blocks in, all 2 x num_layers block layers of them, and
     serves the tokens an engine without the prefix cache serves."""
@@ -386,7 +399,7 @@ def test_a_prefix_hit_restores_both_sublayers_rows(params):
                "b": shared + list(rng.integers(1, VOCAB, 7))}
     want = {}
     for uid, prompt in prompts.items():
-        eng = _engine(params)
+        eng = shared_engine()
         eng.put(uid, prompt, max_new_tokens=4)
         want[uid] = eng.generate_all()[uid]
     eng = _engine(params, enable_prefix_cache=True)
@@ -399,17 +412,19 @@ def test_a_prefix_hit_restores_both_sublayers_rows(params):
     assert eng.kv_bytes_per_token() == 2 * CFG.num_layers * CFG.row_lanes * 4
 
 
-def test_pool_pressure_and_containment_recompute_both_sublayers_rows(params):
+def test_pool_pressure_and_containment_recompute_both_sublayers_rows(
+        params, shared_engine):
     """A pool too small for two sequences at once: the second waits for the
     first's blocks and is computed over them (every block layer holds the
     first's stale rows) to the tokens an engine with room serves; then crash
     containment (a fresh cache) and the same requests again."""
     rng = np.random.default_rng(8)
     prompts = {u: list(rng.integers(1, VOCAB, 14)) for u in ("a", "b")}
-    roomy = _engine(params)
+    roomy = shared_engine()
     for uid, prompt in prompts.items():
         roomy.put(uid, prompt, max_new_tokens=10)
     want = roomy.generate_all()
+    want = {uid: want[uid] for uid in prompts}
     tight = _engine(params, num_blocks=10)       # 9 usable blocks of 4 tokens
     for uid, prompt in prompts.items():
         tight.put(uid, prompt, max_new_tokens=10)

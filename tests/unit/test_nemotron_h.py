@@ -11,6 +11,7 @@ taken in another order; observed differences are under 1e-6 on logits of
 magnitude 0.6.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shared import one_engine_each, over_one_length  # tests/unit on the path
 
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
 from deepspeed_tpu.models import experts, nemotron_h
@@ -54,10 +56,21 @@ def _engine(params, device_state=False, cfg=CFG, **sizes):
                                  dtype=jnp.float32, params=params)
 
 
+@pytest.fixture(scope="module")
+def engine_of(params):
+    """``engine_of(**sizes)``: the module's ONE engine of those sizes, as new
+    each time it is asked for (``shared.py``)."""
+    return one_engine_each(functools.partial(_engine, params))
+
+
 def _prompts(lengths, seed=0):
     rng = np.random.default_rng(seed)
     return {uid: rng.integers(0, CFG.vocab_size, n).tolist()
             for uid, n in enumerate(lengths)}
+
+
+# the longest request served here is 37 + 8 tokens
+_reference_rows = over_one_length(REF.forward, 48)
 
 
 # case -> (engine sizes, prompt lengths, new tokens, step after which the
@@ -93,29 +106,31 @@ def _serve(eng, prompts, new_tokens, recover_after=None):
         return emit_tokens(logits, emit)
 
     eng._emit_tokens = recording
-    for uid, prompt in prompts.items():
-        eng.put(uid, prompt, max_new_tokens=new_tokens)
-    steps = 0
-    while eng.has_work:
-        eng.step()
-        steps += 1
-        if steps == recover_after:
-            eng._recover_device_path()
-        assert steps < 500
+    try:
+        for uid, prompt in prompts.items():
+            eng.put(uid, prompt, max_new_tokens=new_tokens)
+        steps = 0
+        while eng.has_work:
+            eng.step()
+            steps += 1
+            if steps == recover_after:
+                eng._recover_device_path()
+            assert steps < 500
+    finally:
+        del eng._emit_tokens        # the engine is shared: the method again
     return rows
 
 
 @pytest.mark.parametrize("case", SERVED)
-def test_served_logits_match_the_reference(params, case):
+def test_served_logits_match_the_reference(params, engine_of, case):
     sizes, lengths, new_tokens, recover_after = SERVED[case]
-    eng = _engine(params, **sizes)
+    eng = engine_of(**sizes)
     prompts = _prompts(lengths)
     rows = _serve(eng, prompts, new_tokens, recover_after)
     for uid, prompt in prompts.items():
         generated = eng.get_request(uid).generated
         assert len(generated) == new_tokens
-        want = np.asarray(REF.forward(CFG, params,
-                                      jnp.asarray(prompt + generated)))
+        want = _reference_rows(CFG, params, prompt + generated)
         for g in range(new_tokens):
             np.testing.assert_allclose(
                 rows[(uid, g)], want[len(prompt) + g - 1], atol=ATOL,
@@ -130,17 +145,17 @@ def test_served_logits_match_the_reference(params, case):
 
 @pytest.mark.parametrize("case", ["mixed_steps", "recovered_and_recomputed",
                                   "slot_reused"])
-def test_device_resident_path_serves_the_reference_tokens(params, case):
+def test_device_resident_path_serves_the_reference_tokens(params, engine_of,
+                                                          case):
     """The device-resident step (slot rows, picks on the device) against the
     reference's greedy tokens, teacher-forced on what was served."""
     sizes, lengths, new_tokens, recover_after = SERVED[case]
-    eng = _engine(params, device_state=True, **sizes)
+    eng = engine_of(device_state=True, **sizes)
     prompts = _prompts(lengths)
     _serve(eng, prompts, new_tokens, recover_after)
     for uid, prompt in prompts.items():
         generated = eng.get_request(uid).generated[:new_tokens]
-        want = np.asarray(REF.forward(CFG, params,
-                                      jnp.asarray(prompt + generated)))
+        want = _reference_rows(CFG, params, prompt + generated)
         greedy = want.argmax(-1)[len(prompt) - 1:len(prompt) + new_tokens - 1]
         assert generated == greedy.tolist(), (case, uid)
 
@@ -282,8 +297,8 @@ def test_a_period_with_attention_inside(params):
 
 
 # ------------------------------------------------------------ the engine
-def test_engine_accounts_blocks_and_slots_apart(params):
-    eng = _engine(params)
+def test_engine_accounts_blocks_and_slots_apart(params, engine_of):
+    eng = engine_of()
     assert eng.kv_bytes_per_token() == REF.kv_bytes_per_token(CFG, 4)
     assert eng.state_bytes_per_slot() == REF.state_bytes_per_slot(CFG, 4)
     assert eng._block_bytes() == 8 * eng.kv_bytes_per_token()
@@ -339,10 +354,10 @@ def test_engine_refuses_what_slot_state_cannot_restore(params, what):
         _engine(params, **sizes)
 
 
-def test_handoff_is_refused(params):
+def test_handoff_is_refused(engine_of):
     from deepspeed_tpu.inference.ragged import KVHandoff
 
-    eng = _engine(params)
+    eng = engine_of()
     with pytest.raises(ValueError, match="KVHandoff"):
         eng.put(0, [1, 2, 3], handoff=True)
     record = KVHandoff.__new__(KVHandoff)
@@ -379,8 +394,7 @@ def test_both_window_forms_serve_the_same_logits():
     assert b[:, :-1].any() and not b[:, -1].any()
     for uid, prompt in prompts.items():
         generated = engines[0].get_request(uid).generated
-        want = np.asarray(REF.forward(cfg, params,
-                                      jnp.asarray(prompt + generated)))
+        want = _reference_rows(cfg, params, prompt + generated)
         for g in range(4):
             np.testing.assert_allclose(folded[(uid, g)],
                                        want[len(prompt) + g - 1], atol=ATOL)

@@ -59,41 +59,53 @@ def _run(engine, batches):
     return [float(engine.train_batch(b)) for b in batches]
 
 
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """``trained(device)``: ``(engine, its losses over _batches(4))`` of one
+    engine a tier, built and trained once a module. An engine here is two
+    compiles of its step (~12 s) and four steps are nothing: the cases that
+    compare trajectories, or look at a trained engine, share them."""
+    runs = {}
+
+    def get(device):
+        if device not in runs:
+            eng = _engine(device, tmp_path_factory.mktemp(device))
+            runs[device] = (eng, _run(eng, _batches(4)))
+        return runs[device]
+
+    return get
+
+
 class TestWindowedOffload:
-    def test_nvme_training_matches_baseline(self, tmp_path):
+    def test_nvme_training_matches_baseline(self, trained):
         """offload_optimizer.device=nvme: identical loss trajectory to the
         un-offloaded engine, optimizer state never device-resident."""
-        batches = _batches(4)
-        base = _run(_engine("none", tmp_path), batches)
-
-        eng = _engine("nvme", tmp_path)
-        assert eng.opt_state is None  # state lives on NVMe, not in HBM
+        _, base = trained("none")
+        eng, got = trained("nvme")
         assert len(eng._groups) > 1   # genuinely windowed
-        got = _run(eng, batches)
         np.testing.assert_allclose(got, base, rtol=2e-4, atol=2e-5)
-        # still on disk after training, and never materialized on the engine
+        # on disk after training, and never materialized on the engine
         assert eng.opt_state is None
-        swp = [f for f in os.listdir(tmp_path / "nvme") if f.endswith(".swp")]
+        nvme = eng.config.zero_optimization.offload_optimizer.nvme_path
+        swp = [f for f in os.listdir(nvme) if f.endswith(".swp")]
         assert len(swp) >= len(eng._groups)
 
-    def test_cpu_windowed_matches_baseline(self, tmp_path):
+    def test_cpu_windowed_matches_baseline(self, trained):
         """Host-tier path: grouped in-jit update (memory kinds are a no-op on
         the CPU test backend, but the windowed group walk is exercised)."""
-        batches = _batches(4, seed=3)
-        base = _run(_engine("none", tmp_path), batches)
-        eng = _engine("cpu", tmp_path)
+        _, base = trained("none")
+        eng, got = trained("cpu")
         assert isinstance(eng.opt_state, list) and len(eng.opt_state) > 1
-        got = _run(eng, batches)
         np.testing.assert_allclose(got, base, rtol=2e-4, atol=2e-5)
 
-    def test_nvme_checkpoint_roundtrip(self, tmp_path):
+    def test_nvme_checkpoint_roundtrip(self, tmp_path, trained):
         """Save/load with NVMe-offloaded state: resumed run matches the
         continuous one (reference test_nvme_checkpointing.py)."""
-        batches = _batches(4, seed=5)
-        cont = _engine("nvme", tmp_path / "a")
-        cont_losses = _run(cont, batches)
+        batches = _batches(4)
+        _, cont_losses = trained("nvme")
 
         half = _engine("nvme", tmp_path / "b")
+        assert half.opt_state is None  # state lives on NVMe, not in HBM
         _run(half, batches[:2])
         half.save_checkpoint(str(tmp_path / "ckpt"))
 
@@ -102,21 +114,20 @@ class TestWindowedOffload:
         got = _run(resumed, batches[2:])
         np.testing.assert_allclose(got, cont_losses[2:], rtol=2e-4, atol=2e-5)
 
-    def test_backward_path_guarded_under_nvme(self, tmp_path):
-        eng = _engine("nvme", tmp_path)
+    def test_backward_path_guarded_under_nvme(self, trained):
+        eng, _ = trained("nvme")
         with pytest.raises(NotImplementedError):
             eng.backward(_batches(1)[0])
 
     @pytest.mark.parametrize("device", ["cpu", "nvme"])
-    def test_tensor_fragment_api_with_offload(self, tmp_path, device):
+    def test_tensor_fragment_api_with_offload(self, trained, device):
         """safe_get_full_optimizer_state resolves moments across the grouped
         and NVMe representations (reference test_zero_tensor_fragment.py)."""
         from deepspeed_tpu.utils.tensor_fragment import (
             safe_get_full_optimizer_state,
         )
 
-        eng = _engine(device, tmp_path)
-        eng.train_batch(_batches(1)[0])
+        eng, _ = trained(device)
         mu = safe_get_full_optimizer_state(eng, "layers/wq", "exp_avg")
         nu = safe_get_full_optimizer_state(eng, "layers/wq", "exp_avg_sq")
         assert mu.shape == np.asarray(eng.params["layers"]["wq"]).shape

@@ -2,6 +2,9 @@
 plain layer scan, and end-to-end PP training parity
 (reference: ``tests/unit/runtime/pipe/``)."""
 
+import functools
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,8 +81,19 @@ def _cfg(mesh, n_micro=0, gas=1, schedule="gpipe", stage=0, batch=64):
     return cfg
 
 
-def _run(mesh, n_micro=0, n=3, gas=1, schedule="gpipe", stage=0, batch=64,
-         schedule_base_fp32=False):
+def _run(mesh, **options):
+    """``(engine, its three losses)`` of one configuration, once a process: an
+    engine is its compiles, and two cases look at the same one."""
+    return _trained(json.dumps(mesh), json.dumps(options, sort_keys=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _trained(mesh, options):
+    return _train(json.loads(mesh), **json.loads(options))
+
+
+def _train(mesh, n_micro=0, n=3, gas=1, schedule="gpipe", stage=0, batch=64,
+           schedule_base_fp32=False):
     reset_topology()
     cfg = _cfg(mesh, n_micro, gas=gas, schedule=schedule, stage=stage, batch=batch)
     if schedule_base_fp32:
@@ -125,7 +139,7 @@ def test_pp_1f1b_composes_with_fsdp():
 
 
 def test_pp_layers_sharded_over_pipeline_axis():
-    engine, _ = _run({"data": 4, "pipeline": 2}, n_micro=2, n=1)
+    engine, _ = _run({"data": 4, "pipeline": 2}, n_micro=2)
     wq = engine.params["layers"]["wq"]
     assert "pipeline" in str(wq.sharding.spec)
     # 2 layers over 2 stages: each device holds one layer slice

@@ -2,6 +2,8 @@
 error-feedback convergence, engine training parity
 (reference: ``tests/unit/comm``, ``tests/unit/runtime/comm`` + onebit suites)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,10 @@ from deepspeed_tpu.config.config import MeshConfig
 from deepspeed_tpu.models import llama
 
 VOCAB = 256
+# one layer: what these cases compare (two reductions of the same gradients,
+# two optimizers on the same wire) does not depend on depth, and an engine's
+# cost here is its two compiles of the step, which grow with it
+MODEL = dataclasses.replace(llama.LlamaConfig.tiny(VOCAB), num_layers=1)
 
 
 @pytest.fixture
@@ -120,7 +126,8 @@ class TestQuantizedAllReduce:
         assert errs[240] < errs[120] * 0.7, errs
 
 
-def _train(config_extra, optimizer=None, steps=6, seed=3, mesh=None, stage=1):
+def _train(config_extra, optimizer=None, steps=6, seed=3, mesh=None, stage=1,
+           model=MODEL):
     reset_topology()
     cfg = {
         "train_micro_batch_size_per_device": 2,
@@ -133,7 +140,7 @@ def _train(config_extra, optimizer=None, steps=6, seed=3, mesh=None, stage=1):
         "seed": 7,
     }
     engine, _, _, _ = deepspeed_tpu.initialize(
-        model=lambda ctx: llama.build(llama.LlamaConfig.tiny(VOCAB), ctx=ctx),
+        model=lambda ctx: llama.build(model, ctx=ctx),
         config=cfg, seed=11,
     )
     rng = np.random.default_rng(seed)
@@ -171,7 +178,7 @@ class TestQuantizedTraining:
         reset_topology()
         with pytest.raises(ValueError, match="data"):
             deepspeed_tpu.initialize(
-                model=lambda ctx: llama.build(llama.LlamaConfig.tiny(VOCAB), ctx=ctx),
+                model=lambda ctx: llama.build(MODEL, ctx=ctx),
                 config={
                     "train_micro_batch_size_per_device": 2,
                     "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
@@ -273,10 +280,13 @@ class TestOneBitWire:
 
     def test_one_bit_adam_compressed_wire_parity(self):
         opt = {"type": "onebit_adam", "params": {"lr": 5e-3, "freeze_step": 3}}
-        base = _train({}, optimizer=opt, steps=10)
+        # two layers: with one, sign-only gradients fall behind by more than
+        # the 25% below (29% at step 10)
+        two = llama.LlamaConfig.tiny(VOCAB)
+        base = _train({}, optimizer=opt, steps=10, model=two)
         comp = _train({"quantized_gradients": True,
                        "quantized_gradients_bits": 1},
-                      optimizer=opt, steps=10)
+                      optimizer=opt, steps=10, model=two)
         assert comp[-1] < comp[0] * 0.9  # still converges on the 1-bit wire
         # warmup steps are dense-wire: EXACTLY equal trajectories there
         np.testing.assert_allclose(comp[:3], base[:3], rtol=1e-5)
@@ -302,8 +312,7 @@ class TestOneBitWire:
             "seed": 7,
         }
         engine, _, _, _ = deepspeed_tpu.initialize(
-            model=lambda ctx: llama.build(llama.LlamaConfig.tiny(VOCAB),
-                                          ctx=ctx),
+            model=lambda ctx: llama.build(MODEL, ctx=ctx),
             config=cfg, seed=11)
         assert engine._qgrad_warmup_steps == 4
         rng = np.random.default_rng(3)

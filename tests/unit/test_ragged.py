@@ -4,7 +4,8 @@ ragged manager, blocked allocator, engine numerics vs the dense path)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from step_modes import MODES  # tests/unit is rootdir-inserted by pytest
+from shared import one_engine_each  # tests/unit is rootdir-inserted
+from step_modes import MODES
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.ragged import (
@@ -62,6 +63,35 @@ def _dense_reference(prompts, max_new):
     return out
 
 
+def _engine_ds(device_state, **over):
+    import dataclasses
+
+    cfg = dataclasses.replace(RCFG, device_state=device_state, **over)
+    return RaggedInferenceEngine(
+        lambda ctx: llama.build(CFG, ctx=ctx), cfg, dtype=jnp.float32, seed=0)
+
+
+@pytest.fixture(scope="module")
+def engine_of():
+    """``engine_of(device_state, second=False, **over)``: the module's ONE
+    engine of those options (two with ``second``), as new each time it is
+    asked for (``shared.py``). A case that reads an engine's running
+    counts, degrades it, or is about its first compile builds its own with
+    ``_engine_ds``."""
+    get = one_engine_each(_engine_ds)
+    return lambda device_state=RCFG.device_state, **over: get(device_state,
+                                                              **over)
+
+
+def _generate(eng, requests):
+    """Put ``{uid: (prompt, put options)}`` and run to the end; the tokens
+    of these uids alone (a shared engine still holds earlier cases')."""
+    for uid, (prompt, kw) in requests.items():
+        eng.put(uid, prompt, **kw)
+    out = eng.generate_all()
+    return {uid: out[uid] for uid in requests}
+
+
 def _prompts(rng=0):
     r = np.random.default_rng(rng)
     return {
@@ -72,49 +102,30 @@ def _prompts(rng=0):
 
 
 class TestRaggedEngine:
-    def test_mixed_length_parity_vs_dense(self):
+    def test_mixed_length_parity_vs_dense(self, engine_of):
         """Three different-length prompts admitted together produce exactly
         the dense engine's greedy continuations."""
         prompts = _prompts()
         max_new = 8
         ref = _dense_reference(prompts, max_new)
 
-        eng = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
+        eng = engine_of()
         for uid, p in prompts.items():
             eng.put(uid, p, max_new_tokens=max_new)
         got = eng.generate_all()
         for uid in prompts:
             assert got[uid] == [int(t) for t in ref[uid]], uid
 
-    def test_tiled_prefill_token_parity(self):
+    def test_tiled_prefill_token_parity(self, engine_of):
         """The tile-aligned prefill layout + tiled attention path must emit
         exactly the per-token engine's greedy tokens (XLA fallback on CPU
         exercises the scheduler layout + metadata; kernel math is covered by
         test_paged_attention's interpret-mode parity)."""
-        import dataclasses
-
-        prompts = _prompts(13)
-        max_new = 7
-        base = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
-        for uid, p in prompts.items():
-            base.put(uid, p, max_new_tokens=max_new)
-        expect = base.generate_all()
-
-        tiled = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx),
-            dataclasses.replace(RCFG, prefill_tile=8),
-            dtype=jnp.float32, seed=0,
-        )
-        for uid, p in prompts.items():
-            tiled.put(uid, p, max_new_tokens=max_new)
-        got = tiled.generate_all()
-        assert got == expect
+        requests = {uid: (p, dict(max_new_tokens=7))
+                    for uid, p in _prompts(13).items()}
+        expect = _generate(engine_of(), requests)
+        tiled = engine_of(prefill_tile=8)
+        assert _generate(tiled, requests) == expect
         assert (any(key[2] > 0 for key in tiled._dev_step_jits)
                 or tiled._tiled_jits), "tiled step programs never engaged"
 
@@ -133,17 +144,14 @@ class TestRaggedEngine:
                 dtype=jnp.float32, seed=0,
             )
 
-    def test_continuous_admission(self):
+    def test_continuous_admission(self, engine_of):
         """A request put() mid-flight (while others decode) still matches the
         dense reference — continuous batching semantics."""
         prompts = _prompts(3)
         max_new = 6
         ref = _dense_reference(prompts, max_new)
 
-        eng = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
+        eng = engine_of()
         eng.put("a", prompts["a"], max_new_tokens=max_new)
         eng.put("b", prompts["b"], max_new_tokens=max_new)
         for _ in range(3):  # a/b prefill and start decoding
@@ -153,11 +161,8 @@ class TestRaggedEngine:
         for uid in prompts:
             assert got[uid] == [int(t) for t in ref[uid]], uid
 
-    def test_blocks_and_slots_recycled(self):
-        eng = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
+    def test_blocks_and_slots_recycled(self, engine_of):
+        eng = engine_of()
         total_free = eng.allocator.free_blocks
         # two waves through the same engine: slots and blocks must recycle
         for wave in range(2):
@@ -168,7 +173,7 @@ class TestRaggedEngine:
             assert len(eng._free_slots) == RCFG.max_seqs
 
     @pytest.mark.parametrize("mode", list(MODES))
-    def test_eos_stops_sequence(self, mode):
+    def test_eos_stops_sequence(self, engine_of, mode):
         """An EOS token ends its sequence there, on every step path. The
         device step reads a token back with the next step already
         dispatched: the row the sequence has in that step surfaces no token,
@@ -177,7 +182,7 @@ class TestRaggedEngine:
         prompts = _prompts()
         # run once to learn the tokens, then make two of them EOS tokens: x's
         # first, and one y has not produced before, mid-decode
-        eng = _engine_ds(**kw)
+        eng = engine_of(**kw)
         eng.put("px", prompts["a"], max_new_tokens=6)
         eng.put("py", prompts["b"], max_new_tokens=6)
         eng.put("pz", prompts["c"], max_new_tokens=6)
@@ -243,7 +248,7 @@ class TestRaggedEngine:
         assert sorted(out) == ["a", "b", "c"]
         assert [len(out[u]) for u in "abc"] == [6, 6, 5]
 
-    def test_splitfuse_efficiency_vs_dense_padding(self):
+    def test_splitfuse_efficiency_vs_dense_padding(self, engine_of):
         """Scheduled useful tokens must beat dense pad-to-max batching: the
         dense engine processes batch*max_prompt prefill + batch*max_new decode
         token-slots; the ragged schedule only pays for real tokens plus
@@ -251,17 +256,15 @@ class TestRaggedEngine:
         lengths."""
         prompts = _prompts()
         max_new = 8
-        eng = RaggedInferenceEngine(
-            lambda ctx: llama.build(CFG, ctx=ctx), RCFG,
-            dtype=jnp.float32, seed=0,
-        )
+        eng = engine_of()
+        before = eng.tokens_scheduled + eng.tokens_padded
         for uid, p in prompts.items():
             eng.put(uid, p, max_new_tokens=max_new)
         eng.generate_all()
         dense_token_slots = len(prompts) * (
             max(len(p) for p in prompts.values()) + max_new
         )
-        ragged_token_slots = eng.tokens_scheduled + eng.tokens_padded
+        ragged_token_slots = eng.tokens_scheduled + eng.tokens_padded - before
         assert ragged_token_slots < dense_token_slots, (
             f"ragged {ragged_token_slots} >= dense {dense_token_slots}"
         )
@@ -272,37 +275,27 @@ class TestRaggedEngine:
 DISPATCH_MODES = {m: MODES[m] for m in ("plain", "tiled")}
 
 
-def _engine_ds(device_state, **over):
-    import dataclasses
-
-    cfg = dataclasses.replace(RCFG, device_state=device_state, **over)
-    return RaggedInferenceEngine(
-        lambda ctx: llama.build(CFG, ctx=ctx), cfg, dtype=jnp.float32, seed=0)
-
-
 class TestDeviceResidentState:
     """cfg.device_state keeps slot rows / block table / feed tokens on
     device and double-buffers readback; it must be token-identical to the
     host-staged path in every mode, greedy and seeded-sampled."""
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
-    def test_token_parity_vs_host_staged(self, mode):
+    def test_token_parity_vs_host_staged(self, engine_of, mode):
         kw = DISPATCH_MODES[mode]
-        outs = {}
-        for dev in (False, True):
-            eng = _engine_ds(dev, **kw)
-            for uid, p in _prompts(17).items():
-                eng.put(uid, p, max_new_tokens=8)
-            eng.put("s1", _prompts(19)["b"], max_new_tokens=8,
-                    temperature=0.9, top_k=20, seed=123)
-            eng.put("s2", _prompts(19)["a"], max_new_tokens=6,
-                    temperature=0.7, top_p=0.9, seed=7)
-            outs[dev] = eng.generate_all()
-        assert outs[True] == outs[False]
+        requests = {uid: (p, dict(max_new_tokens=8))
+                    for uid, p in _prompts(17).items()}
+        requests["s1"] = (_prompts(19)["b"], dict(
+            max_new_tokens=8, temperature=0.9, top_k=20, seed=123))
+        requests["s2"] = (_prompts(19)["a"], dict(
+            max_new_tokens=6, temperature=0.7, top_p=0.9, seed=7))
+        outs = {dev: _generate(engine_of(dev, **kw), requests)
+                for dev in (False, True)}
+        assert outs[True] == outs[False] and len(outs[True]) == 5
         # the sampled streams really sampled (not a greedy fallback)
-        greedy = _engine_ds(True, **kw)
-        greedy.put("s1", _prompts(19)["b"], max_new_tokens=8)
-        assert greedy.generate_all()["s1"] != outs[True]["s1"]
+        greedy = _generate(engine_of(True, **kw), {
+            "s1": (_prompts(19)["b"], dict(max_new_tokens=8))})
+        assert greedy["s1"] != outs[True]["s1"]
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
     def test_counters_count_what_the_host_staged_steps_do(self, mode):
@@ -355,10 +348,10 @@ class TestDeviceResidentState:
             "steady-state decode still staging host bytes")
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
-    def test_readback_is_double_buffered(self, mode):
+    def test_readback_is_double_buffered(self, engine_of, mode):
         """A dispatched step's tokens are reconciled one step later (window
         of one pending dispatch), and drain() flushes the window."""
-        eng = _engine_ds(True, **DISPATCH_MODES[mode])
+        eng = engine_of(True, **DISPATCH_MODES[mode])
         eng.put("a", _prompts()["a"], max_new_tokens=6)
         eng.step()  # prefill dispatched, nothing reconciled yet
         assert len(eng._pending) == 1
@@ -369,14 +362,14 @@ class TestDeviceResidentState:
         assert len(out["a"]) == 6
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
-    def test_cancel_mid_flight_with_pending_dispatch(self, mode):
+    def test_cancel_mid_flight_with_pending_dispatch(self, engine_of, mode):
         """cancel() while a dispatch is in flight: the sequence retires via
         the deferred-release machinery, its KV blocks and slot recycle, and
         the remaining request still finishes with correct tokens."""
         kw = DISPATCH_MODES[mode]
         want = None
         for with_cancel in (False, True):
-            eng = _engine_ds(True, **kw)
+            eng = engine_of(True, **kw)
             prompts = _prompts(29)
             eng.put("keep", prompts["b"], max_new_tokens=8)
             if with_cancel:
@@ -396,8 +389,8 @@ class TestDeviceResidentState:
         assert eng.allocator.free_blocks == usable
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
-    def test_deadline_timeout_mid_flight(self, mode):
-        eng = _engine_ds(True, **DISPATCH_MODES[mode])
+    def test_deadline_timeout_mid_flight(self, engine_of, mode):
+        eng = engine_of(True, **DISPATCH_MODES[mode])
         eng.put("t", _prompts()["c"], max_new_tokens=40, deadline_s=0.05)
         eng.step()
         import time as _time
@@ -409,22 +402,17 @@ class TestDeviceResidentState:
         assert len(eng._free_slots) == RCFG.max_seqs
 
     @pytest.mark.parametrize("mode", list(DISPATCH_MODES))
-    def test_slot_reuse_rewrites_device_rows(self, mode):
+    def test_slot_reuse_rewrites_device_rows(self, engine_of, mode):
         """A retired slot reused by a new request must behave as a fresh
         row (seed/params rewritten at admission): an oversubscribed sampled
         workload matches the host-staged path request for request."""
-        eng = _engine_ds(True, **DISPATCH_MODES[mode])
-        fresh = _engine_ds(False, **DISPATCH_MODES[mode])
-        for wave in (0, 1):
-            for uid, p in _prompts(wave).items():
-                eng.put(f"{wave}-{uid}", p, max_new_tokens=5,
-                        temperature=0.8, seed=100 + wave)
-        got = eng.generate_all()
-        for wave in (0, 1):
-            for uid, p in _prompts(wave).items():
-                fresh.put(f"{wave}-{uid}", p, max_new_tokens=5,
-                          temperature=0.8, seed=100 + wave)
-        assert fresh.generate_all() == got
+        requests = {f"{wave}-{uid}": (p, dict(
+            max_new_tokens=5, temperature=0.8, seed=100 + wave))
+            for wave in (0, 1) for uid, p in _prompts(wave).items()}
+        got = _generate(engine_of(True, **DISPATCH_MODES[mode]), requests)
+        assert len(got) == 6
+        assert _generate(engine_of(False, **DISPATCH_MODES[mode]),
+                         requests) == got
 
 
 # ---------------------------------------------------------------- step paths
@@ -459,10 +447,11 @@ class TestStepPaths:
     @pytest.mark.parametrize("scenario",
                              ["decode", "prefill", "mixed", "pressure"])
     @pytest.mark.parametrize("tile", [0, 8], ids=["untiled", "tiled"])
-    def test_one_plan_for_device_feed_and_host_feed(self, tile, scenario):
+    def test_one_plan_for_device_feed_and_host_feed(self, engine_of, tile,
+                                                    scenario):
         plans = []
-        for host_feed in (False, True):
-            eng = _engine_ds(False, prefill_tile=tile)
+        for host_feed in (False, True):   # two engines in one state at once
+            eng = engine_of(False, second=host_feed, prefill_tile=tile)
             _drive_to(eng, scenario)
             plans.append(eng._pack_step(host_feed=host_feed))
         dev, host = plans
@@ -515,7 +504,7 @@ class TestStepPaths:
         assert eng.program_dispatches > 0
         assert eng.program_cold_dispatches == 0  # the programs were there
 
-    def test_ladder_second_rung_turns_tiles_off(self):
+    def test_ladder_second_rung_turns_tiles_off(self, engine_of):
         """Failures that go on after rung 1 (host-staged) take rung 2: the
         host-staged step with prefill tiles off, token-identical."""
         from deepspeed_tpu.serving.faults import (POINT_DISPATCH,
@@ -523,17 +512,15 @@ class TestStepPaths:
 
         over = dict(prefill_tile=8, dispatch_retries=2, retry_backoff_s=0.0,
                     degrade_after=2)
-        outs = {}
-        for faulty in (False, True):
-            eng = _engine_ds(True, **over)
-            if faulty:
-                get_fault_injector().configure(
-                    [{"point": POINT_DISPATCH, "after": 2, "times": 4}])
-            for uid, p in _prompts(43).items():
-                eng.put(uid, p, max_new_tokens=6, temperature=0.8,
-                        seed=7 + len(p))
-            outs[faulty] = eng.generate_all()
-        assert outs[True] == outs[False]
+        requests = {uid: (p, dict(max_new_tokens=6, temperature=0.8,
+                                  seed=7 + len(p)))
+                    for uid, p in _prompts(43).items()}
+        # the clean run: the ladder's options do nothing while no step fails
+        clean = _generate(engine_of(True, prefill_tile=8), requests)
+        eng = _engine_ds(True, **over)
+        get_fault_injector().configure(
+            [{"point": POINT_DISPATCH, "after": 2, "times": 4}])
+        assert _generate(eng, requests) == clean
         assert eng.degraded_mode == 2 and eng.step_failures == 4
         assert not eng.cfg.device_state
         assert eng.cfg.prefill_tile == 0 and not eng._use_tiles
@@ -578,7 +565,8 @@ class TestColdCachePrecompile:
     @pytest.mark.parametrize("first_miss,cpus", [
         (1, 4), (1, 16), (2, 4), (None, 4)],
         ids=["cold", "cold_small_zoo", "thinned", "warm"])
-    def test_the_first_programs_miss_starts_the_others(self, first_miss, cpus,
+    def test_the_first_programs_miss_starts_the_others(self, engine_of,
+                                                       first_miss, cpus,
                                                        monkeypatch):
         """The counter ``warmup`` installs (the compile watch's
         ``cache_writes``) is read before and after the FIRST step program's
@@ -595,7 +583,7 @@ class TestColdCachePrecompile:
         monkeypatch.setattr(os, "cpu_count", lambda: cpus)
 
         sizes = dict(prefill_tile=4, max_tokens_per_step=16, max_seqs=4)
-        eng, ref = _engine_ds(True, **sizes), _engine_ds(True, **sizes)
+        eng, ref = _engine_ds(True, **sizes), engine_of(True, **sizes)
         reads = [0]
 
         def misses():   # two reads a probe: the second of the n-th rises
@@ -620,7 +608,7 @@ class TestColdCachePrecompile:
             first = first or next(iter(eng._dev_step_jits))[:4]
         ref.generate_all()
         assert {u: s.generated for u, s in eng._results.items()} == \
-            {u: s.generated for u, s in ref._results.items()}
+            {u: ref._results[u].generated for u in eng._results}
         for _ in range(600):
             if not any(t.name.startswith("ragged-compile")
                        for t in threading.enumerate()):

@@ -8,6 +8,7 @@ ragged per-token positions."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from shared import one_engine_each  # tests/unit is rootdir-inserted
 
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.ragged import RaggedConfig, RaggedInferenceEngine
@@ -68,56 +69,65 @@ def _dense_reference(name, prompts, max_new):
     return out
 
 
-def _ragged(name, tile=0, quant="off", device_state=True, **over):
+def _ragged(name, tile=0, quant="off", device_state=True):
     return RaggedInferenceEngine(
         model=_build(name), dtype=jnp.float32, seed=0,
         ragged_config=RaggedConfig(
             max_tokens_per_step=16, max_seqs=3, block_size=4,
-            num_blocks=49, max_blocks_per_seq=16,
-            prefill_tile=tile, quant=quant, device_state=device_state,
-            **over))
+            num_blocks=49, max_blocks_per_seq=16, retry_backoff_s=0.0,
+            prefill_tile=tile, quant=quant, device_state=device_state))
+
+
+@pytest.fixture(scope="module")
+def ragged():
+    """``ragged(name, ...)``: the module's ONE engine of that family and
+    those options, as new each time it is asked for (``shared.py``);
+    ``second=True`` is another of the same, for the case that moves blocks
+    between two."""
+    get = one_engine_each(_ragged)
+    return lambda name, tile=0, quant="off", device_state=True, second=False: \
+        get(name, tile, quant, device_state, second=second)
+
+
+def _generate(eng, prompts, max_new):
+    """The tokens of ``prompts`` alone: a shared engine's ``generate_all``
+    also returns what earlier cases left."""
+    for uid, p in prompts.items():
+        eng.put(uid, p, max_new_tokens=max_new)
+    out = eng.generate_all()
+    return {uid: out[uid] for uid in prompts}
 
 
 @pytest.mark.parametrize("name", ["mixtral", "gpt2", "deepseek"])
 class TestRaggedFamilies:
-    def test_greedy_parity_vs_dense(self, name):
+    def test_greedy_parity_vs_dense(self, ragged, name):
         """Continuous batching at mixed lengths must reproduce the dense
         engine's greedy continuations exactly (same weights, fp32)."""
         prompts = _prompts()
         want = _dense_reference(name, prompts, max_new=8)
-        eng = _ragged(name)
-        for uid, p in prompts.items():
-            eng.put(uid, p, max_new_tokens=8)
-        assert eng.generate_all() == want
+        assert _generate(ragged(name), prompts, 8) == want
 
-    def test_tiled_prefill_parity(self, name):
+    def test_tiled_prefill_parity(self, ragged, name):
         prompts = _prompts(4, seed=7)
-        flat = _ragged(name)
-        tiled = _ragged(name, tile=4)
-        for uid, p in prompts.items():
-            flat.put(uid, p, max_new_tokens=5)
-            tiled.put(uid, p, max_new_tokens=5)
-        assert flat.generate_all() == tiled.generate_all()
+        assert _generate(ragged(name), prompts, 5) == \
+            _generate(ragged(name, tile=4), prompts, 5)
 
 
 @pytest.mark.parametrize("name", ["gpt2", "llama", "mixtral", "deepseek"])
-def test_host_staged_fallback_parity(name):
+def test_host_staged_fallback_parity(ragged, name):
     """What a degraded engine serves on (the host-staged step, tiled) gives
     the family the device step's tokens: five prompts through three slots,
     so prefill tiles ride beside decode rows."""
     prompts = _prompts(5, seed=11)
-    device = _ragged(name, tile=4)
-    host = _ragged(name, tile=4, device_state=False)
-    for uid, p in prompts.items():
-        device.put(uid, p, max_new_tokens=7)
-        host.put(uid, p, max_new_tokens=7)
-    assert host.generate_all() == device.generate_all()
+    host = ragged(name, tile=4, device_state=False)
+    assert _generate(host, prompts, 7) == \
+        _generate(ragged(name, tile=4), prompts, 7)
     assert host._tiled_jits and not host._dev_step_jits
 
 
 @pytest.mark.parametrize("name", ["gpt2", "mixtral", "deepseek",
                                   "deepseek_v32", "longcat_flash"])
-def test_a_failed_step_is_invisible_in_the_tokens(name):
+def test_a_failed_step_is_invisible_in_the_tokens(ragged, name):
     """One dispatch and, later, one readback fail mid-run: the watchdog
     rewinds to what was delivered and runs those positions again, which
     rewrites the same rows of every block leaf and picks the same tokens
@@ -127,7 +137,8 @@ def test_a_failed_step_is_invisible_in_the_tokens(name):
                                               get_fault_injector)
 
     prompts = _prompts(5, seed=11)
-    eng = _ragged(name, tile=4, retry_backoff_s=0.0)
+    eng = ragged(name, tile=4)
+    failures = eng.step_failures
     outs = {}
     for faulty in (False, True):   # one engine: the programs compile once
         if faulty:
@@ -138,28 +149,23 @@ def test_a_failed_step_is_invisible_in_the_tokens(name):
             kw = dict(temperature=0.8, top_k=20, seed=31 + uid) if uid % 2 \
                 else {}
             eng.put((faulty, uid), p, max_new_tokens=7, **kw)
-        outs[faulty] = {uid: toks for (run, uid), toks
-                        in eng.generate_all().items() if run is faulty}
+        out = eng.generate_all()
+        outs[faulty] = {uid: out[faulty, uid] for uid in prompts}
     assert outs[True] == outs[False]
-    assert eng.step_failures == 2 and eng.degraded_mode == 0
+    assert eng.step_failures - failures == 2 and eng.degraded_mode == 0
     assert eng.cfg.device_state and not eng._pending
     assert eng.allocator.free_blocks == eng.cfg.num_blocks - 1
 
 
-def test_mixtral_decode_routing_is_per_token():
+def test_mixtral_decode_routing_is_per_token(ragged):
     """Decode tokens of DIFFERENT sequences in one mixed batch must route
     independently: serving two different prompts together equals serving
     them alone (no cross-request routing contamination)."""
     prompts = _prompts(3, seed=23)
     solo = {}
     for uid, p in prompts.items():
-        eng = _ragged("mixtral")
-        eng.put(uid, p, max_new_tokens=6)
-        solo.update(eng.generate_all())
-    together = _ragged("mixtral")
-    for uid, p in prompts.items():
-        together.put(uid, p, max_new_tokens=6)
-    assert together.generate_all() == solo
+        solo.update(_generate(ragged("mixtral"), {uid: p}, 6))
+    assert _generate(ragged("mixtral"), prompts, 6) == solo
 
 
 # ------------------------------------------------------- the paged contract
@@ -328,10 +334,10 @@ class TestBlockPayloadsOnTheStorageForm:
     """Host-side code indexes blocks as ``a[:, ids]`` on ``[L, NB, ...]``;
     payloads have the pool's form on both ends."""
 
-    def test_gather_scatter_round_trip(self, name, quant):
+    def test_gather_scatter_round_trip(self, ragged, name, quant):
         import jax
 
-        eng = _ragged(name, quant=quant)
+        eng = ragged(name, quant=quant)
         eng.put("a", list(range(1, 12)), max_new_tokens=2)
         eng.generate_all()
         src, dst = [1, 2, 3], [9, 10, 11]
@@ -345,14 +351,13 @@ class TestBlockPayloadsOnTheStorageForm:
             a = np.asarray(leaf)
             np.testing.assert_array_equal(a[:, dst], a[:, src])
 
-    def test_handoff_export_import(self, name, quant):
+    def test_handoff_export_import(self, ragged, name, quant):
         prompt = list(range(3, 14))
-        want = _ragged(name, quant=quant)
-        want.put("h", prompt, max_new_tokens=6)
-        a, b = _ragged(name, quant=quant), _ragged(name, quant=quant)
+        want = _generate(ragged(name, quant=quant), {"h": prompt}, 6)
+        a, b = ragged(name, quant=quant), ragged(name, quant=quant, second=True)
         a.put("h", prompt, max_new_tokens=6, handoff=True)
         a.generate_all()
         record = a.export_handoff("h")
         assert record.n_blocks == 3 and record.codec == quant
         assert b.import_handoff(record)
-        assert b.generate_all()["h"] == want.generate_all()["h"]
+        assert b.generate_all()["h"] == want["h"]

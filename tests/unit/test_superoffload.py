@@ -41,23 +41,39 @@ def _engine(device, tmp_path, super_offload=False, frac=0.5):
     return engine
 
 
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """``trained(device, super_offload)``: ``(engine, its losses over
+    _batches(4))``, built and trained once a module: an engine here is its
+    compiles (25-35 s of CPU), and the cases that look at a SuperOffload
+    engine look at the one the parity cases trained."""
+    runs = {}
+
+    def get(device, super_offload):
+        key = (device, super_offload)
+        if key not in runs:
+            eng = _engine(device, tmp_path_factory.mktemp(device),
+                          super_offload=super_offload)
+            runs[key] = (eng, [float(eng.train_batch(b)) for b in _batches(4)])
+        return runs[key]
+
+    return get
+
+
 def _batches(n, seed=0):
     rng = np.random.default_rng(seed)
     return [{"input_ids": rng.integers(0, VOCAB, (32, 16), dtype=np.int32)}
             for _ in range(n)]
 
 
-def test_cpu_mixed_residency_parity(tmp_path):
+def test_cpu_mixed_residency_parity(trained):
     """SuperOffload residency must not change the update math."""
-    base = [float(_engine("cpu", tmp_path).train_batch(b)) for b in _batches(4)]
-    reset_topology()
-    so = [float(_engine("cpu", tmp_path, super_offload=True).train_batch(b))
-          for b in _batches(4)]
-    np.testing.assert_allclose(base, so, rtol=1e-6)
+    np.testing.assert_allclose(trained("cpu", False)[1], trained("cpu", True)[1],
+                               rtol=1e-6)
 
 
-def test_cpu_hbm_resident_group_count(tmp_path):
-    engine = _engine("cpu", tmp_path, super_offload=True, frac=0.5)
+def test_cpu_hbm_resident_group_count(trained):
+    engine, _ = trained("cpu", True)        # hbm_resident_fraction 0.5
     n_groups = len(engine._groups)
     assert n_groups >= 2
     # fraction of groups use the device sharding for storage (on backends
@@ -67,20 +83,16 @@ def test_cpu_hbm_resident_group_count(tmp_path):
     assert dev_like >= int(round(0.5 * n_groups))
 
 
-def test_nvme_speculative_parity(tmp_path):
+def test_nvme_speculative_parity(trained):
     """The speculative (sync-free) walk computes exactly the blocking walk."""
-    batches = _batches(4)
-    base = [float(_engine("nvme", tmp_path / "a").train_batch(b)) for b in batches]
-    reset_topology()
-    spec = [float(_engine("nvme", tmp_path / "b", super_offload=True).train_batch(b))
-            for b in batches]
-    np.testing.assert_allclose(base, spec, rtol=1e-6)
+    np.testing.assert_allclose(trained("nvme", False)[1],
+                               trained("nvme", True)[1], rtol=1e-6)
 
 
-def test_group_apply_overflow_guard(tmp_path):
+def test_group_apply_overflow_guard(trained):
     """finite=False must write back unchanged params + state (the on-device
     equivalent of the reference's speculative-step rollback)."""
-    engine = _engine("nvme", tmp_path, super_offload=True)
+    engine, _ = trained("nvme", True)
     apply_g = engine._group_apply(0)
     pg = (jnp.ones((8,), jnp.float32),)
     state = engine.optimizer.init(pg)

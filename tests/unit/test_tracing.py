@@ -37,10 +37,12 @@ def _batch(n=16):
 def test_trace_window_produces_capture(tmp_path):
     trace_dir = str(tmp_path / "trace")
     engine = _engine(tmp_path, {
+        # the window opens after the step program's two compiles: a compile
+        # inside it is a minute of the profiler's Python events
         "tracing": {"enabled": True, "trace_dir": trace_dir,
-                    "start_step": 1, "num_steps": 2},
+                    "start_step": 2, "num_steps": 2},
     })
-    for _ in range(4):
+    for _ in range(5):
         engine.train_batch(_batch())
     engine.step_tracer.close()
     # a profile capture landed on disk (xplane proto under plugins/profile)
@@ -55,8 +57,10 @@ def test_capture_survives_raising_step(tmp_path):
     trace_dir = str(tmp_path / "trace")
     engine = _engine(tmp_path, {
         "tracing": {"enabled": True, "trace_dir": trace_dir,
-                    "start_step": 0, "num_steps": 2},
+                    "start_step": 2, "num_steps": 2},
     })
+    for _ in range(2):      # the step program's two compiles, outside
+        engine.train_batch(_batch())
     orig = engine._put_gas_batch
 
     def boom(batch):

@@ -117,3 +117,37 @@ class TestSanitizeXlaFlags:
         got = xf.sanitize_xla_flags(flags, target_platform="tpu",
                                     use_cache=False)
         assert got == flags
+
+
+@pytest.mark.parametrize("sleep_s,ends", [(30, False), (0, True)],
+                         ids=["past_the_limit", "inside_the_limit"])
+def test_a_test_past_the_limit_ends_with_every_threads_stack(tmp_path, sleep_s,
+                                                             ends):
+    """``tests/conftest.py``'s limit on one test: past it the process writes
+    every thread's stack and exits non-zero (a wedged collective sleeps in
+    C++ just as ``time.sleep`` does); inside it nothing is seen."""
+    import os
+    import sys
+
+    conftest = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "conftest.py")
+    case = tmp_path / "test_sleeps.py"
+    case.write_text(f"import time\ndef test_sleeps(): time.sleep({sleep_s})\n")
+    driver = (
+        "import importlib.util, sys, pytest\n"
+        f"spec = importlib.util.spec_from_file_location('limit_conftest', {conftest!r})\n"
+        "mod = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(mod)\n"
+        "mod.TEST_LIMIT_S = 1\n"
+        f"sys.exit(pytest.main([{str(case)!r}, '-q', '-p', 'no:cacheprovider'],"
+        " plugins=[mod]))\n")
+    done = subprocess.run([sys.executable, "-c", driver], capture_output=True,
+                          text=True, timeout=120, cwd=tmp_path)
+    if ends:
+        assert done.returncode == 0, done.stdout + done.stderr
+        assert "Timeout (" not in done.stderr
+    else:
+        assert done.returncode != 0
+        assert "Timeout (0:00:01)!" in done.stderr
+        assert "most recent call first" in done.stderr
+        assert "test_sleeps.py" in done.stderr
