@@ -323,14 +323,17 @@ def _mla_inputs(cfg: DeepseekConfig, h, lp, positions, cq=None):
     lat)`` under ``mla_scale_kv_lora``: what is cached and what ``W_kvb``
     multiplies), roped ``k_rope`` [T, rope] (both ``rope`` parts as
     projected, unrotated, under ``mla_use_nope``). ``cq``:
-    ``_query_latent``'s, where the caller has it."""
-    t = h.shape[0]
+    ``_query_latent``'s, where the caller has it. The query goes to its heads
+    through the paged contract's ``rows_to_heads`` (the plain pass shares
+    these lines and so its pin, which changes no value there either)."""
+    from deepspeed_tpu.models.paged import rows_to_heads
+
     lat, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     if cfg.q_lora_rank is None:
-        q = h @ lp["wq"]
+        q = rows_to_heads(h, lp["wq"], cfg.num_heads)
     else:
-        q = (_query_latent(cfg, h, lp) if cq is None else cq) @ lp["wq_b"]
-    q = q.reshape(t, cfg.num_heads, cfg.qk_head_dim)
+        q = rows_to_heads(_query_latent(cfg, h, lp) if cq is None else cq,
+                          lp["wq_b"], cfg.num_heads)
     a = h @ lp["wkv_a"]
     c = rmsnorm(a[:, :lat], lp["kv_norm"], cfg.rms_norm_eps)
     if cfg.mla_scale_kv_lora:

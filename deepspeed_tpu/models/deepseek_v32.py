@@ -159,9 +159,10 @@ def _indexer_inputs(cfg: DeepseekV32Config, h, cq, lp, positions):
     """``h`` [T, D] (normed), ``cq`` [T, q_lora_rank] -> the roped index
     queries [T, HI, DI], the roped index key [T, DI], the heads' weights
     [T, HI] float32 with both scale factors in."""
-    t = h.shape[0]
+    from deepspeed_tpu.models.paged import rows_to_heads
+
     hi, di, rope = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
-    q = (cq @ lp["wi_q"]).reshape(t, hi, di)
+    q = rows_to_heads(cq, lp["wi_q"], hi)
     k = (h @ lp["wi_k"]).astype(jnp.float32)
     k = k - jnp.mean(k, axis=-1, keepdims=True)
     k = k * lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)

@@ -218,6 +218,7 @@ def _ragged_block(cfg: GPT2Config, x, lp, kc, vc, positions, slots,
                   block_tables, prefill_tiles=None):
     from deepspeed_tpu.models.paged import (
         ragged_pool_attention,
+        rows_to_heads,
         write_kv_paged,
     )
     from deepspeed_tpu.ops.quantizer import dequantize_layer
@@ -225,9 +226,9 @@ def _ragged_block(cfg: GPT2Config, x, lp, kc, vc, positions, slots,
     lp = dequantize_layer(lp, x.dtype)
     t_tokens, d = x.shape
     h = layernorm(x, lp["ln1_g"], lp["ln1_b"], cfg.layer_norm_eps)
-    q = (h @ lp["wq"] + lp["bq"]).reshape(t_tokens, cfg.num_heads, cfg.hd)
-    kk = (h @ lp["wk"] + lp["bk"]).reshape(t_tokens, cfg.num_heads, cfg.hd)
-    vv = (h @ lp["wv"] + lp["bv"]).reshape(t_tokens, cfg.num_heads, cfg.hd)
+    q = rows_to_heads(h, lp["wq"], cfg.num_heads, lp["bq"])
+    kk = rows_to_heads(h, lp["wk"], cfg.num_heads, lp["bk"])
+    vv = rows_to_heads(h, lp["wv"], cfg.num_heads, lp["bv"])
     kc, vc = write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables,
                             prefill_tiles)
     o = ragged_pool_attention(q, kc, vc, slots, positions, block_tables,

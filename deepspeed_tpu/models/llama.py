@@ -289,6 +289,7 @@ def _ragged_layer(cfg: LlamaConfig, x, lp, kc, vc, positions, slots,
     """
     from deepspeed_tpu.models.paged import (
         ragged_pool_attention,
+        rows_to_heads,
         write_kv_paged,
     )
 
@@ -297,9 +298,9 @@ def _ragged_layer(cfg: LlamaConfig, x, lp, kc, vc, positions, slots,
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
 
     h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q = (h @ lp["wq"]).reshape(t_tokens, hq, hd)
-    kk = (h @ lp["wk"]).reshape(t_tokens, hkv, hd)
-    vv = (h @ lp["wv"]).reshape(t_tokens, hkv, hd)
+    q = rows_to_heads(h, lp["wq"], hq)
+    kk = rows_to_heads(h, lp["wk"], hkv)
+    vv = rows_to_heads(h, lp["wv"], hkv)
     q, kk = apply_rope(q[None], kk[None], positions[None], cfg.rope_theta)
     q, kk = q[0], kk[0]
 

@@ -259,6 +259,7 @@ def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
     generates by blocks (``models/paged.py``, *Blocks of rows*)."""
     from deepspeed_tpu.models.paged import (
         ragged_pool_attention,
+        rows_to_heads,
         write_kv_paged,
     )
     from deepspeed_tpu.ops.quantizer import dequantize_layer
@@ -268,9 +269,9 @@ def _ragged_layer(cfg: MixtralConfig, x, lp, kc, vc, positions, slots,
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
 
     h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q = (h @ lp["wq"]).reshape(t_tokens, hq, hd)
-    kk = (h @ lp["wk"]).reshape(t_tokens, hkv, hd)
-    vv = (h @ lp["wv"]).reshape(t_tokens, hkv, hd)
+    q = rows_to_heads(h, lp["wq"], hq)
+    kk = rows_to_heads(h, lp["wk"], hkv)
+    vv = rows_to_heads(h, lp["wv"], hkv)
     if qk_norm:
         q = rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
         kk = rmsnorm(kk, lp["k_norm"], cfg.rms_norm_eps)

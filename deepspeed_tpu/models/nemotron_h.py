@@ -490,13 +490,17 @@ def init_paged_cache(cfg: NemotronHConfig, num_blocks: int, block_size: int,
 
 def _attn_ragged(cfg: NemotronHConfig, x, lp, pool, layer_tables, slots,
                  positions, prefill_tiles):
-    from deepspeed_tpu.models.paged import ragged_pool_attention, write_kv_paged
+    from deepspeed_tpu.models.paged import (
+        ragged_pool_attention,
+        rows_to_heads,
+        write_kv_paged,
+    )
 
     t = x.shape[0]
     h = rmsnorm(x, lp["norm"], cfg.rms_norm_eps)
-    q = (h @ lp["wq"]).reshape(t, cfg.num_heads, cfg.head_dim)
-    kk = (h @ lp["wk"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
-    vv = (h @ lp["wv"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
+    q = rows_to_heads(h, lp["wq"], cfg.num_heads)
+    kk = rows_to_heads(h, lp["wk"], cfg.num_kv_heads)
+    vv = rows_to_heads(h, lp["wv"], cfg.num_kv_heads)
     kc, vc = write_kv_paged(pool["k"], pool["v"], kk, vv, slots, positions,
                             layer_tables, prefill_tiles)
     o = ragged_pool_attention(q, kc, vc, slots, positions, layer_tables,

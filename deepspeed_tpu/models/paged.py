@@ -122,6 +122,17 @@ v1-style engines.
   finished, leaves there what later blocks read. A pool block and a prefill
   tile are multiples of ``B``, so a prompt's whole blocks are cached by tiles
   under the same mask and no block of rows straddles a pool block.
+- *Rows to heads.* A step program reads a layer's weights where the stack
+  keeps them and re-lays none of them out. A ragged layer projects its rows
+  to heads through ``rows_to_heads``, which pins the product to the layout
+  the stored ``[D, heads x head_dim]`` weight gives it: what follows (a
+  rotation's halves, a head norm, a kernel's wrapper) reads the product
+  head-major, and left to itself the compiler pays for that on the WEIGHT,
+  sliced out of its stack and transposed in every layer of every step (32 MB
+  of ``wq`` in Mixtral, for a product of 3 MB at 392 rows and 32 KB at 4:
+  PERF.md section 6, PR 50), where pinned it copies the rows.
+  ``tests/unit/test_compile_tpu.py::test_step_program_relays_out_no_projection_weight``
+  holds every family's compiled step to it, and is the check for the next.
 """
 
 from __future__ import annotations
@@ -545,6 +556,22 @@ def write_kv_paged(kc, vc, kk, vv, slots, positions, block_tables,
                              prefill_tiles),
             write_rows_paged(vc, vv, slots, positions, block_tables,
                              prefill_tiles))
+
+
+# what holds a product to its stored weight's layout (the contract's *Rows to
+# heads*); it changes no value, which a test shows by taking it out
+_pin = lax.optimization_barrier
+
+
+def rows_to_heads(h, w, heads: int, bias=None):
+    """The rows ``h`` [T, D] times a projection's weight as it is stored,
+    ``w`` [D, heads x head_dim] (plus ``bias``), as ``[T, heads, head_dim]``.
+    The product is pinned before the reshape, so that the layout its consumer
+    wants is paid for on the ``T`` rows and never on the weight."""
+    y = h @ w
+    if bias is not None:
+        y = y + bias
+    return _pin(y).reshape(h.shape[0], heads, -1)
 
 
 def _decode_then_tiles(q, slots, positions, prefill_tiles, decode, prefill):

@@ -248,12 +248,14 @@ def layers_in_order(cfg: SmallThinkerConfig, params):
 def _qkv(cfg: SmallThinkerConfig, kind: str, h, lp, positions):
     """The layer's projections of the normed rows ``h`` [T, D]: ``q`` [T,
     Hq, hd], ``k`` / ``v`` [T, Hkv, hd], rotated where the layer's
-    ``rope_layout`` says so (``kind`` ``W`` / ``f``)."""
-    t = h.shape[0]
-    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = (h @ lp["wq"]).reshape(t, hq, hd)
-    k = (h @ lp["wk"]).reshape(t, hkv, hd)
-    v = (h @ lp["wv"]).reshape(t, hkv, hd)
+    ``rope_layout`` says so (``kind`` ``W`` / ``f``). Through the paged
+    contract's ``rows_to_heads``; the plain ``forward`` shares it and so its
+    pin, which changes no value there either."""
+    from deepspeed_tpu.models.paged import rows_to_heads
+
+    q = rows_to_heads(h, lp["wq"], cfg.num_heads)
+    k = rows_to_heads(h, lp["wk"], cfg.num_kv_heads)
+    v = rows_to_heads(h, lp["wv"], cfg.num_kv_heads)
     if kind in "Wf":
         q, k = apply_rope(q[None], k[None], positions[None], cfg.rope_theta)
         q, k = q[0], k[0]

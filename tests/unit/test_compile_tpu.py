@@ -902,6 +902,173 @@ def test_latent_step_reads_the_pool_once(v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
 
 
+# ------------------------------------------------ rows to heads (PR 50)
+def _row_major(v5e):
+    """``pin(tree)``: ``tree``'s shapes on the first described chip in the
+    row-major layout an engine's own arrays have. Left to itself the compiler
+    gives a described program's arguments layouts of its own choosing (GPT-2
+    XL's ``wte`` comes in column-major and is copied for the row gather: a
+    copy no chip run has)."""
+    from jax.experimental.layout import Format, Layout
+
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def pin(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=Format(
+                Layout(major_to_minor=tuple(range(len(a.shape)))), dev)),
+            tree)
+
+    return pin
+
+
+def _projection_results(text, params, names):
+    """``(weight's name, line)`` of every result of the optimized HLO that is
+    a projection weight in a buffer of its own: bf16, as many elements as one
+    layer of a stacked ``[L, a, b]`` leaf called one of ``names``, ``a`` or
+    ``b`` among its dimensions (``[1, a, b]``, a transpose, the output axis
+    split into heads: no array of a step's rows is that), and computed by the
+    step. The compiler's own prefetches (``slice-start`` / ``copy-start`` into
+    its nearer memory ``S(1)``, their ``-done``, the ``ConcatBitcast`` that
+    joins them) keep the stored layout and run beside the products: they are
+    not counted, and neither is a kernel's or a scatter's in-place operand."""
+    want = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if getattr(path[-1], "key", None) in names and len(leaf.shape) == 3:
+            want[leaf.shape[1:]] = path[-1].key
+    out = []
+    for _, op, ln in _materialized(text):
+        shape = re.match(r"bf16\[([\d,]*)\]", ln.split(" = ")[1])
+        if (op in _IN_PLACE or op.endswith("-done") or op == "custom-call"
+                or not shape):
+            continue
+        dims = [int(n) for n in shape.group(1).split(",") if n]
+        out += [(name, ln) for (a, b), name in want.items()
+                if math.prod(dims) == a * b and (a in dims or b in dims)]
+    return out
+
+
+def _relayout_family(name):
+    """``(module, config, pool blocks or (full, sliding), decode slots,
+    table width, projections, those known to be re-laid out still)`` at the
+    serving cells' attention widths; depth, FFN, experts and vocabulary
+    small."""
+    from deepspeed_tpu.models import deepseek, smallthinker
+
+    if name == "mixtral":
+        mod, cfg, blocks, _, table = _step_family(name)
+        return mod, cfg, blocks, 33, table, ("wq", "wk", "wv", "wo"), ()
+    if name == "smallthinker":   # smallthinker-21b-a3b-ep8.json: F W W W
+        return smallthinker, smallthinker.SmallThinkerConfig(
+            vocab_size=512, hidden_size=2560, moe_intermediate_size=128,
+            num_layers=8, num_heads=SWA_HEADS, num_kv_heads=SWA_KV,
+            head_dim=SWA_D, num_experts=8, top_k=2,
+            sliding_window=SWA_WINDOW, max_seq_len=8192), SWA_POOLS, 17, \
+            SWA_TABLE, ("wq", "wk", "wv", "wo"), ()
+    # deepseek-v32-exp-d5-ep16.json's query: 1,536 -> 128 heads of 192. The
+    # absorbed products' ``wkv_b`` is a batched product over heads, which
+    # wants the head axis outermost: sliced and transposed still (ROADMAP S13)
+    return deepseek, deepseek.DeepseekConfig(
+        vocab_size=512, intermediate_size=256, moe_intermediate_size=128,
+        num_layers=3, num_heads=DSA_HEADS, q_lora_rank=1536, num_experts=4,
+        top_k=2), 1025, 17, DSA_TABLE, (
+            "wq_a", "wq_b", "wkv_a", "wkv_b", "wo"), ("wkv_b",)
+
+
+@pytest.mark.parametrize("family,rows,tiles", [
+    ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
+    ("mla", 16, 1)])
+def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
+                                                      rows, tiles):
+    """The paged contract's *Rows to heads* (``models/paged.py``), on the
+    compiled program with the arguments laid out as an engine's are: a step
+    reads a layer's projection weights where the stack keeps them. Before
+    PR 50 the q and k products came out head-major for the rotation and the
+    compiler paid for that on the weights: in every rotated layer's body
+    ``%constant_dynamic-slice_fusion = bf16[1,4096,4096]{2,1,0}`` (a layer's
+    ``wq`` sliced out of the stack) and ``%copy = bf16[1,4096,4096]{1,2,0}``
+    (transposed), the same for ``wk``: 5.2% of the window cell's device time
+    (ledger, PR 49). ``paged.rows_to_heads`` pins the product and the rows
+    are copied instead. The check for the next family: give it a case."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mod, cfg, blocks, slots, table, names, known = _relayout_family(family)
+    window = isinstance(blocks, tuple)
+    pin = _row_major(v5e)
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        mod.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: mod.init_paged_cache(
+        cfg, blocks[0] if window else blocks, 128, jnp.bfloat16,
+        num_slots=slots))
+
+    def i32(*shape):
+        return pin(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return mod.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE) if tiles else None)
+
+    t, n = rows + tiles * TILE, max(tiles, 1)
+    bt = i32(slots, table)
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        pin(params), pin(cache), i32(t), i32(t), i32(t),
+        (bt, bt) if window else bt, i32(n), i32(n), i32(n)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+    found = _projection_results(text, params, names)
+    assert [ln for name, ln in found if name not in known] == []
+    # what is known to stay is still there: whoever cures it says so here
+    assert {name for name, _ in found} == set(known)
+
+
+def test_a_table_kept_column_major_is_copied_for_its_row_gather(v5e,
+                                                                monkeypatch):
+    """GPT-2 XL's chat step held ``copy bf16[50257,1600]`` every step, 7.6% of
+    it (ledger, PR 49), and it is not the tied head's: a v5e keeps an array
+    whose rows are no whole number of 128-lane tiles column-major
+    (``major_to_minor=(1, 0)``: ``wte`` and ``wpe`` at 1,600 lanes, as
+    ``jax.jit`` hands them back on the chip), the head's product reads that in
+    place, and the token GATHER wants contiguous rows and copies the table for
+    them. The same step with ``wte`` row-major copies nothing of its size:
+    0.73 -> 0.41 ms for gather + head on the chip (PERF.md section 6, PR 50;
+    what giving the engine's tables that layout costs at set-up is ROADMAP
+    S5's to settle)."""
+    from jax.experimental.layout import Format, Layout
+
+    from deepspeed_tpu.models import gpt2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = gpt2.GPT2Config(vocab_size=50257, hidden_size=1600, num_layers=2,
+                          num_heads=25)
+    pin = _row_major(v5e)
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+    params = pin(jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        gpt2.init_params(cfg, jax.random.PRNGKey(0)))))
+    cache = pin(jax.eval_shape(lambda: gpt2.init_paged_cache(
+        cfg, 513, 32, jnp.bfloat16)))
+    rows = pin(jax.ShapeDtypeStruct((4,), jnp.int32))
+    tables = pin(jax.ShapeDtypeStruct((33, 32), jnp.int32))
+
+    def step(params, cache, tokens, slots, positions, tables):
+        logits, cache = gpt2.ragged_forward(cfg, params, tokens, slots,
+                                            positions, tables, cache)
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1), cache
+
+    def copies(params):
+        text = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, rows, rows, rows, tables).compile().as_text()
+        return [ln for size, op, ln in _materialized(text)
+                if op == "copy" and size >= 50257 * 1600 * 2]
+
+    assert copies(params) == []
+    as_the_chip_keeps_it = {**params, "wte": jax.ShapeDtypeStruct(
+        (50257, 1600), jnp.bfloat16,
+        sharding=Format(Layout(major_to_minor=(1, 0)), dev))}
+    (copy,) = copies(as_the_chip_keeps_it)
+    assert "bf16[50257,1600]{1,0" in copy and "params[" in copy
+
+
 # ------------------------------------------------- the contract's slot leaves
 def _tiled_bytes(shape, layout, itemsize):
     """Bytes an array of ``shape`` occupies as the compiler lays it out:
@@ -1479,9 +1646,12 @@ def test_window_step_holds_no_layer_slice_of_either_pool(v5e, monkeypatch):
     """The paged contract with sliding leaves: a step of 16 decode rows beside
     a tile writes each pool's rows by in-place scatters (K and V, twice) and
     reads both through their tables; nothing else is as large as a layer's
-    slice of EITHER pool, no layer's weights are copied out of their stack
-    (the four-body period: ``models/smallthinker.py``), and all four
-    attention kernels are in it under their names."""
+    slice of EITHER pool (67 MB: at this hidden size of 256 every weight of
+    the four-body period, ``models/smallthinker.py``, passes under that;
+    that no layer's weights are copied out of their stack is
+    ``test_step_program_relays_out_no_projection_weight``'s to hold, at the
+    cell's 2,560), and all four attention kernels are in it under their
+    names."""
     from deepspeed_tpu.models import smallthinker
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret

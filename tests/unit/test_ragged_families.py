@@ -361,3 +361,60 @@ class TestBlockPayloadsOnTheStorageForm:
         assert record.n_blocks == 3 and record.codec == quant
         assert b.import_handoff(record)
         assert b.generate_all()["h"] == want["h"]
+
+
+# ----------------------------------------------- rows to heads, and its pin
+from deepspeed_tpu.models import (kimi_linear, nemotron_h,  # noqa: E402
+                                  sdar, smallthinker)
+
+# every family with a ragged step; SDAR's decoding sequence is a block of 4 rows
+PINNED = {**FAMILIES, **LATENT,
+          "nemotron_h": (nemotron_h, nemotron_h.NemotronHConfig.tiny()),
+          "kimi_linear": (kimi_linear, kimi_linear.KimiLinearConfig.tiny()),
+          "smallthinker": (smallthinker,
+                           smallthinker.SmallThinkerConfig.tiny()),
+          "sdar": (sdar, sdar.SdarConfig.tiny(89))}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_the_pin_of_rows_to_heads_changes_no_value(name, monkeypatch):
+    """``paged.rows_to_heads`` holds a projection's product to the layout of
+    its stored weight with an optimization barrier, which is no arithmetic:
+    one mixed step a family (two decoding sequences beside a three-token
+    prompt in a tile, over a cache of random rows) gives the same logits and
+    the same cache, to the last bit, with the pin taken out."""
+    import jax
+
+    from deepspeed_tpu.models import paged
+
+    mod, cfg = PINNED[name]
+    per = 4 if name == "sdar" else 1
+    params = _params(mod, cfg)
+    rng = np.random.default_rng(23)
+    cache = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        mod.init_paged_cache(cfg, NB, BS, jnp.float32, num_slots=PAD_ROW + 1))
+    # slot 0 decodes at position 8 (sdar: its block 8..11), slot 1 at 4
+    slots = [0] * per + [1] * per + [2, 2, 2, PAD_ROW]
+    pos = [8 + j for j in range(per)] + [4 + j for j in range(per)] + [0, 1, 2, 0]
+    toks = list(rng.integers(1, 88, 2 * per + 3)) + [0]
+    i32 = lambda a: jnp.asarray(a, jnp.int32)   # noqa: E731
+    tiles = (2 * per, i32([2]), i32([0]), i32([3]), TILE)
+    bt = jnp.asarray(TABLES)
+    tables = (bt, bt) if paged.SWA in cache else bt   # (full, sliding)
+
+    def step():
+        logits, after, *_ = mod.ragged_forward(
+            cfg, params, i32(toks), i32(slots), i32(pos), tables, cache,
+            prefill_tiles=tiles)
+        return [np.asarray(a) for a in
+                jax.tree_util.tree_leaves((logits, after))]
+
+    pinned = step()
+    calls = []
+    monkeypatch.setattr(paged, "_pin", lambda y: calls.append(y) or y)
+    plain = step()
+    assert calls, "the family's ragged step projects no rows through the helper"
+    for a, b in zip(pinned, plain):
+        np.testing.assert_array_equal(a, b)
+    assert np.isfinite(pinned[0]).all() and pinned[0].any()

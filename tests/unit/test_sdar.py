@@ -39,11 +39,11 @@ ATOL = 5e-4
 # ----------------------------------------- (e) the family the fixtures lack
 def smallthinker_step_digests() -> dict:
     """``{form: sha256 of smallthinker's device step program's jaxpr}``
-    (``test_smallthinker.step_jaxpr_digests``' program and text, for the one
-    family its fixtures do not hold)."""
+    (``test_smallthinker.step_jaxpr_digests``' program and text, its pin
+    taken out likewise, for the one family its fixtures do not hold)."""
     from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                                 RaggedInferenceEngine)
-    from deepspeed_tpu.models import smallthinker
+    from deepspeed_tpu.models import paged, smallthinker
     from deepspeed_tpu.ops import attention
 
     def abstract(tree):
@@ -53,7 +53,8 @@ def smallthinker_step_digests() -> dict:
     cfg = smallthinker.SmallThinkerConfig.tiny()
     rc = RaggedConfig(max_tokens_per_step=32, max_seqs=8, block_size=8,
                       num_blocks=33, max_blocks_per_seq=8, prefill_tile=8)
-    was, out = attention._on_tpu, {}
+    was, pin, out = attention._on_tpu, paged._pin, {}
+    paged._pin = lambda y: y
     try:
         for form in ("xla", "pallas"):
             attention._on_tpu = lambda form=form: form == "pallas"
@@ -74,7 +75,7 @@ def smallthinker_step_digests() -> dict:
             out[form] = {"smallthinker": hashlib.sha256(
                 re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()}
     finally:
-        attention._on_tpu = was
+        attention._on_tpu, paged._pin = was, pin
     return out
 
 

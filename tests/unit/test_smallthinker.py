@@ -69,17 +69,21 @@ def step_jaxpr_digests(on_tpu: bool) -> dict:
     decode rows beside two 8-row tiles, a tiny config, abstract arguments):
     with ``on_tpu`` the attention dispatchers believe they are on the chip,
     so the Pallas kernels are in it (their bodies too); without, XLA's
-    forms."""
+    forms. ``paged.rows_to_heads``' pin (PR 50) is taken out: it is one
+    ``optimization_barrier`` a projection and no arithmetic
+    (``test_the_pin_of_rows_to_heads_changes_no_value``), and without it
+    PR 50's tree still prints PR 48's programs to the letter."""
     from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                                 RaggedInferenceEngine)
+    from deepspeed_tpu.models import paged
     from deepspeed_tpu.ops import attention
 
     def abstract(tree):
         return jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
 
-    was = attention._on_tpu
-    attention._on_tpu = lambda: on_tpu
+    was, pin = attention._on_tpu, paged._pin
+    attention._on_tpu, paged._pin = (lambda: on_tpu), (lambda y: y)
     out = {}
     try:
         for name, (mod, cfg) in _tiny_families().items():
@@ -104,7 +108,7 @@ def step_jaxpr_digests(on_tpu: bool) -> dict:
             text = re.sub(r"0x[0-9a-f]+", "0x", text)
             out[name] = hashlib.sha256(text.encode()).hexdigest()
     finally:
-        attention._on_tpu = was
+        attention._on_tpu, paged._pin = was, pin
     return out
 
 
