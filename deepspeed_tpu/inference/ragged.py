@@ -766,6 +766,7 @@ class RaggedInferenceEngine:
         self._win_reserved = 0
         self.window_blocks_slid = 0
         self._win_step = (0, 0, 0)
+        self._slot_resets = 0    # slots the step being packed starts from zeros
         # the rows of the step packed last that the pool's write site takes
         # as slices (``_note_pool_rows``)
         self._pool_slice_rows = 0
@@ -2757,7 +2758,7 @@ class RaggedInferenceEngine:
         misses = self._cache_misses() if probe else 0
         if self._faults.enabled:
             self._faults.fire(POINT_DISPATCH)
-        state = self._state_attr(n_dec, n_pre, n, nt)
+        state = self._state_attr(n_dec, n_pre, n, nt, nd)
         with span("engine/dispatch",
                   program=self._step_program_name(t_total, nd, nt),
                   tokens=n, pad=t_total - n,
@@ -2780,6 +2781,16 @@ class RaggedInferenceEngine:
             moved.inc(state["dec_state_bytes"], part="decode", **kind)
             moved.inc(state["state_bytes"] - state["dec_state_bytes"],
                       part="prefill", **kind)
+            self.telemetry.counter(
+                "inference_slot_state_pad_rows_total",
+                "rows of the steps' decode buckets that were padding: they "
+                "name the scratch slot and move its state all the same").inc(
+                    state["state_pad_rows"], **kind)
+            self.telemetry.counter(
+                "inference_slot_resets_total",
+                "slots the steps started from zeros (a sequence's first "
+                "tile: an arrival, or a preempted request run again)").inc(
+                    state["slot_resets"], **kind)
         if sel and self.telemetry.enabled:
             tel = self.telemetry
             tel.counter("inference_sparse_selected_tokens_total",
@@ -2892,7 +2903,8 @@ class RaggedInferenceEngine:
         return {} if form is None else {
             "sel_decode": form(width * self.cfg.block_size)}
 
-    def _state_attr(self, n_dec: int, n_pre: int, n: int, nt: int) -> dict:
+    def _state_attr(self, n_dec: int, n_pre: int, n: int, nt: int,
+                    nd: int) -> dict:
         """For a model with slot state, what of it a step moves:
         ``state_bytes``, the slot-state bytes the step must read and write
         (its decode rows and its distinct prefilling slots, a slot's state
@@ -2901,10 +2913,17 @@ class RaggedInferenceEngine:
         recurrence, ``chunk_tiles``, the tiles the step program runs the
         recurrence's chunk form over, a layer (padding tiles too: a tile is
         the same work whatever it holds; ``kda_chunk``'s grid is heads x
-        these), and ``state_kind``, the recurrence's name where the model
-        gives one (``ModelSpec.state_kind``: ``"mamba2"``, ``"kda"``). The
-        dispatch feeds the same bytes to ``inference_slot_state_bytes_total``
-        (``part`` ``decode`` / ``prefill``). Nothing for a model with none."""
+        these), ``state_pad_rows``, the rows of the program's decode bucket
+        (``nd``) past its ``n_dec`` real ones (they name the scratch slot and
+        the decode kernel moves its state like any row's), ``slot_resets``,
+        the slots the step starts from zeros (the sequences whose first tile
+        it carries: the arrivals since the last dispatch, and a preempted
+        request run again), and ``state_kind``, the recurrence's name where
+        the model gives one (``ModelSpec.state_kind``: ``"mamba2"``,
+        ``"kda"``). The dispatch feeds the same to
+        ``inference_slot_state_bytes_total`` (``part`` ``decode`` /
+        ``prefill``), ``inference_slot_state_pad_rows_total`` and
+        ``inference_slot_resets_total``. Nothing for a model with none."""
         if not self._slot_state:
             return {}
         per_slot = 2 * self._slot_bytes
@@ -2913,6 +2932,8 @@ class RaggedInferenceEngine:
                 "dec_state_bytes": n_dec * per_slot,
                 "ssm_prefill_tokens": n - n_dec,
                 "chunk_tiles": nt,
+                "state_pad_rows": nd - n_dec,
+                "slot_resets": self._slot_resets,
                 **({} if kind is None else {"state_kind": kind})}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:
@@ -3011,6 +3032,10 @@ class RaggedInferenceEngine:
             ts = np.full(max(nt, 1), cfg.max_seqs, np.int32)
             tpz = np.zeros(max(nt, 1), np.int32)
             tv = np.zeros(max(nt, 1), np.int32)
+            # read by ``_state_attr`` for a model with slot state, which
+            # always packs tiles (``prefill_tile=0`` is refused beside slot
+            # leaves and the ladder of degraded modes keeps its tiles)
+            self._slot_resets = sum(seq.pos == 0 for seq, _, _ in chunks)
             for seq, tile0, take in chunks:
                 start = nd * per + tile0 * ct
                 sl = slice(start, start + take)

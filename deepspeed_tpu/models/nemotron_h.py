@@ -6,7 +6,8 @@ in the order of the published ``hybrid_override_pattern``.
 Every layer is ``x <- x + mixer(RMSNorm(x))``; a final RMSNorm and an untied
 head follow. No bias anywhere but the convolution's.
 
-- **M, Mamba-2.** ``[z | xBC | dt] = h W_in`` (widths ``d_inner`` | ``d_inner
+- **M, Mamba-2** (``models/mamba2.py``, shared with ``granite_hybrid``).
+  ``[z | xBC | dt] = h W_in`` (widths ``d_inner`` | ``d_inner
   + 2 G N`` | ``H``); ``xBC <- silu(causal depthwise conv_K(xBC) + b)``; split
   ``x`` [H, P], ``B``, ``C`` [G, N] (``H / G`` heads share a group's);
   ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; ``S_t = exp(dt_t
@@ -60,8 +61,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
+from deepspeed_tpu.models import mamba2
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx, causal_lm_loss
 from deepspeed_tpu.models.experts import (
     expert_form,
@@ -167,8 +168,7 @@ class NemotronHConfig:
 
 
 def init_params(cfg: NemotronHConfig, rng) -> dict:
-    d, di, cw = cfg.hidden_size, cfg.d_inner, cfg.conv_width
-    h, k_conv = cfg.mamba_num_heads, cfg.conv_kernel
+    d = cfg.hidden_size
     lat, f, fs = (cfg.moe_latent_size, cfg.moe_intermediate_size,
                   cfg.moe_shared_expert_intermediate_size)
     la, lm, le = (cfg.layers_of(c) for c in "*ME")
@@ -185,13 +185,8 @@ def init_params(cfg: NemotronHConfig, rng) -> dict:
     def norm(key, *shape, s=std):
         return jax.random.normal(key, shape, jnp.float32) * s
 
-    # dt spread log-uniformly over [time_step_min, time_step_max] a head
-    # (dt_bias its inverse softplus), A over [1, 16], as Mamba-2 initialises
-    # them: decay, gate and bias all matter from the first token
-    dt0 = jnp.exp(jax.random.uniform(next(k), (lm, h), jnp.float32)
-                  * (jnp.log(cfg.time_step_max) - jnp.log(cfg.time_step_min))
-                  + jnp.log(cfg.time_step_min))
-    dt0 = jnp.maximum(dt0, cfg.time_step_floor)
+    # drawn first, used by the Mamba layers' draws below (``mamba2.init_mixer``)
+    k_dt = next(k)
     return {
         "embed": norm(next(k), cfg.vocab_size, d),
         "attn": {
@@ -203,16 +198,7 @@ def init_params(cfg: NemotronHConfig, rng) -> dict:
         },
         "mamba": {
             "norm": jnp.ones((lm, d), jnp.float32),
-            "w_in": norm(next(k), lm, d, di + cw + h),
-            "conv_w": jax.random.uniform(next(k), (lm, k_conv, cw), jnp.float32,
-                                         -1.0, 1.0) * k_conv ** -0.5,
-            "conv_b": norm(next(k), lm, cw, s=0.1),
-            "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
-            "a_log": jnp.log(jax.random.uniform(next(k), (lm, h), jnp.float32,
-                                                1.0, 16.0)),
-            "d_skip": 1.0 + norm(next(k), lm, h, s=0.1),
-            "ssm_norm": jnp.ones((lm, di), jnp.float32),
-            "w_out": norm(next(k), lm, di, d, s=out_std),
+            **mamba2.init_mixer(cfg, lm, k_dt, k, std, out_std),
         },
         "moe": {
             "norm": jnp.ones((le, d), jnp.float32),
@@ -243,14 +229,7 @@ PARAM_LOGICAL_AXES = {
     },
     "mamba": {
         "norm": ("layers", "embed"),
-        "w_in": ("layers", "embed", None),
-        "conv_w": ("layers", None, None),
-        "conv_b": ("layers", None),
-        "dt_bias": ("layers", None),
-        "a_log": ("layers", None),
-        "d_skip": ("layers", None),
-        "ssm_norm": ("layers", None),
-        "w_out": ("layers", None, "embed"),
+        **mamba2.LOGICAL_AXES,
     },
     "moe": {
         "norm": ("layers", "embed"),
@@ -290,131 +269,6 @@ def moe_parts(cfg: NemotronHConfig, h, lp, experts, **stacked):
             _relu2(h, lp["ws_up"], lp["ws_down"]))
 
 
-def _mamba_split(cfg: NemotronHConfig, h, lp):
-    """``h`` [..., D] (normed) -> ``z`` [..., d_inner], ``xBC`` [..., conv
-    width] (before the convolution), ``dt`` [..., H] float32 (after the
-    bias and the softplus)."""
-    di = cfg.d_inner
-    zxbcdt = h @ lp["w_in"].astype(h.dtype)
-    dt = jax.nn.softplus(zxbcdt[..., di + cfg.conv_width:].astype(jnp.float32)
-                         + lp["dt_bias"].astype(jnp.float32))
-    return zxbcdt[..., :di], zxbcdt[..., di:di + cfg.conv_width], dt
-
-
-def _conv(cfg: NemotronHConfig, win, w, b, rows: int):
-    """Causal depthwise convolution and silu: ``win`` [..., rows + K - 1,
-    C] (the ``K - 1`` rows before the first, then the rows), ``w`` [K, C] and
-    ``b`` [C] -> [..., rows, C] in ``win``'s dtype, float32 inside. The
-    channels may be folded over two axes in all three, as a window leaf keeps
-    them (``paged.window_fold``): the result's are too."""
-    w = w.astype(jnp.float32)
-    axis = win.ndim - w.ndim
-    acc = b.astype(jnp.float32)
-    for k in range(cfg.conv_kernel):
-        acc = acc + lax.slice_in_dim(win, k, k + rows, axis=axis).astype(
-            jnp.float32) * w[k]
-    return jax.nn.silu(acc).astype(win.dtype)
-
-
-def _xbc_split(cfg: NemotronHConfig, xc):
-    """``xc`` [..., C] -> ``x`` [..., H, P], ``B`` and ``C`` [..., G, N]."""
-    di, gn = cfg.d_inner, cfg.n_groups * cfg.ssm_state_size
-    lead = xc.shape[:-1]
-    return (xc[..., :di].reshape(*lead, cfg.mamba_num_heads, cfg.mamba_head_dim),
-            xc[..., di:di + gn].reshape(*lead, cfg.n_groups, cfg.ssm_state_size),
-            xc[..., di + gn:].reshape(*lead, cfg.n_groups, cfg.ssm_state_size))
-
-
-def _mamba_out(cfg: NemotronHConfig, y, x, z, lp):
-    """``y`` [..., H x P] float32 (the state's part) -> the layer's output
-    [..., D]: the skip ``D x``, the gate ``silu(z)``, the grouped RMSNorm,
-    ``W_out``."""
-    f32 = jnp.float32
-    lead = y.shape[:-1]
-    y = y.reshape(*lead, cfg.mamba_num_heads, cfg.mamba_head_dim) \
-        + lp["d_skip"].astype(f32)[:, None] * x.astype(f32)
-    y = y.reshape(*lead, cfg.d_inner) * jax.nn.silu(z.astype(f32))
-    g = y.reshape(*lead, cfg.n_groups, cfg.d_inner // cfg.n_groups)
-    g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.rms_norm_eps)
-    y = g.reshape(*lead, cfg.d_inner).astype(z.dtype) * lp["ssm_norm"].astype(z.dtype)
-    return y @ lp["w_out"].astype(z.dtype)
-
-
-def ssd_tiles(cfg: NemotronHConfig, x, dt, a, b, c, s0, cont):
-    """The chunked (SSD) form over ``I`` tiles of ``R`` rows, one chunk each:
-    ``x`` [I, R, H, P], ``dt`` [I, R, H] float32 (0 on rows that must
-    neither decay nor feed the state), ``a`` [H] (negative), ``b`` / ``c``
-    [I, R, G, N], ``s0`` [I, N, H x P] float32 (the state each tile would
-    start from were it its slot's first of the step), ``cont`` [I] bool
-    (tile ``i`` goes on where tile ``i - 1`` ended) -> ``(y [I, R, H x P]
-    float32, s [I, N, H x P] float32)``: the recurrence's outputs and each
-    tile's final state. bfloat16 (the inputs' dtype) operands to the
-    matmuls, float32 decay and accumulation."""
-    f32 = jnp.float32
-    n_i, r, h, p = x.shape
-    g, n = b.shape[2:]
-    hg, q = h // g, h // g * p
-    acum = jnp.cumsum(dt * a.astype(f32), axis=1)                 # [I, R, H]
-    # inside the chunk: row t reads row s <= t, decayed from s to t
-    diff = acum[:, :, None] - acum[:, None]                       # [I, t, s, H]
-    causal = jnp.tril(jnp.ones((r, r), bool))[None, :, :, None]
-    decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-    scores = jnp.einsum("itgn,isgn->itsg", c, b, preferred_element_type=f32)
-    m = (jnp.repeat(scores, hg, axis=3) * decay * dt[:, None]).astype(x.dtype)
-    y = jnp.einsum("itsh,ishp->ithp", m, x, preferred_element_type=f32)
-    # each tile's own contribution to the state at its end
-    to_end = jnp.exp(acum[:, -1:] - acum)                         # [I, R, H]
-    xw = (x.astype(f32) * (dt * to_end)[..., None]).astype(x.dtype)
-    # a group at a time, on lane slices of the state's own layout ([N, H x
-    # P], the group's lanes side by side): one einsum over a group axis
-    # makes XLA re-lay the whole slot leaf out to suit it, every step
-    xw = xw.reshape(n_i, r, g, q)
-    ds = jnp.concatenate(
-        [jnp.einsum("isn,isq->inq", b[:, :, j], xw[:, :, j],
-                    preferred_element_type=f32) for j in range(g)], axis=2)
-    total = jnp.repeat(jnp.exp(acum[:, -1]), p, axis=1)[:, None]  # [I, 1, HP]
-    # the carry from tile to tile: in order, tiny beside the matmuls
-    before, after = [], []
-    for i in range(n_i):
-        prev = s0[i] if i == 0 else jnp.where(cont[i], after[-1], s0[i])
-        before.append(prev)
-        after.append(prev * total[i] + ds[i])
-    before = jnp.stack(before)
-    before = before.astype(x.dtype)
-    y_state = jnp.concatenate(
-        [jnp.einsum("itn,inq->itq", c[:, :, j], before[:, :, j * q:(j + 1) * q],
-                    preferred_element_type=f32) for j in range(g)], axis=2)
-    y = (y.reshape(n_i, r, h * p)
-         + y_state * jnp.repeat(jnp.exp(acum), p, axis=2))
-    return y, jnp.stack(after)
-
-
-def _mamba_sequence(cfg: NemotronHConfig, lp, h):
-    """The Mamba mixer over one whole sequence ``h`` [S, D] from an empty
-    state, for the plain forward pass: a scan over chunks of the form the
-    serving tiles run."""
-    s, r, k = h.shape[0], cfg.chunk_size, cfg.conv_kernel
-    z, xbc, dt = _mamba_split(cfg, h, lp)
-    win = jnp.concatenate([jnp.zeros((k - 1, xbc.shape[1]), xbc.dtype), xbc])
-    x, b, c = _xbc_split(cfg, _conv(cfg, win, lp["conv_w"], lp["conv_b"], s))
-    pad = -s % r
-    a = -jnp.exp(lp["a_log"].astype(jnp.float32))
-
-    def chunks(t):
-        return jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1)).reshape(
-            (-1, r) + t.shape[1:])
-
-    def chunk(state, xs):
-        y, state = ssd_tiles(cfg, *(t[None] for t in xs[:2]), a,
-                             *(t[None] for t in xs[2:]), state[None],
-                             jnp.zeros((1,), bool))
-        return state[0], y[0]
-
-    state = jnp.zeros((cfg.ssm_state_size, cfg.d_inner), jnp.float32)
-    _, y = lax.scan(chunk, state, tuple(map(chunks, (x, dt, b, c))))
-    return _mamba_out(cfg, y.reshape(-1, cfg.d_inner)[:s], x, z, lp)
-
-
 def _layer_params(params, kind: str, i: int):
     return jax.tree_util.tree_map(lambda a: a[i], params[KINDS[kind]])
 
@@ -435,7 +289,7 @@ def forward(cfg: NemotronHConfig, params, input_ids, ctx: ShardCtx | None = None
         seen[kind] += 1
         h = rmsnorm(x, lp["norm"], cfg.rms_norm_eps)
         if kind == "M":
-            x = x + jax.vmap(partial(_mamba_sequence, cfg, lp))(h)
+            x = x + jax.vmap(partial(mamba2.sequence, cfg, lp))(h)
         elif kind == "E":
             x = x + sum(moe_parts(cfg, h.reshape(b * s, -1), lp,
                                   routed_experts_einsum)).reshape(x.shape)
@@ -463,11 +317,7 @@ def init_paged_cache(cfg: NemotronHConfig, num_blocks: int, block_size: int,
     ``conv``, the convolution's ``K - 1`` carried rows as a window leaf
     (``paged.init_window_leaf``: ``[L_mamba, num_slots, (K - 1) x r, conv
     width / r]``). The last slot is the scratch slot."""
-    from deepspeed_tpu.models.paged import (
-        SLOTS,
-        init_paged_pool,
-        init_window_leaf,
-    )
+    from deepspeed_tpu.models.paged import SLOTS, init_paged_pool
 
     if codec is not None:
         raise NotImplementedError(
@@ -479,12 +329,7 @@ def init_paged_cache(cfg: NemotronHConfig, num_blocks: int, block_size: int,
     lm = cfg.layers_of("M")
     cache = init_paged_pool(cfg.layers_of("*"), num_blocks, block_size,
                             cfg.num_kv_heads, cfg.head_dim, dtype)
-    cache[SLOTS] = {
-        "ssm": jnp.zeros((lm, num_slots, cfg.ssm_state_size, cfg.d_inner),
-                         jnp.float32),
-        "conv": init_window_leaf(lm, num_slots, cfg.conv_kernel - 1,
-                                 cfg.conv_width, dtype),
-    }
+    cache[SLOTS] = mamba2.init_slot_leaves(cfg, lm, num_slots, dtype)
     return cache
 
 
@@ -512,85 +357,6 @@ def _moe_ragged(cfg: NemotronHConfig, x, lp, stacks):
     h = rmsnorm(x, lp["norm"], cfg.rms_norm_eps)
     stacked = (*stacks, lp["first_expert"]) if "first_expert" in lp else None
     return x + sum(moe_parts(cfg, h, lp, routed_experts, stacked=stacked))
-
-
-def _mamba_ragged(cfg: NemotronHConfig, x, lp, state, slot0, scratch, slots,
-                  positions, prefill_tiles):
-    """The Mamba layer over a flat ragged token batch ``x`` [T, D]:
-    ``state`` the slot leaves, layers and slots merged; this layer's slot
-    ``s`` is row ``slot0 + s``; ``scratch`` the scratch slot."""
-    from deepspeed_tpu.models.paged import (
-        decode_windows,
-        tile_windows,
-        window_fold,
-    )
-    from deepspeed_tpu.ops.pallas.ssm import ssm_decode
-
-    f32 = jnp.float32
-    ssm, conv = state["ssm"], state["conv"]
-    p = cfg.mamba_head_dim
-    h = rmsnorm(x, lp["norm"], cfg.rms_norm_eps)
-    z, xbc, dt = _mamba_split(cfg, h, lp)
-    a = -jnp.exp(lp["a_log"].astype(f32))
-    t = x.shape[0]
-    n_dec = t if prefill_tiles is None else prefill_tiles[0]
-    ys, xs = [], []
-    if n_dec:
-        real = slots[:n_dec] != scratch
-        fresh = real & (positions[:n_dec] == 0)
-        rows = slots[:n_dec] + slot0
-        # the window's arithmetic runs on the channels as the leaf folds
-        # them: the rows come and go as whole tiles, the step's new rows and
-        # the weights are what is folded, its results what is unfolded
-        win, conv = decode_windows(conv, rows, xbc[:n_dec], fresh, real)
-        xd, bd, cd = _xbc_split(cfg, _conv(
-            cfg, win, window_fold(conv, lp["conv_w"]),
-            window_fold(conv, lp["conv_b"]), 1).reshape(n_dec, -1))
-        dtd = jnp.where(real[:, None], dt[:n_dec], 0.0)
-        # position 0: from zeros
-        da = jnp.where(fresh[:, None], 0.0, jnp.exp(dtd * a))
-        ssm, y = ssm_decode(
-            ssm, rows, jnp.repeat(da, p, axis=1),
-            (dtd[..., None] * xd.astype(f32)).reshape(n_dec, -1),
-            bd.astype(f32).transpose(0, 2, 1), cd.astype(f32).transpose(0, 2, 1))
-        ys.append(y)
-        xs.append(xd)
-    if t > n_dec:
-        _, ts, tp, tv, r = prefill_tiles
-        n_i = ts.shape[0]
-        real = ts != scratch
-        rows = ts + slot0
-        fresh = tp == 0
-        # tile i goes on where tile i - 1 of the same slot ended
-        cont = jnp.concatenate([jnp.zeros((1,), bool),
-                                (ts[1:] == ts[:-1]) & real[1:]])
-        write = real & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
-        # a tile that is not its slot's last of the step, and a padding
-        # tile, write the scratch slot, and write it zeros
-        rows_w = jnp.where(write, rows, slot0 + scratch)
-        win, conv = tile_windows(conv, rows, rows_w,
-                                 xbc[n_dec:].reshape(n_i, r, -1), cont, fresh,
-                                 write, tv)
-        xt, bt, ct = _xbc_split(cfg, _conv(cfg, win, lp["conv_w"],
-                                           lp["conv_b"], r))
-        valid = jnp.arange(r)[None, :] < tv[:, None]
-        dtt = jnp.where(valid[..., None], dt[n_dec:].reshape(n_i, r, -1), 0.0)
-        # a tile's state is read and written as ONE row of the leaf, a
-        # dynamic slice each: handed a gather of whole rows, XLA re-lays the
-        # entire leaf out in four lane-quarters first (2.6 GB a step at
-        # Nemotron-3's sizes, on the compiled program)
-        s_old = jnp.stack([lax.dynamic_index_in_dim(ssm, rows[i], 0, False)
-                           for i in range(n_i)])
-        y, s_new = ssd_tiles(cfg, xt, dtt, a, bt, ct,
-                             jnp.where(fresh[:, None, None], 0.0, s_old), cont)
-        s_new = jnp.where(write[:, None, None], s_new, 0.0)
-        for i in range(n_i):
-            ssm = lax.dynamic_update_index_in_dim(ssm, s_new[i], rows_w[i], 0)
-        ys.append(y.reshape(n_i * r, -1))
-        xs.append(xt.reshape((n_i * r,) + xt.shape[2:]))
-    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
-    xh = xs[0] if len(xs) == 1 else jnp.concatenate(xs)
-    return x + _mamba_out(cfg, y, xh, z, lp), {"ssm": ssm, "conv": conv}
 
 
 def _period_stack(tree, lead: int, per: int, j: int, repeats: int):
@@ -623,9 +389,10 @@ def ragged_forward(cfg: NemotronHConfig, params, tokens, slots, positions,
                             prefill_tiles)
 
     def mamba(x, lp, pool, slot0):
-        x, state = _mamba_ragged(cfg, x, lp, pool[SLOTS], slot0, scratch,
-                                 slots, positions, prefill_tiles)
-        return x, {**pool, SLOTS: state}
+        o, state = mamba2.ragged(
+            cfg, rmsnorm(x, lp["norm"], cfg.rms_norm_eps), lp, pool[SLOTS],
+            slot0, scratch, slots, positions, prefill_tiles)
+        return x + o, {**pool, SLOTS: state}
 
     def moe_layer(x, lp, pool, _):
         return _moe_ragged(cfg, x, lp, stacks), pool
@@ -655,9 +422,7 @@ def ragged_forward(cfg: NemotronHConfig, params, tokens, slots, positions,
 def _layer_param_count(cfg: NemotronHConfig, kind: str, experts: int) -> int:
     d = cfg.hidden_size
     if kind == "M":
-        return (d + d * (cfg.d_inner + cfg.conv_width + cfg.mamba_num_heads)
-                + (cfg.conv_kernel + 1) * cfg.conv_width
-                + 3 * cfg.mamba_num_heads + cfg.d_inner + cfg.d_inner * d)
+        return d + mamba2.mixer_param_count(cfg)
     if kind == "*":
         return d + 2 * d * cfg.head_dim * (cfg.num_heads + cfg.num_kv_heads)
     lat, f = cfg.moe_latent_size, cfg.moe_intermediate_size

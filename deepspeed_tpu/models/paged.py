@@ -2,7 +2,8 @@
 
 One home for what every family's serving path shares (gpt2, llama, mixtral
 on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
-nemotron_h and kimi_linear with a recurrent state a slot beside either;
+nemotron_h, granite_hybrid and kimi_linear with a recurrent state a slot
+beside either;
 smallthinker with sliding-window layers in a second pool): the
 paged-pool write, the decode/tiled-prefill attention split over the block
 pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
@@ -74,7 +75,10 @@ v1-style engines.
   of memory, compresses and uncompresses it between the layers (9.4% of
   the Kimi-Linear cell's device time, PERF.md section 6, PR 41); as one
   flat row a slot the layers x slots merge is a copy. A width that ``r x
-  128`` does not divide keeps ``[L_s, S, K - 1, W]``. ``init_window_leaf``
+  128`` does not divide keeps ``[L_s, S, K - 1, W]`` (``granite_hybrid``:
+  8,448 channels are 66 lane tiles, which no whole bfloat16 tile folds; its
+  step programs copy the 30 MB leaf to the compiler's axis order and back,
+  PERF.md section 7). ``init_window_leaf``
   builds the leaf, ``read_windows`` / ``write_windows`` / ``window_fold``
   read the form off the array, ``decode_windows`` / ``tile_windows`` are a
   step's decode rows and prefill tiles through them: one gather and one
@@ -323,7 +327,20 @@ def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=(),
     A stack of several kinds of layer, some with no blocks and some with a
     state a slot, gives ``layer_fn`` as a period of layers instead of one
     function: ``_scan_periods`` below has that form's arguments (and its
-    ``tail``, the layers after the last whole period).
+    ``tail``, the layers after the last whole period). A stack whose order is
+    runs of one kind and no period (five of one, one of another, four of the
+    first) goes through ``scan_runs_paged``.
+
+    Which form a new family of mixed layers takes: this one wherever
+    ``stack_plan`` finds a period that repeats, so that a step program
+    compiles one body a position of the period whatever the depth
+    (``nemotron_h``, ``kimi_linear``, ``smallthinker``). ``scan_runs_paged``
+    only where the published order has no such period and ``stack_plan``
+    would call most of the stack one layer at a time as ``lead``: on
+    ``granite_hybrid``'s ``m m m m m a m m m m`` that form (``m m m m m a``
+    called, ``m`` x 4 scanned: seven bodies, not three) compiled 108 s for
+    68, and its decode step ran 21.06 ms for 20.15 (PERF.md section 6,
+    PR 51: one pair of cold runs on the chip, one seed).
     """
     if not callable(layer_fn):
         return _scan_periods(layer_fn, x, layers, pool, block_tables, lead,
@@ -378,29 +395,10 @@ def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
     A cache with sliding leaves (module doc) has a third kind, ``"swa"``: its
     layer among the sliding leaves' ``L_w``, addressed through the SECOND of
     ``block_tables = (full table, sliding table)``."""
-    blocks, slots = full_leaves(pool), slot_leaves(pool)
-    sliding = sliding_leaves(pool)
-    shapes = {"block": jax.tree_util.tree_leaves(blocks)[0].shape[:2]}
-    if slots is not None:
-        shapes["slot"] = jax.tree_util.tree_leaves(slots)[0].shape[:2]
-    if sliding is not None:
-        shapes["swa"] = jax.tree_util.tree_leaves(sliding)[0].shape[:2]
-        block_tables, sliding_tables = block_tables
-
-    def merged(tree):
-        return jax.tree_util.tree_map(
-            lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), tree)
-
-    def address(kind, i):
-        if kind == "block":
-            return block_tables + i * shapes["block"][1]
-        if kind == "swa":
-            return sliding_tables + i * shapes["swa"][1]
-        return None if kind is None else i * shapes["slot"][1]
-
-    pool = merged(pool)
+    pool, shapes, address, unmerged = _merged_leaves(pool, block_tables)
+    sliding = "swa" in shapes
     seen = {"block": 0, "slot": 0, None: 0}
-    if sliding is not None:
+    if sliding:
         seen["swa"] = 0
     for kind, fn, lp in lead:
         x, pool = fn(x, lp, pool, address(kind, seen[kind]))
@@ -430,15 +428,84 @@ def _scan_periods(period, x, layers, pool, block_tables, lead, tail=()):
     for kind, fn, lp in tail:
         x, pool = fn(x, lp, pool, address(kind, after[kind]))
         after[kind] += 1
-    out = jax.tree_util.tree_map(
-        lambda a: a.reshape(shapes["block"] + a.shape[1:]), full_leaves(pool))
-    if sliding is not None:
-        out = {**out, SWA: jax.tree_util.tree_map(
-            lambda a: a.reshape(shapes["swa"] + a.shape[1:]), pool[SWA])}
+    return x, unmerged(pool)
+
+
+def _merged_leaves(pool, block_tables):
+    """A cache as a stack's layers carry it: ``(pool, shapes, address,
+    unmerged)``. ``pool`` has every leaf's layers merged with its blocks or
+    slots into one axis; ``shapes[kind]`` is ``(layers, blocks or slots)`` of
+    the leaves that count a layer of ``kind`` (``"block"``, and ``"slot"`` /
+    ``"swa"`` where the cache has such leaves); ``address(kind, i)`` is what
+    layer ``i`` of its kind is handed (``_scan_periods``); ``unmerged(pool)``
+    gives the leaves their two axes back."""
+    blocks, slots = full_leaves(pool), slot_leaves(pool)
+    sliding = sliding_leaves(pool)
+    shapes = {"block": jax.tree_util.tree_leaves(blocks)[0].shape[:2]}
     if slots is not None:
-        out = {**out, SLOTS: jax.tree_util.tree_map(
-            lambda a: a.reshape(shapes["slot"] + a.shape[1:]), pool[SLOTS])}
-    return x, out
+        shapes["slot"] = jax.tree_util.tree_leaves(slots)[0].shape[:2]
+    if sliding is not None:
+        shapes["swa"] = jax.tree_util.tree_leaves(sliding)[0].shape[:2]
+        block_tables, sliding_tables = block_tables
+
+    def address(kind, i):
+        if kind == "block":
+            return block_tables + i * shapes["block"][1]
+        if kind == "swa":
+            return sliding_tables + i * shapes["swa"][1]
+        return None if kind is None else i * shapes["slot"][1]
+
+    def unmerged(pool):
+        out = jax.tree_util.tree_map(
+            lambda a: a.reshape(shapes["block"] + a.shape[1:]),
+            full_leaves(pool))
+        if sliding is not None:
+            out = {**out, SWA: jax.tree_util.tree_map(
+                lambda a: a.reshape(shapes["swa"] + a.shape[1:]), pool[SWA])}
+        if slots is not None:
+            out = {**out, SLOTS: jax.tree_util.tree_map(
+                lambda a: a.reshape(shapes["slot"] + a.shape[1:]),
+                pool[SLOTS])}
+        return out
+
+    merged = jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), pool)
+    return merged, shapes, address, unmerged
+
+
+def scan_runs_paged(runs, x, pool, block_tables):
+    """``scan_layers_paged`` for a stack whose order is no lead and period
+    but RUNS of one kind of layer (``granite_hybrid``: five Mamba layers, an
+    attention layer, four Mamba layers): ``runs = [(kind, fn, layers)]`` in
+    the stack's order, ``layers`` the run's weights stacked ``[n, ...]``,
+    ``kind`` and ``fn(x, lp, pool, address) -> (x, pool)`` as
+    ``_scan_periods`` has them. A run of two layers or more is a scan over
+    its own stack, so a step program compiles one body a run and slices no
+    run out of a larger stack; a run of one is called on its stack's only
+    layer. The pool is carried whole through all of them, merged once."""
+    pool, shapes, address, unmerged = _merged_leaves(pool, block_tables)
+    seen = dict.fromkeys(shapes, 0)
+    for kind, fn, layers in runs:
+        n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        first = seen.get(kind, 0)
+        if n == 1:
+            x, pool = fn(x, jax.tree_util.tree_map(lambda a: a[0], layers),
+                         pool, address(kind, first))
+        else:
+            def body(carry, lp_i, kind=kind, fn=fn):
+                lp, i = lp_i
+                return fn(carry[0], lp, carry[1], address(kind, i)), None
+
+            (x, pool), _ = lax.scan(
+                body, (x, pool),
+                (layers, first + jnp.arange(n, dtype=jnp.int32)))
+        seen[kind] = first + n
+    for kind, (n, _) in shapes.items():
+        if seen[kind] != n:
+            raise ValueError(
+                f"the cache's {kind} leaves hold {n} layers, the stack has "
+                f"{seen[kind]} of that kind")
+    return x, unmerged(pool)
 
 
 def tiles_go_as_slices(tile: int, block_size: int) -> bool:

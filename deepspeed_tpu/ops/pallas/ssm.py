@@ -9,7 +9,12 @@ is
     S'[n, q] = dA[q] S[n, q] + B[n, g(q)] dtx[q]        y[q] = sum_n S'[n, q] C[n, g(q)]
 
 with ``dA = exp(dt A)`` and ``dtx = dt x`` a head, spread over the head's
-lanes by the caller. The states of all layers and slots lie in ONE array
+lanes by the caller. ``G`` is read off ``bt`` (``[T, N, G]``) and may be 1
+(``granite_hybrid``: one ``B`` and one ``C`` for all the heads): the kernel's
+loop over the groups is then one trip over all ``H x P`` lanes, a ``[N, 1]``
+column broadcast over 8,192 of them where Nemotron-3's eight groups take 1,024
+each (``tests/unit/test_compile_tpu.py`` compiles both for the chip,
+``tests/unit/test_granite_hybrid.py`` holds one group to the XLA form). The states of all layers and slots lie in ONE array
 ``[rows, N, H x P]`` (``models/paged.py``: the slot leaves, layers and slots
 merged); ``rows[r]`` is where row ``r``'s state lies. The grid is the step's
 rows: the pipeline brings a row's whole state (4 MB at Nemotron-3's 128 x

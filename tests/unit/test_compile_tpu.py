@@ -316,28 +316,36 @@ def _ssm_decode(state, rows, da, dtx, bt, ct):
     return ssm_decode(state, rows, da, dtx, bt, ct, interpret=False)
 
 
-def _ssm_args(devices, rows=128):
-    """The Mamba state of the Nemotron-3 cell: 5 layers x 129 slots of [128,
-    8192] float32, 8 groups, a decode bucket of ``rows``."""
+# the Mamba state of the two cells with such layers: layers, slots, groups,
+# the decode bucket's rows, all of [128, 8192] float32
+SSM_CELLS = {"nemotron-3-super-120b-d11-ep4": (5, 129, 8, 128),
+             "granite-4.0-h-small-d10-ep2": (9, 65, 1, 64)}
+
+
+def _ssm_args(devices, rows=128, layers=5, slots=129, groups=8):
     dev = jax.sharding.SingleDeviceSharding(devices[0])
 
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
-    return (s((5 * 129, 128, 8192)), s((rows,), jnp.int32), s((rows, 8192)),
-            s((rows, 8192)), s((rows, 128, 8)), s((rows, 128, 8)))
+    return (s((layers * slots, 128, 8192)), s((rows,), jnp.int32),
+            s((rows, 8192)), s((rows, 8192)), s((rows, 128, groups)),
+            s((rows, 128, groups)))
 
 
-def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e):
+@pytest.mark.parametrize("cell", sorted(SSM_CELLS))
+def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e, cell):
     """A row's whole state (4 MB) a grid step, in and out double-buffered
-    under the raised VMEM limit, a [128, 1] column broadcast over the lanes;
-    the donated state is the output (aliased), and nothing else in the
-    program is as large as ONE row's state."""
+    under the raised VMEM limit, a [128, 1] column broadcast over the lanes
+    (at ONE group over all 8,192 of them); the donated state is the output
+    (aliased), and nothing else in the program is as large as ONE row's
+    state."""
+    layers, slots, groups, rows = SSM_CELLS[cell]
     compiled = jax.jit(_ssm_decode, donate_argnums=(0,)).lower(
-        *_ssm_args(v5e)).compile()
+        *_ssm_args(v5e, rows, layers, slots, groups)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 5 * 129 * 128 * 8192 * 4
+    assert mem.alias_size_in_bytes >= layers * slots * 128 * 8192 * 4
     assert mem.temp_size_in_bytes < 128 * 8192 * 4
 
 
@@ -953,11 +961,21 @@ def _relayout_family(name):
     table width, projections, those known to be re-laid out still)`` at the
     serving cells' attention widths; depth, FFN, experts and vocabulary
     small."""
-    from deepspeed_tpu.models import deepseek, smallthinker
+    from deepspeed_tpu.models import deepseek, granite_hybrid, smallthinker
 
     if name == "mixtral":
         mod, cfg, blocks, _, table = _step_family(name)
         return mod, cfg, blocks, 33, table, ("wq", "wk", "wv", "wo"), ()
+    if name == "granite":
+        # granite-4.0-h-small-d10-ep2.json's mixers, shared MLP and pool at
+        # their published widths: two runs of Mamba layers around the
+        # attention layer; 4 of 8 experts, a sixteenth of the held table
+        return granite_hybrid, granite_hybrid.GraniteHybridConfig(
+            vocab_size=GRANITE_VOCAB, num_layers=5, layer_types=(
+                "mamba", "mamba", "attention", "mamba", "mamba"),
+            num_experts=8, experts_held=4, top_k=2), 513, 65, 8, (
+                "wq", "wk", "wv", "wo", "w_in", "w_out", "router", "ws_gate",
+                "ws_up", "ws_down"), ()
     if name == "smallthinker":   # smallthinker-21b-a3b-ep8.json: F W W W
         return smallthinker, smallthinker.SmallThinkerConfig(
             vocab_size=512, hidden_size=2560, moe_intermediate_size=128,
@@ -975,9 +993,12 @@ def _relayout_family(name):
             "wq_a", "wq_b", "wkv_a", "wkv_b", "wo"), ("wkv_b",)
 
 
+GRANITE_VOCAB = 3136   # no array of a step's rows has 3,136 x 4,096 elements
+
+
 @pytest.mark.parametrize("family,rows,tiles", [
     ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
-    ("mla", 16, 1)])
+    ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0)])
 def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
                                                       rows, tiles):
     """The paged contract's *Rows to heads* (``models/paged.py``), on the
@@ -1019,6 +1040,15 @@ def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
     assert [ln for name, ln in found if name not in known] == []
     # what is known to stay is still there: whoever cures it says so here
     assert {name for name, _ in found} == set(known)
+    if family == "granite":
+        # the tied table is gathered from as the embedding and multiplied by
+        # as the head where it lies: no copy, transposed or not (the
+        # compiler's own prefetch of this small one into its nearer memory
+        # keeps the stored layout, as ``_projection_results`` says)
+        table = GRANITE_VOCAB * cfg.hidden_size
+        assert [ln for size, op, ln in _materialized(text)
+                if size == 2 * table and " bf16[" in ln
+                and op not in _IN_PLACE and not op.endswith("-done")] == []
 
 
 def test_a_table_kept_column_major_is_copied_for_its_row_gather(v5e,

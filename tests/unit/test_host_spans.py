@@ -375,7 +375,17 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                      # read a slice of the window cell whose spans carry
                      # ``pool_slice_rows``
                      "model.pool_slice_share":
-                     "v5e_smallthinker_mixedlen_slices_spans"}
+                     "v5e_smallthinker_mixedlen_slices_spans",
+                     # PR 51: the open loop over slot state: the readers of
+                     # ``state_pad_rows`` / ``slot_resets`` and the chat
+                     # cell's namesakes of the slot-state readers read a slice
+                     # of the Granite chat cell
+                     **{m: "v5e_granite_chat_spans" for m in (
+                         "sched.state_pad_row_share", "sched.slot_resets_per_s",
+                         "kernel.chat_ssm_decode_share",
+                         "kernel.chat_ssm_decode_roofline",
+                         "model.chat_ssm_step_roofline_kv",
+                         "kernel.chat_hybrid_paged_decode_roofline")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -391,7 +401,9 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "smallthinker-21b-a3b-ep8.mixedlen-pool",
                    "v5e_sdar_blockgen_spans": "sdar-30b-a3b-d7.blockgen-pool",
                    "v5e_smallthinker_mixedlen_slices_spans":
-                   "smallthinker-21b-a3b-ep8.mixedlen-pool"}
+                   "smallthinker-21b-a3b-ep8.mixedlen-pool",
+                   "v5e_granite_chat_spans":
+                   "granite-4.0-h-small-d10-ep2.chat-open"}
 
 
 def _new_readers():

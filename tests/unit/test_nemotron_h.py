@@ -398,3 +398,47 @@ def test_both_window_forms_serve_the_same_logits():
         for g in range(4):
             np.testing.assert_allclose(folded[(uid, g)],
                                        want[len(prompt) + g - 1], atol=ATOL)
+
+
+# ------------------------------------------------- the shared Mamba-2 (PR 51)
+# sha256 of the tiny preset's mixed step (4 decode rows + 2 tiles of 8) as
+# lowered BEFORE the Mamba-2 code moved to ``models/mamba2.py`` (commit
+# 9c8cdec, jax 0.9.0, CPU lowering). A change that is meant to alter this
+# family's step program re-pins it and says so; one that is not does not get
+# to.
+_MIXED_STEP_SHA256 = \
+    "eddfb049fe8815af177cfd0603ac226ce51c5546059735cd96aab2c54f5cf5c9"
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the digest is of jax 0.9.0's lowering")
+def test_step_program_lowers_to_the_text_it_had_before_mamba2_was_shared():
+    """``nemotron_h`` imports its Mamba-2 from ``models/mamba2.py`` (with
+    ``granite_hybrid``) and its step program is the one it was, to the
+    character."""
+    import hashlib
+
+    from deepspeed_tpu.models import granite_hybrid, mamba2
+
+    assert nemotron_h.mamba2 is mamba2 is granite_hybrid.mamba2
+    assert not hasattr(nemotron_h, "ssd_tiles")     # no copy left behind
+    rows, tiles, tile = 4, 2, 8
+    abstract = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        nemotron_h.init_params(CFG, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: nemotron_h.init_paged_cache(
+        CFG, 9, 8, jnp.bfloat16, num_slots=5))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def step(params, cache, tokens, slots, positions, bt, ts, tp, tv):
+        return nemotron_h.ragged_forward(
+            CFG, params, tokens, slots, positions, bt, cache,
+            prefill_tiles=(rows, ts, tp, tv, tile))
+
+    t = rows + tiles * tile
+    text = jax.jit(step).lower(abstract, cache, i32(t), i32(t), i32(t),
+                               i32(5, 4), i32(tiles), i32(tiles),
+                               i32(tiles)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == _MIXED_STEP_SHA256
