@@ -69,6 +69,94 @@ def _kept_pairs(pos0: int, take: int, topk: int) -> int:
     return (full * pos0 + full * (full + 1) // 2) + (take - full) * topk
 
 
+# ---- the tables a step indexes, laid out for their row gather ----
+# A v5e keeps an array whose rows are no whole number of 128-lane tiles
+# column-major (GPT-2 XL's ``wte`` / ``wpe`` at 1,600 lanes: 12.5 tiles; the
+# padding is smaller that way). A product reads that in place; a GATHER of
+# rows wants them contiguous, so every step program copied the whole table
+# first (161 MB, 0.48 ms of a 6.34 ms step; PERF.md section 6, PRs 50 and 52).
+# The engine therefore holds such a table row-major from the start. What
+# decides is the array's own layout and nothing else.
+def _lies_row_major(a) -> bool:
+    """Whether ``a`` (an array, or a ``ShapeDtypeStruct`` that says) lies
+    with its last axis minor. An array that says nothing (a host array, a
+    backend without layouts) has no other layout to be in."""
+    layout = getattr(getattr(a, "format", None), "layout", None)
+    order = getattr(layout, "major_to_minor", None)
+    return order is None or tuple(order) == tuple(range(len(a.shape)))
+
+
+def row_gather_tables(params, names) -> list[tuple]:
+    """``(path, leaf)`` of the tables among ``params`` that a row gather
+    would copy: a leaf with one of ``names`` on its path (``ModelSpec.
+    woq_skip``: "tables the model indexes rather than matmuls", matched as
+    ``ops.quantizer.quantize_params`` matches them) that is not row-major.
+    Empty wherever the tables' rows are whole tiles, and off the chip."""
+    from deepspeed_tpu.ops.quantizer import path_names
+
+    names = set(names)
+    return [(path, leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]
+            if names & path_names(path)
+            and len(getattr(leaf, "shape", ())) >= 2
+            and not _lies_row_major(leaf)]
+
+
+def row_major_program(tables: tuple):
+    """THE program that re-lays ``tables`` (arrays, or abstract values with
+    their formats) row-major: one jitted function for all of them, under one
+    name (``jit_ragged_tables_row_major``), so that a process builds one
+    program more for them, however many they are. It compiles in a tenth of
+    a second on the chip, under the second from which jax's persistent cache
+    keeps an entry: every process builds it, and that is its whole price."""
+    from jax.experimental.layout import Format, Layout
+
+    def ragged_tables_row_major(tables):
+        return tables
+
+    return jax.jit(ragged_tables_row_major, out_shardings=tuple(
+        Format(Layout(major_to_minor=tuple(range(len(t.shape)))), t.sharding)
+        for t in tables))
+
+
+def lay_out_for_row_gather(params, names) -> tuple[Any, tuple]:
+    """``params`` with every table of ``row_gather_tables`` row-major, and
+    those tables as they are now. The same tree of the same values; with
+    nothing to do nothing is built and ``params`` comes back as it is. A
+    re-laid table's old array is DELETED, so that no table is held twice:
+    whoever handed the engine its parameters reads them from
+    ``engine.params`` afterwards. (Deleted and not donated: a donation the
+    compiler cannot alias, and it cannot where the layouts differ, left the
+    buffer with its owner on the chip, 164 MB for as long as the caller kept
+    its tree: PERF.md section 6, PR 52.)"""
+    found = row_gather_tables(params, names)
+    if not found:
+        return params, ()
+    tables = tuple(leaf for _, leaf in found)
+    relaid = jax.block_until_ready(row_major_program(tables)(tables))
+    for table in tables:
+        table.delete()
+    by_path = {path: new for (path, _), new in zip(found, relaid)}
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: by_path.get(path, leaf), params), relaid
+
+
+def abstract_like(tree):
+    """``tree``'s arrays as the abstract values from which a lowering builds
+    the very program a dispatch of the arrays themselves looks up: shape,
+    dtype and, for a COMMITTED array, its format (the layout, and the
+    sharding beside it). Without the layout a re-laid table's program is
+    lowered for the default one, and without the sharding a committed
+    argument's annotation is missing from the program's text: either way the
+    executable is one no dispatch ever asks for. An uncommitted array says
+    neither to a dispatch, so it says neither here."""
+    def one(x):
+        fmt = x.format if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=fmt)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
 # why a model with slot state cannot be resumed from blocks alone
 _NO_SNAPSHOT = ("a sequence's recurrent state is not in its blocks, and there "
                 "is no snapshot of it at a block boundary to restore beside "
@@ -652,6 +740,12 @@ class RaggedInferenceEngine:
     by up to one token (decodes) and/or one prompt chunk (prefills) inside one
     XLA call; finished sequences free their blocks and their slot is reused
     immediately (reference ``engine_v2.put`` + ``DSStateManager`` lifecycle).
+
+    ``params`` handed in are the engine's from then on: read them back from
+    ``engine.params`` (the same tree of the same values). Where the chip
+    keeps a table the steps gather rows from column-major, the engine holds
+    it row-major and deletes the array it was handed
+    (``lay_out_for_row_gather``; ``tables_relaid`` says how many).
     """
 
     def __init__(self, model, ragged_config: RaggedConfig | None = None,
@@ -702,6 +796,20 @@ class RaggedInferenceEngine:
                                           skip=tuple(self.spec.woq_skip))
             )(self.params)
         self.quantize_bits = woq_bits
+        # the parameters as the engine will hold them: a table a step gathers
+        # rows from lies row-major from here on (``lay_out_for_row_gather``)
+        self.params, relaid = lay_out_for_row_gather(self.params,
+                                                     self.spec.woq_skip)
+        self.tables_relaid = len(relaid)
+        self.tables_relaid_bytes = sum(int(t.nbytes) for t in relaid)
+        # a re-laid table is COMMITTED to its device (its layout is a thing
+        # only a committed array tells a dispatch), and a program that takes
+        # a committed array commits everything it returns: the first step
+        # program would hand the pool and the slot rows back committed, and
+        # every program that had taken them uncommitted be built once more.
+        # So beside such a table they are committed from the start
+        # (``_beside_the_tables``); with none re-laid nothing is
+        self._tables_on = relaid[0].sharding if relaid else None
         self.cache = self._build_cache()
         # a model whose layers carry a recurrent state a slot keeps it in
         # slot leaves beside the pool's block leaves (models/paged.py); the
@@ -976,10 +1084,19 @@ class RaggedInferenceEngine:
         self._headroom_stall_ticks = 0  # consecutive zero-progress waits
         self.last_oom_report: str | None = None
         self._register_memory_owners()
+        if self.telemetry.enabled:
+            g = self.telemetry.gauge
+            g("engine_tables_relaid", "tables a step gathers rows from that "
+              "the engine re-laid row-major when it took the parameters").set(
+                  self.tables_relaid)
+            g("engine_tables_relaid_bytes", "bytes of those tables").set(
+                self.tables_relaid_bytes)
         log_dist(
             f"RaggedInferenceEngine: model={self.spec.name} "
             f"budget={self.cfg.max_tokens_per_step} max_seqs={self.cfg.max_seqs} "
-            f"blocks={self.cfg.num_blocks}x{self.cfg.block_size}", ranks=[0],
+            f"blocks={self.cfg.num_blocks}x{self.cfg.block_size} "
+            f"tables_relaid={self.tables_relaid} "
+            f"({self.tables_relaid_bytes} bytes)", ranks=[0],
         )
 
     # ------------------------------------------------------------------ put
@@ -1301,7 +1418,16 @@ class RaggedInferenceEngine:
         if self._blk is not None:
             state += (jnp.zeros((s1, self._blk.length), jnp.int32),
                       jnp.ones((s1, self._blk.length), bool))
-        return state
+        return self._beside_the_tables(state)
+
+    def _beside_the_tables(self, tree):
+        """``tree`` (what the step programs hand back: the pool, the slot
+        rows), committed to the device the re-laid tables are committed to;
+        the same buffers, nothing is copied. As it is where no table was
+        re-laid (``_init``'s comment)."""
+        if self._tables_on is None:
+            return tree
+        return jax.device_put(tree, self._tables_on)
 
     def _blocks(self):
         """The cache's block leaves (``[L, NB, ...]`` each): what every
@@ -1345,9 +1471,9 @@ class RaggedInferenceEngine:
         off (bit-identical to the pre-quant engine), else the low-bit
         ``QuantizedKV`` pool built directly at storage precision (no
         transient fp allocation at the full pool size)."""
-        return self.spec.init_paged_cache_fn(
+        return self._beside_the_tables(self.spec.init_paged_cache_fn(
             self.cfg.num_blocks, self.cfg.block_size, self.dtype,
-            codec=self._kvq, num_slots=self.cfg.max_seqs + 1)
+            codec=self._kvq, num_slots=self.cfg.max_seqs + 1))
 
     def kv_quant_stats(self) -> dict | None:
         """Low-bit KV summary for bench/telemetry readers; None = quant off.
@@ -2662,13 +2788,9 @@ class RaggedInferenceEngine:
         import os
         from concurrent.futures import ThreadPoolExecutor
 
-        def abstract(tree):
-            return jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
-
-        fixed = (abstract(self.params), abstract(self.cache),
-                 abstract(self._dev_state), abstract(self._tables_dev()))
-        root = abstract(self._sample_root)
+        fixed = abstract_like((self.params, self.cache, self._dev_state,
+                               self._tables_dev()))
+        root = abstract_like(self._sample_root)
 
         def compile_one(key):
             t, _, nt, _ = key

@@ -206,6 +206,12 @@ def dequantize_layer(lp: dict, dtype) -> dict:
     return {k: maybe_dequantize(v, dtype) for k, v in lp.items()}
 
 
+def path_names(path) -> set:
+    """The dict keys on a leaf's tree path: what ``skip`` below, and the
+    ragged engine's ``ModelSpec.woq_skip`` rule, match a leaf by."""
+    return {str(getattr(k, "key", "")) for k in path}
+
+
 def quantize_params(params, bits: int = 8, block: int = 256,
                     skip: tuple = ("embed",), stacked_key: str = "layers"):
     """Quantize the matrix leaves of a param pytree into
@@ -219,7 +225,7 @@ def quantize_params(params, bits: int = 8, block: int = 256,
     dense (embedding gathers want a plain array)."""
 
     def q(path, leaf):
-        names = {str(getattr(k, "key", "")) for k in path}
+        names = path_names(path)
         stacked = stacked_key in names
         min_ndim = 3 if stacked else 2
         if (not hasattr(leaf, "ndim") or leaf.ndim < min_ndim

@@ -1060,9 +1060,9 @@ def test_a_table_kept_column_major_is_copied_for_its_row_gather(v5e,
     ``jax.jit`` hands them back on the chip), the head's product reads that in
     place, and the token GATHER wants contiguous rows and copies the table for
     them. The same step with ``wte`` row-major copies nothing of its size:
-    0.73 -> 0.41 ms for gather + head on the chip (PERF.md section 6, PR 50;
-    what giving the engine's tables that layout costs at set-up is ROADMAP
-    S5's to settle)."""
+    0.73 -> 0.41 ms for gather + head on the chip (PERF.md section 6, PR 50).
+    Since PR 52 the engine holds such a table row-major from the moment it
+    takes the parameters: the next test drives its rule."""
     from jax.experimental.layout import Format, Layout
 
     from deepspeed_tpu.models import gpt2
@@ -1097,6 +1097,90 @@ def test_a_table_kept_column_major_is_copied_for_its_row_gather(v5e,
         sharding=Format(Layout(major_to_minor=(1, 0)), dev))}
     (copy,) = copies(as_the_chip_keeps_it)
     assert "bf16[50257,1600]{1,0" in copy and "params[" in copy
+
+
+def _as_the_chip_hands_back(v5e, shapes):
+    """``shapes`` ({name: shape}, bf16) as abstract arrays in the formats the
+    described chip gives the results of a ``jax.jit`` that says none: what an
+    engine's caller hands it (``benchmark/serve_cell.py`` makes the weights
+    so). On the chip ``[50257, 1600]`` and ``[1024, 1600]`` come back
+    ``major_to_minor=(1, 0)`` (PERF.md section 6, PR 50); the described
+    compile says the same."""
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def made(key):
+        return {name: jax.random.normal(key, shape, jnp.bfloat16)
+                for name, shape in shapes.items()}
+
+    compiled = jax.jit(made, out_shardings=dev).lower(jax.ShapeDtypeStruct(
+        (2,), jnp.uint32, sharding=dev)).compile()
+    return {name: jax.ShapeDtypeStruct(shapes[name], jnp.bfloat16, sharding=f)
+            for name, f in compiled.output_formats.items()}
+
+
+# [vocabulary, hidden] of every other configuration under benchmark/configs
+OTHER_TABLES = {
+    "mixtral": (32000, 4096), "moonlight": (163840, 2048),
+    "nemotron": (32768, 4096), "sparse": (16160, 7168),
+    "longcat": (16384, 6144), "kimi": (20480, 2304),
+    "smallthinker": (18992, 2560), "sdar": (151936, 2048),
+    "granite": (50176, 4096)}
+
+
+def test_the_engine_lays_a_column_major_table_out_for_its_row_gather(
+        v5e, monkeypatch):
+    """The engine's rule (``inference/ragged.py``, PR 52) on the described
+    chip's own layouts. GPT-2 XL's tables as the chip hands them back are
+    column-major, ``row_gather_tables`` finds both, ONE program
+    (``ragged_tables_row_major``) re-lays both, and the step program compiled
+    from its results copies nothing of a table's size, where the same step on
+    the tables as they came copies ``wte``. Every other configuration's table
+    (``[32000, 4096]`` ...: rows of whole tiles) comes back row-major: the
+    rule finds nothing, builds nothing and hands the tree back as it is."""
+    from deepspeed_tpu.inference import ragged
+    from deepspeed_tpu.models import gpt2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = gpt2.GPT2Config(vocab_size=50257, hidden_size=1600, num_layers=2,
+                          num_heads=25)
+    names = gpt2.build(cfg).woq_skip
+    pin = _row_major(v5e)
+    tables = _as_the_chip_hands_back(
+        v5e, {"wte": (50257, 1600), "wpe": (1024, 1600), **OTHER_TABLES})
+    others = {cell: {"embed": tables[cell]} for cell in OTHER_TABLES}
+    assert ragged.row_gather_tables(others, ("embed",)) == []
+    assert ragged.lay_out_for_row_gather(others, ("embed",)) == (others, ())
+    params = {**pin(jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        gpt2.init_params(cfg, jax.random.PRNGKey(0))))),
+        "wte": tables["wte"], "wpe": tables["wpe"]}
+    found = ragged.row_gather_tables(params, names)
+    assert sorted(path[-1].key for path, _ in found) == ["wpe", "wte"]
+    came = tuple(leaf for _, leaf in found)
+    program = ragged.row_major_program(came).lower(came).compile()
+    assert "ragged_tables_row_major" in program.as_text()
+    relaid = {path[-1].key: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=f)
+        for (path, leaf), f in zip(found, program.output_formats)}
+    assert ragged.row_gather_tables(relaid, names) == []
+    cache = pin(jax.eval_shape(lambda: gpt2.init_paged_cache(
+        cfg, 513, 32, jnp.bfloat16)))
+    rows = pin(jax.ShapeDtypeStruct((4,), jnp.int32))
+    block_tables = pin(jax.ShapeDtypeStruct((33, 32), jnp.int32))
+
+    def step(params, cache, tokens, slots, positions, block_tables):
+        logits, cache = gpt2.ragged_forward(cfg, params, tokens, slots,
+                                            positions, block_tables, cache)
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1), cache
+
+    def copies(params):
+        text = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, rows, rows, rows, block_tables).compile().as_text()
+        return [ln for size, op, ln in _materialized(text)
+                if op == "copy" and size >= 1024 * 1600 * 2]
+
+    assert len(copies(params)) >= 1
+    assert copies({**params, **relaid}) == []
 
 
 # ------------------------------------------------- the contract's slot leaves

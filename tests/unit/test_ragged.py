@@ -618,3 +618,127 @@ class TestColdCachePrecompile:
             sorted(k for k in eng._step_zoo() if k != first)
             if first_miss == 1 and cpus == 4 else [])
         assert reads[0] == 2     # one probe an engine
+
+
+# ----------------------------- tables laid out for their row gather (PR 52)
+def _gauges(*names):
+    from deepspeed_tpu import telemetry
+
+    metrics = telemetry.snapshot()["metrics"]
+    return [metrics[n]["series"][0]["value"] for n in names]
+
+
+class TestTablesForTheirRowGather:
+    """A table a step gathers rows from is held row-major from the moment the
+    engine takes the parameters (``ragged.lay_out_for_row_gather``). Off the
+    chip every array is row-major and the rule is inert; ``relaid`` makes it
+    engage by saying of every table that it is not (the chip says so of GPT-2
+    XL's: tests/unit/test_compile_tpu.py drives the rule on the described
+    chip's own layouts)."""
+
+    SIZES = dict(prefill_tile=4, max_tokens_per_step=16, max_seqs=4)
+
+    def _served(self, monkeypatch, relaid):
+        import dataclasses
+
+        import jax
+
+        from deepspeed_tpu import telemetry
+        from deepspeed_tpu.inference import ragged
+        from deepspeed_tpu.telemetry.compile_watch import WATCH
+
+        if relaid:
+            monkeypatch.setattr(ragged, "_lies_row_major", lambda a: False)
+        params = jax.jit(lambda key: llama.init_params(CFG, key))(
+            jax.random.PRNGKey(0))
+        handed = params["embed"]
+        WATCH.install()
+        before = len(WATCH.snapshot()["builds"])
+        telemetry.configure(enabled=True)
+        try:
+            eng = RaggedInferenceEngine(
+                lambda ctx: llama.build(CFG, ctx=ctx),
+                dataclasses.replace(RCFG, **self.SIZES), dtype=jnp.float32,
+                params=params, seed=0)
+            gauges = _gauges("engine_tables_relaid",
+                             "engine_tables_relaid_bytes")
+        finally:
+            telemetry.configure(enabled=False)
+        for uid, p in _prompts(23).items():
+            eng.put(uid, p, max_new_tokens=6)
+        eng.generate_all()
+        tokens = {u: s.generated for u, s in eng._results.items()}
+        builds = [b["program"] for b in WATCH.snapshot()["builds"][before:]]
+        return eng, handed, gauges, tokens, builds
+
+    @pytest.mark.parametrize("relaid", [False, True],
+                             ids=["as_made", "relaid"])
+    def test_the_background_lowers_what_a_dispatch_looks_up(
+            self, monkeypatch, relaid):
+        """``abstract_like``, from which ``_precompile_zoo_in_background``
+        lowers: every leaf's shape and dtype, and a committed leaf's format
+        (a re-laid table's layout among them; before PR 52 it said shape and
+        dtype alone, and a cold set-up compiled every program of such an
+        engine twice). What it is for: the program lowered from the abstract
+        values is, to the letter, the one lowered from the arrays."""
+        import jax
+
+        from deepspeed_tpu.inference.ragged import abstract_like
+
+        eng, *_ = self._served(monkeypatch, relaid)
+        real = (eng.params, eng.cache, eng._dev_state, eng._tables_dev())
+        leaves = jax.tree_util.tree_leaves(real)
+        for x, a in zip(leaves, jax.tree_util.tree_leaves(abstract_like(real))):
+            assert (a.shape, a.dtype) == (x.shape, x.dtype)
+            assert a.format == x.format if x.committed else \
+                a.sharding is None and a.format.layout is None
+        assert any(x.committed for x in leaves) == relaid
+        t, nd, nt, w = key = eng._step_zoo()[-2]
+        staged = jnp.zeros((4 * t + 3 * max(nt, 1),), jnp.int32)
+
+        def text(*args):
+            return eng._build_dev_step(*key, False, False, False).lower(
+                *args).as_text()
+
+        assert text(*abstract_like(real), abstract_like(staged),
+                    abstract_like(eng._sample_root)) == text(
+                        *real, staged, eng._sample_root)
+
+    def test_row_major_tables_build_nothing(self, monkeypatch):
+        """No table of the model is column-major (none is off the chip, and
+        on it none of a family whose rows are whole tiles): no program, no
+        array moved, the caller's arrays the engine's, both gauges 0."""
+        eng, handed, gauges, _, builds = self._served(monkeypatch, False)
+        assert (eng.tables_relaid, eng.tables_relaid_bytes) == (0, 0)
+        assert gauges == [0, 0]
+        assert eng.params["embed"] is handed and not handed.is_deleted()
+        assert "jit_ragged_tables_row_major" not in builds
+
+    def test_relaid_tables_cost_one_program_and_change_no_token(
+            self, monkeypatch, engine_of):
+        """With a table to re-lay: ONE program for all of them, their old
+        buffers given up, the same tree of the same values (the same tokens),
+        and no other program built twice: a re-laid table is a committed
+        array, what a program returns that took one is committed too, so the
+        pool and the slot rows are committed from the start, and again after
+        the engine's containment rebuilt them. Without that the first step
+        program and ``ragged_slot_rows`` are each built a second time."""
+        import jax
+
+        ref = engine_of(True, **self.SIZES)     # built as any engine is
+        eng, handed, gauges, tokens, builds = self._served(monkeypatch, True)
+        table = CFG.vocab_size * CFG.hidden_size * 4
+        assert (eng.tables_relaid, eng.tables_relaid_bytes) == (1, table)
+        assert gauges == [1, table]
+        assert handed.is_deleted() and eng.params["embed"].committed
+        assert builds.count("jit_ragged_tables_row_major") == 1
+        for uid, p in _prompts(23).items():
+            ref.put(uid, p, max_new_tokens=6)
+        ref.generate_all()
+        assert tokens == {u: ref._results[u].generated for u in tokens}
+        steps = [b for b in builds if b.startswith("jit_ragged_step_")
+                 or b == "jit_ragged_slot_rows"]
+        assert steps and len(steps) == len(set(steps))
+        eng.reset_state()
+        assert all(x.committed for x in jax.tree_util.tree_leaves(
+            (eng.cache, eng._dev_state)))
