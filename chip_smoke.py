@@ -193,6 +193,7 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                   block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
                   experts=(16, 1024, 2688, 64, 6),
                   kda=(258, 128, 32, 128), chunk=(3, 128, 16),
+                  selscan=(514, 16, 5120, 256, 3, 128),
                   swa=(16, 28, 4, 128, 4096, 8192, 128),
                   blocks=(96, 4, 32, 4, 128, 1300, 128)) -> dict:
     """Each Pallas kernel of the train and serve paths against the XLA path
@@ -210,7 +211,14 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     at ``chunk`` = (tiles, rows a tile, sub-chunk; a mixed step's 3 tiles x
     128 rows) against ``kda_tiles`` between slices (``kda_chunk_ms``), two
     tiles of one slot and a fresh one, the strongest decay on a quarter of
-    the heads; float32 both, so ``kda_chunk_*`` are held to 1e-5. Last, a
+    the heads; float32 both, so ``kda_chunk_*`` are held to 1e-5. The two
+    Mamba-1 kernels at ``selscan`` = (state rows, state size, channels, decode
+    rows, tiles, rows a tile; the Jamba cell's 256 rows and 3 tiles of 128 over
+    [16, 5120] states): ``selscan_decode`` against gather -> update -> scatter
+    and ``selscan_tile`` against a ``lax.scan`` over a tile's rows, both timed
+    with the state donated (``selscan_decode_ms``, ``selscan_tile_ms``),
+    ``dt`` log-uniform in the seeded draw's [0.001, 0.1] and ``A[n] = n + 1``;
+    float32 and the same operations in the same order, held to 1e-5. Last, a
     sliding-window layer's decode walk at ``swa`` = (rows, q heads, kv heads,
     head size, window, context, block; the SmallThinker cell's 16 rows x 28
     heads x 4,096 of an 8,192 context) and its tile kernel over four tiles of
@@ -400,6 +408,49 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
         close(name, got, want, tol=1e-5)
     chunk_ms = median_ms(forms, outs, tiles)
 
+    from deepspeed_tpu.ops.pallas.selscan import selscan_decode, selscan_tile
+
+    rows_n, n, ch, t, n_i, r = selscan
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 5), 10))
+    state = jax.random.normal(next(keys), (rows_n, n, ch), jnp.float32)
+    a = -jnp.broadcast_to(jnp.arange(1.0, n + 1)[:, None], (n, ch))
+
+    def steps(key, *lead):
+        return jnp.exp(jax.random.uniform(key, (*lead, ch), jnp.float32,
+                                          np.log(1e-3), np.log(0.1)))
+
+    where = jnp.asarray(np.random.default_rng(SEED).permutation(rows_n)[:t],
+                        jnp.int32)
+    step = (where, jnp.arange(t) % 7 == 3, steps(next(keys), t),
+            jax.random.normal(next(keys), (t, ch), jnp.bfloat16), a,
+            jax.random.normal(next(keys), (t, n), jnp.float32),
+            jax.random.normal(next(keys), (t, n), jnp.float32))
+    forms = {name: jax.jit(lambda s, *x, name=name: selscan_decode(
+        s, *x, impl=name), donate_argnums=0) for name in ("pallas", "xla")}
+    outs = {name: fn(state + 0.0, *step) for name, fn in forms.items()}
+    for name, got, want in zip(("selscan_decode_state", "selscan_decode_y"),
+                               outs["pallas"], outs["xla"]):
+        close(name, got, want, tol=1e-5)
+    selscan_decode_ms = median_ms(forms, outs, step)
+    slot, other, spare = (int(x) for x in where[:3])
+    tiles = (jnp.asarray([slot] * (n_i - 1) + [other], jnp.int32),
+             jnp.asarray([spare] * (n_i - 2) + [slot, other], jnp.int32),
+             jnp.arange(n_i) == n_i - 1,                       # fresh
+             (jnp.arange(n_i) > 0) & (jnp.arange(n_i) < n_i - 1),
+             jnp.arange(n_i) >= n_i - 2,                       # write
+             steps(next(keys), n_i, r).at[-1, r // 2:].set(0.0),
+             jax.random.normal(next(keys), (n_i, r, ch), jnp.bfloat16), a,
+             jax.random.normal(next(keys), (n_i, r, n), jnp.float32),
+             jax.random.normal(next(keys), (n_i, r, n), jnp.float32))
+    forms = {name: jax.jit(lambda s, *x, name=name: selscan_tile(
+        s, *x, impl=name), donate_argnums=0) for name in ("pallas", "xla")}
+    outs = {name: fn(state + 0.0, *tiles) for name, fn in forms.items()}
+    for name, got, want in zip(("selscan_tile_state", "selscan_tile_y"),
+                               outs["pallas"], outs["xla"]):
+        close(name, got, want, tol=1e-5)
+    selscan_tile_ms = median_ms(forms, outs, tiles)
+    del state, outs
+
     rows_n, hq, hkv, d, window, context, blk = swa
     mb = context // blk
     keys = iter(jax.random.split(jax.random.PRNGKey(SEED + 4), 4))
@@ -501,7 +552,9 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                  geometries=[list(g) for g in geometries],
                  shapes={"seq_len": seq_len, "tile": tile, "block": block},
                  max_rel_err=worst, kda_decode_ms=kda_ms,
-                 kda_chunk_ms=chunk_ms, swa_decode_ms=swa_ms["swa_decode"],
+                 kda_chunk_ms=chunk_ms, selscan_decode_ms=selscan_decode_ms,
+                 selscan_tile_ms=selscan_tile_ms,
+                 swa_decode_ms=swa_ms["swa_decode"],
                  swa_prefill_ms=swa_ms["swa_prefill"],
                  memory=_memory())
 

@@ -3042,7 +3042,10 @@ class RaggedInferenceEngine:
         it carries: the arrivals since the last dispatch, and a preempted
         request run again), and ``state_kind``, the recurrence's name where
         the model gives one (``ModelSpec.state_kind``: ``"mamba2"``,
-        ``"kda"``). The dispatch feeds the same to
+        ``"kda"``, ``"mamba1"``), and for ``"mamba1"`` ``scan_tiles``, the
+        tiles the step program runs the selective scan over, a layer (no
+        chunk form computes that recurrence: ``selscan_tile``'s grid is
+        channel blocks x these). The dispatch feeds the same to
         ``inference_slot_state_bytes_total`` (``part`` ``decode`` /
         ``prefill``), ``inference_slot_state_pad_rows_total`` and
         ``inference_slot_resets_total``. Nothing for a model with none."""
@@ -3056,6 +3059,7 @@ class RaggedInferenceEngine:
                 "chunk_tiles": nt,
                 "state_pad_rows": nd - n_dec,
                 "slot_resets": self._slot_resets,
+                **({"scan_tiles": nt} if kind == "mamba1" else {}),
                 **({} if kind is None else {"state_kind": kind})}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:

@@ -434,7 +434,9 @@ class ModelSpec:
     # a family whose layers carry a recurrent state a slot (slot leaves in its
     # paged cache, ``models/paged.py``) names the recurrence: ``"mamba2"`` (a
     # scalar decay a head, the state fed by an outer product), ``"kda"`` (a
-    # decay a channel, the delta rule). ``state_kind`` on ``engine/dispatch``
+    # decay a channel, the delta rule), ``"mamba1"`` (a decay a channel AND
+    # state index, fed by an outer product: a scan, no chunk form of matmuls
+    # computes it). ``state_kind`` on ``engine/dispatch``
     # and the label of ``inference_slot_state_bytes_total``; None: no state
     state_kind: str | None = None
     # a family some of whose attention layers read the last ``sliding_window``

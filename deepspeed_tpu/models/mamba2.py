@@ -16,6 +16,18 @@ group: over all ``d_inner`` lanes); ``out = y W_out``. What a sequence carries
 from token to token is ``S`` (float32) and the last ``K - 1`` rows of ``xBC``,
 whatever its length.
 
+**Beside ``models/mamba1.py``** (``jamba``'s Mamba-1 mixer). It shares
+``causal_conv``, the slot leaves' layout rule (``init_slot_leaves``: the state
+size first, the lanes last, the convolution's rows a window leaf) and
+``ragged``'s rules for a step's rows (decode rows then tiles, ``cont`` /
+``fresh`` / ``write``: ``tile_rows``; ``dt = 0`` past a tile's valid rows, the
+scratch slot). It cannot share ``ssd_tiles`` (a head's scalar decay factors out of a chunk,
+Mamba-1's decay a channel and state index does not: its tiles are a scan,
+``ops/pallas/selscan.py``), ``split`` / ``xbc_split`` (Mamba-1 convolves ``x``
+alone and makes ``dt``, ``B``, ``C`` after the convolution, through a
+bottleneck and three norms), ``mixer_out`` (no gated norm there) nor
+``ssm_decode`` (one decay a lane, handed in).
+
 ``cfg`` is the family's config; read here: ``d_inner``, ``conv_width``,
 ``conv_kernel``, ``n_groups``, ``ssm_state_size``, ``mamba_num_heads``,
 ``mamba_head_dim``, ``chunk_size``, ``rms_norm_eps`` and, by the draws,
@@ -233,6 +245,25 @@ def sequence(cfg, lp, h):
 
 
 
+def tile_rows(ts, tp, slot0, scratch):
+    """A step's prefill tiles (``ts`` their slots, ``tp`` their first
+    positions) as a slot family's mixer addresses them: ``(rows, rows_w,
+    fresh, cont, write)``, where a tile's state lies, where the state it ends
+    with goes, whether it starts from zeros (position 0), goes on where the
+    tile before ended, writes."""
+    real = ts != scratch
+    rows = ts + slot0
+    fresh = tp == 0
+    # tile i goes on where tile i - 1 of the same slot ended
+    cont = jnp.concatenate([jnp.zeros((1,), bool),
+                            (ts[1:] == ts[:-1]) & real[1:]])
+    write = real & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
+    # a tile that is not its slot's last of the step, and a padding tile,
+    # write the scratch slot, and write it zeros
+    rows_w = jnp.where(write, rows, slot0 + scratch)
+    return rows, rows_w, fresh, cont, write
+
+
 def ragged(cfg, h, lp, state, slot0, scratch, slots, positions,
            prefill_tiles):
     """The mixer over a flat ragged token batch ``h`` [T, D] (normed) ->
@@ -277,16 +308,7 @@ def ragged(cfg, h, lp, state, slot0, scratch, slots, positions,
     if t > n_dec:
         _, ts, tp, tv, r = prefill_tiles
         n_i = ts.shape[0]
-        real = ts != scratch
-        rows = ts + slot0
-        fresh = tp == 0
-        # tile i goes on where tile i - 1 of the same slot ended
-        cont = jnp.concatenate([jnp.zeros((1,), bool),
-                                (ts[1:] == ts[:-1]) & real[1:]])
-        write = real & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
-        # a tile that is not its slot's last of the step, and a padding
-        # tile, write the scratch slot, and write it zeros
-        rows_w = jnp.where(write, rows, slot0 + scratch)
+        rows, rows_w, fresh, cont, write = tile_rows(ts, tp, slot0, scratch)
         win, conv = tile_windows(conv, rows, rows_w,
                                  xbc[n_dec:].reshape(n_i, r, -1), cont, fresh,
                                  write, tv)

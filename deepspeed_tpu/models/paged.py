@@ -2,8 +2,8 @@
 
 One home for what every family's serving path shares (gpt2, llama, mixtral
 on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
-nemotron_h, granite_hybrid and kimi_linear with a recurrent state a slot
-beside either;
+nemotron_h, granite_hybrid, jamba and kimi_linear with a recurrent state a
+slot beside either;
 smallthinker with sliding-window layers in a second pool): the
 paged-pool write, the decode/tiled-prefill attention split over the block
 pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
@@ -78,7 +78,13 @@ v1-style engines.
   128`` does not divide keeps ``[L_s, S, K - 1, W]`` (``granite_hybrid``:
   8,448 channels are 66 lane tiles, which no whole bfloat16 tile folds; its
   step programs copy the 30 MB leaf to the compiler's axis order and back,
-  PERF.md section 7). ``init_window_leaf``
+  PERF.md section 7). A width that HALF the tile's rows fold (``r`` = 8
+  bfloat16: ``jamba``'s 5,120 channels are 40 lane tiles, ``[26, 257, 24,
+  640]``) takes that fold: a slot is then a tile and a half, padded to two in
+  memory, and still enters a step program row-major with nothing of the
+  leaf's size copied, where ``[.., 3, 5120]`` was copied whole, twice a
+  step (205 MB each way at the Jamba cell's sizes, PERF.md section 6, PR
+  53). ``init_window_leaf``
   builds the leaf, ``read_windows`` / ``write_windows`` / ``window_fold``
   read the form off the array, ``decode_windows`` / ``tile_windows`` are a
   step's decode rows and prefill tiles through them: one gather and one
@@ -208,12 +214,11 @@ def init_window_leaf(layers: int, slots: int, taps: int, width: int, dtype):
     """The slot leaf of a causal convolution's carried rows (module doc,
     *Window leaves*): a slot's last ``taps`` rows of ``width`` channels,
     oldest first, ``[L_s, S, taps x r, width / r]`` with ``r`` the rows of
-    the dtype's sublane tile (8 float32, 16 bfloat16), or ``r = 1``
-    (``[L_s, S, taps, width]``) for a width that ``r x 128`` does not
-    divide."""
-    r = 32 // jnp.dtype(dtype).itemsize
-    if width % (r * 128):
-        r = 1
+    the dtype's sublane tile (8 float32, 16 bfloat16), half of them for a
+    width that only ``r / 2 x 128`` divides, or ``r = 1`` (``[L_s, S, taps,
+    width]``) for a width that neither does."""
+    full = 32 // jnp.dtype(dtype).itemsize
+    r = next((r for r in (full, full // 2) if width % (r * 128) == 0), 1)
     return jnp.zeros((layers, slots, taps * r, width // r), dtype)
 
 

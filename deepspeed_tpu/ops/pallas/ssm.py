@@ -27,6 +27,16 @@ makes the gathered rows and the updated rows as arrays of their own and so
 moves every byte twice more; ``chip_smoke.py`` compares the two, PERF.md
 section 6 (PR 31) has the times.
 
+Three recurrences keep their state this way (the state size or key channels on
+the sublanes, what is read out side by side on the lanes, all layers and slots
+in one array, a row a grid step, in place): Mamba-2 here, one decay a lane
+handed in by the caller; Kimi Delta Attention in ``ops/pallas/kda.py``, a
+decay a key channel and the delta rule; Mamba-1 in ``ops/pallas/selscan.py``,
+a decay a channel AND state index, ``exp(dt (x) A)``, which that kernel
+computes itself from the row's ``dt`` and the layer's resident ``A`` (as
+``da`` here it would be a second array the state's size through HBM) and
+whose prefill tiles are a scan and not a chunk of matmuls.
+
 Padding rows all name the scratch slot. Their ``dA`` is 1 and their ``dtx`` 0
 (the caller masks ``dt``), so whatever order the pipeline reads and writes
 the scratch row in, it stays what it was.

@@ -238,7 +238,9 @@ def test_kernel_compiles_for_v5e(v5e, kernel, n_calls, hq, hkv, d):
 # head size, block size, table width, rows, pool blocks)
 DECODE_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, 32, 4, 513),
                 "mixtral.chat": (32, 8, 128, 128, 8, 16, 1025),
-                "mixtral.longdoc": (32, 8, 128, 128, 64, 16, 1537)}
+                "mixtral.longdoc": (32, 8, 128, 128, 64, 16, 1537),
+                # 20 query heads on ONE K/V head: a pool row is one lane tile
+                "jamba.reason": (20, 1, 128, 128, 32, 256, 8193)}
 
 
 @pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
@@ -419,6 +421,63 @@ def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e):
     assert mem.temp_size_in_bytes < 128 * 4096 * 4
 
 
+def _selscan_decode(state, rows, fresh, dt, x, a, b, c):
+    from deepspeed_tpu.ops.pallas.selscan import selscan_decode
+
+    return selscan_decode(state, rows, fresh, dt, x, a, b, c, impl="pallas",
+                          interpret=False)
+
+
+def _selscan_tile(state, rows, rows_w, fresh, cont, write, dt, x, a, b, c):
+    from deepspeed_tpu.ops.pallas.selscan import selscan_tile
+
+    return selscan_tile(state, rows, rows_w, fresh, cont, write, dt, x, a, b,
+                        c, impl="pallas", interpret=False)
+
+
+SELSCAN = (26 * 257, 16, 5120)   # the Jamba cell's state: layers x slots
+
+
+def _selscan_args(devices, kernel, n):
+    """The Mamba-1 state of the Jamba cell, 26 layers x 257 slots of [16,
+    5120] float32, and a decode bucket of ``n`` rows or ``n`` tiles of 128."""
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+    rows_n, ns, ch = SELSCAN
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    if kernel is _selscan_decode:
+        return (s(SELSCAN), s((n,), jnp.int32), s((n,), jnp.bool_), s((n, ch)),
+                s((n, ch), jnp.bfloat16), s((ns, ch)), s((n, ns)), s((n, ns)))
+    return (s(SELSCAN), *(s((n,), jnp.int32),) * 2, *(s((n,), jnp.bool_),) * 3,
+            s((n, 128, ch)), s((n, 128, ch), jnp.bfloat16), s((ns, ch)),
+            s((n, 128, ns)), s((n, 128, ns)))
+
+
+@pytest.mark.parametrize("kernel,n", [(_selscan_decode, 256),
+                                      (_selscan_decode, 128),
+                                      (_selscan_tile, 3), (_selscan_tile, 1)],
+                         ids=["decode_256", "decode_128", "tile_3", "tile_1"])
+def test_selscan_kernels_compile_for_v5e_and_update_in_place(v5e, kernel, n):
+    """``selscan_decode``: a row's whole state (327 KB) a grid step beside
+    the layer's resident ``A``, the row's ``B`` and ``C`` one ``[1, 32]`` lane
+    row whose diagonal is the column; ``selscan_tile``: a block of 1,280
+    channels and a tile a grid step, a dynamic float32 row load and store a
+    row, the bfloat16 ``x`` 16 rows at a time, under the raised VMEM limit.
+    The donated state is the output (aliased) and nothing else in the
+    program is as large as one tile's ``dt``."""
+    compiled = jax.jit(kernel, donate_argnums=(0,)).lower(
+        *_selscan_args(v5e, kernel, n)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= (f32|bf16)\[\d+,128,5120\]\S* (copy|transpose)\(",
+                         text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(SELSCAN) * 4
+    assert mem.temp_size_in_bytes < 128 * 5120 * 4
+
+
 @pytest.mark.parametrize("kernel,name", [
     (_flash_fwd, "flash_fwd"), (_flash_bwd, "flash_bwd_dkv"),
     (_flash_bwd, "flash_bwd_dq"), (_decode, "paged_decode"),
@@ -426,11 +485,13 @@ def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e):
     (_mla_prefill, "mla_prefill"), ("moe_gmm", "moe_gmm"),
     ("moe_gmm_ungated", "moe_gmm"), ("ssm_decode", "ssm_decode"),
     ("kda_decode", "kda_decode"), (_kda_chunk, "kda_chunk"),
+    (_selscan_decode, "selscan_decode"), (_selscan_tile, "selscan_tile"),
     (_dsa_index, "dsa_index"), (_dsa_decode, "dsa_attn_decode"),
     (_dsa_prefill, "dsa_attn_prefill"), (_dsa_walk, "dsa_attn_decode")],
     ids=["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq", "paged_decode",
          "tiled_prefill", "mla_decode", "mla_prefill", "moe_gmm",
          "moe_gmm_ungated", "ssm_decode", "kda_decode", "kda_chunk",
+         "selscan_decode", "selscan_tile",
          "dsa_index",
          "dsa_attn_decode",
          "dsa_attn_prefill", "dsa_attn_decode_walk"])
@@ -463,6 +524,8 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
         text = _compiled(_kda_decode, *_kda_args(v5e, rows=8)).as_text()
     elif kernel is _kda_chunk:
         text = _compiled(kernel, *_kda_chunk_args(v5e, tiles=2)).as_text()
+    elif kernel in (_selscan_decode, _selscan_tile):
+        text = _compiled(kernel, *_selscan_args(v5e, kernel, 2)).as_text()
     elif kernel in (_dsa_index, _dsa_decode, _dsa_prefill, _dsa_walk):
         text = _compiled(kernel, *_dsa_args(kernel, v5e)).as_text()
     else:
@@ -517,7 +580,8 @@ PREFILL_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, None, 32, 513, 8),
                  "mixtral.longdoc": (32, 8, 128, 128, None, 64, 1537, 4),
                  "nemotron.reason": (32, 2, 128, 128, None, 32, 4097, 4),
                  "smallthinker.full": (28, 4, 128, 128, None, 64, 641, 4),
-                 "smallthinker.window": (28, 4, 128, 128, 4096, 64, 529, 4)}
+                 "smallthinker.window": (28, 4, 128, 128, 4096, 64, 529, 4),
+                 "jamba.reason": (20, 1, 128, 128, None, 32, 8193, 4)}
 
 
 @pytest.mark.parametrize("cell,tiles", [(c, 3) for c in sorted(PREFILL_CELLS)]
@@ -961,8 +1025,21 @@ def _relayout_family(name):
     table width, projections, those known to be re-laid out still)`` at the
     serving cells' attention widths; depth, FFN, experts and vocabulary
     small."""
-    from deepspeed_tpu.models import deepseek, granite_hybrid, smallthinker
+    from deepspeed_tpu.models import (
+        deepseek,
+        granite_hybrid,
+        jamba,
+        smallthinker,
+    )
 
+    if name == "jamba":
+        # ai21-jamba2-3b.json's mixers, MLP and pool at their published
+        # widths: m a m m (runs of 1, 1 and 2); a 128th of the table
+        return jamba, jamba.JambaConfig(
+            vocab_size=512, num_layers=4, attn_layer_period=4,
+            attn_layer_offset=1), 8193, 257, 32, (
+                "wq", "wk", "wv", "wo", "w_in", "w_x", "w_dt", "w_out",
+                "w_gate", "w_up", "w_down"), ()
     if name == "mixtral":
         mod, cfg, blocks, _, table = _step_family(name)
         return mod, cfg, blocks, 33, table, ("wq", "wk", "wv", "wo"), ()
@@ -998,7 +1075,8 @@ GRANITE_VOCAB = 3136   # no array of a step's rows has 3,136 x 4,096 elements
 
 @pytest.mark.parametrize("family,rows,tiles", [
     ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
-    ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0)])
+    ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0),
+    ("jamba", 256, 3), ("jamba", 256, 0)])
 def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
                                                       rows, tiles):
     """The paged contract's *Rows to heads* (``models/paged.py``), on the
@@ -1315,6 +1393,77 @@ def test_step_program_holds_no_layer_slice_of_the_slot_state(v5e, monkeypatch,
         # one layer body: a scatter of the decode rows, one of the tiles
         _window_leaf_stays_put(text, cache["slots"]["conv"],
                                bool(rows) + bool(tiles))
+
+
+@pytest.mark.parametrize("rows,tiles", [(256, 2), (256, 0), (0, 2)],
+                         ids=["mixed", "decode", "prefill"])
+def test_step_program_holds_no_layer_slice_of_the_selscan_state(
+        v5e, monkeypatch, rows, tiles):
+    """The slot-leaf clause for the third recurrence, at AI21-Jamba2-3B's
+    Mamba-1 and attention widths and the cell's pool and slots (m a m m; MLP
+    and vocabulary small): the decode rows go through ``selscan_decode`` and
+    the tiles through ``selscan_tile``, both with the float32 state ``[3 x
+    257, 16, 5120]`` aliased in and out, so no gather, slice, copy or
+    re-layout of it or of a layer's part exists; the K and V of ONE K/V head
+    (128 lanes a row) are scattered as in every other family; and the
+    convolution's window leaf ``[3, 257, 24, 640]`` (5,120 channels fold over
+    HALF a bfloat16 tile's rows: ``paged.init_window_leaf``) enters row-major
+    and is touched by the rows' scatters alone. Kept ``[.., 3, 5120]`` the
+    compiler copied the whole leaf (205 MB at the cell's 26 layers) to an
+    axis order of its own and back, every step (PERF.md section 6, PR 53)."""
+    from deepspeed_tpu.models import jamba
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    cfg = jamba.JambaConfig(vocab_size=512, intermediate_size=256,
+                            num_layers=4, attn_layer_period=4,
+                            attn_layer_offset=1)
+    blocks, block, table, slots = 8193, 128, 32, 257
+    pin = _row_major(v5e)
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        jamba.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.eval_shape(lambda: jamba.init_paged_cache(
+        cfg, blocks, block, jnp.bfloat16, num_slots=slots))
+    ssm, conv = cache["slots"]["ssm"], cache["slots"]["conv"]
+    assert ssm.shape == (3, slots, 16, 5120) and ssm.dtype == jnp.float32
+    assert conv.shape == (3, slots, 24, 640)
+    assert cache["k"].shape == (1, blocks, block, 128)
+    state_slice = slots * 16 * 5120 * 4
+
+    def i32(*shape):
+        return pin(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    def step(params, cache, tokens, slots, positions, tables, ts, tp, tv):
+        return jamba.ragged_forward(
+            cfg, params, tokens, slots, positions, tables, cache,
+            prefill_tiles=(rows, ts, tp, tv, TILE))
+
+    t, nt = rows + tiles * TILE, max(tiles, 1)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        pin(params), pin(cache), i32(t), i32(t), i32(t), i32(slots, table),
+        i32(nt), i32(nt), i32(nt)).compile()
+    text = compiled.as_text()
+    # (the compiler's own prefetches of a layer's weights into its nearer
+    # memory keep the stored layout: ``_projection_results`` says the same)
+    big = [(size, op, ln) for size, op, ln in _materialized(text)
+           if size >= state_slice and not op.endswith("-done")
+           and op != "custom-call"]
+    assert [ln for _, op, ln in big if op not in _IN_PLACE] == []
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    # two layer bodies with Mamba layers (a run of one, a run of two)
+    assert sum("selscan_decode" in n for n in names) == (2 if rows else 0)
+    assert sum("selscan_tile" in n for n in names) == (2 if tiles else 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < state_slice
+    # the window leaf enters as the engine's array lies and no copy of it
+    # (or of a layer's part) exists
+    entry = _computations(text)[re.search(r"ENTRY (%[\w.\-]+)", text).group(1)]
+    shape = ",".join(map(str, conv.shape))
+    layouts = [m.group(1) for ln in entry for m in [re.search(
+        rf"= bf16\[{shape}\](\{{[^ ]*\}}) parameter\(", ln)] if m]
+    assert len(layouts) == 1 and layouts[0].startswith("{3,2,1,0:"), layouts
+    assert not re.search(r"= bf16\[(3,257|771|257),24,640\]\S* (copy|transpose)\(",
+                         text)
 
 
 @pytest.mark.parametrize("window", [False, True], ids=["state", "window"])

@@ -385,7 +385,16 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "kernel.chat_ssm_decode_share",
                          "kernel.chat_ssm_decode_roofline",
                          "model.chat_ssm_step_roofline_kv",
-                         "kernel.chat_hybrid_paged_decode_roofline")}}
+                         "kernel.chat_hybrid_paged_decode_roofline")},
+                     # the Mamba-1 kernels' readers read a slice of the Jamba
+                     # cell: its spans say ``state_kind`` "mamba1" and
+                     # ``scan_tiles``, its steps run ``selscan_decode`` and
+                     # ``selscan_tile``
+                     **{m: "v5e_jamba_reason_spans" for m in (
+                         "kernel.selscan_decode_share",
+                         "kernel.selscan_decode_roofline",
+                         "kernel.selscan_tile_share",
+                         "kernel.selscan_tile_roofline")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -403,7 +412,8 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "v5e_smallthinker_mixedlen_slices_spans":
                    "smallthinker-21b-a3b-ep8.mixedlen-pool",
                    "v5e_granite_chat_spans":
-                   "granite-4.0-h-small-d10-ep2.chat-open"}
+                   "granite-4.0-h-small-d10-ep2.chat-open",
+                   "v5e_jamba_reason_spans": "ai21-jamba2-3b.reason-pool"}
 
 
 def _new_readers():
