@@ -122,12 +122,39 @@ def init_slot_leaves(cfg, layers: int, num_slots: int, dtype) -> dict:
 def split(cfg, h, lp):
     """``h`` [..., D] (normed) -> ``z`` [..., d_inner], ``xBC`` [..., conv
     width] (before the convolution), ``dt`` [..., H] float32 (after the
-    bias and the softplus)."""
-    di = cfg.d_inner
+    bias and the softplus).
+
+    ONE product, and its three column parts handed out as arrays of their
+    own, tied by ``lax.optimization_barrier`` so that the compiler cannot
+    look through them to the product. ``xBC`` and ``dt`` are read at once;
+    ``z`` only at the layer's very end (``mixer_out``), after the decode
+    rows' kernel, the tiles' chunk form and every slot write. As plain slices
+    of ``zxbcdt``, keeping ``z`` meant keeping the whole result (15 MB at
+    Granite's 448 rows x 16,768) in the chip's near memory across the layer:
+    the compiler evicted it and, rather than fetch it back, made the product
+    a SECOND time for the gate (``%fusion.669.remat = bf16[448,16768]``:
+    0.1609 s of a 4.0 s slice of ``granite-4.0-h-small-d10-ep2.chat-open``,
+    0.0858 s of the Nemotron cell's; ledger, PR 53). Tied, what lives across
+    the layer is ``z`` alone and every column is made once:
+    ``tests/unit/test_compile_tpu.py
+    test_mamba2_step_makes_the_in_projection_once`` holds that on the
+    compiled programs at the cells' sizes. The tie is no arithmetic (the
+    parts are the product's columns bit for bit, the gradients through them
+    too) and one path for every program, decode-only ones included.
+
+    NOT two products over column slices of ``W_in`` (``z`` made where it is
+    read): with two readers the compiler stops reading the layer's weight
+    where the stack keeps it, slices the layer's whole ``W_in`` (137 MB) out
+    of the stack into a buffer of its own every step and rematerialises
+    THAT (compiled for a described v5e, PR 54; the fault
+    ``test_step_program_relays_out_no_projection_weight`` names)."""
+    di, cw = cfg.d_inner, cfg.conv_width
     zxbcdt = h @ lp["w_in"].astype(h.dtype)
-    dt = jax.nn.softplus(zxbcdt[..., di + cfg.conv_width:].astype(jnp.float32)
+    z, xbc, dt = lax.optimization_barrier(
+        (zxbcdt[..., :di], zxbcdt[..., di:di + cw], zxbcdt[..., di + cw:]))
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + lp["dt_bias"].astype(jnp.float32))
-    return zxbcdt[..., :di], zxbcdt[..., di:di + cfg.conv_width], dt
+    return z, xbc, dt
 
 
 def causal_conv(cfg, win, w, b, rows: int):

@@ -1073,24 +1073,12 @@ def _relayout_family(name):
 GRANITE_VOCAB = 3136   # no array of a step's rows has 3,136 x 4,096 elements
 
 
-@pytest.mark.parametrize("family,rows,tiles", [
-    ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
-    ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0),
-    ("jamba", 256, 3), ("jamba", 256, 0)])
-def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
-                                                      rows, tiles):
-    """The paged contract's *Rows to heads* (``models/paged.py``), on the
-    compiled program with the arguments laid out as an engine's are: a step
-    reads a layer's projection weights where the stack keeps them. Before
-    PR 50 the q and k products came out head-major for the rotation and the
-    compiler paid for that on the weights: in every rotated layer's body
-    ``%constant_dynamic-slice_fusion = bf16[1,4096,4096]{2,1,0}`` (a layer's
-    ``wq`` sliced out of the stack) and ``%copy = bf16[1,4096,4096]{1,2,0}``
-    (transposed), the same for ``wk``: 5.2% of the window cell's device time
-    (ledger, PR 49). ``paged.rows_to_heads`` pins the product and the rows
-    are copied instead. The check for the next family: give it a case."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
-    mod, cfg, blocks, slots, table, names, known = _relayout_family(family)
+def _step_text(v5e, mod, cfg, blocks, slots, table, rows, tiles):
+    """``(optimized HLO, abstract bf16 parameters)`` of ``mod``'s ragged step
+    of ``rows`` decode rows and ``tiles`` tiles, compiled for the first
+    described chip with the arguments laid out as an engine's are
+    (``_row_major``); ``blocks`` a pair: a full and a sliding pool, a block
+    table each."""
     window = isinstance(blocks, tuple)
     pin = _row_major(v5e)
     params = jax.eval_shape(lambda: jax.tree_util.tree_map(
@@ -1113,6 +1101,29 @@ def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
     text = jax.jit(step, donate_argnums=(1,)).lower(
         pin(params), pin(cache), i32(t), i32(t), i32(t),
         (bt, bt) if window else bt, i32(n), i32(n), i32(n)).compile().as_text()
+    return text, params
+
+
+@pytest.mark.parametrize("family,rows,tiles", [
+    ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
+    ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0),
+    ("jamba", 256, 3), ("jamba", 256, 0)])
+def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
+                                                      rows, tiles):
+    """The paged contract's *Rows to heads* (``models/paged.py``), on the
+    compiled program with the arguments laid out as an engine's are: a step
+    reads a layer's projection weights where the stack keeps them. Before
+    PR 50 the q and k products came out head-major for the rotation and the
+    compiler paid for that on the weights: in every rotated layer's body
+    ``%constant_dynamic-slice_fusion = bf16[1,4096,4096]{2,1,0}`` (a layer's
+    ``wq`` sliced out of the stack) and ``%copy = bf16[1,4096,4096]{1,2,0}``
+    (transposed), the same for ``wk``: 5.2% of the window cell's device time
+    (ledger, PR 49). ``paged.rows_to_heads`` pins the product and the rows
+    are copied instead. The check for the next family: give it a case."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    mod, cfg, blocks, slots, table, names, known = _relayout_family(family)
+    text, params = _step_text(v5e, mod, cfg, blocks, slots, table, rows,
+                              tiles)
     assert text.count("tpu_custom_call") >= 1
     found = _projection_results(text, params, names)
     assert [ln for name, ln in found if name not in known] == []
@@ -1127,6 +1138,73 @@ def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
         assert [ln for size, op, ln in _materialized(text)
                 if size == 2 * table and " bf16[" in ln
                 and op not in _IN_PLACE and not op.endswith("-done")] == []
+
+
+@pytest.mark.parametrize("cell,rows,tiles", [
+    ("granite-4.0-h-small-d10-ep2.chat-open", 64, 3),
+    ("granite-4.0-h-small-d10-ep2.chat-open", 64, 2),
+    ("granite-4.0-h-small-d10-ep2.chat-open", 64, 0),
+    ("nemotron-3-super-120b-d11-ep4.reason-pool", 128, 3)],
+    ids=["granite-64-3", "granite-64-2", "granite-64-0", "nemotron-128-3"])
+def test_mamba2_step_makes_the_in_projection_once(v5e, monkeypatch, cell,
+                                                  rows, tiles):
+    """A Mamba-2 layer's step makes each column of ``h @ W_in`` exactly once
+    (``models/mamba2.split``), on the step programs of the two cells that run
+    the mixer, compiled at the cells' OWN depth, expert count, vocabulary,
+    pool and slots, read through ``benchmark/cellspec.py`` as the harness
+    reads them (12.3-12.6 GB of arguments: the 5-layer configuration of
+    ``test_step_program_relays_out_no_projection_weight[granite-64-3]`` does
+    not reproduce this). Before PR 54 ``z``, ``xBC`` and ``dt`` were slices
+    of one result whose last reader (``z``: the gated norm) comes after the
+    whole layer, so the compiler evicted the 15 MB product from its nearer
+    memory and, rather than fetch it back, made it AGAIN for the gate:
+    ``%fusion.669.remat = bf16[448,16768]{1,0:T(8,128)(2,1)S(1)}`` and
+    ``%fusion.730.remat`` in Granite's two scanned bodies (0.0894 + 0.0715 =
+    0.1609 s of a 4.0 s slice, ledger PR 53), ``bf16[320,16768]`` at two
+    tiles, ``%fusion.423.remat = bf16[512,18560]`` in the hybrid cell's
+    (0.0858 s). Held here: no instruction the compiler rematerialised yields
+    the product or one of its column parts; each scanned Mamba body has ONE
+    reader of its ``W_in`` stack, the product, which reads the stack in place
+    (no ``bf16[D, d_inner + conv_width + H]`` of a slice or a copy: splitting
+    the product over the weight's columns gave a 137 MB one a layer)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    monkeypatch.syspath_prepend(os.path.join(
+        os.path.dirname(__file__), os.pardir, os.pardir, "benchmark"))
+    import cellspec
+
+    spec = cellspec.resolve(cell)
+    mod, cfg, _ = cellspec.model(spec)
+    sizes = {**spec["config"]["serve"]["engine"],   # as serve_cell.py's
+             **spec["cell"].get("engine", {})}
+    text, params = _step_text(
+        v5e, mod, cfg, sizes["num_blocks"], sizes["max_seqs"] + 1,
+        sizes["max_blocks_per_seq"], rows, tiles)
+    t = rows + tiles * TILE
+    d, parts = cfg.hidden_size, (cfg.d_inner, cfg.conv_width,
+                                 cfg.mamba_num_heads)
+    made = re.compile(r"\s+(?:ROOT )?(%[\w.\-]+) = bf16\[(\d+),(\d+)\]")
+    again = [m.group(0) for ln in text.splitlines() for m in [made.match(ln)]
+             if m and ".remat" in m.group(1) and int(m.group(2)) == t
+             and int(m.group(3)) in parts + (sum(parts),)]
+    assert again == []
+    # a scanned Mamba body is a computation that takes its run's stack out
+    # of the loop's carry; its readers, the tuple that carries it on aside
+    stack = re.compile(rf"\s+(%[\w.\-]+) = bf16\[\d+,{d},{sum(parts)}\]\S* "
+                       r"get-tuple-element\(")
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    readers = [
+        [ln.strip() for ln in lines
+         if re.search(rf"[(, ]{re.escape(m.group(1))}[,)]", ln)
+         and not re.search(r" (tuple|while)\(", ln)]
+        for comp, lines in _computations(text).items() if comp not in fused
+        for m in map(stack.match, lines) if m]
+    assert len(readers) == sum(
+        getattr(path[-1], "key", None) == "w_in"
+        for path, _ in jax.tree_util.tree_flatten_with_path(params)[0])
+    for lines in readers:
+        assert len(lines) == 1 and f" = bf16[{t},{sum(parts)}]" in lines[0], \
+            lines
+    assert _projection_results(text, params, ("w_in",)) == []
 
 
 def test_a_table_kept_column_major_is_copied_for_its_row_gather(v5e,

@@ -258,6 +258,64 @@ def test_ssm_decode_kernel_at_one_group_is_the_xla_form():
                                   np.asarray(state)[untouched])
 
 
+# ------------------------------------------------- the in-projection's parts
+def _plain_split(cfg, h, lp):
+    """What ``mamba2.split`` computes, written as the columns of the one
+    product with nothing between them and their readers."""
+    di, cw = cfg.d_inner, cfg.conv_width
+    zxbcdt = h @ lp["w_in"].astype(h.dtype)
+    dt = jax.nn.softplus(zxbcdt[..., di + cw:].astype(jnp.float32)
+                         + lp["dt_bias"].astype(jnp.float32))
+    return zxbcdt[..., :di], zxbcdt[..., di:di + cw], dt
+
+
+@pytest.mark.parametrize("how", ["jit", "grad"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("family", ["granite", "nemotron"])
+def test_split_hands_out_the_columns_of_the_one_product(family, dtype, how):
+    """``mamba2.split`` ties its three parts so that the compiler keeps
+    ``z`` and not the whole product across the layer (PR 54;
+    ``test_compile_tpu.test_mamba2_step_makes_the_in_projection_once``). The
+    tie is no arithmetic: the parts are the columns of ``h @ W_in`` (``dt``
+    their softplus) bit for bit, at one group and at two, in the dtype
+    served and the dtype trained, and so are the gradients through them
+    (``mamba2.sequence`` trains through ``split``)."""
+    mod, cfg = {"granite": (granite_hybrid, CFG),
+                "nemotron": (nemotron_h, nemotron_h.NemotronHConfig.tiny())
+                }[family]
+    tree = mod.init_params(cfg, jax.random.PRNGKey(3))
+    stack = tree["runs"][0]["mix"] if family == "granite" else tree["mamba"]
+    lp = jax.tree_util.tree_map(lambda a: a[1].astype(dtype), stack)
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    h = jax.random.normal(keys[0], (20, cfg.hidden_size), jnp.float32).astype(
+        dtype)
+    widths = (cfg.d_inner, cfg.conv_width, cfg.mamba_num_heads)
+    assert lp["w_in"].shape == (cfg.hidden_size, sum(widths))
+    if how == "jit":
+        got = jax.jit(lambda h, lp: mamba2.split(cfg, h, lp))(h, lp)
+        want = jax.jit(lambda h, lp: _plain_split(cfg, h, lp))(h, lp)
+        assert [a.shape[-1] for a in got] == list(widths)
+        assert [a.dtype for a in got] == [dtype, dtype, jnp.float32]
+    else:
+        weights = [jax.random.normal(k, (20, w), jnp.float32)
+                   for k, w in zip(keys[1:], widths)]
+
+        def loss(split, h, lp):
+            return sum(jnp.sum(part.astype(jnp.float32) * w)
+                       for part, w in zip(split(cfg, h, lp), weights))
+
+        got, want = (jax.jit(jax.grad(functools.partial(loss, split),
+                                      argnums=(0, 1)))(h, lp)
+                     for split in (mamba2.split, _plain_split))
+        assert float(jnp.abs(got[1]["w_in"].astype(jnp.float32)).sum()) > 0
+        assert float(jnp.abs(got[1]["dt_bias"].astype(jnp.float32)).sum()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
 # -------------------------------------------------------------- the stack
 def test_the_stack_is_runs_and_the_mixer_is_shared():
     assert CFG.runs == [("mamba", 2), ("attention", 1), ("mamba", 3)]

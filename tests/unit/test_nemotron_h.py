@@ -405,9 +405,12 @@ def test_both_window_forms_serve_the_same_logits():
 # lowered BEFORE the Mamba-2 code moved to ``models/mamba2.py`` (commit
 # 9c8cdec, jax 0.9.0, CPU lowering). A change that is meant to alter this
 # family's step program re-pins it and says so; one that is not does not get
-# to.
+# to. Re-pinned by PR 54 (it was ``eddfb049...5cf5c9``): ``mamba2.split``
+# ties its three parts, which is ONE more ``stablehlo.optimization_barrier``
+# (the scanned period's Mamba body) with the three slices in the parts'
+# order before it; every other line is the parent's but for value numbers.
 _MIXED_STEP_SHA256 = \
-    "eddfb049fe8815af177cfd0603ac226ce51c5546059735cd96aab2c54f5cf5c9"
+    "a7ad283c4c0031d0eebc4703edfc5a0688d24f65a2ca7264b725b304989b12fc"
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0",

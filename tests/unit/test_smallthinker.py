@@ -72,7 +72,11 @@ def step_jaxpr_digests(on_tpu: bool) -> dict:
     forms. ``paged.rows_to_heads``' pin (PR 50) is taken out: it is one
     ``optimization_barrier`` a projection and no arithmetic
     (``test_the_pin_of_rows_to_heads_changes_no_value``), and without it
-    PR 50's tree still prints PR 48's programs to the letter."""
+    PR 50's tree still prints PR 48's programs to the letter. ``nemotron_h``'s
+    two digests are PR 54's: ``mamba2.split`` ties its three parts, one more
+    ``optimization_barrier`` a Mamba body and the slices in the parts' order
+    (no arithmetic: ``test_granite_hybrid.
+    test_split_hands_out_the_columns_of_the_one_product``)."""
     from deepspeed_tpu.inference.ragged import (RaggedConfig,
                                                 RaggedInferenceEngine)
     from deepspeed_tpu.models import paged
