@@ -45,6 +45,11 @@ from typing import Any
 import numpy as np
 
 from deepspeed_tpu.checkpoint import serialization as ser
+from deepspeed_tpu.utils.faults import (
+    POINT_CKPT_COMMIT,
+    POINT_CKPT_LATEST,
+    get_fault_injector,
+)
 from deepspeed_tpu.utils.logging import log_dist
 
 MANIFEST = "manifest.json"
@@ -63,16 +68,6 @@ class CheckpointCorruptError(RuntimeError):
         super().__init__(message)
         self.stage = stage
         self.tag = tag
-
-
-def _fire(point: str, path: str | None = None) -> None:
-    """Checkpoint-seam fault injection (lazy import: serving.faults pulls
-    telemetry only, but keep checkpoint importable standalone)."""
-    try:
-        from deepspeed_tpu.serving import faults
-    except Exception:  # pragma: no cover - injection is best-effort
-        return
-    faults.get_fault_injector().fire(point, path=path)
 
 
 class CheckpointEngine:
@@ -140,7 +135,8 @@ def write_latest(save_dir: str, tag: str) -> None:
     """Atomically move the ``latest`` pointer: temp file + fsync +
     ``os.replace`` + dir fsync. The pointer is the last word of the commit —
     it only ever names a fully committed tag."""
-    _fire("ckpt.latest", path=os.path.join(save_dir, "latest"))
+    get_fault_injector().fire(
+        POINT_CKPT_LATEST, path=os.path.join(save_dir, "latest"))
     ser.atomic_write_text(os.path.join(save_dir, "latest"), str(tag))
 
 
@@ -207,7 +203,8 @@ def commit_checkpoint(save_dir: str, tag: str, manifest: dict) -> str:
     ser.fsync_dir(stage)
     # a kill between here and the replace leaves a complete .tmp dir and an
     # untouched previous checkpoint — exactly the "old state" outcome
-    _fire("ckpt.commit", path=os.path.join(stage, MANIFEST))
+    get_fault_injector().fire(
+        POINT_CKPT_COMMIT, path=os.path.join(stage, MANIFEST))
     if os.path.isdir(final):
         # re-saving an existing tag: move the old dir aside first so the
         # promote below lands on a free name (rename-onto-nonempty fails)

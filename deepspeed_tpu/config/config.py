@@ -15,7 +15,6 @@ precision is bf16-default; offload targets are host DRAM / NVMe on the TPU-VM.
 from __future__ import annotations
 
 import json
-import os
 from dataclasses import dataclass, field
 from typing import Any, ClassVar, Optional, Union
 
@@ -752,22 +751,6 @@ class SentinelConfig(ConfigBase):
 
 
 @dataclass
-class AutotuningConfig(ConfigBase):
-    """Tuned-profile loading at startup (reference ds_config
-    ``autotuning`` block; docs/AUTOTUNING.md). When enabled,
-    ``deepspeed_tpu.initialize`` looks up the persisted profile for
-    (model fingerprint, topology, ``workload``) under ``profile_dir`` and
-    fills knobs the config file did not write — explicit config values
-    always win over tuned ones."""
-
-    enabled: bool = False
-    profile_dir: str = os.path.join("runs", "autotune")
-    # workload class the profile was tuned on (one model can carry distinct
-    # profiles for e.g. "default" vs "long-context" training recipes)
-    workload: str = "default"
-
-
-@dataclass
 class Config(ConfigBase):
     """Top-level framework config (reference: ``DeepSpeedConfig``)."""
 
@@ -806,7 +789,6 @@ class Config(ConfigBase):
         default_factory=ProgressiveLayerDropConfig)
     eigenvalue: EigenvalueConfig = field(default_factory=EigenvalueConfig)
     sentinel: SentinelConfig = field(default_factory=SentinelConfig)
-    autotuning: AutotuningConfig = field(default_factory=AutotuningConfig)
     # reference ds_config["compression_training"] shape, parsed by
     # deepspeed_tpu.compression.CompressionConfig (QAT + pruning schedules)
     compression_training: dict = field(default_factory=dict)

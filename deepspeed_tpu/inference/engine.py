@@ -82,11 +82,11 @@ class InferenceEngine:
         if checkpoint is not None:
             self.load_checkpoint(checkpoint)
         # ONE low-bit config surface shared with the ragged engine
-        # (inference/kvquant.py): the woq component merges with the
+        # (ops/kvquant.py): the woq component merges with the
         # back-compat quantize_bits arg; '+qcol' quantizes the TP logits
         # all-gather; a KV codec only applies to the paged pool, so it is
         # accepted-but-inert on this dense-cache engine (logged).
-        from deepspeed_tpu.inference import kvquant
+        from deepspeed_tpu.ops import kvquant
 
         parsed = kvquant.parse_quant(quant)
         self._qcol = parsed.qcol and self.topo.size("tensor") > 1
@@ -117,7 +117,7 @@ class InferenceEngine:
         gather. Traced inside the jitted generate/forward programs."""
         if not self._qcol:
             return logits
-        from deepspeed_tpu.inference import kvquant
+        from deepspeed_tpu.ops import kvquant
 
         return kvquant.quantized_logits_all_gather(
             logits, self.topo.mesh, axis="tensor")

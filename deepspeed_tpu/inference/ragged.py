@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference import kvquant
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx
 from deepspeed_tpu.models.paged import (
     block_leaves,
@@ -47,8 +46,12 @@ from deepspeed_tpu.models.paged import (
     sliding_leaves,
     tiles_go_as_slices,
 )
+from deepspeed_tpu.ops import kvquant
 from deepspeed_tpu.ops.attention import prefill_step_keys
-from deepspeed_tpu.serving.faults import (
+from deepspeed_tpu.telemetry import get_telemetry
+from deepspeed_tpu.telemetry.memledger import is_resource_exhausted, record_oom
+from deepspeed_tpu.telemetry.tracing import format_traceparent
+from deepspeed_tpu.utils.faults import (
     POINT_ALLOC,
     POINT_DISPATCH,
     POINT_H2D,
@@ -56,9 +59,6 @@ from deepspeed_tpu.serving.faults import (
     classify_transient,
     get_fault_injector,
 )
-from deepspeed_tpu.telemetry import get_telemetry
-from deepspeed_tpu.telemetry.memledger import is_resource_exhausted, record_oom
-from deepspeed_tpu.telemetry.tracing import format_traceparent
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.tracing import phase, span
 
@@ -395,10 +395,6 @@ class RaggedConfig:
     # error escalates out of step(); fatal errors never retry
     dispatch_retries: int = 2
     retry_backoff_s: float = 0.05
-    retry_backoff_max_s: float = 2.0
-    # multiplicative jitter on each backoff sleep, drawn from an
-    # engine-seeded RNG so a replayed run backs off identically
-    retry_jitter: float = 0.25
     # consecutive device-path failures that trigger automatic degradation:
     # device-resident state -> host-staged step -> the same with prefill
     # tiles off (token-identical rungs). 0 disables degradation.
@@ -422,9 +418,6 @@ class RaggedConfig:
     # bytes_limit (the CPU test accelerator) yields "unknown" headroom and
     # the static path verbatim either way.
     headroom_admission: bool = False
-    # fraction of bytes_limit held back from the measured free bytes before
-    # converting headroom to KV blocks (allocator slack + fragmentation)
-    headroom_guard_fraction: float = 0.05
     # consecutive zero-progress scheduler ticks spent headroom-pinned before
     # the stall alarm raises (a headroom wait must never be a silent forever
     # hang — external pressure is expected to lift, and when it doesn't the
@@ -447,19 +440,13 @@ class RaggedConfig:
     kv_tier_disk_blocks: int = 0
     # spill directory; swept for torn temp files at engine startup
     kv_tier_dir: str = "runs/kvtier"
-    # modeled tier-crossing bandwidths for the promotion cost model
-    # (host<->device link, and disk read). <= 0 = unknown, which
-    # conservatively never restores from that tier.
+    # modeled host<->device bandwidth for the promotion cost model. <= 0 =
+    # unknown, which conservatively never restores from that tier.
     kv_tier_host_gbps: float = 100.0
-    kv_tier_disk_gbps: float = 8.0
     # modeled prefill throughput the restore competes against (the same
     # constant ClusterConfig.prefill_tokens_per_s models for wire transfers)
     kv_tier_prefill_tokens_per_s: float = 50000.0
-    # router-kicked async prefetch: stage disk records up to the host arena
-    # while the request rides the queue, so the admission-time restore only
-    # pays the host->device hop
-    kv_tier_prefetch: bool = True
-    # ---- low-bit serving (inference/kvquant.py) ----
+    # ---- low-bit serving (ops/kvquant.py) ----
     # ONE config surface for the full low-bit path, grammar
     # "off" | "int8" | "fp8" | "woq8" | "woq4" | "qcol" joined with "+"
     # (e.g. "int8+woq8"). The KV codec makes the *block* the unit of
@@ -477,6 +464,19 @@ class RaggedConfig:
     @property
     def max_seq_len(self) -> int:
         return self.block_size * self.max_blocks_per_seq
+
+
+# The engine's constants, each with the one method that reads it.
+# ``_backoff``: the cap of the doubling sleep between retries, and the
+# multiplicative jitter on it (engine-seeded, so a replayed run backs off
+# identically)
+RETRY_BACKOFF_MAX_S = 2.0
+RETRY_JITTER = 0.25
+# ``admission_headroom_blocks``: share of bytes_limit held back from the
+# measured free bytes (allocator slack + fragmentation)
+HEADROOM_GUARD_FRACTION = 0.05
+# ``_init``: modeled disk read bandwidth of the tier promotion cost model
+KV_TIER_DISK_GBPS = 8.0
 
 
 @dataclass
@@ -772,7 +772,7 @@ class RaggedInferenceEngine:
             lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
             params,
         )
-        # ---- low-bit serving (inference/kvquant.py) ----
+        # ---- low-bit serving (ops/kvquant.py) ----
         # ONE config surface: cfg.quant carries the KV codec, the woq bits
         # and the collective flag; the quantize_bits ctor arg stays as the
         # back-compat spelling of the woq component.
@@ -896,7 +896,7 @@ class RaggedInferenceEngine:
                 disk_blocks=self.cfg.kv_tier_disk_blocks,
                 directory=self.cfg.kv_tier_dir,
                 host_gbps=self.cfg.kv_tier_host_gbps,
-                disk_gbps=self.cfg.kv_tier_disk_gbps,
+                disk_gbps=KV_TIER_DISK_GBPS,
                 prefill_tokens_per_s=self.cfg.kv_tier_prefill_tokens_per_s,
                 bytes_per_token=self.kv_bytes_per_token(),
                 codec=self._kvq_name,
@@ -1654,7 +1654,7 @@ class RaggedInferenceEngine:
             bb_win = self._leaves_block_bytes(sliding_leaves(self.cache))
             pool_funded = (self.allocator.free_blocks * (bb - bb_win)
                            + self.window_allocator.free_blocks * bb_win)
-        usable = free + pool_funded - int(cfg.headroom_guard_fraction * limit)
+        usable = free + pool_funded - int(HEADROOM_GUARD_FRACTION * limit)
         return max(0, usable // bb)
 
     def _enforce_retained_budget(self) -> int:
@@ -2121,7 +2121,7 @@ class RaggedInferenceEngine:
         the same racy-but-safe read-only index probes
         ``cached_prefix_len`` already makes off-thread."""
         store = self._kvtier
-        if store is None or not self.cfg.kv_tier_prefetch:
+        if store is None:
             return False
         prompt = [int(t) for t in np.asarray(prompt_tokens).reshape(-1)]
         keys = self._chain_keys(prompt)
@@ -3724,9 +3724,8 @@ class RaggedInferenceEngine:
                         f"free_blocks={self.allocator.free_blocks} "
                         f"bytes_in_use={stats.get('bytes_in_use')} "
                         f"bytes_limit={stats.get('bytes_limit')}); another "
-                        "HBM owner is pinning the device — lower "
-                        "headroom_guard_fraction, free the external "
-                        "allocation, or disable headroom_admission"
+                        "HBM owner is pinning the device — free the "
+                        "external allocation or disable headroom_admission"
                     )
                 return
             # has_work but nothing schedulable: every sequence is stalled on
@@ -3852,9 +3851,9 @@ class RaggedInferenceEngine:
 
     def _backoff(self, attempt: int) -> None:
         cfg = self.cfg
-        base = min(cfg.retry_backoff_max_s,
+        base = min(RETRY_BACKOFF_MAX_S,
                    cfg.retry_backoff_s * (2 ** (attempt - 1)))
-        time.sleep(base * (1.0 + cfg.retry_jitter * self._retry_rng.random()))
+        time.sleep(base * (1.0 + RETRY_JITTER * self._retry_rng.random()))
 
     def _step_watched(self) -> dict:
         """Run ``_step_impl`` under the dispatch watchdog: transient

@@ -1,5 +1,5 @@
-"""GPT-2 causal LM — the correctness-baseline model (BASELINE.json: GPT-2 125M
-ZeRO-1 single-host config).
+"""GPT-2 causal LM — the model of the training cell and of ``chip_smoke.py``
+(``benchmark/configs/gpt2-xl.json``: GPT-2 XL at its published widths).
 
 LayerNorm(+bias), learned positional embeddings, GELU MLP, tied LM head —
 matching HF ``GPT2LMHeadModel`` semantics. Same functional stacked-scan design

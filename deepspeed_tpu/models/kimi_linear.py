@@ -45,22 +45,11 @@ layers are ``DK`` + 6 x ``KMKK`` + ``M``, seven layers to compile). A step
 program's scan then hands every layer a slice of a stack that is nothing
 else's, with no copy.
 
-**The chunk form** (``kda_tiles``). A tile of ``R`` rows runs the recurrence
-as matmuls. With ``G_i = sum_{j <= i} g_j`` a channel, ``A[i, j] = beta_i sum_c
-k_i[c] k_j[c] exp(G_i[c] - G_j[c])`` (``j < i``) and ``B[i, j]`` the same with
-``q_i`` and ``j <= i``: ``(I + A) U = beta (V - (K exp(G)) S_0)``, ``O = (Q
-exp(G)) S_0 + B U``, ``S_R = exp(G_R) S_0 + (K exp(G_R - G))^T U``. Every decay
-is ``exp`` of a DIFFERENCE of cumulative log-decays with the later row first,
-so it is at most 1: ``exp(-G_j)`` alone overflows float32 (a channel's ``g``
-reaches -1.6 a token, ``G`` -205 over 128 rows). ``A`` and ``B`` are built in
-sub-chunks of ``sub_chunk`` (16) rows: inside a sub-chunk from pairwise
-differences (``[C, C, K]``), between sub-chunks as a matmul of rows decayed
-from the later sub-chunk's first row and columns decayed up to it (both
-factors <= 1); the unit lower triangular system is solved by forward
-substitution over the sub-chunks, each sub-chunk's 16 x 16 block inverted by
-its finite Neumann series. float32 and ``Precision.HIGHEST`` throughout. Rows
-of a tile past its valid ones have ``g = 0`` and ``beta = 0``: they neither
-decay nor feed the state.
+**The chunk form** (``ops/pallas/kda.kda_tiles``; that module's doc has the
+algebra). A tile of ``R`` rows runs the recurrence as matmuls, in sub-chunks
+of ``sub_chunk`` (16) rows, float32 and ``Precision.HIGHEST`` throughout.
+Rows of a tile past its valid ones have ``g = 0`` and ``beta = 0``: they
+neither decay nor feed the state.
 
 **Serving.** The MLA layers' rows ``[c, k_pe, zeros]`` (``row_lanes``, 640) lie
 in the latent pool, ``cache["kv"]`` ``[L_mla, NB, BS, row_lanes]``; the KDA
@@ -118,7 +107,6 @@ from deepspeed_tpu.models.llama import rmsnorm
 # reads and writes the scratch slot's 2 MB a KDA layer, 1.5% of a step a row)
 DECODE_BUCKET_MIN = 128
 STATE_KIND = "kda"
-_HIGHEST = lax.Precision.HIGHEST
 # the seeded gates: ``dt`` log-uniform over this range a channel (``init_params``)
 _DT_RANGE = (0.001, 0.1)
 
@@ -510,118 +498,12 @@ def _kda_out(cfg: KimiLinearConfig, o, gate, lp):
     return o @ lp["wo"].astype(gate.dtype)
 
 
-def _block_inverse(a):
-    """``(I + a)^-1`` of strictly lower triangular ``a`` [..., C, C]: ``a`` is
-    nilpotent, so the Neumann series ends, ``sum_{n < C} (-a)^n = (I - a)(I +
-    a^2)(I + a^4)...``: log2(C) squarings."""
-    c = a.shape[-1]
-    eye = jnp.eye(c, dtype=a.dtype)
-    p = -a
-    inv = eye + p
-    n = 2
-    while n < c:
-        p = jnp.matmul(p, p, precision=_HIGHEST)
-        inv = jnp.matmul(inv, eye + p, precision=_HIGHEST)
-        n *= 2
-    return inv
-
-
-def kda_tiles(q, k, v, g, beta, s0, cont, sub: int):
-    """The chunk form over ``I`` tiles of ``R`` rows, one chunk each (module
-    doc): ``q`` / ``k`` [I, R, H, K] and ``v`` [I, R, H, V] float32 (``q``
-    scaled), ``g`` [I, R, H, K] (the log-decay, 0 on rows that must neither
-    decay nor feed the state), ``beta`` [I, R, H] (0 on those rows), ``s0``
-    [I, K, H x V] float32 (the state each tile would start from were it its
-    slot's first of the step), ``cont`` [I] bool (tile ``i`` goes on where
-    tile ``i - 1`` ended), ``sub`` the sub-chunk (divides ``R``) -> ``(y [I,
-    R, H x V], s [I, K, H x V])``: the recurrence's readings and each tile's
-    final state. float32, ``Precision.HIGHEST``."""
-    n_i, r, h, kd = q.shape
-    vd = v.shape[-1]
-    n = r // sub
-    ein = partial(jnp.einsum, precision=_HIGHEST)
-    gc = jnp.cumsum(g, axis=1)                                   # [I, R, H, K]
-    g5 = gc.reshape(n_i, n, sub, h, kd)
-    # a sub-chunk's reference: the cumulative log-decay before its first row
-    ref = jnp.concatenate([jnp.zeros_like(g5[:, :1, -1]), g5[:, :-1, -1]], 1)
-    local = g5 - ref[:, :, None]                                 # <= 0
-    k5, q5 = (t.reshape(n_i, n, sub, h, kd) for t in (k, q))
-    # inside a sub-chunk: pairwise differences, later row first
-    tri = jnp.tril(jnp.ones((sub, sub), bool))[None, None, :, :, None, None]
-    pair = jnp.exp(jnp.where(tri, local[:, :, :, None] - local[:, :, None],
-                             -jnp.inf))                          # [I,N,C,C,H,K]
-    kk_in = jnp.sum(k5[:, :, :, None] * k5[:, :, None] * pair, axis=-1)
-    qk_in = jnp.sum(q5[:, :, :, None] * k5[:, :, None] * pair, axis=-1)
-    # between sub-chunks: rows decayed from their sub-chunk's reference,
-    # columns (every earlier row of the tile) decayed up to it
-    decay_in = jnp.exp(local)
-    early = (jnp.arange(r)[None, :] < (jnp.arange(n) * sub)[:, None])
-    cols = k[:, None] * jnp.exp(jnp.where(
-        early[None, :, :, None, None], ref[:, :, None] - gc[:, None], -jnp.inf))
-    kk_off = ein("inchk,inrhk->ihncr", k5 * decay_in, cols)
-    qk_off = ein("inchk,inrhk->ihncr", q5 * decay_in, cols)
-
-    def whole(inside, off, strict):
-        """[I, H, R, R]: the sub-chunks' own blocks on the diagonal."""
-        keep = jnp.tril(jnp.ones((sub, sub), bool), -1 if strict else 0)
-        inside = jnp.where(keep[None, None, :, :, None], inside, 0.0)
-        eye = jnp.eye(n, dtype=inside.dtype)
-        blocks = jnp.einsum("incdh,nm->ihncmd", inside, eye)
-        return off.reshape(n_i, h, r, r) + blocks.reshape(n_i, h, r, r)
-
-    beta_h = beta.transpose(0, 2, 1)[..., None]                  # [I, H, R, 1]
-    a_mat = whole(kk_in, kk_off, True) * beta_h
-    b_mat = whole(qk_in, qk_off, False)
-    # (I + A) X = beta [K exp(G) | V]: forward substitution over sub-chunks
-    k_dec = k * jnp.exp(gc)
-    rhs = jnp.concatenate([k_dec, v], axis=-1).transpose(0, 2, 1, 3) * beta_h
-    a_blocks = a_mat.reshape(n_i, h, n, sub, n, sub)
-    inv = _block_inverse(jnp.stack(
-        [a_blocks[:, :, j, :, j] for j in range(n)], axis=2))    # [I,H,N,C,C]
-
-    # a scan, not ``n`` copies of its body (nor ``I`` of the carry's below):
-    # every tiled step program compiles this once a KDA layer it holds. A
-    # sub-chunk's row of ``A`` is taken whole: its columns from its own
-    # diagonal block on meet rows of ``x`` that are still zero
-    def substitute(x, step):
-        j, a_rows, rhs_rows, inv_j = step
-        mine = rhs_rows - ein("ihcr,ihrx->ihcx", a_rows, x)
-        return lax.dynamic_update_slice_in_dim(
-            x, ein("ihcd,ihdx->ihcx", inv_j, mine), j * sub, axis=2), None
-
-    def blocks(t):  # [I, H, R, ...] -> [N, I, H, C, ...]
-        return jnp.moveaxis(
-            t.reshape((n_i, h, n, sub) + t.shape[3:]), 2, 0)
-
-    x, _ = lax.scan(substitute, jnp.zeros_like(rhs),
-                    (jnp.arange(n), blocks(a_mat), blocks(rhs),
-                     jnp.moveaxis(inv, 2, 0)))
-    w, uv = x[..., :kd], x[..., kd:]                             # [I,H,R,K|V]
-    q_dec = (q * jnp.exp(gc)).transpose(0, 2, 1, 3)              # [I, H, R, K]
-    k_end = (k * jnp.exp(gc[:, -1:] - gc)).transpose(0, 2, 1, 3)
-    total = jnp.exp(gc[:, -1]).transpose(0, 2, 1)                # [I, K, H]
-
-    # the carry from tile to tile, in order: U reads the state it feeds
-    def tile(after, xs):
-        w_i, uv_i, q_i, b_i, k_i, total_i, s0_i, cont_i = xs
-        prev = jnp.where(cont_i, after, s0_i)
-        u = uv_i - ein("hrk,khv->hrv", w_i, prev)
-        y = ein("hrk,khv->rhv", q_i, prev) + ein("hrs,hsv->rhv", b_i, u)
-        after = prev * total_i[..., None] + ein("hrk,hrv->khv", k_i, u)
-        return after, (y, after)
-
-    s0 = s0.reshape(n_i, kd, h, vd)
-    _, (ys, after) = lax.scan(
-        tile, jnp.zeros_like(s0[0]),
-        (w, uv, q_dec, b_mat, k_end, total, s0,
-         cont & (jnp.arange(n_i) > 0)))
-    return (ys.reshape(n_i, r, h * vd), after.reshape(n_i, kd, h * vd))
-
-
 def _kda_sequence(cfg: KimiLinearConfig, lp, h):
     """The KDA mixer over one whole sequence ``h`` [S, D] from an empty
     state, for the plain forward pass: a scan over chunks of the form the
     serving tiles run."""
+    from deepspeed_tpu.ops.pallas.kda import kda_tiles
+
     s, r, kc = h.shape[0], cfg.chunk_size, cfg.conv_kernel
     qkv, g, beta, gate = _kda_inputs(cfg, h, lp)
     win = jnp.concatenate([jnp.zeros((kc - 1, qkv.shape[1]), qkv.dtype), qkv])

@@ -1,6 +1,7 @@
 """Llama-family causal LM (Llama 2/3 architecture), TPU-first.
 
-The flagship training model (BASELINE.json north star: Llama-3-8B ZeRO-3).
+Its RMSNorm / RoPE / GQA backbone is what the MoE families
+(``models/mixtral.py`` and the ones after it) build on.
 Functional design: parameters are a pytree with a *stacked* leading layer dim,
 the decoder runs as one ``lax.scan`` over that stack — one compiled layer body
 regardless of depth (fast compiles, natural pipeline partitioning, uniform

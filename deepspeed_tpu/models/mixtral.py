@@ -1,4 +1,5 @@
-"""Mixtral-family sparse-MoE causal LM (BASELINE.json EP config: Mixtral-8x7B).
+"""Mixtral-family sparse-MoE causal LM (``benchmark/configs/mixtral-8x7b-d3.json``:
+Mixtral-8x7B's widths).
 
 Llama backbone (RMSNorm / RoPE / GQA) with a top-k routed SwiGLU expert FFN in
 every layer (reference analog: ``deepspeed/moe/layer.py MoE`` wrapping an HF

@@ -164,7 +164,7 @@ def init_paged_pool(num_layers: int, num_blocks: int, block_size: int,
     shape = (num_layers, num_blocks, block_size, kv_heads * head_dim)
     if codec is None:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    from deepspeed_tpu.inference.kvquant import QuantizedKV
+    from deepspeed_tpu.ops.kvquant import QuantizedKV
 
     def pool():
         return QuantizedKV(
@@ -558,7 +558,7 @@ def write_rows_paged(pool, rows, slots, positions, block_tables,
     and the step program holds no other operation of the pool's shape.
 
     This is the ONE write site of the paged contract, so it is also the
-    ONE quantize site: a low-bit pool (``inference/kvquant.QuantizedKV``)
+    ONE quantize site: a low-bit pool (``ops/kvquant.QuantizedKV``)
     quantizes each token row at write time — per-row scales keep the
     incremental scatter exact (rewriting a row never re-rounds another). A
     quantized pool keeps the row form for every row (``scatter_rows``; no

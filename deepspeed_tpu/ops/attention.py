@@ -196,7 +196,7 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     keys ``j <= (pos | (B - 1))``, its block whole: the kernel takes a block
     as one row (``blk_decode``), the XLA form moves the mask.
 
-    A quantized pool (``inference/kvquant.QuantizedKV``) always takes the
+    A quantized pool (``ops/kvquant.QuantizedKV``) always takes the
     XLA path: the gather+dequant fuse into one program there (the fp
     context is a per-dispatch transient). A Pallas kernel that streams
     int8 blocks + scales through VMEM is the TPU drop-in point — it slots

@@ -49,6 +49,7 @@ from deepspeed_tpu.runtime import precision
 from deepspeed_tpu.runtime import sentinel as sentinel_mod
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, build_schedule
 from deepspeed_tpu.runtime.precision import LossScaleState
+from deepspeed_tpu.utils import faults as _faults
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import ThroughputTimer
 from deepspeed_tpu.utils.tracing import StepTracer, phase, span
@@ -827,10 +828,7 @@ class Engine:
         self._last_batch_fps: list[str] = []
         self._last_save_dir: str | None = None
         self.train_rollbacks = 0
-        from deepspeed_tpu.serving import faults as _faults_mod
-
-        self._faults = _faults_mod
-        self._fault_injector = _faults_mod.get_fault_injector()
+        self._fault_injector = _faults.get_fault_injector()
         if sent_cfg.enabled:
             conflicts = {
                 "quantized_gradients": self._qgrad,
@@ -981,7 +979,7 @@ class Engine:
     def _microbatch_grads(self, params, mb, rng, scale, step=None):
         """Scaled-loss grads for one microbatch, fp32, ZeRO-sharded."""
         cparams = self._cast_params(params)
-        # fault-injection rail (serving/faults.py train.grads / data.batch
+        # fault-injection rail (utils/faults.py train.grads / data.batch
         # directive kinds): a NaN multiplier models nan-grads, a large
         # finite one a poisoned/divergent batch — applied INSIDE the tape
         # so the gradients blow up with the loss. Key presence is static
@@ -2381,15 +2379,15 @@ class Engine:
                     # _max_inflight steps behind the wedge.
                     sentinel_mod.watched_call(
                         lambda: (self._fault_injector.fire(
-                            self._faults.POINT_TRAIN_DISPATCH),
+                            _faults.POINT_TRAIN_DISPATCH),
                             jax.block_until_ready(metrics["loss"])),
                         self._watchdog_timeout)
                 elif self._fault_injector.enabled:
-                    self._fault_injector.fire(self._faults.POINT_TRAIN_DISPATCH)
+                    self._fault_injector.fire(_faults.POINT_TRAIN_DISPATCH)
             except sentinel_mod.TrainingWedgeError as e:
                 return self._handle_wedge(e)
         elif self._fault_injector.enabled:
-            self._fault_injector.fire(self._faults.POINT_TRAIN_DISPATCH)
+            self._fault_injector.fire(_faults.POINT_TRAIN_DISPATCH)
         # NO per-step device sync here: steps pipeline and Python overhead
         # hides under device compute. _after_step syncs only when a
         # consumer (monitor / steps_per_print / fp16 bookkeeping) needs values.
@@ -2662,7 +2660,7 @@ class Engine:
     # ------------------------------------------------------------------ sentinel
     def _sentinel_pre_step(self, batch):
         """Fingerprint the step's microbatches and consult the train.grads /
-        data.batch fault seams (serving/faults.py directive kinds). Returns
+        data.batch fault seams (utils/faults.py directive kinds). Returns
         the (possibly poisoned) batch — injection rides a ``__loss_mult__``
         key consumed inside the grad tape (``_microbatch_grads``), so the
         loss AND its gradients blow up together like a real poisoned batch.
@@ -2685,10 +2683,10 @@ class Engine:
         inj = self._fault_injector
         if not inj.enabled:
             return batch
-        directive = inj.fire(self._faults.POINT_TRAIN_GRADS)
+        directive = inj.fire(_faults.POINT_TRAIN_GRADS)
         if directive is None:
             for fp in self._last_batch_fps:
-                directive = inj.fire(self._faults.POINT_DATA_BATCH,
+                directive = inj.fire(_faults.POINT_DATA_BATCH,
                                      request_id=fp)
                 if directive is not None:
                     break
@@ -3042,8 +3040,6 @@ class Engine:
 
         from deepspeed_tpu.checkpoint import engine as ckpt
         from deepspeed_tpu.checkpoint import sharded
-        from deepspeed_tpu.serving import faults as _faults
-
         inj = _faults.get_fault_injector()
         ckpt_t0 = time.perf_counter()
         tag = tag or f"global_step{self.global_steps}"
@@ -3186,8 +3182,6 @@ class Engine:
             candidates.insert(0, requested)
         if not candidates:
             return None, None, None
-        from deepspeed_tpu.serving import faults as _faults
-
         inj = _faults.get_fault_injector()
         tel = self.telemetry
         fallbacks = 0
@@ -3262,8 +3256,6 @@ class Engine:
         from deepspeed_tpu.checkpoint import serialization as ser
 
         from deepspeed_tpu.checkpoint import sharded
-        from deepspeed_tpu.serving import faults as _faults
-
         ckpt_t0 = time.perf_counter()
         self._join_ckpt_writer()
         _faults.get_fault_injector().fire(_faults.POINT_CKPT_LOAD)
@@ -3445,22 +3437,7 @@ def initialize(
     """
     if model is None:
         raise ValueError("initialize() requires a model (ModelSpec or builder callable)")
-    if isinstance(config, str):
-        # read the file once here: the tuned-profile precedence check below
-        # needs the raw key set, not just the parsed Config
-        import json as _json
-
-        with open(config) as f:
-            config = _json.load(f)
     cfg = load_config(config)
-    if cfg.autotuning.enabled:
-        # fill knobs the config did not write from the persisted autotune
-        # profile for (this model, this topology, this workload); explicit
-        # config values always win (docs/AUTOTUNING.md)
-        from deepspeed_tpu.autotuning.profiles import maybe_apply_train_profile
-
-        maybe_apply_train_profile(
-            cfg, config if isinstance(config, dict) else None, model)
     mics = cfg.zero_optimization.mics_shard_size
     if mics > 0:
         # MiCS (reference mics.py:63): shard degree = group size k < world.

@@ -47,6 +47,7 @@ from deepspeed_tpu.runtime.pipe.schedule import (
 from deepspeed_tpu.runtime.pipe.transport import (
     ACT, GRAD, InProcTransport, TransportAborted)
 from deepspeed_tpu.telemetry.tracing import format_traceparent
+from deepspeed_tpu.utils import faults as _faults
 from deepspeed_tpu.utils.logging import log_dist
 
 try:
@@ -424,7 +425,7 @@ class PipeEngine(Engine):
         try:
             for ins in self._thread_programs[thread]:
                 if inj.enabled:
-                    inj.fire(self._faults.POINT_PIPE_STAGE,
+                    inj.fire(_faults.POINT_PIPE_STAGE,
                              request_id=f"stage{thread}")
                 self._exec_instr(ins, ctx)
                 if hb is not None:
@@ -530,7 +531,7 @@ class PipeEngine(Engine):
                 return self._handle_wedge(e)
             raise
         if self._fault_injector.enabled:
-            self._fault_injector.fire(self._faults.POINT_TRAIN_DISPATCH)
+            self._fault_injector.fire(_faults.POINT_TRAIN_DISPATCH)
         if scope is not None:
             jax.block_until_ready(metrics["loss"])
             # the pipe's device window is carved as the step residual; the

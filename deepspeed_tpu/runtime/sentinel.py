@@ -25,7 +25,7 @@ verdicts into recovery:
   must stop the beat), polled by ``elasticity.agent.ElasticAgent`` so a
   wedged-but-alive worker is SIGKILLed and the world restarts.
 - :func:`watched_call` — the dispatch watchdog's deadline fence; raises
-  :class:`TrainingWedgeError` (transient in the ``serving/faults.py``
+  :class:`TrainingWedgeError` (transient in the ``utils/faults.py``
   ``classify_transient`` taxonomy) when the device fence exceeds it.
 
 Everything here is off-by-default; with the sentinel disabled the engine
@@ -61,7 +61,7 @@ _REASON_LABELS = (
     (REASON_WEDGE, "wedge"),
 )
 
-# Injection magnitudes for the directive fault kinds (serving/faults.py
+# Injection magnitudes for the directive fault kinds (utils/faults.py
 # train.grads / data.batch seams): the loss multiplier the engine folds into
 # the batch. NaN models nan-grads; the finite factor models a poisoned /
 # divergent batch whose loss AND grads blow up together.
@@ -85,7 +85,7 @@ class DivergenceHaltError(RuntimeError):
 class TrainingWedgeError(TimeoutError):
     """The training dispatch fence exceeded the watchdog deadline (a wedged
     device program or stuck transfer). Subclasses ``TimeoutError`` so the
-    shared ``serving.faults.classify_transient`` taxonomy treats it as
+    shared ``utils.faults.classify_transient`` taxonomy treats it as
     transient — the recovery is rollback/restart, not crash."""
 
 

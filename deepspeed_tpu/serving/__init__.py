@@ -54,7 +54,15 @@ from deepspeed_tpu.serving.protocol import (  # noqa: F401
     encode_sse,
     sse_done,
 )
-from deepspeed_tpu.serving.faults import (  # noqa: F401
+from deepspeed_tpu.serving.router import (  # noqa: F401
+    DeadlineExceeded,
+    Draining,
+    Overloaded,
+    ReplicaRouter,
+    RouterConfig,
+    plan_placement,
+)
+from deepspeed_tpu.utils.faults import (  # noqa: F401
     POINT_ALLOC,
     POINT_CKPT_COLLECT,
     POINT_CKPT_COMMIT,
@@ -72,12 +80,4 @@ from deepspeed_tpu.serving.faults import (  # noqa: F401
     FaultSpec,
     classify_transient,
     get_fault_injector,
-)
-from deepspeed_tpu.serving.router import (  # noqa: F401
-    DeadlineExceeded,
-    Draining,
-    Overloaded,
-    ReplicaRouter,
-    RouterConfig,
-    plan_placement,
 )

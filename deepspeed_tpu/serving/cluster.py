@@ -104,7 +104,7 @@ def transfer_beats_prefill(tokens: int, bytes_per_token: int,
 
     ``bytes_per_token`` comes from the holder engine's
     ``kv_bytes_per_token()``, measured over its actual cache pytree — with
-    low-bit KV (``RaggedConfig.quant``, inference/kvquant.py) that is the
+    low-bit KV (``RaggedConfig.quant``, ops/kvquant.py) that is the
     quantized payload + scale bytes, so a ~2x smaller wire cost shifts this
     inequality toward transferring exactly as it should (and codec-matched
     import is enforced at the importer, not here)."""

@@ -26,7 +26,7 @@ import threading
 import time
 from dataclasses import dataclass
 
-from deepspeed_tpu.serving.faults import POINT_LOOP, get_fault_injector
+from deepspeed_tpu.utils.faults import POINT_LOOP, get_fault_injector
 from deepspeed_tpu.serving.protocol import (
     FINISH_CANCELLED,
     FINISH_LENGTH,

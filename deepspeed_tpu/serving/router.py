@@ -33,7 +33,7 @@ from deepspeed_tpu.serving.engine_loop import (
     ReplicaStats,
     TokenStream,
 )
-from deepspeed_tpu.serving.faults import POINT_SUBMIT, get_fault_injector
+from deepspeed_tpu.utils.faults import POINT_SUBMIT, get_fault_injector
 from deepspeed_tpu.serving.protocol import CompletionRequest, ProtocolError
 from deepspeed_tpu.telemetry import get_telemetry
 
@@ -69,16 +69,6 @@ class RouterConfig:
     breaker_reset_s: float = 5.0
     # failover re-placements allowed per request after its replica dies
     max_failovers: int = 1
-    # --- tuned-profile loading (docs/AUTOTUNING.md) ---
-    # when set, the router loads the persisted serve profile for
-    # (autotune_fingerprint, current topology, autotune_workload) at
-    # startup and exposes it via ``tuned_overrides()`` (the autoscaler's
-    # template for new replicas) + the ``tuned_profile_loaded`` gauge.
-    # Engine RaggedConfigs are filled via profiles.apply_serving_profile —
-    # fields the operator wrote keep their values (config wins).
-    autotune_profile_dir: str | None = None
-    autotune_fingerprint: str = ""
-    autotune_workload: str = "default"
 
 
 class _ReplicaHealth:
@@ -210,41 +200,10 @@ class ReplicaRouter:
         self._failovers: dict[str, int] = {}
         self._faults = get_fault_injector()
         self._draining = False
-        self.tuned_profile = self._load_tuned_profile()
         # guards the replicas/_health pair against autoscaler mutation;
         # every read path works on a _snapshot() so a concurrent
         # add/remove never shifts indices mid-decision
         self._replica_lock = threading.Lock()
-
-    # ------------------------------------------------------ tuned profile
-    def _load_tuned_profile(self) -> dict | None:
-        """Load the persisted serve autotune profile at startup (None when
-        not configured / no profile matches / the store is unreadable —
-        the router must come up either way)."""
-        if not self.cfg.autotune_profile_dir:
-            return None
-        try:
-            from deepspeed_tpu.autotuning import profiles
-
-            prof = profiles.load_profile(
-                self.cfg.autotune_profile_dir, subsystem="serve",
-                fingerprint=self.cfg.autotune_fingerprint,
-                workload=self.cfg.autotune_workload)
-        except Exception:
-            return None
-        if prof is not None:
-            from deepspeed_tpu.utils.logging import log_dist
-
-            log_dist(
-                f"router: loaded serve autotune profile {prof['key']} "
-                f"(workload {prof['workload']!r}): {prof['overrides']}",
-                ranks=[0])
-        return prof
-
-    def tuned_overrides(self) -> dict:
-        """RaggedConfig overrides from the loaded profile (empty when
-        none); the template for autoscaler-built replicas."""
-        return dict((self.tuned_profile or {}).get("overrides") or {})
 
     # ------------------------------------------- replica pool (autoscaler)
     def _snapshot(self) -> tuple[list[EngineLoop], list[_ReplicaHealth]]:
@@ -554,11 +513,6 @@ class ReplicaRouter:
                 "(replicas whose backend reports memory limits)",
             ).set(sum(known))
         tel.gauge("serving_draining").set(1.0 if self._draining else 0.0)
-        if self.cfg.autotune_profile_dir:
-            tel.gauge(
-                "tuned_profile_loaded",
-                "1 when a persisted autotune profile was applied at startup",
-            ).set(1.0 if self.tuned_profile else 0.0, kind="serving")
         cm = tel.costmeter
         if cm is not None:
             for row in cm.ledger.rows():

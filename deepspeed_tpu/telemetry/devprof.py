@@ -17,11 +17,10 @@ ring (telemetry.tracing), parented under the smallest stepscope phase span
 that contains them, so host phases and device kernels render as one nested
 timeline in ``chrome://tracing`` / Perfetto.
 
-Three triggers exist upstream of this module: the training engine captures a
-window every ``telemetry.stepscope.profile_interval_steps`` steps, the
+Two triggers exist upstream of this module: the training engine captures a
+window every ``telemetry.stepscope.profile_interval_steps`` steps, and the
 serving frontend exposes ``GET /debug/profile?steps=N`` (via
-:func:`capture_serving`), and ``bench.py --mode train-anatomy`` reports
-measured-vs-estimated overlap side by side.
+:func:`capture_serving`). ``tests/unit/test_devprof.py`` drives both.
 
 Design constraints honoured here:
 

@@ -3,8 +3,7 @@
 Three sinks over one data model (:mod:`deepspeed_tpu.telemetry.registry` +
 the span/event records emitted by :class:`deepspeed_tpu.telemetry.core.Telemetry`):
 
-- :class:`JsonlSink` — append-only JSONL event log (machine-readable run record;
-  ``bench.py`` persists one next to its ``BENCH_*.json``).
+- :class:`JsonlSink` — append-only JSONL event log (machine-readable run record).
 - :class:`PrometheusExporter` — text exposition format 0.0.4 on a stdlib
   ``ThreadingHTTPServer`` daemon thread (``GET /metrics``); no third-party
   client library required.

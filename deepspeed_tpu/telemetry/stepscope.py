@@ -16,8 +16,8 @@ consumers at once:
   watermark deltas (``step_phase_hbm_delta_bytes{phase=}`` +
   ``step_hbm_peak_bytes{phase=}`` naming the phase that owns the step's
   memory peak — the when-complement to the memory ledger's who).
-- **bench.py --mode train-anatomy**: :meth:`StepScope.summary` is the JSON
-  payload.
+- **Plain data**: :meth:`StepScope.summary` is the whole anatomy as one
+  JSON-able dict (``tests/unit/test_stepscope.py``).
 
 Measurement model. The engine's fused step is ONE XLA program dispatched
 asynchronously, so the host can only directly time the boundaries it owns:
@@ -73,7 +73,7 @@ ATTRIBUTED_PHASES = ("forward", "backward", "grad_comm", "optimizer")
 # the fused window, so the constant's exact value is second-order
 _OPT_FLOPS_PER_PARAM = 18.0
 
-# bf16 peak FLOPs/s per chip generation (public spec sheets; mirrors bench.py)
+# bf16 peak FLOPs/s per chip generation (public spec sheets)
 _PEAK_TABLE = {
     "v6": 918e12,
     "v5p": 459e12,
@@ -524,7 +524,7 @@ class StepScope:
 
     # ------------------------------------------------------------- summary
     def summary(self) -> dict:
-        """The full anatomy as plain data (bench.py --mode train-anatomy)."""
+        """The full anatomy as plain data."""
         if not self.enabled:
             return {"enabled": False}
         skew = self.refresh_skew()

@@ -1,5 +1,6 @@
 """Where the persistent XLA compilation cache lives: one rule for every
-entry point (``bench.py``, ``chip_smoke.py``, ``RaggedInferenceEngine.warmup``).
+entry point (``benchmark/run.py``, ``chip_smoke.py``,
+``RaggedInferenceEngine.warmup``).
 
 The directory is part of the cache key, so a directory that moves never hits:
 no path is built from a pid, a time or a temp name.

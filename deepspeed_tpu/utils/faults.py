@@ -7,9 +7,9 @@ token readback, block allocation, the engine-loop iteration, the
 router→replica submit edge, and the training checkpoint pipeline
 (collect / flush / commit / latest-update / load). Production code calls
 ``fire(point)`` at each seam; with no faults armed this is a single
-attribute check and the hot paths pay nothing. Tests, ``bench.py --mode
-chaos`` / ``--mode train-chaos``, and CI arm a *schedule* of
-:class:`FaultSpec` entries, each of which fires deterministically by hit
+attribute check and the hot paths pay nothing. Tests
+(``tests/unit/test_fault_tolerance.py``, ``test_ckpt_resilience.py``,
+``test_sentinel.py``) arm a *schedule* of :class:`FaultSpec` entries, each of which fires deterministically by hit
 count (``after`` / ``every`` / ``times``) or per request (``request_id``),
 so a failing run replays exactly.
 
@@ -174,7 +174,7 @@ class FaultInjector:
     # ------------------------------------------------------------- arming
     def configure(self, specs, seed: int = 0) -> "FaultInjector":
         """Arm a schedule: a list of :class:`FaultSpec` or plain dicts
-        (JSON-loadable, as used by ``bench.py --mode chaos``)."""
+        (JSON-loadable)."""
         with self._lock:
             self._specs = [
                 s if isinstance(s, FaultSpec) else FaultSpec(**s)

@@ -166,7 +166,7 @@ def _reset_global_state():
     """Each test builds its own topology; reset the module-level singletons."""
     yield
     from deepspeed_tpu.comm.topology import reset_topology
-    from deepspeed_tpu.serving.faults import get_fault_injector
+    from deepspeed_tpu.utils.faults import get_fault_injector
     from deepspeed_tpu.telemetry import TELEMETRY
     from deepspeed_tpu.utils.comms_logging import COMMS_LOGGER
 

@@ -37,7 +37,7 @@ from deepspeed_tpu.serving import (
     plan_placement,
     transfer_beats_prefill,
 )
-from deepspeed_tpu.serving.faults import POINT_LOOP, get_fault_injector
+from deepspeed_tpu.utils.faults import POINT_LOOP, get_fault_injector
 
 CFG = llama.LlamaConfig(
     vocab_size=97, hidden_size=32, intermediate_size=64,

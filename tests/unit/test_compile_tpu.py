@@ -38,7 +38,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
 # GPT-2 medium, Nemotron-3-Super (16 query heads a KV head, 256-lane rows)
 GEOMETRIES = [(32, 8, 128), (25, 25, 64), (16, 16, 64), (32, 2, 128)]
 SEQ = 1024     # one flash block (ops.attention.flash_blocks), walked in sub-blocks
-TILE = 128     # RaggedConfig.prefill_tile of bench.py and chip_smoke.py
+TILE = 128     # RaggedConfig.prefill_tile of chip_smoke.py
 BLOCK = 32     # their KV block_size
 MAX_BLOCKS = 8
 
@@ -762,7 +762,7 @@ def test_step_program_holds_no_layer_slice_of_the_pool(v5e, monkeypatch,
     prefill kernel and, at every table width, the decode kernel; a
     ``QuantizedKV`` pool takes the XLA gather, whose float32 context is
     rows x table wide: here four decode rows over a table of two blocks."""
-    from deepspeed_tpu.inference import kvquant
+    from deepspeed_tpu.ops import kvquant
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
     mod, cfg, blocks, block, table = _step_family(family)

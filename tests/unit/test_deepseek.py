@@ -407,7 +407,7 @@ def test_mixtral_routing_is_bit_equal_after_the_move(dtype):
 
 # ------------------------------------------------------- what is not there
 def test_unimplemented_variants_raise():
-    from deepspeed_tpu.inference import kvquant
+    from deepspeed_tpu.ops import kvquant
 
     with pytest.raises(NotImplementedError, match="quantized latent pool"):
         deepseek.init_paged_cache(CFG, 8, 4, jnp.float32,

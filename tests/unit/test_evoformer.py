@@ -1,5 +1,5 @@
-"""Evoformer attention (reference ``DS4Sci_EvoformerAttention`` numerics,
-``tests/benchmarks/DS4Sci_EvoformerAttention_bench.py`` shapes)."""
+"""Evoformer attention (reference ``DS4Sci_EvoformerAttention`` numerics, at
+the shapes of the reference's own benchmark of it)."""
 
 import jax
 import jax.numpy as jnp

@@ -38,7 +38,7 @@ from deepspeed_tpu.serving import (
     classify_transient,
     get_fault_injector,
 )
-from deepspeed_tpu.serving.faults import POINT_ALLOC, POINT_READBACK
+from deepspeed_tpu.utils.faults import POINT_ALLOC, POINT_READBACK
 from deepspeed_tpu.serving.router import DeadlineExceeded
 
 CFG = llama.LlamaConfig(

@@ -33,6 +33,7 @@ from deepspeed_tpu.ops.pallas.kda import (
     kda_chunk_xla,
     kda_decode,
     kda_decode_xla,
+    kda_tiles,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -263,7 +264,7 @@ def _one_sequence(form, q, k, v, g, beta, s0, sub):
     n_i, r = q.shape[:2]
     cont = jnp.arange(n_i) > 0
     if form == "xla":
-        y, s = kimi_linear.kda_tiles(
+        y, s = kda_tiles(
             q, k, v, g, beta, jnp.broadcast_to(s0, (n_i,) + s0.shape), cont, sub)
         return y, s[-1]
     leaf = jnp.full((4,) + s0.shape, jnp.nan, jnp.float32).at[2].set(s0)
@@ -586,7 +587,7 @@ def test_the_watchdogs_ladder_ends_at_the_host_staged_step(params, family):
     host-staged step) and no further: rung 2 turns prefill tiles off, and the
     recurrence runs a prompt's rows as tiles. Each failed step starts its
     sequences again from an empty state, and the tokens are the clean run's."""
-    from deepspeed_tpu.serving.faults import POINT_DISPATCH, get_fault_injector
+    from deepspeed_tpu.utils.faults import POINT_DISPATCH, get_fault_injector
 
     mod, cfg, own = ((kimi_linear, CFG, params) if family == "kimi_linear"
                      else (nemotron_h, nemotron_h.NemotronHConfig.tiny(), None))

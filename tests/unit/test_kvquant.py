@@ -1,4 +1,4 @@
-"""Low-bit KV serving (inference/kvquant.py).
+"""Low-bit KV serving (ops/kvquant.py).
 
 The contract under test: with ``RaggedConfig.quant`` set, every KV block —
 HBM pool, prefix-cache retained set, host/disk tiers, handoff wire — is
@@ -22,8 +22,8 @@ from step_modes import MODES  # tests/unit is rootdir-inserted by pytest
 from deepspeed_tpu.comm.comm import init_distributed
 from deepspeed_tpu.comm.topology import reset_topology
 from deepspeed_tpu.config.config import MeshConfig
-from deepspeed_tpu.inference import kvquant
-from deepspeed_tpu.inference.kvquant import (
+from deepspeed_tpu.ops import kvquant
+from deepspeed_tpu.ops.kvquant import (
     DRIFT_BUDGET,
     QuantizedKV,
     drift_verdict,

@@ -20,7 +20,7 @@ from deepspeed_tpu.inference.ragged import (
     RaggedInferenceEngine,
 )
 from deepspeed_tpu.models import llama
-from deepspeed_tpu.serving.faults import (
+from deepspeed_tpu.utils.faults import (
     POINT_ALLOC,
     POINT_DISPATCH,
     get_fault_injector,

@@ -6,8 +6,8 @@ On the CPU test mesh the pinned-host memory kind is rejected by the SPMD
 partitioner (see ``runtime/offload.supports_memory_kinds``), so storage
 falls back to device while the full streaming code path — the
 ``ShardCtx.param_stream`` per-slice hook, the whole-leaf stream cast, the
-group-walk param streaming — stays live; the memory claim itself is asserted
-on real TPU by ``bench.py --smoke``."""
+group-walk param streaming — stays live; the memory claim itself needs the
+chip and no chip run asserts it (ROADMAP D4)."""
 
 import numpy as np
 import pytest

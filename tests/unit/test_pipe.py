@@ -20,7 +20,7 @@ from deepspeed_tpu.models import llama
 from deepspeed_tpu.runtime.engine import Engine
 from deepspeed_tpu.runtime.pipe import partition, schedule
 from deepspeed_tpu.runtime.pipe.engine import PipeEngine
-from deepspeed_tpu.serving import faults
+from deepspeed_tpu.utils import faults
 
 VOCAB = 97
 

@@ -507,7 +507,7 @@ class TestStepPaths:
     def test_ladder_second_rung_turns_tiles_off(self, engine_of):
         """Failures that go on after rung 1 (host-staged) take rung 2: the
         host-staged step with prefill tiles off, token-identical."""
-        from deepspeed_tpu.serving.faults import (POINT_DISPATCH,
+        from deepspeed_tpu.utils.faults import (POINT_DISPATCH,
                                                   get_fault_injector)
 
         over = dict(prefill_tile=8, dispatch_retries=2, retry_backoff_s=0.0,

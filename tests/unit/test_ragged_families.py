@@ -133,7 +133,7 @@ def test_a_failed_step_is_invisible_in_the_tokens(ragged, name):
     rewrites the same rows of every block leaf and picks the same tokens
     (greedy and sampled). ``nemotron_h`` and ``kimi_linear``, whose slot
     state cannot be run twice, have ``[recovered_and_recomputed]``."""
-    from deepspeed_tpu.serving.faults import (POINT_DISPATCH, POINT_READBACK,
+    from deepspeed_tpu.utils.faults import (POINT_DISPATCH, POINT_READBACK,
                                               get_fault_injector)
 
     prompts = _prompts(5, seed=11)
@@ -173,7 +173,7 @@ def test_mixtral_decode_routing_is_per_token(ragged):
 # the step) through its own block table and owns no slice of it
 # (``models/paged.py``). The reference below knows nothing of pools, tables
 # or tiles: the family's dense ``decode_forward``, one sequence at a time.
-from deepspeed_tpu.inference import kvquant  # noqa: E402
+from deepspeed_tpu.ops import kvquant  # noqa: E402
 
 NB, BS, TILE, PAD_ROW = 12, 4, 4, 3
 TABLES = np.zeros((PAD_ROW + 1, 3), np.int32)   # the last row: all scratch

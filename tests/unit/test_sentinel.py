@@ -17,7 +17,7 @@ from deepspeed_tpu.models import llama
 from deepspeed_tpu.runtime import sentinel
 from deepspeed_tpu.runtime.dataloader import (CheckpointableLoader,
                                               RepeatingLoader)
-from deepspeed_tpu.serving.faults import classify_transient, get_fault_injector
+from deepspeed_tpu.utils.faults import classify_transient, get_fault_injector
 
 VOCAB = 97
 
