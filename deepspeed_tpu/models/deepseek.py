@@ -453,6 +453,7 @@ def _pool_attention(cfg: DeepseekConfig, h, lp, pool, positions, slots,
     ``_ragged_layer``'s."""
     from deepspeed_tpu.models.paged import (
         latent_pool_attention,
+        latent_queries,
         write_rows_paged,
     )
 
@@ -465,17 +466,17 @@ def _pool_attention(cfg: DeepseekConfig, h, lp, pool, positions, slots,
         jnp.concatenate([c, k_rope, jnp.zeros((t_tokens, pad), c.dtype)], -1),
         slots, positions, block_tables, prefill_tiles)
     wk, wv = _wkv_b(cfg, lp)
-    q = jnp.concatenate(
-        [jnp.einsum("thn,lhn->thl", q_nope, wk), q_rope,
-         jnp.zeros((t_tokens, cfg.num_heads, pad), q_rope.dtype)], axis=-1)
+    # both absorbed products are batched over heads, so their rows lie
+    # head-major: the tile rows go to the kernel and come back that way
+    q = latent_queries(q_nope, wk, q_rope, cfg.row_lanes, prefill_tiles)
     if sparse is None:
         pool = {"kv": kv}
         o_lat = latent_pool_attention(
-            q, kv, slots, positions, block_tables, cfg.kv_lora_rank,
+            *q, kv, slots, positions, block_tables, cfg.kv_lora_rank,
             cfg.softmax_scale, prefill_tiles)
     else:
         o_lat, pool = sparse(h, cq, lp, q, {**pool, "kv": kv})
-    o = jnp.einsum("thl,lhv->thv", o_lat.astype(h.dtype), wv)
+    o = jnp.einsum("htl,lhv->thv", o_lat.astype(h.dtype), wv)
     return o.reshape(t_tokens, -1) @ lp["wo"], pool
 
 
@@ -484,8 +485,9 @@ def _ragged_layer(cfg: DeepseekConfig, x, lp, pool, positions, slots,
     """One layer over a flat ragged token batch [T, D]: ``_pool_attention``,
     then the FFN half. ``sparse(h, cq, lp, q, pool) -> (o_lat, pool)`` takes
     the attention's place for a family that reads some of the cached rows
-    only and keeps further block leaves (``deepseek_v32``); ``pool`` has
-    this step's latent rows in it."""
+    only and keeps further block leaves (``deepseek_v32``): ``q`` the pair
+    ``paged.latent_queries`` gives, ``o_lat`` [H, T, lat]; ``pool`` has this
+    step's latent rows in it."""
     from deepspeed_tpu.ops.quantizer import dequantize_layer
 
     lp = dequantize_layer(lp, x.dtype)

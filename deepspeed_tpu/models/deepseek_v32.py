@@ -267,25 +267,31 @@ def decode_attention_xla(q, rows, n_kept, lat: int, scale: float):
     return jnp.einsum("thk,tkl->thl", p, rows[..., :lat]).astype(q.dtype)
 
 
-def prefill_attention_xla(q, pool, bias, slots, block_tables, lat: int,
+def prefill_attention_xla(q_lat, q_rope, pool, bias, slots, block_tables,
                           scale: float):
     """``dsa_attention.dsa_prefill_attention`` as plain XLA, a row at a time
-    over a gather of its whole table (``slots`` [T]: every row's own)."""
-    t = q.shape[0]
-    ctx = pool[block_tables[slots]].reshape(t, -1, pool.shape[-1]
+    over a gather of its whole table (``slots`` [T]: every row's own), on
+    the kernel's head-major rows in their two parts: ``q_lat`` [H, T, lat],
+    ``q_rope`` [H, T, W - lat] -> [H, T, lat]."""
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    ctx = pool[block_tables[slots]].reshape(q.shape[1], -1, pool.shape[-1]
                                             ).astype(jnp.float32)
-    s = jnp.einsum("thw,tcw->thc", q.astype(jnp.float32) * scale, ctx)
-    p = jax.nn.softmax(s + bias[:, None, :], axis=-1)
-    return jnp.einsum("thc,tcl->thl", p, ctx[..., :lat]).astype(q.dtype)
+    s = jnp.einsum("htw,tcw->htc", q * scale, ctx)
+    p = jax.nn.softmax(s + bias[None], axis=-1)
+    return jnp.einsum("htc,tcl->htl", p, ctx[..., :q_lat.shape[-1]]
+                      ).astype(q_lat.dtype)
 
 
-def sparse_pool_attention(cfg: DeepseekV32Config, q, q_idx, w_idx, pool_kv,
-                          pool_idx, slots, positions, block_tables,
+def sparse_pool_attention(cfg: DeepseekV32Config, q_dec, q_tiles, q_idx, w_idx,
+                          pool_kv, pool_idx, slots, positions, block_tables,
                           prefill_tiles=None, impl: str = "auto"):
     """Absorbed MLA attention of a flat ragged batch over the rows its
-    indexer keeps: ``q`` [T, H, W] (``deepseek._ragged_layer``'s), ``q_idx``
-    [T, HI, DI] and ``w_idx`` [T, HI] the indexer's, the two pools with this
-    step's rows in them -> [T, H, lat]."""
+    indexer keeps: ``q_dec`` [n_dec, H, W] and ``q_tiles`` (the pair [H, T -
+    n_dec, lat], [H, T - n_dec, W - lat]) as ``paged.latent_queries`` gives
+    them, ``q_idx`` [T, HI, DI] and
+    ``w_idx`` [T, HI] the indexer's, the two pools with this step's rows in
+    them -> [H, T, lat] (``paged.head_major``)."""
+    from deepspeed_tpu.models.paged import head_major
     from deepspeed_tpu.ops.attention import _on_tpu
     from deepspeed_tpu.ops.pallas import dsa_attention as dsa
     from deepspeed_tpu.ops.pallas.mla_attention import mla_decode_attention
@@ -300,35 +306,35 @@ def sparse_pool_attention(cfg: DeepseekV32Config, q, q_idx, w_idx, pool_kv,
         scores = index_scores_xla(q_idx, w_idx, pool_idx, slots, positions,
                                   block_tables)
     mask = select_mask(scores, positions, k)
-    n_dec = q.shape[0] if prefill_tiles is None else prefill_tiles[0]
-    parts = []
+    n_dec = 0 if q_dec is None else q_dec.shape[0]
+    o_dec = o_tiles = None
     if n_dec and decode_form(mask.shape[1]) == "walk":
         if impl == "pallas":
-            parts.append(mla_decode_attention(
-                q[:n_dec], pool_kv, slots[:n_dec], positions[:n_dec],
-                block_tables, lat, scale, keep=mask[:n_dec]))
+            o_dec = mla_decode_attention(
+                q_dec, pool_kv, slots[:n_dec], positions[:n_dec],
+                block_tables, lat, scale, keep=mask[:n_dec])
         else:
-            parts.append(prefill_attention_xla(
-                q[:n_dec], pool_kv, jnp.where(mask[:n_dec], 0.0, _NEG_INF),
-                slots[:n_dec], block_tables, lat, scale))
+            q = jnp.swapaxes(q_dec, 0, 1)
+            o_dec = jnp.swapaxes(prefill_attention_xla(
+                q[..., :lat], q[..., lat:], pool_kv,
+                jnp.where(mask[:n_dec], 0.0, _NEG_INF), slots[:n_dec],
+                block_tables, scale), 0, 1)
     elif n_dec:
         rows, n_kept = _gather_kept(mask[:n_dec], min(k, mask.shape[1]),
                                     pool_kv, slots[:n_dec], block_tables)
         attend = (dsa.dsa_decode_attention if impl == "pallas"
                   else decode_attention_xla)
-        parts.append(attend(q[:n_dec], rows, n_kept, lat, scale))
-    if q.shape[0] > n_dec:
+        o_dec = attend(q_dec, rows, n_kept, lat, scale)
+    if q_tiles is not None:
         bias = jnp.where(mask[n_dec:], 0.0, _NEG_INF)
         if impl == "pallas":
             _, ts, tp, tv, ct = prefill_tiles
-            parts.append(dsa.dsa_prefill_attention(
-                q[n_dec:], pool_kv, bias, ts, tp, tv, block_tables, ct, lat,
-                scale))
+            o_tiles = dsa.dsa_prefill_attention(
+                *q_tiles, pool_kv, bias, ts, tp, tv, block_tables, ct, scale)
         else:
-            parts.append(prefill_attention_xla(
-                q[n_dec:], pool_kv, bias, slots[n_dec:], block_tables, lat,
-                scale))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+            o_tiles = prefill_attention_xla(
+                *q_tiles, pool_kv, bias, slots[n_dec:], block_tables, scale)
+    return head_major(o_dec, o_tiles)
 
 
 # -------------------------------------------------------------------- forward
@@ -379,7 +385,7 @@ def ragged_forward(cfg: DeepseekV32Config, params, tokens, slots, positions,
             idx = write_rows_paged(pool["idx"], k_idx, slots, positions,
                                    layer_tables, prefill_tiles)
             o_lat = sparse_pool_attention(
-                cfg, q, q_idx, w_idx, pool["kv"], idx, slots, positions,
+                cfg, *q, q_idx, w_idx, pool["kv"], idx, slots, positions,
                 layer_tables, prefill_tiles)
             return o_lat, {"kv": pool["kv"], "idx": idx}
 

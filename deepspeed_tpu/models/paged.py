@@ -143,6 +143,19 @@ v1-style engines.
   PERF.md section 6, PR 50), where pinned it copies the rows.
   ``tests/unit/test_compile_tpu.py::test_step_program_relays_out_no_projection_weight``
   holds every family's compiled step to it, and is the check for the next.
+  The rule's second clause is for the ROWS of a product batched over heads
+  (MLA's absorbed pair, ``thn,lhn`` and ``htl,lhv``): the chip writes and
+  reads such a product head-major, ``[H, T, lanes]``, so the rows stay
+  head-major into and out of a kernel that shares its keys across heads
+  (``latent_queries`` / ``head_major``: a step's TILE rows, most of them,
+  reach ``mla_prefill`` / ``dsa_attn_prefill`` as the product wrote them,
+  the product's result itself an operand, and come back as the value
+  product reads them; the few decode rows turn, their kernels walk a row at
+  a time). Token-major, the tile rows cost three re-layouts and a
+  concatenation a layer and step (~450 MB of traffic at the sparse cell's
+  128 heads, PERF.md section 6, PR 56);
+  ``test_latent_tile_rows_cross_memory_once_each_way`` holds the compiled
+  steps to it.
 """
 
 from __future__ import annotations
@@ -687,22 +700,70 @@ def ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
             q, kc, vc, ts, tp, tv, block_tables, ct, window=window, **more))
 
 
-def latent_pool_attention(q, pool, slots, positions, block_tables, lat: int,
-                          scale: float, prefill_tiles=None):
+def latent_queries(q_nope, wk, q_rope, width: int, prefill_tiles=None):
+    """A flat ragged step's absorbed queries as the latent kernels take them
+    (module doc, *Rows to heads*): ``q_nope`` [T, H, nope] times the key half
+    of ``kv_b_proj``, ``wk`` [lat, H, nope], beside the roped ``q_rope`` [T,
+    H, rope] -> ``(decode rows, tile rows)``, None for a part the step has no
+    rows of. The decode rows are ``[n_dec, H, W]`` token-major, rows ``[q_lat,
+    q_rope, zeros]``: a decode kernel walks a row at a time, and its few rows
+    are a product of their own, turned. The tile rows, most of a step's, are
+    HEAD-MAJOR and in their two parts, ``(q_lat [H, T - n_dec, lat], q_rope
+    [H, T - n_dec, W - lat])``: the product is batched over heads and writes
+    its rows that way, the prefill kernel reads them where it wrote them
+    (it joins the parts in VMEM), and the small ``q_rope`` is what turns."""
+    t, h, _ = q_nope.shape
+    n_dec = t if prefill_tiles is None else prefill_tiles[0]
+    pad = width - wk.shape[0] - q_rope.shape[-1]
+    q_dec = q_tiles = None
+    # two products: a slice of ONE product's result is an array of its own
+    # to the compiler, the tile rows copied once more
+    if n_dec:
+        q_dec = jnp.concatenate(
+            [jnp.einsum("thn,lhn->thl", q_nope[:n_dec], wk), q_rope[:n_dec],
+             jnp.zeros((n_dec, h, pad), q_rope.dtype)], axis=-1)
+    if t > n_dec:
+        q_tiles = (jnp.einsum("thn,lhn->htl", q_nope[n_dec:], wk),
+                   jnp.concatenate(
+                       [jnp.swapaxes(q_rope[n_dec:], 0, 1),
+                        jnp.zeros((h, t - n_dec, pad), q_rope.dtype)], -1))
+    return q_dec, q_tiles
+
+
+def head_major(o_dec, o_tiles):
+    """``latent_queries``' two parts after attention, ``[n_dec, H, lat]`` and
+    ``[H, T - n_dec, lat]``, as ONE ``[H, T, lat]`` for the product batched
+    over heads that follows: the tile rows where the kernel wrote them, the
+    decode rows turned beside them."""
+    parts = [] if o_dec is None else [jnp.swapaxes(o_dec, 0, 1)]
+    if o_tiles is not None:
+        parts.append(o_tiles)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def latent_pool_attention(q_dec, q_tiles, pool, slots, positions,
+                          block_tables, lat: int, scale: float,
+                          prefill_tiles=None):
     """Absorbed MLA attention over a latent pool (``[blocks, BS, W]``, a
     row ``[c, k_rope, zeros]``, values = its first ``lat`` lanes) for a flat
-    ragged token batch: ``q`` [T, H, W] -> [T, H, lat]."""
+    ragged token batch, its queries as ``latent_queries`` gives them ->
+    [H, T, lat] (``head_major``)."""
     from deepspeed_tpu.ops.attention import (
         latent_paged_attention,
         latent_prefill_attention,
     )
 
-    return _decode_then_tiles(
-        q, slots, positions, prefill_tiles,
-        lambda q, sl, po: latent_paged_attention(
-            q, pool, sl, po, block_tables, lat, scale),
-        lambda q, ts, tp, tv, ct: latent_prefill_attention(
-            q, pool, ts, tp, tv, block_tables, ct, lat, scale))
+    o_dec = o_tiles = None
+    if q_dec is not None:
+        n_dec = q_dec.shape[0]
+        o_dec = latent_paged_attention(
+            q_dec, pool, slots[:n_dec], positions[:n_dec], block_tables, lat,
+            scale)
+    if q_tiles is not None:
+        _, ts, tp, tv, ct = prefill_tiles
+        o_tiles = latent_prefill_attention(
+            *q_tiles, pool, ts, tp, tv, block_tables, ct, scale)
+    return head_major(o_dec, o_tiles)
 
 
 def append_kv_and_attend(q, kk, vv, k_cache, v_cache, start_pos, max_len):

@@ -336,24 +336,28 @@ def latent_paged_attention(q, pool, slots, positions, block_tables, lat: int,
     return jnp.einsum("thc,tcl->thl", p, ctx[..., :lat]).astype(q.dtype)
 
 
-def latent_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
-                             block_tables, tile: int, lat: int, scale: float,
+def latent_prefill_attention(q_lat, q_rope, pool, tile_slot, tile_pos0,
+                             tile_valid, block_tables, tile: int, scale: float,
                              impl: str = "auto"):
     """``latent_paged_attention`` for tile-aligned prefill rows (the
-    scheduler contract of ``ragged_prefill_attention``): on the chip the
-    tiled kernel, a block fetched once a tile; the XLA form expands the tile
+    scheduler contract of ``ragged_prefill_attention``), HEAD-MAJOR and in
+    two parts as the absorbed products around it have them: ``q_lat`` [H, T,
+    lat], ``q_rope`` [H, T, W - lat] -> [H, T, lat]. On the chip the tiled
+    kernel, a block fetched once a tile; the XLA form expands the tile
     metadata to per-row (slot, position)."""
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
     if impl == "pallas":
         from deepspeed_tpu.ops.pallas.mla_attention import mla_prefill_attention
 
-        return mla_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
-                                     block_tables, tile, lat, scale)
-    slots, positions = _tile_rows(q.shape[0], tile_slot, tile_pos0, tile_valid,
-                                  tile, block_tables.shape[0] - 1)
-    return latent_paged_attention(q, pool, slots, positions, block_tables,
-                                  lat, scale, impl=impl)
+        return mla_prefill_attention(q_lat, q_rope, pool, tile_slot, tile_pos0,
+                                     tile_valid, block_tables, tile, scale)
+    slots, positions = _tile_rows(q_lat.shape[1], tile_slot, tile_pos0,
+                                  tile_valid, tile, block_tables.shape[0] - 1)
+    q = jnp.swapaxes(jnp.concatenate([q_lat, q_rope], axis=-1), 0, 1)
+    return jnp.swapaxes(latent_paged_attention(
+        q, pool, slots, positions, block_tables, q_lat.shape[-1], scale,
+        impl=impl), 0, 1)
 
 
 def rope_frequencies(half: int, theta: float, yarn=None):

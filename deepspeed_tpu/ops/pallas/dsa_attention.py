@@ -23,10 +23,13 @@ broadcast of one column of ``w`` (no relayout of either operand). Entries past
 a query's position are whatever the block held: the selection masks by
 position.
 
-``dsa_prefill_attention`` is ``mla_prefill_attention`` with the selection as
-an additive bias ``[T, S]`` (0 on a kept pair, -1e30 elsewhere: causal mask,
-padding and selection in one): a tile's queries keep different rows, so the
-tile still walks every block up to its last position and a block is skipped
+``dsa_prefill_attention`` is ``mla_prefill_attention`` (head-major rows in
+two parts in, head-major out, a tile's rows ordered (head, query)) with the
+selection as an additive bias ``[T, S]`` (0 on a kept pair, -1e30 elsewhere:
+causal mask, padding and selection in one; a query's row of it meets the
+scores seen as ``[H, CT, keys]``, one broadcast over the leading axis): a
+tile's queries keep different rows, so the tile still walks every block up to
+its last position and a block is skipped
 only past it. With the context at a few times ``index_topk`` that costs under
 twice the kept pairs' FLOPs and reads a block once a tile; a gather a query
 would read ``CT`` times the rows.
@@ -49,6 +52,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -57,6 +61,7 @@ from deepspeed_tpu.ops.pallas.mla_attention import (
     _NEG_INF,
     _scores,
     mla_prefill_kernel_tile,
+    tile_rows,
 )
 from deepspeed_tpu.ops.pallas.paged_attention import split_tiles
 
@@ -218,17 +223,20 @@ PREFILL_BLOCKS_A_STEP = 4
 _PREFILL_VMEM_BYTES = 48 * 2 ** 20
 
 
-def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, *refs, bs: int,
-                    heads: int, lat: int, group: int):
-    kv_refs, (bias_ref, o_ref, acc, m_sc, l_sc) = refs[:group], refs[group:]
+def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_lat_ref, q_rope_ref,
+                    *refs, bs: int, scale: float, group: int):
+    kv_refs, (bias_ref, o_ref, q_sc, acc, m_sc, l_sc) = (refs[:group],
+                                                         refs[group:])
     c = pl.program_id(0)   # query tile
     j = pl.program_id(1)   # ordinal of a group of kv blocks
     nj = pl.num_programs(1)
+    heads, ct, lat = q_lat_ref.shape
     valid = tv_ref[c]
     max_pos = tp_ref[c] + valid - 1
 
     @pl.when(j == 0)
     def _init():
+        tile_rows(q_lat_ref, q_rope_ref, q_sc, scale)
         acc[:] = jnp.zeros_like(acc)
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
@@ -237,13 +245,10 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, *refs, bs: int,
     def _compute():
         blk = kv_refs[0][0] if group == 1 else jnp.concatenate(
             [r[0] for r in kv_refs], axis=0)              # [G*BS, W]
-        q = q_ref[...]                                    # [CT*H, W], scaled
-        bias = bias_ref[...]                              # [CT, G*BS]
-        s = _scores(q.astype(blk.dtype), blk)
-        # a query's bias row over its H heads: sublane broadcasts of one row
-        s = jnp.concatenate(
-            [s[t * heads:(t + 1) * heads] + bias[t:t + 1]
-             for t in range(bias.shape[0])], axis=0)
+        s = _scores(q_sc[...], blk)                       # [H*CT, G*BS]
+        # rows lie (head, query): a query's bias row meets its H heads as
+        # ONE broadcast over the leading axis of [H, CT, keys]
+        s = (s.reshape(heads, ct, -1) + bias_ref[...]).reshape(s.shape)
         m_prev = m_sc[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a row that has kept nothing yet sums garbage of size <= 1 a key
@@ -259,22 +264,25 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, *refs, bs: int,
 
     @pl.when(j == nj - 1)
     def _finish():
-        o_ref[...] = (acc[:] / jnp.maximum(l_sc[:, :1], 1e-30)
-                      ).astype(o_ref.dtype)
+        o = acc[:] / jnp.maximum(l_sc[:, :1], 1e-30)
+        o_ref[...] = o.reshape(heads, ct, lat).astype(o_ref.dtype)
 
 
-def dsa_prefill_attention(q, pool, bias, tile_slot, tile_pos0, tile_valid,
-                          block_tables, tile: int, lat: int, scale: float,
+def dsa_prefill_attention(q_lat, q_rope, pool, bias, tile_slot, tile_pos0,
+                          tile_valid, block_tables, tile: int, scale: float,
                           interpret: bool | None = None,
                           group: int = PREFILL_BLOCKS_A_STEP):
-    """``mla_attention.mla_prefill_attention`` with the pairs to keep given
-    as ``bias`` [NT*CT, S] float32 (0 kept, -1e30 not; ``S`` the table's
-    width in tokens): the same scheduler contract and sub-tiling, and a walk
-    of ``group`` blocks a grid step (the pool is ``group`` operands of the
-    call, one block of each a step; past a tile's last position a step
-    repeats its last block under a bias of -1e30)."""
-    t_tokens, h, width = q.shape
-    _, bs, _ = pool.shape
+    """``mla_attention.mla_prefill_attention`` (``q_lat`` [H, NT*CT, lat] and
+    ``q_rope`` [H, NT*CT, W - lat] head-major -> [H, NT*CT, lat]) with the
+    pairs to keep given as ``bias`` [NT*CT, S] float32 (0 kept, -1e30 not;
+    ``S`` the table's width in tokens): the same scheduler contract and
+    sub-tiling, and a walk of ``group`` blocks a grid step (the pool is
+    ``group`` operands of the call, one block of each a step; past a tile's
+    last position a step repeats its last block under a bias of -1e30).
+    ``scale`` meets the queries where the kernel lays a tile's rows out, once
+    a tile, rounded to their dtype."""
+    h, t_tokens, lat = q_lat.shape
+    _, bs, width = pool.shape
     mb = block_tables.shape[1]
     while mb % group:
         group //= 2
@@ -297,35 +305,37 @@ def dsa_prefill_attention(q, pool, bias, tile_slot, tile_pos0, tile_valid,
         return (c, jnp.minimum(j, _last(c, tp, tv) // group))
 
     def _q_map(c, j, ts, tp, tv, bt):
-        return (c, 0)
+        return (0, c, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_tiles, mb // group),
         in_specs=[
-            pl.BlockSpec((ct * h, width), _q_map),
+            pl.BlockSpec((h, ct, lat), _q_map),
+            pl.BlockSpec((h, ct, width - lat), _q_map),
             *[pl.BlockSpec((1, bs, width), _kv_map(i)) for i in range(group)],
             pl.BlockSpec((ct, group * bs), _bias_map),
         ],
-        out_specs=pl.BlockSpec((ct * h, lat), _q_map),
+        out_specs=pl.BlockSpec((h, ct, lat), _q_map),
         scratch_shapes=[
-            pltpu.VMEM((ct * h, lat), jnp.float32),
-            pltpu.VMEM((ct * h, 128), jnp.float32),
-            pltpu.VMEM((ct * h, 128), jnp.float32),
+            pltpu.VMEM((h * ct, width), pool.dtype),
+            pltpu.VMEM((h * ct, lat), jnp.float32),
+            pltpu.VMEM((h * ct, 128), jnp.float32),
+            pltpu.VMEM((h * ct, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_prefill_kernel, bs=bs, heads=h, lat=lat,
-                               group=group)
-    out = pl.pallas_call(
+    kernel = functools.partial(
+        _prefill_kernel, bs=bs, group=group,
+        # as the product ``q * scale`` rounds it outside a kernel
+        scale=float(np.asarray(scale, q_lat.dtype)))
+    return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((t_tokens * h, lat), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((h, t_tokens, lat), q_lat.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_PREFILL_VMEM_BYTES),
         interpret=interpret_mode(interpret),
         name="dsa_attn_prefill",
     )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
-      tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
-      (q * scale).astype(q.dtype).reshape(t_tokens * h, width),
-      *[pool] * group, bias.astype(jnp.float32))
-    return out.reshape(t_tokens, h, lat)
+      tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32), q_lat,
+      q_rope, *[pool] * group, bias.astype(jnp.float32))

@@ -35,6 +35,18 @@ latency; by hand it is two. A grid of rows x table width would spend a grid
 step on every table entry: at 128 rows x 32 entries 4,096 steps a layer for
 ~1,100 blocks of real context.
 
+``mla_prefill`` takes a step's tile-aligned prefill rows HEAD-MAJOR and in
+their two parts, ``q_lat`` ``[H, T, lat]`` (the absorbed product's result
+itself) and ``q_rope`` ``[H, T, W - lat]``, and gives ``[H, T, lat]``: the
+absorbed products on either side of it are batched over heads and lie that
+way in memory, so the rows cross HBM once each way (``models/paged.py``,
+*Rows to heads*). A grid tile's blocks are ``(H, CT, lat)`` and ``(H, CT, W -
+lat)``; inside, a tile's rows are ordered (head, query), row ``h * CT + i``
+head ``h`` of query ``i``: the rows ``[q_lat, q_rope, zeros]`` of ONE ``[H*CT,
+W] x [W, BS]`` product a block, joined and laid out in VMEM once a tile
+(``tile_rows``: at 128 heads ``CT`` is 8, half a bfloat16 tile's rows), a
+query's position ``row mod CT``.
+
 A family that selects the rows a query attends over (``deepseek_v32``) hands
 the same walk its decode rows' selection, ``keep`` [T, S]: one row of it is
 resident a grid step, a step's lanes one sublane of it, and a dropped
@@ -246,18 +258,38 @@ def _mla_decode(q, pool, slots, positions, block_tables, keep=None, *,
 
 
 # --------------------------------------------------------------- tiled prefill
-def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, kv_ref, o_ref,
-                    acc, m_sc, l_sc, *, bs: int, heads: int, lat: int,
+def tile_rows(q_lat_ref, q_rope_ref, q_sc, scale=None):
+    """A tile's two query blocks, ``[H, CT, lat]`` and ``[H, CT, W - lat]``,
+    into ``q_sc`` [H * CT, W]: the rows of one product, row ``h * CT + i``
+    head ``h`` of the tile's query ``i``, ``[q_lat, q_rope, zeros]`` (times
+    ``scale``, rounded once as a product outside the kernel would be).
+    Through float32, whose eight-row tiles make the merge of the two leading
+    axes no move at any ``CT`` the tile chooser gives; packed to the
+    scratch's dtype after it. Once a tile."""
+    lat = q_lat_ref.shape[-1]
+    for ref, lanes in ((q_lat_ref, slice(0, lat)),
+                       (q_rope_ref, slice(lat, None))):
+        h, ct, n = ref.shape
+        q = ref[...].astype(jnp.float32)
+        if scale is not None:
+            q = q * scale
+        q_sc[:, lanes] = q.reshape(h * ct, n).astype(q_sc.dtype)
+
+
+def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_lat_ref, q_rope_ref,
+                    kv_ref, o_ref, q_sc, acc, m_sc, l_sc, *, bs: int,
                     scale: float):
     c = pl.program_id(0)   # query tile
     j = pl.program_id(1)   # kv block ordinal
     nj = pl.num_programs(1)
+    heads, ct, lat = q_lat_ref.shape
     pos0 = tp_ref[c]
     valid = tv_ref[c]
     max_pos = pos0 + valid - 1
 
     @pl.when(j == 0)
     def _init():
+        tile_rows(q_lat_ref, q_rope_ref, q_sc)
         acc[:] = jnp.zeros_like(acc)
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
@@ -265,10 +297,12 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, kv_ref, o_ref,
     @pl.when(jnp.logical_and(valid > 0, j * bs <= max_pos))
     def _compute():
         blk = kv_ref[0]                                   # [BS, W]
-        q = q_ref[...]                                    # [CT*H, W]
+        q = q_sc[...]                                     # [H*CT, W]
         rows = q.shape[0]
-        s = _scores(q.astype(blk.dtype), blk) * scale
-        qi = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+        s = _scores(q, blk) * scale
+        # rows lie (head, query): a row's query is its index within a head
+        qi = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), ct)
         kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         mask = jnp.logical_and(kpos <= pos0 + qi, qi < valid)
         s = jnp.where(mask, s, _NEG_INF)
@@ -286,17 +320,19 @@ def _prefill_kernel(ts_ref, tp_ref, tv_ref, bt_ref, q_ref, kv_ref, o_ref,
 
     @pl.when(j == nj - 1)
     def _finish():
-        o_ref[...] = (acc[:] / jnp.maximum(l_sc[:, :1], 1e-30)
-                      ).astype(o_ref.dtype)
+        o = acc[:] / jnp.maximum(l_sc[:, :1], 1e-30)
+        o_ref[...] = o.reshape(heads, ct, lat).astype(o_ref.dtype)
 
 
 # What one query row of one head costs the prefill kernel in scoped VMEM
 # (16 MiB on v5e, ``paged_attention._VMEM_SCOPED_BYTES``), in bytes: the
 # float32 accumulator (lat lanes) and m, l (128 lanes each), the
-# double-buffered q (W lanes) and o blocks in bf16, and about three float32
-# [rows, BS] temporaries (scores, p, the mask's select).
+# double-buffered q (W lanes) and o blocks in bf16, the tile's rows as the
+# products take them (W lanes, bf16) and about three float32 [rows, BS]
+# temporaries (scores, p, the mask's select).
 def _prefill_row_bytes(lat: int, width: int, bs: int) -> int:
-    return (lat + 2 * 128) * 4 + 2 * 2 * (width + lat) + 3 * 4 * bs
+    return ((lat + 2 * 128) * 4 + 2 * 2 * (width + lat) + 2 * width
+            + 3 * 4 * bs)
 
 
 def mla_prefill_kernel_tile(tile: int, heads: int, lat: int, width: int,
@@ -311,14 +347,16 @@ def mla_prefill_kernel_tile(tile: int, heads: int, lat: int, width: int,
     return ct
 
 
-def mla_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
-                          block_tables, tile: int, lat: int, scale: float,
+def mla_prefill_attention(q_lat, q_rope, pool, tile_slot, tile_pos0,
+                          tile_valid, block_tables, tile: int, scale: float,
                           interpret: bool | None = None):
-    """Absorbed MLA prefill over the latent pool: ``q`` [NT*CT, H, W]
-    tile-aligned prefill tokens -> [NT*CT, H, lat]. Same scheduler contract
-    and sub-tiling as ``paged_attention.ragged_prefill_attention``; a tile's
-    ``CT * H`` query rows share each fetched block, so the matmuls are
-    ``[CT*H, W] x [W, BS]`` and ``[CT*H, BS] x [BS, lat]``.
+    """Absorbed MLA prefill over the latent pool: the tile-aligned prefill
+    tokens' queries HEAD-MAJOR and in their two parts (module doc), ``q_lat``
+    [H, NT*CT, lat] and ``q_rope`` [H, NT*CT, W - lat] (the roped lanes,
+    zeros) -> [H, NT*CT, lat]. Same scheduler contract and sub-tiling as
+    ``paged_attention.ragged_prefill_attention``; a tile's ``H * CT`` query
+    rows share each fetched block, so the matmuls are ``[H*CT, W] x [W, BS]``
+    and ``[H*CT, BS] x [BS, lat]``.
 
     Absorbed, a query-key pair costs ``2 H (2 lat + rope)`` FLOPs (34.8 k at
     Moonlight's widths) against ``2 H (nope + rope + v)`` (10.2 k) on
@@ -329,8 +367,8 @@ def mla_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
     both are a few per cent of the step's expert einsum; the absorbed form
     keeps one read path over the cached rows and materialises nothing.
     """
-    t_tokens, h, width = q.shape
-    _, bs, _ = pool.shape
+    h, t_tokens, lat = q_lat.shape
+    _, bs, width = pool.shape
     mb = block_tables.shape[1]
     ct = mla_prefill_kernel_tile(tile, h, lat, width, bs)
     tile_slot, tile_pos0, tile_valid = split_tiles(
@@ -343,31 +381,31 @@ def mla_prefill_attention(q, pool, tile_slot, tile_pos0, tile_valid,
         return (bt[ts[c], jnp.minimum(j, last)], 0, 0)
 
     def _q_map(c, j, ts, tp, tv, bt):
-        return (c, 0)
+        return (0, c, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_tiles, mb),
         in_specs=[
-            pl.BlockSpec((ct * h, width), _q_map),
+            pl.BlockSpec((h, ct, lat), _q_map),
+            pl.BlockSpec((h, ct, width - lat), _q_map),
             pl.BlockSpec((1, bs, width), _kv_map),
         ],
-        out_specs=pl.BlockSpec((ct * h, lat), _q_map),
+        out_specs=pl.BlockSpec((h, ct, lat), _q_map),
         scratch_shapes=[
-            pltpu.VMEM((ct * h, lat), jnp.float32),
-            pltpu.VMEM((ct * h, 128), jnp.float32),
-            pltpu.VMEM((ct * h, 128), jnp.float32),
+            pltpu.VMEM((h * ct, width), pool.dtype),
+            pltpu.VMEM((h * ct, lat), jnp.float32),
+            pltpu.VMEM((h * ct, 128), jnp.float32),
+            pltpu.VMEM((h * ct, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_prefill_kernel, bs=bs, heads=h, lat=lat,
-                               scale=scale)
-    out = pl.pallas_call(
+    kernel = functools.partial(_prefill_kernel, bs=bs, scale=scale)
+    return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((t_tokens * h, lat), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((h, t_tokens, lat), q_lat.dtype),
         grid_spec=grid_spec,
         interpret=interpret_mode(interpret),
         name="mla_prefill",
     )(tile_slot.astype(jnp.int32), tile_pos0.astype(jnp.int32),
-      tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q.reshape(t_tokens * h, width), pool)
-    return out.reshape(t_tokens, h, lat)
+      tile_valid.astype(jnp.int32), block_tables.astype(jnp.int32), q_lat,
+      q_rope, pool)

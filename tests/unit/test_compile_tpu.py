@@ -92,8 +92,9 @@ def _mla_decode(q, pool, slots, pos, bt):
 
 
 def _mla_prefill(q, pool, ts, tp, tv, bt):
-    return mla_prefill_attention(q, pool, ts, tp, tv, bt, TILE, MLA_LAT,
-                                 192 ** -0.5, interpret=False)
+    return mla_prefill_attention(q[..., :MLA_LAT], q[..., MLA_LAT:], pool, ts,
+                                 tp, tv, bt, TILE, 192 ** -0.5,
+                                 interpret=False)
 
 
 # DeepSeek-V3.2-Exp's sparse attention at the longctx-pool cell's shapes: 16
@@ -119,8 +120,9 @@ def _dsa_walk(q, pool, slots, pos, bt, keep):
 
 
 def _dsa_prefill(q, pool, bias, ts, tp, tv, bt):
-    return dsa.dsa_prefill_attention(q, pool, bias, ts, tp, tv, bt, TILE,
-                                     MLA_LAT, 192 ** -0.5, interpret=False)
+    return dsa.dsa_prefill_attention(q[..., :MLA_LAT], q[..., MLA_LAT:], pool,
+                                     bias, ts, tp, tv, bt, TILE, 192 ** -0.5,
+                                     interpret=False)
 
 
 def _dsa_args(kernel, devices):
@@ -146,7 +148,7 @@ def _dsa_args(kernel, devices):
         return (s((DSA_ROWS, DSA_HEADS, MLA_WIDTH)),
                 s((64, MLA_BLOCK, MLA_WIDTH)), rows, rows, bt,
                 s((DSA_ROWS, DSA_TABLE * MLA_BLOCK), jnp.bool_))
-    return (s((DSA_TILES * TILE, DSA_HEADS, MLA_WIDTH)),
+    return (s((DSA_HEADS, DSA_TILES * TILE, MLA_WIDTH)),
             s((64, MLA_BLOCK, MLA_WIDTH)),
             s((DSA_TILES * TILE, DSA_TABLE * MLA_BLOCK), jnp.float32),
             tiles, tiles, tiles, bt)
@@ -206,7 +208,7 @@ def _args(kernel, hq, hkv, d, devices, mla_block=MLA_BLOCK):
             rows = s((128,), jnp.int32)
             return s((128, MLA_HEADS, MLA_WIDTH)), pool, rows, rows, bt
         tiles = s((3,), jnp.int32)
-        return s((3 * TILE, MLA_HEADS, MLA_WIDTH)), pool, tiles, tiles, tiles, bt
+        return s((MLA_HEADS, 3 * TILE, MLA_WIDTH)), pool, tiles, tiles, tiles, bt
     if kernel in (_flash_fwd, _flash_bwd):
         return s((1, SEQ, hq, d)), s((1, SEQ, hkv, d)), s((1, SEQ, hkv, d))
     pool = s((64, BLOCK, hkv * d))
@@ -1140,6 +1142,26 @@ def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
                 and op not in _IN_PLACE and not op.endswith("-done")] == []
 
 
+def _cell_step_text(v5e, monkeypatch, cell, rows, tiles):
+    """``_step_text`` of a benchmark cell's own step program: its depth,
+    experts, vocabulary, pool, slots and table, read through
+    ``benchmark/cellspec.py`` as the harness reads them -> ``(optimized HLO,
+    abstract parameters, config)``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
+    monkeypatch.syspath_prepend(os.path.join(
+        os.path.dirname(__file__), os.pardir, os.pardir, "benchmark"))
+    import cellspec
+
+    spec = cellspec.resolve(cell)
+    mod, cfg, _ = cellspec.model(spec)
+    sizes = {**spec["config"]["serve"]["engine"],   # as serve_cell.py's
+             **spec["cell"].get("engine", {})}
+    text, params = _step_text(
+        v5e, mod, cfg, sizes["num_blocks"], sizes["max_seqs"] + 1,
+        sizes["max_blocks_per_seq"], rows, tiles)
+    return text, params, cfg
+
+
 @pytest.mark.parametrize("cell,rows,tiles", [
     ("granite-4.0-h-small-d10-ep2.chat-open", 64, 3),
     ("granite-4.0-h-small-d10-ep2.chat-open", 64, 2),
@@ -1167,18 +1189,7 @@ def test_mamba2_step_makes_the_in_projection_once(v5e, monkeypatch, cell,
     reader of its ``W_in`` stack, the product, which reads the stack in place
     (no ``bf16[D, d_inner + conv_width + H]`` of a slice or a copy: splitting
     the product over the weight's columns gave a 137 MB one a layer)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
-    monkeypatch.syspath_prepend(os.path.join(
-        os.path.dirname(__file__), os.pardir, os.pardir, "benchmark"))
-    import cellspec
-
-    spec = cellspec.resolve(cell)
-    mod, cfg, _ = cellspec.model(spec)
-    sizes = {**spec["config"]["serve"]["engine"],   # as serve_cell.py's
-             **spec["cell"].get("engine", {})}
-    text, params = _step_text(
-        v5e, mod, cfg, sizes["num_blocks"], sizes["max_seqs"] + 1,
-        sizes["max_blocks_per_seq"], rows, tiles)
+    text, params, cfg = _cell_step_text(v5e, monkeypatch, cell, rows, tiles)
     t = rows + tiles * TILE
     d, parts = cfg.hidden_size, (cfg.d_inner, cfg.conv_width,
                                  cfg.mamba_num_heads)
@@ -1205,6 +1216,74 @@ def test_mamba2_step_makes_the_in_projection_once(v5e, monkeypatch, cell,
         assert len(lines) == 1 and f" = bf16[{t},{sum(parts)}]" in lines[0], \
             lines
     assert _projection_results(text, params, ("w_in",)) == []
+
+
+@pytest.mark.parametrize("cell,rows,kernel", [
+    ("deepseek-v32-exp-d5-ep16.longctx-pool", 16, "dsa_attn_prefill"),
+    ("moonlight-16b-a3b-d8.reason-pool", 128, "mla_prefill")],
+    ids=["sparse-d16-t3", "moonlight-d128-t3"])
+def test_latent_tile_rows_cross_memory_once_each_way(v5e, monkeypatch, cell,
+                                                     rows, kernel):
+    """The paged contract's *Rows to heads*, second clause
+    (``models/paged.py``): the absorbed products are batched over heads and
+    lay their rows head-major, and the latent prefill kernels take a step's
+    TILE rows and give them back that way. On the mixed step programs of the
+    two cells, compiled at the cells' own sizes: in every layer body the
+    bfloat16 arrays of ``tile rows x H x lat`` (or ``x W``) elements are the
+    absorbed product's result, which IS the kernel's ``q_lat`` operand (the
+    roped lanes ride beside it, a quarter of its size, and the kernel joins
+    the two in VMEM), and the kernel's result, which the value product reads
+    where the
+    kernel wrote it. Before PR 56 the kernels' rows were (query, head) and
+    the sparse cell's scanned body held, 128 heads x 384 tile rows + 16
+    decode rows,
+
+        %fusion.560 = bf16[128,512,400]{1,2,0}          52.4 MB  thn,lhn->thl
+        %copy_bitcast_fusion.9 = bf16[400,128,512]{2,1,0}  52.4  its transpose
+        %maximum_maximum_fusion.6 = bf16[400,128,640]   65.5  [q_lat, q_rope, 0]
+        %slice_multiply_fusion.3 = bf16[384,128,640]    62.9  q[n_dec:] * scale
+        %dsa_attn_prefill.13 = bf16[49152,512]          50.3  the kernel's result
+        %copy.235 = bf16[384,128,512]{2,0,1}            50.3  head-major again
+
+    three re-layouts of rows that were right somewhere already, ~330 MB of
+    traffic a layer and step (the dense lead layer's ``copy_bitcast_fusion.3``,
+    ``slice_multiply_fusion.1``, ``copy.153`` alike; Moonlight's at 16 heads a
+    sixteenth of it)."""
+    text, _, cfg = _cell_step_text(v5e, monkeypatch, cell, rows, 3)
+    h, lat, width = cfg.num_heads, cfg.kv_lora_rank, cfg.row_lanes
+    tile_rows = 3 * TILE
+    sizes = {n * h * lanes for n in (tile_rows, tile_rows + rows)
+             for lanes in (lat, width)}
+    body_of = {ln.strip(): comp for comp, lines in _computations(text).items()
+               for ln in lines}
+
+    def name(ln):
+        return ln.removeprefix("ROOT ").split(" = ")[0]
+
+    def operands(ln):
+        return re.findall(r"%[\w.\-]+", ln.split(" = ", 1)[1].split(
+            ", custom_call_target")[0].split(", kind=")[0])
+
+    bodies = {}
+    for size, op, ln in _materialized(text):
+        # but the compiler's own prefetches: Moonlight's 2,048 x 2,048
+        # projections have as many elements as its 512 rows x 16 x 512
+        if (ln.split(" = ")[1].startswith("bf16[") and size // 2 in sizes
+                and not op.endswith("-done")):
+            bodies.setdefault(body_of[ln], []).append((op, ln))
+    held = {comp: found for comp, found in bodies.items()
+            if any(op == "kernel" and name(ln).startswith("%" + kernel)
+                   for op, ln in found)}
+    assert len(held) == 2, sorted(bodies)      # the lead layer, the scan's
+    for comp, found in held.items():
+        (call,) = [ln for op, ln in found if op == "kernel"]
+        (product,) = [ln for op, ln in found if op != "kernel"]
+        assert "dot_general" in product
+        assert operands(call)[4] == name(product)   # after the 4 prefetched
+        readers = [ln for ln in _computations(text)[comp]
+                   if " = " in ln and name(call) in operands(ln.strip())]
+        assert readers and not [ln for ln in readers if re.search(
+            r" (copy|transpose)\(", ln)], readers
 
 
 def test_a_table_kept_column_major_is_copied_for_its_row_gather(v5e,

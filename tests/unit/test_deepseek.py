@@ -202,14 +202,65 @@ def test_mla_kernels_equal_the_xla_gather(dtype, tol):
     ts = jnp.asarray([0, 0, 2, 3], jnp.int32)
     tp = jnp.asarray([8, 16, 0, 0], jnp.int32)
     tv = jnp.asarray([8, 5, 8, 0], jnp.int32)
-    qp = q(4 * tile)
-    want = attention.latent_prefill_attention(qp, pool, ts, tp, tv, tables,
-                                              tile, lat, scale, impl="xla")
-    got = mla_attention.mla_prefill_attention(qp, pool, ts, tp, tv, tables,
-                                              tile, lat, scale, interpret=True)
+    qp = jnp.swapaxes(q(4 * tile), 0, 1)       # a tile's rows lie head-major
+    qp = (qp[..., :lat], qp[..., lat:])         # and come in their two parts
+    want = attention.latent_prefill_attention(*qp, pool, ts, tp, tv, tables,
+                                              tile, scale, impl="xla")
+    got = mla_attention.mla_prefill_attention(*qp, pool, ts, tp, tv, tables,
+                                              tile, scale, interpret=True)
+    assert got.shape == (heads, 4 * tile, lat)
     valid = np.concatenate([np.arange(tile) < n for n in np.asarray(tv)])
-    np.testing.assert_allclose(np.asarray(got, np.float32)[valid],
-                               np.asarray(want, np.float32)[valid],
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, valid],
+                               np.asarray(want, np.float32)[:, valid],
+                               rtol=tol, atol=tol)
+
+
+# heads -> the kernel's sub-tile of a 128-row tile at the cells' widths (512
+# latent + 64 rope lanes in 640, 128-token blocks): Moonlight, Kimi-Linear,
+# LongCat-Flash, DeepSeek-V3.2
+HEAD_MAJOR_SUBTILES = {16: 64, 32: 32, 64: 16, 128: 8}
+
+
+def head_major_case(rng, heads, dtype):
+    """Two 128-row tiles at the cells' widths over a table of two 128-token
+    blocks: the second chunk of a prompt (positions 128 .. 255) and a first
+    chunk of 70 tokens, their queries HEAD-MAJOR in two parts, ``[H, 256,
+    512]`` and ``[H, 256, 128]``; the kernels' sub-tile is the cell's."""
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_prefill_kernel_tile
+
+    lat, rope, width, bs, tile = 512, 64, 640, 128, 128
+    assert mla_prefill_kernel_tile(tile, heads, lat, width,
+                                   bs) == HEAD_MAJOR_SUBTILES[heads]
+    lanes = jnp.arange(width) < lat + rope
+    pool = (_random_pool(rng, 6, bs, width) * lanes).astype(dtype)
+    q = (jnp.asarray(rng.standard_normal((heads, 2 * tile, width)),
+                     jnp.float32) * lanes).astype(dtype)
+    tables = jnp.asarray([[3, 1], [4, 2], [0, 0]], jnp.int32)
+    ts, tp, tv = (jnp.asarray(a, jnp.int32) for a in ([0, 1], [128, 0],
+                                                      [128, 70]))
+    valid = np.concatenate([np.arange(tile) < n for n in np.asarray(tv)])
+    return dict(q=(q[..., :lat], q[..., lat:]), pool=pool, tables=tables,
+                ts=ts, tp=tp, tv=tv, tile=tile, lat=lat, bs=bs, valid=valid)
+
+
+@pytest.mark.parametrize("heads,dtype,tol", [
+    (16, jnp.float32, 2e-5), (32, jnp.float32, 2e-5), (64, jnp.float32, 2e-5),
+    (128, jnp.bfloat16, 3e-2)], ids=["16", "32", "64", "128-bf16"])
+def test_mla_prefill_takes_and_gives_head_major_rows(heads, dtype, tol):
+    """``mla_prefill`` (interpret mode) on head-major rows against the XLA
+    gather at the four cells' head counts, each at the sub-tile the chooser
+    gives it there: 8 queries at 128 heads, half a bfloat16 tile's rows,
+    which the kernel lays out as the rows of its products once a tile."""
+    from deepspeed_tpu.ops.pallas import mla_attention
+
+    c = head_major_case(np.random.default_rng(heads), heads, dtype)
+    args = (*c["q"], c["pool"], c["ts"], c["tp"], c["tv"], c["tables"],
+            c["tile"], 0.03)
+    want = attention.latent_prefill_attention(*args, impl="xla")
+    got = mla_attention.mla_prefill_attention(*args, interpret=True)
+    assert got.shape == (heads, 2 * c["tile"], c["lat"])
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, c["valid"]],
+                               np.asarray(want, np.float32)[:, c["valid"]],
                                rtol=tol, atol=tol)
 
 

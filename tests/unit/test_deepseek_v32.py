@@ -226,14 +226,52 @@ def test_dsa_kernels_equal_their_xla_forms(dtype, tol):
     class Geometry:
         kv_lora_rank, softmax_scale, index_topk = c["lat"], 0.3, 16
 
+    n_dec = c["tiles"][0]       # decode rows token-major, tiles head-major
+    q_tiles = jnp.swapaxes(c["q"][n_dec:], 0, 1)
     out = {impl: np.asarray(v32.sparse_pool_attention(
-        Geometry, c["q"], c["q_idx"].astype(jnp.float32), c["w_idx"],
+        Geometry, c["q"][:n_dec],
+        (q_tiles[..., :c["lat"]], q_tiles[..., c["lat"]:]),
+        c["q_idx"].astype(jnp.float32), c["w_idx"],
         c["pool_kv"], c["pool_idx"].astype(jnp.float32), c["slots"],
         c["positions"], c["bt"], c["tiles"], impl=impl), np.float32)
         for impl in ("pallas", "xla")}
-    assert out["pallas"].shape == (len(c["real"]), 2, c["lat"])
-    np.testing.assert_allclose(out["pallas"][c["real"]], out["xla"][c["real"]],
-                               rtol=0, atol=tol)
+    assert out["pallas"].shape == (2, len(c["real"]), c["lat"])
+    np.testing.assert_allclose(out["pallas"][:, c["real"]],
+                               out["xla"][:, c["real"]], rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("heads,dtype,tol", [
+    (16, jnp.float32, 2e-5), (32, jnp.float32, 2e-5), (64, jnp.float32, 2e-5),
+    (128, jnp.bfloat16, 3e-2)], ids=["16", "32", "64", "128-bf16"])
+def test_dsa_prefill_takes_and_gives_head_major_rows(heads, dtype, tol):
+    """``dsa_attn_prefill`` (interpret mode) on head-major rows against its
+    XLA form at the four head counts of the cells that share the contract
+    (``test_deepseek.head_major_case``: the cells' widths, so each at its own
+    sub-tile, 8 queries at this family's 128 heads), under a selection that
+    keeps a third of the causal pairs and every query's own position: one
+    bias row a query, broadcast over its heads."""
+    from test_deepseek import head_major_case
+
+    rng = np.random.default_rng(heads)
+    c = head_major_case(rng, heads, dtype)
+    t = 2 * c["tile"]
+    slots = np.repeat(np.asarray(c["ts"]), c["tile"])
+    positions = (np.repeat(np.asarray(c["tp"]), c["tile"])
+                 + np.tile(np.arange(c["tile"]), 2))
+    keys = np.arange(2 * c["bs"])[None, :]
+    keep = (rng.random((t, 2 * c["bs"])) < 1 / 3) | (keys == positions[:, None])
+    keep &= (keys <= positions[:, None]) & c["valid"][:, None]
+    bias = jnp.where(jnp.asarray(keep), 0.0, -1e30)
+    want = v32.prefill_attention_xla(
+        *c["q"], c["pool"], bias, jnp.asarray(np.where(c["valid"], slots, 2)),
+        c["tables"], 0.03)
+    got = dsa.dsa_prefill_attention(
+        *c["q"], c["pool"], bias, c["ts"], c["tp"], c["tv"], c["tables"],
+        c["tile"], 0.03, interpret=True)
+    assert got.shape == (heads, t, c["lat"])
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, c["valid"]],
+                               np.asarray(want, np.float32)[:, c["valid"]],
+                               rtol=tol, atol=tol)
 
 
 WALK_ROWS = ["everything_kept", "scattered_over_three_steps",
@@ -315,7 +353,7 @@ def test_both_decode_forms_attend_alike(monkeypatch, table, form, impl):
 
     assert v32.decode_form(table * 8) == form
     got = np.asarray(v32.sparse_pool_attention(
-        Geometry, c["q"][:n_dec], c["q_idx"][:n_dec], c["w_idx"][:n_dec],
+        Geometry, c["q"][:n_dec], None, c["q_idx"][:n_dec], c["w_idx"][:n_dec],
         c["pool_kv"], c["pool_idx"], c["slots"][:n_dec], positions[:n_dec],
         bt, impl=impl))
     assert bool(gathers) == (form == "gather")
@@ -324,9 +362,10 @@ def test_both_decode_forms_attend_alike(monkeypatch, table, form, impl):
                                   positions[:n_dec], bt)
     mask = v32.select_mask(scores, positions[:n_dec], 6)
     assert int(mask[0].sum()) == 6          # the selection bites
+    q = jnp.swapaxes(c["q"][:n_dec], 0, 1)            # head-major, as got
     want = np.asarray(v32.prefill_attention_xla(
-        c["q"][:n_dec], c["pool_kv"], jnp.where(mask, 0.0, -1e30),
-        c["slots"][:n_dec], bt, c["lat"], 0.3))
+        q[..., :c["lat"]], q[..., c["lat"]:], c["pool_kv"],
+        jnp.where(mask, 0.0, -1e30), c["slots"][:n_dec], bt, 0.3))
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
 
 
