@@ -2903,6 +2903,12 @@ class RaggedInferenceEngine:
             moved.inc(state["dec_state_bytes"], part="decode", **kind)
             moved.inc(state["state_bytes"] - state["dec_state_bytes"],
                       part="prefill", **kind)
+            if "chunk_slots" in state:
+                self.telemetry.counter(
+                    "inference_chunk_tiles_total",
+                    "prefill tiles the step programs ran a recurrence's "
+                    "chunk form over, a layer (padding tiles too)").inc(
+                        state["chunk_tiles"], **kind)
             self.telemetry.counter(
                 "inference_slot_state_pad_rows_total",
                 "rows of the steps' decode buckets that were padding: they "
@@ -3035,9 +3041,13 @@ class RaggedInferenceEngine:
         recurrence, ``chunk_tiles``, the tiles the step program runs the
         recurrence's chunk form over, a layer (padding tiles too: a tile is
         the same work whatever it holds; ``kda_chunk``'s grid is heads x
-        these), ``state_pad_rows``, the rows of the program's decode bucket
-        (``nd``) past its ``n_dec`` real ones (they name the scratch slot and
-        the decode kernel moves its state like any row's), ``slot_resets``,
+        these), ``chunk_slots``, the distinct prefilling slots of the step:
+        the states the chunk form must read and write once each (the prefill
+        part of ``state_bytes`` over a slot's bytes; not for ``"mamba1"``,
+        which has no chunk form), ``state_pad_rows``, the rows of the
+        program's decode bucket (``nd``) past its ``n_dec`` real ones (they
+        name the scratch slot and the decode kernel moves its state like any
+        row's), ``slot_resets``,
         the slots the step starts from zeros (the sequences whose first tile
         it carries: the arrivals since the last dispatch, and a preempted
         request run again), and ``state_kind``, the recurrence's name where
@@ -3047,7 +3057,8 @@ class RaggedInferenceEngine:
         chunk form computes that recurrence: ``selscan_tile``'s grid is
         channel blocks x these). The dispatch feeds the same to
         ``inference_slot_state_bytes_total`` (``part`` ``decode`` /
-        ``prefill``), ``inference_slot_state_pad_rows_total`` and
+        ``prefill``), ``inference_chunk_tiles_total`` (a family with a chunk
+        form), ``inference_slot_state_pad_rows_total`` and
         ``inference_slot_resets_total``. Nothing for a model with none."""
         if not self._slot_state:
             return {}
@@ -3059,7 +3070,8 @@ class RaggedInferenceEngine:
                 "chunk_tiles": nt,
                 "state_pad_rows": nd - n_dec,
                 "slot_resets": self._slot_resets,
-                **({"scan_tiles": nt} if kind == "mamba1" else {}),
+                **({"scan_tiles": nt} if kind == "mamba1"
+                   else {"chunk_slots": n_pre}),
                 **({} if kind is None else {"state_kind": kind})}
 
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:

@@ -358,31 +358,17 @@ def init_paged_cache(cfg: GraniteHybridConfig, num_blocks: int, block_size: int,
     return cache
 
 
-def _attention_ragged(cfg: GraniteHybridConfig, h, lp, pool, layer_tables,
-                      slots, positions, prefill_tiles):
-    from deepspeed_tpu.models.paged import (
-        ragged_pool_attention,
-        rows_to_heads,
-        write_kv_paged,
-    )
-
-    q = rows_to_heads(h, lp["wq"], cfg.num_heads) * cfg.q_scale
-    kk = rows_to_heads(h, lp["wk"], cfg.num_kv_heads)
-    vv = rows_to_heads(h, lp["wv"], cfg.num_kv_heads)
-    kc, vc = write_kv_paged(pool["k"], pool["v"], kk, vv, slots, positions,
-                            layer_tables, prefill_tiles)
-    o = ragged_pool_attention(q, kc, vc, slots, positions, layer_tables,
-                              prefill_tiles).astype(h.dtype)
-    return o.reshape(h.shape[0], -1) @ lp["wo"], {**pool, "k": kc, "v": vc}
-
-
 def ragged_forward(cfg: GraniteHybridConfig, params, tokens, slots, positions,
                    block_tables, cache, prefill_tiles=None):
     """Flat ragged step: [T] mixed tokens -> ([T, V] logits, cache). Each run
     of layers is scanned where its stack lies (``paged.scan_runs_paged``), a
     layer addressed in the leaves that count it: an attention layer through
     its block table, a Mamba layer by its slots' rows."""
-    from deepspeed_tpu.models.paged import SLOTS, scan_runs_paged
+    from deepspeed_tpu.models.paged import (
+        SLOTS,
+        nope_attention_ragged,
+        scan_runs_paged,
+    )
 
     scratch = cache[SLOTS]["ssm"].shape[1] - 1
 
@@ -395,8 +381,9 @@ def ragged_forward(cfg: GraniteHybridConfig, params, tokens, slots, positions,
                                          prefill_tiles)
                 pool = {**pool, SLOTS: state}
             else:
-                o, pool = _attention_ragged(cfg, h, lp["mix"], pool, address,
-                                            slots, positions, prefill_tiles)
+                o, pool = nope_attention_ragged(cfg, h, lp["mix"], pool,
+                                                address, slots, positions,
+                                                prefill_tiles)
             x = x + o * cfg.residual_multiplier
             ffn = lp["ffn"]
             st = (*stacks, ffn["first_expert"]) if stacks is not None else None

@@ -110,7 +110,7 @@ class JambaConfig:
 
     @property
     def q_scale(self) -> float:
-        """``granite_hybrid._attention_ragged``'s factor on ``q``: none, the
+        """``paged.nope_attention_ragged``'s factor on ``q``: none, the
         scores' scale is the kernels' own ``head_dim ** -0.5``."""
         return 1.0
 
@@ -294,8 +294,11 @@ def ragged_forward(cfg: JambaConfig, params, tokens, slots, positions,
     of layers is scanned where its stack lies (``paged.scan_runs_paged``), a
     layer addressed in the leaves that count it: an attention layer through
     its block table, a Mamba layer by its slots' rows."""
-    from deepspeed_tpu.models.granite_hybrid import _attention_ragged
-    from deepspeed_tpu.models.paged import SLOTS, scan_runs_paged
+    from deepspeed_tpu.models.paged import (
+        SLOTS,
+        nope_attention_ragged,
+        scan_runs_paged,
+    )
 
     scratch = cache[SLOTS]["ssm"].shape[1] - 1
 
@@ -308,8 +311,9 @@ def ragged_forward(cfg: JambaConfig, params, tokens, slots, positions,
                                          prefill_tiles)
                 pool = {**pool, SLOTS: state}
             else:
-                o, pool = _attention_ragged(cfg, h, lp["mix"], pool, address,
-                                            slots, positions, prefill_tiles)
+                o, pool = nope_attention_ragged(cfg, h, lp["mix"], pool,
+                                                address, slots, positions,
+                                                prefill_tiles)
             return _ffn_sublayer(cfg, x + o, lp), pool
 
         return ("slot" if kind == "mamba" else "block"), fn

@@ -29,6 +29,11 @@ ffn(norm(x))``, a final RMSNorm, an untied head::
     MoE(h):   s = sigmoid(h W_r) over num_experts (float32);  pick = top_k(s + e_score_correction_bias)
               w = s[pick] / sum(s[pick]) * routed_scaling_factor;   y = sum_i w_i SwiGLU_i(h) + SwiGLU_shared(h)
 
+**One KDA mixer** (``models/kda.py``, ``solar_open2``'s too): the equations
+above at ``kda_beta_scale`` 1.0; the projections, the convolutions, the chunk
+form, the decode row, the slot leaves and the gates' seeded draws are that
+module's, and this family hands it the normed rows.
+
 A layer is a mixer AND a feed-forward part, and the two vary independently:
 ``layer_pattern`` names each layer by one letter, ``D`` KDA + dense, ``K`` KDA +
 experts, ``M`` MLA + experts, ``A`` MLA + dense (``DKKMKKKM...`` as published).
@@ -45,12 +50,6 @@ layers are ``DK`` + 6 x ``KMKK`` + ``M``, seven layers to compile). A step
 program's scan then hands every layer a slice of a stack that is nothing
 else's, with no copy.
 
-**The chunk form** (``ops/pallas/kda.kda_tiles``; that module's doc has the
-algebra). A tile of ``R`` rows runs the recurrence as matmuls, in sub-chunks
-of ``sub_chunk`` (16) rows, float32 and ``Precision.HIGHEST`` throughout.
-Rows of a tile past its valid ones have ``g = 0`` and ``beta = 0``: they
-neither decay nor feed the state.
-
 **Serving.** The MLA layers' rows ``[c, k_pe, zeros]`` (``row_lanes``, 640) lie
 in the latent pool, ``cache["kv"]`` ``[L_mla, NB, BS, row_lanes]``; the KDA
 layers' state lies beside it in slot leaves (``models/paged.py``):
@@ -60,14 +59,8 @@ the sublanes, a head's values side by side on the lanes:
 convolutions' inputs ``[q | k | v]``, oldest first, as a window leaf
 (``models/paged.py``: ``[L_kda, S, 3 x 16, 3 P / 16]`` in bfloat16, a row's
 channels folded over a sublane tile's rows; ``[L_kda, S, 3, 3 P]`` for a width
-the tile does not divide). A decode row is one delta-rule update of its slot's
-state (``kda_decode``), a prefill tile one chunk, the state carried from tile
-to tile of a slot in order: on the chip ``kda_chunk``, the chunk form as one
-kernel that reads the tiles' rows and the slots' states where they lie; off
-it ``kda_tiles`` between a slice and an update a tile (``kda_chunk_xla``; its
-forward substitution and carry are ``lax.scan``s, which the plain forward
-pass compiles too). A row or tile at position 0 starts from zeros whatever
-the slot held.
+the tile does not divide). ``models/kda.py`` says what a decode row and a
+prefill tile do with them.
 
 **One rank's share.** ``experts_held`` of the ``num_experts`` routed experts
 live here (``expert_rank``'s); the router scores and picks over all of them.
@@ -81,12 +74,13 @@ A quantized pool beside the slot state raises.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import lru_cache, partial
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.models import kda
 from deepspeed_tpu.models.api import ModelSpec, ShardCtx, causal_lm_loss
 from deepspeed_tpu.models.deepseek import (
     _ffn,
@@ -101,37 +95,12 @@ from deepspeed_tpu.models.experts import (
     routed_experts_einsum,
 )
 from deepspeed_tpu.models.llama import rmsnorm
+from deepspeed_tpu.models.paged import stack_plan_tail
 
 # ONE decode bucket at the benchmark's 128 slots: 7 step programs, not 27
 # (``longcat_flash.DECODE_BUCKET_MIN`` has the argument; a padding row here
 # reads and writes the scratch slot's 2 MB a KDA layer, 1.5% of a step a row)
 DECODE_BUCKET_MIN = 128
-STATE_KIND = "kda"
-# the seeded gates: ``dt`` log-uniform over this range a channel (``init_params``)
-_DT_RANGE = (0.001, 0.1)
-
-
-@lru_cache(maxsize=None)
-def _plan(pattern: str):
-    """``(lead, period, repeats, tail)`` with ``pattern == lead + period *
-    repeats + tail``, ``paged.stack_plan`` of what comes before the tail, and
-    ``lead + period + tail`` (the layers a step program compiles) the least."""
-    from deepspeed_tpu.models.paged import stack_plan
-
-    best = None
-    for t in range(len(pattern)):
-        try:
-            plan = (*stack_plan(pattern[:len(pattern) - t]),
-                    pattern[len(pattern) - t:])
-        except NotImplementedError:
-            continue
-        if best is None or len(plan[0] + plan[1] + plan[3]) \
-                < len(best[0] + best[1] + best[3]):
-            best = plan
-    if best is None:
-        raise NotImplementedError(
-            f"kimi_linear: layer pattern {pattern!r} has no repeated period")
-    return best
 
 
 @dataclass(frozen=True)
@@ -206,7 +175,7 @@ class KimiLinearConfig:
                              "shares")
         if self.chunk_size % self.sub_chunk:
             raise ValueError("kimi_linear: sub_chunk must divide chunk_size")
-        _plan(self.layer_pattern)  # raises what cannot be scanned
+        stack_plan_tail(self.layer_pattern)  # raises what cannot be scanned
 
     # ---- the linear-attention group
     @property
@@ -229,6 +198,8 @@ class KimiLinearConfig:
     @property
     def conv_kernel(self) -> int:
         return self._lin["short_conv_kernel_size"]
+
+    kda_beta_scale = 1.0                  # ``beta`` in (0, 1) (``models/kda.py``)
 
     @property
     def layer_pattern(self) -> str:
@@ -293,19 +264,12 @@ class KimiLinearConfig:
 
 
 # ------------------------------------------------------------------ weights
-def _mixer_shapes(cfg: KimiLinearConfig, kda: bool) -> dict:
-    """``{name: (shape, init)}`` of one mixer; ``init`` a std, ``"out"`` (an
-    output projection's), ``"ones"`` or a gate's own name."""
-    d = cfg.hidden_size
-    if kda:
-        h, kd, p = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_width
-        return {"w_qkv": ((d, 3 * p), 0.02), "conv_w": ((cfg.conv_kernel, 3 * p), "conv"),
-                "w_fa": ((d, kd), 0.02), "w_fb": ((kd, p), 0.02),
-                "dt_bias": ((p,), "dt"), "a_log": ((h,), "a"),
-                "w_b": ((d, h), 0.02),
-                "w_ga": ((d, kd), 0.02), "w_gb": ((kd, p), 0.02),
-                "o_norm": ((kd,), "ones"), "wo": ((p, d), "out")}
-    heads, lat = cfg.num_heads, cfg.kv_lora_rank
+def _mixer_shapes(cfg: KimiLinearConfig, is_kda: bool) -> dict:
+    """``{name: (shape, init)}`` of one mixer (``kda.mixer_shapes`` has the
+    forms of ``init``)."""
+    if is_kda:
+        return kda.mixer_shapes(cfg)
+    d, heads, lat = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
     return {"wq": ((d, heads * cfg.qk_head_dim), 0.02),
             "wkv_a": ((d, lat + cfg.qk_rope_head_dim), 0.02),
             "kv_norm": ((lat,), "ones"),
@@ -339,14 +303,13 @@ def _layer_shapes(cfg: KimiLinearConfig, kind: str) -> dict:
 
 def init_params(cfg: KimiLinearConfig, rng) -> dict:
     """Seeded weights: std 0.02 (output projections 0.02 / sqrt(2 x layers)),
-    ``A_log = log U(1, 16)`` a head, ``dt_bias`` the inverse softplus of a
-    log-uniform draw over ``_DT_RANGE`` ([0.001, 0.1]) a channel (so a
-    channel's decay a token lies in ~[0.2, 0.999]), the convolutions uniform
-    in +-1 / sqrt(kernel), ``e_score_correction_bias ~ N(0, 0.01)``: decay,
+    the KDA gates and convolutions by ``kda.draw`` (``A_log = log U(1, 16)``
+    a head, a channel's decay a token in ~[0.2, 0.999], the convolutions
+    uniform in +-1 / sqrt(kernel)), ``e_score_correction_bias ~ N(0, 0.01)``: decay,
     ``beta`` and selection bias all matter from the first token. The draws
     come from the device's own generator (``nemotron_h.init_params`` says
     why)."""
-    lead, period, repeats, tail = _plan(cfg.layer_pattern)
+    lead, period, repeats, tail = stack_plan_tail(cfg.layer_pattern)
     rng = jax.random.wrap_key_data(jax.random.bits(rng, (4,), jnp.uint32),
                                    impl="rbg")
     draws = 2 + sum(init != "ones" for kind in lead + period + tail
@@ -356,22 +319,13 @@ def init_params(cfg: KimiLinearConfig, rng) -> dict:
     # ONE split: a ``fold_in`` a draw costs this program 9 s more to compile
     k = iter(jax.random.split(rng, draws))
     out_std = 0.02 / jnp.sqrt(2.0 * cfg.num_layers)
-    lo, hi = (jnp.log(t) for t in _DT_RANGE)
 
     def leaf(stack, shape, init):
         shape = stack + shape
         if init == "ones":
             return jnp.ones(shape, jnp.float32)
-        if init == "conv":
-            return jax.random.uniform(next(k), shape, jnp.float32, -1.0,
-                                      1.0) * cfg.conv_kernel ** -0.5
-        if init == "a":
-            return jnp.log(jax.random.uniform(next(k), shape, jnp.float32,
-                                              1.0, 16.0))
-        if init == "dt":
-            dt = jnp.exp(jax.random.uniform(next(k), shape, jnp.float32)
-                         * (hi - lo) + lo)
-            return dt + jnp.log(-jnp.expm1(-dt))
+        if init in ("conv", "a", "dt"):
+            return kda.draw(cfg, next(k), shape, init)
         std = out_std if init == "out" else init
         return jax.random.normal(next(k), shape, jnp.float32) * std
 
@@ -390,8 +344,7 @@ def init_params(cfg: KimiLinearConfig, rng) -> dict:
     }
 
 
-_AXES = {"w_qkv": ("embed", None), "w_fa": ("embed", None),
-         "w_b": ("embed", None), "w_ga": ("embed", None),
+_AXES = {**kda.LOGICAL_AXES,
          "wq": ("embed", "heads"), "wkv_a": ("embed", None),
          "wkv_b": (None, "heads"), "router": ("embed", None),
          "ws_gate": ("embed", "ffn"), "ws_up": ("embed", "ffn"),
@@ -406,14 +359,12 @@ _DENSE_AXES = {"w_gate": ("embed", "ffn"), "w_up": ("embed", "ffn"),
 
 def param_logical_axes(cfg: KimiLinearConfig) -> dict:
     """The logical axes of ``init_params``' tree, leaf for leaf."""
-    lead, period, _, tail = _plan(cfg.layer_pattern)
+    lead, period, _, tail = stack_plan_tail(cfg.layer_pattern)
 
     def layer(kind, stack=()):
         def axes(tree, table):
-            return {name: stack + table.get(
-                name, ("heads", "embed") if name == "wo"
-                else (None,) * len(shape))
-                for name, (shape, _) in tree.items()}
+            return {name: stack + table.get(name, (None,) * len(shape))
+                    for name, (shape, _) in tree.items()}
 
         shapes = _layer_shapes(cfg, kind)
         ffn = {**_AXES, **(_DENSE_AXES if kind in "DA" else _EXPERT_AXES)}
@@ -438,92 +389,6 @@ def _layer_weights(lp: dict, prepare) -> dict:
             "mix": prepare(lp["mix"]), "ffn": prepare(lp["ffn"])}
 
 
-# ------------------------------------------------------------------ KDA
-def _kda_inputs(cfg: KimiLinearConfig, h, lp):
-    """``h`` [..., D] (normed) -> ``qkv`` [..., 3 P] (before the
-    convolutions), the log-decay ``g`` [..., H, K] float32 (<= 0), ``beta``
-    [..., H] float32 and the output gate's logits [..., P]."""
-    f32 = jnp.float32
-    lead = h.shape[:-1]
-    heads, kd = cfg.kda_heads, cfg.kda_head_dim
-    dtype = h.dtype
-    f = (h @ lp["w_fa"].astype(dtype)) @ lp["w_fb"].astype(dtype)
-    dt = jax.nn.softplus(f.astype(f32) + lp["dt_bias"].astype(f32))
-    g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * dt.reshape(*lead, heads, kd)
-    beta = jax.nn.sigmoid((h @ lp["w_b"].astype(dtype)).astype(f32))
-    gate = (h @ lp["w_ga"].astype(dtype)) @ lp["w_gb"].astype(dtype)
-    return h @ lp["w_qkv"].astype(dtype), g, beta, gate
-
-
-def _conv(cfg: KimiLinearConfig, win, w, rows: int):
-    """The three causal depthwise convolutions as one over ``[q | k | v]``,
-    and silu: ``win`` [..., rows + kernel - 1, 3 P] (the ``kernel - 1`` rows
-    before the first, then the rows) and ``w`` [kernel, 3 P] -> [..., rows,
-    3 P] float32. The channels may be folded over two axes in both, as a
-    window leaf keeps them (``paged.window_fold``): the result's are too."""
-    w = w.astype(jnp.float32)
-    axis = win.ndim - w.ndim
-    acc = 0.0
-    for j in range(cfg.conv_kernel):
-        acc = acc + lax.slice_in_dim(win, j, j + rows, axis=axis).astype(
-            jnp.float32) * w[j]
-    return jax.nn.silu(acc)
-
-
-def _qkv_split(cfg: KimiLinearConfig, xc):
-    """``xc`` [..., 3 P] float32 (after convolution and silu) -> ``q``, ``k``
-    [..., H, K] (each L2-normalised over ``K``; ``q`` times ``K^-0.5``) and
-    ``v`` [..., H, V], float32."""
-    lead = xc.shape[:-1]
-    heads, kd = cfg.kda_heads, cfg.kda_head_dim
-    q, k, v = (xc[..., j * cfg.kda_width:(j + 1) * cfg.kda_width].reshape(
-        *lead, heads, kd) for j in range(3))
-
-    def unit(x):
-        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-    return unit(q) * kd ** -0.5, unit(k), v
-
-
-def _kda_out(cfg: KimiLinearConfig, o, gate, lp):
-    """``o`` [..., H x V] float32 (the state's reading) -> the layer's output
-    [..., D]: RMSNorm over each head's ``V`` (its weight ``[V]``), the sigmoid
-    gate, ``W_o``."""
-    f32 = jnp.float32
-    lead = o.shape[:-1]
-    o = o.reshape(*lead, cfg.kda_heads, cfg.kda_head_dim)
-    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps)
-    o = (o * lp["o_norm"].astype(f32)).reshape(*lead, cfg.kda_width)
-    o = (o * jax.nn.sigmoid(gate.astype(f32))).astype(gate.dtype)
-    return o @ lp["wo"].astype(gate.dtype)
-
-
-def _kda_sequence(cfg: KimiLinearConfig, lp, h):
-    """The KDA mixer over one whole sequence ``h`` [S, D] from an empty
-    state, for the plain forward pass: a scan over chunks of the form the
-    serving tiles run."""
-    from deepspeed_tpu.ops.pallas.kda import kda_tiles
-
-    s, r, kc = h.shape[0], cfg.chunk_size, cfg.conv_kernel
-    qkv, g, beta, gate = _kda_inputs(cfg, h, lp)
-    win = jnp.concatenate([jnp.zeros((kc - 1, qkv.shape[1]), qkv.dtype), qkv])
-    q, k, v = _qkv_split(cfg, _conv(cfg, win, lp["conv_w"], s))
-    pad = -s % r
-
-    def chunks(t):
-        return jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1)).reshape(
-            (-1, r) + t.shape[1:])
-
-    def chunk(state, xs):
-        y, state = kda_tiles(*(t[None] for t in xs), state[None],
-                             jnp.zeros((1,), bool), cfg.sub_chunk)
-        return state[0], y[0]
-
-    state = jnp.zeros((cfg.kda_head_dim, cfg.kda_width), jnp.float32)
-    _, y = lax.scan(chunk, state, tuple(map(chunks, (q, k, v, g, beta))))
-    return _kda_out(cfg, y.reshape(-1, cfg.kda_width)[:s], gate, lp)
-
-
 def forward(cfg: KimiLinearConfig, params, input_ids,
             ctx: ShardCtx | None = None):
     """``[B, S]`` token ids -> ``[B, S, V]`` logits: the plain forward pass
@@ -531,7 +396,7 @@ def forward(cfg: KimiLinearConfig, params, input_ids,
     absorbed), the experts through the einsum form."""
     ctx = ctx or ShardCtx()
     b, s = input_ids.shape
-    lead, period, _, tail = _plan(cfg.layer_pattern)
+    lead, period, _, tail = stack_plan_tail(cfg.layer_pattern)
     x = ctx.embed_lookup(params["embed"], input_ids, "batch", "seq", "embed_act")
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
 
@@ -539,7 +404,7 @@ def forward(cfg: KimiLinearConfig, params, input_ids,
         lp = _layer_weights(lp, partial(ctx.layer_weights, dtype=x.dtype))
         h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
         if kind in "DK":
-            x = x + jax.vmap(partial(_kda_sequence, cfg, lp["mix"]))(h)
+            x = x + jax.vmap(partial(kda.sequence, cfg, lp["mix"]))(h)
         else:
             x = x + _plain_attention(cfg, h, lp["mix"], positions)
         h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps)
@@ -574,7 +439,7 @@ def init_paged_cache(cfg: KimiLinearConfig, num_blocks: int, block_size: int,
     ``3 P`` channels, oldest first, as a window leaf
     (``paged.init_window_leaf``: ``[L_kda, num_slots, (kernel - 1) x r, 3 P /
     r]``). The last slot is the scratch slot."""
-    from deepspeed_tpu.models.paged import SLOTS, init_window_leaf
+    from deepspeed_tpu.models.paged import SLOTS
 
     if codec is not None:
         raise NotImplementedError(
@@ -583,90 +448,12 @@ def init_paged_cache(cfg: KimiLinearConfig, num_blocks: int, block_size: int,
     if num_slots is None:
         raise ValueError("kimi_linear: the cache needs the engine's slot "
                          "count (num_slots = max_seqs + 1) for its KDA state")
-    n_kda = cfg.layers_of("DK")
     return {
         "kv": jnp.zeros((cfg.layers_of("MA"), num_blocks, block_size,
                          cfg.row_lanes), dtype),
-        SLOTS: {
-            "kda": jnp.zeros((n_kda, num_slots, cfg.kda_head_dim,
-                              cfg.kda_width), jnp.float32),
-            "conv": init_window_leaf(n_kda, num_slots, cfg.conv_kernel - 1,
-                                     3 * cfg.kda_width, dtype),
-        },
+        SLOTS: kda.init_slot_leaves(cfg, cfg.layers_of("DK"), num_slots,
+                                    dtype),
     }
-
-
-def _kda_ragged(cfg: KimiLinearConfig, h, lp, state, slot0, scratch, slots,
-                positions, prefill_tiles):
-    """The KDA mixer over the normed rows ``h`` [T, D] of a flat ragged token
-    batch: ``state`` the slot leaves, layers and slots merged; this layer's
-    slot ``s`` is row ``slot0 + s``; ``scratch`` the scratch slot. Returns
-    ``(out [T, D], state)``."""
-    from deepspeed_tpu.models.paged import (
-        decode_windows,
-        tile_windows,
-        window_fold,
-    )
-    from deepspeed_tpu.ops.pallas.kda import kda_chunk, kda_decode
-
-    kda, conv = state["kda"], state["conv"]
-    vd = cfg.kda_head_dim
-
-    qkv, g, beta, gate = _kda_inputs(cfg, h, lp)
-    t = h.shape[0]
-    n_dec = t if prefill_tiles is None else prefill_tiles[0]
-    ys = []
-    if n_dec:
-        real = slots[:n_dec] != scratch
-        fresh = real & (positions[:n_dec] == 0)
-        rows = slots[:n_dec] + slot0
-        # the window's arithmetic runs on the channels as the leaf folds
-        # them: the rows come and go as whole tiles, the 128 new rows and
-        # the weights are what is folded, the 128 results what is unfolded
-        win, conv = decode_windows(conv, rows, qkv[:n_dec], fresh, real)
-        qd, kd, vv = _qkv_split(cfg, _conv(
-            cfg, win, window_fold(conv, lp["conv_w"]), 1).reshape(n_dec, -1))
-        # a padding row neither decays nor feeds; position 0 starts from zeros
-        a = jnp.where(fresh[:, None, None], 0.0, jnp.exp(
-            jnp.where(real[:, None, None], g[:n_dec], 0.0)))
-        bd = jnp.where(real[:, None], beta[:n_dec], 0.0)
-        kda, y = kda_decode(
-            kda, rows, *(x.transpose(0, 2, 1) for x in (a, kd, qd)),
-            vv.reshape(n_dec, -1), jnp.repeat(bd, vd, axis=1))
-        ys.append(y)
-    if t > n_dec:
-        _, ts, tp, tv, r = prefill_tiles
-        sub = min(cfg.sub_chunk, r)
-        if r % sub:
-            raise ValueError(f"kimi_linear: a prefill tile of {r} rows is no "
-                             f"multiple of the sub-chunk ({sub})")
-        n_i = ts.shape[0]
-        real = ts != scratch
-        rows = ts + slot0
-        fresh = tp == 0
-        # tile i goes on where tile i - 1 of the same slot ended
-        cont = jnp.concatenate([jnp.zeros((1,), bool),
-                                (ts[1:] == ts[:-1]) & real[1:]])
-        write = real & ~jnp.concatenate([cont[1:], jnp.zeros((1,), bool)])
-        # a tile that is not its slot's last of the step, and a padding
-        # tile, write the scratch slot, and write it zeros
-        rows_w = jnp.where(write, rows, slot0 + scratch)
-        win, conv = tile_windows(conv, rows, rows_w,
-                                 qkv[n_dec:].reshape(n_i, r, -1), cont, fresh,
-                                 write, tv)
-        qt, kt, vt = _qkv_split(cfg, _conv(cfg, win, lp["conv_w"], r))
-        valid = (jnp.arange(r)[None, :] < tv[:, None])[..., None]
-        gt = jnp.where(valid[..., None],
-                       g[n_dec:].reshape((n_i, r) + g.shape[1:]), 0.0)
-        bt = jnp.where(valid, beta[n_dec:].reshape(n_i, r, -1), 0.0)
-        # a head's channels are a lane block of the rows as they lie: the
-        # chunk form reads them, and the slots' states in the leaf, in place
-        kda, y = kda_chunk(
-            kda, rows, rows_w, fresh, cont, write,
-            *(x.reshape(n_i, r, -1) for x in (qt, kt, gt, vt)), bt, sub)
-        ys.append(y.reshape(n_i * r, -1))
-    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
-    return _kda_out(cfg, y, gate, lp), {"kda": kda, "conv": conv}
 
 
 def ragged_forward(cfg: KimiLinearConfig, params, tokens, slots, positions,
@@ -679,7 +466,7 @@ def ragged_forward(cfg: KimiLinearConfig, params, tokens, slots, positions,
     from deepspeed_tpu.models.paged import SLOTS, scan_layers_paged
     from deepspeed_tpu.ops.quantizer import dequantize_layer
 
-    lead, period, _, tail = _plan(cfg.layer_pattern)
+    lead, period, _, tail = stack_plan_tail(cfg.layer_pattern)
     scratch = cache[SLOTS]["kda"].shape[1] - 1
     stacks, stacked = [], []
     for kind, tree in zip(period, params["period"]):
@@ -693,8 +480,8 @@ def ragged_forward(cfg: KimiLinearConfig, params, tokens, slots, positions,
             lp = _layer_weights(lp, partial(dequantize_layer, dtype=x.dtype))
             h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps)
             if kind in "DK":
-                o, state = _kda_ragged(cfg, h, lp["mix"], pool[SLOTS], address,
-                                       scratch, slots, positions, prefill_tiles)
+                o, state = kda.ragged(cfg, h, lp["mix"], pool[SLOTS], address,
+                                      scratch, slots, positions, prefill_tiles)
                 pool = {**pool, SLOTS: state}
             else:
                 o, kv = _pool_attention(cfg, h, lp["mix"], {"kv": pool["kv"]},
@@ -786,5 +573,5 @@ def build(cfg: KimiLinearConfig, ctx: ShardCtx | None = None) -> ModelSpec:
         moe_form=partial(expert_form, num_experts=cfg.num_experts,
                          top_k=cfg.top_k),
         decode_bucket_min=DECODE_BUCKET_MIN,
-        state_kind=STATE_KIND,
+        state_kind=kda.STATE_KIND,
     )

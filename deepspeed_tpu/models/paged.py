@@ -2,8 +2,8 @@
 
 One home for what every family's serving path shares (gpt2, llama, mixtral
 on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
-nemotron_h, granite_hybrid, jamba and kimi_linear with a recurrent state a
-slot beside either;
+nemotron_h, granite_hybrid, jamba, kimi_linear and solar_open2 with a
+recurrent state a slot beside either;
 smallthinker with sliding-window layers in a second pool): the
 paged-pool write, the decode/tiled-prefill attention split over the block
 pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
@@ -47,7 +47,8 @@ v1-style engines.
   whose state a key channel decays by its own factor and is fed by the
   delta rule) keeps it beside the block leaves, K and V heads or a latent
   row alike (``kimi_linear``: ``{"kv": [L_mla, NB, BS, 640], "slots":
-  {...}}``), under the cache's ``"slots"`` key: leaves ``[L_s, S, ...]``,
+  {...}}``; ``solar_open2``, the same KDA state beside ``{"k", "v"}``), under
+  the cache's ``"slots"`` key: leaves ``[L_s, S, ...]``,
   one row a layer and engine slot, ``S = max_seqs + 1``; the last
   row is the scratch slot padding rows use, as block 0 is for the pool.
   ``block_leaves`` / ``slot_leaves`` tell the two apart. The engine owns
@@ -160,7 +161,7 @@ v1-style engines.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -317,6 +318,29 @@ def stack_plan(pattern: str) -> tuple[str, str, int]:
             "repeated period: the layer scan cannot express it")
     lead, period, repeats = best
     return pattern[:lead], pattern[lead:lead + period], repeats
+
+
+@lru_cache(maxsize=None)
+def stack_plan_tail(pattern: str) -> tuple[str, str, int, str]:
+    """``(lead, period, repeats, tail)`` with ``pattern == lead + period *
+    repeats + tail``, ``stack_plan`` of what comes before the tail, and
+    ``lead + period + tail`` (the layers a step program compiles) the least:
+    what ``_scan_periods`` runs for a published order that ends off its
+    period."""
+    best = None
+    for t in range(len(pattern)):
+        try:
+            plan = (*stack_plan(pattern[:len(pattern) - t]),
+                    pattern[len(pattern) - t:])
+        except NotImplementedError:
+            continue
+        if best is None or len(plan[0] + plan[1] + plan[3]) \
+                < len(best[0] + best[1] + best[3]):
+            best = plan
+    if best is None:
+        raise NotImplementedError(
+            f"layer pattern {pattern!r} has no repeated period")
+    return best
 
 
 def scan_layers_paged(layer_fn, x, layers, pool, block_tables, lead=(),
@@ -698,6 +722,29 @@ def ragged_pool_attention(q, kc, vc, slots, positions, block_tables,
                                           window=window, **more),
         lambda q, ts, tp, tv, ct: ragged_prefill_attention(
             q, kc, vc, ts, tp, tv, block_tables, ct, window=window, **more))
+
+
+def nope_attention_ragged(cfg, h, lp, pool, layer_tables, slots, positions,
+                          prefill_tiles, gate=None):
+    """A grouped-query attention layer WITHOUT positions over the normed rows
+    ``h`` [T, D] of a flat ragged token batch (``granite_hybrid``'s, ``jamba``'s
+    and ``solar_open2``'s): ``cfg.num_heads`` query heads (times
+    ``cfg.q_scale``) on ``cfg.num_kv_heads`` K/V heads, this layer's rows
+    written to ``pool["k"]`` / ``pool["v"]`` through its table and read back by
+    the two paged kernels, then ``W_o``. ``gate`` [T, heads x head_dim]
+    multiplies the heads' output before ``W_o`` (a gated attention layer's
+    sigmoid, ``solar_open2``). Returns ``(out [T, D], pool)``."""
+    q = rows_to_heads(h, lp["wq"], cfg.num_heads) * cfg.q_scale
+    kk = rows_to_heads(h, lp["wk"], cfg.num_kv_heads)
+    vv = rows_to_heads(h, lp["wv"], cfg.num_kv_heads)
+    kc, vc = write_kv_paged(pool["k"], pool["v"], kk, vv, slots, positions,
+                            layer_tables, prefill_tiles)
+    o = ragged_pool_attention(q, kc, vc, slots, positions, layer_tables,
+                              prefill_tiles).astype(h.dtype)
+    o = o.reshape(h.shape[0], -1)
+    if gate is not None:
+        o = o * gate
+    return o @ lp["wo"], {**pool, "k": kc, "v": vc}
 
 
 def latent_queries(q_nope, wk, q_rope, width: int, prefill_tiles=None):

@@ -242,7 +242,9 @@ DECODE_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, 32, 4, 513),
                 "mixtral.chat": (32, 8, 128, 128, 8, 16, 1025),
                 "mixtral.longdoc": (32, 8, 128, 128, 64, 16, 1537),
                 # 20 query heads on ONE K/V head: a pool row is one lane tile
-                "jamba.reason": (20, 1, 128, 128, 32, 256, 8193)}
+                "jamba.reason": (20, 1, 128, 128, 32, 256, 8193),
+                # eight query heads a K/V head, a table of 8K tokens
+                "solar.longctx": (64, 8, 128, 128, 64, 16, 1025)}
 
 
 @pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
@@ -360,30 +362,40 @@ def _kda_decode(state, rows, a, k, q, v, beta):
                       interpret=False)
 
 
-def _kda_args(devices, rows=128):
-    """The KDA state of the Kimi-Linear cell: 10 layers x 129 slots of [128,
-    4096] float32 (32 heads of 128 x 128), a decode bucket of ``rows``."""
+# the KDA state of the two cells that have one: (layers x slots, heads, the
+# decode bucket): Kimi-Linear's 10 layers x 129 slots of [128, 4096] float32
+# (32 heads of 128 x 128, 2 MB a row), Solar-Open2's 3 x 17 of [128, 8192]
+# (64 heads, 4 MB a row: 16 MB double-buffered each way, [128, 64] operands)
+KDA_CELLS = {"kimi-linear": (10 * 129, 32, 128), "solar-open2": (3 * 17, 64, 16)}
+
+
+def _kda_args(devices, rows=None, cell="kimi-linear"):
+    """A cell's KDA state and a decode bucket of ``rows`` (the cell's own)."""
     dev = jax.sharding.SingleDeviceSharding(devices[0])
+    n, heads, bucket = KDA_CELLS[cell]
+    rows, hv = rows or bucket, heads * 128
 
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
-    return (s((10 * 129, 128, 4096)), s((rows,), jnp.int32),
-            s((rows, 128, 32)), s((rows, 128, 32)), s((rows, 128, 32)),
-            s((rows, 4096)), s((rows, 4096)))
+    return (s((n, 128, hv)), s((rows,), jnp.int32),
+            s((rows, 128, heads)), s((rows, 128, heads)),
+            s((rows, 128, heads)), s((rows, hv)), s((rows, hv)))
 
 
-def test_kda_decode_compiles_for_v5e_and_updates_in_place(v5e):
-    """A row's whole state (2 MB) a grid step, in and out double-buffered, a
-    head's [128, 1] columns broadcast over its lanes; the donated state is the
-    output (aliased), and nothing else in the program is as large as ONE
-    row's state."""
+@pytest.mark.parametrize("cell", sorted(KDA_CELLS))
+def test_kda_decode_compiles_for_v5e_and_updates_in_place(v5e, cell):
+    """A row's whole state (2 MB, 4 MB at 64 heads) a grid step, in and out
+    double-buffered, a head's [128, 1] columns broadcast over its lanes; the
+    donated state is the output (aliased), and nothing else in the program
+    is as large as ONE row's state."""
+    n, heads, _ = KDA_CELLS[cell]
     compiled = jax.jit(_kda_decode, donate_argnums=(0,)).lower(
-        *_kda_args(v5e)).compile()
+        *_kda_args(v5e, cell=cell)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 10 * 129 * 128 * 4096 * 4
-    assert mem.temp_size_in_bytes < 128 * 4096 * 4
+    assert mem.alias_size_in_bytes >= n * 128 * heads * 128 * 4
+    assert mem.temp_size_in_bytes < 128 * heads * 128 * 4
 
 
 def _kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta):
@@ -393,34 +405,40 @@ def _kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta):
                      beta, 16, impl="pallas", interpret=False)
 
 
-def _kda_chunk_args(devices, tiles=3):
+def _kda_chunk_args(devices, tiles=3, cell="kimi-linear"):
     """The same state, and a mixed step's ``tiles`` prefill tiles of 128
-    rows: 32 heads of 128 channels side by side on the lanes."""
+    rows: the heads' 128 channels side by side on the lanes."""
     dev = jax.sharding.SingleDeviceSharding(devices[0])
+    n, heads, _ = KDA_CELLS[cell]
+    hv = heads * 128
 
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
-    return (s((10 * 129, 128, 4096)), *(s((tiles,), jnp.int32),) * 2,
-            *(s((tiles,), jnp.bool_),) * 3, *(s((tiles, 128, 4096)),) * 4,
-            s((tiles, 128, 32)))
+    return (s((n, 128, hv)), *(s((tiles,), jnp.int32),) * 2,
+            *(s((tiles,), jnp.bool_),) * 3, *(s((tiles, 128, hv)),) * 4,
+            s((tiles, 128, heads)))
 
 
-def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e):
-    """A head and a tile a grid step: float32 products at
-    ``Precision.HIGHEST``, a transposed product, lane sums of ``[16, 16,
-    128]`` blocks and a sub-chunk loop in Mosaic; the donated state is the
-    output (aliased), nothing else in the program is as large as ONE row's
-    state, and the tiles' operands reach the kernel as they are handed in
-    (no transpose to a head-major layout, no copy)."""
+@pytest.mark.parametrize("cell,tiles", [("kimi-linear", 3), ("solar-open2", 4)])
+def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e, cell, tiles):
+    """A head and a tile a grid step (64 heads x 4 tiles in the Solar cell's
+    widest step): float32 products at ``Precision.HIGHEST``, a transposed
+    product, lane sums of ``[16, 16, 128]`` blocks and a sub-chunk loop in
+    Mosaic; the donated state is the output (aliased), nothing else in the
+    program is as large as ONE row's state, and the tiles' operands reach
+    the kernel as they are handed in (no transpose to a head-major layout,
+    no copy)."""
+    n, heads, _ = KDA_CELLS[cell]
     compiled = jax.jit(_kda_chunk, donate_argnums=(0,)).lower(
-        *_kda_chunk_args(v5e)).compile()
+        *_kda_chunk_args(v5e, tiles=tiles, cell=cell)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
-    assert not re.search(r"= f32\[3,128,4096\]\S* (copy|transpose)\(", text)
+    assert not re.search(
+        rf"= f32\[{tiles},128,{heads * 128}\]\S* (copy|transpose)\(", text)
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 10 * 129 * 128 * 4096 * 4
-    assert mem.temp_size_in_bytes < 128 * 4096 * 4
+    assert mem.alias_size_in_bytes >= n * 128 * heads * 128 * 4
+    assert mem.temp_size_in_bytes < 128 * heads * 128 * 4
 
 
 def _selscan_decode(state, rows, fresh, dt, x, a, b, c):
@@ -534,13 +552,6 @@ def test_kernel_instruction_goes_by_its_name(v5e, kernel, name):
         text = _compiled(kernel, *_args(kernel, *GEOMETRIES[0], v5e)).as_text()
     calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
-    if name == "kda_chunk":
-        # no file of its own: the benchmark reads it off ``device_ops`` by
-        # name, and no other kernel's pattern may take it for its own
-        assert len(calls) == 1 and calls[0].startswith("%kda_chunk.")
-        assert [k for k, rx in patterns.items() if rx.search(calls[0])] == [
-            "pallas_custom_call"]
-        return
     mine = [ln for ln in calls if patterns[name].search(ln)]
     assert mine and all(name in ln.split(" = ")[0] for ln in mine)
     if kernel in (_mla_decode, _dsa_walk):
@@ -583,7 +594,9 @@ PREFILL_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, None, 32, 513, 8),
                  "nemotron.reason": (32, 2, 128, 128, None, 32, 4097, 4),
                  "smallthinker.full": (28, 4, 128, 128, None, 64, 641, 4),
                  "smallthinker.window": (28, 4, 128, 128, 4096, 64, 529, 4),
-                 "jamba.reason": (20, 1, 128, 128, None, 32, 8193, 4)}
+                 "jamba.reason": (20, 1, 128, 128, None, 32, 8193, 4),
+                 # a tile's query block is [8, 1024, 128]
+                 "solar.longctx": (64, 8, 128, 128, None, 64, 1025, 4)}
 
 
 @pytest.mark.parametrize("cell,tiles", [(c, 3) for c in sorted(PREFILL_CELLS)]
@@ -1032,8 +1045,20 @@ def _relayout_family(name):
         granite_hybrid,
         jamba,
         smallthinker,
+        solar_open2,
     )
 
+    if name == "solar":
+        # solar-open2-250b-d4-ep8.json's two mixers, shared expert and pool at
+        # their published widths, TWO periods so that the G layer's weights
+        # are a stack too (the cell's one period keeps them as ``lead``); 4 of
+        # 8 experts, a 48th of the held table. Not ``w_fa`` / ``w_ga``:
+        # [4096, 128] has a tile's rows x the hidden size's elements
+        return solar_open2, solar_open2.SolarOpen2Config(
+            vocab_size=512, num_layers=8, gqa_layers=(0, 4), num_experts=8,
+            experts_held=4, top_k=2), 1025, 17, 64, (
+                "wq", "wk", "wv", "w_g", "wo", "w_qkv", "w_fb", "w_gb",
+                "router", "ws_gate", "ws_up", "ws_down"), ()
     if name == "jamba":
         # ai21-jamba2-3b.json's mixers, MLP and pool at their published
         # widths: m a m m (runs of 1, 1 and 2); a 128th of the table
@@ -1109,7 +1134,7 @@ def _step_text(v5e, mod, cfg, blocks, slots, table, rows, tiles):
 @pytest.mark.parametrize("family,rows,tiles", [
     ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
     ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0),
-    ("jamba", 256, 3), ("jamba", 256, 0)])
+    ("jamba", 256, 3), ("jamba", 256, 0), ("solar", 16, 3), ("solar", 16, 0)])
 def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
                                                       rows, tiles):
     """The paged contract's *Rows to heads* (``models/paged.py``), on the
@@ -1216,6 +1241,57 @@ def test_mamba2_step_makes_the_in_projection_once(v5e, monkeypatch, cell,
         assert len(lines) == 1 and f" = bf16[{t},{sum(parts)}]" in lines[0], \
             lines
     assert _projection_results(text, params, ("w_in",)) == []
+
+
+@pytest.mark.parametrize("rows,tiles", [(16, 3), (0, 4), (16, 0)],
+                         ids=["mixed-d16-t3", "prefill-t4", "decode-d16"])
+def test_solar_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
+        v5e, monkeypatch, rows, tiles):
+    """The step programs of ``solar-open2-250b-d4-ep8.longctx-pool`` at the
+    cell's OWN sizes (``benchmark/cellspec.py``: 7.38 GB of arguments; a lead
+    ``G`` and a scan over three ``K``, two layer bodies), compiled before the
+    first chip call as ROADMAP Queue R asks: the four kernels are there at
+    their new geometries (``kda_decode`` on 4 MB rows, ``kda_chunk`` on a
+    grid of 64 heads x tiles, the paged kernels on 64 query heads over 8 K/V
+    heads) and the grouped expert kernel in both bodies of a step of 256
+    rows or more; nothing the size of a layer's slice of the float32 state
+    (``[17, 128, 8192]``, 71 MB) or of the K/V pool gets a buffer of its own
+    but in place, the compiler's own prefetches into its nearer memory
+    aside; the window leaf ``[3, 17, 48, 1536]`` enters row-major in whole
+    tiles and no array of its shape is copied, compressed or uncompressed;
+    no projection of a step's rows (``[rows, 8192]``, ``[rows, 24576]``) is
+    made a second time (``.remat``); no stacked projection weight is re-laid
+    out (D14; ``test_step_program_relays_out_no_projection_weight[solar-*]``
+    has the G layer's in a stack too)."""
+    text, params, cfg = _cell_step_text(
+        v5e, monkeypatch, "solar-open2-250b-d4-ep8.longctx-pool", rows, tiles)
+    assert (cfg.layer_pattern, cfg.kda_heads, cfg.held) == ("GKKK", 64, 40)
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    count = {k: sum(k in n for n in names) for k in (
+        "kda_decode", "kda_chunk", "paged_decode", "tiled_prefill", "moe_gmm")}
+    assert count == {"kda_decode": bool(rows), "kda_chunk": bool(tiles),
+                     "paged_decode": bool(rows), "tiled_prefill": bool(tiles),
+                     "moe_gmm": 2 * (rows + tiles * TILE >= 256)}
+    state_slice = 17 * 128 * 8192 * 4
+    assert [ln for size, op, ln in _materialized(text)
+            if size >= state_slice and op not in _IN_PLACE
+            and not op.endswith("-done") and "ConcatBitcast" not in ln] == []
+    entry = _computations(text)[re.search(r"ENTRY (%[\w.\-]+)", text).group(1)]
+    assert [m.group(1)[:9] for ln in entry for m in [re.search(
+        r"= bf16\[3,17,48,1536\](\{[^ ]*\}) parameter\(", ln)] if m] == [
+            "{3,2,1,0:"]
+    assert [ln for _, op, ln in _materialized(text)
+            if re.search(r"= bf16\[(3,17|51),48,1536\]", ln)
+            and op not in _IN_PLACE] == []
+    assert "remat_compressed" not in text and "remat_uncompressed" not in text
+    t = rows + tiles * TILE
+    assert [ln for ln in text.splitlines() if re.match(
+        rf"\s+(?:ROOT )?%[\w.\-]*\.remat[\w.\-]* = \w+\[{t},(8192|24576)\]",
+        ln)] == []
+    assert _projection_results(text, params, (
+        "w_qkv", "w_fb", "w_gb", "wo", "router", "ws_gate", "ws_up",
+        "ws_down")) == []
 
 
 @pytest.mark.parametrize("cell,rows,kernel", [
@@ -1649,7 +1725,7 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
     rows on the sublanes the compiler copied the whole leaf to a padded
     layout and back and, in the cell's program, compressed and uncompressed
     it between the layers: 9.4% of the cell's device time (ledger, PR 40)."""
-    from deepspeed_tpu.models import kimi_linear
+    from deepspeed_tpu.models import kimi_linear, paged
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # no interpret
     widths = {"head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4}
@@ -1658,13 +1734,13 @@ def test_step_program_holds_no_layer_slice_of_the_kda_state(v5e, monkeypatch,
         moe_intermediate_size=128, num_experts=16, experts_held=4, top_k=6,
         linear_attn_config={"kda_layers": [1, 2, 4], "full_attn_layers": [3, 5],
                             **widths})
-    assert kimi_linear._plan(cfg.layer_pattern) == ("D", "KM", 2, "")
+    assert paged.stack_plan_tail(cfg.layer_pattern) == ("D", "KM", 2, "")
     if window:
         cfg = dataclasses.replace(
             cfg, hidden_size=512, num_layers=13, linear_attn_config={
                 "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13],
                 "full_attn_layers": [4, 8, 12], **widths})
-    bodies = "".join(kimi_linear._plan(cfg.layer_pattern)[i] for i in (0, 1, 3))
+    bodies = "".join(paged.stack_plan_tail(cfg.layer_pattern)[i] for i in (0, 1, 3))
     n_kda, n_mla = bodies.count("D") + bodies.count("K"), bodies.count("M")
     blocks, block, table, slots = 4097, 128, 32, 129
     dev = jax.sharding.SingleDeviceSharding(v5e[0])

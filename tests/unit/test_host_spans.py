@@ -394,7 +394,14 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "kernel.selscan_decode_share",
                          "kernel.selscan_decode_roofline",
                          "kernel.selscan_tile_share",
-                         "kernel.selscan_tile_roofline")}}
+                         "kernel.selscan_tile_roofline")},
+                     # PR 57: the chunk kernel's readers read a slice of the
+                     # Solar-Open2 cell: its spans say ``chunk_slots`` beside
+                     # ``chunk_tiles``, its steps run ``kda_chunk`` on a grid
+                     # of 64 heads x tiles in three layers
+                     **{m: "v5e_solar_longctx_spans" for m in (
+                         "kernel.kda_chunk_share",
+                         "kernel.kda_chunk_roofline")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -413,7 +420,9 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "smallthinker-21b-a3b-ep8.mixedlen-pool",
                    "v5e_granite_chat_spans":
                    "granite-4.0-h-small-d10-ep2.chat-open",
-                   "v5e_jamba_reason_spans": "ai21-jamba2-3b.reason-pool"}
+                   "v5e_jamba_reason_spans": "ai21-jamba2-3b.reason-pool",
+                   "v5e_solar_longctx_spans":
+                   "solar-open2-250b-d4-ep8.longctx-pool"}
 
 
 def _new_readers():

@@ -182,7 +182,7 @@ def test_plain_forward_is_the_reference(pattern):
     ``KKMK`` + ``M``), and a leading layer that is MLA + dense."""
     cfg = kimi_linear.KimiLinearConfig.tiny(pattern=pattern)
     p = kimi_linear.init_params(cfg, jax.random.PRNGKey(3))
-    lead, period, repeats, tail = kimi_linear._plan(cfg.layer_pattern)
+    lead, period, repeats, tail = paged.stack_plan_tail(cfg.layer_pattern)
     assert lead + period * repeats + tail == pattern
     assert (len(p["lead"]), len(p["period"]), len(p["tail"])) == (
         len(lead), len(period), len(tail))
@@ -205,7 +205,7 @@ def test_a_tail_after_the_scan_is_served(params):
     """``D`` + 2 x ``KKMK`` + ``M``: the last MLA layer runs after the scan, at
     block layer 2 of 3 (``paged._scan_periods``' ``tail``)."""
     cfg = kimi_linear.KimiLinearConfig.tiny(pattern="DKKMKKKMKM")
-    assert kimi_linear._plan(cfg.layer_pattern) == ("D", "KKMK", 2, "M")
+    assert paged.stack_plan_tail(cfg.layer_pattern) == ("D", "KKMK", 2, "M")
     p = kimi_linear.init_params(cfg, jax.random.PRNGKey(4))
     eng = _engine(p, cfg=cfg, max_tokens_per_step=16, max_seqs=2,
                   num_blocks=17, max_blocks_per_seq=4)
@@ -220,7 +220,7 @@ def test_a_tail_after_the_scan_is_served(params):
 def test_the_published_order_has_a_plan():
     full = kimi_linear.KimiLinearConfig()
     assert full.layer_pattern == "D" + "KKMK" * 5 + "KKMKKM"
-    lead, period, repeats, tail = kimi_linear._plan(full.layer_pattern)
+    lead, period, repeats, tail = paged.stack_plan_tail(full.layer_pattern)
     assert len(lead + period + tail) == 7 and repeats == 6
     # 49.12 B whole (published: 48B); the benchmark's cut is in its own test
     assert REF.num_params(full) == kimi_linear.num_params(full) == 49_122_681_728
