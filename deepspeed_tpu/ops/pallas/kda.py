@@ -38,27 +38,61 @@ what runs off the chip and what ``chip_smoke.py`` times the kernel against
 (PERF.md section 6, PR 40).
 
 ``kda_chunk`` runs the recurrence's CHUNK form (below) over a step's prefill
-tiles, a head and a tile a grid step, the tile axis the inner, sequential one. ``q`` / ``k`` / ``g`` /
-``v`` come as the model has them, ``[tiles, R, H x K]``: a head is a block of
-128 lanes, and so it is of the state leaf, so nothing is transposed on the
-way in or out. By scalar prefetch a tile names the leaf row its slot's state
-lies in, the row the state it ends with goes to, and whether it starts from
-zeros (position 0), goes on where the tile before it ended (the state stays
-in VMEM scratch between the tiles of a slot) and writes (a tile that is not
-its slot's last of the step, and a padding tile, write the scratch slot
-zeros). The output aliases the leaf: tile ``i + 1``'s row is fetched while
-tile ``i`` still computes, which is safe because a continued tile ignores
-what it fetched and only the scratch slot's row is written twice. Inside, a
-head and tile at a time (everything it needs is under 1 MB): the cumulative
-log-decays as sums of picked rows on the MXU; ``beta (V - (K e^G) S0)`` and
-``(Q e^G) S0`` against the state; then a loop over the sub-chunks of 16 rows,
-each its pairwise block ``[16, 16, K]`` (later row first, every exponent <=
-0) summed over the lanes, its rows' products with the tile's earlier rows
-decayed through the sub-chunk's first row, and the unit lower triangular
-solve by forward substitution (over the sub-chunks, and a column a step
-inside one); last the state's update. float32, every product of two float32
-operands at ``Precision.HIGHEST``. It is a kernel because XLA's form of the
-same algebra (``kda_chunk_xla``: ``kda_tiles`` between a dynamic slice and a
+tiles, four heads and a tile a grid step where four divide the head count
+(two where two do, else one), the tile axis the inner, sequential one. ``q`` /
+``k`` / ``g`` / ``v`` come as the model has them, ``[tiles, R, H x K]``: a
+head is a block of 128 lanes, and so it is of the state leaf, so nothing is
+transposed on the way in or out. By scalar prefetch a tile names the leaf row
+its slot's state lies in, the row the state it ends with goes to, and whether
+it starts from zeros (position 0), goes on where the tile before it ended (the
+state stays in VMEM scratch between the tiles of a slot) and writes (a tile
+that is not its slot's last of the step, and a padding tile, write the scratch
+slot zeros). The output aliases the leaf: tile ``i + 1``'s row is fetched
+while tile ``i`` still computes, which is safe because a continued tile
+ignores what it fetched and only the scratch slot's row is written twice.
+
+Inside, the chunk form is run a SUB-CHUNK at a time (16 rows), the state
+carried from one to the next (``kda_tiles`` with ``R`` = 16 between them), so
+that a turn of the loop costs what is new to its 16 rows (PERF.md section 6,
+PR 58: until then a turn rebuilt, split and pushed two ``[R, 128]`` operands
+of the whole tile for 32 streamed rows, two thirds of the kernel's schedule).
+Once a tile: a row's cumulative log-decay INSIDE its sub-chunk (``loc`` <= 0)
+as sums of picked rows on the MXU, and a head's column of ``beta``. Then a
+turn, with ``S`` the state at the sub-chunk's first row and ``tot`` its last
+row's ``loc``:
+
+    read = [beta K e^loc; Q e^loc] S            # [32, K] x [K, V]
+    (I + A) U = beta V - read_k,  Y = read_q + B U
+    S' = diag(e^tot) S + (K e^(tot - loc))^T U  # [K, 16] x [16, V]
+
+``A`` / ``B`` are the sub-chunk's pairwise block, ``[d, c, K]`` with the later
+row ``c`` first, summed over the lanes, in two bands of 8 rows of ``d`` (the
+pairs of the second band's ``d`` with the first band's ``c`` are never made);
+the unit lower triangular solve is forward substitution, a column a step, the
+heads of the grid step side by side on the lanes: ONE chain of dependent steps
+for all of them. **Every exponent is still a difference with the later row
+first, at most 0**: ``loc`` (a row from its sub-chunk's first), ``loc_c -
+loc_d`` (``c`` after ``d``), ``tot - loc`` (a row to its sub-chunk's last) and
+``tot`` (the whole sub-chunk). The state is only ever MULTIPLIED by ``e^tot``
+<= 1; nothing is divided by a decay, so ``exp(-G)`` never appears.
+
+float32 throughout, and every product of two float32 operands is the six
+partial products ``Precision.HIGHEST`` makes of their bfloat16 parts (``x =
+x1 + x2 + x3`` to the last bit: ``x1 w1 + x1 w2 + x2 w1 + x1 w3 + x2 w2 + x3
+w1``, accumulated in float32), written out, because of what the compiler's own
+form costs here: it pushes the stationary operand six times as float32
+registers (96 pushes for ``S`` a turn). Written out, a part of ``S`` is pushed
+ONCE, as 8 packed registers, under all the rows' parts it meets; and the
+update, whose contraction is the sub-chunk's 16 rows, lays its six products
+SIDE BY SIDE along one pass's contraction (6 x 16 <= 128: ``[k1 k1 k2 k1 k2
+k3]^T [u1; u2; u1; u3; u2; u1]``), so the MXU sums them in float32 and ``S'``
+comes off in 16 pops, not 96. The keys' parts turn from rows to columns on the
+MXU (an identity against them: exact), and the decay's three parts ride the
+same product, against three rows of ones, which spreads ``e^tot`` (channels on
+the lanes) over the state's lanes as a column (channels on the sublanes) with
+no work for the XLU, whose lane sums of the pairwise block are what bounds a
+turn now. It is a kernel because XLA's form of the same algebra
+(``kda_chunk_xla``: ``kda_tiles`` between a dynamic slice and a
 dynamic-update-slice a tile) is ~60 operations a layer around 0.4 ms of
 products: transposes to a head-major layout and back, a scan whose carry is
 materialised each step (9-11 ms of a 45 ms mixed step at Kimi-Linear's ten
@@ -102,6 +136,8 @@ from deepspeed_tpu.ops.pallas.flash_attention import interpret_mode
 # a row's state in and out, double-buffered, is 8 MB at 128 x 4,096 float32
 _VMEM_LIMIT_BYTES = 48 * 2**20
 _HIGHEST = jax.lax.Precision.HIGHEST
+# the heads ``kda_chunk`` takes a grid step: the most of these the count divides
+_HEADS_A_STEP = (4, 2, 1)
 
 
 def _kernel(rows_ref, s_ref, a_ref, k_ref, q_ref, v_ref, b_ref, o_ref, y_ref,
@@ -215,127 +251,195 @@ def _on_chip(impl: str) -> bool:
                                 and jax.default_backend() == "tpu")
 
 
+@jax.jit
+def _parts(x):
+    """``x`` float32 -> its three bfloat16 parts, largest first: their sum is
+    ``x`` to the last bit (a part is the 8 leading bits of what the parts
+    before it left, cut and not rounded, so what is left stays a float32)."""
+    out = []
+    for _ in range(2):
+        top = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, jnp.uint32)
+            & jnp.uint32(0xFFFF0000), jnp.float32)
+        out.append(top.astype(jnp.bfloat16))
+        x = x - top
+    return out + [x.astype(jnp.bfloat16)]
+
+
 def _sum_rows(picks, x):
     """``picks`` [M, R] bool, ``x`` [R, N] float32 -> ``picks @ x`` as float32
     sums, in three single bfloat16 passes: 0 / 1 is exact in bfloat16 and
-    ``x`` is the sum of three bfloat16 parts."""
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    picks = picks.astype(f32).astype(bf16)
-    out = None
-    for _ in range(3):
-        part = x.astype(bf16)
-        x = x - part.astype(f32)
-        term = jnp.dot(picks, part, preferred_element_type=f32)
-        out = term if out is None else out + term
-    return out
+    ``x`` is the sum of its three bfloat16 parts."""
+    picks = picks.astype(jnp.float32).astype(jnp.bfloat16)
+    x1, x2, x3 = (jnp.dot(picks, part, preferred_element_type=jnp.float32)
+                  for part in _parts(x))
+    return x1 + x2 + x3
 
 
 def _chunk_kernel(rows_ref, rows_w_ref, fresh_ref, cont_ref, write_ref,
                   s_ref, q_ref, k_ref, g_ref, v_ref, b_ref, o_ref, y_ref,
-                  carry_ref, loc_ref, base_ref, rhs_ref, u_ref, *, sub: int):
+                  carry_ref, loc_ref, beta_ref, *, sub: int, heads: int):
     del rows_ref, rows_w_ref  # the state's index maps read them
-    f32 = jnp.float32
-    h, i = pl.program_id(0), pl.program_id(1)
-    r, kd = q_ref.shape[1:]
-    vd = v_ref.shape[2]
-    dot = functools.partial(jax.lax.dot_general, precision=_HIGHEST,
-                            preferred_element_type=f32)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    i = pl.program_id(1)
+    r = q_ref.shape[1]
+    kd = s_ref.shape[1]
+    vd = s_ref.shape[2] // heads
+    n_sub = r // sub
+    mm = functools.partial(jnp.dot, preferred_element_type=f32)
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32)
+    # the rows of a sub-chunk in bands of 8 where that is half of it: the
+    # pairs of a later band's ``d`` with an earlier band's ``c`` are not made
+    bands = [(0, sub)] if sub % 16 else [(0, sub // 2), (sub // 2, sub)]
+    # the update's operands: six blocks of the sub-chunk's rows side by side,
+    # the decay's three parts after them, up to whole lane tiles
+    room = -(-3 // sub) * sub
+    tall = -(-(6 * sub + room) // 128) * 128
+    first = sub * iota((n_sub, sub, r), 0).reshape(r, r)  # of a row's sub-chunk
+    inside = (iota((r, r), 1) >= first) & (iota((r, r), 1) <= iota((r, r), 0))
 
-    def mm(a, b):  # [M, C] x [C, N]
-        return dot(a, b, (((1,), (0,)), ((), ())))
+    for j in range(heads):
+        # what a head of the grid step needs of the whole tile, once: the
+        # state it starts from, its column of beta, and a row's cumulative
+        # log-decay inside its sub-chunk (<= 0) as sums of picked rows
+        h = pl.program_id(0) * heads + j
+        lv = slice(j * vd, (j + 1) * vd)
+        carry_ref[:, lv] = jnp.where(
+            cont_ref[i] > 0, carry_ref[:, lv],
+            jnp.where(fresh_ref[i] > 0, 0.0, s_ref[0, :, lv]))
+        mine = iota(b_ref.shape[1:], 1) == h
+        beta_ref[:, j * kd:(j + 1) * kd] = jnp.broadcast_to(
+            jnp.sum(jnp.where(mine, b_ref[0], 0.0), axis=1, keepdims=True),
+            (r, kd))
+        loc_ref[:, j * kd:(j + 1) * kd] = _sum_rows(
+            inside, g_ref[0, :, j * kd:(j + 1) * kd])
 
-    def head_column(b):  # [.., H] -> head h's [.., 1]
-        lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
-        return jnp.sum(jnp.where(lane == h, b, 0.0), axis=1, keepdims=True)
+    def over_heads(cols):
+        """The heads' [rows, 1] columns, each over its own head's lanes."""
+        return jnp.concatenate(
+            [jnp.broadcast_to(c, (c.shape[0], vd)) for c in cols], axis=1)
 
-    prev = jnp.where(cont_ref[i] > 0, carry_ref[...],
-                     jnp.where(fresh_ref[i] > 0, 0.0, s_ref[0]))
-    # cumulative log-decays: a row's sum inside its sub-chunk (``loc``, <= 0)
-    # and of the sub-chunks before it (``base``), as sums of picked rows
-    row = jax.lax.broadcasted_iota(jnp.int32, (r, r), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (r, r), 1)
-    first = sub * jax.lax.broadcasted_iota(        # of the row's sub-chunk
-        jnp.int32, (r // sub, sub, r), 0).reshape(r, r)
-    sums = _sum_rows(jnp.concatenate([(col >= first) & (col <= row),
-                                      col < first]), g_ref[0])
-    loc, base = sums[:r], sums[r:]
-    loc_ref[...] = loc
-    base_ref[...] = base
-    decay = jnp.exp(base + loc)
-    # against the state the tile starts from: beta (V - (K e^G) S0), (Q e^G) S0
-    read = mm(jnp.concatenate([k_ref[0] * decay, q_ref[0] * decay]),
-              prev)                                                # [2R, V]
-    rhs_ref[...] = head_column(b_ref[0]) * (v_ref[0] - read[:r])
-    y_ref[0] = read[r:]
-    u_ref[...] = jnp.zeros_like(u_ref)
-    later = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub, kd), 1)
-             - jax.lax.broadcasted_iota(jnp.int32, (sub, sub, kd), 0))
-    row1 = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+    def pairwise(loc_n, k_n, kb_n, q_n):
+        """Inside the sub-chunk: pairwise differences, later row ``c`` first
+        (every exponent <= 0), as [d, c, K] a band of ``d``; the sum over K
+        leaves pair (c, d) on row c of block d, what multiplies row d of U:
+        a band's columns of ``A``, then its columns of ``B``, [C - d0, 1]
+        each."""
+        out = []
+        for d0, d1 in bands:
+            shape = (d1 - d0, sub - d0, kd)
+            later = iota(shape, 1) - iota(shape, 0)
+            kp = k_n[d0:d1][:, None] * jnp.exp(jnp.where(
+                later >= 0, loc_n[d0:][None] - loc_n[d0:d1][:, None], -jnp.inf))
+            out.append(list(jnp.sum(jnp.concatenate(
+                [jnp.where(later > 0, kb_n[d0:][None] * kp, 0.0),
+                 q_n[d0:][None] * kp]), axis=-1, keepdims=True)))
+        return out
 
-    def ahead(n):
-        """What sub-chunk ``n`` needs that no earlier sub-chunk's ``U`` feeds:
-        its pairwise block's sums and its rows' products with the tile's
-        earlier rows."""
-        at = pl.multiple_of(n * sub, sub)
-        rows = pl.ds(at, sub)
-        loc_n, k_n, q_n = loc_ref[rows, :], k_ref[0, rows, :], q_ref[0, rows, :]
-        beta_n = head_column(b_ref[0, rows, :])                    # [C, 1]
-        # inside the sub-chunk: pairwise differences, later row ``c`` first,
-        # as [d, c, K]; the sum over K leaves pair (c, d) on row c of block
-        # d: what multiplies row d of U in ``solve``
-        e = jnp.exp(jnp.where(later >= 0, loc_n[None] - loc_n[:, None],
-                              -jnp.inf))
-        kp = k_n[:, None] * e
-        pairs = jnp.sum(jnp.concatenate(
-            [jnp.where(later > 0, (beta_n * k_n)[None] * kp, 0.0),
-             q_n[None] * kp]), axis=-1, keepdims=True)             # [2C, C, 1]
-        # between sub-chunks: rows decayed from the sub-chunk's first row,
-        # columns (the tile's earlier rows) decayed up to it
-        cols = k_ref[0] * jnp.exp(jnp.where(
-            row1 < at, base_ref[pl.ds(at, 1), :]
-            - (base_ref[...] + loc_ref[...]), -jnp.inf))           # [R, K]
+    def against(state, x):
+        """``x`` [2C, K] x ``state`` [K, V]: the six products of
+        ``Precision.HIGHEST`` written out, so that each part of the state is
+        pushed once, under all the rows' parts it meets."""
+        x1, x2, x3 = _parts(x)
+        s1, s2, s3 = _parts(state)
+        by1 = mm(jnp.concatenate([x1, x2, x3]), s1)
+        by2 = mm(jnp.concatenate([x1, x2]), s2)
+        m = x.shape[0]
+        return ((by1[2 * m:] + by2[m:] + mm(x1, s3))
+                + (by1[m:2 * m] + by2[:m]) + by1[:m])
+
+    def turned(k_end, decay):
+        """``k_end`` [C, K] and ``decay`` [1, K] in parts, their rows turned
+        to columns by the MXU (an identity against them: exact) -> [K, tall]:
+        the keys' parts as the update's six products meet U's, then the
+        decay's three."""
+        k1, k2, k3 = _parts(k_end)
+        e1, e2, e3 = (t.astype(f32) for t in _parts(decay))
+        nth = iota((room, kd), 0)
+        eye = (iota((kd, kd), 0) == iota((kd, kd), 1)).astype(f32).astype(bf16)
+        return jax.lax.dot_general(
+            eye, jnp.concatenate(
+                [k1, k1, k2, k1, k2, k3,
+                 jnp.where(nth == 0, e1, jnp.where(
+                     nth == 1, e2, jnp.where(nth == 2, e3, 0.0))).astype(bf16),
+                 jnp.zeros((tall - 6 * sub - room, kd), bf16)]),
+            (((1,), (1,)), ((), ())), preferred_element_type=f32).astype(bf16)
+
+    # what a turn does a head is traced once and called once a head (the
+    # kernel is traced in every step program's set-up, and a nested ``jit``
+    # is inlined when the kernel is lowered)
+    @jax.jit
+    def before(state, loc_n, k_n, q_n, beta_n):
+        """A head's turn as far as it does not wait for U: its pairwise
+        block, its rows against the state, its keys turned."""
+        kb_n = k_n * beta_n
+        tot = loc_n[sub - 1:]
         d_in = jnp.exp(loc_n)
-        off = dot(jnp.concatenate([k_n * d_in * beta_n, q_n * d_in]), cols,
-                  (((1,), (1,)), ((), ())))                        # [2C, R]
-        return off, pairs
+        return (pairwise(loc_n, k_n, kb_n, q_n),
+                against(state, jnp.concatenate([kb_n * d_in, q_n * d_in])),
+                turned(k_n * jnp.exp(tot - loc_n), jnp.exp(tot)))
 
-    def solve(n, off, pairs):
-        """(I + A) U = ... for sub-chunk ``n``'s rows, and their readings."""
-        rows = pl.ds(pl.multiple_of(n * sub, sub), sub)
-        both = mm(off, u_ref[...])                                 # [2C, V]
-        # forward substitution, a column a step; row d of U_n is final when
-        # column d is taken (a few operations a step on two registers' worth
-        # of rows, so the steps are written out)
-        u_n = rhs_ref[rows, :] - both[:sub]
-        y_n = y_ref[0, rows, :] + both[sub:]
-        for d in range(sub):
-            u_d = u_n[d:d + 1]
-            y_n = y_n + pairs[sub + d] * u_d
-            if d + 1 < sub:
-                u_n = u_n - pairs[d] * u_d
-        u_ref[rows, :] = u_n
-        y_ref[0, rows, :] = y_n
+    @functools.partial(jax.jit, static_argnames=("cuts", "last"))
+    def column(us, ys, u_d, a_d, b_d, *, cuts, last):
+        """A step of the forward substitution: row ``d`` of U, ``u_d``, is
+        final; column ``d`` of ``A`` and of ``B`` (``a_d`` / ``b_d``, a head's
+        [rows, 1] each, from ``d``'s band on) takes it to the rows after it
+        (``cuts``: the bands from ``d``'s on; ``last``: ``d`` is its band's
+        last row, whose own band has no row after it)."""
+        a_d, b_d = over_heads(a_d), over_heads(b_d)
+        ys = [y + b_d[c0:c1] * u_d for y, (c0, c1) in zip(ys, cuts)]
+        us = [u if last and n == 0 else u - a_d[c0:c1] * u_d
+              for n, (u, (c0, c1)) in enumerate(zip(us, cuts))]
+        return us, ys
 
-    # a loop, not ``R / sub`` copies of its body (the kernel is compiled in
-    # every step program that has tiles, once a KDA layer body), and in it
-    # sub-chunk ``n + 1``'s ``ahead`` beside sub-chunk ``n``'s ``solve``: the
-    # solve is a chain of short dependent steps that leaves the units idle
-    # (0.49 -> 0.43 ms a layer's call at 3 tiles, PERF.md section 6, PR 42)
+    @jax.jit
+    def after(state, keys, u_n):
+        """S' = diag(e^tot) S + (K e^(tot - loc))^T U, [K, C] x [C, V]: the
+        same six products in ONE pass, side by side along the contraction;
+        the decay's three parts against rows of ones spread its column over
+        the lanes."""
+        u1, u2, u3 = _parts(u_n)
+        ones = (iota((tall, vd), 0) // 3 == 2 * sub).astype(f32).astype(bf16)
+        return state * mm(keys, ones) + mm(keys, jnp.concatenate(
+            [u1, u2, u1, u3, u2, u1, jnp.zeros((tall - 6 * sub, vd), bf16)]))
+
+    # a loop, not ``R / sub`` copies of its body: the kernel is compiled in
+    # every step program that has tiles, once a KDA layer body
     def step(n, carry):
-        nxt = ahead(n + 1)
-        solve(n, *carry)
-        return nxt
+        rows = pl.ds(pl.multiple_of(n * sub, sub), sub)
+        states = [carry_ref[:, j * vd:(j + 1) * vd] for j in range(heads)]
+        pairs, reads, keys = zip(*(
+            before(states[j], loc_ref[rows, j * kd:(j + 1) * kd],
+                   k_ref[0, rows, j * kd:(j + 1) * kd],
+                   q_ref[0, rows, j * kd:(j + 1) * kd],
+                   beta_ref[rows, j * kd:(j + 1) * kd])
+            for j in range(heads)))
+        # (I + A) U = beta V - read_k, Y = read_q + B U by forward
+        # substitution, a column a step, the heads side by side on the lanes
+        # (ONE chain of dependent steps for all of them): row d of U is final
+        # when column d is taken
+        read = jnp.concatenate(reads, axis=1)                      # [2C, H V]
+        rhs = v_ref[0, rows, :] * over_heads(
+            [beta_ref[rows, j * kd:j * kd + 1] for j in range(heads)])
+        us = [rhs[d0:d1] - read[d0:d1] for d0, d1 in bands]
+        ys = [read[sub + d0:sub + d1] for d0, d1 in bands]
+        for at, (d0, d1) in enumerate(bands):
+            cuts = tuple((t0 - d0, t1 - d0) for t0, t1 in bands[at:])
+            for d in range(d1 - d0):
+                us[at:], ys[at:] = column(
+                    us[at:], ys[at:], us[at][d:d + 1],
+                    [p[at][d] for p in pairs],
+                    [p[at][d1 - d0 + d] for p in pairs],
+                    cuts=cuts, last=d + 1 == d1 - d0)
+        y_ref[0, rows, :] = jnp.concatenate(ys)
+        u_n = jnp.concatenate(us)
+        for j in range(heads):
+            lv = slice(j * vd, (j + 1) * vd)
+            carry_ref[:, lv] = after(states[j], keys[j], u_n[:, lv])
+        return carry
 
-    solve(r // sub - 1, *jax.lax.fori_loop(0, r // sub - 1, step, ahead(0)))
-    # the state the tile ends with: every channel decayed by the tile's whole
-    # sum (the channels are the state's sublanes: a transpose), plus the
-    # rows' feeds decayed from their row to the tile's end
-    gc = base_ref[...] + loc_ref[...]
-    k_end = k_ref[0] * jnp.exp(gc[r - 1:r] - gc)
-    total = jnp.broadcast_to(jnp.exp(gc[r - 1:r]), (vd, kd)).T    # [K, V]
-    new = prev * total + dot(k_end, u_ref[...], (((0,), (0,)), ((), ())))
-    carry_ref[...] = new
-    o_ref[0] = jnp.where(write_ref[i] > 0, new, 0.0)
+    jax.lax.fori_loop(0, n_sub, step, 0)
+    o_ref[0] = jnp.where(write_ref[i] > 0, carry_ref[...], 0.0)
 
 
 def kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta,
@@ -351,7 +455,8 @@ def kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta,
     ``write`` (else zeros are written: a tile that is not its slot's last of
     the step, and a padding tile, name the scratch slot in ``rows_w``).
     ``sub`` the sub-chunk (divides ``R``) -> ``(state, y [I, R, H x V])``.
-    float32, ``Precision.HIGHEST``. ``impl`` as ``kda_decode``'s."""
+    float32, the six products of ``Precision.HIGHEST`` (module doc). ``impl``
+    as ``kda_decode``'s."""
     if not _on_chip(impl):
         return kda_chunk_xla(state, rows, rows_w, fresh, cont, write, q, k, g,
                              v, beta, sub)
@@ -371,6 +476,10 @@ def _kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta, *,
     vd = hv // heads
     f32 = jnp.float32
 
+    # the heads of a grid step share ONE chain of dependent steps (the
+    # forward substitution), side by side on the lanes
+    hb = next(n for n in _HEADS_A_STEP if heads % n == 0)
+
     def tile(h, i, *_):
         return (i, 0, h)
 
@@ -378,29 +487,27 @@ def _kda_chunk(state, rows, rows_w, fresh, cont, write, q, k, g, v, beta, *,
         num_scalar_prefetch=5,
         # the tiles are the inner, sequential axis: a head's state goes from
         # tile to tile of a slot in ``carry_ref``
-        grid=(heads, n_i),
+        grid=(heads // hb, n_i),
         in_specs=[
-            pl.BlockSpec((1, kd, vd),
+            pl.BlockSpec((1, kd, hb * vd),
                          lambda h, i, rows, *_: (rows[i], 0, h)),
-            pl.BlockSpec((1, r, kd), tile),
-            pl.BlockSpec((1, r, kd), tile),
-            pl.BlockSpec((1, r, kd), tile),
-            pl.BlockSpec((1, r, vd), tile),
+            pl.BlockSpec((1, r, hb * kd), tile),
+            pl.BlockSpec((1, r, hb * kd), tile),
+            pl.BlockSpec((1, r, hb * kd), tile),
+            pl.BlockSpec((1, r, hb * vd), tile),
             pl.BlockSpec((1, r, heads), lambda h, i, *_: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, kd, vd),
+            pl.BlockSpec((1, kd, hb * vd),
                          lambda h, i, rows, rows_w, *_: (rows_w[i], 0, h)),
-            pl.BlockSpec((1, r, vd), tile),
+            pl.BlockSpec((1, r, hb * vd), tile),
         ],
-        scratch_shapes=[pltpu.VMEM((kd, vd), f32),        # carry
-                        pltpu.VMEM((r, kd), f32),         # loc
-                        pltpu.VMEM((r, kd), f32),         # base
-                        pltpu.VMEM((r, vd), f32),         # rhs
-                        pltpu.VMEM((r, vd), f32)],        # u
+        scratch_shapes=[pltpu.VMEM((kd, hb * vd), f32),             # carry
+                        pltpu.VMEM((r, hb * kd), f32),              # loc
+                        pltpu.VMEM((r, hb * kd), f32)],            # beta
     )
     return pl.pallas_call(
-        functools.partial(_chunk_kernel, sub=sub),
+        functools.partial(_chunk_kernel, sub=sub, heads=hb),
         out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
                    jax.ShapeDtypeStruct((n_i, r, hv), f32)],
         grid_spec=grid_spec,

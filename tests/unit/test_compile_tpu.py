@@ -422,9 +422,10 @@ def _kda_chunk_args(devices, tiles=3, cell="kimi-linear"):
 
 @pytest.mark.parametrize("cell,tiles", [("kimi-linear", 3), ("solar-open2", 4)])
 def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e, cell, tiles):
-    """A head and a tile a grid step (64 heads x 4 tiles in the Solar cell's
-    widest step): float32 products at ``Precision.HIGHEST``, a transposed
-    product, lane sums of ``[16, 16, 128]`` blocks and a sub-chunk loop in
+    """Four heads and a tile a grid step (16 x 4 grid steps in the Solar
+    cell's widest step): products of bfloat16 parts, one of them against a
+    transposed operand, lane sums of ``[8, 16, 128]`` blocks, a bit mask over
+    float32 and a sub-chunk loop in
     Mosaic; the donated state is the output (aliased), nothing else in the
     program is as large as ONE row's state, and the tiles' operands reach
     the kernel as they are handed in (no transpose to a head-major layout,
@@ -439,6 +440,77 @@ def test_kda_chunk_compiles_for_v5e_and_updates_in_place(v5e, cell, tiles):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= n * 128 * heads * 128 * 4
     assert mem.temp_size_in_bytes < 128 * heads * 128 * 4
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for inner in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _kda_chunk_loop(rows, tiles=3, cell="solar-open2"):
+    """The ``exp`` and ``dot_general`` equations of ``kda_chunk``'s sub-chunk
+    loop at a cell's heads and ``rows`` rows a tile, as (primitive, operand
+    shapes, operand dtypes), and the loop's trip count: read off the
+    ``pallas_call``'s kernel jaxpr, nothing compiled."""
+    n, heads, _ = KDA_CELLS[cell]
+    hv = heads * 128
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    traced = jax.make_jaxpr(_kda_chunk)(
+        s((n, 128, hv)), *(s((tiles,), jnp.int32),) * 2,
+        *(s((tiles,), jnp.bool_),) * 3, *(s((tiles, rows, hv)),) * 4,
+        s((tiles, rows, heads)))
+    (call,) = [e for e in _eqns(traced.jaxpr) if e.primitive.name == "pallas_call"]
+    loops = [e for e in _eqns(call.params["jaxpr"]) if e.primitive.name == "scan"]
+    assert len(loops) == 1, "ONE loop over the sub-chunks, not copies of its body"
+    (loop,) = loops
+    return loop.params["length"], [
+        (e.primitive.name, tuple(v.aval.shape for v in e.invars),
+         tuple(str(v.aval.dtype) for v in e.invars))
+        for e in _eqns(loop.params["jaxpr"].jaxpr)
+        if e.primitive.name in ("exp", "dot_general")]
+
+
+def test_kda_chunk_loop_pays_a_turn_for_its_sub_chunk_alone():
+    """The mechanism of PR 58, held off the chip: a turn of ``kda_chunk``'s
+    sub-chunk loop costs what is new to its 16 rows. At the Solar cell's
+    sizes (64 heads, four a grid step) the loop's body takes ``exp`` of
+    nothing larger than a band of the pairwise block ``[8, 16, 128]`` and of
+    no ``[R, K]`` operand, every product is of bfloat16 PARTS (no float32
+    operand for the compiler to split and push six times), and the only
+    128-row operands are the carried state's three parts ``[K, V]``, pushed
+    once each a head, the identity that turns the keys to columns and the
+    turned keys ``[K, 128]``. The same kernel over tiles of 256 rows has the
+    SAME equations in its loop, twice the turns: nothing in a turn is as long
+    as the tile (the parent rebuilt, split and pushed ``cols`` ``[R, K]`` and
+    ``u`` ``[R, V]`` whole every turn)."""
+    turns, body = _kda_chunk_loop(128)
+    turns_256, body_256 = _kda_chunk_loop(256)
+    assert (turns, turns_256) == (8, 16)
+    assert body == body_256
+    sub, kd, heads = 16, 128, 4
+    exps = [shapes[0] for name, shapes, _ in body if name == "exp"]
+    assert exps and max(math.prod(x) for x in exps) == sub // 2 * sub * kd
+    assert not [x for x in exps if len(x) == 2 and x[0] > sub]
+    dots = [(shapes, dtypes) for name, shapes, dtypes in body
+            if name == "dot_general"]
+    assert all(dtypes == ("bfloat16", "bfloat16") for _, dtypes in dots)
+    # a head: the state's three parts under the rows' parts, the identity
+    # under the keys' parts, the turned keys over U's parts and over the ones
+    assert len(dots) == 6 * heads
+    state_parts = [shapes for shapes, _ in dots if shapes[1] == (kd, 128)
+                   and shapes[0][0] in (6 * sub, 4 * sub, 2 * sub)]
+    assert len(state_parts) == 3 * heads
+    streamed = sorted({shapes[0][0] for shapes, _ in dots})
+    assert streamed == [2 * sub, 4 * sub, 6 * sub, kd]
 
 
 def _selscan_decode(state, rows, fresh, dt, x, a, b, c):
@@ -1252,7 +1324,7 @@ def test_solar_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
     ``G`` and a scan over three ``K``, two layer bodies), compiled before the
     first chip call as ROADMAP Queue R asks: the four kernels are there at
     their new geometries (``kda_decode`` on 4 MB rows, ``kda_chunk`` on a
-    grid of 64 heads x tiles, the paged kernels on 64 query heads over 8 K/V
+    grid of 16 blocks of four heads x tiles, the paged kernels on 64 query heads over 8 K/V
     heads) and the grouped expert kernel in both bodies of a step of 256
     rows or more; nothing the size of a layer's slice of the float32 state
     (``[17, 128, 8192]``, 71 MB) or of the K/V pool gets a buffer of its own

@@ -356,6 +356,17 @@ CHUNK_STEPS = {
     # two slots, the first continued: the second must not start from the
     # first's carry, and both rows are written
     "two_slots": [(2, 5, 0, 0, 0), (2, 2, 0, 1, 1), (0, 0, 0, 0, 1)],
+    # two slots, both continued, a padding tile between them: the scratch
+    # slot is written zeros three times, and the state the kernel carries
+    # from turn to turn of a tile leaks into neither the padding tile nor
+    # the second slot
+    "two_slots_around_a_padding_tile": [
+        (2, 5, 0, 0, 0), (2, 2, 0, 1, 1), (5, 5, 1, 0, 0),
+        (0, 5, 1, 0, 0), (0, 0, 0, 1, 1)],
+    # a tile whose valid rows end INSIDE a sub-chunk (6 of 16 rows in
+    # sub-chunks of 4), then the slot's next tile: the rows past them
+    # neither decay nor feed the state that is carried on
+    "valid_rows_end_inside_a_sub_chunk": [(1, 5, 0, 0, 0), (1, 1, 0, 1, 1)],
 }
 
 
@@ -381,6 +392,10 @@ def test_kda_chunk_kernel_is_the_xla_form(case):
     beta = jax.nn.sigmoid(draw(n_i, r, h))
     if case == "padding_tile":
         g, beta = g.at[1:].set(0.0), beta.at[1:].set(0.0)
+    if case == "two_slots_around_a_padding_tile":
+        g, beta = g.at[2].set(0.0), beta.at[2].set(0.0)
+    if case == "valid_rows_end_inside_a_sub_chunk":
+        g, beta = g.at[0, 6:].set(0.0), beta.at[0, 6:].set(0.0)
     leaf = draw(6, kd, h * kd)
     if case == "fresh_slot_over_garbage":
         leaf = leaf.at[4].set(jnp.nan)
@@ -400,6 +415,41 @@ def test_kda_chunk_kernel_is_the_xla_form(case):
         assert not np.asarray(got_s[5]).any()
     assert all((np.asarray(got_s[i]) != np.asarray(leaf[i])).any()
                for i in written)
+
+
+@pytest.mark.parametrize("heads", [1, 2, 3, 4, 6, 8])
+def test_kda_chunk_kernel_takes_the_heads_a_grid_step_that_divide(heads):
+    """The kernel takes four heads a grid step where four divide the head
+    count, two where two do, else one (the heads of a step share ONE
+    substitution chain, side by side on the lanes): every count gives XLA's
+    form, a slot continued over two tiles beside a fresh one in sub-chunks
+    of 16 (two bands of 8 rows), each head its own ``beta`` column."""
+    rng = np.random.default_rng(heads)
+    n_i, r, kd, sub = 3, 32, 16, 16
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(x):  # a head's K normalised, as the model's keys are
+        x = x.reshape(n_i, r, heads, kd)
+        return (x / jnp.linalg.norm(x, axis=-1, keepdims=True)).reshape(
+            n_i, r, heads * kd)
+
+    q, k, v = (unit(draw(n_i, r, heads * kd)), unit(draw(n_i, r, heads * kd)),
+               draw(n_i, r, heads * kd))
+    g = -jnp.abs(draw(n_i, r, heads * kd)) * 0.3
+    beta = jax.nn.sigmoid(draw(n_i, r, heads))
+    leaf = draw(4, kd, heads * kd)
+    tiles = [(1, 3, 0, 0, 0), (1, 1, 0, 1, 1), (0, 0, 1, 0, 1)]  # 3: scratch
+    rows, rows_w, fresh, cont, write = (
+        jnp.asarray(col, jnp.int32) for col in zip(*tiles))
+    args = (leaf, rows, rows_w, fresh > 0, cont > 0, write > 0, q, k, g, v,
+            beta, sub)
+    got_s, got_y = kda_chunk(*args, impl="pallas", interpret=True)
+    want_s, want_y = kda_chunk_xla(*args)
+    assert _rel(got_y, want_y) < 1e-5 and _rel(got_s, want_s) < 1e-5
+    np.testing.assert_array_equal(np.asarray(got_s[2]), np.asarray(leaf[2]))
+    assert not np.asarray(got_s[3]).any()
 
 
 # ------------------------------------------------------------ the kernel

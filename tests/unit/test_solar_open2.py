@@ -368,8 +368,8 @@ def test_kda_decode_kernel_at_64_heads_is_the_xla_form():
 
 
 def test_kda_chunk_kernel_at_64_heads_is_the_xla_form():
-    """``kda_chunk`` in interpret mode at 64 heads of 128 (a grid of 64 heads
-    x tiles) against ``kda_chunk_xla``: a slot's prompt continued over two
+    """``kda_chunk`` in interpret mode at 64 heads of 128 (a grid of 16
+    blocks of four heads x tiles) against ``kda_chunk_xla``: a slot's prompt continued over two
     16-row tiles beside another slot's fresh one, ``beta`` up to 2 (the
     delta rule with negative eigenvalues), readings and states to 1e-5 of
     their largest magnitude."""
@@ -406,6 +406,53 @@ def test_kda_chunk_kernel_at_64_heads_is_the_xla_form():
     np.testing.assert_array_equal(np.asarray(got_s[2]), np.asarray(leaf[2]))
     assert not np.asarray(got_s[3]).any()
     assert float(beta.max()) > 1.5
+
+
+@pytest.mark.parametrize("tiles", [1, 4])
+def test_kda_chunk_kernel_carries_the_strongest_decay_over_the_widest_step(
+        tiles):
+    """``g`` = -1.6 a token on EVERY channel (``G`` -205 over a 128-row tile:
+    ``exp(-G)`` is float32's ``inf``) through the kernel at 128 channels a
+    head, four heads a grid step, a slot continued over four tiles (the
+    cell's widest program, ``d0_t4``) from a state that is not zero: the
+    state the loop carries from sub-chunk to sub-chunk decays by ``exp`` of
+    a sub-chunk's sum, never grows by ``exp`` of a difference the other way,
+    so readings and state are finite and XLA's form to 1e-5 of its largest
+    value."""
+    rng = np.random.default_rng(tiles)
+    r, h, kd, sub = 128, 4, 128, 16
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def unit(x):
+        x = x.reshape(tiles, r, h, kd)
+        return (x / jnp.linalg.norm(x, axis=-1, keepdims=True)).reshape(
+            tiles, r, h * kd)
+
+    q, k, v = unit(draw(tiles, r, h * kd)) * kd ** -0.5, unit(
+        draw(tiles, r, h * kd)), draw(tiles, r, h * kd)
+    g = jnp.full((tiles, r, h * kd), -1.6, jnp.float32)
+    beta = 2.0 * jax.nn.sigmoid(draw(tiles, r, h))
+    leaf = draw(3, kd, h * kd)
+    last = jnp.arange(tiles) == tiles - 1
+    args = (leaf, jnp.full((tiles,), 1), jnp.where(last, 1, 2),
+            jnp.zeros((tiles,), bool), jnp.arange(tiles) > 0, last, q, k, g, v,
+            beta, sub)
+    assert float(g[0, :, 0].sum()) < -200.0
+    got_s, got_y = kda_chunk(*args, impl="pallas", interpret=True)
+    want_s, want_y = kda_chunk_xla(*args)
+
+    def rel(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    assert np.isfinite(np.asarray(got_y)).all()
+    assert np.isfinite(np.asarray(got_s)).all()
+    assert rel(got_y, want_y) < 1e-5 and rel(got_s[1], want_s[1]) < 1e-5
+    np.testing.assert_array_equal(np.asarray(got_s[0]), np.asarray(leaf[0]))
+    if tiles > 1:
+        assert not np.asarray(got_s[2]).any()
 
 
 # ------------------------------------------------------------ the engine
