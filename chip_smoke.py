@@ -229,9 +229,9 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     row's weight and an edge off by a block would show. And a block model's
     decode walk at ``blocks`` = (sequences, block length, q heads, kv heads, head
     size, context, pool block; the SDAR cell's 96 blocks x 4 rows x 32 heads
-    over ~1.3K contexts): ``blk_decode`` (a block ONE row of 128 query heads,
-    its context fetched once) against XLA's gather under the block-causal
-    mask, and beside it the same rows as four single-query ``paged_decode``
+    over ~1.3K contexts): ``blk_decode`` (a block ONE row of the walk, its
+    context fetched once for its queries by KV head) against XLA's gather
+    under the block-causal mask, and beside it the same rows as four single-query ``paged_decode``
     walks (every row its block's last position: the same keys, fetched four
     times), all three timed (``blk_decode_ms``)."""
     import jax

@@ -193,8 +193,9 @@ def paged_attention(q, k_pool, v_pool, slots, positions, block_tables,
     ``block`` (static, ``B``): a model that generates by blocks; the rows are
     whole decoding blocks, ``B`` consecutive rows a sequence at positions
     ``p0 .. p0 + B - 1`` (``p0`` a multiple of ``B``), and each attends over
-    keys ``j <= (pos | (B - 1))``, its block whole: the kernel takes a block
-    as one row (``blk_decode``), the XLA form moves the mask.
+    keys ``j <= (pos | (B - 1))``, its block whole: the kernel walks a
+    block's context once, its queries laid out by KV head (``blk_decode``),
+    the XLA form moves the mask.
 
     A quantized pool (``ops/kvquant.QuantizedKV``) always takes the
     XLA path: the gather+dequant fuse into one program there (the fp

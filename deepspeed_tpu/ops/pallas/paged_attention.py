@@ -80,11 +80,27 @@ sees keys ``j <= (i | (B - 1))``, its own block whole. A tile and a pool block
 are multiples of ``B``, so the tile kernel's grid is the causal one and only
 its mask's query position moves to its block's last. A decoding block's ``B``
 queries all read the same context, ``p0 + B`` keys with no mask among them,
-so the decode kernel takes the block as ONE row of ``B x Hq`` query heads at
-position ``p0 + B - 1``: a sequence's chunks are fetched once a pass, not
-once a query, in as many grid steps as one row of that context takes. Both
-then go by another name again (``blk_decode``, ``blk_prefill``); ``block=None``
-traces what it always did.
+so the block is ONE row of the decode walk at position ``p0 + B - 1``: a
+sequence's chunks are fetched once a pass, not once a query, in as many grid
+steps as one row of that context takes. That row has a kernel body of its own
+(``_block_decode``): q comes laid out by KV head, ``[rows, Hkv, B * rep, D]``
+(the tile kernel's form: an XLA transpose beside the projection that made it,
+and back), and a step is one batched product of ``Hkv`` matrices each way, a
+KV head's ``B * rep`` queries against that head's ``D`` lanes of the chunk
+only; the accumulator is ``[Hkv, B * rep, D]`` and the finish a divide and a
+store. The two needs conflict, so it is a path and not a parameter of the
+shared one: the ``[Hq, Hkv*D]`` form serves a row of one query best (1 to 8
+query heads a KV head, ``D`` 64 in one model: 77-86% of its roofline) and a
+block's 32 queries a KV head worst (``(Hkv - 1) / Hkv`` of its products are
+products with zeros, every one popped as partial sums over ``Hkv`` lane
+parts and added by the vector unit, and the outputs rotated out of the
+accumulator's diagonal segments: 1,410 + 453 bundles where the split issues
+639 + 93). The block path also hands the steps' pool block ids in as one
+more prefetched vector, made by XLA beside ``decode_steps``, where the shared
+path's eight index maps work them out (748 bundles a grid step, 302 so;
+PERF.md section 6, PR 59). What chooses is ``block``, the layer's kind. Both
+kernels then go by another name again (``blk_decode``, ``blk_prefill``);
+``block=None`` traces what it always did.
 
 Inference-only (no VJP): the ragged engine never differentiates through
 decode.
@@ -237,9 +253,16 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
     With a ``block`` (static, ``B``) a row is a decoding BLOCK: ``q`` is
     ``[rows, B, Hq, D]``, ``positions`` the blocks' first positions ``p0``
     (multiples of ``B``), and every query of a row reads keys ``0 .. p0 + B -
-    1``, the block's own among them, unmasked: the row rides through the
-    kernel as ``B x Hq`` query heads at position ``p0 + B - 1``, so its
-    context is fetched once. -> ``[rows, B, Hq, D]``.
+    1``, the block's own among them, unmasked: the row walks its context
+    once, at position ``p0 + B - 1``. -> ``[rows, B, Hq, D]``. The kernel
+    (``_block_decode``) takes the queries by KV head, ``[rows, Hkv, B * rep,
+    D]``, and multiplies a head's ``B * rep`` against that head's lanes of a
+    chunk only: a body of its own, because the single-query body's ``[Hq,
+    Hkv*D]`` form, right for a few query heads a KV head, spends a block's
+    time on products with zeros and on taking the heads apart again (the
+    module's docstring). Where ``D`` is no multiple of 128 a head's lanes are
+    no whole tile and the block keeps that form, ``B x Hq`` heads of one row
+    (no cell has such a block model).
     """
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if block is None:
@@ -250,16 +273,24 @@ def paged_decode_attention(q, k_pool, v_pool, slots, positions, block_tables,
             window=window)
     rows, b, hq, d = q.shape
     hkv = k_pool.shape[-1] // d
-    # head (g, r) of query b becomes head (g, b * rep + r) of the row: a KV
-    # head's group is the B queries' groups side by side
-    as_heads = q.reshape(rows, b, hkv, hq // hkv, d).transpose(
-        0, 2, 1, 3, 4).reshape(rows, b * hq, d)
-    out = _paged_decode(
-        as_heads, k_pool, v_pool, slots.astype(jnp.int32),
-        positions.astype(jnp.int32) + (b - 1), block_tables.astype(jnp.int32),
-        scale=float(scale), interpret=interpret_mode(interpret),
-        window=window, name=_kernel_name("paged_decode", window, block))
-    return out.reshape(rows, hkv, b, hq // hkv, d).transpose(
+    rep = hq // hkv
+    name = _kernel_name("paged_decode", window, block)
+    statics = dict(scale=float(scale), interpret=interpret_mode(interpret))
+    # head (g, r) of query b becomes row b * rep + r of KV head g's group: a
+    # KV head's group is the B queries' groups one under the other
+    by_kv_head = q.reshape(rows, b, hkv, rep, d).transpose(0, 2, 1, 3, 4)
+    operands = (k_pool, v_pool, slots.astype(jnp.int32),
+                positions.astype(jnp.int32) + (b - 1),
+                block_tables.astype(jnp.int32))
+    if d % 128:
+        # a head's lanes are no whole tile: the wide form, the B x Hq heads
+        # of a block as one row's (no cell has such a block model)
+        out = _paged_decode(by_kv_head.reshape(rows, b * hq, d), *operands,
+                            name=name, **statics)
+    else:
+        out = _block_decode(by_kv_head.reshape(rows, hkv, b * rep, d),
+                            *operands, name=name, **statics)
+    return out.reshape(rows, hkv, b, rep, d).transpose(
         0, 2, 1, 3, 4).reshape(rows, b, hq, d)
 
 
@@ -339,6 +370,107 @@ def _paged_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
       *([k_pool] * nb), *([v_pool] * nb))
     return out.reshape(t_tokens, rep, hkv, d).transpose(0, 2, 1, 3).reshape(
         t_tokens, hq, d)
+
+
+# --------------------------------------------------------------- block decode
+def _block_decode_kernel(row_ref, chunk_ref, pos_ref, ids_ref, q_ref, *refs,
+                         bs: int, nb: int, hkv: int, d: int, scale: float):
+    del ids_ref  # the index maps' alone
+    k_refs, v_refs = refs[:nb], refs[nb:2 * nb]
+    o_ref, acc, m_sc, l_sc = refs[2 * nb:]
+    s_id = pl.program_id(0)
+    c = chunk_ref[s_id]
+    pos = pos_ref[row_ref[s_id]]
+    ch = nb * bs
+
+    @pl.when(c == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    def heads(block_refs):                                 # [Hkv, CH, D]
+        # a head's keys are a static lane slice of the blocks in VMEM
+        return jnp.stack([
+            jnp.concatenate([r[0, :, g * d:(g + 1) * d] for r in block_refs],
+                            axis=0) for g in range(hkv)])
+
+    # ONE batched product of Hkv matrices each way: a KV head's B * rep
+    # queries against that head's D lanes of the chunk only
+    k, v = heads(k_refs), heads(v_refs)
+    s = jax.lax.dot_general(
+        q_ref[0].astype(k.dtype), k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale        # [Hkv, B*rep, CH]
+    # position 0 is never masked, so a row's running maximum is real from
+    # its first chunk on and no row of p is all zeros
+    seen = c * ch + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ch), 2) <= pos
+    s = jnp.where(seen, s, _NEG_INF)
+    m_prev = m_sc[:]                                       # [Hkv, B*rep, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - _lanes(m_new, ch))
+    corr = jnp.exp(m_prev - m_new)
+    l_sc[:] = l_sc[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_sc[:] = m_new
+    acc[:] = acc[:] * _lanes(corr, d) + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(c == pos // ch)
+    def _finish():
+        o_ref[0] = (acc[:] / _lanes(l_sc[:], d)).astype(o_ref.dtype)
+
+
+# ONE jitted function, as ``_paged_decode``
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "name"))
+def _block_decode(q, k_pool, v_pool, slots, positions, block_tables, *,
+                  scale: float, interpret: bool, name: str):
+    """``q`` [rows, Hkv, B*rep, D], a decoding block's queries by KV head, at
+    ``positions`` (the blocks' LAST) -> as much. ``_paged_decode``'s walk
+    (``decode_steps``, ``decode_step_blocks``, the same block of the pool in
+    the same operand of the same step) under a body of its own."""
+    rows, hkv, group, d = q.shape
+    _, bs, hd = k_pool.shape
+    nb = decode_step_blocks(bs, hd, k_pool.dtype.itemsize)
+    n_steps = rows * -(-block_tables.shape[1] // nb) + 1
+    ends, step_row, step_chunk = decode_steps(positions, nb * bs, n_steps)
+
+    # The pool block of operand i of step s, made here and not in eight index
+    # maps: ``_paged_decode._kv_map``'s rule (past the row's last block the
+    # operand's block of a step ago, or the row's last)
+    j = step_chunk[:, None] * nb + jnp.arange(nb, dtype=jnp.int32)
+    last = (positions // bs)[step_row][:, None]
+    j = jnp.where(j <= last, j, jnp.where(j >= nb, j - nb, last))
+    step_ids = block_tables[slots[step_row][:, None], j].reshape(-1)
+
+    def _row_map(s, row, chunk, po, ids):
+        return (row[s], 0, 0, 0)
+
+    def _kv_map(i):
+        return lambda s, row, chunk, po, ids: (ids[s * nb + i], 0, 0)
+
+    kv_specs = [pl.BlockSpec((1, bs, hd), _kv_map(i)) for i in range(nb)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(ends[-1],),
+        in_specs=[pl.BlockSpec((1, hkv, group, d), _row_map)]
+        + kv_specs + kv_specs,
+        out_specs=pl.BlockSpec((1, hkv, group, d), _row_map),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, group, d), jnp.float32),
+            pltpu.VMEM((hkv, group, 128), jnp.float32),
+            pltpu.VMEM((hkv, group, 128), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_block_decode_kernel, bs=bs, nb=nb, hkv=hkv,
+                               d=d, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name=name,
+    )(step_row, step_chunk, positions, step_ids, q,
+      *([k_pool] * nb), *([v_pool] * nb))
 
 
 # --------------------------------------------------------------- tiled prefill

@@ -267,6 +267,44 @@ def test_decode_kernel_compiles_at_the_cells_shapes(v5e, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**21
 
 
+DECODE_FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "fixtures", "paged_decode_jaxprs_pr58.json")
+
+
+def single_query_decode_digests() -> dict:
+    """sha256 of what ``paged_decode_attention(block=None)`` traces to (the
+    wrapper's XLA operations, the grid, the index maps and the kernel's
+    body) at GPT-2 XL's and Mixtral's cells' shapes. ``python
+    tests/unit/test_compile_tpu.py OUT`` writes them from whatever tree
+    ``PYTHONPATH`` names (the fixture: PR 58's)."""
+    import hashlib
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    out = {}
+    for cell in ("gpt2-xl.chat", "mixtral.chat", "mixtral.longdoc"):
+        hq, hkv, d, block, table, rows, blocks = DECODE_CELLS[cell]
+        pool, i32 = s((blocks, block, hkv * d)), s((rows,), jnp.int32)
+        text = str(jax.make_jaxpr(_decode)(
+            s((rows, hq, d)), pool, pool, i32, i32,
+            s((rows + 1, table), jnp.int32)))
+        assert "paged_decode" in text
+        out[cell] = hashlib.sha256(
+            re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()
+    return out
+
+
+def test_single_query_decode_traces_what_the_parent_of_the_split_traced():
+    """(PR 59) A decoding block got a kernel body of its own; a row of one
+    query (``block=None``) goes through the wrapper to ``_paged_decode`` and
+    ``_decode_kernel`` as before: the same text, operation for operation."""
+    import json
+
+    with open(DECODE_FIXTURE) as f:
+        assert single_query_decode_digests() == json.load(f)
+
+
 @pytest.mark.parametrize("kernel,block", [
     (_mla_decode, MLA_BLOCK), (_mla_prefill, MLA_BLOCK), (_mla_decode, 16)],
     ids=["mla_decode", "mla_prefill", "mla_decode_8_blocks_a_step"])
@@ -2277,12 +2315,13 @@ BLK_HEADS, BLK_KV, BLK_D, BLK_LEN, BLK_TABLE, BLK_POOL = 32, 4, 128, 4, 16, 1537
 
 @pytest.mark.parametrize("kernel", ["blk_decode", "blk_prefill"])
 def test_block_kernels_compile_at_the_cells_shapes(v5e, kernel):
-    """The two paged kernels with ``block``: a decoding block as ONE row of 4
-    x 32 = 128 query heads in the ``[Hq, Hkv*D]`` form (a [128, 512] query, a
-    float32 accumulator of as much, 32 output rows a KV group), a whole
-    128-row tile under the block-causal mask; the instruction goes by the
-    blocks' name, which ``benchmark/kernels/<name>.json`` finds and the
-    causal kernels' patterns do not."""
+    """The two paged kernels with ``block``: a decoding block's 4 x 32 = 128
+    query heads laid out by KV head, ``[96, 4, 32, 128]`` (a KV head's 32
+    queries against that head's 128 lanes of a chunk; no ``[96, 128, 512]``
+    query of three quarters zeros is made), a whole 128-row tile under the
+    block-causal mask; the instruction goes by the blocks' name, which
+    ``benchmark/kernels/<name>.json`` finds and the causal kernels' patterns
+    do not."""
     import json
     import os
 
@@ -2317,6 +2356,10 @@ def test_block_kernels_compile_at_the_cells_shapes(v5e, kernel):
                 found.append(f[:-5])
     assert sorted(found) == sorted([kernel, "pallas_custom_call"])
     assert compiled.memory_analysis().temp_size_in_bytes < 2**24
+    if kernel == "blk_decode":
+        # the split is there: q goes in, and the output comes back, by KV head
+        assert calls[0].count("bf16[96,4,32,128]") >= 2
+        assert "bf16[96,128,512]" not in compiled.as_text()
 
 
 def test_block_step_holds_no_layer_slice_of_the_pool(v5e, monkeypatch):
@@ -2355,3 +2398,11 @@ def test_block_step_holds_no_layer_slice_of_the_pool(v5e, monkeypatch):
     assert {"blk_decode", "blk_prefill"} <= names
     assert not {"paged_decode", "tiled_prefill"} & names
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
+
+
+if __name__ == "__main__":
+    import json
+    import sys
+
+    with open(sys.argv[1], "w") as f:
+        json.dump(single_query_decode_digests(), f, indent=1)

@@ -429,6 +429,81 @@ def test_a_block_takes_the_grid_steps_of_one_row_at_its_context():
     assert int(as_rows[-1]) == B * int(as_blocks[-1])
 
 
+# (q heads, kv heads): the SDAR cell's, one KV head, a KV head a query head
+SPLIT_GEOMETRIES = {"cell_32_over_4": (32, 4), "one_kv_head": (8, 1),
+                    "a_kv_head_a_query_head": (4, 4)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("geometry", sorted(SPLIT_GEOMETRIES))
+def test_block_kernel_splits_by_kv_head_and_serves_the_wide_forms_rows(
+        geometry, dtype):
+    """The block kernel's own body (heads of 128 lanes: a KV head's ``B x
+    rep`` queries against that head's lanes of the chunk only) against the
+    XLA form under the block-causal mask, and against the form it replaced
+    (the block as ONE row of ``B x Hq`` heads through ``_paged_decode``'s
+    ``[Hq, Hkv*D]`` body), whose rows it gives bit for bit in the pool's
+    bfloat16 (the products dropped are products with exact zeros). 128-token
+    pool blocks; contexts that end inside a chunk's first block, on a chunk's
+    edge (its last position and the next chunk's first), in a chunk's last
+    block and at position 0; two padding rows on the scratch slot; a table
+    wider than any row's context."""
+    from deepspeed_tpu.ops.attention import paged_attention
+    from deepspeed_tpu.ops.pallas import paged_attention as kernels
+
+    hq, hkv = SPLIT_GEOMETRIES[geometry]
+    d, bs, dt = 128, 128, jnp.dtype(dtype)
+    rep = hq // hkv
+    nb = decode_step_blocks(bs, hkv * d, dt.itemsize)
+    ch = nb * bs
+    rng = np.random.default_rng(2)
+    k, v, table = _pool(rng, 5, 2 * nb + 3, bs, hkv, d)
+    k, v = k.astype(dt), v.astype(dt)
+    # p0 + B - 1 is the row's position in the walk
+    p0s = [bs // 2, ch - B, ch, 2 * ch - bs // 2, 0]
+    assert [(p + B - 1) // ch for p in p0s] == [0, 0, 1, 1, 0]
+    assert max(p0s) + B <= (table.shape[1] - 3) * bs       # a wider table
+    slots = jnp.asarray([0, 1, 2, 3, 4, 5, 5], jnp.int32)  # 5: the scratch
+    p0 = jnp.asarray(p0s + [0, 0], jnp.int32)
+    rows = len(p0s) + 2
+    q = jnp.asarray(rng.standard_normal((rows, B, hq, d)), dt)
+    got = kernels.paged_decode_attention(q, k, v, slots, p0, table,
+                                         interpret=True, block=B)
+    assert got.shape == q.shape and got.dtype == dt
+
+    # the XLA form, a block a call (its context is gathered head by head)
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    for r, at in enumerate(p0s):
+        want = paged_attention(
+            q[r].astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), jnp.full((B,), r, jnp.int32),
+            at + jnp.arange(B, dtype=jnp.int32), table, impl="xla", block=B)
+        np.testing.assert_allclose(
+            np.asarray(got[r], np.float32), np.asarray(want), atol=tol,
+            err_msg=f"block at {at}")
+    assert np.isfinite(np.asarray(got, np.float32)).all()  # the padding's too
+
+    # the parent's block form: head (g, r) of query b is head g * B * rep +
+    # b * rep + r of ONE row at the block's last position
+    as_heads = q.reshape(rows, B, hkv, rep, d).transpose(
+        0, 2, 1, 3, 4).reshape(rows, B * hq, d)
+    wide = kernels._paged_decode(
+        as_heads, k, v, slots, p0 + (B - 1), table, scale=1.0 / d ** 0.5,
+        interpret=True, name="blk_decode")
+    wide = np.asarray(wide.reshape(rows, hkv, B, rep, d).transpose(
+        0, 2, 1, 3, 4).reshape(q.shape), np.float32)
+    got = np.asarray(got, np.float32)
+    if dtype == "float32":
+        np.testing.assert_allclose(got, wide, atol=1e-6)
+    elif rep > 1:
+        assert np.array_equal(got, wide)
+    else:
+        # four rows a product: the CPU's routine for them adds the same terms
+        # in another order, an ulp of bfloat16 in a value of two thousand
+        np.testing.assert_allclose(got, wide, rtol=2.0 ** -7, atol=2.0 ** -10)
+        assert (got != wide).mean() < 1e-3
+
+
 @pytest.mark.parametrize("pos0,valid", [(0, 16), (16, 16), (8, 12), (24, 4)])
 def test_block_prefill_tiles_match_a_dense_masked_softmax(pos0, valid):
     """``tiled_prefill(block=4)``: a tile's rows under ``kpos <= (qpos |
