@@ -62,11 +62,20 @@ from deepspeed_tpu.utils.faults import (
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.tracing import phase, span
 
-def _kept_pairs(pos0: int, take: int, topk: int) -> int:
-    """``sum(min(p + 1, topk) for p in range(pos0, pos0 + take))``: the
-    query x kept-row pairs of a chunk of ``take`` queries from ``pos0``."""
+def _kept_pairs(pos0: int, take: int, topk: int, rule=None) -> int:
+    """The query x kept-row pairs of a chunk of ``take`` queries from
+    ``pos0``: ``sum(min(p + 1, topk) for p in range(pos0, pos0 + take))``, or
+    with a family's ``rule`` (``ModelSpec.index_blocks``) the sum of what the
+    rule says a query keeps."""
+    if rule is not None:
+        return int(rule.kept(np.arange(pos0, pos0 + take), topk).sum())
     full = max(0, min(take, topk - pos0))     # queries that keep every row
     return (full * pos0 + full * (full + 1) // 2) + (take - full) * topk
+
+
+def _kept_keys(pos: int, topk: int, rule=None) -> int:
+    """The keys the query at ``pos`` keeps: ``_kept_pairs`` of that one."""
+    return _kept_pairs(pos, 1, topk, rule)
 
 
 # ---- the tables a step indexes, laid out for their row gather ----
@@ -843,6 +852,9 @@ class RaggedInferenceEngine:
         # (``ModelSpec.index_topk`` rows a query): counted beside the context
         # on ``engine/dispatch``
         self._topk = self.spec.index_topk
+        # ... by the family's own rule where it selects by blocks
+        # (``ModelSpec.index_blocks``), else ``min(position + 1, index_topk)``
+        self._sel_rule = self.spec.index_blocks
         # counts only a step program knows (``ModelSpec.step_counters``): it
         # hands their sums back behind the picked tokens, the reconcile folds
         # them into ``step_counts`` (totals) and ``_counts_unspanned`` (what
@@ -2843,7 +2855,12 @@ class RaggedInferenceEngine:
         any implementation must read (``min(context, index_topk)`` a decode
         row and a prefill tile), ``dec_sel_kv_tokens``, the decode rows'
         part of that, and ``sel_decode``, the form the program's decode rows
-        read the pool in (``_sel_decode_attr``); a model whose step program
+        read the pool in (``_sel_decode_attr``); one that selects by blocks
+        past a dense length (``ModelSpec.index_blocks``) counts the three by
+        its own rule (every key up to the dense length, the kept blocks past
+        it) and adds ``sel_queries``, the queries past that length, and
+        ``cmp_kv_tokens``, the compressed keys their scores must read
+        (``_count_selecting``); a model whose step program
         counts what only it knows (``ModelSpec.step_counters``: a router's
         ``moe_picks``, ``moe_zero_picks``, ``moe_held_picks``) adds those
         counts of the steps before this one (``_counts_attr``); a model with
@@ -2928,6 +2945,15 @@ class RaggedInferenceEngine:
                         "cached rows the same queries could have read (a "
                         "query x context-row pair each)").inc(
                             kv_dec + pairs_pre)
+            if "sel_queries" in sel:
+                tel.counter("inference_sparse_selecting_queries_total",
+                            "queries past the family's dense length: those "
+                            "that scored blocks and kept some").inc(
+                                sel["sel_queries"])
+                tel.counter("inference_sparse_compressed_keys_total",
+                            "compressed keys the selecting queries' scores "
+                            "had to read (once a decode row and a tile)").inc(
+                                sel["cmp_kv_tokens"])
         if probe and self._cache_misses() > misses:
             self._precompile_zoo_in_background()
         participants: dict[int, _SeqState] = {}
@@ -3074,6 +3100,20 @@ class RaggedInferenceEngine:
                    else {"chunk_slots": n_pre}),
                 **({} if kind is None else {"state_kind": kind})}
 
+    def _count_selecting(self, sel: dict, pos0: int, take: int) -> None:
+        """For a family that selects by blocks (``ModelSpec.index_blocks``),
+        add a run of ``take`` queries from ``pos0`` (a decode row, a tile) to
+        ``sel``: ``sel_queries``, those of them past the dense length, and
+        ``cmp_kv_tokens``, the compressed keys any implementation must read
+        to score them (those its last query sees, once a row or tile)."""
+        rule = self._sel_rule
+        if rule is None:
+            return
+        n = int(rule.selects(np.arange(pos0, pos0 + take)).sum())
+        sel["sel_queries"] += n
+        if n:
+            sel["cmp_kv_tokens"] += int(rule.compressed(pos0 + take - 1))
+
     def _step_program_name(self, t: int, nd: int, nt: int) -> str:
         """The name a device-resident step program goes by in a trace: its
         decode-row bucket and tile count (untiled: its token bucket)."""
@@ -3098,8 +3138,10 @@ class RaggedInferenceEngine:
         win_dec = win_pre = win_pairs = 0   # the same, cut to the window
         window = self._window
         topk = self._topk
+        rule = self._sel_rule
         sel = dict.fromkeys(
-            ("sel_pairs", "sel_kv_tokens", "dec_sel_kv_tokens"), 0) \
+            ("sel_pairs", "sel_kv_tokens", "dec_sel_kv_tokens")
+            + (("cmp_kv_tokens", "sel_queries") if rule else ()), 0) \
             if topk else {}
         size = budget + ct
         tokens = np.zeros(size, np.int32)
@@ -3159,8 +3201,11 @@ class RaggedInferenceEngine:
             kv_dec += seq.pos
             win_dec += min(seq.pos, window)
             n_dec += 1
-            for key in sel:     # a decode row: one query, its kept rows
-                sel[key] += min(seq.pos, topk)
+            if topk:            # a decode row: one query, its kept rows
+                kept = _kept_keys(seq.pos - 1, topk, rule)
+                for key in ("sel_pairs", "sel_kv_tokens", "dec_sel_kv_tokens"):
+                    sel[key] += kept
+                self._count_selecting(sel, seq.pos - 1, 1)
 
         ts = tpz = tv = None
         if ct:
@@ -3190,10 +3235,14 @@ class RaggedInferenceEngine:
                 pairs_pre += take * seq.pos + take * (take + per) // 2
                 win_pairs += _kept_pairs(seq.pos, take, window)
                 if topk:
-                    sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
+                    sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk, rule)
+                    # a tile must read what its last query keeps at least
                     sel["sel_kv_tokens"] += sum(
-                        min(seq.pos + min(i + ct, take), topk)
+                        _kept_keys(seq.pos + min(i + ct, take) - 1, topk, rule)
                         for i in range(0, take, ct))
+                    for i in range(0, take, ct):
+                        self._count_selecting(sel, seq.pos + i,
+                                              min(ct, take - i))
                 seq.pos += take
                 kv_pre += seq.pos
                 win_pre += min(seq.pos, window)
@@ -3225,8 +3274,10 @@ class RaggedInferenceEngine:
                 pairs_pre += take * seq.pos + take * (take + 1) // 2
                 win_pairs += _kept_pairs(seq.pos, take, window)
                 if topk:
-                    sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk)
-                    sel["sel_kv_tokens"] += min(seq.pos + take, topk)
+                    sel["sel_pairs"] += _kept_pairs(seq.pos, take, topk, rule)
+                    sel["sel_kv_tokens"] += _kept_keys(seq.pos + take - 1,
+                                                       topk, rule)
+                    self._count_selecting(sel, seq.pos, take)
                 seq.pos += take
                 kv_pre += seq.pos
                 win_pre += min(seq.pos, window)

@@ -28,6 +28,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Mesh-axis mapping for activation sharding constraints (GSPMD hints).
 DEFAULT_ACTIVATION_RULES = {
@@ -364,6 +365,37 @@ class BlockGen:
         return self.length // self.steps
 
 
+
+@dataclass(frozen=True)
+class BlockSelection:
+    """How a family that selects its keys by blocks counts what a query keeps
+    (``ModelSpec.index_blocks``): a query at position ``p`` keeps every key
+    while ``p + 1 <= dense_len``; past it the ``index_topk / block`` best
+    blocks of ``block`` keys up to its own, the own one cut at ``p``. The
+    blocks are scored against compressed keys, one the mean of ``kernel`` keys
+    every ``stride``; key ``j`` is visible to ``p`` once ``stride * j + kernel
+    - 1 <= p``."""
+    dense_len: int
+    block: int
+    kernel: int
+    stride: int
+
+    def selects(self, p):
+        """Whether the query at position(s) ``p`` selects (numpy)."""
+        return p + 1 > self.dense_len
+
+    def kept(self, p, topk: int):
+        """Keys the query at position(s) ``p`` keeps (numpy)."""
+        p = np.asarray(p)
+        cut = np.minimum(p + 1, topk - self.block + p % self.block + 1)
+        return np.where(self.selects(p), cut, p + 1)
+
+    def compressed(self, p):
+        """Compressed keys visible to the query at position(s) ``p``."""
+        return np.maximum((np.asarray(p) - self.kernel + 1) // self.stride + 1,
+                          0)
+
+
 @dataclass
 class ModelSpec:
     """Everything the engine needs to train/evaluate a model."""
@@ -422,6 +454,12 @@ class ModelSpec:
     # ``table_tokens`` wide, from the rule its ragged_forward_fn calls
     # (``sel_decode`` on ``engine/dispatch``); None without ``index_topk``
     sparse_decode_form: Callable[[int], str] | None = None
+    # a family that selects by BLOCKS of keys past a dense length, scoring
+    # them against compressed keys (``minicpm_sala``), says the rule the
+    # engine counts its selected work by (``BlockSelection``; ``index_topk``
+    # is then the keys a selecting query keeps at most, blocks x their size);
+    # None: ``min(position + 1, index_topk)`` keys a query
+    index_blocks: "BlockSelection | None" = None
     # counts only the step program knows (what a router picked): the names,
     # in order, of the per-token int32 counts ``ragged_forward_fn(...,
     # row_counts=True)`` returns as a third result, ``[len(step_counters),

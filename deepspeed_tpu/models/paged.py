@@ -554,8 +554,26 @@ def tiles_go_as_slices(tile: int, block_size: int) -> bool:
     """Whether ``write_rows_paged`` writes a step's prefill tiles of ``tile``
     rows into a pool of ``block_size``-row blocks as slices (the rule it goes
     by itself; the engine counts a step's rows by it): a tile must be whole
-    runs of ``block_size`` rows."""
-    return tile > 0 and tile % block_size == 0
+    runs of ``block_size`` rows, or a block whole tiles (a page of several
+    tiles, ``minicpm_sala``'s 512-token pages: the pool is then seen in
+    blocks of a tile's rows, ``sub_blocks``)."""
+    return tile > 0 and (tile % block_size == 0 or block_size % tile == 0)
+
+
+def sub_blocks(pool, block_tables, rows: int):
+    """A pool ``[blocks, BS, lanes]`` and its table seen in blocks of
+    ``rows`` rows (a divisor of ``BS``): ``([blocks x BS / rows, rows,
+    lanes], [S, MB x BS / rows])``. The pool's is a bitcast (a block's rows
+    are contiguous), the table's entry ``j`` is sub-block ``j % (BS / rows)``
+    of block ``j // (BS / rows)``."""
+    n, bs = pool.shape[:2]
+    r = bs // rows
+    if r == 1:
+        return pool, block_tables
+    tables = (block_tables[..., None] * r
+              + jnp.arange(r, dtype=block_tables.dtype)).reshape(
+                  block_tables.shape[:-1] + (-1,))
+    return pool.reshape((n * r, rows) + pool.shape[2:]), tables
 
 
 def write_rows_paged(pool, rows, slots, positions, block_tables,
@@ -581,7 +599,9 @@ def write_rows_paged(pool, rows, slots, positions, block_tables,
       them, which drops them into the scratch block. At offset 0 the second
       block's mask is empty. One algorithm on an offset it reads
       (``tile_pos0 % BS``): no branch, no second program. A ``tile`` that
-      ``BS`` does not divide keeps the row form (``tiles_go_as_slices``).
+      ``BS`` does not divide keeps the row form (``tiles_go_as_slices``),
+      unless it divides ``BS``: the pool is then seen in blocks of ``tile``
+      rows (``sub_blocks``, a bitcast) and a tile is one run.
     - ``rows[:n_dec]``, and every row with no ``prefill_tiles``: one scatter
       of single rows, ``pool.at[blk, off].set(rows)`` (a step with no row
       past ``n_dec`` traces that scatter and nothing else). So are the decode
@@ -611,8 +631,10 @@ def write_rows_paged(pool, rows, slots, positions, block_tables,
     if (prefill_tiles is not None and prefill_tiles[0] < n_dec
             and tiles_go_as_slices(prefill_tiles[-1], bs)):
         n_dec, tile_slot, tile_pos0, tile_valid, tile = prefill_tiles
-        pool = _write_tile_runs(pool, rows[n_dec:], tile_slot, tile_pos0,
-                                tile_valid, tile, block_tables)
+        # a block of several tiles is written as blocks of a tile's rows
+        view, tables = sub_blocks(pool, block_tables, min(bs, tile))
+        pool = _write_tile_runs(view, rows[n_dec:], tile_slot, tile_pos0,
+                                tile_valid, tile, tables).reshape(pool.shape)
     if not n_dec:
         return pool
     return pool.at[blk[:n_dec], off[:n_dec]].set(rows[:n_dec])

@@ -177,6 +177,34 @@ def _reset_global_state():
     get_fault_injector().reset()
 
 
+# A process may hold 65,530 memory mappings (``vm.max_map_count``) and every
+# compiled program keeps a few: a module of a serving family leaves ~10,000
+# behind in its worker's caches, and a worker that has run some sixty modules
+# met the limit near the end of the suite: the compiler's next ``mmap`` failed
+# and the worker died inside ``backend_compile`` ("Fatal Python error:
+# Aborted" / "Segmentation fault", a `node down` and one lost test, in
+# whatever module came next: twice of two runs once PR 60's modules had
+# joined). Past this many mappings a module's end drops jax's caches; what a
+# later module shares with an earlier one (the kernels' wrappers) compiles
+# once more, seconds a worker.
+MAPS_HIGH_WATER = 40_000
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:   # no procfs: nothing to count, nothing to do
+        return
+    if held > MAPS_HIGH_WATER:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture
 def mesh8():
     """A data=8 topology over the simulated devices."""

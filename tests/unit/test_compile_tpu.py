@@ -1210,7 +1210,7 @@ def _relayout_family(name):
 GRANITE_VOCAB = 3136   # no array of a step's rows has 3,136 x 4,096 elements
 
 
-def _step_text(v5e, mod, cfg, blocks, slots, table, rows, tiles):
+def _step_text(v5e, mod, cfg, blocks, slots, table, rows, tiles, block=128):
     """``(optimized HLO, abstract bf16 parameters)`` of ``mod``'s ragged step
     of ``rows`` decode rows and ``tiles`` tiles, compiled for the first
     described chip with the arguments laid out as an engine's are
@@ -1222,7 +1222,7 @@ def _step_text(v5e, mod, cfg, blocks, slots, table, rows, tiles):
         lambda a: a.astype(jnp.bfloat16),
         mod.init_params(cfg, jax.random.PRNGKey(0))))
     cache = jax.eval_shape(lambda: mod.init_paged_cache(
-        cfg, blocks[0] if window else blocks, 128, jnp.bfloat16,
+        cfg, blocks[0] if window else blocks, block, jnp.bfloat16,
         num_slots=slots))
 
     def i32(*shape):
@@ -1293,7 +1293,7 @@ def _cell_step_text(v5e, monkeypatch, cell, rows, tiles):
              **spec["cell"].get("engine", {})}
     text, params = _step_text(
         v5e, mod, cfg, sizes["num_blocks"], sizes["max_seqs"] + 1,
-        sizes["max_blocks_per_seq"], rows, tiles)
+        sizes["max_blocks_per_seq"], rows, tiles, block=sizes["block_size"])
     return text, params, cfg
 
 
@@ -1402,6 +1402,49 @@ def test_solar_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
     assert _projection_results(text, params, (
         "w_qkv", "w_fb", "w_gb", "wo", "router", "ws_gate", "ws_up",
         "ws_down")) == []
+
+
+SALA_CELL = "minicpm-sala-d8.longctx32k-pool"
+
+
+@pytest.mark.parametrize("rows,tiles", [(16, 3), (0, 4), (16, 0)],
+                         ids=["mixed-d16-t3", "prefill-t4", "decode-d16"])
+def test_sala_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
+        v5e, monkeypatch, rows, tiles):
+    """The step programs of ``minicpm-sala-d8.longctx32k-pool`` at the cell's
+    OWN sizes (``benchmark/cellspec.py``: pages of 512 tokens, tables of 64,
+    17 slots; runs ``S``, ``L`` x 6, ``S``: three layer bodies), compiled
+    before the first chip call: the block-sparse kernels are there (a decode
+    and a tile kernel a sparse layer) and ``ssm_decode`` at a group a head
+    (``G = H`` = 32); nothing the size of a layer's slice of the K/V pool
+    (``[1089, 512, 256]`` bf16, 285 MB), of the compressed keys or of the
+    float32 state (``[17, 128, 4096]``, 36 MB) gets a buffer of its own but
+    in place (the pool seen in blocks of a tile's and of the selection's rows
+    is a bitcast: ``paged.sub_blocks``); no projection of a step's rows is
+    made a second time; no stacked projection weight is re-laid out."""
+    text, params, cfg = _cell_step_text(v5e, monkeypatch, SALA_CELL, rows,
+                                        tiles)
+    assert [n for _, n in cfg.runs] == [1, 6, 1]
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    count = {k: sum(k in n for n in names) for k in (
+        "bsa_decode", "bsa_prefill", "ssm_decode")}
+    assert count == {"bsa_decode": 2 * bool(rows), "bsa_prefill": 2 * bool(tiles),
+                     "ssm_decode": bool(rows)}
+    # a leaf, whole or a layer's slice of it, however the step sees it: the
+    # state, the pages (in pages, in tiles' rows, in selection blocks), the
+    # compressed keys
+    leaf = re.compile(
+        r"= (f32\[(6,17|102),128,4096\]|bf16\[(2,1089|2178),(512|32),256\]"
+        r"|bf16\[8712,128,256\]|bf16\[17424,64,256\])")
+    assert [ln for _, op, ln in _materialized(text)
+            if leaf.search(ln) and op not in _IN_PLACE] == []
+    t = rows + tiles * TILE
+    assert [ln for ln in text.splitlines() if re.match(
+        rf"\s+(?:ROOT )?%[\w.\-]*\.remat[\w.\-]* = \w+\[{t},(4096|16384)\]",
+        ln)] == []
+    assert _projection_results(text, params, (
+        "wq", "wk", "wv", "w_z", "wo", "w_gate", "w_up", "w_down")) == []
 
 
 @pytest.mark.parametrize("cell,rows,kernel", [

@@ -401,7 +401,19 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                      # of 64 heads x tiles in three layers
                      **{m: "v5e_solar_longctx_spans" for m in (
                          "kernel.kda_chunk_share",
-                         "kernel.kda_chunk_roofline")}}
+                         "kernel.kda_chunk_roofline")},
+                     # PR 60: the block-sparse readers read a slice of the
+                     # MiniCPM-SALA cell: its spans say ``sel_queries`` and
+                     # ``cmp_kv_tokens`` beside the selected work and
+                     # ``state_kind`` lightning, its steps run ``bsa_decode``,
+                     # ``bsa_prefill`` and ``ssm_decode`` at a group a head
+                     **{m: "v5e_minicpm_sala_longctx32k_spans" for m in (
+                         "kernel.bsa_decode_share",
+                         "kernel.bsa_decode_roofline",
+                         "kernel.bsa_prefill_share",
+                         "kernel.bsa_prefill_roofline",
+                         "model.bsa_step_roofline_kv",
+                         "sched.bsa_selected_share")}}
 CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "moonlight-16b-a3b-d8.reason-pool",
                    "v5e_gpt2xl_chat_decode_spans": "gpt2-xl.chat-open",
@@ -422,7 +434,9 @@ CELL_OF_FIXTURE = {"v5e_moonlight_reason_moe_spans":
                    "granite-4.0-h-small-d10-ep2.chat-open",
                    "v5e_jamba_reason_spans": "ai21-jamba2-3b.reason-pool",
                    "v5e_solar_longctx_spans":
-                   "solar-open2-250b-d4-ep8.longctx-pool"}
+                   "solar-open2-250b-d4-ep8.longctx-pool",
+                   "v5e_minicpm_sala_longctx32k_spans":
+                   "minicpm-sala-d8.longctx32k-pool"}
 
 
 def _new_readers():
@@ -434,7 +448,8 @@ def _new_readers():
         with open(path) as f:
             text = f.read()
         if not any(m in text for m in ("host_spans", "latent_spans", "ssm_spans",
-                                       "dsa_spans", "swa_spans", "blk_spans")):
+                                       "dsa_spans", "swa_spans", "blk_spans",
+                                       "bsa_spans")):
             continue  # a reader from before the spans
         fixture = FIXTURE_OF_METRIC.get(m["name"])
         cell = (CELL_OF_FIXTURE[fixture] if fixture

@@ -46,10 +46,13 @@ def _cases(bs, tile):
     }
 
 
-# (block size, tile): the pool cells', GPT-2 XL's four runs a tile, and two
-# where the block does not divide the tile, which keep the row form
+# (block size, tile): the pool cells', GPT-2 XL's four runs a tile, two
+# where the block is whole tiles (seen in blocks of a tile's rows,
+# ``paged.sub_blocks``: the long-context cell's pages of four tiles), and one
+# where neither divides the other, which keeps the row form
 SHAPES = {"block_is_tile": (128, 128), "gpt2_xl": (32, 128),
-          "tile_under_block": (64, 16), "block_beside_tile": (48, 128)}
+          "tile_under_block": (64, 16), "page_of_four_tiles": (512, 128),
+          "block_beside_tile": (48, 128)}
 
 
 def _step(case, bs, tile, block):
@@ -167,14 +170,14 @@ def test_mixed_step_scatters_no_more_than_its_decode_rows():
 
 
 # ------------------------------------------------- what the engine says of it
-@pytest.mark.parametrize("block,tile", [(8, 8), (4, 8), (16, 8)],
+@pytest.mark.parametrize("block,tile", [(8, 8), (4, 8), (16, 8), (12, 8)],
                          ids=["block_is_tile", "two_runs_a_tile",
-                              "block_over_tile"])
+                              "two_tiles_a_block", "block_beside_tile"])
 def test_dispatch_span_counts_the_rows_written_as_slices(block, tile,
                                                          monkeypatch):
     """``engine/dispatch`` carries ``pool_slice_rows``, the step's real rows
     in prefill tiles where the write site takes tiles as slices (a block's
-    rows divide a tile's), 0 on a decode-only step and where it keeps single
+    rows divide a tile's, or a tile's a block's), 0 on a decode-only step and where it keeps single
     rows; ``inference_pool_rows_written_total``'s two forms sum to the
     tokens scheduled."""
     from deepspeed_tpu import telemetry
@@ -202,7 +205,7 @@ def test_dispatch_span_counts_the_rows_written_as_slices(block, tile,
             "inference_pool_rows_written_total"]["series"]
     finally:
         telemetry.configure(enabled=False)
-    sliced = tile % block == 0
+    sliced = tile % block == 0 or block % tile == 0
     assert [a["tokens"] for a in seen[:2]] == [21, 12]
     assert [a["pool_slice_rows"] for a in seen[:2]] == (
         [21, 11] if sliced else [0, 0])
