@@ -192,6 +192,7 @@ def device_phase(chips: int) -> dict:
 def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                   block: int = KV_BLOCK, ssm=(16, 128, 8192, 8),
                   experts=(16, 1024, 2688, 64, 6),
+                  ssd=(102, 32, 3, 128),
                   kda=(258, 128, 32, 128), chunk=(3, 128, 16),
                   selscan=(514, 16, 5120, 256, 3, 128),
                   swa=(16, 28, 4, 128, 4096, 8192, 128),
@@ -201,7 +202,11 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     Off the chip the kernels interpret; on it this is their first execution.
     Beside the attention kernels, once each: the Mamba-2 decode kernel at
     ``ssm`` = (state rows, state size, heads x head size, groups) against
-    XLA's gather -> update -> scatter, the grouped expert kernel's
+    XLA's gather -> update -> scatter, the chunk form at a group a head
+    (``ssd_chunk``) at ``ssd`` = (state rows, heads of 128 x 128, tiles, rows
+    a tile; the MiniCPM-SALA cell's 6 x 17 rows, 32 heads and a mixed step's
+    3 tiles x 128 rows in bfloat16, two tiles of one slot and a fresh one)
+    against ``ssd_chunk_xla``, both timed (``ssd_chunk_ms``), the grouped expert kernel's
     two-matrix ``relu**2`` form at ``experts`` = (held, latent, ffn, routed,
     top k), a held share, against the all-experts einsum, and the KDA decode
     kernel at ``kda`` = (state rows, key channels = values a head, heads,
@@ -323,6 +328,53 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                                jax.jit(ssm_decode_xla)(*args)):
         close(name, got, want, tol=1e-5)
 
+    def median_ms(forms, outs, args):
+        """Each form again from the state it left, which it donates: the
+        median of five calls, ms."""
+        ms = {}
+        for name, fn in forms.items():
+            s_run, times = outs[name][0], []
+            for _ in range(5):
+                t1 = time.perf_counter()
+                s_run, y = fn(s_run, *args)
+                jax.block_until_ready((s_run, y))
+                times.append((time.perf_counter() - t1) * 1e3)
+            ms[name] = round(sorted(times)[2], 3)
+        return ms
+
+    def tiles_of(n_i, slot, other, spare):
+        """A chunk kernel's step of ``n_i`` tiles: all but the last go on in
+        ``slot`` (parking zeros in ``spare`` until its last), the last is
+        fresh in ``other`` -> (rows, rows_w, fresh, cont, write)."""
+        return (jnp.asarray([slot] * (n_i - 1) + [other], jnp.int32),
+                jnp.asarray([spare] * (n_i - 2) + [slot, other], jnp.int32),
+                jnp.arange(n_i) == n_i - 1,
+                (jnp.arange(n_i) > 0) & (jnp.arange(n_i) < n_i - 1),
+                jnp.arange(n_i) >= n_i - 2)
+
+    from deepspeed_tpu.ops.pallas.ssm import ssd_chunk
+
+    rows_n, heads, n_i, r = ssd
+    draws = iter(jax.random.split(jax.random.PRNGKey(SEED + 7), 6))
+    state = jax.random.normal(next(draws), (rows_n, 128, heads * 128),
+                              jnp.float32)
+    x, b, c = (jax.random.normal(next(draws), (n_i, r, heads * 128),
+                                 jnp.bfloat16) * scale
+               for scale in (1.0, 128 ** -0.5, 128 ** -0.5))
+    tiles = (*tiles_of(n_i, *np.random.default_rng(SEED).permutation(
+                 rows_n)[:3].tolist()),
+             x, jax.random.uniform(next(draws), (n_i, r, heads), jnp.float32),
+             -jnp.exp(jax.random.uniform(next(draws), (heads,), jnp.float32,
+                                         np.log(1e-3), np.log(0.5))), b, c)
+    forms = {name: jax.jit(lambda s, *a, name=name: ssd_chunk(
+        s, *a, impl=name), donate_argnums=0) for name in ("pallas", "xla")}
+    outs = {name: fn(state + 0.0, *tiles) for name, fn in forms.items()}
+    for name, got, want in zip(("ssd_chunk_state", "ssd_chunk_y"),
+                               outs["pallas"], outs["xla"]):
+        # bfloat16 operands: an operand rounded the other way moves a sum
+        close(name, got, want, tol=2e-3)
+    ssd_ms = median_ms(forms, outs, tiles)
+
     held, lat, ffn, routed, top_k = experts
     h = jax.random.normal(next(keys), (256, lat), jnp.bfloat16)
     router = jax.random.normal(next(keys), (lat, routed), jnp.float32)
@@ -337,20 +389,6 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                                                   held, share))(h, topv, topi),
           jax.jit(lambda *a: moe._einsum_experts(*a, None, w_up, w_down,
                                                  share))(h, topv, topi))
-
-    def median_ms(forms, outs, args):
-        """Each form again from the state it left, which it donates: the
-        median of five calls, ms."""
-        ms = {}
-        for name, fn in forms.items():
-            s_run, times = outs[name][0], []
-            for _ in range(5):
-                t1 = time.perf_counter()
-                s_run, y = fn(s_run, *args)
-                jax.block_until_ready((s_run, y))
-                times.append((time.perf_counter() - t1) * 1e3)
-            ms[name] = round(sorted(times)[2], 3)
-        return ms
 
     from deepspeed_tpu.ops.pallas.kda import kda_decode, kda_decode_xla
 
@@ -389,12 +427,7 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
     g = -jnp.exp(jax.random.uniform(next(keys), (n_i, r, heads, kd),
                                     jnp.float32, np.log(1e-3), np.log(1.6)))
     g = g.at[:, :, :max(heads // 4, 1)].set(-1.6)
-    slot, other, spare = (int(x) for x in step[0][:3])
-    tiles = (jnp.asarray([slot] * (n_i - 1) + [other], jnp.int32),
-             jnp.asarray([spare] * (n_i - 2) + [slot, other], jnp.int32),
-             jnp.arange(n_i) == n_i - 1,                       # fresh
-             (jnp.arange(n_i) > 0) & (jnp.arange(n_i) < n_i - 1),
-             jnp.arange(n_i) >= n_i - 2,                       # write
+    tiles = (*tiles_of(n_i, *(int(x) for x in step[0][:3])),
              *((x.reshape(n_i, r, -1) for x in (
                  unit(next(keys)) * kd ** -0.5, unit(next(keys)), g,
                  jax.random.normal(next(keys), (n_i, r, heads, kd),
@@ -551,7 +584,7 @@ def kernels_phase(geometries, seq_len: int = 256, tile: int = PREFILL_TILE,
                  blk_decode_ms=blk_ms,
                  geometries=[list(g) for g in geometries],
                  shapes={"seq_len": seq_len, "tile": tile, "block": block},
-                 max_rel_err=worst, kda_decode_ms=kda_ms,
+                 max_rel_err=worst, ssd_chunk_ms=ssd_ms, kda_decode_ms=kda_ms,
                  kda_chunk_ms=chunk_ms, selscan_decode_ms=selscan_decode_ms,
                  selscan_tile_ms=selscan_tile_ms,
                  swa_decode_ms=swa_ms["swa_decode"],

@@ -20,9 +20,12 @@ With ``x`` the residual stream, ``L`` the PUBLISHED depth (``depth_layers``,
   ``2^(-8 (h + 1) / H) (1 - l / (L - 1) + 1e-5)`` at the PUBLISHED layer
   index ``l``); RMSNorm over each head's ``o`` (``use_output_norm``), ``out =
   (o * sigmoid(h W_z)) W_o`` (``use_output_gate``). It runs on the Mamba-2
-  path: ``mamba2.ssd_tiles`` (the chunk form) and ``ops/pallas/ssm.py
-  ssm_decode`` compute it with ``dA`` = the head's decay, ``dtx`` = v, ``B`` =
-  k, ``C`` = q / sqrt(d) and a group a head (``G = H``).
+  path with ``dA`` = the head's decay, ``dtx`` = v, ``B`` = k, ``C`` = q /
+  sqrt(d) and a group a head (``G = H``): served, both kernels of
+  ``ops/pallas/ssm.py``, ``ssm_decode`` a decode row and ``ssd_chunk`` (the
+  chunk form, which at a group a head cuts a head's q, k and v out of the rows
+  as the projections leave them) a step's tiles; the plain forward pass,
+  ``mamba2.ssd_tiles``.
 - **Sparse layer.** ``q`` ``num_heads`` heads, ``k, v`` ``num_kv_heads`` heads
   (a group of ``rep`` query heads a K/V head), RMSNorm a head on q and k, NO
   positions; the heads' output times ``sigmoid(h W_z)`` before ``W_o``. A
@@ -81,7 +84,6 @@ from itertools import accumulate, groupby
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.models import mamba2
 from deepspeed_tpu.models.api import (
@@ -718,14 +720,17 @@ def lightning_ragged(cfg: MiniCPMSalaConfig, h, lp, decay, ssm, slot0, scratch,
     """A Lightning layer over the normed rows ``h`` [T, D] of a flat ragged
     batch -> ``(out [T, D], ssm)``: ``ssm`` the state leaf, layers and slots
     merged, this layer's slot ``s`` row ``slot0 + s``; decode rows through
-    ``ssm_decode``, tiles through ``mamba2.ssd_tiles``, ``mamba2.ragged``'s
-    rules for a step's rows (``dt`` = 1 on a real row, 0 on padding)."""
-    from deepspeed_tpu.ops.pallas.ssm import ssm_decode
+    ``ssm_decode``, tiles through ``ssd_chunk`` (both ``ops/pallas/ssm.py``),
+    ``mamba2.ragged``'s rules for a step's rows (``dt`` = 1 on a real row, 0
+    on padding)."""
+    from deepspeed_tpu.ops.pallas.ssm import ssd_chunk, ssm_decode
 
     f32 = jnp.float32
     t = h.shape[0]
     nh, d = cfg.lightning_nh, cfg.lightning_head_dim
     q, k, v = _lightning_qkv(cfg, h, lp, positions)
+    # both kernels take a row's values flat, as the projection left them
+    v = v.reshape(t, nh * d)
     n_dec = t if prefill_tiles is None else prefill_tiles[0]
     ys = []
     if n_dec:
@@ -735,37 +740,25 @@ def lightning_ragged(cfg: MiniCPMSalaConfig, h, lp, decay, ssm, slot0, scratch,
         # padding neither decays nor feeds; position 0 starts from zeros
         da = jnp.where(fresh[:, None], 0.0,
                        jnp.where(real[:, None], lam[None], 1.0))
-        dtx = jnp.where(real[:, None, None], v[:n_dec].astype(f32), 0.0)
+        dtx = jnp.where(real[:, None], v[:n_dec].astype(f32), 0.0)
         ssm, y = ssm_decode(
-            ssm, slots[:n_dec] + slot0, jnp.repeat(da, d, axis=1),
-            dtx.reshape(n_dec, -1),
+            ssm, slots[:n_dec] + slot0, jnp.repeat(da, d, axis=1), dtx,
             k[:n_dec].astype(f32).transpose(0, 2, 1),
             q[:n_dec].astype(f32).transpose(0, 2, 1))
         ys.append(y)
     if t > n_dec:
         _, ts, tp, tv, r = prefill_tiles
         n_i = ts.shape[0]
-        rows, rows_w, fresh, cont, write = mamba2.tile_rows(ts, tp, slot0,
-                                                            scratch)
         live = (jnp.arange(r)[None, :] < tv[:, None]).astype(f32)
-        s_old = jnp.stack([lax.dynamic_index_in_dim(ssm, rows[i], 0, False)
-                           for i in range(n_i)])
-        y, s_new = mamba2.ssd_tiles(
-            cfg, v[n_dec:].reshape(n_i, r, nh, d),
+        # a head's k, q and v are whole lane tiles of the rows as the
+        # projections leave them: the kernel cuts them out itself, and its
+        # operand holds the leaf to its layout in every program
+        ssm, y = ssd_chunk(
+            ssm, *mamba2.tile_rows(ts, tp, slot0, scratch),
+            v[n_dec:].reshape(n_i, r, nh * d),
             jnp.broadcast_to(live[..., None], (n_i, r, nh)),
-            -decay.astype(f32), k[n_dec:].reshape(n_i, r, nh, d),
-            q[n_dec:].reshape(n_i, r, nh, d),
-            jnp.where(fresh[:, None, None], 0.0, s_old), cont)
-        # the tiles' new states row-major, as the leaf lies: at a group a
-        # head the chunk form's 32 products a tile come out with the state
-        # size minor, and a program without decode rows (whose kernel's
-        # operand holds the leaf to its layout) re-laid the WHOLE leaf out to
-        # suit them, 204 MB each way (compiled for a described v5e, PR 60)
-        s_new = with_layout_constraint(
-            jnp.where(write[:, None, None], s_new, 0.0),
-            Layout(major_to_minor=(0, 1, 2)))
-        for i in range(n_i):
-            ssm = lax.dynamic_update_index_in_dim(ssm, s_new[i], rows_w[i], 0)
+            -decay.astype(f32), k[n_dec:].reshape(n_i, r, nh * d),
+            q[n_dec:].reshape(n_i, r, nh * d))
         ys.append(y.reshape(n_i * r, -1))
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
     return _lightning_out(cfg, y, h, lp), ssm

@@ -118,7 +118,8 @@ def test_serve_check_fails_on_a_wrong_token():
 @pytest.mark.slow
 def test_kernels_phase_at_tiny_size():
     out = _run(chip_smoke.kernels_phase, [(4, 2, 16)], seq_len=32, tile=8,
-               block=8, ssm=(10, 16, 256, 2), experts=(4, 32, 48, 16, 6),
+               block=8, ssm=(10, 16, 256, 2), ssd=(6, 2, 3, 16),
+               experts=(4, 32, 48, 16, 6),
                kda=(10, 16, 2, 4), chunk=(3, 16, 4),
                selscan=(10, 8, 128, 4, 3, 16),
                swa=(4, 4, 2, 16, 12, 40, 4), blocks=(8, 4, 4, 2, 16, 40, 8))
@@ -126,10 +127,12 @@ def test_kernels_phase_at_tiny_size():
         "blk_decode", "blk_decode_as_four_walks",
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dk", "flash_bwd_dv",
         "paged_decode", "tiled_prefill", "ssm_decode_state", "ssm_decode_y",
+        "ssd_chunk_state", "ssd_chunk_y",
         "moe_gmm_relu2", "kda_decode_state", "kda_decode_y",
         "kda_chunk_state", "kda_chunk_y", "selscan_decode_state",
         "selscan_decode_y", "selscan_tile_state", "selscan_tile_y",
         "swa_decode", "swa_prefill"}
+    assert set(out["ssd_chunk_ms"]) == {"pallas", "xla"}
     assert set(out["kda_decode_ms"]) == {"pallas", "xla"}
     assert set(out["kda_chunk_ms"]) == {"pallas", "xla"}
     assert set(out["selscan_decode_ms"]) == {"pallas", "xla"}

@@ -393,6 +393,56 @@ def test_ssm_decode_compiles_for_v5e_and_updates_in_place(v5e, cell):
     assert mem.temp_size_in_bytes < 128 * 8192 * 4
 
 
+def _ssd_chunk(state, rows, rows_w, fresh, cont, write, x, dt, a, b, c):
+    from deepspeed_tpu.ops.pallas.ssm import ssd_chunk
+
+    return ssd_chunk(state, rows, rows_w, fresh, cont, write, x, dt, a, b, c,
+                     impl="pallas", interpret=False)
+
+
+# MiniCPM-SALA's Lightning state in its cell: 6 layers x 17 slots of [128,
+# 4096] float32 (32 heads of 128 x 128, a group a head)
+SSD_ROWS, SSD_HEADS = 6 * 17, 32
+
+
+def _ssd_chunk_args(devices, tiles):
+    """The cell's state and a step's ``tiles`` prefill tiles of 128 rows in
+    bfloat16, the heads' 128 lanes side by side as the projections leave
+    them."""
+    dev = jax.sharding.SingleDeviceSharding(devices[0])
+    hp = SSD_HEADS * 128
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    return (s((SSD_ROWS, 128, hp)), *(s((tiles,), jnp.int32),) * 2,
+            *(s((tiles,), jnp.bool_),) * 3, s((tiles, 128, hp), jnp.bfloat16),
+            s((tiles, 128, SSD_HEADS)), s((SSD_HEADS,)),
+            *(s((tiles, 128, hp), jnp.bfloat16),) * 2)
+
+
+@pytest.mark.parametrize("tiles", [1, 3])
+def test_ssd_chunk_compiles_for_v5e_and_updates_in_place(v5e, tiles):
+    """Eight heads and a tile a grid step (4 x 3 grid steps in the cell's
+    mixed step): a head's ``x`` / ``B`` / ``C`` a lane slice of the block,
+    four bfloat16 products of 128 x 128 x 128 a head, one of them over the
+    rows of both operands (``B^T xw``), a head's column of ``[128, 64]`` by a
+    masked lane sum and its row by a dynamic sublane index in Mosaic; the
+    donated state is the output (aliased), nothing else in the program is as
+    large as ONE row's state, and the tiles' operands reach the kernel as
+    they are handed in (no transpose to a head-major layout, no copy)."""
+    compiled = jax.jit(_ssd_chunk, donate_argnums=(0,)).lower(
+        *_ssd_chunk_args(v5e, tiles)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(
+        rf"= bf16\[{tiles},128,{SSD_HEADS * 128}\]\S* (copy|transpose)\(",
+        text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= SSD_ROWS * 128 * SSD_HEADS * 128 * 4
+    assert mem.temp_size_in_bytes < 128 * SSD_HEADS * 128 * 4
+
+
 def _kda_decode(state, rows, a, k, q, v, beta):
     from deepspeed_tpu.ops.pallas.kda import kda_decode
 
@@ -1407,16 +1457,21 @@ def test_solar_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
 SALA_CELL = "minicpm-sala-d8.longctx32k-pool"
 
 
-@pytest.mark.parametrize("rows,tiles", [(16, 3), (0, 4), (16, 0)],
-                         ids=["mixed-d16-t3", "prefill-t4", "decode-d16"])
+@pytest.mark.parametrize("rows,tiles", [(16, 3), (0, 4), (16, 0), (0, 3)],
+                         ids=["mixed-d16-t3", "prefill-t4", "decode-d16",
+                              "prefill-t3"])
 def test_sala_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
         v5e, monkeypatch, rows, tiles):
     """The step programs of ``minicpm-sala-d8.longctx32k-pool`` at the cell's
     OWN sizes (``benchmark/cellspec.py``: pages of 512 tokens, tables of 64,
     17 slots; runs ``S``, ``L`` x 6, ``S``: three layer bodies), compiled
     before the first chip call: the block-sparse kernels are there (a decode
-    and a tile kernel a sparse layer) and ``ssm_decode`` at a group a head
-    (``G = H`` = 32); nothing the size of a layer's slice of the K/V pool
+    and a tile kernel a sparse layer), ``ssm_decode`` at a group a head
+    (``G = H`` = 32) and ``ssd_chunk`` over the tiles; no head is cut out of
+    the tiles' rows as an array of its own (``bf16[3,128,1,128]``, 32 of them
+    a tile and layer and 21% of the cell's device time when
+    ``mamba2.ssd_tiles`` ran here: ledger, PR 60), in a program without
+    decode rows either; nothing the size of a layer's slice of the K/V pool
     (``[1089, 512, 256]`` bf16, 285 MB), of the compressed keys or of the
     float32 state (``[17, 128, 4096]``, 36 MB) gets a buffer of its own but
     in place (the pool seen in blocks of a tile's and of the selection's rows
@@ -1428,9 +1483,11 @@ def test_sala_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
     names = [ln.split(" = ")[0] for ln in text.splitlines()
              if " custom-call(" in ln and "tpu_custom_call" in ln]
     count = {k: sum(k in n for n in names) for k in (
-        "bsa_decode", "bsa_prefill", "ssm_decode")}
+        "bsa_decode", "bsa_prefill", "ssm_decode", "ssd_chunk")}
     assert count == {"bsa_decode": 2 * bool(rows), "bsa_prefill": 2 * bool(tiles),
-                     "ssm_decode": bool(rows)}
+                     "ssm_decode": bool(rows), "ssd_chunk": bool(tiles)}
+    assert [ln for _, _, ln in _materialized(text)
+            if re.search(r"= \w+\[\d+,128,1,128\]", ln)] == []
     # a leaf, whole or a layer's slice of it, however the step sees it: the
     # state, the pages (in pages, in tiles' rows, in selection blocks), the
     # compressed keys

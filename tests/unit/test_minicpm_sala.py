@@ -37,7 +37,7 @@ from deepspeed_tpu.models.api import BlockSelection
 from deepspeed_tpu.models.paged import SLOTS, sub_blocks
 from deepspeed_tpu.ops.pallas import bsa_attention as bsa
 from deepspeed_tpu.ops.pallas.paged_attention import decode_steps
-from deepspeed_tpu.ops.pallas.ssm import ssm_decode_xla
+from deepspeed_tpu.ops.pallas.ssm import ssd_chunk, ssd_chunk_xla, ssm_decode_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 ATOL = 2e-4
@@ -314,6 +314,130 @@ def test_lightning_through_the_mamba2_path_is_the_plain_recurrence():
         np.testing.assert_allclose(np.asarray(y).reshape(nh, d), want[t],
                                    atol=1e-4)
     assert not np.asarray(leaf[0]).any() and not np.asarray(leaf[2]).any()
+
+
+# a step's tiles as the engine hands them over (``mamba2.tile_rows``): (read
+# row, write row, fresh, cont, write) a tile; row 5 of the 6-row leaf is the
+# scratch slot
+SSD_STEPS = {
+    "one_whole_tile": [(1, 1, 0, 0, 1)],
+    # a slot's prompt over three tiles: the state is carried in the kernel,
+    # the tiles before the last park zeros in the scratch slot
+    "three_tiles_of_one_slot": [(3, 5, 1, 0, 0), (3, 5, 0, 1, 0),
+                                (3, 3, 0, 1, 1)],
+    # the second slot must not start from the first's carry; both are written
+    "two_slots": [(2, 5, 0, 0, 0), (2, 2, 0, 1, 1), (0, 0, 0, 0, 1)],
+    # position 0 of a slot whose row holds what the last request left (NaN)
+    "fresh_over_garbage": [(4, 4, 1, 0, 1)],
+    # ``dt`` 0 past the valid rows of a slot's last tile: they neither decay
+    # nor feed the state that is written
+    "short_last_tile": [(1, 5, 0, 0, 0), (1, 1, 0, 1, 1)],
+    # a padding tile names the scratch slot both ways and writes it zeros
+    "padding_tile": [(0, 0, 0, 0, 1), (5, 5, 1, 0, 0)],
+    # B and C of two groups for four heads: ``mamba2.ssd_tiles``' shapes
+    "shared_groups_are_refused": [(1, 1, 0, 0, 1)],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("case", SSD_STEPS)
+def test_ssd_chunk_kernel_is_the_xla_form(case, dtype):
+    """``ssd_chunk`` in interpret mode (heads of 128 x 128, a whole lane tile
+    each, cut out by the kernel's blocks) against ``ssd_chunk_xla`` on a leaf
+    whose scratch row holds garbage before the step, ``dt`` any positive
+    number: readings and written rows to 2e-4 of their largest magnitude
+    under bfloat16 operands (an operand rounded at another point than
+    ``ssd_tiles`` rounds it is 4e-3 off) and 1e-5 in float32, and no row but
+    the written ones changes by a bit."""
+    tiles = SSD_STEPS[case]
+    rng = np.random.default_rng(len(case))
+    n_i, r, h, n = len(tiles), 32, 4, 128
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.float32)
+
+    x = draw(n_i, r, h * n).astype(dtype)
+    b, c = (draw(n_i, r, h * n, scale=0.1).astype(dtype) for _ in range(2))
+    dt = jnp.abs(draw(n_i, r, h)) * 0.5
+    a = -jnp.arange(1, h + 1, dtype=jnp.float32) * 0.05
+    if case == "short_last_tile":
+        dt = dt.at[1, 11:].set(0.0)
+    if case == "padding_tile":
+        dt = dt.at[1].set(0.0)
+    leaf = draw(6, n, h * n)
+    if case == "fresh_over_garbage":
+        leaf = leaf.at[4].set(jnp.nan)
+    rows, rows_w, fresh, cont, write = (
+        jnp.asarray(col, jnp.int32) for col in zip(*tiles))
+    args = [leaf, rows, rows_w, fresh > 0, cont > 0, write > 0, x, dt, a, b, c]
+    if case == "shared_groups_are_refused":
+        args[-2:] = b[..., :2 * n], c[..., :2 * n]
+        for impl in ("pallas", "xla"):
+            with pytest.raises(ValueError, match="a group a head"):
+                ssd_chunk(*args, impl=impl, interpret=True)
+        return
+    got_s, got_y = ssd_chunk(*args, impl="pallas", interpret=True)
+    want_s, want_y = ssd_chunk_xla(*args)
+    assert got_y.dtype == jnp.float32 and got_y.shape == (n_i, r, h * n)
+    assert np.isfinite(np.asarray(got_y)).all()
+
+    def rel(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    tol = 2e-4 if dtype == jnp.bfloat16 else 1e-5
+    assert rel(got_y, want_y) < tol and rel(got_s, want_s) < tol
+    written = sorted(set(np.asarray(rows_w).tolist()))
+    untouched = [i for i in range(6) if i not in written]
+    np.testing.assert_array_equal(np.asarray(got_s)[untouched],
+                                  np.asarray(leaf)[untouched])
+    if 5 in written:
+        assert not np.asarray(got_s[5]).any()
+    assert all((np.asarray(got_s[i]) != np.asarray(leaf[i])).any()
+               for i in written)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_ssd_chunk_xla_is_ssd_tiles_at_a_group_a_head(dtype):
+    """``ssd_chunk_xla`` (one product over a head axis where
+    ``mamba2.ssd_tiles`` loops over its groups; ``ops`` may not import
+    ``models``) against the path ``lightning_ragged`` ran before the kernel:
+    a dynamic slice a tile's state, ``mamba2.ssd_tiles`` at ``G = H``, a
+    dynamic-update-slice a tile. The same roundings: to the bit in float32
+    on this backend, a rounding flip of a bfloat16 operand apart otherwise.
+    Head sizes that are no lane tiles (the tiny configurations'), which the
+    kernel refuses by name."""
+    rng = np.random.default_rng(5)
+    n_i, r, h, n, p = 3, 8, 4, 16, 8
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.float32)
+
+    x = draw(n_i, r, h * p).astype(dtype)
+    b, c = (draw(n_i, r, h * n, scale=0.3).astype(dtype) for _ in range(2))
+    dt = (jnp.abs(draw(n_i, r, h)) * 0.5).at[2, 5:].set(0.0)
+    a = -jnp.arange(1, h + 1, dtype=jnp.float32) * 0.05
+    leaf = draw(4, n, h * p)
+    rows, rows_w, fresh, cont, write = mamba2.tile_rows(
+        jnp.asarray([1, 1, 2]), jnp.asarray([8, 16, 0]), 0, 3)
+    args = (leaf, rows, rows_w, fresh, cont, write, x, dt, a, b, c)
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        ssd_chunk(*args, impl="pallas", interpret=True)
+    got_s, got_y = ssd_chunk_xla(*args)
+    s_old = jnp.stack([leaf[int(i)] for i in rows])
+    want_y, s_new = mamba2.ssd_tiles(
+        None, x.reshape(n_i, r, h, p), dt, a, b.reshape(n_i, r, h, n),
+        c.reshape(n_i, r, h, n), jnp.where(fresh[:, None, None], 0.0, s_old),
+        cont)
+    want_s = leaf
+    for i in range(n_i):
+        want_s = want_s.at[rows_w[i]].set(jnp.where(write[i], s_new[i], 0.0))
+    tol = 2e-4 if dtype == jnp.bfloat16 else 1e-6
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               atol=tol * float(jnp.abs(want_y).max()))
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s),
+                               atol=tol * float(jnp.abs(want_s).max()))
 
 
 def test_the_decay_table_keeps_the_published_indices():

@@ -39,11 +39,14 @@ ATOL = 2e-4
 
 # ------------------------------------------------ (e) the families before it
 def _tiny_families():
-    """``{name: (module, tiny config)}`` of the families PR 43's tree serves."""
+    """``{name: (module, tiny config)}`` of the families PR 43's tree serves,
+    and ``granite_hybrid`` (PR 61: with ``nemotron_h`` the other caller of
+    ``mamba2.ssd_tiles``, its digests written from PR 60's tree)."""
     from deepspeed_tpu.models import (
         deepseek,
         deepseek_v32,
         gpt2,
+        granite_hybrid,
         kimi_linear,
         llama,
         longcat_flash,
@@ -61,6 +64,8 @@ def _tiny_families():
                           longcat_flash.LongcatFlashConfig.tiny(89)),
         "nemotron_h": (nemotron_h, nemotron_h.NemotronHConfig.tiny()),
         "kimi_linear": (kimi_linear, kimi_linear.KimiLinearConfig.tiny()),
+        "granite_hybrid": (granite_hybrid,
+                           granite_hybrid.GraniteHybridConfig.tiny()),
     }
 
 
