@@ -3070,7 +3070,9 @@ class RaggedInferenceEngine:
         these), ``chunk_slots``, the distinct prefilling slots of the step:
         the states the chunk form must read and write once each (the prefill
         part of ``state_bytes`` over a slot's bytes; not for ``"mamba1"``,
-        which has no chunk form), ``state_pad_rows``, the rows of the
+        which has no chunk form, and neither of the two for ``"shortconv"``,
+        whose state is a convolution's carried rows alone: no matrix, no
+        chunk form, no scan), ``state_pad_rows``, the rows of the
         program's decode bucket (``nd``) past its ``n_dec`` real ones (they
         name the scratch slot and the decode kernel moves its state like any
         row's), ``slot_resets``,
@@ -3078,7 +3080,8 @@ class RaggedInferenceEngine:
         it carries: the arrivals since the last dispatch, and a preempted
         request run again), and ``state_kind``, the recurrence's name where
         the model gives one (``ModelSpec.state_kind``: ``"mamba2"``,
-        ``"kda"``, ``"mamba1"``), and for ``"mamba1"`` ``scan_tiles``, the
+        ``"kda"``, ``"mamba1"``, ``"shortconv"``), and for ``"mamba1"``
+        ``scan_tiles``, the
         tiles the step program runs the selective scan over, a layer (no
         chunk form computes that recurrence: ``selscan_tile``'s grid is
         channel blocks x these). The dispatch feeds the same to
@@ -3090,14 +3093,18 @@ class RaggedInferenceEngine:
             return {}
         per_slot = 2 * self._slot_bytes
         kind = self.spec.state_kind
+        if kind == "shortconv":
+            tiles = {}
+        elif kind == "mamba1":
+            tiles = {"chunk_tiles": nt, "scan_tiles": nt}
+        else:
+            tiles = {"chunk_tiles": nt, "chunk_slots": n_pre}
         return {"state_bytes": (n_dec + n_pre) * per_slot,
                 "dec_state_bytes": n_dec * per_slot,
                 "ssm_prefill_tokens": n - n_dec,
-                "chunk_tiles": nt,
                 "state_pad_rows": nd - n_dec,
                 "slot_resets": self._slot_resets,
-                **({"scan_tiles": nt} if kind == "mamba1"
-                   else {"chunk_slots": n_pre}),
+                **tiles,
                 **({} if kind is None else {"state_kind": kind})}
 
     def _count_selecting(self, sel: dict, pos0: int, take: int) -> None:

@@ -474,7 +474,10 @@ class ModelSpec:
     # scalar decay a head, the state fed by an outer product), ``"kda"`` (a
     # decay a channel, the delta rule), ``"mamba1"`` (a decay a channel AND
     # state index, fed by an outer product: a scan, no chunk form of matmuls
-    # computes it). ``state_kind`` on ``engine/dispatch``
+    # computes it), ``"lightning"`` (a constant decay a head, on the Mamba-2
+    # path), ``"shortconv"`` (no recurrence at all: a gated short
+    # convolution's carried input rows, a window leaf alone, so the span says
+    # neither chunk nor scan tiles). ``state_kind`` on ``engine/dispatch``
     # and the label of ``inference_slot_state_bytes_total``; None: no state
     state_kind: str | None = None
     # a family some of whose attention layers read the last ``sliding_window``

@@ -226,13 +226,20 @@ def _grouped_experts(h, topv, topi, w_gate, w_up, w_down, first_expert,
     e, k = num_experts, topi.shape[1]
     if t > _GROUPED_MAX_ROWS:
         pad = -t % _GROUPED_MAX_ROWS
-        # with ``held`` the rows that pad the last call pick an expert the
-        # layer does not hold (-1), so they get no row: as picks of expert 0
-        # they would all land on the rank that holds it
-        picks_of_padding = 0 if held is None else -1
+        # the rows that pad the last call (zeros, at weight zero) pick as a
+        # real row does, ``top_k`` DIFFERENT experts: with every expert held,
+        # experts 0 .. top_k - 1, one row of each, so that no expert of a call
+        # gets more rows than the call has (``max_rows``: all the kernel's
+        # VMEM holds of an expert; as ``top_k`` picks of expert 0 a row, 384
+        # padding rows of top-4 ran its DMA out of bounds on the chip). With
+        # ``held`` they pick an expert the layer does not hold (-1) and get
+        # no row
         parts = [jnp.pad(a, ((0, pad), (0, 0)), constant_values=c).reshape(
             -1, _GROUPED_MAX_ROWS, a.shape[1])
-            for a, c in ((h, 0), (topv, 0), (topi, picks_of_padding))]
+            for a, c in ((h, 0), (topv, 0), (topi, -1))]
+        if held is None:
+            parts[2] = jnp.where(parts[2] < 0, jnp.arange(k, dtype=topi.dtype),
+                                 parts[2])
         out = lax.map(lambda p: _grouped_experts(
             *p, w_gate, w_up, w_down, first_expert, e, held, zero_experts,
             gate_act), tuple(parts))

@@ -157,19 +157,21 @@ def split(cfg, h, lp):
     return z, xbc, dt
 
 
-def causal_conv(cfg, win, w, b, rows: int):
-    """Causal depthwise convolution and silu: ``win`` [..., rows + K - 1,
+def causal_conv(cfg, win, w, b, rows: int, act=jax.nn.silu):
+    """Causal depthwise convolution and ``act`` (silu; None: none, the gated
+    short convolution of ``models/shortconv.py``): ``win`` [..., rows + K - 1,
     C] (the ``K - 1`` rows before the first, then the rows), ``w`` [K, C] and
-    ``b`` [C] -> [..., rows, C] in ``win``'s dtype, float32 inside. The
-    channels may be folded over two axes in all three, as a window leaf keeps
-    them (``paged.window_fold``): the result's are too."""
+    ``b`` [C] (None: no bias) -> [..., rows, C] in ``win``'s dtype, float32
+    inside. The channels may be folded over two axes in all three, as a
+    window leaf keeps them (``paged.window_fold``): the result's are too."""
     w = w.astype(jnp.float32)
     axis = win.ndim - w.ndim
-    acc = b.astype(jnp.float32)
+    acc = None if b is None else b.astype(jnp.float32)
     for k in range(cfg.conv_kernel):
-        acc = acc + lax.slice_in_dim(win, k, k + rows, axis=axis).astype(
+        tap = lax.slice_in_dim(win, k, k + rows, axis=axis).astype(
             jnp.float32) * w[k]
-    return jax.nn.silu(acc).astype(win.dtype)
+        acc = tap if acc is None else acc + tap
+    return (acc if act is None else act(acc)).astype(win.dtype)
 
 
 def xbc_split(cfg, xc):

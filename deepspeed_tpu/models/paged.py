@@ -2,8 +2,8 @@
 
 One home for what every family's serving path shares (gpt2, llama, mixtral
 on K and V heads; deepseek, deepseek_v32, longcat_flash on a latent row;
-nemotron_h, granite_hybrid, jamba, kimi_linear and solar_open2 with a
-recurrent state a slot beside either;
+nemotron_h, granite_hybrid, jamba, kimi_linear, solar_open2 and lfm2_moe with
+a recurrent state a slot beside either;
 smallthinker with sliding-window layers in a second pool): the
 paged-pool write, the decode/tiled-prefill attention split over the block
 pool (reference ``inference/v2/ragged_ops`` layout), the scan over a stack of
@@ -47,8 +47,10 @@ v1-style engines.
   whose state a key channel decays by its own factor and is fed by the
   delta rule) keeps it beside the block leaves, K and V heads or a latent
   row alike (``kimi_linear``: ``{"kv": [L_mla, NB, BS, 640], "slots":
-  {...}}``; ``solar_open2``, the same KDA state beside ``{"k", "v"}``), under
-  the cache's ``"slots"`` key: leaves ``[L_s, S, ...]``,
+  {...}}``; ``solar_open2``, the same KDA state beside ``{"k", "v"}``;
+  ``lfm2_moe``, whose gated short convolution carries its last two input
+  rows and NOTHING else: its slot leaves are a window leaf alone, below),
+  under the cache's ``"slots"`` key: leaves ``[L_s, S, ...]``,
   one row a layer and engine slot, ``S = max_seqs + 1``; the last
   row is the scratch slot padding rows use, as block 0 is for the pool.
   ``block_leaves`` / ``slot_leaves`` tell the two apart. The engine owns
@@ -65,12 +67,13 @@ v1-style engines.
   no reset program. Padding rows read and write the scratch slot and leave
   it zero.
 - *Window leaves.* A causal convolution's carried rows (a slot's last
-  ``K - 1`` inputs of ``W`` channels, oldest first: both families') are a
-  slot leaf whose slot is WHOLE TILES, as the float32 state's is: ``[L_s,
+  ``K - 1`` inputs of ``W`` channels, oldest first: every such family's) are
+  a slot leaf whose slot is WHOLE TILES, as the float32 state's is: ``[L_s,
   S, (K - 1) x r, W / r]``, each row's channels folded over the ``r`` rows
   of the dtype's sublane tile (16 bfloat16: ``kimi_linear`` ``[10, 129, 48,
-  768]``, ``nemotron_h`` ``[5, 129, 48, 640]``; row ``j`` is rows ``[r j,
-  r j + r)``). With the ``K - 1`` rows themselves on the sublanes (``[..,
+  768]``, ``nemotron_h`` ``[5, 129, 48, 640]``, ``lfm2_moe`` ``[9, 513, 32,
+  128]``, the one family with no other slot leaf: ``models/shortconv.py``;
+  row ``j`` is rows ``[r j, r j + r)``). With the ``K - 1`` rows themselves on the sublanes (``[..,
   3, 12288]``) the compiler keeps the argument in an axis order of its own,
   copies the whole leaf to a padded layout and back every step and, short
   of memory, compresses and uncompresses it between the layers (9.4% of

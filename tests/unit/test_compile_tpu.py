@@ -244,7 +244,10 @@ DECODE_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, 32, 4, 513),
                 # 20 query heads on ONE K/V head: a pool row is one lane tile
                 "jamba.reason": (20, 1, 128, 128, 32, 256, 8193),
                 # eight query heads a K/V head, a table of 8K tokens
-                "solar.longctx": (64, 8, 128, 128, 64, 16, 1025)}
+                "solar.longctx": (64, 8, 128, 128, 64, 16, 1025),
+                # 64-lane heads, four query heads a K/V head: the WIDE form
+                # at 512 rows ([32, 512] query matrix a row)
+                "lfm2.reason": (32, 8, 64, 128, 32, 512, 6145)}
 
 
 @pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
@@ -756,7 +759,9 @@ PREFILL_CELLS = {"gpt2-xl.chat": (25, 25, 64, 32, None, 32, 513, 8),
                  "smallthinker.window": (28, 4, 128, 128, 4096, 64, 529, 4),
                  "jamba.reason": (20, 1, 128, 128, None, 32, 8193, 4),
                  # a tile's query block is [8, 1024, 128]
-                 "solar.longctx": (64, 8, 128, 128, None, 64, 1025, 4)}
+                 "solar.longctx": (64, 8, 128, 128, None, 64, 1025, 4),
+                 # 64-lane heads padded to 128 in the kernel's scratches
+                 "lfm2.reason": (32, 8, 64, 128, None, 32, 6145, 4)}
 
 
 @pytest.mark.parametrize("cell,tiles", [(c, 3) for c in sorted(PREFILL_CELLS)]
@@ -1204,10 +1209,23 @@ def _relayout_family(name):
         deepseek,
         granite_hybrid,
         jamba,
+        lfm2_moe,
         smallthinker,
         solar_open2,
     )
 
+    if name == "lfm2":
+        # lfm2-8b-a1b-d12.json's two operators, dense FFN and pool at their
+        # published widths: c c a c c (a dense run of two, an attention layer,
+        # an expert run of two); 8 of 32 experts, a 128th of the table. Not
+        # ``wk`` / ``wv``: [2048, 512] has as many elements as the 512 rows
+        # of 2,048 lanes one call of the grouped expert kernel takes
+        return lfm2_moe, lfm2_moe.Lfm2MoeConfig(
+            vocab_size=512, num_layers=5, layer_types=(
+                "conv", "conv", "full_attention", "conv", "conv"),
+            num_experts=8, top_k=2), 6145, 513, 32, (
+                "w_in", "w_out", "wq", "wo", "router", "w_gate", "w_up",
+                "w_down"), ()
     if name == "solar":
         # solar-open2-250b-d4-ep8.json's two mixers, shared expert and pool at
         # their published widths, TWO periods so that the G layer's weights
@@ -1294,7 +1312,8 @@ def _step_text(v5e, mod, cfg, blocks, slots, table, rows, tiles, block=128):
 @pytest.mark.parametrize("family,rows,tiles", [
     ("mixtral", 8, 3), ("mixtral", 4, 0), ("smallthinker", 16, 3),
     ("mla", 16, 1), ("granite", 64, 3), ("granite", 64, 0),
-    ("jamba", 256, 3), ("jamba", 256, 0), ("solar", 16, 3), ("solar", 16, 0)])
+    ("jamba", 256, 3), ("jamba", 256, 0), ("solar", 16, 3), ("solar", 16, 0),
+    ("lfm2", 256, 3), ("lfm2", 256, 0)])
 def test_step_program_relays_out_no_projection_weight(v5e, monkeypatch, family,
                                                       rows, tiles):
     """The paged contract's *Rows to heads* (``models/paged.py``), on the
@@ -1452,6 +1471,60 @@ def test_solar_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
     assert _projection_results(text, params, (
         "w_qkv", "w_fb", "w_gb", "wo", "router", "ws_gate", "ws_up",
         "ws_down")) == []
+
+
+LFM2_CELL = "lfm2-8b-a1b-d12.reason-pool"
+
+
+@pytest.mark.parametrize("rows,tiles", [(512, 4), (0, 8), (512, 0)],
+                         ids=["mixed-d512-t4", "prefill-t8", "decode-d512"])
+def test_lfm2_cell_step_moves_its_rows_and_nothing_of_a_leafs_size(
+        v5e, monkeypatch, rows, tiles):
+    """The step programs of ``lfm2-8b-a1b-d12.reason-pool`` at the cell's OWN
+    sizes (``benchmark/cellspec.py``: 7.86 GB of weights, a pool of 6,145
+    blocks, 513 slots, tables of 32; runs 2 c (dense), a, 3 c, a, 3 c, a, c:
+    seven layer bodies), compiled before the first chip call: the paged
+    kernels are there at 64 lanes a head and four query heads a K/V head (a
+    decode and a tile kernel an attention layer) and the grouped expert
+    kernel, one in each of the six bodies that hold experts (at 513-1,024
+    rows it sits in a loop of two turns, ``lax.map`` over
+    ``experts._GROUPED_MAX_ROWS`` rows: the layer's experts are read twice a
+    step); nothing the size of a layer's slice of
+    the K/V pool (``[6145, 128, 512]`` bf16, 805 MB) gets a buffer of its own
+    but in place; the window leaf ``[9, 513, 32, 128]`` enters row-major in
+    whole tiles and no array of its shape (or of a layer's, ``[513, 32,
+    128]``) is copied, compressed or uncompressed: a step's rows are one
+    gather and one scatter a layer; no stacked expert or dense weight is
+    re-laid out (D14)."""
+    text, params, cfg = _cell_step_text(v5e, monkeypatch, LFM2_CELL, rows,
+                                        tiles)
+    assert [n for _, n in cfg.runs] == [2, 1, 3, 1, 3, 1, 1]
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " custom-call(" in ln and "tpu_custom_call" in ln]
+    count = {k: sum(k in n for n in names) for k in (
+        "paged_decode", "tiled_prefill", "moe_gmm")}
+    t = rows + tiles * TILE
+    # an expert run is one body: six runs hold experts
+    assert t >= 256 and count == {"paged_decode": 3 * bool(rows),
+                                  "tiled_prefill": 3 * bool(tiles),
+                                  "moe_gmm": 6}
+    pool_slice = 6145 * 128 * 512 * 2
+    assert [ln for size, op, ln in _materialized(text)
+            if size >= pool_slice and op not in _IN_PLACE
+            and not op.endswith("-done") and "ConcatBitcast" not in ln] == []
+    entry = _computations(text)[re.search(r"ENTRY (%[\w.\-]+)", text).group(1)]
+    assert [m.group(1)[:9] for ln in entry for m in [re.search(
+        r"= bf16\[9,513,32,128\](\{[^ ]*\}) parameter\(", ln)] if m] == [
+            "{3,2,1,0:"]
+    assert [ln for _, op, ln in _materialized(text)
+            if re.search(r"= bf16\[(9,513|4617|513),32,128\]", ln)
+            and op not in _IN_PLACE] == []
+    assert "remat_compressed" not in text and "remat_uncompressed" not in text
+    # not ``wk`` / ``wv``: [2048, 512] has the elements of 512 rows' 2,048
+    # lanes (``test_step_program_relays_out_no_projection_weight[lfm2-*]``
+    # holds them at 256 rows)
+    assert _projection_results(text, params, (
+        "router", "w_gate", "w_up", "w_down")) == []
 
 
 SALA_CELL = "minicpm-sala-d8.longctx32k-pool"

@@ -162,12 +162,16 @@ def test_held_grouped_form_equals_the_einsum_form(routing, geometry,
                                atol=2e-5 * max(np.abs(want).max(), 1e-3))
 
 
-def test_rows_that_pad_a_second_call_get_no_row(monkeypatch):
+@pytest.mark.parametrize("held", [(0, ROUTED), None], ids=["held", "every_expert"])
+def test_rows_that_pad_a_second_call_get_no_row(monkeypatch, held):
     """A 528-row step (the window cell's four tiles beside 16 decode rows) is
     two calls of 512 rows, the second 16 rows and 496 of padding. The rank
     that holds expert 0 must not take the padding's picks for its own: they
     were 496 x top_k rows of one expert, more than a call's 512 rows, which
-    is all the kernel's VMEM holds of an expert."""
+    is all the kernel's VMEM holds of an expert. A layer that holds EVERY
+    routed expert gives a padding row one row of each of experts 0 .. top_k -
+    1, as a real row picks (ROADMAP D13: the LFM2 cell's steps are 513-1,024
+    rows, and on the chip the kernel's DMA ran out of bounds)."""
     seen = []
 
     def counting(x, w_gate, w_up, w_down, row0, counts, tm, **kw):
@@ -175,19 +179,22 @@ def test_rows_that_pad_a_second_call_get_no_row(monkeypatch):
         return jnp.zeros(x.shape, jnp.float32)
 
     monkeypatch.setattr(experts, "grouped_swiglu", counting)
-    t, e, k = 528, 4, 3
+    t, e, k = 528, 4 if held else ROUTED, 3
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     h = jax.random.normal(ks[0], (t, D), jnp.float32)
     topi = jnp.argsort(jax.random.normal(ks[1], (t, ROUTED)), axis=1)[:, :k]
     topv = jnp.full((t, k), 1.0 / k)
     w = [jnp.zeros(s_) for s_ in [(e, D, F), (e, D, F), (e, F, D)]]
     jax.block_until_ready(experts._grouped_experts(
-        h, topv, topi.astype(jnp.int32), *w, 0, e, (0, ROUTED)))
+        h, topv, topi.astype(jnp.int32), *w, 0, e, held))
     jax.effects_barrier()
     want = [np.bincount(np.asarray(topi[a:b]).ravel(), minlength=ROUTED)[:e]
             for a, b in ((0, 512), (512, t))]
+    if held is None:
+        want[1][:k] += 2 * 512 - t
     assert len(seen) == 2
     for got in seen:
+        assert got.max() <= 512
         assert any((got == w_).all() for w_ in want), (got, want)
 
 

@@ -364,8 +364,8 @@ class TestBlockPayloadsOnTheStorageForm:
 
 
 # ----------------------------------------------- rows to heads, and its pin
-from deepspeed_tpu.models import (kimi_linear, nemotron_h,  # noqa: E402
-                                  sdar, smallthinker)
+from deepspeed_tpu.models import (kimi_linear, lfm2_moe,  # noqa: E402
+                                  nemotron_h, sdar, smallthinker)
 
 # every family with a ragged step; SDAR's decoding sequence is a block of 4 rows
 PINNED = {**FAMILIES, **LATENT,
@@ -373,7 +373,9 @@ PINNED = {**FAMILIES, **LATENT,
           "kimi_linear": (kimi_linear, kimi_linear.KimiLinearConfig.tiny()),
           "smallthinker": (smallthinker,
                            smallthinker.SmallThinkerConfig.tiny()),
-          "sdar": (sdar, sdar.SdarConfig.tiny(89))}
+          "sdar": (sdar, sdar.SdarConfig.tiny(89)),
+          # a head norm between the product and the rotation, at 16 lanes
+          "lfm2_moe": (lfm2_moe, lfm2_moe.Lfm2MoeConfig.tiny())}
 
 
 @pytest.mark.parametrize("name", sorted(PINNED))
