@@ -381,7 +381,10 @@ class ServingCluster:
                 retry_after_s=self.router.cfg.retry_after_s)
         if not req.t_submit:
             req.t_submit = time.perf_counter()
-        out = TokenStream(req.request_id)
+        # read as it comes exactly when the request streams: the worker's
+        # ``_pipe`` then hands tokens on one by one, and otherwise wakes the
+        # handler's thread once, at the request's end
+        out = TokenStream(req.request_id, incremental=req.stream)
         with self._stage_lock:
             self._stages[req.request_id] = _Stage()
         self.disagg_requests += 1

@@ -52,10 +52,19 @@ class TokenStream:
     The loop thread pushes ``("token", id)`` events and exactly one terminal
     ``("done", finish_reason)`` or ``("error", message)``; consumers iterate
     ``events()`` (SSE path) or block on ``collect()`` (non-streaming path).
+
+    ``incremental`` says whether the consumer reads tokens as they come (a
+    request that streams): each token is then one queue event, which wakes
+    the consumer's thread. Otherwise (``"stream": false``) a token is a plain
+    append to the stream's own list on the producer's thread, the terminal
+    event is the only queue event, and ``events()`` yields the held tokens
+    ahead of it: the same sequence, all of it at the request's end, and the
+    consumer is woken once.
     """
 
-    def __init__(self, request_id: str):
+    def __init__(self, request_id: str, incremental: bool = True):
         self.request_id = request_id
+        self.incremental = bool(incremental)
         self.finish_reason: str | None = None
         self.error: str | None = None
         # structured failure detail: an HTTP-equivalent status and a
@@ -65,10 +74,17 @@ class TokenStream:
         self.error_code: int | None = None
         self.error_reason: str | None = None
         self._q: queue.SimpleQueue = queue.SimpleQueue()
+        # a stream that is not incremental holds its tokens here until its
+        # terminal event: written by the producer alone, read by the consumer
+        # only after it took that event off the queue
+        self._held: list[int] = []
 
     # ---------------------------------------------- producer (loop thread)
     def _push(self, token: int) -> None:
-        self._q.put(("token", int(token)))
+        if self.incremental:
+            self._q.put(("token", int(token)))
+        else:
+            self._held.append(int(token))
 
     def _finish(self, reason: str) -> None:
         self.finish_reason = reason
@@ -85,7 +101,9 @@ class TokenStream:
     def events(self, timeout: float | None = None):
         """Yield ``("token", id)`` events until the terminal ``("done", _)``
         / ``("error", _)`` event, which is yielded last. ``timeout`` bounds
-        the wait for EACH event (TimeoutError past it)."""
+        the wait for EACH event this blocks on (TimeoutError past it): every
+        token of an incremental stream, the terminal event alone, and so the
+        whole request, of one that is not."""
         while True:
             try:
                 kind, value = self._q.get(timeout=timeout)
@@ -93,9 +111,12 @@ class TokenStream:
                 raise TimeoutError(
                     f"request {self.request_id}: no event within {timeout}s"
                 ) from None
-            yield kind, value
             if kind in ("done", "error"):
+                for token in self._held:
+                    yield "token", token
+                yield kind, value
                 return
+            yield kind, value
 
     def collect(self, timeout: float | None = None) -> tuple[list[int], str]:
         """Block until terminal; returns ``(tokens, finish_reason)`` or
@@ -149,10 +170,15 @@ class ReplicaStats:
 class _Open:
     """Loop-thread bookkeeping for one in-engine request."""
 
-    __slots__ = ("stream", "delivered", "t_submit", "noted")
+    __slots__ = ("stream", "seq", "delivered", "t_submit", "noted")
 
-    def __init__(self, stream: TokenStream, t_submit: float = 0.0):
+    def __init__(self, stream: TokenStream, seq, t_submit: float = 0.0):
         self.stream = stream
+        # the engine's own descriptor of the request, looked up ONCE: it is
+        # one object from the queue to its retirement, and looking it up by
+        # uid walks the running sequences (at 512 open requests 9 ms a turn
+        # of ``_deliver``: PERF.md section 6, PR 63)
+        self.seq = seq
         self.delivered = 0
         self.t_submit = t_submit
         # profiler instants written for this request so far: 0 none,
@@ -260,7 +286,7 @@ class EngineLoop:
             # stamp here (not only in the frontend) so deadline-aware inbox
             # shedding measures queue wait for direct submitters too
             req.t_submit = time.perf_counter()
-        stream = TokenStream(req.request_id)
+        stream = TokenStream(req.request_id, incremental=req.stream)
         with self._lock:
             heapq.heappush(
                 self._inbox, (req.priority, next(self._seqno), req, stream))
@@ -370,26 +396,24 @@ class EngineLoop:
         fall back to a cold submit."""
         if self._draining.is_set():
             raise ReplicaDraining(f"{self.name} is draining")
-        stream = TokenStream(req.request_id)
+        stream = TokenStream(req.request_id, incremental=req.stream)
         rid = req.request_id
+
+        def reject(msg: str) -> None:
+            stream._fail(msg, code=503, reason="import_rejected")
+            self._count_events(0, 1)
 
         def _do(eng):
             if self._draining.is_set():
-                stream._fail(f"{self.name} is draining", code=503,
-                             reason="import_rejected")
-                return
+                return reject(f"{self.name} is draining")
             try:
                 ok = eng.import_handoff(handoff)
             except Exception as e:  # noqa: BLE001 - structurally unservable
-                stream._fail(f"handoff import failed on {self.name}: {e}",
-                             code=503, reason="import_rejected")
-                return
+                return reject(f"handoff import failed on {self.name}: {e}")
             if not ok:
-                stream._fail(
-                    f"{self.name}: no slot/blocks to adopt handoff {rid}",
-                    code=503, reason="import_rejected")
-                return
-            self._open[rid] = _Open(stream)
+                return reject(
+                    f"{self.name}: no slot/blocks to adopt handoff {rid}")
+            self._open[rid] = _Open(stream, eng.get_request(rid))
 
         def drop(msg: str):
             stream._fail(msg, code=503, reason="replica_died")
@@ -439,6 +463,7 @@ class EngineLoop:
             self._cancel_ids = set()
         for _, _, req, stream in items:
             rid = req.request_id
+            opened = False
             if rid in cancels:
                 cancels.discard(rid)
                 stream._finish(FINISH_CANCELLED)
@@ -476,9 +501,13 @@ class EngineLoop:
                             tenant=getattr(req, "tenant", "default"),
                             sla_class=getattr(
                                 req, "sla_class", "interactive"))
-                    self._open[rid] = _Open(stream, req.t_submit)
+                    self._open[rid] = _Open(stream, eng.get_request(rid),
+                                            req.t_submit)
+                    opened = True
                 except ValueError as e:
                     stream._fail(str(e))
+            if not opened:  # the request ended here, unseen by the engine
+                self._count_events(0, 1)
             with self._lock:
                 self._pending_blocks -= self._worst_blocks(req)
                 self._pending_tokens -= req.total_tokens
@@ -493,24 +522,54 @@ class EngineLoop:
             return FINISH_STOP
         return FINISH_LENGTH
 
-    def _deliver(self) -> None:
+    def _deliver(self) -> int:
+        """Hand every open request what the engine made of it since the last
+        turn; returns the queue events that took (each one wakes a consumer's
+        thread): a token of a stream that is read as it comes, and every
+        request's end."""
         eng = self._engine
-        for rid in list(self._open):
-            op = self._open[rid]
-            seq = eng.get_request(rid)
-            if seq is None:  # pragma: no cover - put() succeeded, must exist
-                op.stream._fail(f"request {rid} lost by engine")
-                del self._open[rid]
-                continue
+        tokens = finals = 0
+        for rid, op in list(self._open.items()):
+            seq = op.seq
             if op.noted < 2:
                 self._note_progress(op, seq)
-            gen = seq.generated
-            while op.delivered < len(gen):
-                op.stream._push(gen[op.delivered])
-                op.delivered += 1
+            if op.delivered < len(seq.generated):
+                new = seq.generated[op.delivered:]
+                for token in new:
+                    op.stream._push(token)
+                op.delivered += len(new)
+                if op.stream.incremental:
+                    tokens += len(new)
             if rid in eng._results:
                 op.stream._finish(self._finish_reason(seq))
                 del self._open[rid]
+                finals += 1
+        self._count_events(tokens, finals)
+        return tokens + finals
+
+    def _count_events(self, tokens: int, finals: int) -> None:
+        """``serving_stream_events_total``: the queue events this loop's
+        thread put, by kind."""
+        tel = get_telemetry()
+        if tel.enabled and (tokens or finals):
+            c = tel.counter(
+                "serving_stream_events_total",
+                "events the engine loop put on its requests' stream queues, "
+                "each a wake of the consumer's thread: kind=token only for "
+                "requests that stream, kind=final once a request")
+            if tokens:
+                c.inc(tokens, replica=self.name, kind="token")
+            if finals:
+                c.inc(finals, replica=self.name, kind="final")
+
+    def _deliver_turn(self) -> None:
+        """The delivering half of a turn, under its span: ``open`` requests
+        walked, ``events`` put on their queues."""
+        with span("loop/deliver") as sp:
+            walked = len(self._open)
+            events = self._deliver()
+            self._publish_stats()
+            sp.set_metadata(events=events, open=walked)
 
     @staticmethod
     def _note_progress(op: _Open, seq) -> None:
@@ -586,6 +645,7 @@ class EngineLoop:
             pass
         for op in self._open.values():
             op.stream._fail(msg, code=500, reason="engine_crash")
+        self._count_events(0, len(self._open))
         self._open.clear()
         self._engine.reset_state()
         self._publish_stats()
@@ -622,13 +682,9 @@ class EngineLoop:
                 else:
                     self._consec_crashes = 0
                     self.steps += 1
-                with span("loop/deliver"):
-                    self._deliver()
-                    self._publish_stats()
+                self._deliver_turn()
                 continue
-            with span("loop/deliver"):
-                self._deliver()
-                self._publish_stats()
+            self._deliver_turn()
             with self._lock:
                 idle = (not self._inbox and not self._cancel_ids
                         and not self._pending_calls)
@@ -640,12 +696,13 @@ class EngineLoop:
     def _fail_all(self, msg: str, code: int, reason: str) -> None:
         for op in self._open.values():
             op.stream._fail(msg, code=code, reason=reason)
-        self._open.clear()
         with self._lock:
             items, self._inbox = self._inbox, []
             self._pending_blocks = self._pending_tokens = 0
         for _, _, _, stream in items:
             stream._fail(msg, code=code, reason=reason)
+        self._count_events(0, len(self._open) + len(items))
+        self._open.clear()
         self._drop_calls(msg)
 
     def _run(self) -> None:

@@ -118,6 +118,15 @@ def test_a_dispatch_span_carries_its_work(served):
         assert args["attn_pairs"] >= args["kv_tokens"] >= args["dec_kv_tokens"]
 
 
+def test_a_deliver_span_says_what_it_walked_and_put(served):
+    # three requests that do not stream: the only queue events of the
+    # session are their three ends, whatever the turns they fell in
+    args = served["events"]["loop/deliver"]
+    assert all(set(a) == {"events", "open"} for a in args)
+    assert sum(a["events"] for a in args) == 3
+    assert max(a["open"] for a in args) == 3 and min(a["open"] for a in args) == 0
+
+
 @pytest.mark.parametrize("name", ["request/admit", "request/first_token"])
 def test_a_request_writes_its_two_waits(served, name):
     waits = [a["wait_s"] for a in served["events"][name]]
@@ -178,8 +187,8 @@ def test_no_session_a_dispatch_pays_under_30_us():
             pass
         with span("engine/readback"):
             pass
-        with span("loop/deliver"):
-            pass
+        with span("loop/deliver") as sp:
+            sp.set_metadata(events=3, open=512)
         instant("request/admit", wait_s=0.001)
 
     best = float("inf")
@@ -394,7 +403,11 @@ FIXTURE_OF_METRIC = {"sched.moe_grouped_share": "v5e_moonlight_reason_moe_spans"
                          "kernel.selscan_decode_share",
                          "kernel.selscan_decode_roofline",
                          "kernel.selscan_tile_share",
-                         "kernel.selscan_tile_roofline")},
+                         "kernel.selscan_tile_roofline",
+                         # PR 63: the loop thread of that slice wrote a
+                         # ``loop/deliver`` span a turn (256 open requests,
+                         # none of them streaming)
+                         "loop.deliver_share")},
                      # PR 57: the chunk kernel's readers read a slice of the
                      # Solar-Open2 cell: its spans say ``chunk_slots`` beside
                      # ``chunk_tiles``, its steps run ``kda_chunk`` on a grid
@@ -461,8 +474,10 @@ def _new_readers():
 @pytest.mark.parametrize("metric,cell,fixture", _new_readers())
 def test_a_reader_on_a_slice_recorded_on_the_chip(bench, metric, cell, fixture):
     cellspec, host_spans = bench
-    with open(os.path.join(FIXTURES, fixture + ".expect.json")) as f:
-        expect = json.load(f)
+    expect = {}  # a later PR's readings of a slice lie in a file beside it
+    for path in sorted(glob.glob(os.path.join(FIXTURES, fixture + ".*expect.json"))):
+        with open(path) as f:
+            expect.update(json.load(f))
     read = cellspec.layer_readers(cellspec.resolve(cell))[metric][1]
     value = read(_ctx(cellspec, cell, _timeline(fixture)))
     assert value == pytest.approx(expect[metric], rel=1e-6)
@@ -472,6 +487,25 @@ def test_a_reader_on_a_slice_recorded_on_the_chip(bench, metric, cell, fixture):
     bare = dict(_timeline(fixture), host=[])
     bare["kernels"] = {k: [] for k in bare["kernels"]}
     assert read(_ctx(cellspec, cell, bare)) is None
+
+
+@pytest.mark.parametrize("threads,want", [
+    # two deliveries of 3 ms and 1 ms in a thread that spans 10 ms
+    ([("loop", [["loop/inbox", 0.0, 1e6, {}],
+                ["engine/dispatch", 1e6, 1e6, {"program": "ragged_step_d4_t0"}],
+                ["loop/deliver", 3e6, 3e6, {"events": 0, "open": 4}],
+                ["engine/readback", 6e6, 2e6, {}],
+                ["loop/deliver", 9e6, 1e6, {"events": 1, "open": 4}]])], 40.0),
+    # spans, but no thread that dispatches: not the engine loop's slice
+    ([("handler", [["loop/deliver", 0.0, 1e6, {}]])], None)])
+def test_deliver_share_on_a_hand_made_timeline(bench, threads, want):
+    cellspec, _ = bench
+    cell = "lfm2-8b-a1b-d12.reason-pool"
+    tl = {"host": [{"thread": t, "events": ev} for t, ev in threads],
+          "modules": [["jit_ragged_step_d4_t0(1)", 1.5e6, 4e6]],
+          "busy": [[1.5e6, 5.5e6]], "kernels": {}}
+    read = cellspec.layer_readers(cellspec.resolve(cell))["loop.deliver_share"][1]
+    assert read(_ctx(cellspec, cell, tl)) == pytest.approx(want)
 
 
 @pytest.mark.parametrize("fixture", sorted(FIXTURE_OF.values()))

@@ -9,6 +9,7 @@ import os
 import signal
 import threading
 import time
+from types import SimpleNamespace
 
 import jax.numpy as jnp
 import numpy as np
@@ -242,8 +243,9 @@ class TestEngineLoop:
         baseline = eng.allocator.free_blocks
         loop = EngineLoop(eng, name="t1").start()
         try:
+            # a consumer that reads as tokens come: the request streams
             s = loop.submit(CompletionRequest(prompt=_prompt(5),
-                                              max_tokens=32))
+                                              max_tokens=32, stream=True))
             ev = s.events(timeout=60)
             kind, _ = next(ev)
             assert kind == "token"
@@ -262,6 +264,239 @@ class TestEngineLoop:
         with pytest.raises(ReplicaDraining):
             loop.submit(CompletionRequest(prompt=[1], max_tokens=1))
         assert loop.join(timeout=60)
+
+
+# ------------------------------------- a stream's two kinds, on a fake engine
+class _FakeSeq:
+    def __init__(self, prompt, max_new_tokens, eos_token_id):
+        self.prompt, self.max_new_tokens = prompt, max_new_tokens
+        self.eos_token_id = eos_token_id
+        self.generated: list[int] = []
+        self.pos = len(prompt)
+        self.status = "running"
+        self.t_admit = self.t_first_token = 0.0
+
+
+class _FakeEngine:
+    """What ``EngineLoop`` asks of an engine, with no model behind it: a step
+    gives every running request its next token (11, 12, ...); a request ends
+    at ``max_new_tokens``, by ``cancel`` or by ``expire`` (its deadline)."""
+
+    cfg = SimpleNamespace(block_size=4, num_blocks=49, max_blocks_per_seq=16,
+                          max_seq_len=128)
+
+    def __init__(self):
+        self.allocator = SimpleNamespace(free_blocks=48)
+        self._reserved = 0
+        self._queued: list = []
+        self._running: dict = {}
+        self._results: dict = {}
+        self._seqs: dict = {}
+
+    has_work = property(lambda self: bool(self._running))
+
+    def put(self, uid, prompt, max_new_tokens, eos_token_id=None, **_):
+        self._seqs[uid] = self._running[uid] = _FakeSeq(
+            prompt, max_new_tokens, eos_token_id)
+
+    def get_request(self, uid):
+        return self._seqs.get(uid)
+
+    def step(self):
+        for uid, seq in list(self._running.items()):
+            seq.generated.append(11 + len(seq.generated))
+            if len(seq.generated) >= seq.max_new_tokens:
+                self._end(uid, "finished")
+
+    def _end(self, uid, status):
+        seq = self._running.pop(uid, None)
+        if seq is not None:
+            seq.status = status
+            self._results[uid] = list(seq.generated)
+
+    def cancel(self, uid):
+        self._end(uid, "cancelled")
+
+    def expire(self, uid):
+        self._end(uid, "timeout")
+
+    def reset_state(self):
+        self._running.clear()
+
+
+def _turn(loop, step=True):
+    """One turn of ``EngineLoop._run_loop``, on the caller's thread."""
+    loop._drain_inbox()
+    if step and loop._engine.has_work:
+        loop._engine.step()
+    loop._deliver_turn()
+
+
+def _fake_loop(stream: bool, max_tokens=8, turns=3):
+    """A never-started loop over a fake engine with one request ``turns``
+    tokens in; returns ``(loop, its stream)``."""
+    loop = EngineLoop(_FakeEngine(), name="fake")
+    s = loop.submit(CompletionRequest(prompt=[1, 2, 3], max_tokens=max_tokens,
+                                      stream=stream))
+    assert s.incremental is stream
+    for _ in range(turns):
+        _turn(loop)
+    return loop, s
+
+
+def _end_request(loop, s, how):
+    """End the open request with NO new token in that turn."""
+    eng = loop._engine
+    if how == "cancel":          # EngineLoop.cancel: the client went away
+        loop.cancel(s.request_id)
+    elif how == "abort":         # the engine dropped it (scheduler abort)
+        eng.cancel(s.request_id)
+    elif how == "deadline":
+        eng.expire(s.request_id)
+    else:                        # "crash": a step raised, _contain fails it
+        loop._contain(RuntimeError("boom"))
+        return
+    _turn(loop, step=False)
+
+
+class TestStreamKinds:
+    @pytest.mark.parametrize("how", ["done", "error", "cancel"])
+    def test_a_stream_not_read_as_it_comes_wakes_its_consumer_once(self, how):
+        loop, s = _fake_loop(stream=False, max_tokens=5)
+        # three tokens in: nothing on the queue, so nobody was woken
+        assert s._q.qsize() == 0 and s._held == [11, 12, 13]
+        if how == "done":
+            _turn(loop), _turn(loop)
+            want = [("token", t) for t in (11, 12, 13, 14, 15)] + [
+                ("done", "length")]
+        elif how == "error":
+            _end_request(loop, s, "crash")
+            want = [("token", t) for t in (11, 12, 13)]
+        else:
+            _end_request(loop, s, "cancel")
+            want = [("token", t) for t in (11, 12, 13)] + [
+                ("done", "cancelled")]
+        assert s._q.qsize() == 1  # the terminal event alone
+        got = list(s.events(timeout=5))
+        if how == "error":  # the held tokens first, then the error
+            assert got[:-1] == want and got[-1][0] == "error"
+            assert s.error_reason == "engine_crash" and s.error_code == 500
+        else:
+            assert got == want
+        assert not loop._open
+
+    def test_a_stream_read_as_it_comes_yields_each_token_as_pushed(self):
+        loop, s = _fake_loop(stream=True, max_tokens=5, turns=0)
+        ev = s.events(timeout=5)
+        for n in range(1, 6):
+            _turn(loop)
+            # one queue event a token, there before the request's end
+            assert s._q.qsize() == (1 if n < 5 else 2) and s._held == []
+            assert next(ev) == ("token", 10 + n)
+        assert list(ev) == [("done", "length")]
+
+    @pytest.mark.parametrize("stream", [False, True])
+    @pytest.mark.parametrize("how,kind,value", [
+        ("abort", "done", "cancelled"), ("cancel", "done", "cancelled"),
+        ("deadline", "done", "timeout"), ("crash", "error", None)])
+    def test_an_end_with_no_token_that_turn_still_ends_the_stream(
+            self, stream, how, kind, value):
+        loop, s = _fake_loop(stream=stream)
+        before = s._q.qsize()
+        assert before == (3 if stream else 0)
+        _end_request(loop, s, how)
+        assert s._q.qsize() == before + 1 and not loop._open
+        got = list(s.events(timeout=5))
+        assert got[:-1] == [("token", t) for t in (11, 12, 13)]
+        assert got[-1][0] == kind and (value is None or got[-1][1] == value)
+
+    @pytest.mark.parametrize("how", ["done", "cancel", "crash"])
+    def test_collect_is_the_same_on_both_kinds(self, how):
+        out = []
+        for stream in (False, True):
+            loop, s = _fake_loop(stream=stream, max_tokens=4)
+            if how == "done":
+                _turn(loop)
+            else:
+                _end_request(loop, s, how)
+            try:
+                out.append(s.collect(timeout=5))
+            except Exception as e:  # noqa: BLE001 - compared below
+                out.append((type(e).__name__, str(e), s.error_reason))
+        assert out[0] == out[1]
+        assert out[0][0] == {"done": [11, 12, 13, 14], "cancel": [11, 12, 13],
+                             "crash": "StreamError"}[how]
+
+    @pytest.mark.parametrize("stream", [False, True])
+    def test_consumers_on_many_threads_see_every_token_in_order(self, stream):
+        """More consumers than cores, each parked in ``collect`` while ONE
+        producer pushes: the held list is read only after the terminal event
+        came off the queue, so no token is lost or seen early."""
+        import sys
+
+        from deepspeed_tpu.serving.engine_loop import TokenStream
+
+        n, per = 48, 200
+        streams = [TokenStream(f"s{i}", incremental=stream) for i in range(n)]
+        got: dict = {}
+
+        def consume(i):
+            got[i] = streams[i].collect(timeout=30)
+
+        threads = [threading.Thread(target=consume, args=(i,)) for i in range(n)]
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for k in range(per):
+                for i, s in enumerate(streams):
+                    s._push(1000 * i + k)
+            for s in streams:
+                s._finish("length")
+            for t in threads:
+                t.join(timeout=30)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert got == {i: ([1000 * i + k for k in range(per)], "length")
+                       for i in range(n)}
+
+    @pytest.mark.parametrize("stream", [False, True])
+    def test_what_the_loop_put_on_the_queues_is_counted(self, stream):
+        telemetry.configure(enabled=True)
+        loop = EngineLoop(_FakeEngine(), name="counted")
+        for _ in range(2):
+            loop.submit(CompletionRequest(prompt=[1], max_tokens=3,
+                                          stream=stream))
+        for _ in range(3):
+            _turn(loop)
+        c = telemetry.TELEMETRY.counter("serving_stream_events_total")
+        assert c.value(replica="counted", kind="token") == (6 if stream else 0)
+        assert c.value(replica="counted", kind="final") == 2
+
+    @pytest.mark.parametrize("stream", [False, True])
+    def test_a_clusters_outer_stream_is_read_as_the_request_streams(
+            self, stream, monkeypatch):
+        from deepspeed_tpu.serving.cluster import ServingCluster
+        from deepspeed_tpu.serving.engine_loop import TokenStream
+
+        cluster = ServingCluster(
+            [EngineLoop(_FakeEngine(), name="p0", role="prefill")],
+            [EngineLoop(_FakeEngine(), name="d0", role="decode")])
+        # the two-stage worker is not this test's business
+        monkeypatch.setattr(cluster, "_serve_disagg", lambda req, out: None)
+        req = CompletionRequest(prompt=[1, 2], max_tokens=4, stream=stream)
+        out = cluster.submit(req)
+        assert out.incremental is stream
+        # the hand-on: a decode replica's stream piped into the outer one
+        src = TokenStream(req.request_id, incremental=stream)
+        for t in (5, 6, 7):
+            src._push(t)
+        src._finish("length")
+        assert cluster._pipe(src, out, req, skip=1) == (True, 2)
+        assert out._q.qsize() == (3 if stream else 1)
+        assert out.collect(timeout=5) == ([6, 7], "length")
 
 
 # ---------------------------------------------------------- end-to-end HTTP
